@@ -1,0 +1,50 @@
+"""Where the persistent XLA compilation cache lives — one rule for the CLI,
+``bench.py`` and the tools.
+
+``JAX_COMPILATION_CACHE_DIR``, when set, IS the cache: whoever runs the
+program placed it from outside, and nothing in code sets another. When it
+is not set, the cache is one fixed, git-ignored directory inside the
+checkout — the path is part of a cache entry's key, so a directory that
+moves (a tempdir, a per-user home) never hits.
+
+Stdlib only at import: ``bench.py``'s parent and ``chip_smoke.py`` must be
+able to ask where the cache is without importing jax.
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+
+ENV = "JAX_COMPILATION_CACHE_DIR"
+_MIN_SECS_ENV = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".xla_cache")
+
+
+def cache_dir() -> str:
+    """The directory the rule above names (not created)."""
+    return os.environ.get(ENV) or DEFAULT_DIR
+
+
+def enable() -> str | None:
+    """Turn the persistent cache on at :func:`cache_dir` for this process
+    and its children: creates the directory, exports the env (children
+    inherit it) and, when jax is already imported — it snapshots the env at
+    import — updates its config too. Returns the directory, or None (with
+    one line on stderr) when it cannot be created."""
+    path = cache_dir()
+    try:
+        os.makedirs(path, exist_ok=True)
+    except OSError as e:
+        print(f"🚧 compilation cache {path}: {e}; running uncached",
+              file=sys.stderr)
+        return None
+    os.environ[ENV] = path
+    os.environ.setdefault(_MIN_SECS_ENV, "1")
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          float(os.environ[_MIN_SECS_ENV]))
+    return path

@@ -44,7 +44,7 @@ from ..ops.linear import (
     quantize_weight_q40,
 )
 from ..ops.norms import rms_norm, rms_norm_per_head
-from ..parallel.api import constrain, shard_map
+from ..parallel.api import constrain, on_tpu, shard_map
 from ..parallel.api import current_plan as _current_plan
 from ..runtime import numerics as _numerics
 from ..runtime.kvcache import KVCache, update_layer
@@ -116,7 +116,7 @@ def _use_flash(cfg: ModelConfig, q_shape, kv_shape) -> bool:
                 "(the Pallas kernel can't nest inside the manual pp "
                 "shard_map with auto axes); use 'auto' or 'xla', or pure pp")
         return plan_ok
-    return ok and _fa.default_enabled() and plan_ok
+    return ok and on_tpu() and plan_ok
 
 
 def _sharded_flash(cfg: ModelConfig, plan, q, k_cache, v_cache, start_pos):
@@ -150,11 +150,11 @@ def _sharded_flash(cfg: ModelConfig, plan, q, k_cache, v_cache, start_pos):
                 f"use 'auto'")
         return None
     force = cfg.attn_impl == "flash"
-    if not force and not _fa.default_enabled():
+    if not force and not on_tpu():
         return None
     res = _fa.flash_attention_sharded(
         plan, q, k_cache, v_cache, start_pos, cfg.head_dim,
-        interpret=force and not _fa.default_enabled())
+        interpret=force and not on_tpu())
     if res is None and force:
         raise ValueError(
             f"attn_impl='flash' forced but the sharded kernel does not apply "
@@ -648,7 +648,7 @@ def _layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
                 att = flash_attention(
                     q, k_cache, v_cache, start_pos, cfg.head_dim,
                     interpret=(cfg.attn_impl == "flash"
-                               and not _fa.default_enabled()))
+                               and not on_tpu()))
             else:
                 att = attention(q, k_cache, v_cache, positions, cfg.head_dim)
     att = constrain(att, "batch", None, "heads", None)
@@ -854,6 +854,32 @@ def sampled_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     return sampled_token(logits[:, -1, :], temperature, topp, coin), kv
 
 
+def _exact_f32_dots(fn):
+    """Trace an f32 ("exact") graph with every dot at HIGHEST precision.
+
+    On a TPU the MXU's default for an f32 dot is ONE bf16 pass (~1e-3
+    relative): only the Pallas quantized matmuls asked for HIGHEST, so the
+    attention dots, dense (F32/F16-file) planes and the XLA dequant fallback
+    of the parity mode were bf16-grade, and the reference-binary transcript
+    replayed on the chip flipped its first token at a 1.3e-3 logit margin
+    (tests/test_tpu_hw.py::test_macbeth_transcript_on_hw, PR 22). The
+    precision context is read when a dot is traced — Pallas kernels
+    included — so wrapping the forward covers the whole program family.
+    bf16 (fast) graphs are untouched; the CPU backend computes f32 dots in
+    f32 whatever the precision says."""
+    import functools
+
+    @functools.wraps(fn)
+    def wrapped(params, cfg, *args, **kwargs):
+        if jnp.dtype(cfg.compute_dtype) != jnp.float32:
+            return fn(params, cfg, *args, **kwargs)
+        with jax.default_matmul_precision("highest"):
+            return fn(params, cfg, *args, **kwargs)
+
+    return wrapped
+
+
+@_exact_f32_dots
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             start_pos: jax.Array, kv: KVCache) -> tuple[jax.Array, KVCache]:
     """Full forward: ``tokens [B, T]`` at absolute ``start_pos`` → logits.
@@ -881,14 +907,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         # activation along the ring (parallel/pipeline.py — new capability).
         # Ragged [B] start_pos (batched serving) rides along: each stage's
         # _layer_step gets the per-row depths.
-        from ..parallel.pipeline import pp_forward, pp_manual_supported
+        from ..parallel.pipeline import pp_forward
 
-        if pp_manual_supported(plan):
-            return pp_forward(plan, cfg, params, tokens, start_pos, kv)
-        # mixed pp mesh on a jax whose partial-auto shard_map is broken
-        # (see pp_manual_supported): fall through to the auto-sharded
-        # body — XLA derives the stage transfers from the layer-stack
-        # sharding, value-identical to the manual schedule
+        return pp_forward(plan, cfg, params, tokens, start_pos, kv)
 
     B, T = tokens.shape
     x = params.embedding[tokens].astype(cfg.compute_dtype)
@@ -1163,6 +1184,7 @@ def ragged_verify_step_guarded(params: Params, cfg: ModelConfig,
 # admissions/retirements — the continuous-batching requirement.
 
 
+@_exact_f32_dots
 def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                   pos_vec: jax.Array, pkv, tables: jax.Array,
                   write_lens: jax.Array | None = None):

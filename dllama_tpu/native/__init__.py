@@ -6,7 +6,9 @@ is native C++, like the reference's (src/nn/nn-quants.cpp, and the weight
 slicing half of src/nn/nn-network.cpp:809-854). The library is built on first
 use with ``make`` and falls back to the numpy implementations in
 :mod:`dllama_tpu.formats.quants` when a toolchain isn't available, so the
-package stays importable everywhere.
+package stays importable everywhere. The fallback is never silent: it prints
+one line with the reason, and :func:`describe` says which codec this process
+runs (the CLI banner prints it).
 
 All entry points are ``extern "C"`` over raw buffers; this module wraps them
 with numpy ctypes bindings. Use :func:`get_lib` (returns ``None`` when
@@ -18,6 +20,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 from pathlib import Path
 
 import numpy as np
@@ -26,6 +29,9 @@ _DIR = Path(__file__).resolve().parent
 
 _lib: ctypes.CDLL | None = None
 _tried = False
+# how this process came by its codec (see describe()): "built here", "found
+# built", or why numpy serves instead
+_how = "not loaded yet"
 
 _c_f32p = ctypes.POINTER(ctypes.c_float)
 _c_u8p = ctypes.POINTER(ctypes.c_uint8)
@@ -77,8 +83,9 @@ def _stale() -> bool:
         return True
 
 
-def _build() -> bool:
-    """Build to a per-(host, process) temp name and rename into place:
+def _build() -> str | None:
+    """Build to a per-(host, process) temp name and rename into place
+    (returns None on success, else the reason it failed):
     concurrent first-use builds (pytest workers, multi-process launches) each
     produce a valid .so and the atomic replace keeps the last one. The host
     signature in the temp name keeps two hosts with colliding pids (pid
@@ -90,11 +97,12 @@ def _build() -> bool:
             ["make", "-C", str(_DIR), "-s", f"SO={tmp}"],
             capture_output=True, text=True, timeout=120)
         if proc.returncode != 0 or not (_DIR / tmp).exists():
-            return False
+            err = (proc.stderr or proc.stdout or "").strip().splitlines()
+            return f"make rc={proc.returncode}" + (f": {err[-1]}" if err else "")
         os.replace(_DIR / tmp, _so_path())
-        return True
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+        return None
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{type(e).__name__}: {e}"
     finally:
         (_DIR / tmp).unlink(missing_ok=True)
 
@@ -104,18 +112,25 @@ def get_lib() -> ctypes.CDLL | None:
     or older than its source; None if that fails. Only ever dlopens a .so
     whose filename carries THIS host's CPU signature — a build from another
     machine (shared FS) is invisible rather than a SIGILL risk."""
-    global _lib, _tried
+    global _lib, _tried, _how
     if _lib is not None or _tried:
         return _lib
     _tried = True
     if os.environ.get("DLLAMA_NO_NATIVE"):
+        _how = "numpy (DLLAMA_NO_NATIVE is set)"
         return None
-    if _stale() and not _build() and not _so_path().exists():
-        return None
+    how = f"native, found built ({_so_path().name})"
+    if _stale():
+        why = _build()
+        if why is None:
+            how = f"native, built here from the .cpp ({_so_path().name})"
+        elif not _so_path().exists():
+            return _fallback(f"build failed: {why}")
     try:
         lib = ctypes.CDLL(str(_so_path()))
-    except OSError:
-        return None
+    except OSError as e:
+        return _fallback(f"dlopen failed: {e}")
+    _how = how
     for name, (argtypes, restype) in {
         "q40_quantize": ((_c_f32p, ctypes.c_int64, _c_u8p, ctypes.c_int), None),
         "q40_dequantize": ((_c_u8p, ctypes.c_int64, _c_f32p, ctypes.c_int), None),
@@ -136,8 +151,25 @@ def get_lib() -> ctypes.CDLL | None:
     return _lib
 
 
+def _fallback(why: str) -> None:
+    """Record and SAY that numpy serves instead of the native library."""
+    global _how
+    _how = f"numpy ({why})"
+    print(f"🚧 native codec unavailable, using the numpy codec: {why}",
+          file=sys.stderr)
+    return None
+
+
 def available() -> bool:
     return get_lib() is not None
+
+
+def describe() -> str:
+    """Which codec serves this process and how it got here: ``native, built
+    here from the .cpp (...)``, ``native, found built (...)`` or ``numpy
+    (<reason>)``."""
+    get_lib()
+    return _how
 
 
 def _u8(buf) -> np.ndarray:

@@ -233,11 +233,6 @@ def supports(q_shape: tuple[int, ...], n_kv: int, s: int) -> bool:
             and T * kv_mul <= MAX_TQ)
 
 
-def default_enabled() -> bool:
-    """Flash is the default on TPU backends; the XLA oracle elsewhere."""
-    return jax.default_backend() == "tpu"
-
-
 def flash_attention_sharded(plan, q: jax.Array, k_cache: jax.Array,
                             v_cache: jax.Array, start_pos: jax.Array,
                             head_dim: int, *, interpret: bool = False):

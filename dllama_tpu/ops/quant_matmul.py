@@ -37,7 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..parallel.api import shard_map
+from ..parallel.api import on_tpu, shard_map
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -226,11 +226,15 @@ def _decode_call(xf: jax.Array, w: QuantizedWeight, *, interpret: bool,
 
 def _pick_block(dim: int, candidates: tuple[int, ...], min_align: int) -> int | None:
     """A 128-aligned block dividing ``dim``, or the whole dim (Mosaic allows a
-    block equal to the array extent) when it at least meets ``min_align``."""
+    block equal to the array extent) when it at least meets ``min_align`` and
+    is no larger than the largest candidate: a whole-dim block past that
+    outgrows VMEM (the chip's compiler refuses the tp=4 logits shard,
+    N = 128256/4 = 32064 = 64 x 501, with 134 MB of spills) — such a shape
+    gets None and its caller the XLA dequant+dot path."""
     for c in candidates:
         if dim % c == 0:
             return c
-    if dim % min_align == 0:
+    if dim % min_align == 0 and dim <= max(candidates):
         return dim
     return None
 
@@ -416,16 +420,16 @@ def pallas_mode_gate(fast: bool) -> dict | None:  # dlint: static-fn
     wire pricing — one rule, so none of them can drift from what
     linear() dispatches (dlint rule ``pallas-gate`` machine-checks the
     routing)."""
-    from .linear import _kernel_mode, _on_tpu  # lazy: linear imports us
+    from .linear import _kernel_mode  # lazy: linear imports us
 
     mode = _kernel_mode()
     if mode == "xla":
         return None
     if mode == "fused":
-        return {"interpret": not _on_tpu(), "fused": True}
-    if mode != "pallas" and (fast or not _on_tpu()):
+        return {"interpret": not on_tpu(), "fused": True}
+    if mode != "pallas" and (fast or not on_tpu()):
         return None
-    return {"interpret": mode == "pallas" and not _on_tpu()}
+    return {"interpret": mode == "pallas" and not on_tpu()}
 
 
 def wants_fused(kw: dict | None) -> bool:  # dlint: static-fn

@@ -115,24 +115,23 @@ def use_plan(plan: MeshPlan | None):
         _state.plan = prev
 
 
+def on_tpu() -> bool:
+    """THE rule for "does this process drive a TPU": the default backend's
+    platform name. Every kernel gate (``quant_matmul.pallas_mode_gate``,
+    ``paged_attention.kernel_choice``, the flash-attention default, the sp
+    ring's per-block kernel) asks here, so ``interpret`` can never come out
+    differently for two kernels of one program."""
+    return jax.default_backend() == "tpu"
+
+
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
               axis_names=None):
-    """Version-compat ``shard_map``: the top-level ``jax.shard_map``
-    (jax ≥ 0.5: ``check_vma`` / ``axis_names``) or the 0.4.x
-    ``jax.experimental.shard_map`` (``check_rep`` / ``auto`` — the axes
-    NOT named manual). All manual-SPMD call sites route through here so
-    a jax upgrade/downgrade is one shim, not six edits."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, **kw)
+    """The single ``jax.shard_map`` entry: all manual-SPMD call sites route
+    through here (dlint rule ``shard-map-shim``), so a change of the JAX API
+    is one edit, not six."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def plan_scoped_jit(fun, *, program: str | None = None,

@@ -62,25 +62,6 @@ def _repl_specs(tree):
     return jax.tree.map(lambda a: P(*([None] * a.ndim)), tree)
 
 
-def pp_manual_supported(plan: "MeshPlan") -> bool:
-    """Whether the manual pipeline schedule can run on this jax/mesh.
-
-    A mixed mesh (pp × tp/sp/dp) needs PARTIAL-AUTO shard_map — pp
-    manual, the other axes left to XLA inside each stage. On jax 0.4.x
-    (no top-level ``jax.shard_map``) that mode is broken on the SPMD
-    partitioner: ``lax.axis_index`` lowers to a PartitionId instruction
-    it rejects, and some partial-auto input layouts hard-crash the
-    partitioner outright. Full-manual (pure-pp mesh) always works.
-    Callers (models.llama.forward) fall back to the auto-sharded body
-    when this is False — value-identical (XLA derives the stage
-    transfers from the layer-stack sharding), merely without the manual
-    schedule's compute/transfer overlap."""
-    if hasattr(jax, "shard_map"):
-        return True
-    return all(plan.mesh.shape[a] == 1
-               for a in plan.mesh.axis_names if a != AXIS)
-
-
 def pp_forward(plan: "MeshPlan", cfg: "ModelConfig", params, tokens, start_pos,
                kv):
     """Full forward with the layer stack sharded over ``pp``.

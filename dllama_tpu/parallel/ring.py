@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .api import shard_map
+from .api import on_tpu, shard_map
 
 if TYPE_CHECKING:
     from .api import MeshPlan
@@ -279,8 +279,8 @@ def _kernel_eligible(plan: "MeshPlan", q_shape, kv_shape,
                 f"q={q_shape}, S_local={S // n_sp} (needs S/sp % 128 == 0)")
         return False, False
     if attn_impl == "flash":
-        return True, not _fa.default_enabled()
-    return _fa.default_enabled(), False
+        return True, not on_tpu()
+    return on_tpu(), False
 
 
 def sp_attention(plan: "MeshPlan", q: jax.Array, k_cache: jax.Array,

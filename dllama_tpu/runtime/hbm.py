@@ -36,21 +36,22 @@ _FIXED_OVERHEAD = 512 * 1024 * 1024
 
 
 def device_memory_bytes() -> int | None:
-    """The per-device memory limit, or None when unknown (CPU backend,
-    plugin without memory_stats). ``DLLAMA_HBM_BYTES`` overrides (testing +
-    plugins that misreport)."""
+    """The per-device memory limit, or None on a backend that has none to
+    report (the CPU mesh). A TPU that cannot say its limit is an error, not
+    an "unknown budget": every guard below would silently stand down on the
+    one platform it exists for. ``DLLAMA_HBM_BYTES`` overrides (testing)."""
     env = os.environ.get("DLLAMA_HBM_BYTES")
     if env:
         return int(env)
-    try:
-        import jax
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-        if stats:
-            return stats.get("bytes_limit")
-    except Exception:  # noqa: BLE001 — no stats is simply "unknown"
-        return None
-    return None
+    dev = jax.local_devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if limit is None and dev.platform == "tpu":
+        raise RuntimeError(
+            f"{dev} reports no memory_stats()['bytes_limit']: the HBM "
+            f"budget cannot be checked (set DLLAMA_HBM_BYTES to state it)")
+    return limit
 
 
 def matmul_weight_count(cfg) -> int:

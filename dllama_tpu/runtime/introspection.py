@@ -40,6 +40,7 @@ telemetry lint tooling can import it without a backend.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 from collections import deque
@@ -134,10 +135,31 @@ def cost_analysis_dict(compiled) -> dict:
     return dict(ca) if ca else {}
 
 
+_MOSAIC_CALL = re.compile(
+    r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"')
+_JIT_NAME = re.compile(r"jit\(([^()]*)\)")
+
+
+def mosaic_kernels(hlo_text: str) -> dict[str, int]:
+    """The COMPILED Pallas (Mosaic) kernels of an optimized HLO text, counted
+    by the jitted function that made the ``pallas_call`` (``quant_matmul``,
+    ``_call`` of flash attention, ``paged_ragged_attention``, ...). A kernel
+    run in interpret mode lowers to plain HLO and never appears here, so a
+    nonempty answer is the proof that a program runs the kernels themselves.
+    A call inside a layer scan's body counts once, as the text holds it."""
+    out: dict[str, int] = {}
+    for op_name in _MOSAIC_CALL.findall(hlo_text):
+        names = _JIT_NAME.findall(op_name)
+        key = names[-1] if names else "pallas_call"
+        out[key] = out.get(key, 0) + 1
+    return out
+
+
 def analyze_compiled(program: str, compiled, *,
                      scope: str = "default") -> dict:
-    """Pull ``memory_analysis()`` bytes and ``cost_analysis()`` FLOPs off a
-    compiled stage and publish them as per-(scope, program) gauges — two
+    """Pull ``memory_analysis()`` bytes, ``cost_analysis()`` FLOPs and the
+    compiled Pallas kernels (:func:`mosaic_kernels`) off a compiled stage
+    and publish the first two as per-(scope, program) gauges — two
     engines share program NAMES (``forward``, ``sampled_step``) but not
     shapes or shardings, so a scope-less gauge would let whichever engine
     compiled last silently overwrite the other's bytes. Best-effort: a
@@ -164,6 +186,10 @@ def analyze_compiled(program: str, compiled, *,
                                                program=program)
     except Exception as e:  # noqa: BLE001 — analysis is advisory, record why
         out["cost_analysis_error"] = f"{type(e).__name__}: {e}"
+    try:
+        out["kernels"] = mosaic_kernels(compiled.as_text())
+    except Exception as e:  # noqa: BLE001 — analysis is advisory, record why
+        out["kernels_error"] = f"{type(e).__name__}: {e}"
     return out
 
 
@@ -434,6 +460,40 @@ def _gb(n: float) -> str:
     return f"{n / 1024 ** 3:.2f} GB" if n >= 1024 ** 2 else f"{n / 1024:.0f} kB"
 
 
+def hbm_budget_line(engine) -> str:
+    """The one-line per-device HBM budget: the shape-algebra estimate
+    (runtime/hbm.py) against the limit the device reports."""
+    from .hbm import device_memory_bytes
+
+    est = engine.hbm_estimate
+    limit = device_memory_bytes()
+    return (f"🧮 HBM budget/device: weights {_gb(est['weights_bytes'])} + "
+            f"KV {_gb(est['kv_bytes'])} over {engine.tp * engine.pp} "
+            f"shard(s) + margin → need {_gb(est['need_per_device'])}"
+            + (f" of {_gb(limit)}" if limit else " (device limit unknown)"))
+
+
+def compile_report(scope: str, emit=print) -> None:
+    """One line per program the ledger saw compile in ``scope``: wall and
+    XLA-backend seconds (backend 0 = the persistent cache served it) and,
+    where a miss was analyzed (``ledger().analyze``), its measured HBM bytes
+    and compiled Pallas kernels."""
+    events = [e for e in _ledger.snapshot()["events"] if e["scope"] == scope]
+    emit(f"🧮 compiles: {len(events)} in {scope}, "
+         f"{sum(e['compile_s'] for e in events):.2f} s wall, "
+         f"{sum(e['backend_s'] for e in events):.2f} s in the XLA backend")
+    for e in events:
+        a = e["analysis"] or {}
+        kern = a.get("kernels")
+        emit(f"🧮   compiled {e['program']}: {e['compile_s']:.2f} s wall, "
+             f"{e['backend_s']:.2f} s backend"
+             + (f", HBM {_gb(a['hbm_total_bytes'])}"
+                if a.get("hbm_total_bytes") else "")
+             + ("" if kern is None else ", Pallas kernels: "
+                + (" ".join(f"{k}x{n}" for k, n in sorted(kern.items()))
+                   or "none")))
+
+
 def hbm_startup_report(engine, emit=print) -> dict:
     """Per-device HBM budget table at engine load: the shape-algebra
     estimate (runtime/hbm.py — weights + KV + margin) cross-checked against
@@ -454,10 +514,7 @@ def hbm_startup_report(engine, emit=print) -> dict:
         "n_shards": engine.tp * engine.pp,
         "programs": {},
     }
-    emit(f"🧮 HBM budget/device: weights {_gb(est['weights_bytes'])} + "
-         f"KV {_gb(est['kv_bytes'])} over {report['n_shards']} shard(s) "
-         f"+ margin → need {_gb(est['need_per_device'])}"
-         + (f" of {_gb(limit)}" if limit else " (device limit unknown)"))
+    emit(hbm_budget_line(engine))
     max_temp = 0
     scope = getattr(engine, "introspection_scope", "default")
     for name in ("decode", "prefill"):

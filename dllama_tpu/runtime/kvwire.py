@@ -90,7 +90,7 @@ class DeadlineExceeded(KVWireError):
 
 
 # the closed ``dllama_kvwire_fallback_total{reason}`` vocabulary (the
-# failure-taxonomy dlint rule holds call sites and PERF.md to it):
+# failure-taxonomy dlint rule holds call sites and TELEMETRY.md to it):
 # "timeout" deadline/socket expiry, "crc" integrity or geometry refusal,
 # "peer_death" the peer vanished mid-transfer, "exhaustion" the import
 # side could not stage blocks (assigned in runtime/serving.py, not here)

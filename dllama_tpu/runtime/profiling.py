@@ -101,6 +101,23 @@ OP_CLASSES = (
 )
 
 
+# A TPU device lane names each event with the WHOLE HLO instruction
+# (`%psum.22 = f32[1,1,2048]{2,1,0:T(1,128)S(1)} all-reduce(%bitcast.253),
+# ...`, seen on the v5e, PR 22), so a name regex would call any fusion that
+# merely consumes `%all-reduce.3` a collective. Such a name is reduced to
+# "<instruction> <opcode>" first; the opcode is the first word that follows
+# whitespace and precedes "(" (tilings like `T(8,128)` follow ":" or ")").
+_HLO_INSTR_RE = re.compile(r"^%([\w.\-]+) = .*?\s([a-z][\w\-]*)\(")
+
+
+def op_label(name: str) -> str:
+    """The event name every classifier below reads: CPU thunk names pass
+    through, a TPU lane's full-instruction names become
+    ``"psum.22 all-reduce"``."""
+    m = _HLO_INSTR_RE.match(name)
+    return f"{m.group(1)} {m.group(2)}" if m else name
+
+
 def classify_op(name: str) -> str:
     """Op-class label for one device event name (see :data:`OP_CLASSES`;
     ``"other"`` for everything unmatched)."""
@@ -159,7 +176,7 @@ def op_attribution(trace_dir: str | None = None, *, xspace=None,
         # to absolute ns so the cross-lane union compares real intervals
         base_ns = getattr(line, "timestamp_ns", 0) or 0
         for ev in line.events:
-            name = names.get(ev.metadata_id, str(ev.metadata_id))
+            name = op_label(names.get(ev.metadata_id, str(ev.metadata_id)))
             if _NOISE_RE.search(name):
                 continue
             dur = ev.duration_ps // 1000  # -> ns
@@ -230,28 +247,33 @@ def _union_ms(intervals: list[tuple[int, int]]) -> float:
     return union_span(intervals) / 1e9
 
 
-# CPU-backend executor lane families, in preference order. The naming has
-# changed across jaxlib's CPU-runtime rewrites: tf_XLAPjRt* client threads
-# (older), then the thunk runtime's tf_XLAEigen* per-device intra-op pools
-# (which carry the thunk-level op events, collectives included) with
-# tf_XLATfrtCpuClient* dispatch threads around them.
+# CPU-backend executor lane families. The naming has changed across jaxlib's
+# CPU-runtime rewrites: tf_XLAPjRt* client threads (older), then the thunk
+# runtime's tf_XLAEigen* per-device intra-op pools with tf_XLATfrtCpuClient*
+# dispatch threads around them. Under jaxlib 0.9 BOTH tf_XLAPjRtCpuClient*
+# and tf_XLAEigen* lanes carry thunk-level op events (the client thread runs
+# a program's first thunks, the pool the rest, collectives included), so the
+# family is chosen by what it carries, not by its name: the one with the
+# most op events (this order breaks ties).
 _CPU_LANE_FAMILIES = ("tf_XLAPjRt", "tf_XLAEigen", "tf_XLATfrtCpuClient")
 
 
 def _device_lines(xspace):
     """(plane, line) pairs for lanes that carry per-op device events:
-    TPU/GPU ``/device:*`` planes ("XLA Ops" lines), or the CPU backend's
-    executor lanes. Exactly ONE lane family is used — the first in
-    preference order with any events — because mixing families would
-    inflate the lane count (client dispatch threads are not devices) and
-    skew the per-lane average the Eval/Sync split divides by."""
+    TPU/GPU ``/device:*`` planes (the line named exactly "XLA Ops": a v5e
+    plane also has "XLA Modules", "Async XLA Ops" and "TC Overlay", and
+    taking every line whose name CONTAINS "XLA Ops" counted each device as
+    two lanes), or the CPU backend's executor lanes. Exactly ONE lane family is used — the one carrying
+    the most op events (runtime bookkeeping excluded) — because mixing
+    families would inflate the lane count (client dispatch threads are not
+    devices) and skew the per-lane average the Eval/Sync split divides by."""
     device: list = []
     families: dict[str, list] = {f: [] for f in _CPU_LANE_FAMILIES}
     for plane in xspace.planes:
         is_dev = "/device:" in plane.name
         for line in plane.lines:
-            if is_dev and plane.lines and (
-                    "XLA Ops" in line.name or len(plane.lines) == 1):
+            if is_dev and (line.name == "XLA Ops"
+                           or len(plane.lines) == 1):
                 device.append((plane, line))
                 continue
             for fam in _CPU_LANE_FAMILIES:
@@ -260,11 +282,14 @@ def _device_lines(xspace):
                     break
     if device:
         return device
-    for fam in _CPU_LANE_FAMILIES:
-        lanes = families[fam]
-        if any(len(line.events) for _, line in lanes):
-            return lanes
-    return []
+
+    def n_ops(fam: str) -> int:
+        return sum(1 for plane, line in families[fam] for ev in line.events
+                   if not _NOISE_RE.search(
+                       plane.event_metadata[ev.metadata_id].name))
+
+    best = max(_CPU_LANE_FAMILIES, key=n_ops)  # first of the maxima
+    return families[best] if n_ops(best) else []
 
 
 _xplane_pb2 = None
@@ -341,7 +366,7 @@ def split_from_trace(trace_dir: str, n_steps: int) -> EvalSyncSplit:
         sync_iv: list[tuple[int, int]] = []
         eval_iv: list[tuple[int, int]] = []
         for ev in line.events:
-            name = evmeta[ev.metadata_id].name
+            name = op_label(evmeta[ev.metadata_id].name)
             if _NOISE_RE.search(name):
                 continue
             span = (ev.offset_ps, ev.offset_ps + ev.duration_ps)
@@ -458,8 +483,13 @@ _DTYPE_BYTES = {
 # and be followed by its `(` operand list — consumer lines that merely
 # reference `%all-reduce.3` as an operand never match, and the -done half of
 # an async start/done pair is skipped so each collective counts once.
+# The layout after the shape is skipped as one `{...}` group: the TPU
+# compiler writes tilings with parentheses there (`{2,1,0:T(1,128)S(1)}`),
+# which a "no `(` before the opcode" rule read as "not a collective" — on
+# the chip every tp program reported zero collectives (PR 22).
 _COLL_RE = re.compile(
-    r"=\s*\(?\s*([a-z0-9]+)\[([0-9,]*)\][^=(]*?\s"
+    r"=\s*\(?\s*([a-z0-9]+)\[([0-9,]*)\](?:\{[^}]*\})?"
+    r"(?:,\s*[a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)*\)?\s"
     r"((?:all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
     r"|collective-broadcast)(?:-start|-done)?)\(")
 

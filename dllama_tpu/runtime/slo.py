@@ -40,7 +40,7 @@ import time
 from . import telemetry
 
 # the closed-world objective vocabulary: cli grammar, /debug/slo,
-# gauges, bench output, and PERF.md all spell these names exactly
+# gauges, bench output, and TELEMETRY.md all spell these names exactly
 OBJECTIVES = ("ttft_p95_ms", "itl_p50_ms", "shed_rate")
 
 # burn-rate windows (label, seconds) — the classic short/long pair: the

@@ -36,7 +36,7 @@ from dataclasses import dataclass
 # -- metric name constants ----------------------------------------------------
 # One declaration point: instrumentation imports these; the lint
 # (tools/check_metrics_names.py) checks every name matches dllama_[a-z_]+
-# and is documented in PERF.md.
+# and is documented in TELEMETRY.md.
 
 # engine (runtime/engine.py)
 PREFILL_CHUNK_MS = "dllama_prefill_chunk_ms"
@@ -804,7 +804,7 @@ def registry() -> Registry:
 # The documented span-phase vocabulary — the closed world
 # tools/check_span_phases.py lints against (both directions: every
 # tracer().emit call site uses a name listed here, and every name here
-# has a call site and a PERF.md mention):
+# has a call site and a TELEMETRY.md mention):
 #
 # * ``queue`` — submit → admission start (batched serving).
 # * ``admit`` — the paged pool's admission bookkeeping (block

@@ -136,7 +136,7 @@ RESUME_FROM_HEADER = "X-Dllama-Resume-From"
 TENANT_HEADER = "X-Dllama-Tenant"
 # Closed outcome vocabulary of dllama_router_stream_resumes_total (the
 # failure-taxonomy dlint rule holds it to telemetry's label docs and
-# PERF.md): resumed — continuation spliced, the client's transcript
+# TELEMETRY.md): resumed — continuation spliced, the client's transcript
 # continued token-exactly; exhausted — another death after
 # --max-stream-resumes continuations; no_budget — no remaining
 # --request-timeout budget to resume into; failed — the re-dispatch

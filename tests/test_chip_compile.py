@@ -1,0 +1,200 @@
+"""Compile the main path's Pallas kernels for a TPU v5e that is DESCRIBED, not
+attached, at Llama-3.2-1B widths — the chip's own compiler refuses here what
+it would refuse there (unaligned slices, too much VMEM), at no chip time.
+Plus a CPU rehearsal of ``chip_smoke.py``'s control flow at a toy size.
+
+Nothing runs on a device: a compile that passes is not a chip run.
+
+The rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture that skips when it
+cannot be, never at import, never in a ``skipif`` or a ``parametrize``
+argument, never ``autouse``; shardings and shapes are built in fixtures or
+tests; every compile runs in the test's own process (the process that loaded
+the TPU library keeps it); the persistent compilation cache is off around
+them (an entry compiled for a described chip cannot be read back without
+one). All such tests live in THIS file, so that one xdist worker gets them.
+"""
+
+import functools
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# meta-llama/Llama-3.2-1B
+DIM, HIDDEN, VOCAB = 2048, 8192, 128256
+N_HEADS, N_KV, HEAD_DIM = 32, 8, 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever stops it, the tests cannot run
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compiled_kernels(fn, *args) -> dict:
+    """Compile ``fn`` for the described chip; the Mosaic kernels in the
+    result (a kernel lowered in interpret mode would not be among them)."""
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+
+    return mosaic_kernels(jax.jit(fn).lower(*args).compile().as_text())
+
+
+def test_described_device_is_a_v5e_with_a_roofline_row(topo):
+    from dllama_tpu.runtime import roofline
+
+    dev = topo.devices[0]
+    assert dev.platform == "tpu" and len(topo.devices) == 4
+    ceil = roofline.nameplate_ceilings(dev.device_kind)
+    assert (ceil.tflops, ceil.hbm_gbps) == (197.0, 819.0), dev.device_kind
+
+
+# every plane of the model, at decode (1 row), verify (16) and prefill (128)
+# widths; exact = f32 scales + f32 activations, fast = bf16 scales + bf16
+@pytest.mark.parametrize("k,n,rows,fast,fused", [
+    (DIM, DIM, 1, False, False),       # wq / wo
+    (DIM, 512, 1, False, False),       # wk / wv
+    (DIM, HIDDEN, 1, False, False),    # w1 / w3
+    (HIDDEN, DIM, 1, False, False),    # w2
+    (DIM, VOCAB, 1, False, False),     # the logits head
+    (DIM, HIDDEN, 16, False, False),
+    (HIDDEN, DIM, 128, False, False),
+    (DIM, VOCAB, 128, False, False),
+    (DIM, HIDDEN, 128, True, False),
+    (DIM, HIDDEN, 1, False, True),     # the decode-shaped fused kernel
+    (HIDDEN, DIM, 16, True, True),
+    (DIM, VOCAB, 1, True, True),
+])
+def test_quant_matmul_compiles_for_v5e(one_chip, k, n, rows, fast, fused):
+    from dllama_tpu.ops.linear import QuantizedWeight
+    from dllama_tpu.ops.quant_matmul import (quant_matmul, supports,
+                                             supports_decode)
+
+    act = jnp.bfloat16 if fast else jnp.float32
+    w = QuantizedWeight(scales=_shape(one_chip, (k // 32, n), act),
+                        codes=_shape(one_chip, (k, n), jnp.int8))
+    x = _shape(one_chip, (1, rows, k), act)
+    assert supports(x.shape, w)
+    if fused:
+        assert supports_decode(x.shape, w, fast)
+    kernels = _compiled_kernels(
+        functools.partial(quant_matmul, interpret=False, fast=fast,
+                          fused=fused), x, w)
+    assert kernels.get("quant_matmul") == 1, kernels
+
+
+def test_tiled_kernel_declines_the_tp4_logits_shard():
+    """128256 / 4 = 32064 = 64 x 501 has no 128-aligned divisor. Taken as one
+    whole-N block it needs 134 MB of VMEM and the chip's compiler refuses the
+    tp=4 prefill program; the shape gate must say no, so that the shard takes
+    the XLA dequant+dot path."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+    from dllama_tpu.ops.quant_matmul import supports
+
+    n = VOCAB // 4
+    w = QuantizedWeight(scales=jax.ShapeDtypeStruct((DIM // 32, n), jnp.float32),
+                        codes=jax.ShapeDtypeStruct((DIM, n), jnp.int8))
+    assert not supports((1, 64, DIM), w)
+    assert not supports((1, 1, DIM), w)
+
+
+@pytest.mark.parametrize("seq,rows,cache", [
+    (1024, 1, jnp.float32),
+    (4096, 1, jnp.float32),    # chip_smoke.py's --max-seq-len
+    (4096, 64, jnp.float32),   # a prefill chunk
+    (8192, 1, jnp.bfloat16),
+    (4096, 64, jnp.bfloat16),
+])
+def test_flash_attention_compiles_for_v5e(one_chip, seq, rows, cache):
+    from dllama_tpu.ops.flash_attention import flash_attention, supports
+
+    q = _shape(one_chip, (1, rows, N_HEADS, HEAD_DIM), jnp.float32)
+    kv = _shape(one_chip, (1, N_KV, seq, HEAD_DIM), cache)
+    assert supports(q.shape, N_KV, seq)
+    kernels = _compiled_kernels(
+        functools.partial(flash_attention, head_dim=HEAD_DIM, interpret=False),
+        q, kv, kv, _shape(one_chip, (), jnp.int32))
+    assert kernels.get("_call") == 1, kernels
+
+
+@pytest.mark.parametrize("block,per_seq,slots,pool", [
+    (16, 8, 4, jnp.float32),
+    (16, 64, 4, jnp.bfloat16),
+    (16, 256, 4, jnp.bfloat16),   # chip_smoke.py: 4096 / 16, 4 slots, bf16
+    (32, 64, 4, jnp.float32),
+    (128, 32, 8, jnp.bfloat16),
+])
+def test_paged_attention_compiles_for_v5e(one_chip, block, per_seq, slots,
+                                          pool):
+    from dllama_tpu.ops.paged_attention import (paged_ragged_attention,
+                                                supports)
+
+    q = _shape(one_chip, (slots, 1, N_HEADS, HEAD_DIM), jnp.float32)
+    kv = _shape(one_chip, (slots * per_seq + 1, N_KV, block, HEAD_DIM), pool)
+    assert supports(q.shape, N_KV, per_seq, block)
+    kernels = _compiled_kernels(
+        functools.partial(paged_ragged_attention, head_dim=HEAD_DIM,
+                          interpret=False),
+        q, kv, kv, _shape(one_chip, (slots, per_seq), jnp.int32),
+        _shape(one_chip, (slots, 1), jnp.int32))
+    assert kernels.get("paged_ragged_attention") == 1, kernels
+
+
+# -- chip_smoke.py's control flow, without a chip ------------------------------
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_chip_smoke_rehearsal_on_cpu(chips):
+    """The script end to end at a toy size with JAX_PLATFORMS=cpu children
+    (``--rehearse``): every phase's control flow and the contract's last
+    line, with ``platform`` relaxed to what a rehearsal can have."""
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--rehearse",
+         "--chips", str(chips)],
+        capture_output=True, text=True, cwd=REPO, timeout=600)
+    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-2000:]
+    last = json.loads(p.stdout.strip().splitlines()[-1])
+    assert last["ok"] is True and last["rehearsal"] is True
+    assert last["device"] == {"platform": "cpu", "kind": "cpu", "count": chips}
+    assert "SIGTERM drained, exit 0" in p.stdout
+    if chips == 4:
+        assert "tp=4 text == tp=1 text" in p.stdout
+
+
+def test_chip_smoke_refuses_to_run_without_a_chip():
+    """The default run starts its children with JAX_PLATFORMS=tpu, so that jax
+    itself refuses when there is no chip: nonzero exit, no result line."""
+    p = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       capture_output=True, text=True, cwd=REPO, timeout=300)
+    assert p.returncode != 0
+    assert '"ok"' not in p.stdout

@@ -348,7 +348,7 @@ def test_metrics_names_fixture_violations(tmp_path):
     from tools.dlint import metrics_names
 
     project = _tree(tmp_path, {
-        "PERF.md": "dllama_counter_total\n",
+        "dllama_tpu/runtime/TELEMETRY.md": "dllama_counter_total\n",
         "dllama_tpu/x.py": 'NAME = "dllama_orphan_total"\n',
     })
     specs = {
@@ -578,7 +578,7 @@ def test_tenant_reasons_fixture(tmp_path):
                                      tenant=tenant)
         ''',
         "dllama_tpu/serve/router.py": "",
-        "PERF.md": "`dllama_tenant_shed_total{tenant,reason}` — sheds.\n"
+        "dllama_tpu/runtime/TELEMETRY.md": "`dllama_tenant_shed_total{tenant,reason}` — sheds.\n"
                    "Reasons: queue_full, ghost_reason.\n",
     })
     specs = {"dllama_tenant_shed_total": SimpleNamespace(

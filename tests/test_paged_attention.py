@@ -49,10 +49,20 @@ def _mk(rng, B, T, n_heads, n_kv, hd, bs, M, nb, dtype=jnp.float32):
 
 
 def _assert_bitwise(q, k_pool, v_pool, tables, positions, hd):
+    """Kernel vs reference, to the last few bits. Written as bitwise
+    equality, which held on the jaxlib this was written against. Under
+    jaxlib 0.9 the CPU backend blocks the reference's batched einsum and the
+    interpret-mode kernel's per-(b, h) dots differently, and the two differ
+    in the last bit (max abs 2.4e-7 on outputs of order 1, PR 22). Every
+    structural fault these cases hunt (a wrong block, a leaked null block, a
+    mask off by one row) is wrong by order 1, so a few ulps of slack loses
+    nothing; whether the COMPILED kernel is bit-identical is the chip's to
+    say (test_paged_kernel_compiled_parity_on_hw)."""
     got = paged_ragged_attention(q, k_pool, v_pool, tables, positions, hd,
                                  interpret=True)
     want = _reference(q, k_pool, v_pool, tables, positions, hd)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-6, atol=2e-6)
 
 
 def test_scrambled_block_table_bitwise():
@@ -244,9 +254,8 @@ def test_paged_kernel_steady_state_never_retraces(monkeypatch):
 
 @pytest.mark.tpu
 def test_paged_kernel_compiled_parity_on_hw():
-    devs = jax.devices()
-    if not devs or "tpu" not in devs[0].device_kind.lower():
-        pytest.skip(f"no TPU backend (devices: {devs})")
+    if jax.default_backend() != "tpu":  # the repo's one rule: parallel.api.on_tpu
+        pytest.skip(f"no TPU backend (devices: {jax.devices()})")
     rng = np.random.default_rng(31)
     B, T, n_heads, n_kv, hd, bs, M, nb = 2, 1, 8, 2, 128, 16, 4, 10
     q, kp, vp = _mk(rng, B, T, n_heads, n_kv, hd, bs, M, nb)

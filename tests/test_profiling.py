@@ -224,6 +224,48 @@ def test_collective_traffic_async_pairs_and_consumers_count_once():
     assert tr.sent_kb == pytest.approx(2 * (4 * 1024 * 4 / 1024) * 7 / 8)
 
 
+@pytest.mark.parametrize("name,label,sync", [
+    # a v5e lane names an event with the whole instruction (PR 22)
+    ('%psum.22 = f32[1,1,2048]{2,1,0:T(1,128)S(1)} all-reduce(%bitcast.253), '
+     'channel_id=1, replica_groups={{0,1,2,3}}', "psum.22 all-reduce", True),
+    # a fusion that CONSUMES a collective is not one
+    ('%fusion.3 = bf16[2048,2048]{1,0:T(8,128)(2,1)S(1)} fusion(f32[2048,2048]'
+     ' %all-reduce.3), kind=kLoop', "fusion.3 fusion", False),
+    ('%copy-start = (f32[8,128]{1,0:T(8,128)S(1)}, f32[8,128]{1,0:T(8,128)}, '
+     'u32[]{:S(2)}) copy-start(f32[8,128]{1,0:T(8,128)} %x.1)',
+     "copy-start copy-start", False),
+    # CPU thunk names pass through
+    ("dot.31", "dot.31", False),
+    ("Rendezvous", "Rendezvous", True),
+])
+def test_op_label_reduces_tpu_instruction_names(name, label, sync):
+    from dllama_tpu.runtime import profiling
+
+    assert profiling.op_label(name) == label
+    assert bool(profiling._SYNC_RE.search(profiling.op_label(name))) is sync
+
+
+def test_collective_traffic_reads_the_tpu_compilers_layouts():
+    """Lines as the v5e compiler writes them (the tp=4 Llama-3.2-1B decode
+    step, PR 22): tiled layouts carry parentheses, results can be tuples,
+    and the per-layer psums sit in the layer scan's while body."""
+    hlo = """
+%wide.region_0.5.clone (wide.arg: (s32[], f32[1,1,2048])) -> (s32[], f32[1,1,2048]) {
+  %psum.22 = f32[1,1,2048]{2,1,0:T(1,128)S(1)} all-reduce(%bitcast.253), channel_id=1, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%region_2.6, metadata={op_name="jit(greedy_step_guarded)/while/body/closed_call/shard_map/psum"}
+}
+ENTRY %main.7 (p0: f32[4]) -> f32[4] {
+  %while.1 = (s32[], f32[1,1,2048]{2,1,0:T(1,128)}) while(%tuple.1), condition=%cond.3, body=%wide.region_0.5.clone
+  %all-reduce.3 = (s32[4]{0:T(128)S(1)}, s32[1]{0:T(128)}) all-reduce(%dynamic-update-slice.8, %bitcast.11), channel_id=6, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add.1
+  %get-tuple-element.686 = s32[4]{0:T(128)S(1)} get-tuple-element(%all-reduce.3), index=0
+}
+"""
+    tr = collective_traffic(hlo, n_devices=4, loop_multiplier=16)
+    assert tr.n_collectives == 16 + 1
+    psum = 16 * 2 * (2048 * 4 / 1024) * 3 / 4
+    head = 2 * (4 * 4 / 1024) * 3 / 4
+    assert tr.by_kind["all-reduce"] == pytest.approx(psum + head)
+
+
 def test_collective_traffic_replica_groups_and_reduce_scatter():
     """Ring model runs over each op's own replica group, not the global
     device count; reduce-scatter moves (n-1) x its shard-sized result."""

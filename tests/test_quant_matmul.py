@@ -256,6 +256,17 @@ from dllama_tpu.ops.linear import dequantize_weight  # noqa: E402
 from dllama_tpu.ops.quant_matmul import supports_decode  # noqa: E402
 
 
+def _assert_bit_parity(got, want):
+    """Fused kernel vs the XLA fused-dequant reference, to the last few
+    bits. Written as bitwise equality; under jaxlib 0.9 the CPU backend picks
+    its GEMV/GEMM blocking by host (the one-row cases differ in the last
+    bits on some hosts and not on others: max abs 4.3e-6 on values of order
+    5, PR 22), so the interpret-mode claim a CPU can check is "a few ulps".
+    Whether the COMPILED kernel is bit-identical is the chip's to say."""
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=4e-6, atol=8e-6)
+
+
 def _xla_fused_dequant(x, w, fast=False):
     """The XLA fused-dequant reference linear() falls back to — computed
     with the same ops, so the kernel's parity target is the real thing."""
@@ -278,8 +289,7 @@ def test_fused_kernel_bit_parity_q40(m, n, k):
                     jnp.float32)
     assert supports_decode((m, k), w)
     got = quant_matmul(x, w, interpret=True, fused=True)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(_xla_fused_dequant(x, w)))
+    _assert_bit_parity(got, _xla_fused_dequant(x, w))
 
 
 def test_fused_kernel_bit_parity_q80_planes():
@@ -297,8 +307,7 @@ def test_fused_kernel_bit_parity_q80_planes():
     assert int(np.abs(np.asarray(qw.codes)).max()) > 8  # genuinely 8-bit
     x = jnp.asarray(rng.standard_normal((1, 512)), jnp.float32)
     got = quant_matmul(x, qw, interpret=True, fused=True)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(_xla_fused_dequant(x, qw)))
+    _assert_bit_parity(got, _xla_fused_dequant(x, qw))
 
 
 def test_fused_kernel_fast_mode_drift_bounded():
@@ -378,7 +387,7 @@ def test_fused_mode_linear_end_to_end(monkeypatch):
     want = linear(x, w)
     monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "fused")
     got = linear(x, w)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _assert_bit_parity(got, want)
 
 
 def test_fused_sharded_col_split_matches_oracle():

@@ -3,7 +3,7 @@
 Round-5 helper: quantify what a change bought —
 
     python tools/bench_compare.py BENCH_r04_manual.json \\
-        capture_artifacts/<ts>/BENCH_live.json
+        bench_results/capture_<ts>/BENCH_live.json
 
 Accepts bench JSON files (the one-line emit), capture directories
 (reads BENCH_live.json inside), or a ``PERF_BASELINE.json`` artifact

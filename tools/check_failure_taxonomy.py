@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Failure-taxonomy lint: the finish_reason / resume-outcome /
 kvwire-fallback vocabularies are closed-world — declared tuples,
-emitting call sites, telemetry label docs, and PERF.md's "Failure
+emitting call sites, telemetry label docs, and TELEMETRY.md's "Failure
 taxonomy" section agree in both directions.
 
 Thin wrapper (Makefile ``lint`` compatibility): the scanner itself

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Metric-name lint: telemetry.SPECS naming convention + PERF.md docs + source literals, closed-world in both directions.
+"""Metric-name lint: telemetry.SPECS naming convention + TELEMETRY.md docs + source literals, closed-world in both directions.
 
 Thin wrapper (Makefile ``lint`` compatibility): the scanner itself now
 lives on the shared dlint framework as the ``metrics-names`` rule —

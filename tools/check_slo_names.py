@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """SLO objective-name lint: every objective in slo.OBJECTIVES is
-grammar-clean, documented (cli grammar, PERF.md, README.md, bench), and
+grammar-clean, documented (cli grammar, TELEMETRY.md, README.md, bench), and
 closed-world vs objective-shaped tokens anywhere in the tree.
 
 Thin wrapper (Makefile ``lint`` compatibility): the scanner itself

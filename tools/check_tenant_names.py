@@ -3,7 +3,7 @@
 runtime/serving.py and serve/router.py names a reason from
 tenancy.ADMIT_REASONS, every reason has a live emit site + docs, and
 the dllama_tenant_* metric family is closed-world vs telemetry.SPECS
-and PERF.md.
+and TELEMETRY.md.
 
 Thin wrapper (Makefile ``lint`` compatibility): the scanner itself
 lives on the shared dlint framework as the ``tenant-reasons`` rule —

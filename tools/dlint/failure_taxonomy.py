@@ -8,7 +8,7 @@ RESUME_OUTCOMES``), and the KV-migration fallback reason on
 ``dllama_kvwire_fallback_total`` (``runtime/kvwire.py
 FALLBACK_REASONS``). Each is the same three-way contract slo-names
 enforces for objectives: the DECLARED tuple, the CALL SITES that emit
-members, and the OPERATOR DOCS (telemetry label help + PERF.md's
+members, and the OPERATOR DOCS (telemetry label help + TELEMETRY.md's
 "Failure taxonomy" section) must agree in both directions — a literal
 outside its tuple is a typo that silently forks the vocabulary, a
 declared member nothing emits is dead taxonomy, and an undocumented
@@ -33,7 +33,7 @@ VOCABS = (
     ("RESUME_OUTCOMES", "dllama_tpu/serve/router.py"),
     ("FALLBACK_REASONS", "dllama_tpu/runtime/kvwire.py"),
 )
-PERF = "PERF.md"
+PERF = "dllama_tpu/runtime/TELEMETRY.md"
 PERF_SECTION = "Failure taxonomy"
 
 
@@ -170,18 +170,18 @@ def check(project: Project) -> tuple[list[Finding], str]:
             f(rel, f"{name} has duplicate members: {vals}")
         vocabs[name] = vals
 
-    # forward, docs: every member spelled in PERF.md's taxonomy section
+    # forward, docs: every member spelled in TELEMETRY.md's taxonomy section
     perf = project.file(PERF)
     perf_text = perf.text if perf is not None else ""
     if PERF_SECTION not in perf_text:
-        f(PERF, f"PERF.md needs a {PERF_SECTION!r} section documenting "
+        f(PERF, f"TELEMETRY.md needs a {PERF_SECTION!r} section documenting "
                 f"the three failure vocabularies")
     for name, rel in VOCABS:
         for member in vocabs[name]:
             if f'"{member}"' not in perf_text \
                     and f"`{member}`" not in perf_text:
                 f(PERF, f"{name} member {member!r} ({rel}) is not "
-                        f"documented in PERF.md")
+                        f"documented in TELEMETRY.md")
 
     # forward, telemetry: the label-bearing metrics' help strings must
     # name every member (the operator reads the /metrics exposition)
@@ -231,11 +231,11 @@ def check(project: Project) -> tuple[list[Finding], str]:
     n_sites = sum(len(s) for s in sites.values())
     return findings, (f"3 failure vocabularies ({n} members, {n_sites} "
                       f"emit sites): declarations, call sites, "
-                      f"telemetry label docs, and PERF.md all agree")
+                      f"telemetry label docs, and TELEMETRY.md all agree")
 
 
 rule("failure-taxonomy",
      "finish_reason / resume-outcome / kvwire-fallback vocabularies are "
      "closed-world: declared tuples, emitting call sites, telemetry "
-     "label docs, and PERF.md's Failure taxonomy section agree in both "
+     "label docs, and TELEMETRY.md's Failure taxonomy section agree in both "
      "directions")(check)

@@ -1,7 +1,7 @@
 """Metric-name rule (migrated from ``tools/check_metrics_names.py``).
 
 Closed-world in BOTH directions against the single declaration point
-(``dllama_tpu.runtime.telemetry.SPECS``): naming convention, PERF.md
+(``dllama_tpu.runtime.telemetry.SPECS``): naming convention, TELEMETRY.md
 documentation, no orphaned source literals, no stale doc mentions.
 Importing only the telemetry module keeps this runnable without jax.
 """
@@ -55,11 +55,11 @@ def check(project: Project, specs=None) -> tuple[list[Finding], str]:
         if not spec.help:
             f(T, f"{name}: empty help text")
 
-    perf_sf = project.file("PERF.md")
+    perf_sf = project.file("dllama_tpu/runtime/TELEMETRY.md")
     perf = perf_sf.text if perf_sf is not None else ""
     for name in specs:
         if name not in perf:
-            f("PERF.md", f"metric {name} is not documented in PERF.md")
+            f("dllama_tpu/runtime/TELEMETRY.md", f"metric {name} is not documented in TELEMETRY.md")
 
     derived = {base + suffix for base, spec in specs.items()
                if spec.kind == "histogram"
@@ -68,7 +68,7 @@ def check(project: Project, specs=None) -> tuple[list[Finding], str]:
                        | set(TOKEN_RE.findall(perf))):
         if _not_a_metric(name) or name in specs or name in derived:
             continue
-        f("PERF.md", f"PERF.md mentions {name!r} but no such metric "
+        f("dllama_tpu/runtime/TELEMETRY.md", f"TELEMETRY.md mentions {name!r} but no such metric "
                      f"family is registered in telemetry.SPECS "
                      f"(stale doc or typo)")
 
@@ -81,10 +81,10 @@ def check(project: Project, specs=None) -> tuple[list[Finding], str]:
                           f"but is not registered in telemetry.SPECS",
                   lineno)
 
-    return findings, (f"{len(specs)} metric names: convention + PERF.md "
+    return findings, (f"{len(specs)} metric names: convention + TELEMETRY.md "
                       f"docs + source literals all consistent")
 
 
 rule("metrics-names",
      "every telemetry metric name is convention-clean, documented in "
-     "PERF.md, and closed-world vs source literals")(check)
+     "TELEMETRY.md, and closed-world vs source literals")(check)

@@ -3,7 +3,7 @@
 The span ring's phase vocabulary (``runtime/telemetry.PHASES``) is an
 operator contract: every SpanTracer call site emits a CONSTANT phase
 from the vocabulary, every member is emitted somewhere, and both the
-telemetry docstring and PERF.md document it. The router tier's span
+telemetry docstring and TELEMETRY.md document it. The router tier's span
 ring (``serve/router.py RouterSpanRing.emit_span``) carries the same
 contract against ``telemetry.ROUTER_PHASES``.
 """
@@ -95,7 +95,7 @@ def check(project: Project, phases=None) -> tuple[list[Finding], str]:
 
     tsf = project.file(T)
     telemetry_src = tsf.text if tsf is not None else ""
-    psf = project.file("PERF.md")
+    psf = project.file("dllama_tpu/runtime/TELEMETRY.md")
     perf = psf.text if psf is not None else ""
     for phase in (*phases, *router_phases):
         if f"``{phase}``" not in telemetry_src:
@@ -105,14 +105,14 @@ def check(project: Project, phases=None) -> tuple[list[Finding], str]:
                 f"vocabulary docstring"))
         if phase not in perf:
             findings.append(Finding(
-                "span-phases", "PERF.md", 0,
-                f"phase {phase!r} is not documented in PERF.md"))
+                "span-phases", "dllama_tpu/runtime/TELEMETRY.md", 0,
+                f"phase {phase!r} is not documented in TELEMETRY.md"))
 
     n_sites = sum(len(w) for w in sites.values()) \
         + sum(len(w) for w in r_sites.values())
     return findings, (f"{len(phases)} span + {len(router_phases)} router "
                       f"phases: {n_sites} call sites, vocabulary + "
-                      f"telemetry docstring + PERF.md all consistent")
+                      f"telemetry docstring + TELEMETRY.md all consistent")
 
 
 rule("span-phases",

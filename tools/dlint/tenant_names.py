@@ -8,7 +8,7 @@ every decision names a reason from ONE closed vocabulary
 vocabulary closed in BOTH directions — every emit site names a declared
 reason, every declared reason has a live emit site and a doc line — and
 holds the ``dllama_tenant_*`` metric family closed-world between
-``telemetry.SPECS`` and PERF.md (the tenant-scoped twin of the
+``telemetry.SPECS`` and TELEMETRY.md (the tenant-scoped twin of the
 metrics-names rule, so a renamed tenant metric cannot strand its docs).
 A misspelled reason must fail lint, not silently never match a
 postmortem query. Importing only tenancy/telemetry keeps this runnable
@@ -30,7 +30,7 @@ T = "dllama_tpu/runtime/telemetry.py"
 # the files allowed (and required) to emit admission decisions
 EMIT_FILES = ("dllama_tpu/runtime/serving.py",
               "dllama_tpu/serve/router.py")
-DOC_FILES = ("PERF.md",)
+DOC_FILES = ("dllama_tpu/runtime/TELEMETRY.md",)
 
 # an admission-decision flight note: the event name is one of the four
 # decision verbs and a reason= kwarg follows inside the same call (the
@@ -101,7 +101,7 @@ def check(project: Project, vocab=None) -> tuple[list[Finding], str]:
                        f"entry — remove it or wire the decision)")
 
     # the dllama_tenant_* metric family: registered names documented,
-    # documented names registered, and reasons spelled out in PERF.md
+    # documented names registered, and reasons spelled out in TELEMETRY.md
     tenant_metrics = sorted(n for n in specs
                             if n.startswith("dllama_tenant_"))
     if not tenant_metrics:
@@ -135,4 +135,4 @@ rule("tenant-reasons",
      "every tenant admission decision (defer/shed/requeue/preempt) "
      "names a reason from tenancy.ADMIT_REASONS, every reason has a "
      "live emit site and docs, and the dllama_tenant_* family is "
-     "closed-world vs telemetry.SPECS and PERF.md")(check)
+     "closed-world vs telemetry.SPECS and TELEMETRY.md")(check)

@@ -91,9 +91,7 @@ def run_combo(preset: str, budget: float, quant: str | None,
             os.environ[var] = val
         else:
             os.environ.pop(var, None)
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          "/tmp/dllama-xla-cache-bench")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
+    bench._stage_cache_env()
     return bench.run_stage(preset, budget)
 
 
