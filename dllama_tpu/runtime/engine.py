@@ -144,6 +144,13 @@ class InferenceEngine:
                  numerics_failfast: bool | None = None):
         from ..ops.linear import turbo_mode
 
+        # start-up stamps (monotonic seconds per phase of the build; the
+        # serving generator adds its own): logged once beside the HBM
+        # report (introspection.startup_line), summed by the benchmark's
+        # engine_build_s
+        self.startup_s: dict[str, float] = {}
+        t_phase = time.monotonic()
+
         if turbo_mode() is not None and weight_mode != "auto":
             # fail BEFORE the multi-GB load: turbo requires quantized planes
             # resident on device. offload would pull host-DRAM stacks into
@@ -274,6 +281,7 @@ class InferenceEngine:
                 "it needs --kv-block-size (block-granular KV) to have "
                 "blocks to spill")
 
+        t_phase = self._stamp_startup("header", t_phase)
         n_dev = len(jax.devices())
         for name, n in (("dp", dp), ("sp", sp), ("pp", pp)):
             if n < 1:
@@ -405,6 +413,7 @@ class InferenceEngine:
             self._ctrl = ControlCodec(self.packet_slots)
             validate_cluster_config(self)  # fail fast before the weight load
 
+        t_phase = self._stamp_startup("mesh_plan", t_phase)
         # pre-staging HBM budget check (runtime.hbm): the reference prints
         # its required-memory estimate before loading (nn-core.cpp:162-176);
         # here a misfit additionally risks wedging the TPU backend for hours,
@@ -535,6 +544,7 @@ class InferenceEngine:
         # golden canary drift sentinel (numerics.CanarySentinel), wired by
         # the serving layer (run_api_server --canary-interval) or tests
         self.canary = None
+        t_phase = self._stamp_startup("hbm_budget", t_phase)
 
         try:
             if verify_weights:
@@ -550,7 +560,7 @@ class InferenceEngine:
                         f"--verify-weights: {len(res['corrupt'])} of "
                         f"{res['tensors']} tensors corrupt in {model_path}: "
                         + ", ".join(res["corrupt"]))
-            self._load_and_build(profile_split)
+            self._load_and_build(profile_split, t_phase)
         except BaseException:
             # atomic failure: a load/build that dies partway (corrupt
             # tensor, exhausted read retries, device staging error) must
@@ -560,10 +570,12 @@ class InferenceEngine:
             self._teardown_partial()
             raise
 
-    def _load_and_build(self, profile_split: bool) -> None:
+    def _load_and_build(self, profile_split: bool, t_phase: float) -> None:
         """Weight load + device staging + jitted-program construction —
         the failable tail of ``__init__``, split out so its caller can
-        guarantee atomic teardown on ANY exception."""
+        guarantee atomic teardown on ANY exception. ``t_phase``: where
+        the previous start-up stamp ended (an optional --verify-weights
+        sweep counts as weight load)."""
         from ..ops.linear import turbo_mode
 
         weight_mode, multihost = self.weight_mode, self.multihost
@@ -594,6 +606,7 @@ class InferenceEngine:
         # silently running one mode's math over the other mode's stored
         # weights (ADVICE r4: report-vs-dispatch drift).
         self._load_quant_resolution = self._quant_resolution()
+        t_phase = self._stamp_startup("weight_load", t_phase)
         self.kv: KVCache = self._fresh_kv()
         self.pos = 0
         # Eval/Sync split (reference dllama.cpp:59-67): measured lazily on
@@ -706,6 +719,14 @@ class InferenceEngine:
             self._step_tapped = plan_scoped_jit(forward_with_taps, scope=_sc,
                                                 static_argnums=1,
                                                 donate_argnums=(4,))
+        self._stamp_startup("kv_and_programs", t_phase)
+
+    def _stamp_startup(self, phase: str, t0: float) -> float:
+        """Add the seconds since ``t0`` to ``startup_s[phase]``; returns
+        now, the next phase's start."""
+        now = time.monotonic()
+        self.startup_s[phase] = self.startup_s.get(phase, 0.0) + (now - t0)
+        return now
 
     def _teardown_partial(self) -> None:
         """Explicit teardown after a failed load/build: no half-placed

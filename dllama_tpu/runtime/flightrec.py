@@ -31,10 +31,20 @@ batch) needs. This module is that record:
   tracks, one flow per request). ``GET /debug/timeline`` serves it live;
   ``python -m dllama_tpu timeline --dump f.json`` converts offline.
 
+* **Tick phases** — :meth:`FlightRecorder.tick_phase` divides the open
+  tick into the closed ``telemetry.TICK_PHASES`` vocabulary: each phase
+  is a ``jax.profiler.TraceAnnotation`` (``dllama.tick.<name>`` under
+  the root ``dllama.tick`` span, which carries the tick number — so a
+  profiler capture shows the loop thread on the device lanes' clock and
+  names the same tick as this ring), an entry of the tick record's
+  ``phases``, and a series of ``dllama_tick_phase_ms_total{phase}``.
+
 Dependency-free (stdlib + runtime.telemetry only — importable without
-jax). Like the span ring, the recorder is process-global: two schedulers
-in one process interleave their ticks (request ids are per-scheduler
-counters), so this is a debug view, not an audit log.
+jax: the serving layer injects the annotation factory through
+:func:`set_annotation_factory`). Like the span ring, the recorder is
+process-global: two schedulers in one process interleave their ticks
+(request ids are per-scheduler counters), so this is a debug view, not
+an audit log.
 """
 
 from __future__ import annotations
@@ -58,6 +68,68 @@ DUMP_MIN_INTERVAL_S = 30.0
 # synthetic "engine" thread in the trace
 _NO_SLOT_TID = 999
 
+# ``jax.profiler.TraceAnnotation`` once runtime/serving.py is imported;
+# None keeps this module jax-free (phases then only feed the tick record
+# and the counter)
+_annotate = None
+
+
+def set_annotation_factory(factory) -> None:
+    """Install the profiler-annotation factory tick phases open
+    (``factory(name, **metadata)`` → a context manager with
+    ``set_metadata``); ``None`` turns annotations off."""
+    global _annotate
+    _annotate = factory
+
+
+def _phase_sums(phase_spans) -> dict:
+    """``{phase: ms}`` over a tick's ``[name, offset_ms, ms]`` spans, in
+    first-seen order."""
+    sums: dict[str, float] = {}
+    for name, _off, ms in phase_spans:
+        sums[name] = sums.get(name, 0.0) + ms
+    return sums
+
+
+class _TickPhase:
+    """One ``with recorder.tick_phase(name)`` span (see
+    :meth:`FlightRecorder.tick_phase`)."""
+
+    __slots__ = ("_rec", "_name", "_ann", "t0_ns", "t1_ns")
+
+    def __init__(self, rec: "FlightRecorder", name: str):
+        self._rec = rec
+        self._name = name
+        self._ann = None
+
+    def __enter__(self):
+        if _annotate is not None:
+            self._ann = _annotate(f"{telemetry.TICK_SPAN}.{self._name}")
+            self._ann.__enter__()
+        self.t0_ns = self._rec._clock()
+        return self
+
+    def next_phase(self, name: str) -> None:
+        """End this phase and start ``name`` at the same instant, inside
+        one ``with`` (a guard held across both phases then costs neither
+        a gap). ``t0_ns`` moves to the new phase's start."""
+        self.__exit__(None, None, None)
+        self._name, self._ann = name, None
+        self.__enter__()
+
+    def set(self, **metadata) -> None:
+        """Attach ``key=value`` metadata to the phase's profiler
+        annotation (shown as the event's stats in the trace)."""
+        if self._ann is not None:
+            self._ann.set_metadata(**metadata)
+
+    def __exit__(self, *exc):
+        self.t1_ns = self._rec._clock()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._rec._note_phase(self._name, self.t0_ns, self.t1_ns)
+        return False
+
 
 class FlightRecorder:
     """Bounded tick + event rings for one process's serving loop(s).
@@ -75,12 +147,14 @@ class FlightRecorder:
         self._ticks: deque = deque(maxlen=RING_TICKS)
         self._events: deque = deque(maxlen=RING_EVENTS)
         self._cur: dict | None = None
+        self._root = None  # the open tick's dllama.tick annotation
         self._tick_seq = 0
         self._dump_seq = 0
         self._last_dump: dict[str, float] = {}
         self._dumps: deque = deque(maxlen=16)
         reg = telemetry.registry()
         self._m_ticks = reg.counter(telemetry.FLIGHT_TICKS)
+        self._m_phase_ms = reg.counter(telemetry.TICK_PHASE_MS)
         self._m_dumps = reg.counter(telemetry.FLIGHT_DUMPS)
 
     def reset(self) -> None:
@@ -96,16 +170,47 @@ class FlightRecorder:
 
     # -- tick lifecycle (scheduler loop thread) -----------------------------
 
-    def begin_tick(self, queue_depth: int = 0, n_admissions: int = 0) -> None:
+    def begin_tick(self, queue_depth: int = 0, n_admissions: int = 0,
+                   n_active: int = 0) -> None:
+        """Open a tick record and, under a profiler, the root
+        ``dllama.tick`` annotation (``tick`` = this record's number,
+        ``n_active`` = live slots going in) the tick's phases nest in."""
         with self._lock:
             self._tick_seq += 1
-            self._cur = {"tick": self._tick_seq,
+            seq = self._tick_seq
+            self._cur = {"tick": seq,
                          "t_start_ns": self._clock(),
                          "queue_depth": queue_depth,
                          "n_admissions": n_admissions,
                          "decisions": [], "dispatch_ms": 0.0,
                          "prefill_ms": 0.0, "prefill_tokens": 0,
-                         "decode_tokens": 0, "n_active": 0}
+                         "decode_tokens": 0, "n_active": 0,
+                         "phase_spans": []}
+        if _annotate is not None:
+            self._root = _annotate(telemetry.TICK_SPAN, tick=seq,
+                                   n_active=n_active)
+            self._root.__enter__()
+
+    def tick_phase(self, name: str) -> _TickPhase:
+        """``with recorder.tick_phase("emit"):`` — one phase of the
+        scheduler's tick (``name`` from ``telemetry.TICK_PHASES``, a
+        literal at every call site: dlint span-phases holds the
+        vocabulary closed). Three records, one clock read each side:
+        the profiler annotation ``dllama.tick.<name>``, the open tick
+        record's ``phase_spans`` entry (``[name, offset_ms, ms]``;
+        ``phases[name]``, the sum over repeats, is filled in when the
+        tick closes), and ``dllama_tick_phase_ms_total``. With no
+        profiler running the annotation is a no-op (~0.4 µs)."""
+        return _TickPhase(self, name)
+
+    def _note_phase(self, name: str, t0_ns: int, t1_ns: int) -> None:
+        ms = (t1_ns - t0_ns) / 1e6
+        with self._lock:
+            cur = self._cur
+            if cur is not None:
+                cur["phase_spans"].append(
+                    [name, (t0_ns - cur["t_start_ns"]) / 1e6, ms])
+        self._m_phase_ms.inc(ms, phase=name)
 
     def note(self, event: str, rid: int = -1, reason: str = "",
              **extra) -> None:
@@ -161,19 +266,23 @@ class FlightRecorder:
                 self._cur.get("spec_accept_tokens", 0) + accepted)
 
     def end_tick(self, blocks: dict | None = None, **extra) -> None:
-        """Close the tick. Idle ticks (no decisions, no dispatch, no
-        prefill) are dropped — the ring stays signal-dense and tick
-        numbering gaps mark idle stretches."""
+        """Close the tick (and its root annotation). Idle ticks (no
+        decisions, no dispatch, no prefill) are dropped — the ring stays
+        signal-dense and tick numbering gaps mark idle stretches."""
+        root, self._root = self._root, None
+        if root is not None:
+            root.__exit__(None, None, None)
         with self._lock:
             cur, self._cur = self._cur, None
             if cur is None:
                 return
             cur["t_end_ns"] = self._clock()
+            cur["phases"] = _phase_sums(cur["phase_spans"])
             if blocks is not None:
                 cur["blocks"] = dict(blocks)
             cur.update(extra)
             if not (cur["decisions"] or cur["dispatch_ms"]
-                    or cur["prefill_ms"]):
+                    or cur["prefill_ms"] or cur["prefill_tokens"]):
                 return
             self._ticks.append(cur)
         self._m_ticks.inc()
@@ -192,6 +301,8 @@ class FlightRecorder:
             if self._cur is not None:
                 cur = dict(self._cur)
                 cur["decisions"] = list(cur["decisions"])
+                cur["phase_spans"] = list(cur["phase_spans"])
+                cur["phases"] = _phase_sums(cur["phase_spans"])
                 cur["open"] = True
                 ticks.append(cur)
             return {"tick_seq": self._tick_seq,
@@ -353,17 +464,31 @@ def to_chrome_trace(data: dict) -> dict:
 
     for t in ticks:
         ts = t["t_start_ns"] / 1e3
-        dur = max(0.0, (t.get("t_end_ns", t["t_start_ns"])
-                        - t["t_start_ns"]) / 1e3)
+        phase_spans = t.get("phase_spans") or ()
+        if "t_end_ns" in t:
+            dur = max(0.0, (t["t_end_ns"] - t["t_start_ns"]) / 1e3)
+        else:
+            # an open tick (mid-tick postmortem) reaches as far as its
+            # last finished phase
+            dur = max((off + ms for _n, off, ms in phase_spans),
+                      default=0.0) * 1e3
         args = {k: t[k] for k in ("queue_depth", "n_admissions", "decisions",
                                   "dispatch_ms", "prefill_ms",
                                   "prefill_tokens", "decode_tokens",
                                   "spec_draft_tokens", "spec_accept_tokens",
                                   "n_active", "slots", "blocks",
-                                  "prefill_budget") if k in t}
+                                  "prefill_budget", "phases") if k in t}
         out.append({"ph": "X", "pid": 1, "tid": 0, "ts": ts, "dur": dur,
                     "name": f"tick {t['tick']}", "cat": "tick",
                     "args": args})
+        # the tick divided: one nested slice per phase span, same track
+        # (Perfetto stacks a slice under the one that contains it)
+        for name, off_ms, ms in phase_spans:
+            out.append({"ph": "X", "pid": 1, "tid": 0,
+                        "ts": ts + off_ms * 1e3,
+                        "dur": max(0.0, min(ms * 1e3, dur - off_ms * 1e3)),
+                        "name": name, "cat": "tick_phase",
+                        "args": {"tick": t["tick"]}})
         out.append({"ph": "C", "pid": 1, "tid": 0, "ts": ts,
                     "name": "queue_depth",
                     "args": {"requests": t.get("queue_depth", 0)}})

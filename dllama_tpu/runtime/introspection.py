@@ -473,6 +473,15 @@ def hbm_budget_line(engine) -> str:
             + (f" of {_gb(limit)}" if limit else " (device limit unknown)"))
 
 
+def startup_line(engine) -> str:
+    """The engine's start-up stamps (``engine.startup_s``: seconds per
+    build phase, the serving generator's included once it is built), one
+    line beside the HBM budget."""
+    parts = getattr(engine, "startup_s", None) or {}
+    return (f"🧮 start-up: {sum(parts.values()):.2f} s ("
+            + ", ".join(f"{k} {v:.2f}" for k, v in parts.items()) + ")")
+
+
 def compile_report(scope: str, emit=print) -> None:
     """One line per program the ledger saw compile in ``scope``: wall and
     XLA-backend seconds (backend 0 = the persistent cache served it) and,
@@ -515,6 +524,7 @@ def hbm_startup_report(engine, emit=print) -> dict:
         "programs": {},
     }
     emit(hbm_budget_line(engine))
+    emit(startup_line(engine))
     max_temp = 0
     scope = getattr(engine, "introspection_scope", "default")
     for name in ("decode", "prefill"):

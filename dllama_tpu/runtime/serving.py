@@ -27,11 +27,13 @@ fixed-seed reproducibility.
 from __future__ import annotations
 
 import os
+import statistics
 import threading
 import time
+from collections import deque
 from contextlib import nullcontext
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Callable
+from typing import TYPE_CHECKING, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +60,14 @@ if TYPE_CHECKING:
     from .engine import InferenceEngine
 
 _MASK64 = (1 << 64) - 1
+
+# tick phases (flightrec.FlightRecorder.tick_phase) open profiler
+# annotations from here on: flightrec itself stays importable without jax
+flightrec.set_annotation_factory(jax.profiler.TraceAnnotation)
+
+# chunk-free step_wait samples kept for the prefill-cost estimate's
+# baseline (_GeneratorCore._settle_prefill)
+STEP_WAIT_SAMPLES = 64
 
 
 class SchedulerError(RuntimeError):
@@ -203,7 +213,7 @@ class Request:
     # last emitted-run stamp (monotonic ns): the per-tenant ITL
     # histogram records each emit-run's mean inter-token gap from it
     t_last_emit: int = 0
-    ms_prefill: float = 0.0       # own prefill chunk dispatch wall
+    ms_prefill: float = 0.0       # own prefill chunks' settled cost (_settle_prefill)
     ms_decode_steps: float = 0.0  # decode dispatch wall while slot active
     ms_preempt: float = 0.0       # others' interleaved prefill wall while
     #                               this slot was decode-armed (tick-budget
@@ -287,6 +297,17 @@ class _Admission:
     need_take: bool = False
 
 
+class _PendingChunk(NamedTuple):
+    """A prefill chunk enqueued and not yet waited for
+    (:meth:`_GeneratorCore._settle_prefill`)."""
+
+    req: Request
+    slot: int
+    width: int      # dispatched (padded) tokens
+    n_valid: int
+    t_enqueue_ns: int
+
+
 @dataclass
 class _KVMigration:
     """One in-flight peer-KV pull (runtime/kvwire): the request parks
@@ -346,6 +367,11 @@ class _GeneratorCore:
         self.flight = flightrec.recorder()
         self._m_ttft_attrib = self._tm.histogram(telemetry.TTFT_ATTRIB_MS)
         self._m_itl_attrib = self._tm.histogram(telemetry.ITL_ATTRIB_MS)
+        self._m_prefill_ms = self._tm.histogram(telemetry.PREFILL_CHUNK_MS)
+        # prefill chunks enqueued and not yet waited for, and the recent
+        # step_wait walls of chunk-free steps (_settle_prefill)
+        self._chunks_pending: list[_PendingChunk] = []
+        self._step_waits: deque = deque(maxlen=STEP_WAIT_SAMPLES)
         # tenant observatory (runtime/tenancy): every accounting site
         # below notes the SAME value it publishes globally, so per-tenant
         # sums reconcile with the global counters bit-exactly
@@ -562,23 +588,56 @@ class _GeneratorCore:
             return None
 
     def _prefill_chunk(self, adm: "_Admission", padded, n_valid: int) -> None:
-        """One timed prefill chunk dispatch for ``adm``, with attribution:
-        the admission's own ``prefill`` wall, every decode-armed slot's
-        preempt stall (this chunk ran INSTEAD of their next decode step —
-        the tick-budget interleave cost), the tick's prefill-token spend,
-        and a ``prefill_chunk`` span."""
+        """One prefill chunk dispatch for ``adm``. The dispatch only
+        ENQUEUES the program (nothing here waits for the device, and no
+        sync is added for telemetry's sake), so its cost is attributed
+        later, by :meth:`_settle_prefill`, when the next step's fetch has
+        waited for it."""
         t0 = telemetry.now_ns()
         adm.col = self._exec_prefill(adm.col, padded, adm.pos)
-        t1 = telemetry.now_ns()
-        ms = (t1 - t0) / 1e6
-        adm.req.ms_prefill += ms
-        for s in self.slots:
-            if s is not None:
-                s.ms_preempt += ms
+        self._chunks_pending.append(
+            _PendingChunk(adm.req, adm.slot, len(padded), n_valid, t0))
         self._tenancy.note_prefill_tokens(adm.req.tenant, n_valid)
-        self.flight.note_prefill(adm.req.rid, ms, n_valid)
-        telemetry.tracer().emit(adm.req.rid, "prefill_chunk", t0, t1,
-                                slot=adm.slot, n_tokens=n_valid)
+        # the tick that dispatched the chunk spent the tokens; the wall
+        # lands in the tick whose step waited for it (usually this one)
+        self.flight.note_prefill(adm.req.rid, 0.0, n_valid)
+
+    def _settle_prefill(self, t_wait0_ns: int, t_wait1_ns: int) -> None:  # dlint: owner=loop-thread
+        """Attribute the prefill chunks enqueued since the last step,
+        now that a step's ``step_wait`` (``t_wait0_ns``..``t_wait1_ns``)
+        has waited for them. The device runs programs in dispatch order,
+        so the wall from the first pending chunk's enqueue to the end of
+        this wait holds the chunks AND the step; less the running median
+        wait of chunk-free steps it is the chunks' device-inclusive
+        cost, split over them by dispatched width. Each share goes to
+        the admission's own ``ms_prefill``, to every decode-armed
+        request's ``ms_preempt`` (the chunk ran in front of their next
+        token), to the tick record, to ``dllama_prefill_chunk_ms`` and a
+        ``prefill_chunk`` span. With no chunk pending the wait is a
+        baseline sample. Until a chunk-free step has been seen (a cold
+        server's first request) the baseline is 0 and the first step's
+        own time is charged with the chunks, once."""
+        pending = self._chunks_pending
+        if not pending:
+            self._step_waits.append((t_wait1_ns - t_wait0_ns) / 1e6)
+            return
+        self._chunks_pending = []
+        base = statistics.median(self._step_waits) if self._step_waits else 0.0
+        t0 = pending[0].t_enqueue_ns
+        total = max(0.0, (t_wait1_ns - t0) / 1e6 - base)
+        width = sum(c.width for c in pending)
+        for c in pending:
+            ms = total * c.width / width
+            c.req.ms_prefill += ms
+            for s in self.slots:
+                if s is not None and s is not c.req:
+                    s.ms_preempt += ms
+            self._m_prefill_ms.record(ms)
+            self.flight.note_prefill(c.req.rid, ms, 0)
+            t1 = t0 + int(ms * 1e6)
+            telemetry.tracer().emit(c.req.rid, "prefill_chunk", t0, t1,
+                                    slot=c.slot, n_tokens=c.n_valid)
+            t0 = t1
 
     def _record_ttft_attrib(self, req: Request) -> None:
         """Publish the TTFT decomposition (:meth:`Request.ttft_breakdown`)
@@ -914,7 +973,8 @@ class BatchedGenerator(_GeneratorCore):
         self.kv = self._put(self.kv, col, slot)
 
     def _exec_step(self, tokens, pos, temps, topps, coins):
-        with self.eng.watchdog.guard("batch_step"):
+        with self.flight.tick_phase("step_dispatch") as self._wait, \
+                self.eng.watchdog.guard("batch_step"):
             failpoints.fire("step_hang")
             with self._plan_ctx():
                 (nxt, nf), self.kv = self._step(
@@ -925,10 +985,12 @@ class BatchedGenerator(_GeneratorCore):
                     jnp.asarray(np.asarray(topps, np.float32)),
                     jnp.asarray(np.asarray(coins, np.float32)),
                     self._poison())
+            self._wait.next_phase("step_wait")
             return np.asarray(nxt), np.asarray(nf)
 
     def _exec_step_chunk(self, tokens, pos, temps, topps, coins, k: int):
-        with self.eng.watchdog.guard("batch_chunk"):
+        with self.flight.tick_phase("step_dispatch") as self._wait, \
+                self.eng.watchdog.guard("batch_chunk"):
             failpoints.fire("step_hang")
             with self._plan_ctx():
                 (toks, nf), self.kv = self._steps(
@@ -939,10 +1001,12 @@ class BatchedGenerator(_GeneratorCore):
                     jnp.asarray(np.asarray(topps, np.float32)),
                     jnp.asarray(np.asarray(coins, np.float32)), k,
                     self._poison())
+            self._wait.next_phase("step_wait")
             return np.asarray(toks), np.asarray(nf)  # [B, k], [B]
 
     def _exec_verify(self, toks_2d, pos, temps, topps, coins):
-        with self.eng.watchdog.guard("batch_verify"):
+        with self.flight.tick_phase("step_dispatch") as self._wait, \
+                self.eng.watchdog.guard("batch_verify"):
             failpoints.fire("step_hang")
             with self._plan_ctx():
                 (n_acc, preds, nf), self.kv = self._verify(
@@ -953,6 +1017,7 @@ class BatchedGenerator(_GeneratorCore):
                     jnp.asarray(np.asarray(topps, np.float32)),
                     jnp.asarray(np.asarray(coins, np.float32)),
                     self._poison())
+            self._wait.next_phase("step_wait")
             return np.asarray(n_acc), np.asarray(preds), np.asarray(nf)
 
     # -- slot lifecycle -----------------------------------------------------
@@ -1005,6 +1070,15 @@ class BatchedGenerator(_GeneratorCore):
 
     def continue_admit(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
         """Run one prefill chunk; True when the slot is armed for decode."""
+        with self.flight.tick_phase("prefill_dispatch"):
+            if not self._advance_prefill(adm):
+                return False
+        with self.flight.tick_phase("admit_commit"):
+            self._commit_admit(adm)
+        return True
+
+    def _advance_prefill(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
+        """Dispatch the next chunk; True once the prompt is prefilled."""
         rest = adm.req.prompt_ids[:-1]
         if adm.pos < len(rest):
             # same bucketed chunk sizing as engine.prefill (TPU-sized
@@ -1028,13 +1102,17 @@ class BatchedGenerator(_GeneratorCore):
             adm.pos += len(chunk)
             if adm.pos < len(rest):
                 return False
+        return True
+
+    def _commit_admit(self, adm: "_Admission") -> None:  # dlint: owner=loop-thread
+        """Commit the prefilled column and arm the slot for decode."""
         if adm.req.score:
             # eval sequences are done at end of prefill: no commit (the
             # scored column is discarded — the slot's pool rows and any
             # recorded prefix context stay exactly as the previous
             # occupant left them), no proposer, no decode arming
             self._finish_score(adm)
-            return True
+            return
         self._bcast(CTRL_SRV_COMMIT, adm.slot)
         self._exec_commit(adm.slot, adm.col)
         self._ctx[adm.slot] = list(adm.req.prompt_ids[:-1])
@@ -1044,7 +1122,6 @@ class BatchedGenerator(_GeneratorCore):
             self._proposers[adm.slot] = NgramProposer(self.spec)
             self._proposers[adm.slot].extend(adm.req.prompt_ids)
         self._arm_decode(adm)
-        return True
 
     def admit(self, req: Request, slot: int) -> None:  # dlint: owner=loop-thread
         """Admit in one go (tests / non-interleaved callers)."""
@@ -1063,6 +1140,7 @@ class BatchedGenerator(_GeneratorCore):
         self.slots = [None] * self.n_slots
         self._ctx = [None] * self.n_slots
         self._proposers = [None] * self.n_slots
+        self._chunks_pending = []
         self.pos[:] = 0
         self.next_token[:] = 0
         self._m_occupancy.set(0)
@@ -1075,45 +1153,52 @@ class BatchedGenerator(_GeneratorCore):
         of tokens emitted. Inactive slots ride along as temp-0 rows writing
         into their own (unused) cache positions — static shapes, one
         compiled program regardless of occupancy."""
-        active = self._sweep_cancelled()
-        if self.spec:
-            # the K+1-wide cache write would CLAMP (and corrupt earlier
-            # rows) past seq_len - spec - 1: retire slots that close to the
-            # cap before dispatching (non-spec mode retires at seq_len; spec
-            # trades the last few positions of capacity for run dispatches)
-            for i in list(active):
-                if self.pos[i] + self.spec + 1 > self.cfg.seq_len:
-                    self._retire(i, "ctx_full")
-                    active.remove(i)
-        if not active:
-            return 0
-        if __debug__:
-            # cross-slot prefix-reuse safety: every slot with a recorded
-            # prefill context must have its write cursor at/above that
-            # region, or a ride-along write could corrupt reusable rows
-            for i, ctx in enumerate(self._ctx):
-                assert ctx is None or self.pos[i] >= len(ctx), (
-                    i, int(self.pos[i]), len(ctx))
-        temps, topps, coins = self._sampling_rows(active)
-
+        with self.flight.tick_phase("step_prepare"):
+            active = self._sweep_cancelled()
+            if self.spec:
+                # the K+1-wide cache write would CLAMP (and corrupt
+                # earlier rows) past seq_len - spec - 1: retire slots that
+                # close to the cap before dispatching (non-spec mode
+                # retires at seq_len; spec trades the last few positions
+                # of capacity for run dispatches)
+                for i in list(active):
+                    if self.pos[i] + self.spec + 1 > self.cfg.seq_len:
+                        self._retire(i, "ctx_full")
+                        active.remove(i)
+            if not active:
+                return 0
+            if __debug__:
+                # cross-slot prefix-reuse safety: every slot with a
+                # recorded prefill context must have its write cursor
+                # at/above that region, or a ride-along write could
+                # corrupt reusable rows
+                for i, ctx in enumerate(self._ctx):
+                    assert ctx is None or self.pos[i] >= len(ctx), (
+                        i, int(self.pos[i]), len(ctx))
+            temps, topps, coins = self._sampling_rows(active)
+            if self._root_bcast and not self.spec:
+                # payload built only when it will be sent
+                self._bcast(CTRL_SRV_STEP, 0, np.concatenate([
+                    self.next_token.astype(np.int32),
+                    self.pos.astype(np.int32),
+                    self._f32bits(temps, topps, coins)]))
         if self.spec:
             return self._spec_step(active, temps, topps, coins)
-        if self._root_bcast:  # payload built only when it will be sent
-            self._bcast(CTRL_SRV_STEP, 0, np.concatenate([
-                self.next_token.astype(np.int32), self.pos.astype(np.int32),
-                self._f32bits(temps, topps, coins)]))
         t0 = time.perf_counter()
         nxt, nf = self._exec_step(self.next_token, self.pos, temps, topps,
                                   coins)
         ms = (time.perf_counter() - t0) * 1000.0
-        self._attrib_decode(active, ms)
-        poisoned = self._handle_nonfinite(active, nf)
-        emitted = 0
-        for i in active:
-            if i in poisoned:
-                continue
-            emitted += self._emit_run(i, [int(nxt[i])])
-        self._record_step(len(active), ms, emitted)
+        with self.flight.tick_phase("emit"):
+            self._settle_prefill(self._wait.t0_ns, self._wait.t1_ns)
+            self._attrib_decode(active, ms)
+            poisoned = self._handle_nonfinite(active, nf)
+            emitted = 0
+            for i in active:
+                if i in poisoned:
+                    continue
+                emitted += self._emit_run(i, [int(nxt[i])])
+        with self.flight.tick_phase("bookkeeping"):
+            self._record_step(len(active), ms, emitted)
         return emitted
 
     def step_chunk(self, k: int) -> int:  # dlint: owner=loop-thread
@@ -1129,50 +1214,56 @@ class BatchedGenerator(_GeneratorCore):
         to its solo run."""
         if k <= 1 or self.spec:
             return self.step()
-        active = self._sweep_cancelled()
+        with self.flight.tick_phase("step_prepare"):
+            active = self._sweep_cancelled()
+            fits = active and not (
+                any(self.pos[i] + k > self.cfg.seq_len for i in active)
+                or any(self.slots[i].max_tokens - len(self.slots[i].tokens)
+                       < k for i in active))
+            if fits:
+                temps = np.zeros(self.n_slots, dtype=np.float32)
+                topps = np.zeros(self.n_slots, dtype=np.float32)
+                coins = np.zeros((k, self.n_slots), dtype=np.float32)
+                for i in active:
+                    req = self.slots[i]
+                    temps[i] = req.temperature
+                    topps[i] = req.topp
+                    if req.temperature > 0.0:
+                        st = req.rng_state  # COPY: committed after truncation
+                        for j in range(k):
+                            coins[j, i], st = xorshift_random_f32(st)
+                if self._root_bcast:
+                    self._bcast(CTRL_SRV_STEP_CHUNK, k, np.concatenate([
+                        self.next_token.astype(np.int32),
+                        self.pos.astype(np.int32),
+                        self._f32bits(temps, topps, coins.reshape(-1))]))
         if not active:
             return 0
-        if any(self.pos[i] + k > self.cfg.seq_len for i in active) or \
-                any(self.slots[i].max_tokens - len(self.slots[i].tokens) < k
-                    for i in active):
+        if not fits:
             return self.step()
-
-        temps = np.zeros(self.n_slots, dtype=np.float32)
-        topps = np.zeros(self.n_slots, dtype=np.float32)
-        coins = np.zeros((k, self.n_slots), dtype=np.float32)
-        for i in active:
-            req = self.slots[i]
-            temps[i] = req.temperature
-            topps[i] = req.topp
-            if req.temperature > 0.0:
-                st = req.rng_state  # COPY: committed after truncation
-                for j in range(k):
-                    coins[j, i], st = xorshift_random_f32(st)
-
-        if self._root_bcast:
-            self._bcast(CTRL_SRV_STEP_CHUNK, k, np.concatenate([
-                self.next_token.astype(np.int32), self.pos.astype(np.int32),
-                self._f32bits(temps, topps, coins.reshape(-1))]))
         t0 = time.perf_counter()
         toks, nf = self._exec_step_chunk(self.next_token, self.pos, temps,
                                          topps, coins, k)
         step_ms = (time.perf_counter() - t0) * 1000.0
-        self._attrib_decode(active, step_ms)
-        poisoned = self._handle_nonfinite(active, nf)
-        emitted = 0
-        for i in active:
-            if i in poisoned:
-                continue
-            req = self.slots[i]
-            sampled = req.temperature > 0.0
-            n = self._emit_run(i, [int(t) for t in toks[i]])
-            emitted += n
-            if sampled:
-                st = req.rng_state
-                for _ in range(n):  # commit exactly the kept draws
-                    _, st = xorshift_random_f32(st)
-                req.rng_state = st
-        self._record_step(len(active), step_ms, emitted)
+        with self.flight.tick_phase("emit"):
+            self._settle_prefill(self._wait.t0_ns, self._wait.t1_ns)
+            self._attrib_decode(active, step_ms)
+            poisoned = self._handle_nonfinite(active, nf)
+            emitted = 0
+            for i in active:
+                if i in poisoned:
+                    continue
+                req = self.slots[i]
+                sampled = req.temperature > 0.0
+                n = self._emit_run(i, [int(t) for t in toks[i]])
+                emitted += n
+                if sampled:
+                    st = req.rng_state
+                    for _ in range(n):  # commit exactly the kept draws
+                        _, st = xorshift_random_f32(st)
+                    req.rng_state = st
+        with self.flight.tick_phase("bookkeeping"):
+            self._record_step(len(active), step_ms, emitted)
         return emitted
 
     def _kv_fraction(self) -> float:
@@ -1186,59 +1277,64 @@ class BatchedGenerator(_GeneratorCore):
     def _spec_step(self, active: list[int], temps, topps, coins) -> int:  # dlint: owner=loop-thread
         """One ragged speculative verify dispatch (models.ragged_verify_step):
         greedy rows emit their accepted run, sampled rows exactly one token."""
-        toks = np.zeros((self.n_slots, self.spec + 1), dtype=np.int32)
-        degraded: set[int] = set()
-        for i in active:
-            toks[i, 0] = self.next_token[i]
-            if self.slots[i].temperature <= 0.0:
-                d = self._safe_draft(i)
-                if d is None:
-                    # degraded: the program's K+1 width is static, so the
-                    # row still carries filler (the committed token —
-                    # acceptance-neutral for greedy verify), but the slot
-                    # emits only its verified token and counts NO drafts
-                    # — plain decode for this step, same as the paged
-                    # path's lens=0
-                    degraded.add(i)
-                    toks[i, 1:] = int(toks[i, 0])
-                else:
-                    toks[i, 1:] = d
-        if self._root_bcast:
-            self._bcast(CTRL_SRV_VERIFY, self.spec, np.concatenate([
-                toks.reshape(-1), self.pos.astype(np.int32),
-                self._f32bits(temps, topps, coins)]))
+        with self.flight.tick_phase("step_prepare"):
+            toks = np.zeros((self.n_slots, self.spec + 1), dtype=np.int32)
+            degraded: set[int] = set()
+            for i in active:
+                toks[i, 0] = self.next_token[i]
+                if self.slots[i].temperature <= 0.0:
+                    d = self._safe_draft(i)
+                    if d is None:
+                        # degraded: the program's K+1 width is static, so
+                        # the row still carries filler (the committed
+                        # token — acceptance-neutral for greedy verify),
+                        # but the slot emits only its verified token and
+                        # counts NO drafts — plain decode for this step,
+                        # same as the paged path's lens=0
+                        degraded.add(i)
+                        toks[i, 1:] = int(toks[i, 0])
+                    else:
+                        toks[i, 1:] = d
+            if self._root_bcast:
+                self._bcast(CTRL_SRV_VERIFY, self.spec, np.concatenate([
+                    toks.reshape(-1), self.pos.astype(np.int32),
+                    self._f32bits(temps, topps, coins)]))
         t0 = time.perf_counter()
         n_acc, preds, nf = self._exec_verify(toks, self.pos, temps, topps,
                                              coins)
         ms = (time.perf_counter() - t0) * 1000.0
-        self._attrib_verify(active, ms)
-        drafted = sum(self.spec for i in active
-                      if self.slots[i].temperature <= 0.0
-                      and i not in degraded)
-        if drafted:
-            self._tm.counter(telemetry.SPEC_DRAFT_TOKENS).inc(
-                drafted, generator="dense")
-        poisoned = self._handle_nonfinite(active, nf)
-        emitted = 0
-        accepted = 0
-        for i in active:
-            if i in poisoned:
-                continue
-            req = self.slots[i]
-            # a degraded slot's filler draft must not count as drafted
-            # OR accepted — it emits exactly its verified token
-            acc = 0 if i in degraded else int(n_acc[i])
-            if req.temperature <= 0.0 and i not in degraded:
-                req.spec_drafted += self.spec
-                req.spec_accepted += acc
-                accepted += acc
-                if acc:
-                    self._tm.counter(telemetry.SPEC_ACCEPTED_TOKENS).inc(
-                        acc, generator="dense")
-            run = [int(t) for t in preds[i, : acc + 1]]
-            emitted += self._emit_run(i, run)
-        self.flight.note_spec(drafted, accepted)
-        self._record_step(len(active), ms, emitted)
+        with self.flight.tick_phase("emit"):
+            self._settle_prefill(self._wait.t0_ns, self._wait.t1_ns)
+            self._attrib_verify(active, ms)
+            drafted = sum(self.spec for i in active
+                          if self.slots[i].temperature <= 0.0
+                          and i not in degraded)
+            if drafted:
+                self._tm.counter(telemetry.SPEC_DRAFT_TOKENS).inc(
+                    drafted, generator="dense")
+            poisoned = self._handle_nonfinite(active, nf)
+            emitted = 0
+            accepted = 0
+            for i in active:
+                if i in poisoned:
+                    continue
+                req = self.slots[i]
+                # a degraded slot's filler draft must not count as drafted
+                # OR accepted — it emits exactly its verified token
+                acc = 0 if i in degraded else int(n_acc[i])
+                if req.temperature <= 0.0 and i not in degraded:
+                    req.spec_drafted += self.spec
+                    req.spec_accepted += acc
+                    accepted += acc
+                    if acc:
+                        self._tm.counter(
+                            telemetry.SPEC_ACCEPTED_TOKENS).inc(
+                                acc, generator="dense")
+                run = [int(t) for t in preds[i, : acc + 1]]
+                emitted += self._emit_run(i, run)
+        with self.flight.tick_phase("bookkeeping"):
+            self.flight.note_spec(drafted, accepted)
+            self._record_step(len(active), ms, emitted)
         return emitted
 
 
@@ -1296,6 +1392,7 @@ class PagedGenerator(_GeneratorCore):
             raise ValueError("--kv-block-size is single-host only (the "
                              "worker mirror protocol has no paged ops yet)")
         self._init_core(engine, n_slots)
+        t_phase = time.monotonic()
         self.block_size = block_size
         self.table_width = blocks_per_seq(self.cfg.seq_len, block_size)
         # pool sizing through the HBM guard: want the dense pool's worst
@@ -1332,6 +1429,7 @@ class PagedGenerator(_GeneratorCore):
                   f"host DRAM budget — degrading to {n_host} "
                   f"(runtime/hbm.py fit_host_pool)", flush=True)
         self.pool = BlockPool(n_blocks, block_size, n_host_blocks=n_host)
+        t_phase = engine._stamp_startup("pool_fit", t_phase)
         pkv = PagedKVCache.create(self.cfg, n_blocks, block_size,
                                   dtype=engine.kv_dtype)
         if engine.plan is not None:
@@ -1483,6 +1581,7 @@ class PagedGenerator(_GeneratorCore):
         self._m_blocks_total.set(n_blocks - 1)
         self._m_host_total.set(self.pool.n_host_blocks)
         self._update_block_gauges()
+        engine._stamp_startup("generator", t_phase)
 
     # -- pool bookkeeping ---------------------------------------------------
 
@@ -1931,6 +2030,17 @@ class PagedGenerator(_GeneratorCore):
         (shared-prefix entries redirected to the null block — a shared
         block is never a write target) and registers the prompt's blocks
         for future sharing."""
+        with self.flight.tick_phase("prefill_dispatch"):
+            if not self._advance_prefill(adm):
+                return False
+        with self.flight.tick_phase("admit_commit"):
+            self._commit_admit(adm)
+        return True
+
+    def _advance_prefill(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
+        """The dispatch half of :meth:`continue_admit`; True once every
+        prompt position is prefilled (nothing here waits for the
+        device)."""
         if adm.pagein:
             self._exec_pagein(adm)  # raises PageInError on failure
             if adm.pagein:
@@ -1969,6 +2079,12 @@ class PagedGenerator(_GeneratorCore):
             adm.pos += len(chunk)
             if adm.pos < len(rest):
                 return False
+        return True
+
+    def _commit_admit(self, adm: "_Admission") -> None:  # dlint: owner=loop-thread
+        """The commit half of :meth:`continue_admit`, after the last
+        chunk."""
+        rest = adm.req.prompt_ids[:-1]
         slot = adm.slot
         if adm.req.score:
             # eval sequences are done at end of prefill: no commit
@@ -1977,7 +2093,7 @@ class PagedGenerator(_GeneratorCore):
             # release now and the scored column is discarded
             self._release_blocks(slot)
             self._finish_score(adm)
-            return True
+            return
         bids = self._seq_bids[slot]
         if adm.col is not None:
             # scatter only the slot's OWN blocks back: shared-prefix
@@ -2003,7 +2119,6 @@ class PagedGenerator(_GeneratorCore):
             self._proposers[slot] = NgramProposer(self.spec)
             self._proposers[slot].extend(adm.req.prompt_ids)
         self._arm_decode(adm)
-        return True
 
     def admit(self, req: Request, slot: int) -> None:  # dlint: owner=loop-thread
         """Admit in one go (tests / non-interleaved callers)."""
@@ -2052,6 +2167,7 @@ class PagedGenerator(_GeneratorCore):
         half-finished dispatch may have corrupted."""
         self.slots = [None] * self.n_slots
         self._proposers = [None] * self.n_slots
+        self._chunks_pending = []
         self._seq_bids = [[] for _ in range(self.n_slots)]
         self._n_shared = [0] * self.n_slots
         self._reserve = [0] * self.n_slots
@@ -2121,19 +2237,23 @@ class PagedGenerator(_GeneratorCore):
         occupancy or block-table contents. Under ``--spec-lookup`` the
         dispatch is the ragged paged VERIFY step instead
         (:meth:`_spec_step`)."""
-        active = self._sweep_cancelled()
+        rows = None
+        with self.flight.tick_phase("step_prepare"):
+            active = self._sweep_cancelled()
+            if active and not self.spec:
+                zeros = [0] * self.n_slots
+                self._grow_or_fail(active, zeros)
+                if active:
+                    self._assert_writable(active, zeros)
+                    rows = self._sampling_rows(active)
         if not active:
             return 0
         if self.spec:
             return self._spec_step(active)
-        zeros = [0] * self.n_slots
-        self._grow_or_fail(active, zeros)
-        if not active:
-            return 0
-        self._assert_writable(active, zeros)
-        temps, topps, coins = self._sampling_rows(active)
+        temps, topps, coins = rows
         t0 = time.perf_counter()
-        with self.eng.watchdog.guard("batch_step"):
+        with self.flight.tick_phase("step_dispatch") as wait, \
+                self.eng.watchdog.guard("batch_step"):
             failpoints.fire("step_hang")
             with self._plan_ctx():
                 (nxt, nf), self.pkv = self._step(
@@ -2143,19 +2263,23 @@ class PagedGenerator(_GeneratorCore):
                     jnp.asarray(self.tables),
                     jnp.asarray(temps), jnp.asarray(topps),
                     jnp.asarray(coins), self._poison())
+            wait.next_phase("step_wait")
             nxt, nf = np.asarray(nxt), np.asarray(nf)
         ms = (time.perf_counter() - t0) * 1000.0
-        if not self._tier_rewarmed:
-            self._tier_rewarm()
-        self._attrib_decode(active, ms)
-        poisoned = self._handle_nonfinite(active, nf)
-        emitted = 0
-        for i in active:
-            if i in poisoned:
-                continue
-            emitted += self._emit_run(i, [int(nxt[i])])
-        self._record_step(len(active), ms, emitted)
-        self._update_block_gauges()
+        with self.flight.tick_phase("emit"):
+            self._settle_prefill(wait.t0_ns, wait.t1_ns)
+            if not self._tier_rewarmed:
+                self._tier_rewarm()
+            self._attrib_decode(active, ms)
+            poisoned = self._handle_nonfinite(active, nf)
+            emitted = 0
+            for i in active:
+                if i in poisoned:
+                    continue
+                emitted += self._emit_run(i, [int(nxt[i])])
+        with self.flight.tick_phase("bookkeeping"):
+            self._record_step(len(active), ms, emitted)
+            self._update_block_gauges()
         return emitted
 
     def _spec_step(self, active: list[int]) -> int:  # dlint: owner=loop-thread
@@ -2178,6 +2302,68 @@ class PagedGenerator(_GeneratorCore):
         request's stream stays independent of its batch-mates."""
         from .speculative import spec_coins_consumed
 
+        with self.flight.tick_phase("step_prepare"):
+            drafted, rows = self._spec_rows(active)
+        if not active:
+            return 0
+        toks, lens, temps, topps, acoins, fcoins = rows
+        t0 = time.perf_counter()
+        with self.flight.tick_phase("step_dispatch") as wait, \
+                self.eng.watchdog.guard("batch_verify"):
+            failpoints.fire("step_hang")
+            with self._plan_ctx():
+                (n_acc, out, nf), self.pkv = self._verify(
+                    self.eng.params, self.cfg, jnp.asarray(toks),
+                    jnp.asarray(self.pos.astype(np.int32)), self.pkv,
+                    jnp.asarray(self.tables), jnp.asarray(lens),
+                    jnp.asarray(temps), jnp.asarray(topps),
+                    jnp.asarray(acoins), jnp.asarray(fcoins),
+                    self._poison())
+            wait.next_phase("step_wait")
+            n_acc = np.asarray(n_acc)
+            out = np.asarray(out)
+            nf = np.asarray(nf)
+        ms = (time.perf_counter() - t0) * 1000.0
+        with self.flight.tick_phase("emit"):
+            self._settle_prefill(wait.t0_ns, wait.t1_ns)
+            if not self._tier_rewarmed:
+                self._tier_rewarm()
+            self._attrib_verify(active, ms)
+            if drafted:
+                self._tm.counter(telemetry.SPEC_DRAFT_TOKENS).inc(
+                    drafted, generator="paged")
+            poisoned = self._handle_nonfinite(active, nf)
+            emitted = 0
+            accepted = 0
+            for i in active:
+                if i in poisoned:
+                    continue
+                req = self.slots[i]
+                acc = int(n_acc[i])
+                accepted += acc
+                req.spec_drafted += int(lens[i])
+                req.spec_accepted += acc
+                if req.temperature > 0.0:
+                    st = req.rng_state
+                    for _ in range(spec_coins_consumed(acc, int(lens[i]))):
+                        _, st = xorshift_random_f32(st)
+                    req.rng_state = st
+                emitted += self._emit_run(
+                    i, [int(t) for t in out[i, :acc + 1]])
+        with self.flight.tick_phase("bookkeeping"):
+            if accepted:
+                self._tm.counter(telemetry.SPEC_ACCEPTED_TOKENS).inc(
+                    accepted, generator="paged")
+            self.flight.note_spec(drafted, accepted)
+            self._record_step(len(active), ms, emitted)
+            self._update_block_gauges()
+        return emitted
+
+    def _spec_rows(self, active: list[int]):  # dlint: owner=loop-thread
+        """The verify dispatch's host rows (drafts, ragged lengths,
+        sampling knobs, pre-drawn coins) with block growth over each
+        row's verify width; ``active`` shrinks in place when growth
+        fails a row. Returns ``(drafted, rows)``."""
         spec = self.spec
         B = self.n_slots
         toks = np.zeros((B, spec + 1), dtype=np.int32)
@@ -2213,54 +2399,9 @@ class PagedGenerator(_GeneratorCore):
                     acoins[i, j], st = xorshift_random_f32(st)
                 fcoins[i], st = xorshift_random_f32(st)
         self._grow_or_fail(active, lens)
-        if not active:
-            return 0
-        self._assert_writable(active, lens)
-        t0 = time.perf_counter()
-        with self.eng.watchdog.guard("batch_verify"):
-            failpoints.fire("step_hang")
-            with self._plan_ctx():
-                (n_acc, out, nf), self.pkv = self._verify(
-                    self.eng.params, self.cfg, jnp.asarray(toks),
-                    jnp.asarray(self.pos.astype(np.int32)), self.pkv,
-                    jnp.asarray(self.tables), jnp.asarray(lens),
-                    jnp.asarray(temps), jnp.asarray(topps),
-                    jnp.asarray(acoins), jnp.asarray(fcoins),
-                    self._poison())
-            n_acc = np.asarray(n_acc)
-            out = np.asarray(out)
-            nf = np.asarray(nf)
-        ms = (time.perf_counter() - t0) * 1000.0
-        if not self._tier_rewarmed:
-            self._tier_rewarm()
-        self._attrib_verify(active, ms)
-        if drafted:
-            self._tm.counter(telemetry.SPEC_DRAFT_TOKENS).inc(
-                drafted, generator="paged")
-        poisoned = self._handle_nonfinite(active, nf)
-        emitted = 0
-        accepted = 0
-        for i in active:
-            if i in poisoned:
-                continue
-            req = self.slots[i]
-            acc = int(n_acc[i])
-            accepted += acc
-            req.spec_drafted += int(lens[i])
-            req.spec_accepted += acc
-            if req.temperature > 0.0:
-                st = req.rng_state
-                for _ in range(spec_coins_consumed(acc, int(lens[i]))):
-                    _, st = xorshift_random_f32(st)
-                req.rng_state = st
-            emitted += self._emit_run(i, [int(t) for t in out[i, :acc + 1]])
-        if accepted:
-            self._tm.counter(telemetry.SPEC_ACCEPTED_TOKENS).inc(
-                accepted, generator="paged")
-        self.flight.note_spec(drafted, accepted)
-        self._record_step(len(active), ms, emitted)
-        self._update_block_gauges()
-        return emitted
+        if active:
+            self._assert_writable(active, lens)
+        return drafted, (toks, lens, temps, topps, acoins, fcoins)
 
     def step_chunk(self, k: int) -> int:  # dlint: owner=loop-thread
         """Fused multi-step decode is not built for the paged path yet
@@ -2317,6 +2458,11 @@ class BatchScheduler:
         # flight recorder (runtime/flightrec): the scheduler owns the tick
         # framing; every decision in _tick lands in the open tick record
         self.flight = self.gen.flight
+        # a scrape shows every tick phase from start-up, not only those
+        # a tick has reached yet
+        for ph in telemetry.TICK_PHASES:
+            telemetry.registry().counter(telemetry.TICK_PHASE_MS).inc(
+                0.0, phase=ph)
         self.max_queue = max_queue
         self.max_restarts = max_restarts
         # tenant observatory (runtime/tenancy): the process-wide
@@ -2384,6 +2530,10 @@ class BatchScheduler:
             raise ValueError(
                 "eval scoring is unsupported on this engine: no "
                 "prefill_nll program (multihost has no replicated twin)")
+        # the request's clock starts at the call: the loop thread holds
+        # _lock through admit_begin (block match, gather dispatch, a first
+        # compile), and a submit waiting behind it is queueing already
+        t_submit = telemetry.now_ns()
         # resolve BEFORE the lock: the cardinality bound + overflow
         # counter live in the tenancy registry, not scheduler state
         tenant = self._tenancy.resolve(tenant)
@@ -2435,7 +2585,7 @@ class BatchScheduler:
                 # peer-KV migration is paged-pool-only; a dense pool (or
                 # an empty peer) just recomputes — no error, no field
                 req.kv_peer = kv_peer
-            req.t_submit = telemetry.now_ns()
+            req.t_submit = t_submit
             if timeout_s is not None and timeout_s > 0:
                 req.deadline_ns = req.t_submit + int(timeout_s * 1e9)
             # the span tracer binds rid → tenant BEFORE the request is
@@ -2873,7 +3023,8 @@ class BatchScheduler:
         ring stays signal-dense. The finally also closes the tick on a
         crash, so the postmortem dump includes the dying tick."""
         self.flight.begin_tick(queue_depth=len(self._queue),
-                               n_admissions=len(self._admissions))
+                               n_admissions=len(self._admissions),
+                               n_active=self.gen.n_active)
         try:
             self._tick_body()
         except BaseException as e:
@@ -2883,26 +3034,77 @@ class BatchScheduler:
             self.flight.note("crash", reason=type(e).__name__)
             raise
         finally:
-            self.flight.end_tick(
-                blocks=self.gen.flight_blocks(),
-                slots=[s.rid if s is not None else None
-                       for s in self.gen.slots],
-                prefill_budget=self.prefill_budget)
+            with self.flight.tick_phase("bookkeeping"):
+                blocks = self.gen.flight_blocks()
+                slots = [s.rid if s is not None else None
+                         for s in self.gen.slots]
+            self.flight.end_tick(blocks=blocks, slots=slots,
+                                 prefill_budget=self.prefill_budget)
 
     def _tick_body(self) -> None:  # dlint: owner=loop-thread
-        compiles_before = (
-            introspection.ledger().compile_count(self._introspect_scope)
-            if self._introspect_scope else 0)
-        self._check_deadlines()
-        # KV migration service points (runtime/kvwire): peer export
-        # gathers run here (the loop thread owns the pool), and finished
-        # peer pulls commit or fall back before this tick's admissions —
-        # a just-migrated prefix is matchable by its own request's
-        # begin_admit below
-        if self._export_jobs:
-            self._service_exports()
-        if self._migrating:
-            self._service_migrations()
+        """One tick, divided into ``telemetry.TICK_PHASES`` spans
+        (``flight.tick_phase``): the phases tile the tick, so whatever
+        the device's idle gaps overlap in a profile names what the host
+        was doing."""
+        with self.flight.tick_phase("deadlines"):
+            compiles_before = (
+                introspection.ledger().compile_count(self._introspect_scope)
+                if self._introspect_scope else 0)
+            self._check_deadlines()
+            # KV migration service points (runtime/kvwire): peer export
+            # gathers run here (the loop thread owns the pool), and
+            # finished peer pulls commit or fall back before this tick's
+            # admissions — a just-migrated prefix is matchable by its own
+            # request's begin_admit below
+            if self._export_jobs:
+                self._service_exports()
+            if self._migrating:
+                self._service_migrations()
+        with self.flight.tick_phase("admit_begin") as span:
+            span.set(admitted=self._begin_admissions())
+        self._advance_admissions()
+        # golden canary drift sentinel (runtime/numerics): time-gated
+        # fixed-seed replay on this thread — the same thread that owns
+        # every device dispatch, so it can never race a batch step. Its
+        # golden was recorded at startup (run_api_server), so replays are
+        # compile-cache hits and cannot trip the retrace sentinel.
+        canary = getattr(self.gen.eng, "canary", None)
+        if canary is not None:
+            with self.flight.tick_phase("canary"):
+                canary.maybe_run()
+        if self.gen.n_active == 0 and not self._admissions:
+            with self.flight.tick_phase("idle_wait"):
+                # idle: nobody holds KV, so reset the usage clock (a quiet
+                # hour must not be billed to whoever admits next) — but
+                # the ledger keeps its cadence so consumers see liveness
+                self._t_last_tick = time.monotonic()
+                tenancy.ledger().maybe_write(self._tenancy)
+                self._wake.wait(timeout=0.05)
+                self._wake.clear()
+            return
+        failpoints.fire("step")
+        # --decode-chunk composes with batched serving: K fused steps
+        # per tick (admissions then interleave per-K-tokens instead of
+        # per-token — the same latency/throughput trade as the engine's
+        # chunked decode)
+        chunk = getattr(self.gen.eng, "decode_chunk", 1)
+        if chunk > 1:
+            self.gen.step_chunk(chunk)
+        else:
+            self.gen.step()
+        with self.flight.tick_phase("bookkeeping"):
+            # only work-carrying ticks advance the steady countdown: an
+            # idle server must not declare itself steady before ever
+            # compiling
+            self._mark_steady_if_quiet(compiles_before)
+            self._note_tick_usage()
+
+    def _begin_admissions(self) -> int:  # dlint: owner=loop-thread
+        """The ``admit_begin`` phase: drain the queue into free slots
+        (``gen.begin_admit`` under the scheduler lock), launch parked
+        peer-KV pulls, sweep cancelled admissions. Returns how many
+        requests began admission."""
+        n_begun = 0
         reserved = {a.slot for a in self._admissions}
         started: list[_KVMigration] = []
         with self._lock:
@@ -2980,6 +3182,7 @@ class BatchScheduler:
                     continue
                 self._admissions.append(adm)
                 reserved.add(adm.slot)
+                n_begun += 1
             telemetry.registry().gauge(telemetry.QUEUE_DEPTH).set(
                 len(self._queue))
         # fetch threads launch OUTSIDE the admission lock (the spawn
@@ -3010,6 +3213,12 @@ class BatchScheduler:
                 self.flight.note("cancel", adm.req.rid, reason="admitting",
                                  tenant=adm.req.tenant)
                 adm.req.done.set()
+        return n_begun
+
+    def _advance_admissions(self) -> None:  # dlint: owner=loop-thread
+        """One chunk for the first admission, more while the tick's
+        prefill budget lasts (``prefill_dispatch`` / ``admit_commit``
+        phases inside ``gen.continue_admit``)."""
         spent = 0
         for adm in list(self._admissions):
             if spent >= self.prefill_budget:
@@ -3040,37 +3249,6 @@ class BatchScheduler:
                 self.flight.note("reject", adm.req.rid,
                                  reason=type(e).__name__)
                 adm.req.done.set()
-        # golden canary drift sentinel (runtime/numerics): time-gated
-        # fixed-seed replay on this thread — the same thread that owns
-        # every device dispatch, so it can never race a batch step. Its
-        # golden was recorded at startup (run_api_server), so replays are
-        # compile-cache hits and cannot trip the retrace sentinel.
-        canary = getattr(self.gen.eng, "canary", None)
-        if canary is not None:
-            canary.maybe_run()
-        if self.gen.n_active == 0 and not self._admissions:
-            # idle: nobody holds KV, so reset the usage clock (a quiet
-            # hour must not be billed to whoever admits next) — but the
-            # ledger keeps its cadence so consumers see liveness
-            self._t_last_tick = time.monotonic()
-            tenancy.ledger().maybe_write(self._tenancy)
-            self._wake.wait(timeout=0.05)
-            self._wake.clear()
-            return
-        failpoints.fire("step")
-        # --decode-chunk composes with batched serving: K fused steps
-        # per tick (admissions then interleave per-K-tokens instead of
-        # per-token — the same latency/throughput trade as the engine's
-        # chunked decode)
-        chunk = getattr(self.gen.eng, "decode_chunk", 1)
-        if chunk > 1:
-            self.gen.step_chunk(chunk)
-        else:
-            self.gen.step()
-        # only work-carrying ticks advance the steady countdown: an idle
-        # server must not declare itself steady before ever compiling
-        self._mark_steady_if_quiet(compiles_before)
-        self._note_tick_usage()
 
     def _tenant_active(self, tenant: str, reserved: set) -> int:  # dlint: owner=loop-thread
         """Slots ``tenant`` currently occupies or is admitting into —

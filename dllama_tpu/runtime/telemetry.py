@@ -117,6 +117,7 @@ TTFT_ATTRIB_MS = "dllama_ttft_attrib_ms"
 ITL_ATTRIB_MS = "dllama_itl_attrib_ms"
 FLIGHT_TICKS = "dllama_flight_ticks_total"
 FLIGHT_DUMPS = "dllama_flight_dumps_total"
+TICK_PHASE_MS = "dllama_tick_phase_ms_total"
 
 # fleet router (serve/router.py — the scheduler-over-engines tier)
 ROUTER_REPLICA_UP = "dllama_router_replica_up"
@@ -211,7 +212,9 @@ def _spec(name, kind, help, buckets=_LATENCY_BUCKETS_MS):
 
 SPECS: dict[str, MetricSpec] = {s.name: s for s in (
     _spec(PREFILL_CHUNK_MS, "histogram",
-          "Wall time of one prefill chunk dispatch"),
+          "Wall time of one prefill chunk (single-sequence: the fetched "
+          "dispatch; batched serving: the chunk's device-inclusive cost, "
+          "settled when the next step's fetch has waited for it)"),
     _spec(PREFILL_TOKENS, "counter", "Prompt tokens prefilled"),
     _spec(DECODE_STEP_MS, "histogram",
           "Wall time of one decode dispatch (single, fused-chunk, or "
@@ -447,6 +450,12 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "Work-carrying scheduler ticks recorded by the flight recorder "
           "(idle ticks are dropped; gaps in the dump's tick numbering "
           "mark idle stretches)"),
+    _spec(TICK_PHASE_MS, "counter",
+          "Wall milliseconds the scheduler loop spent in each phase of "
+          "its tick (label phase, one of telemetry.TICK_PHASES; every "
+          "phase renders from start-up). Host share of the loop between "
+          "two scrapes = the increase of every phase except step_wait "
+          "and idle_wait over the increase of all phases"),
     _spec(FLIGHT_DUMPS, "counter",
           "Flight-recorder postmortem dumps written, by reason "
           "(watchdog_stall / scheduler_crash / kv_block_exhaustion; "
@@ -832,6 +841,49 @@ def registry() -> Registry:
 #   chunked ``prefill_nll`` loop in the single-sequence path.
 PHASES = ("queue", "admit", "prefill", "prefill_chunk", "decode", "verify",
           "requeue", "pagein", "kvmigrate", "eval")
+
+# Tick-phase vocabulary: how ``BatchScheduler._tick`` divides its wall.
+# Each phase is a ``FlightRecorder.tick_phase(name)`` span (runtime/
+# flightrec): a ``jax.profiler.TraceAnnotation`` named
+# ``dllama.tick.<name>`` under the root span :data:`TICK_SPAN` (so a
+# profile shows the loop thread on the device lanes' clock), an entry in
+# the open flight tick record's ``phases``, and a series of
+# ``dllama_tick_phase_ms_total{phase}``. Closed-world like PHASES
+# (tools/dlint span-phases): every ``tick_phase`` call site uses a name
+# listed here, every name has a call site, and TELEMETRY.md documents it.
+# Phases are flat children of the root and never nest in each other.
+#
+# * ``deadlines`` — request deadlines, peer-KV export gathers and
+#   finished migrations, serviced before admissions.
+# * ``admit_begin`` — the queue drain under the scheduler lock with the
+#   generator's ``begin_admit`` (prefix match, block allocation,
+#   copy-on-write, column gather dispatch) and the cancelled-admission
+#   sweep; the annotation carries ``admitted=<n>``.
+# * ``prefill_dispatch`` — ``continue_admit`` up to the chunk's enqueue
+#   (page-in batch, deferred copy/gather, the prefill program's
+#   dispatch). Nothing waits for the device here.
+# * ``admit_commit`` — ``continue_admit`` after the last chunk: the
+#   commit scatter's dispatch, prompt registration, decode arming.
+# * ``step_prepare`` — cancelled-slot sweep, block growth, sampling
+#   rows, speculative drafts: the host work before a step's dispatch.
+# * ``step_dispatch`` — the jitted step/verify call until it returns
+#   (argument upload and enqueue; a compile inside the window shows
+#   here).
+# * ``step_wait`` — fetching the step's outputs: the one place the loop
+#   waits for the device, so it holds the device time of everything
+#   queued before the step as well.
+# * ``emit`` — decode attribution, the non-finite tripwire tail, and the
+#   per-row emit loop (decoder, ``on_token`` callbacks, retirements).
+# * ``bookkeeping`` — per-step telemetry, block gauges, the steady-state
+#   countdown, tenant usage accounting, and the tick record's closing
+#   snapshot.
+# * ``canary`` — the golden canary's time-gated replay, when configured.
+# * ``idle_wait`` — nothing to do: the loop sleeps on its wake event
+#   (at most 50 ms).
+TICK_PHASES = ("deadlines", "admit_begin", "prefill_dispatch",
+               "admit_commit", "step_prepare", "step_dispatch", "step_wait",
+               "emit", "bookkeeping", "canary", "idle_wait")
+TICK_SPAN = "dllama.tick"
 
 # The closed-world eval config vocabulary (tools/check_eval_names.py
 # lints it both directions): the ``eval --compare`` CLI grammar, the

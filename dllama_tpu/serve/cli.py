@@ -600,6 +600,7 @@ def run_inference(args) -> int:
 
     engine = make_engine(args)
     print(introspection.hbm_budget_line(engine))
+    print(introspection.startup_line(engine))
     print(args.prompt)
     ids = engine.tokenizer.encode(args.prompt)
     max_new = max(0, min(args.steps, engine.cfg.seq_len) - len(ids))

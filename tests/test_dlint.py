@@ -462,6 +462,81 @@ def test_span_phases_router_vocabulary(tmp_path):
     assert "rt_queue" in msgs                   # documented, never emitted
 
 
+_TICK_FIXTURE = {
+    "dllama_tpu/runtime/loop.py": """\
+        def tick(flight):
+            with flight.tick_phase("emit") as ph:
+                ph.next_phase("bogus_wait")
+            with flight.tick_phase("not_a_phase"):
+                pass
+        """,
+    "dllama_tpu/runtime/telemetry.py": """\
+        # * ``emit`` — documented here
+        # * ``never_used`` — documented, no call site
+        TICK_PHASES = ("emit", "never_used", "undocumented")
+        """,
+    "dllama_tpu/runtime/TELEMETRY.md": "emit never_used\n",
+}
+
+
+def _tick_findings(tmp_path, vocab):
+    from tools.dlint import span_phases
+
+    findings, _ = span_phases.check(_tree(tmp_path, _TICK_FIXTURE),
+                                    phases=((), (), vocab))
+    return "\n".join(f.message for f in findings)
+
+
+def test_span_phases_tick_literal_outside_vocabulary(tmp_path):
+    """tick_phase / next_phase literals are held to TICK_PHASES the way
+    tracer().emit literals are held to PHASES."""
+    msgs = _tick_findings(tmp_path, ("emit", "never_used", "undocumented"))
+    assert "'not_a_phase' which is not in telemetry.TICK_PHASES" in msgs
+    assert "'bogus_wait' which is not in telemetry.TICK_PHASES" in msgs
+    assert "'emit' which is not in" not in msgs
+
+
+def test_span_phases_unused_tick_phase(tmp_path):
+    msgs = _tick_findings(tmp_path, ("emit", "never_used", "undocumented"))
+    assert "telemetry.TICK_PHASES documents 'never_used' but no call site" in msgs
+    assert "documents 'emit' but no call site" not in msgs
+
+
+def test_span_phases_undocumented_tick_phase(tmp_path):
+    msgs = _tick_findings(tmp_path, ("emit", "never_used", "undocumented"))
+    assert "'undocumented' is not documented in TELEMETRY.md" in msgs
+    assert "'undocumented' is not described in the telemetry.py" in msgs
+    assert "'emit' is not documented" not in msgs
+
+
+def test_span_phases_tick_phase_must_be_a_literal(tmp_path):
+    from tools.dlint import span_phases
+
+    project = _tree(tmp_path, {
+        "dllama_tpu/runtime/loop.py": """\
+            def tick(flight, name):
+                with flight.tick_phase(name):
+                    pass
+            """,
+    })
+    findings, _ = span_phases.check(project, phases=((), (), ("emit",)))
+    assert any("tick_phase phase argument is not a string constant" in f.message
+               and f.lineno == 2 for f in findings)
+
+
+def test_span_phases_live_tick_vocabulary_is_closed():
+    """The live tree: every TICK_PHASES name has a call site in
+    runtime/serving.py and the two-tuple form older fixtures pass still
+    means 'no tick vocabulary'."""
+    from tools.dlint import span_phases
+
+    findings, summary = span_phases.check(Project(REPO))
+    assert findings == []
+    assert "11 tick phases" in summary
+    findings, _ = span_phases.check(Project(REPO), phases=((), ()))
+    assert all("TICK_PHASES documents" not in f.message for f in findings)
+
+
 def test_pallas_gate_fixture_violation(tmp_path):
     """A new kernel module dispatching pl.pallas_call without consulting
     quant_matmul.pallas_mode_gate fires pallas-gate at the call line; a

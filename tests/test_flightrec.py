@@ -132,6 +132,27 @@ def test_golden_fixture_converts_to_valid_chrome_trace():
     assert {"queue", "admit", "prefill", "prefill_chunk", "decode"} <= phases
 
 
+def test_golden_fixture_divides_every_tick_into_phases():
+    """The fixture's ticks carry ``phases``/``phase_spans`` from the closed
+    TICK_PHASES vocabulary, and the timeline nests one slice per span under
+    its tick on the scheduler track."""
+    data = json.loads(GOLDEN.read_text(encoding="utf-8"))
+    for t in data["ticks"]:
+        assert t["phases"] and set(t["phases"]) <= set(tm.TICK_PHASES)
+        assert sum(ms for _n, _o, ms in t["phase_spans"]) \
+            == pytest.approx(sum(t["phases"].values()))
+    evs = flightrec.to_chrome_trace(data)["traceEvents"]
+    ticks = {e["name"]: e for e in evs if e.get("cat") == "tick"}
+    slices = [e for e in evs if e.get("cat") == "tick_phase"]
+    assert len(slices) == sum(len(t["phase_spans"]) for t in data["ticks"])
+    assert {"admit_begin", "prefill_dispatch", "admit_commit", "step_wait",
+            "emit"} <= {e["name"] for e in slices}
+    for e in slices:
+        t = ticks[f"tick {e['args']['tick']}"]
+        assert (e["pid"], e["tid"]) == (t["pid"], t["tid"])
+        assert t["ts"] <= e["ts"] and e["ts"] + e["dur"] <= t["ts"] + t["dur"] + 1e-6
+
+
 def test_validator_catches_regressions_and_broken_flows():
     data = json.loads(GOLDEN.read_text(encoding="utf-8"))
     trace = flightrec.to_chrome_trace(data)

@@ -5,7 +5,11 @@ operator contract: every SpanTracer call site emits a CONSTANT phase
 from the vocabulary, every member is emitted somewhere, and both the
 telemetry docstring and TELEMETRY.md document it. The router tier's span
 ring (``serve/router.py RouterSpanRing.emit_span``) carries the same
-contract against ``telemetry.ROUTER_PHASES``.
+contract against ``telemetry.ROUTER_PHASES``, and the scheduler's tick
+phases (``FlightRecorder.tick_phase`` / ``next_phase`` in
+``runtime/flightrec.py``) against ``telemetry.TICK_PHASES``: what a
+profile, a flight dump and ``dllama_tick_phase_ms_total`` call one part
+of a tick is one closed set of names.
 """
 
 from __future__ import annotations
@@ -21,10 +25,11 @@ PKG = "dllama_tpu"
 def _load_phases():
     sys.path.insert(0, str(REPO))
     try:
-        from dllama_tpu.runtime.telemetry import PHASES, ROUTER_PHASES
+        from dllama_tpu.runtime.telemetry import (PHASES, ROUTER_PHASES,
+                                                  TICK_PHASES)
     finally:
         sys.path.pop(0)
-    return PHASES, ROUTER_PHASES
+    return PHASES, ROUTER_PHASES, TICK_PHASES
 
 
 def _is_tracer_emit(node: ast.Call) -> bool:
@@ -44,12 +49,23 @@ def _is_router_emit(node: ast.Call) -> bool:
         and node.func.attr == "emit_span"
 
 
+def _is_tick_phase(node: ast.Call) -> bool:
+    """``<anything>.tick_phase(...)`` / ``<phase>.next_phase(...)`` — both
+    method names are unique to the flight recorder's tick phases."""
+    return isinstance(node.func, ast.Attribute) \
+        and node.func.attr in ("tick_phase", "next_phase")
+
+
 def check(project: Project, phases=None) -> tuple[list[Finding], str]:
-    phases, router_phases = (phases if phases is not None
-                             else _load_phases())
+    """``phases``: ``(span, router[, tick])`` vocabularies (fixtures); the
+    live ones are read from telemetry."""
+    phases, router_phases, *rest = (phases if phases is not None
+                                    else _load_phases())
+    tick_phases = rest[0] if rest else ()
     findings: list[Finding] = []
     sites: dict[str, list[tuple[str, int]]] = {}
     r_sites: dict[str, list[tuple[str, int]]] = {}
+    t_sites: dict[str, list[tuple[str, int]]] = {}
 
     for sf in project.walk(PKG):
         if sf.tree is None:
@@ -61,24 +77,28 @@ def check(project: Project, phases=None) -> tuple[list[Finding], str]:
                 into, what = sites, "tracer().emit"
             elif _is_router_emit(node):
                 into, what = r_sites, "emit_span"
+            elif _is_tick_phase(node):
+                into, what = t_sites, node.func.attr
             else:
                 continue
-            if len(node.args) < 2 or not (
-                    isinstance(node.args[1], ast.Constant)
-                    and isinstance(node.args[1].value, str)):
+            at = 0 if into is t_sites else 1    # the phase's position
+            if len(node.args) <= at or not (
+                    isinstance(node.args[at], ast.Constant)
+                    and isinstance(node.args[at].value, str)):
                 findings.append(Finding(
                     "span-phases", sf.rel, node.lineno,
                     f"{what} phase argument is not a string "
                     f"constant — the closed-world vocabulary cannot be "
                     f"checked"))
                 continue
-            into.setdefault(node.args[1].value, []).append(
+            into.setdefault(node.args[at].value, []).append(
                 (sf.rel, node.lineno))
 
     T = f"{PKG}/runtime/telemetry.py"
     for vocab_name, vocab, found in (
             ("telemetry.PHASES", phases, sites),
-            ("telemetry.ROUTER_PHASES", router_phases, r_sites)):
+            ("telemetry.ROUTER_PHASES", router_phases, r_sites),
+            ("telemetry.TICK_PHASES", tick_phases, t_sites)):
         for phase, where in sorted(found.items()):
             if phase not in vocab:
                 findings.append(Finding(
@@ -97,7 +117,7 @@ def check(project: Project, phases=None) -> tuple[list[Finding], str]:
     telemetry_src = tsf.text if tsf is not None else ""
     psf = project.file("dllama_tpu/runtime/TELEMETRY.md")
     perf = psf.text if psf is not None else ""
-    for phase in (*phases, *router_phases):
+    for phase in (*phases, *router_phases, *tick_phases):
         if f"``{phase}``" not in telemetry_src:
             findings.append(Finding(
                 "span-phases", T, 0,
@@ -108,13 +128,15 @@ def check(project: Project, phases=None) -> tuple[list[Finding], str]:
                 "span-phases", "dllama_tpu/runtime/TELEMETRY.md", 0,
                 f"phase {phase!r} is not documented in TELEMETRY.md"))
 
-    n_sites = sum(len(w) for w in sites.values()) \
-        + sum(len(w) for w in r_sites.values())
+    n_sites = sum(len(w) for found in (sites, r_sites, t_sites)
+                  for w in found.values())
     return findings, (f"{len(phases)} span + {len(router_phases)} router "
+                      f"+ {len(tick_phases)} tick "
                       f"phases: {n_sites} call sites, vocabulary + "
                       f"telemetry docstring + TELEMETRY.md all consistent")
 
 
 rule("span-phases",
-     "every SpanTracer phase literal is in telemetry.PHASES; the "
-     "vocabulary is emitted and documented")(check)
+     "every SpanTracer phase literal is in telemetry.PHASES (router: "
+     "ROUTER_PHASES; scheduler tick: TICK_PHASES); the vocabulary is "
+     "emitted and documented")(check)

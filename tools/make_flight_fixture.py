@@ -6,7 +6,9 @@ The fixture is a deterministic mini-run recorded through the REAL
 :class:`runtime.flightrec.FlightRecorder` API (injected fake clock, no
 jax): three requests stream through two slots with admissions, an
 interleaved prefill, a budget preemption, retirements for three
-different reasons, and paged block-pool occupancy on every tick. The
+different reasons, paged block-pool occupancy on every tick, and each
+tick divided into ``telemetry.TICK_PHASES`` spans (``phases`` /
+``phase_spans``, rendered as nested slices on the scheduler track). The
 span ring entries are derived from the recorded event timeline, so
 spans and ticks share one clock — exactly what a live dump looks like.
 
@@ -61,43 +63,75 @@ def record() -> dict:
         rec.note("submit", rid, n_prompt=n_prompt, max_tokens=8)
 
     def t1():
-        rec.note("admit", 0, slot=0, reused=0, n_prompt=24)
-        rec.note("admit", 1, slot=1, reused=0, n_prompt=9)
-        rec.note_prefill(0, 2.0, 23)
-        rec.note_prefill(1, 0.9, 8)
-        rec.note("decode_armed", 1, slot=1, pos=8, reused=0)
+        with rec.tick_phase("admit_begin"):
+            rec.note("admit", 0, slot=0, reused=0, n_prompt=24)
+            rec.note("admit", 1, slot=1, reused=0, n_prompt=9)
+        with rec.tick_phase("prefill_dispatch"):
+            rec.note_prefill(0, 2.0, 23)
+        with rec.tick_phase("prefill_dispatch"):
+            rec.note_prefill(1, 0.9, 8)
+        with rec.tick_phase("admit_commit"):
+            rec.note("decode_armed", 1, slot=1, pos=8, reused=0)
 
     tick({"queue_depth": 3, "n_admissions": 0, "run": t1},
          [None, None], 4, 0)
 
+    def step(fn):
+        """One decode step's phases around ``fn`` (its emit-side notes)."""
+        with rec.tick_phase("step_prepare"):
+            pass
+        with rec.tick_phase("step_dispatch") as ph:
+            ph.next_phase("step_wait")
+        with rec.tick_phase("emit"):
+            fn()
+        with rec.tick_phase("bookkeeping"):
+            pass
+
     def t2():
         rec.note("preempt", 0, reason="prefill_budget")
-        rec.note("decode_armed", 0, slot=0, pos=23, reused=0)
-        rec.note_dispatch(1.5, 2, 2)
-        rec.note("first_token", 0, slot=0)
-        rec.note("first_token", 1, slot=1)
+        with rec.tick_phase("admit_commit"):
+            rec.note("decode_armed", 0, slot=0, pos=23, reused=0)
+
+        def emit():
+            rec.note_dispatch(1.5, 2, 2)
+            rec.note("first_token", 0, slot=0)
+            rec.note("first_token", 1, slot=1)
+
+        step(emit)
 
     tick({"queue_depth": 1, "n_admissions": 1, "run": t2}, [0, 1], 4, 0)
 
     def t3():
-        rec.note_dispatch(1.4, 2, 2)
-        rec.note("retire", 1, reason="eos", slot=1, n_tokens=3)
-        rec.note("admit", 2, slot=1, reused=8, n_prompt=17)
-        rec.note_prefill(2, 0.8, 8)
-        rec.note("decode_armed", 2, slot=1, pos=16, reused=8)
+        def emit():
+            rec.note_dispatch(1.4, 2, 2)
+            rec.note("retire", 1, reason="eos", slot=1, n_tokens=3)
+
+        step(emit)
+        with rec.tick_phase("admit_begin"):
+            rec.note("admit", 2, slot=1, reused=8, n_prompt=17)
+        with rec.tick_phase("prefill_dispatch"):
+            rec.note_prefill(2, 0.8, 8)
+        with rec.tick_phase("admit_commit"):
+            rec.note("decode_armed", 2, slot=1, pos=16, reused=8)
 
     tick({"queue_depth": 1, "n_admissions": 0, "run": t3}, [0, None], 5, 1)
 
     def t4():
-        rec.note_dispatch(1.6, 2, 2)
-        rec.note("first_token", 2, slot=1)
-        rec.note("retire", 0, reason="max_tokens", slot=0, n_tokens=8)
+        def emit():
+            rec.note_dispatch(1.6, 2, 2)
+            rec.note("first_token", 2, slot=1)
+            rec.note("retire", 0, reason="max_tokens", slot=0, n_tokens=8)
+
+        step(emit)
 
     tick({"queue_depth": 0, "n_admissions": 0, "run": t4}, [None, 2], 5, 1)
 
     def t5():
-        rec.note_dispatch(1.3, 1, 1)
-        rec.note("retire", 2, reason="max_tokens", slot=1, n_tokens=8)
+        def emit():
+            rec.note_dispatch(1.3, 1, 1)
+            rec.note("retire", 2, reason="max_tokens", slot=1, n_tokens=8)
+
+        step(emit)
 
     tick({"queue_depth": 0, "n_admissions": 0, "run": t5}, [None, None], 2, 0)
 
