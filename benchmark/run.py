@@ -32,9 +32,6 @@ PROBE_PROMPTS = (321, 40)    # one spans two prefill buckets (1 + 256 + 64), one
 PROBE_TOKENS = 32
 WARM_TOKENS = 4
 CHECK_REQUESTS = 4
-TRACE_START_SHARE = 0.2      # the traced slice starts this far into the window
-TRACE_SECONDS = 8.0          # and lasts this long (or half the window, if shorter): at 0.45 requests/s
-                             # a 4 s slice can miss every prefill chunk (it did, in the long-prompt cell)
 
 
 MODEL_KEYS = ("hidden_size", "intermediate_size", "num_hidden_layers", "num_attention_heads",
@@ -305,6 +302,22 @@ def metrics_for_cell(manifest: dict, section: str, workload: str) -> list[dict]:
     return [m for m in manifest[section] if "workloads" not in m or workload in m["workloads"]]
 
 
+def read_metrics(entries: list[dict], folder: str, ctx: dict) -> tuple[dict, list[tuple[str, str]]]:
+    """The line's metrics from the manifest's entries, and the (metric, reader)
+    pairs left out of it. Each metric is a file ``<folder>/<name>.json`` naming
+    its reader and arguments; a reader that finds nothing returns ``None``."""
+    metrics, missing = {}, []
+    for m in entries:
+        spec = _load_json(os.path.join(HERE, folder, m["name"] + ".json"))
+        reader = _import_file("reader_" + spec["reader"], os.path.join(HERE, "readers", spec["reader"] + ".py"))
+        value = reader.read(ctx, **spec.get("args", {}))
+        if value is None:
+            missing.append((m["name"], spec["reader"]))
+        else:
+            metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    return metrics, missing
+
+
 def main(argv=None) -> int:
     args = parse_args(argv)
     manifest = _load_json(args.manifest)
@@ -338,6 +351,11 @@ def main(argv=None) -> int:
     if plan.max_context > int(conf["engine"]["max_seq_len"]):
         _fail(f"traffic reaches {plan.max_context} tokens; the configuration's context is "
               f"{conf['engine']['max_seq_len']}", 2)
+    if args.trace:
+        slice_start_s, slice_s = traffic.trace_slice(mix, seconds)
+        fault = traffic.slice_fault(plan, slice_start_s, slice_s, seconds)
+        if fault:
+            _fail(f"{traffic_path}: {fault}", 2)
 
     engine, sched = build_engine(conf, plan.engine, chips, args.seed)
     rng = np.random.default_rng([args.seed & 0xFFFFFFFF, args.seed >> 32, 1])
@@ -368,13 +386,13 @@ def main(argv=None) -> int:
 
             sampler = Sampler()
             sampler.start()
-            tracer = trace_window.TraceWindow(trace_dir, start_after_s=TRACE_START_SHARE * seconds,
-                                              seconds=min(TRACE_SECONDS, 0.5 * seconds))
+            tracer = trace_window.TraceWindow(trace_dir, start_after_s=slice_start_s, seconds=slice_s)
             tracer.start()
         if os.environ.get("BENCH_LOG_COMPILES"):
             jax.config.update("jax_log_compiles", True)    # builder's aid: name what compiles in the window
         setup_s = time.monotonic() - T_START
-        _note(f"set-up done; window of {seconds:g} s starts")
+        _note(f"set-up done; window of {seconds:g} s starts"
+              + (f", traced from {slice_start_s:g} to {slice_start_s + slice_s:g} s" if args.trace else ""))
         gen.run(seconds)
         _note(f"window and drain done: {len(gen.sent)} sent")
         traced = tracer.finish() if tracer else None
@@ -434,13 +452,12 @@ def main(argv=None) -> int:
             # --trace 0: the cell's end-to-end metrics; --trace 1: its per-layer metrics.
             # Each metric is a file naming its reader; a reader that finds nothing returns None.
             section, folder = (("per_layer", "layer_metrics") if args.trace else ("end_to_end", "end_to_end"))
-            for m in metrics_for_cell(manifest, section, args.workload):
-                spec = _load_json(os.path.join(HERE, folder, m["name"] + ".json"))
-                reader = _import_file("reader_" + spec["reader"],
-                                      os.path.join(HERE, "readers", spec["reader"] + ".py"))
-                value = reader.read(ctx, **spec.get("args", {}))
-                if value is not None:
-                    result["metrics"][m["name"]] = {"value": float(value), "unit": m["unit"]}
+            result["metrics"], missing = read_metrics(metrics_for_cell(manifest, section, args.workload),
+                                                      folder, ctx)
+            for name, reader in missing:
+                # the driver refuses a line that lacks a metric its parent's line had
+                _note(f"{section} metric {name!r} is NOT in the line: its reader readers/{reader}.py "
+                      f"found nothing to read in this run")
         result["gap"] = {"tolerance": tol, "max": float(all_gaps.max()) if len(all_gaps) else None,
                          "positions": int(len(all_gaps)), "control": args.control,
                          "invariants": invariants}

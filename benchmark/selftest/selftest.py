@@ -7,11 +7,14 @@ configurations name their device ``cpu`` and report counts only) and checks
 the parts of the yardstick that need no chip:
 
 1. the trace reduction on ``fixtures/tiny.xplane.pb`` (busy union, idle share,
-   collective share, ``op_label`` names, widest-program choice);
+   collective share, ``op_label`` names under their program's, widest-program
+   choice);
 2. the peaks table (an unknown device kind raises) and the bytes and FLOPs
    functions on numbers worked by hand;
 3. the traffic generator (a seed repeats itself; every seed gets the same
-   multiset of sizes and gaps);
+   multiset of sizes and gaps), and ``test_trace_slice.py`` beside this file:
+   every mix's traced slice is anchored on arrivals, a line that lacks a
+   metric names it, the breakdown names programs and tick phases;
 4. the command end to end: the output line's keys, a Qwen3-style block against
    the reference, the tp=4 path on four virtual devices, the negative control
    turning ``correct`` false, and every real cell refusing to run without a TPU.
@@ -50,7 +53,9 @@ def test_trace_reduce() -> None:
     check("trace: busy union 6 ms (nested wait not counted twice)", near(r["busy_s"], 0.006))
     check("trace: idle share 0.4", near(r["idle_share"], 0.4))
     check("trace: collective 1.5 ms (all-reduce by opcode, psum by name)", near(r["collective_s"], 0.0015))
-    check("trace: op_label names", r["device_ops"][0][0] == "fusion.1 fusion" and near(r["device_ops"][0][1], 0.005)
+    check("trace: op_label names, each under the program that ran it",
+          r["device_ops"][0][0] == "paged_sampled_step_guarded/fusion.1 fusion" and near(r["device_ops"][0][1], 0.004)
+          and ["forward/fusion.1 fusion", 0.001] in [[k, round(v, 9)] for k, v in r["device_ops"]]
           and tr.op_label("%psum.9 = f32[4096]{0} all-reduce(%x)") == "psum.9 all-reduce"
           and tr.op_label("dot_general.1") == "dot_general.1")
     check("trace: the gap is labelled by the callback's span", r["idle_gaps"] == [["bench.on_token", r["idle_gaps"][0][1]]]
@@ -103,6 +108,13 @@ def test_traffic() -> None:
         if a.loop == "open":
             check(f"traffic {name}: all arrivals inside the window", all(0 <= r.due_s < 20 for r in a.requests)
                   and len(a.requests) == len(c.requests) == round(mix["rate_per_s"] * 20))
+
+
+def test_trace_slice() -> None:
+    p = subprocess.run([sys.executable, "-m", "pytest", os.path.join(HERE, "test_trace_slice.py"), "-q",
+                        "-p", "no:cacheprovider"], env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT,
+                       capture_output=True, text=True, timeout=900)
+    check("trace slice: test_trace_slice.py passes", p.returncode == 0, p.stdout[-600:])
 
 
 def run_cmd(args: list[str], *, devices: int = 1, manifest: bool = True):
@@ -161,6 +173,7 @@ def main() -> int:
     test_trace_reduce()
     test_peaks()
     test_traffic()
+    test_trace_slice()
     if "--fast" not in sys.argv:
         test_command()
     print(f"{len(FAILED)} failed" if FAILED else "all passed", flush=True)

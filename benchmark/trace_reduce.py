@@ -1,6 +1,6 @@
 """From a profiler trace (``.xplane.pb``) to numbers: device busy union, idle
-share, collective share, time per device operation, the longest idle gaps
-labelled by what the host was doing, and per-program (XLA module) durations.
+share, collective share, time per device operation of each program, the idle
+gaps by what the host was doing, and per-program (XLA module) durations.
 
 The benchmark's own copy of the idea in ``dllama_tpu/runtime/profiling.py``
 (``_device_lines``, ``op_label``, ``split_from_trace``), so that a later PR
@@ -10,7 +10,9 @@ alone. Checked against ``fixtures/tiny.xplane.pb`` by the self-test.
 
 from __future__ import annotations
 
+import bisect
 import re
+from collections.abc import Callable
 
 # a v5e lane names each event with the whole HLO instruction:
 #   %all-reduce.3 = f32[1,1,2048]{2,1,0:T(1,128)S(1)} all-reduce(...)
@@ -91,22 +93,73 @@ def _host_spans(pd) -> list[tuple[str, float, float]]:
     return spans
 
 
-def _label_gap(a: float, b: float, spans) -> str:
-    """What the host was doing in [a, b]: the benchmark's span that covers most
-    of it, the scheduler's callback first (it runs on the thread that feeds
-    the device). Where no span of the benchmark overlaps, the time is inside
-    the program (the scheduler's tick), which has no spans of its own yet."""
-    best: dict[str, float] = {}
+def idle_stretches(busy: list[tuple[float, float]], phases) -> list[tuple[float, float]]:
+    """The stretches in which no op ran: between ``busy``'s merged intervals,
+    and from the first tick phase to the first op and from the last op to the
+    last phase's end (a slice that begins on an empty server begins idle; the
+    same hull as ``program_spans.idle_by_phase``)."""
+    lo = min([busy[0][0]] + [s for _n, s, _e in phases])
+    hi = max([busy[-1][1]] + [e for _n, _s, e in phases])
+    return ([(lo, busy[0][0])] + [(b0, a1) for (_a0, b0), (a1, _b1) in zip(busy, busy[1:])]
+            + [(busy[-1][1], hi)])
+
+
+def _overlaps(a: float, b: float, spans) -> dict[str, float]:
+    """Seconds of [a, b] under each span name."""
+    out: dict[str, float] = {}
     for name, s, e in spans:
         ov = min(b, e) - max(a, s)
         if ov > 0:
-            best[name] = best.get(name, 0.0) + ov
-    for name in ("bench.on_token", "bench.submit"):
-        if best.get(name, 0.0) > 0.5 * (b - a):
-            return name
-    if best.get("bench.sleep", 0.0) > 0.5 * (b - a):
-        return "scheduler tick (generator asleep)"
-    return "scheduler tick (no span)"
+            out[name] = out.get(name, 0.0) + ov
+    return out
+
+
+def _label_gap(a: float, b: float, phases, spans) -> dict[str, float]:
+    """What the host was doing in the idle stretch [a, b], in seconds by label.
+    The program's own spans first: each ``dllama.tick.<phase>`` over the
+    stretch gets its part of it (the loop thread feeds the device, and the
+    stretch between two steps lies under several phases). What no phase
+    covers goes to the benchmark's span over most of the whole stretch, the
+    scheduler's callbacks first (they run on that same thread), then the
+    generator's sleep."""
+    out = _overlaps(a, b, phases)
+    rest = (b - a) - sum(out.values())
+    if rest < MIN_GAP_S:
+        return out
+    best = _overlaps(a, b, spans)
+    label = next((n for n in ("bench.on_token", "bench.submit", "bench.sleep") if best.get(n, 0.0) > 0.5 * (b - a)),
+                 "no span")
+    out[label] = out.get(label, 0.0) + rest
+    return out
+
+
+def _tick_phases(pd) -> list[tuple[str, float, float]]:
+    """The program's ``dllama.tick.<phase>`` spans as (name, start_s, end_s),
+    through ``program_spans``' own grouping (imported here: that module
+    imports this one)."""
+    import program_spans
+
+    return [(f"{program_spans.ROOT_SPAN}.{phase}", s, e)
+            for t in program_spans.group_ticks(program_spans._tick_events(pd))
+            for phase, s, e, _stats in t["children"]]
+
+
+_MODULE_NAME = re.compile(r"^(?:jit_)?(.*?)(?:\(\d+\))?$")
+
+
+def _module_of(mods) -> Callable[[float], str | None]:
+    """start_s -> the name of the program (XLA module) running then, without
+    ``jit_`` and the run's id, or ``None``. A fusion's name says nothing of
+    its program: ``dynamic-slice_convert_fusion.8`` is one fusion of the decode
+    step and another of the prefill chunk."""
+    mods = sorted((s, e, _MODULE_NAME.match(n).group(1)) for n, s, e in mods)
+    starts = [m[0] for m in mods]
+
+    def at(t: float) -> str | None:
+        i = bisect.bisect_right(starts, t) - 1
+        return mods[i][2] if i >= 0 and t < mods[i][1] else None
+
+    return at
 
 
 def reduce(path: str, window_s: float | None = None) -> dict:
@@ -118,7 +171,7 @@ def reduce(path: str, window_s: float | None = None) -> dict:
     lanes = _lanes(pd)
     if not lanes:
         raise ValueError(f"{path}: no device lane in the trace")
-    spans = _host_spans(pd)
+    spans, phases = _host_spans(pd), _tick_phases(pd)
     busy, coll = [], []
     for _name, ops, _mods in lanes:
         busy.append(total(union([(s, e) for _n, s, e in ops])))
@@ -127,17 +180,19 @@ def reduce(path: str, window_s: float | None = None) -> dict:
     if window_s is None:
         window_s = max(e for _n, _s, e in ops0) - min(s for _n, s, _e in ops0)
     per_op: dict[str, float] = {}
+    module_at = _module_of(mods0)
     for n, s, e in ops0:
         lab = op_label(n)
         if lab.endswith(_CONTAINERS):
             continue
+        mod = module_at(s)
+        lab = f"{mod}/{lab}" if mod else lab
         per_op[lab] = per_op.get(lab, 0.0) + (e - s)
-    merged = union([(s, e) for _n, s, e in ops0])
     gaps: dict[str, float] = {}
-    for (_a0, b0), (a1, _b1) in zip(merged, merged[1:]):
-        if a1 - b0 >= MIN_GAP_S:
-            lab = _label_gap(b0, a1, spans)
-            gaps[lab] = gaps.get(lab, 0.0) + (a1 - b0)
+    for a, b in idle_stretches(union([(s, e) for _n, s, e in ops0]), phases):
+        if b - a >= MIN_GAP_S:
+            for lab, secs in _label_gap(a, b, phases, spans).items():
+                gaps[lab] = gaps.get(lab, 0.0) + secs
     modules: dict[str, list[float]] = {}
     for n, s, e in mods0:
         modules.setdefault(n, []).append(e - s)

@@ -27,6 +27,13 @@ Schema (all lengths in tokens; see ``benchmark/README.md`` for an example):
     drain_limit_s   open loop: how long after the window an arrival may still
                     be awaited before it counts as failed
     sizes_seed      the seed of the sizes and gaps (see below)
+    trace_slice     optional {"start_s": s, "seconds": n}: where a ``--trace 1``
+                    run's traced slice lies in the window (``trace_slice``
+                    below has the default). An open loop's slice is anchored
+                    on arrivals (``slice_fault``): work begins at an arrival
+                    whatever the program's speed, so a faster program cannot
+                    empty such a slice, and it does empty one laid over the
+                    tail of an earlier burst
 
 Every ``--seed`` sees the SAME sequence of sizes and inter-arrival gaps, drawn
 once from ``sizes_seed``; the seed draws the token ids, the per-request
@@ -75,6 +82,40 @@ class Plan:
 def load(path: str) -> dict:
     with open(path, encoding="utf-8") as f:
         return json.load(f)
+
+
+TRACE_START_SHARE = 0.2   # absent "trace_slice": the slice starts this far into the window
+TRACE_SECONDS = 8.0       # and lasts this long, or half the window if that is shorter
+ANCHOR_ARRIVALS = 3       # an open loop's slice holds this many arrivals in its first half,
+ANCHOR_WITHIN_S = 1.0     # the earliest of them due this soon after the slice starts
+
+
+def trace_slice(mix: dict, seconds: float) -> tuple[float, float]:
+    """(start_s, seconds) of the traced slice in a window of ``seconds``: the
+    mix's ``trace_slice`` where it states one, else the default."""
+    stated = mix.get("trace_slice")
+    if stated is None:
+        return TRACE_START_SHARE * seconds, min(TRACE_SECONDS, 0.5 * seconds)
+    return float(stated["start_s"]), float(stated["seconds"])
+
+
+def slice_fault(plan: "Plan", start_s: float, slice_s: float, seconds: float) -> str | None:
+    """What is wrong with tracing [start_s, start_s + slice_s) of this plan's
+    window, or ``None``. The slice lies inside the window. An open loop's
+    slice is anchored on arrivals: its first half holds ANCHOR_ARRIVALS or
+    more, the earliest due within ANCHOR_WITHIN_S of its start. A closed loop
+    is loaded throughout and needs no anchor."""
+    if not (slice_s > 0 and 0 <= start_s and start_s + slice_s <= seconds):
+        return f"the slice {start_s:g}-{start_s + slice_s:g} s does not lie inside the window of {seconds:g} s"
+    if plan.loop != "open":
+        return None
+    half = [r.due_s for r in plan.requests if start_s <= r.due_s < start_s + 0.5 * slice_s]
+    if len(half) >= ANCHOR_ARRIVALS and min(half) - start_s <= ANCHOR_WITHIN_S:
+        return None
+    first = f"the earliest {min(half) - start_s:.2f} s in" if half else "none to be the earliest"
+    return (f"the slice {start_s:g}-{start_s + slice_s:g} s is not anchored on arrivals: its first half holds "
+            f"{len(half)}, {first} (an open loop needs {ANCHOR_ARRIVALS} or more, the earliest within "
+            f"{ANCHOR_WITHIN_S:g} s); arrivals are due at " + ", ".join(f"{r.due_s:.2f}" for r in plan.requests) + " s")
 
 
 STRATUM = 16   # lengths are dealt in blocks of this many, each block spread over the whole distribution
