@@ -323,9 +323,12 @@ def run_inference(name: str, ctx: dict, extra: list[str]) -> dict:
         f"in the XLA backend")
     for prog, kern in kernels.items():
         say(f"  [{name}]   {prog}: Pallas kernels compiled in: {kern}")
+    paths = re.search(r"q40 matmuls: (.*)", text)
+    paths = paths.group(1) if paths else ""
+    say(f"  [{name}]   q40 matmuls by path: {paths or 'none counted'}")
     return {"text": generated, "first_token_s": first_token_s, "dev": dev,
             "backend_s": float(comp.group(3)), "kernels": kernels,
-            "log": text, "child": ch}
+            "q40_paths": paths, "log": text, "child": ch}
 
 
 def cache_entries(ctx: dict) -> int:
@@ -377,8 +380,15 @@ def phase_inference_f32(ctx: dict) -> dict:
 
 
 def phase_inference_bf16(ctx: dict) -> dict:
-    say("phase inference-bf16: serving numerics (XLA fused-dequant path)")
-    return run_inference("inference-bf16", ctx, ["--compute-dtype", "bf16"])
+    say("phase inference-bf16: serving numerics (on a TPU the fused "
+        "dequant-GEMV at M = 1, XLA dequant + dot for the prompt)")
+    out = run_inference("inference-bf16", ctx, ["--compute-dtype", "bf16"])
+    if ctx["platform"] == "tpu" and not re.search(
+            r"greedy_step [1-9]\d* fused / 0 tiled / 0 xla", out["q40_paths"]):
+        # `auto` has no row floor: one row is a decode-shaped dispatch
+        out["child"].fail("the bf16 decode step (M = 1) did not take the "
+                          f"fused kernel: {out['q40_paths']!r}")
+    return out
 
 
 def http(port: int, path: str, body: dict | None = None,
@@ -498,11 +508,15 @@ def phase_api(ctx: dict, extra: list[str], n_tokens: int = 24) -> dict:
         f"{sum(p['total_compile_s'] for p in compiled):.1f} s compile wall")
     for p in compiled:
         kern = (p["analysis"] or {}).get("kernels") or {}
+        paths = p.get("q40_paths") or {}
         say(f"  [{name}]   {p['program']}: HBM "
             f"{p['hbm_total_bytes'] / 2**30:.2f} GiB, Pallas kernels "
             f"compiled in: "
             + (" ".join(f"{k}x{n}" for k, n in sorted(kern.items()))
-               or "none"))
+               or "none")
+            + ("; q40 matmuls " + " / ".join(f"{n} {k}"
+                                             for k, n in paths.items())
+               if paths else ""))
 
     os.kill(ch.proc.pid, signal.SIGTERM)
     rc = ch.wait_exit(time.monotonic() - ch.t0 + 60)

@@ -37,6 +37,7 @@ from ..ops import flash_attention as _fa
 from ..ops.attention import attention
 from ..ops.flash_attention import flash_attention
 from ..ops.linear import (
+    LayerSlice,
     QuantizedWeight,
     Weight,
     fake_quant_q80,
@@ -76,6 +77,49 @@ class LayerParams(NamedTuple):
     we1: Weight | None = None          # [L, E, dim, hidden_dim] (gate)
     we2: Weight | None = None          # [L, E, hidden_dim, dim] (down)
     we3: Weight | None = None          # [L, E, dim, hidden_dim] (up)
+
+
+# the per-layer 2-D matmul planes: the leaves whose leading axis a layer scan
+# may hand to linear() as stack + index (LayerSlice) instead of slicing
+_LAYER_MATMULS = ("wq", "wk", "wv", "wo", "w1", "w2", "w3")
+
+
+def _layer_at(layers: LayerParams, l: jax.Array) -> LayerParams:
+    """Layer ``l`` of the stacked weights, for a scan that closes over the
+    stack and walks the index. A scan over ``layers`` as ``xs`` hands the
+    body a slice, and XLA materializes that slice in front of a custom call:
+    the fused dequant-GEMV would read a fresh copy of every plane it was
+    built to read once. So the Q40 matmul planes stay whole and go to
+    :func:`linear` as a :class:`LayerSlice` — this is the place that knows
+    their leading axis is the layer; MoE expert stacks (``[L, E, ..]``) and
+    everything small are sliced as a scan would slice them."""
+    def at(name: str, leaf):
+        if name in _LAYER_MATMULS and isinstance(leaf, QuantizedWeight):
+            return LayerSlice(leaf, l)
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
+            leaf)
+
+    return LayerParams(*(at(n, getattr(layers, n))
+                         for n in LayerParams._fields))
+
+
+def _scan_by_index(cfg: ModelConfig, rows: int) -> bool:
+    """Whether a layer scan walks the layer INDEX with the weight stack
+    closed over (:func:`_layer_at`) instead of scanning the stack: a
+    decode-shaped dispatch (``rows`` = B x T flattened, the fused kernel's
+    regime) on one device. Wider dispatches (a prefill chunk), mesh plans
+    and offloaded weights scan the stack itself, as ever. Platform and
+    kernel mode do not enter: where no kernel takes the stack, linear()
+    slices it, which is what the scan did."""
+    from ..ops.quant_matmul import FUSED_MAX_M
+
+    return (_current_plan() is None and not cfg.offload
+            and rows <= FUSED_MAX_M)
+
+
+def _layer_indices(cfg: ModelConfig) -> jax.Array:
+    return jnp.arange(cfg.n_layers, dtype=jnp.int32)
 
 
 class Params(NamedTuple):
@@ -658,6 +702,28 @@ def _layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
     return x, k_cache, v_cache
 
 
+def _write_kv_rows(pool: jax.Array, new: jax.Array, blk: jax.Array,
+                   off: jax.Array) -> jax.Array:
+    """Write ``new [B, T, n_kv, hd]`` into ``pool [n_blocks, n_kv, bs, hd]``
+    at the cells ``(blk[b, t], :, off[b, t], :)``.
+
+    The decode step (T == 1, static) writes its B cells row by row; wider
+    dispatches (the verify step, a paged prefill) scatter. Same cells, same
+    bytes — but beside a Pallas matmul in the scanned body the scatter kept
+    the pool slices out of VMEM and paged attention went from 0.29 to 1.0 ms
+    a layer on the chip (PERF.md section 6, PR 26 / PR 28)."""
+    new = new.astype(pool.dtype)
+    if new.shape[1] != 1:
+        # advanced (blk, off) indices around the head slice address each
+        # row's [n_kv, hd] cell
+        return pool.at[blk, :, off, :].set(new)
+    cells = jnp.swapaxes(new, 1, 2)                          # [B, n_kv, 1, hd]
+    for b in range(new.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, cells[b:b + 1], (blk[b, 0], 0, off[b, 0], 0))
+    return pool
+
+
 def _paged_layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
                       k_pool: jax.Array, v_pool: jax.Array,
                       cos: jax.Array, sin: jax.Array,
@@ -698,11 +764,10 @@ def _paged_layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
         # lengths never retrace.
         lane = jnp.arange(T, dtype=jnp.int32)[None, :]
         blk = jnp.where(lane <= write_lens[:, None], blk, 0)
-    # scatter the new rows: advanced (blk, off) indices around the head
-    # slice address each row's [n_kv, hd] cell; inactive rows carry
-    # all-null tables, so their ride-along writes land in the null block
-    k_pool = k_pool.at[blk, :, off, :].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, :, off, :].set(v.astype(v_pool.dtype))
+    # inactive rows carry all-null tables, so their ride-along writes land
+    # in the null block
+    k_pool = _write_kv_rows(k_pool, k, blk, off)
+    v_pool = _write_kv_rows(v_pool, v, blk, off)
 
     kernel = _pa.kernel_choice(tuple(q.shape), cfg.n_kv_heads,
                                n_blocks_seq, bs)
@@ -920,10 +985,14 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     positions = (start_pos[:, None] if ragged else start_pos) + arange
     positions = jnp.broadcast_to(positions, (B, T))
 
+    by_index = _scan_by_index(cfg, B * T) and not collect
+
     def body(carry, xs):
         x = carry
         lp, k_l, v_l = xs
-        if cfg.offload:
+        if by_index:
+            lp = _layer_at(params.layers, lp)
+        elif cfg.offload:
             # weights stream host → device per layer; XLA prefetches the next
             # layer's transfer while this layer computes (cfg.offload docs)
             lp = jax.device_put(lp, jax.memory.Space.Device)
@@ -941,7 +1010,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     # per-step loop overhead beyond the matmuls on the 1b shape. Part of the
     # multihost cluster fingerprint (different unroll = different program).
     unroll = int(os.environ.get("DLLAMA_TPU_SCAN_UNROLL", "1"))
-    x, ys = jax.lax.scan(body, x, (params.layers, kv.k, kv.v),
+    layers = _layer_indices(cfg) if by_index else params.layers
+    x, ys = jax.lax.scan(body, x, (layers, kv.k, kv.v),
                          unroll=max(1, unroll))
     if collect:
         new_k, new_v, layer_taps = ys  # stacked [L] leaves per site
@@ -1214,17 +1284,22 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     arange = jnp.arange(T, dtype=jnp.int32)[None, :]
     positions = jnp.broadcast_to(pos_vec[:, None] + arange, (B, T))
 
+    by_index = _scan_by_index(cfg, B * T)
+
     def body(carry, xs):
         x = carry
         lp, k_l, v_l = xs
-        if cfg.offload:
+        if by_index:
+            lp = _layer_at(params.layers, lp)
+        elif cfg.offload:
             lp = jax.device_put(lp, jax.memory.Space.Device)
         x, k_l, v_l = _paged_layer_step(cfg, x, lp, k_l, v_l, cos, sin,
                                         positions, tables, write_lens)
         return x, (k_l, v_l)
 
+    layers = _layer_indices(cfg) if by_index else params.layers
     unroll = int(os.environ.get("DLLAMA_TPU_SCAN_UNROLL", "1"))
-    x, (new_k, new_v) = jax.lax.scan(body, x, (params.layers, pkv.k, pkv.v),
+    x, (new_k, new_v) = jax.lax.scan(body, x, (layers, pkv.k, pkv.v),
                                      unroll=max(1, unroll))
     x = rms_norm(x, params.final_norm, cfg.norm_epsilon)
     if cfg.sync_q80:

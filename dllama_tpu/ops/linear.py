@@ -56,7 +56,32 @@ class QuantizedWeight(NamedTuple):
         return self.codes.shape[-2]
 
 
-Weight = Union[jax.Array, QuantizedWeight]
+class LayerSlice(NamedTuple):
+    """Layer ``index`` of a Q40 weight whose leading axis is the LAYER
+    stack (``stack.codes [L, in, out]``, ``stack.scales [L, in/32, out]``).
+
+    The call site that scans the layers builds it (models/llama.py), and by
+    building it says what the leading axis means — :func:`linear` never
+    guesses that from ``codes.ndim == 3``, which is also how MoE experts
+    are stacked. It lets the fused decode kernel read layer ``index``'s
+    stripes out of the stack where it lies (quant_matmul's ``layer``
+    entry); every other path takes the plain slice (:meth:`take`)."""
+
+    stack: QuantizedWeight
+    index: jax.Array  # int32 scalar, traced
+
+    def take(self) -> QuantizedWeight:
+        return QuantizedWeight(*(
+            jax.lax.dynamic_index_in_dim(p, self.index, 0, keepdims=False)
+            for p in self.stack))
+
+    def one_layer(self) -> QuantizedWeight:
+        """One layer's planes as shapes (what the shape gates look at)."""
+        return QuantizedWeight(*(
+            jax.ShapeDtypeStruct(p.shape[1:], p.dtype) for p in self.stack))
+
+
+Weight = Union[jax.Array, QuantizedWeight, LayerSlice]
 
 
 def quantize_weight_q40(w: np.ndarray) -> QuantizedWeight:
@@ -139,23 +164,23 @@ def quant_mode_label(activations_bf16: bool) -> str:
 def _pallas_wanted(x: jax.Array, w: QuantizedWeight, fast: bool) -> dict | None:  # dlint: static-fn (shape/env gate)
     """quant_matmul kwargs when the plain (no-plan) Pallas path applies,
     else None. The mode rule is quant_matmul.pallas_mode_gate — the ONE
-    gate; this adds only the shape check and the plan-free requirement.
+    gate, which is shown the dispatch's shapes; this adds only the forced
+    modes' shape check and the plan-free requirement. ``w`` may carry
+    ShapeDtypeStruct leaves.
 
-    auto resolves Pallas only for EXACT mode on TPU (its HIGHEST-precision
-    dots match the host oracle; CPU interpret is slow and GPU can't lower
-    it). Fast mode's auto takes the XLA fused-dequant path: on the real
-    chip it streams codes at 450-750 GB/s vs the tiled kernel's ~130 GB/s
-    (tools/gemv_sweep.py, 2026-07-31 capture) — XLA fuses convert+scale
-    into the matmul's HBM loads, which a custom-call operand cannot; the
-    ``fused`` decode kernel is the candidate built to close exactly that
-    gap (single full-K pass per stripe), promotable via the perf-matrix
-    A/B. Under a mesh plan the sharded entry in linear() handles dispatch;
-    this plain path must stay out of GSPMD-partitioned graphs (the
-    auto-sharder can't split a pallas_call)."""
+    What ``auto`` comes to on a TPU: exact mode takes the tiled kernel
+    (HIGHEST-precision dots that match the host oracle); fast mode takes
+    the fused dequant-GEMV for 1..16 flattened rows over a 2-D plane pair
+    and the XLA dequant + dot for everything wider (a prefill chunk's
+    dequant amortizes over its rows, and the tiled kernel streams codes at
+    ~130 GB/s where XLA reaches 450-750: tools/gemv_sweep.py). Under a
+    mesh plan the sharded entry in linear() handles dispatch; this plain
+    path must stay out of GSPMD-partitioned graphs (the auto-sharder can't
+    split a pallas_call)."""
     from .quant_matmul import (pallas_mode_gate, supports, supports_decode,
                                wants_fused)
 
-    kw = pallas_mode_gate(fast)
+    kw = pallas_mode_gate(fast, tuple(x.shape), w)
     if kw is None:
         return None
     if not (supports(tuple(x.shape), w)
@@ -174,7 +199,8 @@ def _pallas_sharded(x: jax.Array, w: QuantizedWeight, out_axis: str | None,
     falls back to XLA dequant+dot (auto-sharded via constraints). The
     mode/numerics gate is quant_matmul.pallas_mode_gate — the ONE rule
     this, the overlapped merge, and the engine's wire pricing share
-    (fast mode: XLA fused dequant wins, see _pallas_wanted)."""
+    (it is shown no shape here, so fast mode's ``auto`` resolves to no
+    kernel under a plan, as it always did: the tp cell decides that)."""
     from .quant_matmul import pallas_mode_gate, quant_matmul_sharded
 
     kw = pallas_mode_gate(fast)
@@ -190,6 +216,35 @@ def _pallas_sharded(x: jax.Array, w: QuantizedWeight, out_axis: str | None,
         fused=kw.get("fused", False))
 
 
+# dlint: static-fn (shape/env gate)
+def _takes_decode_kernel(kw: dict | None, x: jax.Array, w: QuantizedWeight,
+                         fast: bool) -> bool:
+    """Whether quant_matmul, given these gate kwargs, runs the decode
+    kernel on this dispatch (and not the tiled one)."""
+    from .quant_matmul import supports_decode, wants_fused
+
+    return wants_fused(kw) and supports_decode(tuple(x.shape), w, fast)
+
+
+def _layer_slice_fused(x: jax.Array, w: LayerSlice) -> jax.Array | None:
+    """The fused decode kernel over the layer stack and an index, where the
+    gate resolves it for one layer's shapes (no plan: the stack entry has
+    no sharded twin); None sends the caller to the plain slice."""
+    from ..parallel.api import current_plan
+    from ..runtime.introspection import note_q40_path
+    from .quant_matmul import quant_matmul
+
+    if current_plan() is not None:
+        return None
+    fast = _fast_mode(x) or w.stack.scales.dtype == jnp.bfloat16
+    one = w.one_layer()
+    kw = _pallas_wanted(x, one, fast)
+    if not _takes_decode_kernel(kw, x, one, fast):
+        return None
+    note_q40_path("fused")
+    return quant_matmul(x, w.stack, fast=fast, layer=w.index, **kw)
+
+
 def linear(x: jax.Array, w: Weight, *, out_axis: str | None = None,
            in_axis: str | None = None) -> jax.Array:
     """``y[..., out] = x[..., in] @ w.T`` with dense or Q40 weight.
@@ -201,10 +256,16 @@ def linear(x: jax.Array, w: Weight, *, out_axis: str | None = None,
     reference's sliceRowMatmul/sliceColMatmul split): under a mesh plan they
     route Q40 weights to the shard_map-wrapped Pallas kernel
     (quant_matmul_sharded); single-device Q40 dispatches the plain kernel.
-    Override with DLLAMA_TPU_QUANT_KERNEL=auto|pallas|fused|xla (``fused``
-    = the decode-shaped fused dequant-GEMV; the ONE resolution rule is
-    quant_matmul.pallas_mode_gate); unsupported shapes fall back to XLA
-    dequant+dot with identical f32 dequant values.
+    DLLAMA_TPU_QUANT_KERNEL=auto|pallas|fused|xla; the ONE resolution rule
+    is quant_matmul.pallas_mode_gate. On a TPU ``auto`` means: exact (f32)
+    graphs take the tiled kernel; fast (bf16) graphs take the fused
+    dequant-GEMV for a decode-shaped dispatch (1..16 flattened rows, a 2-D
+    plane pair, no plan) and the XLA dequant + dot for everything else, a
+    prefill chunk included. A :class:`LayerSlice` hands that kernel the
+    layer stack and an index; unsupported shapes fall back to XLA
+    dequant + dot with identical dequant values. Each Q40 dispatch notes
+    the path it took (``fused`` / ``tiled`` / ``xla``) for the program
+    being traced (runtime.introspection.note_q40_path).
     """
     out_dtype = x.dtype
     from .turbo import TurboWeight, turbo_matmul  # lazy: turbo imports us
@@ -213,8 +274,14 @@ def linear(x: jax.Array, w: Weight, *, out_axis: str | None = None,
         # a8/a16 rides on the weight (fixed at derivation) — the ambient env
         # cannot silently flip serving numerics after load
         return turbo_matmul(x, w).astype(out_dtype)
+    if isinstance(w, LayerSlice):
+        y = _layer_slice_fused(x, w)
+        if y is not None:
+            return y
+        w = w.take()
     if isinstance(w, QuantizedWeight):
         from ..parallel.api import current_plan
+        from ..runtime.introspection import note_q40_path
 
         # the stored scale dtype wins over the ambient env: bf16 scales were
         # written by a fast-mode load, and an "exact" f32 dequant over them
@@ -223,13 +290,20 @@ def linear(x: jax.Array, w: Weight, *, out_axis: str | None = None,
         if current_plan() is not None and (out_axis or in_axis):
             y = _pallas_sharded(x, w, out_axis, in_axis, fast)
             if y is not None:
+                # what the gate asked for: a shard too wide for the decode
+                # kernel runs tiled inside quant_matmul_sharded
+                note_q40_path("fused" if _kernel_mode() == "fused"
+                              else "tiled")
                 return y.astype(x.dtype)
         else:
             kernel_kw = _pallas_wanted(x, w, fast)
             if kernel_kw is not None:
                 from .quant_matmul import quant_matmul
 
+                note_q40_path("fused" if _takes_decode_kernel(
+                    kernel_kw, x, w, fast) else "tiled")
                 return quant_matmul(x, w, fast=fast, **kernel_kw)
+        note_q40_path("xla")
         # XLA fallback: in fast mode the dense dequant lands in bf16 (half the
         # HBM traffic of f32) and the dot takes one MXU pass; exact mode
         # dequantizes at the activation dtype as before

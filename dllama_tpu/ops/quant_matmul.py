@@ -37,7 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..parallel.api import on_tpu, shard_map
+from ..parallel.api import current_plan, on_tpu, shard_map
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -97,19 +97,28 @@ BN_CANDIDATES = (512, 256, 128)
 BK_CANDIDATES = (512, 256, 128)
 
 
-def _decode_kernel(x_ref, codes_ref, scales_ref, expand_ref, out_ref, wd_ref,
-                   *, bk_e: int, fast: bool):
+def _decode_kernel(x_ref, codes_ref, scales_ref, out_ref, wd_ref, s32_ref,
+                   *, groups: int, fast: bool):
     """One n-column stripe of the DECODE-shaped fused dequant-GEMV.
 
     Unlike :func:`_kernel`'s (n, k) grid, the decode kernel keeps the whole
     K axis in one block: the grid walks N only, each step streams the full
-    ``[K, bn]`` code stripe from HBM once, dequantizes it in-register into
-    the ``wd`` VMEM scratch (chunked scale expansion — the ``[K, K/32]``
-    expansion matrix of the full-K trick would itself be MBs), and runs ONE
-    dot over the whole contraction. No revisited output tile, no k-step
-    read-modify-write: the kernel is a single pass over the weight planes,
-    which is exactly the decode regime's byte budget (weights dominate; the
-    T<=16 activation rides along in VMEM).
+    ``[K, bn]`` code stripe from HBM once, dequantizes it into the ``wd``
+    VMEM scratch and runs ONE dot over the whole contraction. No revisited
+    output tile, no k-step read-modify-write: the kernel is a single pass
+    over the weight planes, which is exactly the decode regime's byte
+    budget (weights dominate; the T<=16 activation rides along in VMEM).
+
+    The dequant is VPU work, one Q40 block of 32 rows at a time: an int8
+    tile is 32 sublanes, so a block's codes are whole tiles and its scale
+    row broadcasts across them. (Until PR 28 the scales were expanded by a
+    0/1 matmul at HIGHEST precision, which costs the MXU seven times what
+    the dot itself costs at <= 16 rows: 125 GB/s on a v5e where this reads
+    590-710, tools/gemv_sweep.py and PERF.md.) The values are the old
+    ones bit for bit: a code is an integer in [-8, 7] (Q80: [-127, 127])
+    and the scale is first rounded to the dequant dtype, so their f32
+    product is exact and rounds once, to what a multiply at that dtype
+    gives.
 
     The single full-K dot is also what makes the kernel bit-parity with the
     XLA fused-dequant reference (ops.linear's dequant+dot fallback) instead
@@ -122,30 +131,39 @@ def _decode_kernel(x_ref, codes_ref, scales_ref, expand_ref, out_ref, wd_ref,
     dequant, one default-precision MXU pass, f32 accumulation —
     drift-bounded for the same reason.
     """
-    K = codes_ref.shape[0]
-    g = bk_e // Q40_BLOCK_SIZE
-    # chunked scale expansion: static python loop (K//bk_e is trace-time),
-    # each chunk element-repeats its scale rows 32x via the 0/1 matmul and
-    # lands the dequantized stripe in the wd scratch
     wd_dt = wd_ref.dtype  # bf16 in fast mode, the activation dtype in exact
-    for i in range(K // bk_e):
-        sexp = jax.lax.dot_general(
-            expand_ref[:], scales_ref[i * g:(i + 1) * g, :],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_HIGHEST)
-        codes = codes_ref[i * bk_e:(i + 1) * bk_e, :]
-        wd_ref[i * bk_e:(i + 1) * bk_e, :] = (codes.astype(wd_dt)
-                                              * sexp.astype(wd_dt))
-    if fast:
-        out_ref[:] = jax.lax.dot_general(
-            x_ref[:], wd_ref[:],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    else:
-        out_ref[:] = jax.lax.dot_general(
-            x_ref[:], wd_ref[:],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_HIGHEST)
+    # scales widen here, not in HBM (a fast-mode load stores them bf16, and
+    # the stack entry cannot afford a cast of all L layers per call); f32
+    # rows are what a dynamic sublane index can address
+    s32_ref[...] = scales_ref[...].astype(wd_dt).astype(jnp.float32)
+
+    def dequant(c, carry):
+        # `groups` Q40 blocks a trip, unrolled: independent loads, converts
+        # and stores for the scheduler to overlap
+        for j in range(groups):
+            g = c * groups + j
+            k0 = pl.multiple_of(g * Q40_BLOCK_SIZE, Q40_BLOCK_SIZE)
+            rows = pl.ds(k0, Q40_BLOCK_SIZE)
+            wd_ref[rows, :] = (codes_ref[rows, :].astype(jnp.float32)
+                               * s32_ref[pl.ds(g, 1), :]).astype(wd_dt)
+        return carry
+
+    n_blocks = codes_ref.shape[0] // Q40_BLOCK_SIZE
+    jax.lax.fori_loop(0, n_blocks // groups, dequant, 0)
+    out_ref[...] = jax.lax.dot_general(
+        x_ref[...], wd_ref[...],
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=None if fast else _HIGHEST)
+
+
+def _decode_kernel_at(layer_ref, *refs, groups: int, fast: bool):
+    """:func:`_decode_kernel` behind a scalar-prefetch operand: the layer
+    index is spent in the BlockSpec index maps (the stripe of layer ``l``
+    is DMA'd straight out of the ``[L, K, N]`` stack), the body is the
+    same."""
+    del layer_ref
+    _decode_kernel(*refs, groups=groups, fast=fast)
 
 
 # Widest dispatch that counts as the decode regime for the fused kernel:
@@ -153,31 +171,34 @@ def _decode_kernel(x_ref, codes_ref, scales_ref, expand_ref, out_ref, wd_ref,
 # (T=K+1, small) — the same rule as models.llama._OVERLAP_MAX_WIDTH.
 FUSED_MAX_M = 16
 
-# VMEM budget for the decode kernel's resident set: wd scratch + the
-# double-buffered code stripe + the full-K activation block must leave
-# room for Mosaic's own pipelining (~16MB/core total).
-_FUSED_VMEM_BUDGET = 10 * 1024 * 1024
+# VMEM the decode kernel asks Mosaic for (the default scoped limit is 16 MB
+# of a v5e's 128), and what its resident set may take of that: the wd
+# scratch, the double-buffered code and scale stripes, the widened scales
+# and the full-K activation block, with room left for Mosaic's own.
+_FUSED_VMEM_LIMIT = 32 * 1024 * 1024
+_FUSED_VMEM_BUDGET = 20 * 1024 * 1024
 
 
 def _decode_blocks(M: int, K: int, N: int,
                    fast: bool) -> tuple[int, int] | None:
-    """``(bn, bk_e)`` for the decode kernel, or None when the shape doesn't
-    fit: bn is the largest 128-multiple (or whole-N, >=8-aligned) dividing N
-    whose resident set fits the VMEM budget; bk_e the largest expansion
-    chunk dividing K."""
+    """``(bn, groups)`` for the decode kernel, or None when the shape
+    doesn't fit: bn is the largest 128-multiple (or whole-N, >=8-aligned)
+    dividing N whose resident set fits the VMEM budget (on the chip 256 and
+    512 read alike and 1024 reads worse: PERF.md, PR 28); groups the Q40
+    blocks dequantized per loop trip."""
     if not (0 < M <= FUSED_MAX_M) or K % Q40_BLOCK_SIZE:
         return None
-    bk_e = next((c for c in (512, 256, 128, 64, 32) if K % c == 0), None)
-    if bk_e is None:
-        return None
+    kb = K // Q40_BLOCK_SIZE
+    groups = next(c for c in (8, 4, 2, 1) if kb % c == 0)
     wd_bytes = 2 if fast else 4
     x_bytes = M * K * (2 if fast else 4)
     for bn in BN_CANDIDATES + ((N,) if N % 8 == 0 else ()):
         if N % bn:
             continue
-        resident = K * bn * (wd_bytes + 2) + x_bytes  # wd + 2x codes + x
+        # wd + 2x codes, and per scale row: 2x stored (<= f32) + widened
+        resident = K * bn * (wd_bytes + 2) + kb * bn * 12 + x_bytes
         if resident <= _FUSED_VMEM_BUDGET:
-            return bn, bk_e
+            return bn, groups
     return None
 
 
@@ -194,34 +215,55 @@ def supports_decode(x_shape: tuple[int, ...], w: QuantizedWeight,
 
 
 def _decode_call(xf: jax.Array, w: QuantizedWeight, *, interpret: bool,
-                 fast: bool) -> jax.Array:
+                 fast: bool, layer: jax.Array | None = None) -> jax.Array:
     """Dispatch the decode kernel over ``xf [M, K]`` (already cast).
 
     Exact mode dequantizes at the ACTIVATION dtype — the same rule as the
     XLA reference (``dequantize_weight(w, dtype=x.dtype)``), so an
     exact-mode bf16 graph gets bf16 dequant on both paths instead of the
-    kernel silently upgrading to f32 and breaking xla↔fused identity."""
+    kernel silently upgrading to f32 and breaking xla↔fused identity.
+
+    With ``layer`` (an int32 scalar, traced) ``w`` is the LAYER STACK —
+    codes ``[L, K, N]``, scales ``[L, K/32, N]`` — and the index rides in
+    as a scalar-prefetch operand: the index maps pick ``(layer, 0, n)``, so
+    the stripes stream out of the stack where it lies. Handing the kernel
+    ``stack[layer]`` instead makes XLA materialize a copy of both planes
+    in front of every custom call (a scan's ``xs`` slice is the same
+    copy), which costs what the fusion saved (PERF.md, PR 26 / PR 28)."""
     M, K = xf.shape
     N = w.out_features
-    bn, bk_e = _decode_blocks(M, K, N, fast)
+    bn, groups = _decode_blocks(M, K, N, fast)
     wd_dtype = jnp.bfloat16 if fast else xf.dtype
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, bk_e=bk_e, fast=fast),
+    kb = K // Q40_BLOCK_SIZE
+    vmem = pltpu.VMEM
+    if layer is None:
+        kernel, prefetch, lead = _decode_kernel, (), ()
+        plane = lambda n, *_: (0, n)
+    else:
+        kernel = _decode_kernel_at
+        prefetch, lead = (jnp.reshape(layer, (1,)).astype(jnp.int32),), (None,)
+        plane = lambda n, l: (l[0], 0, n)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
         grid=(N // bn,),
         in_specs=[
-            pl.BlockSpec((M, K), lambda n: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((K, bn), lambda n: (0, n), memory_space=pltpu.VMEM),
-            pl.BlockSpec((K // Q40_BLOCK_SIZE, bn), lambda n: (0, n),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((bk_e, bk_e // Q40_BLOCK_SIZE), lambda n: (0, 0),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((M, K), lambda n, *_: (0, 0), memory_space=vmem),
+            pl.BlockSpec(lead + (K, bn), plane, memory_space=vmem),
+            pl.BlockSpec(lead + (kb, bn), plane, memory_space=vmem),
         ],
-        out_specs=pl.BlockSpec((M, bn), lambda n: (0, n),
-                               memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((M, bn), lambda n, *_: (0, n),
+                               memory_space=vmem),
+        scratch_shapes=[pltpu.VMEM((K, bn), wd_dtype),
+                        pltpu.VMEM((kb, bn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, groups=groups, fast=fast),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((K, bn), wd_dtype)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_FUSED_VMEM_LIMIT),
         interpret=interpret,
-    )(xf, w.codes, w.scales.astype(jnp.float32), _expansion_matrix(bk_e))
+    )(*prefetch, xf, w.codes, w.scales)
 
 
 def _pick_block(dim: int, candidates: tuple[int, ...], min_align: int) -> int | None:
@@ -253,7 +295,8 @@ def _expansion_matrix(bk: int) -> np.ndarray:
                    static_argnames=("interpret", "fast", "bn", "bk", "fused"))
 def quant_matmul(x: jax.Array, w: QuantizedWeight, *, interpret: bool = False,
                  fast: bool = False, bn: int | None = None,
-                 bk: int | None = None, fused: bool = False) -> jax.Array:
+                 bk: int | None = None, fused: bool = False,
+                 layer: jax.Array | None = None) -> jax.Array:
     """``y[..., N] = x[..., K] @ dequant(w)`` via the Pallas kernel.
 
     ``fast=False``: ``x`` is cast to f32 for the dequantized dot (parity with
@@ -264,7 +307,11 @@ def quant_matmul(x: jax.Array, w: QuantizedWeight, *, interpret: bool = False,
     (:func:`_decode_kernel` — bit-parity with the XLA fused-dequant
     reference) when :func:`supports_decode` holds, falling back to the
     (n, k)-tiled kernel otherwise, so a ``fused``-mode dispatch never
-    fails on a prefill-wide shape.
+    fails on a prefill-wide shape. ``layer`` (an int32 scalar) says that
+    ``w`` is the layer STACK, leading axis = layer, and picks one: the
+    decode kernel's stack-and-index entry (:func:`_decode_call`), for
+    callers that checked :func:`supports_decode` on one layer's shapes —
+    there is no tiled twin, so anything else raises.
     """
     *lead, K = x.shape
     N = w.out_features
@@ -272,14 +319,18 @@ def quant_matmul(x: jax.Array, w: QuantizedWeight, *, interpret: bool = False,
     for d in lead:
         M *= d
 
-    if fused and bn is None and bk is None \
-            and _decode_blocks(M, K, N, fast) is not None:
+    decode_fits = (fused and bn is None and bk is None
+                   and _decode_blocks(M, K, N, fast) is not None)
+    if layer is not None and not decode_fits:
+        raise ValueError(f"the layer-stack entry is the decode kernel's: "
+                         f"x {x.shape}, stack {w.codes.shape} do not fit it")
+    if decode_fits:
         # fast casts to bf16; exact keeps the activation dtype (the XLA
         # reference dequantizes at x.dtype — see _decode_call)
         xf = x.reshape(M, K)
         if fast:
             xf = xf.astype(jnp.bfloat16)
-        out = _decode_call(xf, w, interpret=interpret, fast=fast)
+        out = _decode_call(xf, w, interpret=interpret, fast=fast, layer=layer)
         return out.reshape(*lead, N).astype(x.dtype)
 
     bn = bn or _pick_block(N, BN_CANDIDATES, min_align=8)
@@ -405,20 +456,38 @@ def quant_matmul_sharded(plan, x: jax.Array, w: QuantizedWeight,
     return fn(x, w.scales, w.codes)
 
 
-def pallas_mode_gate(fast: bool) -> dict | None:  # dlint: static-fn
-    """The ONE mode/numerics gate for every Pallas kernel dispatch:
-    ``DLLAMA_TPU_QUANT_KERNEL`` = ``auto`` (Pallas only for exact mode on
-    TPU), ``pallas`` (force the tiled kernel; interpret mode off-TPU, the
-    test path), ``fused`` (force the decode-shaped fused dequant-GEMV —
-    the built-but-unpromoted serving candidate, à la turbo: never resolved
-    from ``auto``), or ``xla`` (the fused-dequant XLA reference, also the
-    kill switch for every kernel this gate guards). Returns the
-    :func:`quant_matmul` kwargs (``interpret``, optionally ``fused``) or
-    None. Consulted by ops.linear's single-device and sharded dispatch,
-    the overlapped merge's :func:`pallas_local_choice`, the ragged paged
-    attention entry (ops.paged_attention.kernel_choice), and the engine's
-    wire pricing — one rule, so none of them can drift from what
-    linear() dispatches (dlint rule ``pallas-gate`` machine-checks the
+# dlint: static-fn (env/platform/shape gate; w may carry ShapeDtypeStruct leaves)
+def pallas_mode_gate(fast: bool, x_shape: tuple[int, ...] | None = None,
+                     w: QuantizedWeight | None = None) -> dict | None:
+    """The ONE mode/numerics gate for every Pallas kernel dispatch.
+    ``DLLAMA_TPU_QUANT_KERNEL`` = ``xla`` (the XLA dequant + dot reference,
+    also the kill switch for every kernel this gate guards), ``pallas``
+    (force the tiled kernel; interpret mode off-TPU, the test path),
+    ``fused`` (force the decode-shaped fused dequant-GEMV where it fits,
+    the tiled kernel where it does not), or ``auto``, resolved from what
+    the dispatch shows:
+
+    * off a TPU: no kernel.
+    * exact mode (f32 graphs, the goldens): the tiled kernel, whose
+      HIGHEST-precision dots match the host oracle.
+    * fast mode (bf16 graphs, serving): the fused dequant-GEMV for a
+      decode-shaped dispatch — ``x_shape`` flattens to 1..``FUSED_MAX_M``
+      rows, ``w`` is ONE 2-D Q40 plane pair whose stripe fits VMEM
+      (:func:`supports_decode`) and no mesh plan is active — and NO kernel
+      for anything else: a prefill chunk keeps the XLA dequant + dot and
+      never lands on the tiled kernel (130 GB/s against XLA's 450-750,
+      tools/gemv_sweep.py). There is no lower row bound: on the chip the
+      kernel beats XLA's dequant-then-dot at every M from 1 to 16 (the
+      sweep's table, PERF.md section 6, PR 28). Callers that pass no
+      shape (the sharded entry, the overlapped merge, wire pricing: all
+      under a plan) resolve as before: no kernel in fast mode.
+
+    Returns the :func:`quant_matmul` kwargs (``interpret``, optionally
+    ``fused``) or None. Consulted by ops.linear's single-device and sharded
+    dispatch, the overlapped merge's :func:`pallas_local_choice`, the
+    ragged paged attention entry (ops.paged_attention.kernel_choice), and
+    the engine's wire pricing — one rule, so none of them can drift from
+    what linear() dispatches (dlint rule ``pallas-gate`` machine-checks the
     routing)."""
     from .linear import _kernel_mode  # lazy: linear imports us
 
@@ -427,9 +496,16 @@ def pallas_mode_gate(fast: bool) -> dict | None:  # dlint: static-fn
         return None
     if mode == "fused":
         return {"interpret": not on_tpu(), "fused": True}
-    if mode != "pallas" and (fast or not on_tpu()):
+    if mode == "pallas":
+        return {"interpret": not on_tpu()}
+    if not on_tpu():
         return None
-    return {"interpret": mode == "pallas" and not on_tpu()}
+    if not fast:
+        return {"interpret": False}
+    if (x_shape is not None and w is not None and current_plan() is None
+            and supports_decode(tuple(x_shape), w, True)):
+        return {"interpret": False, "fused": True}
+    return None
 
 
 def wants_fused(kw: dict | None) -> bool:  # dlint: static-fn

@@ -226,7 +226,7 @@ class CompileLedger:
                          "hits": 0, "warns": 0, "last_sig": None,
                          "last_plan": None, "last_compile_s": 0.0,
                          "total_compile_s": 0.0, "analysis": None,
-                         "unexpected": 0}
+                         "unexpected": 0, "q40_paths": None}
                 self._programs[(scope, program)] = entry
             return entry
 
@@ -263,6 +263,27 @@ class CompileLedger:
         return out
 
     # -- miss/hit recording (ObservedJit) ------------------------------------
+
+    def note_q40_paths(self, entry: dict, paths: dict | None) -> None:
+        """File the per-path Q40 matmul counts of a trace of ``entry``'s
+        program (:func:`note_q40_path`) and publish them. A call that did
+        not trace (a cached jaxpr) brings None and changes nothing."""
+        if not paths:
+            return
+        with self._lock:
+            entry["q40_paths"] = dict(paths)
+        g = telemetry.registry().gauge(telemetry.Q40_MATMUL_PATHS)
+        for path, n in paths.items():
+            g.set(n, scope=entry["scope"], program=entry["program"],
+                  path=path)
+
+    def q40_paths(self, scope: str) -> dict[str, dict[str, int]]:
+        """``{program: {path: count}}`` for the programs of ``scope`` whose
+        newest trace held a Q40 matmul."""
+        with self._lock:
+            return {program: dict(entry["q40_paths"])
+                    for (sc, program), entry in sorted(self._programs.items())
+                    if sc == scope and entry.get("q40_paths")}
 
     def record(self, entry: dict, compile_s: float, signature: dict,
                plan: str, analysis: dict | None, *,
@@ -380,6 +401,21 @@ def _event_listener(name: str, duration_s: float, **_kw) -> None:
         win["n_trace"] += 1
 
 
+Q40_PATHS = ("fused", "tiled", "xla")
+
+
+def note_q40_path(path: str) -> None:
+    """``ops.linear`` calls this while a Q40 matmul is traced, with the path
+    it gave it (one of :data:`Q40_PATHS`). The count goes to the program
+    whose :class:`ObservedJit` is tracing on this thread; outside one it is
+    dropped. A layer scan's body is traced once, so its matmuls count once
+    (as :func:`mosaic_kernels` counts a kernel in a scan's body once)."""
+    win = getattr(_tls, "window", None)
+    if win is not None:
+        paths = win.setdefault("q40", dict.fromkeys(Q40_PATHS, 0))
+        paths[path] += 1
+
+
 def _monitoring_on() -> bool:
     if not _monitoring_state:
         try:
@@ -390,6 +426,12 @@ def _monitoring_on() -> bool:
         except Exception:  # noqa: BLE001 — degrade to pass-through, no ledger
             _monitoring_state.append(False)
     return _monitoring_state[0]
+
+
+def _new_window() -> dict:
+    """What one traced call gathers on its thread: the monitoring events'
+    counts, and (``q40``, once a matmul is noted) the Q40 path counts."""
+    return {"backend_s": 0.0, "n_backend": 0, "n_trace": 0}
 
 
 class ObservedJit:
@@ -410,8 +452,7 @@ class ObservedJit:
         if not self._observed:
             return self._jitted(*args, **kwargs)
         prev = getattr(_tls, "window", None)
-        win = {"backend_s": 0.0, "n_backend": 0, "n_trace": 0}
-        _tls.window = win
+        win = _tls.window = _new_window()
         t0 = time.perf_counter()
         try:
             out = self._jitted(*args, **kwargs)
@@ -436,12 +477,21 @@ class ObservedJit:
         except Exception as e:  # noqa: BLE001 — never break the dispatch
             analysis = {"error": f"{type(e).__name__}: {e}"}
             sig = {}
+        _ledger.note_q40_paths(self._entry, win.get("q40"))
         _ledger.record(self._entry, compile_s, sig, _plan_desc(), analysis,
                        backend_s=win["backend_s"])
         return out
 
     def lower(self, *args, **kwargs):
-        return self._jitted.lower(*args, **kwargs)
+        # an AOT lowering traces too (the start-up report's programs): give
+        # note_q40_path a window, and keep it out of a dispatch's own
+        prev = getattr(_tls, "window", None)
+        win = _tls.window = _new_window()
+        try:
+            return self._jitted.lower(*args, **kwargs)
+        finally:
+            _tls.window = prev
+            _ledger.note_q40_paths(self._entry, win.get("q40"))
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)
@@ -482,6 +532,24 @@ def startup_line(engine) -> str:
             + ", ".join(f"{k} {v:.2f}" for k, v in parts.items()) + ")")
 
 
+def q40_paths_line(scope: str) -> str:
+    """Which path ``ops.linear`` gave the Q40 matmuls of each program traced
+    so far in ``scope`` (:func:`note_q40_path`): the line that says whether
+    the fused dequant-GEMV engaged. Empty before any such trace."""
+    by_program = _ledger.q40_paths(scope)
+    if not by_program:
+        return ""
+    return "🧮 q40 matmuls: " + "; ".join(
+        f"{program} " + " / ".join(f"{n[p]} {p}" for p in Q40_PATHS)
+        for program, n in by_program.items())
+
+
+def _emit_q40_paths(scope: str, emit) -> None:
+    line = q40_paths_line(scope)
+    if line:
+        emit(line)
+
+
 def compile_report(scope: str, emit=print) -> None:
     """One line per program the ledger saw compile in ``scope``: wall and
     XLA-backend seconds (backend 0 = the persistent cache served it) and,
@@ -501,6 +569,7 @@ def compile_report(scope: str, emit=print) -> None:
              + ("" if kern is None else ", Pallas kernels: "
                 + (" ".join(f"{k}x{n}" for k, n in sorted(kern.items()))
                    or "none")))
+    _emit_q40_paths(scope, emit)
 
 
 def hbm_startup_report(engine, emit=print) -> dict:
@@ -543,6 +612,7 @@ def hbm_startup_report(engine, emit=print) -> dict:
              f"output {_gb(hbm.get('output', 0))}, "
              f"args {_gb(hbm.get('argument', 0))}"
              + (f", {flops:.3g} flops/dispatch" if flops else ""))
+    _emit_q40_paths(scope, emit)
     actual = est["weights_bytes"] + est["kv_bytes"]
     actual = actual // max(1, report["n_shards"]) + max_temp
     report["actual_floor_bytes"] = actual

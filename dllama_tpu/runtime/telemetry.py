@@ -184,6 +184,7 @@ COMPILE_TOTAL = "dllama_compile_total"
 COMPILE_SECONDS = "dllama_compile_seconds"
 PROGRAM_HBM_BYTES = "dllama_program_hbm_bytes"
 PROGRAM_FLOPS = "dllama_program_flops"
+Q40_MATMUL_PATHS = "dllama_q40_matmul_paths"
 RETRACE_UNEXPECTED = "dllama_retrace_unexpected_total"
 
 # latency buckets in ms: sub-ms CPU ticks through multi-second TPU compiles
@@ -425,6 +426,10 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "alias) from compiled.memory_analysis()"),
     _spec(PROGRAM_FLOPS, "gauge",
           "Per-program FLOPs per dispatch from compiled.cost_analysis()"),
+    _spec(Q40_MATMUL_PATHS, "gauge",
+          "Q40 matmuls of a program's newest trace by the path linear() "
+          "gave them: fused (the decode dequant-GEMV kernel), tiled (the "
+          "(n, k)-tiled Pallas kernel) or xla (dequant + dot)"),
     _spec(RETRACE_UNEXPECTED, "counter",
           "Recompiles observed AFTER an engine scope reached serving "
           "steady state (each is a latency cliff; the shape/plan diff is "

@@ -113,6 +113,57 @@ def test_quant_matmul_compiles_for_v5e(one_chip, k, n, rows, fast, fused):
     assert kernels.get("quant_matmul") == 1, kernels
 
 
+# the benchmark's two configurations (benchmark/configs): every Q40 plane
+# of a layer, [K, N], and the quantized head of each
+MISTRAL_7B = [(4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096)]
+QWEN3_4B = [(2560, 4096), (2560, 1024), (4096, 2560), (2560, 9728),
+            (9728, 2560)]
+HEADS = [(4096, 32768), (2560, 151936)]
+
+
+@pytest.mark.parametrize("rows", [4, 16])
+@pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B)
+def test_decode_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
+    """The fused dequant-GEMV as the paged step calls it in fast mode: bf16
+    rows, bf16 scales as a fast-mode load stores them, the LAYER STACK and
+    a traced index (scalar prefetch), at long-prompt's 4 rows and
+    batch-decode's 16."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+    from dllama_tpu.ops.quant_matmul import quant_matmul, supports_decode
+
+    L = 4
+    stack = QuantizedWeight(
+        scales=_shape(one_chip, (L, k // 32, n), jnp.bfloat16),
+        codes=_shape(one_chip, (L, k, n), jnp.int8))
+    one = QuantizedWeight(*(jax.ShapeDtypeStruct(p.shape[1:], p.dtype)
+                            for p in stack))
+    x = _shape(one_chip, (rows, 1, k), jnp.bfloat16)
+    assert supports_decode(x.shape, one, True)
+    kernels = _compiled_kernels(
+        lambda x, w, l: quant_matmul(x, w, interpret=False, fast=True,
+                                     fused=True, layer=l),
+        x, stack, _shape(one_chip, (), jnp.int32))
+    assert kernels.get("quant_matmul") == 1, kernels
+
+
+@pytest.mark.parametrize("rows", [4, 16])
+@pytest.mark.parametrize("k,n", HEADS)
+def test_decode_kernel_compiles_at_the_heads_for_v5e(one_chip, k, n, rows):
+    """The two vocabularies as one 2-D plane pair (a head quantized by
+    DLLAMA_TPU_DENSE_LOGITS=off; fast mode's default head is dense)."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+    from dllama_tpu.ops.quant_matmul import quant_matmul, supports_decode
+
+    w = QuantizedWeight(scales=_shape(one_chip, (k // 32, n), jnp.bfloat16),
+                        codes=_shape(one_chip, (k, n), jnp.int8))
+    x = _shape(one_chip, (rows, 1, k), jnp.bfloat16)
+    assert supports_decode(x.shape, w, True)
+    kernels = _compiled_kernels(
+        functools.partial(quant_matmul, interpret=False, fast=True,
+                          fused=True), x, w)
+    assert kernels.get("quant_matmul") == 1, kernels
+
+
 def test_tiled_kernel_declines_the_tp4_logits_shard():
     """128256 / 4 = 32064 = 64 x 501 has no 128-aligned divisor. Taken as one
     whole-N block it needs 134 MB of VMEM and the chip's compiler refuses the

@@ -97,6 +97,52 @@ def test_ledger_records_compiles_hits_and_analysis(model_files):
         led.analyze = prev_analyze
 
 
+def test_q40_path_counts_per_program_line_and_gauge(model_files, capsys):
+    """linear() notes the path of every Q40 matmul it traces; the ledger
+    files the counts under the program that was tracing, prints them on one
+    line (start-up report, compile report) and exports them as
+    ``dllama_q40_matmul_paths{scope,program,path}``. Off a TPU ``auto`` is
+    the XLA path for all seven planes of the scanned layer and the head."""
+    e = InferenceEngine(model_files[0], model_files[1], temperature=0.0,
+                        seed=3, tp=1)
+    scope = e.introspection_scope
+    assert introspection.q40_paths_line(scope) == ""   # nothing traced yet
+    e.generate("hello world", 3, stop_on_eos=False)
+    paths = introspection.ledger().q40_paths(scope)
+    assert paths["greedy_step"] == {"fused": 0, "tiled": 0, "xla": 8}
+    assert paths["forward"] == {"fused": 0, "tiled": 0, "xla": 8}
+    line = introspection.q40_paths_line(scope)
+    assert line.startswith("🧮 q40 matmuls: ")
+    assert "greedy_step 0 fused / 0 tiled / 8 xla" in line
+    g = telemetry.registry().gauge(telemetry.Q40_MATMUL_PATHS)
+    assert g.value(scope=scope, program="greedy_step", path="xla") == 8
+    assert g.value(scope=scope, program="greedy_step", path="fused") == 0
+    snap = {p["program"]: p for p in introspection.ledger().snapshot()["programs"]
+            if p["scope"] == scope}
+    assert snap["greedy_step"]["q40_paths"]["xla"] == 8   # /debug/compiles
+    introspection.compile_report(scope)
+    assert line in capsys.readouterr().out
+    # a dispatch that hits the executable cache traces nothing and must not
+    # wipe the counts; outside any program a note is dropped
+    e.generate("hello again", 2, stop_on_eos=False)
+    introspection.note_q40_path("fused")
+    assert introspection.ledger().q40_paths(scope)["greedy_step"]["xla"] == 8
+    e.close()
+
+
+def test_aot_lowering_counts_q40_paths_too(model_files):
+    """The start-up report AOT-lowers its programs (``ObservedJit.lower``):
+    that trace is counted as a dispatch's would be."""
+    e = InferenceEngine(model_files[0], model_files[1], temperature=0.0,
+                        seed=3, tp=1)
+    out = []
+    introspection.hbm_startup_report(e, emit=out.append)
+    line = introspection.q40_paths_line(e.introspection_scope)
+    assert "greedy_step 0 fused / 0 tiled / 8 xla" in line
+    assert line in out
+    e.close()
+
+
 def test_retrace_sentinel_fires_after_steady(model_files, capsys):
     led = introspection.ledger()
     reg = telemetry.registry()

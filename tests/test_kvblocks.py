@@ -602,6 +602,117 @@ def test_paged_forward_matches_dense_forward_bitwise():
     np.testing.assert_array_equal(k_p[:, :, :T], k_d[:, :, :T])
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["distinct", "one-null-row", "null-rows",
+                                  "all-null"])
+def test_rowwise_kv_writes_equal_the_scatter_cell_for_cell(case, dtype):
+    """The decode step (T == 1) writes its new K/V rows one
+    ``dynamic_update_slice`` a row; wider dispatches scatter. Same cells,
+    same bytes, every other cell untouched — rows parked on the null block
+    included (several of them at one offset: the last row wins either way,
+    on a pool nobody reads through)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dllama_tpu.models.llama import _write_kv_rows
+
+    rng = np.random.default_rng(11)
+    n_blocks, n_kv, bs, hd, B = 9, 2, 16, 8, 5
+    pool = jnp.asarray(rng.standard_normal((n_blocks, n_kv, bs, hd)), dtype)
+    new = jnp.asarray(rng.standard_normal((B, 1, n_kv, hd)), jnp.float32)
+    blk = np.asarray([[3], [7], [1], [8], [5]], np.int32)
+    off = np.asarray([[0], [15], [4], [4], [9]], np.int32)
+    if case == "one-null-row":
+        blk[2, 0], off[2, 0] = 0, 0
+    elif case == "null-rows":        # inactive rows: null block, offset 0
+        blk[1:4, 0], off[1:4, 0] = 0, 0
+    elif case == "all-null":
+        blk[:], off[:] = 0, 0
+    got = jax.jit(_write_kv_rows)(pool, new, jnp.asarray(blk), jnp.asarray(off))
+    want = pool.at[jnp.asarray(blk), :, jnp.asarray(off), :].set(
+        new.astype(pool.dtype))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    # and against plain numpy, row after row
+    ref = np.asarray(pool, np.float32).copy()
+    for b in range(B):
+        ref[blk[b, 0], :, off[b, 0], :] = np.asarray(
+            new.astype(pool.dtype), np.float32)[b, 0]
+    np.testing.assert_array_equal(np.asarray(got, np.float32), ref)
+
+
+def test_wide_kv_writes_keep_the_scatter():
+    """T > 1 (the verify step, write_lens): the scatter, as before."""
+    import jax.numpy as jnp
+
+    from dllama_tpu.models.llama import _write_kv_rows
+
+    rng = np.random.default_rng(12)
+    pool = jnp.zeros((6, 2, 16, 8), jnp.float32)
+    new = jnp.asarray(rng.standard_normal((2, 3, 2, 8)), jnp.float32)
+    blk = jnp.asarray([[1, 1, 2], [4, 0, 0]], jnp.int32)
+    off = jnp.asarray([[14, 15, 0], [3, 4, 5]], jnp.int32)
+    got = _write_kv_rows(pool, new, blk, off)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(pool.at[blk, :, off, :].set(new)))
+
+
+@pytest.mark.parametrize("mode,path", [("fused", "fused"), ("pallas", "tiled")])
+def test_paged_step_tokens_equal_the_xla_modes(monkeypatch, mode, path):
+    """The paged decode step over Q40 planes, scanned by layer index with
+    the stack closed over: under the fused kernel (stack + index entry,
+    interpret mode here) and under the tiled one (plain slices) it emits the
+    tokens the XLA mode emits, writes the same pool, and the program's
+    Q40 matmuls are all noted on the path the mode names."""
+    import jax
+    import jax.numpy as jnp
+
+    from dllama_tpu.formats.mfile import ArchType, RopeType
+    from dllama_tpu.models import ModelConfig
+    from dllama_tpu.models.llama import init_random_params, paged_forward
+
+    cfg = ModelConfig(arch=ArchType.LLAMA, dim=64, hidden_dim=96, n_layers=3,
+                      n_heads=8, n_kv_heads=2, head_dim=8, vocab_size=128,
+                      seq_len=64, norm_epsilon=1e-5, rope_theta=10000.0,
+                      rope_type=RopeType.LLAMA)
+    params = init_random_params(cfg, seed=7, quantized=True)
+    rng = np.random.default_rng(3)
+    B, M = 4, 4
+    tables = rng.permutation(np.arange(1, 1 + B * M)).reshape(B, M)
+    tables[3] = 0                                   # an inactive row
+    tables = jnp.asarray(tables.astype(np.int32))
+    pos0 = np.asarray([5, 0, 33, 0], np.int32)
+    toks0 = jnp.asarray(rng.integers(1, 127, (B, 1)).astype(np.int32))
+
+    def run(kernel_mode):
+        monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", kernel_mode)
+        scope = f"paged-step-{mode}-{kernel_mode}"
+        step = introspection.observe(
+            jax.jit(lambda p, c, t, s, kv, tb: paged_forward(p, c, t, s, kv, tb),
+                    static_argnums=1), scope=scope, program="step")
+        pkv = PagedKVCache.create(cfg, n_blocks=1 + B * M, block_size=16)
+        toks, out = toks0, []
+        for i in range(4):
+            logits, pkv = step(params, cfg, toks, jnp.asarray(pos0 + i), pkv,
+                               tables)
+            toks = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+            out.append(np.asarray(logits))
+        return out, pkv, introspection.ledger().q40_paths(scope)["step"]
+
+    want, pkv_x, paths_x = run("xla")
+    got, pkv_k, paths_k = run(mode)
+    n = 7 * 1 + 1      # the scanned body's seven planes, and the Q40 head
+    assert paths_x == {"fused": 0, "tiled": 0, "xla": n}
+    assert paths_k == {"fused": 0, "tiled": 0, "xla": 0, path: n}
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a.argmax(-1), b.argmax(-1))
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(pkv_x.k), np.asarray(pkv_k.k),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(pkv_x.v), np.asarray(pkv_k.v),
+                               rtol=1e-5, atol=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # 3. Serving acceptance (PagedGenerator / BatchScheduler)
 # ---------------------------------------------------------------------------

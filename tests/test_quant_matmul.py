@@ -361,18 +361,182 @@ def test_fused_falls_back_to_tiled_for_prefill_widths():
 
 def test_fused_mode_gate(monkeypatch):
     """DLLAMA_TPU_QUANT_KERNEL=fused resolves through pallas_mode_gate
-    (the ONE gate): fused kwargs off-TPU carry interpret=True; auto never
-    resolves to fused (a built-but-unpromoted mode, à la turbo)."""
-    from dllama_tpu.ops.quant_matmul import pallas_mode_gate
+    (the ONE gate): fused kwargs off-TPU carry interpret=True. ``auto``
+    resolves to the fused kernel from what the dispatch shows — fast mode,
+    a TPU, no plan, a decode-shaped 2-D dispatch — and to nothing off a
+    TPU (the truth table below has the rest)."""
+    from dllama_tpu.ops import quant_matmul as qm
 
     monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "fused")
     for fast in (False, True):
-        kw = pallas_mode_gate(fast)
+        kw = qm.pallas_mode_gate(fast)
         assert kw is not None and kw["fused"] is True
         assert kw["interpret"] is True  # off-TPU test path
     monkeypatch.delenv("DLLAMA_TPU_QUANT_KERNEL", raising=False)
-    kw = pallas_mode_gate(False)
-    assert kw is None or "fused" not in kw
+    w = _w_shapes(512, 256)
+    assert qm.pallas_mode_gate(True, (4, 512), w) is None   # not a TPU
+    monkeypatch.setattr(qm, "on_tpu", lambda: True)
+    assert qm.pallas_mode_gate(True, (4, 512), w) == {"interpret": False,
+                                                      "fused": True}
+    assert qm.pallas_mode_gate(True) is None    # shown no shape: no kernel
+    kw = qm.pallas_mode_gate(False, (4, 512), w)
+    assert kw == {"interpret": False}           # exact mode: tiled, as ever
+
+
+def _w_shapes(k, n, lead=(), scales=jnp.float32):
+    """A Q40 weight as shapes only (what the gates look at)."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    return QuantizedWeight(
+        scales=jax.ShapeDtypeStruct(lead + (k // 32, n), scales),
+        codes=jax.ShapeDtypeStruct(lead + (k, n), jnp.int8))
+
+
+def _gate_oracle(mode, fast, tpu, m, ndim, plan):
+    """The rule, written out again: (kernel, interpret) or None."""
+    if mode == "xla":
+        return None
+    if mode == "fused":
+        return ("fused", not tpu)
+    if mode == "pallas":
+        return ("tiled", not tpu)
+    if not tpu:
+        return None
+    if not fast:
+        return ("tiled", False)
+    if 1 <= m <= 16 and ndim == 2 and not plan:
+        return ("fused", False)
+    return None
+
+
+@pytest.mark.parametrize("m", [1, 2, 4, 8, 16, 17, 256])
+@pytest.mark.parametrize("plan", [False, True], ids=["noplan", "plan"])
+@pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "tpu"])
+@pytest.mark.parametrize("fast", [False, True], ids=["exact", "fast"])
+@pytest.mark.parametrize("mode", ["auto", "xla", "pallas", "fused"])
+def test_gate_truth_table(monkeypatch, mode, fast, tpu, plan, m):
+    """mode x fast x platform x M x 2-D/3-D weight x plan/no plan. The rows
+    that matter: fast ``auto`` on a TPU is the fused kernel at EVERY M from
+    1 to 16 (no lower bound: 2 and 4 engage like 16) and nothing at 17 or
+    256 — a prefill chunk must not land on the tiled kernel by this rule."""
+    from contextlib import nullcontext
+
+    from dllama_tpu.ops import quant_matmul as qm
+    from dllama_tpu.ops.linear import _pallas_wanted
+    from dllama_tpu.parallel.api import make_tp_mesh, use_plan
+
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", mode)
+    monkeypatch.setattr(qm, "on_tpu", lambda: tpu)
+    x = jax.ShapeDtypeStruct((m, 512), jnp.bfloat16 if fast else jnp.float32)
+    with (use_plan(make_tp_mesh(2)) if plan else nullcontext()):
+        for ndim, w in ((2, _w_shapes(512, 256)),
+                        (3, _w_shapes(512, 256, lead=(4,)))):
+            want = _gate_oracle(mode, fast, tpu, m, ndim, plan)
+            kw = qm.pallas_mode_gate(fast, x.shape, w)
+            got = None if kw is None else (
+                "fused" if qm.wants_fused(kw) else "tiled", kw["interpret"])
+            assert got == want, (ndim, kw)
+            # what linear()'s plain path makes of it: a kernel only where a
+            # kernel covers the shape, and never tiled for fast auto
+            plain = _pallas_wanted(x, w, fast)
+            if mode == "auto" and fast:
+                assert (plain is not None) == (want is not None)
+                assert plain is None or qm.wants_fused(plain)
+            if ndim == 3:
+                assert plain is None    # MoE-style stacks keep the XLA path
+
+
+def test_auto_has_no_row_floor():
+    """No constant keeps a row count between 1 and FUSED_MAX_M off the
+    kernel: the only bounds in the module are the upper one and VMEM."""
+    from dllama_tpu.ops import quant_matmul as qm
+
+    assert not hasattr(qm, "FUSED_MIN_M")
+    w = _w_shapes(4096, 14336, scales=jnp.bfloat16)
+    assert all(qm.supports_decode((m, 4096), w, True)
+               for m in range(1, qm.FUSED_MAX_M + 1))
+    assert not qm.supports_decode((qm.FUSED_MAX_M + 1, 4096), w, True)
+
+
+def _stack(n_layers, out, in_, seed):
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    ws = [_mk(out, in_, seed=seed + l) for l in range(n_layers)]
+    return ws, QuantizedWeight(scales=jnp.stack([w.scales for w in ws]),
+                               codes=jnp.stack([w.codes for w in ws]))
+
+
+@pytest.mark.parametrize("fast", [False, True], ids=["exact", "fast"])
+@pytest.mark.parametrize("m", [1, 2, 4, 16])
+def test_stack_and_index_equals_per_layer_bitwise(m, fast):
+    """The decode kernel handed the layer stack and an index computes what
+    it computes handed that layer's planes: bitwise, for every index, with
+    f32 and with bf16 (fast-load) scales."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    ws, stack = _stack(3, 256, 512, seed=90)
+    if fast:
+        cast = lambda w: QuantizedWeight(w.scales.astype(jnp.bfloat16), w.codes)
+        ws, stack = [cast(w) for w in ws], cast(stack)
+    x = jnp.asarray(np.random.default_rng(m).standard_normal((m, 512)),
+                    jnp.float32)
+    call = jax.jit(lambda x, s, l: quant_matmul(
+        x, s, interpret=True, fused=True, fast=fast, layer=l))
+    for l, w in enumerate(ws):
+        want = quant_matmul(x, w, interpret=True, fused=True, fast=fast)
+        got = call(x, stack, jnp.int32(l))   # traced index: one program
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert call._cache_size() == 1
+
+
+def test_stack_entry_refuses_what_the_decode_kernel_cannot_take():
+    """``layer`` has no tiled twin: a prefill-wide dispatch or a forced
+    tile raises instead of silently reading layer 0."""
+    _, stack = _stack(2, 256, 512, seed=95)
+    x = jnp.zeros((32, 512), jnp.float32)
+    with pytest.raises(ValueError, match="layer-stack entry"):
+        quant_matmul(x, stack, interpret=True, fused=True, layer=jnp.int32(1))
+    with pytest.raises(ValueError, match="layer-stack entry"):
+        quant_matmul(x[:1], stack, interpret=True, layer=jnp.int32(1))
+
+
+@pytest.mark.parametrize("mode,path", [("xla", "xla"), ("pallas", "tiled"),
+                                       ("fused", "fused")])
+def test_linear_layer_slice_matches_the_plain_slice(monkeypatch, mode, path):
+    """linear() over a LayerSlice: the fused mode reads the stack through
+    the index, every other mode takes the slice — same values as linear()
+    on that layer's own planes, and the path is noted for the program."""
+    from dllama_tpu.ops.linear import LayerSlice
+    from dllama_tpu.runtime import introspection
+
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_MODE", "exact")
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", mode)
+    ws, stack = _stack(3, 256, 512, seed=70)
+    x = jnp.asarray(np.random.default_rng(8).standard_normal((2, 2, 512)),
+                    jnp.float32)
+    scope = f"layer-slice-{mode}"
+    f = introspection.observe(
+        jax.jit(lambda x, s, l: linear(x, LayerSlice(s, l))),
+        scope=scope, program="p")
+    for l, w in enumerate(ws):
+        np.testing.assert_array_equal(
+            np.asarray(f(x, stack, jnp.int32(l))), np.asarray(linear(x, w)))
+    counts = introspection.ledger().q40_paths(scope)["p"]
+    assert counts == {"fused": 0, "tiled": 0, "xla": 0, path: 1}
+
+
+def test_layer_slice_under_a_plan_takes_the_slice(monkeypatch):
+    """The stack entry has no sharded twin: under a mesh plan a LayerSlice
+    is sliced and dispatched like any 2-D weight."""
+    from dllama_tpu.ops.linear import LayerSlice
+
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "fused")
+    ws, stack = _stack(2, 256, 512, seed=75)
+    x = _x3(1, 4, 512, seed=76)
+    with use_plan(make_tp_mesh(2)):
+        got = linear(x, LayerSlice(stack, jnp.int32(1)), out_axis="hidden")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(linear(x, ws[1])),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_fused_mode_linear_end_to_end(monkeypatch):
@@ -418,3 +582,45 @@ def test_linear_dispatches_sharded_kernel_under_plan(monkeypatch):
     with use_plan(plan):
         got = linear(x, w, out_axis="hidden")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("t,path", [(1, "fused"), (4, "fused"), (32, "tiled")])
+def test_dense_forward_scans_the_layer_index_for_decode_shapes(monkeypatch, t,
+                                                               path):
+    """``forward`` (the dense slot pool: ``inference``, ``greedy_step``)
+    walks the layer index for a decode-shaped dispatch, so its Q40 planes
+    reach linear() as stack + index and the forced fused mode reads them
+    in place; a prefill-wide dispatch scans the stack as ever (forced
+    ``fused`` then falls to the tiled kernel on the slice). Same logits and
+    cache as the XLA mode either way."""
+    from dllama_tpu.formats.mfile import ArchType, RopeType
+    from dllama_tpu.models import ModelConfig, init_random_params
+    from dllama_tpu.models.llama import forward
+    from dllama_tpu.runtime import introspection
+    from dllama_tpu.runtime.kvcache import KVCache
+
+    cfg = ModelConfig(arch=ArchType.LLAMA, dim=64, hidden_dim=96, n_layers=3,
+                      n_heads=8, n_kv_heads=2, head_dim=8, vocab_size=128,
+                      seq_len=64, norm_epsilon=1e-5, rope_theta=10000.0,
+                      rope_type=RopeType.LLAMA)
+    params = init_random_params(cfg, seed=11, quantized=True)
+    tokens = jnp.asarray(
+        np.random.default_rng(t).integers(1, 127, (1, t)).astype(np.int32))
+
+    def run(mode):
+        monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", mode)
+        scope = f"dense-forward-{t}-{mode}"
+        f = introspection.observe(
+            jax.jit(lambda p, c, tk, s, kv: forward(p, c, tk, s, kv),
+                    static_argnums=1), scope=scope, program="forward")
+        logits, kv = f(params, cfg, tokens, jnp.int32(3), KVCache.create(cfg))
+        return logits, kv, introspection.ledger().q40_paths(scope)["forward"]
+
+    want, kv_x, paths_x = run("xla")
+    got, kv_k, paths_k = run("fused")
+    assert paths_x == {"fused": 0, "tiled": 0, "xla": 8}
+    assert paths_k == {"fused": 0, "tiled": 0, "xla": 0, path: 8}
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(kv_k.k), np.asarray(kv_x.k),
+                               rtol=1e-5, atol=1e-6)

@@ -1,17 +1,17 @@
 """On-chip sweep of decode-GEMV kernel variants (honest slope timing).
 
-The decode profile (tools/profile_decode.py) shows the Q40 quant matmul
-streaming codes at ~114-130 GB/s effective against an 819 GB/s chip — the
-dominant term in the 8.4x roofline gap.  This sweep times, for the hot
-decode shapes, the production Pallas kernel at several (bn, bk) block
-choices against: the decode-shaped FUSED dequant-GEMV kernel
-(ops/quant_matmul._decode_kernel — one full-K pass per N stripe, dequant
-in-register; the DLLAMA_TPU_QUANT_KERNEL=fused candidate), the XLA
-dequant+dot fallback (f32- and bf16-stored scales), a dense bf16 matmul
-(the no-quantization reference point), a raw s8xs8 MXU dot -> s32 (rate
-bound for a w8a8 "turbo" mode), manually packed 4-bit codes unpacked on
-the VPU (halved code HBM vs shift/mask cost), and multi-row activations
-(M=8 verify / M=256 prefill-chunk shapes).
+Two sweeps over Q40 planes (int8 codes + block scales, one byte a weight on
+the device) against a v5e's 819 GB/s, reported as GB/s of quantized bytes:
+the ROW SWEEP (default; below) and, with ``--variants``, the older M = 1
+exploration that sized the kernels: the (n, k)-tiled Pallas kernel at
+several (bn, bk) block choices against the decode-shaped FUSED
+dequant-GEMV (ops/quant_matmul._decode_kernel — one full-K pass per N
+stripe, dequant in VMEM), the XLA dequant+dot fallback (f32- and
+bf16-stored scales), a dense bf16 matmul (the no-quantization reference
+point), a raw s8xs8 MXU dot -> s32 (rate bound for a w8a8 "turbo" mode),
+manually packed 4-bit codes unpacked on the VPU (halved code HBM vs
+shift/mask cost), and multi-row activations (M=8 verify / M=256
+prefill-chunk shapes).
 
 Timing methodology: the host->device round trip measured ~67 ms in the
 2026-07-31 capture and per-dispatch host enqueue ~1 ms, so sub-millisecond
@@ -23,7 +23,18 @@ from hoisting the matmul).  Wall time is taken at two iteration counts and
 the per-op cost is the SLOPE, which cancels the RTT and any fixed
 dispatch/loop overhead.
 
-Usage:  python tools/gemv_sweep.py [n_lo] [n_hi] [--json]
+Usage:  python tools/gemv_sweep.py [n_lo] [n_hi] [--json] [--variants]
+                                    [--rows 1,2,4,8,16] [--models mistral-7b,qwen3-4b]
+
+The default run is the ROW SWEEP, the evidence under ``auto``'s fast-mode
+rule (ops/quant_matmul.pallas_mode_gate; table in PERF.md): for every Q40
+plane shape of a layer of Mistral-7B-v0.3 and Qwen3-4B and every M in
+``--rows``, the XLA dequant + dot against the fused dequant-GEMV, each both
+ways a layer scan can hand it the weight: one 2-D plane pair, or a stack of
+layers — XLA slicing layer ``i % L`` out of it (what a scan's ``xs`` slice
+is; before a custom call it is a copy), the kernel taking the stack and the
+index (quant_matmul's ``layer`` entry). ``--variants`` runs the older
+M = 1 exploration instead (tile picks, packed codes, s8 x s8, ...).
 
 ``--json`` prints ONE machine-readable JSON line (same contract as
 ``tools/profile_decode.py --json``): ``{"tool": "gemv_sweep",
@@ -43,16 +54,39 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+# the Q40 planes of one layer, [K, N], at the benchmark's two configurations
+# (benchmark/configs): wq | wo, wk | wv, w1 | w3, w2 (Qwen3-4B's q width is
+# 4096 over a 2560 model dim, so its wq and wo differ)
+LAYER_SHAPES = {
+    "mistral-7b": ((4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096)),
+    "qwen3-4b": ((2560, 4096), (2560, 1024), (4096, 2560), (2560, 9728),
+                 (9728, 2560)),
+}
+STACK_LAYERS = 4  # enough that no layer's planes stay in VMEM between uses
+VARIANT_SHAPES = ((2048, 8192), (4096, 14336), (2048, 128256))  # --variants
+
+
 def main() -> None:
-    args = [a for a in sys.argv[1:] if a != "--json"]
-    as_json = "--json" in sys.argv[1:]
+    argv = sys.argv[1:]
+    opts = {}
+    for flag in ("--rows", "--models"):
+        if flag in argv:
+            i = argv.index(flag)
+            opts[flag] = argv[i + 1]
+            del argv[i:i + 2]
+    args = [a for a in argv if a not in ("--json", "--variants")]
+    as_json = "--json" in argv
+    variants = "--variants" in argv
     n_lo = int(args[0]) if len(args) > 0 else 64
     n_hi = int(args[1]) if len(args) > 1 else 448
+    sweep_rows = [int(m) for m in opts.get("--rows", "1,2,4,8,16").split(",")]
+    models = opts.get("--models", ",".join(LAYER_SHAPES)).split(",")
     import jax
     import jax.numpy as jnp
 
     from dllama_tpu.ops import quant_matmul as qm
-    from dllama_tpu.ops.linear import QuantizedWeight, dequantize_weight
+    from dllama_tpu.ops.linear import (LayerSlice, QuantizedWeight,
+                                       dequantize_weight)
 
     rows: list = []
 
@@ -75,14 +109,15 @@ def main() -> None:
 
     shape_label = [""]  # current "K=..,N=.." tag for the JSON rows
 
-    def bench(label, op, x, *wargs, bytes_moved: int):
-        """op(x, *wargs) -> y [1, N]; loop it on device, slope-time it."""
+    def bench(label, op, x, *wargs, bytes_moved: int, indexed: bool = False):
+        """op(x, *wargs) -> y [M, N]; loop it on device, slope-time it.
+        ``indexed`` ops take the iteration number first (a layer index)."""
 
-        @functools.partial(jax.jit, static_argnums=0)
+        @jax.jit
         def looped(n, x, *wargs):
             def body(i, carry):
                 x, acc = carry
-                y = op(x, *wargs)
+                y = op(i, x, *wargs) if indexed else op(x, *wargs)
                 acc = acc + jnp.sum(y, dtype=jnp.float32)
                 # perturb the activation so no iteration is hoistable; the
                 # scale keeps values finite over hundreds of iterations
@@ -117,7 +152,51 @@ def main() -> None:
             row["error"] = f"{type(e).__name__}: {str(e)[:120]}"
             return None
 
-    for K, N in ((2048, 8192), (4096, 14336), (2048, 128256)):
+    def row_sweep():
+        """XLA dequant + dot against the fused kernel, per shape and M."""
+        L = STACK_LAYERS
+        for model in models:
+            for K, N in LAYER_SHAPES[model]:
+                w = make_w(K, N)
+                w = QuantizedWeight(scales=w.scales.astype(jnp.bfloat16),
+                                    codes=w.codes)  # as a fast-mode load holds it
+                stack = QuantizedWeight(*(
+                    jnp.stack([jnp.roll(p, j, axis=-1) for j in range(L)])
+                    for p in w))
+                nbytes = K * N + (K // 32) * N * 2
+                for M in sweep_rows:
+                    x = jax.random.normal(jax.random.fold_in(key, K + M),
+                                          (M, K), jnp.bfloat16)
+                    shape_label[0] = f"{model},K={K},N={N},M={M}"
+                    say(f"\n{model} [{M},{K}] x [{K},{N}]  "
+                        f"({nbytes / 1e6:.1f} MB quant)", flush=True)
+
+                    def xla(x, w):
+                        return x @ dequantize_weight(w, dtype=jnp.bfloat16)
+
+                    def take(i, s):
+                        return LayerSlice(s, i % L).take()
+
+                    fused = functools.partial(qm.quant_matmul, fast=True,
+                                              fused=True)
+                    bench("xla", xla, x, w, bytes_moved=nbytes)
+                    bench("xla, stack slice",
+                          lambda i, x, s: xla(x, take(i, s)), x, stack,
+                          bytes_moved=nbytes, indexed=True)
+                    if not qm.supports_decode((M, K), w, True):
+                        continue
+                    bench("fused", fused, x, w, bytes_moved=nbytes)
+                    bench("fused, stack slice",
+                          lambda i, x, s: fused(x, take(i, s)), x, stack,
+                          bytes_moved=nbytes, indexed=True)
+                    bench("fused, stack + index",
+                          lambda i, x, s: fused(
+                              x, s, layer=(i % L).astype(jnp.int32)),
+                          x, stack, bytes_moved=nbytes, indexed=True)
+
+    if not variants:
+        row_sweep()
+    for K, N in (VARIANT_SHAPES if variants else ()):
         w = make_w(K, N)
         x = jax.random.normal(jax.random.fold_in(key, K), (1, K), jnp.bfloat16)
         nbytes = K * N + (K // 32) * N * 4  # codes + f32 scales
