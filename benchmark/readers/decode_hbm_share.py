@@ -1,12 +1,12 @@
 """The decode step against the HBM roof, in percent: the bytes one chip must
-read for one step (``peaks.decode_step_bytes``: weights as held, the dense head,
-the cache rows of the contexts that were live, by the mean of the sampled
-``dllama_kv_blocks_used``) over the chip's published bandwidth, divided by the
-step program's device time. A decode step at 16 rows is memory-bound on this
-chip, so this is its roofline share; ``peaks.roofline_seconds`` says so."""
+read for one step (the configuration's ``counts.decode_step_bytes``: weights as
+held, the head, the cache rows of the contexts that were live, by the mean of
+the sampled ``dllama_kv_blocks_used``) over the chip's published bandwidth,
+divided by the step program's device time. A decode step at 16 rows is
+memory-bound on this chip, so this is its roofline share;
+``peaks.roofline_seconds`` says so."""
 
-import peaks                                # run.py puts benchmark/ on sys.path
-from readers_common import module_seconds
+from readers_common import module_seconds   # run.py puts benchmark/ on sys.path
 
 
 def read(ctx, match: str):
@@ -16,5 +16,5 @@ def read(ctx, match: str):
         return None
     rows = c["tokens"] / c["steps"]
     context = s["kv_used_mean"] * int(ctx["conf"]["engine"]["kv_block_size"])
-    need = peaks.decode_step_bytes(ctx["model"], rows=rows, context_tokens=context, chips=ctx["chips"])
-    return 100.0 * need / peaks.peaks(ctx["device_kind"])["hbm_bytes_per_s"] / step_s
+    need = ctx["counts"].decode_step_bytes(ctx["model"], rows=rows, context_tokens=context, chips=ctx["chips"])
+    return 100.0 * need / ctx["peaks"]["hbm_bytes_per_s"] / step_s

@@ -21,6 +21,15 @@ tokens the PROGRAM emitted. At emitted position i the gap is
 A near-tie that the program's bf16 arithmetic resolved the other way has a gap
 near 0 whatever the batch composition was; a token decoded over a wrong cache,
 at a wrong position or with part of the model missing has a gap of order 1.
+
+This file is the DEFAULT ``reference`` module (README, "Adding things"): a
+configuration whose layer equation is another names its own, and builds it
+from the parts here that no equation changes: :class:`Planes`, :func:`_planes`,
+:func:`_dequant`, :func:`_rms_norm`, :func:`_rope`, :func:`_attention`,
+:func:`attention_half`, :func:`swiglu`, :func:`layers_program` (the scan, the
+controls' handles, ``highest`` precision, the jit), :func:`head_gaps` (the
+blocked head and the gap) and :func:`teacher_force` (the driver, which takes
+the layer stack's program as ``layers_fn``).
 """
 
 from __future__ import annotations
@@ -42,13 +51,19 @@ LOST_BLOCK = 16      # positions the dropblock control hides
 _HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def tolerance(compute_dtype: str) -> float:
-    """The gap tolerance for a compute dtype, from ``gap_tolerance.json``."""
-    with open(os.path.join(_HERE, "gap_tolerance.json"), encoding="utf-8") as f:
+def tolerance_from(path: str, compute_dtype: str) -> float:
+    """The gap tolerance for a compute dtype from a file shaped like
+    ``gap_tolerance.json``: a module's own lies beside it, with its reason."""
+    with open(path, encoding="utf-8") as f:
         table = json.load(f)["tolerance"]
     if compute_dtype not in table:
-        raise KeyError(f"no gap tolerance measured for compute dtype {compute_dtype!r}")
+        raise KeyError(f"no gap tolerance measured for compute dtype {compute_dtype!r} in {path}")
     return float(table[compute_dtype])
+
+
+def tolerance(compute_dtype: str) -> float:
+    """The dense decoders' gap tolerance, from ``gap_tolerance.json``."""
+    return tolerance_from(os.path.join(_HERE, "gap_tolerance.json"), compute_dtype)
 
 
 class Planes(NamedTuple):
@@ -127,44 +142,52 @@ def _attention(q, k, v, hide):
     return out.reshape(T, H * hd)
 
 
-@functools.lru_cache(maxsize=None)
-def _layers_fn(model_key: str):
-    """jit of the whole layer stack for one configuration: ``(tokens[T],
-    embedding, layer planes, keep[L], shift, shift_from, hide) -> x[T, dim]``.
+def attention_half(m: dict, x, lp, positions, hide):
+    """A layer's attention half, residual added: pre-norm, q/k/v, per-head q/k
+    norm where ``qk_norm``, rotary positions, causal GQA attention, ``wo``.
+    ``x [T, dim]`` float32; ``lp`` one layer's leaves."""
+    T = x.shape[0]
+    H, KV, hd = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
+    eps, theta, conv = float(m["norm_epsilon"]), float(m["rope_theta"]), m["rope_convention"]
+    h = _rms_norm(x, lp["norm_att"], eps)
+    q = (h @ _dequant(lp["wq"])).reshape(T, H, hd)
+    k = (h @ _dequant(lp["wk"])).reshape(T, KV, hd)
+    v = (h @ _dequant(lp["wv"])).reshape(T, KV, hd)
+    if m.get("qk_norm"):
+        q = _rms_norm(q, lp["norm_q"], eps)
+        k = _rms_norm(k, lp["norm_k"], eps)
+    q, k = _rope(q, positions, theta, conv), _rope(k, positions, theta, conv)
+    return x + _attention(q, k, v, hide) @ _dequant(lp["wo"])
+
+
+def swiglu(h, w1, w2, w3):
+    """``(silu(h w1) * (h w3)) w2`` over planes or dense matrices."""
+    import jax
+
+    return (jax.nn.silu(h @ _dequant(w1)) * (h @ _dequant(w3))) @ _dequant(w2)
+
+
+def layers_program(layer_fn):
+    """jit of a whole layer stack scanned over its leading axis: ``(tokens[T],
+    embedding, layers, keep[L], shift, shift_from, hide) -> x[T, dim]``, where
+    ``layer_fn(x, lp, positions, hide) -> x`` is ONE layer with its residuals.
     ``keep`` and ``shift`` are the negative control's handles: an honest run
     passes ones and 0. Rotary positions are relative, so moving every row by
     one changes nothing; the control moves the rows from ``shift_from`` on
     (the emitted tokens) against the prompt, as a decode at a wrong position
-    would."""
+    would. A stack that is not one scan (two stacks in a pattern) writes its
+    own program with this signature."""
     import jax
     import jax.numpy as jnp
 
-    m = json.loads(model_key)
-    H, KV, hd = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
-    eps, theta, conv = float(m["norm_epsilon"]), float(m["rope_theta"]), m["rope_convention"]
-    qk_norm = bool(m.get("qk_norm"))
-
     def run(tokens, embedding, layers, keep, shift, shift_from, hide):
-        T = tokens.shape[0]
-        positions = jnp.arange(T)
+        positions = jnp.arange(tokens.shape[0])
         positions = positions + jnp.where(positions >= shift_from, shift, 0)
         x = embedding[tokens].astype(jnp.float32)
 
         def body(x, xs):
             lp, keep_l = xs
-            h = _rms_norm(x, lp["norm_att"], eps)
-            q = (h @ _dequant(lp["wq"])).reshape(T, H, hd)
-            k = (h @ _dequant(lp["wk"])).reshape(T, KV, hd)
-            v = (h @ _dequant(lp["wv"])).reshape(T, KV, hd)
-            if qk_norm:
-                q = _rms_norm(q, lp["norm_q"], eps)
-                k = _rms_norm(k, lp["norm_k"], eps)
-            q, k = _rope(q, positions, theta, conv), _rope(k, positions, theta, conv)
-            x1 = x + _attention(q, k, v, hide) @ _dequant(lp["wo"])
-            h = _rms_norm(x1, lp["norm_ffn"], eps)
-            ffn = (jax.nn.silu(h @ _dequant(lp["w1"])) * (h @ _dequant(lp["w3"]))) @ _dequant(lp["w2"])
-            x2 = x1 + ffn
-            return x + keep_l * (x2 - x), None
+            return x + keep_l * (layer_fn(x, lp, positions, hide) - x), None
 
         x, _ = jax.lax.scan(body, x, (layers, keep))
         return x
@@ -174,6 +197,19 @@ def _layers_fn(model_key: str):
             return run(*args)
 
     return jax.jit(traced)
+
+
+@functools.lru_cache(maxsize=None)
+def _layers_fn(model_key: str):
+    """The dense decoder's stack for one configuration (its ``model`` as JSON)."""
+    m = json.loads(model_key)
+    eps = float(m["norm_epsilon"])
+
+    def layer(x, lp, positions, hide):
+        x1 = attention_half(m, x, lp, positions, hide)
+        return x1 + swiglu(_rms_norm(x1, lp["norm_ffn"], eps), lp["w1"], lp["w2"], lp["w3"])
+
+    return layers_program(layer)
 
 
 @functools.lru_cache(maxsize=None)
@@ -199,50 +235,48 @@ def _head_fn(eps: float):
     return jax.jit(run)
 
 
+ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "norm_att", "norm_ffn")
+
+
+def layer_tree(params, names) -> dict:
+    """The named leaves of the engine's layer stack, Q40 planes as :class:`Planes`."""
+    return {n: _planes(getattr(params.layers, n)) for n in names}
+
+
 def _layer_tree(params) -> dict:
-    lp = params.layers
-    names = ["wq", "wk", "wv", "wo", "w1", "w2", "w3", "norm_att", "norm_ffn"]
-    if lp.norm_q is not None:
-        names += ["norm_q", "norm_k"]
-    return {n: _planes(getattr(lp, n)) for n in names}
+    names = ATTENTION_LEAVES + ("w1", "w2", "w3")
+    if params.layers.norm_q is not None:
+        names += ("norm_q", "norm_k")
+    return layer_tree(params, names)
 
 
-def reference_gaps(model: dict, params, prompt: list[int], emitted: list[int], *,
-                   control: str = "none") -> dict:
-    """Teacher-force the reference on ``prompt + emitted`` and return, per
-    emitted position, the gap, the reference's top-2 margin (both in units of
-    the logits' standard deviation) and that standard deviation, as numpy
-    arrays of ``len(emitted)``.
-
-    ``control``: ``none`` (honest), or a negative control made in the reference
-    only: ``shift`` (emitted rows one position late), ``droplayer`` (the middle
-    layer left out) or ``dropblock`` (the emitted rows do not see the middle
-    16 positions of the prompt: a cache block lost under a live sequence)."""
+def control_handles(n_layers: int, n_prompt: int, T: int, control: str):
+    """``(keep[L,1,1], shift, shift_from, hide)`` as :func:`layers_program`
+    takes them: ``shift`` (emitted rows one position late), ``droplayer`` (the
+    middle layer left out), ``dropblock`` (the emitted rows do not see the
+    middle 16 positions of the prompt: a cache block lost under a live
+    sequence); anything else is an honest run."""
     import jax.numpy as jnp
 
-    if control not in CONTROLS:
-        raise ValueError(f"unknown control {control!r}")
-    n_out = len(emitted)
-    seq = list(prompt) + list(emitted[:-1])      # row P-1+i predicts emitted[i]
-    T = -(-len(seq) // BLOCK_Q) * BLOCK_Q
-    tokens = np.zeros(T, dtype=np.int32)
-    tokens[:len(seq)] = seq
-    L = model["num_hidden_layers"]
-    keep = np.ones((L, 1, 1), dtype=np.float32)
+    keep = np.ones((n_layers, 1, 1), dtype=np.float32)
     if control == "droplayer":
-        keep[L // 2] = 0.0
-    shift = 1 if control == "shift" else 0
-    P = len(prompt)
-    lo = max(0, P // 2 - LOST_BLOCK // 2)
-    hide = (P, lo, lo + LOST_BLOCK) if control == "dropblock" else (T, 0, 0)
+        keep[n_layers // 2] = 0.0
+    lo = max(0, n_prompt // 2 - LOST_BLOCK // 2)
+    hide = (n_prompt, lo, lo + LOST_BLOCK) if control == "dropblock" else (T, 0, 0)
+    return (jnp.asarray(keep), jnp.int32(1 if control == "shift" else 0), jnp.int32(n_prompt),
+            jnp.asarray(hide, dtype=jnp.int32))
 
-    layers_fn = _layers_fn(json.dumps(model, sort_keys=True))
-    x = layers_fn(jnp.asarray(tokens), params.embedding, _layer_tree(params),
-                  jnp.asarray(keep), jnp.int32(shift), jnp.int32(P),
-                  jnp.asarray(hide, dtype=jnp.int32))
 
+def head_gaps(model: dict, params, x, n_prompt: int, emitted: list[int]) -> dict:
+    """From the stack's output ``x [T, dim]``: final norm and the head in
+    chunks of VOCAB_CHUNK rows over the rows that predict ``emitted``, and per
+    emitted position the gap, the top-2 margin and the logits' standard
+    deviation."""
+    import jax.numpy as jnp
+
+    n_out, T = len(emitted), x.shape[0]
     n_pad = -(-n_out // N_OUT_PAD) * N_OUT_PAD
-    rows = np.clip(len(prompt) - 1 + np.arange(n_pad), 0, T - 1)
+    rows = np.clip(n_prompt - 1 + np.arange(n_pad), 0, T - 1)
     x_rows = x[jnp.asarray(rows)]
     em = np.zeros(n_pad, dtype=np.int32)
     em[:n_out] = emitted
@@ -269,3 +303,36 @@ def reference_gaps(model: dict, params, prompt: list[int], emitted: list[int], *
     sl = slice(0, n_out)
     return {"gap": ((top1 - mine) / std)[sl], "margin": ((top1 - top2) / std)[sl],
             "std": std[sl], "finite": bool(np.isfinite(s2[sl]).all())}
+
+
+def teacher_force(model: dict, params, prompt: list[int], emitted: list[int], *, control: str,
+                  layers_fn, layers, controls=CONTROLS) -> dict:
+    """Run ``layers_fn`` (a :func:`layers_program`, or a program of its
+    signature) over ``prompt + emitted`` with ``layers`` as its layer tree and
+    the control's handles, then :func:`head_gaps`. Row P-1+i predicts
+    ``emitted[i]``; sequence lengths pad to BLOCK_Q."""
+    import jax.numpy as jnp
+
+    if control not in controls:
+        raise ValueError(f"unknown control {control!r}")
+    seq = list(prompt) + list(emitted[:-1])
+    T = -(-len(seq) // BLOCK_Q) * BLOCK_Q
+    tokens = np.zeros(T, dtype=np.int32)
+    tokens[:len(seq)] = seq
+    x = layers_fn(jnp.asarray(tokens), params.embedding, layers,
+                  *control_handles(model["num_hidden_layers"], len(prompt), T, control))
+    return head_gaps(model, params, x, len(prompt), emitted)
+
+
+def reference_gaps(model: dict, params, prompt: list[int], emitted: list[int], *,
+                   control: str = "none") -> dict:
+    """Teacher-force the reference on ``prompt + emitted`` and return, per
+    emitted position, the gap, the reference's top-2 margin (both in units of
+    the logits' standard deviation) and that standard deviation, as numpy
+    arrays of ``len(emitted)``.
+
+    ``control``: ``none`` (honest), or a negative control made in the reference
+    only: ``shift``, ``droplayer`` or ``dropblock`` (:func:`control_handles`)."""
+    return teacher_force(model, params, prompt, emitted, control=control,
+                         layers_fn=_layers_fn(json.dumps(model, sort_keys=True)),
+                         layers=_layer_tree(params))

@@ -8,7 +8,9 @@ cell's shapes, probes the reference gap, drives ``BatchScheduler.submit`` for
 ``--seconds`` from the benchmark's own load generator, checks the outputs the
 server produced under load against the plain reference, and prints one JSON
 line. Everything cell-specific is data: ``BENCHMARK.json`` names the cell's
-configuration file and traffic file, and each per-layer metric's reader.
+configuration file and traffic file, and each per-layer metric's reader; the
+configuration names its ``reference``, ``weights`` and ``counts`` modules, or
+gets the dense decoders' (``load_modules``; README, "Adding things").
 """
 
 from __future__ import annotations
@@ -27,6 +29,10 @@ import threading  # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 WORK_DIR = os.path.join(ROOT, ".bench_work")      # sparse model files, traces: git-ignored
+for _p in (ROOT, HERE):      # the program, then the benchmark's own modules first
+    if _p in sys.path:
+        sys.path.remove(_p)
+    sys.path.insert(0, _p)
 
 PROBE_PROMPTS = (321, 40)    # one spans two prefill buckets (1 + 256 + 64), one is short
 PROBE_TOKENS = 32
@@ -34,8 +40,10 @@ WARM_TOKENS = 4
 CHECK_REQUESTS = 4
 
 
-MODEL_KEYS = ("hidden_size", "intermediate_size", "num_hidden_layers", "num_attention_heads",
-              "num_key_value_heads", "head_dim", "vocab_size", "rope_theta", "max_position_embeddings")
+# a configuration file's own sections; every other top-level key is a published key of the model
+HARNESS_SECTIONS = ("program", "engine", "device", "assumed", "deployment", "memory", "modules", "name", "source")
+# what a configuration's "modules" may name, and the dense decoders' module where it names none
+DEFAULT_MODULES = {"reference": "reference", "weights": "weights", "counts": "counts"}
 
 
 def _fail(msg: str, code: int = 3) -> None:
@@ -68,8 +76,9 @@ def parse_args(argv=None):
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     # the rest is for the builder and the self-test; the driver passes none of it
     p.add_argument("--manifest", default=os.path.join(ROOT, "BENCHMARK.json"))
-    p.add_argument("--control", choices=("none", "shift", "droplayer", "dropblock"), default="none",
-                   help="negative control: break the REFERENCE this way; correct must come out false")
+    p.add_argument("--control", default="none",
+                   help="negative control: break the REFERENCE this way (one of its module's CONTROLS: "
+                        "shift, droplayer, dropblock, ...); correct must come out false")
     p.add_argument("--jitter-ms", type=float, default=0.0,
                    help="perturb clients' start times and gaps by up to this much")
     p.add_argument("--jitter-seed", type=int, default=0)
@@ -83,6 +92,39 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
+def model_view(conf: dict) -> dict:
+    """The model as the configuration's modules see it: every published key
+    (top-level, not one of the harness's sections; ``null`` passes), then what
+    the program needs said, then ``norm_epsilon``."""
+    model = {k: v for k, v in conf.items() if k not in HARNESS_SECTIONS and not k.startswith("reduced")}
+    model.update(conf["program"])
+    if "rms_norm_eps" in conf:
+        model["norm_epsilon"] = conf["rms_norm_eps"]
+    return model
+
+
+def load_modules(conf: dict) -> dict:
+    """``{"reference", "weights", "counts"}``: the files the configuration names
+    under ``modules`` (paths under ``benchmark/``), the dense decoders' where
+    it names none. Each is loaded once, here."""
+    named = conf.get("modules", {})
+    if set(named) - set(DEFAULT_MODULES):
+        _fail(f"configuration {conf['name']!r}: modules may name {sorted(DEFAULT_MODULES)}, "
+              f"not {sorted(set(named) - set(DEFAULT_MODULES))}", 2)
+    mods = {}
+    for kind, default in DEFAULT_MODULES.items():
+        if kind not in named:
+            mods[kind] = importlib.import_module(default)    # the one place the defaults are resolved
+            continue
+        path = os.path.normpath(os.path.join(HERE, named[kind]))
+        if not path.startswith(HERE + os.sep) or not os.path.isfile(path):
+            _fail(f"configuration {conf['name']!r} names its {kind} module {named[kind]!r}: "
+                  f"no file {path} under {HERE}", 2)
+        mods[kind] = _import_file(f"bench_{kind}_" + "".join(c if c.isalnum() else "_" for c in conf["name"]),
+                                  path)
+    return mods
+
+
 def resolve_cell(manifest: dict, manifest_path: str, workload: str):
     base = os.path.dirname(os.path.abspath(manifest_path))
     cells = {w["name"]: w for w in manifest["workloads"]}
@@ -92,12 +134,10 @@ def resolve_cell(manifest: dict, manifest_path: str, workload: str):
     conf_entry = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
     conf_path = os.path.join(base, conf_entry["file"])
     conf = _load_json(conf_path)
-    # the harness's view of the model: the published keys plus what the program needs said
-    conf["model"] = {**{k: conf[k] for k in MODEL_KEYS}, **conf["program"],
-                     "norm_epsilon": conf["rms_norm_eps"]}
+    conf["model"] = model_view(conf)
     traffic_path = os.path.join(os.path.dirname(os.path.dirname(conf_path)), "traffic",
                                 cell["traffic"] + ".json")
-    return cell, conf, traffic_path
+    return cell, conf, traffic_path, load_modules(conf)
 
 
 def need_devices(conf: dict, chips: int):
@@ -171,9 +211,7 @@ def counters_snapshot() -> dict:
             "nonfinite": reg.counter(telemetry.NONFINITE).total()}
 
 
-def build_engine(conf: dict, mix_engine: dict, chips: int, seed: int):
-    import weights
-
+def build_engine(conf: dict, mix_engine: dict, chips: int, seed: int, weights):
     from dllama_tpu import compile_cache
 
     compile_cache.enable()       # JAX_COMPILATION_CACHE_DIR if set, else .xla_cache/ in the checkout
@@ -236,10 +274,9 @@ def warm_up(engine, sched, mix: dict, rng) -> None:
     run_fixed(sched, second, WARM_TOKENS)
 
 
-def gap_check(conf: dict, engine, pairs: list[tuple[list[int], list[int]]], control: str) -> dict:
+def gap_check(reference, conf: dict, engine, pairs: list[tuple[list[int], list[int]]], control: str) -> dict:
     """Reference gaps of (prompt, emitted) pairs, pooled."""
     import numpy as np
-    import reference
 
     gaps, margins, stds, finite = [], [], [], True
     for prompt, emitted in pairs:
@@ -321,12 +358,13 @@ def read_metrics(entries: list[dict], folder: str, ctx: dict) -> tuple[dict, lis
 def main(argv=None) -> int:
     args = parse_args(argv)
     manifest = _load_json(args.manifest)
-    cell, conf, traffic_path = resolve_cell(manifest, args.manifest, args.workload)
+    cell, conf, traffic_path, mods = resolve_cell(manifest, args.manifest, args.workload)
+    reference = mods["reference"]
+    if args.control not in reference.CONTROLS:
+        _fail(f"--control {args.control!r}: {conf['name']}'s reference knows {list(reference.CONTROLS)}", 2)
     seconds = float(args.seconds if args.seconds is not None else manifest["run_seconds"])
     chips = int(cell["chips"])
 
-    sys.path.insert(0, ROOT)
-    sys.path.insert(0, HERE)
     devs = need_devices(conf, chips)
     platform, kind = devs[0].platform, devs[0].device_kind
     on_device = platform == "tpu"    # only a chip run may report a time or a rate
@@ -335,12 +373,10 @@ def main(argv=None) -> int:
     import numpy as np
 
     import loadgen
-    import peaks
-    import reference
     import traffic
 
-    if on_device:
-        peaks.peaks(kind)            # an unknown device kind is an error before anything runs
+    # an unknown device kind is an error before anything runs
+    device_peaks = importlib.import_module("peaks").peaks(kind) if on_device else None
     compiles = CompileCounter()
     mix = traffic.load(traffic_path)
     if args.rate is not None:
@@ -357,7 +393,7 @@ def main(argv=None) -> int:
         if fault:
             _fail(f"{traffic_path}: {fault}", 2)
 
-    engine, sched = build_engine(conf, plan.engine, chips, args.seed)
+    engine, sched = build_engine(conf, plan.engine, chips, args.seed, mods["weights"])
     rng = np.random.default_rng([args.seed & 0xFFFFFFFF, args.seed >> 32, 1])
     try:
         # ---- set-up: warm the cell's shapes, then the reference probe ----------
@@ -369,8 +405,8 @@ def main(argv=None) -> int:
                          for n in PROBE_PROMPTS]
         probe = run_fixed(sched, probe_prompts, PROBE_TOKENS)
         tol = reference.tolerance(conf["engine"]["compute_dtype"])
-        probe_gaps = gap_check(conf, engine, [(p, list(r.tokens)) for p, r in zip(probe_prompts, probe)],
-                               args.control)
+        probe_gaps = gap_check(reference, conf, engine,
+                               [(p, list(r.tokens)) for p, r in zip(probe_prompts, probe)], args.control)
         compiles_before = (compiles.traces, compiles.backend)
         counters_before = counters_snapshot()
 
@@ -406,7 +442,7 @@ def main(argv=None) -> int:
 
         # ---- correct: the reference gap on what the server did under load ------
         checked = pick_checked(summary["completed"], args.check_requests, args.seed)
-        window_gaps = gap_check(conf, engine, [(s.prompt, list(s.req.tokens)) for s in checked],
+        window_gaps = gap_check(reference, conf, engine, [(s.prompt, list(s.req.tokens)) for s in checked],
                                 args.control)
         finished_ok = [s for s in gen.sent if s.finished and not s.cut and not s.late
                        and s.req.error is None]
@@ -429,6 +465,7 @@ def main(argv=None) -> int:
                   "failed": len(summary["failed"]), "metrics": {}, "device": device}
         ctx = {"summary": summary, "samples": samples, "model": conf["model"], "conf": conf,
                "mix": mix, "cell": cell, "chips": chips, "device_kind": kind, "engine": engine,
+               "counts": mods["counts"], "peaks": device_peaks,
                "counters": {k: counters_after[k] - counters_before[k] for k in counters_after},
                "window_compiles": window_compiles, "trace": None, "sent": gen.sent, "setup_s": setup_s}
         if traced is not None:

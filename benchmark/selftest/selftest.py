@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The benchmark's own self-test: ``python3 benchmark/selftest/selftest.py``.
 
-Runs on the CPU, outside ``tests/``, in about two minutes. It rehearses the
+Runs on the CPU, outside ``tests/``, in about five minutes. It rehearses the
 whole command at a tiny preset (``selftest/manifest.json``; the tiny
 configurations name their device ``cpu`` and report counts only) and checks
 the parts of the yardstick that need no chip:
@@ -9,19 +9,27 @@ the parts of the yardstick that need no chip:
 1. the trace reduction on ``fixtures/tiny.xplane.pb`` (busy union, idle share,
    collective share, ``op_label`` names under their program's, widest-program
    choice);
-2. the peaks table (an unknown device kind raises) and the bytes and FLOPs
-   functions on numbers worked by hand;
+2. the peaks table (an unknown device kind raises) and the dense decoders'
+   bytes and FLOPs functions (``counts.py``) on numbers worked by hand;
 3. the traffic generator (a seed repeats itself; every seed gets the same
-   multiset of sizes and gaps), and ``test_trace_slice.py`` beside this file:
-   every mix's traced slice is anchored on arrivals, a line that lacks a
-   metric names it, the breakdown names programs and tick phases;
+   multiset of sizes and gaps), and every ``test_*.py`` beside this file
+   (pytest): ``test_trace_slice.py`` (every mix's traced slice is anchored on
+   arrivals, a line that lacks a metric names it, the breakdown names programs
+   and tick phases), ``test_modules.py`` (the seam through which a
+   configuration brings its reference, weight-maker and counts) and
+   ``test_broken_path.py`` (the program alters tokens: ``correct`` false);
 4. the command end to end: the output line's keys, a Qwen3-style block against
    the reference, the tp=4 path on four virtual devices, the negative control
-   turning ``correct`` false, and every real cell refusing to run without a TPU.
+   turning ``correct`` false, every workload of ``manifest.json`` that carries
+   a ``selftest`` entry (``{"seed": n, "controls": [..]}``: ``correct`` true,
+   and false under each control; how a configuration with modules of its own
+   joins the self-test by adding files and entries), and every real cell
+   refusing to run without a TPU.
 """
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import subprocess
@@ -69,6 +77,7 @@ def test_trace_reduce() -> None:
 
 
 def test_peaks() -> None:
+    import counts
     import peaks
 
     try:
@@ -81,13 +90,13 @@ def test_peaks() -> None:
     m = {"hidden_size": 4096, "intermediate_size": 14336, "num_hidden_layers": 32,
          "num_attention_heads": 32, "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 32768}
     w = 32 * (4096 * 4096 * 2 + 2 * 4096 * 1024 + 3 * 4096 * 14336)
-    check("peaks: Mistral-7B layer weights", peaks.layer_matmul_weights(m) == w == 6979321856)
+    check("peaks: Mistral-7B layer weights", counts.layer_matmul_weights(m) == w == 6979321856)
     want = w * (1 + 2 / 32) + 32768 * 4096 * 2 + 2 * 32 * 1024 * 2 * 8000 + 16 * 4096 * 2
-    check("peaks: decode step bytes", near(peaks.decode_step_bytes(m, rows=16, context_tokens=8000), want))
+    check("peaks: decode step bytes", near(counts.decode_step_bytes(m, rows=16, context_tokens=8000), want))
     want_f = 2 * 256 * w + 4 * 32 * 4096 * (256 * 1000 + 256 * 257 / 2)
-    check("peaks: prefill chunk FLOPs", near(peaks.prefill_chunk_flops(m, chunk=256, context_before=1000), want_f))
-    t, roof = peaks.roofline_seconds(peaks.decode_step_flops(m, rows=16, context_tokens=8000),
-                                     peaks.decode_step_bytes(m, rows=16, context_tokens=8000), "TPU v5 lite")
+    check("peaks: prefill chunk FLOPs", near(counts.prefill_chunk_flops(m, chunk=256, context_before=1000), want_f))
+    t, roof = peaks.roofline_seconds(counts.decode_step_flops(m, rows=16, context_tokens=8000),
+                                     counts.decode_step_bytes(m, rows=16, context_tokens=8000), "TPU v5 lite")
     check("peaks: a 16-row decode step is memory-bound", roof == "memory" and 0.009 < t < 0.012)
 
 
@@ -110,11 +119,12 @@ def test_traffic() -> None:
                   and len(a.requests) == len(c.requests) == round(mix["rate_per_s"] * 20))
 
 
-def test_trace_slice() -> None:
-    p = subprocess.run([sys.executable, "-m", "pytest", os.path.join(HERE, "test_trace_slice.py"), "-q",
-                        "-p", "no:cacheprovider"], env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT,
-                       capture_output=True, text=True, timeout=900)
-    check("trace slice: test_trace_slice.py passes", p.returncode == 0, p.stdout[-600:])
+def test_pytest_files() -> None:
+    for path in sorted(glob.glob(os.path.join(HERE, "test_*.py"))):
+        p = subprocess.run([sys.executable, "-m", "pytest", path, "-q", "-p", "no:cacheprovider"],
+                           env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT,
+                           capture_output=True, text=True, timeout=900)
+        check(f"pytest: {os.path.basename(path)} passes", p.returncode == 0, p.stdout[-600:])
 
 
 def run_cmd(args: list[str], *, devices: int = 1, manifest: bool = True):
@@ -162,6 +172,18 @@ def test_command() -> None:
     check("command: the negative control turns correct false", rc == 0 and r.get("correct") is False
           and r["gap"]["max"] > r["gap"]["tolerance"], err[-400:])
 
+    for w in json.load(open(os.path.join(HERE, "manifest.json"), encoding="utf-8"))["workloads"]:
+        spec = w.get("selftest")
+        if spec is None:
+            continue
+        for control in ["none"] + spec["controls"]:
+            rc, line, err = run_cmd(["--workload", w["name"], "--seed", str(spec["seed"]), "--seconds", "2",
+                                     "--control", control], devices=w["chips"])
+            r = json.loads(line) if rc == 0 and line else {}
+            check(f"command: {w['name']} is " + ("correct against its own reference" if control == "none"
+                                                 else f"not correct under its control {control}"),
+                  rc == 0 and r.get("correct") is (control == "none") and r.get("failed") == 0, err[-400:])
+
     manifest = json.load(open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8"))
     for w in manifest["workloads"]:
         rc, line, err = run_cmd(["--workload", w["name"], "--seed", "1", "--seconds", "1"],
@@ -173,7 +195,7 @@ def main() -> int:
     test_trace_reduce()
     test_peaks()
     test_traffic()
-    test_trace_slice()
+    test_pytest_files()
     if "--fast" not in sys.argv:
         test_command()
     print(f"{len(FAILED)} failed" if FAILED else "all passed", flush=True)
