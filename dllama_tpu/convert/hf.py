@@ -32,6 +32,7 @@ ARCH_BY_MODEL_TYPE = {
     "mixtral": ArchType.LLAMA,
     "qwen3": ArchType.QWEN3,
     "qwen3_moe": ArchType.QWEN3,
+    "olmo_hybrid": ArchType.OLMO_HYBRID,
 }
 
 HIDDEN_ACT_BY_NAME = {"gelu": HiddenAct.GELU, "silu": HiddenAct.SILU}
@@ -146,6 +147,9 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
         else:
             params["moe_norm_topk"] = 1
 
+    if model_type == "olmo_hybrid":
+        params.update(_olmo_hybrid_header(cfg))
+
     if cfg.get("rope_theta") is not None:
         params["rope_theta"] = int(cfg["rope_theta"])
 
@@ -174,6 +178,40 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
     return params
 
 
+def _olmo_hybrid_header(cfg: dict) -> dict:
+    """``model_type: olmo_hybrid``'s config keys as the header's extension
+    keys (formats/mfile.py, HeaderKey 22-28). ``layer_types`` must be whole
+    periods of linear-attention layers closed by one full layer. Three things
+    the published config does not say are taken from the Olmo 2/3 family:
+    block norms on a sublayer's output, the q/k norm over the whole
+    projection, and, ``rope_parameters.rope_theta`` being null, no rotary
+    embedding. The head width is ``hidden_size / num_attention_heads``."""
+    kinds = list(cfg["layer_types"])
+    period = kinds.index("full_attention") + 1
+    want = (["linear_attention"] * (period - 1) + ["full_attention"]) \
+        * (len(kinds) // period)
+    if kinds != want or len(kinds) != cfg["num_hidden_layers"]:
+        raise ValueError(
+            "olmo_hybrid: layer_types is not whole periods of linear "
+            "layers closed by one full layer")
+    rope = (cfg.get("rope_parameters") or {}).get("rope_theta")
+    out = {
+        "layer_period": period,
+        "linear_n_key_heads": cfg["linear_num_key_heads"],
+        "linear_n_value_heads": cfg["linear_num_value_heads"],
+        "linear_key_head_dim": cfg["linear_key_head_dim"],
+        "linear_value_head_dim": cfg["linear_value_head_dim"],
+        "linear_conv_kernel": cfg["linear_conv_kernel_dim"],
+        "linear_neg_eigval": int(bool(cfg.get("linear_allow_neg_eigval"))),
+        "head_dim": cfg["hidden_size"] // cfg["num_attention_heads"],
+    }
+    if rope is not None:
+        raise ValueError(
+            f"olmo_hybrid: rope_parameters.rope_theta is {rope!r}; this "
+            f"architecture's full layers carry no rotary embedding here")
+    return out
+
+
 # ---------------------------------------------------------------------------
 # tensor plan
 # ---------------------------------------------------------------------------
@@ -196,6 +234,12 @@ def hf_tensor_plan(params: dict) -> list[PlanItem]:
     :meth:`dllama_tpu.formats.mfile.ModelFile._walk`)."""
     wt = params["weight_float_type"]
     arch = ArchType(params["arch_type"])
+    if arch == ArchType.OLMO_HYBRID:
+        raise NotImplementedError(
+            "olmo_hybrid: the header is mapped (load_hf_config), the "
+            "checkpoint's tensor names are not: they could not be read where "
+            "this was written, and a guessed map is worse than none. The "
+            "target layout is formats/mfile.py's _walk_hybrid_layer")
     n_heads = params["n_heads"]
     n_kv_heads = params["n_kv_heads"]
 

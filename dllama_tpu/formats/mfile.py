@@ -88,6 +88,17 @@ class HeaderKey(enum.IntEnum):
     # weights are renormalized over the selected top-k (HF norm_topk_prob;
     # Mixtral always normalizes, Qwen3-MoE defaults to raw softmax probs).
     MOE_NORM_TOPK = 21
+    # OUR format extension, read by ArchType.OLMO_HYBRID only (the C++
+    # reference stops at key 20 and refuses these): the layer pattern as its
+    # period (P: each period is P-1 linear-attention layers, then one full
+    # one) and the six ``linear_*`` sizes of the gated delta-rule mixer.
+    LAYER_PERIOD = 22
+    LINEAR_N_KEY_HEADS = 23
+    LINEAR_N_VALUE_HEADS = 24
+    LINEAR_KEY_HEAD_DIM = 25
+    LINEAR_VALUE_HEAD_DIM = 26
+    LINEAR_CONV_KERNEL = 27
+    LINEAR_NEG_EIGVAL = 28
 
 
 class ArchType(enum.IntEnum):
@@ -95,6 +106,9 @@ class ArchType(enum.IntEnum):
 
     LLAMA = 0xABCD00
     QWEN3 = 0xABCD01
+    # ours: a hybrid decoder, gated delta-rule (linear-attention) layers and
+    # full softmax-attention layers in a periodic pattern (models/hybrid.py)
+    OLMO_HYBRID = 0xABCD02
 
 
 class RopeType(enum.IntEnum):
@@ -140,6 +154,26 @@ class ModelHeader:
     sync_type: int = F32
     header_size: int = 0
     file_size: int = 0
+    # OLMO_HYBRID (HeaderKey 22-28); 0 / defaults for every other arch
+    layer_period: int = 0
+    linear_n_key_heads: int = 0
+    linear_n_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 0
+    linear_neg_eigval: int = 0
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels the causal convolution runs over: q~, k~, v~."""
+        return (2 * self.linear_n_key_heads * self.linear_key_head_dim
+                + self.linear_n_value_heads * self.linear_value_head_dim)
+
+    @property
+    def linear_in_dim(self) -> int:
+        """Width of the mixer's packed input projection: q~ k~ v~ z."""
+        return (self.linear_conv_dim
+                + self.linear_n_value_heads * self.linear_value_head_dim)
 
     @property
     def q_dim(self) -> int:
@@ -165,6 +199,14 @@ def norm_epsilon_to_int(eps: float) -> int:
     if abs(eps - 1e-6) < 1e-10:
         return 6
     raise ValueError(f"unsupported norm epsilon {eps}")
+
+
+# the hybrid arch's keys land in the ModelHeader field of the same name
+_HYBRID_KEYS = {k: k.name.lower() for k in (
+    HeaderKey.LAYER_PERIOD, HeaderKey.LINEAR_N_KEY_HEADS,
+    HeaderKey.LINEAR_N_VALUE_HEADS, HeaderKey.LINEAR_KEY_HEAD_DIM,
+    HeaderKey.LINEAR_VALUE_HEAD_DIM, HeaderKey.LINEAR_CONV_KERNEL,
+    HeaderKey.LINEAR_NEG_EIGVAL)}
 
 
 def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
@@ -223,6 +265,8 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
             h.head_dim = value
         elif key == HeaderKey.NORM_EPSILON:
             h.norm_epsilon = _norm_epsilon_from_int(value)
+        elif key in _HYBRID_KEYS:
+            setattr(h, _HYBRID_KEYS[key], value)
         else:
             raise ValueError(f"unsupported header key {key}")
 
@@ -239,6 +283,13 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
     h.file_size = path_size
     if h.arch_type == ArchType.QWEN3:
         h.rope_type = RopeType.FALCON
+    if h.arch_type == ArchType.OLMO_HYBRID:
+        if h.layer_period < 2 or h.n_layers % h.layer_period:
+            raise ValueError(
+                f"hybrid model: layer period {h.layer_period} does not "
+                f"divide {h.n_layers} layers into whole periods")
+        if h.n_experts:
+            raise ValueError("hybrid model: routed experts are unsupported")
     return h
 
 
@@ -356,6 +407,9 @@ class ModelFile:
         # (llm.cpp:503-538).
         off += self._add("embedding", -1, (h.vocab_size, h.dim), F32, off)
         for l in range(h.n_layers):
+            if h.arch_type == ArchType.OLMO_HYBRID:
+                off = self._walk_hybrid_layer(l, off)
+                continue
             off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
             off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
             off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
@@ -392,6 +446,41 @@ class ModelFile:
             raise ValueError(
                 f"weight file size mismatch: file has {h.file_size} bytes, "
                 f"tensor walk needs {off}")
+
+    def _walk_hybrid_layer(self, l: int, off: int) -> int:
+        """One layer of an OLMO_HYBRID file (OUR layout; the reference has
+        none). A linear-attention layer: the mixer's packed input
+        projection (q~ k~ v~ z rows, in that order), the a/b gate
+        projections (F32), the convolution taps ``[kernel, channels]``,
+        ``A_log``, ``dt_bias``, the output norm over a value head, the
+        output projection. A full layer (the last of each period): q k v
+        wo and the q/k norms over the whole projection. Both end with w1
+        w2 w3 and the two block norms."""
+        h, wt = self.header, self.header.weight_type
+        if (l + 1) % h.layer_period:
+            nh = h.linear_n_value_heads
+            vdim = nh * h.linear_value_head_dim
+            off += self._add("block_gdn_in", l, (h.linear_in_dim, h.dim), wt, off)
+            off += self._add("block_gdn_ab", l, (2 * nh, h.dim), F32, off)
+            off += self._add("block_gdn_conv", l,
+                             (h.linear_conv_kernel, h.linear_conv_dim), F32, off)
+            off += self._add("block_gdn_a_log", l, (nh,), F32, off)
+            off += self._add("block_gdn_dt_bias", l, (nh,), F32, off)
+            off += self._add("block_gdn_norm", l, (h.linear_value_head_dim,), F32, off)
+            off += self._add("block_gdn_out", l, (h.dim, vdim), wt, off)
+        else:
+            off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
+            off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
+            off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
+            off += self._add("block_matmul_wo", l, (h.dim, h.q_dim), wt, off)
+            off += self._add("block_norm_q", l, (h.q_dim,), F32, off)
+            off += self._add("block_norm_k", l, (h.kv_dim,), F32, off)
+        off += self._add("block_matmul_w1", l, (h.hidden_dim, h.dim), wt, off)
+        off += self._add("block_matmul_w2", l, (h.dim, h.hidden_dim), wt, off)
+        off += self._add("block_matmul_w3", l, (h.hidden_dim, h.dim), wt, off)
+        off += self._add("block_norm_0", l, (h.dim,), F32, off)
+        off += self._add("block_norm_1", l, (h.dim,), F32, off)
+        return off
 
     # -- tensor access ------------------------------------------------------
 

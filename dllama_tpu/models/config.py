@@ -39,6 +39,19 @@ class ModelConfig:
     # softmax-then-topk-renorm and topk-then-softmax are the same function —
     # only the renorm-vs-raw choice changes behavior.
     moe_norm_topk: bool = True
+    # Hybrid decoders (ArchType.OLMO_HYBRID, models/hybrid.py): the layer
+    # pattern as its period (0 = one homogeneous stack; P = each period is
+    # P-1 gated delta-rule layers, then one full softmax-attention layer),
+    # and the mixer's sizes. The arch implies the rest, as QWEN3 implies its
+    # per-head q/k norm: block norms on a sublayer's OUTPUT (x + norm(f(x))),
+    # a q/k norm over the whole projection before the heads are split, no
+    # rotary embedding (rope_type and rope_theta are not read).
+    layer_period: int = 0
+    lin_heads: int = 0
+    lin_key_dim: int = 0
+    lin_value_dim: int = 0
+    lin_conv_kernel: int = 0
+    lin_neg_eigval: bool = False
 
     # TPU execution choices (no reference equivalent):
     compute_dtype: str = "float32"  # "float32" for parity, "bfloat16" for speed
@@ -93,11 +106,54 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def is_hybrid(self) -> bool:
+        """Recurrent (linear-attention) layers beside the full ones: a
+        slot's context is K/V blocks AND a state row (runtime/kvblocks)."""
+        return self.layer_period > 0
+
+    @property
+    def n_periods(self) -> int:
+        return self.n_layers // self.layer_period if self.is_hybrid else 0
+
+    @property
+    def n_linear_layers(self) -> int:
+        return self.n_periods * (self.layer_period - 1)
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that hold a K/V cache: every one, or a hybrid's full ones."""
+        return self.n_periods if self.is_hybrid else self.n_layers
+
+    @property
+    def lin_conv_dim(self) -> int:
+        """Channels of the mixer's causal convolution: q~, k~, v~."""
+        return self.lin_heads * (2 * self.lin_key_dim + self.lin_value_dim)
+
+    @property
+    def lin_in_dim(self) -> int:
+        """Width of the mixer's packed input projection: q~ k~ v~ z."""
+        return self.lin_conv_dim + self.lin_heads * self.lin_value_dim
+
     @classmethod
     def from_header(cls, h: ModelHeader, compute_dtype: str = "float32") -> "ModelConfig":
         from ..formats.quants import Q80
 
+        hybrid = {}
+        if h.arch_type == ArchType.OLMO_HYBRID:
+            if h.linear_n_key_heads != h.linear_n_value_heads:
+                raise ValueError(
+                    f"hybrid model: {h.linear_n_key_heads} key heads against "
+                    f"{h.linear_n_value_heads} value heads; the mixer here "
+                    f"pairs them one to one")
+            hybrid = dict(
+                layer_period=h.layer_period, lin_heads=h.linear_n_value_heads,
+                lin_key_dim=h.linear_key_head_dim,
+                lin_value_dim=h.linear_value_head_dim,
+                lin_conv_kernel=h.linear_conv_kernel,
+                lin_neg_eigval=bool(h.linear_neg_eigval))
         return cls(
+            **hybrid,
             sync_q80=h.sync_type == Q80,
             arch=h.arch_type,
             dim=h.dim,

@@ -93,15 +93,21 @@ def _layer_at(layers: LayerParams, l: jax.Array) -> LayerParams:
     :func:`linear` as a :class:`LayerSlice` — this is the place that knows
     their leading axis is the layer; MoE expert stacks (``[L, E, ..]``) and
     everything small are sliced as a scan would slice them."""
+    return _stack_at(layers, l, _LAYER_MATMULS)
+
+
+def _stack_at(layers, l: jax.Array, matmuls: tuple[str, ...]):
+    """:func:`_layer_at` for any stacked NamedTuple of layer weights whose
+    2-D matmul planes are the fields ``matmuls`` (the hybrid decoder's
+    linear-attention stack has its own, models/hybrid.py)."""
     def at(name: str, leaf):
-        if name in _LAYER_MATMULS and isinstance(leaf, QuantizedWeight):
+        if name in matmuls and isinstance(leaf, QuantizedWeight):
             return LayerSlice(leaf, l)
         return jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
             leaf)
 
-    return LayerParams(*(at(n, getattr(layers, n))
-                         for n in LayerParams._fields))
+    return type(layers)(*(at(n, getattr(layers, n)) for n in layers._fields))
 
 
 def _scan_by_index(cfg: ModelConfig, rows: int) -> bool:
@@ -665,6 +671,23 @@ def _layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
     # -- attention half (reference att segment, llm.cpp:226-366) -----------
     q, k, v = _attn_qkv(cfg, x, lp, cos, sin, positions, fq)
 
+    att, k_cache, v_cache = _attend_dense(cfg, q, k, v, k_cache, v_cache,
+                                          start_pos, positions)
+    x, stats = _attn_out_and_ffn(cfg, x, att, lp, fq, taps)
+    if taps:
+        return x, k_cache, v_cache, stats
+    return x, k_cache, v_cache
+
+
+def _attend_dense(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
+                  k_cache: jax.Array, v_cache: jax.Array,
+                  start_pos: jax.Array, positions: jax.Array):
+    """Append the new rows ``k, v [B, T, n_kv, hd]`` to one layer's dense
+    cache and attend ``q [B, T, n_heads, hd]`` over it: the sp ring, the
+    sharded or plain flash kernel, or the XLA oracle, as plan and shapes
+    resolve. Returns ``(att, k_cache, v_cache)``. Shared by
+    :func:`_layer_step` and the hybrid decoder's full layers
+    (models/hybrid.py)."""
     sp_res = None
     plan = _current_plan()
     if plan is not None and plan.axis_size("sp") > 1 \
@@ -696,10 +719,7 @@ def _layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
             else:
                 att = attention(q, k_cache, v_cache, positions, cfg.head_dim)
     att = constrain(att, "batch", None, "heads", None)
-    x, stats = _attn_out_and_ffn(cfg, x, att, lp, fq, taps)
-    if taps:
-        return x, k_cache, v_cache, stats
-    return x, k_cache, v_cache
+    return att, k_cache, v_cache
 
 
 def _write_kv_rows(pool: jax.Array, new: jax.Array, blk: jax.Array,
@@ -744,12 +764,25 @@ def _paged_layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
     PAPERS.md "Ragged Paged Attention") replaces the gather+oracle pair
     bit-identically whenever its gate resolves — same callers, same
     program names, zero extra compiles."""
-    from ..ops import paged_attention as _pa
-
-    B, T, _ = x.shape
     fq = fake_quant_q80 if cfg.sync_q80 else (lambda a: a)
     q, k, v = _attn_qkv(cfg, x, lp, cos, sin, positions, fq)
+    att, k_pool, v_pool = _attend_paged(cfg, q, k, v, k_pool, v_pool,
+                                        positions, tables, write_lens)
+    x, _ = _attn_out_and_ffn(cfg, x, att, lp, fq, taps=False)
+    return x, k_pool, v_pool
 
+
+def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
+                  k_pool: jax.Array, v_pool: jax.Array, positions: jax.Array,
+                  tables: jax.Array, write_lens: jax.Array | None = None):
+    """Write the new rows ``k, v [B, T, n_kv, hd]`` into one layer's block
+    pool and attend ``q`` through the block ``tables``: the ragged paged
+    kernel where its gate resolves, the gather + XLA oracle otherwise
+    (:func:`_paged_layer_step` has the contract). Returns ``(att, k_pool,
+    v_pool)``. Shared with the hybrid decoder's full layers."""
+    from ..ops import paged_attention as _pa
+
+    B, T = q.shape[:2]
     bs = k_pool.shape[2]
     n_blocks_seq = tables.shape[1]
     brow = jnp.arange(B, dtype=jnp.int32)[:, None]
@@ -785,8 +818,7 @@ def _paged_layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
         att = attention(q, view(k_pool), view(v_pool), positions,
                         cfg.head_dim)
     att = constrain(att, "batch", None, "heads", None)
-    x, _ = _attn_out_and_ffn(cfg, x, att, lp, fq, taps=False)
-    return x, k_pool, v_pool
+    return att, k_pool, v_pool
 
 
 def greedy_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -946,8 +978,16 @@ def _exact_f32_dots(fn):
 
 @_exact_f32_dots
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
-            start_pos: jax.Array, kv: KVCache) -> tuple[jax.Array, KVCache]:
+            start_pos: jax.Array, kv: KVCache,
+            n_valid: jax.Array | None = None) -> tuple[jax.Array, KVCache]:
     """Full forward: ``tokens [B, T]`` at absolute ``start_pos`` → logits.
+
+    ``n_valid`` is a hybrid decoder's alone (models/hybrid.py, where ``kv``
+    is a :class:`~dllama_tpu.models.hybrid.HybridColumn`): how many of the
+    chunk's ``T`` positions are real. K/V rows written for padding are
+    overwritten later; a recurrent state would keep them, so padded
+    positions leave it untouched. The dense decoders pad freely and never
+    pass it.
 
     Returns float32 logits ``[B, T, vocab]`` and the updated cache. Jittable;
     ``start_pos`` is a traced scalar (all rows at one position) or a ``[B]``
@@ -955,6 +995,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     (runtime/serving.py), where each slot of the batch is its own sequence
     at its own depth. One compilation per ``T`` either way.
     """
+    if cfg.is_hybrid:
+        from . import hybrid
+
+        return hybrid.forward(params, cfg, tokens, start_pos, kv, n_valid)
     start_pos = jnp.asarray(start_pos, dtype=jnp.int32)
     ragged = start_pos.ndim > 0
     # numerics observatory taps (runtime/numerics): a TRACE-TIME flag, so
@@ -1268,6 +1312,11 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     block — see :func:`_paged_layer_step`."""
     from ..runtime.kvblocks import PagedKVCache
 
+    if cfg.is_hybrid:
+        from . import hybrid
+
+        return hybrid.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
+                                    write_lens)
     if _numerics.taps_active():
         raise ValueError("numerics taps are unsupported on the paged KV "
                          "path (use the dense slot pool for tap sessions)")

@@ -228,6 +228,37 @@ class InferenceEngine:
                 f"spec_lookup {self.spec_lookup} exceeds the control packet's "
                 f"{self.packet_slots} token slots (raise --nbatches)")
 
+        if self.cfg.is_hybrid:
+            # recurrent state beside K/V (models/hybrid.py): what this
+            # engine does not carry to a state is refused HERE, by flag and
+            # reason; nothing is silently ignored and no code stands in
+            tp = 1 if tp is None else tp
+            unsupported = [
+                ("no --kv-block-size (the dense slot pool, and the "
+                 "single-sequence inference/chat/perplexity path: only the "
+                 "paged generator carries the state pool)",
+                 not int(kv_block_size or 0)),
+                ("--spec-lookup (a rejected draft cannot be rolled back "
+                 "out of a recurrent state)", self.spec_lookup > 0),
+                ("--kv-host-blocks (the host tier spills and pages in K/V "
+                 "blocks; a state has no host copy)",
+                 int(kv_host_blocks or 0) > 0),
+                ("--tp > 1", tp > 1), ("--sp > 1", sp > 1),
+                ("--pp > 1", pp > 1), ("--dp > 1", dp > 1),
+                ("multihost workers", multihost),
+                ("--weight-mode offload", weight_mode == "offload"),
+                ("--quant-mode turbo/turbo16", turbo_mode() is not None),
+                ("--buffer-float-type q80 (Q80 sync emulation)",
+                 self.cfg.sync_q80),
+                ("--numerics-taps", numerics_taps
+                 or os.environ.get("DLLAMA_NUMERICS_TAPS") == "1"),
+            ]
+            bad = [name for name, hit in unsupported if hit]
+            if bad:
+                raise ValueError(
+                    f"a hybrid decoder (linear-attention layers with a "
+                    f"recurrent state; the period scan has no mesh plan "
+                    f"yet) does not support: {'; '.join(bad)}")
         # paged KV serving (--kv-block-size, runtime/kvblocks.py): validate
         # the block geometry AND the feature combos up front — the paged
         # program family covers plain + tp ragged decode only, and a combo
@@ -607,8 +638,13 @@ class InferenceEngine:
         # weights (ADVICE r4: report-vs-dispatch drift).
         self._load_quant_resolution = self._quant_resolution()
         t_phase = self._stamp_startup("weight_load", t_phase)
-        self.kv: KVCache = self._fresh_kv()
+        # a hybrid decoder is served by the paged generator alone, which
+        # owns its pools: no batch-1 cache for a solo path it refuses
+        self.kv: KVCache = None if self.cfg.is_hybrid else self._fresh_kv()
         self.pos = 0
+        kinds = telemetry.registry().gauge(telemetry.LAYER_KINDS)
+        kinds.set(self.cfg.n_linear_layers, kind="linear")
+        kinds.set(self.cfg.n_kv_layers, kind="full")
         # Eval/Sync split (reference dllama.cpp:59-67): measured lazily on
         # the first decode of a generation when enabled; see measure_split()
         self.profile_split = profile_split
@@ -749,6 +785,16 @@ class InferenceEngine:
 
         return (fast_numerics_resolved(self.cfg.compute_dtype), turbo_mode())
 
+    def _require_solo_cache(self) -> None:
+        """The single-sequence programs run over ``self.kv``, which a hybrid
+        decoder does not have: its context is K/V AND a recurrent state,
+        and only the paged generator carries both."""
+        if self.kv is None:
+            raise RuntimeError(
+                "a hybrid decoder is served through BatchScheduler over the "
+                "paged pool only: the single-sequence path (inference, chat, "
+                "perplexity, score_nll) has no recurrent state")
+
     def _fresh_kv(self) -> KVCache:
         # dtype policy in __init__ (self.kv_dtype): compute dtype for parity,
         # bf16/f8 for serving footprint+bandwidth
@@ -762,7 +808,8 @@ class InferenceEngine:
             from ..parallel.multihost import CTRL_RESET
 
             self._ctrl.send(self._ctrl.encode(CTRL_RESET))
-        self.kv = self._fresh_kv()
+        if not self.cfg.is_hybrid:
+            self.kv = self._fresh_kv()
         self.pos = 0
         if self.tokenizer is not None:
             self.tokenizer.reset_decoder()
@@ -783,6 +830,7 @@ class InferenceEngine:
         """Run one jitted step under the active mesh plan; returns
         (primary output, updated kv stored on self). ``extras`` are trailing
         traced f32 scalars (the sampled step's temperature/topp/coin)."""
+        self._require_solo_cache()
         live = self._quant_resolution()
         if live != self._load_quant_resolution:
             raise RuntimeError(
@@ -1093,6 +1141,7 @@ class InferenceEngine:
         which does not share the jit wrapper's executable cache; the
         persistent compile cache absorbs the duplicate (cost note on
         :meth:`measure_split`)."""
+        self._require_solo_cache()
         pos = min(self.pos, self.cfg.seq_len - 1)
         with (use_plan(self.plan) if self.plan is not None else nullcontext()):
             if kind == "decode":
@@ -1400,6 +1449,7 @@ class InferenceEngine:
         Resets the engine's cache and advances ``self.pos`` like
         :meth:`perplexity`.
         """
+        self._require_solo_cache()
         if self._nll_step is None:
             raise RuntimeError(
                 "eval scoring is unsupported under --multihost (no "

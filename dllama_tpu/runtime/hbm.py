@@ -56,6 +56,16 @@ def device_memory_bytes() -> int | None:
 
 def matmul_weight_count(cfg) -> int:
     """Total matmul-plane weights (the quantized payload)."""
+    if cfg.is_hybrid:
+        # two kinds of layer: the mixer's packed input projection and its
+        # output projection, or q k v wo; a dense feed-forward in both
+        ffn = 3 * cfg.dim * cfg.hidden_dim
+        linear = (cfg.dim * cfg.lin_in_dim
+                  + cfg.lin_heads * cfg.lin_value_dim * cfg.dim + ffn)
+        full = (cfg.dim * cfg.q_dim + 2 * cfg.dim * cfg.kv_dim
+                + cfg.q_dim * cfg.dim + ffn)
+        return (cfg.n_linear_layers * linear + cfg.n_kv_layers * full
+                + cfg.dim * cfg.vocab_size)
     per_layer = (cfg.dim * cfg.q_dim + 2 * cfg.dim * cfg.kv_dim
                  + cfg.q_dim * cfg.dim)
     if cfg.is_moe:
@@ -114,7 +124,7 @@ def estimate_device_bytes(cfg, *, weight_repr: str, kv_dtype_bytes: int,
             largest_leaf = cfg.n_layers * cfg.dim * cfg.hidden_dim * (
                 cfg.n_experts if cfg.is_moe else 1)
             weights += largest_leaf + 4 * cfg.dim * dense_cols
-    kv = (2 * cfg.n_layers * padded_cache_len(cfg.seq_len) * cfg.kv_dim
+    kv = (2 * cfg.n_kv_layers * padded_cache_len(cfg.seq_len) * cfg.kv_dim
           * batch * kv_dtype_bytes)
     need = int(((weights + kv) / max(1, n_shards)) * _MARGIN) + _FIXED_OVERHEAD
     return {"weights_bytes": weights, "kv_bytes": kv,
@@ -148,13 +158,14 @@ def estimate_block_pool_bytes(cfg, n_blocks: int, block_size: int,
                               kv_dtype_bytes: int) -> int:
     """Device bytes of a paged KV block pool
     ``[L, n_blocks, n_kv, block_size, hd]`` ×2 (K and V)."""
-    return 2 * cfg.n_layers * n_blocks * cfg.kv_dim * block_size \
+    return 2 * cfg.n_kv_layers * n_blocks * cfg.kv_dim * block_size \
         * kv_dtype_bytes
 
 
 def fit_block_pool(cfg, n_blocks: int, *, block_size: int, min_blocks: int,
                    weight_repr: str, kv_dtype_bytes: int, n_shards: int = 1,
-                   offload: bool = False) -> tuple[int, dict]:
+                   offload: bool = False,
+                   state_bytes: int = 0) -> tuple[int, dict]:
     """Largest paged block-pool size ``<= n_blocks`` whose estimate fits
     the device limit — the paged twin of :func:`fit_batch_slots`: blocks
     are the admission currency, so the pool shrinks block-granularly
@@ -166,7 +177,9 @@ def fit_block_pool(cfg, n_blocks: int, *, block_size: int, min_blocks: int,
     pool costs capacity for LIVE context only — cold (cached) blocks
     spill to the host mirror under pressure and page back at resume, so
     the device size stops bounding how many idle sessions keep their
-    KV."""
+    KV. ``state_bytes`` is a hybrid decoder's recurrent state pool
+    (kvblocks.state_pool_bytes): it does not shrink with the blocks, so it
+    is charged whole, beside them."""
     limit = (None if os.environ.get("DLLAMA_SKIP_HBM_CHECK")
              else device_memory_bytes())
     base = estimate_device_bytes(
@@ -177,8 +190,10 @@ def fit_block_pool(cfg, n_blocks: int, *, block_size: int, min_blocks: int,
         pool = estimate_block_pool_bytes(cfg, k, block_size, kv_dtype_bytes)
         est = dict(base)
         est["kv_pool_bytes"] = pool
-        est["need_per_device"] = (base["need_per_device"]
-                                  + int(pool / max(1, n_shards) * _MARGIN))
+        est["state_pool_bytes"] = state_bytes
+        est["need_per_device"] = (
+            base["need_per_device"]
+            + int((pool / max(1, n_shards) + state_bytes) * _MARGIN))
         return est
 
     n = max(min_blocks, n_blocks)

@@ -277,6 +277,25 @@ class CompileLedger:
             g.set(n, scope=entry["scope"], program=entry["program"],
                   path=path)
 
+    def note_gdn_paths(self, entry: dict, paths: dict | None) -> None:
+        """The gated delta-rule twin of :meth:`note_q40_paths`
+        (:func:`note_gdn_path`): gauge ``dllama_gated_delta_paths``."""
+        if not paths:
+            return
+        with self._lock:
+            entry["gdn_paths"] = dict(paths)
+        g = telemetry.registry().gauge(telemetry.GATED_DELTA_PATHS)
+        for key, n in paths.items():
+            form, path = key.split(":")
+            g.set(n, scope=entry["scope"], program=entry["program"],
+                  form=form, path=path)
+
+    def gdn_paths(self, scope: str) -> dict[str, dict[str, int]]:
+        with self._lock:
+            return {program: dict(entry["gdn_paths"])
+                    for (sc, program), entry in sorted(self._programs.items())
+                    if sc == scope and entry.get("gdn_paths")}
+
     def q40_paths(self, scope: str) -> dict[str, dict[str, int]]:
         """``{program: {path: count}}`` for the programs of ``scope`` whose
         newest trace held a Q40 matmul."""
@@ -416,6 +435,16 @@ def note_q40_path(path: str) -> None:
         paths[path] += 1
 
 
+def note_gdn_path(form: str, path: str) -> None:
+    """``models.hybrid`` calls this while a gated delta-rule mixer is traced:
+    which ``form`` (``chunk`` or ``step``) took which ``path`` (``pallas`` or
+    ``xla``). Filed like :func:`note_q40_path`, under ``form:path``."""
+    win = getattr(_tls, "window", None)
+    if win is not None:
+        paths = win.setdefault("gdn", {})
+        paths[f"{form}:{path}"] = paths.get(f"{form}:{path}", 0) + 1
+
+
 def _monitoring_on() -> bool:
     if not _monitoring_state:
         try:
@@ -478,6 +507,7 @@ class ObservedJit:
             analysis = {"error": f"{type(e).__name__}: {e}"}
             sig = {}
         _ledger.note_q40_paths(self._entry, win.get("q40"))
+        _ledger.note_gdn_paths(self._entry, win.get("gdn"))
         _ledger.record(self._entry, compile_s, sig, _plan_desc(), analysis,
                        backend_s=win["backend_s"])
         return out
@@ -492,6 +522,7 @@ class ObservedJit:
         finally:
             _tls.window = prev
             _ledger.note_q40_paths(self._entry, win.get("q40"))
+            _ledger.note_gdn_paths(self._entry, win.get("gdn"))
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)
@@ -528,8 +559,12 @@ def startup_line(engine) -> str:
     build phase, the serving generator's included once it is built), one
     line beside the HBM budget."""
     parts = getattr(engine, "startup_s", None) or {}
+    cfg = engine.cfg
+    kinds = (f"; layers: {cfg.n_linear_layers} linear, {cfg.n_kv_layers} full"
+             if cfg.is_hybrid else "")
     return (f"🧮 start-up: {sum(parts.values()):.2f} s ("
-            + ", ".join(f"{k} {v:.2f}" for k, v in parts.items()) + ")")
+            + ", ".join(f"{k} {v:.2f}" for k, v in parts.items()) + ")"
+            + kinds)
 
 
 def q40_paths_line(scope: str) -> str:
@@ -544,7 +579,21 @@ def q40_paths_line(scope: str) -> str:
         for program, n in by_program.items())
 
 
+def gdn_paths_line(scope: str) -> str:
+    """Which path each program's gated delta-rule mixers took, by form
+    (:func:`note_gdn_path`); empty for a model without them."""
+    by_program = _ledger.gdn_paths(scope)
+    if not by_program:
+        return ""
+    return "🧮 gated delta rule: " + "; ".join(
+        f"{program} " + ", ".join(f"{n} {key}" for key, n in sorted(paths.items()))
+        for program, paths in by_program.items())
+
+
 def _emit_q40_paths(scope: str, emit) -> None:
+    gdn = gdn_paths_line(scope)
+    if gdn:
+        emit(gdn)
     line = q40_paths_line(scope)
     if line:
         emit(line)

@@ -178,7 +178,8 @@ class PagedKVCache(NamedTuple):
         import jax.numpy as jnp
 
         dtype = dtype if dtype is not None else jnp.float32
-        shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size,
+        # a hybrid decoder caches K/V in its full layers only
+        shape = (cfg.n_kv_layers, n_blocks, cfg.n_kv_heads, block_size,
                  cfg.head_dim)
         return cls(k=jnp.zeros(shape, dtype=dtype),
                    v=jnp.zeros(shape, dtype=dtype))
@@ -190,6 +191,56 @@ class PagedKVCache(NamedTuple):
     @property
     def block_size(self) -> int:
         return self.k.shape[3]
+
+
+class StatePool(NamedTuple):
+    """Device-side recurrent state of a hybrid decoder's linear-attention
+    layers (models/hybrid.py), slot-indexed, beside the K/V block pool:
+    ``s [n_linear, rows, H, dk, dv]`` float32 and the convolution's last
+    ``K - 1`` inputs ``conv [n_linear, rows, K - 1, channels]``. Row 0 is the
+    NULL row, as block 0 is the null block: inactive slots riding along a
+    decode step read and write it, and its contents are value-invisible.
+    Slot ``i`` owns row ``i + 1``.
+
+    Its rules are not the block pool's. A state is one slot's, never
+    shared: it is a function of the whole prefix, so a matched prefix block
+    brings no state with it, and block-level prefix sharing is off for a
+    model that has one. It starts at zero in the admission
+    (``HybridColumn.zeros``), is carried from prefill chunk to chunk there,
+    is written to the slot's row ONCE, at commit, goes through every decode
+    step in place, and is simply left behind at retirement: the next
+    admission's commit overwrites the row."""
+
+    s: "jax.Array"
+    conv: "jax.Array"
+
+    NULL = 0
+
+    @classmethod
+    def create(cls, cfg, n_slots: int, conv_dtype) -> "StatePool":
+        import jax.numpy as jnp
+
+        from ..models.hybrid import conv_shape, state_shape
+
+        return cls(s=jnp.zeros(state_shape(cfg, n_slots + 1), jnp.float32),
+                   conv=jnp.zeros(conv_shape(cfg, n_slots + 1), conv_dtype))
+
+    @property
+    def n_bytes(self) -> int:
+        return self.s.nbytes + self.conv.nbytes
+
+
+def state_pool_bytes(cfg, n_slots: int, conv_dtype_bytes: int) -> int:
+    """Device bytes of :class:`StatePool` for ``n_slots`` (0 for a model
+    without recurrent layers): what ``runtime/hbm.py``'s fit counts."""
+    if not cfg.is_hybrid:
+        return 0
+    import math
+
+    from ..models.hybrid import conv_shape, state_shape
+
+    return (math.prod(state_shape(cfg, n_slots + 1)) * 4
+            + math.prod(conv_shape(cfg, n_slots + 1)) * conv_dtype_bytes)
 
 
 class HostKVMirror:

@@ -594,7 +594,7 @@ class _GeneratorCore:
         later, by :meth:`_settle_prefill`, when the next step's fetch has
         waited for it."""
         t0 = telemetry.now_ns()
-        adm.col = self._exec_prefill(adm.col, padded, adm.pos)
+        adm.col = self._exec_prefill(adm.col, padded, adm.pos, n_valid)
         self._chunks_pending.append(
             _PendingChunk(adm.req, adm.slot, len(padded), n_valid, t0))
         self._tenancy.note_prefill_tokens(adm.req.tenant, n_valid)
@@ -959,7 +959,10 @@ class BatchedGenerator(_GeneratorCore):
     def _exec_take(self, src: int):
         return self._take(self.kv, src)
 
-    def _exec_prefill(self, col, padded, pos: int):
+    def _exec_prefill(self, col, padded, pos: int, n_valid: int):
+        # a dense decoder pads freely (padded K/V rows are overwritten
+        # later): n_valid is the paged generator's, for a recurrent state
+        del n_valid
         with self.eng.watchdog.guard("batch_prefill"):
             failpoints.fire("step_hang")
             with self._plan_ctx():
@@ -1380,8 +1383,8 @@ class PagedGenerator(_GeneratorCore):
     """
 
     def __init__(self, engine: "InferenceEngine", n_slots: int = 4):
-        from ..runtime.kvblocks import (BlockPool, PagedKVCache,
-                                        blocks_per_seq)
+        from ..runtime.kvblocks import (BlockPool, PagedKVCache, StatePool,
+                                        blocks_per_seq, state_pool_bytes)
         from .hbm import check_budget, fit_block_pool
 
         block_size = int(getattr(engine, "kv_block_size", 0) or 0)
@@ -1405,7 +1408,9 @@ class PagedGenerator(_GeneratorCore):
             weight_repr=getattr(engine, "hbm_weight_repr", "q40"),
             kv_dtype_bytes=engine.kv_dtype.itemsize,
             n_shards=engine.tp * engine.pp,
-            offload=(engine.weight_mode == "offload"))
+            offload=(engine.weight_mode == "offload"),
+            state_bytes=state_pool_bytes(
+                self.cfg, n_slots, jnp.dtype(self.cfg.compute_dtype).itemsize))
         if n_blocks == 0:
             check_budget(est["need_per_device"],
                          f"paged serving ({want} blocks of {block_size})")
@@ -1437,6 +1442,12 @@ class PagedGenerator(_GeneratorCore):
 
             pkv = jax.device_put(pkv, paged_kv_sharding(engine.plan, pkv))
         self.pkv = pkv
+        # a hybrid decoder's recurrent state, slot-indexed, beside the
+        # blocks (kvblocks.StatePool has its rules: never shared, written
+        # once at commit, in place through every step)
+        self.spool = (StatePool.create(self.cfg, n_slots,
+                                       jnp.dtype(self.cfg.compute_dtype))
+                      if self.cfg.is_hybrid else None)
         # per-slot block tables (host truth; shipped per dispatch as a
         # traced [n_slots, table_width] int32 — values never recompile)
         self.tables = np.zeros((n_slots, self.table_width), dtype=np.int32)
@@ -1485,6 +1496,20 @@ class PagedGenerator(_GeneratorCore):
                                  M * bs, self.cfg.head_dim)
             return KVCache(k=view(pkv.k), v=view(pkv.v))
 
+        def _take_hybrid_fn(pkv, table):
+            # an admission starts from a zero state: prefix blocks are
+            # never shared here, so a column is always a sequence's first
+            from ..models.hybrid import HybridColumn
+
+            kv = _take_fn(pkv, table)
+            return HybridColumn.zeros(self.cfg, kv.k, kv.v,
+                                      self.spool.conv.dtype)
+
+        def _state_put_fn(spool, s, conv, row):
+            put = jax.lax.dynamic_update_index_in_dim
+            return StatePool(s=put(spool.s, s[:, 0], row, 1),
+                             conv=put(spool.conv, conv[:, 0], row, 1))
+
         def _put_fn(pkv, col, table):
             def back(pool, c):
                 L = c.shape[0]
@@ -1504,7 +1529,10 @@ class PagedGenerator(_GeneratorCore):
         # raw jit is deliberate for the three block-movement programs:
         # plan-independent gather/scatter/copy (no constrain()), safe to
         # share across engines — same argument as the dense pool's pair
-        self._take = jax.jit(_take_fn)  # dlint: disable=jit-entry
+        self._take = jax.jit(_take_hybrid_fn if self.cfg.is_hybrid else _take_fn)  # dlint: disable=jit-entry
+        # a hybrid decoder's commit writes the admission's state to the
+        # slot's row of the state pool, in place
+        self._state_put = jax.jit(_state_put_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
         self._put = jax.jit(_put_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
         self._copy_block = jax.jit(_copy_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
         # KV migration wire (runtime/kvwire): export gathers one block at
@@ -1580,6 +1608,12 @@ class PagedGenerator(_GeneratorCore):
         self._m_pagein_ms = self._tm.counter(telemetry.KV_PAGEIN_MS)
         self._m_blocks_total.set(n_blocks - 1)
         self._m_host_total.set(self.pool.n_host_blocks)
+        self._m_state_used = self._tm.gauge(telemetry.STATE_SLOTS_USED)
+        self._m_skipped = self._tm.counter(telemetry.PREFIX_REUSE_SKIPPED)
+        self._tm.gauge(telemetry.STATE_SLOTS_TOTAL).set(
+            n_slots if self.spool is not None else 0)
+        self._tm.gauge(telemetry.STATE_POOL_BYTES).set(
+            self.spool.n_bytes if self.spool is not None else 0)
         self._update_block_gauges()
         engine._stamp_startup("generator", t_phase)
 
@@ -1587,6 +1621,8 @@ class PagedGenerator(_GeneratorCore):
 
     def _update_block_gauges(self) -> None:
         self._m_blocks_used.set(self.pool.used_blocks())
+        if self.spool is not None:
+            self._m_state_used.set(self.n_active)
         self._m_blocks_shared.set(self.pool.shared_blocks())
         if self.pool.n_host_blocks:
             self._m_host_used.set(self.pool.host_used_blocks())
@@ -1765,6 +1801,13 @@ class PagedGenerator(_GeneratorCore):
                 "head_dim": self.cfg.head_dim,
                 "dtype": str(_np.dtype(self.eng.kv_dtype))}
 
+    def _refuse_wire(self) -> None:
+        if self.spool is not None:
+            raise ValueError(
+                "kvwire export/ingest moves K/V blocks between replicas; a "
+                "hybrid decoder's blocks are useless without the recurrent "
+                "state, which has no wire format")
+
     def export_prefix(self, tokens: list[int]) -> tuple[int, list]:  # dlint: owner=loop-thread
         """Gather the device-resident shared-prefix blocks matching
         ``tokens`` for a peer's ``/v1/kv/export`` pull: ``(n_tokens,
@@ -1775,6 +1818,7 @@ class PagedGenerator(_GeneratorCore):
         across the gather so a concurrent admission's pressure cannot
         spill or evict them mid-read, and released after — refcounts
         balance exactly."""
+        self._refuse_wire()
         shared, _n_tok, _cow, _cow_r = self.pool.match_prefix(list(tokens))
         dev: list[int] = []
         for b in shared:
@@ -1809,6 +1853,7 @@ class PagedGenerator(_GeneratorCore):
         a failed ingest leaves the pool untouched. Returns the number of
         prefix tokens now resident (0 when already matched locally —
         a duplicate migration must not burn blocks)."""
+        self._refuse_wire()
         n_tokens = len(blocks) * self.block_size
         usable = list(tokens[:n_tokens])
         if len(usable) < n_tokens:
@@ -1870,6 +1915,17 @@ class PagedGenerator(_GeneratorCore):
             shared, n_tok, cow_src, cow_r = [], 0, None, 0
         else:
             shared, n_tok, cow_src, cow_r = self.pool.match_prefix(rest)
+        if self.spool is not None:
+            if req.score:
+                raise ValueError(
+                    "teacher-forced scoring is not carried to a hybrid "
+                    "decoder's recurrent state (its chunks run unmasked)")
+            # a matched block holds K/V this request did not compute and
+            # NO state of the linear layers: nothing is reused, and the
+            # counter says a match was passed over
+            if n_tok or (cow_src is not None and cow_r > 0):
+                self._m_skipped.inc(reason="recurrent_state")
+            shared, n_tok, cow_src, cow_r = [], 0, None, 0
         # KV tier: matched blocks may be HOST-resident (a resumed /
         # prefix-matched session whose cold blocks spilled under
         # pressure). Stage their page-in NOW — device blocks allocated
@@ -1941,7 +1997,9 @@ class PagedGenerator(_GeneratorCore):
             # gather/scatter round-trip entirely — THE hot path of
             # repeated system prompts, where reuse must mean zero device
             # work beyond the one CoW copy
-            need_take = reused < len(rest)
+            # (a hybrid decoder always takes one: its column carries the
+            # zero state its commit writes to the slot's row)
+            need_take = reused < len(rest) or self.spool is not None
             col = (self._exec_take(bids)
                    if need_take and not pairs else None)
         except Exception as e:  # noqa: BLE001 — atomic rollback, re-raised
@@ -2009,16 +2067,20 @@ class PagedGenerator(_GeneratorCore):
 
             return jax.device_put(col, kv_cache_sharding(self.eng.plan, col))
         s = jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
-        return jax.device_put(col, KVCache(k=s, v=s))
+        return jax.device_put(col, jax.tree.map(lambda _: s, col))
 
-    def _exec_prefill(self, col, padded, pos: int):
+    def _exec_prefill(self, col, padded, pos: int, n_valid: int):
+        # a recurrent state would keep what padding wrote into it: the
+        # hybrid's chunk carries its valid length (models/hybrid.forward);
+        # a dense decoder pads freely and is passed none
+        valid = (jnp.int32(n_valid),) if self.cfg.is_hybrid else ()
         with self.eng.watchdog.guard("batch_prefill"):
             failpoints.fire("step_hang")
             with self._plan_ctx():
                 _, col = self._prefill_fwd(
                     self.eng.params, self.cfg,
                     jnp.asarray(np.asarray(padded).reshape(1, -1), jnp.int32),
-                    jnp.int32(pos), col)
+                    jnp.int32(pos), col, *valid)
             return col
 
     def continue_admit(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
@@ -2033,8 +2095,10 @@ class PagedGenerator(_GeneratorCore):
         with self.flight.tick_phase("prefill_dispatch"):
             if not self._advance_prefill(adm):
                 return False
-        with self.flight.tick_phase("admit_commit"):
+        with self.flight.tick_phase("admit_commit") as span:
             self._commit_admit(adm)
+            if self.spool is not None and not adm.req.score:
+                span.set(state_bytes=adm.col.s.nbytes + adm.col.conv.nbytes)
         return True
 
     def _advance_prefill(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
@@ -2102,8 +2166,13 @@ class PagedGenerator(_GeneratorCore):
                                 dtype=np.int32)
             n_sh = self._n_shared[slot]
             put_table[n_sh:len(bids)] = bids[n_sh:]
-            self.pkv = self._put(self.pkv, adm.col,
+            self.pkv = self._put(self.pkv, KVCache(k=adm.col.k, v=adm.col.v),
                                  jnp.asarray(put_table))
+        if self.spool is not None:
+            # the state's one write: the admission's carry becomes the
+            # slot's row (the previous occupant's state goes with it)
+            self.spool = self._state_put(self.spool, adm.col.s, adm.col.conv,
+                                         jnp.int32(slot + 1))
         self.pool.register_prompt(bids, rest)
         # the table goes live only NOW, with the committed pos riding in
         # _arm_decode — no dispatch ever sees this slot's real table
@@ -2255,14 +2324,22 @@ class PagedGenerator(_GeneratorCore):
         with self.flight.tick_phase("step_dispatch") as wait, \
                 self.eng.watchdog.guard("batch_step"):
             failpoints.fire("step_hang")
+            # a hybrid decoder's step takes the state pool beside the
+            # blocks, both donated, and gives both back
+            cache = (self.pkv if self.spool is None
+                     else (self.pkv, self.spool))
             with self._plan_ctx():
-                (nxt, nf), self.pkv = self._step(
+                (nxt, nf), cache = self._step(
                     self.eng.params, self.cfg,
                     jnp.asarray(self.next_token.astype(np.int32)[:, None]),
-                    jnp.asarray(self.pos.astype(np.int32)), self.pkv,
+                    jnp.asarray(self.pos.astype(np.int32)), cache,
                     jnp.asarray(self.tables),
                     jnp.asarray(temps), jnp.asarray(topps),
                     jnp.asarray(coins), self._poison())
+            if self.spool is None:
+                self.pkv = cache
+            else:
+                self.pkv, self.spool = cache
             wait.next_phase("step_wait")
             nxt, nf = np.asarray(nxt), np.asarray(nf)
         ms = (time.perf_counter() - t0) * 1000.0

@@ -185,6 +185,12 @@ COMPILE_SECONDS = "dllama_compile_seconds"
 PROGRAM_HBM_BYTES = "dllama_program_hbm_bytes"
 PROGRAM_FLOPS = "dllama_program_flops"
 Q40_MATMUL_PATHS = "dllama_q40_matmul_paths"
+GATED_DELTA_PATHS = "dllama_gated_delta_paths"
+LAYER_KINDS = "dllama_layer_kinds"
+STATE_SLOTS_USED = "dllama_state_slots_used"
+STATE_SLOTS_TOTAL = "dllama_state_slots_total"
+STATE_POOL_BYTES = "dllama_state_pool_bytes"
+PREFIX_REUSE_SKIPPED = "dllama_prefix_reuse_skipped_total"
 RETRACE_UNEXPECTED = "dllama_retrace_unexpected_total"
 
 # latency buckets in ms: sub-ms CPU ticks through multi-second TPU compiles
@@ -430,6 +436,27 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "Q40 matmuls of a program's newest trace by the path linear() "
           "gave them: fused (the decode dequant-GEMV kernel), tiled (the "
           "(n, k)-tiled Pallas kernel) or xla (dequant + dot)"),
+    _spec(GATED_DELTA_PATHS, "gauge",
+          "Gated delta-rule mixers of a program's newest trace by form "
+          "(chunk: a prefill chunk; step: the decode step) and the path "
+          "they took: pallas (the gated_delta_step kernel) or xla"),
+    _spec(LAYER_KINDS, "gauge",
+          "Layers of the loaded model by kind (linear: gated delta-rule "
+          "layers with a recurrent state; full: softmax attention with a "
+          "K/V cache); a dense decoder is all full"),
+    _spec(STATE_SLOTS_USED, "gauge",
+          "Rows of the recurrent state pool held by live sequences "
+          "(committed and not yet retired); 0 without recurrent layers"),
+    _spec(STATE_SLOTS_TOTAL, "gauge",
+          "Usable rows of the recurrent state pool (excludes the null "
+          "row); 0 without recurrent layers"),
+    _spec(STATE_POOL_BYTES, "gauge",
+          "Device bytes of the recurrent state pool: float32 states and "
+          "the convolution tails of every row, the null row included"),
+    _spec(PREFIX_REUSE_SKIPPED, "counter",
+          "Admissions whose prompt matched cached prefix blocks that were "
+          "NOT reused, by reason (recurrent_state: the blocks carry K/V "
+          "but no state of the linear-attention layers)"),
     _spec(RETRACE_UNEXPECTED, "counter",
           "Recompiles observed AFTER an engine scope reached serving "
           "steady state (each is a latency cliff; the shape/plan diff is "

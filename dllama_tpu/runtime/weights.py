@@ -292,7 +292,7 @@ class _StreamingLoader:
 
     def matmul(self, name: str, out_dim: int, in_dim: int, *, stacked: bool,
                out_axis: str | None, in_axis: str | None,
-               force_dense: object = None):
+               force_dense: object = None, layers: list[int] | None = None):
         """One (possibly layer-stacked) matmul weight, quantized or dense.
 
         ``force_dense`` (a dtype) loads a quantized disk tensor as a resident
@@ -300,8 +300,11 @@ class _StreamingLoader:
         XLA materializes the huge [dim, vocab] dequant every step anyway
         (166 GB/s effective) while a resident bf16 head streams at
         ~750 GB/s (tools/gemv_sweep.py)."""
-        L = self.h.n_layers
-        key = (lambda l: f"{name}.{l}") if stacked else (lambda _l: name)
+        # ``layers``: the model's layers this stack holds, in order (a
+        # hybrid decoder's two stacks); absent, every layer
+        ids = list(range(self.h.n_layers)) if layers is None else layers
+        L = len(ids)
+        key = (lambda l: f"{name}.{ids[l]}") if stacked else (lambda _l: name)
 
         if self.quantized and force_dense is None:
             lead = ("layers",) if stacked else ()  # pipeline axis when present
@@ -372,15 +375,17 @@ class _StreamingLoader:
 
     # -- small / dense tensors ---------------------------------------------
 
-    def stacked_f32(self, name: str, *shape_tail: int) -> jax.Array:
-        L = self.h.n_layers
+    def stacked_f32(self, name: str, *shape_tail: int,
+                    layers: list[int] | None = None) -> jax.Array:
+        ids = list(range(self.h.n_layers)) if layers is None else layers
+        L = len(ids)
         shape = (L, *shape_tail)
         sh = self._sharding(shape, "layers", *([None] * len(shape_tail)))
 
         def read(idx):
-            layers = _layer_range(idx[0], L)
             return np.stack([
-                self.rd.tensor_f32(f"{name}.{l}") for l in layers])
+                self.rd.tensor_f32(f"{name}.{ids[l]}").reshape(shape_tail)
+                for l in _layer_range(idx[0], L)])
 
         return _make(shape, jnp.float32, sh, read)
 
@@ -467,6 +472,65 @@ class _StreamingLoader:
         return _make(shape, target, sh, read)
 
 
+def _load_hybrid_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
+    """A hybrid decoder's two stacks (models/hybrid.py) from the tensors
+    ``mfile._walk_hybrid_layer`` names: the linear layers' and the full
+    layers', each stacked over its own layers of the model."""
+    from ..models.hybrid import HybridLayers, LinearLayerParams
+    from ..models.llama import LayerParams, Params
+
+    h = ld.h
+    P = h.layer_period
+    lin_ids = [l for l in range(h.n_layers) if (l + 1) % P]
+    full_ids = [l for l in range(h.n_layers) if (l + 1) % P == 0]
+    vdim = h.linear_n_value_heads * h.linear_value_head_dim
+
+    def stack(ids):
+        mm = lambda name, o, i, **kw: ld.matmul(
+            name, o, i, stacked=True, out_axis=None, in_axis=None,
+            layers=ids, **kw)
+        f32 = lambda name, *tail: ld.stacked_f32(name, *tail, layers=ids)
+        return mm, f32
+
+    mm, f32 = stack(lin_ids)
+    lin = LinearLayerParams(
+        w_in=mm("block_gdn_in", h.linear_in_dim, h.dim),
+        w_ab=f32("block_gdn_ab", 2 * h.linear_n_value_heads, h.dim),
+        conv_w=f32("block_gdn_conv", h.linear_conv_kernel, h.linear_conv_dim),
+        a_log=f32("block_gdn_a_log", h.linear_n_value_heads),
+        dt_bias=f32("block_gdn_dt_bias", h.linear_n_value_heads),
+        norm_o=f32("block_gdn_norm", h.linear_value_head_dim),
+        w_out=mm("block_gdn_out", h.dim, vdim),
+        w1=mm("block_matmul_w1", h.hidden_dim, h.dim),
+        w2=mm("block_matmul_w2", h.dim, h.hidden_dim),
+        w3=mm("block_matmul_w3", h.hidden_dim, h.dim),
+        norm_att=f32("block_norm_0", h.dim),
+        norm_ffn=f32("block_norm_1", h.dim))
+    mm, f32 = stack(full_ids)
+    full = LayerParams(
+        wq=mm("block_matmul_q", h.q_dim, h.dim),
+        wk=mm("block_matmul_k", h.kv_dim, h.dim),
+        wv=mm("block_matmul_v", h.kv_dim, h.dim),
+        wo=mm("block_matmul_wo", h.dim, h.q_dim),
+        w1=mm("block_matmul_w1", h.hidden_dim, h.dim),
+        w2=mm("block_matmul_w2", h.dim, h.hidden_dim),
+        w3=mm("block_matmul_w3", h.hidden_dim, h.dim),
+        norm_att=f32("block_norm_0", h.dim),
+        norm_ffn=f32("block_norm_1", h.dim),
+        norm_q=f32("block_norm_q", h.q_dim),
+        norm_k=f32("block_norm_k", h.kv_dim))
+    return Params(
+        embedding=ld.f32("embedding", h.vocab_size, h.dim,
+                         dtype=jnp.dtype(cfg.compute_dtype)),
+        layers=HybridLayers(lin=lin, full=full),
+        final_norm=ld.f32("final_norm", h.dim),
+        logits=ld.matmul(
+            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
+            out_axis="vocab", in_axis=None,
+            force_dense=(jnp.bfloat16
+                         if dense_logits_wanted(ld.fast_numerics) else None)))
+
+
 def load_params(mf: ModelFile, cfg: "ModelConfig", weight_mode: str = "auto",
                 plan: MeshPlan | None = None) -> "Params":
     """Build fully-placed (and, under a plan, fully-sharded) device params.
@@ -486,6 +550,8 @@ def load_params(mf: ModelFile, cfg: "ModelConfig", weight_mode: str = "auto",
             "python -m dllama_tpu.convert")
     ld = _StreamingLoader(mf, cfg, plan, weight_mode)
     qwen3 = h.arch_type == ArchType.QWEN3
+    if h.arch_type == ArchType.OLMO_HYBRID:
+        return _load_hybrid_params(ld, cfg)
 
     # Under offload only the per-layer stacks go host-side: they are the
     # O(model) bytes and stream through the scan; embedding / final norm /

@@ -119,10 +119,14 @@ MISTRAL_7B = [(4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096)]
 QWEN3_4B = [(2560, 4096), (2560, 1024), (4096, 2560), (2560, 9728),
             (9728, 2560)]
 HEADS = [(4096, 32768), (2560, 151936)]
+# allenai/Olmo-Hybrid-7B: the mixer's packed q k v z plane (17280 = 135 x 128)
+# and its output plane, q k v wo of the full layers, the feed-forward
+OLMO_HYBRID_7B = [(3840, 17280), (5760, 3840), (3840, 3840), (3840, 11008),
+                  (11008, 3840)]
 
 
 @pytest.mark.parametrize("rows", [4, 16])
-@pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B)
+@pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B + OLMO_HYBRID_7B)
 def test_decode_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
     """The fused dequant-GEMV as the paged step calls it in fast mode: bf16
     rows, bf16 scales as a fast-mode load stores them, the LAYER STACK and
@@ -218,6 +222,45 @@ def test_paged_attention_compiles_for_v5e(one_chip, block, per_seq, slots,
                           interpret=False),
         q, kv, kv, _shape(one_chip, (slots, per_seq), jnp.int32),
         _shape(one_chip, (slots, 1), jnp.int32))
+    assert kernels.get("paged_ragged_attention") == 1, kernels
+
+
+@pytest.mark.parametrize("slots", [4, 16])
+def test_gated_delta_step_compiles_for_v5e(one_chip, slots):
+    """The step form's kernel at Olmo-Hybrid-7B's sizes (24 linear layers, 30
+    heads of 96 x 192), over the state pool in place: one Mosaic kernel, its
+    output aliased onto the pool."""
+    from dllama_tpu.ops.gated_delta import gated_delta_step
+
+    H, dk, dv = 30, 96, 192
+    f32 = jnp.float32
+    pool = _shape(one_chip, (24, slots + 1, H, dk, dv), f32)
+    vec = lambda *tail: _shape(one_chip, (slots, H) + tail, f32)
+    compiled = jax.jit(functools.partial(gated_delta_step, interpret=False),
+                       donate_argnums=(0,)).lower(
+        pool, _shape(one_chip, (), jnp.int32), _shape(one_chip, (slots,), jnp.int32),
+        vec(dk), vec(dk), vec(dv), vec(), vec()).compile()
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+
+    assert mosaic_kernels(compiled.as_text()).get("gated_delta_step") == 1
+    # in place: the program holds no second pool (24 x 5 x 2.2 MB = 265 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024 * 1024
+
+
+def test_paged_attention_compiles_at_30_kv_heads_for_v5e(one_chip):
+    """Olmo-Hybrid-7B's full layers: 30:30 heads of 128 (``kv_mul`` 1), 4
+    slots of 4096 in blocks of 16."""
+    from dllama_tpu.ops.paged_attention import (paged_ragged_attention,
+                                                supports)
+
+    q = _shape(one_chip, (4, 1, 30, 128), jnp.float32)
+    kv = _shape(one_chip, (1025, 30, 16, 128), jnp.bfloat16)
+    assert supports(q.shape, 30, 256, 16)
+    kernels = _compiled_kernels(
+        functools.partial(paged_ragged_attention, head_dim=128,
+                          interpret=False),
+        q, kv, kv, _shape(one_chip, (4, 256), jnp.int32),
+        _shape(one_chip, (4, 1), jnp.int32))
     assert kernels.get("paged_ragged_attention") == 1, kernels
 
 
