@@ -762,8 +762,10 @@ def _paged_layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
     table entries read the null block and are position-masked). The
     TPU-native ragged-paged-attention kernel (ops/paged_attention.py,
     PAPERS.md "Ragged Paged Attention") replaces the gather+oracle pair
-    bit-identically whenever its gate resolves — same callers, same
-    program names, zero extra compiles."""
+    whenever its gate resolves — same callers, same program names, zero
+    extra compiles; the oracle's arithmetic in another reduction order
+    (float32 ulps), bounded by each row's length, zeros on a row whose
+    table starts with the null block."""
     fq = fake_quant_q80 if cfg.sync_q80 else (lambda a: a)
     q, k, v = _attn_qkv(cfg, x, lp, cos, sin, positions, fq)
     att, k_pool, v_pool = _attend_paged(cfg, q, k, v, k_pool, v_pool,
@@ -805,8 +807,9 @@ def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
     kernel = _pa.kernel_choice(tuple(q.shape), cfg.n_kv_heads,
                                n_blocks_seq, bs)
     if kernel is not None:
-        # walk the block table in-kernel: the dense logical cache never
-        # materializes in HBM (the whole point of the paged kernel)
+        # walk the block table in-kernel, as far as each row is long: the
+        # dense logical cache never materializes in HBM, and a dead row
+        # (an all-null table, whatever its stale position) costs nothing
         att = _pa.paged_ragged_attention(q, k_pool, v_pool, tables,
                                          positions, cfg.head_dim, **kernel)
     else:

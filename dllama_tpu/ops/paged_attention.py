@@ -6,28 +6,54 @@ cache per layer per step (``pool[tables]`` writes ``[B, M, n_kv, bs, hd]``
 to HBM, then the oracle reads it straight back), so the paged program
 family pays the KV bytes twice plus a scatter's worth of write bandwidth.
 This kernel is the "Ragged Paged Attention" shape (PAPERS.md, arxiv
-2604.15464): the block table rides in as a scalar-prefetch operand and the
-kernel's *index maps* walk it directly — grid step ``(b, h, m)`` DMAs
-physical block ``tables[b, m]`` of the pool straight into VMEM, so the
-dense logical cache never exists in HBM at all.
+2604.15464): the pools stay in HBM, the block table and each row's bound
+ride in as scalar-prefetch operands, and the kernel fetches the physical
+blocks a row OWNS straight into VMEM, so the dense logical cache never
+exists in HBM at all.
 
-Semantics are exactly the gather+oracle pair's, bit for bit:
+**It walks what is live.** The walk and the softmax are bounded per row
+by what the inputs show:
 
-* **ragged rows** — every batch row sits at its own depth; query row ``r``
-  (GQA-folded, source position ``pos0[b] + r // kv_mul``) sees cache
-  columns ``s <= pos0[b] + r // kv_mul``, the oracle's position mask;
+* **the bound** — ``len[b] = pos0[b] + T`` where the row's first table
+  entry is a real block, 0 where it is the null block (a retired slot
+  keeps a stale ``pos`` and an all-null table, a slot under admission has
+  a null table until commit: liveness is the table's to say). The walk
+  covers ``ceil(len[b] / bs)`` table entries; both are traced values, so
+  table contents and depths vary dispatch to dispatch without a retrace;
+* **dead rows** — a row of length 0 fetches nothing and writes zeros
+  (finite: its logits still pass ``_nonfinite_rows``);
+* **whole blocks a fetch** — one ``make_async_copy`` moves a physical
+  block for every K/V head of the grid step's head group (the pool is
+  ``[n_blocks, n_kv, bs, hd]``: contiguous over heads), ``G`` blocks a
+  loop iteration, double-buffered, the next row's first group started
+  under this row's last; the grid is rows x head groups and the loop's
+  trip count is the row's. ``_plan`` picks the head group and ``G`` from
+  the static shapes against ``_VMEM_BUDGET``: no knob, no model's name.
+
+Semantics are the gather+oracle pair's on every live row:
+
+* **ragged rows** — query row ``r`` (GQA-folded, source position
+  ``pos0[b] + r // kv_mul``) sees cache columns ``s <= pos0[b] + r //
+  kv_mul``, the oracle's position mask;
 * **partial tail block** — the row's newest block is masked per position,
   not per block, so a mid-block write point behaves identically;
-* **null block 0** — unallocated table tail entries point at physical
-  block 0 (runtime/kvblocks.py); its rows are gathered and then position-
-  masked to zero weight, the same argument as the oracle's padded tails.
+* **null block 0** — a null entry INSIDE the walk (a verify lane's
+  padding) is fetched and position-masked like the oracle's; entries
+  past the walk are never read. The last fetch group re-reads the row's
+  own newest block in place of entries past its bound, so a row only
+  ever sees bytes of blocks it owns (a neighbour's NaN cannot leak in
+  through a masked column's ``0 * NaN``).
 
-Per (b, h) instance the kernel stages per-block score stripes and f32
-value rows into VMEM scratch and runs the oracle's own epilogue (scale →
-mask → softmax → weighted sum) on the assembled arrays, so the math is
-op-for-op the oracle's and interpret-mode parity is bitwise
-(tests/test_paged_attention.py drives scrambled tables, CoW-redirects,
-T=1/T=16 and non-128-aligned head dims against the dense reference).
+The arithmetic is the oracle's (float32 scores, statistics, accumulator
+and probabilities; dots at the oracle's operand widths and the ambient
+``precision``); what differs is the ORDER of the reductions over the
+cache axis: a running maximum, running sum and accumulator over fetch
+groups (online softmax) instead of one softmax over the whole table. So
+parity is to a tolerance, not bitwise: ``2e-6`` in interpret mode
+(tests/test_paged_attention.py: scrambled tables, CoW-redirects, dead
+rows, ragged lengths around block and group edges, T=1/T=16, 30:30
+heads, non-128-aligned head dims), ``2e-5`` compiled on the chip under
+``highest`` (tools/paged_attn_sweep.py; PERF.md section 6, PR 31).
 
 Mode selection routes through :func:`quant_matmul.pallas_mode_gate` — the
 ONE kernel gate (dlint rule ``pallas-gate``): ``auto`` enables the kernel
@@ -46,77 +72,192 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, out_ref,
-            kbuf_ref, vbuf_ref, *, bs: int, kv_mul: int, hd: int):
-    """One (b, h, m) grid step over physical block ``tables[b, m]``.
+def _kernel(tbl_ref, pos_ref, nblk_ref, q_ref, k_hbm, v_hbm, out_ref,
+            kbuf, vbuf, sems, m_ref, l_ref, slot_ref, *,
+            bs: int, kv_mul: int, hd: int, group: int, heads: int,
+            n_entries: int):
+    """One (row, head group) grid step: walk the row's ``nblk`` table
+    entries in fetch groups of ``group`` blocks.
 
-    ``kbuf_ref`` / ``vbuf_ref [S, hd]`` assemble the (b, h) instance's f32
-    logical K/V rows (S-major, so the per-block writes are sublane
-    slices); the last block runs the oracle's own epilogue — score gemm at
-    the oracle's ``(TQ, hd) x (hd, S)`` contraction shape, scale, position
-    mask, softmax over S, value gemm — the same ops in the same order at
-    the same shapes as ops.attention.attention, which is what makes the
-    kernel bit-identical rather than merely close (an online-softmax
-    rewrite, or even per-block score dots, reassociate the reductions and
-    drift by ulps)."""
-    b = pl.program_id(0)
-    m = pl.program_id(2)
-    nm = pl.num_programs(2)
+    ``kbuf`` / ``vbuf [2, heads, group * bs, D]`` are the double-buffered
+    landing zones (pool dtype, head-major so a head's keys are one 2-D
+    slab); ``out_ref`` doubles as the float32 accumulator, ``m_ref`` /
+    ``l_ref`` hold the running maximum and sum. ``slot_ref[0]`` is the
+    buffer half this step's first group lands in: whoever ran before
+    started that fetch (the grid is sequential), so a row's first DMA is
+    hidden under its predecessor's last group."""
+    b, g = pl.program_id(0), pl.program_id(1)
+    n_rows, n_groups = pl.num_programs(0), pl.num_programs(1)
+    gt = group * bs
+    tq = q_ref.shape[2]
+    n = nblk_ref[b]
+    trips = pl.cdiv(n, group)
 
-    kbuf_ref[pl.ds(m * bs, bs), :] = k_ref[0, 0].astype(jnp.float32)
-    vbuf_ref[pl.ds(m * bs, bs), :] = v_ref[0, 0].astype(jnp.float32)
+    def fetch(row, hgrp, j, slot):
+        """The 2 * group copies of fetch group ``j`` of (row, hgrp)."""
+        last = nblk_ref[row] - 1
+        copies = []
+        for i in range(group):
+            # entries past the row's bound re-read its own newest block
+            blk = tbl_ref[row * n_entries + jnp.minimum(j * group + i, last)]
+            for w, (pool, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                copies.append(pltpu.make_async_copy(
+                    pool.at[blk, pl.ds(hgrp * heads, heads)],
+                    buf.at[slot, :, pl.ds(i * bs, bs), :],
+                    sems.at[w, slot]))
+        return copies
 
-    @pl.when(m == nm - 1)
+    # the grid step after this one, and whether it will walk anything
+    wraps = g + 1 == n_groups
+    nxt_row = jnp.where(wraps, b + 1, b)
+    nxt_grp = jnp.where(wraps, 0, g + 1)
+    nxt_live = jnp.logical_and(
+        nxt_row < n_rows, nblk_ref[jnp.minimum(nxt_row, n_rows - 1)] > 0)
+    nxt_row = jnp.minimum(nxt_row, n_rows - 1)
+
+    first = jnp.logical_and(b == 0, g == 0)
+
+    @pl.when(first)
     def _():
-        s_total = nm * bs
-        q = q_ref[0, 0].astype(jnp.float32)      # (TQ, hd)
-        tq = q.shape[0]
-        scores = jax.lax.dot_general(
-            q, kbuf_ref[:], dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (TQ, S)
-        scores = scores / jnp.sqrt(jnp.float32(hd))
-        # the oracle's position mask: column s visible to query row r iff
-        # s <= pos0 + r // kv_mul (ragged depths, partial tail blocks and
-        # null-block garbage all handled by this one rule)
-        row_t = jax.lax.broadcasted_iota(jnp.int32, (tq, s_total), 0) // kv_mul
-        col = jax.lax.broadcasted_iota(jnp.int32, (tq, s_total), 1)
-        scores = jnp.where(col <= pos_ref[b] + row_t, scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out_ref[0, 0] = jax.lax.dot_general(
-            probs, vbuf_ref[:],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (TQ, hd)
+        slot_ref[0] = 0
+
+        @pl.when(n > 0)
+        def _():
+            for c in fetch(b, g, 0, 0):
+                c.start()
+
+    @pl.when(n == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+        @pl.when(nxt_live)
+        def _():
+            for c in fetch(nxt_row, nxt_grp, 0, slot_ref[0]):
+                c.start()
+
+    @pl.when(n > 0)
+    def _():
+        slot0 = slot_ref[0]
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
+        pos0 = pos_ref[b]
+        row_t = jax.lax.broadcasted_iota(jnp.int32, (tq, gt), 0) // kv_mul
+        col = jax.lax.broadcasted_iota(jnp.int32, (tq, gt), 1)
+        # the oracle's position mask, less the group's first column:
+        # column s visible to query row r iff s <= pos0 + r // kv_mul
+        # (ragged depths, partial tail blocks, re-read blocks past the
+        # bound and null-block garbage all handled by this one rule)
+        reach = pos0 + row_t - col
+
+        def body(j, _):
+            slot = (slot0 + j) % 2
+            more = j + 1 < trips
+
+            @pl.when(jnp.logical_or(more, nxt_live))
+            def _():
+                for c in fetch(jnp.where(more, b, nxt_row),
+                               jnp.where(more, g, nxt_grp),
+                               jnp.where(more, j + 1, 0), 1 - slot):
+                    c.start()
+
+            for c in fetch(b, g, j, slot):
+                c.wait()
+            visible = reach >= j * gt
+            for h in range(heads):
+                scores = jax.lax.dot_general(
+                    q_ref[0, h], kbuf[slot, h].astype(jnp.float32),
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # (TQ, gt)
+                scores = scores / jnp.sqrt(jnp.float32(hd))
+                scores = jnp.where(visible, scores, -jnp.inf)
+                m_prev = m_ref[h]
+                # column 0 is visible to every query row, so from the
+                # first group on the running maximum is finite
+                m_next = jnp.maximum(
+                    m_prev, jnp.max(scores, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_next)
+                probs = jnp.exp(scores - m_next)
+                l_ref[h] = alpha * l_ref[h] + jnp.sum(probs, axis=-1,
+                                                      keepdims=True)
+                m_ref[h] = m_next
+                out_ref[0, h] = alpha * out_ref[0, h] + jax.lax.dot_general(
+                    probs, vbuf[slot, h].astype(jnp.float32),
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # (TQ, D)
+
+        jax.lax.fori_loop(0, trips, body, None)
+        out_ref[0] = out_ref[0] / l_ref[...]
+        slot_ref[0] = (slot0 + trips) % 2
 
 
-# VMEM budget for the assembled per-(b, h) resident set: K + V scratch
-# [S, hd] plus the epilogue's score matrix [TQ, S], all f32.
+# VMEM budget for one grid step's resident set (:func:`vmem_bytes`): the
+# double-buffered landing zones, the pipelined q and out blocks, the
+# softmax statistics and one head's float32 temporaries. Under Mosaic's
+# 16 MiB default scoped limit, with room for what the compiler spills.
 _VMEM_BUDGET = 12 * 1024 * 1024
 
 MAX_TQ = 512  # folded query rows per (b, h) instance
 
+# cache positions a fetch group covers: the running softmax's step. 128
+# is one lane tile of scores for every query row.
+_GROUP_TOKENS = 128
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def vmem_bytes(heads: int, group: int, tq: int, D: int, block_size: int,
+               itemsize: int) -> int:  # dlint: static-fn
+    """What one grid step keeps in VMEM at ``heads`` K/V heads and
+    ``group`` blocks a fetch, padded as Mosaic tiles it (8 sublanes x 128
+    lanes of 32 bits)."""
+    gt, d, rows = group * block_size, _round_up(D, 128), _round_up(tq, 8)
+    landing = 2 * 2 * heads * gt * d * itemsize          # K and V, two halves
+    q_out = 2 * 2 * heads * rows * d * 4                 # pipelined blocks
+    stats = 2 * heads * rows * 128 * 4                   # m, l: one lane used
+    temps = 2 * gt * d * 4 + 4 * rows * _round_up(gt, 128) * 4
+    return landing + q_out + stats + temps
+
+
+def _plan(n_kv: int, tq: int, D: int, n_blocks_seq: int, block_size: int,
+          itemsize: int) -> tuple[int, int] | None:  # dlint: static-fn
+    """(K/V heads a grid step, blocks a fetch group) for a geometry, or
+    None where not even one head fits: the most heads (whole blocks are
+    contiguous over heads, so the widest DMA) whose resident set stays
+    under ``_VMEM_BUDGET`` at a group of ``_GROUP_TOKENS`` positions."""
+    group = max(1, min(n_blocks_seq, _GROUP_TOKENS // block_size))
+    for heads in range(n_kv, 0, -1):
+        if n_kv % heads == 0 and vmem_bytes(
+                heads, group, tq, D, block_size, itemsize) <= _VMEM_BUDGET:
+            return heads, group
+    return None
+
 
 def supports(q_shape: tuple[int, ...], n_kv: int, n_blocks_seq: int,
-             block_size: int) -> bool:  # dlint: static-fn
+             block_size: int, *, compiled: bool = False) -> bool:  # dlint: static-fn
     """Whether the kernel covers this paged geometry (caller falls back to
-    the gather+oracle path otherwise)."""
+    the gather+oracle path otherwise), priced at the widest pool there is
+    (float32). ``compiled``: for Mosaic, where a manual DMA cannot slice
+    an HBM ref whose minor dim is not lane-aligned (it is padded there);
+    interpret mode takes any head dim of whole sublanes."""
     B, T, n_heads, D = q_shape
-    if n_heads % n_kv:
+    if n_heads % n_kv or D % (128 if compiled else 8):
         return False
     tq = T * (n_heads // n_kv)
-    s = n_blocks_seq * block_size
-    scratch = 4 * s * (tq + 2 * D)
-    return (D % 8 == 0 and block_size % 8 == 0 and 0 < tq <= MAX_TQ
-            and scratch <= _VMEM_BUDGET)
+    return (block_size % 8 == 0 and 0 < tq <= MAX_TQ
+            and _plan(n_kv, tq, D, n_blocks_seq, block_size, 4) is not None)
 
 
 def kernel_choice(q_shape: tuple[int, ...], n_kv: int, n_blocks_seq: int,
                   block_size: int) -> dict | None:  # dlint: static-fn
     """The paged-attention kernel gate: mode selection routes through
     :func:`quant_matmul.pallas_mode_gate` (the ONE gate; fast=False — the
-    kernel is bit-identical, so there is no fast/exact numerics split to
-    pick), plus the shape predicate and the plan-free requirement (the
-    paged forward auto-shards under a mesh plan, and the auto-sharder
-    cannot partition a ``pallas_call``). Returns
+    kernel keeps the oracle's arithmetic, so there is no fast/exact
+    numerics split to pick), plus the shape predicate and the plan-free
+    requirement (the paged forward auto-shards under a mesh plan, and the
+    auto-sharder cannot partition a ``pallas_call``). Returns
     :func:`paged_ragged_attention` kwargs or None (gather+oracle)."""
     from ..parallel.api import current_plan
     from .quant_matmul import pallas_mode_gate
@@ -124,7 +265,8 @@ def kernel_choice(q_shape: tuple[int, ...], n_kv: int, n_blocks_seq: int,
     kw = pallas_mode_gate(False)
     if kw is None or current_plan() is not None:
         return None
-    if not supports(q_shape, n_kv, n_blocks_seq, block_size):
+    if not supports(q_shape, n_kv, n_blocks_seq, block_size,
+                    compiled=not kw["interpret"]):
         return None
     return {"interpret": kw["interpret"]}
 
@@ -139,52 +281,58 @@ def paged_ragged_attention(q: jax.Array, k_pool: jax.Array,
     [B, M]`` (0 = null block), with per-row absolute positions
     ``positions [B, T]`` (affine per row, the model's invariant).
 
-    Value-identical (bitwise, in f32) to::
+    On every row whose first table entry is a real block, equal (to
+    float32 reduction-order noise) to::
 
         gathered = pool[tables]           # the dense logical cache
         view = moveaxis(gathered, 2, 1).reshape(B, n_kv, M*bs, hd)
         attention(q, view_k, view_v, positions, head_dim)
-    """
+
+    and zero on a row whose table starts with the null block (a dead
+    slot, whatever its stale ``positions`` say)."""
     B, T, n_heads, D = q.shape
     n_kv, bs = k_pool.shape[1], k_pool.shape[2]
     M = tables.shape[1]
     kv_mul = n_heads // n_kv
     tq = T * kv_mul
+    heads, group = _plan(n_kv, tq, D, M, bs, k_pool.dtype.itemsize)
 
     q_g = (q.reshape(B, T, n_kv, kv_mul, D)
             .transpose(0, 2, 1, 3, 4)
             .reshape(B, n_kv, tq, D)
             .astype(jnp.float32))
+    tables = jnp.asarray(tables, jnp.int32)
     pos0 = jnp.asarray(positions, jnp.int32)[:, 0]
+    # the walk's bound, from the table and the depth (traced: no retrace)
+    n_walk = jnp.where(tables[:, 0] != 0,
+                       jnp.clip(-(-(pos0 + T) // bs), 1, M), 0)
 
+    q_spec = pl.BlockSpec((1, heads, tq, D),
+                          lambda b, g, tbl, pos, nblk: (b, g, 0, 0),
+                          memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # tables, pos0
-        grid=(B, n_kv, M),
-        in_specs=[
-            pl.BlockSpec((1, 1, tq, D),
-                         lambda b, h, m, tbl, pos: (b, h, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda b, h, m, tbl, pos: (tbl[b, m], h, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda b, h, m, tbl, pos: (tbl[b, m], h, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, tq, D),
-                               lambda b, h, m, tbl, pos: (b, h, 0, 0),
-                               memory_space=pltpu.VMEM),
+        num_scalar_prefetch=3,  # tables (flat), pos0, n_walk
+        grid=(B, n_kv // heads),
+        in_specs=[q_spec,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((M * bs, D), jnp.float32),   # assembled f32 keys
-            pltpu.VMEM((M * bs, D), jnp.float32),   # assembled f32 values
+            pltpu.VMEM((2, heads, group * bs, D), k_pool.dtype),
+            pltpu.VMEM((2, heads, group * bs, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),          # (K | V, half)
+            pltpu.VMEM((heads, tq, 1), jnp.float32),  # running maximum
+            pltpu.VMEM((heads, tq, 1), jnp.float32),  # running sum
+            pltpu.SMEM((1,), jnp.int32),              # next landing half
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, kv_mul=kv_mul, hd=head_dim),
+        functools.partial(_kernel, bs=bs, kv_mul=kv_mul, hd=head_dim,
+                          group=group, heads=heads, n_entries=M),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv, tq, D), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(tables, jnp.int32), pos0, q_g, k_pool, v_pool)
+    )(tables.reshape(-1), pos0, n_walk, q_g, k_pool, v_pool)
 
     return (out.reshape(B, n_kv, T, kv_mul, D)
                .transpose(0, 2, 1, 3, 4)

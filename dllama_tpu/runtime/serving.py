@@ -1606,6 +1606,8 @@ class PagedGenerator(_GeneratorCore):
         self._m_pagein_blocks = self._tm.counter(telemetry.KV_PAGEIN_BLOCKS)
         self._m_pagein_bytes = self._tm.counter(telemetry.KV_PAGEIN_BYTES)
         self._m_pagein_ms = self._tm.counter(telemetry.KV_PAGEIN_MS)
+        self._m_walk_blocks = self._tm.counter(telemetry.PAGED_WALK_BLOCKS)
+        self._m_table_blocks = self._tm.counter(telemetry.PAGED_TABLE_BLOCKS)
         self._m_blocks_total.set(n_blocks - 1)
         self._m_host_total.set(self.pool.n_host_blocks)
         self._m_state_used = self._tm.gauge(telemetry.STATE_SLOTS_USED)
@@ -1629,6 +1631,17 @@ class PagedGenerator(_GeneratorCore):
 
     def _kv_fraction(self) -> float:
         return self.pool.used_blocks() / max(1, self.pool.n_blocks - 1)
+
+    def _record_step(self, n_active: int, ms: float, emitted: int) -> None:
+        """Beside the core's record: the share of the block tables that
+        paged attention walks (ops/paged_attention.py bounds its walk by
+        the same two arrays: a row is live where its table starts with a
+        real block, and walks ``ceil((pos + 1) / block_size)`` entries)."""
+        super()._record_step(n_active, ms, emitted)
+        live = self.tables[:, 0] != self.pool.NULL
+        self._m_walk_blocks.inc(int(np.sum(
+            -(-(self.pos[live] + 1) // self.block_size))))
+        self._m_table_blocks.inc(self.tables.size)
 
     def flight_blocks(self) -> dict | None:
         d = {"total": self.pool.n_blocks - 1,

@@ -76,6 +76,9 @@ KV_BLOCKS_TOTAL = "dllama_kv_blocks_total"
 KV_BLOCKS_USED = "dllama_kv_blocks_used"
 KV_BLOCKS_SHARED = "dllama_kv_blocks_shared"
 KV_BLOCK_EXHAUSTION = "dllama_kv_block_exhaustion_total"
+# how much of the block tables paged attention walks (ops/paged_attention.py)
+PAGED_WALK_BLOCKS = "dllama_paged_walk_blocks_total"
+PAGED_TABLE_BLOCKS = "dllama_paged_table_blocks_total"
 
 KV_BLOCKS_HOST_TOTAL = "dllama_kv_blocks_host_total"
 KV_BLOCKS_HOST_USED = "dllama_kv_blocks_host_used"
@@ -295,6 +298,13 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "found no free/evictable block and degraded to queueing (or "
           "failed that one request 503-shaped mid-decode), never a "
           "crash"),
+    _spec(PAGED_WALK_BLOCKS, "counter",
+          "Block-table entries the paged-attention walk covers, summed "
+          "over decode dispatches: ceil((pos + 1) / block_size) of every "
+          "row whose table starts with a real block"),
+    _spec(PAGED_TABLE_BLOCKS, "counter",
+          "Block-table entries there are, summed over decode dispatches "
+          "(slots x table width a step): the walk's denominator"),
     _spec(KV_BLOCKS_HOST_TOTAL, "gauge",
           "Host-tier KV mirror capacity in blocks (--kv-host-blocks "
           "through hbm.fit_host_pool; 0 = tiering off)"),

@@ -202,6 +202,30 @@ def test_flash_attention_compiles_for_v5e(one_chip, seq, rows, cache):
     assert kernels.get("_call") == 1, kernels
 
 
+def _compile_paged_attention(one_chip, slots, t, n_heads, n_kv, head_dim,
+                             per_seq, block, pool):
+    """``paged_ragged_attention`` for the described chip at one geometry:
+    one Mosaic kernel, its plan's resident set under the module's budget."""
+    from dllama_tpu.ops import paged_attention as pa
+
+    q = _shape(one_chip, (slots, t, n_heads, head_dim), jnp.float32)
+    kv = _shape(one_chip, (slots * per_seq + 1, n_kv, block, head_dim), pool)
+    itemsize = jnp.dtype(pool).itemsize
+    assert pa.supports(q.shape, n_kv, per_seq, block, compiled=True)
+    tq = t * n_heads // n_kv
+    heads, group = pa._plan(n_kv, tq, head_dim, per_seq, block, itemsize)
+    assert n_kv % heads == 0 and 1 <= group <= per_seq
+    assert pa.vmem_bytes(heads, group, tq, head_dim, block,
+                         itemsize) <= pa._VMEM_BUDGET
+    kernels = _compiled_kernels(
+        functools.partial(pa.paged_ragged_attention, head_dim=head_dim,
+                          interpret=False),
+        q, kv, kv, _shape(one_chip, (slots, per_seq), jnp.int32),
+        _shape(one_chip, (slots, t), jnp.int32))
+    assert kernels.get("paged_ragged_attention") == 1, kernels
+    return heads, group
+
+
 @pytest.mark.parametrize("block,per_seq,slots,pool", [
     (16, 8, 4, jnp.float32),
     (16, 64, 4, jnp.bfloat16),
@@ -211,18 +235,35 @@ def test_flash_attention_compiles_for_v5e(one_chip, seq, rows, cache):
 ])
 def test_paged_attention_compiles_for_v5e(one_chip, block, per_seq, slots,
                                           pool):
-    from dllama_tpu.ops.paged_attention import (paged_ragged_attention,
-                                                supports)
+    """The pool geometries the 1B-width cases always held, at heads of 128
+    lanes: compiled, the kernel's manual DMA cannot slice an HBM ref whose
+    minor dim is not lane-aligned, so at this file's 64-wide heads
+    (Llama-3.2-1B) the gate keeps the gather + oracle on the chip."""
+    from dllama_tpu.ops.paged_attention import supports
 
-    q = _shape(one_chip, (slots, 1, N_HEADS, HEAD_DIM), jnp.float32)
-    kv = _shape(one_chip, (slots * per_seq + 1, N_KV, block, HEAD_DIM), pool)
-    assert supports(q.shape, N_KV, per_seq, block)
-    kernels = _compiled_kernels(
-        functools.partial(paged_ragged_attention, head_dim=HEAD_DIM,
-                          interpret=False),
-        q, kv, kv, _shape(one_chip, (slots, per_seq), jnp.int32),
-        _shape(one_chip, (slots, 1), jnp.int32))
-    assert kernels.get("paged_ragged_attention") == 1, kernels
+    q64 = (slots, 1, N_HEADS, HEAD_DIM)
+    assert supports(q64, N_KV, per_seq, block)            # interpret mode
+    assert not supports(q64, N_KV, per_seq, block, compiled=True)
+    _compile_paged_attention(one_chip, slots, 1, N_HEADS, N_KV, 128, per_seq,
+                             block, pool)
+
+
+# rows, query width, heads, K/V heads, head dim, table entries (blocks of
+# 16, bf16 pools): the benchmark's cells, and the widths other callers bring
+@pytest.mark.parametrize("slots,t,n_heads,n_kv,per_seq", [
+    (16, 1, 32, 8, 64),      # mistral-7b-v0.3, both 16-slot cells
+    (16, 1, 32, 8, 80),      # qwen3-4b.chat
+    (4, 1, 32, 8, 256),      # mistral-7b-v0.3.long-prompt
+    (16, 5, 32, 8, 64),      # paged_verify_step under --spec-lookup 4
+    (4, 16, 32, 8, 256),     # a 16-wide verify / prefill tail
+    (2, 128, 32, 8, 64),     # MAX_TQ folded query rows: fewer heads a step
+])
+def test_paged_attention_compiles_at_the_cells_geometries_for_v5e(
+        one_chip, slots, t, n_heads, n_kv, per_seq):
+    heads, group = _compile_paged_attention(
+        one_chip, slots, t, n_heads, n_kv, 128, per_seq, 16, jnp.bfloat16)
+    assert group * 16 == 128
+    assert heads == (n_kv if t <= 16 else 4)
 
 
 @pytest.mark.parametrize("slots", [4, 16])
@@ -249,19 +290,11 @@ def test_gated_delta_step_compiles_for_v5e(one_chip, slots):
 
 def test_paged_attention_compiles_at_30_kv_heads_for_v5e(one_chip):
     """Olmo-Hybrid-7B's full layers: 30:30 heads of 128 (``kv_mul`` 1), 4
-    slots of 4096 in blocks of 16."""
-    from dllama_tpu.ops.paged_attention import (paged_ragged_attention,
-                                                supports)
-
-    q = _shape(one_chip, (4, 1, 30, 128), jnp.float32)
-    kv = _shape(one_chip, (1025, 30, 16, 128), jnp.bfloat16)
-    assert supports(q.shape, 30, 256, 16)
-    kernels = _compiled_kernels(
-        functools.partial(paged_ragged_attention, head_dim=128,
-                          interpret=False),
-        q, kv, kv, _shape(one_chip, (4, 256), jnp.int32),
-        _shape(one_chip, (4, 1), jnp.int32))
-    assert kernels.get("paged_ragged_attention") == 1, kernels
+    slots of 4096 in blocks of 16. A whole block, 122 KB over its 30 heads,
+    is one copy."""
+    heads, _ = _compile_paged_attention(one_chip, 4, 1, 30, 30, 128, 256, 16,
+                                        jnp.bfloat16)
+    assert heads == 30
 
 
 # -- chip_smoke.py's control flow, without a chip ------------------------------

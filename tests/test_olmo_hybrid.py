@@ -229,15 +229,28 @@ def _decode_logits(gen, slot, n_steps):
 
 
 # 70: a chunk of 64, then 5 tokens padded to 32; 20: shorter than one sub-chunk, padded to 32;
-# 300: 256, 32, then 11 padded to 32; 257: exactly one widest chunk, nothing padded
-@pytest.mark.parametrize("n_prompt", [70, 20, 300, 257])
-def test_padded_chunked_prefill_then_decode_logits(bench, engine, n_prompt):
+# 300: 256, 32, then 11 padded to 32; 257: exactly one widest chunk, nothing padded.
+# kernel "pallas": the decode steps' full layers through ``paged_ragged_attention`` (interpret mode
+# off a TPU; ``kv_mul`` 1, a dead slot with a stale depth beside the live one): the benchmark's
+# ``correct`` hardly sees that path (a lost block under a long prompt reads as the honest run), the
+# logits do. 70 ends inside the walk's first fetch group, 300 walks three.
+@pytest.mark.parametrize("n_prompt,kernel", [(70, None), (20, None), (300, None), (257, None),
+                                             (70, "pallas"), (300, "pallas")])
+def test_padded_chunked_prefill_then_decode_logits(bench, engine, n_prompt, kernel, monkeypatch):
+    from dllama_tpu.ops import paged_attention as pa
     from dllama_tpu.runtime.serving import PagedGenerator, Request
 
+    calls = []
+    entry = pa.paged_ragged_attention
+    monkeypatch.setattr(pa, "paged_ragged_attention", lambda *a, **kw: calls.append(kw) or entry(*a, **kw))
+    if kernel:
+        monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", kernel)
     gen = PagedGenerator(engine, n_slots=2)
+    gen.pos[0] = 123                      # a retired slot's stale depth
     prompt = _tokens(n_prompt, seed=n_prompt)
     gen.admit(Request(rid=1, prompt_ids=prompt, max_tokens=8, stop_on_eos=False), 1)
     got, emitted = _decode_logits(gen, 1, 8)
+    assert calls == ([{"interpret": True}] if kernel else [])      # traced once: one full layer's body
     want = _reference_logits(bench, engine.params, prompt + emitted)[n_prompt - 1:n_prompt + 7]
     assert float(np.abs(got - want).max()) < LOGIT_TOL
 
