@@ -18,18 +18,10 @@
 #                   completeness), the thread-ownership analyzer
 #                   (owner=loop/monitor/any call-graph checking,
 #                   guarded-by lock discipline, lock-order cycles), and
-#                   the six historical scanners (metric names, exception
+#                   the name and registry rules (metric names, exception
 #                   hygiene, route labels, failpoint sites, span phases,
-#                   shard_map shim) consolidated as rules. One rule:
+#                   shard_map shim, ...). One rule:
 #                   python -m tools.dlint --only RULE; CI summary: --json.
-#   make bench      The driver's benchmark: ONE JSON line on stdout.
-#   make perf-check The perf-regression sentinel: run the bench and
-#                   compare against the committed PERF_BASELINE.json
-#                   (tools/perf_baseline.py). Exits nonzero naming any
-#                   regressed metric; a no-hardware run is first-class
-#                   "no evidence" and stays green. Re-record with
-#                   `python bench.py --baseline update` after a
-#                   deliberate perf change lands ON CHIP.
 #   make quality-check  The quality-regression sentinel: the built-in
 #                   fixture eval (deterministic tiny model over
 #                   tests/goldens/eval_tiny.jsonl, every config in
@@ -46,7 +38,7 @@
 
 PY ?= python
 
-.PHONY: test test-tpu test-all native tsan bench perf-check quality-check graft lint clean
+.PHONY: test test-tpu test-all native tsan quality-check graft lint clean
 
 test:
 	$(PY) -m pytest tests/ -q
@@ -65,12 +57,6 @@ tsan:
 
 lint:
 	$(PY) -m tools.dlint
-
-bench:
-	$(PY) bench.py
-
-perf-check:
-	$(PY) bench.py --baseline check
 
 quality-check:
 	JAX_PLATFORMS=cpu $(PY) tools/quality_baseline.py check

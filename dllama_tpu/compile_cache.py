@@ -1,5 +1,5 @@
 """Where the persistent XLA compilation cache lives — one rule for the CLI,
-``bench.py`` and the tools.
+``chip_smoke.py``, ``benchmark/run.py`` and the tools.
 
 ``JAX_COMPILATION_CACHE_DIR``, when set, IS the cache: whoever runs the
 program placed it from outside, and nothing in code sets another. When it
@@ -7,8 +7,7 @@ is not set, the cache is one fixed, git-ignored directory inside the
 checkout — the path is part of a cache entry's key, so a directory that
 moves (a tempdir, a per-user home) never hits.
 
-Stdlib only at import: ``bench.py``'s parent and ``chip_smoke.py`` must be
-able to ask where the cache is without importing jax.
+Stdlib only at import: ``chip_smoke.py``'s parent must be able to ask where the cache is without importing jax.
 """
 
 from __future__ import annotations

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 from typing import Any, NamedTuple
 
-import os
 
 import jax
 import jax.numpy as jnp
@@ -68,9 +67,9 @@ class LayerParams(NamedTuple):
     norm_q: jax.Array | None  # [L, head_dim] (qwen3) or None
     norm_k: jax.Array | None
     # MoE (None for dense models). Expert weights carry any Weight repr:
-    # dense (compute dtype), stacked QuantizedWeight (Q40/Q80 planes — 1
-    # B/weight resident, dequant fused into the consuming dot), or
-    # TurboWeight after turbo derivation. Layout is IN-major
+    # dense (compute dtype) or stacked QuantizedWeight (Q40/Q80 planes — 1
+    # B/weight resident, dequant fused into the consuming dot). Layout is
+    # IN-major
     # ("[.., in, out]") so ``lax.ragged_dot``'s grouped matmul consumes the
     # dense planes with no per-step transpose (its rhs contracts axis 1).
     moe_gate: jax.Array | None = None  # [L, E, dim] router
@@ -245,44 +244,19 @@ def _experts_dense(we, x: jax.Array, rows: jax.Array | None = None) -> jax.Array
     slices only, and XLA fuses it into the consuming dot, the same fused-
     dequant fast path ops.linear uses)."""
     from ..ops.linear import QuantizedWeight, _fast_mode, dequantize_weight
-    from ..ops.turbo import TurboWeight
 
     if isinstance(we, QuantizedWeight):
         if rows is not None:
             we = QuantizedWeight(scales=we.scales[rows], codes=we.codes[rows])
         fast = _fast_mode(x) or we.scales.dtype == jnp.bfloat16
         return dequantize_weight(we, dtype=jnp.bfloat16 if fast else x.dtype)
-    if isinstance(we, TurboWeight):
-        w8 = we.w8 if rows is None else we.w8[rows]
-        scale = we.scale if rows is None else we.scale[rows]
-        # per-column scales: ONE multiply per element (half the fast path's
-        # per-element convert+scale); the ragged/dense consumers need a
-        # dense rhs, so the s8 dot itself is not used on this path
-        return w8.astype(jnp.bfloat16) * scale[..., None, :].astype(jnp.bfloat16)
     return we if rows is None else we[rows]
 
 
 def _expert_gather_dot(x: jax.Array, we, rows: jax.Array) -> jax.Array:
     """``y[n] = x[n] @ plane(rows[n])`` — the decode-regime per-row expert
-    dot. ``x [N, D]``, result f32 ``[N, out]``. TurboWeight runs its real
-    integer-dot contraction (scales in the epilogue, ops.turbo semantics);
-    other reprs gather-then-dequant via :func:`_experts_dense`."""
-    from ..ops.turbo import TurboWeight
-
-    if isinstance(we, TurboWeight):
-        w8 = we.w8[rows]                       # [N, D, out] int8
-        scale = we.scale[rows]                 # [N, out] f32
-        if we.a8:
-            from ..ops.turbo import quantize_activations_a8
-
-            xq, sx = quantize_activations_a8(x)
-            acc = jnp.einsum("nd,ndh->nh", xq, w8,
-                             preferred_element_type=jnp.int32)
-            return acc.astype(jnp.float32) * sx * scale
-        acc = jnp.einsum("nd,ndh->nh", x.astype(jnp.bfloat16),
-                         w8.astype(jnp.bfloat16),
-                         preferred_element_type=jnp.float32)
-        return acc * scale
+    dot. ``x [N, D]``, result f32 ``[N, out]``: gather, then dequant via
+    :func:`_experts_dense`."""
     w = _experts_dense(we, x, rows)
     return jnp.einsum("nd,ndh->nh", x.astype(w.dtype), w,
                       preferred_element_type=jnp.float32)
@@ -348,7 +322,7 @@ def _moe_sparse_local(cfg: ModelConfig, x: jax.Array, idx: jax.Array,
         order = jnp.argsort(flat_e)                    # group rows by expert
         xs = x_rep[order]
         group_sizes = jnp.bincount(flat_e, length=e_local).astype(jnp.int32)
-        # ragged_dot needs a dense rhs: quantized/turbo planes expand to a
+        # ragged_dot needs a dense rhs: quantized planes expand to a
         # bf16 transient of this device's local expert slice here (prefill
         # regime — MXU-bound, so the extra HBM of the expansion is paid
         # where it is cheapest; decode takes the gather regime above)
@@ -514,18 +488,15 @@ def _overlapped_col_linear(cfg: ModelConfig, x: jax.Array, w,
     the same hops when ``--wire q80``). Returns None when this geometry
     keeps the monolithic GSPMD path: no plan / no tp resolution for
     ``in_logical`` / non-divisible shapes / sp-pp meshes (their manual
-    regions can't nest another shard_map) / turbo weights (their integer
-    dot is fused per shard in ops.turbo) / prefill-wide dispatches."""
+    regions can't nest another shard_map) / prefill-wide dispatches."""
     from jax.sharding import PartitionSpec as P
 
     from ..formats.quants import Q40_BLOCK_SIZE
     from ..ops.linear import _fast_mode, dequantize_weight
-    from ..ops.turbo import TurboWeight
     from ..parallel.qcollectives import overlapped_wire_psum
 
     plan = _current_plan()
     if (cfg.comm_overlap <= 1 or plan is None or x.ndim != 3
-            or isinstance(w, TurboWeight)
             or x.shape[1] > _OVERLAP_MAX_WIDTH
             or any(plan.axis_size(a) > 1 for a in ("sp", "pp"))):
         return None
@@ -827,9 +798,7 @@ def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
 def greedy_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
                 start_pos: jax.Array, kv: KVCache) -> tuple[jax.Array, KVCache]:
     """Fused forward + argmax of the last position — the single-dispatch
-    greedy decode step (SURVEY.md §7.4 "single fused jitted step"). Shared by
-    the engine's fast path and bench.py so the benchmark measures the
-    production program."""
+    greedy decode step (SURVEY.md §7.4 "single fused jitted step")."""
     logits, kv = forward(params, cfg, tokens, start_pos, kv)
     return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32), kv
 
@@ -1052,14 +1021,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return x, (k_l, v_l)
 
     # scan over the stacked layer axis; caches ride along as per-layer xs/ys.
-    # DLLAMA_TPU_SCAN_UNROLL (default 1) trades program size for fusion
-    # across layer boundaries — the round-4 decode profile showed ~0.9 ms of
-    # per-step loop overhead beyond the matmuls on the 1b shape. Part of the
-    # multihost cluster fingerprint (different unroll = different program).
-    unroll = int(os.environ.get("DLLAMA_TPU_SCAN_UNROLL", "1"))
     layers = _layer_indices(cfg) if by_index else params.layers
-    x, ys = jax.lax.scan(body, x, (layers, kv.k, kv.v),
-                         unroll=max(1, unroll))
+    x, ys = jax.lax.scan(body, x, (layers, kv.k, kv.v))
     if collect:
         new_k, new_v, layer_taps = ys  # stacked [L] leaves per site
     else:
@@ -1140,8 +1103,8 @@ def prefill_nll(params: Params, cfg: ModelConfig, tokens: jax.Array,
 # (a traced f32 scalar driven by the `logits` failpoint — 0.0 in
 # production, so arming chaos never recompiles) and (b) a fused per-row
 # count of non-finite decode-step logits returned alongside the picked
-# token. The raw steps above keep their signatures for bench.py and the
-# parity tests; the guarded ones are what the engine jits (under the same
+# token. The raw steps above keep their signatures for the parity tests
+# and __graft_entry__.py; the guarded ones are what the engine jits (under the same
 # program names, so the compile ledger's view is unchanged).
 
 
@@ -1165,8 +1128,8 @@ def _guarded_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     trace contains them) carry the SAME traced poison scalar the logits
     site uses — codes 1-2 poison logits, 3-4 poison this device's shipped
     ring partial (batch row 0 only). One traced selector, so arming either
-    chaos site never recompiles. Unguarded programs (prefill, bench paths)
-    never enter the scope and trace no injection code at all."""
+    chaos site never recompiles. Unguarded programs (prefill) never
+    enter the scope and trace no injection code at all."""
     from ..parallel.qcollectives import wire_poison_scope
 
     with wire_poison_scope(poison):
@@ -1350,9 +1313,7 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return x, (k_l, v_l)
 
     layers = _layer_indices(cfg) if by_index else params.layers
-    unroll = int(os.environ.get("DLLAMA_TPU_SCAN_UNROLL", "1"))
-    x, (new_k, new_v) = jax.lax.scan(body, x, (layers, pkv.k, pkv.v),
-                                     unroll=max(1, unroll))
+    x, (new_k, new_v) = jax.lax.scan(body, x, (layers, pkv.k, pkv.v))
     x = rms_norm(x, params.final_norm, cfg.norm_epsilon)
     if cfg.sync_q80:
         x = fake_quant_q80(x)

@@ -127,38 +127,30 @@ def _fast_mode(x: jax.Array) -> bool:  # dlint: static-fn (dtype/env gate)
         "bfloat16" if x.dtype == jnp.bfloat16 else "float32")
 
 
-def turbo_mode() -> str | None:
-    """``"a8"`` / ``"a16"`` when DLLAMA_TPU_QUANT_MODE selects turbo
-    numerics (ops.turbo: per-column int8 weights, scales in the epilogue),
-    else None. Opt-in only — never resolved from ``auto``."""
+QUANT_MODES = ("auto", "exact", "fast")
+
+
+def quant_mode() -> str:
+    """``DLLAMA_TPU_QUANT_MODE`` as set, refused when it is not one of
+    QUANT_MODES. The ONE reader of the variable: a value this build does
+    not know must not fall through to ``auto`` and serve an operator other
+    numerics than the ones they exported."""
     mode = os.environ.get("DLLAMA_TPU_QUANT_MODE", "auto")
-    return {"turbo": "a8", "turbo16": "a16"}.get(mode)
+    if mode not in QUANT_MODES:
+        raise ValueError(
+            f"DLLAMA_TPU_QUANT_MODE={mode!r} is not a quant mode: "
+            f"use one of {', '.join(QUANT_MODES)}")
+    return mode
 
 
 def fast_numerics_resolved(compute_dtype: str) -> bool:
     """The load-time fast/exact resolution (same rule as _fast_mode, keyed
     on the config's compute dtype instead of a live activation): decides
-    stored scale dtype and the dense-logits default in runtime.weights.
-    Turbo modes load like fast (bf16 scales feed the derivation, dense
-    head) before the planes requantize."""
-    mode = os.environ.get("DLLAMA_TPU_QUANT_MODE", "auto")
-    if mode == "exact":
-        return False
-    if mode in ("fast", "turbo", "turbo16"):
-        return True
-    return compute_dtype == "bfloat16"
-
-
-def quant_mode_label(activations_bf16: bool) -> str:
-    """The resolved mode label for diagnostics (bench captures, logs) — the
-    ONE place the env knob + auto rule turn into a string, so reports can't
-    drift from what _fast_mode actually dispatches."""
-    mode = os.environ.get("DLLAMA_TPU_QUANT_MODE", "auto")
-    if mode not in ("exact", "fast", "turbo", "turbo16"):
-        mode = "auto"
-    resolved = mode if mode != "auto" else (
-        "fast" if activations_bf16 else "exact")
-    return resolved if mode != "auto" else f"auto({resolved})"
+    stored scale dtype and the dense-logits default in runtime.weights."""
+    mode = quant_mode()
+    if mode == "auto":
+        return compute_dtype == "bfloat16"
+    return mode == "fast"
 
 
 def _pallas_wanted(x: jax.Array, w: QuantizedWeight, fast: bool) -> dict | None:  # dlint: static-fn (shape/env gate)
@@ -268,12 +260,6 @@ def linear(x: jax.Array, w: Weight, *, out_axis: str | None = None,
     being traced (runtime.introspection.note_q40_path).
     """
     out_dtype = x.dtype
-    from .turbo import TurboWeight, turbo_matmul  # lazy: turbo imports us
-
-    if isinstance(w, TurboWeight):
-        # a8/a16 rides on the weight (fixed at derivation) — the ambient env
-        # cannot silently flip serving numerics after load
-        return turbo_matmul(x, w).astype(out_dtype)
     if isinstance(w, LayerSlice):
         y = _layer_slice_fused(x, w)
         if y is not None:

@@ -283,6 +283,7 @@ def validate_cluster_config(engine: "InferenceEngine") -> None:
     import jax
     from jax.experimental import multihost_utils
 
+    from ..ops.linear import quant_mode
     from ..runtime.weights import dense_logits_resolved as _dense_logits
 
     def s32(text: str) -> int:  # stable string → i32 slot
@@ -306,17 +307,11 @@ def validate_cluster_config(engine: "InferenceEngine") -> None:
         # exact vs fast quant-matmul numerics compile different programs
         # (ops/linear.py _fast_mode); `auto` resolves identically on both
         # sides because compute_dtype is fingerprinted above
-        s32(os.environ.get("DLLAMA_TPU_QUANT_MODE", "auto")),
+        s32(quant_mode()),
         # kernel-dispatch choice (pallas vs xla) compiles different programs
-        # — and is now promotable (serve.cli promoted serving config), so a
-        # root/worker bench_promoted.json divergence must fail fast here
         s32(os.environ.get("DLLAMA_TPU_QUANT_KERNEL", "auto")),
         # wire format changes the collective program (qcollectives.py)
         s32(os.environ.get("DLLAMA_TPU_WIRE", "f32")),
-        # layer-scan unroll factor shapes the forward program (models.llama);
-        # fingerprint the EFFECTIVE value (same max(1,..) clamp as llama.py)
-        # so e.g. unset-vs-0 doesn't reject an identical cluster
-        max(1, int(os.environ.get("DLLAMA_TPU_SCAN_UNROLL", "1"))),
         # dense-bf16 vs quantized logits head compile different programs;
         # fingerprint the resolved decision (knob + numerics mode)
         1 if _dense_logits(engine.cfg.compute_dtype) else 0,

@@ -43,14 +43,6 @@ def _weight_sharding(plan: MeshPlan, w, out_axis: str | None, in_axis: str | Non
             scales=plan.sharding_for(tuple(w.scales.shape), *lead, in_axis, out_axis),
             codes=plan.sharding_for(tuple(w.codes.shape), *lead, in_axis, out_axis),
         )
-    from ..ops.turbo import TurboWeight
-
-    if isinstance(w, TurboWeight):
-        return TurboWeight(
-            plan.sharding_for(tuple(w.w8.shape), *lead, in_axis, out_axis),
-            plan.sharding_for(tuple(w.scale.shape), *lead, out_axis),
-            w.a8,
-        )
     return plan.sharding_for(tuple(w.shape), *lead, out_axis, in_axis)
 
 
@@ -60,18 +52,13 @@ def map_expert_weight(we, in_axis, out_axis, f):
     PLANE dims (the leading ``[L?, E]`` axes are the caller's concern).
 
     THE single statement of per-repr expert plane layout — quantized scale
-    planes shard like their codes (the K/32 block axis follows the in axis),
-    turbo scales are ``[..., out]`` — consumed by both the NamedSharding
+    planes shard like their codes (the K/32 block axis follows the in
+    axis) — consumed by both the NamedSharding
     builder below and the shard_map in_specs in models.llama, so the two
     can't drift apart."""
     if isinstance(we, QuantizedWeight):
         return QuantizedWeight(scales=f(we.scales, (in_axis, out_axis)),
                                codes=f(we.codes, (in_axis, out_axis)))
-    from ..ops.turbo import TurboWeight
-
-    if isinstance(we, TurboWeight):
-        return TurboWeight(f(we.w8, (in_axis, out_axis)),
-                           f(we.scale, (out_axis,)), we.a8)
     return f(we, (in_axis, out_axis))
 
 
@@ -105,7 +92,7 @@ def param_shardings(plan: MeshPlan, params: "Params") -> "Params":
         # MoE: experts over ep, expert-hidden over tp (new capability; the
         # reference has no runtime MoE, SURVEY.md §2.2). Expert weights are
         # in-major (ragged_dot layout, see LayerParams): we1/we3 [L,E,D,H],
-        # we2 [L,E,H,D] — any Weight repr (dense / quantized / turbo).
+        # we2 [L,E,H,D] — any Weight repr (dense / quantized).
         moe_gate=None if lp.moe_gate is None else plan.sharding_for(
             tuple(lp.moe_gate.shape), "layers", "experts", None),
         we1=None if lp.we1 is None else _expert_sharding(

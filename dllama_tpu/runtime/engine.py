@@ -142,7 +142,7 @@ class InferenceEngine:
                  verify_weights: bool = False,
                  numerics_taps: bool = False,
                  numerics_failfast: bool | None = None):
-        from ..ops.linear import turbo_mode
+        from ..ops.linear import quant_mode
 
         # start-up stamps (monotonic seconds per phase of the build; the
         # serving generator adds its own): logged once beside the HBM
@@ -151,15 +151,8 @@ class InferenceEngine:
         self.startup_s: dict[str, float] = {}
         t_phase = time.monotonic()
 
-        if turbo_mode() is not None and weight_mode != "auto":
-            # fail BEFORE the multi-GB load: turbo requires quantized planes
-            # resident on device. offload would pull host-DRAM stacks into
-            # HBM; f32/bf16 modes have no Q40 planes to requantize (silently
-            # serving dense weights while reports say "turbo" would be the
-            # report-vs-dispatch drift quant_mode_label exists to prevent).
-            raise ValueError(
-                f"--quant-mode turbo/turbo16 requires --weight-mode auto "
-                f"with a quantized model (got --weight-mode {weight_mode})")
+        # an unknown DLLAMA_TPU_QUANT_MODE fails BEFORE the multi-GB load
+        quant_mode()
         self.model_file = ModelFile.open(model_path, max_seq_len=max_seq_len,
                                          sync_type=sync_type)
         self.cfg = ModelConfig.from_header(self.model_file.header,
@@ -247,7 +240,6 @@ class InferenceEngine:
                 ("--pp > 1", pp > 1), ("--dp > 1", dp > 1),
                 ("multihost workers", multihost),
                 ("--weight-mode offload", weight_mode == "offload"),
-                ("--quant-mode turbo/turbo16", turbo_mode() is not None),
                 ("--buffer-float-type q80 (Q80 sync emulation)",
                  self.cfg.sync_q80),
                 ("--numerics-taps", numerics_taps
@@ -395,12 +387,6 @@ class InferenceEngine:
                 ("--sp > 1", sp > 1),
                 ("--pp > 1", pp > 1),
                 ("--weight-mode offload", weight_mode == "offload"),
-                # turbo weights skip the overlapped merge entirely
-                # (models.llama._overlapped_col_linear returns None for
-                # TurboWeight) — a knob that silently does nothing while
-                # the banner/pricing say otherwise must refuse instead
-                ("--quant-mode turbo/turbo16",
-                 turbo_mode() is not None),
                 # a verify dispatch is K+1 columns wide; past the overlap
                 # width gate it would trace the monolithic psum while
                 # plain greedy traces the ring — their f32 sum orders
@@ -420,8 +406,7 @@ class InferenceEngine:
                 raise ValueError(
                     f"--comm-overlap (overlapped collectives) does not "
                     f"support {', '.join(bad)} yet — their manual-SPMD "
-                    f"regions can't nest the ring shard_map (turbo: its "
-                    f"integer-dot path has no overlapped merge); drop "
+                    f"regions can't nest the ring shard_map; drop "
                     f"those flags or --comm-overlap")
         if n_chunks:
             from dataclasses import replace as _replace
@@ -480,7 +465,7 @@ class InferenceEngine:
         from ..ops.quant_matmul import pallas_local_choice
         from ..parallel.qcollectives import wire_traffic_model
 
-        quant_planes = _repr in ("q40", "q80") and turbo_mode() is None
+        quant_planes = _repr in ("q40", "q80")
         _by_key: dict = {}
         for k_dim in ([self.cfg.q_dim] if self.cfg.is_moe
                       else [self.cfg.q_dim, self.cfg.hidden_dim]):
@@ -607,35 +592,17 @@ class InferenceEngine:
         guarantee atomic teardown on ANY exception. ``t_phase``: where
         the previous start-up stamp ended (an optional --verify-weights
         sweep counts as weight load)."""
-        from ..ops.linear import turbo_mode
-
         weight_mode, multihost = self.weight_mode, self.multihost
         # streaming loader: shard-direct reads from the mmap, host memory
         # bounded by one tensor shard (VERDICT round-1 missing #4)
         self.params: Params = load_params_from_mfile(
             self.model_file, self.cfg, weight_mode, plan=self.plan)
-        if turbo_mode() is not None:
-            # opt-in integer-dot numerics (ops.turbo): requantize every Q40
-            # plane to per-column int8 on device, layer-at-a-time (same
-            # 1 B/weight HBM footprint; scales move to the matmul epilogue).
-            # Source buffers free as each leaf derives, so the transient is
-            # one extra leaf, not a second model (runtime.hbm charges it).
-            from ..ops.turbo import TurboWeight, turbo_params
-
-            self.params = turbo_params(self.params,
-                                       a8=turbo_mode() == "a8")
-            if not isinstance(self.params.layers.wq, TurboWeight):
-                raise ValueError(
-                    "--quant-mode turbo/turbo16 requires a quantized (Q40/"
-                    "Q80) model file — this one loaded dense weights, so "
-                    "there is nothing to requantize and reports would "
-                    "mislabel plain dense numerics as turbo")
-        # pin the load-time quant-mode resolution: stored scale dtype, the
-        # dense-vs-Q40 logits head, and turbo derivation were all decided by
+        # pin the load-time quant-mode resolution: stored scale dtype and the
+        # dense-vs-Q40 logits head were decided by
         # DLLAMA_TPU_QUANT_MODE as it read HERE. _dispatch re-checks this
         # resolution so an env flip after load fails loudly instead of
         # silently running one mode's math over the other mode's stored
-        # weights (ADVICE r4: report-vs-dispatch drift).
+        # weights.
         self._load_quant_resolution = self._quant_resolution()
         t_phase = self._stamp_startup("weight_load", t_phase)
         # a hybrid decoder is served by the paged generator alone, which
@@ -776,14 +743,14 @@ class InferenceEngine:
         except Exception:  # noqa: BLE001 — teardown must not mask the original load failure
             pass
 
-    def _quant_resolution(self) -> tuple:
+    def _quant_resolution(self) -> bool:
         """The env's quant-mode RESOLUTION (not the display label): what the
         loader bakes into the weights. Label spellings that resolve the same
         way (``auto`` on a bf16 config vs explicit ``fast``) are equal here,
         so only a genuine numerics change trips the _dispatch guard."""
-        from ..ops.linear import fast_numerics_resolved, turbo_mode
+        from ..ops.linear import fast_numerics_resolved
 
-        return (fast_numerics_resolved(self.cfg.compute_dtype), turbo_mode())
+        return fast_numerics_resolved(self.cfg.compute_dtype)
 
     def _require_solo_cache(self) -> None:
         """The single-sequence programs run over ``self.kv``, which a hybrid
@@ -835,9 +802,10 @@ class InferenceEngine:
         if live != self._load_quant_resolution:
             raise RuntimeError(
                 f"DLLAMA_TPU_QUANT_MODE changed after load: weights were "
-                f"loaded for {self._load_quant_resolution!r} (scale dtype, "
-                f"logits head, turbo planes are baked in) but the env now "
-                f"resolves {live!r} — restart with the desired mode instead")
+                f"loaded for fast={self._load_quant_resolution!r} (scale "
+                f"dtype and logits head are baked in) but the env now "
+                f"resolves fast={live!r} — restart with the desired mode "
+                f"instead")
         if self.multihost and self._is_root:
             # the reference's LlmControlPacket broadcast (app.cpp:193-204):
             # ship (program, tokens, position[, sampling scalars]) so workers

@@ -1,10 +1,9 @@
 """Quality observatory: batched teacher-forced eval over the serving stack.
 
-The promotion story every perf gate in this repo leans on (Q40/Q80
-quants, fused dequant-GEMV, ragged paged attention, turbo int8,
-speculative acceptance) is speed-guarded by ``tools/perf_baseline.py``
-but says nothing about whether the model still *predicts well*. This
-module closes that gap: it scores a JSONL dataset teacher-forced —
+Every speed-up this repo ships (Q40/Q80 quants, fused dequant-GEMV,
+ragged paged attention, speculative acceptance) is measured for speed
+by ``benchmark/run.py``, which says nothing about whether the model
+still *predicts well*. This module closes that gap: it scores a JSONL dataset teacher-forced —
 per-token negative log-likelihood of each next token given its prefix —
 through the REAL serving machinery, two ways:
 
@@ -17,8 +16,7 @@ through the REAL serving machinery, two ways:
   ``BatchScheduler``/``PagedGenerator`` as continuous-batching work
   (``Request.score``): same program, same chunk boundaries, same zero
   padding, which is what makes the batched totals **bit-identical** to
-  the oracle's — the property ``tools/quality_baseline.py`` gates and
-  ``tools/bench_compare.py`` flags as "parity drift" when it breaks.
+  the oracle's — the property ``tools/quality_baseline.py`` gates.
 
 Sums are canonical: each sequence's float32 NLL values accumulate into
 a float64 sum in position order; the run total sums the per-sequence

@@ -33,7 +33,7 @@ fire increments ``dllama_failpoints_fired_total{name=...}`` so chaos
 tests assert injection *and* recovery through the same telemetry
 registry.
 
-Site registry — the closed world ``tools/check_failpoint_sites.py``
+Site registry — the closed world dlint rule ``failpoint-sites``
 lints against: every ``failpoints.fire("<name>")`` call site in the
 package must use a name listed here, and every name listed here must
 have at least one call site:

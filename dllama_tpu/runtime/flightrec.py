@@ -373,8 +373,7 @@ def ttft_phases(t_submit: int, t_admit: int, t_decode: int,
                 ms_kvmigrate: float = 0.0) -> dict:
     """THE TTFT phase formula — every surface that decomposes a first
     token (the ``dllama_ttft_attrib_ms`` histograms, the API ``timing``
-    block on both serving paths, bench.py's attribution section) derives
-    from this one function, so they can never drift apart. Timestamps
+    block on both serving paths) derives from this one function, so they can never drift apart. Timestamps
     are monotonic ns; ``ms_prefill`` is the request's own prefill chunk
     dispatch wall, ``ms_pagein`` its KV-tier page-in wall (resumed
     sessions restoring spilled blocks; 0 everywhere else), and
@@ -628,7 +627,7 @@ def fleet_chrome_trace(router_dump: dict,
     as slices under a ``local:`` id but contribute no flow. A top-level
     ``fleetJoin`` summary counts what joined — the offline
     ``fleettrace`` CLI exits 1 when nothing does. Timestamps are each
-    process's monotonic ns: same-process fleets (tests, bench) share one
+    process's monotonic ns: same-process fleets (tests) share one
     clock; cross-process dumps keep per-track order but tracks may be
     mutually offset."""
     out: list[dict] = []

@@ -3,9 +3,9 @@
 The reference prints its required-memory estimate before loading
 (nn-core.cpp:162-176, "required memory" at graph-build time) and a malloc
 failure is a clean abort. On this TPU stack the failure mode is much worse:
-an HBM OOM can wedge the backend server-side for HOURS (the round-1/2 bench
-outage), so the engine and the bench estimate device bytes up front and
-refuse with an actionable error when the budget doesn't fit.
+an HBM OOM can wedge the backend server-side for HOURS, so the engine
+estimates device bytes up front and refuses with an actionable error when
+the budget doesn't fit.
 
 Estimates are deliberately simple shape algebra with a safety margin — the
 goal is catching the 2x-and-worse misfits (8B f32 on a 16 GB chip, 70B on
@@ -104,26 +104,6 @@ def estimate_device_bytes(cfg, *, weight_repr: str, kv_dtype_bytes: int,
         weights = emb_bytes + int(2 * per_layer * wbytes)
     else:
         weights = emb_bytes + int(matmul_weight_count(cfg) * wbytes)
-        from ..ops.linear import turbo_mode
-
-        if turbo_mode() is not None and wbytes < 2.0:
-            # turbo derivation (ops.turbo) transiently holds one extra
-            # derived int8 leaf (source planes free leaf-by-leaf) PLUS the
-            # dense f32 intermediate of the plane being derived: one layer's
-            # [dim, hidden] for stacked leaves, or the whole [dim, vocab]
-            # when the logits head stays quantized (2-D branch)
-            from .weights import dense_logits_resolved
-
-            dense_cols = cfg.hidden_dim
-            if not dense_logits_resolved(getattr(cfg, "compute_dtype", "")):
-                dense_cols = max(dense_cols, cfg.vocab_size)
-            # largest int8 leaf held twice during its derivation: for MoE
-            # that is an expert stack [L, E, dim, hidden] (experts quantize
-            # too); the dense f32 intermediate stays ONE plane (lax.map
-            # flattens the leading axes)
-            largest_leaf = cfg.n_layers * cfg.dim * cfg.hidden_dim * (
-                cfg.n_experts if cfg.is_moe else 1)
-            weights += largest_leaf + 4 * cfg.dim * dense_cols
     kv = (2 * cfg.n_kv_layers * padded_cache_len(cfg.seq_len) * cfg.kv_dim
           * batch * kv_dtype_bytes)
     need = int(((weights + kv) / max(1, n_shards)) * _MARGIN) + _FIXED_OVERHEAD

@@ -1,7 +1,7 @@
 """Numerics observatory — the layer that watches the *values*.
 
 The stack is quantized end to end (Q40 weights, Q80 activation-sync
-collectives, the turbo int8 matmul path) and the whole design bets that
+collectives) and the whole design bets that
 those lossy representations stay quality-neutral. Until this module,
 nothing checked: a NaN burst, a mis-scaled Q40 block, or replica drift in
 the quantized collectives surfaced only as garbage tokens — no metric, no

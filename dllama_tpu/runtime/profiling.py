@@ -138,9 +138,8 @@ def empty_attribution(n_steps: int = 0) -> dict:
 
 def op_attribution(trace_dir: str | None = None, *, xspace=None,
                    n_steps: int = 1, top: int = 25) -> dict:
-    """Per-op device-time decomposition of an xplane capture — the
-    reusable core of ``tools/profile_decode.py``, also served live via
-    ``POST /debug/profile?ops=1``. Takes either a trace directory (newest
+    """Per-op device-time decomposition of an xplane capture, served
+    live via ``POST /debug/profile?ops=1``. Takes either a trace directory (newest
     ``*.xplane.pb`` inside) or an already-parsed ``xspace``.
 
     Attribution comes from the PRIMARY lane (the device lane with the
@@ -226,7 +225,7 @@ def union_span(intervals: list[tuple[int, int]]) -> int:
     """Total covered length of possibly-overlapping [start, end] spans, in
     the caller's units — nested profiler events (a rendezvous wait inside a
     psum span) must not double-count. THE one interval-union sweep (the
-    Eval/Sync split and tools/profile_decode both use it)."""
+    Eval/Sync split and op_attribution both use it)."""
     if not intervals:
         return 0
     intervals.sort()

@@ -1,11 +1,6 @@
 """Roofline attribution — achieved vs ceiling, per compiled program.
 
-ROADMAP #2 (close the single-chip roofline gap) runs a profile → A/B →
-promote loop whose evidence lived in ad-hoc scripts: bench.py computed a
-shape-algebra roofline in its parent, tools/profile_decode.py decomposed
-device time by op, and nothing joined the two against what the stack
-already MEASURES. This module is that join, over three data sources the
-repo already records:
+A join over three sources the serving stack already records:
 
 * **per-program bytes + FLOPs** — the compile ledger's AOT analysis
   (``runtime/introspection.py``: ``memory_analysis()`` argument/temp/
@@ -17,14 +12,10 @@ repo already records:
   subtracted so warm-up dispatches don't dilute the steady-state mean
   (the first dispatch of every program rode a trace+compile and its
   recorded wall is mostly compiler, not hardware);
-* **chip ceilings** — ``tools/hw_probe.py``'s honestly measured numbers
-  when a probe file is present (``--out`` / ``DLLAMA_HW_PROBE_FILE``;
-  the 2026-07-31 capture measured ~770 GB/s effective HBM and
-  ~70 TFLOP/s chained bf16 on one v5e), falling back to the nameplate
-  table by device kind. A kind the table does not hold is an error
-  (:class:`UnknownDeviceKind`), never a default row. The ceiling source
-  is always named in the output — a fraction against nameplate and a
-  fraction against measured silicon are different claims.
+* **chip ceilings** — the nameplate table below, by device kind. A kind
+  the table does not hold is an error (:class:`UnknownDeviceKind`),
+  never a default row. The ceiling source (``nameplate:<kind>``) is
+  named in the output.
 
 Per program it yields achieved HBM GB/s, achieved TFLOP/s, the roofline
 fraction (max of the bandwidth and compute fractions, clamped to (0, 1]
@@ -32,38 +23,19 @@ fraction (max of the bandwidth and compute fractions, clamped to (0, 1]
 aliased arguments, and is kept in ``raw_fraction``), and a memory-bound
 vs compute-bound classification. Surfaces: ``GET /debug/roofline``,
 ``dllama_roofline_fraction{scope,program}`` /
-``dllama_achieved_hbm_gbps`` / ``dllama_achieved_tflops`` gauges, a
-``roofline=…%`` fragment in ``--stats``, and bench.py's ``roofline``
-section.
+``dllama_achieved_hbm_gbps`` / ``dllama_achieved_tflops`` gauges and a
+``roofline=…%`` fragment in ``--stats``. This is the serving process's
+own view of itself; the repository's measured record is
+``benchmark/run.py``'s per-layer metrics (PERF.md section 3), which
+reduce a profiler trace and do not read this module.
 
-HONEST TIMING RULES (normative — PERF.md "Methodology"; every wall this
-module consumes was produced under them, and every new measurement in
-this repo must be too):
-
-1. a measured region ends with ``jax.device_get`` of a value that
-   **data-depends** on the computation — the rule was written against
-   a transport whose ``block_until_ready`` returned before the device
-   finished; ``chip_smoke.py`` re-checks that claim on the chip it runs
-   on (PERF.md "Bring-up"), and a data-dependent fetch is right either
-   way;
-2. the host↔device fetch round-trip (~67 ms in the 2026-07-31 capture)
-   is measured separately and subtracted once per region; a region whose
-   net time is below the RTT floor reports **null**, never an inflated
-   rate (the perf-regression sentinel's thresholds inherit this floor);
-3. the first dispatch after a compile is a thrown-away warmup (this
-   module subtracts ledger compile walls for the same reason);
-4. sub-millisecond kernels are timed inside one dispatch with a
-   device-side loop at two iteration counts, taking the **slope**.
-
-Import-time dependency-free (stdlib only when loaded by file path; the
-telemetry/introspection joins import lazily) so bench.py's jax-free
-parent can load it for the ceilings table.
+The walls it consumes end in a ``jax.device_get`` of a value that
+data-depends on the dispatch, and the first dispatch after a compile is
+subtracted as warm-up (the ledger's compile walls).
 """
 
 from __future__ import annotations
 
-import json
-import os
 from dataclasses import asdict, dataclass
 
 # nameplate peak dense-bf16 TFLOP/s and HBM GB/s by device-kind substring
@@ -84,23 +56,16 @@ NAMEPLATE_SPECS = (
 
 class UnknownDeviceKind(ValueError):
     """No nameplate row for this ``device_kind`` — add the row with its
-    source to :data:`NAMEPLATE_SPECS` (or point ``DLLAMA_HW_PROBE_FILE`` at
-    a probe capture) rather than price a run against another chip's peaks."""
-
-# probe-file search order (after the env override): a repo-root snapshot,
-# then the chip watcher's capture directory
-_PROBE_ENV = "DLLAMA_HW_PROBE_FILE"
-_PROBE_CANDIDATES = ("HW_PROBE.json", os.path.join("bench_results",
-                                                   "hw_probe.jsonl"))
+    source to :data:`NAMEPLATE_SPECS` rather than price a run against
+    another chip's peaks."""
 
 
 @dataclass(frozen=True)
 class Ceilings:
     """One chip's roofline ceilings and where they came from.
 
-    ``source`` is ``probe:<path>`` (hw_probe measurements) or
-    ``nameplate:<kind>`` — achieved-vs-probe and achieved-vs-nameplate
-    are different claims and every consumer must say which it made."""
+    ``source`` is ``nameplate:<kind>``: the row of
+    :data:`NAMEPLATE_SPECS` the fractions are taken against."""
 
     hbm_gbps: float
     tflops: float
@@ -109,9 +74,8 @@ class Ceilings:
 
 
 def nameplate_ceilings(device_kind: str) -> Ceilings:
-    """Nameplate ceilings by device-kind substring (the fallback when no
-    probe file is present); raises :class:`UnknownDeviceKind` for a kind
-    the table does not hold."""
+    """Nameplate ceilings by device-kind substring; raises
+    :class:`UnknownDeviceKind` for a kind the table does not hold."""
     dk = (device_kind or "").lower()
     for key, tflops, gbps in NAMEPLATE_SPECS:
         if key in dk:
@@ -122,99 +86,11 @@ def nameplate_ceilings(device_kind: str) -> Ceilings:
         f"(known: {', '.join(k for k, _, _ in NAMEPLATE_SPECS)})")
 
 
-def probe_ceilings(path: str) -> Ceilings | None:
-    """Parse a hw_probe output file into ceilings, or None when the file
-    is absent/unreadable/incomplete. Two accepted shapes:
+def load_ceilings() -> Ceilings:
+    """The running backend's ceilings, from the nameplate table."""
+    import jax
 
-    * the tool's own JSONL stream (``tools/hw_probe.py --out FILE``):
-      the ``hbm_bw`` stage's ``chain_gbps`` (fetch-forced chain — the
-      honest effective bandwidth; ``sync_gbps`` pays one RTT per rep)
-      and the ``mxu`` stage's ``tflops``;
-    * a plain object ``{"hbm_gbps": ..., "tflops": ...}`` for
-      hand-curated snapshots.
-    """
-    try:
-        with open(path, encoding="utf-8") as f:
-            text = f.read()
-    except OSError:
-        return None
-    gbps = tflops = None
-    kind = ""
-    try:
-        obj = json.loads(text)
-        if isinstance(obj, dict) and "stage" not in obj:
-            gbps = obj.get("hbm_gbps")
-            tflops = obj.get("tflops")
-            kind = str(obj.get("device_kind", ""))
-    except ValueError:
-        obj = None
-    if gbps is None and tflops is None:
-        for line in text.splitlines():
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            try:
-                rec = json.loads(line)
-            except ValueError:
-                continue
-            stage = rec.get("stage")
-            if stage == "hbm_bw":
-                gbps = rec.get("chain_gbps") or rec.get("sync_gbps") or gbps
-            elif stage == "mxu":
-                tflops = rec.get("tflops") or tflops
-            elif stage == "device":
-                kind = str(rec.get("kind", kind))
-    if not gbps or not tflops:
-        return None  # a half-measured probe is not a ceiling claim
-    return Ceilings(hbm_gbps=float(gbps), tflops=float(tflops),
-                    source=f"probe:{path}", device_kind=kind)
-
-
-_ceilings_cache: list = []  # [] = unresolved; [Ceilings] once resolved
-
-
-def load_ceilings(device_kind: str | None = None,
-                  probe_path: str | None = None, *,
-                  refresh: bool = False) -> Ceilings:
-    """The process's chip ceilings: probe file first (the explicit path,
-    then the env override, then the repo-root candidates), nameplate by
-    device kind otherwise. The no-argument call is cached — a probe file
-    does not change mid-process."""
-    default_call = probe_path is None and device_kind is None
-    if default_call and _ceilings_cache and not refresh:
-        return _ceilings_cache[0]
-    paths = [probe_path] if probe_path else []
-    env = os.environ.get(_PROBE_ENV)
-    if env:
-        paths.append(env)
-    here = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    paths += [os.path.join(here, c) for c in _PROBE_CANDIDATES]
-    for p in paths:
-        c = probe_ceilings(p)
-        if c is not None:
-            break
-    else:
-        c = nameplate_ceilings(device_kind if device_kind is not None
-                               else _detect_device_kind())
-    if default_call:
-        _ceilings_cache.clear()
-        _ceilings_cache.append(c)
-    return c
-
-
-def _detect_device_kind() -> str:
-    """The running backend's device kind. Only consults jax when the
-    process has ALREADY imported it (an engine is running) — a jax-free
-    caller (the bench parent, lint tooling) must not trigger a backend
-    import/init just to label a ceiling: it gets "" and, with it,
-    :class:`UnknownDeviceKind` unless it names the kind itself."""
-    import sys as _sys
-
-    jax = _sys.modules.get("jax")
-    if jax is None:
-        return ""
-    return jax.devices()[0].device_kind
+    return nameplate_ceilings(jax.devices()[0].device_kind)
 
 
 # -- the per-program math ------------------------------------------------------

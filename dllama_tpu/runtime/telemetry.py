@@ -11,7 +11,7 @@ without jax) and cheap enough for the decode hot path:
   fixed-bucket :class:`Histogram` (a ``record()`` is one lock + one bisect
   + three float ops, ~1 µs against a multi-ms decode step). Every metric
   name is declared once in :data:`SPECS` (the lint surface for
-  ``tools/check_metrics_names.py``) and rendered as Prometheus text by
+  dlint rule ``metrics-names``) and rendered as Prometheus text by
   :meth:`Registry.render` for the API server's ``GET /metrics``.
 * **Span tracer** — per-request phase spans (``queue|prefill|decode|
   verify``) emitted as JSONL to an operator-chosen file (``--trace-out``).
@@ -35,7 +35,7 @@ from dataclasses import dataclass
 
 # -- metric name constants ----------------------------------------------------
 # One declaration point: instrumentation imports these; the lint
-# (tools/check_metrics_names.py) checks every name matches dllama_[a-z_]+
+# (dlint rule metrics-names) checks every name matches dllama_[a-z_]+
 # and is documented in TELEMETRY.md.
 
 # engine (runtime/engine.py)
@@ -421,8 +421,8 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "ceiling-bandwidth and achieved-compute / ceiling-compute, "
           "clamped to (0, 1] (runtime/roofline joins the compile "
           "ledger's measured bytes/FLOPs with the step-histogram walls "
-          "against the hw_probe or nameplate ceilings; refreshed by "
-          "GET /debug/roofline, the --stats tick, and bench.py)"),
+          "against the nameplate ceilings; refreshed by "
+          "GET /debug/roofline and the --stats tick)"),
     _spec(ACHIEVED_HBM_GBPS, "gauge",
           "Per-program achieved HBM bandwidth, GB/s: measured "
           "argument+temp+output bytes per dispatch over the "
@@ -853,7 +853,7 @@ def registry() -> Registry:
 # -- per-request span tracing -------------------------------------------------
 
 # The documented span-phase vocabulary — the closed world
-# tools/check_span_phases.py lints against (both directions: every
+# dlint rule span-phases lints against (both directions: every
 # tracer().emit call site uses a name listed here, and every name here
 # has a call site and a TELEMETRY.md mention):
 #
@@ -927,7 +927,7 @@ TICK_PHASES = ("deadlines", "admit_begin", "prefill_dispatch",
                "emit", "bookkeeping", "canary", "idle_wait")
 TICK_SPAN = "dllama.tick"
 
-# The closed-world eval config vocabulary (tools/check_eval_names.py
+# The closed-world eval config vocabulary (dlint rule eval-names
 # lints it both directions): the ``eval --compare`` CLI grammar, the
 # parity keys in QUALITY_BASELINE.json, and the ``config`` label on
 # dllama_eval_* series all draw from exactly this set.

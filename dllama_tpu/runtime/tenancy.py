@@ -67,7 +67,7 @@ OTHER = "other"
 TENANT_CAP = 64
 
 # The closed-world admission decision-reason vocabulary
-# (tools/check_tenant_names.py lints it both directions): every
+# (dlint rule tenant-reasons lints it both directions): every
 # flight-ring defer/shed/requeue/preempt decision in runtime/serving.py
 # and serve/router.py names one of exactly these reasons, and every
 # reason here has a live emit site — a misspelled reason must fail lint,

@@ -49,7 +49,7 @@ from ..tokenizer.chat import (ChatItem, ChatTemplateGenerator,
 # known routes for the HTTP request counter's route label — anything else is
 # folded into "other" so a scanner can't explode the label cardinality.
 # Closed-world: every route literal a handler matches on must be listed here
-# (tools/check_route_labels.py enforces it in `make lint`).
+# (dlint rule route-labels enforces it in `make lint`).
 _ROUTES = ("/v1/chat/completions", "/v1/kv/export", "/v1/models", "/metrics",
            "/health", "/healthz", "/readyz", "/debug",
            "/debug/compiles", "/debug/requests", "/debug/profile",
@@ -57,7 +57,7 @@ _ROUTES = ("/v1/chat/completions", "/v1/kv/export", "/v1/models", "/metrics",
            "/debug/roofline", "/debug/eval", "/debug/tenants")
 
 # the GET /debug index: one line per diagnostic endpoint. Closed-world with
-# _ROUTES (tools/check_route_labels.py: every /debug/* route has exactly one
+# _ROUTES (dlint rule route-labels: every /debug/* route has exactly one
 # entry here and vice versa), so the index can never silently omit a surface.
 _DEBUG_INDEX = {
     "/debug/compiles": "GET: compile ledger — every XLA trace+compile event "
@@ -1003,7 +1003,7 @@ def make_handler(state: ApiState):
             elif path == "/debug":
                 # the diagnostic surface's index: every /debug/* endpoint
                 # with a one-line description (closed-world vs _ROUTES —
-                # tools/check_route_labels.py)
+                # dlint rule route-labels)
                 self._json(200, {"endpoints": dict(_DEBUG_INDEX)})
             elif path == "/debug/roofline":
                 # the roofline observatory: per-program achieved bandwidth/

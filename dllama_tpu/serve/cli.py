@@ -25,6 +25,7 @@ import sys
 import time
 
 from ..formats.quants import F32, Q80
+from ..ops.linear import QUANT_MODES
 from ..runtime import telemetry as _telemetry
 from ..runtime.engine import InferenceEngine
 from ..tokenizer.chat import (ChatItem, ChatTemplateGenerator,
@@ -85,16 +86,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "the same partials twice (the reference does it "
                         "once)")
     p.add_argument("--quant-mode",
-                   choices=["auto", "exact", "fast", "turbo", "turbo16"],
-                   default="auto",
+                   choices=list(QUANT_MODES), default="auto",
                    help="quantized-matmul numerics (ops/linear.py): exact = "
                         "f32 dequant + HIGHEST-precision dots (golden "
                         "parity); fast = bf16 dequant, one MXU pass, f32 "
-                        "accumulation; turbo/turbo16 = per-column int8 "
-                        "planes with integer dots and scales in the "
-                        "epilogue (ops/turbo.py — the reference's Q80xQ40 "
-                        "integer-dot shape; turbo also row-quantizes "
-                        "activations to int8); auto = fast iff "
+                        "accumulation; auto = fast iff "
                         "--compute-dtype bf16")
     p.add_argument("--kv-dtype", choices=["auto", "f32", "bf16", "f8"],
                    default="auto",
@@ -420,42 +416,6 @@ _cli_wrote_quant_mode = False
 _env_quant_before_cli: str | None = None
 _cli_wrote_wire = False
 _env_wire_before_cli: str | None = None
-# non-quant-mode env knobs a promotion applied (var -> value WE wrote):
-# retired when the promotion stops covering them, so stale knobs can't
-# outlive their evidence
-_promo_applied: dict = {}
-
-
-def _promoted_serving_env():
-    """``(env, evidence)`` when an on-chip A/B promoted a serving config
-    (tools/promote_config.py wrote ``bench_promoted.json``), else None.
-
-    This is how a perf-matrix win becomes the SERVING default, not just a
-    bench configuration: every ``DLLAMA_TPU_*`` knob of the promotion (the
-    engine-scoped ones — quant mode, kernel choice, scan unroll, logits
-    residency; ``DLLAMA_BENCH_*`` knobs are bench-only) applies when the
-    user hasn't set it, with provenance printed and flags/env as the
-    override. The file lives at the repo root (absent for installed
-    packages — promotion is a checkout-level record).
-    ``DLLAMA_TPU_PROMOTED_CONFIG`` overrides the path; the value ``off``
-    disables promotion entirely (the test suite pins it off so an
-    operator's local promotion can't flip test numerics)."""
-    override = os.environ.get("DLLAMA_TPU_PROMOTED_CONFIG")
-    if override == "off":
-        return None
-    path = override or os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))), "bench_promoted.json")
-    try:
-        with open(path) as f:
-            promo = json.load(f)
-    except (OSError, ValueError):
-        return None
-    env = {k: str(v) for k, v in (promo.get("env") or {}).items()
-           if k.startswith("DLLAMA_TPU_")}
-    if not env:
-        return None
-    return env, promo.get("evidence") or {}
 
 
 def make_engine(args, multihost: bool | None = None) -> InferenceEngine:
@@ -479,41 +439,6 @@ def make_engine(args, multihost: bool | None = None) -> InferenceEngine:
         else:
             os.environ["DLLAMA_TPU_QUANT_MODE"] = _env_quant_before_cli
         _cli_wrote_quant_mode = False
-    promo = _promoted_serving_env()
-    # retire knobs a PRIOR make_engine promoted that no longer apply (the
-    # promotion file changed, was removed, or was turned off) — a user's
-    # own exports are untouched because only values WE wrote are tracked
-    env_now = promo[0] if promo is not None else {}
-    for var, val in list(_promo_applied.items()):
-        if env_now.get(var) != val:
-            if os.environ.get(var) == val:
-                os.environ.pop(var, None)
-            _promo_applied.pop(var, None)
-    if promo is not None:
-        # the on-chip A/B's winner serves by default (with provenance); an
-        # explicit flag or user env always wins per knob
-        env, ev = promo
-        applied = {}
-        for var, val in env.items():
-            if var == "DLLAMA_TPU_QUANT_MODE":
-                if (getattr(args, "quant_mode", "auto") != "auto"
-                        or "DLLAMA_TPU_QUANT_MODE" in os.environ):
-                    continue
-                os.environ[var] = val
-                _cli_wrote_quant_mode = True  # restore discipline applies
-            elif var not in os.environ or _promo_applied.get(var) == val:
-                os.environ[var] = val
-                _promo_applied[var] = val
-            else:
-                continue
-            applied[var] = val
-        if applied:
-            print(f"⚡ promoted serving config: "
-                  + " ".join(f"{k.removeprefix('DLLAMA_TPU_')}={v}"
-                             for k, v in applied.items())
-                  + f" — on-chip A/B (decode {ev.get('decode_tok_per_s')} vs "
-                    f"auto {ev.get('auto_decode_tok_per_s')} tok/s, "
-                    f"{ev.get('gain')}x); flags/env override")
     # --wire mirrors the quant-mode discipline: an explicit flag value is
     # set (and overrides a user export), the unset default restores
     # whatever a PRIOR make_engine in this process overwrote
@@ -943,7 +868,7 @@ def run_perplexity(args) -> int:
 
 def _eval_primary_config(args) -> str:
     """The PRIMARY eval config implied by the serving flags (one of
-    telemetry.EVAL_CONFIGS — the closed world tools/check_eval_names.py
+    telemetry.EVAL_CONFIGS — the closed world dlint rule eval-names
     lints)."""
     if args.batch_slots and args.batch_slots > 1:
         if args.kv_block_size:

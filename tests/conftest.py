@@ -13,10 +13,6 @@ import os
 # (pytest -m tpu); default is the 8-device virtual CPU mesh.
 _TPU_TIER = os.environ.get("DLLAMA_TESTS_TPU") == "1"
 
-# an operator's local bench_promoted.json must not flip test numerics:
-# promotion is off for the whole suite unless a test opts in explicitly
-os.environ.setdefault("DLLAMA_TPU_PROMOTED_CONFIG", "off")
-
 if not _TPU_TIER:
     os.environ["JAX_PLATFORMS"] = "cpu"  # force: the suite runs on the CPU mesh
     xla_flags = os.environ.get("XLA_FLAGS", "")

@@ -66,51 +66,59 @@ def test_chat_mode_replies_and_exits_on_eof(model_files, capsys, monkeypatch):
     assert "context is full" not in out.split("🤖")[0]  # prompt fit
 
 
-def test_promoted_quant_mode_becomes_default(model_files, tmp_path,
-                                             monkeypatch, capsys):
-    """A perf-matrix promotion (bench_promoted.json) becomes the SERVING
-    default: --quant-mode auto with no user env resolves to the promoted
-    mode with a provenance line; an explicit flag still wins."""
-    import json as _json
+@pytest.mark.parametrize("mode", ["turbo", "turbo16", "bananas"])
+def test_unknown_quant_mode_env_refused_at_engine_construction(
+        model_files, monkeypatch, mode):
+    """An exported DLLAMA_TPU_QUANT_MODE this build does not know is
+    refused before the load, naming the valid values: falling through to
+    ``auto`` would serve the operator other numerics without a word."""
+    from dllama_tpu.runtime.engine import InferenceEngine
 
-    from dllama_tpu.ops.turbo import TurboWeight
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_MODE", mode)
+    with pytest.raises(ValueError) as exc:
+        InferenceEngine(*model_files, compute_dtype="bfloat16")
+    msg = str(exc.value)
+    assert repr(mode) in msg
+    assert all(valid in msg for valid in ("auto", "exact", "fast"))
 
-    promo = tmp_path / "bench_promoted.json"
-    promo.write_text(_json.dumps({
-        "env": {"DLLAMA_TPU_QUANT_MODE": "turbo16"}, "combo": "turbo16",
-        "evidence": {"decode_tok_per_s": 70.2, "auto_decode_tok_per_s": 34.5,
-                     "gain": 2.03}}))
-    monkeypatch.setenv("DLLAMA_TPU_PROMOTED_CONFIG", str(promo))
-    monkeypatch.delenv("DLLAMA_TPU_SCAN_UNROLL", raising=False)
-    # DLLAMA_TPU_QUANT_MODE is managed MANUALLY, not via monkeypatch:
-    # make_engine itself writes the var by design, and monkeypatch.setenv
-    # would record that cli-written value as "previous" and re-instate it
-    # at teardown — leaking turbo/fast numerics into the rest of the suite
-    # (the round-5 full-suite golden failures).
+
+def test_quant_mode_flag_has_three_choices(model_files, capsys):
+    with pytest.raises(SystemExit) as exc:
+        cli.build_parser().parse_args(
+            ["inference", "--model", model_files[0],
+             "--tokenizer", model_files[1], "--quant-mode", "turbo"])
+    assert exc.value.code == 2
+    assert "choose from auto, exact, fast" in capsys.readouterr().err.replace("'", "")
+
+
+@pytest.mark.parametrize("exported", [None, "exact"])
+def test_make_engine_restores_quant_mode_env(model_files, exported):
+    """--quant-mode fast writes DLLAMA_TPU_QUANT_MODE for the engine it
+    builds; a later make_engine with the default ``auto`` in the same
+    process puts back what the user had (nothing, or their own export),
+    so the whole environment reads as it was found."""
+    # managed by hand, not monkeypatch.setenv: make_engine writes the
+    # variable by design, and monkeypatch would re-instate at teardown
+    # whatever value it saw first
     prev_qm = os.environ.pop("DLLAMA_TPU_QUANT_MODE", None)
+    if exported is not None:
+        os.environ["DLLAMA_TPU_QUANT_MODE"] = exported
+    found = dict(os.environ)
     base = ["inference", "--model", model_files[0],
             "--tokenizer", model_files[1], "--compute-dtype", "bf16",
             "--temperature", "0"]
     try:
-        eng = cli.make_engine(cli.build_parser().parse_args(base))
-        assert isinstance(eng.params.layers.wq, TurboWeight)
-        eng.close()
-        assert "promoted serving config" in capsys.readouterr().out
-        # explicit --quant-mode overrides the promotion
-        eng2 = cli.make_engine(cli.build_parser().parse_args(
+        eng = cli.make_engine(cli.build_parser().parse_args(
             base + ["--quant-mode", "fast"]))
-        assert not isinstance(eng2.params.layers.wq, TurboWeight)
+        assert os.environ["DLLAMA_TPU_QUANT_MODE"] == "fast"
+        assert eng.params.layers.wq.scales.dtype == "bfloat16"
+        eng.close()
+        eng2 = cli.make_engine(cli.build_parser().parse_args(base))
         eng2.close()
-        # user-exported env overrides it too
-        os.environ["DLLAMA_TPU_QUANT_MODE"] = "fast"
-        cli._cli_wrote_quant_mode = False
-        eng3 = cli.make_engine(cli.build_parser().parse_args(base))
-        assert not isinstance(eng3.params.layers.wq, TurboWeight)
-        eng3.close()
+        assert dict(os.environ) == found
     finally:
         cli._cli_wrote_quant_mode = False
         cli._env_quant_before_cli = None
-        cli._promo_applied.clear()
         if prev_qm is None:
             os.environ.pop("DLLAMA_TPU_QUANT_MODE", None)
         else:

@@ -73,7 +73,7 @@ def test_explicit_n_needs_tp_and_divisibility(model_files):
     eng.close()
 
 
-def test_unsupported_combos_refused_at_startup(model_files, monkeypatch):
+def test_unsupported_combos_refused_at_startup(model_files):
     mpath, tpath = model_files
     with pytest.raises(ValueError, match="--sp"):
         InferenceEngine(mpath, tpath, tp=2, sp=2, comm_overlap=4)
@@ -82,13 +82,6 @@ def test_unsupported_combos_refused_at_startup(model_files, monkeypatch):
     with pytest.raises(ValueError, match="offload"):
         InferenceEngine(mpath, tpath, tp=2, weight_mode="offload",
                         comm_overlap=4)
-    # turbo weights skip the overlapped merge entirely — a knob that
-    # would silently do nothing (while the banner and the bytes counter
-    # claim otherwise) must refuse, not lie
-    monkeypatch.setenv("DLLAMA_TPU_QUANT_MODE", "turbo16")
-    with pytest.raises(ValueError, match="turbo"):
-        InferenceEngine(mpath, tpath, tp=2, comm_overlap=4)
-    monkeypatch.delenv("DLLAMA_TPU_QUANT_MODE")
     with pytest.raises(ValueError, match="off.*auto.*integer"):
         InferenceEngine(mpath, tpath, tp=2, comm_overlap="bananas")
 

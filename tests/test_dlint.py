@@ -616,10 +616,10 @@ def test_pallas_gate_live_repo_kernels_routed():
     assert not res.findings, [str(f) for f in res.findings]
 
 
-def test_shard_map_wrapper_cli_still_works():
-    """The historical CLI entry points survive as thin wrappers."""
+def test_shard_map_rule_runs_from_cli():
+    """One rule by name through the module's own command line."""
     out = subprocess.run(
-        [sys.executable, "tools/check_shard_map_shim.py"],
+        [sys.executable, "-m", "tools.dlint", "--only", "shard-map-shim"],
         cwd=REPO, capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
     assert "shard-map-shim" in out.stdout
@@ -681,9 +681,9 @@ def test_tenant_reasons_live_repo_clean():
     assert not res.findings, [str(f) for f in res.findings]
 
 
-def test_tenant_wrapper_cli_still_works():
+def test_tenant_rule_runs_from_cli():
     out = subprocess.run(
-        [sys.executable, "tools/check_tenant_names.py"],
+        [sys.executable, "-m", "tools.dlint", "--only", "tenant-reasons"],
         cwd=REPO, capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
     assert "tenant-reasons" in out.stdout

@@ -175,8 +175,8 @@ def test_prefill_bucketed_matches_fixed(tmp_path):
 
 def test_quant_mode_flip_after_load_fails_loudly(model_files, monkeypatch):
     """Flipping DLLAMA_TPU_QUANT_MODE after load must raise, not silently run
-    one mode's math over the other mode's stored weights (bf16 scales, logits
-    head and turbo planes are baked in at load — ADVICE r4 drift finding)."""
+    one mode's math over the other mode's stored weights (bf16 scales and the
+    logits head are baked in at load)."""
     monkeypatch.setenv("DLLAMA_TPU_QUANT_MODE", "fast")
     e = make_engine(model_files, compute_dtype="bfloat16")
     e.generate("ab", 2, stop_on_eos=False)  # sanity: matching env serves

@@ -2,7 +2,7 @@
 (runtime/flightrec.py + the serving/engine wiring).
 
 The ISSUE-7 acceptance criterion lives here: a continuous-batching run
-(the CPU-mesh equivalent of ``bench.py --scenario continuous``) must
+on the CPU mesh (staggered arrivals through the paged scheduler) must
 export a Perfetto-loadable Chrome trace in which every request's TTFT
 attribution phases sum to within 5% of the measured wall TTFT — and the
 compile ledger must show zero post-steady compiles with the recorder

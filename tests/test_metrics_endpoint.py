@@ -104,7 +104,7 @@ def test_metrics_endpoint_after_one_completion(server):
 
 def test_metrics_names_all_match_convention(server):
     """Every sample name on the wire derives from a dllama_[a-z0-9_]+
-    metric (the contract tools/check_metrics_names.py lints at the source
+    metric (the contract dlint rule metrics-names lints at the source
     level; digits admitted for format names like q80)."""
     import re
 

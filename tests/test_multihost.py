@@ -893,54 +893,6 @@ def test_four_process_dp_tp_batched_serving(tiny_files):
         assert "served" in wtxt and "served 0" not in wtxt, wtxt[-1000:]
 
 
-# root driving turbo integer-dot planes over the worker mesh: both
-# processes derive identical TurboWeights from the same file + env (the
-# quant mode is cluster-fingerprinted), and the s8 dot's int32 partials
-# make the tp split exact
-TURBO_ROOT_SCRIPT = textwrap.dedent("""
-    import os, sys
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
-    os.environ["DLLAMA_TPU_QUANT_MODE"] = "turbo"
-    sys.path.insert(0, sys.argv[1])
-    from dllama_tpu.parallel.multihost import init_distributed
-    init_distributed(sys.argv[2], 2, 0, platform="cpu")
-    from dllama_tpu.runtime.engine import InferenceEngine
-    eng = InferenceEngine(sys.argv[3], sys.argv[4], tp=2, temperature=0.0,
-                          seed=5, multihost=True, compute_dtype="bfloat16")
-    from dllama_tpu.ops.turbo import TurboWeight
-    assert isinstance(eng.params.layers.wq, TurboWeight)
-    res = eng.generate([1, 2, 3], max_tokens=6, stop_on_eos=False)
-    print("TOKENS=" + ",".join(map(str, res.tokens)), flush=True)
-    eng.close()
-""")
-
-
-@pytest.mark.slow
-def test_two_process_turbo_decode(tmp_path, monkeypatch):
-    """Turbo composes with multihost: a 2-process tp=2 cluster under the
-    knob reproduces the solo turbo transcript (the mode is part of the
-    cluster fingerprint; each process derives its own shard)."""
-    from dllama_tpu.formats import quants, tfile
-    from dllama_tpu.runtime.engine import InferenceEngine
-
-    m, t = tmp_path / "m.m", tmp_path / "t.t"
-    write_tiny_model(m, tiny_header_params(vocab_size=268, seq_len=32,
-                                           weight_type=quants.Q40),
-                     np.random.default_rng(3))
-    tfile.write_tfile(t, byte_vocab_tokenizer())
-    m, t = str(m), str(t)
-
-    monkeypatch.setenv("DLLAMA_TPU_QUANT_MODE", "turbo")
-    local = InferenceEngine(m, t, tp=1, temperature=0.0, seed=5,
-                            compute_dtype="bfloat16")
-    expect = local.generate([1, 2, 3], max_tokens=6, stop_on_eos=False).tokens
-
-    got, _, _ = _run_two_proc_tokens(
-        TURBO_ROOT_SCRIPT, 50, m, t,
-        ("--compute-dtype", "bf16", "--buffer-float-type", "f32"))
-    assert got == expect
-
-
 # root driving PIPELINE stages across processes: pp is the DCN-friendly
 # axis (per-forward activation traffic independent of depth), so a
 # 2-process pp=2 cluster is the distributed deployment it exists for

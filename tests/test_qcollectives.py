@@ -6,7 +6,7 @@ collectives.
 All manual-SPMD entry goes through the version-compat shim
 (``parallel.api.shard_map``) — raw ``jax.shard_map`` does not exist on
 0.4.x jax and ``jax.experimental.shard_map`` is gone on ≥0.5, so a direct
-call can never trace on one of the two; tools/check_shard_map_shim.py
+call can never trace on one of the two; dlint rule shard-map-shim
 keeps this closed-world."""
 
 import numpy as np

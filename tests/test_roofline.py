@@ -1,18 +1,13 @@
 """Roofline observatory (runtime/roofline) + per-op attribution
-(profiling.op_attribution) + the perf-regression sentinel
-(tools/perf_baseline.py, bench.py --baseline).
+(profiling.op_attribution).
 
-Acceptance tier (ISSUE 9): on the CPU mesh, ``GET /debug/roofline``
-returns per-program entries whose achieved bytes/FLOPs are derived from
-the compile ledger's measured values, with zero post-steady compiles
-while the observatory is snapshotting — and a 20% synthetic step-time
-regression makes ``bench.py --baseline check`` exit nonzero naming the
-regressed metric."""
+On the CPU mesh, ``GET /debug/roofline`` returns per-program entries
+whose achieved bytes/FLOPs are derived from the compile ledger's measured
+values, with zero post-steady compiles while the observatory is
+snapshotting."""
 
 import json
 import os
-import subprocess
-import sys
 import threading
 import urllib.request
 from http.server import ThreadingHTTPServer
@@ -106,7 +101,7 @@ def test_snapshot_missing_memory_analysis_is_no_evidence():
             led._steady.pop("rooftest-scope", None)
 
 
-# -- ceilings: probe file vs nameplate ----------------------------------------
+# -- ceilings: the nameplate table --------------------------------------------
 
 
 def test_nameplate_ceilings_by_device_kind():
@@ -123,39 +118,6 @@ def test_nameplate_ceilings_by_device_kind():
     for kind in ("TPU v9 hypothetical", "NVIDIA H100", ""):
         with pytest.raises(roofline.UnknownDeviceKind):
             roofline.nameplate_ceilings(kind)
-
-
-def test_probe_ceilings_from_hw_probe_jsonl(tmp_path):
-    p = tmp_path / "hw_probe.jsonl"
-    p.write_text(
-        json.dumps({"stage": "device", "platform": "tpu",
-                    "kind": "TPU v5 lite"}) + "\n"
-        + json.dumps({"stage": "hbm_bw", "gib": 2, "chain_gbps": 770.2,
-                      "sync_gbps": 31.1}) + "\n"
-        + json.dumps({"stage": "mxu", "tflops": 70.4}) + "\n")
-    c = roofline.load_ceilings(probe_path=str(p))
-    assert c.hbm_gbps == pytest.approx(770.2)
-    assert c.tflops == pytest.approx(70.4)
-    assert c.source.startswith("probe:")
-    assert c.device_kind == "TPU v5 lite"
-
-
-def test_probe_ceilings_plain_object_and_fallbacks(tmp_path):
-    p = tmp_path / "HW_PROBE.json"
-    p.write_text(json.dumps({"hbm_gbps": 765.0, "tflops": 69.0}))
-    c = roofline.load_ceilings(probe_path=str(p))
-    assert (c.hbm_gbps, c.tflops) == (765.0, 69.0)
-    # a half-measured probe (no mxu stage) is NOT a ceiling claim: the
-    # nameplate fallback applies instead
-    half = tmp_path / "half.jsonl"
-    half.write_text(json.dumps({"stage": "hbm_bw", "chain_gbps": 700.0}))
-    assert roofline.probe_ceilings(str(half)) is None
-    c = roofline.load_ceilings(device_kind="v5e", probe_path=str(half))
-    assert c.source == "nameplate:v5e"
-    # absent file → nameplate too
-    c = roofline.load_ceilings(device_kind="v4",
-                               probe_path=str(tmp_path / "nope.json"))
-    assert c.source == "nameplate:v4"
 
 
 # -- per-op attribution vs the checked-in xplane fixture ----------------------
@@ -347,203 +309,3 @@ def test_debug_index_lists_every_debug_route(roofline_server):
         text = r.read().decode()
     assert 'route="/debug",status="200"' in text
     assert 'route="/debug/roofline",status="200"' in text
-
-
-# -- perf-regression sentinel -------------------------------------------------
-
-sys.path.insert(0, os.path.join(REPO, "tools"))
-import perf_baseline  # noqa: E402
-
-
-def _sample_bench() -> dict:
-    return {
-        "metric": "decode_tok_per_s_llama8b_q40_1chip",
-        "value": 34.54, "git": "abc1234", "device_kind": "TPU v5 lite",
-        "roofline": {"roofline_fraction": 0.356},
-        "stages": {
-            "8b": {"decode_tok_per_s": 34.54, "decode_ms_per_step": 28.949,
-                   "fetch_rtt_ms": 68.8},
-            "1b": {"decode_tok_per_s": 181.03, "decode_ms_per_step": 5.524,
-                   "fetch_rtt_ms": 66.4},
-        },
-    }
-
-
-def test_noise_thresholds_are_rtt_floor_aware():
-    m = perf_baseline.extract_metrics(_sample_bench())
-    # 8b: rtt/(64×28.9 ms) ≈ 3.7% → the flat 10% floor dominates
-    assert m["8b.decode_tok_per_s"]["noise_frac"] == pytest.approx(0.10)
-    # 1b: rtt/(64×5.5 ms) ≈ 18.8% → the RTT floor dominates
-    assert m["1b.decode_tok_per_s"]["noise_frac"] == pytest.approx(
-        66.4 / (64 * 5.524), abs=1e-3)
-    assert m["headline.roofline_fraction"]["higher_better"] is True
-
-
-def test_synthetic_20pct_regression_fails_check_naming_metric(tmp_path):
-    # THE acceptance criterion: a 20% step-time regression on the 8b
-    # preset must exit nonzero and NAME the regressed metric
-    base_res = tmp_path / "base.json"
-    reg_res = tmp_path / "regressed.json"
-    bfile = tmp_path / "PERF_BASELINE.json"
-    base_res.write_text(json.dumps(_sample_bench()))
-    worse = _sample_bench()
-    worse["stages"]["8b"]["decode_ms_per_step"] *= 1.2
-    worse["stages"]["8b"]["decode_tok_per_s"] /= 1.2
-    reg_res.write_text(json.dumps(worse))
-
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    rc_update = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--baseline",
-         "update", "--result", str(base_res), "--baseline-file", str(bfile),
-         "--name", "test"],
-        capture_output=True, text=True, cwd=REPO, env=env)
-    assert rc_update.returncode == 0, rc_update.stderr
-    # unregressed self-check passes
-    ok = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--baseline",
-         "check", "--result", str(base_res), "--baseline-file", str(bfile)],
-        capture_output=True, text=True, cwd=REPO, env=env)
-    assert ok.returncode == 0, ok.stdout + ok.stderr
-    # the regressed side fails, naming the metric in BOTH the human
-    # report and the emitted JSON line
-    bad = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--baseline",
-         "check", "--result", str(reg_res), "--baseline-file", str(bfile)],
-        capture_output=True, text=True, cwd=REPO, env=env)
-    assert bad.returncode == 1, bad.stdout + bad.stderr
-    assert "8b.decode_ms_per_step" in bad.stderr
-    line = json.loads(bad.stdout.strip().splitlines()[-1])
-    assert line["verdict"] == "regression"
-    assert "8b.decode_ms_per_step" in line["regressed"]
-    assert "8b.decode_tok_per_s" in line["regressed"]
-    # the 1b preset moved 0% — well inside ITS (RTT-floor-raised) noise
-    assert "1b.decode_tok_per_s" not in line["regressed"]
-
-
-def test_zero_baseline_metric_is_evidence_not_noise():
-    # a measured 0.0 (fully-overlapped exposed comm — the best possible
-    # result) is EVIDENCE: it must be recorded, and a real later growth
-    # is a regression, not a divide-by-zero or a silent drop
-    base = {"stages": {"multichip": {"comm_exposed_ms": 0.0,
-                                     "agg_tok_per_s": 10.0}}}
-    m = perf_baseline.extract_metrics(base)
-    assert m["multichip.comm_exposed_ms"]["value"] == 0.0
-    bl = perf_baseline.make_baseline(base, "zero")
-    worse = {"stages": {"multichip": {"comm_exposed_ms": 5.0,
-                                      "agg_tok_per_s": 10.0}}}
-    cmp = perf_baseline.compare(worse, bl)
-    assert [r["metric"] for r in cmp["regressions"]] \
-        == ["multichip.comm_exposed_ms"]
-    # holding at zero is a perfect hold, not a regression
-    cmp = perf_baseline.compare(base, bl)
-    assert cmp["verdict"] == "ok" and not cmp["regressions"]
-    # ...and sub-resolution timer jitter above an exact zero is NOISE —
-    # a 0.05 ms union sliver must not hard-fail CI as a -100% regression
-    jitter = {"stages": {"multichip": {"comm_exposed_ms": 0.05,
-                                       "agg_tok_per_s": 10.0}}}
-    cmp = perf_baseline.compare(jitter, bl)
-    assert not cmp["regressions"] and cmp["verdict"] == "ok"
-    # the band applies to NONZERO tiny latency baselines too: 0.15 ms →
-    # 0.35 ms is the same sub-resolution sliver as 0 → 0.2, not a -133%
-    # regression
-    tiny = {"stages": {"multichip": {"comm_exposed_ms": 0.15,
-                                     "agg_tok_per_s": 10.0}}}
-    bl2 = perf_baseline.make_baseline(tiny, "tiny")
-    drift = {"stages": {"multichip": {"comm_exposed_ms": 0.35,
-                                      "agg_tok_per_s": 10.0}}}
-    cmp = perf_baseline.compare(drift, bl2)
-    assert not cmp["regressions"] and cmp["verdict"] == "ok"
-
-
-def test_batched_stage_rtt_floor_uses_its_own_step_count():
-    # @b16 stages measure 32 decode steps (bench.py stage_child), not 64:
-    # their RTT floor is twice as tall as the same step time unbatched
-    bench = {"stages": {
-        "1b": {"decode_tok_per_s": 100.0, "decode_ms_per_step": 5.5,
-               "fetch_rtt_ms": 66.0},
-        "1b@b16": {"decode_tok_per_s": 400.0, "decode_ms_per_step": 5.5,
-                   "fetch_rtt_ms": 66.0},
-    }}
-    m = perf_baseline.extract_metrics(bench)
-    plain = m["1b.decode_tok_per_s"]["noise_frac"]
-    batched = m["1b@b16.decode_tok_per_s"]["noise_frac"]
-    assert plain == pytest.approx(66.0 / (64 * 5.5), abs=1e-3)
-    assert batched == pytest.approx(66.0 / (32 * 5.5), abs=1e-3)
-
-
-def test_corrupt_baseline_file_is_named_rc2_not_a_regression(tmp_path):
-    bad = tmp_path / "PERF_BASELINE.json"
-    bad.write_text('{"name": "r05", "metrics": {TRUNCATED')
-    res = tmp_path / "r.json"
-    res.write_text(json.dumps(_sample_bench()))
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--baseline",
-         "check", "--result", str(res), "--baseline-file", str(bad)],
-        capture_output=True, text=True, cwd=REPO)
-    assert p.returncode == 2, p.stdout + p.stderr
-    assert "baseline file unusable" in p.stderr
-    assert "Traceback" not in p.stderr
-    # a missing/corrupt RESULT file is rc 2 too — the regression exit
-    # code stays reserved for real regressions
-    good_bl = tmp_path / "good_bl.json"
-    good_bl.write_text(json.dumps(
-        perf_baseline.make_baseline(_sample_bench(), "ok")))
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--baseline",
-         "check", "--result", str(tmp_path / "missing.json"),
-         "--baseline-file", str(good_bl)],
-        capture_output=True, text=True, cwd=REPO)
-    assert p.returncode == 2, p.stdout + p.stderr
-    assert "result file unusable" in p.stderr
-    assert "Traceback" not in p.stderr
-
-
-def test_skipped_run_is_no_evidence_never_a_verdict(tmp_path):
-    bfile = tmp_path / "PERF_BASELINE.json"
-    bfile.write_text(json.dumps(
-        perf_baseline.make_baseline(_sample_bench(), "test")))
-    skipped = {"metric": "decode_tok_per_s_llama8b_q40_1chip", "value": 0.0,
-               "skipped": True,
-               "skip_reason": "backend unavailable: 5 probe attempts failed",
-               "stages": {}}
-    cmp = perf_baseline.compare(skipped, json.loads(bfile.read_text()))
-    assert cmp["verdict"] == "no_evidence"
-    assert not cmp["regressions"] and not cmp["improvements"]
-    assert len(cmp["no_evidence"]) == len(
-        perf_baseline.extract_metrics(_sample_bench()))
-    assert all("skipped" in r["reason"] for r in cmp["no_evidence"])
-    # a skipped run must never overwrite a real baseline either
-    with pytest.raises(ValueError):
-        perf_baseline.make_baseline(skipped, "nope")
-    # and the CLI exit code for no-evidence is 0 (green, explicitly
-    # unverified — the make perf-check contract on no-hardware runners)
-    skipped_path = tmp_path / "skipped.json"
-    skipped_path.write_text(json.dumps(skipped))
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--baseline",
-         "check", "--result", str(skipped_path),
-         "--baseline-file", str(bfile)],
-        capture_output=True, text=True, cwd=REPO, env=env)
-    assert p.returncode == 0, p.stdout + p.stderr
-    assert "no evidence" in p.stderr
-
-
-def test_committed_baseline_matches_recorded_bench_numbers():
-    # the committed PERF_BASELINE.json must stay loadable and carry the
-    # BENCH-trajectory headline (8B decode) with an RTT-aware threshold
-    with open(os.path.join(REPO, "PERF_BASELINE.json")) as f:
-        doc = json.load(f)
-    assert doc["metrics"]["8b.decode_tok_per_s"]["value"] > 0
-    assert 0.05 <= doc["metrics"]["8b.decode_tok_per_s"]["noise_frac"] <= 0.5
-    # and bench_compare accepts it as a side (satellite: baseline
-    # artifacts are comparable)
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bench_compare.py"),
-         os.path.join(REPO, "PERF_BASELINE.json"),
-         os.path.join(REPO, "BENCH_r04_manual.json")],
-        capture_output=True, text=True, cwd=REPO)
-    assert p.returncode == 0, p.stderr
-    assert "decode_tok_per_s" in p.stdout

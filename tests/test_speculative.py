@@ -366,13 +366,10 @@ def test_spec_coins_consumed_rule():
     assert spec_coins_consumed(4, 4) == 5   # all accepted + bonus
 
 
-def test_speculative_identical_under_turbo(model_files, monkeypatch):
-    """Speculation composes with turbo numerics: a8 quantizes activations
-    per ROW, so each token position quantizes identically in a [B, K+1]
-    verify and a [B, 1] decode dispatch — greedy identity holds modulo the
-    same dispatch-shape ulp hazard the fast path documents (asserted
-    exactly here on CPU, like the fast-mode identity tests)."""
-    monkeypatch.setenv("DLLAMA_TPU_QUANT_MODE", "turbo")
+def test_speculative_identical_under_fast(model_files):
+    """Speculation composes with fast numerics (bf16 engines, mode
+    ``auto``): a [B, K+1] verify and a [B, 1] decode dispatch pick the
+    same greedy tokens (asserted exactly here on CPU)."""
     plain = _gen(model_files, "the quick brown fox", 32,
                  compute_dtype="bfloat16")
     spec = _gen(model_files, "the quick brown fox", 32, spec_lookup=4,

@@ -9,14 +9,12 @@ suppressions — and every repo invariant as a rule module on top:
 * :mod:`tools.dlint.thread_ownership` — declared thread ownership
   (``# dlint: owner=...``), monitor-vs-loop call-graph checking,
   lock-discipline (``# dlint: guarded-by=...``) and lock-order cycles.
-* the six historical ``tools/check_*.py`` scanners, consolidated as rule
-  modules (:mod:`tools.dlint.metrics_names`, ``exception_hygiene``,
-  ``route_labels``, ``failpoint_sites``, ``span_phases``,
-  ``shard_map_shim``) — each old CLI entry point survives as a thin
-  wrapper.
+* the name and registry rules (:mod:`tools.dlint.metrics_names`,
+  ``exception_hygiene``, ``route_labels``, ``failpoint_sites``,
+  ``span_phases``, and ``shard-map-shim`` in ``trace_safety``).
 * :mod:`tools.dlint.slo_names` — the SLO observatory's objective
   vocabulary (``runtime/slo.OBJECTIVES``) closed-world across the cli
-  grammar, gauges, bench output, and docs.
+  grammar, gauges and docs.
 
 Run everything: ``python -m tools.dlint`` (repo-clean exit 0); one rule:
 ``--only RULE``; machine-readable: ``--json``. The invariant catalog

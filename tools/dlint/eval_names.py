@@ -2,10 +2,9 @@
 
 The quality observatory's config vocabulary
 (``dllama_tpu.runtime.telemetry.EVAL_CONFIGS``) names the same thing in
-five places: the eval CLI's ``--compare`` grammar, the ``config`` label
+four places: the eval CLI's ``--compare`` grammar, the ``config`` label
 on the ``dllama_eval_*`` metric family, the parity map inside the
-committed ``QUALITY_BASELINE.json``, the bench eval scenario's
-per-config section, and the README docs. This rule keeps the vocabulary
+committed ``QUALITY_BASELINE.json``, and the README docs. This rule keeps the vocabulary
 closed in BOTH directions: every declared config is grammar-clean,
 derived (not hand-copied) into the CLI grammar, recorded in the
 committed baseline, and documented — and every config-shaped consumer
@@ -31,7 +30,7 @@ BASELINE = "QUALITY_BASELINE.json"
 # instead of hand-spelling it (a hand-copied list is how grammars drift)
 DERIVING_FILES = ("dllama_tpu/serve/cli.py",
                   "dllama_tpu/runtime/evalharness.py",
-                  "bench.py", "tools/quality_baseline.py")
+                  "tools/quality_baseline.py")
 # operator-facing docs where every config must be spelled out
 DOC_FILES = ("README.md",)
 
@@ -131,5 +130,5 @@ def check(project: Project, vocab=None) -> tuple[list[Finding], str]:
 rule("eval-names",
      "every eval config name is grammar-clean, derived from "
      "telemetry.EVAL_CONFIGS by its consumers (cli/--compare, harness, "
-     "bench, quality ledger), documented in README, and closed-world vs "
+     "quality ledger), documented in README, and closed-world vs "
      "the committed QUALITY_BASELINE.json parity keys")(check)

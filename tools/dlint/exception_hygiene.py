@@ -1,4 +1,4 @@
-"""Exception-hygiene rule (migrated from ``tools/check_exception_hygiene.py``).
+"""Exception-hygiene rule.
 
 The serving stack's fault-tolerance contract (ISSUE 2): no failure is
 silently swallowed — a request either completes or its waiter gets an

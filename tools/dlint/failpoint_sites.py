@@ -1,4 +1,4 @@
-"""Failpoint-site rule (migrated from ``tools/check_failpoint_sites.py``).
+"""Failpoint-site rule.
 
 The chaos suite can only drive failure paths whose injection sites exist
 and are named what the docs say. Closed-world both directions: every

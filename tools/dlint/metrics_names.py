@@ -1,4 +1,4 @@
-"""Metric-name rule (migrated from ``tools/check_metrics_names.py``).
+"""Metric-name rule.
 
 Closed-world in BOTH directions against the single declaration point
 (``dllama_tpu.runtime.telemetry.SPECS``): naming convention, TELEMETRY.md

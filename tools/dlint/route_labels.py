@@ -1,4 +1,4 @@
-"""Route-label rule (migrated from ``tools/check_route_labels.py``).
+"""Route-label rule.
 
 ``serve/api.py`` folds unknown paths into the ``other`` route label; that
 only works if every route a handler matches is in ``_ROUTES``, and the

@@ -1,10 +1,9 @@
 """SLO objective-name rule.
 
 The SLO observatory's objective vocabulary
-(``dllama_tpu.runtime.slo.OBJECTIVES``) names the same thing in five
+(``dllama_tpu.runtime.slo.OBJECTIVES``) names the same thing in four
 places: the ``--slo`` cli grammar, the ``/debug/slo`` body, the
-``dllama_slo_*`` gauge labels, the fleet bench's ``slo`` section, and
-the TELEMETRY.md / README.md docs. This rule keeps the vocabulary closed in
+``dllama_slo_*`` gauge labels, and the TELEMETRY.md / README.md docs. This rule keeps the vocabulary closed in
 BOTH directions: every declared objective follows the grammar and is
 documented everywhere, and every objective-shaped token anywhere in the
 tree names a declared objective — a typo'd SLO name must fail lint, not
@@ -26,11 +25,11 @@ GRAMMAR_RE = re.compile(r"^(?:(?:ttft|itl)_p\d{2}_ms|shed_rate)$")
 TOKEN_RE = re.compile(r"(?<![a-z0-9_])((?:ttft|itl)_p\d{2}_ms)(?!_)")
 
 # where every objective must be spelled (the operator-facing contract)
-DOC_FILES = ("dllama_tpu/runtime/TELEMETRY.md", "README.md", "dllama_tpu/serve/cli.py",
-             "bench.py")
+DOC_FILES = ("dllama_tpu/runtime/TELEMETRY.md", "README.md",
+             "dllama_tpu/serve/cli.py")
 # where objective-shaped tokens are hunted for the reverse direction
 SCAN_DIRS = ("dllama_tpu",)
-SCAN_FILES = ("bench.py", "dllama_tpu/runtime/TELEMETRY.md", "README.md")
+SCAN_FILES = ("dllama_tpu/runtime/TELEMETRY.md", "README.md")
 
 
 def _load_objectives():
@@ -100,5 +99,5 @@ def check(project: Project, objectives=None) -> tuple[list[Finding], str]:
 
 rule("slo-names",
      "every SLO objective name is grammar-clean, documented in the cli "
-     "grammar / TELEMETRY.md / README.md / bench, and closed-world vs "
+     "grammar / TELEMETRY.md / README.md, and closed-world vs "
      "objective-shaped tokens")(check)

@@ -1,4 +1,4 @@
-"""Span-phase rule (migrated from ``tools/check_span_phases.py``).
+"""Span-phase rule.
 
 The span ring's phase vocabulary (``runtime/telemetry.PHASES``) is an
 operator contract: every SpanTracer call site emits a CONSTANT phase

@@ -117,7 +117,7 @@ def check_jit_entry(project: Project):
                       f"plan_scoped_jit")
 
 
-# -- rule: shard-map-shim (migrated from tools/check_shard_map_shim.py) -------
+# -- rule: shard-map-shim ----------------------------------------------------
 
 _RAW_SHARD_RE = re.compile(
     r"(jax\.shard_map"
@@ -137,8 +137,7 @@ def check_shard_map_shim(project: Project):
     findings: list[Finding] = []
     n = 0
     for sf in project.walk(PKG, "tests", "tools"):
-        if sf.rel == SHIM or _is_dlint_path(sf.rel) \
-                or sf.rel == "tools/check_shard_map_shim.py":
+        if sf.rel == SHIM or _is_dlint_path(sf.rel):
             continue
         n += 1
         for lineno, line in sf.code_lines():
@@ -159,10 +158,9 @@ def check_shard_map_shim(project: Project):
 _JIT_WRAPPERS = {"plan_scoped_jit", "jit", "shard_map"}
 # static reads on traced values: array metadata, plus shape-derived
 # properties and pytree AUX fields this repo declares static under jit
-# (QuantizedWeight.out_features is codes.shape-derived; TurboWeight.a8
-# is aux data — "a static under jit", ops/turbo.py)
+# (QuantizedWeight.out_features is codes.shape-derived)
 _METADATA_ATTRS = {"shape", "ndim", "dtype", "size", "sharding", "aval",
-                   "itemsize", "out_features", "a8"}
+                   "itemsize", "out_features"}
 _STATIC_NAMES = {"cfg", "config", "plan", "mesh", "self", "impl", "axis",
                  "axis_name", "axis_names", "interpret", "fast", "bn", "bk",
                  "block_size", "unroll", "site", "sites", "program", "scope",

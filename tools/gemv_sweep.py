@@ -8,7 +8,7 @@ several (bn, bk) block choices against the decode-shaped FUSED
 dequant-GEMV (ops/quant_matmul._decode_kernel — one full-K pass per N
 stripe, dequant in VMEM), the XLA dequant+dot fallback (f32- and
 bf16-stored scales), a dense bf16 matmul (the no-quantization reference
-point), a raw s8xs8 MXU dot -> s32 (rate bound for a w8a8 "turbo" mode),
+point), a raw s8xs8 MXU dot -> s32 (rate bound for a w8a8 mode),
 manually packed 4-bit codes unpacked on the VPU (halved code HBM vs
 shift/mask cost), and multi-row activations (M=8 verify / M=256
 prefill-chunk shapes).
@@ -36,11 +36,9 @@ is; before a custom call it is a copy), the kernel taking the stack and the
 index (quant_matmul's ``layer`` entry). ``--variants`` runs the older
 M = 1 exploration instead (tile picks, packed codes, s8 x s8, ...).
 
-``--json`` prints ONE machine-readable JSON line (same contract as
-``tools/profile_decode.py --json``): ``{"tool": "gemv_sweep",
+``--json`` prints ONE machine-readable JSON line: ``{"tool": "gemv_sweep",
 "device_kind": ..., "rows": [{"shape", "label", "us", "gbps"}, ...]}`` —
-scriptable kernel A/Bs, and ``tools/bench_compare.py`` diffs two sweep
-lines ranking each variant's effective GB/s.
+scriptable kernel A/Bs.
 """
 
 from __future__ import annotations
@@ -262,7 +260,7 @@ def main() -> None:
         bench("dense bf16 (2B/weight)", lambda x, w: x @ w, x, wd,
               bytes_moved=2 * K * N)
         # s8 x s8 -> s32 directly on the MXU (no converts the compiler could
-        # hoist): the per-op rate bounds a w8a8 "turbo" quant mode
+        # hoist): the per-op rate bounds a w8a8 quant mode
         xq = jnp.clip(jnp.round(x.astype(jnp.float32) * 16.0),
                       -127, 127).astype(jnp.int8)
         bench("s8xs8 MXU dot -> s32",

@@ -2,10 +2,10 @@
 """Quality-regression sentinel: record a perplexity baseline from the
 eval harness's JSON output and gate later runs against it.
 
-``tools/perf_baseline.py`` guards speed; nothing guarded whether a
-promoted config still *predicts well* — a quant or kernel change could
+``benchmark/run.py`` and the ledger measure speed; they do not say
+whether a build still *predicts well* — a quant or kernel change could
 trade perplexity for throughput and stay green. This tool is the
-quality half of the promotion ledger:
+quality half of that record:
 
     python -m dllama_tpu eval --model m.m --data d.jsonl --json > R.json
     python tools/quality_baseline.py record R.json --name r01
@@ -154,8 +154,8 @@ def check_parity(result: dict) -> list[dict]:
 
 
 def write_baseline(doc: dict, path: str) -> None:
-    """THE baseline writer (same byte-stable format discipline as
-    tools/perf_baseline.write_baseline — committed files diff cleanly)."""
+    """THE baseline writer (byte-stable: sorted keys, one-space indent, so
+    committed files diff cleanly)."""
     with open(path, "w", encoding="utf-8") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
         f.write("\n")
@@ -184,9 +184,9 @@ def make_baseline(result: dict, name: str, source: str = "") -> dict:
 
 
 def compare(result: dict, baseline: dict) -> dict:
-    """Every baseline metric against the current result. Verdict
-    grammar matches tools/perf_baseline.compare: only ``regressions``
-    can fail a check; ``no_evidence`` never passes or fails one."""
+    """Every baseline metric against the current result. Only
+    ``regressions`` can fail a check; ``no_evidence`` never passes or
+    fails one."""
     current = extract_metrics(result)
     out: dict = {"baseline_name": baseline.get("name"),
                  "regressions": [], "improvements": [],
