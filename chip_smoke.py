@@ -537,7 +537,7 @@ def phase_hw_tier(ctx: dict) -> None:
     rc = ch.wait_exit(ctx["timeout"])
     last = ch.text().strip().splitlines()[-1]
     counts = {k: int(n) for n, k in re.findall(r"(\d+) (\w+)", last)}
-    if rc != 0 or counts.get("passed", 0) < 11 or \
+    if rc != 0 or counts.get("passed", 0) < 13 or \
             set(counts) - {"passed", "warnings", "warning"}:
         ch.fail(f"rc={rc}: {last}")
     say(f"  [hw-tier] {last.strip('= ')}")

@@ -30,6 +30,13 @@ state ``[n_linear, H, dk, dv]`` and the convolution's last ``K - 1`` inputs
   ``gated_delta_step`` on a TPU, its XLA twin elsewhere); rows whose block
   table is all null (inactive slots riding along) use the pool's null row.
 
+In both, everything a slot's context is made of rides the period scan's
+CARRY whole: the state and the tail, and the full layers' K/V (a column's
+or the pool's), which period ``p`` writes in place and attends through the
+whole array and ``p`` (:func:`~dllama_tpu.models.llama._attend_paged`). As
+the scan's ``xs``/``ys`` the K/V pool was sliced, stacked and copied back
+every step (PERF.md section 6, PR 33).
+
 A linear layer's mixer, for its input ``u``: one packed projection ``[q~ k~
 v~ z] = W_in u``, gates ``[a b] = W_ab u``; a causal depthwise convolution
 of ``K`` taps and SiLU over ``q~ k~ v~``; per head ``q = l2norm(q') /
@@ -229,19 +236,20 @@ def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 def _scan_periods(params: Params, cfg: ModelConfig, x: jax.Array, s, conv,
                   k, v, mixer, store, attend):
     """The period scan both programs share: a period's linear layers (a
-    ``fori_loop`` over one traced body), then its full layer. ``s, conv``
-    (every linear layer's state and tail, a column's or the pool) ride the
-    carry; ``k, v`` (the full layers' cache, a column's or the pool) go in
-    per period as the scan's ``xs`` and come back as its ``ys``.
-    ``mixer(h, lp, l, s, conv) -> (y, s', conv')`` is the form of the mixer
-    and ``store(a, a', l)`` puts what it gave back into the carry (a
+    ``fori_loop`` over one traced body), then its full layer. Everything a
+    slot's context is made of rides the CARRY whole, a column's or the
+    pool: ``s, conv`` (every linear layer's state and tail) and ``k, v``
+    (the full layers' cache, indexed by the period ``p``); nothing is
+    sliced into the scan or stacked out of it, so the pools are written in
+    place. ``mixer(h, lp, l, s, conv) -> (y, s', conv')`` is the form of the
+    mixer and ``store(a, a', l)`` puts what it gave back into the carry (a
     column's layer ``l``; the pool comes back whole);
-    ``attend(q, k, v, k_p, v_p) -> (att, k_p, v_p)`` owns a period's cache."""
+    ``attend(q, k, v, k_c, v_c, p) -> (att, k_c, v_c)`` owns the cache."""
     per_period = cfg.layer_period - 1
     lin, full = params.layers
 
-    def period(carry, xs):
-        p, k_p, v_p = xs
+    def period(carry, p):
+        x, s, conv, k_c, v_c = carry
 
         def linear_layer(j, carry):
             x, s, conv = carry
@@ -257,18 +265,19 @@ def _scan_periods(params: Params, cfg: ModelConfig, x: jax.Array, s, conv,
             x = _sublayer(cfg, x, lp.norm_ffn, lambda h: _ffn(cfg, h, lp))
             return x, store(s, new["s"], l), store(conv, new["conv"], l)
 
-        x, s, conv = jax.lax.fori_loop(0, per_period, linear_layer, carry)
+        x, s, conv = jax.lax.fori_loop(0, per_period, linear_layer,
+                                       (x, s, conv))
         cache = {}
 
         def attend_p(q, k, v):
-            att, cache["k"], cache["v"] = attend(q, k, v, k_p, v_p)
+            att, cache["k"], cache["v"] = attend(q, k, v, k_c, v_c, p)
             return att
 
         x = _full_layer(cfg, x, _layer_at(full, p), attend_p)
-        return (x, s, conv), (cache["k"], cache["v"])
+        return (x, s, conv, cache["k"], cache["v"]), None
 
     periods = jnp.arange(cfg.n_periods, dtype=jnp.int32)
-    (x, s, conv), (k, v) = jax.lax.scan(period, (x, s, conv), (periods, k, v))
+    (x, s, conv, k, v), _ = jax.lax.scan(period, (x, s, conv, k, v), periods)
     return _head(params, cfg, x), s, conv, k, v
 
 
@@ -290,15 +299,19 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.broadcast_to(
         start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
 
+    def at(a, l):
+        return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+
     def mixer(h, lp, l, s, conv):
-        at = lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-        return _mixer_chunk(cfg, h, lp, at(s), at(conv), n_valid)
+        return _mixer_chunk(cfg, h, lp, at(s, l), at(conv, l), n_valid)
 
     def store(a, a_l, l):
         return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
 
-    def attend(q, k, v, k_p, v_p):
-        return _attend_dense(cfg, q, k, v, k_p, v_p, start_pos, positions)
+    def attend(q, k, v, k_c, v_c, p):
+        att, k_p, v_p = _attend_dense(cfg, q, k, v, at(k_c, p), at(v_c, p),
+                                      start_pos, positions)
+        return att, store(k_c, k_p, p), store(v_c, v_p, p)
 
     logits, s, conv, k, v = _scan_periods(params, cfg, x, col.s, col.conv,
                                           col.k, col.v, mixer, store,
@@ -330,8 +343,9 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     def mixer(h, lp, l, s, conv):
         return _mixer_step(cfg, h, lp, l, rows, s, conv)
 
-    def attend(q, k, v, k_p, v_p):
-        return _attend_paged(cfg, q, k, v, k_p, v_p, positions, tables)
+    def attend(q, k, v, k_pool, v_pool, p):
+        return _attend_paged(cfg, q, k, v, k_pool, v_pool, p, positions,
+                             tables)
 
     logits, s, conv, k, v = _scan_periods(params, cfg, x, pool.s, pool.conv,
                                           pkv.k, pkv.v, mixer,

@@ -693,10 +693,12 @@ def _attend_dense(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
     return att, k_cache, v_cache
 
 
-def _write_kv_rows(pool: jax.Array, new: jax.Array, blk: jax.Array,
-                   off: jax.Array) -> jax.Array:
-    """Write ``new [B, T, n_kv, hd]`` into ``pool [n_blocks, n_kv, bs, hd]``
-    at the cells ``(blk[b, t], :, off[b, t], :)``.
+def _write_kv_rows(pool: jax.Array, l: jax.Array, new: jax.Array,
+                   blk: jax.Array, off: jax.Array) -> jax.Array:
+    """Write ``new [B, T, n_kv, hd]`` into layer ``l`` of the whole pool
+    ``[L, n_blocks, n_kv, bs, hd]`` at the cells ``(l, blk[b, t], :,
+    off[b, t], :)``, in place where the pool is a scan's carry: nothing of
+    the pool is sliced out or put back.
 
     The decode step (T == 1, static) writes its B cells row by row; wider
     dispatches (the verify step, a paged prefill) scatter. Same cells, same
@@ -706,27 +708,28 @@ def _write_kv_rows(pool: jax.Array, new: jax.Array, blk: jax.Array,
     new = new.astype(pool.dtype)
     if new.shape[1] != 1:
         # advanced (blk, off) indices around the head slice address each
-        # row's [n_kv, hd] cell
-        return pool.at[blk, :, off, :].set(new)
-    cells = jnp.swapaxes(new, 1, 2)                          # [B, n_kv, 1, hd]
+        # row's [n_kv, hd] cell of layer l
+        return pool.at[l, blk, :, off, :].set(new)
+    cells = jnp.swapaxes(new, 1, 2)[:, None, None]     # [B, 1, 1, n_kv, 1, hd]
     for b in range(new.shape[0]):
         pool = jax.lax.dynamic_update_slice(
-            pool, cells[b:b + 1], (blk[b, 0], 0, off[b, 0], 0))
+            pool, cells[b], (l, blk[b, 0], 0, off[b, 0], 0))
     return pool
 
 
 def _paged_layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
-                      k_pool: jax.Array, v_pool: jax.Array,
+                      k_pool: jax.Array, v_pool: jax.Array, l: jax.Array,
                       cos: jax.Array, sin: jax.Array,
                       positions: jax.Array, tables: jax.Array,
                       write_lens: jax.Array | None = None):
-    """One transformer block over the PAGED cache (runtime/kvblocks.py).
+    """Transformer block ``l`` over the PAGED cache (runtime/kvblocks.py).
 
-    ``k_pool/v_pool: [n_blocks, n_kv, block_size, hd]`` is this layer's
-    slice of the block pool; ``tables [B, max_blocks]`` maps each row's
-    logical block index to a physical block (0 = the null block). New K/V
-    rows scatter into their physical (block, offset) cell, then the row's
-    logical cache is gathered back to the dense head-major view and
+    ``k_pool/v_pool: [L, n_blocks, n_kv, block_size, hd]`` is the WHOLE
+    block pool, given back whole with layer ``l``'s new rows written;
+    ``tables [B, max_blocks]`` maps each row's logical block index to a
+    physical block (0 = the null block). New K/V rows scatter into their
+    physical (layer, block, offset) cell, then the row's logical cache is
+    gathered out of layer ``l`` to the dense head-major view and
     attended by the XLA oracle — value-identical to the dense slot-pool
     layer step on the same context (the gather materializes exactly the
     rows ``update_layer`` would have produced; rows behind unallocated
@@ -739,24 +742,29 @@ def _paged_layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
     table starts with the null block."""
     fq = fake_quant_q80 if cfg.sync_q80 else (lambda a: a)
     q, k, v = _attn_qkv(cfg, x, lp, cos, sin, positions, fq)
-    att, k_pool, v_pool = _attend_paged(cfg, q, k, v, k_pool, v_pool,
+    att, k_pool, v_pool = _attend_paged(cfg, q, k, v, k_pool, v_pool, l,
                                         positions, tables, write_lens)
     x, _ = _attn_out_and_ffn(cfg, x, att, lp, fq, taps=False)
     return x, k_pool, v_pool
 
 
 def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
-                  k_pool: jax.Array, v_pool: jax.Array, positions: jax.Array,
-                  tables: jax.Array, write_lens: jax.Array | None = None):
-    """Write the new rows ``k, v [B, T, n_kv, hd]`` into one layer's block
-    pool and attend ``q`` through the block ``tables``: the ragged paged
-    kernel where its gate resolves, the gather + XLA oracle otherwise
-    (:func:`_paged_layer_step` has the contract). Returns ``(att, k_pool,
-    v_pool)``. Shared with the hybrid decoder's full layers."""
+                  k_pool: jax.Array, v_pool: jax.Array, l: jax.Array,
+                  positions: jax.Array, tables: jax.Array,
+                  write_lens: jax.Array | None = None):
+    """Write the new rows ``k, v [B, T, n_kv, hd]`` into layer ``l`` of the
+    whole block pool ``[L, n_blocks, n_kv, bs, hd]`` and attend ``q``
+    through the block ``tables``: the ragged paged kernel where its gate
+    resolves, the gather + XLA oracle otherwise (:func:`_paged_layer_step`
+    has the contract). Both are handed the whole pool and the index (a
+    slice in front of a custom call is materialized, the lesson of
+    :func:`_layer_at`), so a layer scan that carries the pool moves none of
+    it. Returns ``(att, k_pool, v_pool)``, the pools whole. Shared with the
+    hybrid decoder's full layers, whose ``l`` is the period."""
     from ..ops import paged_attention as _pa
 
     B, T = q.shape[:2]
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     n_blocks_seq = tables.shape[1]
     brow = jnp.arange(B, dtype=jnp.int32)[:, None]
     blk = tables[brow, positions // bs]                      # [B, T]
@@ -772,8 +780,8 @@ def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
         blk = jnp.where(lane <= write_lens[:, None], blk, 0)
     # inactive rows carry all-null tables, so their ride-along writes land
     # in the null block
-    k_pool = _write_kv_rows(k_pool, k, blk, off)
-    v_pool = _write_kv_rows(v_pool, v, blk, off)
+    k_pool = _write_kv_rows(k_pool, l, k, blk, off)
+    v_pool = _write_kv_rows(v_pool, l, v, blk, off)
 
     kernel = _pa.kernel_choice(tuple(q.shape), cfg.n_kv_heads,
                                n_blocks_seq, bs)
@@ -781,11 +789,11 @@ def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
         # walk the block table in-kernel, as far as each row is long: the
         # dense logical cache never materializes in HBM, and a dead row
         # (an all-null table, whatever its stale position) costs nothing
-        att = _pa.paged_ragged_attention(q, k_pool, v_pool, tables,
+        att = _pa.paged_ragged_attention(q, k_pool, v_pool, l, tables,
                                          positions, cfg.head_dim, **kernel)
     else:
         def view(pool):
-            gathered = pool[tables]              # [B, M, n_kv, bs, hd]
+            gathered = pool[l, tables]           # [B, M, n_kv, bs, hd]
             return jnp.moveaxis(gathered, 2, 1).reshape(
                 B, cfg.n_kv_heads, n_blocks_seq * bs, cfg.head_dim)
 
@@ -1271,7 +1279,12 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     """Full forward over the paged pool: ``tokens [B, T]`` at per-row
     ``pos_vec [B]`` with block ``tables [B, max_blocks]``. Returns float32
     logits ``[B, T, vocab]`` and the updated pool (a
-    :class:`~dllama_tpu.runtime.kvblocks.PagedKVCache`). Always ragged —
+    :class:`~dllama_tpu.runtime.kvblocks.PagedKVCache`). The program owns
+    ONE pool: ``pkv.k / pkv.v [L, n_blocks, n_kv, bs, hd]`` ride the layer
+    scan's carry whole, layer ``l`` writes its ``B x T`` rows in place and
+    attends through the whole pool and ``l``; no layer's slice is cut out,
+    nothing is stacked back, and with the pool donated (the serving
+    wrappers do) what comes back is the caller's buffer. Always ragged —
     the paged path exists for continuous batching only. ``write_lens``
     (speculative verify: per-row valid input width minus one, i.e. the
     row's draft length) masks KV writes for lanes past it to the null
@@ -1302,18 +1315,20 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     by_index = _scan_by_index(cfg, B * T)
 
     def body(carry, xs):
-        x = carry
-        lp, k_l, v_l = xs
+        x, k_pool, v_pool = carry
+        l, lp = xs
         if by_index:
-            lp = _layer_at(params.layers, lp)
+            lp = _layer_at(params.layers, l)
         elif cfg.offload:
             lp = jax.device_put(lp, jax.memory.Space.Device)
-        x, k_l, v_l = _paged_layer_step(cfg, x, lp, k_l, v_l, cos, sin,
-                                        positions, tables, write_lens)
-        return x, (k_l, v_l)
+        return _paged_layer_step(cfg, x, lp, k_pool, v_pool, l, cos, sin,
+                                 positions, tables, write_lens), None
 
-    layers = _layer_indices(cfg) if by_index else params.layers
-    x, (new_k, new_v) = jax.lax.scan(body, x, (layers, pkv.k, pkv.v))
+    # the pool in the carry, not as xs/ys: there every layer's slice was
+    # copied out and back and the stacked output was a second pool, half of
+    # a decode step's device time (PERF.md section 6, PR 33)
+    xs = (_layer_indices(cfg), None if by_index else params.layers)
+    (x, new_k, new_v), _ = jax.lax.scan(body, (x, pkv.k, pkv.v), xs)
     x = rms_norm(x, params.final_norm, cfg.norm_epsilon)
     if cfg.sync_q80:
         x = fake_quant_q80(x)

@@ -1,8 +1,8 @@
 """Pallas TPU kernel: ragged paged attention over the block-table KV pool.
 
-The TPU-native replacement for ``_paged_layer_step``'s gather+oracle pair
+The TPU-native replacement for ``_attend_paged``'s gather+oracle pair
 (models/llama.py): the XLA path materializes each row's full dense logical
-cache per layer per step (``pool[tables]`` writes ``[B, M, n_kv, bs, hd]``
+cache per layer per step (``pool[l, tables]`` writes ``[B, M, n_kv, bs, hd]``
 to HBM, then the oracle reads it straight back), so the paged program
 family pays the KV bytes twice plus a scatter's worth of write bandwidth.
 This kernel is the "Ragged Paged Attention" shape (PAPERS.md, arxiv
@@ -10,6 +10,15 @@ This kernel is the "Ragged Paged Attention" shape (PAPERS.md, arxiv
 ride in as scalar-prefetch operands, and the kernel fetches the physical
 blocks a row OWNS straight into VMEM, so the dense logical cache never
 exists in HBM at all.
+
+**It is handed the whole pool.** ``k_pool / v_pool`` are ``[L, n_blocks,
+n_kv, bs, hd]``, every layer's blocks, and the layer is a traced scalar
+that rides in as the fourth scalar-prefetch operand and picks each DMA's
+source (``pool.at[layer, blk, heads]``). A caller that cut the layer's
+slice out first would have XLA materialize that slice in front of the
+custom call, a whole layer's pool copied a call; the decode step carries
+the pool through its layer scan instead and writes it in place
+(models/llama.paged_forward). One signature: a one-layer pool is ``L = 1``.
 
 **It walks what is live.** The walk and the softmax are bounded per row
 by what the inputs show:
@@ -23,8 +32,8 @@ by what the inputs show:
 * **dead rows** — a row of length 0 fetches nothing and writes zeros
   (finite: its logits still pass ``_nonfinite_rows``);
 * **whole blocks a fetch** — one ``make_async_copy`` moves a physical
-  block for every K/V head of the grid step's head group (the pool is
-  ``[n_blocks, n_kv, bs, hd]``: contiguous over heads), ``G`` blocks a
+  block for every K/V head of the grid step's head group (a block is
+  ``[n_kv, bs, hd]``: contiguous over heads), ``G`` blocks a
   loop iteration, double-buffered, the next row's first group started
   under this row's last; the grid is rows x head groups and the loop's
   trip count is the row's. ``_plan`` picks the head group and ``G`` from
@@ -72,8 +81,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(tbl_ref, pos_ref, nblk_ref, q_ref, k_hbm, v_hbm, out_ref,
-            kbuf, vbuf, sems, m_ref, l_ref, slot_ref, *,
+def _kernel(tbl_ref, pos_ref, nblk_ref, layer_ref, q_ref, k_hbm, v_hbm,
+            out_ref, kbuf, vbuf, sems, m_ref, l_ref, slot_ref, *,
             bs: int, kv_mul: int, hd: int, group: int, heads: int,
             n_entries: int):
     """One (row, head group) grid step: walk the row's ``nblk`` table
@@ -88,6 +97,7 @@ def _kernel(tbl_ref, pos_ref, nblk_ref, q_ref, k_hbm, v_hbm, out_ref,
     hidden under its predecessor's last group."""
     b, g = pl.program_id(0), pl.program_id(1)
     n_rows, n_groups = pl.num_programs(0), pl.num_programs(1)
+    layer = layer_ref[0]
     gt = group * bs
     tq = q_ref.shape[2]
     n = nblk_ref[b]
@@ -102,7 +112,7 @@ def _kernel(tbl_ref, pos_ref, nblk_ref, q_ref, k_hbm, v_hbm, out_ref,
             blk = tbl_ref[row * n_entries + jnp.minimum(j * group + i, last)]
             for w, (pool, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
                 copies.append(pltpu.make_async_copy(
-                    pool.at[blk, pl.ds(hgrp * heads, heads)],
+                    pool.at[layer, blk, pl.ds(hgrp * heads, heads)],
                     buf.at[slot, :, pl.ds(i * bs, bs), :],
                     sems.at[w, slot]))
         return copies
@@ -273,25 +283,28 @@ def kernel_choice(q_shape: tuple[int, ...], n_kv: int, n_blocks_seq: int,
 
 @functools.partial(jax.jit, static_argnames=("head_dim", "interpret"))
 def paged_ragged_attention(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array, tables: jax.Array,
-                           positions: jax.Array, head_dim: int, *,
+                           v_pool: jax.Array, layer: jax.Array,
+                           tables: jax.Array, positions: jax.Array,
+                           head_dim: int, *,
                            interpret: bool = False) -> jax.Array:
-    """Causal GQA attention of ``q [B, T, n_heads, hd]`` over the paged
-    pool ``k/v_pool [n_blocks, n_kv, bs, hd]`` through block ``tables
-    [B, M]`` (0 = null block), with per-row absolute positions
-    ``positions [B, T]`` (affine per row, the model's invariant).
+    """Causal GQA attention of ``q [B, T, n_heads, hd]`` over layer
+    ``layer`` (a traced scalar) of the WHOLE paged pool ``k/v_pool [L,
+    n_blocks, n_kv, bs, hd]`` through block ``tables [B, M]`` (0 = null
+    block), with per-row absolute positions ``positions [B, T]`` (affine
+    per row, the model's invariant). The pool is never sliced: the layer
+    rides in as a scalar-prefetch operand and picks the DMA's source.
 
     On every row whose first table entry is a real block, equal (to
     float32 reduction-order noise) to::
 
-        gathered = pool[tables]           # the dense logical cache
+        gathered = pool[layer, tables]    # the dense logical cache
         view = moveaxis(gathered, 2, 1).reshape(B, n_kv, M*bs, hd)
         attention(q, view_k, view_v, positions, head_dim)
 
     and zero on a row whose table starts with the null block (a dead
     slot, whatever its stale ``positions`` say)."""
     B, T, n_heads, D = q.shape
-    n_kv, bs = k_pool.shape[1], k_pool.shape[2]
+    n_kv, bs = k_pool.shape[2], k_pool.shape[3]
     M = tables.shape[1]
     kv_mul = n_heads // n_kv
     tq = T * kv_mul
@@ -308,10 +321,10 @@ def paged_ragged_attention(q: jax.Array, k_pool: jax.Array,
                        jnp.clip(-(-(pos0 + T) // bs), 1, M), 0)
 
     q_spec = pl.BlockSpec((1, heads, tq, D),
-                          lambda b, g, tbl, pos, nblk: (b, g, 0, 0),
+                          lambda b, g, tbl, pos, nblk, layer: (b, g, 0, 0),
                           memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # tables (flat), pos0, n_walk
+        num_scalar_prefetch=4,  # tables (flat), pos0, n_walk, layer
         grid=(B, n_kv // heads),
         in_specs=[q_spec,
                   pl.BlockSpec(memory_space=pl.ANY),
@@ -332,7 +345,8 @@ def paged_ragged_attention(q: jax.Array, k_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv, tq, D), jnp.float32),
         interpret=interpret,
-    )(tables.reshape(-1), pos0, n_walk, q_g, k_pool, v_pool)
+    )(tables.reshape(-1), pos0, n_walk,
+      jnp.asarray(layer, jnp.int32).reshape(1), q_g, k_pool, v_pool)
 
     return (out.reshape(B, n_kv, T, kv_mul, D)
                .transpose(0, 2, 1, 3, 4)

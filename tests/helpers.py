@@ -171,3 +171,80 @@ def require_host_memory() -> str:
         pytest.skip(f"no jax host memory kind places on this backend: "
                     f"{reason}")
     return kind
+
+
+def compile_paged_step(cfg, params, n_slots: int, n_blocks: int,
+                        block_size: int, table_width: int, pool_dtype):
+    """Compile ``paged_sampled_step_guarded`` with its cache donated, as the
+    server's wrapper jits it, for the default backend from shapes alone
+    (``params``: arrays or ``ShapeDtypeStruct``s). Returns ``(compiled,
+    bytes of the K pool)``: a step that carries the pool through its layer
+    scan and writes it in place holds no temporary
+    (``compiled.memory_analysis().temp_size_in_bytes``) anywhere near a
+    pool's size; one that stacks the pool out of the scan holds a whole
+    second pool (k and v) there."""
+    import jax
+    import jax.numpy as jnp
+
+    from dllama_tpu.models import llama
+    from dllama_tpu.runtime.kvblocks import PagedKVCache, StatePool
+
+    S = jax.ShapeDtypeStruct
+    shapes = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+    i32, f32 = jnp.int32, jnp.float32
+    cache = pkv = shapes(PagedKVCache.create(cfg, n_blocks, block_size,
+                                             dtype=pool_dtype))
+    if cfg.is_hybrid:
+        cache = (pkv, shapes(StatePool.create(cfg, n_slots, pool_dtype)))
+    B = n_slots
+    compiled = jax.jit(llama.paged_sampled_step_guarded, static_argnums=1,
+                       donate_argnums=(4,)).lower(
+        shapes(params), cfg, S((B, 1), i32), S((B,), i32), cache,
+        S((B, table_width), i32), S((B,), f32), S((B,), f32), S((B,), f32),
+        S((), f32)).compile()
+    return compiled, pkv.k.size * pkv.k.dtype.itemsize
+
+
+def param_shapes(cfg, scales_dtype):
+    """``Params`` of a dense or a hybrid decoder as shapes: Q40 planes for
+    every matmul of the layer stack(s), a dense head in the compute dtype.
+    For compiling a program, not for running it."""
+    import jax
+    import jax.numpy as jnp
+
+    from dllama_tpu.models.hybrid import HybridLayers, LinearLayerParams
+    from dllama_tpu.models.llama import LayerParams, Params
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    S = jax.ShapeDtypeStruct
+    f32 = lambda *shape: S(shape, jnp.float32)
+    dim, hid = cfg.dim, cfg.hidden_dim
+
+    def q40(n, out, in_):
+        return QuantizedWeight(scales=S((n, in_ // 32, out), scales_dtype),
+                               codes=S((n, in_, out), jnp.int8))
+
+    def full(n, norm_qk):
+        return LayerParams(
+            wq=q40(n, cfg.q_dim, dim), wk=q40(n, cfg.kv_dim, dim),
+            wv=q40(n, cfg.kv_dim, dim), wo=q40(n, dim, cfg.q_dim),
+            w1=q40(n, hid, dim), w2=q40(n, dim, hid), w3=q40(n, hid, dim),
+            norm_att=f32(n, dim), norm_ffn=f32(n, dim), **norm_qk)
+
+    if cfg.is_hybrid:
+        NL, NF, H, K = (cfg.n_linear_layers, cfg.n_periods, cfg.lin_heads,
+                        cfg.lin_conv_kernel)
+        lin = LinearLayerParams(
+            w_in=q40(NL, cfg.lin_in_dim, dim), w_ab=f32(NL, 2 * H, dim),
+            conv_w=f32(NL, K, cfg.lin_conv_dim), a_log=f32(NL, H),
+            dt_bias=f32(NL, H), norm_o=f32(NL, cfg.lin_value_dim),
+            w_out=q40(NL, dim, H * cfg.lin_value_dim), w1=q40(NL, hid, dim),
+            w2=q40(NL, dim, hid), w3=q40(NL, hid, dim),
+            norm_att=f32(NL, dim), norm_ffn=f32(NL, dim))
+        layers = HybridLayers(lin=lin, full=full(NF, dict(
+            norm_q=f32(NF, cfg.q_dim), norm_k=f32(NF, cfg.kv_dim))))
+    else:
+        layers = full(cfg.n_layers, dict(norm_q=None, norm_k=None))
+    dense = jnp.dtype(cfg.compute_dtype)
+    return Params(embedding=S((cfg.vocab_size, dim), dense), layers=layers,
+                  final_norm=f32(dim), logits=S((cfg.vocab_size, dim), dense))

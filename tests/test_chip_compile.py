@@ -209,7 +209,9 @@ def _compile_paged_attention(one_chip, slots, t, n_heads, n_kv, head_dim,
     from dllama_tpu.ops import paged_attention as pa
 
     q = _shape(one_chip, (slots, t, n_heads, head_dim), jnp.float32)
-    kv = _shape(one_chip, (slots * per_seq + 1, n_kv, block, head_dim), pool)
+    # the whole pool and a layer index: two layers, so the index is live
+    kv = _shape(one_chip, (2, slots * per_seq + 1, n_kv, block, head_dim),
+                pool)
     itemsize = jnp.dtype(pool).itemsize
     assert pa.supports(q.shape, n_kv, per_seq, block, compiled=True)
     tq = t * n_heads // n_kv
@@ -220,7 +222,8 @@ def _compile_paged_attention(one_chip, slots, t, n_heads, n_kv, head_dim,
     kernels = _compiled_kernels(
         functools.partial(pa.paged_ragged_attention, head_dim=head_dim,
                           interpret=False),
-        q, kv, kv, _shape(one_chip, (slots, per_seq), jnp.int32),
+        q, kv, kv, _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (slots, per_seq), jnp.int32),
         _shape(one_chip, (slots, t), jnp.int32))
     assert kernels.get("paged_ragged_attention") == 1, kernels
     return heads, group

@@ -617,8 +617,9 @@ def test_rowwise_kv_writes_equal_the_scatter_cell_for_cell(case, dtype):
     from dllama_tpu.models.llama import _write_kv_rows
 
     rng = np.random.default_rng(11)
-    n_blocks, n_kv, bs, hd, B = 9, 2, 16, 8, 5
-    pool = jnp.asarray(rng.standard_normal((n_blocks, n_kv, bs, hd)), dtype)
+    n_layers, l, n_blocks, n_kv, bs, hd, B = 3, 1, 9, 2, 16, 8, 5
+    pool = jnp.asarray(rng.standard_normal((n_layers, n_blocks, n_kv, bs, hd)),
+                       dtype)
     new = jnp.asarray(rng.standard_normal((B, 1, n_kv, hd)), jnp.float32)
     blk = np.asarray([[3], [7], [1], [8], [5]], np.int32)
     off = np.asarray([[0], [15], [4], [4], [9]], np.int32)
@@ -628,15 +629,16 @@ def test_rowwise_kv_writes_equal_the_scatter_cell_for_cell(case, dtype):
         blk[1:4, 0], off[1:4, 0] = 0, 0
     elif case == "all-null":
         blk[:], off[:] = 0, 0
-    got = jax.jit(_write_kv_rows)(pool, new, jnp.asarray(blk), jnp.asarray(off))
-    want = pool.at[jnp.asarray(blk), :, jnp.asarray(off), :].set(
+    got = jax.jit(_write_kv_rows)(pool, jnp.int32(l), new, jnp.asarray(blk),
+                                  jnp.asarray(off))
+    want = pool.at[l, jnp.asarray(blk), :, jnp.asarray(off), :].set(
         new.astype(pool.dtype))
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
-    # and against plain numpy, row after row
+    # and against plain numpy, row after row: layer l's cells, no other's
     ref = np.asarray(pool, np.float32).copy()
     for b in range(B):
-        ref[blk[b, 0], :, off[b, 0], :] = np.asarray(
+        ref[l, blk[b, 0], :, off[b, 0], :] = np.asarray(
             new.astype(pool.dtype), np.float32)[b, 0]
     np.testing.assert_array_equal(np.asarray(got, np.float32), ref)
 
@@ -648,13 +650,94 @@ def test_wide_kv_writes_keep_the_scatter():
     from dllama_tpu.models.llama import _write_kv_rows
 
     rng = np.random.default_rng(12)
-    pool = jnp.zeros((6, 2, 16, 8), jnp.float32)
+    pool = jnp.zeros((2, 6, 2, 16, 8), jnp.float32)
     new = jnp.asarray(rng.standard_normal((2, 3, 2, 8)), jnp.float32)
     blk = jnp.asarray([[1, 1, 2], [4, 0, 0]], jnp.int32)
     off = jnp.asarray([[14, 15, 0], [3, 4, 5]], jnp.int32)
-    got = _write_kv_rows(pool, new, blk, off)
+    got = _write_kv_rows(pool, jnp.int32(1), new, blk, off)
     np.testing.assert_array_equal(
-        np.asarray(got), np.asarray(pool.at[blk, :, off, :].set(new)))
+        np.asarray(got), np.asarray(pool.at[1, blk, :, off, :].set(new)))
+    assert not np.asarray(got)[0].any()
+
+
+def _three_layer_cfg():
+    """A three-layer toy Llama for the program-level cases below."""
+    from dllama_tpu.formats.mfile import ArchType, RopeType
+    from dllama_tpu.models import ModelConfig
+
+    return ModelConfig(arch=ArchType.LLAMA, dim=64, hidden_dim=96, n_layers=3,
+                       n_heads=8, n_kv_heads=2, head_dim=8, vocab_size=128,
+                       seq_len=64, norm_epsilon=1e-5, rope_theta=10000.0,
+                       rope_type=RopeType.LLAMA)
+
+
+def _written_cells(shape, tables, pos, lanes):
+    """The cells ``[L, n_blocks, bs]`` a dispatch writes: in every layer,
+    for live row ``b`` and lane ``t < lanes[b]``, position ``pos[b] + t``
+    of the row's table (rows parked on the null block write block 0)."""
+    L, _, _, bs, _ = shape
+    want = np.zeros((L, shape[1], bs), bool)
+    for b, n in enumerate(lanes):
+        for t in range(n):
+            want[:, tables[b, (pos[b] + t) // bs], (pos[b] + t) % bs] = True
+    return want
+
+
+@pytest.mark.parametrize("width", ["step", "verify"])
+def test_a_dispatch_changes_only_the_cells_it_writes(width):
+    """The pool rides the layer scan's carry and is written in place: after
+    a decode step (row-wise writes) or a verify dispatch (the scatter,
+    ``write_lens``) every layer's pool differs from what went in at the
+    rows' own cells and nowhere else, the null block (where inactive rows
+    and lanes past a draft land) aside."""
+    import jax
+    import jax.numpy as jnp
+
+    from dllama_tpu.models.llama import init_random_params, paged_forward
+
+    cfg = _three_layer_cfg()
+    params = init_random_params(cfg, seed=7)
+    rng = np.random.default_rng(5)
+    B, M, bs = 4, 4, 16
+    T = 1 if width == "step" else 4
+    tables = rng.permutation(np.arange(1, 1 + B * M)).reshape(B, M).astype(np.int32)
+    tables[1] = 0                                   # an inactive row
+    pos = np.asarray([5, 40, 30, 0], np.int32)      # row 2 crosses a block edge
+    lens = None if T == 1 else np.asarray([3, 0, 2, 1], np.int32)
+    lanes = [1, 0, 1, 1] if T == 1 else [4, 0, 3, 2]
+    shape = (cfg.n_layers, 1 + B * M, cfg.n_kv_heads, bs, cfg.head_dim)
+    pkv = PagedKVCache(k=jnp.asarray(rng.standard_normal(shape), jnp.float32),
+                       v=jnp.asarray(rng.standard_normal(shape), jnp.float32))
+    toks = jnp.asarray(rng.integers(1, 127, (B, T)).astype(np.int32))
+    # a lambda of its own: jits of one function object share an executable
+    # cache, and tests/test_paged_attention.py counts paged_forward's entries
+    _, out = jax.jit(lambda *a: paged_forward(a[0], cfg, *a[1:]))(
+        params, toks, jnp.asarray(pos), pkv, jnp.asarray(tables),
+        None if lens is None else jnp.asarray(lens))
+    want = _written_cells(shape, tables, pos, lanes)
+    for got, was in ((out.k, pkv.k), (out.v, pkv.v)):
+        changed = (np.asarray(got) != np.asarray(was)).any(axis=(2, 4))
+        np.testing.assert_array_equal(changed[:, 1:], want[:, 1:])
+
+
+def test_the_compiled_step_holds_no_second_pool():
+    """Structure, not time: with the pool donated (as the server's wrapper
+    jits it) the compiled decode step's temporaries stay far under ONE
+    pool's bytes at a geometry where the pool (2 x 12.6 MB) dwarfs the
+    rest. As the scan's stacked output the pool was a temporary of both."""
+    import jax.numpy as jnp
+
+    from dllama_tpu.models.llama import init_random_params
+    from helpers import compile_paged_step
+
+    cfg = _three_layer_cfg()
+    params = init_random_params(cfg, seed=7, quantized=True)
+    compiled, pool = compile_paged_step(cfg, params, n_slots=4, n_blocks=4096,
+                                        block_size=16, table_width=4,
+                                        pool_dtype=jnp.float32)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert pool == 3 * 4096 * 2 * 16 * 8 * 4
+    assert temp < pool // 2, (temp, pool)
 
 
 @pytest.mark.parametrize("mode,path", [("fused", "fused"), ("pallas", "tiled")])
@@ -667,14 +750,9 @@ def test_paged_step_tokens_equal_the_xla_modes(monkeypatch, mode, path):
     import jax
     import jax.numpy as jnp
 
-    from dllama_tpu.formats.mfile import ArchType, RopeType
-    from dllama_tpu.models import ModelConfig
     from dllama_tpu.models.llama import init_random_params, paged_forward
 
-    cfg = ModelConfig(arch=ArchType.LLAMA, dim=64, hidden_dim=96, n_layers=3,
-                      n_heads=8, n_kv_heads=2, head_dim=8, vocab_size=128,
-                      seq_len=64, norm_epsilon=1e-5, rope_theta=10000.0,
-                      rope_type=RopeType.LLAMA)
+    cfg = _three_layer_cfg()
     params = init_random_params(cfg, seed=7, quantized=True)
     rng = np.random.default_rng(3)
     B, M = 4, 4

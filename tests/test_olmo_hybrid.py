@@ -255,6 +255,52 @@ def test_padded_chunked_prefill_then_decode_logits(bench, engine, n_prompt, kern
     assert float(np.abs(got - want).max()) < LOGIT_TOL
 
 
+@pytest.mark.parametrize("kernel", [None, "pallas"])
+def test_a_step_changes_only_the_cells_it_writes(engine, kernel, monkeypatch):
+    """The full layers' K/V pool rides the period scan's carry beside the state and is written in
+    place: after a step every period's pool differs from what went in at each live row's own cell
+    and nowhere else (the null block, where an inactive row writes, aside); with the kernel forced,
+    ``paged_ragged_attention`` is handed the whole pool and the period."""
+    from dllama_tpu.models.llama import paged_forward
+    from dllama_tpu.runtime.kvblocks import PagedKVCache, StatePool
+
+    if kernel:
+        monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", kernel)
+    cfg = engine.cfg
+    rng = np.random.default_rng(9)
+    B, M, bs = 3, 4, 16
+    tables = rng.permutation(np.arange(1, 1 + B * M)).reshape(B, M).astype(np.int32)
+    tables[1] = 0
+    pos = np.asarray([5, 40, 33], np.int32)
+    shape = (cfg.n_periods, 1 + B * M, cfg.n_kv_heads, bs, cfg.head_dim)
+    pkv = PagedKVCache(k=jnp.asarray(rng.standard_normal(shape), jnp.float32),
+                       v=jnp.asarray(rng.standard_normal(shape), jnp.float32))
+    toks = jnp.asarray(rng.integers(1, 127, (B, 1)).astype(np.int32))
+    # a fresh lambda a mode: a jit around the same function would reuse the other mode's program
+    logits, (out, _) = jax.jit(lambda *a: paged_forward(a[0], cfg, *a[1:]))(
+        engine.params, toks, jnp.asarray(pos), (pkv, StatePool.create(cfg, B, jnp.float32)), jnp.asarray(tables))
+    assert np.all(np.isfinite(np.asarray(logits)))
+    want = np.zeros((cfg.n_periods, shape[1], bs), bool)
+    for b in (0, 2):
+        want[:, tables[b, pos[b] // bs], pos[b] % bs] = True
+    for got, was in ((out.k, pkv.k), (out.v, pkv.v)):
+        changed = (np.asarray(got) != np.asarray(was)).any(axis=(2, 4))
+        np.testing.assert_array_equal(changed[:, 1:], want[:, 1:])
+
+
+def test_the_compiled_step_holds_no_second_pool(engine):
+    """As ``tests/test_kvblocks.py``'s, for the period scan: K/V pool and state pool donated, the
+    compiled step's temporaries stay under half of ONE K/V pool (2 x 4.2 MB here; the state pool,
+    in place since PR 30, is 0.6 MB)."""
+    from helpers import compile_paged_step
+
+    compiled, pool = compile_paged_step(engine.cfg, engine.params, n_slots=4, n_blocks=2048, block_size=16,
+                                        table_width=4, pool_dtype=jnp.float32)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert pool == engine.cfg.n_periods * 2048 * engine.cfg.n_kv_heads * 16 * engine.cfg.head_dim * 4
+    assert temp < pool // 2, (temp, pool)
+
+
 def _serve(sched, prompt, n=10):
     req = sched.submit(prompt, n, stop_on_eos=False)
     assert req.done.wait(300) and req.error is None, req.error

@@ -52,6 +52,14 @@ def _reference(q, k_pool, v_pool, tables, positions, head_dim):
     return ref(q, k_pool, v_pool, tables, positions)
 
 
+def _kernel_1(q, k_pool, v_pool, tables, positions, hd, **kw):
+    """The kernel over a one-layer pool: it takes the whole pool ``[L, ..]``
+    and a layer index, these cases hold one layer's ``[n_blocks, ..]``."""
+    kw.setdefault("interpret", True)
+    return paged_ragged_attention(q, k_pool[None], v_pool[None], 0, tables,
+                                  positions, hd, **kw)
+
+
 def _mk(rng, B, T, n_heads, n_kv, hd, bs, M, nb, dtype=jnp.float32):
     k_pool = jnp.asarray(rng.standard_normal((nb, n_kv, bs, hd)), dtype)
     v_pool = jnp.asarray(rng.standard_normal((nb, n_kv, bs, hd)), dtype)
@@ -67,8 +75,7 @@ def _assert_close(q, k_pool, v_pool, tables, positions, hd):
     every dead row. Whether the COMPILED kernel holds its 2e-5 is the
     chip's to say (test_paged_kernel_compiled_parity_on_hw,
     tools/paged_attn_sweep.py)."""
-    got = np.asarray(paged_ragged_attention(q, k_pool, v_pool, tables,
-                                            positions, hd, interpret=True))
+    got = np.asarray(_kernel_1(q, k_pool, v_pool, tables, positions, hd))
     want = np.asarray(_reference(q, k_pool, v_pool, tables, positions, hd))
     live = np.asarray(tables)[:, 0] != 0
     np.testing.assert_allclose(got[live], want[live], rtol=TOL, atol=TOL)
@@ -202,10 +209,10 @@ def test_walk_bounded_by_each_rows_length(case):
     dead = np.asarray(lengths) == 0
     if dead.any():
         other = np.where(dead, (pos0 + 77) % (M * _BS), pos0).astype(np.int32)
-        again = paged_ragged_attention(
+        again = _kernel_1(
             q, kp, vp, jnp.asarray(tables),
             jnp.asarray(other[:, None] + np.arange(T)[None, :], jnp.int32),
-            hd, interpret=True)
+            hd)
         np.testing.assert_array_equal(np.asarray(again), got)
 
 
@@ -220,13 +227,38 @@ def test_a_row_never_reads_past_its_bound():
     q, kp, vp = _mk(rng, B, T, n_heads, n_kv, hd, _BS, M, nb)
     tables = rng.permutation(np.arange(1, nb)).reshape(B, M).astype(np.int32)
     positions = jnp.asarray([[40], [150]], jnp.int32)
-    clean = paged_ragged_attention(q, kp, vp, jnp.asarray(tables), positions,
-                                   hd, interpret=True)
+    clean = _kernel_1(q, kp, vp, jnp.asarray(tables), positions, hd)
     past = np.concatenate([tables[0, 3:], tables[1, 10:]])
-    poisoned = paged_ragged_attention(
+    poisoned = _kernel_1(
         q, kp.at[past].set(jnp.nan), vp.at[past].set(jnp.nan),
-        jnp.asarray(tables), positions, hd, interpret=True)
+        jnp.asarray(tables), positions, hd)
     np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(clean))
+
+
+@pytest.mark.parametrize("n_layers,layer", [(3, 0), (3, 1), (4, 3)])
+def test_the_kernel_reads_its_layer_and_no_other(n_layers, layer):
+    """The whole pool ``[L, n_blocks, ..]`` and a layer index: every OTHER
+    layer holds NaN, so one byte fetched from a neighbour shows; the output
+    is the one-layer pool's, bit for bit (the same bytes in the same
+    order), under jit with the index traced."""
+    rng = np.random.default_rng(90 + layer)
+    B, T, n_heads, n_kv, hd, M = 3, 1, 8, 2, 16, _M
+    nb = B * M + 1
+    q, kp, vp = _mk(rng, B, T, n_heads, n_kv, hd, _BS, M, nb)
+    tables = rng.permutation(np.arange(1, nb)).reshape(B, M).astype(np.int32)
+    tables[1] = 0                                   # a dead row rides along
+    tables = jnp.asarray(tables)
+    positions = jnp.asarray([[40], [9], [150]], jnp.int32)
+    alone = _assert_close(q, kp, vp, tables, positions, hd)
+
+    def whole(pool):
+        return jnp.full((n_layers,) + pool.shape, jnp.nan,
+                        pool.dtype).at[layer].set(pool)
+
+    got = jax.jit(lambda l: paged_ragged_attention(
+        q, whole(kp), whole(vp), l, tables, positions, hd,
+        interpret=True))(jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got), alone)
 
 
 def test_verify_width_with_write_lens_through_the_seam(monkeypatch):
@@ -234,7 +266,8 @@ def test_verify_width_with_write_lens_through_the_seam(monkeypatch):
     lanes a row, ``write_lens`` redirecting the lanes past a row's draft to
     the null block. The kernel's bound is ``pos0 + T`` whatever the draft's
     length, its mask per query row; the pools are written before either
-    path attends, so they stay bit-equal."""
+    path attends, so they stay bit-equal. The pool is whole (three layers,
+    the middle one written and read): the others come back untouched."""
     from dllama_tpu.models.llama import _attend_paged
 
     cfg = _tiny_cfg()
@@ -244,8 +277,8 @@ def test_verify_width_with_write_lens_through_the_seam(monkeypatch):
     q = jnp.asarray(rng.standard_normal((B, T, cfg.n_heads, hd)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((B, T, n_kv, hd)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, T, n_kv, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((nb, n_kv, 16, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((nb, n_kv, 16, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((3, nb, n_kv, 16, hd)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((3, nb, n_kv, 16, hd)), jnp.float32)
     tables = rng.permutation(np.arange(1, nb)).reshape(B, M).astype(np.int32)
     pos0 = np.asarray([5, 30, 70], np.int32)
     write_lens = jnp.asarray([15, 0, 6], jnp.int32)
@@ -255,7 +288,8 @@ def test_verify_width_with_write_lens_through_the_seam(monkeypatch):
 
     def run():
         return jax.jit(lambda *a: _attend_paged(cfg, *a))(
-            q, k, v, kp, vp, positions, jnp.asarray(tables), write_lens)
+            q, k, v, kp, vp, jnp.int32(1), positions, jnp.asarray(tables),
+            write_lens)
 
     monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "xla")
     ax, kx, vx = run()
@@ -265,6 +299,10 @@ def test_verify_width_with_write_lens_through_the_seam(monkeypatch):
                                rtol=TOL, atol=TOL)
     np.testing.assert_array_equal(np.asarray(kpp), np.asarray(kx))
     np.testing.assert_array_equal(np.asarray(vpp), np.asarray(vx))
+    for got, was in ((kpp, kp), (vpp, vp)):
+        np.testing.assert_array_equal(np.asarray(got)[[0, 2]],
+                                      np.asarray(was)[[0, 2]])
+        assert not np.array_equal(np.asarray(got)[1], np.asarray(was)[1])
 
 
 def test_supports_predicate():
@@ -412,8 +450,8 @@ def test_paged_kernel_compiled_parity_on_hw():
     # is where Mosaic compiled vs XLA is accumulation-order noise only
     # (tools/paged_attn_sweep.py reads both, at the benchmark's geometries)
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(paged_ragged_attention(q, kp, vp, jnp.asarray(tables),
-                                                positions, hd))
+        got = np.asarray(_kernel_1(q, kp, vp, jnp.asarray(tables), positions,
+                                   hd, interpret=False))
         want = np.asarray(_reference(q, kp, vp, jnp.asarray(tables),
                                      positions, hd))
     live = tables[:, 0] != 0

@@ -482,7 +482,10 @@ def test_paged_lifecycle_emits_spans_and_debug_requests_timeline(
     sched = BatchScheduler(eng, n_slots=2)
     t0 = tm.now_ns()
     try:
-        prompts = ["hello world hello", "hello", " world hello world"]
+        # no prompt a prefix of another: admitted after the first one's
+        # blocks are registered, "hello" shares them whole and runs no
+        # prefill chunk (a race this test lost one run in two)
+        prompts = ["hello world hello", "world", " again hello world"]
         reqs = [sched.submit(eng.tokenizer.encode(p, is_start=True), 4,
                              stop_on_eos=False) for p in prompts]
         for r in reqs:

@@ -222,6 +222,40 @@ def test_ragged_serving_programs_on_hw(tpu_backend):
     assert (n_acc[sampled_rows] == 0).all()  # sampled rows accept nothing
 
 
+@pytest.mark.parametrize("arch", ["dense", "hybrid"])
+def test_the_compiled_paged_step_holds_no_second_pool_on_hw(tpu_backend, arch):
+    """The twin of ``tests/test_kvblocks.py`` / ``tests/test_olmo_hybrid.py``'s
+    structural test on the REAL lowering, where the Mosaic kernels stand in
+    the layer scan as custom calls (a slice in front of one is materialized,
+    and XLA may copy a carry whose layout a custom call does not accept):
+    bf16 over Q40 planes, heads of 128 lanes so ``paged_ragged_attention``
+    is compiled in, the cache donated. The step's temporaries stay under
+    half of ONE pool (k: 67 MB dense, 17 MB hybrid); as the scan's stacked
+    output the pool was a temporary of both pools."""
+    import jax.numpy as jnp
+
+    from dllama_tpu.formats.mfile import ArchType, RopeType
+    from dllama_tpu.models import ModelConfig
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+    from helpers import compile_paged_step, param_shapes
+
+    hybrid = dict(arch=ArchType.OLMO_HYBRID, layer_period=4, lin_heads=2,
+                  lin_key_dim=64, lin_value_dim=128, lin_conv_kernel=4)
+    cfg = ModelConfig(
+        **(hybrid if arch == "hybrid" else dict(arch=ArchType.LLAMA)),
+        dim=256, hidden_dim=512, n_layers=8, n_heads=2, n_kv_heads=2,
+        head_dim=128, vocab_size=2048, seq_len=4096, norm_epsilon=1e-5,
+        rope_theta=10000.0, rope_type=RopeType.LLAMA,
+        compute_dtype="bfloat16")
+    compiled, pool = compile_paged_step(
+        cfg, param_shapes(cfg, jnp.bfloat16), n_slots=4, n_blocks=1 + 4 * 256,
+        block_size=16, table_width=256, pool_dtype=jnp.bfloat16)
+    kernels = mosaic_kernels(compiled.as_text())
+    assert kernels.get("paged_ragged_attention") == 1, kernels
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool // 2, (temp, pool)
+
+
 def test_spec_transcript_identity_on_hw(tpu_backend):
     """--spec-lookup vs plain greedy transcript identity ON HARDWARE
     (ADVICE r3 #1): the claim 'exact by construction' rides on logits being

@@ -9,7 +9,11 @@ and GB/s of LIVE K/V bytes against a v5e's 819 GB/s, beside the jitted
 gather + XLA oracle (the fallback path) and any other version of the
 kernel file named with ``--against`` (another checkout's
 ``dllama_tpu/ops/paged_attention.py``, or a variant of it: PR 31 timed its
-parent's kernel and that kernel's epilogue alone this way).
+parent's kernel and that kernel's epilogue alone this way). The kernel
+takes the whole pool ``[L, n_blocks, ..]`` and a layer index: the pools
+here hold ``LAYERS`` layers and every variant reads layer ``LAYER``; a copy
+of the file from before PR 33 (no ``layer`` parameter) is handed that
+layer's slice, cut once outside the timed loop.
 
 Then parity, at ragged lengths with a dead row in the batch: the kernel
 against the jitted gather + oracle under ``highest`` (``rtol = atol =
@@ -39,8 +43,8 @@ interpret mode and prints ``not measured`` where a time would stand.
 from __future__ import annotations
 
 import argparse
-import functools
 import importlib.util
+import inspect
 import json
 import os
 import sys
@@ -58,6 +62,7 @@ GEOMETRIES = {
 }
 TOY = {"toy": (4, 8, 2, 16, 12)}
 BLOCK = 16
+LAYERS, LAYER = 2, 1  # the pools' leading axis, and the layer every variant reads
 HBM_GBPS = 819.0  # one v5e (benchmark/peaks.py)
 
 
@@ -97,47 +102,51 @@ def main() -> int:
     dev = jax.devices()[0]
     print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}")
 
-    def kernel(q, kp, vp, tables, positions, hd):
-        return pa.paged_ragged_attention(q, kp, vp, tables, positions, hd,
-                                         interpret=not on_chip)
+    # every variant: op(q, pools, tables, positions, hd), where ``pools`` is
+    # (k, v) whole ``[LAYERS, ..]`` and then layer LAYER's slices of them
+    def entry(f):
+        if "layer" in inspect.signature(f).parameters:
+            return lambda q, pools, t, p, hd: f(
+                q, pools[0], pools[1], LAYER, t, p, hd, interpret=not on_chip)
+        return lambda q, pools, t, p, hd: f(
+            q, pools[2], pools[3], t, p, hd, interpret=not on_chip)
 
-    variants = {"kernel": kernel}
+    variants = {"kernel": entry(pa.paged_ragged_attention)}
     for spec in args.against:
         name, path = spec.split("=", 1)
-        variants[name] = functools.partial(
-            lambda f, q, kp, vp, t, p, hd: f(q, kp, vp, t, p, hd,
-                                             interpret=not on_chip),
-            load_kernel(path))
+        variants[name] = entry(load_kernel(path))
     for gt in filter(None, args.group_tokens.split(",")):
-        def at_group(q, kp, vp, t, p, hd, gt=int(gt)):
+        def at_group(q, pools, t, p, hd, gt=int(gt)):
             was = pa._GROUP_TOKENS
             pa._GROUP_TOKENS = gt
             try:  # jit's cache is keyed on the wrapper: trace a fresh one
                 return jax.jit(pa.paged_ragged_attention.__wrapped__,
                                static_argnames=("head_dim", "interpret"))(
-                    q, kp, vp, t, p, hd, interpret=not on_chip)
+                    q, pools[0], pools[1], LAYER, t, p, hd,
+                    interpret=not on_chip)
             finally:
                 pa._GROUP_TOKENS = was
         variants[f"kernel@{gt}"] = at_group
 
-    def oracle(q, kp, vp, tables, positions, hd):
+    def oracle(q, pools, tables, positions, hd):
         B, M = tables.shape
-        n_kv, bs = kp.shape[1], kp.shape[2]
+        n_kv, bs = pools[0].shape[2], pools[0].shape[3]
 
         def view(pool):
-            return jnp.moveaxis(pool[tables], 2, 1).reshape(B, n_kv, M * bs, hd)
+            return jnp.moveaxis(pool[LAYER, tables], 2, 1).reshape(
+                B, n_kv, M * bs, hd)
 
-        return attention(q, view(kp), view(vp), positions, hd)
+        return attention(q, view(pools[0]), view(pools[1]), positions, hd)
 
     def looped(op, hd):
         @jax.jit
-        def run(n, q, kp, vp, tables, positions):
+        def run(n, q, pools, tables, positions):
             def body(_, q):
                 # tie the table to the carry, or XLA hoists the oracle's
                 # gather (the bytes being measured) out of the loop
                 q, tbl, pos = jax.lax.optimization_barrier(
                     (q, tables, positions))
-                return q + 1e-3 * op(q, kp, vp, tbl, pos, hd)
+                return q + 1e-3 * op(q, pools, tbl, pos, hd)
 
             return jax.lax.fori_loop(0, n, body, q)
 
@@ -161,8 +170,9 @@ def main() -> int:
         nb = B * M + 1
         key = jax.random.fold_in(jax.random.PRNGKey(31), nb + n_kv)
         kk, kv_, kq = jax.random.split(key, 3)
-        kp = jax.random.normal(kk, (nb, n_kv, BLOCK, hd), pool_dtype)
-        vp = jax.random.normal(kv_, (nb, n_kv, BLOCK, hd), pool_dtype)
+        kp = jax.random.normal(kk, (LAYERS, nb, n_kv, BLOCK, hd), pool_dtype)
+        vp = jax.random.normal(kv_, (LAYERS, nb, n_kv, BLOCK, hd), pool_dtype)
+        pools = (kp, vp, kp[LAYER], vp[LAYER])
         q = jax.random.normal(kq, (B, 1, n_heads, hd), jnp.float32)
         real = rng.permutation(np.arange(1, nb)).reshape(B, M).astype(np.int32)
         block_bytes = 2 * n_kv * BLOCK * hd * kp.dtype.itemsize  # K and V
@@ -190,7 +200,7 @@ def main() -> int:
                         line["us"][name] = None
                         continue
                     line["us"][name] = slope_us(
-                        run, q, kp, vp, jnp.asarray(tables), jnp.asarray(pos))
+                        run, q, pools, jnp.asarray(tables), jnp.asarray(pos))
                 cells = "  ".join(
                     f"{name} " + ("not measured" if us is None else
                                   f"{us:8.1f} us {live_bytes / us / 1e3:6.1f} GB/s")
@@ -210,7 +220,7 @@ def main() -> int:
             tables[b, -(-int(lengths[b]) // BLOCK):] = 0
         pos = (lengths - 1).astype(np.int32)[:, None]
         live = np.arange(B) != dead
-        operands = (q, kp, vp, jnp.asarray(tables), jnp.asarray(pos))
+        operands = (q, pools, jnp.asarray(tables), jnp.asarray(pos))
         truth = None  # the oracle under ``highest``
         for precision in ("highest", "default"):
             with jax.default_matmul_precision(precision):
