@@ -33,6 +33,7 @@ ARCH_BY_MODEL_TYPE = {
     "qwen3": ArchType.QWEN3,
     "qwen3_moe": ArchType.QWEN3,
     "olmo_hybrid": ArchType.OLMO_HYBRID,
+    "laguna": ArchType.LAGUNA,
 }
 
 HIDDEN_ACT_BY_NAME = {"gelu": HiddenAct.GELU, "silu": HiddenAct.SILU}
@@ -108,7 +109,10 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
     params: dict = {
         "version": 0,
         "arch_type": int(ARCH_BY_MODEL_TYPE[model_type]),
-        "hidden_act": int(HIDDEN_ACT_BY_NAME[cfg["hidden_act"]]),
+        # the laguna config names no activation: its experts are SwiGLU
+        "hidden_act": int(HIDDEN_ACT_BY_NAME[cfg.get("hidden_act", "silu")
+                                             if model_type == "laguna"
+                                             else cfg["hidden_act"]]),
         "dim": cfg["hidden_size"],
         "hidden_dim": cfg["intermediate_size"],
         "n_layers": cfg["num_hidden_layers"],
@@ -149,6 +153,8 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
 
     if model_type == "olmo_hybrid":
         params.update(_olmo_hybrid_header(cfg))
+    if model_type == "laguna":
+        params.update(_laguna_header(cfg))
 
     if cfg.get("rope_theta") is not None:
         params["rope_theta"] = int(cfg["rope_theta"])
@@ -212,6 +218,79 @@ def _olmo_hybrid_header(cfg: dict) -> dict:
     return out
 
 
+def _laguna_header(cfg: dict) -> dict:
+    """``model_type: laguna``'s config keys as the header's extension keys
+    (formats/mfile.py, HeaderKey 22, 29-38, and the rope-scaling keys 14-17
+    for the full layers' YaRN table). ``layer_types`` must be whole periods
+    of one full layer and then sliding ones, ``num_attention_heads_per_layer``
+    one number a kind, ``mlp_only_layers`` the leading layers. A whole
+    checkpoint holds every expert: the router's width is ``num_experts`` and
+    the first held expert 0 (a share is written by whoever cuts one). What
+    the published config does not say (pre-norm, no q/k norm, a softmax
+    router, an ungated shared expert, the per-head gate's form, a window
+    that counts the current token) the arch implies (models/laguna.py)."""
+    kinds = list(cfg["layer_types"])
+    heads = list(cfg["num_attention_heads_per_layer"])
+    period = (kinds.index("full_attention", 1)
+              if "full_attention" in kinds[1:] else len(kinds))
+    want = (["full_attention"] + ["sliding_attention"] * (period - 1)) \
+        * (len(kinds) // period)
+    slide = {h for h, k in zip(heads, kinds) if k == "sliding_attention"}
+    full = {h for h, k in zip(heads, kinds) if k == "full_attention"}
+    if (kinds != want or len(kinds) != cfg["num_hidden_layers"]
+            or len(heads) != len(kinds) or len(slide) != 1
+            or full != {cfg["num_attention_heads"]}):
+        raise ValueError(
+            "laguna: layer_types is not whole periods of a full layer and "
+            "then sliding ones, or num_attention_heads_per_layer is not one "
+            "number a layer kind")
+    dense = list(cfg.get("mlp_only_layers") or [])
+    if dense != list(range(len(dense))) or len(dense) > 1 \
+            or int(cfg.get("decoder_sparse_step") or 1) != 1:
+        raise ValueError(
+            f"laguna: mlp_only_layers {dense} must be the leading layer (or "
+            f"none) and decoder_sparse_step 1")
+    if cfg.get("gating") != "per-head" or cfg.get("attention_bias") \
+            or cfg.get("moe_apply_router_weight_on_input") \
+            or cfg.get("moe_router_logit_softcapping"):
+        raise ValueError(
+            "laguna: a gate that is not per head, attention bias, the "
+            "router's weight on the input or a router soft cap are not "
+            "carried")
+    rope = cfg["rope_parameters"]
+    rf, rs = rope["full_attention"], rope["sliding_attention"]
+    if rf.get("rope_type") != "yarn" or rs.get("rope_type") != "default" \
+            or rs.get("partial_rotary_factor", 1) != 1:
+        raise ValueError("laguna: rope_parameters must be yarn on the full "
+                         "layers and default over the whole head on the "
+                         "sliding ones")
+    return {
+        "hidden_dim": int(cfg["moe_intermediate_size"]),
+        "moe_norm_topk": int(bool(cfg.get("norm_topk_prob", False))),
+        "rope_theta": int(rf["rope_theta"]),
+        "rope_type": int(RopeType.YARN),
+        "rope_scaling_factor": int(rf["factor"]),
+        "rope_scaling_low_freq_factor": int(rf["beta_slow"]),
+        "rope_scaling_high_freq_factory": int(rf["beta_fast"]),
+        "rope_scaling_orig_max_seq_len": int(
+            rf["original_max_position_embeddings"]),
+        "layer_period": period,
+        "sliding_window": int(cfg["sliding_window"]),
+        "n_heads_sliding": slide.pop(),
+        "rope_theta_sliding": int(rs["rope_theta"]),
+        "rope_dim": int(round(cfg["head_dim"]
+                              * rf.get("partial_rotary_factor", 1))),
+        "n_dense_layers": len(dense),
+        "dense_hidden_dim": int(cfg["intermediate_size"]),
+        "shared_expert_dim": int(
+            cfg.get("shared_expert_intermediate_size") or 0),
+        "moe_routed_scale_milli": int(round(
+            float(cfg.get("moe_routed_scaling_factor", 1.0)) * 1000)),
+        "moe_router_width": int(cfg["num_experts"]),
+        "moe_first_expert": 0,
+    }
+
+
 # ---------------------------------------------------------------------------
 # tensor plan
 # ---------------------------------------------------------------------------
@@ -240,6 +319,12 @@ def hf_tensor_plan(params: dict) -> list[PlanItem]:
             "checkpoint's tensor names are not: they could not be read where "
             "this was written, and a guessed map is worse than none. The "
             "target layout is formats/mfile.py's _walk_hybrid_layer")
+    if arch == ArchType.LAGUNA:
+        raise NotImplementedError(
+            "laguna: the header is mapped (load_hf_config), the checkpoint's "
+            "tensor names are not: they could not be read where this was "
+            "written, and a guessed map is worse than none. The target "
+            "layout is formats/mfile.py's _walk_laguna_layer")
     n_heads = params["n_heads"]
     n_kv_heads = params["n_kv_heads"]
 

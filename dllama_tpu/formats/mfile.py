@@ -99,6 +99,22 @@ class HeaderKey(enum.IntEnum):
     LINEAR_VALUE_HEAD_DIM = 26
     LINEAR_CONV_KERNEL = 27
     LINEAR_NEG_EIGVAL = 28
+    # OUR format extension, read by ArchType.LAGUNA only (models/laguna.py).
+    # LAYER_PERIOD is shared: there P = one full-attention layer, then P-1
+    # sliding-window ones. N_HEADS is the full layers' query heads; the
+    # ROPE_* keys 12-17 describe the FULL layers' YaRN table (RopeType.YARN:
+    # factor, beta_slow and beta_fast in the low / high frequency factor
+    # keys, the original context).
+    SLIDING_WINDOW = 29          # keys a sliding layer's query sees, itself included
+    N_HEADS_SLIDING = 30         # query heads of a sliding layer
+    ROPE_THETA_SLIDING = 31      # the sliding layers' plain rotary base
+    ROPE_DIM = 32                # lanes of a FULL layer's head that rotate (partial rotary)
+    N_DENSE_LAYERS = 33          # leading layers whose feed-forward is dense
+    DENSE_HIDDEN_DIM = 34        # their width (HIDDEN_DIM is an expert's)
+    SHARED_EXPERT_DIM = 35       # the shared expert's width (0: none)
+    MOE_ROUTED_SCALE_MILLI = 36  # routed sum's scale, in thousandths
+    MOE_ROUTER_WIDTH = 37        # experts the router scores (N_EXPERTS are HELD here)
+    MOE_FIRST_EXPERT = 38        # the first held expert's index among them
 
 
 class ArchType(enum.IntEnum):
@@ -109,6 +125,11 @@ class ArchType(enum.IntEnum):
     # ours: a hybrid decoder, gated delta-rule (linear-attention) layers and
     # full softmax-attention layers in a periodic pattern (models/hybrid.py)
     OLMO_HYBRID = 0xABCD02
+    # ours: window and full attention layers in a periodic pattern with a
+    # per-head output gate, a leading dense layer, then routed experts and a
+    # shared one; the experts, heads and vocabulary HELD may be one chip's
+    # share of a deployment (models/laguna.py)
+    LAGUNA = 0xABCD03
 
 
 class RopeType(enum.IntEnum):
@@ -117,6 +138,10 @@ class RopeType(enum.IntEnum):
     LLAMA = 0
     FALCON = 1
     LLAMA3_1 = 2
+    # ours (ArchType.LAGUNA's full layers): half-split pairing over the first
+    # ROPE_DIM lanes, YaRN's banded frequency interpolation, cos and sin
+    # scaled by 0.1 ln(factor) + 1 (models/rope.py)
+    YARN = 3
 
 
 class HiddenAct(enum.IntEnum):
@@ -162,6 +187,17 @@ class ModelHeader:
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 0
     linear_neg_eigval: int = 0
+    # LAGUNA (HeaderKey 29-38); 0 for every other arch
+    sliding_window: int = 0
+    n_heads_sliding: int = 0
+    rope_theta_sliding: int = 0
+    rope_dim: int = 0
+    n_dense_layers: int = 0
+    dense_hidden_dim: int = 0
+    shared_expert_dim: int = 0
+    moe_routed_scale_milli: int = 1000
+    moe_router_width: int = 0
+    moe_first_expert: int = 0
 
     @property
     def linear_conv_dim(self) -> int:
@@ -206,7 +242,12 @@ _HYBRID_KEYS = {k: k.name.lower() for k in (
     HeaderKey.LAYER_PERIOD, HeaderKey.LINEAR_N_KEY_HEADS,
     HeaderKey.LINEAR_N_VALUE_HEADS, HeaderKey.LINEAR_KEY_HEAD_DIM,
     HeaderKey.LINEAR_VALUE_HEAD_DIM, HeaderKey.LINEAR_CONV_KERNEL,
-    HeaderKey.LINEAR_NEG_EIGVAL)}
+    HeaderKey.LINEAR_NEG_EIGVAL,
+    HeaderKey.SLIDING_WINDOW, HeaderKey.N_HEADS_SLIDING,
+    HeaderKey.ROPE_THETA_SLIDING, HeaderKey.ROPE_DIM,
+    HeaderKey.N_DENSE_LAYERS, HeaderKey.DENSE_HIDDEN_DIM,
+    HeaderKey.SHARED_EXPERT_DIM, HeaderKey.MOE_ROUTED_SCALE_MILLI,
+    HeaderKey.MOE_ROUTER_WIDTH, HeaderKey.MOE_FIRST_EXPERT)}
 
 
 def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
@@ -290,6 +331,31 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
                 f"divide {h.n_layers} layers into whole periods")
         if h.n_experts:
             raise ValueError("hybrid model: routed experts are unsupported")
+    if h.arch_type == ArchType.LAGUNA:
+        h.rope_type = RopeType.YARN
+        h.moe_router_width = h.moe_router_width or h.n_experts
+        if h.layer_period < 2 or h.n_layers % h.layer_period:
+            raise ValueError(
+                f"laguna model: layer period {h.layer_period} does not "
+                f"divide {h.n_layers} layers into whole periods")
+        if not (0 < h.sliding_window and h.n_heads_sliding
+                and h.n_heads_sliding % h.n_kv_heads == 0):
+            raise ValueError(
+                f"laguna model: window {h.sliding_window}, "
+                f"{h.n_heads_sliding} sliding heads over {h.n_kv_heads} "
+                f"K/V heads")
+        if not (0 < h.n_active_experts <= h.moe_router_width
+                and 0 < h.n_experts
+                and h.moe_first_expert + h.n_experts <= h.moe_router_width):
+            raise ValueError(
+                f"laguna model: experts [{h.moe_first_expert}, "
+                f"{h.moe_first_expert + h.n_experts}) held of a router over "
+                f"{h.moe_router_width}, {h.n_active_experts} a token")
+        if h.n_dense_layers > 1 or (h.n_dense_layers
+                                    and not h.dense_hidden_dim):
+            raise ValueError(
+                f"laguna model: {h.n_dense_layers} leading dense layers "
+                f"(this walk carries at most one, with its width)")
     return h
 
 
@@ -410,6 +476,9 @@ class ModelFile:
             if h.arch_type == ArchType.OLMO_HYBRID:
                 off = self._walk_hybrid_layer(l, off)
                 continue
+            if h.arch_type == ArchType.LAGUNA:
+                off = self._walk_laguna_layer(l, off)
+                continue
             off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
             off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
             off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
@@ -478,6 +547,46 @@ class ModelFile:
         off += self._add("block_matmul_w1", l, (h.hidden_dim, h.dim), wt, off)
         off += self._add("block_matmul_w2", l, (h.dim, h.hidden_dim), wt, off)
         off += self._add("block_matmul_w3", l, (h.hidden_dim, h.dim), wt, off)
+        off += self._add("block_norm_0", l, (h.dim,), F32, off)
+        off += self._add("block_norm_1", l, (h.dim,), F32, off)
+        return off
+
+    def _walk_laguna_layer(self, l: int, off: int) -> int:
+        """One layer of a LAGUNA file (OUR layout; the reference has none):
+        q k v wo at the layer kind's head count (the first of each period is
+        a full layer), the per-head gate's rows (F32), then a leading dense
+        layer's w1 w2 w3 at ``dense_hidden_dim``, or the router's rows over
+        ``moe_router_width`` (F32), the HELD experts (w3 w1 w2 each, as the
+        other MoE files order them) and the shared expert's w1 w2 w3; the
+        two block norms."""
+        h, wt = self.header, self.header.weight_type
+        heads = h.n_heads if l % h.layer_period == 0 else h.n_heads_sliding
+        q_dim = heads * h.head_dim
+        off += self._add("block_matmul_q", l, (q_dim, h.dim), wt, off)
+        off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
+        off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
+        off += self._add("block_matmul_wo", l, (h.dim, q_dim), wt, off)
+        off += self._add("block_attn_gate", l, (heads, h.dim), F32, off)
+        if l < h.n_dense_layers:
+            wide = h.dense_hidden_dim
+            off += self._add("block_matmul_w1", l, (wide, h.dim), wt, off)
+            off += self._add("block_matmul_w2", l, (h.dim, wide), wt, off)
+            off += self._add("block_matmul_w3", l, (wide, h.dim), wt, off)
+        else:
+            off += self._add("block_moe_gate", l,
+                             (h.moe_router_width, h.dim), F32, off)
+            for e in range(h.n_experts):
+                off += self._add("block_expert_w3", l, (h.hidden_dim, h.dim),
+                                 wt, off, expert=e)
+                off += self._add("block_expert_w1", l, (h.hidden_dim, h.dim),
+                                 wt, off, expert=e)
+                off += self._add("block_expert_w2", l, (h.dim, h.hidden_dim),
+                                 wt, off, expert=e)
+            if h.shared_expert_dim:
+                wide = h.shared_expert_dim
+                off += self._add("block_shared_w1", l, (wide, h.dim), wt, off)
+                off += self._add("block_shared_w2", l, (h.dim, wide), wt, off)
+                off += self._add("block_shared_w3", l, (wide, h.dim), wt, off)
         off += self._add("block_norm_0", l, (h.dim,), F32, off)
         off += self._add("block_norm_1", l, (h.dim,), F32, off)
         return off

@@ -52,6 +52,29 @@ class ModelConfig:
     lin_value_dim: int = 0
     lin_conv_kernel: int = 0
     lin_neg_eigval: bool = False
+    # Window and full attention layers in a periodic pattern, routed
+    # experts of which a SHARE may be held (ArchType.LAGUNA,
+    # models/laguna.py). ``layer_period`` P there = one full layer, then
+    # P - 1 sliding ones. ``n_heads`` counts a full layer's query heads,
+    # ``n_heads_sliding`` a sliding layer's; ``rope_theta`` and the
+    # ``rope_scaling_*`` fields are the full layers' YaRN table over
+    # ``rope_dim`` lanes, ``rope_theta_sliding`` the sliding layers' plain
+    # one over the whole head. ``n_experts`` are the experts HELD here,
+    # ``[moe_first_expert, moe_first_expert + n_experts)`` of the
+    # ``moe_router_width`` the router scores; ``hidden_dim`` is an expert's
+    # width, ``dense_hidden_dim`` the leading dense layers'. The arch
+    # implies: pre-norm, no q/k norm, a softmax router, an ungated shared
+    # expert, a sigmoid gate per head on the attention output before ``wo``.
+    sliding_window: int = 0
+    n_heads_sliding: int = 0
+    rope_theta_sliding: float = 0.0
+    rope_dim: int = 0
+    n_dense_layers: int = 0
+    dense_hidden_dim: int = 0
+    shared_expert_dim: int = 0
+    moe_routed_scale: float = 1.0
+    moe_router_width: int = 0
+    moe_first_expert: int = 0
 
     # TPU execution choices (no reference equivalent):
     compute_dtype: str = "float32"  # "float32" for parity, "bfloat16" for speed
@@ -107,23 +130,59 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def has_window_layers(self) -> bool:
+        """Sliding-window attention layers beside the full ones: a slot's
+        context is blocks of TWO pools (runtime/kvblocks), the window
+        layers' returned as the window passes them."""
+        return self.sliding_window > 0
+
+    @property
+    def paged_only(self) -> bool:
+        """Served through the paged generator alone: a slot's context is
+        more than one list of K/V blocks (a recurrent state, a second pool),
+        which the dense slot pool and the single-sequence path do not
+        carry."""
+        return self.is_hybrid or self.has_window_layers
+
+    @property
+    def prefix_reuse_skipped(self) -> str | None:
+        """Why a matched prefix block is passed over (the label of
+        ``dllama_prefix_reuse_skipped_total``), or None where blocks are
+        shared."""
+        if self.is_hybrid:
+            return "recurrent_state"
+        return "window_layers" if self.has_window_layers else None
+
+    @property
+    def n_window_layers(self) -> int:
+        return (self.n_layers - self.n_layers // self.layer_period
+                if self.has_window_layers else 0)
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.is_moe else 0
+
+    @property
     def is_hybrid(self) -> bool:
         """Recurrent (linear-attention) layers beside the full ones: a
         slot's context is K/V blocks AND a state row (runtime/kvblocks)."""
-        return self.layer_period > 0
+        return self.lin_heads > 0
 
     @property
     def n_periods(self) -> int:
-        return self.n_layers // self.layer_period if self.is_hybrid else 0
+        return self.n_layers // self.layer_period if self.layer_period else 0
 
     @property
     def n_linear_layers(self) -> int:
-        return self.n_periods * (self.layer_period - 1)
+        return (self.n_periods * (self.layer_period - 1)
+                if self.is_hybrid else 0)
 
     @property
     def n_kv_layers(self) -> int:
-        """Layers that hold a K/V cache: every one, or a hybrid's full ones."""
-        return self.n_periods if self.is_hybrid else self.n_layers
+        """Layers whose K/V lives in THE block pool: every one, a hybrid's
+        full ones, or the full ones beside window layers (those have a pool
+        of their own, ``n_window_layers`` deep)."""
+        return self.n_periods if self.layer_period else self.n_layers
 
     @property
     def lin_conv_dim(self) -> int:
@@ -152,6 +211,19 @@ class ModelConfig:
                 lin_value_dim=h.linear_value_head_dim,
                 lin_conv_kernel=h.linear_conv_kernel,
                 lin_neg_eigval=bool(h.linear_neg_eigval))
+        if h.arch_type == ArchType.LAGUNA:
+            hybrid = dict(
+                layer_period=h.layer_period,
+                sliding_window=h.sliding_window,
+                n_heads_sliding=h.n_heads_sliding,
+                rope_theta_sliding=float(h.rope_theta_sliding),
+                rope_dim=h.rope_dim or h.head_dim,
+                n_dense_layers=h.n_dense_layers,
+                dense_hidden_dim=h.dense_hidden_dim,
+                shared_expert_dim=h.shared_expert_dim,
+                moe_routed_scale=h.moe_routed_scale_milli / 1000.0,
+                moe_router_width=h.moe_router_width,
+                moe_first_expert=h.moe_first_expert)
         return cls(
             **hybrid,
             sync_q80=h.sync_type == Q80,

@@ -751,7 +751,7 @@ def _paged_layer_step(cfg: ModelConfig, x: jax.Array, lp: LayerParams,
 def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
                   k_pool: jax.Array, v_pool: jax.Array, l: jax.Array,
                   positions: jax.Array, tables: jax.Array,
-                  write_lens: jax.Array | None = None):
+                  write_lens: jax.Array | None = None, *, window: int = 0):
     """Write the new rows ``k, v [B, T, n_kv, hd]`` into layer ``l`` of the
     whole block pool ``[L, n_blocks, n_kv, bs, hd]`` and attend ``q``
     through the block ``tables``: the ragged paged kernel where its gate
@@ -760,7 +760,10 @@ def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
     slice in front of a custom call is materialized, the lesson of
     :func:`_layer_at`), so a layer scan that carries the pool moves none of
     it. Returns ``(att, k_pool, v_pool)``, the pools whole. Shared with the
-    hybrid decoder's full layers, whose ``l`` is the period."""
+    hybrid decoder's full layers, whose ``l`` is the period, and with
+    models/laguna.py's two pools. ``window`` > 0 is a sliding-window layer:
+    the query sees the newest ``window`` keys, and table entries behind
+    them may be null (their blocks went back to the free list)."""
     from ..ops import paged_attention as _pa
 
     B, T = q.shape[:2]
@@ -790,7 +793,8 @@ def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
         # dense logical cache never materializes in HBM, and a dead row
         # (an all-null table, whatever its stale position) costs nothing
         att = _pa.paged_ragged_attention(q, k_pool, v_pool, l, tables,
-                                         positions, cfg.head_dim, **kernel)
+                                         positions, cfg.head_dim,
+                                         window=window, **kernel)
     else:
         def view(pool):
             gathered = pool[l, tables]           # [B, M, n_kv, bs, hd]
@@ -798,7 +802,7 @@ def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
                 B, cfg.n_kv_heads, n_blocks_seq * bs, cfg.head_dim)
 
         att = attention(q, view(k_pool), view(v_pool), positions,
-                        cfg.head_dim)
+                        cfg.head_dim, window=window)
     att = constrain(att, "batch", None, "heads", None)
     return att, k_pool, v_pool
 
@@ -979,6 +983,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         from . import hybrid
 
         return hybrid.forward(params, cfg, tokens, start_pos, kv, n_valid)
+    if cfg.has_window_layers:
+        from . import laguna
+
+        return laguna.forward(params, cfg, tokens, start_pos, kv, n_valid)
     start_pos = jnp.asarray(start_pos, dtype=jnp.int32)
     ragged = start_pos.ndim > 0
     # numerics observatory taps (runtime/numerics): a TRACE-TIME flag, so
@@ -1295,6 +1303,11 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         from . import hybrid
 
         return hybrid.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
+                                    write_lens)
+    if cfg.has_window_layers:
+        from . import laguna
+
+        return laguna.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
                                     write_lens)
     if _numerics.taps_active():
         raise ValueError("numerics taps are unsupported on the paged KV "

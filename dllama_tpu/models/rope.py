@@ -20,6 +20,8 @@ every head uses identical frequencies, so slicing the cache per node
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 import jax.numpy as jnp
 
@@ -89,3 +91,78 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
         r1 = x0 * s + x1 * c
         return jnp.concatenate([r0, r1], axis=-1).astype(dtype)
     raise ValueError(f"unsupported rope type {rope_type}")
+
+
+def yarn_inv_freq(theta: float, rot_dim: int, factor: float,
+                  orig_max: int, beta_fast: float,
+                  beta_slow: float) -> np.ndarray:
+    """YaRN's banded frequencies over ``rot_dim`` rotating lanes (Peng et
+    al., arXiv:2309.00071, as the published configs parametrise it): pair
+    ``i`` of ``rot_dim / 2`` keeps ``e_i = theta^(-2i/rot_dim)`` where it
+    turns more than ``beta_fast`` times inside the original context, takes
+    ``e_i / factor`` where it turns fewer than ``beta_slow`` times, and a
+    linear ramp between::
+
+        dim(n)  = rot_dim ln(orig_max / (2 pi n)) / (2 ln theta)
+        low     = max(floor(dim(beta_fast)), 0)
+        high    = min(ceil(dim(beta_slow)), rot_dim - 1)
+        ramp_i  = clip((i - low) / (high - low), 0, 1)
+        inv_i   = (e_i / factor) ramp_i + e_i (1 - ramp_i)
+
+    float64 throughout; the caller rounds once."""
+    half = rot_dim // 2
+    i = np.arange(half, dtype=np.float64)
+    e = np.power(theta, -2.0 * i / rot_dim)
+
+    def dim(n: float) -> float:
+        return (rot_dim * math.log(orig_max / (2.0 * math.pi * n))
+                / (2.0 * math.log(theta)))
+
+    low = max(math.floor(dim(beta_fast)), 0)
+    high = min(math.ceil(dim(beta_slow)), rot_dim - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (e / factor) * ramp + e * (1.0 - ramp)
+
+
+def yarn_attention_factor(factor: float) -> float:
+    """What YaRN multiplies cos and sin by: ``0.1 ln(factor) + 1``."""
+    return 0.1 * math.log(factor) + 1.0
+
+
+@functools.lru_cache(maxsize=16)
+def build_partial_rope_cache(seq_len: int, rot_dim: int, theta: float,
+                             yarn: tuple | None = None
+                             ) -> tuple[np.ndarray, np.ndarray]:
+    """cos/sin ``[seq_len, rot_dim // 2]`` float32 for a rotary embedding
+    over the first ``rot_dim`` lanes of a head (half-split pairing,
+    :func:`apply_rope_partial`). ``yarn = (factor, orig_max, beta_fast,
+    beta_slow)`` selects :func:`yarn_inv_freq` and scales both tables by
+    :func:`yarn_attention_factor`; None is the plain ``theta^(-2i/rot_dim)``.
+    numpy, memoized: see :func:`build_rope_cache`."""
+    half = rot_dim // 2
+    if yarn is None:
+        inv = np.power(theta, -2.0 * np.arange(half, dtype=np.float64) / rot_dim)
+        scale = 1.0
+    else:
+        factor, orig_max, beta_fast, beta_slow = yarn
+        inv = yarn_inv_freq(theta, rot_dim, factor, orig_max, beta_fast,
+                            beta_slow)
+        scale = yarn_attention_factor(factor)
+    angles = (np.arange(seq_len, dtype=np.float64)[:, None]
+              * inv[None, :]).astype(np.float32)
+    return ((np.cos(angles) * scale).astype(np.float32),
+            (np.sin(angles) * scale).astype(np.float32))
+
+
+def apply_rope_partial(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
+                       positions: jnp.ndarray) -> jnp.ndarray:
+    """Rotate the first ``2 * cos.shape[-1]`` lanes of every head of ``x
+    [B, T, n_heads, head_dim]`` at ``positions [B, T]``, pairing lane ``j``
+    with lane ``j + r/2`` (half-split); the lanes past ``r`` pass through."""
+    half = cos.shape[-1]
+    c = jnp.asarray(cos)[positions][:, :, None, :]
+    s = jnp.asarray(sin)[positions][:, :, None, :]
+    xf = x.astype(jnp.float32)
+    x0, x1, rest = xf[..., :half], xf[..., half:2 * half], xf[..., 2 * half:]
+    return jnp.concatenate([x0 * c - x1 * s, x0 * s + x1 * c, rest],
+                           axis=-1).astype(x.dtype)

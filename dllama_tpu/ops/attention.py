@@ -18,13 +18,18 @@ import jax.numpy as jnp
 
 
 def attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-              positions: jax.Array, head_dim: int) -> jax.Array:
+              positions: jax.Array, head_dim: int, *, window: int = 0,
+              key_start: jax.Array | int = 0) -> jax.Array:
     """Attend ``q: [B, T, n_heads, head_dim]`` over cached
     ``k/v: [B, n_kv_heads, S, head_dim]`` (head-major, see runtime.kvcache).
 
     ``positions: [B, T]`` is the absolute position of each query row; cache
     entries at ``s <= position`` are visible (the reference's ``t <= pos`` loop
     bound), which assumes the cache holds keys for positions ``0..pos``.
+    ``window`` > 0 is a sliding-window layer: a query at position ``i`` sees
+    keys ``i - window + 1 .. i`` only. ``key_start`` is the position of the
+    cache's first row (a caller that cut the window's span out of a longer
+    cache says where the cut starts).
     """
     B, T, n_heads, _ = q.shape
     n_kv = k_cache.shape[1]
@@ -36,7 +41,12 @@ def attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                         k_cache.astype(jnp.float32))
     scores = scores / jnp.sqrt(jnp.float32(head_dim))
 
-    mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]  # [B, T, S]
+    key_pos = jnp.arange(S)[None, None, :]
+    if not (isinstance(key_start, int) and key_start == 0):
+        key_pos = key_start + key_pos
+    mask = key_pos <= positions[:, :, None]  # [B, T, S]
+    if window:
+        mask &= key_pos > positions[:, :, None] - window
     scores = jnp.where(mask[:, :, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
 

@@ -81,12 +81,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(tbl_ref, pos_ref, nblk_ref, layer_ref, q_ref, k_hbm, v_hbm,
-            out_ref, kbuf, vbuf, sems, m_ref, l_ref, slot_ref, *,
+def _kernel(tbl_ref, pos_ref, nblk_ref, lo_ref, layer_ref, q_ref, k_hbm,
+            v_hbm, out_ref, kbuf, vbuf, sems, m_ref, l_ref, slot_ref, *,
             bs: int, kv_mul: int, hd: int, group: int, heads: int,
-            n_entries: int):
-    """One (row, head group) grid step: walk the row's ``nblk`` table
-    entries in fetch groups of ``group`` blocks.
+            n_entries: int, window: int):
+    """One (row, head group) grid step: walk the row's table entries
+    ``lo .. nblk - 1`` in fetch groups of ``group`` blocks (``lo`` is 0 but
+    in a sliding-window layer, whose walk starts at the row's first live
+    block: the entries before it were returned and read null).
 
     ``kbuf`` / ``vbuf [2, heads, group * bs, D]`` are the double-buffered
     landing zones (pool dtype, head-major so a head's keys are one 2-D
@@ -101,15 +103,18 @@ def _kernel(tbl_ref, pos_ref, nblk_ref, layer_ref, q_ref, k_hbm, v_hbm,
     gt = group * bs
     tq = q_ref.shape[2]
     n = nblk_ref[b]
-    trips = pl.cdiv(n, group)
+    lo = lo_ref[b]
+    trips = pl.cdiv(n - lo, group)
 
     def fetch(row, hgrp, j, slot):
         """The 2 * group copies of fetch group ``j`` of (row, hgrp)."""
         last = nblk_ref[row] - 1
+        first = lo_ref[row]
         copies = []
         for i in range(group):
             # entries past the row's bound re-read its own newest block
-            blk = tbl_ref[row * n_entries + jnp.minimum(j * group + i, last)]
+            blk = tbl_ref[row * n_entries
+                          + jnp.minimum(first + j * group + i, last)]
             for w, (pool, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
                 copies.append(pltpu.make_async_copy(
                     pool.at[layer, blk, pl.ds(hgrp * heads, heads)],
@@ -158,7 +163,7 @@ def _kernel(tbl_ref, pos_ref, nblk_ref, layer_ref, q_ref, k_hbm, v_hbm,
         # column s visible to query row r iff s <= pos0 + r // kv_mul
         # (ragged depths, partial tail blocks, re-read blocks past the
         # bound and null-block garbage all handled by this one rule)
-        reach = pos0 + row_t - col
+        reach = pos0 + row_t - col - lo * bs
 
         def body(j, _):
             slot = (slot0 + j) % 2
@@ -174,6 +179,11 @@ def _kernel(tbl_ref, pos_ref, nblk_ref, layer_ref, q_ref, k_hbm, v_hbm,
             for c in fetch(b, g, j, slot):
                 c.wait()
             visible = reach >= j * gt
+            if window:
+                # a sliding layer: keys more than window - 1 behind the
+                # query are out of sight (the walk's first block holds the
+                # oldest visible key, so the running maximum stays finite)
+                visible = jnp.logical_and(visible, reach - j * gt < window)
             for h in range(heads):
                 scores = jax.lax.dot_general(
                     q_ref[0, h], kbuf[slot, h].astype(jnp.float32),
@@ -281,12 +291,30 @@ def kernel_choice(q_shape: tuple[int, ...], n_kv: int, n_blocks_seq: int,
     return {"interpret": kw["interpret"]}
 
 
-@functools.partial(jax.jit, static_argnames=("head_dim", "interpret"))
+def walk_bounds(tables: jax.Array, pos0: jax.Array, T: int, bs: int,
+                window: int = 0) -> tuple[jax.Array, jax.Array]:
+    """``(first, end)`` of each row's walk over its ``tables [B, M]`` row:
+    entries ``first .. end - 1``. ``end = ceil((pos0 + T) / bs)``; ``first``
+    is 0, or in a sliding-window layer the block that holds the oldest key
+    the row's first query still sees (``pos0 - window + 1``). A row is live
+    where the table entry at ``first`` is a real block, else its walk is
+    empty (``end`` = 0). Traced: no retrace as depths and tables vary."""
+    M = tables.shape[1]
+    first = (jnp.maximum(pos0 - window + 1, 0) // bs if window
+             else jnp.zeros_like(pos0))
+    first = jnp.minimum(first, M - 1)
+    live = jnp.take_along_axis(tables, first[:, None], axis=1)[:, 0] != 0
+    end = jnp.where(live, jnp.clip(-(-(pos0 + T) // bs), 1, M), 0)
+    return jnp.where(live, first, 0), end
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("head_dim", "interpret", "window"))
 def paged_ragged_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, layer: jax.Array,
                            tables: jax.Array, positions: jax.Array,
-                           head_dim: int, *,
-                           interpret: bool = False) -> jax.Array:
+                           head_dim: int, *, interpret: bool = False,
+                           window: int = 0) -> jax.Array:
     """Causal GQA attention of ``q [B, T, n_heads, hd]`` over layer
     ``layer`` (a traced scalar) of the WHOLE paged pool ``k/v_pool [L,
     n_blocks, n_kv, bs, hd]`` through block ``tables [B, M]`` (0 = null
@@ -302,8 +330,18 @@ def paged_ragged_attention(q: jax.Array, k_pool: jax.Array,
         attention(q, view_k, view_v, positions, head_dim)
 
     and zero on a row whose table starts with the null block (a dead
-    slot, whatever its stale ``positions`` say)."""
+    slot, whatever its stale ``positions`` say).
+
+    ``window`` > 0 (static) is a sliding-window layer: the query at
+    position ``i`` sees keys ``i - window + 1 .. i``, the walk starts at the
+    block that holds the oldest of them (:func:`walk_bounds`; earlier
+    entries are never read, so a returned block's entry may be null), and a
+    row is live where THAT entry is a real block. One token a row: with
+    ``T`` > 1 a later query's window could begin past the first fetch
+    group, which the running maximum does not carry."""
     B, T, n_heads, D = q.shape
+    if window and T != 1:
+        raise ValueError("a sliding-window walk takes one token a row")
     n_kv, bs = k_pool.shape[2], k_pool.shape[3]
     M = tables.shape[1]
     kv_mul = n_heads // n_kv
@@ -316,15 +354,14 @@ def paged_ragged_attention(q: jax.Array, k_pool: jax.Array,
             .astype(jnp.float32))
     tables = jnp.asarray(tables, jnp.int32)
     pos0 = jnp.asarray(positions, jnp.int32)[:, 0]
-    # the walk's bound, from the table and the depth (traced: no retrace)
-    n_walk = jnp.where(tables[:, 0] != 0,
-                       jnp.clip(-(-(pos0 + T) // bs), 1, M), 0)
+    # the walk's bounds, from the table and the depth (traced: no retrace)
+    n_first, n_walk = walk_bounds(tables, pos0, T, bs, window)
 
     q_spec = pl.BlockSpec((1, heads, tq, D),
-                          lambda b, g, tbl, pos, nblk, layer: (b, g, 0, 0),
+                          lambda b, g, *_: (b, g, 0, 0),
                           memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # tables (flat), pos0, n_walk, layer
+        num_scalar_prefetch=5,  # tables (flat), pos0, n_walk, n_first, layer
         grid=(B, n_kv // heads),
         in_specs=[q_spec,
                   pl.BlockSpec(memory_space=pl.ANY),
@@ -341,11 +378,12 @@ def paged_ragged_attention(q: jax.Array, k_pool: jax.Array,
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, kv_mul=kv_mul, hd=head_dim,
-                          group=group, heads=heads, n_entries=M),
+                          group=group, heads=heads, n_entries=M,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv, tq, D), jnp.float32),
         interpret=interpret,
-    )(tables.reshape(-1), pos0, n_walk,
+    )(tables.reshape(-1), pos0, n_walk, n_first,
       jnp.asarray(layer, jnp.int32).reshape(1), q_g, k_pool, v_pool)
 
     return (out.reshape(B, n_kv, T, kv_mul, D)

@@ -221,20 +221,38 @@ class InferenceEngine:
                 f"spec_lookup {self.spec_lookup} exceeds the control packet's "
                 f"{self.packet_slots} token slots (raise --nbatches)")
 
-        if self.cfg.is_hybrid:
-            # recurrent state beside K/V (models/hybrid.py): what this
-            # engine does not carry to a state is refused HERE, by flag and
-            # reason; nothing is silently ignored and no code stands in
+        if self.cfg.paged_only:
+            # a recurrent state beside K/V (models/hybrid.py), or window
+            # layers with a block pool of their own and an expert share
+            # (models/laguna.py): what this engine does not carry to them
+            # is refused HERE, by flag and reason; nothing is silently
+            # ignored and no code stands in
             tp = 1 if tp is None else tp
+            hybrid = self.cfg.is_hybrid
+            what = ("a hybrid decoder (linear-attention layers with a "
+                    "recurrent state; the period scan has no mesh plan yet)"
+                    if hybrid else
+                    "a decoder with window layers and an expert share (two "
+                    "block pools a sequence; the period scan has no mesh "
+                    "plan yet, the share's exchange between chips is not "
+                    "built)")
             unsupported = [
                 ("no --kv-block-size (the dense slot pool, and the "
                  "single-sequence inference/chat/perplexity path: only the "
-                 "paged generator carries the state pool)",
+                 "paged generator carries "
+                 + ("the state pool)" if hybrid else "the two block pools)"),
                  not int(kv_block_size or 0)),
                 ("--spec-lookup (a rejected draft cannot be rolled back "
-                 "out of a recurrent state)", self.spec_lookup > 0),
+                 "out of a recurrent state)" if hybrid else
+                 "--spec-lookup (a sliding window's walk takes one token a "
+                 "row; a verify's lanes would each need a window of their "
+                 "own)", self.spec_lookup > 0),
                 ("--kv-host-blocks (the host tier spills and pages in K/V "
-                 "blocks; a state has no host copy)",
+                 "blocks; a state has no host copy)" if hybrid else
+                 "--kv-host-blocks (the host tier keeps one list of blocks "
+                 "by token range; the window pool's blocks behind the "
+                 "window are gone, and with them kvwire export/ingest and "
+                 "mid-stream resume)",
                  int(kv_host_blocks or 0) > 0),
                 ("--tp > 1", tp > 1), ("--sp > 1", sp > 1),
                 ("--pp > 1", pp > 1), ("--dp > 1", dp > 1),
@@ -248,9 +266,7 @@ class InferenceEngine:
             bad = [name for name, hit in unsupported if hit]
             if bad:
                 raise ValueError(
-                    f"a hybrid decoder (linear-attention layers with a "
-                    f"recurrent state; the period scan has no mesh plan "
-                    f"yet) does not support: {'; '.join(bad)}")
+                    f"{what} does not support: {'; '.join(bad)}")
         # paged KV serving (--kv-block-size, runtime/kvblocks.py): validate
         # the block geometry AND the feature combos up front — the paged
         # program family covers plain + tp ragged decode only, and a combo
@@ -607,11 +623,17 @@ class InferenceEngine:
         t_phase = self._stamp_startup("weight_load", t_phase)
         # a hybrid decoder is served by the paged generator alone, which
         # owns its pools: no batch-1 cache for a solo path it refuses
-        self.kv: KVCache = None if self.cfg.is_hybrid else self._fresh_kv()
+        self.kv: KVCache = None if self.cfg.paged_only else self._fresh_kv()
         self.pos = 0
         kinds = telemetry.registry().gauge(telemetry.LAYER_KINDS)
         kinds.set(self.cfg.n_linear_layers, kind="linear")
         kinds.set(self.cfg.n_kv_layers, kind="full")
+        kinds.set(self.cfg.n_window_layers, kind="sliding")
+        # the expert share (models/laguna.py): held here, of those routed
+        telemetry.registry().gauge(telemetry.MOE_EXPERTS_HELD).set(
+            self.cfg.n_experts)
+        telemetry.registry().gauge(telemetry.MOE_EXPERTS_TOTAL).set(
+            self.cfg.moe_router_width or self.cfg.n_experts)
         # Eval/Sync split (reference dllama.cpp:59-67): measured lazily on
         # the first decode of a generation when enabled; see measure_split()
         self.profile_split = profile_split
@@ -755,12 +777,14 @@ class InferenceEngine:
     def _require_solo_cache(self) -> None:
         """The single-sequence programs run over ``self.kv``, which a hybrid
         decoder does not have: its context is K/V AND a recurrent state,
-        and only the paged generator carries both."""
+        and only the paged generator carries both. Nor does a decoder with
+        window layers: its context is blocks of two pools."""
         if self.kv is None:
             raise RuntimeError(
-                "a hybrid decoder is served through BatchScheduler over the "
-                "paged pool only: the single-sequence path (inference, chat, "
-                "perplexity, score_nll) has no recurrent state")
+                "a hybrid decoder, or one with window layers, is served "
+                "through BatchScheduler over the paged pool only: the "
+                "single-sequence path (inference, chat, perplexity, "
+                "score_nll) has no recurrent state and no second pool")
 
     def _fresh_kv(self) -> KVCache:
         # dtype policy in __init__ (self.kv_dtype): compute dtype for parity,
@@ -775,7 +799,7 @@ class InferenceEngine:
             from ..parallel.multihost import CTRL_RESET
 
             self._ctrl.send(self._ctrl.encode(CTRL_RESET))
-        if not self.cfg.is_hybrid:
+        if not self.cfg.paged_only:
             self.kv = self._fresh_kv()
         self.pos = 0
         if self.tokenizer is not None:

@@ -117,6 +117,12 @@ class _TickPhase:
         self._name, self._ann = name, None
         self.__enter__()
 
+    @property
+    def traced(self) -> bool:
+        """A profiler is listening: metadata that costs something to make
+        is worth making."""
+        return self._ann is not None and self._ann.is_enabled()
+
     def set(self, **metadata) -> None:
         """Attach ``key=value`` metadata to the phase's profiler
         annotation (shown as the event's stats in the trace)."""

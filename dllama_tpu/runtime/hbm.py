@@ -66,6 +66,19 @@ def matmul_weight_count(cfg) -> int:
                 + cfg.q_dim * cfg.dim + ffn)
         return (cfg.n_linear_layers * linear + cfg.n_kv_layers * full
                 + cfg.dim * cfg.vocab_size)
+    if cfg.has_window_layers:
+        # what is HELD: two kinds of attention layer at their own head
+        # counts, the leading dense feed-forward, the held experts of a
+        # routed layer with its router (over its whole width) and shared
+        # expert, the vocabulary's rows
+        attn = lambda heads: 2 * cfg.dim * (heads * cfg.head_dim + cfg.kv_dim)
+        routed = (cfg.dim * cfg.moe_router_width
+                  + 3 * cfg.dim * (cfg.hidden_dim * cfg.n_experts
+                                   + cfg.shared_expert_dim))
+        return (cfg.n_kv_layers * attn(cfg.n_heads)
+                + cfg.n_window_layers * attn(cfg.n_heads_sliding)
+                + cfg.n_dense_layers * 3 * cfg.dim * cfg.dense_hidden_dim
+                + cfg.n_moe_layers * routed + cfg.dim * cfg.vocab_size)
     per_layer = (cfg.dim * cfg.q_dim + 2 * cfg.dim * cfg.kv_dim
                  + cfg.q_dim * cfg.dim)
     if cfg.is_moe:

@@ -562,6 +562,11 @@ def startup_line(engine) -> str:
     cfg = engine.cfg
     kinds = (f"; layers: {cfg.n_linear_layers} linear, {cfg.n_kv_layers} full"
              if cfg.is_hybrid else "")
+    if cfg.has_window_layers:
+        kinds = (f"; layers: {cfg.n_kv_layers} full, {cfg.n_window_layers} "
+                 f"sliding (window {cfg.sliding_window}); experts: "
+                 f"{cfg.n_experts} of {cfg.moe_router_width} held from "
+                 f"{cfg.moe_first_expert}, {cfg.n_active_experts} a token")
     return (f"🧮 start-up: {sum(parts.values()):.2f} s ("
             + ", ".join(f"{k} {v:.2f}" for k, v in parts.items()) + ")"
             + kinds)

@@ -33,6 +33,19 @@ in XLA:
   last ulp from the prefill a solo run would execute; golden_assets
   documents ulp flips becoming token flips).
 
+* **Window layers** — a block id addresses ``pool[l, bid]`` for every
+  layer of a pool at once, so a sliding-window layer cannot give a block
+  back while a full-attention layer keeps it. A model with both
+  (models/laguna.py) has TWO pools and two tables a sequence: the full
+  layers' here, and the window layers' in a second :class:`PagedKVCache`
+  over a second :class:`BlockPool` (no prefix index is ever built in it),
+  whose blocks return to the free list as soon as every position in them
+  is more than ``window - 1`` behind the row's next position
+  (:func:`window_first_block`); their table entries become the null
+  block, which paged attention never reads (its walk starts at the first
+  live block). Prefix sharing would hand a request blocks the window pool
+  has already taken back, so it is off for such a model, and counted.
+
 * **Host tier** — with ``n_host_blocks > 0`` (``--kv-host-blocks``), the
   LRU cached machinery becomes a *spill point* instead of a drop point:
   under allocation pressure the coldest cached blocks move to a
@@ -160,6 +173,24 @@ def validate_block_size(seq_len: int, block_size: int) -> None:
 def blocks_per_seq(seq_len: int, block_size: int) -> int:
     """Block-table width: blocks covering the padded physical context."""
     return padded_cache_len(seq_len) // block_size
+
+
+def window_first_block(pos: int, window: int, block_size: int) -> int:
+    """Table index of the oldest block a sliding-window layer still reads
+    when the query sits at position ``pos``: the block that holds position
+    ``pos - window + 1`` (the window counts the query's own position).
+    Every block before it holds only positions more than ``window - 1``
+    behind, and goes back to the window pool's free list
+    (``PagedGenerator._slide_window``); ``ops/paged_attention.walk_bounds``
+    starts the row's walk at the same index."""
+    return max(0, pos - window + 1) // block_size
+
+
+def window_blocks_cap(window: int, block_size: int) -> int:
+    """Blocks of the window pool one sequence can hold at once, at the
+    most: the window's, one more where it starts mid-block, and the block
+    the next position opens before the oldest is returned."""
+    return window // block_size + 2
 
 
 class PagedKVCache(NamedTuple):

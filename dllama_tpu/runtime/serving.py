@@ -53,7 +53,8 @@ from ..parallel.multihost import (
     CTRL_SRV_VERIFY,
 )
 from ..tokenizer.sampler import xorshift_random_f32
-from .kvblocks import SPILL_BATCH, BlockPoolExhausted, PageInError
+from .kvblocks import (SPILL_BATCH, BlockPoolExhausted, PageInError,
+                       window_blocks_cap, window_first_block)
 from .kvcache import KVCache
 
 if TYPE_CHECKING:
@@ -1402,6 +1403,19 @@ class PagedGenerator(_GeneratorCore):
         # case (every slot at max context) + the null block; degrade to the
         # largest pool that fits the device budget (>= one full sequence)
         want = n_slots * self.table_width + 1
+        # sliding-window layers live in a pool of their own (a block id
+        # addresses every layer of its pool at once, so a window layer
+        # cannot give a block back while a full layer keeps it): a slot
+        # holds at most the window's blocks, one for the position being
+        # written and one for a window that starts mid-block
+        self.window = (self.cfg.sliding_window
+                       if self.cfg.has_window_layers else 0)
+        self._wcap = (window_blocks_cap(self.window, block_size)
+                      if self.window else 0)
+        n_wblocks = n_slots * self._wcap + 1 if self.window else 0
+        wpool_bytes = (2 * self.cfg.n_window_layers * n_wblocks
+                       * self.cfg.kv_dim * block_size
+                       * engine.kv_dtype.itemsize)
         n_blocks, est = fit_block_pool(
             self.cfg, want, block_size=block_size,
             min_blocks=self.table_width + 1,
@@ -1409,7 +1423,7 @@ class PagedGenerator(_GeneratorCore):
             kv_dtype_bytes=engine.kv_dtype.itemsize,
             n_shards=engine.tp * engine.pp,
             offload=(engine.weight_mode == "offload"),
-            state_bytes=state_pool_bytes(
+            state_bytes=wpool_bytes + state_pool_bytes(
                 self.cfg, n_slots, jnp.dtype(self.cfg.compute_dtype).itemsize))
         if n_blocks == 0:
             check_budget(est["need_per_device"],
@@ -1448,9 +1462,31 @@ class PagedGenerator(_GeneratorCore):
         self.spool = (StatePool.create(self.cfg, n_slots,
                                        jnp.dtype(self.cfg.compute_dtype))
                       if self.cfg.is_hybrid else None)
+        # window layers: the second pool, its allocator and its tables,
+        # and the routing counters the step and the chunks accumulate on
+        # the device (models/laguna.py)
+        self.wpool = self.wkv = self.wtables = self.moe_stats = None
+        if self.window:
+            from ..models.laguna import zero_totals
+
+            self.wpool = BlockPool(n_wblocks, block_size)
+            wshape = (self.cfg.n_window_layers, n_wblocks,
+                      self.cfg.n_kv_heads, block_size, self.cfg.head_dim)
+            self.wkv = PagedKVCache(k=jnp.zeros(wshape, engine.kv_dtype),
+                                    v=jnp.zeros(wshape, engine.kv_dtype))
+            self.moe_stats = zero_totals(self.cfg)
+            self._moe_seen = np.zeros((2, 2 + self.cfg.n_experts), np.int64)
+        # window blocks a slot owns, by table index: host truth from
+        # begin_admit on (the table row is published at commit)
+        self._wbids: list[dict[int, int]] = [{} for _ in range(n_slots)]
         # per-slot block tables (host truth; shipped per dispatch as a
         # traced [n_slots, table_width] int32 — values never recompile)
         self.tables = np.zeros((n_slots, self.table_width), dtype=np.int32)
+        if self.window:
+            # a row's two tables side by side, as the step takes them:
+            # ``tables`` and ``wtables`` are the halves of one array
+            self._both_tables = np.zeros((2,) + self.tables.shape, np.int32)
+            self.tables, self.wtables = self._both_tables
         self._seq_bids: list[list[int]] = [[] for _ in range(n_slots)]
         # shared-prefix length (in blocks) per slot: the commit scatter
         # redirects those entries to the null block so a shared block is
@@ -1505,6 +1541,35 @@ class PagedGenerator(_GeneratorCore):
             return HybridColumn.zeros(self.cfg, kv.k, kv.v,
                                       self.spool.conv.dtype)
 
+        def _take_window_fn(pkv, table):
+            # prefix blocks are never shared here, so an admission's column
+            # starts empty; every layer's rows are built in it, the two
+            # pools see them at commit
+            from ..models.laguna import LagunaColumn
+
+            return LagunaColumn.zeros(self.cfg, engine.kv_dtype)
+
+        full_ids = np.arange(0, self.cfg.n_layers,
+                             max(1, self.cfg.layer_period))
+        slide_ids = np.setdiff1d(np.arange(self.cfg.n_layers), full_ids)
+
+        def _put_window_fn(pkv, wkv, stats, col, table, wtable):
+            # the column's full layers through the slot's table, its
+            # sliding layers through the window table (entries behind the
+            # window are null: those rows land in the null block), and the
+            # chunks' routing counters into the running totals' chunk row
+            def back(pool, c, tbl):
+                L = c.shape[0]
+                c = c[:, 0].reshape(L, self.cfg.n_kv_heads, M, bs,
+                                    self.cfg.head_dim)
+                c = jnp.moveaxis(c, 2, 1)
+                return pool.at[:, tbl].set(c.astype(pool.dtype))
+            return (PagedKVCache(k=back(pkv.k, col.k[full_ids], table),
+                                 v=back(pkv.v, col.v[full_ids], table)),
+                    PagedKVCache(k=back(wkv.k, col.k[slide_ids], wtable),
+                                 v=back(wkv.v, col.v[slide_ids], wtable)),
+                    stats.at[1].add(col.stats))
+
         def _state_put_fn(spool, s, conv, row):
             put = jax.lax.dynamic_update_index_in_dim
             return StatePool(s=put(spool.s, s[:, 0], row, 1),
@@ -1529,7 +1594,9 @@ class PagedGenerator(_GeneratorCore):
         # raw jit is deliberate for the three block-movement programs:
         # plan-independent gather/scatter/copy (no constrain()), safe to
         # share across engines — same argument as the dense pool's pair
-        self._take = jax.jit(_take_hybrid_fn if self.cfg.is_hybrid else _take_fn)  # dlint: disable=jit-entry
+        self._take = jax.jit(_take_hybrid_fn if self.cfg.is_hybrid  # dlint: disable=jit-entry
+                             else _take_window_fn if self.window else _take_fn)
+        self._put_window = jax.jit(_put_window_fn, donate_argnums=(0, 1, 2))  # dlint: disable=jit-entry
         # a hybrid decoder's commit writes the admission's state to the
         # slot's row of the state pool, in place
         self._state_put = jax.jit(_state_put_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
@@ -1616,6 +1683,18 @@ class PagedGenerator(_GeneratorCore):
             n_slots if self.spool is not None else 0)
         self._tm.gauge(telemetry.STATE_POOL_BYTES).set(
             self.spool.n_bytes if self.spool is not None else 0)
+        self._m_wblocks_used = self._tm.gauge(telemetry.KV_WINDOW_BLOCKS_USED)
+        self._m_wblocks_alloc = self._tm.counter(
+            telemetry.KV_WINDOW_BLOCKS_ALLOCATED)
+        self._m_wblocks_returned = self._tm.counter(
+            telemetry.KV_WINDOW_BLOCKS_RETURNED)
+        self._tm.gauge(telemetry.KV_WINDOW_BLOCKS_TOTAL).set(
+            max(0, n_wblocks - 1))
+        self._m_moe_pairs = self._tm.counter(telemetry.MOE_PAIRS)
+        self._m_moe_tokens = self._tm.counter(telemetry.MOE_EXPERT_TOKENS)
+        if self.window:
+            for where in ("held", "absent"):
+                self._m_moe_pairs.inc(0, where=where)
         self._update_block_gauges()
         engine._stamp_startup("generator", t_phase)
 
@@ -1623,6 +1702,8 @@ class PagedGenerator(_GeneratorCore):
 
     def _update_block_gauges(self) -> None:
         self._m_blocks_used.set(self.pool.used_blocks())
+        if self.wpool is not None:
+            self._m_wblocks_used.set(self.wpool.used_blocks())
         if self.spool is not None:
             self._m_state_used.set(self.n_active)
         self._m_blocks_shared.set(self.pool.shared_blocks())
@@ -1796,9 +1877,19 @@ class PagedGenerator(_GeneratorCore):
         worst-case price already covers the device blocks a
         prefix-matched (possibly host-resident) prompt pages back
         into."""
-        return (self.pool.free_blocks() - sum(self._reserve)
-                >= self._worst_case_blocks(len(req.prompt_ids),
-                                           req.max_tokens))
+        price = self._worst_case_blocks(len(req.prompt_ids), req.max_tokens)
+        if self.wpool is not None:
+            # BOTH pools are asked: a window slot may yet grow to its cap
+            # (or to all its request will ever write, if that is less)
+            owed = sum(max(0, self._wprice(i) - len(w))
+                       for i, w in enumerate(self._wbids))
+            if self.wpool.free_blocks() - owed < min(self._wcap, price):
+                return False
+        return self.pool.free_blocks() - sum(self._reserve) >= price
+
+    def _wprice(self, slot: int) -> int:
+        """Window blocks ``slot`` may hold at once, at the most."""
+        return min(self._wcap, len(self._seq_bids[slot]) + self._reserve[slot])
 
     # -- KV migration wire: export (peer pull) / ingest (local commit) ------
 
@@ -1815,6 +1906,12 @@ class PagedGenerator(_GeneratorCore):
                 "dtype": str(_np.dtype(self.eng.kv_dtype))}
 
     def _refuse_wire(self) -> None:
+        if self.wpool is not None:
+            raise ValueError(
+                "kvwire export/ingest moves one list of K/V blocks by token "
+                "range; with window layers a sequence's context is blocks of "
+                "two pools, the window pool's already returned behind the "
+                "window, which has no wire format")
         if self.spool is not None:
             raise ValueError(
                 "kvwire export/ingest moves K/V blocks between replicas; a "
@@ -1928,16 +2025,21 @@ class PagedGenerator(_GeneratorCore):
             shared, n_tok, cow_src, cow_r = [], 0, None, 0
         else:
             shared, n_tok, cow_src, cow_r = self.pool.match_prefix(rest)
-        if self.spool is not None:
+        skip = self.cfg.prefix_reuse_skipped
+        if skip is not None:
             if req.score:
                 raise ValueError(
                     "teacher-forced scoring is not carried to a hybrid "
-                    "decoder's recurrent state (its chunks run unmasked)")
+                    "decoder's recurrent state (its chunks run unmasked), "
+                    "nor to window layers (their column carries routing "
+                    "counters, not scores)")
             # a matched block holds K/V this request did not compute and
-            # NO state of the linear layers: nothing is reused, and the
-            # counter says a match was passed over
+            # NO state of the linear layers (or, with window layers, names
+            # positions whose window blocks went back to their pool long
+            # ago): nothing is reused, and the counter says a match was
+            # passed over
             if n_tok or (cow_src is not None and cow_r > 0):
-                self._m_skipped.inc(reason="recurrent_state")
+                self._m_skipped.inc(reason=skip)
             shared, n_tok, cow_src, cow_r = [], 0, None, 0
         # KV tier: matched blocks may be HOST-resident (a resumed /
         # prefix-matched session whose cold blocks spilled under
@@ -1957,7 +2059,18 @@ class PagedGenerator(_GeneratorCore):
         pinned: list[int] = []  # device shares taken before bids exist
         cow_exec: tuple | None = None
         cow_release = 0
+        wbids: dict[int, int] = {}
         try:
+            if self.wpool is not None and rest:
+                # the window pool's share of the prompt: the blocks the
+                # first decode step's window still reaches (the earlier
+                # positions live in the admission's column only, and are
+                # never written to a block)
+                for idx in range(
+                        window_first_block(len(rest), self.window,
+                                           self.block_size),
+                        (len(rest) - 1) // self.block_size + 1):
+                    wbids[idx] = self.wpool.alloc()
             # pin every DEVICE-resident matched block FIRST: the page-in
             # (and CoW/growth) allocations below resolve pressure against
             # the cached LRU, and an unpinned match sitting there could
@@ -2025,6 +2138,8 @@ class PagedGenerator(_GeneratorCore):
             # also restores the host pins); fresh blocks are whatever
             # remains in bids.
             pair_devs = {dev for _, dev in pairs}
+            for b in wbids.values():
+                self.wpool.release(b)
             for b in bids:
                 if b not in pair_devs and b not in pinned:
                     self.pool.release(b)
@@ -2037,6 +2152,8 @@ class PagedGenerator(_GeneratorCore):
                     telemetry.KV_BLOCK_EXHAUSTION).inc()
             raise
         self._seq_bids[slot] = bids
+        self._wbids[slot] = wbids
+        self._m_wblocks_alloc.inc(len(wbids))
         self._n_shared[slot] = len(shared)
         self._reserve[slot] = max(
             0, self._worst_case_blocks(len(ids), req.max_tokens) - len(bids))
@@ -2086,7 +2203,7 @@ class PagedGenerator(_GeneratorCore):
         # a recurrent state would keep what padding wrote into it: the
         # hybrid's chunk carries its valid length (models/hybrid.forward);
         # a dense decoder pads freely and is passed none
-        valid = (jnp.int32(n_valid),) if self.cfg.is_hybrid else ()
+        valid = (jnp.int32(n_valid),) if self.cfg.paged_only else ()
         with self.eng.watchdog.guard("batch_prefill"):
             failpoints.fire("step_hang")
             with self._plan_ctx():
@@ -2112,6 +2229,8 @@ class PagedGenerator(_GeneratorCore):
             self._commit_admit(adm)
             if self.spool is not None and not adm.req.score:
                 span.set(state_bytes=adm.col.s.nbytes + adm.col.conv.nbytes)
+            if self.wpool is not None:
+                span.set(window_blocks=len(self._wbids[adm.slot]))
         return True
 
     def _advance_prefill(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
@@ -2179,8 +2298,15 @@ class PagedGenerator(_GeneratorCore):
                                 dtype=np.int32)
             n_sh = self._n_shared[slot]
             put_table[n_sh:len(bids)] = bids[n_sh:]
-            self.pkv = self._put(self.pkv, KVCache(k=adm.col.k, v=adm.col.v),
-                                 jnp.asarray(put_table))
+            if self.wpool is not None:
+                self.pkv, self.wkv, self.moe_stats = self._put_window(
+                    self.pkv, self.wkv, self.moe_stats, adm.col,
+                    jnp.asarray(put_table),
+                    jnp.asarray(self._wtable_row(slot)))
+            else:
+                self.pkv = self._put(
+                    self.pkv, KVCache(k=adm.col.k, v=adm.col.v),
+                    jnp.asarray(put_table))
         if self.spool is not None:
             # the state's one write: the admission's carry becomes the
             # slot's row (the previous occupant's state goes with it)
@@ -2191,6 +2317,8 @@ class PagedGenerator(_GeneratorCore):
         # _arm_decode — no dispatch ever sees this slot's real table
         # paired with a stale position
         self.tables[slot, :len(bids)] = bids
+        if self.wpool is not None:
+            self.wtables[slot] = self._wtable_row(slot)
         adm.pos = len(rest)
         if self.spec:
             from .speculative import NgramProposer
@@ -2214,6 +2342,11 @@ class PagedGenerator(_GeneratorCore):
         all-null row sends ride-along writes to the null block)."""
         for b in self._seq_bids[slot]:
             self.pool.release(b)
+        for b in self._wbids[slot].values():
+            self.wpool.release(b)
+        self._wbids[slot] = {}
+        if self.wtables is not None:
+            self.wtables[slot, :] = self.pool.NULL
         self._seq_bids[slot] = []
         self._n_shared[slot] = 0
         self._reserve[slot] = 0
@@ -2254,6 +2387,10 @@ class PagedGenerator(_GeneratorCore):
         self._n_shared = [0] * self.n_slots
         self._reserve = [0] * self.n_slots
         self.pool.reset()
+        if self.wpool is not None:
+            self.wpool.reset()
+            self.wtables[:, :] = self.pool.NULL
+        self._wbids = [{} for _ in range(self.n_slots)]
         if self.mirror is not None:
             self.mirror.drop_all()  # host buffers follow the pool's reset
         self.tables[:, :] = self.pool.NULL
@@ -2280,6 +2417,35 @@ class PagedGenerator(_GeneratorCore):
                 self._seq_bids[i].append(bid)
                 self._reserve[i] = max(0, self._reserve[i] - 1)
                 self.tables[i, idx] = bid
+        if self.wpool is not None:
+            self._slide_window(i, last_pos)
+
+    def _wtable_row(self, slot: int) -> np.ndarray:
+        row = np.full(self.table_width, self.pool.NULL, dtype=np.int32)
+        for idx, bid in self._wbids[slot].items():
+            row[idx] = bid
+        return row
+
+    def _slide_window(self, i: int, pos: int) -> None:  # dlint: owner=loop-thread
+        """The window pool's lazy growth AND its return: before the step
+        that writes position ``pos``, every block of slot ``i`` whose
+        positions all lie more than ``window - 1`` behind ``pos`` goes back
+        to the free list (its table entry becomes the null block; paged
+        attention starts the row's walk past it), and the block ``pos``
+        falls in is allocated where the table has none."""
+        held = self._wbids[i]
+        first = window_first_block(pos, self.window, self.block_size)
+        gone = [idx for idx in held if idx < first]
+        for idx in gone:
+            self.wpool.release(held.pop(idx))
+            self.wtables[i, idx] = self.pool.NULL
+        if gone:
+            self._m_wblocks_returned.inc(len(gone))
+        idx = pos // self.block_size
+        if idx not in held:
+            held[idx] = self.wpool.alloc()
+            self.wtables[i, idx] = held[idx]
+            self._m_wblocks_alloc.inc()
 
     def _grow_or_fail(self, active: list[int], grow: list[int]) -> None:  # dlint: owner=loop-thread
         """Lazy growth for one dispatch: ensure every active slot's write
@@ -2341,20 +2507,34 @@ class PagedGenerator(_GeneratorCore):
             # blocks, both donated, and gives both back
             cache = (self.pkv if self.spool is None
                      else (self.pkv, self.spool))
+            tables = self.tables
+            if self.wpool is not None:
+                # two pools and the running counters in, all three back
+                cache = (self.pkv, self.wkv, self.moe_stats)
+                tables = self._both_tables
             with self._plan_ctx():
                 (nxt, nf), cache = self._step(
                     self.eng.params, self.cfg,
                     jnp.asarray(self.next_token.astype(np.int32)[:, None]),
                     jnp.asarray(self.pos.astype(np.int32)), cache,
-                    jnp.asarray(self.tables),
+                    jnp.asarray(tables),
                     jnp.asarray(temps), jnp.asarray(topps),
                     jnp.asarray(coins), self._poison())
-            if self.spool is None:
+            if self.wpool is not None:
+                self.pkv, self.wkv, self.moe_stats = cache
+            elif self.spool is None:
                 self.pkv = cache
             else:
                 self.pkv, self.spool = cache
             wait.next_phase("step_wait")
-            nxt, nf = np.asarray(nxt), np.asarray(nf)
+            if self.wpool is None:
+                nxt, nf = np.asarray(nxt), np.asarray(nf)
+            else:
+                # the routing counters are the same program's output as
+                # the tokens: one fetch brings all three
+                nxt, nf, totals = jax.device_get(
+                    (nxt, nf, self.moe_stats))
+                self._note_moe(totals, wait)
         ms = (time.perf_counter() - t0) * 1000.0
         with self.flight.tick_phase("emit"):
             self._settle_prefill(wait.t0_ns, wait.t1_ns)
@@ -2371,6 +2551,37 @@ class PagedGenerator(_GeneratorCore):
             self._record_step(len(active), ms, emitted)
             self._update_block_gauges()
         return emitted
+
+    def _note_moe(self, totals: np.ndarray, wait) -> None:  # dlint: owner=loop-thread
+        """The device's running routing counters (row 0 the steps', row 1
+        the committed chunks') into the registry: what was added since the
+        last step's fetch (int32 on the device: the difference is taken
+        modulo 2**32). The step's ``step_wait`` span gets the held pairs
+        THIS step computed (``moe_pairs``: the routed kernel's roofline
+        share reads them) and, while a profiler listens, the counters'
+        running totals, so that a reader of a traced slice takes what the
+        slice added and not what the process has counted since it started
+        (``moe_held`` / ``moe_absent``, ``moe_tokens`` a held expert joined
+        by ``/``, ``wblocks_allocated`` / ``wblocks_returned``)."""
+        delta = (totals.astype(np.int64) - self._moe_seen) % (1 << 32)
+        self._moe_seen = totals.astype(np.int64)
+        both = delta.sum(axis=0)
+        if both[0]:
+            self._m_moe_pairs.inc(int(both[0]), where="held")
+        if both[1]:
+            self._m_moe_pairs.inc(int(both[1]), where="absent")
+        for e in np.nonzero(both[2:])[0]:
+            self._m_moe_tokens.inc(int(both[2 + e]), expert=str(int(e)))
+        wait.set(moe_pairs=int(delta[0, 0]))
+        if wait.traced:
+            pairs, tokens = self._m_moe_pairs, self._m_moe_tokens
+            wait.set(moe_held=int(pairs.total(where="held")),
+                     moe_absent=int(pairs.total(where="absent")),
+                     moe_tokens="/".join(
+                         str(int(tokens.total(expert=str(e))))
+                         for e in range(self.cfg.n_experts)),
+                     wblocks_allocated=int(self._m_wblocks_alloc.total()),
+                     wblocks_returned=int(self._m_wblocks_returned.total()))
 
     def _spec_step(self, active: list[int]) -> int:  # dlint: owner=loop-thread
         """One ragged paged speculative verify dispatch

@@ -194,6 +194,14 @@ STATE_SLOTS_USED = "dllama_state_slots_used"
 STATE_SLOTS_TOTAL = "dllama_state_slots_total"
 STATE_POOL_BYTES = "dllama_state_pool_bytes"
 PREFIX_REUSE_SKIPPED = "dllama_prefix_reuse_skipped_total"
+KV_WINDOW_BLOCKS_USED = "dllama_kv_window_blocks_used"
+KV_WINDOW_BLOCKS_TOTAL = "dllama_kv_window_blocks_total"
+KV_WINDOW_BLOCKS_ALLOCATED = "dllama_kv_window_blocks_allocated_total"
+KV_WINDOW_BLOCKS_RETURNED = "dllama_kv_window_blocks_returned_total"
+MOE_PAIRS = "dllama_moe_pairs_total"
+MOE_EXPERT_TOKENS = "dllama_moe_expert_tokens_total"
+MOE_EXPERTS_HELD = "dllama_moe_experts_held"
+MOE_EXPERTS_TOTAL = "dllama_moe_experts_total"
 RETRACE_UNEXPECTED = "dllama_retrace_unexpected_total"
 
 # latency buckets in ms: sub-ms CPU ticks through multi-second TPU compiles
@@ -466,7 +474,35 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
     _spec(PREFIX_REUSE_SKIPPED, "counter",
           "Admissions whose prompt matched cached prefix blocks that were "
           "NOT reused, by reason (recurrent_state: the blocks carry K/V "
-          "but no state of the linear-attention layers)"),
+          "but no state of the linear-attention layers; window_layers: the "
+          "window pool has already taken back the blocks the match names)"),
+    _spec(KV_WINDOW_BLOCKS_USED, "gauge",
+          "Blocks of the sliding-window layers' pool held by live "
+          "sequences; 0 without window layers"),
+    _spec(KV_WINDOW_BLOCKS_TOTAL, "gauge",
+          "Usable blocks of the sliding-window layers' pool (excludes the "
+          "null block); 0 without window layers"),
+    _spec(KV_WINDOW_BLOCKS_ALLOCATED, "counter",
+          "Blocks taken from the sliding-window layers' pool (at "
+          "admission for the prompt's last window, one a block boundary "
+          "as decode advances)"),
+    _spec(KV_WINDOW_BLOCKS_RETURNED, "counter",
+          "Blocks given back to the sliding-window layers' pool by a LIVE "
+          "sequence because every position in them fell behind its window "
+          "(retirement's releases are not counted)"),
+    _spec(MOE_PAIRS, "counter",
+          "(token, expert) pairs the router chose in routed layers, by "
+          "where the expert lives: held (computed on this chip) or absent "
+          "(another chip's share: nothing is computed for it here). "
+          "Accumulated on the device, fetched with each step's tokens"),
+    _spec(MOE_EXPERT_TOKENS, "counter",
+          "Tokens each HELD expert computed, summed over the routed "
+          "layers, by the expert's index among those held"),
+    _spec(MOE_EXPERTS_HELD, "gauge",
+          "Routed experts a layer holds on this chip; 0 for a dense model"),
+    _spec(MOE_EXPERTS_TOTAL, "gauge",
+          "Routed experts the router scores (the deployment's count); "
+          "equals the held count where the whole layer is here"),
     _spec(RETRACE_UNEXPECTED, "counter",
           "Recompiles observed AFTER an engine scope reached serving "
           "steady state (each is a latency cliff; the shape/plan diff is "

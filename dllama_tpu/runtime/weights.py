@@ -395,7 +395,8 @@ class _StreamingLoader:
                      lambda idx: self.rd.tensor_f32(name)[idx])
 
     def expert_stack(self, name: str, out_dim: int, in_dim: int,
-                     out_axis: str | None, in_axis: str | None):
+                     out_axis: str | None, in_axis: str | None,
+                     layers: list[int] | None = None):
         """[L, E, in, out] experts — IN-major, the lax.ragged_dot rhs layout
         (see models.llama.LayerParams). Sharded experts→ep, expert-hidden→tp;
         one (layer, expert) slice read at a time.
@@ -406,7 +407,10 @@ class _StreamingLoader:
         the HBM the budget estimator charged (VERDICT r4 weak #7). Dense
         files load at compute dtype (bf16 by default: a dense-f32 Mixtral
         would be unloadable — advisor round-1 medium finding)."""
-        L, E = self.h.n_layers, self.h.n_experts
+        # ``layers``: the model's layers this stack holds, in order (a
+        # model whose leading layers are dense); absent, every layer
+        ids = list(range(self.h.n_layers)) if layers is None else layers
+        L, E = len(ids), self.h.n_experts
         if self.quantized:
             cshape = (L, E, in_dim, out_dim)
             sshape = (L, E, in_dim // QUANT_BLOCK_SIZE, out_dim)
@@ -429,9 +433,10 @@ class _StreamingLoader:
                     for ei, e in enumerate(experts):
                         if want_scales:
                             part = self.rd.tensor_scales_kmajor_sub(
-                                f"{name}.{l}.{e}", n_lo, n_hi, k_al, k_ah)
+                                f"{name}.{ids[l]}.{e}", n_lo, n_hi, k_al,
+                                k_ah)
                         else:
-                            _, codes = sub(f"{name}.{l}.{e}",
+                            _, codes = sub(f"{name}.{ids[l]}.{e}",
                                            n_lo, n_hi, k_al, k_ah)
                             part = codes[k_lo - k_al:k_hi - k_al]
                         if out is None:  # fill in place, one slice at a time
@@ -461,7 +466,7 @@ class _StreamingLoader:
             for li, l in enumerate(_layer_range(l_sl, L)):
                 for ei, e in enumerate(_layer_range(e_sl, E)):
                     part = self.rd.tensor_f32_rows(
-                        f"{name}.{l}.{e}", o_lo, o_hi)[:, i_sl].T  # -> [in, out]
+                        f"{name}.{ids[l]}.{e}", o_lo, o_hi)[:, i_sl].T  # -> [in, out]
                     if out is None:
                         out = np.empty(
                             (len(_layer_range(l_sl, L)), len(_layer_range(e_sl, E)))
@@ -531,6 +536,63 @@ def _load_hybrid_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
                          if dense_logits_wanted(ld.fast_numerics) else None)))
 
 
+def _load_laguna_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
+    """A decoder of window and full attention layers with an expert share
+    (models/laguna.py) from the tensors ``mfile._walk_laguna_layer`` names:
+    two attention stacks by layer kind, the leading dense layers'
+    feed-forward, and the routed layers' router, HELD experts and shared
+    expert, each stacked over its own layers of the model."""
+    from ..models.laguna import AttnParams, LagunaLayers
+    from ..models.llama import Params
+
+    h = ld.h
+    P, hd = h.layer_period, h.head_dim
+    every = list(range(h.n_layers))
+    full_ids = [l for l in every if l % P == 0]
+    slide_ids = [l for l in every if l % P]
+    dense_ids, moe_ids = every[:h.n_dense_layers], every[h.n_dense_layers:]
+    mm = lambda ids, name, o, i: ld.matmul(
+        name, o, i, stacked=True, out_axis=None, in_axis=None, layers=ids)
+
+    def attn(ids, heads):
+        return AttnParams(
+            wq=mm(ids, "block_matmul_q", heads * hd, h.dim),
+            wk=mm(ids, "block_matmul_k", h.kv_dim, h.dim),
+            wv=mm(ids, "block_matmul_v", h.kv_dim, h.dim),
+            wo=mm(ids, "block_matmul_wo", h.dim, heads * hd),
+            wg=ld.stacked_f32("block_attn_gate", heads, h.dim, layers=ids),
+            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=ids))
+
+    wide, sh = h.dense_hidden_dim, h.shared_expert_dim
+    experts = lambda name, o, i: ld.expert_stack(name, o, i, None, None,
+                                                 layers=moe_ids)
+    layers = LagunaLayers(
+        full=attn(full_ids, h.n_heads),
+        slide=attn(slide_ids, h.n_heads_sliding),
+        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
+        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim),
+        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide),
+        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim),
+        moe_gate=ld.stacked_f32("block_moe_gate", h.moe_router_width, h.dim,
+                                layers=moe_ids),
+        we1=experts("block_expert_w1", h.hidden_dim, h.dim),
+        we2=experts("block_expert_w2", h.dim, h.hidden_dim),
+        we3=experts("block_expert_w3", h.hidden_dim, h.dim),
+        ws1=mm(moe_ids, "block_shared_w1", sh, h.dim) if sh else None,
+        ws2=mm(moe_ids, "block_shared_w2", h.dim, sh) if sh else None,
+        ws3=mm(moe_ids, "block_shared_w3", sh, h.dim) if sh else None)
+    return Params(
+        embedding=ld.f32("embedding", h.vocab_size, h.dim,
+                         dtype=jnp.dtype(cfg.compute_dtype)),
+        layers=layers,
+        final_norm=ld.f32("final_norm", h.dim),
+        logits=ld.matmul(
+            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
+            out_axis="vocab", in_axis=None,
+            force_dense=(jnp.bfloat16
+                         if dense_logits_wanted(ld.fast_numerics) else None)))
+
+
 def load_params(mf: ModelFile, cfg: "ModelConfig", weight_mode: str = "auto",
                 plan: MeshPlan | None = None) -> "Params":
     """Build fully-placed (and, under a plan, fully-sharded) device params.
@@ -552,6 +614,13 @@ def load_params(mf: ModelFile, cfg: "ModelConfig", weight_mode: str = "auto",
     qwen3 = h.arch_type == ArchType.QWEN3
     if h.arch_type == ArchType.OLMO_HYBRID:
         return _load_hybrid_params(ld, cfg)
+    if h.arch_type == ArchType.LAGUNA:
+        if not ld.quantized:
+            raise ValueError(
+                "a LAGUNA file's matmul planes must be Q40 or Q80: the routed "
+                "decode kernel (ops/expert_gemv.py) and its XLA form read "
+                "quantized expert stacks")
+        return _load_laguna_params(ld, cfg)
 
     # Under offload only the per-layer stacks go host-side: they are the
     # O(model) bytes and stream through the scan; embedding / final norm /

@@ -8,8 +8,9 @@ benchmark/selftest/selftest.py`` still runs them with the rest of the
 self-test; before PR 30 nothing under ``tests/`` did, so tier-1 could not see
 the seam regress.
 
-**Three cases are red, and are marked so** (``KNOWN_RED``, strict: one that
-turns green fails the run until its row is taken out). Two of
+**Three cases a PR's two cells are red, and are marked so** (``KNOWN_RED``,
+strict: one that turns green fails the run until its row is taken out; PR 30's
+three and, for the same reason, PR 34's three). Two of
 ``test_modules.py``'s tests run over EVERY cell of ``BENCHMARK.json`` and
 assert what held of PR 29's three cells: 24 rows a cell in
 ``selftest/counts_frozen.json``, and no configuration naming modules. PR 30's
@@ -31,7 +32,14 @@ import pytest
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
 SELFTEST = os.path.join(BENCH, "selftest")
 NEW_CELLS = ("olmo-hybrid-7b.long-prompt", "mistral-7b-v0.3.single-stream")
+PR34_CELLS = ("laguna-s-2.1.mixed-queue", "mistral-7b-v0.3.mixed-queue")     # the same three cases, for the same reason
 KNOWN_RED = {
+    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR34_CELLS[0]}]":
+        "selftest/counts_frozen.json has no rows for a cell PR 34 added",
+    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR34_CELLS[1]}]":
+        "selftest/counts_frozen.json has no rows for a cell PR 34 added",
+    f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{PR34_CELLS[0]}]":
+        "laguna-s-2.1 names its modules: the test asserts that no configuration of BENCHMARK.json does",
     f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{NEW_CELLS[0]}]":
         "selftest/counts_frozen.json has no rows for a cell PR 30 added",
     f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{NEW_CELLS[1]}]":
@@ -96,3 +104,62 @@ def test_the_hybrid_cell_gets_the_modules_it_names_and_single_stream_the_dense_o
     assert "bf16state" in mods["reference"].CONTROLS and callable(mods["counts"].kernel_counts)
     _cell, conf, _traffic, mods = _seam._resolve(NEW_CELLS[1])
     assert "modules" not in conf and os.path.relpath(mods["counts"].__file__, BENCH) == "counts.py"
+
+
+# -- and PR 34's cells, from a file of PR 34's own ---------------------------------
+
+with open(os.path.join(BENCH, "laguna", "selftest", "counts_frozen.json"), encoding="utf-8") as _f:
+    PR34_FROZEN = json.load(_f)
+
+
+@pytest.mark.parametrize("cell", PR34_CELLS)
+def test_pr34s_cells_counts_through_the_seam_are_what_pr34_froze(cell):
+    _cell, conf, _traffic, mods = _seam._resolve(cell)
+    rows = [r for r in PR34_FROZEN["rows"] if r["cell"] == cell]
+    assert len(rows) == 24
+    for r in rows:
+        assert getattr(mods["counts"], r["fn"])(conf["model"], **r["args"]) == r["value"], r
+
+
+def test_the_routed_cell_gets_the_modules_it_names_and_mixed_queue_the_dense_ones():
+    _cell, conf, _traffic, mods = _seam._resolve(PR34_CELLS[0])
+    assert {k: os.path.relpath(m.__file__, BENCH) for k, m in mods.items()} == conf["modules"] == {
+        "reference": "laguna/reference.py", "weights": "laguna/weights.py", "counts": "laguna/counts.py"}
+    assert {"misroute", "noshared", "nogate", "nowindow"} <= set(mods["reference"].CONTROLS)
+    assert mods["counts"].kernel_counts(conf["model"], "expert_gemv", rows=16)["layers"] == 23
+    _cell, conf, _traffic, mods = _seam._resolve(PR34_CELLS[1])
+    assert "modules" not in conf and os.path.relpath(mods["counts"].__file__, BENCH) == "counts.py"
+
+
+# -- the form the driver holds BENCHMARK.json to before any run --------------------
+
+_NAME = r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}"
+_KEYS = {"configs": {"name", "source", "file", "reduced", "why"},
+         "workloads": {"name", "config", "traffic", "chips", "why"},
+         "end_to_end": {"name", "unit", "better", "bound", "source"},
+         "per_layer": {"name", "unit", "better", "source", "layer", "moves"}}
+
+
+@pytest.mark.parametrize("section", sorted(_KEYS))
+def test_benchmark_json_keeps_the_form_the_driver_refuses_a_file_for(section):
+    """A ``why`` of 201 characters refuses the whole PR before a single run
+    (PR 34's first draft had one): names, units, one-line texts of at most 200
+    characters, the keys an entry may have, 64 KiB."""
+    import re
+
+    path = os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")
+    assert os.path.getsize(path) <= 64 * 1024
+    with open(path, encoding="utf-8") as f:
+        entries = json.load(f)[section]
+    names = [e["name"] for e in entries]
+    assert len(set(names)) == len(names)
+    for e in entries:
+        assert set(e) - {"workloads"} == _KEYS[section] and ("workloads" not in e or section not in ("configs", "workloads")), e
+        for key in ("name", "config", "traffic", "moves", *e.get("reduced", ()), *e.get("workloads", ())):
+            assert re.fullmatch(_NAME, e.get(key, key)), (e["name"], key)
+        for key in ("why", "layer", "source"):
+            text = e.get(key, "x")
+            assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text, (e["name"], key, len(text))
+        assert re.fullmatch(r"[A-Za-z0-9_/%.\-]{1,16}", e.get("unit", "ms")), e
+        assert e.get("better", "lower") in ("lower", "higher") and e.get("chips", 1) in (1, 4) and len(e.get("reduced", ())) <= 16
+        assert re.fullmatch(r"benchmark/[A-Za-z0-9_.\-/]+", e.get("file", "benchmark/x")), e

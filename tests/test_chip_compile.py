@@ -303,6 +303,43 @@ def test_paged_attention_compiles_at_30_kv_heads_for_v5e(one_chip):
 # -- chip_smoke.py's control flow, without a chip ------------------------------
 
 
+@pytest.mark.parametrize("heads,layers,blocks,window", [(6, 6, 4097, 0), (9, 18, 545, 512)])
+def test_paged_attention_compiles_at_one_kv_head_with_a_window_for_v5e(one_chip, heads, layers, blocks, window):
+    """One chip's share of a layer (laguna-s-2.1): query groups of 6 and 9
+    over ONE K/V head, a block of 4 KB; the sliding layers' walk starts at
+    the row's first live block and masks by the window."""
+    from dllama_tpu.ops import paged_attention as pa
+
+    kernels = _compiled_kernels(
+        lambda q, k, v, layer, tables, pos: pa.paged_ragged_attention(
+            q, k, v, layer, tables, pos, 128, window=window),
+        _shape(one_chip, (16, 1, heads, 128), jnp.bfloat16),
+        _shape(one_chip, (layers, blocks, 1, 16, 128), jnp.bfloat16),
+        _shape(one_chip, (layers, blocks, 1, 16, 128), jnp.bfloat16),
+        _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (16, 256), jnp.int32),
+        _shape(one_chip, (16, 1), jnp.int32))
+    assert kernels
+
+
+@pytest.mark.parametrize("k,n", [(3072, 1024), (1024, 3072)])
+def test_expert_gemv_compiles_for_v5e(one_chip, k, n):
+    """The routed decode kernel at laguna-s-2.1's expert (3072 x 1024, gate /
+    up and down), 23 layers of 32 held experts, 160 pairs (16 rows x 10): two
+    landing halves of a whole 3 MB plane and its dequantized copy in VMEM."""
+    from dllama_tpu.ops import expert_gemv as eg
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    assert eg.supports(160, k, n, True)
+    stack = QuantizedWeight(scales=_shape(one_chip, (23, 32, k // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (23, 32, k, n), jnp.int8))
+    kernels = _compiled_kernels(
+        lambda x, st, layer, experts, n_pairs: eg.expert_gemv(x, st, layer, experts, n_pairs, fast=True),
+        _shape(one_chip, (160, k), jnp.bfloat16), stack, _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (160,), jnp.int32), _shape(one_chip, (), jnp.int32))
+    assert any("expert_gemv" in name for name in kernels), kernels
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_chip_smoke_rehearsal_on_cpu(chips):
     """The script end to end at a toy size with JAX_PLATFORMS=cpu children

@@ -1323,3 +1323,55 @@ def test_cancel_behind_prefill_budget_releases_immediately(paged_engine):
         assert a.error is None and len(a.tokens) == 4
     finally:
         sched.close()
+
+
+# -- window layers: the second pool's return rule -----------------------------
+
+
+def test_window_first_block_is_the_oldest_position_still_seen():
+    from dllama_tpu.runtime.kvblocks import window_blocks_cap, window_first_block
+
+    # a window of 512 counts the query's own position: at 511 the oldest key seen is 0, at 512 it is 1
+    assert [window_first_block(p, 512, 16) for p in (0, 511, 512, 526, 527, 528, 1039)] == [0, 0, 0, 0, 1, 1, 33]
+    assert window_first_block(100, 32, 16) == 4 and window_first_block(79, 32, 16) == 3
+    assert window_blocks_cap(512, 16) == 34 and window_blocks_cap(32, 16) == 4
+
+
+@pytest.mark.parametrize("seed", range(5))
+def test_window_pool_churn_never_passes_its_cap_and_comes_back_whole(seed):
+    """The window pool under the return rule, host bookkeeping alone: rows of
+    random prompt lengths decode at random, each returning what falls behind
+    its window before it takes the block its next position opens. No row ever
+    holds more than the cap, a pool of slots x cap never runs dry, a returned
+    block is handed out again while its first owner lives, and after every row
+    retires the free count is what it started at."""
+    from dllama_tpu.runtime.kvblocks import BlockPool, window_blocks_cap, window_first_block
+
+    rng = np.random.default_rng(seed)
+    window, bs, slots = 48, 16, 3
+    cap = window_blocks_cap(window, bs)
+    pool = BlockPool(slots * cap + 1, bs)
+    free0 = pool.free_blocks()
+    held = [dict() for _ in range(slots)]
+    pos = [int(p) for p in rng.integers(1, 200, size=slots)]
+    owners: dict[int, set] = {}
+    for i in range(slots):                       # admission: the blocks the first step's window reaches
+        for idx in range(window_first_block(pos[i], window, bs), (pos[i] - 1) // bs + 1):
+            held[i][idx] = pool.alloc()
+    for _ in range(600):
+        i = int(rng.integers(slots))
+        first = window_first_block(pos[i], window, bs)
+        for idx in [j for j in held[i] if j < first]:
+            pool.release(held[i].pop(idx))
+        if pos[i] // bs not in held[i]:
+            bid = pool.alloc()
+            held[i][pos[i] // bs] = bid
+            owners.setdefault(bid, set()).add(i)
+        assert len(held[i]) <= cap and min(held[i]) >= first
+        assert all(pool.refcount(b) == 1 for b in held[i].values())
+        pos[i] += 1
+    assert any(len(rows) > 1 for rows in owners.values())          # a block served more than one row
+    for i in range(slots):
+        for bid in held[i].values():
+            pool.release(bid)
+    assert pool.free_blocks() == free0 and pool.used_blocks() == 0

@@ -511,3 +511,58 @@ def test_q40_expert_hbm_estimate_charges_quantized(tmp_path):
     # loader keeps ~1.125 B/weight (codes + scales) for the expert planes
     assert resident <= n_expert_w * 1.5
     assert est_q["need_per_device"] < est_d["need_per_device"]
+
+
+# -- the decode form's kernel: expert_gemv against its XLA oracle -------------
+
+
+def _expert_stack(rng, L, E, K, N, scale_dtype=jnp.float32):
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    codes = rng.integers(-7, 8, size=(L, E, K, N)).astype(np.int8)
+    scales = rng.uniform(0.5, 1.5, size=(L, E, K // 32, N)) / (4.2 * K ** 0.5)
+    return QuantizedWeight(scales=jnp.asarray(scales, scale_dtype), codes=jnp.asarray(codes))
+
+
+@pytest.mark.parametrize("n_pairs", [0, 1, 7, 12])
+@pytest.mark.parametrize("K,N", [(64, 128), (128, 256)])
+def test_expert_gemv_is_its_xla_oracle(n_pairs, K, N):
+    """``expert_gemv`` (interpret mode off a TPU) against the gather form: each
+    of the first ``n_pairs`` pairs is one row times ONE expert's planes, read
+    out of layer ``layer`` of the stack where it lies; the pairs behind them
+    (an absent expert's, a dead row's) are not computed and read zero, whatever
+    their expert index says. float32 graphs: the same dequantized values and
+    one dot over the whole contraction on both sides, so 1e-5."""
+    from dllama_tpu.ops import expert_gemv as eg
+
+    rng = np.random.default_rng(K + n_pairs)
+    L, E, P = 3, 4, 12
+    stack = _expert_stack(rng, L, E, K, N)
+    x = jnp.asarray(rng.standard_normal((P, K)), jnp.float32)
+    experts = rng.integers(0, E, size=P).astype(np.int32)
+    experts[n_pairs:] = 10_000            # behind the count: never read
+    for layer in (0, 2):
+        got = np.asarray(eg.expert_gemv(x, stack, jnp.int32(layer), jnp.asarray(experts),
+                                        jnp.int32(n_pairs), interpret=True))
+        want = np.asarray(eg.expert_gemv_xla(x, stack, jnp.int32(layer), jnp.asarray(experts),
+                                             jnp.int32(n_pairs)))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        assert np.all(got[n_pairs:] == 0)
+        if n_pairs:
+            # against the planes themselves, so the oracle is not its own witness
+            e0 = int(experts[0])
+            w = np.asarray(stack.codes[layer, e0], np.float32) \
+                * np.repeat(np.asarray(stack.scales[layer, e0]), 32, axis=0)
+            np.testing.assert_allclose(got[0], np.asarray(x[0]) @ w, rtol=2e-4, atol=2e-4)
+
+
+def test_expert_gemv_gate_routes_through_the_one_gate(monkeypatch):
+    from dllama_tpu.ops import expert_gemv as eg
+
+    stack = _expert_stack(np.random.default_rng(0), 1, 2, 64, 128)
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "xla")
+    assert eg.kernel_choice(8, stack, False) is None
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "fused")
+    assert eg.kernel_choice(8, stack, False) == {"interpret": True, "fast": False}
+    monkeypatch.delenv("DLLAMA_TPU_QUANT_KERNEL")
+    assert eg.kernel_choice(8, stack, False) is None      # auto, off a TPU

@@ -250,7 +250,7 @@ def test_padded_chunked_prefill_then_decode_logits(bench, engine, n_prompt, kern
     prompt = _tokens(n_prompt, seed=n_prompt)
     gen.admit(Request(rid=1, prompt_ids=prompt, max_tokens=8, stop_on_eos=False), 1)
     got, emitted = _decode_logits(gen, 1, 8)
-    assert calls == ([{"interpret": True}] if kernel else [])      # traced once: one full layer's body
+    assert calls == ([{"interpret": True, "window": 0}] if kernel else [])      # traced once: one full layer's body
     want = _reference_logits(bench, engine.params, prompt + emitted)[n_prompt - 1:n_prompt + 7]
     assert float(np.abs(got - want).max()) < LOGIT_TOL
 

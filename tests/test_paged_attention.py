@@ -457,3 +457,64 @@ def test_paged_kernel_compiled_parity_on_hw():
     live = tables[:, 0] != 0
     np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
     assert np.all(got[~live] == 0)
+
+
+# -- a sliding-window layer: a lower bound on the walk, the window in the mask --
+
+
+def _window_reference(q, k_pool, v_pool, tables, positions, head_dim, window):
+    B, M = tables.shape
+    n_kv, bs, hd = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
+
+    @jax.jit
+    def ref(q, k_pool, v_pool, tables, positions):
+        def view(pool):
+            return jnp.moveaxis(pool[tables], 2, 1).reshape(B, n_kv, M * bs, hd)
+
+        return attention(q, view(k_pool), view(v_pool), positions, head_dim,
+                         window=window)
+
+    return ref(q, k_pool, v_pool, tables, positions)
+
+
+@pytest.mark.parametrize("n_heads,n_kv", [(6, 1), (9, 1), (8, 2)])
+@pytest.mark.parametrize("window", [32, 48, 200])
+def test_window_walk_starts_at_the_first_live_block(n_heads, n_kv, window):
+    """A sliding-window layer's rows: the query sees its newest ``window``
+    keys, the walk starts at the block that holds the oldest of them, and
+    every table entry behind it is the NULL block (the allocator took those
+    blocks back), so a kernel that read one would read garbage: the pool's
+    block 0 is poisoned with NaN here. Query groups of 6 and 9 over ONE K/V
+    head are what one chip's share of a layer holds; depths sit under the
+    window, on its edge, and blocks past it; one row is dead."""
+    rng = np.random.default_rng(window + n_heads)
+    B, T, hd, bs, M, nb = 6, 1, 16, 16, 16, 60
+    q, kp, vp = _mk(rng, B, T, n_heads, n_kv, hd, bs, M, nb)
+    kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+    depths = [5, window - 1, window, window + bs + 3, 5 * bs + 9, 77]
+    tables = np.zeros((B, M), np.int32)
+    ids = iter(rng.permutation(np.arange(1, nb)))
+    for b, pos in enumerate(depths[:-1]):
+        first = max(0, pos - window + 1) // bs
+        for idx in range(first, pos // bs + 1):
+            tables[b, idx] = next(ids)          # entries behind `first` stay null
+    positions = jnp.asarray([[p] for p in depths], jnp.int32)
+    got = np.asarray(_kernel_1(q, kp, vp, jnp.asarray(tables), positions, hd,
+                               window=window))
+    # the oracle gathers the null block too: give it a finite one to mask
+    want = np.asarray(_window_reference(q, kp.at[0].set(0.0), vp.at[0].set(0.0),
+                                        jnp.asarray(tables), positions, hd, window))
+    np.testing.assert_allclose(got[:-1], want[:-1], rtol=TOL, atol=TOL)
+    assert np.all(got[-1] == 0)                 # the dead row: zeros, no NaN
+    # and the window really cuts: a full-attention read of the same row differs
+    full = np.asarray(_window_reference(q, kp.at[0].set(0.0), vp.at[0].set(0.0),
+                                        jnp.asarray(tables), positions, hd, 0))
+    assert np.abs(full[3] - want[3]).max() > 1e-2      # row 3 is a block and more past its window
+
+
+def test_window_walk_takes_one_token_a_row():
+    rng = np.random.default_rng(3)
+    q, kp, vp = _mk(rng, 1, 2, 4, 2, 16, 16, 4, 6)
+    with pytest.raises(ValueError, match="one token a row"):
+        _kernel_1(q, kp, vp, jnp.ones((1, 4), jnp.int32),
+                  jnp.asarray([[3, 4]], jnp.int32), 16, window=8)

@@ -1551,16 +1551,29 @@ def test_router_fronts_real_engine_replica(tmp_path):
               timeout=30)
         body = {"messages": [{"role": "user", "content": "hello"}],
                 "max_tokens": 6, "temperature": 0}
-        with _post(f"http://127.0.0.1:{port}", body, timeout=120) as r:
+        # the replica is ONE thread: while it serves a completion (whose
+        # first call compiles, seconds on a loaded machine) the router's
+        # health probes go unanswered and it is ejected until the next
+        # probe after the answer. So each routed call waits for the EVENT
+        # "the router sees the replica up again", not for a margin of
+        # wall time that six workers on one machine can outlast.
+        def ready():
+            _wait(lambda: fleet.readiness()[0], what="engine replica up",
+                  timeout=120)
+
+        with _post(f"http://127.0.0.1:{port}", body, timeout=300) as r:
             direct = json.loads(r.read())
-        with _post(url, body, timeout=120) as r:
+        ready()
+        with _post(url, body, timeout=300) as r:
             routed = json.loads(r.read())
         assert routed["choices"] == direct["choices"]
         assert routed["usage"] == direct["usage"]
         # and the streaming path relays the real SSE stream
-        with _post(url, dict(body, stream=True), timeout=120) as r:
+        ready()
+        with _post(url, dict(body, stream=True), timeout=300) as r:
             raw = r.read().decode()
         assert "data: [DONE]" in raw
+        ready()
         # trace identity reaches the REAL replica: a completion routed
         # with a client-chosen id lands in the api server's flight dump
         # as a fleet_rid binding with the serving hop, its span ring
@@ -1571,7 +1584,7 @@ def test_router_fronts_real_engine_replica(tmp_path):
             data=json.dumps(dict(body, timing=True)).encode(),
             headers={"Content-Type": "application/json",
                      "X-Dllama-Request-Id": "e2e.trace-1"})
-        with urllib.request.urlopen(req, timeout=120) as r:
+        with urllib.request.urlopen(req, timeout=300) as r:
             assert r.headers["X-Dllama-Request-Id"] == "e2e.trace-1"
             timed = json.loads(r.read())
         assert timed["timing"]["request_id"] == "e2e.trace-1"

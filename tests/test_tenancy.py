@@ -556,6 +556,7 @@ def test_contention_flooder_cannot_starve_light(engine, tmp_path):
     base_batch = g.counter(tm.BATCH_TOKENS).total()
     sched = BatchScheduler(engine, n_slots=2, tenant_limits=limits)
     try:
+        t_wave = time.monotonic()
         flood = [sched.submit(ids_f, 6, stop_on_eos=False, tenant="flood")
                  for _ in range(12)]
         lights = []
@@ -566,6 +567,11 @@ def test_contention_flooder_cannot_starve_light(engine, tmp_path):
         for r in flood + lights:
             assert r.done.wait(timeout=300)
             assert r.error is None
+        # what ONE request took on this machine under this load: 18
+        # requests over 2 slots are 9 rounds. The bounds below are in
+        # this unit, so six test workers on one machine slow the light
+        # tenant's waits and their limit alike
+        round_ms = (time.monotonic() - t_wave) * 1000.0 / 9
     finally:
         sched.close()
 
@@ -574,9 +580,15 @@ def test_contention_flooder_cannot_starve_light(engine, tmp_path):
     # (the floor absorbs CPU-tier tick jitter on the tiny model — a
     # FIFO queue behind 12 flooder requests would be far past it)
     light_p95 = snap["light"]["queue_wait_ms"]["p95"]
-    assert light_p95 <= 2.0 * max(solo_p95, 250.0), \
-        f"light p95 {light_p95:.0f}ms vs solo {solo_p95:.0f}ms"
-    assert light_p95 <= snap["flood"]["queue_wait_ms"]["p95"] * 1.5 + 1.0
+    # under weighted round-robin a light request waits for a slot to
+    # free, a round or two; in FIFO order behind 12 flooder requests the
+    # last ones would wait six rounds and more
+    assert light_p95 <= 2.0 * max(solo_p95, 250.0, 1.5 * round_ms), \
+        f"light p95 {light_p95:.0f}ms vs solo {solo_p95:.0f}ms, a round " \
+        f"{round_ms:.0f}ms"
+    # and in the wave's own terms, whatever the machine's speed: FIFO
+    # would put the light tenant's tail at or past the flooder's
+    assert light_p95 <= snap["flood"]["queue_wait_ms"]["p95"] * 0.75 + 1.0
     # the wave was served fairly: 72 vs 36 demanded tokens -> 0.9
     jain = tenancy.jain_index([snap["flood"]["decode_tokens"],
                                snap["light"]["decode_tokens"]])
