@@ -72,6 +72,9 @@ N_SPECIAL = 256  # Llama 3: 128000 regular tokens, then 256 special ones
 PROMPT = ("The quick brown fox jumps over the lazy dog while a distributed "
           "llama shards its tensors over chips")
 assert len(PROMPT) == 99
+# the bf16 phase's: 255 characters + BOS = ONE 256-row prefill bucket (the
+# widest of engine.PREFILL_BUCKETS), so `forward` is traced at that width
+PROMPT_256 = ((PROMPT + " ") * 3)[:255]
 NEW_TOKENS = 64
 
 
@@ -283,21 +286,22 @@ def banner_facts(ch: Child, platform: str, n_devices: int) -> dict:
     return dev
 
 
-def run_inference(name: str, ctx: dict, extra: list[str]) -> dict:
+def run_inference(name: str, ctx: dict, extra: list[str],
+                  prompt: str = PROMPT) -> dict:
     """``python -m dllama_tpu inference`` with the README quick-start
     defaults, greedy; returns the generated text and what the run printed."""
     ch = Child(name, [
         sys.executable, "-m", "dllama_tpu", "inference",
         "--model", ctx["model"], "--tokenizer", ctx["tokenizer"],
-        "--max-seq-len", str(ctx["max_seq_len"]), "--prompt", PROMPT,
-        "--steps", str(len(PROMPT) + 1 + NEW_TOKENS), "--temperature", "0",
+        "--max-seq-len", str(ctx["max_seq_len"]), "--prompt", prompt,
+        "--steps", str(len(prompt) + 1 + NEW_TOKENS), "--temperature", "0",
         "--seed", "1", *extra], ctx["env"])
     rc = ch.wait_exit(ctx["timeout"])
     if rc != 0:
         ch.fail(f"exited rc={rc}")
     dev = banner_facts(ch, ctx["platform"], ctx["n_devices"])
     text = ch.text()
-    head = text.index(PROMPT + "\n") + len(PROMPT) + 1
+    head = text.index(prompt + "\n") + len(prompt) + 1
     end = text.index("\nEvaluation\n", head)
     generated = text[head:end]
     n_pred = int(re.search(r"Prediction\n\s+nTokens: (\d+)", text).group(1))
@@ -307,7 +311,7 @@ def run_inference(name: str, ctx: dict, extra: list[str]) -> dict:
     # time to first token, from the prompt's echo (the engine is loaded, the
     # prompt goes in) to the first generated byte: prefill and first decode,
     # compiles included; process start and the weight load are not in it
-    echo = (PROMPT + "\n").encode()
+    echo = (prompt + "\n").encode()
     at = ch.buf.index(echo)
     loaded_s = ch.time_of_byte(at)
     first_token_s = ch.time_of_byte(at + len(echo)) - loaded_s
@@ -381,13 +385,23 @@ def phase_inference_f32(ctx: dict) -> dict:
 
 def phase_inference_bf16(ctx: dict) -> dict:
     say("phase inference-bf16: serving numerics (on a TPU the fused "
-        "dequant-GEMV at M = 1, XLA dequant + dot for the prompt)")
-    out = run_inference("inference-bf16", ctx, ["--compute-dtype", "bf16"])
-    if ctx["platform"] == "tpu" and not re.search(
-            r"greedy_step [1-9]\d* fused / 0 tiled / 0 xla", out["q40_paths"]):
+        "dequant-GEMV at M = 1 and the same kernel's chunk regime for a "
+        "256-token prompt: one 256-row bucket)")
+    out = run_inference("inference-bf16", ctx, ["--compute-dtype", "bf16"],
+                        prompt=PROMPT_256)
+    if ctx["platform"] != "tpu":
+        return out
+    if not re.search(r"greedy_step 0 chunk / [1-9]\d* fused / 0 tiled / 0 xla",
+                     out["q40_paths"]):
         # `auto` has no row floor: one row is a decode-shaped dispatch
         out["child"].fail("the bf16 decode step (M = 1) did not take the "
                           f"fused kernel: {out['q40_paths']!r}")
+    if not re.search(r"forward [1-9]\d* chunk / 0 fused / 0 tiled / 0 xla",
+                     out["q40_paths"]):
+        # a chunk that fell back dequantizes in passes through HBM again
+        out["child"].fail("the bf16 prefill (one 256-row bucket) did not "
+                          "take the fused kernel's chunk regime on every "
+                          f"Q40 matmul: {out['q40_paths']!r}")
     return out
 
 
@@ -750,7 +764,7 @@ def main() -> int:
     ctx = {"env": env, "platform": "cpu" if args.rehearse else "tpu",
            "n_devices": args.chips, "cache_dir": compile_cache.cache_dir(),
            "timeout": 900.0,
-           "max_seq_len": 256 if args.rehearse else MAX_SEQ_LEN}
+           "max_seq_len": TOY["seq_len"] if args.rehearse else MAX_SEQ_LEN}
 
     # the cheapest child first: with no chip, fail before touching anything
     phase_block_until_ready(ctx)

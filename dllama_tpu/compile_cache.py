@@ -40,7 +40,12 @@ def enable() -> str | None:
               file=sys.stderr)
         return None
     os.environ[ENV] = path
-    os.environ.setdefault(_MIN_SECS_ENV, "1")
+    # persist what costs a quarter second to compile, not JAX's one second:
+    # a prefill bucket whose Q40 matmuls are Pallas kernels compiles in
+    # 0.9-1.9 s, and the ones under a second were compiled again at every
+    # start (three of qwen3-4b's eight `forward` traces, 4.5 s of a 28 s
+    # set-up: PERF.md section 6, PR 35)
+    os.environ.setdefault(_MIN_SECS_ENV, "0.25")
     jax = sys.modules.get("jax")
     if jax is not None:
         jax.config.update("jax_compilation_cache_dir", path)

@@ -112,15 +112,16 @@ def _stack_at(layers, l: jax.Array, matmuls: tuple[str, ...]):
 def _scan_by_index(cfg: ModelConfig, rows: int) -> bool:
     """Whether a layer scan walks the layer INDEX with the weight stack
     closed over (:func:`_layer_at`) instead of scanning the stack: a
-    decode-shaped dispatch (``rows`` = B x T flattened, the fused kernel's
-    regime) on one device. Wider dispatches (a prefill chunk), mesh plans
-    and offloaded weights scan the stack itself, as ever. Platform and
-    kernel mode do not enter: where no kernel takes the stack, linear()
-    slices it, which is what the scan did."""
-    from ..ops.quant_matmul import FUSED_MAX_M
+    dispatch the fused kernel has a regime for (``rows`` = B x T
+    flattened: a decode step's 1..16, a prefill chunk's up to 256) on one
+    device. Wider dispatches, mesh plans and offloaded weights scan the
+    stack itself, as ever. Platform and kernel mode do not enter: where
+    no kernel takes the stack, linear() slices it, which is what the scan
+    did."""
+    from ..ops.quant_matmul import CHUNK_MAX_M
 
     return (_current_plan() is None and not cfg.offload
-            and rows <= FUSED_MAX_M)
+            and rows <= CHUNK_MAX_M)
 
 
 def _layer_indices(cfg: ModelConfig) -> jax.Array:

@@ -162,21 +162,21 @@ def _pallas_wanted(x: jax.Array, w: QuantizedWeight, fast: bool) -> dict | None:
 
     What ``auto`` comes to on a TPU: exact mode takes the tiled kernel
     (HIGHEST-precision dots that match the host oracle); fast mode takes
-    the fused dequant-GEMV for 1..16 flattened rows over a 2-D plane pair
-    and the XLA dequant + dot for everything wider (a prefill chunk's
-    dequant amortizes over its rows, and the tiled kernel streams codes at
-    ~130 GB/s where XLA reaches 450-750: tools/gemv_sweep.py). Under a
-    mesh plan the sharded entry in linear() handles dispatch; this plain
-    path must stay out of GSPMD-partitioned graphs (the auto-sharder can't
-    split a pallas_call)."""
-    from .quant_matmul import (pallas_mode_gate, supports, supports_decode,
-                               wants_fused)
+    the fused full-K kernel over a 2-D plane pair for 1..16 flattened rows
+    (a decode step: the dequant-GEMV) and for 17..256 (a prefill chunk:
+    the same dequant into VMEM in front of one MXU pass), and the XLA
+    dequant + dot for everything else: wider, stacked expert planes, a
+    width off the lane grid (the tiled kernel streams codes at ~130 GB/s
+    where XLA reaches 450-750: tools/gemv_sweep.py). Under a mesh plan the
+    sharded entry in linear() handles dispatch; this plain path must stay
+    out of GSPMD-partitioned graphs (the auto-sharder can't split a
+    pallas_call)."""
+    from .quant_matmul import pallas_mode_gate, supports
 
     kw = pallas_mode_gate(fast, tuple(x.shape), w)
     if kw is None:
         return None
-    if not (supports(tuple(x.shape), w)
-            or (wants_fused(kw) and supports_decode(tuple(x.shape), w, fast))):
+    if not (supports(tuple(x.shape), w) or _fused_path(kw, x, w, fast)):
         return None
     if _kernel_mode() in ("pallas", "fused"):
         return kw  # forced: replicated operands are fine under a plan
@@ -209,19 +209,20 @@ def _pallas_sharded(x: jax.Array, w: QuantizedWeight, out_axis: str | None,
 
 
 # dlint: static-fn (shape/env gate)
-def _takes_decode_kernel(kw: dict | None, x: jax.Array, w: QuantizedWeight,
-                         fast: bool) -> bool:
-    """Whether quant_matmul, given these gate kwargs, runs the decode
-    kernel on this dispatch (and not the tiled one)."""
-    from .quant_matmul import supports_decode, wants_fused
+def _fused_path(kw: dict | None, x: jax.Array, w: QuantizedWeight,
+                fast: bool) -> str | None:
+    """The path quant_matmul, given these gate kwargs, runs the full-K
+    fused kernel under on this dispatch (``fused`` at 1..16 rows, ``chunk``
+    at 17..256), or None where it runs the tiled one."""
+    from .quant_matmul import fused_path, wants_fused
 
-    return wants_fused(kw) and supports_decode(tuple(x.shape), w, fast)
+    return fused_path(tuple(x.shape), w, fast) if wants_fused(kw) else None
 
 
 def _layer_slice_fused(x: jax.Array, w: LayerSlice) -> jax.Array | None:
-    """The fused decode kernel over the layer stack and an index, where the
-    gate resolves it for one layer's shapes (no plan: the stack entry has
-    no sharded twin); None sends the caller to the plain slice."""
+    """The fused kernel over the layer stack and an index, where the gate
+    resolves it for one layer's shapes (no plan: the stack entry has no
+    sharded twin); None sends the caller to the plain slice."""
     from ..parallel.api import current_plan
     from ..runtime.introspection import note_q40_path
     from .quant_matmul import quant_matmul
@@ -231,9 +232,10 @@ def _layer_slice_fused(x: jax.Array, w: LayerSlice) -> jax.Array | None:
     fast = _fast_mode(x) or w.stack.scales.dtype == jnp.bfloat16
     one = w.one_layer()
     kw = _pallas_wanted(x, one, fast)
-    if not _takes_decode_kernel(kw, x, one, fast):
+    path = _fused_path(kw, x, one, fast)
+    if path is None:
         return None
-    note_q40_path("fused")
+    note_q40_path(path)
     return quant_matmul(x, w.stack, fast=fast, layer=w.index, **kw)
 
 
@@ -250,14 +252,15 @@ def linear(x: jax.Array, w: Weight, *, out_axis: str | None = None,
     (quant_matmul_sharded); single-device Q40 dispatches the plain kernel.
     DLLAMA_TPU_QUANT_KERNEL=auto|pallas|fused|xla; the ONE resolution rule
     is quant_matmul.pallas_mode_gate. On a TPU ``auto`` means: exact (f32)
-    graphs take the tiled kernel; fast (bf16) graphs take the fused
-    dequant-GEMV for a decode-shaped dispatch (1..16 flattened rows, a 2-D
-    plane pair, no plan) and the XLA dequant + dot for everything else, a
-    prefill chunk included. A :class:`LayerSlice` hands that kernel the
-    layer stack and an index; unsupported shapes fall back to XLA
-    dequant + dot with identical dequant values. Each Q40 dispatch notes
-    the path it took (``fused`` / ``tiled`` / ``xla``) for the program
-    being traced (runtime.introspection.note_q40_path).
+    graphs take the tiled kernel; fast (bf16) graphs take the fused full-K
+    kernel over a 2-D plane pair with no plan, for a decode-shaped
+    dispatch (1..16 flattened rows) and for a prefill chunk (17..256: the
+    plane is dequantized in VMEM, not in passes through HBM), and the XLA
+    dequant + dot for everything else. A :class:`LayerSlice` hands that
+    kernel the layer stack and an index; unsupported shapes fall back to
+    XLA dequant + dot with identical dequant values. Each Q40 dispatch
+    notes the path it took (``fused`` / ``chunk`` / ``tiled`` / ``xla``)
+    for the program being traced (runtime.introspection.note_q40_path).
     """
     out_dtype = x.dtype
     if isinstance(w, LayerSlice):
@@ -286,8 +289,7 @@ def linear(x: jax.Array, w: Weight, *, out_axis: str | None = None,
             if kernel_kw is not None:
                 from .quant_matmul import quant_matmul
 
-                note_q40_path("fused" if _takes_decode_kernel(
-                    kernel_kw, x, w, fast) else "tiled")
+                note_q40_path(_fused_path(kernel_kw, x, w, fast) or "tiled")
                 return quant_matmul(x, w, fast=fast, **kernel_kw)
         note_q40_path("xla")
         # XLA fallback: in fast mode the dense dequant lands in bf16 (half the

@@ -109,6 +109,14 @@ def _decode_kernel(x_ref, codes_ref, scales_ref, out_ref, wd_ref, s32_ref,
     over the weight planes, which is exactly the decode regime's byte
     budget (weights dominate; the T<=16 activation rides along in VMEM).
 
+    The same body is the prefill CHUNK's kernel in fast mode (17..256 rows,
+    PR 35): there the stripe's dequant is what XLA otherwise does in passes
+    of its own through HBM (slice the codes, convert, spread the scales,
+    multiply, write a bf16 plane, read it back: 48% of a cell's device
+    time), and the one dot is an MXU pass at 130-145 TFLOP/s on a v5e
+    against 153-174 for a dense bf16 plane of the same shape
+    (tools/gemv_sweep.py, PERF.md section 6).
+
     The dequant is VPU work, one Q40 block of 32 rows at a time: an int8
     tile is 32 sublanes, so a block's codes are whole tiles and its scale
     row broadcasts across them. (Until PR 28 the scales were expanded by a
@@ -170,13 +178,20 @@ def _decode_kernel_at(layer_ref, *refs, groups: int, fast: bool):
 # single steps (T=1), fused-chunk scan bodies, speculative verifies
 # (T=K+1, small) — the same rule as models.llama._OVERLAP_MAX_WIDTH.
 FUSED_MAX_M = 16
+# Widest dispatch the same kernel takes in its CHUNK regime (fast mode
+# only): the widest prefill bucket (runtime.engine.PREFILL_BUCKETS). Past
+# it the dequant amortizes over enough rows for XLA's dequant + dot.
+CHUNK_MAX_M = 256
 
-# VMEM the decode kernel asks Mosaic for (the default scoped limit is 16 MB
-# of a v5e's 128), and what its resident set may take of that: the wd
-# scratch, the double-buffered code and scale stripes, the widened scales
-# and the full-K activation block, with room left for Mosaic's own.
+# VMEM the kernel asks Mosaic for (the default scoped limit is 16 MB of a
+# v5e's 128), and what its resident set may take of that: the wd scratch,
+# the double-buffered code and scale stripes, the widened scales and the
+# full-K activation block, with room left for Mosaic's own. A chunk's
+# activation block alone is 7 MB at K = 14336, so its regime asks for more.
 _FUSED_VMEM_LIMIT = 32 * 1024 * 1024
 _FUSED_VMEM_BUDGET = 20 * 1024 * 1024
+_CHUNK_VMEM_LIMIT = 96 * 1024 * 1024
+_CHUNK_VMEM_BUDGET = 64 * 1024 * 1024
 
 
 def _decode_blocks(M: int, K: int, N: int,
@@ -185,8 +200,17 @@ def _decode_blocks(M: int, K: int, N: int,
     doesn't fit: bn is the largest 128-multiple (or whole-N, >=8-aligned)
     dividing N whose resident set fits the VMEM budget (on the chip 256 and
     512 read alike and 1024 reads worse: PERF.md, PR 28); groups the Q40
-    blocks dequantized per loop trip."""
-    if not (0 < M <= FUSED_MAX_M) or K % Q40_BLOCK_SIZE:
+    blocks dequantized per loop trip. Rows 1..``FUSED_MAX_M`` are the
+    decode regime; ``FUSED_MAX_M`` + 1..``CHUNK_MAX_M`` the chunk regime,
+    fast mode's alone (exact mode's f32 ``wd`` would double the stripe, and
+    its wide dispatches keep the tiled kernel the goldens were taken with)."""
+    if M <= 0 or K % Q40_BLOCK_SIZE:
+        return None
+    if M <= FUSED_MAX_M:
+        budget, chunk = _FUSED_VMEM_BUDGET, False
+    elif fast and M <= CHUNK_MAX_M:
+        budget, chunk = _CHUNK_VMEM_BUDGET, True
+    else:
         return None
     kb = K // Q40_BLOCK_SIZE
     groups = next(c for c in (8, 4, 2, 1) if kb % c == 0)
@@ -197,21 +221,37 @@ def _decode_blocks(M: int, K: int, N: int,
             continue
         # wd + 2x codes, and per scale row: 2x stored (<= f32) + widened
         resident = K * bn * (wd_bytes + 2) + kb * bn * 12 + x_bytes
-        if resident <= _FUSED_VMEM_BUDGET:
+        if chunk:
+            # at chunk width the activation's second buffer and the
+            # double-buffered f32 output block are no longer small change
+            resident += x_bytes + 2 * M * bn * 4
+        if resident <= budget:
             return bn, groups
     return None
 
 
 # dlint: static-fn (shape gate; w may carry ShapeDtypeStruct leaves)
-def supports_decode(x_shape: tuple[int, ...], w: QuantizedWeight,
-                    fast: bool = False) -> bool:
-    """Whether the decode-shaped fused kernel covers these shapes."""
+def fused_path(x_shape: tuple[int, ...], w: QuantizedWeight,
+               fast: bool = False) -> str | None:
+    """Which regime of the full-K fused kernel covers these shapes, by the
+    name :func:`~dllama_tpu.runtime.introspection.note_q40_path` files it
+    under: ``"fused"`` (1..``FUSED_MAX_M`` flattened rows), ``"chunk"``
+    (up to ``CHUNK_MAX_M``, fast mode) or None."""
     K = x_shape[-1]
     M = 1
     for d in x_shape[:-1]:
         M *= d
-    return (w.codes.ndim == 2 and w.in_features == K
-            and _decode_blocks(M, K, w.out_features, fast) is not None)
+    if (w.codes.ndim != 2 or w.codes.shape[0] != K
+            or _decode_blocks(M, K, w.codes.shape[1], fast) is None):
+        return None
+    return "fused" if M <= FUSED_MAX_M else "chunk"
+
+
+# dlint: static-fn (shape gate; w may carry ShapeDtypeStruct leaves)
+def supports_decode(x_shape: tuple[int, ...], w: QuantizedWeight,
+                    fast: bool = False) -> bool:
+    """Whether the fused kernel's DECODE regime covers these shapes."""
+    return fused_path(x_shape, w, fast) == "fused"
 
 
 def _decode_call(xf: jax.Array, w: QuantizedWeight, *, interpret: bool,
@@ -261,7 +301,8 @@ def _decode_call(xf: jax.Array, w: QuantizedWeight, *, interpret: bool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_FUSED_VMEM_LIMIT),
+            vmem_limit_bytes=(_FUSED_VMEM_LIMIT if M <= FUSED_MAX_M
+                              else _CHUNK_VMEM_LIMIT)),
         interpret=interpret,
     )(*prefetch, xf, w.codes, w.scales)
 
@@ -303,15 +344,15 @@ def quant_matmul(x: jax.Array, w: QuantizedWeight, *, interpret: bool = False,
     the XLA exact path). ``fast=True``: bf16 operands, one MXU pass, f32
     accumulation (see _kernel). Leading dims flatten into M.  ``bn``/``bk``
     override the tile picks (tools/gemv_sweep.py measures the candidates).
-    ``fused=True`` prefers the decode-shaped full-K kernel
-    (:func:`_decode_kernel` — bit-parity with the XLA fused-dequant
-    reference) when :func:`supports_decode` holds, falling back to the
-    (n, k)-tiled kernel otherwise, so a ``fused``-mode dispatch never
-    fails on a prefill-wide shape. ``layer`` (an int32 scalar) says that
-    ``w`` is the layer STACK, leading axis = layer, and picks one: the
-    decode kernel's stack-and-index entry (:func:`_decode_call`), for
-    callers that checked :func:`supports_decode` on one layer's shapes —
-    there is no tiled twin, so anything else raises.
+    ``fused=True`` prefers the full-K kernel (:func:`_decode_kernel` —
+    bit-parity with the XLA fused-dequant reference) in either of its
+    regimes (:func:`fused_path`: up to 16 rows, or a fast-mode chunk of up
+    to 256), falling back to the (n, k)-tiled kernel otherwise, so a
+    ``fused``-mode dispatch never fails on a shape past both. ``layer`` (an
+    int32 scalar) says that ``w`` is the layer STACK, leading axis = layer,
+    and picks one: that kernel's stack-and-index entry
+    (:func:`_decode_call`), for callers that checked :func:`fused_path` on
+    one layer's shapes — there is no tiled twin, so anything else raises.
     """
     *lead, K = x.shape
     N = w.out_features
@@ -319,12 +360,12 @@ def quant_matmul(x: jax.Array, w: QuantizedWeight, *, interpret: bool = False,
     for d in lead:
         M *= d
 
-    decode_fits = (fused and bn is None and bk is None
-                   and _decode_blocks(M, K, N, fast) is not None)
-    if layer is not None and not decode_fits:
-        raise ValueError(f"the layer-stack entry is the decode kernel's: "
+    full_k = (fused and bn is None and bk is None
+              and _decode_blocks(M, K, N, fast) is not None)
+    if layer is not None and not full_k:
+        raise ValueError(f"the layer-stack entry is the fused kernel's: "
                          f"x {x.shape}, stack {w.codes.shape} do not fit it")
-    if decode_fits:
+    if full_k:
         # fast casts to bf16; exact keeps the activation dtype (the XLA
         # reference dequantizes at x.dtype — see _decode_call)
         xf = x.reshape(M, K)
@@ -422,8 +463,7 @@ def quant_matmul_sharded(plan, x: jax.Array, w: QuantizedWeight,
         scales=jax.ShapeDtypeStruct((k_loc // Q40_BLOCK_SIZE, n_loc), jnp.float32),
         codes=jax.ShapeDtypeStruct((k_loc, n_loc), jnp.int8))
     if not (supports((b_loc, T, k_loc), local_w)
-            or (fused
-                and supports_decode((b_loc, T, k_loc), local_w, fast))):
+            or (fused and fused_path((b_loc, T, k_loc), local_w, fast))):
         return None
 
     if k_ax is not None:
@@ -463,24 +503,29 @@ def pallas_mode_gate(fast: bool, x_shape: tuple[int, ...] | None = None,
     ``DLLAMA_TPU_QUANT_KERNEL`` = ``xla`` (the XLA dequant + dot reference,
     also the kill switch for every kernel this gate guards), ``pallas``
     (force the tiled kernel; interpret mode off-TPU, the test path),
-    ``fused`` (force the decode-shaped fused dequant-GEMV where it fits,
-    the tiled kernel where it does not), or ``auto``, resolved from what
+    ``fused`` (force the full-K fused kernel where it fits — up to 16
+    rows, or a fast-mode chunk of up to 256 — and the tiled kernel where
+    it does not), or ``auto``, resolved from what
     the dispatch shows:
 
     * off a TPU: no kernel.
     * exact mode (f32 graphs, the goldens): the tiled kernel, whose
       HIGHEST-precision dots match the host oracle.
-    * fast mode (bf16 graphs, serving): the fused dequant-GEMV for a
-      decode-shaped dispatch — ``x_shape`` flattens to 1..``FUSED_MAX_M``
-      rows, ``w`` is ONE 2-D Q40 plane pair whose stripe fits VMEM
-      (:func:`supports_decode`) and no mesh plan is active — and NO kernel
-      for anything else: a prefill chunk keeps the XLA dequant + dot and
-      never lands on the tiled kernel (130 GB/s against XLA's 450-750,
-      tools/gemv_sweep.py). There is no lower row bound: on the chip the
-      kernel beats XLA's dequant-then-dot at every M from 1 to 16 (the
-      sweep's table, PERF.md section 6, PR 28). Callers that pass no
-      shape (the sharded entry, the overlapped merge, wire pricing: all
-      under a plan) resolve as before: no kernel in fast mode.
+    * fast mode (bf16 graphs, serving): the fused full-K kernel where
+      :func:`fused_path` finds a regime for the dispatch — ``x_shape``
+      flattens to 1..``FUSED_MAX_M`` rows (a decode step: the dequant-GEMV)
+      or to ``CHUNK_MAX_M`` at most (a prefill chunk: the same body at
+      chunk width, so the dequantized plane stays in VMEM and never
+      crosses HBM), ``w`` is ONE 2-D Q40 plane pair whose stripe fits VMEM
+      and no mesh plan is active — and NO kernel for anything else (wider,
+      a plan, stacked expert planes, a width off the lane grid): those keep
+      the XLA dequant + dot and never land on the tiled kernel (130 GB/s
+      against XLA's 450-750, tools/gemv_sweep.py). There is no lower row
+      bound: on the chip the kernel beats XLA's dequant-then-dot at every
+      M from 1 to 16 (PERF.md section 6, PR 28) and at 32 to 256 (PR 35).
+      Callers that pass no shape (the sharded entry, the overlapped merge,
+      wire pricing: all under a plan) resolve as before: no kernel in fast
+      mode.
 
     Returns the :func:`quant_matmul` kwargs (``interpret``, optionally
     ``fused``) or None. Consulted by ops.linear's single-device and sharded
@@ -503,14 +548,14 @@ def pallas_mode_gate(fast: bool, x_shape: tuple[int, ...] | None = None,
     if not fast:
         return {"interpret": False}
     if (x_shape is not None and w is not None and current_plan() is None
-            and supports_decode(tuple(x_shape), w, True)):
+            and fused_path(tuple(x_shape), w, True)):
         return {"interpret": False, "fused": True}
     return None
 
 
 def wants_fused(kw: dict | None) -> bool:  # dlint: static-fn
-    """Whether a :func:`pallas_mode_gate` result selects the decode-shaped
-    fused kernel (trace-time env config, never a traced value)."""
+    """Whether a :func:`pallas_mode_gate` result selects the full-K fused
+    kernel (trace-time env config, never a traced value)."""
     return kw is not None and kw.get("fused", False) is True
 
 
@@ -525,7 +570,7 @@ def pallas_local_choice(x_shape: tuple[int, ...], w: QuantizedWeight,
     if kw is None:
         return None
     if not (supports(tuple(x_shape), w)
-            or (wants_fused(kw) and supports_decode(tuple(x_shape), w, fast))):
+            or (wants_fused(kw) and fused_path(tuple(x_shape), w, fast))):
         return None
     return kw
 

@@ -1455,6 +1455,11 @@ class PagedGenerator(_GeneratorCore):
             from ..parallel.sharding import paged_kv_sharding
 
             pkv = jax.device_put(pkv, paged_kv_sharding(engine.plan, pkv))
+        else:
+            # where every step will leave it (see _pin_home): a pool that
+            # starts elsewhere keys a second trace of whatever touches it
+            # before and after the first step
+            pkv = self._pin_home(pkv)
         self.pkv = pkv
         # a hybrid decoder's recurrent state, slot-indexed, beside the
         # blocks (kvblocks.StatePool has its rules: never shared, written
@@ -2183,20 +2188,35 @@ class PagedGenerator(_GeneratorCore):
     def _exec_take(self, bids: list[int]):
         table = np.full(self.table_width, self.pool.NULL, dtype=np.int32)
         table[:len(bids)] = bids
-        col = self._take(self.pkv, jnp.asarray(table))
-        # pin ONE canonical sharding on the gathered column: the prefill
-        # executable is keyed on its input shardings, and the pool cycles
-        # through jit outputs whose resolved sharding/commitment varies
-        # with the ops that produced them (copy-on-write vs step vs
-        # create) — without this, an identical-shape column could key a
-        # second forward executable AFTER steady state (a post-steady
-        # retrace = a latency cliff on TPU). device_put on a matching
-        # layout is a no-copy alias.
+        return self._pin_home(self._take(self.pkv, jnp.asarray(table)))
+
+    def _pin_home(self, col):
+        """Pin ONE canonical sharding on an admission's column (and on the
+        pool it is gathered from, at its creation): the prefill
+        executable is keyed on its input's sharding (and its trace on the
+        mesh that sharding names), and a column is either gathered from a
+        pool that cycles through jit outputs whose resolved
+        sharding/commitment varies with the ops that produced them
+        (copy-on-write vs step vs create), or is the previous chunk's
+        output, which takes the weights' mesh. Without the pin an
+        identical-shape column keys a second forward trace and executable
+        a bucket: AFTER steady state if the variant first shows up then (a
+        latency cliff on TPU), and in every warm-up otherwise (a second
+        walk of every bucket, 0.5-1.3 s each once a bucket carries Pallas
+        kernels: PERF.md section 6, PR 35). device_put on a matching
+        layout is a no-copy alias."""
         if self.eng.plan is not None:
             from ..parallel.sharding import kv_cache_sharding
 
             return jax.device_put(col, kv_cache_sharding(self.eng.plan, col))
-        s = jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
+        # no plan: where the weights live. Weights placed through a
+        # one-device mesh name that mesh in every output computed from
+        # them, a chunk's column included; a bare device otherwise
+        s = jax.tree.leaves(self.eng.params)[0].sharding
+        if isinstance(s, jax.sharding.NamedSharding):
+            s = jax.sharding.NamedSharding(s.mesh, jax.sharding.PartitionSpec())
+        else:
+            s = jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
         return jax.device_put(col, jax.tree.map(lambda _: s, col))
 
     def _exec_prefill(self, col, padded, pos: int, n_valid: int):
@@ -2211,7 +2231,7 @@ class PagedGenerator(_GeneratorCore):
                     self.eng.params, self.cfg,
                     jnp.asarray(np.asarray(padded).reshape(1, -1), jnp.int32),
                     jnp.int32(pos), col, *valid)
-            return col
+            return self._pin_home(col)
 
     def continue_admit(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
         """One admission step: drain a page-in batch (KV tier, resumed

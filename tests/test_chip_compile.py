@@ -150,6 +150,66 @@ def test_decode_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
     assert kernels.get("quant_matmul") == 1, kernels
 
 
+@pytest.mark.parametrize("rows", [64, 256])
+@pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B + OLMO_HYBRID_7B)
+def test_chunk_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
+    """The same kernel as a prefill chunk's ``forward`` calls it (PR 35):
+    a 64- or 256-row bucket of bf16 rows, the layer stack and a traced
+    index. K = 14336 with 256 rows resident is what the raised VMEM limit
+    is for; K = 3840 / 5760 / 11008 / 9728 / 2560 are whole-K blocks no
+    512-row tile divides; N = 17280 takes 128-wide stripes."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+    from dllama_tpu.ops.quant_matmul import fused_path, quant_matmul
+
+    L = 4
+    stack = QuantizedWeight(
+        scales=_shape(one_chip, (L, k // 32, n), jnp.bfloat16),
+        codes=_shape(one_chip, (L, k, n), jnp.int8))
+    one = QuantizedWeight(*(jax.ShapeDtypeStruct(p.shape[1:], p.dtype)
+                            for p in stack))
+    x = _shape(one_chip, (1, rows, k), jnp.bfloat16)
+    assert fused_path(x.shape, one, True) == "chunk"
+    kernels = _compiled_kernels(
+        lambda x, w, l: quant_matmul(x, w, interpret=False, fast=True,
+                                     fused=True, layer=l),
+        x, stack, _shape(one_chip, (), jnp.int32))
+    assert kernels.get("quant_matmul") == 1, kernels
+
+
+@pytest.mark.parametrize("rows", [17, 40, 128])
+def test_chunk_kernel_compiles_off_the_buckets_for_v5e(one_chip, rows):
+    """Row counts no bucket has (a speculative verify over several slots, a
+    pinned chunk) and a plane pair without the stack, at Mistral's w2."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+    from dllama_tpu.ops.quant_matmul import fused_path, quant_matmul
+
+    k, n = 14336, 4096
+    w = QuantizedWeight(scales=_shape(one_chip, (k // 32, n), jnp.bfloat16),
+                        codes=_shape(one_chip, (k, n), jnp.int8))
+    x = _shape(one_chip, (rows, k), jnp.bfloat16)
+    assert fused_path(x.shape, w, True) == "chunk"
+    kernels = _compiled_kernels(
+        functools.partial(quant_matmul, interpret=False, fast=True,
+                          fused=True), x, w)
+    assert kernels.get("quant_matmul") == 1, kernels
+
+
+def test_the_decode_regimes_picks_are_the_ones_pr28_measured():
+    """The chunk regime shares ``_decode_blocks``: rows 1..16 must still
+    get the stripe and the VMEM limit their programs were measured with."""
+    from dllama_tpu.ops import quant_matmul as qm
+
+    narrow = {(14336, 4096): 256, (9728, 2560): 256, (3840, 17280): 128,
+              (5760, 3840): 256, (3840, 3840): 256, (3840, 11008): 256,
+              (11008, 3840): 256, (2560, 151936): 128}   # the rest: 512
+    for k, n in MISTRAL_7B + QWEN3_4B + OLMO_HYBRID_7B + HEADS:
+        want = (narrow.get((k, n), 512), 4 if k == 5760 else 8)
+        for rows in (1, 4, 16):
+            assert qm._decode_blocks(rows, k, n, True) == want, (k, n, rows)
+    assert qm._FUSED_VMEM_LIMIT == 32 * 1024 * 1024
+    assert qm.FUSED_MAX_M == 16
+
+
 @pytest.mark.parametrize("rows", [4, 16])
 @pytest.mark.parametrize("k,n", HEADS)
 def test_decode_kernel_compiles_at_the_heads_for_v5e(one_chip, k, n, rows):

@@ -109,11 +109,11 @@ def test_q40_path_counts_per_program_line_and_gauge(model_files, capsys):
     assert introspection.q40_paths_line(scope) == ""   # nothing traced yet
     e.generate("hello world", 3, stop_on_eos=False)
     paths = introspection.ledger().q40_paths(scope)
-    assert paths["greedy_step"] == {"fused": 0, "tiled": 0, "xla": 8}
-    assert paths["forward"] == {"fused": 0, "tiled": 0, "xla": 8}
+    assert paths["greedy_step"] == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 8}
+    assert paths["forward"] == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 8}
     line = introspection.q40_paths_line(scope)
     assert line.startswith("🧮 q40 matmuls: ")
-    assert "greedy_step 0 fused / 0 tiled / 8 xla" in line
+    assert "greedy_step 0 chunk / 0 fused / 0 tiled / 8 xla" in line
     g = telemetry.registry().gauge(telemetry.Q40_MATMUL_PATHS)
     assert g.value(scope=scope, program="greedy_step", path="xla") == 8
     assert g.value(scope=scope, program="greedy_step", path="fused") == 0
@@ -138,7 +138,7 @@ def test_aot_lowering_counts_q40_paths_too(model_files):
     out = []
     introspection.hbm_startup_report(e, emit=out.append)
     line = introspection.q40_paths_line(e.introspection_scope)
-    assert "greedy_step 0 fused / 0 tiled / 8 xla" in line
+    assert "greedy_step 0 chunk / 0 fused / 0 tiled / 8 xla" in line
     assert line in out
     e.close()
 

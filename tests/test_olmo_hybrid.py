@@ -476,7 +476,9 @@ def test_converter_maps_the_config_and_says_it_has_no_tensor_map(tmp_path):
 def test_dense_decoders_compile_what_they_compiled():
     """The period scan and the ``ModelConfig`` changes leave the two dense
     configurations' programs as they were: digests of the lowered decode step
-    and prefill chunk, written from PR 30's parent commit."""
+    and prefill chunk, written from PR 30's parent commit (the chunk's again
+    by PR 35, whose ``forward`` walks the layer index at chunk width: the
+    step's two digests are still PR 30's parent's)."""
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     try:
         import dense_hlo_digest

@@ -404,12 +404,23 @@ def _gate_oracle(mode, fast, tpu, m, ndim, plan):
         return None
     if not fast:
         return ("tiled", False)
-    if 1 <= m <= 16 and ndim == 2 and not plan:
+    if 1 <= m <= 256 and ndim == 2 and not plan:
         return ("fused", False)
     return None
 
 
-@pytest.mark.parametrize("m", [1, 2, 4, 8, 16, 17, 256])
+def _path_oracle(fast, m, ndim):
+    """Which path a gate result that wants the fused kernel is counted
+    under (None: quant_matmul runs the tiled kernel, or nothing does)."""
+    if ndim != 2:
+        return None
+    if 1 <= m <= 16:
+        return "fused"
+    return "chunk" if fast and m <= 256 else None
+
+
+@pytest.mark.parametrize("m", [1, 2, 4, 8, 16, 17, 32, 64, 128, 256, 257,
+                               512])
 @pytest.mark.parametrize("plan", [False, True], ids=["noplan", "plan"])
 @pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "tpu"])
 @pytest.mark.parametrize("fast", [False, True], ids=["exact", "fast"])
@@ -417,12 +428,14 @@ def _gate_oracle(mode, fast, tpu, m, ndim, plan):
 def test_gate_truth_table(monkeypatch, mode, fast, tpu, plan, m):
     """mode x fast x platform x M x 2-D/3-D weight x plan/no plan. The rows
     that matter: fast ``auto`` on a TPU is the fused kernel at EVERY M from
-    1 to 16 (no lower bound: 2 and 4 engage like 16) and nothing at 17 or
-    256 — a prefill chunk must not land on the tiled kernel by this rule."""
+    1 to 16 (no lower bound: 2 and 4 engage like 16), counted ``fused``,
+    and at every M from 17 to 256 (a prefill chunk), counted ``chunk``;
+    nothing at 257 or 512, under a plan or over a stack of experts — and
+    never the tiled kernel by this rule. Exact mode has no chunk regime."""
     from contextlib import nullcontext
 
     from dllama_tpu.ops import quant_matmul as qm
-    from dllama_tpu.ops.linear import _pallas_wanted
+    from dllama_tpu.ops.linear import _fused_path, _pallas_wanted
     from dllama_tpu.parallel.api import make_tp_mesh, use_plan
 
     monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", mode)
@@ -436,6 +449,11 @@ def test_gate_truth_table(monkeypatch, mode, fast, tpu, plan, m):
             got = None if kw is None else (
                 "fused" if qm.wants_fused(kw) else "tiled", kw["interpret"])
             assert got == want, (ndim, kw)
+            if want is not None and want[0] == "fused":
+                assert _fused_path(kw, x, w, fast) == _path_oracle(
+                    fast, m, ndim)
+            else:
+                assert _fused_path(kw, x, w, fast) is None
             # what linear()'s plain path makes of it: a kernel only where a
             # kernel covers the shape, and never tiled for fast auto
             plain = _pallas_wanted(x, w, fast)
@@ -456,6 +474,12 @@ def test_auto_has_no_row_floor():
     assert all(qm.supports_decode((m, 4096), w, True)
                for m in range(1, qm.FUSED_MAX_M + 1))
     assert not qm.supports_decode((qm.FUSED_MAX_M + 1, 4096), w, True)
+    # ... and none between FUSED_MAX_M and CHUNK_MAX_M off the chunk regime
+    assert not hasattr(qm, "CHUNK_MIN_M")
+    assert all(qm.fused_path((m, 4096), w, True) == "chunk"
+               for m in range(qm.FUSED_MAX_M + 1, qm.CHUNK_MAX_M + 1))
+    assert qm.fused_path((qm.CHUNK_MAX_M + 1, 4096), w, True) is None
+    assert qm.fused_path((qm.FUSED_MAX_M + 1, 4096), w, False) is None
 
 
 def _stack(n_layers, out, in_, seed):
@@ -522,7 +546,7 @@ def test_linear_layer_slice_matches_the_plain_slice(monkeypatch, mode, path):
         np.testing.assert_array_equal(
             np.asarray(f(x, stack, jnp.int32(l))), np.asarray(linear(x, w)))
     counts = introspection.ledger().q40_paths(scope)["p"]
-    assert counts == {"fused": 0, "tiled": 0, "xla": 0, path: 1}
+    assert counts == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 0, path: 1}
 
 
 def test_layer_slice_under_a_plan_takes_the_slice(monkeypatch):
@@ -590,8 +614,9 @@ def test_dense_forward_scans_the_layer_index_for_decode_shapes(monkeypatch, t,
     """``forward`` (the dense slot pool: ``inference``, ``greedy_step``)
     walks the layer index for a decode-shaped dispatch, so its Q40 planes
     reach linear() as stack + index and the forced fused mode reads them
-    in place; a prefill-wide dispatch scans the stack as ever (forced
-    ``fused`` then falls to the tiled kernel on the slice). Same logits and
+    in place; a chunk-wide dispatch walks the index too, and in EXACT mode
+    (this f32 graph) the fused kernel has no regime for it, so forced
+    ``fused`` falls to the tiled kernel on the slice. Same logits and
     cache as the XLA mode either way."""
     from dllama_tpu.formats.mfile import ArchType, RopeType
     from dllama_tpu.models import ModelConfig, init_random_params
@@ -618,9 +643,169 @@ def test_dense_forward_scans_the_layer_index_for_decode_shapes(monkeypatch, t,
 
     want, kv_x, paths_x = run("xla")
     got, kv_k, paths_k = run("fused")
-    assert paths_x == {"fused": 0, "tiled": 0, "xla": 8}
-    assert paths_k == {"fused": 0, "tiled": 0, "xla": 0, path: 8}
+    assert paths_x == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 8}
+    assert paths_k == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 0, path: 8}
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(kv_k.k), np.asarray(kv_x.k),
                                rtol=1e-5, atol=1e-6)
+
+
+# --- the chunk regime of the fused kernel (PR 35): 17..256 rows, fast mode ---
+
+# reduced copies of the benchmark configurations' plane shapes [K, N]: the
+# widths cut by 8 (or so) and kept on the 128-lane grid, the oddities kept:
+# K = 640 / 480 / 1376 are 20 / 15 / 43 Q40 blocks (512 divides none of
+# them), and the hybrid's packed q k v z plane keeps its own 17280 columns
+# (135 x 128: only a 128-wide stripe divides it)
+CHUNK_SHAPES = {
+    "mistral-wq": (512, 512), "mistral-wk": (512, 128),
+    "mistral-w1": (512, 1792), "mistral-w2": (1792, 512),
+    "qwen3-wq": (640, 1024), "qwen3-wo": (1024, 640),
+    "qwen3-w2": (2432, 640),
+    "hybrid-qkvz": (480, 17280), "hybrid-w2": (1376, 384),
+}
+
+
+def _planes(k, n, seed, lead=()):
+    """Random Q40 planes as a fast-mode load holds them (bf16 scales),
+    made directly: quantizing a dense [17280, 480] on the host is slow."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    rng = np.random.default_rng(seed)
+    return QuantizedWeight(
+        scales=jnp.asarray(rng.uniform(0.001, 0.011, lead + (k // 32, n)),
+                           jnp.bfloat16),
+        codes=jnp.asarray(rng.integers(-8, 8, lead + (k, n)), jnp.int8))
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["plane", "stack"])
+@pytest.mark.parametrize("m", [17, 32, 64, 128, 256])
+@pytest.mark.parametrize("shape", sorted(CHUNK_SHAPES))
+def test_chunk_kernel_matches_dequant_then_dot(shape, m, stacked):
+    """The chunk regime against ``dequantize_weight`` + ``dot_general``
+    (what linear() falls back to) at every bucket width and one off the
+    buckets, handed a plane pair or the layer stack and a traced index."""
+    from dllama_tpu.ops import quant_matmul as qm
+
+    k, n = CHUNK_SHAPES[shape]
+    x = jnp.asarray(np.random.default_rng(m).standard_normal((m, k)),
+                    jnp.bfloat16)
+    if stacked:
+        stack = _planes(k, n, seed=k + n, lead=(3,))
+        w = type(stack)(*(p[2] for p in stack))
+        assert qm.fused_path((m, k), w, True) == "chunk"
+        got = jax.jit(lambda x, s, l: qm._decode_call(
+            x, s, interpret=True, fast=True, layer=l))(x, stack, jnp.int32(2))
+    else:
+        w = _planes(k, n, seed=k + n)
+        assert qm.fused_path((m, k), w, True) == "chunk"
+        got = qm._decode_call(x, w, interpret=True, fast=True)
+    assert got.shape == (m, n) and got.dtype == jnp.float32
+    _assert_bit_parity(got, _xla_fused_dequant(x, w, fast=True))
+
+
+@pytest.mark.parametrize("scales", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16-scales", "f32-scales"])
+def test_chunk_kernel_dequantizes_the_tile_bit_for_bit(scales):
+    """An identity activation reads the ``wd`` scratch out: every entry is
+    one product and 255 zeros, so the f32 output IS the dequantized tile,
+    and it equals ``dequantize_weight(w, bfloat16)`` bit for bit (the scale
+    rounded to bf16 first, the product rounded once)."""
+    from dllama_tpu.ops import quant_matmul as qm
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    k, n = 256, 384
+    w = _mk(n, k, seed=5)
+    w = QuantizedWeight(w.scales.astype(scales), w.codes)
+    got = qm._decode_call(jnp.eye(k, dtype=jnp.bfloat16), w, interpret=True,
+                          fast=True)
+    want = dequantize_weight(w, jnp.bfloat16)
+    assert np.any(np.asarray(want, np.float32) != 0)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(want, np.float32))
+
+
+def test_chunk_regime_is_fast_modes_alone_and_ends_at_256_rows():
+    """quant_matmul(fused=True) past either bound runs the tiled kernel
+    (exact mode keeps what the goldens were taken with), and the stack
+    entry refuses such a dispatch instead of reading layer 0."""
+    w = _mk(256, 512, seed=31)
+    _, stack = _stack(2, 256, 512, seed=95)
+    for m, fast in ((32, False), (257, True)):
+        x = jnp.asarray(np.random.default_rng(m).standard_normal((m, 512)),
+                        jnp.float32)
+        np.testing.assert_array_equal(
+            np.asarray(quant_matmul(x, w, interpret=True, fused=True,
+                                    fast=fast)),
+            np.asarray(quant_matmul(x, w, interpret=True, fast=fast)))
+        with pytest.raises(ValueError, match="layer-stack entry"):
+            quant_matmul(x, stack, interpret=True, fused=True, fast=fast,
+                         layer=jnp.int32(1))
+
+
+@pytest.mark.parametrize("mode,path", [("xla", "xla"), ("fused", "chunk")])
+def test_linear_layer_slice_at_chunk_width(monkeypatch, mode, path):
+    """linear() over a LayerSlice of 64 bf16 rows: the fused mode reads the
+    stack through the index and counts the dispatch as ``chunk``."""
+    from dllama_tpu.ops.linear import LayerSlice, QuantizedWeight
+    from dllama_tpu.runtime import introspection
+
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", mode)
+    stack = _planes(512, 256, seed=3, lead=(3,))
+    x = jnp.asarray(np.random.default_rng(8).standard_normal((1, 64, 512)),
+                    jnp.bfloat16)
+    scope = f"layer-slice-chunk-{mode}"
+    f = introspection.observe(
+        jax.jit(lambda x, s, l: linear(x, LayerSlice(s, l))),
+        scope=scope, program="p")
+    for l in range(3):
+        w = QuantizedWeight(*(p[l] for p in stack))
+        want = _xla_fused_dequant(x, w, fast=True).astype(jnp.bfloat16)
+        np.testing.assert_array_equal(
+            np.asarray(f(x, stack, jnp.int32(l)), np.float32),
+            np.asarray(want, np.float32))
+    counts = introspection.ledger().q40_paths(scope)["p"]
+    assert counts == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 0, path: 1}
+
+
+@pytest.mark.parametrize("t", [64, 256])
+def test_dense_forward_takes_the_chunk_kernel_at_bucket_widths(monkeypatch, t):
+    """``forward`` over a bf16 graph at a 64- and a 256-row bucket: it walks
+    the layer index, every Q40 dispatch (seven a layer and this toy's
+    quantized head) reaches the fused kernel's chunk regime through the
+    stack entry, none falls back to XLA, and the logits and the cache are
+    the XLA mode's to bf16 rounding."""
+    from dllama_tpu.formats.mfile import ArchType, RopeType
+    from dllama_tpu.models import ModelConfig, init_random_params
+    from dllama_tpu.models.llama import forward
+    from dllama_tpu.runtime import introspection
+    from dllama_tpu.runtime.kvcache import KVCache
+
+    cfg = ModelConfig(arch=ArchType.LLAMA, dim=128, hidden_dim=256,
+                      n_layers=3, n_heads=8, n_kv_heads=2, head_dim=16,
+                      vocab_size=256, seq_len=320, norm_epsilon=1e-5,
+                      rope_theta=10000.0, rope_type=RopeType.LLAMA,
+                      compute_dtype="bfloat16")
+    params = init_random_params(cfg, seed=11, quantized=True)
+    tokens = jnp.asarray(
+        np.random.default_rng(t).integers(1, 255, (1, t)).astype(np.int32))
+
+    def run(mode):
+        monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", mode)
+        scope = f"dense-forward-chunk-{t}-{mode}"
+        f = introspection.observe(
+            jax.jit(lambda p, c, tk, s, kv: forward(p, c, tk, s, kv),
+                    static_argnums=1), scope=scope, program="forward")
+        logits, kv = f(params, cfg, tokens, jnp.int32(3), KVCache.create(cfg))
+        return logits, kv, introspection.ledger().q40_paths(scope)["forward"]
+
+    want, kv_x, paths_x = run("xla")
+    got, kv_k, paths_k = run("fused")
+    assert paths_x == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 8}
+    assert paths_k == {"chunk": 8, "fused": 0, "tiled": 0, "xla": 0}
+    scale = float(np.abs(np.asarray(want)).max())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=3e-2 * scale)
+    np.testing.assert_allclose(np.asarray(kv_k.k, np.float32),
+                               np.asarray(kv_x.k, np.float32), atol=3e-2)

@@ -1,4 +1,4 @@
-"""On-chip sweep of decode-GEMV kernel variants (honest slope timing).
+"""On-chip sweep of the Q40 matmul kernels (honest slope timing).
 
 Two sweeps over Q40 planes (int8 codes + block scales, one byte a weight on
 the device) against a v5e's 819 GB/s, reported as GB/s of quantized bytes:
@@ -24,16 +24,22 @@ the per-op cost is the SLOPE, which cancels the RTT and any fixed
 dispatch/loop overhead.
 
 Usage:  python tools/gemv_sweep.py [n_lo] [n_hi] [--json] [--variants]
-                                    [--rows 1,2,4,8,16] [--models mistral-7b,qwen3-4b]
+                                    [--rows 1,2,4,8,16,32,64,128,256]
+                                    [--models mistral-7b,qwen3-4b,olmo-hybrid-7b]
 
 The default run is the ROW SWEEP, the evidence under ``auto``'s fast-mode
-rule (ops/quant_matmul.pallas_mode_gate; table in PERF.md): for every Q40
-plane shape of a layer of Mistral-7B-v0.3 and Qwen3-4B and every M in
-``--rows``, the XLA dequant + dot against the fused dequant-GEMV, each both
+rule (ops/quant_matmul.pallas_mode_gate; tables in PERF.md): for every Q40
+plane shape of a layer of Mistral-7B-v0.3, Qwen3-4B and Olmo-Hybrid-7B and
+every M in ``--rows`` (1-16: a decode step; 32-256: a prefill chunk's
+buckets), the XLA dequant + dot against the fused full-K kernel, each both
 ways a layer scan can hand it the weight: one 2-D plane pair, or a stack of
 layers — XLA slicing layer ``i % L`` out of it (what a scan's ``xs`` slice
 is; before a custom call it is a copy), the kernel taking the stack and the
-index (quant_matmul's ``layer`` entry). ``--variants`` runs the older
+index (quant_matmul's ``layer`` entry). **Read the stack rows**: a 2-D
+plane is loop-invariant here, so XLA hoists its dequant out of the timing
+loop and the plain ``xla`` row times a dense bf16 dot — a floor no model
+program reaches, since a layer scan hands it another layer every time.
+Rows of 32 and more also print TFLOP/s. ``--variants`` runs the older
 M = 1 exploration instead (tile picks, packed codes, s8 x s8, ...).
 
 ``--json`` prints ONE machine-readable JSON line: ``{"tool": "gemv_sweep",
@@ -52,13 +58,17 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-# the Q40 planes of one layer, [K, N], at the benchmark's two configurations
-# (benchmark/configs): wq | wo, wk | wv, w1 | w3, w2 (Qwen3-4B's q width is
-# 4096 over a 2560 model dim, so its wq and wo differ)
+# the Q40 planes of one layer, [K, N], at the benchmark's dense and hybrid
+# configurations (benchmark/configs): wq | wo, wk | wv, w1 | w3, w2
+# (Qwen3-4B's q width is 4096 over a 2560 model dim, so its wq and wo
+# differ); the hybrid's: the mixer's packed q k v z plane (17280 = 135 x
+# 128) and its output plane, q | k | v | wo of a full layer, w1 | w3, w2
 LAYER_SHAPES = {
     "mistral-7b": ((4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096)),
     "qwen3-4b": ((2560, 4096), (2560, 1024), (4096, 2560), (2560, 9728),
                  (9728, 2560)),
+    "olmo-hybrid-7b": ((3840, 17280), (5760, 3840), (3840, 3840),
+                       (3840, 11008), (11008, 3840)),
 }
 STACK_LAYERS = 4  # enough that no layer's planes stay in VMEM between uses
 VARIANT_SHAPES = ((2048, 8192), (4096, 14336), (2048, 128256))  # --variants
@@ -77,7 +87,8 @@ def main() -> None:
     variants = "--variants" in argv
     n_lo = int(args[0]) if len(args) > 0 else 64
     n_hi = int(args[1]) if len(args) > 1 else 448
-    sweep_rows = [int(m) for m in opts.get("--rows", "1,2,4,8,16").split(",")]
+    sweep_rows = [int(m) for m in opts.get(
+        "--rows", "1,2,4,8,16,32,64,128,256").split(",")]
     models = opts.get("--models", ",".join(LAYER_SHAPES)).split(",")
     import jax
     import jax.numpy as jnp
@@ -107,9 +118,11 @@ def main() -> None:
 
     shape_label = [""]  # current "K=..,N=.." tag for the JSON rows
 
-    def bench(label, op, x, *wargs, bytes_moved: int, indexed: bool = False):
+    def bench(label, op, x, *wargs, bytes_moved: int, indexed: bool = False,
+              flops: int = 0):
         """op(x, *wargs) -> y [M, N]; loop it on device, slope-time it.
-        ``indexed`` ops take the iteration number first (a layer index)."""
+        ``indexed`` ops take the iteration number first (a layer index);
+        ``flops`` (a chunk-wide dispatch) adds a TFLOP/s column."""
 
         @jax.jit
         def looped(n, x, *wargs):
@@ -141,9 +154,13 @@ def main() -> None:
                 row["error"] = "slope <= 0"
                 return None
             gbps = bytes_moved / per_op / 1e9
-            say(f"  {label:<28} {1e6 * per_op:9.1f} us  {gbps:7.1f} GB/s")
+            tflops = flops / per_op / 1e12
+            say(f"  {label:<28} {1e6 * per_op:9.1f} us  {gbps:7.1f} GB/s"
+                + (f"  {tflops:6.1f} TFLOP/s" if flops else ""))
             row["us"] = round(1e6 * per_op, 2)
             row["gbps"] = round(gbps, 1)
+            if flops:
+                row["tflops"] = round(tflops, 1)
             return per_op
         except Exception as e:  # noqa: BLE001
             say(f"  {label:<28} {type(e).__name__}: {str(e)[:70]}")
@@ -177,20 +194,24 @@ def main() -> None:
 
                     fused = functools.partial(qm.quant_matmul, fast=True,
                                               fused=True)
-                    bench("xla", xla, x, w, bytes_moved=nbytes)
+                    # the gate's own name for the regime: fused | chunk
+                    name = qm.fused_path((M, K), w, True)
+                    kw = {"bytes_moved": nbytes,
+                          "flops": 2 * M * K * N if M > qm.FUSED_MAX_M else 0}
+                    bench("xla", xla, x, w, **kw)
                     bench("xla, stack slice",
                           lambda i, x, s: xla(x, take(i, s)), x, stack,
-                          bytes_moved=nbytes, indexed=True)
-                    if not qm.supports_decode((M, K), w, True):
+                          indexed=True, **kw)
+                    if name is None:
                         continue
-                    bench("fused", fused, x, w, bytes_moved=nbytes)
-                    bench("fused, stack slice",
+                    bench(name, fused, x, w, **kw)
+                    bench(f"{name}, stack slice",
                           lambda i, x, s: fused(x, take(i, s)), x, stack,
-                          bytes_moved=nbytes, indexed=True)
-                    bench("fused, stack + index",
+                          indexed=True, **kw)
+                    bench(f"{name}, stack + index",
                           lambda i, x, s: fused(
                               x, s, layer=(i % L).astype(jnp.int32)),
-                          x, stack, bytes_moved=nbytes, indexed=True)
+                          x, stack, indexed=True, **kw)
 
     if not variants:
         row_sweep()
