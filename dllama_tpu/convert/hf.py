@@ -34,6 +34,7 @@ ARCH_BY_MODEL_TYPE = {
     "qwen3_moe": ArchType.QWEN3,
     "olmo_hybrid": ArchType.OLMO_HYBRID,
     "laguna": ArchType.LAGUNA,
+    "falcon_h1": ArchType.FALCON_H1,
 }
 
 HIDDEN_ACT_BY_NAME = {"gelu": HiddenAct.GELU, "silu": HiddenAct.SILU}
@@ -156,7 +157,9 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
     if model_type == "laguna":
         params.update(_laguna_header(cfg))
 
-    if cfg.get("rope_theta") is not None:
+    if model_type == "falcon_h1":
+        params.update(_falcon_h1_header(cfg))
+    elif cfg.get("rope_theta") is not None:
         params["rope_theta"] = int(cfg["rope_theta"])
 
     rs = cfg.get("rope_scaling")
@@ -216,6 +219,54 @@ def _olmo_hybrid_header(cfg: dict) -> dict:
             f"olmo_hybrid: rope_parameters.rope_theta is {rope!r}; this "
             f"architecture's full layers carry no rotary embedding here")
     return out
+
+
+def _falcon_h1_header(cfg: dict) -> dict:
+    """``model_type: falcon_h1``'s config keys as the header's extension
+    keys (formats/mfile.py, HeaderKey 39-59): the ``mamba_*`` sizes, the
+    rotary base as a float (1e11 does not fit the integer key) and the
+    fourteen multipliers. What the layer equation here does not carry is
+    refused (a projection bias, a mixer whose norm is absent or comes
+    before the gate, attention in some layers only)."""
+    if cfg.get("attention_bias") or cfg.get("mamba_proj_bias") \
+            or cfg.get("mlp_bias") or cfg.get("projectors_bias") \
+            or not cfg.get("mamba_conv_bias", True) \
+            or not cfg.get("mamba_rms_norm", True) \
+            or cfg.get("mamba_norm_before_gate") \
+            or cfg.get("attn_layer_indices") is not None \
+            or cfg.get("rope_scaling") is not None \
+            or cfg.get("tie_word_embeddings"):
+        raise ValueError(
+            "falcon_h1: projection biases, a convolution without its bias, "
+            "a mixer without its gated norm or with the norm before the "
+            "gate, attention in some layers only, rope scaling and tied "
+            "embeddings are not carried")
+    heads, hd = int(cfg["mamba_n_heads"]), int(cfg["mamba_d_head"])
+    if heads * hd != int(cfg["mamba_d_ssm"]):
+        raise ValueError(
+            f"falcon_h1: mamba_d_ssm {cfg['mamba_d_ssm']} is not "
+            f"{heads} heads of {hd}")
+    gate, down = cfg["mlp_multipliers"]
+    z, x, b, c, dt = cfg["ssm_multipliers"]
+    return {
+        "ssm_n_heads": heads, "ssm_head_dim": hd,
+        "ssm_n_groups": int(cfg["mamba_n_groups"]),
+        "ssm_state_dim": int(cfg["mamba_d_state"]),
+        "ssm_conv_kernel": int(cfg["mamba_d_conv"]),
+        "ssm_chunk_size": int(cfg["mamba_chunk_size"]),
+        "rope_theta_f32": float(cfg["rope_theta"]),
+        "embedding_mult": float(cfg["embedding_multiplier"]),
+        "lm_head_mult": float(cfg["lm_head_multiplier"]),
+        "attn_in_mult": float(cfg["attention_in_multiplier"]),
+        "attn_out_mult": float(cfg["attention_out_multiplier"]),
+        "key_mult": float(cfg["key_multiplier"]),
+        "ssm_in_mult": float(cfg["ssm_in_multiplier"]),
+        "ssm_out_mult": float(cfg["ssm_out_multiplier"]),
+        "mlp_gate_mult": float(gate), "mlp_down_mult": float(down),
+        "ssm_mult_z": float(z), "ssm_mult_x": float(x),
+        "ssm_mult_b": float(b), "ssm_mult_c": float(c),
+        "ssm_mult_dt": float(dt),
+    }
 
 
 def _laguna_header(cfg: dict) -> dict:
@@ -325,6 +376,14 @@ def hf_tensor_plan(params: dict) -> list[PlanItem]:
             "tensor names are not: they could not be read where this was "
             "written, and a guessed map is worse than none. The target "
             "layout is formats/mfile.py's _walk_laguna_layer")
+    if arch == ArchType.FALCON_H1:
+        raise NotImplementedError(
+            "falcon_h1: the header is mapped (load_hf_config), the "
+            "checkpoint's tensor names are not: they could not be read where "
+            "this was written, and a guessed map is worse than none. The "
+            "target layout is formats/mfile.py's _walk_falcon_h1_layer (the "
+            "published in_proj is split there: its last mamba_n_heads rows "
+            "are the float32 dt plane)")
     n_heads = params["n_heads"]
     n_kv_heads = params["n_kv_heads"]
 

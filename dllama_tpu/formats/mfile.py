@@ -115,6 +115,33 @@ class HeaderKey(enum.IntEnum):
     MOE_ROUTED_SCALE_MILLI = 36  # routed sum's scale, in thousandths
     MOE_ROUTER_WIDTH = 37        # experts the router scores (N_EXPERTS are HELD here)
     MOE_FIRST_EXPERT = 38        # the first held expert's index among them
+    # OUR format extension, read by ArchType.FALCON_H1 only
+    # (models/falcon_h1.py): the Mamba-2 (SSD) mixer's sizes, then what a
+    # 32-bit integer cannot say, each as the BITS of its float32: the
+    # rotary base (1e11 there) and the fourteen multipliers of the layer
+    # equation (``ssm_mult_*``: ``ssm_multipliers`` over the z, x, B, C and
+    # dt lanes of the in-projection).
+    SSM_N_HEADS = 39
+    SSM_HEAD_DIM = 40
+    SSM_N_GROUPS = 41
+    SSM_STATE_DIM = 42
+    SSM_CONV_KERNEL = 43
+    SSM_CHUNK_SIZE = 44
+    ROPE_THETA_F32 = 45
+    EMBEDDING_MULT = 46
+    LM_HEAD_MULT = 47
+    ATTN_IN_MULT = 48
+    ATTN_OUT_MULT = 49
+    KEY_MULT = 50
+    SSM_IN_MULT = 51
+    SSM_OUT_MULT = 52
+    MLP_GATE_MULT = 53
+    MLP_DOWN_MULT = 54
+    SSM_MULT_Z = 55
+    SSM_MULT_X = 56
+    SSM_MULT_B = 57
+    SSM_MULT_C = 58
+    SSM_MULT_DT = 59
 
 
 class ArchType(enum.IntEnum):
@@ -130,6 +157,10 @@ class ArchType(enum.IntEnum):
     # shared one; the experts, heads and vocabulary HELD may be one chip's
     # share of a deployment (models/laguna.py)
     LAGUNA = 0xABCD03
+    # ours: ONE homogeneous stack whose every layer runs a Mamba-2 (SSD)
+    # mixer and grouped-query attention side by side over one normed input
+    # and adds both to the residual (models/falcon_h1.py)
+    FALCON_H1 = 0xABCD04
 
 
 class RopeType(enum.IntEnum):
@@ -198,6 +229,43 @@ class ModelHeader:
     moe_routed_scale_milli: int = 1000
     moe_router_width: int = 0
     moe_first_expert: int = 0
+    # FALCON_H1 (HeaderKey 39-59); 0 / 1.0 for every other arch
+    ssm_n_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_n_groups: int = 0
+    ssm_state_dim: int = 0
+    ssm_conv_kernel: int = 0
+    ssm_chunk_size: int = 0
+    embedding_mult: float = 1.0
+    lm_head_mult: float = 1.0
+    attn_in_mult: float = 1.0
+    attn_out_mult: float = 1.0
+    key_mult: float = 1.0
+    ssm_in_mult: float = 1.0
+    ssm_out_mult: float = 1.0
+    mlp_gate_mult: float = 1.0
+    mlp_down_mult: float = 1.0
+    ssm_mult_z: float = 1.0
+    ssm_mult_x: float = 1.0
+    ssm_mult_b: float = 1.0
+    ssm_mult_c: float = 1.0
+    ssm_mult_dt: float = 1.0
+
+    @property
+    def ssm_inner_dim(self) -> int:
+        """The SSD mixer's width (``mamba_d_ssm``): heads x head width."""
+        return self.ssm_n_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the causal convolution runs over: x, B, C."""
+        return self.ssm_inner_dim + 2 * self.ssm_n_groups * self.ssm_state_dim
+
+    @property
+    def ssm_in_dim(self) -> int:
+        """Width of the mixer's packed Q40 input projection: z x B C (the
+        ``dt`` rows are a float32 plane of their own)."""
+        return self.ssm_inner_dim + self.ssm_conv_dim
 
     @property
     def linear_conv_dim(self) -> int:
@@ -247,7 +315,23 @@ _HYBRID_KEYS = {k: k.name.lower() for k in (
     HeaderKey.ROPE_THETA_SLIDING, HeaderKey.ROPE_DIM,
     HeaderKey.N_DENSE_LAYERS, HeaderKey.DENSE_HIDDEN_DIM,
     HeaderKey.SHARED_EXPERT_DIM, HeaderKey.MOE_ROUTED_SCALE_MILLI,
-    HeaderKey.MOE_ROUTER_WIDTH, HeaderKey.MOE_FIRST_EXPERT)}
+    HeaderKey.MOE_ROUTER_WIDTH, HeaderKey.MOE_FIRST_EXPERT,
+    HeaderKey.SSM_N_HEADS, HeaderKey.SSM_HEAD_DIM, HeaderKey.SSM_N_GROUPS,
+    HeaderKey.SSM_STATE_DIM, HeaderKey.SSM_CONV_KERNEL,
+    HeaderKey.SSM_CHUNK_SIZE)}
+# FALCON_H1's float keys: the value is a float32's bit pattern
+_F32_BITS_KEYS = {k: k.name.lower() for k in HeaderKey
+                  if HeaderKey.EMBEDDING_MULT <= k <= HeaderKey.SSM_MULT_DT}
+_F32_BITS_KEYS[HeaderKey.ROPE_THETA_F32] = "rope_theta"
+
+
+def f32_bits(x: float) -> int:
+    """A float32's bit pattern as the int32 a header value is."""
+    return struct.unpack("<i", struct.pack("<f", x))[0]
+
+
+def f32_from_bits(v: int) -> float:
+    return struct.unpack("<f", struct.pack("<i", v))[0]
 
 
 def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
@@ -308,6 +392,8 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
             h.norm_epsilon = _norm_epsilon_from_int(value)
         elif key in _HYBRID_KEYS:
             setattr(h, _HYBRID_KEYS[key], value)
+        elif key in _F32_BITS_KEYS:
+            setattr(h, _F32_BITS_KEYS[key], f32_from_bits(value))
         else:
             raise ValueError(f"unsupported header key {key}")
 
@@ -331,6 +417,20 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
                 f"divide {h.n_layers} layers into whole periods")
         if h.n_experts:
             raise ValueError("hybrid model: routed experts are unsupported")
+    if h.arch_type == ArchType.FALCON_H1:
+        h.rope_type = RopeType.FALCON
+        per_group = h.ssm_n_heads // max(1, h.ssm_n_groups)
+        if not (h.ssm_n_heads and h.ssm_head_dim and h.ssm_state_dim
+                and h.ssm_conv_kernel > 1 and h.ssm_chunk_size
+                and per_group * h.ssm_n_groups == h.ssm_n_heads):
+            raise ValueError(
+                f"falcon_h1 model: {h.ssm_n_heads} mixer heads of "
+                f"{h.ssm_head_dim} in {h.ssm_n_groups} groups, state "
+                f"{h.ssm_state_dim}, {h.ssm_conv_kernel} taps, chunks of "
+                f"{h.ssm_chunk_size}: every size must be set and the groups "
+                f"must divide the heads")
+        if h.n_experts:
+            raise ValueError("falcon_h1 model: routed experts are unsupported")
     if h.arch_type == ArchType.LAGUNA:
         h.rope_type = RopeType.YARN
         h.moe_router_width = h.moe_router_width or h.n_experts
@@ -479,6 +579,9 @@ class ModelFile:
             if h.arch_type == ArchType.LAGUNA:
                 off = self._walk_laguna_layer(l, off)
                 continue
+            if h.arch_type == ArchType.FALCON_H1:
+                off = self._walk_falcon_h1_layer(l, off)
+                continue
             off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
             off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
             off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
@@ -544,6 +647,37 @@ class ModelFile:
             off += self._add("block_matmul_wo", l, (h.dim, h.q_dim), wt, off)
             off += self._add("block_norm_q", l, (h.q_dim,), F32, off)
             off += self._add("block_norm_k", l, (h.kv_dim,), F32, off)
+        off += self._add("block_matmul_w1", l, (h.hidden_dim, h.dim), wt, off)
+        off += self._add("block_matmul_w2", l, (h.dim, h.hidden_dim), wt, off)
+        off += self._add("block_matmul_w3", l, (h.hidden_dim, h.dim), wt, off)
+        off += self._add("block_norm_0", l, (h.dim,), F32, off)
+        off += self._add("block_norm_1", l, (h.dim,), F32, off)
+        return off
+
+    def _walk_falcon_h1_layer(self, l: int, off: int) -> int:
+        """One layer of a FALCON_H1 file (OUR layout; the reference has
+        none): q k v wo; the SSD mixer's packed input projection (z x B C
+        rows, in that order: the published ``in_proj`` without its last
+        ``ssm_n_heads`` rows), those ``dt`` rows (F32), the convolution
+        taps ``[kernel, channels]`` and bias, ``A_log``, ``D``,
+        ``dt_bias``, the gated norm's weight over the mixer's width, the
+        output projection; w1 w2 w3 and the two block norms."""
+        h, wt = self.header, self.header.weight_type
+        nh = h.ssm_n_heads
+        off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
+        off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
+        off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
+        off += self._add("block_matmul_wo", l, (h.dim, h.q_dim), wt, off)
+        off += self._add("block_ssm_in", l, (h.ssm_in_dim, h.dim), wt, off)
+        off += self._add("block_ssm_dt", l, (nh, h.dim), F32, off)
+        off += self._add("block_ssm_conv", l,
+                         (h.ssm_conv_kernel, h.ssm_conv_dim), F32, off)
+        off += self._add("block_ssm_conv_bias", l, (h.ssm_conv_dim,), F32, off)
+        off += self._add("block_ssm_a_log", l, (nh,), F32, off)
+        off += self._add("block_ssm_d", l, (nh,), F32, off)
+        off += self._add("block_ssm_dt_bias", l, (nh,), F32, off)
+        off += self._add("block_ssm_norm", l, (h.ssm_inner_dim,), F32, off)
+        off += self._add("block_ssm_out", l, (h.dim, h.ssm_inner_dim), wt, off)
         off += self._add("block_matmul_w1", l, (h.hidden_dim, h.dim), wt, off)
         off += self._add("block_matmul_w2", l, (h.dim, h.hidden_dim), wt, off)
         off += self._add("block_matmul_w3", l, (h.hidden_dim, h.dim), wt, off)
@@ -778,7 +912,10 @@ def write_header(f, params: dict) -> None:
     """Write the .m header (reference: converter/writer.py:109-147)."""
     data = b""
     for key, value in params.items():
-        data += struct.pack("<ii", int(HeaderKey[key.upper()]), int(value))
+        k = HeaderKey[key.upper()]
+        # FALCON_H1's float keys take the float and store its float32 bits
+        data += struct.pack("<ii", int(k), f32_bits(value)
+                            if k in _F32_BITS_KEYS else int(value))
     f.write(struct.pack("<i", MODEL_MAGIC))
     f.write(struct.pack("<i", 8 + len(data)))
     f.write(data)

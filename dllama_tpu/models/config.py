@@ -8,8 +8,33 @@ layout) that have no reference equivalent.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from typing import NamedTuple
 
 from ..formats.mfile import ArchType, HiddenAct, ModelHeader, RopeType
+
+
+class Multipliers(NamedTuple):
+    """The fixed scalars of ``ArchType.FALCON_H1``'s layer equation
+    (models/falcon_h1.py says where each is applied): the published
+    ``embedding_multiplier``, ``lm_head_multiplier``, ``attention_in/out_
+    multiplier``, ``key_multiplier``, ``ssm_in/out_multiplier``,
+    ``mlp_multipliers`` (gate, down) and ``ssm_multipliers`` (over the z, x,
+    B, C and dt lanes of the mixer's in-projection)."""
+
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    attn_in: float = 1.0
+    attn_out: float = 1.0
+    key: float = 1.0
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    mlp_gate: float = 1.0
+    mlp_down: float = 1.0
+    ssm_z: float = 1.0
+    ssm_x: float = 1.0
+    ssm_b: float = 1.0
+    ssm_c: float = 1.0
+    ssm_dt: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -75,6 +100,21 @@ class ModelConfig:
     moe_routed_scale: float = 1.0
     moe_router_width: int = 0
     moe_first_expert: int = 0
+    # A Mamba-2 (SSD) mixer and grouped-query attention SIDE BY SIDE in
+    # every layer of one homogeneous stack (ArchType.FALCON_H1,
+    # models/falcon_h1.py): ``ssm_heads`` heads of ``ssm_head_dim`` in
+    # ``ssm_groups`` groups that share B and C, a state of ``ssm_state_dim``
+    # a lane, ``ssm_conv_kernel`` taps, sub-chunks of ``ssm_chunk`` in the
+    # chunk form; ``mult`` are the equation's fixed scalars. The arch
+    # implies: pre-norm, no q/k norm, the half-split rotary over the whole
+    # head, the mixer's gate before its grouped RMS norm.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state_dim: int = 0
+    ssm_conv_kernel: int = 0
+    ssm_chunk: int = 0
+    mult: Multipliers | None = None
 
     # TPU execution choices (no reference equivalent):
     compute_dtype: str = "float32"  # "float32" for parity, "bfloat16" for speed
@@ -142,14 +182,14 @@ class ModelConfig:
         more than one list of K/V blocks (a recurrent state, a second pool),
         which the dense slot pool and the single-sequence path do not
         carry."""
-        return self.is_hybrid or self.has_window_layers
+        return self.has_state or self.has_window_layers
 
     @property
     def prefix_reuse_skipped(self) -> str | None:
         """Why a matched prefix block is passed over (the label of
         ``dllama_prefix_reuse_skipped_total``), or None where blocks are
         shared."""
-        if self.is_hybrid:
+        if self.has_state:
             return "recurrent_state"
         return "window_layers" if self.has_window_layers else None
 
@@ -164,9 +204,61 @@ class ModelConfig:
 
     @property
     def is_hybrid(self) -> bool:
-        """Recurrent (linear-attention) layers beside the full ones: a
-        slot's context is K/V blocks AND a state row (runtime/kvblocks)."""
+        """Gated delta-rule (linear-attention) layers beside the full ones
+        in a periodic pattern: models/hybrid.py's two stacks."""
         return self.lin_heads > 0
+
+    @property
+    def has_ssm(self) -> bool:
+        """An SSD mixer beside attention in every layer
+        (models/falcon_h1.py)."""
+        return self.ssm_heads > 0
+
+    @property
+    def has_state(self) -> bool:
+        """Layers with a recurrent state: a slot's context is K/V blocks
+        AND a row of the state pool (runtime/kvblocks.StatePool), whichever
+        architecture owns the state's shape."""
+        return self.is_hybrid or self.has_ssm
+
+    @property
+    def n_state_layers(self) -> int:
+        """Layers that own a row of the state pool: a hybrid's linear ones,
+        or every layer where the mixer sits beside attention."""
+        return self.n_layers if self.has_ssm else self.n_linear_layers
+
+    def state_shape(self, rows: int) -> tuple[int, ...]:
+        """The float32 recurrent state of ``rows`` sequences, the
+        architecture's: ``[layers, rows, heads, ...]``."""
+        if self.has_ssm:
+            return (self.n_layers, rows, self.ssm_heads, self.ssm_head_dim,
+                    self.ssm_state_dim)
+        return (self.n_linear_layers, rows, self.lin_heads, self.lin_key_dim,
+                self.lin_value_dim)
+
+    def conv_shape(self, rows: int) -> tuple[int, ...]:
+        """The causal convolution's last ``K - 1`` inputs of ``rows``
+        sequences: ``[layers, rows, K - 1, channels]``."""
+        if self.has_ssm:
+            return (self.n_layers, rows, self.ssm_conv_kernel - 1,
+                    self.ssm_conv_dim)
+        return (self.n_linear_layers, rows, self.lin_conv_kernel - 1,
+                self.lin_conv_dim)
+
+    @property
+    def ssm_inner_dim(self) -> int:
+        """The SSD mixer's width: heads x head width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of the SSD mixer's causal convolution: x, B, C."""
+        return self.ssm_inner_dim + 2 * self.ssm_groups * self.ssm_state_dim
+
+    @property
+    def ssm_in_dim(self) -> int:
+        """Width of the mixer's packed Q40 input projection: z x B C."""
+        return self.ssm_inner_dim + self.ssm_conv_dim
 
     @property
     def n_periods(self) -> int:
@@ -211,6 +303,19 @@ class ModelConfig:
                 lin_value_dim=h.linear_value_head_dim,
                 lin_conv_kernel=h.linear_conv_kernel,
                 lin_neg_eigval=bool(h.linear_neg_eigval))
+        if h.arch_type == ArchType.FALCON_H1:
+            hybrid = dict(
+                ssm_heads=h.ssm_n_heads, ssm_head_dim=h.ssm_head_dim,
+                ssm_groups=h.ssm_n_groups, ssm_state_dim=h.ssm_state_dim,
+                ssm_conv_kernel=h.ssm_conv_kernel, ssm_chunk=h.ssm_chunk_size,
+                mult=Multipliers(
+                    embedding=h.embedding_mult, lm_head=h.lm_head_mult,
+                    attn_in=h.attn_in_mult, attn_out=h.attn_out_mult,
+                    key=h.key_mult, ssm_in=h.ssm_in_mult,
+                    ssm_out=h.ssm_out_mult, mlp_gate=h.mlp_gate_mult,
+                    mlp_down=h.mlp_down_mult, ssm_z=h.ssm_mult_z,
+                    ssm_x=h.ssm_mult_x, ssm_b=h.ssm_mult_b,
+                    ssm_c=h.ssm_mult_c, ssm_dt=h.ssm_mult_dt))
         if h.arch_type == ArchType.LAGUNA:
             hybrid = dict(
                 layer_period=h.layer_period,

@@ -1,0 +1,300 @@
+"""A decoder whose every layer runs two mixers SIDE BY SIDE over one normed
+input: a Mamba-2 (SSD) state-space mixer and grouped-query softmax attention
+(``ArchType.FALCON_H1``; Falcon-H1-34B-Instruct is 72 such layers).
+
+One layer, for its input ``x`` (pre-norm, RMS)::
+
+    h = rmsnorm(x; w_in)
+    a = attn(h * attn_in)  * attn_out
+    m = mamba(h * ssm_in)  * ssm_out
+    x = x + a + m                                # ONE residual add for both
+    g = rmsnorm(x; w_ff)
+    x = x + W_down(silu(W_gate g * mlp_gate) * (W_up g)) * mlp_down
+
+``attn``: q k v without bias or q/k norm, ``k * key``, the half-split rotary
+over the whole head, causal GQA softmax at ``1 / sqrt(hd)``, ``W_o``.
+``mamba`` (ops/ssd.py has the recurrence), for its input ``u``::
+
+    [z | xBC | dt] = (W_inproj u) * mup      # mup: ssm_z ssm_x ssm_b ssm_c ssm_dt
+    xBC = silu(causal_conv(xBC) + conv_bias);  x_ B C = split(xBC)
+    dt = softplus(dt + dt_bias);  A = -exp(A_log)
+    y  = SSD(x_, dt, A, B, C) + D x_
+    W_outproj group_rmsnorm(y * silu(z); w_norm)     # the gate first, then
+                                                     # an RMS norm a group
+
+and around the stack ``embed(ids) * embedding`` in front and ``(W_head
+rmsnorm(x)) * lm_head`` behind. The scalars are ``cfg.mult``
+(:class:`~dllama_tpu.models.config.Multipliers`).
+
+The stack is ONE homogeneous stack (``FalconH1Layers``), as
+models/llama.py's is, and every layer owns K/V blocks AND a row of the state
+pool (``cfg.n_kv_layers == cfg.n_layers == cfg.n_state_layers``). One scan
+over the layer index; everything a slot's context is made of rides the
+CARRY whole and is written in place: the K/V (a column's or the block
+pool), the state and the convolution's tail (a
+:class:`~dllama_tpu.runtime.kvblocks.StateColumn`'s or the
+:class:`~dllama_tpu.runtime.kvblocks.StatePool`). As the scan's ``xs``/``ys``
+a pool is sliced, stacked and copied back every step (PERF.md section 6, PR
+33). Every Q40 plane reaches :func:`~dllama_tpu.ops.linear.linear` as stack +
+index (:class:`~dllama_tpu.ops.linear.LayerSlice`). The in-projection is 9248
+wide at the published sizes, 72.25 lanes of 128: its ``z x B C`` rows are one
+Q40 plane (``w_in``, 9216 = 72 x 128) that the fused kernels take, its
+``dt`` rows a float32 plane (``w_dt``, one row a head), as the gated delta
+rule's gate rows are.
+
+* :func:`forward`: a prefill chunk over a slot's gathered column, the mixer
+  in its CHUNK form. ``n_valid`` masks padding: positions at or past it get
+  ``dt = 0`` (the update is then the identity) and never enter the
+  convolution's tail; their K/V rows are overwritten later.
+* :func:`paged_forward`: the decode step, one token a row, the mixer in its
+  STEP form over the state pool in place (the Pallas kernel ``ssd_step`` on
+  a TPU, its XLA twin elsewhere); rows whose block table is all null
+  (inactive slots riding along) use the pool's null row.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import ssd
+from ..ops.gated_delta import causal_conv
+from ..ops.linear import Weight, linear
+from ..ops.norms import rms_norm
+from ..parallel.api import current_plan
+from ..runtime.introspection import note_ssd_path
+from .config import ModelConfig
+from .llama import (Params, _attend_dense, _attend_paged, _hidden_act,
+                    _stack_at)
+from .rope import apply_rope, build_rope_cache
+
+
+class FalconH1Layers(NamedTuple):
+    """``Params.layers``: every leaf carries a leading ``[n_layers]`` axis."""
+
+    wq: Weight            # [L, q_dim, dim]
+    wk: Weight
+    wv: Weight
+    wo: Weight            # [L, dim, q_dim]
+    w_in: Weight          # [L, ssm_in_dim, dim]: the z x B C rows, packed
+    w_dt: jax.Array       # [L, H, dim] float32: the dt rows
+    conv_w: jax.Array     # [L, K, ssm_conv_dim]
+    conv_b: jax.Array     # [L, ssm_conv_dim]
+    a_log: jax.Array      # [L, H]
+    d_skip: jax.Array     # [L, H]
+    dt_bias: jax.Array    # [L, H]
+    norm_ssm: jax.Array   # [L, ssm_inner_dim]: the gated norm's weight
+    w_out: Weight         # [L, dim, ssm_inner_dim]
+    w1: Weight            # [L, hidden_dim, dim] (gate)
+    w2: Weight            # (down)
+    w3: Weight            # (up)
+    norm_att: jax.Array   # [L, dim]: the norm both mixers read through
+    norm_ffn: jax.Array   # [L, dim]
+
+
+_MATMULS = ("wq", "wk", "wv", "wo", "w_in", "w_out", "w1", "w2", "w3")
+
+
+def _check(cfg: ModelConfig) -> None:
+    if current_plan() is not None:
+        raise ValueError("a decoder with an SSD mixer beside attention has "
+                         "no mesh plan (tp/sp/pp/dp > 1) yet")
+    if cfg.sync_q80 or cfg.offload:
+        raise ValueError("a decoder with an SSD mixer beside attention "
+                         "supports neither Q80 sync emulation nor offloaded "
+                         "weights")
+
+
+def _mixer_inputs(cfg: ModelConfig, u: jax.Array, lp: FalconH1Layers,
+                  tail: jax.Array, n_valid):
+    """Everything of the SSD mixer in front of the recurrence, for ``u [B,
+    T, dim]`` and the convolution's ``tail [B, K - 1, C]``: float32 ``x [B,
+    T, H, P]``, ``dt [B, T, H]`` (after its softplus), the groups' ``Bm, Cm
+    [B, T, G, N]``, the gate ``z [B, T, d_ssm]`` and the new tail."""
+    B, T, _ = u.shape
+    m = cfg.mult
+    H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state_dim
+    d_ssm, gn = cfg.ssm_inner_dim, G * N
+    proj = linear(u, lp.w_in)
+    dt = jnp.einsum("btd,hd->bth", u.astype(jnp.float32), lp.w_dt,
+                    precision=jax.lax.Precision.HIGHEST) * m.ssm_dt
+    z = proj[..., :d_ssm].astype(jnp.float32) * m.ssm_z
+    # the x, B and C lanes of the in-projection, each under its multiplier
+    lanes = jnp.concatenate([jnp.full((d_ssm,), m.ssm_x, jnp.float32),
+                             jnp.full((gn,), m.ssm_b, jnp.float32),
+                             jnp.full((gn,), m.ssm_c, jnp.float32)])
+    # folded into the taps (the convolution is linear a channel), so the
+    # tail keeps the projection's own values, exact in its dtype
+    xbc, tail = causal_conv(proj[..., d_ssm:], tail, lp.conv_w * lanes,
+                            n_valid, bias=lp.conv_b)
+    x = xbc[..., :d_ssm].reshape(B, T, H, P)
+    Bm = xbc[..., d_ssm:d_ssm + gn].reshape(B, T, G, N)
+    Cm = xbc[..., d_ssm + gn:].reshape(B, T, G, N)
+    return x, jax.nn.softplus(dt + lp.dt_bias), Bm, Cm, z, tail
+
+
+def _mixer_output(cfg: ModelConfig, y: jax.Array, x: jax.Array, z: jax.Array,
+                  lp: FalconH1Layers, dtype) -> jax.Array:
+    """``W_out group_rmsnorm((y + D x) * silu(z))`` from float32 ``y, x [B,
+    T, H, P]``: the gate first, then an RMS norm over each group's lanes."""
+    B, T = y.shape[:2]
+    y = (y + lp.d_skip[:, None] * x).reshape(B, T, -1) * jax.nn.silu(z)
+    grouped = y.reshape(B, T, cfg.ssm_groups, -1)
+    normed = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + cfg.norm_epsilon)
+    return linear((normed.reshape(B, T, -1) * lp.norm_ssm).astype(dtype),
+                  lp.w_out)
+
+
+def _mixer_chunk(cfg, u, lp, s_l, conv_l, n_valid):
+    """The mixer over a chunk: ``s_l [B, H, P, N]`` in and out."""
+    T = u.shape[1]
+    x, dt, Bm, Cm, z, conv_l = _mixer_inputs(cfg, u, lp, conv_l, n_valid)
+    real = (jnp.arange(T) < n_valid)[None, :, None]
+    note_ssd_path("chunk", "xla")
+    y, s_l = ssd.ssd_chunk(x, jnp.where(real, dt, 0.0), -jnp.exp(lp.a_log),
+                           Bm, Cm, s_l, cfg.ssm_chunk)
+    return _mixer_output(cfg, y, x, z, lp, u.dtype), s_l, conv_l
+
+
+def _mixer_step(cfg, u, lp, l, rows, s_pool, conv_pool):
+    """The mixer over one token a row, the pools in and out: row ``b``'s
+    state and tail are ``[l, rows[b]]`` of them."""
+    tail = jax.lax.dynamic_index_in_dim(conv_pool, l, 0, keepdims=False)[rows]
+    x, dt, Bm, Cm, z, tail = _mixer_inputs(cfg, u, lp, tail, None)
+    conv_pool = conv_pool.at[l, rows].set(tail)
+    kernel = ssd.step_kernel_choice()
+    note_ssd_path("step", "xla" if kernel is None else "pallas")
+    step = (ssd.ssd_step_xla if kernel is None
+            else lambda *a: ssd.ssd_step(*a, **kernel))
+    dt1 = dt[:, 0]
+    y, s_pool = step(s_pool, l, rows, x[:, 0], dt1,
+                     jnp.exp(-dt1 * jnp.exp(lp.a_log)), Bm[:, 0], Cm[:, 0])
+    return _mixer_output(cfg, y[:, None], x, z, lp, u.dtype), s_pool, conv_pool
+
+
+def _qkv(cfg: ModelConfig, u: jax.Array, lp: FalconH1Layers, cos, sin,
+         positions):
+    B, T, _ = u.shape
+    q = linear(u, lp.wq, out_axis="heads").reshape(B, T, cfg.n_heads, cfg.head_dim)
+    k = linear(u, lp.wk, out_axis="kv_heads").reshape(
+        B, T, cfg.n_kv_heads, cfg.head_dim) * cfg.mult.key
+    v = linear(u, lp.wv, out_axis="kv_heads").reshape(
+        B, T, cfg.n_kv_heads, cfg.head_dim)
+    return (apply_rope(q, cos, sin, positions, cfg.rope_type),
+            apply_rope(k.astype(u.dtype), cos, sin, positions, cfg.rope_type),
+            v)
+
+
+def _scan_layers(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                 positions: jax.Array, s, conv, k, v, mixer, attend):
+    """The layer scan both programs share. Everything a slot's context is
+    made of rides the CARRY whole, a column's or the pools: ``s, conv``
+    (every layer's state and tail) and ``k, v`` (every layer's cache);
+    nothing is sliced into the scan or stacked out of it. ``mixer(u, lp, l,
+    s, conv) -> (y, s, conv)`` is the form of the SSD mixer and
+    ``attend(q, k, v, k_c, v_c, l) -> (att, k_c, v_c)`` owns the cache; both
+    give the whole arrays back."""
+    m = cfg.mult
+    B, T = tokens.shape
+    cos, sin = build_rope_cache(cfg)
+    x = (params.embedding[tokens].astype(jnp.float32)
+         * m.embedding).astype(cfg.compute_dtype)
+
+    def layer(carry, l):
+        x, s, conv, k_c, v_c = carry
+        lp = _stack_at(params.layers, l, _MATMULS)
+        h = rms_norm(x, lp.norm_att, cfg.norm_epsilon)
+        q, k_new, v_new = _qkv(cfg, h * m.attn_in, lp, cos, sin, positions)
+        att, k_c, v_c = attend(q, k_new, v_new, k_c, v_c, l)
+        a = linear(att.reshape(B, T, cfg.q_dim), lp.wo, in_axis="heads")
+        y, s, conv = mixer(h * m.ssm_in, lp, l, s, conv)
+        x = x + (a * m.attn_out + y * m.ssm_out).astype(x.dtype)
+        g = rms_norm(x, lp.norm_ffn, cfg.norm_epsilon)
+        gate = _hidden_act(cfg, linear(g, lp.w1, out_axis="hidden") * m.mlp_gate)
+        ffn = linear(gate * linear(g, lp.w3, out_axis="hidden"), lp.w2,
+                     in_axis="hidden")
+        x = x + (ffn * m.mlp_down).astype(x.dtype)
+        return (x, s, conv, k_c, v_c), None
+
+    layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    (x, s, conv, k, v), _ = jax.lax.scan(layer, (x, s, conv, k, v), layers)
+    x = rms_norm(x, params.final_norm, cfg.norm_epsilon)
+    logits = linear(x, params.logits, out_axis="vocab").astype(jnp.float32)
+    return logits * m.lm_head, s, conv, k, v
+
+
+def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+            start_pos: jax.Array, col, n_valid: jax.Array | None = None):
+    """A chunk ``tokens [B, T]`` at scalar ``start_pos`` over a gathered
+    :class:`~dllama_tpu.runtime.kvblocks.StateColumn`: float32 logits ``[B,
+    T, vocab]`` and the column, advanced by the chunk's first ``n_valid``
+    positions (absent: all ``T``)."""
+    from ..runtime.kvblocks import StateColumn
+
+    _check(cfg)
+    start_pos = jnp.asarray(start_pos, dtype=jnp.int32)
+    if start_pos.ndim:
+        raise ValueError("a recurrent state's chunk form takes one start "
+                         "position (the dense slot pool's ragged rows are "
+                         "not carried to it)")
+    B, T = tokens.shape
+    n_valid = jnp.asarray(T if n_valid is None else n_valid, jnp.int32)
+    positions = jnp.broadcast_to(
+        start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
+
+    def at(a, l):
+        return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+
+    def put(a, a_l, l):
+        return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
+
+    def mixer(u, lp, l, s, conv):
+        y, s_l, conv_l = _mixer_chunk(cfg, u, lp, at(s, l), at(conv, l),
+                                      n_valid)
+        return y, put(s, s_l, l), put(conv, conv_l, l)
+
+    def attend(q, k, v, k_c, v_c, l):
+        att, k_l, v_l = _attend_dense(cfg, q, k, v, at(k_c, l), at(v_c, l),
+                                      start_pos, positions)
+        return att, put(k_c, k_l, l), put(v_c, v_l, l)
+
+    logits, s, conv, k, v = _scan_layers(params, cfg, tokens, positions,
+                                         col.s, col.conv, col.k, col.v,
+                                         mixer, attend)
+    return logits, StateColumn(k=k, v=v, s=s, conv=conv)
+
+
+def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                  pos_vec: jax.Array, cache, tables: jax.Array,
+                  write_lens: jax.Array | None = None):
+    """The decode step over the paged pool and the state pool: ``tokens [B,
+    1]`` at per-row ``pos_vec``, ``cache = (PagedKVCache, StatePool)``, both
+    given back. Row ``b`` is slot ``b``: its state is row ``b + 1`` of the
+    pool, or the null row 0 while its block table is all null."""
+    from ..runtime.kvblocks import PagedKVCache, StatePool
+
+    _check(cfg)
+    B, T = tokens.shape
+    if T != 1 or write_lens is not None:
+        raise ValueError("a recurrent state's step form takes one token a "
+                         "row: a speculative verify's rejected drafts "
+                         "cannot be rolled back out of it")
+    pkv, pool = cache
+    positions = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]
+    rows = jnp.where(tables[:, 0] != 0, jnp.arange(1, B + 1, dtype=jnp.int32),
+                     StatePool.NULL)
+
+    def mixer(u, lp, l, s, conv):
+        return _mixer_step(cfg, u, lp, l, rows, s, conv)
+
+    def attend(q, k, v, k_pool, v_pool, l):
+        return _attend_paged(cfg, q, k, v, k_pool, v_pool, l, positions,
+                             tables)
+
+    logits, s, conv, k, v = _scan_layers(params, cfg, tokens, positions,
+                                         pool.s, pool.conv, pkv.k, pkv.v,
+                                         mixer, attend)
+    return logits, (PagedKVCache(k=k, v=v), StatePool(s=s, conv=conv))

@@ -16,8 +16,8 @@ A slot's context is two things side by side: K/V rows of the FULL layers
 only (a column ``[n_periods, 1, n_kv, S, hd]`` during prefill, blocks of the
 paged pool afterwards) and, for the linear layers, a float32 recurrent
 state ``[n_linear, H, dk, dv]`` and the convolution's last ``K - 1`` inputs
-(:class:`HybridColumn` during prefill, a row of
-:class:`~dllama_tpu.runtime.kvblocks.StatePool` afterwards).
+(:class:`~dllama_tpu.runtime.kvblocks.StateColumn` during prefill, a row
+of :class:`~dllama_tpu.runtime.kvblocks.StatePool` afterwards).
 
 * :func:`forward`: a prefill chunk over a slot's gathered column. The
   mixer runs its CHUNK form (ops/gated_delta.gated_delta_chunk), state in
@@ -62,6 +62,7 @@ from ..ops.linear import Weight, linear
 from ..ops.norms import rms_norm
 from ..parallel.api import current_plan
 from ..runtime.introspection import note_gdn_path
+from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
 from .llama import (LayerParams, Params, _attend_dense, _attend_paged,
                     _hidden_act, _layer_at, _stack_at)
@@ -96,33 +97,9 @@ class HybridLayers(NamedTuple):
     full: LayerParams     # norm_q/norm_k: [NF, q_dim]/[NF, kv_dim], over the whole projection
 
 
-class HybridColumn(NamedTuple):
-    """One slot's context gathered for chunked prefill (the hybrid's
-    ``KVCache``): K/V of the full layers, the linear layers' state and the
-    convolution's tail."""
-
-    k: jax.Array      # [NF, B, n_kv, S, hd]
-    v: jax.Array
-    s: jax.Array      # [NL, B, H, dk, dv] float32
-    conv: jax.Array   # [NL, B, K - 1, lin_conv_dim]
-
-    @classmethod
-    def zeros(cls, cfg: ModelConfig, k: jax.Array, v: jax.Array,
-              conv_dtype) -> "HybridColumn":
-        """A sequence's start: the given K/V column, zero state and tail."""
-        B = k.shape[1]
-        return cls(k=k, v=v, s=jnp.zeros(state_shape(cfg, B), jnp.float32),
-                   conv=jnp.zeros(conv_shape(cfg, B), conv_dtype))
-
-
-def state_shape(cfg: ModelConfig, rows: int) -> tuple[int, ...]:
-    return (cfg.n_linear_layers, rows, cfg.lin_heads, cfg.lin_key_dim,
-            cfg.lin_value_dim)
-
-
-def conv_shape(cfg: ModelConfig, rows: int) -> tuple[int, ...]:
-    return (cfg.n_linear_layers, rows, cfg.lin_conv_kernel - 1,
-            cfg.lin_conv_dim)
+# one slot's context gathered for chunked prefill: the state's own column
+# type (runtime/kvblocks.py), under the name this module gave it first
+HybridColumn = StateColumn
 
 
 def _sublayer(cfg: ModelConfig, x: jax.Array, norm_w: jax.Array, f):

@@ -967,8 +967,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             n_valid: jax.Array | None = None) -> tuple[jax.Array, KVCache]:
     """Full forward: ``tokens [B, T]`` at absolute ``start_pos`` → logits.
 
-    ``n_valid`` is a hybrid decoder's alone (models/hybrid.py, where ``kv``
-    is a :class:`~dllama_tpu.models.hybrid.HybridColumn`): how many of the
+    ``n_valid`` belongs to the decoders with a recurrent state
+    (models/hybrid.py, models/falcon_h1.py, where ``kv`` is a
+    :class:`~dllama_tpu.runtime.kvblocks.StateColumn`): how many of the
     chunk's ``T`` positions are real. K/V rows written for padding are
     overwritten later; a recurrent state would keep them, so padded
     positions leave it untouched. The dense decoders pad freely and never
@@ -984,6 +985,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         from . import hybrid
 
         return hybrid.forward(params, cfg, tokens, start_pos, kv, n_valid)
+    if cfg.has_ssm:
+        from . import falcon_h1
+
+        return falcon_h1.forward(params, cfg, tokens, start_pos, kv, n_valid)
     if cfg.has_window_layers:
         from . import laguna
 
@@ -1305,6 +1310,11 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
         return hybrid.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
                                     write_lens)
+    if cfg.has_ssm:
+        from . import falcon_h1
+
+        return falcon_h1.paged_forward(params, cfg, tokens, pos_vec, pkv,
+                                       tables, write_lens)
     if cfg.has_window_layers:
         from . import laguna
 

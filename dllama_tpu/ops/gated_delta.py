@@ -73,18 +73,23 @@ def gates(a: jax.Array, b: jax.Array, a_log: jax.Array, dt_bias: jax.Array,
 
 
 def causal_conv(x: jax.Array, tail: jax.Array, w: jax.Array,
-                n_valid: jax.Array | None = None):
+                n_valid: jax.Array | None = None,
+                bias: jax.Array | None = None):
     """Causal depthwise convolution over time, then SiLU: ``y_t = silu(sum_j
-    w[j] x_{t-(K-1)+j})``. ``x [B, T, C]``; ``tail [B, K-1, C]`` are the K-1
-    inputs before the chunk (zeros at a sequence's start); ``w [K, C]``.
-    Returns float32 ``y [B, T, C]`` and the new tail: the last K-1 inputs at
-    or before position ``n_valid`` (a scalar; absent, ``T``), so padding
-    behind a chunk's valid length never enters it."""
+    w[j] x_{t-(K-1)+j} + bias)``. ``x [B, T, C]``; ``tail [B, K-1, C]`` are
+    the K-1 inputs before the chunk (zeros at a sequence's start); ``w [K,
+    C]``; ``bias [C]`` where the mixer has one (ops/ssd.py's does, the
+    gated delta rule's does not). Returns float32 ``y [B, T, C]`` and the
+    new tail: the last K-1 inputs at or before position ``n_valid`` (a
+    scalar; absent, ``T``), so padding behind a chunk's valid length never
+    enters it."""
     K, T = w.shape[0], x.shape[1]
     seq = jnp.concatenate([tail.astype(jnp.float32), x.astype(jnp.float32)],
                           axis=1)
     wf = w.astype(jnp.float32)
     y = sum(wf[j] * seq[:, j:j + T] for j in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     start = T if n_valid is None else n_valid
     new_tail = jax.lax.dynamic_slice_in_dim(seq, start, K - 1, axis=1)
     return jax.nn.silu(y), new_tail.astype(tail.dtype)

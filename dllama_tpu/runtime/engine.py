@@ -222,16 +222,22 @@ class InferenceEngine:
                 f"{self.packet_slots} token slots (raise --nbatches)")
 
         if self.cfg.paged_only:
-            # a recurrent state beside K/V (models/hybrid.py), or window
+            # a recurrent state beside K/V (models/hybrid.py: in the linear
+            # layers of a pattern; models/falcon_h1.py: in every layer,
+            # beside attention), or window
             # layers with a block pool of their own and an expert share
             # (models/laguna.py): what this engine does not carry to them
             # is refused HERE, by flag and reason; nothing is silently
             # ignored and no code stands in
             tp = 1 if tp is None else tp
-            hybrid = self.cfg.is_hybrid
-            what = ("a hybrid decoder (linear-attention layers with a "
+            state = self.cfg.has_state
+            what = ("a decoder with an SSD mixer beside attention in every "
+                    "layer (a recurrent state a layer; the layer scan "
+                    "carries the state pool and has no mesh plan yet)"
+                    if self.cfg.has_ssm else
+                    "a hybrid decoder (linear-attention layers with a "
                     "recurrent state; the period scan has no mesh plan yet)"
-                    if hybrid else
+                    if state else
                     "a decoder with window layers and an expert share (two "
                     "block pools a sequence; the period scan has no mesh "
                     "plan yet, the share's exchange between chips is not "
@@ -240,15 +246,15 @@ class InferenceEngine:
                 ("no --kv-block-size (the dense slot pool, and the "
                  "single-sequence inference/chat/perplexity path: only the "
                  "paged generator carries "
-                 + ("the state pool)" if hybrid else "the two block pools)"),
+                 + ("the state pool)" if state else "the two block pools)"),
                  not int(kv_block_size or 0)),
                 ("--spec-lookup (a rejected draft cannot be rolled back "
-                 "out of a recurrent state)" if hybrid else
+                 "out of a recurrent state)" if state else
                  "--spec-lookup (a sliding window's walk takes one token a "
                  "row; a verify's lanes would each need a window of their "
                  "own)", self.spec_lookup > 0),
                 ("--kv-host-blocks (the host tier spills and pages in K/V "
-                 "blocks; a state has no host copy)" if hybrid else
+                 "blocks; a state has no host copy)" if state else
                  "--kv-host-blocks (the host tier keeps one list of blocks "
                  "by token range; the window pool's blocks behind the "
                  "window are gone, and with them kvwire export/ingest and "
@@ -621,13 +627,17 @@ class InferenceEngine:
         # weights.
         self._load_quant_resolution = self._quant_resolution()
         t_phase = self._stamp_startup("weight_load", t_phase)
-        # a hybrid decoder is served by the paged generator alone, which
+        # a paged-only decoder is served by the paged generator alone, which
         # owns its pools: no batch-1 cache for a solo path it refuses
         self.kv: KVCache = None if self.cfg.paged_only else self._fresh_kv()
         self.pos = 0
         kinds = telemetry.registry().gauge(telemetry.LAYER_KINDS)
         kinds.set(self.cfg.n_linear_layers, kind="linear")
-        kinds.set(self.cfg.n_kv_layers, kind="full")
+        # a layer with an SSD mixer beside its attention is neither
+        kinds.set(self.cfg.n_layers if self.cfg.has_ssm else 0,
+                  kind="ssm_beside_full")
+        kinds.set(0 if self.cfg.has_ssm else self.cfg.n_kv_layers,
+                  kind="full")
         kinds.set(self.cfg.n_window_layers, kind="sliding")
         # the expert share (models/laguna.py): held here, of those routed
         telemetry.registry().gauge(telemetry.MOE_EXPERTS_HELD).set(
@@ -775,14 +785,14 @@ class InferenceEngine:
         return fast_numerics_resolved(self.cfg.compute_dtype)
 
     def _require_solo_cache(self) -> None:
-        """The single-sequence programs run over ``self.kv``, which a hybrid
-        decoder does not have: its context is K/V AND a recurrent state,
-        and only the paged generator carries both. Nor does a decoder with
+        """The single-sequence programs run over ``self.kv``, which a decoder
+        with a recurrent state does not have: its context is K/V AND that
+        state, and only the paged generator carries both. Nor does a decoder with
         window layers: its context is blocks of two pools."""
         if self.kv is None:
             raise RuntimeError(
-                "a hybrid decoder, or one with window layers, is served "
-                "through BatchScheduler over the paged pool only: the "
+                "a decoder with a recurrent state or window layers is "
+                "served through BatchScheduler over the paged pool only: the "
                 "single-sequence path (inference, chat, perplexity, "
                 "score_nll) has no recurrent state and no second pool")
 

@@ -66,6 +66,14 @@ def matmul_weight_count(cfg) -> int:
                 + cfg.q_dim * cfg.dim + ffn)
         return (cfg.n_linear_layers * linear + cfg.n_kv_layers * full
                 + cfg.dim * cfg.vocab_size)
+    if cfg.has_ssm:
+        # one kind of layer: q k v wo, the mixer's packed input projection
+        # (its dt rows are a small float32 plane, not counted here) and
+        # output projection, a dense feed-forward
+        layer = (cfg.dim * cfg.q_dim + 2 * cfg.dim * cfg.kv_dim
+                 + cfg.q_dim * cfg.dim + cfg.dim * cfg.ssm_in_dim
+                 + cfg.ssm_inner_dim * cfg.dim + 3 * cfg.dim * cfg.hidden_dim)
+        return cfg.n_layers * layer + cfg.dim * cfg.vocab_size
     if cfg.has_window_layers:
         # what is HELD: two kinds of attention layer at their own head
         # counts, the leading dense feed-forward, the held experts of a
@@ -170,7 +178,7 @@ def fit_block_pool(cfg, n_blocks: int, *, block_size: int, min_blocks: int,
     pool costs capacity for LIVE context only — cold (cached) blocks
     spill to the host mirror under pressure and page back at resume, so
     the device size stops bounding how many idle sessions keep their
-    KV. ``state_bytes`` is a hybrid decoder's recurrent state pool
+    KV. ``state_bytes`` is the recurrent state pool of a decoder that has one
     (kvblocks.state_pool_bytes): it does not shrink with the blocks, so it
     is charged whole, beside them."""
     limit = (None if os.environ.get("DLLAMA_SKIP_HBM_CHECK")

@@ -277,24 +277,27 @@ class CompileLedger:
             g.set(n, scope=entry["scope"], program=entry["program"],
                   path=path)
 
-    def note_gdn_paths(self, entry: dict, paths: dict | None) -> None:
-        """The gated delta-rule twin of :meth:`note_q40_paths`
-        (:func:`note_gdn_path`): gauge ``dllama_gated_delta_paths``."""
-        if not paths:
-            return
-        with self._lock:
-            entry["gdn_paths"] = dict(paths)
-        g = telemetry.registry().gauge(telemetry.GATED_DELTA_PATHS)
-        for key, n in paths.items():
-            form, path = key.split(":")
-            g.set(n, scope=entry["scope"], program=entry["program"],
-                  form=form, path=path)
+    def note_mixer_paths(self, entry: dict, win: dict) -> None:
+        """The recurrent mixers' twin of :meth:`note_q40_paths`
+        (:func:`note_gdn_path`, :func:`note_ssd_path`): gauges
+        ``dllama_gated_delta_paths`` and ``dllama_ssd_paths``."""
+        for kind, gauge in _MIXER_GAUGES.items():
+            paths = win.get(kind)
+            if not paths:
+                continue
+            with self._lock:
+                entry[kind + "_paths"] = dict(paths)
+            g = telemetry.registry().gauge(gauge)
+            for key, n in paths.items():
+                form, path = key.split(":")
+                g.set(n, scope=entry["scope"], program=entry["program"],
+                      form=form, path=path)
 
-    def gdn_paths(self, scope: str) -> dict[str, dict[str, int]]:
+    def mixer_paths(self, scope: str, kind: str) -> dict[str, dict[str, int]]:
         with self._lock:
-            return {program: dict(entry["gdn_paths"])
+            return {program: dict(entry[kind + "_paths"])
                     for (sc, program), entry in sorted(self._programs.items())
-                    if sc == scope and entry.get("gdn_paths")}
+                    if sc == scope and entry.get(kind + "_paths")}
 
     def q40_paths(self, scope: str) -> dict[str, dict[str, int]]:
         """``{program: {path: count}}`` for the programs of ``scope`` whose
@@ -436,14 +439,29 @@ def note_q40_path(path: str) -> None:
         paths[path] += 1
 
 
+# the recurrent mixers by the key their counts are filed under: the gauge
+# each publishes to and the name the start-up report gives it
+_MIXER_GAUGES = {"gdn": telemetry.GATED_DELTA_PATHS, "ssd": telemetry.SSD_PATHS}
+_MIXER_TITLES = {"gdn": "gated delta rule", "ssd": "ssd mixer"}
+
+
+def _note_mixer_path(kind: str, form: str, path: str) -> None:
+    win = getattr(_tls, "window", None)
+    if win is not None:
+        paths = win.setdefault(kind, {})
+        paths[f"{form}:{path}"] = paths.get(f"{form}:{path}", 0) + 1
+
+
 def note_gdn_path(form: str, path: str) -> None:
     """``models.hybrid`` calls this while a gated delta-rule mixer is traced:
     which ``form`` (``chunk`` or ``step``) took which ``path`` (``pallas`` or
     ``xla``). Filed like :func:`note_q40_path`, under ``form:path``."""
-    win = getattr(_tls, "window", None)
-    if win is not None:
-        paths = win.setdefault("gdn", {})
-        paths[f"{form}:{path}"] = paths.get(f"{form}:{path}", 0) + 1
+    _note_mixer_path("gdn", form, path)
+
+
+def note_ssd_path(form: str, path: str) -> None:
+    """:func:`note_gdn_path` for the SSD mixer (``models.falcon_h1``)."""
+    _note_mixer_path("ssd", form, path)
 
 
 def _monitoring_on() -> bool:
@@ -508,7 +526,7 @@ class ObservedJit:
             analysis = {"error": f"{type(e).__name__}: {e}"}
             sig = {}
         _ledger.note_q40_paths(self._entry, win.get("q40"))
-        _ledger.note_gdn_paths(self._entry, win.get("gdn"))
+        _ledger.note_mixer_paths(self._entry, win)
         _ledger.record(self._entry, compile_s, sig, _plan_desc(), analysis,
                        backend_s=win["backend_s"])
         return out
@@ -523,7 +541,7 @@ class ObservedJit:
         finally:
             _tls.window = prev
             _ledger.note_q40_paths(self._entry, win.get("q40"))
-            _ledger.note_gdn_paths(self._entry, win.get("gdn"))
+            _ledger.note_mixer_paths(self._entry, win)
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)
@@ -562,7 +580,9 @@ def startup_line(engine) -> str:
     parts = getattr(engine, "startup_s", None) or {}
     cfg = engine.cfg
     kinds = (f"; layers: {cfg.n_linear_layers} linear, {cfg.n_kv_layers} full"
-             if cfg.is_hybrid else "")
+             if cfg.is_hybrid else
+             f"; layers: {cfg.n_layers} with an SSD mixer beside attention"
+             if cfg.has_ssm else "")
     if cfg.has_window_layers:
         kinds = (f"; layers: {cfg.n_kv_layers} full, {cfg.n_window_layers} "
                  f"sliding (window {cfg.sliding_window}); experts: "
@@ -585,21 +605,23 @@ def q40_paths_line(scope: str) -> str:
         for program, n in by_program.items())
 
 
-def gdn_paths_line(scope: str) -> str:
-    """Which path each program's gated delta-rule mixers took, by form
-    (:func:`note_gdn_path`); empty for a model without them."""
-    by_program = _ledger.gdn_paths(scope)
+def mixer_paths_line(scope: str, kind: str) -> str:
+    """Which path each program's recurrent mixers of ``kind`` (``gdn``,
+    ``ssd``) took, by form (:func:`note_gdn_path`, :func:`note_ssd_path`);
+    empty for a model without them."""
+    by_program = _ledger.mixer_paths(scope, kind)
     if not by_program:
         return ""
-    return "🧮 gated delta rule: " + "; ".join(
+    return f"🧮 {_MIXER_TITLES[kind]}: " + "; ".join(
         f"{program} " + ", ".join(f"{n} {key}" for key, n in sorted(paths.items()))
         for program, paths in by_program.items())
 
 
 def _emit_q40_paths(scope: str, emit) -> None:
-    gdn = gdn_paths_line(scope)
-    if gdn:
-        emit(gdn)
+    for kind in _MIXER_GAUGES:
+        line = mixer_paths_line(scope, kind)
+        if line:
+            emit(line)
     line = q40_paths_line(scope)
     if line:
         emit(line)

@@ -209,7 +209,7 @@ class PagedKVCache(NamedTuple):
         import jax.numpy as jnp
 
         dtype = dtype if dtype is not None else jnp.float32
-        # a hybrid decoder caches K/V in its full layers only
+        # every layer, or a hybrid decoder's full ones only
         shape = (cfg.n_kv_layers, n_blocks, cfg.n_kv_heads, block_size,
                  cfg.head_dim)
         return cls(k=jnp.zeros(shape, dtype=dtype),
@@ -225,21 +225,25 @@ class PagedKVCache(NamedTuple):
 
 
 class StatePool(NamedTuple):
-    """Device-side recurrent state of a hybrid decoder's linear-attention
-    layers (models/hybrid.py), slot-indexed, beside the K/V block pool:
-    ``s [n_linear, rows, H, dk, dv]`` float32 and the convolution's last
-    ``K - 1`` inputs ``conv [n_linear, rows, K - 1, channels]``. Row 0 is the
-    NULL row, as block 0 is the null block: inactive slots riding along a
-    decode step read and write it, and its contents are value-invisible.
-    Slot ``i`` owns row ``i + 1``.
+    """Device-side recurrent state of a decoder whose layers carry one
+    (``cfg.has_state``), slot-indexed, beside the K/V block pool: ``s
+    [state layers, rows, heads, ...]`` float32 and the causal convolution's
+    last ``K - 1`` inputs ``conv [state layers, rows, K - 1, channels]``.
+    The shapes are the ARCHITECTURE's (``cfg.state_shape`` /
+    ``cfg.conv_shape``): a gated delta-rule state ``[H, dk, dv]`` in a
+    hybrid's linear layers (models/hybrid.py), an SSD state ``[H, P, N]`` in
+    every layer where the mixer sits beside attention (models/falcon_h1.py).
+    Row 0 is the NULL row, as block 0 is the null block: inactive slots
+    riding along a decode step read and write it, and its contents are
+    value-invisible. Slot ``i`` owns row ``i + 1``.
 
     Its rules are not the block pool's. A state is one slot's, never
     shared: it is a function of the whole prefix, so a matched prefix block
     brings no state with it, and block-level prefix sharing is off for a
     model that has one. It starts at zero in the admission
-    (``HybridColumn.zeros``), is carried from prefill chunk to chunk there,
-    is written to the slot's row ONCE, at commit, goes through every decode
-    step in place, and is simply left behind at retirement: the next
+    (:meth:`StateColumn.zeros`), is carried from prefill chunk to chunk
+    there, is written to the slot's row ONCE, at commit, goes through every
+    decode step in place, and is simply left behind at retirement: the next
     admission's commit overwrites the row."""
 
     s: "jax.Array"
@@ -251,27 +255,47 @@ class StatePool(NamedTuple):
     def create(cls, cfg, n_slots: int, conv_dtype) -> "StatePool":
         import jax.numpy as jnp
 
-        from ..models.hybrid import conv_shape, state_shape
-
-        return cls(s=jnp.zeros(state_shape(cfg, n_slots + 1), jnp.float32),
-                   conv=jnp.zeros(conv_shape(cfg, n_slots + 1), conv_dtype))
+        return cls(s=jnp.zeros(cfg.state_shape(n_slots + 1), jnp.float32),
+                   conv=jnp.zeros(cfg.conv_shape(n_slots + 1), conv_dtype))
 
     @property
     def n_bytes(self) -> int:
         return self.s.nbytes + self.conv.nbytes
 
 
+class StateColumn(NamedTuple):
+    """One slot's context gathered for chunked prefill where it is more
+    than K/V (the ``KVCache`` of a decoder with a recurrent state): K/V of
+    the layers that cache it, the state layers' state and the convolution's
+    tail. What an admission carries from chunk to chunk and its commit
+    writes: K/V through the block table, ``s`` and ``conv`` to the slot's
+    row of :class:`StatePool`."""
+
+    k: "jax.Array"      # [n_kv_layers, B, n_kv, S, hd]
+    v: "jax.Array"
+    s: "jax.Array"      # cfg.state_shape(B), float32
+    conv: "jax.Array"   # cfg.conv_shape(B)
+
+    @classmethod
+    def zeros(cls, cfg, k: "jax.Array", v: "jax.Array",
+              conv_dtype) -> "StateColumn":
+        """A sequence's start: the given K/V column, zero state and tail."""
+        import jax.numpy as jnp
+
+        B = k.shape[1]
+        return cls(k=k, v=v, s=jnp.zeros(cfg.state_shape(B), jnp.float32),
+                   conv=jnp.zeros(cfg.conv_shape(B), conv_dtype))
+
+
 def state_pool_bytes(cfg, n_slots: int, conv_dtype_bytes: int) -> int:
     """Device bytes of :class:`StatePool` for ``n_slots`` (0 for a model
-    without recurrent layers): what ``runtime/hbm.py``'s fit counts."""
-    if not cfg.is_hybrid:
+    without a recurrent state): what ``runtime/hbm.py``'s fit counts."""
+    if not cfg.has_state:
         return 0
     import math
 
-    from ..models.hybrid import conv_shape, state_shape
-
-    return (math.prod(state_shape(cfg, n_slots + 1)) * 4
-            + math.prod(conv_shape(cfg, n_slots + 1)) * conv_dtype_bytes)
+    return (math.prod(cfg.state_shape(n_slots + 1)) * 4
+            + math.prod(cfg.conv_shape(n_slots + 1)) * conv_dtype_bytes)
 
 
 class HostKVMirror:

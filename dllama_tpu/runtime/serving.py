@@ -54,7 +54,7 @@ from ..parallel.multihost import (
 )
 from ..tokenizer.sampler import xorshift_random_f32
 from .kvblocks import (SPILL_BATCH, BlockPoolExhausted, PageInError,
-                       window_blocks_cap, window_first_block)
+                       StateColumn, window_blocks_cap, window_first_block)
 from .kvcache import KVCache
 
 if TYPE_CHECKING:
@@ -1461,12 +1461,12 @@ class PagedGenerator(_GeneratorCore):
             # before and after the first step
             pkv = self._pin_home(pkv)
         self.pkv = pkv
-        # a hybrid decoder's recurrent state, slot-indexed, beside the
-        # blocks (kvblocks.StatePool has its rules: never shared, written
-        # once at commit, in place through every step)
+        # a recurrent state, slot-indexed, beside the blocks, in the shape
+        # the architecture gives it (kvblocks.StatePool has its rules:
+        # never shared, written once at commit, in place through every step)
         self.spool = (StatePool.create(self.cfg, n_slots,
                                        jnp.dtype(self.cfg.compute_dtype))
-                      if self.cfg.is_hybrid else None)
+                      if self.cfg.has_state else None)
         # window layers: the second pool, its allocator and its tables,
         # and the routing counters the step and the chunks accumulate on
         # the device (models/laguna.py)
@@ -1537,14 +1537,12 @@ class PagedGenerator(_GeneratorCore):
                                  M * bs, self.cfg.head_dim)
             return KVCache(k=view(pkv.k), v=view(pkv.v))
 
-        def _take_hybrid_fn(pkv, table):
+        def _take_state_fn(pkv, table):
             # an admission starts from a zero state: prefix blocks are
             # never shared here, so a column is always a sequence's first
-            from ..models.hybrid import HybridColumn
-
             kv = _take_fn(pkv, table)
-            return HybridColumn.zeros(self.cfg, kv.k, kv.v,
-                                      self.spool.conv.dtype)
+            return StateColumn.zeros(self.cfg, kv.k, kv.v,
+                                     self.spool.conv.dtype)
 
         def _take_window_fn(pkv, table):
             # prefix blocks are never shared here, so an admission's column
@@ -1599,10 +1597,10 @@ class PagedGenerator(_GeneratorCore):
         # raw jit is deliberate for the three block-movement programs:
         # plan-independent gather/scatter/copy (no constrain()), safe to
         # share across engines — same argument as the dense pool's pair
-        self._take = jax.jit(_take_hybrid_fn if self.cfg.is_hybrid  # dlint: disable=jit-entry
+        self._take = jax.jit(_take_state_fn if self.cfg.has_state  # dlint: disable=jit-entry
                              else _take_window_fn if self.window else _take_fn)
         self._put_window = jax.jit(_put_window_fn, donate_argnums=(0, 1, 2))  # dlint: disable=jit-entry
-        # a hybrid decoder's commit writes the admission's state to the
+        # a recurrent state's commit writes the admission's state to the
         # slot's row of the state pool, in place
         self._state_put = jax.jit(_state_put_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
         self._put = jax.jit(_put_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
@@ -1919,9 +1917,9 @@ class PagedGenerator(_GeneratorCore):
                 "window, which has no wire format")
         if self.spool is not None:
             raise ValueError(
-                "kvwire export/ingest moves K/V blocks between replicas; a "
-                "hybrid decoder's blocks are useless without the recurrent "
-                "state, which has no wire format")
+                "kvwire export/ingest moves K/V blocks between replicas; "
+                "the blocks of a decoder with a recurrent state are useless "
+                "without that state, which has no wire format")
 
     def export_prefix(self, tokens: list[int]) -> tuple[int, list]:  # dlint: owner=loop-thread
         """Gather the device-resident shared-prefix blocks matching
@@ -2034,8 +2032,8 @@ class PagedGenerator(_GeneratorCore):
         if skip is not None:
             if req.score:
                 raise ValueError(
-                    "teacher-forced scoring is not carried to a hybrid "
-                    "decoder's recurrent state (its chunks run unmasked), "
+                    "teacher-forced scoring is not carried to a "
+                    "recurrent state (its chunks run unmasked), "
                     "nor to window layers (their column carries routing "
                     "counters, not scores)")
             # a matched block holds K/V this request did not compute and
@@ -2128,8 +2126,9 @@ class PagedGenerator(_GeneratorCore):
             # gather/scatter round-trip entirely — THE hot path of
             # repeated system prompts, where reuse must mean zero device
             # work beyond the one CoW copy
-            # (a hybrid decoder always takes one: its column carries the
-            # zero state its commit writes to the slot's row)
+            # (a decoder with a recurrent state always takes one: its
+            # column carries the zero state its commit writes to the
+            # slot's row)
             need_take = reused < len(rest) or self.spool is not None
             col = (self._exec_take(bids)
                    if need_take and not pairs else None)
@@ -2221,7 +2220,7 @@ class PagedGenerator(_GeneratorCore):
 
     def _exec_prefill(self, col, padded, pos: int, n_valid: int):
         # a recurrent state would keep what padding wrote into it: the
-        # hybrid's chunk carries its valid length (models/hybrid.forward);
+        # chunk of a decoder that has one carries its valid length;
         # a dense decoder pads freely and is passed none
         valid = (jnp.int32(n_valid),) if self.cfg.paged_only else ()
         with self.eng.watchdog.guard("batch_prefill"):
@@ -2523,7 +2522,7 @@ class PagedGenerator(_GeneratorCore):
         with self.flight.tick_phase("step_dispatch") as wait, \
                 self.eng.watchdog.guard("batch_step"):
             failpoints.fire("step_hang")
-            # a hybrid decoder's step takes the state pool beside the
+            # with a recurrent state the step takes the state pool beside the
             # blocks, both donated, and gives both back
             cache = (self.pkv if self.spool is None
                      else (self.pkv, self.spool))

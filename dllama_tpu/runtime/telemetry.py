@@ -189,6 +189,7 @@ PROGRAM_HBM_BYTES = "dllama_program_hbm_bytes"
 PROGRAM_FLOPS = "dllama_program_flops"
 Q40_MATMUL_PATHS = "dllama_q40_matmul_paths"
 GATED_DELTA_PATHS = "dllama_gated_delta_paths"
+SSD_PATHS = "dllama_ssd_paths"
 LAYER_KINDS = "dllama_layer_kinds"
 STATE_SLOTS_USED = "dllama_state_slots_used"
 STATE_SLOTS_TOTAL = "dllama_state_slots_total"
@@ -458,10 +459,16 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "Gated delta-rule mixers of a program's newest trace by form "
           "(chunk: a prefill chunk; step: the decode step) and the path "
           "they took: pallas (the gated_delta_step kernel) or xla"),
+    _spec(SSD_PATHS, "gauge",
+          "SSD (Mamba-2) mixers of a program's newest trace by form "
+          "(chunk: a prefill chunk; step: the decode step) and the path "
+          "they took: pallas (the ssd_step kernel) or xla"),
     _spec(LAYER_KINDS, "gauge",
           "Layers of the loaded model by kind (linear: gated delta-rule "
           "layers with a recurrent state; full: softmax attention with a "
-          "K/V cache); a dense decoder is all full"),
+          "K/V cache; ssm_beside_full: an SSD mixer with a recurrent state "
+          "and softmax attention side by side in one layer); a dense "
+          "decoder is all full"),
     _spec(STATE_SLOTS_USED, "gauge",
           "Rows of the recurrent state pool held by live sequences "
           "(committed and not yet retired); 0 without recurrent layers"),
@@ -474,7 +481,7 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
     _spec(PREFIX_REUSE_SKIPPED, "counter",
           "Admissions whose prompt matched cached prefix blocks that were "
           "NOT reused, by reason (recurrent_state: the blocks carry K/V "
-          "but no state of the linear-attention layers; window_layers: the "
+          "but no state of the layers that have one; window_layers: the "
           "window pool has already taken back the blocks the match names)"),
     _spec(KV_WINDOW_BLOCKS_USED, "gauge",
           "Blocks of the sliding-window layers' pool held by live "

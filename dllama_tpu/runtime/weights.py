@@ -536,6 +536,48 @@ def _load_hybrid_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
                          if dense_logits_wanted(ld.fast_numerics) else None)))
 
 
+def _load_falcon_h1_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
+    """One homogeneous stack of layers with an SSD mixer beside attention
+    (models/falcon_h1.py) from the tensors ``mfile._walk_falcon_h1_layer``
+    names."""
+    from ..models.falcon_h1 import FalconH1Layers
+    from ..models.llama import Params
+
+    h = ld.h
+    mm = lambda name, o, i: ld.matmul(name, o, i, stacked=True,
+                                      out_axis=None, in_axis=None)
+    f32 = ld.stacked_f32
+    layers = FalconH1Layers(
+        wq=mm("block_matmul_q", h.q_dim, h.dim),
+        wk=mm("block_matmul_k", h.kv_dim, h.dim),
+        wv=mm("block_matmul_v", h.kv_dim, h.dim),
+        wo=mm("block_matmul_wo", h.dim, h.q_dim),
+        w_in=mm("block_ssm_in", h.ssm_in_dim, h.dim),
+        w_dt=f32("block_ssm_dt", h.ssm_n_heads, h.dim),
+        conv_w=f32("block_ssm_conv", h.ssm_conv_kernel, h.ssm_conv_dim),
+        conv_b=f32("block_ssm_conv_bias", h.ssm_conv_dim),
+        a_log=f32("block_ssm_a_log", h.ssm_n_heads),
+        d_skip=f32("block_ssm_d", h.ssm_n_heads),
+        dt_bias=f32("block_ssm_dt_bias", h.ssm_n_heads),
+        norm_ssm=f32("block_ssm_norm", h.ssm_inner_dim),
+        w_out=mm("block_ssm_out", h.dim, h.ssm_inner_dim),
+        w1=mm("block_matmul_w1", h.hidden_dim, h.dim),
+        w2=mm("block_matmul_w2", h.dim, h.hidden_dim),
+        w3=mm("block_matmul_w3", h.hidden_dim, h.dim),
+        norm_att=f32("block_norm_0", h.dim),
+        norm_ffn=f32("block_norm_1", h.dim))
+    return Params(
+        embedding=ld.f32("embedding", h.vocab_size, h.dim,
+                         dtype=jnp.dtype(cfg.compute_dtype)),
+        layers=layers,
+        final_norm=ld.f32("final_norm", h.dim),
+        logits=ld.matmul(
+            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
+            out_axis="vocab", in_axis=None,
+            force_dense=(jnp.bfloat16
+                         if dense_logits_wanted(ld.fast_numerics) else None)))
+
+
 def _load_laguna_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
     """A decoder of window and full attention layers with an expert share
     (models/laguna.py) from the tensors ``mfile._walk_laguna_layer`` names:
@@ -614,6 +656,8 @@ def load_params(mf: ModelFile, cfg: "ModelConfig", weight_mode: str = "auto",
     qwen3 = h.arch_type == ArchType.QWEN3
     if h.arch_type == ArchType.OLMO_HYBRID:
         return _load_hybrid_params(ld, cfg)
+    if h.arch_type == ArchType.FALCON_H1:
+        return _load_falcon_h1_params(ld, cfg)
     if h.arch_type == ArchType.LAGUNA:
         if not ld.quantized:
             raise ValueError(

@@ -194,7 +194,7 @@ def compile_paged_step(cfg, params, n_slots: int, n_blocks: int,
     i32, f32 = jnp.int32, jnp.float32
     cache = pkv = shapes(PagedKVCache.create(cfg, n_blocks, block_size,
                                              dtype=pool_dtype))
-    if cfg.is_hybrid:
+    if cfg.has_state:
         cache = (pkv, shapes(StatePool.create(cfg, n_slots, pool_dtype)))
     B = n_slots
     compiled = jax.jit(llama.paged_sampled_step_guarded, static_argnums=1,
@@ -206,7 +206,8 @@ def compile_paged_step(cfg, params, n_slots: int, n_blocks: int,
 
 
 def param_shapes(cfg, scales_dtype):
-    """``Params`` of a dense or a hybrid decoder as shapes: Q40 planes for
+    """``Params`` of a dense decoder, a hybrid one or one with an SSD mixer
+    beside attention as shapes: Q40 planes for
     every matmul of the layer stack(s), a dense head in the compute dtype.
     For compiling a program, not for running it."""
     import jax
@@ -243,6 +244,20 @@ def param_shapes(cfg, scales_dtype):
             norm_att=f32(NL, dim), norm_ffn=f32(NL, dim))
         layers = HybridLayers(lin=lin, full=full(NF, dict(
             norm_q=f32(NF, cfg.q_dim), norm_k=f32(NF, cfg.kv_dim))))
+    elif cfg.has_ssm:
+        from dllama_tpu.models.falcon_h1 import FalconH1Layers
+
+        L, H, d_ssm = cfg.n_layers, cfg.ssm_heads, cfg.ssm_inner_dim
+        layers = FalconH1Layers(
+            wq=q40(L, cfg.q_dim, dim), wk=q40(L, cfg.kv_dim, dim),
+            wv=q40(L, cfg.kv_dim, dim), wo=q40(L, dim, cfg.q_dim),
+            w_in=q40(L, cfg.ssm_in_dim, dim), w_dt=f32(L, H, dim),
+            conv_w=f32(L, cfg.ssm_conv_kernel, cfg.ssm_conv_dim),
+            conv_b=f32(L, cfg.ssm_conv_dim), a_log=f32(L, H),
+            d_skip=f32(L, H), dt_bias=f32(L, H), norm_ssm=f32(L, d_ssm),
+            w_out=q40(L, dim, d_ssm), w1=q40(L, hid, dim),
+            w2=q40(L, dim, hid), w3=q40(L, hid, dim),
+            norm_att=f32(L, dim), norm_ffn=f32(L, dim))
     else:
         layers = full(cfg.n_layers, dict(norm_q=None, norm_k=None))
     dense = jnp.dtype(cfg.compute_dtype)

@@ -10,7 +10,7 @@ the seam regress.
 
 **Three cases a PR's two cells are red, and are marked so** (``KNOWN_RED``,
 strict: one that turns green fails the run until its row is taken out; PR 30's
-three and, for the same reason, PR 34's three). Two of
+three and, for the same reason, PR 34's three and PR 36's two). Two of
 ``test_modules.py``'s tests run over EVERY cell of ``BENCHMARK.json`` and
 assert what held of PR 29's three cells: 24 rows a cell in
 ``selftest/counts_frozen.json``, and no configuration naming modules. PR 30's
@@ -33,7 +33,12 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SELFTEST = os.path.join(BENCH, "selftest")
 NEW_CELLS = ("olmo-hybrid-7b.long-prompt", "mistral-7b-v0.3.single-stream")
 PR34_CELLS = ("laguna-s-2.1.mixed-queue", "mistral-7b-v0.3.mixed-queue")     # the same three cases, for the same reason
+PR36_CELL = "falcon-h1-34b.chat"     # one cell, so two of the three cases
 KNOWN_RED = {
+    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR36_CELL}]":
+        "selftest/counts_frozen.json has no rows for the cell PR 36 added",
+    f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{PR36_CELL}]":
+        "falcon-h1-34b names its modules: the test asserts that no configuration of BENCHMARK.json does",
     f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR34_CELLS[0]}]":
         "selftest/counts_frozen.json has no rows for a cell PR 34 added",
     f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR34_CELLS[1]}]":
@@ -129,6 +134,29 @@ def test_the_routed_cell_gets_the_modules_it_names_and_mixed_queue_the_dense_one
     assert mods["counts"].kernel_counts(conf["model"], "expert_gemv", rows=16)["layers"] == 23
     _cell, conf, _traffic, mods = _seam._resolve(PR34_CELLS[1])
     assert "modules" not in conf and os.path.relpath(mods["counts"].__file__, BENCH) == "counts.py"
+
+
+# -- and PR 36's cell, from a file of PR 36's own -----------------------------------
+
+with open(os.path.join(BENCH, "falcon_h1", "selftest", "counts_frozen.json"), encoding="utf-8") as _f:
+    PR36_FROZEN = json.load(_f)
+
+
+def test_pr36s_cell_counts_through_the_seam_are_what_pr36_froze():
+    _cell, conf, _traffic, mods = _seam._resolve(PR36_CELL)
+    rows = [r for r in PR36_FROZEN["rows"] if r["cell"] == PR36_CELL]
+    assert len(rows) == 24
+    for r in rows:
+        assert getattr(mods["counts"], r["fn"])(conf["model"], **r["args"]) == r["value"], r
+
+
+def test_the_side_by_side_cell_gets_the_modules_it_names():
+    _cell, conf, _traffic, mods = _seam._resolve(PR36_CELL)
+    assert {k: os.path.relpath(m.__file__, BENCH) for k, m in mods.items()} == conf["modules"] == {
+        "reference": "falcon_h1/reference.py", "weights": "falcon_h1/weights.py", "counts": "falcon_h1/counts.py"}
+    assert {"dropstate", "nodecay", "dropssm", "bf16state", "shift"} <= set(mods["reference"].CONTROLS)
+    assert mods["counts"].kernel_counts(conf["model"], "ssd_step", rows=16)["calls_per_program"] == 12
+    assert conf["reduced"] == ["num_hidden_layers", "max_position_embeddings"] and conf["vocab_size"] == 261120
 
 
 # -- the form the driver holds BENCHMARK.json to before any run --------------------

@@ -125,8 +125,16 @@ OLMO_HYBRID_7B = [(3840, 17280), (5760, 3840), (3840, 3840), (3840, 11008),
                   (11008, 3840)]
 
 
+# tiiuae/Falcon-H1-34B-Instruct: q, k and v, wo, the mixer's packed z x B C
+# plane (9216 = 72 x 128; the published in-projection is 9248 wide, its 32 dt
+# rows are a float32 plane) and its output plane, the feed-forward
+FALCON_H1_34B = [(5120, 2560), (5120, 512), (2560, 5120), (5120, 9216),
+                 (4096, 5120), (5120, 21504), (21504, 5120)]
+
+
 @pytest.mark.parametrize("rows", [4, 16])
-@pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B + OLMO_HYBRID_7B)
+@pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B + OLMO_HYBRID_7B
+                         + FALCON_H1_34B)
 def test_decode_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
     """The fused dequant-GEMV as the paged step calls it in fast mode: bf16
     rows, bf16 scales as a fast-mode load stores them, the LAYER STACK and
@@ -151,7 +159,8 @@ def test_decode_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
 
 
 @pytest.mark.parametrize("rows", [64, 256])
-@pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B + OLMO_HYBRID_7B)
+@pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B + OLMO_HYBRID_7B
+                         + FALCON_H1_34B)
 def test_chunk_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
     """The same kernel as a prefill chunk's ``forward`` calls it (PR 35):
     a 64- or 256-row bucket of bf16 rows, the layer stack and a traced
@@ -320,6 +329,7 @@ def test_paged_attention_compiles_for_v5e(one_chip, block, per_seq, slots,
     (16, 5, 32, 8, 64),      # paged_verify_step under --spec-lookup 4
     (4, 16, 32, 8, 256),     # a 16-wide verify / prefill tail
     (2, 128, 32, 8, 64),     # MAX_TQ folded query rows: fewer heads a step
+    (16, 1, 20, 4, 80),      # falcon-h1-34b.chat: a group of 5 query heads a K/V head
 ])
 def test_paged_attention_compiles_at_the_cells_geometries_for_v5e(
         one_chip, slots, t, n_heads, n_kv, per_seq):
@@ -348,6 +358,27 @@ def test_gated_delta_step_compiles_for_v5e(one_chip, slots):
 
     assert mosaic_kernels(compiled.as_text()).get("gated_delta_step") == 1
     # in place: the program holds no second pool (24 x 5 x 2.2 MB = 265 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024 * 1024
+
+
+def test_ssd_step_compiles_for_v5e(one_chip):
+    """The SSD step form's kernel at Falcon-H1-34B's sizes (12 layers held,
+    32 heads of 128 x 256 in 2 groups, 16 slots and the null row), over the
+    state pool in place: one Mosaic kernel, its output aliased onto the pool."""
+    from dllama_tpu.ops.ssd import ssd_step
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+
+    slots, H, P, G, N = 16, 32, 128, 2, 256
+    f32 = jnp.float32
+    pool = _shape(one_chip, (12, slots + 1, H, P, N), f32)
+    compiled = jax.jit(functools.partial(ssd_step, interpret=False),
+                       donate_argnums=(0,)).lower(
+        pool, _shape(one_chip, (), jnp.int32), _shape(one_chip, (slots,), jnp.int32),
+        _shape(one_chip, (slots, H, P), f32), _shape(one_chip, (slots, H), f32),
+        _shape(one_chip, (slots, H), f32), _shape(one_chip, (slots, G, N), f32),
+        _shape(one_chip, (slots, G, N), f32)).compile()
+    assert mosaic_kernels(compiled.as_text()).get("ssd_step") == 1
+    # in place: the program holds no second pool (12 x 17 x 4.19 MB = 856 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024 * 1024
 
 
