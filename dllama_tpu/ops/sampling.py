@@ -91,42 +91,55 @@ def sampled_token(logits: jax.Array, temperature: jax.Array, topp: jax.Array,
     (ragged batched serving): per-row knobs, with ``temperature <= 0`` rows
     taking the greedy argmax — one fused program covers a mixed batch.
     ``topp`` outside (0, 1) selects plain multinomial, matching the host
-    oracle."""
+    oracle. A batch with no ``temperature > 0`` row runs the argmax alone
+    (one ``lax.cond`` on the traced temperatures: same program, no
+    recompile when a sampling request joins)."""
     logits = logits.astype(jnp.float32)
     B, V = logits.shape
     temp = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(temperature)), (B,))
     topp_v = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(topp)), (B,))
     coin_v = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(coin)), (B,))
-    safe_t = jnp.where(temp > 0.0, temp, 1.0)
-    probs = jax.nn.softmax(logits / safe_t[:, None], axis=-1)
-    # greedy rows (temp <= 0) never use their nucleus draw, so they must not
-    # be able to force the full-vocab sort fallback for the whole batch: a
-    # serving batch of mostly-greedy rows keeps the windowed fast path
-    topp_row = (topp_v > 0.0) & (topp_v < 1.0) & (temp > 0.0)
 
-    if V > TOPP_WINDOW:
-        K = TOPP_WINDOW
-        cutoff = ((1.0 - topp_v) / (V - 1))[:, None]
-        masked = jnp.where(probs >= cutoff, probs, 0.0)
-        n_kept = jnp.count_nonzero(masked, axis=-1).astype(jnp.int32)
-        vals, idxs = jax.lax.top_k(masked, K)
-        # the window covers the nucleus iff it either exhausts the kept set
-        # or its cumulative mass already crosses topp
-        window_ok = (jnp.cumsum(vals, axis=-1)[:, -1] > topp_v) | (n_kept <= K)
-        all_safe = jnp.all(window_ok | ~topp_row)
+    def greedy_path():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        def windowed():
-            return jax.vmap(_nucleus_pick)(vals, topp_v, coin_v,
-                                           jnp.minimum(n_kept, K), idxs)
+    def sampled_path():
+        safe_t = jnp.where(temp > 0.0, temp, 1.0)
+        probs = jax.nn.softmax(logits / safe_t[:, None], axis=-1)
+        # greedy rows (temp <= 0) never use their nucleus draw, so they must
+        # not be able to force the full-vocab sort fallback for the whole
+        # batch: a serving batch of mostly-greedy rows keeps the windowed
+        # fast path
+        topp_row = (topp_v > 0.0) & (topp_v < 1.0) & (temp > 0.0)
 
-        def full():
-            return jax.vmap(topp_sample)(probs, topp_v, coin_v)
+        if V > TOPP_WINDOW:
+            K = TOPP_WINDOW
+            cutoff = ((1.0 - topp_v) / (V - 1))[:, None]
+            masked = jnp.where(probs >= cutoff, probs, 0.0)
+            n_kept = jnp.count_nonzero(masked, axis=-1).astype(jnp.int32)
+            vals, idxs = jax.lax.top_k(masked, K)
+            # the window covers the nucleus iff it either exhausts the kept
+            # set or its cumulative mass already crosses topp
+            window_ok = ((jnp.cumsum(vals, axis=-1)[:, -1] > topp_v)
+                         | (n_kept <= K))
+            all_safe = jnp.all(window_ok | ~topp_row)
 
-        nucleus = jax.lax.cond(all_safe, windowed, full)
-    else:
-        nucleus = jax.vmap(topp_sample)(probs, topp_v, coin_v)
+            def windowed():
+                return jax.vmap(_nucleus_pick)(vals, topp_v, coin_v,
+                                               jnp.minimum(n_kept, K), idxs)
 
-    multi = jax.vmap(mult_sample)(probs, coin_v)
-    sampled = jnp.where(topp_row, nucleus, multi)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jnp.where(temp > 0.0, sampled, greedy)
+            def full():
+                return jax.vmap(topp_sample)(probs, topp_v, coin_v)
+
+            nucleus = jax.lax.cond(all_safe, windowed, full)
+        else:
+            nucleus = jax.vmap(topp_sample)(probs, topp_v, coin_v)
+
+        multi = jax.vmap(mult_sample)(probs, coin_v)
+        sampled = jnp.where(topp_row, nucleus, multi)
+        return jnp.where(temp > 0.0, sampled, greedy_path())
+
+    # a batch in which no row samples reads none of the vocabulary-wide
+    # softmax, top_k and cumulative sums; batch level like the cond above
+    # (per row it would lower to a select under vmap and run both sides)
+    return jax.lax.cond(jnp.any(temp > 0.0), sampled_path, greedy_path)

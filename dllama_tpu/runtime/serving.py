@@ -361,6 +361,7 @@ class _GeneratorCore:
         self._m_step_ms = self._tm.histogram(telemetry.BATCH_STEP_MS)
         self._m_occupancy = self._tm.gauge(telemetry.BATCH_OCCUPANCY)
         self._m_tokens = self._tm.counter(telemetry.BATCH_TOKENS)
+        self._m_sampler = self._tm.counter(telemetry.SAMPLER_STEPS)
         self._m_kv = self._tm.gauge(telemetry.KV_OCCUPANCY)
         # flight recorder (runtime/flightrec): the scheduler opens/closes
         # ticks; the generator records decisions and dispatch/prefill wall
@@ -531,7 +532,9 @@ class _GeneratorCore:
         form: one xorshift coin drawn and committed per temperature>0
         row — multi-step dispatches pre-draw from a COPY instead, see
         step_chunk). Shared so the coin-stream rules can never diverge
-        between the dense and paged paths."""
+        between the dense and paged paths. The temperatures also decide
+        the step's sampler path (``ops.sampling.sampled_token``: the argmax
+        alone unless a row samples), counted here once a dispatch."""
         temps = np.zeros(self.n_slots, dtype=np.float32)
         topps = np.zeros(self.n_slots, dtype=np.float32)
         coins = np.zeros(self.n_slots, dtype=np.float32)
@@ -541,6 +544,8 @@ class _GeneratorCore:
             topps[i] = req.topp
             if req.temperature > 0.0:
                 coins[i], req.rng_state = xorshift_random_f32(req.rng_state)
+        self._m_sampler.inc(
+            path="sampled" if (temps > 0.0).any() else "greedy")
         return temps, topps, coins
 
     def _record_step(self, n_active: int, ms: float, emitted: int) -> None:

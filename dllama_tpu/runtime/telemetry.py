@@ -70,6 +70,7 @@ BATCH_SLOTS = "dllama_batch_slots"
 BATCH_TOKENS = "dllama_batch_tokens_total"
 ADMISSIONS = "dllama_admissions_total"
 RETIRES = "dllama_retires_total"
+SAMPLER_STEPS = "dllama_sampler_steps_total"
 PREFIX_REUSE_TOKENS = "dllama_prefix_reuse_tokens_total"
 # paged KV block pool (runtime/kvblocks.py via runtime/serving.py)
 KV_BLOCKS_TOTAL = "dllama_kv_blocks_total"
@@ -290,6 +291,11 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
     _spec(BATCH_TOKENS, "counter", "Tokens emitted by batched serving"),
     _spec(ADMISSIONS, "counter", "Requests admitted into a slot"),
     _spec(RETIRES, "counter", "Slots retired (EOS, limits, or cancel)"),
+    _spec(SAMPLER_STEPS, "counter",
+          "Batched decode dispatches by the sampler path their temperatures "
+          "select on the device (label path): greedy = no live row has "
+          "temperature > 0, so the step takes the argmax alone; sampled = "
+          "at least one does, so the softmax / top_k / CDF run"),
     _spec(PREFIX_REUSE_TOKENS, "counter",
           "Prompt tokens skipped via KV prefix reuse (cross-slot on the "
           "dense pool; block-level sharing + copy-on-write on the paged "

@@ -13,7 +13,8 @@ from dllama_tpu.ops.sampling import sampled_token
 from dllama_tpu.runtime.engine import InferenceEngine
 from dllama_tpu.tokenizer.sampler import Sampler, softmax, xorshift_random_f32
 
-from helpers import byte_vocab_tokenizer, tiny_header_params, write_tiny_model
+from helpers import (byte_vocab_tokenizer, param_shapes, tiny_header_params,
+                     write_tiny_model)
 
 VOCAB = 257  # odd size: exercises the cutoff denominator (n-1)
 
@@ -143,3 +144,192 @@ def test_sampling_knob_change_does_not_recompile(model_files):
     e.sampler.topp = 0.5
     e.generate("world", 2, stop_on_eos=False)
     assert sampled_compiles() == before
+
+
+# ---------------------------------------------------------------------------
+# the batch-level skip: a batch in which no row samples takes the argmax alone
+# ---------------------------------------------------------------------------
+
+WIDE = 1031  # wider than TOPP_WINDOW: the top_k window and its fallback exist
+
+
+def _parent_sampled_token(logits, temperature, topp, coin):
+    """``sampled_token``'s body as it stood before the batch-level skip
+    (every row pays the softmax, the ``top_k`` and both cumulative sums, the
+    last line picks): the oracle the skip must match token for token."""
+    from dllama_tpu.ops.sampling import (TOPP_WINDOW, _nucleus_pick,
+                                         mult_sample, topp_sample)
+    logits = logits.astype(jnp.float32)
+    B, V = logits.shape
+    temp = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(temperature)), (B,))
+    topp_v = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(topp)), (B,))
+    coin_v = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(coin)), (B,))
+    safe_t = jnp.where(temp > 0.0, temp, 1.0)
+    probs = jax.nn.softmax(logits / safe_t[:, None], axis=-1)
+    topp_row = (topp_v > 0.0) & (topp_v < 1.0) & (temp > 0.0)
+    assert V > TOPP_WINDOW
+    K = TOPP_WINDOW
+    cutoff = ((1.0 - topp_v) / (V - 1))[:, None]
+    masked = jnp.where(probs >= cutoff, probs, 0.0)
+    n_kept = jnp.count_nonzero(masked, axis=-1).astype(jnp.int32)
+    vals, idxs = jax.lax.top_k(masked, K)
+    window_ok = (jnp.cumsum(vals, axis=-1)[:, -1] > topp_v) | (n_kept <= K)
+    all_safe = jnp.all(window_ok | ~topp_row)
+    nucleus = jax.lax.cond(
+        all_safe,
+        lambda: jax.vmap(_nucleus_pick)(vals, topp_v, coin_v,
+                                        jnp.minimum(n_kept, K), idxs),
+        lambda: jax.vmap(topp_sample)(probs, topp_v, coin_v))
+    multi = jax.vmap(mult_sample)(probs, coin_v)
+    sampled = jnp.where(topp_row, nucleus, multi)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jnp.where(temp > 0.0, sampled, greedy)
+
+
+@pytest.mark.parametrize("topps", [
+    pytest.param([0.9, 0.5, 0.95, 0.9, 0.05, 0.9], id="nucleus"),
+    pytest.param([0.9, 1.0, 0.0, 0.9, 1.0, 0.3], id="nucleus+multinomial"),
+    # flat rows at top-p 0.999: the 256-wide window cannot hold the nucleus
+    pytest.param([0.999] * 6, id="full-sort-fallback"),
+])
+@pytest.mark.parametrize("temps", [
+    pytest.param([0.0] * 6, id="all-greedy"),
+    pytest.param([0.0, 0.0, 0.8, 0.0, 0.0, 0.0], id="one-samples"),
+    pytest.param([0.0, 0.7, 0.0, 1.3, 0.0, 0.9], id="mixed"),
+    pytest.param([0.8, 0.7, 1.0, 1.3, 0.5, 0.9], id="all-sample"),
+])
+def test_batch_level_skip_matches_the_parents_body(jit_sampled, temps, topps):
+    """For all-greedy, mixed and all-sampling temperature rows the tokens are
+    exactly what the parent's body gives for the same logits, knobs and
+    coins, and a greedy row's is the argmax of its float32 logits."""
+    rng = np.random.default_rng(0xC0FFEE)
+    parent = jax.jit(_parent_sampled_token)
+    temps = jnp.asarray(temps, jnp.float32)
+    topps = jnp.asarray(topps, jnp.float32)
+    scale = 0.05 if float(topps[0]) == 0.999 else 3.0
+    state = 0xFEED
+    for _ in range(25):
+        logits = (rng.standard_normal((6, WIDE)) * scale).astype(np.float32)
+        coins = np.zeros(6, np.float32)
+        for i in range(6):
+            coins[i], state = xorshift_random_f32(state)
+        got = np.asarray(jit_sampled(logits, temps, topps, coins))
+        want = np.asarray(parent(logits, temps, topps, coins))
+        np.testing.assert_array_equal(got, want)
+        greedy = np.asarray(temps) <= 0.0
+        np.testing.assert_array_equal(got[greedy],
+                                      logits.argmax(-1)[greedy])
+
+
+_VOCAB_WIDE = ("top_k", "sort", "cumsum", "exp")
+
+
+def _walk(jaxpr, under_cond, seen):
+    """Every equation of ``jaxpr`` and its sub-jaxprs as (primitive name,
+    whether a ``cond`` encloses it)."""
+    for eqn in jaxpr.eqns:
+        seen.append((eqn.primitive.name, under_cond))
+        inner = under_cond or eqn.primitive.name == "cond"
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _walk(sub, inner, seen)
+
+
+def _sampler_alone():
+    S = jax.ShapeDtypeStruct
+    return jax.make_jaxpr(sampled_token)(
+        S((4, WIDE), jnp.float32), S((4,), jnp.float32),
+        S((4,), jnp.float32), S((4,), jnp.float32))
+
+
+def _paged_step():
+    from dllama_tpu.formats.mfile import ArchType, RopeType
+    from dllama_tpu.models import llama
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.runtime.kvblocks import PagedKVCache
+
+    cfg = ModelConfig(arch=ArchType.LLAMA, dim=64, hidden_dim=128, n_layers=2,
+                      n_heads=4, n_kv_heads=2, head_dim=16, vocab_size=512,
+                      seq_len=128, norm_epsilon=1e-5, rope_theta=10000.0,
+                      rope_type=RopeType.LLAMA, compute_dtype="bfloat16")
+    S, i32, f32 = jax.ShapeDtypeStruct, jnp.int32, jnp.float32
+    pkv = jax.eval_shape(
+        lambda: PagedKVCache.create(cfg, 33, 16, dtype=jnp.bfloat16))
+    return jax.make_jaxpr(llama.paged_sampled_step_guarded, static_argnums=1)(
+        param_shapes(cfg, jnp.bfloat16), cfg, S((4, 1), i32), S((4,), i32),
+        pkv, S((4, 8), i32), S((4,), f32), S((4,), f32), S((4,), f32),
+        S((), f32))
+
+
+@pytest.mark.parametrize("trace", [
+    pytest.param(_sampler_alone, id="sampled_token"),
+    pytest.param(_paged_step, id="paged_sampled_step_guarded"),
+])
+def test_the_samplers_vocabulary_wide_ops_sit_under_a_cond(trace):
+    """No ``top_k``, sort or cumulative sum of the traced program lies outside
+    a ``cond``'s branch (nor the softmax's ``exp`` in the sampler alone), and
+    the ``top_k`` is there, inside one: the skip cannot silently rot."""
+    seen = []
+    _walk(trace().jaxpr, False, seen)
+    outside = {name for name, under in seen if not under}
+    inside = {name for name, under in seen if under}
+    wide = set(_VOCAB_WIDE)
+    if trace is _paged_step:
+        wide.discard("exp")  # the layers' attention softmax and SiLU
+    assert not outside & wide, sorted(outside & wide)
+    assert {"top_k", "cumsum", "exp"} <= inside
+    assert "argmax" in inside  # the greedy branch
+
+
+@pytest.fixture(scope="module")
+def paged_engine(model_files):
+    e = InferenceEngine(*model_files, tp=1, kv_block_size=16)
+    yield e
+    e.close()
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_sampler_path_counter_follows_the_temperatures(model_files,
+                                                       paged_engine, kind):
+    """``dllama_sampler_steps_total`` counts one dispatch a step: ``greedy``
+    while no live row samples, ``sampled`` once one does, and ``greedy``
+    again when that row has retired."""
+    from dllama_tpu.runtime import telemetry as tm
+    from dllama_tpu.runtime.serving import (BatchedGenerator, PagedGenerator,
+                                            Request)
+
+    if kind == "dense":
+        eng = InferenceEngine(*model_files, tp=1)
+        gen = BatchedGenerator(eng, n_slots=2)
+    else:
+        eng = paged_engine
+        gen = PagedGenerator(eng, n_slots=2)
+    counter = tm.registry().counter(tm.SAMPLER_STEPS)
+    read = lambda: (counter.total(path="greedy"), counter.total(path="sampled"))
+    enc = lambda p: eng.tokenizer.encode(p, is_start=True)
+
+    g0, s0 = read()
+    r_greedy = Request(rid=0, prompt_ids=enc("hello world"), max_tokens=12,
+                       stop_on_eos=False)
+    gen.admit(r_greedy, 0)
+    for _ in range(3):
+        gen.step()
+    assert read() == (g0 + 3, s0)
+
+    r_sampled = Request(rid=1, prompt_ids=enc("hello"), max_tokens=4,
+                        stop_on_eos=False, temperature=0.8, topp=0.9, seed=5)
+    gen.admit(r_sampled, 1)
+    n = 0
+    while not r_sampled.done.is_set():
+        gen.step()
+        n += 1
+    assert n >= 2 and read() == (g0 + 3, s0 + n)
+
+    gen.step()
+    assert read() == (g0 + 4, s0 + n)
+    while gen.n_active:
+        gen.step()
+    if kind == "dense":
+        eng.close()

@@ -3,7 +3,8 @@ the paged decode step and a prefill chunk, for the Llama and the Qwen3
 equations. ``tests/test_olmo_hybrid.py`` holds them against
 ``tests/goldens/dense_hlo_sha256.json``, which PR 30 wrote from its PARENT
 commit (PR 35 rewrote the two ``forward`` digests: a chunk scans the layer
-index now; the step's are unchanged since): a change to ``models/llama.py`` or ``ModelConfig`` that alters what a
+index now; PR 39 the two step digests: the sampler's vocabulary-wide ops sit under a
+``lax.cond``): a change to ``models/llama.py`` or ``ModelConfig`` that alters what a
 dense configuration compiles shows up as a mismatch. After a deliberate
 change: ``python tools/dense_hlo_digest.py > tests/goldens/dense_hlo_sha256.json``.
 """
