@@ -38,6 +38,9 @@ batch) needs. This module is that record:
   profiler capture shows the loop thread on the device lanes' clock and
   names the same tick as this ring), an entry of the tick record's
   ``phases``, and a series of ``dllama_tick_phase_ms_total{phase}``.
+  Inside ``step_wait`` each blocking fetch is a nested
+  ``dllama.step.fetch`` annotation (:func:`fetch_span`), the profiler's
+  alone.
 
 Dependency-free (stdlib + runtime.telemetry only — importable without
 jax: the serving layer injects the annotation factory through
@@ -49,6 +52,7 @@ an audit log.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import tempfile
@@ -72,6 +76,7 @@ _NO_SLOT_TID = 999
 # None keeps this module jax-free (phases then only feed the tick record
 # and the counter)
 _annotate = None
+_NO_SPAN = contextlib.nullcontext()
 
 
 def set_annotation_factory(factory) -> None:
@@ -80,6 +85,18 @@ def set_annotation_factory(factory) -> None:
     ``set_metadata``); ``None`` turns annotations off."""
     global _annotate
     _annotate = factory
+
+
+def fetch_span(what: str):
+    """``with flightrec.fetch_span("tokens"):`` — one blocking fetch of a
+    step output, nested in the step's ``step_wait`` phase as the
+    profiler annotation ``telemetry.STEP_FETCH_SPAN`` (``what=<output>``).
+    The trace is its one record: nothing is written to the tick record
+    or the registry and no lock is taken, so with no profiler running it
+    is the annotation's ~0.4 µs no-op."""
+    if _annotate is None:
+        return _NO_SPAN
+    return _annotate(telemetry.STEP_FETCH_SPAN, what=what)
 
 
 def _phase_sums(phase_spans) -> dict:

@@ -31,7 +31,7 @@ import statistics
 import threading
 import time
 from collections import deque
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Callable, NamedTuple
 
@@ -284,6 +284,7 @@ class _Admission:
     col: KVCache  # the slot's gathered cache column, being filled
     pos: int = 0
     reused: int = 0  # prefix tokens skipped via cross-slot KV reuse
+    bucket: int = 0  # the last chunk's DISPATCHED (padded) width
     # KV tier (paged pool with --kv-host-blocks): outstanding page-in
     # pairs (host_bid, dev_bid) — drained in SPILL_BATCH batches, one per
     # continue_admit call, so a long resume's restore interleaves with
@@ -307,6 +308,56 @@ class _PendingChunk(NamedTuple):
     width: int      # dispatched (padded) tokens
     n_valid: int
     t_enqueue_ns: int
+
+
+class _StepIO:
+    """One step's way through its three phases
+    (:meth:`_GeneratorCore._step_io`): ``step_upload`` while :meth:`call`
+    makes the device arguments, ``step_dispatch`` (the plan context and
+    the jitted call alone) until :meth:`fetch`, ``step_wait`` from there.
+    Every step path goes through this one object, so the phases' names
+    stay at the same boundaries on all of them."""
+
+    __slots__ = ("_gen", "span")
+
+    def __init__(self, gen: "_GeneratorCore", span):
+        self._gen = gen
+        self.span = span  # the open phase: step_wait's once fetch() ran
+
+    def call(self, program, cache, *host, static=()):
+        """``program(params, cfg, tokens, pos, cache, *rest, *static,
+        poison)``, the signature every step and verify program has:
+        ``host`` (tokens, positions, then the rest) uploaded in that
+        order, then the tripwire's poison selector. While a profiler
+        listens ``step_upload`` carries how many transfers that was and
+        their host bytes. The device arguments die with this frame, as
+        the call's temporaries did."""
+        gen = self._gen
+        dev = [jnp.asarray(a) for a in host]
+        poison = gen._poison()
+        if self.span.traced:
+            self.span.set(arrays=len(dev) + 1,
+                          bytes=sum(a.nbytes for a in host) + 4)
+        self.span.next_phase("step_dispatch")
+        with gen._plan_ctx():
+            return program(gen.eng.params, gen.cfg, dev[0], dev[1], cache,
+                           *dev[2:], *static, poison)
+
+    def fetch(self, together: bool = False, **outs) -> tuple:
+        """The step's outputs on the host, in the order named: one
+        blocking fetch each (``together``: one ``device_get`` of them
+        all), each a ``dllama.step.fetch`` span under ``step_wait``. The
+        first waits for the device; one that starts after the program
+        has ended is the copy alone. No sync but the fetches themselves."""
+        self.span.next_phase("step_wait")
+        if together:
+            with flightrec.fetch_span("/".join(outs)):
+                return jax.device_get(tuple(outs.values()))
+        got = []
+        for what, out in outs.items():
+            with flightrec.fetch_span(what):
+                got.append(np.asarray(out))
+        return tuple(got)
 
 
 @dataclass
@@ -411,6 +462,18 @@ class _GeneratorCore:
         failpoint (runtime/numerics)."""
         return jnp.float32(0.0 if self.eng.multihost
                            else numerics.poison_code())
+
+    @contextmanager
+    def _step_io(self, guard: str):
+        """``with self._step_io("batch_step") as io:`` — one step or
+        verify dispatch under the watchdog's ``guard``, which covers the
+        uploads, the call and the wait: ``io.call(program, cache,
+        *host_arrays)`` uploads and dispatches, ``io.fetch(...)`` brings
+        the outputs back (:class:`_StepIO`)."""
+        with self.flight.tick_phase("step_upload") as span, \
+                self.eng.watchdog.guard(guard):
+            failpoints.fire("step_hang")
+            yield _StepIO(self, span)
 
     def _retire(self, slot: int, reason: str = "done") -> None:  # dlint: owner=loop-thread
         req = self.slots[slot]
@@ -592,6 +655,19 @@ class _GeneratorCore:
             self.flight.note("spec_degraded", self.slots[i].rid,
                              reason=type(e).__name__, slot=i)
             return None
+
+    def _advance_traced(self, adm: "_Admission", span) -> bool:  # dlint: owner=loop-thread
+        """``_advance_prefill`` under its ``prefill_dispatch`` phase; while
+        a profiler listens the phase names its cause: the request, the
+        valid tokens of the chunk this call enqueued and the padded
+        width dispatched (both 0 for a call that only paged blocks in)."""
+        pos0 = adm.pos
+        done = self._advance_prefill(adm)
+        if span.traced:
+            tokens = adm.pos - pos0
+            span.set(rid=adm.req.rid, tokens=tokens,
+                     bucket=adm.bucket if tokens else 0)
+        return done
 
     def _prefill_chunk(self, adm: "_Admission", padded, n_valid: int) -> None:
         """One prefill chunk dispatch for ``adm``. The dispatch only
@@ -982,52 +1058,32 @@ class BatchedGenerator(_GeneratorCore):
         self.kv = self._put(self.kv, col, slot)
 
     def _exec_step(self, tokens, pos, temps, topps, coins):
-        with self.flight.tick_phase("step_dispatch") as self._wait, \
-                self.eng.watchdog.guard("batch_step"):
-            failpoints.fire("step_hang")
-            with self._plan_ctx():
-                (nxt, nf), self.kv = self._step(
-                    self.eng.params, self.cfg,
-                    jnp.asarray(np.asarray(tokens, np.int32)[:, None]),
-                    jnp.asarray(np.asarray(pos, np.int32)), self.kv,
-                    jnp.asarray(np.asarray(temps, np.float32)),
-                    jnp.asarray(np.asarray(topps, np.float32)),
-                    jnp.asarray(np.asarray(coins, np.float32)),
-                    self._poison())
-            self._wait.next_phase("step_wait")
-            return np.asarray(nxt), np.asarray(nf)
+        with self._step_io("batch_step") as io:
+            self._wait = io.span
+            (nxt, nf), self.kv = io.call(
+                self._step, self.kv, np.asarray(tokens, np.int32)[:, None],
+                np.asarray(pos, np.int32), np.asarray(temps, np.float32),
+                np.asarray(topps, np.float32), np.asarray(coins, np.float32))
+            return io.fetch(tokens=nxt, nonfinite=nf)
 
     def _exec_step_chunk(self, tokens, pos, temps, topps, coins, k: int):
-        with self.flight.tick_phase("step_dispatch") as self._wait, \
-                self.eng.watchdog.guard("batch_chunk"):
-            failpoints.fire("step_hang")
-            with self._plan_ctx():
-                (toks, nf), self.kv = self._steps(
-                    self.eng.params, self.cfg,
-                    jnp.asarray(np.asarray(tokens, np.int32)),
-                    jnp.asarray(np.asarray(pos, np.int32)), self.kv,
-                    jnp.asarray(np.asarray(temps, np.float32)),
-                    jnp.asarray(np.asarray(topps, np.float32)),
-                    jnp.asarray(np.asarray(coins, np.float32)), k,
-                    self._poison())
-            self._wait.next_phase("step_wait")
-            return np.asarray(toks), np.asarray(nf)  # [B, k], [B]
+        with self._step_io("batch_chunk") as io:
+            self._wait = io.span
+            (toks, nf), self.kv = io.call(
+                self._steps, self.kv, np.asarray(tokens, np.int32),
+                np.asarray(pos, np.int32), np.asarray(temps, np.float32),
+                np.asarray(topps, np.float32), np.asarray(coins, np.float32),
+                static=(k,))
+            return io.fetch(tokens=toks, nonfinite=nf)  # [B, k], [B]
 
     def _exec_verify(self, toks_2d, pos, temps, topps, coins):
-        with self.flight.tick_phase("step_dispatch") as self._wait, \
-                self.eng.watchdog.guard("batch_verify"):
-            failpoints.fire("step_hang")
-            with self._plan_ctx():
-                (n_acc, preds, nf), self.kv = self._verify(
-                    self.eng.params, self.cfg,
-                    jnp.asarray(np.asarray(toks_2d, np.int32)),
-                    jnp.asarray(np.asarray(pos, np.int32)), self.kv,
-                    jnp.asarray(np.asarray(temps, np.float32)),
-                    jnp.asarray(np.asarray(topps, np.float32)),
-                    jnp.asarray(np.asarray(coins, np.float32)),
-                    self._poison())
-            self._wait.next_phase("step_wait")
-            return np.asarray(n_acc), np.asarray(preds), np.asarray(nf)
+        with self._step_io("batch_verify") as io:
+            self._wait = io.span
+            (n_acc, preds, nf), self.kv = io.call(
+                self._verify, self.kv, np.asarray(toks_2d, np.int32),
+                np.asarray(pos, np.int32), np.asarray(temps, np.float32),
+                np.asarray(topps, np.float32), np.asarray(coins, np.float32))
+            return io.fetch(accepted=n_acc, tokens=preds, nonfinite=nf)
 
     # -- slot lifecycle -----------------------------------------------------
 
@@ -1079,11 +1135,13 @@ class BatchedGenerator(_GeneratorCore):
 
     def continue_admit(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
         """Run one prefill chunk; True when the slot is armed for decode."""
-        with self.flight.tick_phase("prefill_dispatch"):
-            if not self._advance_prefill(adm):
+        with self.flight.tick_phase("prefill_dispatch") as span:
+            if not self._advance_traced(adm, span):
                 return False
-        with self.flight.tick_phase("admit_commit"):
+        with self.flight.tick_phase("admit_commit") as span:
             self._commit_admit(adm)
+            if span.traced:
+                span.set(rid=adm.req.rid)
         return True
 
     def _advance_prefill(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
@@ -1107,7 +1165,8 @@ class BatchedGenerator(_GeneratorCore):
             else:
                 self._bcast(CTRL_SRV_PREFILL, adm.slot, [adm.pos] + padded)
                 self._prefill_chunk(adm, padded, len(chunk))
-            self.eng.seen_buckets.add(len(padded))  # the DISPATCHED width
+            adm.bucket = len(padded)
+            self.eng.seen_buckets.add(adm.bucket)  # the DISPATCHED width
             adm.pos += len(chunk)
             if adm.pos < len(rest):
                 return False
@@ -2246,11 +2305,13 @@ class PagedGenerator(_GeneratorCore):
         (shared-prefix entries redirected to the null block — a shared
         block is never a write target) and registers the prompt's blocks
         for future sharing."""
-        with self.flight.tick_phase("prefill_dispatch"):
-            if not self._advance_prefill(adm):
+        with self.flight.tick_phase("prefill_dispatch") as span:
+            if not self._advance_traced(adm, span):
                 return False
         with self.flight.tick_phase("admit_commit") as span:
             self._commit_admit(adm)
+            if span.traced:
+                span.set(rid=adm.req.rid)
             if self.spool is not None and not adm.req.score:
                 span.set(state_bytes=adm.col.s.nbytes + adm.col.conv.nbytes)
             if self.wpool is not None:
@@ -2295,7 +2356,8 @@ class PagedGenerator(_GeneratorCore):
                 self._prefill_nll_chunk(adm, padded, tgt, len(chunk))
             else:
                 self._prefill_chunk(adm, padded, len(chunk))
-            self.eng.seen_buckets.add(len(padded))
+            adm.bucket = len(padded)
+            self.eng.seen_buckets.add(adm.bucket)
             adm.pos += len(chunk)
             if adm.pos < len(rest):
                 return False
@@ -2524,9 +2586,8 @@ class PagedGenerator(_GeneratorCore):
             return self._spec_step(active)
         temps, topps, coins = rows
         t0 = time.perf_counter()
-        with self.flight.tick_phase("step_dispatch") as wait, \
-                self.eng.watchdog.guard("batch_step"):
-            failpoints.fire("step_hang")
+        with self._step_io("batch_step") as io:
+            wait = io.span
             # with a recurrent state the step takes the state pool beside the
             # blocks, both donated, and gives both back
             cache = (self.pkv if self.spool is None
@@ -2536,28 +2597,23 @@ class PagedGenerator(_GeneratorCore):
                 # two pools and the running counters in, all three back
                 cache = (self.pkv, self.wkv, self.moe_stats)
                 tables = self._both_tables
-            with self._plan_ctx():
-                (nxt, nf), cache = self._step(
-                    self.eng.params, self.cfg,
-                    jnp.asarray(self.next_token.astype(np.int32)[:, None]),
-                    jnp.asarray(self.pos.astype(np.int32)), cache,
-                    jnp.asarray(tables),
-                    jnp.asarray(temps), jnp.asarray(topps),
-                    jnp.asarray(coins), self._poison())
+            (nxt, nf), cache = io.call(
+                self._step, cache, self.next_token.astype(np.int32)[:, None],
+                self.pos.astype(np.int32), tables, temps, topps, coins)
             if self.wpool is not None:
                 self.pkv, self.wkv, self.moe_stats = cache
             elif self.spool is None:
                 self.pkv = cache
             else:
                 self.pkv, self.spool = cache
-            wait.next_phase("step_wait")
             if self.wpool is None:
-                nxt, nf = np.asarray(nxt), np.asarray(nf)
+                nxt, nf = io.fetch(tokens=nxt, nonfinite=nf)
             else:
                 # the routing counters are the same program's output as
                 # the tokens: one fetch brings all three
-                nxt, nf, totals = jax.device_get(
-                    (nxt, nf, self.moe_stats))
+                nxt, nf, totals = io.fetch(
+                    together=True, tokens=nxt, nonfinite=nf,
+                    moe_stats=self.moe_stats)
                 self._note_moe(totals, wait)
         ms = (time.perf_counter() - t0) * 1000.0
         with self.flight.tick_phase("emit"):
@@ -2633,21 +2689,13 @@ class PagedGenerator(_GeneratorCore):
             return 0
         toks, lens, temps, topps, acoins, fcoins = rows
         t0 = time.perf_counter()
-        with self.flight.tick_phase("step_dispatch") as wait, \
-                self.eng.watchdog.guard("batch_verify"):
-            failpoints.fire("step_hang")
-            with self._plan_ctx():
-                (n_acc, out, nf), self.pkv = self._verify(
-                    self.eng.params, self.cfg, jnp.asarray(toks),
-                    jnp.asarray(self.pos.astype(np.int32)), self.pkv,
-                    jnp.asarray(self.tables), jnp.asarray(lens),
-                    jnp.asarray(temps), jnp.asarray(topps),
-                    jnp.asarray(acoins), jnp.asarray(fcoins),
-                    self._poison())
-            wait.next_phase("step_wait")
-            n_acc = np.asarray(n_acc)
-            out = np.asarray(out)
-            nf = np.asarray(nf)
+        with self._step_io("batch_verify") as io:
+            wait = io.span
+            (n_acc, out, nf), self.pkv = io.call(
+                self._verify, self.pkv, toks, self.pos.astype(np.int32),
+                self.tables, lens, temps, topps, acoins, fcoins)
+            n_acc, out, nf = io.fetch(accepted=n_acc, tokens=out,
+                                      nonfinite=nf)
         ms = (time.perf_counter() - t0) * 1000.0
         with self.flight.tick_phase("emit"):
             self._settle_prefill(wait.t0_ns, wait.t1_ns)
@@ -3386,7 +3434,10 @@ class BatchScheduler:
             if self._migrating:
                 self._service_migrations()
         with self.flight.tick_phase("admit_begin") as span:
-            span.set(admitted=self._begin_admissions())
+            rids = self._begin_admissions()
+            span.set(admitted=len(rids))
+            if rids and span.traced:
+                span.set(rids="/".join(map(str, rids)))
         self._advance_admissions()
         # golden canary drift sentinel (runtime/numerics): time-gated
         # fixed-seed replay on this thread — the same thread that owns
@@ -3424,12 +3475,12 @@ class BatchScheduler:
             self._mark_steady_if_quiet(compiles_before)
             self._note_tick_usage()
 
-    def _begin_admissions(self) -> int:  # dlint: owner=loop-thread
+    def _begin_admissions(self) -> list[int]:  # dlint: owner=loop-thread
         """The ``admit_begin`` phase: drain the queue into free slots
         (``gen.begin_admit`` under the scheduler lock), launch parked
-        peer-KV pulls, sweep cancelled admissions. Returns how many
-        requests began admission."""
-        n_begun = 0
+        peer-KV pulls, sweep cancelled admissions. Returns the ids of
+        the requests that began admission."""
+        begun: list[int] = []
         reserved = {a.slot for a in self._admissions}
         started: list[_KVMigration] = []
         with self._lock:
@@ -3507,7 +3558,7 @@ class BatchScheduler:
                     continue
                 self._admissions.append(adm)
                 reserved.add(adm.slot)
-                n_begun += 1
+                begun.append(req.rid)
             telemetry.registry().gauge(telemetry.QUEUE_DEPTH).set(
                 len(self._queue))
         # fetch threads launch OUTSIDE the admission lock (the spawn
@@ -3538,7 +3589,7 @@ class BatchScheduler:
                 self.flight.note("cancel", adm.req.rid, reason="admitting",
                                  tenant=adm.req.tenant)
                 adm.req.done.set()
-        return n_begun
+        return begun
 
     def _advance_admissions(self) -> None:  # dlint: owner=loop-thread
         """One chunk for the first admission, more while the tick's

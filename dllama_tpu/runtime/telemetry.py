@@ -949,20 +949,30 @@ PHASES = ("queue", "admit", "prefill", "prefill_chunk", "decode", "verify",
 # * ``admit_begin`` — the queue drain under the scheduler lock with the
 #   generator's ``begin_admit`` (prefix match, block allocation,
 #   copy-on-write, column gather dispatch) and the cancelled-admission
-#   sweep; the annotation carries ``admitted=<n>``.
+#   sweep; the annotation carries ``admitted=<n>`` and, under a
+#   profiler when it admitted something, ``rids`` (joined by ``/``).
 # * ``prefill_dispatch`` — ``continue_admit`` up to the chunk's enqueue
 #   (page-in batch, deferred copy/gather, the prefill program's
-#   dispatch). Nothing waits for the device here.
+#   dispatch). Nothing waits for the device here. Under a profiler it
+#   names its cause: ``rid``, ``tokens`` (valid) and ``bucket`` (the
+#   padded width dispatched; 0 where the call enqueued no chunk).
 # * ``admit_commit`` — ``continue_admit`` after the last chunk: the
-#   commit scatter's dispatch, prompt registration, decode arming.
+#   commit scatter's dispatch, prompt registration, decode arming
+#   (``rid`` under a profiler).
 # * ``step_prepare`` — cancelled-slot sweep, block growth, sampling
 #   rows, speculative drafts: the host work before a step's dispatch.
-# * ``step_dispatch`` — the jitted step/verify call until it returns
-#   (argument upload and enqueue; a compile inside the window shows
-#   here).
+# * ``step_upload`` — the step's host arrays made device arguments, in
+#   the call's order (every ``jnp.asarray`` of a host array and the
+#   tripwire's poison selector); under a profiler the annotation
+#   carries ``arrays=<n>`` and ``bytes=<host bytes>``. It ends where
+#   ``step_dispatch`` begins.
+# * ``step_dispatch`` — the plan context and the jitted step/verify
+#   call alone, until it returns (the enqueue; a compile inside the
+#   window shows here).
 # * ``step_wait`` — fetching the step's outputs: the one place the loop
 #   waits for the device, so it holds the device time of everything
-#   queued before the step as well.
+#   queued before the step as well. Each blocking fetch inside it is a
+#   nested profiler-only span :data:`STEP_FETCH_SPAN`.
 # * ``emit`` — decode attribution, the non-finite tripwire tail, and the
 #   per-row emit loop (decoder, ``on_token`` callbacks, retirements).
 # * ``bookkeeping`` — per-step telemetry, block gauges, the steady-state
@@ -972,9 +982,17 @@ PHASES = ("queue", "admit", "prefill", "prefill_chunk", "decode", "verify",
 # * ``idle_wait`` — nothing to do: the loop sleeps on its wake event
 #   (at most 50 ms).
 TICK_PHASES = ("deadlines", "admit_begin", "prefill_dispatch",
-               "admit_commit", "step_prepare", "step_dispatch", "step_wait",
-               "emit", "bookkeeping", "canary", "idle_wait")
+               "admit_commit", "step_prepare", "step_upload",
+               "step_dispatch", "step_wait", "emit", "bookkeeping", "canary",
+               "idle_wait")
 TICK_SPAN = "dllama.tick"
+# One blocking fetch of a step output inside ``step_wait``
+# (``what=<tokens|nonfinite|...>``; outputs fetched in one call are
+# joined by ``/``). Deliberately NOT under ``dllama.tick.``: every span
+# with that prefix is read as a phase and given the device idle under
+# it, so a nested one would be counted twice. The profiler's trace is
+# its only record: no flight-record entry, no registry series.
+STEP_FETCH_SPAN = "dllama.step.fetch"
 
 # The closed-world eval config vocabulary (dlint rule eval-names
 # lints it both directions): the ``eval --compare`` CLI grammar, the

@@ -532,7 +532,7 @@ def test_span_phases_live_tick_vocabulary_is_closed():
 
     findings, summary = span_phases.check(Project(REPO))
     assert findings == []
-    assert "11 tick phases" in summary
+    assert "12 tick phases" in summary
     findings, _ = span_phases.check(Project(REPO), phases=((), ()))
     assert all("TICK_PHASES documents" not in f.message for f in findings)
 

@@ -80,7 +80,8 @@ def record() -> dict:
         """One decode step's phases around ``fn`` (its emit-side notes)."""
         with rec.tick_phase("step_prepare"):
             pass
-        with rec.tick_phase("step_dispatch") as ph:
+        with rec.tick_phase("step_upload") as ph:
+            ph.next_phase("step_dispatch")
             ph.next_phase("step_wait")
         with rec.tick_phase("emit"):
             fn()
