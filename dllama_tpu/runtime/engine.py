@@ -47,7 +47,7 @@ from ..parallel.api import MeshPlan, make_mesh, plan_scoped_jit, use_plan
 from ..parallel.sharding import kv_cache_sharding, shard_params, validate_tp
 from ..tokenizer.bpe import Tokenizer
 from ..tokenizer.sampler import Sampler, xorshift_random_f32
-from . import failpoints, flightrec, numerics, telemetry
+from . import failpoints, flightrec, numerics, steppack, telemetry
 from .kvcache import KVCache
 from .watchdog import StepWatchdog
 
@@ -735,6 +735,17 @@ class InferenceEngine:
                                                 program="verify_step",
                                                 static_argnums=1,
                                                 donate_argnums=(4,))
+            # the sampled pair again behind packed arguments
+            # (runtime/steppack), as the slot-pool generator dispatches them
+            # at its pool's batch width: owned here so that every generator
+            # serving this engine shares one executable a program. Lazy like
+            # the rest: nothing compiles until a generator dispatches, and
+            # the ledger's entries are the two names above.
+            self._packed_sampled_step = steppack.jit_packed_step(
+                sampled_step_guarded, scope=_sc, name="sampled_step")
+            self._packed_sampled_steps = steppack.jit_packed_step(
+                sampled_steps_guarded, scope=_sc, name="sampled_steps",
+                n_static=1)
             # quality observatory (runtime/evalharness): teacher-forced
             # prefill twin whose epilogue is the fused log-softmax-gather
             # NLL reduction — eval chunks never download full-vocab

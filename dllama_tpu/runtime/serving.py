@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import failpoints, flightrec, introspection, numerics, telemetry, tenancy
+from . import (failpoints, flightrec, introspection, numerics, steppack,
+               telemetry, tenancy)
 
 from ..models.llama import forward, sampled_step_guarded
 from ..parallel.api import plan_scoped_jit, use_plan
@@ -325,34 +326,34 @@ class _StepIO:
         self.span = span  # the open phase: step_wait's once fetch() ran
 
     def call(self, program, cache, *host, static=()):
-        """``program(params, cfg, tokens, pos, cache, *rest, *static,
-        poison)``, the signature every step and verify program has:
-        ``host`` (tokens, positions, then the rest) uploaded in that
-        order, then the tripwire's poison selector. While a profiler
-        listens ``step_upload`` carries how many transfers that was and
-        their host bytes. The device arguments die with this frame, as
-        the call's temporaries did."""
+        """``program`` is a :func:`steppack.packed_program`: ``host``
+        (tokens, positions, then the rest, as the model's step function
+        takes them) and the tripwire's poison selector go up as ONE packed
+        transfer that the program takes apart again. While a profiler
+        listens ``step_upload`` carries that transfer's count and its host
+        bytes. The device argument dies with this frame, as the call's
+        temporaries did."""
         gen = self._gen
-        dev = [jnp.asarray(a) for a in host]
-        poison = gen._poison()
+        fields = (*host, gen._poison())
+        # fresh every tick: never a buffer a transfer still reads
+        words = steppack.pack(fields)
+        dev = jnp.asarray(words)
         if self.span.traced:
-            self.span.set(arrays=len(dev) + 1,
-                          bytes=sum(a.nbytes for a in host) + 4)
+            self.span.set(arrays=1, bytes=words.nbytes)
         self.span.next_phase("step_dispatch")
         with gen._plan_ctx():
-            return program(gen.eng.params, gen.cfg, dev[0], dev[1], cache,
-                           *dev[2:], *static, poison)
+            return program(gen.eng.params, gen.cfg, dev, cache,
+                           steppack.layout_of(fields), *static)
 
-    def fetch(self, together: bool = False, **outs) -> tuple:
-        """The step's outputs on the host, in the order named: one
-        blocking fetch each (``together``: one ``device_get`` of them
-        all), each a ``dllama.step.fetch`` span under ``step_wait``. The
-        first waits for the device; one that starts after the program
-        has ended is the copy alone. No sync but the fetches themselves."""
+    def fetch(self, **outs) -> tuple:
+        """The step's outputs on the host, in the order named. Every
+        output's copy to the host is started before the first wait, then
+        each is fetched under its own ``dllama.step.fetch`` span inside
+        ``step_wait``: the first waits for the device, the others find
+        their bytes on the host. No sync but the fetches themselves."""
         self.span.next_phase("step_wait")
-        if together:
-            with flightrec.fetch_span("/".join(outs)):
-                return jax.device_get(tuple(outs.values()))
+        for out in outs.values():
+            out.copy_to_host_async()
         got = []
         for what, out in outs.items():
             with flightrec.fetch_span(what):
@@ -455,13 +456,13 @@ class _GeneratorCore:
         return (use_plan(self.eng.plan) if self.eng.plan is not None
                 else nullcontext())
 
-    def _poison(self) -> jnp.ndarray:
-        """The tripwire's poison selector for one ragged dispatch: always
-        0 under multihost (root AND mirrors — a one-sided injection would
-        desync the replicated outputs), else driven by the `logits`
-        failpoint (runtime/numerics)."""
-        return jnp.float32(0.0 if self.eng.multihost
-                           else numerics.poison_code())
+    def _poison(self) -> np.float32:
+        """The tripwire's poison selector for one ragged dispatch, the last
+        word of the step's packed arguments: always 0 under multihost (root
+        AND mirrors — a one-sided injection would desync the replicated
+        outputs), else driven by the `logits` failpoint (runtime/numerics)."""
+        return np.float32(0.0 if self.eng.multihost
+                          else numerics.poison_code())
 
     @contextmanager
     def _step_io(self, guard: str):
@@ -965,26 +966,28 @@ class BatchedGenerator(_GeneratorCore):
         # plan_scoped_jit everywhere a shared module-level model function
         # is jitted: the traced program bakes in THIS engine's mesh plan
         # (constrain is trace-time), so the trace cache must be scoped to
-        # the ENGINE, not shared via the bare function's identity. Where
-        # the engine already wrapped the exact same function with the
-        # same jit options (same plan — this generator serves that
-        # engine), its callable is reused instead of re-wrapped: a fresh
-        # wrapper here would recompile the full-model program the engine
-        # already owns (minutes on real models).
+        # the ENGINE, not shared via the bare function's identity. The
+        # step programs are the model's step functions behind their packed
+        # arguments (steppack.jit_packed_step). The engine owns the two
+        # that every slot-pool generator serving it dispatches, so a second
+        # generator on this engine (a supervised restart builds one) shares
+        # the executables the first compiled: a fresh wrapper here would
+        # recompile a full-model program (minutes on real models).
         _sc = getattr(engine, "introspection_scope", None) or "default"
-        self._step = (plan_scoped_jit(_replicated_ragged_step, scope=_sc,
-                                      static_argnums=1, donate_argnums=(4,))
-                      if engine.multihost else engine._sampled_step)
+        self._step = (steppack.jit_packed_step(
+            _replicated_ragged_step, scope=_sc,
+            name="_replicated_ragged_step")
+            if engine.multihost else engine._packed_sampled_step)
         # chunked ragged decode (engine --decode-chunk composed with
         # --batch-slots): K fused steps over the whole pool per dispatch —
         # K× fewer dispatches and host-loop ticks (and control packets,
         # under multihost) when every active slot has K rows of headroom.
         # sampled_steps broadcasts over rows (vector temps/topps, [K, B]
         # coins), so the engine's chunk program IS the ragged chunk program.
-        self._steps = (plan_scoped_jit(_replicated_ragged_steps, scope=_sc,
-                                       static_argnums=(1, 8),
-                                       donate_argnums=(4,))
-                       if engine.multihost else engine._sampled_steps)
+        self._steps = (steppack.jit_packed_step(
+            _replicated_ragged_steps, scope=_sc,
+            name="_replicated_ragged_steps", n_static=1)
+            if engine.multihost else engine._packed_sampled_steps)
         # speculative serving (engine --spec-lookup): per-slot prompt-lookup
         # drafts verified in the ragged program. Greedy rows accept runs;
         # sampled rows keep their exact one-token/one-coin behavior, so every
@@ -994,13 +997,11 @@ class BatchedGenerator(_GeneratorCore):
         if self.spec:
             from ..models.llama import ragged_verify_step_guarded
 
-            self._verify = plan_scoped_jit(
+            self._verify = steppack.jit_packed_step(
                 _replicated_ragged_verify if engine.multihost
-                else ragged_verify_step_guarded,
-                scope=_sc, program=("_replicated_ragged_verify"
-                                    if engine.multihost
-                                    else "ragged_verify_step"),
-                static_argnums=1, donate_argnums=(4,))
+                else ragged_verify_step_guarded, scope=_sc,
+                name=("_replicated_ragged_verify" if engine.multihost
+                      else "ragged_verify_step"))
         # non-multihost engine._step IS jit(forward) with these options;
         # multihost needs plain forward (the engine's replicated_forward
         # constrains logits this path discards, but matching the seed's
@@ -1572,9 +1573,8 @@ class PagedGenerator(_GeneratorCore):
         _sc = getattr(engine, "introspection_scope", None) or "default"
         from ..models.llama import paged_sampled_step_guarded
 
-        self._step = plan_scoped_jit(paged_sampled_step_guarded, scope=_sc,
-                                     program="paged_sampled_step",
-                                     static_argnums=1, donate_argnums=(4,))
+        self._step = steppack.jit_packed_step(
+            paged_sampled_step_guarded, scope=_sc, name="paged_sampled_step")
         # speculative serving (--spec-lookup composed with --kv-block-size):
         # ONE ragged paged verify program per pool geometry — K+1 width,
         # table width, and batch width are static; per-slot draft lengths,
@@ -1584,10 +1584,9 @@ class PagedGenerator(_GeneratorCore):
         if self.spec:
             from ..models.llama import paged_verify_step_guarded
 
-            self._verify = plan_scoped_jit(
+            self._verify = steppack.jit_packed_step(
                 paged_verify_step_guarded, scope=_sc,
-                program="paged_verify_step", static_argnums=1,
-                donate_argnums=(4,))
+                name="paged_verify_step")
         # prefill rides the ENGINE's jitted forward over the gathered
         # column (same program its solo path compiles — shared cache)
         self._prefill_fwd = engine._step
@@ -2610,10 +2609,9 @@ class PagedGenerator(_GeneratorCore):
                 nxt, nf = io.fetch(tokens=nxt, nonfinite=nf)
             else:
                 # the routing counters are the same program's output as
-                # the tokens: one fetch brings all three
-                nxt, nf, totals = io.fetch(
-                    together=True, tokens=nxt, nonfinite=nf,
-                    moe_stats=self.moe_stats)
+                # the tokens
+                nxt, nf, totals = io.fetch(tokens=nxt, nonfinite=nf,
+                                           moe_stats=self.moe_stats)
                 self._note_moe(totals, wait)
         ms = (time.perf_counter() - t0) * 1000.0
         with self.flight.tick_phase("emit"):

@@ -140,13 +140,24 @@ def _capture(eng, tmp_path, prompts):
     return events, n, [r.rid for r in reqs]
 
 
-def test_a_capture_holds_the_fetches_and_no_phase_outside_the_vocabulary(path_engine, tmp_path):
+def test_a_capture_holds_the_fetches_and_no_phase_outside_the_vocabulary(path_engine, tmp_path, monkeypatch):
     """Under a profiler: every span named ``dllama.tick.<x>`` is a phase of
     the vocabulary (a nested span under that prefix would be given the idle
     under it twice); each ``step_wait`` holds the step's fetches as
     ``dllama.step.fetch`` spans, in order, each inside it on its own line;
-    ``step_upload`` says how many transfers it made and of how many bytes."""
+    ``step_upload`` says that it made ONE transfer, of the packed buffer's
+    bytes: every field of the step and the poison selector (PR 41)."""
+    from dllama_tpu.runtime import steppack
+
     name, eng = path_engine
+    packed, real_pack = [], steppack.pack
+
+    def pack(fields):
+        words = real_pack(fields)
+        packed.append((len(fields), sum(a.nbytes for a in fields), words.nbytes))
+        return words
+
+    monkeypatch.setattr(steppack, "pack", pack)
     events, _n, _rids = _capture(eng, tmp_path, PROMPTS[1:3])
     prefix = tm.TICK_SPAN + "."
     phases = [e for e in events if e[1].startswith(prefix)]
@@ -161,9 +172,12 @@ def test_a_capture_holds_the_fetches_and_no_phase_outside_the_vocabulary(path_en
         assert all(a[3] <= b[2] for a, b in zip(inside, inside[1:]))        # one after the other
     uploads = [e for e in phases if e[1] == prefix + "step_upload"]
     assert len(uploads) == len(waits)
-    n_arrays = {"paged_step": 7, "paged_verify": 9, "dense_step": 6, "dense_step_chunk": 6, "dense_verify": 6}[name]
-    assert {int(u[4]["arrays"]) for u in uploads} == {n_arrays}
-    assert all(int(u[4]["bytes"]) > 4 * n_arrays for u in uploads)
+    n_fields = {"paged_step": 7, "paged_verify": 9, "dense_step": 6, "dense_step_chunk": 6, "dense_verify": 6}[name]
+    assert {int(u[4]["arrays"]) for u in uploads} == {1}
+    # one layout a program (the chunk path steps singly too, K times fewer coins)
+    assert len(set(packed)) == (2 if name == "dense_step_chunk" else 1)
+    assert all(fields == n_fields and words == held > 4 * n_fields for fields, held, words in packed)
+    assert {int(u[4]["bytes"]) for u in uploads} == {words for _f, _h, words in packed}
     # the call alone carries no transfer count
     assert all("arrays" not in e[4] for e in phases if e[1] == prefix + "step_dispatch")
 
@@ -198,8 +212,9 @@ def test_admission_spans_name_their_cause(model_files, tmp_path):
 
 
 def test_the_device_arguments_die_before_the_wait(path_engine, monkeypatch):
-    """The uploaded arguments are the call's temporaries, as they were in one
-    expression: by the first fetch none is alive. Kept until the step returns
+    """The uploaded argument (the step's packed words) is the call's
+    temporary, as the seven were in one expression: by the first fetch it is
+    not alive. Kept until the step returns
     they were freed between two phases, after the last token's ``done`` was
     set, and freeing a device buffer lets another thread take the GIL there (a
     waiting client then stopped a profiler inside the slice's last tick)."""
@@ -229,7 +244,7 @@ def test_the_device_arguments_die_before_the_wait(path_engine, monkeypatch):
         _drive(sched, _submit(eng, sched, PROMPTS[:2]))
     finally:
         sched.close()
-    assert len(refs) >= 6 and alive_at_fetch and set(alive_at_fetch) == {0}
+    assert len(refs) == 1 and alive_at_fetch and set(alive_at_fetch) == {0}      # the packed words alone
 
 
 def _series(text: str) -> set[str]:
