@@ -35,6 +35,7 @@ ARCH_BY_MODEL_TYPE = {
     "olmo_hybrid": ArchType.OLMO_HYBRID,
     "laguna": ArchType.LAGUNA,
     "falcon_h1": ArchType.FALCON_H1,
+    "axk1": ArchType.AXK1,
 }
 
 HIDDEN_ACT_BY_NAME = {"gelu": HiddenAct.GELU, "silu": HiddenAct.SILU}
@@ -157,6 +158,9 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
     if model_type == "laguna":
         params.update(_laguna_header(cfg))
 
+    if model_type == "axk1":
+        return {**params, **_axk1_header(cfg)}
+
     if model_type == "falcon_h1":
         params.update(_falcon_h1_header(cfg))
     elif cfg.get("rope_theta") is not None:
@@ -269,6 +273,65 @@ def _falcon_h1_header(cfg: dict) -> dict:
     }
 
 
+def _axk1_header(cfg: dict) -> dict:
+    """``model_type: axk1``'s config keys as the header's extension keys
+    (formats/mfile.py, HeaderKey 60-69, the share's 33-38 and the
+    rope-scaling keys 14-17 for YaRN over the rope lanes). A whole checkpoint
+    holds every expert: the router's width is ``n_routed_experts`` and the
+    first held expert 0 (a share is written by whoever cuts one).
+    ``topk_method: "none"`` is read as "no score-correction bias", the
+    selection group-limited as ``n_group`` / ``topk_group`` state; what the
+    config does not say (pre-norm, an ungated shared expert, an RMS norm on
+    both latents) the arch implies (models/axk1.py)."""
+    rs = cfg.get("rope_scaling") or {}
+    if (cfg.get("attention_bias") or cfg.get("moe_layer_freq", 1) != 1
+            or cfg.get("hidden_act", "silu") != "silu"
+            or cfg.get("tie_word_embeddings")
+            or cfg.get("scoring_func") not in ("sigmoid", "softmax")
+            or cfg.get("topk_method", "none") not in ("none", "group_limited_greedy")
+            or rs.get("type", rs.get("rope_type")) != "yarn"
+            or cfg.get("rms_norm_eps") != 1e-6):
+        raise ValueError(
+            "axk1: attention bias, an expert layer frequency other than 1, "
+            "an activation other than silu, tied embeddings, a scoring "
+            "function other than sigmoid or softmax, a top-k method with a "
+            "score-correction bias, a rope scaling other than yarn or a norm "
+            "epsilon other than 1e-6 are not carried")
+    n_shared = int(cfg.get("n_shared_experts") or 0)
+    return {
+        "hidden_dim": int(cfg["moe_intermediate_size"]),
+        "n_experts": int(cfg["n_routed_experts"]),
+        "n_active_experts": int(cfg["num_experts_per_tok"]),
+        "moe_norm_topk": int(bool(cfg.get("norm_topk_prob", False))),
+        "head_dim": int(cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]),
+        "norm_epsilon": 6,
+        "rope_theta": int(cfg["rope_theta"]),
+        "rope_type": int(RopeType.YARN),
+        "rope_scaling_factor": int(rs["factor"]),
+        "rope_scaling_low_freq_factor": int(rs["beta_slow"]),
+        "rope_scaling_high_freq_factory": int(rs["beta_fast"]),
+        "rope_scaling_orig_max_seq_len": int(
+            rs["original_max_position_embeddings"]),
+        "yarn_mscale": float(rs.get("mscale", 1.0)),
+        "yarn_mscale_all_dim": float(rs.get("mscale_all_dim", 0.0)),
+        "q_lora_rank": int(cfg["q_lora_rank"]),
+        "kv_lora_rank": int(cfg["kv_lora_rank"]),
+        "qk_nope_head_dim": int(cfg["qk_nope_head_dim"]),
+        "qk_rope_head_dim": int(cfg["qk_rope_head_dim"]),
+        "v_head_dim": int(cfg["v_head_dim"]),
+        "moe_n_group": int(cfg.get("n_group") or 0),
+        "moe_topk_group": int(cfg.get("topk_group") or 0),
+        "moe_score_func": int(cfg["scoring_func"] == "sigmoid"),
+        "n_dense_layers": int(cfg.get("first_k_dense_replace") or 0),
+        "dense_hidden_dim": int(cfg["intermediate_size"]),
+        "shared_expert_dim": n_shared * int(cfg["moe_intermediate_size"]),
+        "moe_routed_scale_milli": int(round(
+            float(cfg.get("routed_scaling_factor", 1.0)) * 1000)),
+        "moe_router_width": int(cfg["n_routed_experts"]),
+        "moe_first_expert": 0,
+    }
+
+
 def _laguna_header(cfg: dict) -> dict:
     """``model_type: laguna``'s config keys as the header's extension keys
     (formats/mfile.py, HeaderKey 22, 29-38, and the rope-scaling keys 14-17
@@ -376,6 +439,15 @@ def hf_tensor_plan(params: dict) -> list[PlanItem]:
             "tensor names are not: they could not be read where this was "
             "written, and a guessed map is worse than none. The target "
             "layout is formats/mfile.py's _walk_laguna_layer")
+    if arch == ArchType.AXK1:
+        raise NotImplementedError(
+            "axk1: the header is mapped (load_hf_config), the checkpoint's "
+            "tensor names are not: they could not be read where this was "
+            "written, and a guessed map is worse than none. The target "
+            "layout is formats/mfile.py's _walk_axk1_layer (its rope lanes "
+            "pair half-split: a published checkpoint's interleaved pairs are "
+            "permuted in W_uq's and W_dkv's rope rows, as permute_rope_rows "
+            "does for the Llama files)")
     if arch == ArchType.FALCON_H1:
         raise NotImplementedError(
             "falcon_h1: the header is mapped (load_hf_config), the "

@@ -142,6 +142,23 @@ class HeaderKey(enum.IntEnum):
     SSM_MULT_B = 57
     SSM_MULT_C = 58
     SSM_MULT_DT = 59
+    # OUR format extension, read by ArchType.AXK1 only (models/axk1.py):
+    # latent attention's five sizes (N_HEADS heads whose queries are
+    # QK_NOPE_HEAD_DIM + QK_ROPE_HEAD_DIM wide: HEAD_DIM is their sum), the
+    # router's groups and score function, and YaRN's two mscale numbers as
+    # float32 bits. The arch shares N_DENSE_LAYERS .. MOE_FIRST_EXPERT (33-38)
+    # and MOE_NORM_TOPK with LAGUNA, and YaRN's factor, beta_slow, beta_fast
+    # and original context in keys 14-17.
+    Q_LORA_RANK = 60             # the query's latent width
+    KV_LORA_RANK = 61            # the cached latent's width (c)
+    QK_NOPE_HEAD_DIM = 62        # a head's lanes that do not rotate
+    QK_ROPE_HEAD_DIM = 63        # the lanes that do: ONE k_r a token, shared by the heads
+    V_HEAD_DIM = 64
+    MOE_N_GROUP = 65             # the router's groups (0 / 1: none)
+    MOE_TOPK_GROUP = 66          # groups a token's experts may come from
+    MOE_SCORE_FUNC = 67          # 0 softmax, 1 sigmoid
+    YARN_MSCALE = 68
+    YARN_MSCALE_ALL_DIM = 69
 
 
 class ArchType(enum.IntEnum):
@@ -161,6 +178,11 @@ class ArchType(enum.IntEnum):
     # mixer and grouped-query attention side by side over one normed input
     # and adds both to the residual (models/falcon_h1.py)
     FALCON_H1 = 0xABCD04
+    # ours: latent attention (MLA: the cache holds one compressed row a
+    # token, no per-head keys or values), a leading dense layer, then a
+    # sigmoid group-limited router over experts of which a share may be
+    # held, and a shared one (models/axk1.py)
+    AXK1 = 0xABCD05
 
 
 class RopeType(enum.IntEnum):
@@ -250,6 +272,17 @@ class ModelHeader:
     ssm_mult_b: float = 1.0
     ssm_mult_c: float = 1.0
     ssm_mult_dt: float = 1.0
+    # AXK1 (HeaderKey 60-69); 0 / 1.0 for every other arch
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    moe_n_group: int = 0
+    moe_topk_group: int = 0
+    moe_score_func: int = 0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 1.0
 
     @property
     def ssm_inner_dim(self) -> int:
@@ -318,11 +351,17 @@ _HYBRID_KEYS = {k: k.name.lower() for k in (
     HeaderKey.MOE_ROUTER_WIDTH, HeaderKey.MOE_FIRST_EXPERT,
     HeaderKey.SSM_N_HEADS, HeaderKey.SSM_HEAD_DIM, HeaderKey.SSM_N_GROUPS,
     HeaderKey.SSM_STATE_DIM, HeaderKey.SSM_CONV_KERNEL,
-    HeaderKey.SSM_CHUNK_SIZE)}
+    HeaderKey.SSM_CHUNK_SIZE,
+    HeaderKey.Q_LORA_RANK, HeaderKey.KV_LORA_RANK,
+    HeaderKey.QK_NOPE_HEAD_DIM, HeaderKey.QK_ROPE_HEAD_DIM,
+    HeaderKey.V_HEAD_DIM, HeaderKey.MOE_N_GROUP, HeaderKey.MOE_TOPK_GROUP,
+    HeaderKey.MOE_SCORE_FUNC)}
 # FALCON_H1's float keys: the value is a float32's bit pattern
 _F32_BITS_KEYS = {k: k.name.lower() for k in HeaderKey
                   if HeaderKey.EMBEDDING_MULT <= k <= HeaderKey.SSM_MULT_DT}
 _F32_BITS_KEYS[HeaderKey.ROPE_THETA_F32] = "rope_theta"
+_F32_BITS_KEYS[HeaderKey.YARN_MSCALE] = "yarn_mscale"
+_F32_BITS_KEYS[HeaderKey.YARN_MSCALE_ALL_DIM] = "yarn_mscale_all_dim"
 
 
 def f32_bits(x: float) -> int:
@@ -431,6 +470,42 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
                 f"must divide the heads")
         if h.n_experts:
             raise ValueError("falcon_h1 model: routed experts are unsupported")
+    if h.arch_type == ArchType.AXK1:
+        h.rope_type = RopeType.YARN
+        h.moe_router_width = h.moe_router_width or h.n_experts
+        if not (h.q_lora_rank and h.kv_lora_rank and h.qk_nope_head_dim
+                and h.qk_rope_head_dim and h.v_head_dim
+                and h.head_dim == h.qk_nope_head_dim + h.qk_rope_head_dim
+                and h.qk_rope_head_dim % 2 == 0):
+            raise ValueError(
+                f"axk1 model: latent attention's sizes (q_lora "
+                f"{h.q_lora_rank}, kv_lora {h.kv_lora_rank}, nope "
+                f"{h.qk_nope_head_dim}, rope {h.qk_rope_head_dim}, v "
+                f"{h.v_head_dim}) must all be set, a query head "
+                f"({h.head_dim}) being nope + rope")
+        if not (0 < h.n_active_experts <= h.moe_router_width
+                and 0 < h.n_experts
+                and h.moe_first_expert + h.n_experts <= h.moe_router_width):
+            raise ValueError(
+                f"axk1 model: experts [{h.moe_first_expert}, "
+                f"{h.moe_first_expert + h.n_experts}) held of a router over "
+                f"{h.moe_router_width}, {h.n_active_experts} a token")
+        G, kg = h.moe_n_group, h.moe_topk_group
+        if G > 1 and (h.moe_router_width % G or not 0 < kg <= G
+                      or h.n_active_experts % kg
+                      or h.n_active_experts > kg * (h.moe_router_width // G)):
+            raise ValueError(
+                f"axk1 model: {h.moe_router_width} experts in {G} groups, "
+                f"{kg} groups and {h.n_active_experts} experts a token")
+        if h.moe_score_func not in (0, 1):
+            raise ValueError(
+                f"axk1 model: score function code {h.moe_score_func} "
+                f"(0 softmax, 1 sigmoid)")
+        if h.n_dense_layers > 1 or (h.n_dense_layers
+                                    and not h.dense_hidden_dim):
+            raise ValueError(
+                f"axk1 model: {h.n_dense_layers} leading dense layers "
+                f"(this walk carries at most one, with its width)")
     if h.arch_type == ArchType.LAGUNA:
         h.rope_type = RopeType.YARN
         h.moe_router_width = h.moe_router_width or h.n_experts
@@ -582,6 +657,9 @@ class ModelFile:
             if h.arch_type == ArchType.FALCON_H1:
                 off = self._walk_falcon_h1_layer(l, off)
                 continue
+            if h.arch_type == ArchType.AXK1:
+                off = self._walk_axk1_layer(l, off)
+                continue
             off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
             off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
             off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
@@ -685,6 +763,64 @@ class ModelFile:
         off += self._add("block_norm_1", l, (h.dim,), F32, off)
         return off
 
+    def _walk_axk1_layer(self, l: int, off: int) -> int:
+        """One layer of an AXK1 file (OUR layout; the reference has none):
+        latent attention's ``W_dq``, the query latent's norm (F32), ``W_uq``
+        (a head's nope lanes, then its rope lanes), ``W_dkv`` (the latent's
+        ``kv_lora_rank`` rows, then the ``qk_rope_head_dim`` rows of the one
+        shared rotary key), the cached latent's norm (F32), ``W_ukv`` (a
+        head's nope key rows, then its value rows), ``W_o``; then a leading
+        dense layer's w1 w2 w3 or the router, the HELD experts and the
+        shared one, as :meth:`_walk_laguna_layer` orders them; the two block
+        norms. The rope lanes pair half-split (lane ``j`` with ``j + r/2``)
+        in the file's order."""
+        h, wt = self.header, self.header.weight_type
+        H, r = h.n_heads, h.kv_lora_rank
+        off += self._add("block_mla_dq", l, (h.q_lora_rank, h.dim), wt, off)
+        off += self._add("block_mla_norm_q", l, (h.q_lora_rank,), F32, off)
+        off += self._add("block_mla_uq", l, (H * h.head_dim, h.q_lora_rank),
+                         wt, off)
+        off += self._add("block_mla_dkv", l,
+                         (r + h.qk_rope_head_dim, h.dim), wt, off)
+        off += self._add("block_mla_norm_kv", l, (r,), F32, off)
+        off += self._add("block_mla_ukv", l,
+                         (H * (h.qk_nope_head_dim + h.v_head_dim), r), wt, off)
+        off += self._add("block_matmul_wo", l, (h.dim, H * h.v_head_dim),
+                         wt, off)
+        off = self._walk_share_ffn(l, off)
+        off += self._add("block_norm_0", l, (h.dim,), F32, off)
+        off += self._add("block_norm_1", l, (h.dim,), F32, off)
+        return off
+
+    def _walk_share_ffn(self, l: int, off: int) -> int:
+        """The feed-forward of a layer whose routed experts may be a SHARE:
+        a leading dense layer's w1 w2 w3 at ``dense_hidden_dim``, or the
+        router's rows over ``moe_router_width`` (F32), the HELD experts (w3
+        w1 w2 each, as the other MoE files order them) and the shared
+        expert's w1 w2 w3."""
+        h, wt = self.header, self.header.weight_type
+        if l < h.n_dense_layers:
+            wide = h.dense_hidden_dim
+            off += self._add("block_matmul_w1", l, (wide, h.dim), wt, off)
+            off += self._add("block_matmul_w2", l, (h.dim, wide), wt, off)
+            off += self._add("block_matmul_w3", l, (wide, h.dim), wt, off)
+            return off
+        off += self._add("block_moe_gate", l,
+                         (h.moe_router_width, h.dim), F32, off)
+        for e in range(h.n_experts):
+            off += self._add("block_expert_w3", l, (h.hidden_dim, h.dim),
+                             wt, off, expert=e)
+            off += self._add("block_expert_w1", l, (h.hidden_dim, h.dim),
+                             wt, off, expert=e)
+            off += self._add("block_expert_w2", l, (h.dim, h.hidden_dim),
+                             wt, off, expert=e)
+        if h.shared_expert_dim:
+            wide = h.shared_expert_dim
+            off += self._add("block_shared_w1", l, (wide, h.dim), wt, off)
+            off += self._add("block_shared_w2", l, (h.dim, wide), wt, off)
+            off += self._add("block_shared_w3", l, (wide, h.dim), wt, off)
+        return off
+
     def _walk_laguna_layer(self, l: int, off: int) -> int:
         """One layer of a LAGUNA file (OUR layout; the reference has none):
         q k v wo at the layer kind's head count (the first of each period is
@@ -701,26 +837,7 @@ class ModelFile:
         off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
         off += self._add("block_matmul_wo", l, (h.dim, q_dim), wt, off)
         off += self._add("block_attn_gate", l, (heads, h.dim), F32, off)
-        if l < h.n_dense_layers:
-            wide = h.dense_hidden_dim
-            off += self._add("block_matmul_w1", l, (wide, h.dim), wt, off)
-            off += self._add("block_matmul_w2", l, (h.dim, wide), wt, off)
-            off += self._add("block_matmul_w3", l, (wide, h.dim), wt, off)
-        else:
-            off += self._add("block_moe_gate", l,
-                             (h.moe_router_width, h.dim), F32, off)
-            for e in range(h.n_experts):
-                off += self._add("block_expert_w3", l, (h.hidden_dim, h.dim),
-                                 wt, off, expert=e)
-                off += self._add("block_expert_w1", l, (h.hidden_dim, h.dim),
-                                 wt, off, expert=e)
-                off += self._add("block_expert_w2", l, (h.dim, h.hidden_dim),
-                                 wt, off, expert=e)
-            if h.shared_expert_dim:
-                wide = h.shared_expert_dim
-                off += self._add("block_shared_w1", l, (wide, h.dim), wt, off)
-                off += self._add("block_shared_w2", l, (h.dim, wide), wt, off)
-                off += self._add("block_shared_w3", l, (wide, h.dim), wt, off)
+        off = self._walk_share_ffn(l, off)
         off += self._add("block_norm_0", l, (h.dim,), F32, off)
         off += self._add("block_norm_1", l, (h.dim,), F32, off)
         return off

@@ -115,6 +115,32 @@ class ModelConfig:
     ssm_conv_kernel: int = 0
     ssm_chunk: int = 0
     mult: Multipliers | None = None
+    # Latent attention (MLA) over a cache of ONE compressed row a token, a
+    # leading dense layer, then a group-limited router over experts of which
+    # a SHARE may be held (ArchType.AXK1, models/axk1.py). ``n_heads`` query
+    # heads of ``head_dim = qk_nope_dim + qk_rope_dim``; the cache row is
+    # ``kv_lora_rank`` normed lanes and ``qk_rope_dim`` rotated ones (the one
+    # rotary key all heads share), padded to whole lane tiles
+    # (``latent_row``). ``rope_theta`` and the ``rope_scaling_*`` fields are
+    # YaRN's over the ``qk_rope_dim`` lanes; its ``yarn_mscale`` numbers
+    # scale the tables by their ratio and the WHOLE score by the square of
+    # the second (``attn_scale``). The share's fields (``n_experts`` held
+    # from ``moe_first_expert`` of ``moe_router_width``, ``n_dense_layers``,
+    # ``dense_hidden_dim``, ``shared_expert_dim``, ``moe_routed_scale``) are
+    # LAGUNA's; ``moe_score`` is the router's score function and
+    # ``moe_n_group`` / ``moe_topk_group`` its group limit (0: none). The
+    # arch implies: pre-norm, an RMS norm on both latents, no bias, an
+    # ungated shared expert, half-split pairing of the rope lanes.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    moe_score: str = "softmax"
+    moe_n_group: int = 0
+    moe_topk_group: int = 0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 1.0
 
     # TPU execution choices (no reference equivalent):
     compute_dtype: str = "float32"  # "float32" for parity, "bfloat16" for speed
@@ -182,7 +208,66 @@ class ModelConfig:
         more than one list of K/V blocks (a recurrent state, a second pool),
         which the dense slot pool and the single-sequence path do not
         carry."""
-        return self.has_state or self.has_window_layers
+        return (self.has_state or self.has_window_layers
+                or self.has_latent_cache)
+
+    @property
+    def has_latent_cache(self) -> bool:
+        """The cache holds one compressed latent row a token a layer
+        (models/axk1.py), not per-head keys and values: ONE pool, no V."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def has_expert_share(self) -> bool:
+        """Routed experts of which this chip may hold a share
+        (models/share.py: the router scores ``moe_router_width`` experts,
+        ``n_experts`` of them are held): the step and the chunks count their
+        pairs on the device."""
+        return self.moe_router_width > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Useful lanes of a cached latent row: ``c`` and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def latent_row(self) -> int:
+        """Lanes of a latent row as the pool holds it: ``latent_dim``
+        rounded up to whole lane tiles of 128 (a manual DMA cannot slice a
+        minor dimension that is not, and XLA's tiled layout pads it so in
+        HBM anyway); the tail is zero."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def cache_heads(self) -> int:
+        """Heads of a cache block ``[heads, block_size, width]``."""
+        return 1 if self.has_latent_cache else self.n_kv_heads
+
+    @property
+    def cache_width(self) -> int:
+        """Lanes of a cached row a head: a K (and V) head, or the latent
+        row."""
+        return self.latent_row if self.has_latent_cache else self.head_dim
+
+    @property
+    def cache_row_elems(self) -> int:
+        """Elements a cached token takes in one layer over all the pool's
+        planes: K and V of every K/V head, or the one latent row."""
+        return (self.latent_row if self.has_latent_cache
+                else 2 * self.kv_dim)
+
+    @property
+    def attn_scale(self) -> float:
+        """What multiplies an attention score: ``head_dim ** -0.5``, and
+        with latent attention under YaRN the square of ``0.1 mscale_all_dim
+        ln(factor) + 1`` on top (the whole score's, nope part too)."""
+        scale = self.head_dim ** -0.5
+        if self.has_latent_cache and self.rope_scaling_factor > 1.0:
+            from .rope import yarn_mscale
+
+            scale *= yarn_mscale(self.rope_scaling_factor,
+                                 self.yarn_mscale_all_dim) ** 2
+        return scale
 
     @property
     def prefix_reuse_skipped(self) -> str | None:
@@ -316,6 +401,21 @@ class ModelConfig:
                     mlp_down=h.mlp_down_mult, ssm_z=h.ssm_mult_z,
                     ssm_x=h.ssm_mult_x, ssm_b=h.ssm_mult_b,
                     ssm_c=h.ssm_mult_c, ssm_dt=h.ssm_mult_dt))
+        if h.arch_type == ArchType.AXK1:
+            hybrid = dict(
+                q_lora_rank=h.q_lora_rank, kv_lora_rank=h.kv_lora_rank,
+                qk_nope_dim=h.qk_nope_head_dim, qk_rope_dim=h.qk_rope_head_dim,
+                v_head_dim=h.v_head_dim,
+                moe_score=("softmax", "sigmoid")[h.moe_score_func],
+                moe_n_group=h.moe_n_group, moe_topk_group=h.moe_topk_group,
+                yarn_mscale=h.yarn_mscale,
+                yarn_mscale_all_dim=h.yarn_mscale_all_dim,
+                n_dense_layers=h.n_dense_layers,
+                dense_hidden_dim=h.dense_hidden_dim,
+                shared_expert_dim=h.shared_expert_dim,
+                moe_routed_scale=h.moe_routed_scale_milli / 1000.0,
+                moe_router_width=h.moe_router_width,
+                moe_first_expert=h.moe_first_expert)
         if h.arch_type == ArchType.LAGUNA:
             hybrid = dict(
                 layer_period=h.layer_period,

@@ -24,8 +24,8 @@ and takes ``n_active_experts``; the planes hold ``n_experts`` of them, from
 ``moe_first_expert``. A (row, expert) pair whose expert is not held, or whose
 row is dead or padding, is not computed: the decode form compacts the held
 pairs to the front and :func:`~dllama_tpu.ops.expert_gemv.expert_gemv` loops
-over those alone; the chunk form sorts them by expert in front of the absent
-ones, which fall outside every group of ``lax.ragged_dot``. What the absent
+over those alone; the chunk form runs every held expert that some row chose
+over every row and weights the others 0 (``models/share.py``). What the absent
 experts would have added is left out, and that partial sum goes on to the
 next layer: on one chip the layer runs without its exchange. With every
 expert held the same code is the whole layer. The attention share (fewer
@@ -61,17 +61,16 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import expert_gemv as eg
 from ..ops.attention import attention
-from ..ops.linear import (LayerSlice, QuantizedWeight, Weight, _fast_mode,
-                          linear)
+from ..ops.linear import Weight, linear
 from ..ops.norms import rms_norm
 from ..parallel.api import current_plan
 from ..runtime.kvcache import update_layer
 from .config import ModelConfig
-from .llama import (Params, _attend_dense, _attend_paged, _experts_dense,
-                    _hidden_act, _stack_at)
+from .llama import Params, _attend_dense, _attend_paged, _stack_at
 from .rope import apply_rope_partial, build_partial_rope_cache
+from .share import (ffn_half, route, routed_ffn, routed_pairs,  # noqa: F401
+                    zero_stats, zero_totals)
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -112,15 +111,6 @@ class LagunaLayers(NamedTuple):
     ws3: Weight | None
 
 
-def _plane(w: Weight, l) -> Weight:
-    """Entry ``l`` of a stacked 2-D matmul weight: stack + index for a Q40
-    plane (the fused kernel reads it where it lies, llama._layer_at), the
-    slice otherwise."""
-    if isinstance(w, QuantizedWeight):
-        return LayerSlice(w, l)
-    return jax.lax.dynamic_index_in_dim(w, l, 0, keepdims=False)
-
-
 class LagunaColumn(NamedTuple):
     """One slot's context during chunked prefill: a dense K/V column over
     ALL layers in the model's order, and the chunks' routing counters."""
@@ -137,19 +127,6 @@ class LagunaColumn(NamedTuple):
                  padded_cache_len(cfg.seq_len), cfg.head_dim)
         return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                    stats=zero_stats(cfg))
-
-
-def zero_stats(cfg: ModelConfig) -> jax.Array:
-    """One dispatch's routing counters: held pairs, absent pairs, tokens a
-    held expert."""
-    return jnp.zeros((2 + cfg.n_experts,), jnp.int32)
-
-
-def zero_totals(cfg: ModelConfig) -> jax.Array:
-    """The generator's running totals beside its pools: row 0 what the
-    decode steps added, row 1 what the prefill chunks did (kept apart so
-    that a step's own pairs can be read off after it)."""
-    return jnp.zeros((2, 2 + cfg.n_experts), jnp.int32)
 
 
 def rope_tables(cfg: ModelConfig):
@@ -211,143 +188,6 @@ def _attend_window_dense(cfg: ModelConfig, q, k, v, k_l, v_l, start_pos,
     return att, k_l, v_l
 
 
-# -- the feed-forward --------------------------------------------------------
-
-
-def _swiglu(cfg: ModelConfig, h: jax.Array, w1, w2, w3) -> jax.Array:
-    gate = _hidden_act(cfg, linear(h, w1))
-    return linear(gate * linear(h, w3), w2)
-
-
-def route(cfg: ModelConfig, h: jax.Array, gate: jax.Array):
-    """The router over its whole width, float32: ``(weights [N, k], experts
-    [N, k])`` for ``h [N, dim]``, weights renormalised over the chosen where
-    ``moe_norm_topk`` and scaled by ``moe_routed_scale``."""
-    logits = jnp.einsum("nd,ed->ne", h.astype(jnp.float32),
-                        gate.astype(jnp.float32), precision=_HIGHEST)
-    top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                             cfg.n_active_experts)
-    if cfg.moe_norm_topk:
-        top = top / jnp.sum(top, axis=-1, keepdims=True)
-    return top * cfg.moe_routed_scale, idx
-
-
-def routed_pairs(cfg: ModelConfig, idx: jax.Array, live: jax.Array):
-    """Of the (row, expert) pairs ``idx [N, k]``, flattened row-major:
-    ``local [N k]`` the expert's index among those held (``n_experts`` where
-    it is absent or the row is not ``live [N]``), and ``stats`` (held
-    pairs, absent pairs of live rows, tokens a held expert)."""
-    E = cfg.n_experts
-    local = idx - cfg.moe_first_expert
-    here = (local >= 0) & (local < E)
-    held = (here & live[:, None]).reshape(-1)
-    local = jnp.where(held, local.reshape(-1), E)
-    absent = jnp.sum(~here & live[:, None])
-    tokens = jnp.bincount(local, length=E + 1)[:E]
-    stats = jnp.concatenate([jnp.stack([jnp.sum(held), absent]),
-                             tokens]).astype(jnp.int32)
-    return local, stats
-
-
-def _sorted_pairs(cfg: ModelConfig, local: jax.Array, weights: jax.Array):
-    """The pairs ``local [N k]`` sorted by held expert, the absent ones
-    last: ``(rows, experts, w, n_held)``, each pair's token row, its
-    expert among those held (``n_experts`` behind the first ``n_held``) and
-    its router weight (0 there)."""
-    k = weights.shape[1]
-    order = jnp.argsort(local, stable=True)
-    experts = local[order]
-    w = jnp.where(experts < cfg.n_experts, weights.reshape(-1)[order], 0.0)
-    n_held = jnp.sum(local < cfg.n_experts).astype(jnp.int32)
-    return order // k, experts, w, n_held
-
-
-def _experts_step(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
-    """The decode form: the held pairs compacted to the front, one GEMV a
-    pair over the chosen expert's planes read in place (``expert_gemv``; its
-    XLA gather form off a TPU), rows summed back per token."""
-    rows, experts, w, n_held = _sorted_pairs(cfg, local, weights)
-    experts = jnp.minimum(experts, cfg.n_experts - 1)
-    P = rows.shape[0]
-    fast = _fast_mode(x) or lp.we1.scales.dtype == jnp.bfloat16
-    kw = eg.kernel_choice(P, lp.we1, fast)
-    if kw is not None and eg.kernel_choice(P, lp.we2, fast) is not None:
-        gemv = lambda a, stack: eg.expert_gemv(a, stack, m, experts, n_held,
-                                               **kw)
-    else:
-        gemv = lambda a, stack: eg.expert_gemv_xla(a, stack, m, experts,
-                                                   n_held, fast=fast)
-    xp = x[rows]
-    a = _hidden_act(cfg, gemv(xp, lp.we1)) * gemv(xp, lp.we3)
-    y = gemv(a.astype(x.dtype), lp.we2) * w[:, None]
-    return jnp.zeros(x.shape, jnp.float32).at[rows].add(y)
-
-
-def _experts_chunk(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
-    """The chunk form: pairs sorted by held expert, the absent ones behind
-    every group, one ``lax.ragged_dot`` a projection over the held planes
-    (dequantized here: the chunk regime is where that is cheapest)."""
-    E = cfg.n_experts
-    rows, experts, w, _n_held = _sorted_pairs(cfg, local, weights)
-    sizes = jnp.bincount(local, length=E + 1)[:E].astype(jnp.int32)
-    xs = x[rows]
-    at = lambda we: jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False), we)
-    d1, d2, d3 = (_experts_dense(at(we), xs)
-                  for we in (lp.we1, lp.we2, lp.we3))
-    dot = lambda a, d: jax.lax.ragged_dot(
-        a.astype(d.dtype), d, sizes, preferred_element_type=jnp.float32)
-    a = _hidden_act(cfg, dot(xs, d1)) * dot(xs, d3)
-    # rows past the groups are not computed; what ragged_dot leaves there
-    # is not read
-    y = jnp.where((experts < E)[:, None], dot(a, d2), 0.0) * w[:, None]
-    return jnp.zeros(x.shape, jnp.float32).at[rows].add(y)
-
-
-def routed_ffn(cfg: ModelConfig, h: jax.Array, lp: LagunaLayers, m,
-               live: jax.Array):
-    """``scale sum_{held} w_e E_e(h) + S(h)`` for ``h [B, T, dim]`` in routed
-    layer ``m``, and the layer's ``stats``; ``live [B * T]`` marks the rows
-    that are real (a dead slot's, a chunk's padding, are not routed)."""
-    from ..ops.quant_matmul import FUSED_MAX_M
-
-    B, T, D = h.shape
-    x = h.reshape(B * T, D)
-    at = lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False)
-    weights, idx = route(cfg, x, at(lp.moe_gate))
-    local, stats = routed_pairs(cfg, idx, live)
-    form = _experts_step if B * T <= FUSED_MAX_M else _experts_chunk
-    y = form(cfg, x, local, weights, m, lp)
-    if lp.ws1 is not None:
-        y = y + _swiglu(cfg, h, _plane(lp.ws1, m), _plane(lp.ws2, m),
-                        _plane(lp.ws3, m)).reshape(B * T, D)
-    return y.reshape(B, T, D).astype(h.dtype), stats
-
-
-def _ffn_half(cfg: ModelConfig, x: jax.Array, lp: LagunaLayers, l, live,
-              may_be_dense: bool):
-    """A layer's feed-forward half, residual added, and its ``stats``. Only
-    a period's first layer can be a leading dense one (``may_be_dense``,
-    static): there the choice is a ``cond`` on the traced layer index."""
-    h = rms_norm(x, jax.lax.dynamic_index_in_dim(lp.norm_ffn, l, 0, False),
-                 cfg.norm_epsilon)
-    m = jnp.maximum(l - cfg.n_dense_layers, 0)
-
-    def routed(h):
-        return routed_ffn(cfg, h, lp, m, live)
-
-    def dense(h):
-        d = jnp.minimum(l, cfg.n_dense_layers - 1)
-        return (_swiglu(cfg, h, _plane(lp.w1, d), _plane(lp.w2, d),
-                        _plane(lp.w3, d)), zero_stats(cfg))
-
-    if may_be_dense and cfg.n_dense_layers:
-        y, stats = jax.lax.cond(l < cfg.n_dense_layers, dense, routed, h)
-    else:
-        y, stats = routed(h)
-    return x + y, stats
-
-
 # -- the two programs --------------------------------------------------------
 
 
@@ -375,7 +215,7 @@ def _scan_periods(params: Params, cfg: ModelConfig, x, caches, stats, live,
             return out
 
         x = _attention_half(cfg, x, ap, heads, table, positions, att)
-        x, s = _ffn_half(cfg, x, lp, l, live, may_be_dense=first)
+        x, s = ffn_half(cfg, x, lp, l, live, may_be_dense=first)
         return x, box["caches"], stats + s
 
     def period(carry, p):

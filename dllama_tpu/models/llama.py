@@ -993,6 +993,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         from . import laguna
 
         return laguna.forward(params, cfg, tokens, start_pos, kv, n_valid)
+    if cfg.has_latent_cache:
+        from . import axk1
+
+        return axk1.forward(params, cfg, tokens, start_pos, kv, n_valid)
     start_pos = jnp.asarray(start_pos, dtype=jnp.int32)
     ragged = start_pos.ndim > 0
     # numerics observatory taps (runtime/numerics): a TRACE-TIME flag, so
@@ -1320,6 +1324,11 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
         return laguna.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
                                     write_lens)
+    if cfg.has_latent_cache:
+        from . import axk1
+
+        return axk1.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
+                                  write_lens)
     if _numerics.taps_active():
         raise ValueError("numerics taps are unsupported on the paged KV "
                          "path (use the dense slot pool for tap sessions)")

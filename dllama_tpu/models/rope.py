@@ -129,15 +129,26 @@ def yarn_attention_factor(factor: float) -> float:
     return 0.1 * math.log(factor) + 1.0
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """``0.1 mscale ln(factor) + 1`` (1 where nothing is stretched): the
+    DeepSeek-style configs' parametrisation, whose tables take the ratio of
+    two of these and whose scores take the square of the second
+    (``ModelConfig.attn_scale``)."""
+    return 0.1 * mscale * math.log(max(factor, 1.0)) + 1.0
+
+
 @functools.lru_cache(maxsize=16)
 def build_partial_rope_cache(seq_len: int, rot_dim: int, theta: float,
-                             yarn: tuple | None = None
+                             yarn: tuple | None = None,
+                             table_scale: float | None = None
                              ) -> tuple[np.ndarray, np.ndarray]:
     """cos/sin ``[seq_len, rot_dim // 2]`` float32 for a rotary embedding
     over the first ``rot_dim`` lanes of a head (half-split pairing,
     :func:`apply_rope_partial`). ``yarn = (factor, orig_max, beta_fast,
     beta_slow)`` selects :func:`yarn_inv_freq` and scales both tables by
-    :func:`yarn_attention_factor`; None is the plain ``theta^(-2i/rot_dim)``.
+    :func:`yarn_attention_factor`, or by ``table_scale`` where one is given
+    (latent attention's tables take the ratio of two mscales, 1 where they
+    are equal: models/axk1.py); None is the plain ``theta^(-2i/rot_dim)``.
     numpy, memoized: see :func:`build_rope_cache`."""
     half = rot_dim // 2
     if yarn is None:
@@ -147,7 +158,8 @@ def build_partial_rope_cache(seq_len: int, rot_dim: int, theta: float,
         factor, orig_max, beta_fast, beta_slow = yarn
         inv = yarn_inv_freq(theta, rot_dim, factor, orig_max, beta_fast,
                             beta_slow)
-        scale = yarn_attention_factor(factor)
+        scale = (yarn_attention_factor(factor) if table_scale is None
+                 else table_scale)
     angles = (np.arange(seq_len, dtype=np.float64)[:, None]
               * inv[None, :]).astype(np.float32)
     return ((np.cos(angles) * scale).astype(np.float32),

@@ -1,0 +1,229 @@
+"""The routed feed-forward of which this chip may hold a SHARE: what
+``models/laguna.py`` and ``models/axk1.py`` both run behind their attention
+halves.
+
+**The share is data.** The router always scores ``moe_router_width`` experts
+and takes ``n_active_experts``; the planes hold ``n_experts`` of them, from
+``moe_first_expert``. A (row, expert) pair whose expert is not held, or whose
+row is dead or padding, is not computed: the decode form compacts the held
+pairs to the front and :func:`~dllama_tpu.ops.expert_gemv.expert_gemv` loops
+over those alone; the chunk form runs every held expert that some row chose
+over every row through the fused Q40 chunk kernel and weights the rows that
+did not choose it 0 (:func:`_experts_chunk`). What the absent
+experts would have added is left out, and that partial sum goes on to the
+next layer: on one chip the layer runs without its exchange. With every
+expert held the same code is the whole layer.
+
+**The router** (:func:`route`) takes its score function (``cfg.moe_score``:
+a softmax or a sigmoid over the whole width, float32) and its group limit
+(``cfg.moe_n_group`` groups of which a token's experts come from the
+``cfg.moe_topk_group`` best, a group's score the sum of its ``n_active /
+topk_group`` largest) from the configuration.
+
+**Counters**: ``stats`` = (pairs computed here, pairs that fell on absent
+experts, tokens each held expert saw), summed over the layers, accumulated
+on the device and given back with the pools.
+
+A layer stack that uses these functions names its leaves ``norm_ffn``, ``w1
+w2 w3`` (the leading dense layers'), ``moe_gate``, ``we1 we2 we3``, ``ws1 ws2
+ws3``, the routed ones stacked over the ``n_moe_layers`` that have one.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import expert_gemv as eg
+from ..ops.linear import (LayerSlice, QuantizedWeight, Weight, _fast_mode,
+                          linear)
+from ..ops.norms import rms_norm
+from .config import ModelConfig
+from .llama import _hidden_act
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _plane(w: Weight, l) -> Weight:
+    """Entry ``l`` of a stacked 2-D matmul weight: stack + index for a Q40
+    plane (the fused kernel reads it where it lies, llama._layer_at), the
+    slice otherwise."""
+    if isinstance(w, QuantizedWeight):
+        return LayerSlice(w, l)
+    return jax.lax.dynamic_index_in_dim(w, l, 0, keepdims=False)
+
+
+def zero_stats(cfg: ModelConfig) -> jax.Array:
+    """One dispatch's routing counters: held pairs, absent pairs, tokens a
+    held expert."""
+    return jnp.zeros((2 + cfg.n_experts,), jnp.int32)
+
+
+def zero_totals(cfg: ModelConfig) -> jax.Array:
+    """The generator's running totals beside its pools: row 0 what the
+    decode steps added, row 1 what the prefill chunks did (kept apart so
+    that a step's own pairs can be read off after it)."""
+    return jnp.zeros((2, 2 + cfg.n_experts), jnp.int32)
+
+
+def swiglu(cfg: ModelConfig, h: jax.Array, w1, w2, w3) -> jax.Array:
+    gate = _hidden_act(cfg, linear(h, w1))
+    return linear(gate * linear(h, w3), w2)
+
+
+def route(cfg: ModelConfig, h: jax.Array, gate: jax.Array):
+    """The router over its whole width, float32: ``(weights [N, k], experts
+    [N, k])`` for ``h [N, dim]``. Scores are ``cfg.moe_score`` of the logits;
+    with ``cfg.moe_n_group`` groups the choice is limited to the
+    ``cfg.moe_topk_group`` groups whose ``k / topk_group`` largest scores sum
+    highest (ties go to the lower index, as ``lax.top_k`` breaks them);
+    weights are the chosen scores, renormalised over the chosen where
+    ``moe_norm_topk`` and scaled by ``moe_routed_scale``."""
+    logits = jnp.einsum("nd,ed->ne", h.astype(jnp.float32),
+                        gate.astype(jnp.float32), precision=_HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if cfg.moe_score == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    k, G = cfg.n_active_experts, cfg.moe_n_group
+    if G > 1:
+        N, W = scores.shape
+        per_group = jax.lax.top_k(scores.reshape(N, G, W // G),
+                                  k // cfg.moe_topk_group)[0].sum(axis=-1)
+        _, best = jax.lax.top_k(per_group, cfg.moe_topk_group)
+        allowed = jnp.zeros((N, G), bool).at[
+            jnp.arange(N)[:, None], best].set(True)
+        limited = jnp.where(jnp.repeat(allowed, W // G, axis=1), scores,
+                            -jnp.inf)
+        top, idx = jax.lax.top_k(limited, k)
+    else:
+        top, idx = jax.lax.top_k(scores, k)
+    if cfg.moe_norm_topk:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return top * cfg.moe_routed_scale, idx
+
+
+def routed_pairs(cfg: ModelConfig, idx: jax.Array, live: jax.Array):
+    """Of the (row, expert) pairs ``idx [N, k]``, flattened row-major:
+    ``local [N k]`` the expert's index among those held (``n_experts`` where
+    it is absent or the row is not ``live [N]``), and ``stats`` (held
+    pairs, absent pairs of live rows, tokens a held expert)."""
+    E = cfg.n_experts
+    local = idx - cfg.moe_first_expert
+    here = (local >= 0) & (local < E)
+    held = (here & live[:, None]).reshape(-1)
+    local = jnp.where(held, local.reshape(-1), E)
+    absent = jnp.sum(~here & live[:, None])
+    tokens = jnp.bincount(local, length=E + 1)[:E]
+    stats = jnp.concatenate([jnp.stack([jnp.sum(held), absent]),
+                             tokens]).astype(jnp.int32)
+    return local, stats
+
+
+def _sorted_pairs(cfg: ModelConfig, local: jax.Array, weights: jax.Array):
+    """The pairs ``local [N k]`` sorted by held expert, the absent ones
+    last: ``(rows, experts, w, n_held)``, each pair's token row, its
+    expert among those held (``n_experts`` behind the first ``n_held``) and
+    its router weight (0 there)."""
+    k = weights.shape[1]
+    order = jnp.argsort(local, stable=True)
+    experts = local[order]
+    w = jnp.where(experts < cfg.n_experts, weights.reshape(-1)[order], 0.0)
+    n_held = jnp.sum(local < cfg.n_experts).astype(jnp.int32)
+    return order // k, experts, w, n_held
+
+
+def _experts_step(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
+    """The decode form: the held pairs compacted to the front, one GEMV a
+    pair over the chosen expert's planes read in place (``expert_gemv``; its
+    XLA gather form off a TPU), rows summed back per token."""
+    rows, experts, w, n_held = _sorted_pairs(cfg, local, weights)
+    experts = jnp.minimum(experts, cfg.n_experts - 1)
+    P = rows.shape[0]
+    fast = _fast_mode(x) or lp.we1.scales.dtype == jnp.bfloat16
+    kw = eg.kernel_choice(P, lp.we1, fast)
+    if kw is not None and eg.kernel_choice(P, lp.we2, fast) is not None:
+        gemv = lambda a, stack: eg.expert_gemv(a, stack, m, experts, n_held,
+                                               **kw)
+    else:
+        gemv = lambda a, stack: eg.expert_gemv_xla(a, stack, m, experts,
+                                                   n_held, fast=fast)
+    xp = x[rows]
+    a = _hidden_act(cfg, gemv(xp, lp.we1)) * gemv(xp, lp.we3)
+    y = gemv(a.astype(x.dtype), lp.we2) * w[:, None]
+    return jnp.zeros(x.shape, jnp.float32).at[rows].add(y)
+
+
+def _experts_chunk(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
+    """The chunk form: every held expert that some row chose, over EVERY
+    row of the chunk, through the fused Q40 chunk kernel (its plane
+    dequantized in VMEM, read where it lies in the ``[NM, held, in, out]``
+    stack: entry ``m held + e``), the rows that did not choose it weighted
+    0. ``held / k`` times the pairs' FLOPs, which the MXU has to spare, and
+    no dequantized plane in HBM."""
+    E = cfg.n_experts
+    N, k = weights.shape
+    flat = lambda we: QuantizedWeight(*(a.reshape((-1,) + a.shape[2:])
+                                        for a in we))
+    f1, f2, f3 = flat(lp.we1), flat(lp.we2), flat(lp.we3)
+    # [N, held]: row n's router weight for held expert e, 0 where it did
+    # not choose it (or is not live: ``local`` reads ``held`` there)
+    w = jnp.zeros((N, E + 1), jnp.float32).at[
+        jnp.arange(N)[:, None], local.reshape(N, k)].add(weights)[:, :E]
+    chosen = jnp.bincount(local, length=E + 1)[:E]
+
+    def expert(e, y):
+        def some(y):
+            i = m * E + e
+            out = swiglu(cfg, x, LayerSlice(f1, i), LayerSlice(f2, i),
+                         LayerSlice(f3, i))
+            return y + out.astype(jnp.float32) * jax.lax.dynamic_slice_in_dim(
+                w, e, 1, axis=1)
+
+        return jax.lax.cond(chosen[e] > 0, some, lambda y: y, y)
+
+    return jax.lax.fori_loop(0, E, expert, jnp.zeros(x.shape, jnp.float32))
+
+
+def routed_ffn(cfg: ModelConfig, h: jax.Array, lp, m,
+               live: jax.Array):
+    """``scale sum_{held} w_e E_e(h) + S(h)`` for ``h [B, T, dim]`` in routed
+    layer ``m``, and the layer's ``stats``; ``live [B * T]`` marks the rows
+    that are real (a dead slot's, a chunk's padding, are not routed)."""
+    from ..ops.quant_matmul import FUSED_MAX_M
+
+    B, T, D = h.shape
+    x = h.reshape(B * T, D)
+    at = lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False)
+    weights, idx = route(cfg, x, at(lp.moe_gate))
+    local, stats = routed_pairs(cfg, idx, live)
+    form = _experts_step if B * T <= FUSED_MAX_M else _experts_chunk
+    y = form(cfg, x, local, weights, m, lp)
+    if lp.ws1 is not None:
+        y = y + swiglu(cfg, h, _plane(lp.ws1, m), _plane(lp.ws2, m),
+                        _plane(lp.ws3, m)).reshape(B * T, D)
+    return y.reshape(B, T, D).astype(h.dtype), stats
+
+
+def ffn_half(cfg: ModelConfig, x: jax.Array, lp, l, live,
+              may_be_dense: bool):
+    """A layer's feed-forward half, residual added, and its ``stats``. Only
+    a period's first layer can be a leading dense one (``may_be_dense``,
+    static): there the choice is a ``cond`` on the traced layer index."""
+    h = rms_norm(x, jax.lax.dynamic_index_in_dim(lp.norm_ffn, l, 0, False),
+                 cfg.norm_epsilon)
+    m = jnp.maximum(l - cfg.n_dense_layers, 0)
+
+    def routed(h):
+        return routed_ffn(cfg, h, lp, m, live)
+
+    def dense(h):
+        d = jnp.minimum(l, cfg.n_dense_layers - 1)
+        return (swiglu(cfg, h, _plane(lp.w1, d), _plane(lp.w2, d),
+                        _plane(lp.w3, d)), zero_stats(cfg))
+
+    if may_be_dense and cfg.n_dense_layers:
+        y, stats = jax.lax.cond(l < cfg.n_dense_layers, dense, routed, h)
+    else:
+        y, stats = routed(h)
+    return x + y, stats
+
+

@@ -21,6 +21,13 @@ where it lies, once a pair:
   by two DMAs into one half of a double buffer while the previous pair is
   dequantized and multiplied: the next pair's fetch runs under this pair's
   work;
+* a plane too wide for VMEM whole (7168 x 2048 is 58.7 MB with its two
+  landing halves and its dequantized copy) is walked in STRIPES of ``tn`` of
+  its ``N`` output columns (:func:`stripe`: the widest divisor of ``N`` in
+  whole lane tiles whose resident set fits), a stripe a double-buffer half:
+  the contraction stays whole, so a stripe's columns are final and no
+  partial sums are kept. A plane that fits (3072 x 1024) is one stripe and
+  the kernel is what it was;
 * the dequant is :func:`quant_matmul._decode_kernel`'s: one Q40 block of 32
   rows at a time on the VPU, the scale rounded to the dequant dtype first,
   then ONE dot over the whole contraction (``[1, K] @ [K, N]``), so a pair's
@@ -58,66 +65,90 @@ def _kernel(layer_ref, eid_ref, n_ref, x_ref, codes_hbm, scales_hbm, out_ref,
     layer, n = layer_ref[0], n_ref[0]
     wd_dt = wd_ref.dtype
     out_ref[...] = jnp.zeros_like(out_ref)
+    tn = cbuf.shape[2]
+    n_stripes = out_ref.shape[1] // tn
 
-    def copies(p, slot):
+    def copies(p, t, slot):
+        """Stripe ``t`` (static) of pair ``p``'s two planes into half
+        ``slot``."""
         e = eid_ref[p]
-        return (pltpu.make_async_copy(codes_hbm.at[layer, e], cbuf.at[slot],
+        cols = () if n_stripes == 1 else (slice(None), pl.ds(t * tn, tn))
+        at = lambda hbm: hbm.at[(layer, e) + cols]
+        return (pltpu.make_async_copy(at(codes_hbm), cbuf.at[slot],
                                       sems.at[0, slot]),
-                pltpu.make_async_copy(scales_hbm.at[layer, e], sbuf.at[slot],
+                pltpu.make_async_copy(at(scales_hbm), sbuf.at[slot],
                                       sems.at[1, slot]))
 
     @pl.when(n > 0)
     def _():
-        for c in copies(0, 0):
+        for c in copies(0, 0, 0):
             c.start()
 
     n_blocks = cbuf.shape[1] // Q40_BLOCK_SIZE
 
     def pair(p, carry):
-        slot = p % 2
+        for t in range(n_stripes):
+            slot = (p * n_stripes + t) % 2
+            if t + 1 < n_stripes:
+                for c in copies(p, t + 1, 1 - slot):
+                    c.start()
+            else:
+                @pl.when(p + 1 < n)
+                def _(slot=slot):
+                    for c in copies(p + 1, 0, 1 - slot):
+                        c.start()
 
-        @pl.when(p + 1 < n)
-        def _():
-            for c in copies(p + 1, 1 - slot):
-                c.start()
+            for c in copies(p, t, slot):
+                c.wait()
+            s32_ref[...] = sbuf[slot].astype(wd_dt).astype(jnp.float32)
 
-        for c in copies(p, slot):
-            c.wait()
-        s32_ref[...] = sbuf[slot].astype(wd_dt).astype(jnp.float32)
+            def dequant(c, carry, slot=slot):
+                for j in range(groups):
+                    g = c * groups + j
+                    k0 = pl.multiple_of(g * Q40_BLOCK_SIZE, Q40_BLOCK_SIZE)
+                    rows = pl.ds(k0, Q40_BLOCK_SIZE)
+                    wd_ref[rows, :] = (cbuf[slot, rows, :].astype(jnp.float32)
+                                       * s32_ref[pl.ds(g, 1), :]).astype(wd_dt)
+                return carry
 
-        def dequant(c, carry):
-            for j in range(groups):
-                g = c * groups + j
-                k0 = pl.multiple_of(g * Q40_BLOCK_SIZE, Q40_BLOCK_SIZE)
-                rows = pl.ds(k0, Q40_BLOCK_SIZE)
-                wd_ref[rows, :] = (cbuf[slot, rows, :].astype(jnp.float32)
-                                   * s32_ref[pl.ds(g, 1), :]).astype(wd_dt)
-            return carry
-
-        jax.lax.fori_loop(0, n_blocks // groups, dequant, 0)
-        out_ref[pl.ds(p, 1), :] = jax.lax.dot_general(
-            x_ref[pl.ds(p, 1), :].astype(wd_dt), wd_ref[...],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=None if fast else _HIGHEST)
+            jax.lax.fori_loop(0, n_blocks // groups, dequant, 0)
+            out_ref[pl.ds(p, 1), t * tn:(t + 1) * tn] = jax.lax.dot_general(
+                x_ref[pl.ds(p, 1), :].astype(wd_dt), wd_ref[...],
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=None if fast else _HIGHEST)
         return carry
 
     jax.lax.fori_loop(0, n, pair, 0)
 
 
-def supports(n_pairs: int, K: int, N: int, fast: bool, *,
-             compiled: bool = True) -> bool:  # dlint: static-fn
-    """Whether one expert's planes, twice, its dequantized copy and the
-    pairs' rows fit the kernel's VMEM budget. ``compiled``: for Mosaic, whose
-    DMAs land whole lane tiles (``N`` a multiple of 128); interpret mode
-    takes any width of whole sublanes."""
-    if K % Q40_BLOCK_SIZE or N % (128 if compiled else 8) or n_pairs < 1:
-        return False
+def stripe(n_pairs: int, K: int, N: int, fast: bool, *,
+           compiled: bool = True) -> int | None:  # dlint: static-fn
+    """Output columns a stripe of one expert's planes takes: the widest
+    ``N / i`` in whole lane tiles (``compiled``: for Mosaic, whose DMAs land
+    them; interpret mode takes whole sublanes) for which a stripe twice, its
+    dequantized copy and the pairs' rows fit the kernel's VMEM budget. ``N``
+    itself where the whole plane fits; None where nothing does."""
+    lane = 128 if compiled else 8
+    if K % Q40_BLOCK_SIZE or N % lane or n_pairs < 1:
+        return None
     wd_bytes = 2 if fast else 4
     kb = K // Q40_BLOCK_SIZE
-    resident = (K * N * (2 + wd_bytes) + kb * N * (2 * 4 + 4)
-                + 2 * n_pairs * (K + N) * 4)
-    return resident <= _VMEM_BUDGET
+    rows = 2 * n_pairs * (K + N) * 4
+    for i in range(1, N // lane + 1):
+        tn = N // i
+        if N % i or tn % lane:
+            continue
+        if K * tn * (2 + wd_bytes) + kb * tn * (2 * 4 + 4) + rows \
+                <= _VMEM_BUDGET:
+            return tn
+    return None
+
+
+def supports(n_pairs: int, K: int, N: int, fast: bool, *,
+             compiled: bool = True) -> bool:  # dlint: static-fn
+    """Whether the kernel covers these planes, whole or in stripes."""
+    return stripe(n_pairs, K, N, fast, compiled=compiled) is not None
 
 
 def kernel_choice(n_pairs: int, stack: QuantizedWeight,
@@ -142,6 +173,10 @@ def kernel_choice(n_pairs: int, stack: QuantizedWeight,
     return {"interpret": kw["interpret"], "fast": fast}
 
 
+def _stripe_of(P: int, K: int, N: int, fast: bool, interpret: bool) -> int:
+    return stripe(P, K, N, fast, compiled=not interpret) or N
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "fast"))
 def expert_gemv(x: jax.Array, stack: QuantizedWeight, layer: jax.Array,
                 experts: jax.Array, n_pairs: jax.Array, *,
@@ -156,6 +191,7 @@ def expert_gemv(x: jax.Array, stack: QuantizedWeight, layer: jax.Array,
     kb = K // Q40_BLOCK_SIZE
     groups = next(c for c in (8, 4, 2, 1) if kb % c == 0)
     wd_dtype = jnp.bfloat16 if fast else jnp.float32
+    tn = _stripe_of(P, K, N, fast, interpret)
     whole = lambda i, *_: (0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, each pair's expert, the pair count
@@ -165,11 +201,11 @@ def expert_gemv(x: jax.Array, stack: QuantizedWeight, layer: jax.Array,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((P, N), whole, memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((2, K, N), jnp.int8),
-            pltpu.VMEM((2, kb, N), stack.scales.dtype),
+            pltpu.VMEM((2, K, tn), jnp.int8),
+            pltpu.VMEM((2, kb, tn), stack.scales.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),          # (codes | scales, half)
-            pltpu.VMEM((K, N), wd_dtype),
-            pltpu.VMEM((kb, N), jnp.float32),
+            pltpu.VMEM((K, tn), wd_dtype),
+            pltpu.VMEM((kb, tn), jnp.float32),
         ])
     return pl.pallas_call(
         functools.partial(_kernel, groups=groups, fast=fast),

@@ -231,7 +231,13 @@ class InferenceEngine:
             # ignored and no code stands in
             tp = 1 if tp is None else tp
             state = self.cfg.has_state
-            what = ("a decoder with an SSD mixer beside attention in every "
+            latent = self.cfg.has_latent_cache
+            what = ("a decoder with latent attention and an expert share "
+                    "(one pool of compressed rows a sequence; the layer scan "
+                    "has no mesh plan yet, the share's exchange between "
+                    "chips is not built)"
+                    if latent else
+                    "a decoder with an SSD mixer beside attention in every "
                     "layer (a recurrent state a layer; the layer scan "
                     "carries the state pool and has no mesh plan yet)"
                     if self.cfg.has_ssm else
@@ -246,15 +252,23 @@ class InferenceEngine:
                 ("no --kv-block-size (the dense slot pool, and the "
                  "single-sequence inference/chat/perplexity path: only the "
                  "paged generator carries "
-                 + ("the state pool)" if state else "the two block pools)"),
+                 + ("the state pool)" if state else "the latent pool)"
+                    if latent else "the two block pools)"),
                  not int(kv_block_size or 0)),
                 ("--spec-lookup (a rejected draft cannot be rolled back "
                  "out of a recurrent state)" if state else
+                 "--spec-lookup (the latent walk takes one token a row; a "
+                 "verify's lanes would each need a bound of their own)"
+                 if latent else
                  "--spec-lookup (a sliding window's walk takes one token a "
                  "row; a verify's lanes would each need a window of their "
                  "own)", self.spec_lookup > 0),
                 ("--kv-host-blocks (the host tier spills and pages in K/V "
                  "blocks; a state has no host copy)" if state else
+                 "--kv-host-blocks (the host tier's transfer programs, and "
+                 "with them kvwire export/ingest and mid-stream resume, "
+                 "frame a block as K and V planes; a latent pool's block is "
+                 "one plane of compressed rows)" if latent else
                  "--kv-host-blocks (the host tier keeps one list of blocks "
                  "by token range; the window pool's blocks behind the "
                  "window are gone, and with them kvwire export/ingest and "
@@ -636,10 +650,12 @@ class InferenceEngine:
         # a layer with an SSD mixer beside its attention is neither
         kinds.set(self.cfg.n_layers if self.cfg.has_ssm else 0,
                   kind="ssm_beside_full")
-        kinds.set(0 if self.cfg.has_ssm else self.cfg.n_kv_layers,
-                  kind="full")
+        kinds.set(0 if self.cfg.has_ssm or self.cfg.has_latent_cache
+                  else self.cfg.n_kv_layers, kind="full")
+        kinds.set(self.cfg.n_layers if self.cfg.has_latent_cache else 0,
+                  kind="latent")
         kinds.set(self.cfg.n_window_layers, kind="sliding")
-        # the expert share (models/laguna.py): held here, of those routed
+        # the expert share (models/share.py): held here, of those routed
         telemetry.registry().gauge(telemetry.MOE_EXPERTS_HELD).set(
             self.cfg.n_experts)
         telemetry.registry().gauge(telemetry.MOE_EXPERTS_TOTAL).set(

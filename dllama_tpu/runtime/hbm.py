@@ -74,6 +74,23 @@ def matmul_weight_count(cfg) -> int:
                  + cfg.q_dim * cfg.dim + cfg.dim * cfg.ssm_in_dim
                  + cfg.ssm_inner_dim * cfg.dim + 3 * cfg.dim * cfg.hidden_dim)
         return cfg.n_layers * layer + cfg.dim * cfg.vocab_size
+    if cfg.has_latent_cache:
+        # what is HELD: latent attention's planes a layer (W_ukv per head in
+        # the compute dtype: two Q40 weights' bytes a weight), the
+        # leading dense feed-forward, the held experts of a routed layer with its
+        # router (over its whole width) and shared expert, the vocabulary's
+        # rows
+        H = cfg.n_heads
+        attn = (cfg.dim * (cfg.q_lora_rank + cfg.latent_row)
+                + cfg.q_lora_rank * H * cfg.head_dim
+                + 2 * cfg.kv_lora_rank * H * (cfg.qk_nope_dim + cfg.v_head_dim)
+                + H * cfg.v_head_dim * cfg.dim)
+        routed = (cfg.dim * cfg.moe_router_width
+                  + 3 * cfg.dim * (cfg.hidden_dim * cfg.n_experts
+                                   + cfg.shared_expert_dim))
+        return (cfg.n_layers * attn
+                + cfg.n_dense_layers * 3 * cfg.dim * cfg.dense_hidden_dim
+                + cfg.n_moe_layers * routed + cfg.dim * cfg.vocab_size)
     if cfg.has_window_layers:
         # what is HELD: two kinds of attention layer at their own head
         # counts, the leading dense feed-forward, the held experts of a
@@ -125,8 +142,8 @@ def estimate_device_bytes(cfg, *, weight_repr: str, kv_dtype_bytes: int,
         weights = emb_bytes + int(2 * per_layer * wbytes)
     else:
         weights = emb_bytes + int(matmul_weight_count(cfg) * wbytes)
-    kv = (2 * cfg.n_kv_layers * padded_cache_len(cfg.seq_len) * cfg.kv_dim
-          * batch * kv_dtype_bytes)
+    kv = (cfg.n_kv_layers * padded_cache_len(cfg.seq_len)
+          * cfg.cache_row_elems * batch * kv_dtype_bytes)
     need = int(((weights + kv) / max(1, n_shards)) * _MARGIN) + _FIXED_OVERHEAD
     return {"weights_bytes": weights, "kv_bytes": kv,
             "need_per_device": need}
@@ -158,8 +175,9 @@ def fit_batch_slots(cfg, n_slots: int, *, weight_repr: str,
 def estimate_block_pool_bytes(cfg, n_blocks: int, block_size: int,
                               kv_dtype_bytes: int) -> int:
     """Device bytes of a paged KV block pool
-    ``[L, n_blocks, n_kv, block_size, hd]`` ×2 (K and V)."""
-    return 2 * cfg.n_kv_layers * n_blocks * cfg.kv_dim * block_size \
+    ``[L, n_blocks, n_kv, block_size, hd]`` ×2 (K and V), or of the one
+    latent pool ``[L, n_blocks, 1, block_size, latent_row]``."""
+    return cfg.n_kv_layers * n_blocks * cfg.cache_row_elems * block_size \
         * kv_dtype_bytes
 
 

@@ -441,8 +441,10 @@ def note_q40_path(path: str) -> None:
 
 # the recurrent mixers by the key their counts are filed under: the gauge
 # each publishes to and the name the start-up report gives it
-_MIXER_GAUGES = {"gdn": telemetry.GATED_DELTA_PATHS, "ssd": telemetry.SSD_PATHS}
-_MIXER_TITLES = {"gdn": "gated delta rule", "ssd": "ssd mixer"}
+_MIXER_GAUGES = {"gdn": telemetry.GATED_DELTA_PATHS, "ssd": telemetry.SSD_PATHS,
+                 "mla": telemetry.MLA_PATHS}
+_MIXER_TITLES = {"gdn": "gated delta rule", "ssd": "ssd mixer",
+                 "mla": "latent attention"}
 
 
 def _note_mixer_path(kind: str, form: str, path: str) -> None:
@@ -462,6 +464,13 @@ def note_gdn_path(form: str, path: str) -> None:
 def note_ssd_path(form: str, path: str) -> None:
     """:func:`note_gdn_path` for the SSD mixer (``models.falcon_h1``)."""
     _note_mixer_path("ssd", form, path)
+
+
+def note_mla_path(form: str, path: str) -> None:
+    """:func:`note_gdn_path` for latent attention (``models.axk1``): ``step``
+    and ``chunk`` each took ``pallas`` (the ``mla_paged_step`` / ``mla_chunk``
+    kernel) or ``xla``."""
+    _note_mixer_path("mla", form, path)
 
 
 def _monitoring_on() -> bool:
@@ -583,6 +592,13 @@ def startup_line(engine) -> str:
              if cfg.is_hybrid else
              f"; layers: {cfg.n_layers} with an SSD mixer beside attention"
              if cfg.has_ssm else "")
+    if cfg.has_latent_cache:
+        kinds = (f"; layers: {cfg.n_layers} of latent attention (a row of "
+                 f"{cfg.latent_dim} in {cfg.latent_row} lanes a token); "
+                 f"experts: {cfg.n_experts} of {cfg.moe_router_width} held "
+                 f"from {cfg.moe_first_expert}, {cfg.n_active_experts} a "
+                 f"token of {cfg.moe_topk_group or 1} of "
+                 f"{cfg.moe_n_group or 1} groups")
     if cfg.has_window_layers:
         kinds = (f"; layers: {cfg.n_kv_layers} full, {cfg.n_window_layers} "
                  f"sliding (window {cfg.sliding_window}); experts: "

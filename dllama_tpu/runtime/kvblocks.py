@@ -198,10 +198,17 @@ class PagedKVCache(NamedTuple):
 
     The block axis replaces the slot-pool batch axis; under a mesh plan the
     kv-head axis shards over tp exactly like the dense cache (the block and
-    row axes stay replicated — parallel/sharding.paged_kv_sharding)."""
+    row axes stay replicated — parallel/sharding.paged_kv_sharding).
+
+    The block geometry is the ARCHITECTURE's (``cfg.cache_heads``,
+    ``cfg.cache_width``): K and V of every K/V head, or, with latent
+    attention (models/axk1.py), ONE pool ``k [L, n_blocks, 1, block_size,
+    latent_row]`` of compressed rows and ``v`` None. It is one list of blocks
+    by token range either way, so the allocator, the prefix index and
+    copy-on-write are the same."""
 
     k: "jax.Array"
-    v: "jax.Array"
+    v: "jax.Array | None"
 
     @classmethod
     def create(cls, cfg, n_blocks: int, block_size: int,
@@ -210,10 +217,11 @@ class PagedKVCache(NamedTuple):
 
         dtype = dtype if dtype is not None else jnp.float32
         # every layer, or a hybrid decoder's full ones only
-        shape = (cfg.n_kv_layers, n_blocks, cfg.n_kv_heads, block_size,
-                 cfg.head_dim)
+        shape = (cfg.n_kv_layers, n_blocks, cfg.cache_heads, block_size,
+                 cfg.cache_width)
         return cls(k=jnp.zeros(shape, dtype=dtype),
-                   v=jnp.zeros(shape, dtype=dtype))
+                   v=(None if cfg.has_latent_cache
+                      else jnp.zeros(shape, dtype=dtype)))
 
     @property
     def n_blocks(self) -> int:

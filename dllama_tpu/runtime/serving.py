@@ -446,6 +446,12 @@ class _GeneratorCore:
         pool prices the request in blocks."""
         return True
 
+    def prefix_totals(self) -> tuple[int, int]:
+        """``(matched, prompt)``: the prompt tokens admitted since start-up
+        and those of them that came from matched blocks. The dense pool
+        shares nothing."""
+        return 0, 0
+
     def abort_admit(self, adm: "_Admission") -> None:  # dlint: owner=loop-thread
         """Roll back an admission that will never commit (client cancel
         mid-prefill, or a prefill dispatch raised). The dense pool has
@@ -660,14 +666,15 @@ class _GeneratorCore:
     def _advance_traced(self, adm: "_Admission", span) -> bool:  # dlint: owner=loop-thread
         """``_advance_prefill`` under its ``prefill_dispatch`` phase; while
         a profiler listens the phase names its cause: the request, the
-        valid tokens of the chunk this call enqueued and the padded
-        width dispatched (both 0 for a call that only paged blocks in)."""
+        valid tokens of the chunk this call enqueued, the padded width
+        dispatched (both 0 for a call that only paged blocks in) and the
+        position the chunk starts at (the context it attends behind)."""
         pos0 = adm.pos
         done = self._advance_prefill(adm)
         if span.traced:
             tokens = adm.pos - pos0
             span.set(rid=adm.req.rid, tokens=tokens,
-                     bucket=adm.bucket if tokens else 0)
+                     bucket=adm.bucket if tokens else 0, start=pos0)
         return done
 
     def _prefill_chunk(self, adm: "_Admission", padded, n_valid: int) -> None:
@@ -1536,16 +1543,20 @@ class PagedGenerator(_GeneratorCore):
         # and the routing counters the step and the chunks accumulate on
         # the device (models/laguna.py)
         self.wpool = self.wkv = self.wtables = self.moe_stats = None
-        if self.window:
-            from ..models.laguna import zero_totals
+        # latent attention (models/axk1.py): ONE pool of compressed rows,
+        # the same list of blocks by token range, so prefix reuse is carried
+        self.latent = self.cfg.has_latent_cache
+        if self.cfg.has_expert_share:
+            from ..models.share import zero_totals
 
+            self.moe_stats = zero_totals(self.cfg)
+            self._moe_seen = np.zeros((2, 2 + self.cfg.n_experts), np.int64)
+        if self.window:
             self.wpool = BlockPool(n_wblocks, block_size)
             wshape = (self.cfg.n_window_layers, n_wblocks,
                       self.cfg.n_kv_heads, block_size, self.cfg.head_dim)
             self.wkv = PagedKVCache(k=jnp.zeros(wshape, engine.kv_dtype),
                                     v=jnp.zeros(wshape, engine.kv_dtype))
-            self.moe_stats = zero_totals(self.cfg)
-            self._moe_seen = np.zeros((2, 2 + self.cfg.n_experts), np.int64)
         # window blocks a slot owns, by table index: host truth from
         # begin_admit on (the table row is published at commit)
         self._wbids: list[dict[int, int]] = [{} for _ in range(n_slots)]
@@ -1569,6 +1580,9 @@ class PagedGenerator(_GeneratorCore):
         # block-priced admission guarantee holds across the whole batch,
         # not just per request
         self._reserve = [0] * n_slots
+        # prompt tokens admitted and those of them that came from matched
+        # blocks (or a copy-on-write block's reused rows), since start-up
+        self._n_prefix_tokens = self._n_prompt_tokens = 0
 
         _sc = getattr(engine, "introspection_scope", None) or "default"
         from ..models.llama import paged_sampled_step_guarded
@@ -1592,13 +1606,24 @@ class PagedGenerator(_GeneratorCore):
         self._prefill_fwd = engine._step
         M, bs = self.table_width, block_size
 
+        heads, width = self.cfg.cache_heads, self.cfg.cache_width
+
+        def view(pool, table):
+            g = pool[:, table]                    # [L, M, n_kv, bs, hd]
+            g = jnp.moveaxis(g, 1, 2)             # [L, n_kv, M, bs, hd]
+            return g.reshape(g.shape[0], 1, heads, M * bs, width)
+
         def _take_fn(pkv, table):
-            def view(pool):
-                g = pool[:, table]                    # [L, M, n_kv, bs, hd]
-                g = jnp.moveaxis(g, 1, 2)             # [L, n_kv, M, bs, hd]
-                return g.reshape(g.shape[0], 1, self.cfg.n_kv_heads,
-                                 M * bs, self.cfg.head_dim)
-            return KVCache(k=view(pkv.k), v=view(pkv.v))
+            return KVCache(k=view(pkv.k, table), v=view(pkv.v, table))
+
+        def _take_latent_fn(pkv, table):
+            # the slot's latent rows through its table, matched prefix
+            # blocks included: the chunks attend over them as they lie
+            from ..models.axk1 import LatentColumn
+            from ..models.share import zero_stats
+
+            return LatentColumn(c=view(pkv.k, table),
+                                stats=zero_stats(self.cfg))
 
         def _take_state_fn(pkv, table):
             # an admission starts from a zero state: prefix blocks are
@@ -1619,17 +1644,17 @@ class PagedGenerator(_GeneratorCore):
                              max(1, self.cfg.layer_period))
         slide_ids = np.setdiff1d(np.arange(self.cfg.n_layers), full_ids)
 
+        def back(pool, c, table):
+            L = c.shape[0]
+            c = c[:, 0].reshape(L, heads, M, bs, width)
+            c = jnp.moveaxis(c, 2, 1)                 # [L, M, n_kv, bs, hd]
+            return pool.at[:, table].set(c.astype(pool.dtype))
+
         def _put_window_fn(pkv, wkv, stats, col, table, wtable):
             # the column's full layers through the slot's table, its
             # sliding layers through the window table (entries behind the
             # window are null: those rows land in the null block), and the
             # chunks' routing counters into the running totals' chunk row
-            def back(pool, c, tbl):
-                L = c.shape[0]
-                c = c[:, 0].reshape(L, self.cfg.n_kv_heads, M, bs,
-                                    self.cfg.head_dim)
-                c = jnp.moveaxis(c, 2, 1)
-                return pool.at[:, tbl].set(c.astype(pool.dtype))
             return (PagedKVCache(k=back(pkv.k, col.k[full_ids], table),
                                  v=back(pkv.v, col.v[full_ids], table)),
                     PagedKVCache(k=back(wkv.k, col.k[slide_ids], wtable),
@@ -1642,26 +1667,31 @@ class PagedGenerator(_GeneratorCore):
                              conv=put(spool.conv, conv[:, 0], row, 1))
 
         def _put_fn(pkv, col, table):
-            def back(pool, c):
-                L = c.shape[0]
-                c = c[:, 0].reshape(L, self.cfg.n_kv_heads, M, bs,
-                                    self.cfg.head_dim)
-                c = jnp.moveaxis(c, 2, 1)             # [L, M, n_kv, bs, hd]
-                return pool.at[:, table].set(c.astype(pool.dtype))
-            return PagedKVCache(k=back(pkv.k, col.k), v=back(pkv.v, col.v))
+            return PagedKVCache(k=back(pkv.k, col.k, table),
+                                v=back(pkv.v, col.v, table))
+
+        def _put_latent_fn(pkv, stats, col, table):
+            # the column's rows through the slot's OWN entries (matched
+            # ones are null there), the chunks' routing counters into the
+            # running totals' chunk row
+            return (PagedKVCache(k=back(pkv.k, col.c, table), v=None),
+                    stats.at[1].add(col.stats))
 
         def _copy_fn(pkv, src, dst):
             def cp(pool):
                 blk = jax.lax.dynamic_slice_in_dim(pool, src, 1, axis=1)
                 return jax.lax.dynamic_update_slice_in_dim(pool, blk, dst,
                                                            axis=1)
-            return PagedKVCache(k=cp(pkv.k), v=cp(pkv.v))
+            # a latent pool has no V plane: the tree holds one array
+            return jax.tree.map(cp, pkv)
 
         # raw jit is deliberate for the three block-movement programs:
         # plan-independent gather/scatter/copy (no constrain()), safe to
         # share across engines — same argument as the dense pool's pair
         self._take = jax.jit(_take_state_fn if self.cfg.has_state  # dlint: disable=jit-entry
-                             else _take_window_fn if self.window else _take_fn)
+                             else _take_window_fn if self.window
+                             else _take_latent_fn if self.latent else _take_fn)
+        self._put_latent = jax.jit(_put_latent_fn, donate_argnums=(0, 1))  # dlint: disable=jit-entry
         self._put_window = jax.jit(_put_window_fn, donate_argnums=(0, 1, 2))  # dlint: disable=jit-entry
         # a recurrent state's commit writes the admission's state to the
         # slot's row of the state pool, in place
@@ -1758,13 +1788,16 @@ class PagedGenerator(_GeneratorCore):
             max(0, n_wblocks - 1))
         self._m_moe_pairs = self._tm.counter(telemetry.MOE_PAIRS)
         self._m_moe_tokens = self._tm.counter(telemetry.MOE_EXPERT_TOKENS)
-        if self.window:
+        if self.moe_stats is not None:
             for where in ("held", "absent"):
                 self._m_moe_pairs.inc(0, where=where)
         self._update_block_gauges()
         engine._stamp_startup("generator", t_phase)
 
     # -- pool bookkeeping ---------------------------------------------------
+
+    def prefix_totals(self) -> tuple[int, int]:
+        return int(self._n_prefix_tokens), int(self._n_prompt_tokens)
 
     def _update_block_gauges(self) -> None:
         self._m_blocks_used.set(self.pool.used_blocks())
@@ -1972,6 +2005,12 @@ class PagedGenerator(_GeneratorCore):
                 "dtype": str(_np.dtype(self.eng.kv_dtype))}
 
     def _refuse_wire(self) -> None:
+        if self.latent:
+            raise ValueError(
+                "kvwire export/ingest frames a block as K and V planes of "
+                "[layers, kv heads, block, head width]; a latent pool's "
+                "block is one plane of compressed rows, which has no wire "
+                "format yet")
         if self.wpool is not None:
             raise ValueError(
                 "kvwire export/ingest moves one list of K/V blocks by token "
@@ -2091,6 +2130,10 @@ class PagedGenerator(_GeneratorCore):
             shared, n_tok, cow_src, cow_r = [], 0, None, 0
         else:
             shared, n_tok, cow_src, cow_r = self.pool.match_prefix(rest)
+        if req.score and self.latent:
+            raise ValueError(
+                "teacher-forced scoring is not carried to a latent column "
+                "(its chunks carry routing counters, not scores)")
         skip = self.cfg.prefix_reuse_skipped
         if skip is not None:
             if req.score:
@@ -2244,6 +2287,10 @@ class PagedGenerator(_GeneratorCore):
                                 telemetry.now_ns(), slot=slot,
                                 n_tokens=reused)
         self._note_admitted(req, slot, reused)
+        # matched against prompt tokens, as running totals: a traced step's
+        # ``step_wait`` span carries both, ``admit_begin`` its own admissions'
+        self._n_prefix_tokens += reused
+        self._n_prompt_tokens += len(rest)
         self._update_block_gauges()
         return adm
 
@@ -2315,6 +2362,10 @@ class PagedGenerator(_GeneratorCore):
                 span.set(state_bytes=adm.col.s.nbytes + adm.col.conv.nbytes)
             if self.wpool is not None:
                 span.set(window_blocks=len(self._wbids[adm.slot]))
+            if self.latent and adm.col is not None:
+                # the latent rows this commit wrote: the slot's own blocks
+                own = len(self._seq_bids[adm.slot]) - self._n_shared[adm.slot]
+                span.set(latent_bytes=own * self._block_bytes)
         return True
 
     def _advance_prefill(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
@@ -2388,6 +2439,10 @@ class PagedGenerator(_GeneratorCore):
                     self.pkv, self.wkv, self.moe_stats, adm.col,
                     jnp.asarray(put_table),
                     jnp.asarray(self._wtable_row(slot)))
+            elif self.latent:
+                self.pkv, self.moe_stats = self._put_latent(
+                    self.pkv, self.moe_stats, adm.col,
+                    jnp.asarray(put_table))
             else:
                 self.pkv = self._put(
                     self.pkv, KVCache(k=adm.col.k, v=adm.col.v),
@@ -2596,16 +2651,25 @@ class PagedGenerator(_GeneratorCore):
                 # two pools and the running counters in, all three back
                 cache = (self.pkv, self.wkv, self.moe_stats)
                 tables = self._both_tables
+            elif self.latent:
+                cache = (self.pkv, self.moe_stats)
+                # blocks this step's latent walk reads, over the live rows
+                # (ops/mla.py walks ceil((pos + 1) / block_size) entries)
+                walk_blocks = int(sum(
+                    -(-(int(self.pos[i]) + 1) // self.block_size)
+                    for i in active))
             (nxt, nf), cache = io.call(
                 self._step, cache, self.next_token.astype(np.int32)[:, None],
                 self.pos.astype(np.int32), tables, temps, topps, coins)
             if self.wpool is not None:
                 self.pkv, self.wkv, self.moe_stats = cache
+            elif self.latent:
+                self.pkv, self.moe_stats = cache
             elif self.spool is None:
                 self.pkv = cache
             else:
                 self.pkv, self.spool = cache
-            if self.wpool is None:
+            if self.moe_stats is None:
                 nxt, nf = io.fetch(tokens=nxt, nonfinite=nf)
             else:
                 # the routing counters are the same program's output as
@@ -2613,6 +2677,13 @@ class PagedGenerator(_GeneratorCore):
                 nxt, nf, totals = io.fetch(tokens=nxt, nonfinite=nf,
                                            moe_stats=self.moe_stats)
                 self._note_moe(totals, wait)
+            if self.latent:
+                wait.set(mla_walk_blocks=walk_blocks)
+            if wait.traced:
+                # running totals, as the routing counters': a reader of a
+                # traced slice takes last less first
+                matched, prompt = self.prefix_totals()
+                wait.set(prefix_tokens=matched, prompt_tokens=prompt)
         ms = (time.perf_counter() - t0) * 1000.0
         with self.flight.tick_phase("emit"):
             self._settle_prefill(wait.t0_ns, wait.t1_ns)
@@ -3432,8 +3503,15 @@ class BatchScheduler:
             if self._migrating:
                 self._service_migrations()
         with self.flight.tick_phase("admit_begin") as span:
+            before = self.gen.prefix_totals()
             rids = self._begin_admissions()
             span.set(admitted=len(rids))
+            if rids:
+                # of the prompt tokens admitted here, those that came from
+                # matched blocks (the paged generator's running totals)
+                matched, prompt = (a - b for a, b in zip(
+                    self.gen.prefix_totals(), before))
+                span.set(prefix_tokens=matched, prompt_tokens=prompt)
             if rids and span.traced:
                 span.set(rids="/".join(map(str, rids)))
         self._advance_admissions()

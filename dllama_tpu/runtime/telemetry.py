@@ -191,6 +191,7 @@ PROGRAM_FLOPS = "dllama_program_flops"
 Q40_MATMUL_PATHS = "dllama_q40_matmul_paths"
 GATED_DELTA_PATHS = "dllama_gated_delta_paths"
 SSD_PATHS = "dllama_ssd_paths"
+MLA_PATHS = "dllama_mla_paths"
 LAYER_KINDS = "dllama_layer_kinds"
 STATE_SLOTS_USED = "dllama_state_slots_used"
 STATE_SLOTS_TOTAL = "dllama_state_slots_total"
@@ -469,12 +470,19 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "SSD (Mamba-2) mixers of a program's newest trace by form "
           "(chunk: a prefill chunk; step: the decode step) and the path "
           "they took: pallas (the ssd_step kernel) or xla"),
+    _spec(MLA_PATHS, "gauge",
+          "Latent attention (MLA) layers of a program's newest trace by "
+          "form (step: the decode step over the latent pool; chunk: a "
+          "prefill chunk over a latent column) and the path they took: "
+          "pallas (the mla_paged_step and mla_chunk kernels) or xla; both "
+          "forms are absorbed"),
     _spec(LAYER_KINDS, "gauge",
           "Layers of the loaded model by kind (linear: gated delta-rule "
           "layers with a recurrent state; full: softmax attention with a "
           "K/V cache; ssm_beside_full: an SSD mixer with a recurrent state "
-          "and softmax attention side by side in one layer); a dense "
-          "decoder is all full"),
+          "and softmax attention side by side in one layer; latent: "
+          "latent attention over one compressed cache row a token); a "
+          "dense decoder is all full"),
     _spec(STATE_SLOTS_USED, "gauge",
           "Rows of the recurrent state pool held by live sequences "
           "(committed and not yet retired); 0 without recurrent layers"),

@@ -578,6 +578,67 @@ def _load_falcon_h1_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params"
                          if dense_logits_wanted(ld.fast_numerics) else None)))
 
 
+def _load_axk1_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
+    """A decoder of latent attention layers with an expert share
+    (models/axk1.py) from the tensors ``mfile._walk_axk1_layer`` names.
+    ``W_dkv``'s plane is padded with zero columns to ``cfg.latent_row``
+    (whole lane tiles for the fused kernels); ``W_ukv`` is contracted per
+    head on its plane's output side in the absorbed form, so it is held
+    per head in the compute dtype (``wuk``, ``wuv [L, H, ., kv_lora]``),
+    dequantized once here."""
+    from ..models.axk1 import AxK1Layers
+    from ..models.llama import Params
+
+    h = ld.h
+    every = list(range(h.n_layers))
+    dense_ids, moe_ids = every[:h.n_dense_layers], every[h.n_dense_layers:]
+    mm = lambda ids, name, o, i, **kw: ld.matmul(
+        name, o, i, stacked=True, out_axis=None, in_axis=None, layers=ids,
+        **kw)
+    H, r, nope = h.n_heads, h.kv_lora_rank, h.qk_nope_head_dim
+    wdkv = mm(every, "block_mla_dkv", r + h.qk_rope_head_dim, h.dim)
+    pad = cfg.latent_row - cfg.latent_dim
+    wdkv = jax.tree.map(
+        lambda a: jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, pad),)), wdkv)
+    wukv = mm(every, "block_mla_ukv", H * (nope + h.v_head_dim), r,
+              force_dense=jnp.dtype(cfg.compute_dtype)).reshape(
+        h.n_layers, H, nope + h.v_head_dim, r)
+    wide, sh = h.dense_hidden_dim, h.shared_expert_dim
+    experts = lambda name, o, i: ld.expert_stack(name, o, i, None, None,
+                                                 layers=moe_ids)
+    layers = AxK1Layers(
+        wdq=mm(every, "block_mla_dq", h.q_lora_rank, h.dim),
+        norm_qa=ld.stacked_f32("block_mla_norm_q", h.q_lora_rank),
+        wuq=mm(every, "block_mla_uq", H * h.head_dim, h.q_lora_rank),
+        wdkv=wdkv,
+        norm_kva=ld.stacked_f32("block_mla_norm_kv", r),
+        wuk=wukv[:, :, :nope], wuv=wukv[:, :, nope:],
+        wo=mm(every, "block_matmul_wo", h.dim, H * h.v_head_dim),
+        norm_att=ld.stacked_f32("block_norm_0", h.dim),
+        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
+        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim),
+        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide),
+        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim),
+        moe_gate=ld.stacked_f32("block_moe_gate", h.moe_router_width, h.dim,
+                                layers=moe_ids),
+        we1=experts("block_expert_w1", h.hidden_dim, h.dim),
+        we2=experts("block_expert_w2", h.dim, h.hidden_dim),
+        we3=experts("block_expert_w3", h.hidden_dim, h.dim),
+        ws1=mm(moe_ids, "block_shared_w1", sh, h.dim) if sh else None,
+        ws2=mm(moe_ids, "block_shared_w2", h.dim, sh) if sh else None,
+        ws3=mm(moe_ids, "block_shared_w3", sh, h.dim) if sh else None)
+    return Params(
+        embedding=ld.f32("embedding", h.vocab_size, h.dim,
+                         dtype=jnp.dtype(cfg.compute_dtype)),
+        layers=layers,
+        final_norm=ld.f32("final_norm", h.dim),
+        logits=ld.matmul(
+            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
+            out_axis="vocab", in_axis=None,
+            force_dense=(jnp.bfloat16
+                         if dense_logits_wanted(ld.fast_numerics) else None)))
+
+
 def _load_laguna_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
     """A decoder of window and full attention layers with an expert share
     (models/laguna.py) from the tensors ``mfile._walk_laguna_layer`` names:
@@ -658,13 +719,14 @@ def load_params(mf: ModelFile, cfg: "ModelConfig", weight_mode: str = "auto",
         return _load_hybrid_params(ld, cfg)
     if h.arch_type == ArchType.FALCON_H1:
         return _load_falcon_h1_params(ld, cfg)
-    if h.arch_type == ArchType.LAGUNA:
+    if h.arch_type in (ArchType.LAGUNA, ArchType.AXK1):
         if not ld.quantized:
             raise ValueError(
-                "a LAGUNA file's matmul planes must be Q40 or Q80: the routed "
-                "decode kernel (ops/expert_gemv.py) and its XLA form read "
-                "quantized expert stacks")
-        return _load_laguna_params(ld, cfg)
+                f"a {h.arch_type.name} file's matmul planes must be Q40 or "
+                f"Q80: the routed decode kernel (ops/expert_gemv.py) and its "
+                f"XLA form read quantized expert stacks")
+        return (_load_laguna_params if h.arch_type == ArchType.LAGUNA
+                else _load_axk1_params)(ld, cfg)
 
     # Under offload only the per-layer stacks go host-side: they are the
     # O(model) bytes and stream through the scan; embedding / final norm /

@@ -34,7 +34,12 @@ SELFTEST = os.path.join(BENCH, "selftest")
 NEW_CELLS = ("olmo-hybrid-7b.long-prompt", "mistral-7b-v0.3.single-stream")
 PR34_CELLS = ("laguna-s-2.1.mixed-queue", "mistral-7b-v0.3.mixed-queue")     # the same three cases, for the same reason
 PR36_CELL = "falcon-h1-34b.chat"     # one cell, so two of the three cases
+PR42_CELL = "a.x-k1.agent-sessions"  # one cell again
 KNOWN_RED = {
+    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR42_CELL}]":
+        "selftest/counts_frozen.json has no rows for the cell PR 42 added",
+    f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{PR42_CELL}]":
+        "a.x-k1 names its modules: the test asserts that no configuration of BENCHMARK.json does",
     f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR36_CELL}]":
         "selftest/counts_frozen.json has no rows for the cell PR 36 added",
     f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{PR36_CELL}]":
@@ -157,6 +162,59 @@ def test_the_side_by_side_cell_gets_the_modules_it_names():
     assert {"dropstate", "nodecay", "dropssm", "bf16state", "shift"} <= set(mods["reference"].CONTROLS)
     assert mods["counts"].kernel_counts(conf["model"], "ssd_step", rows=16)["calls_per_program"] == 12
     assert conf["reduced"] == ["num_hidden_layers", "max_position_embeddings"] and conf["vocab_size"] == 261120
+
+
+# -- and PR 42's cell, from a file of PR 42's own -----------------------------------
+
+with open(os.path.join(BENCH, "a_x_k1", "selftest", "counts_frozen.json"), encoding="utf-8") as _f:
+    PR42_FROZEN = json.load(_f)
+
+
+def test_pr42s_cell_counts_through_the_seam_are_what_pr42_froze():
+    _cell, conf, _traffic, mods = _seam._resolve(PR42_CELL)
+    rows = [r for r in PR42_FROZEN["rows"] if r["cell"] == PR42_CELL]
+    assert len(rows) == 24
+    for r in rows:
+        assert getattr(mods["counts"], r["fn"])(conf["model"], **r["args"]) == r["value"], r
+
+
+def test_the_latent_cell_gets_the_modules_it_names_and_its_traffic_is_the_issues():
+    cell, conf, traffic_path, mods = _seam._resolve(PR42_CELL)
+    assert {k: os.path.relpath(m.__file__, BENCH) for k, m in mods.items()} == conf["modules"] == {
+        "reference": "a_x_k1/reference.py", "weights": "a_x_k1/weights.py", "counts": "a_x_k1/counts.py"}
+    assert {"nogroups", "bf16router", "nomscale", "norope", "nocnorm", "noshared", "latent8", "dropblock"} \
+        <= set(mods["reference"].CONTROLS)
+    counts = mods["counts"]
+    assert counts.kernel_counts(conf["model"], "mla_paged_step", rows=16)["calls_per_program"] == 9
+    assert counts.kernel_counts(conf["model"], "expert_gemv", rows=16)["layers"] == 8
+    assert conf["reduced"] == ["n_routed_experts", "vocab_size", "num_hidden_layers", "max_position_embeddings"]
+    assert (cell["chips"], cell["traffic"], len(cell["why"]) <= 200) == (1, "agent-sessions-a.x-k1", True)
+    with open(traffic_path, encoding="utf-8") as f:
+        mix = json.load(f)
+    assert (mix["loop"], mix["clients"], mix["sessions"], mix["shared_prefix"], mix["sizes_seed"]) == (
+        "closed", 16, {"turns": [4, 4], "think_s": 0.5}, {"share": 1.0, "tokens": 4096}, 808)
+    assert mix["engine"] == {"slots": 16, "max_seq_len": 17408} and mix["sampling"]["temperature"] == 0.0
+    assert [(c["prompt_tokens"], c["output_tokens"]) for c in mix["mix"]] == [
+        ({"dist": "uniform", "low": 1024, "high": 3072}, {"dist": "uniform", "low": 64, "high": 192})]
+    import traffic
+    plan = traffic.plan(mix, seed=2 ** 31 + 5, seconds=45.0, vocab_size=conf["model"]["vocab_size"])
+    assert plan.max_context == 4096 + 4 * (3072 + 192) + 192 == 17344 <= mix["engine"]["max_seq_len"]
+    first = [r for r in plan.requests if r.session < 16]
+    assert all(len(r.new_tokens) >= 4096 + 1024 for r in first if r.turn == 0) and {r.turn for r in first} == {0, 1, 2, 3}
+    assert all(r.new_tokens[:4096] == first[0].new_tokens[:4096] for r in first if r.turn == 0)     # ONE system prompt
+    assert max(t for r in plan.requests for t in r.new_tokens[:64]) < 20480
+    # every new per-layer metric of the cell names a reader that is there
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json"), encoding="utf-8") as f:
+        manifest = json.load(f)
+    mine = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [PR42_CELL]]
+    assert mine == ["mla_step_share", "mla_step_hbm_share", "mla_step_mxu_share", "mla_chunk_share", "mla_chunk_mxu_share",
+                    "prefix_hit_share"]
+    for name in mine:
+        with open(os.path.join(BENCH, "layer_metrics", name + ".json"), encoding="utf-8") as f:
+            assert os.path.isfile(os.path.join(BENCH, "readers", json.load(f)["reader"] + ".py")), name
+    listed = {m["name"] for m in manifest["per_layer"] if PR42_CELL in m.get("workloads", ())}
+    assert {"expert_gemv_share", "expert_gemv_hbm_share", "moe_held_pair_share", "moe_expert_load_max_over_mean"} <= listed
+    assert not {"ssd_step_share", "gated_delta_step_share", "window_blocks_returned_share"} & listed
 
 
 # -- the form the driver holds BENCHMARK.json to before any run --------------------

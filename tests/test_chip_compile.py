@@ -132,9 +132,18 @@ FALCON_H1_34B = [(5120, 2560), (5120, 512), (2560, 5120), (5120, 9216),
                  (4096, 5120), (5120, 21504), (21504, 5120)]
 
 
+# skt/A.X-K1: W_dq, W_uq, W_dkv (576 wide, padded to 640), W_o, an expert's
+# gate / up and down (in the chunk form read out of the FLATTENED [layers x
+# held] stack, models/share._experts_chunk_vmem), layer 0's dense feed-forward
+# (K = 18432 at 256 resident rows is the widest contraction the chunk regime
+# holds)
+A_X_K1 = [(7168, 1536), (1536, 12288), (7168, 640), (8192, 7168),
+          (7168, 2048), (2048, 7168), (7168, 18432), (18432, 7168)]
+
+
 @pytest.mark.parametrize("rows", [4, 16])
 @pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B + OLMO_HYBRID_7B
-                         + FALCON_H1_34B)
+                         + FALCON_H1_34B + A_X_K1)
 def test_decode_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
     """The fused dequant-GEMV as the paged step calls it in fast mode: bf16
     rows, bf16 scales as a fast-mode load stores them, the LAYER STACK and
@@ -160,7 +169,7 @@ def test_decode_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
 
 @pytest.mark.parametrize("rows", [64, 256])
 @pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B + OLMO_HYBRID_7B
-                         + FALCON_H1_34B)
+                         + FALCON_H1_34B + A_X_K1)
 def test_chunk_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
     """The same kernel as a prefill chunk's ``forward`` calls it (PR 35):
     a 64- or 256-row bucket of bf16 rows, the layer stack and a traced
@@ -429,6 +438,60 @@ def test_expert_gemv_compiles_for_v5e(one_chip, k, n):
         _shape(one_chip, (160, k), jnp.bfloat16), stack, _shape(one_chip, (), jnp.int32),
         _shape(one_chip, (160,), jnp.int32), _shape(one_chip, (), jnp.int32))
     assert any("expert_gemv" in name for name in kernels), kernels
+
+
+@pytest.mark.parametrize("k,n,tn", [(7168, 2048, 512), (2048, 7168, 1792)])
+def test_expert_gemv_stripes_a_wide_plane_for_v5e(one_chip, k, n, tn):
+    """A.X-K1's expert (7168 x 2048, gate / up and down), 9 layers of 12 held
+    experts, 128 pairs (16 rows x 8): a whole plane, twice, with its
+    dequantized copy is 58.7 MB, so the kernel walks stripes of ``tn`` output
+    columns, a stripe a double-buffer half."""
+    from dllama_tpu.ops import expert_gemv as eg
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    assert eg.stripe(128, k, n, True) == tn
+    stack = QuantizedWeight(scales=_shape(one_chip, (9, 12, k // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (9, 12, k, n), jnp.int8))
+    kernels = _compiled_kernels(
+        lambda x, st, layer, experts, n_pairs: eg.expert_gemv(x, st, layer, experts, n_pairs, fast=True),
+        _shape(one_chip, (128, k), jnp.bfloat16), stack, _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (128,), jnp.int32), _shape(one_chip, (), jnp.int32))
+    assert any("expert_gemv" in name for name in kernels), kernels
+
+
+def test_mla_paged_step_compiles_for_v5e(one_chip):
+    """A.X-K1's latent walk: 16 rows x 64 heads over rows of 576 values in 640
+    lanes, 10 layers of 17,409 blocks of 16 (3.57 GB in bfloat16), a table of
+    1088 entries: one 20 KB copy a block, 32 a fetch group."""
+    from dllama_tpu.ops import mla
+
+    assert mla.supports((16, 1, 64, 640), 640, 1088, 16, compiled=True)
+    kernels = _compiled_kernels(
+        lambda qa, pool, layer, tables, pos: mla.mla_paged_step(
+            qa, pool, layer, tables, pos, scale=0.1309, vdim=512),
+        _shape(one_chip, (16, 1, 64, 640), jnp.bfloat16),
+        _shape(one_chip, (10, 17409, 1, 16, 640), jnp.bfloat16),
+        _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (16, 1088), jnp.int32),
+        _shape(one_chip, (16,), jnp.int32))
+    assert any("mla_paged_step" in name for name in kernels), kernels
+
+
+@pytest.mark.parametrize("T", [64, 256])
+def test_mla_chunk_compiles_for_v5e(one_chip, T):
+    """A.X-K1's chunk form: ``T`` tokens x 64 heads of absorbed query rows in
+    tiles of 2048 against key blocks of 512 of a whole latent column (9 layers
+    of 17,408 rows of 640 lanes), the layer scalar-prefetched, the score tile
+    in VMEM."""
+    from dllama_tpu.ops import mla
+
+    assert mla._chunk_tiles(T * 64, 17408, True) == (2048, 512)
+    kernels = _compiled_kernels(
+        lambda qa, col, layer, start: mla.mla_chunk_kernel(qa, col, layer, start, scale=0.1309, vdim=512),
+        _shape(one_chip, (T, 64, 640), jnp.bfloat16),
+        _shape(one_chip, (9, 1, 1, 17408, 640), jnp.bfloat16),
+        _shape(one_chip, (), jnp.int32), _shape(one_chip, (), jnp.int32))
+    assert any("mla_chunk" in name for name in kernels), kernels
 
 
 @pytest.mark.parametrize("chips", [1, 4])

@@ -261,15 +261,15 @@ def test_a_bfloat16_router_in_the_program_fails(bench, engine, monkeypatch, roun
     2): the PROGRAM with its router's rows rounded to bfloat16, or its product
     taken as the MXU's default one-pass one, is no longer the reference's
     function, by the logits' tolerance and by the benchmark's own comparison."""
-    from dllama_tpu.models import laguna, llama
+    from dllama_tpu.models import laguna, llama, share
 
     round16 = lambda a: jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
-    honest = laguna.route
+    honest = share.route
 
     def route(cfg, h, gate):
         return honest(cfg, round16(h.astype(jnp.float32)) if rounded == "product" else h, round16(gate))
 
-    monkeypatch.setattr(laguna, "route", route)
+    monkeypatch.setattr(share, "route", route)
     tokens = _tokens(96, seed=3)
     col = laguna.LagunaColumn.zeros(engine.cfg, jnp.float32)
     logits, _ = jax.jit(lambda params, ids, col: llama.forward(params, engine.cfg, ids, jnp.int32(0), col))(
@@ -310,6 +310,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(bench, uncut):
     experts it holds, its attention half over sliced planes); the whole is the
     reference's."""
     from dllama_tpu.models import laguna
+    from dllama_tpu.models import share as share_mod
     from dllama_tpu.ops.attention import attention
     from dllama_tpu.ops.linear import QuantizedWeight
 
@@ -360,16 +361,16 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(bench, uncut):
                 share = lp._replace(ws1=None, ws2=None, ws3=None,
                                     **{n: jax.tree.map(lambda a: a[:, 2 * s:2 * s + 2], getattr(lp, n))
                                        for n in ("we1", "we2", "we3")})
-                y, stats = laguna.routed_ffn(c, h2, share, jnp.int32(m), live)
+                y, stats = share_mod.routed_ffn(c, h2, share, jnp.int32(m), live)
                 routed.append(y)
                 held += int(stats[0])
                 assert int(stats[0]) + int(stats[1]) == T * 4          # every pair is held or absent, once
-            shared = laguna._swiglu(cfg, h2, at(lp.ws1, m), at(lp.ws2, m), at(lp.ws3, m))     # counted once
+            shared = share_mod.swiglu(cfg, h2, at(lp.ws1, m), at(lp.ws2, m), at(lp.ws3, m))     # counted once
             assert held == T * 4                                        # the eight shares hold every pair between them
             assert float(jnp.abs(sum(routed) + shared - want[None]).max()) < SHARE_TOL, kind
         # the leading dense layer is every chip's alike: the program's once is the reference's
         h0 = dense_reference._rms_norm(x, lp.norm_ffn[0], cfg.norm_epsilon)
-        got, stats = laguna._ffn_half(cfg, x, lp, jnp.int32(0), live, may_be_dense=True)
+        got, stats = share_mod.ffn_half(cfg, x, lp, jnp.int32(0), live, may_be_dense=True)
         want0 = dense_reference.swiglu(h0[0], *(at(tree["dense"], 0)[n] for n in ("w1", "w2", "w3")))
         assert float(jnp.abs(got - x - want0[None]).max()) < SHARE_TOL and int(stats.sum()) == 0
 

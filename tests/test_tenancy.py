@@ -516,17 +516,16 @@ def test_slot_cap_defers_without_blocking_others(engine):
     assert snap["free"]["admissions"] == 2
 
 
-def _queue_p95(tenant):
-    st = tenancy.registry().snapshot()["tenants"][tenant]
-    return st["queue_wait_ms"]["p95"]
-
-
 def test_contention_flooder_cannot_starve_light(engine, tmp_path):
     """THE acceptance scenario: a flooding tenant dumping a burst of
     requests cannot starve a light interactive tenant. Weighted
-    round-robin keeps the light tenant's queue-wait p95 within 2x its
-    solo baseline (plus a CPU-tier tick floor), Jain's index over the
-    wave's decode tokens stays >= 0.8, every defer/shed decision in the
+    round-robin admits a waiting light request ahead of the flooder's
+    queue: while it waits at most a slot's worth of flooder requests are
+    admitted, where FIFO order would admit every one queued before it
+    (held to the ORDER of admissions, which six busy test workers on one
+    machine cannot move, and not to two wall clocks: the waits of a solo
+    run against the wave's read 2x apart on a loaded host), Jain's index
+    over the wave's decode tokens stays >= 0.8, every defer/shed decision in the
     flight ring is machine-attributed, the per-tenant totals reconcile
     bit-exactly with the global counter, and the usage ledger kept
     writing monotonic lines throughout."""
@@ -535,7 +534,8 @@ def test_contention_flooder_cannot_starve_light(engine, tmp_path):
     ids_f = _enc(engine, "hello world")
     ids_l = _enc(engine, "hello")
 
-    # solo baseline: the light tenant's staggered trickle, alone
+    # the light tenant's staggered trickle, alone: it warms the programs
+    # the wave runs, so that nothing compiles inside the wave
     solo = BatchScheduler(engine, n_slots=2, tenant_limits=limits)
     try:
         rs = []
@@ -545,7 +545,6 @@ def test_contention_flooder_cannot_starve_light(engine, tmp_path):
             time.sleep(0.03)
         for r in rs:
             assert r.done.wait(timeout=300) and r.error is None
-        solo_p95 = _queue_p95("light")
     finally:
         solo.close()
 
@@ -556,7 +555,6 @@ def test_contention_flooder_cannot_starve_light(engine, tmp_path):
     base_batch = g.counter(tm.BATCH_TOKENS).total()
     sched = BatchScheduler(engine, n_slots=2, tenant_limits=limits)
     try:
-        t_wave = time.monotonic()
         flood = [sched.submit(ids_f, 6, stop_on_eos=False, tenant="flood")
                  for _ in range(12)]
         lights = []
@@ -567,28 +565,28 @@ def test_contention_flooder_cannot_starve_light(engine, tmp_path):
         for r in flood + lights:
             assert r.done.wait(timeout=300)
             assert r.error is None
-        # what ONE request took on this machine under this load: 18
-        # requests over 2 slots are 9 rounds. The bounds below are in
-        # this unit, so six test workers on one machine slow the light
-        # tenant's waits and their limit alike
-        round_ms = (time.monotonic() - t_wave) * 1000.0 / 9
     finally:
         sched.close()
 
     snap = tenancy.registry().snapshot()["tenants"]
-    # no starvation: the light tenant's waits stay near its solo run
-    # (the floor absorbs CPU-tier tick jitter on the tiny model — a
-    # FIFO queue behind 12 flooder requests would be far past it)
-    light_p95 = snap["light"]["queue_wait_ms"]["p95"]
-    # under weighted round-robin a light request waits for a slot to
-    # free, a round or two; in FIFO order behind 12 flooder requests the
-    # last ones would wait six rounds and more
-    assert light_p95 <= 2.0 * max(solo_p95, 250.0, 1.5 * round_ms), \
-        f"light p95 {light_p95:.0f}ms vs solo {solo_p95:.0f}ms, a round " \
-        f"{round_ms:.0f}ms"
-    # and in the wave's own terms, whatever the machine's speed: FIFO
-    # would put the light tenant's tail at or past the flooder's
-    assert light_p95 <= snap["flood"]["queue_wait_ms"]["p95"] * 0.75 + 1.0
+    # no starvation, in the scheduler's own order (``Request.t_submit`` /
+    # ``t_admit`` on one monotonic clock; only their ORDER is read): the
+    # flooder requests admitted while a light request waited. Under
+    # weighted round-robin (4 : 1 over 2 slots) a light request waits for
+    # a slot to free and at most the flooder's turn of that cycle goes
+    # first; in FIFO order the first light request, submitted behind 12
+    # flooder requests of which 2 hold the slots, would see all 10 queued
+    # ones admitted before it
+    jumped = [sum(1 for f in flood if lt.t_submit < f.t_admit < lt.t_admit)
+              for lt in lights]
+    assert max(jumped) <= 3, jumped
+    # and the light tenant as a whole is not served last: fewer flooder
+    # admissions go ahead of its requests than of the flooder's own
+    # queued ones (a flooder request waits behind its predecessors)
+    behind_own = [sum(1 for o in flood if f.t_submit < o.t_admit < f.t_admit)
+                  for f in flood]
+    assert sum(jumped) / len(jumped) <= max(behind_own), (jumped, behind_own)
+    assert snap["light"]["queue_wait_ms"]["p95"] > 0.0
     # the wave was served fairly: 72 vs 36 demanded tokens -> 0.9
     jain = tenancy.jain_index([snap["flood"]["decode_tokens"],
                                snap["light"]["decode_tokens"]])
