@@ -5,14 +5,22 @@ halves.
 **The share is data.** The router always scores ``moe_router_width`` experts
 and takes ``n_active_experts``; the planes hold ``n_experts`` of them, from
 ``moe_first_expert``. A (row, expert) pair whose expert is not held, or whose
-row is dead or padding, is not computed: the decode form compacts the held
-pairs to the front and :func:`~dllama_tpu.ops.expert_gemv.expert_gemv` loops
-over those alone; the chunk form runs every held expert that some row chose
-over every row through the fused Q40 chunk kernel and weights the rows that
-did not choose it 0 (:func:`_experts_chunk`). What the absent
-experts would have added is left out, and that partial sum goes on to the
-next layer: on one chip the layer runs without its exchange. With every
-expert held the same code is the whole layer.
+row is dead or padding, is not computed: both forms sort the pairs by held
+expert, the absent ones last. The decode form (:func:`_sorted_pairs`)
+compacts the held ones to the front
+and :func:`~dllama_tpu.ops.expert_gemv.expert_gemv` loops over those alone,
+a plane a PAIR; the chunk form (:func:`_experts_chunk`) runs each of the
+three projections as one grouped kernel,
+:func:`~dllama_tpu.ops.expert_chunk.expert_chunk`: a plane fetched and
+dequantized once a RUN of pairs that share its expert and multiplied with
+that run's rows alone, in tiles, an expert nobody chose not touched, the
+pairs' weighted rows added back per token. Off a TPU and under a mesh plan
+the chunk form is the every-row one through ``linear``
+(:func:`_experts_chunk_xla`: every chosen held expert over EVERY row, the
+rows that did not choose it weighted 0; the grouped kernel's oracle). What
+the absent experts would have added is left out, and that partial sum goes
+on to the next layer: on one chip the layer runs without its exchange. With
+every expert held the same code is the whole layer.
 
 **The router** (:func:`route`) takes its score function (``cfg.moe_score``:
 a softmax or a sigmoid over the whole width, float32) and its group limit
@@ -21,8 +29,10 @@ a softmax or a sigmoid over the whole width, float32) and its group limit
 topk_group`` largest) from the configuration.
 
 **Counters**: ``stats`` = (pairs computed here, pairs that fell on absent
-experts, tokens each held expert saw), summed over the layers, accumulated
-on the device and given back with the pools.
+experts, rows the chunk form fed its planes (with the grouped kernel the
+pairs rounded up to whole tiles a run: over the pairs, what the tiling
+pads), tokens each held expert saw), summed over the layers, accumulated on
+the device and given back with the pools.
 
 A layer stack that uses these functions names its leaves ``norm_ffn``, ``w1
 w2 w3`` (the leading dense layers'), ``moe_gate``, ``we1 we2 we3``, ``ws1 ws2
@@ -34,10 +44,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..ops import expert_chunk as ec
 from ..ops import expert_gemv as eg
 from ..ops.linear import (LayerSlice, QuantizedWeight, Weight, _fast_mode,
                           linear)
 from ..ops.norms import rms_norm
+from ..runtime.introspection import note_q40_path
 from .config import ModelConfig
 from .llama import _hidden_act
 
@@ -54,16 +66,16 @@ def _plane(w: Weight, l) -> Weight:
 
 
 def zero_stats(cfg: ModelConfig) -> jax.Array:
-    """One dispatch's routing counters: held pairs, absent pairs, tokens a
-    held expert."""
-    return jnp.zeros((2 + cfg.n_experts,), jnp.int32)
+    """One dispatch's routing counters: held pairs, absent pairs, rows the
+    chunk form fed the planes, tokens a held expert."""
+    return jnp.zeros((3 + cfg.n_experts,), jnp.int32)
 
 
 def zero_totals(cfg: ModelConfig) -> jax.Array:
     """The generator's running totals beside its pools: row 0 what the
     decode steps added, row 1 what the prefill chunks did (kept apart so
     that a step's own pairs can be read off after it)."""
-    return jnp.zeros((2, 2 + cfg.n_experts), jnp.int32)
+    return jnp.zeros((2, 3 + cfg.n_experts), jnp.int32)
 
 
 def swiglu(cfg: ModelConfig, h: jax.Array, w1, w2, w3) -> jax.Array:
@@ -105,7 +117,8 @@ def routed_pairs(cfg: ModelConfig, idx: jax.Array, live: jax.Array):
     """Of the (row, expert) pairs ``idx [N, k]``, flattened row-major:
     ``local [N k]`` the expert's index among those held (``n_experts`` where
     it is absent or the row is not ``live [N]``), and ``stats`` (held
-    pairs, absent pairs of live rows, tokens a held expert)."""
+    pairs, absent pairs of live rows, 0 for the rows fed, which the chunk
+    form fills in, tokens a held expert)."""
     E = cfg.n_experts
     local = idx - cfg.moe_first_expert
     here = (local >= 0) & (local < E)
@@ -113,7 +126,7 @@ def routed_pairs(cfg: ModelConfig, idx: jax.Array, live: jax.Array):
     local = jnp.where(held, local.reshape(-1), E)
     absent = jnp.sum(~here & live[:, None])
     tokens = jnp.bincount(local, length=E + 1)[:E]
-    stats = jnp.concatenate([jnp.stack([jnp.sum(held), absent]),
+    stats = jnp.concatenate([jnp.stack([jnp.sum(held), absent, 0]),
                              tokens]).astype(jnp.int32)
     return local, stats
 
@@ -152,13 +165,13 @@ def _experts_step(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
     return jnp.zeros(x.shape, jnp.float32).at[rows].add(y)
 
 
-def _experts_chunk(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
-    """The chunk form: every held expert that some row chose, over EVERY
-    row of the chunk, through the fused Q40 chunk kernel (its plane
-    dequantized in VMEM, read where it lies in the ``[NM, held, in, out]``
-    stack: entry ``m held + e``), the rows that did not choose it weighted
-    0. ``held / k`` times the pairs' FLOPs, which the MXU has to spare, and
-    no dequantized plane in HBM."""
+def _experts_chunk_xla(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
+    """The chunk form off a TPU and under a mesh plan, and the grouped
+    kernel's oracle: every held expert that some row chose, over EVERY row
+    of the chunk, through ``linear`` (its plane read where it lies in the
+    ``[NM, held, in, out]`` stack: entry ``m held + e``), the rows that did
+    not choose it weighted 0: ``held / k`` times the pairs' FLOPs. Also the
+    rows it fed the planes: the chunk's, once a chosen expert."""
     E = cfg.n_experts
     N, k = weights.shape
     flat = lambda we: QuantizedWeight(*(a.reshape((-1,) + a.shape[2:])
@@ -180,7 +193,67 @@ def _experts_chunk(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
 
         return jax.lax.cond(chosen[e] > 0, some, lambda y: y, y)
 
-    return jax.lax.fori_loop(0, E, expert, jnp.zeros(x.shape, jnp.float32))
+    y = jax.lax.fori_loop(0, E, expert, jnp.zeros(x.shape, jnp.float32))
+    return y, N * jnp.sum(chosen > 0)
+
+
+def _runs(cfg: ModelConfig, local: jax.Array, tm: int):
+    """The RUNS of the sorted held pairs ``local [N k]``, what
+    :func:`~dllama_tpu.ops.expert_chunk.expert_chunk` loops over: ``(n_runs,
+    expert, tile0, pair0, length)``, the chosen held experts ascending, each
+    with the first of its whole tiles of ``tm`` rows in the fed layout, the
+    first of its pairs among the sorted and how many it has; and the rows
+    fed, pairs rounded up to whole tiles a run."""
+    E = cfg.n_experts
+    counts = jnp.bincount(local, length=E + 1)[:E].astype(jnp.int32)
+    tiles = (counts + tm - 1) // tm
+    order = jnp.argsort(counts == 0, stable=True).astype(jnp.int32)
+    tile_end, pair_end = jnp.cumsum(tiles), jnp.cumsum(counts)
+    runs = (jnp.sum(counts > 0), order, (tile_end - tiles)[order],
+            (pair_end - counts)[order], counts[order])
+    return runs, tile_end[-1] * tm
+
+
+def _experts_chunk(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
+    """The chunk form, and the rows it fed the planes: the held pairs sorted
+    by expert, each of the three projections ONE grouped kernel over the
+    held stack read where it lies (``expert_chunk``: a plane fetched and
+    dequantized once a RUN of pairs that share it, multiplied with that
+    run's rows in tiles, an expert nobody chose not touched), the pairs'
+    weighted results added to their token's row in the sorted order, a
+    token's experts ascending; float32 between the projections and the
+    gate-times-up product rounded once to the activation dtype, as
+    :func:`_experts_step` has it. Between the projections the pairs live in
+    the FED layout (a run owns whole tiles), at its static bound
+    ``fed_rows(N min(k, held), held)``: the gate-times-up product there and
+    the copies of those arrays in and out of the kernels' VMEM are the work
+    that the bound, not the pairs, sizes. Off a TPU, under a plan, or where
+    the kernel's VMEM predicate refuses: :func:`_experts_chunk_xla`."""
+    N, k = weights.shape
+    E = cfg.n_experts
+    fast = _fast_mode(x) or lp.we1.scales.dtype == jnp.bfloat16
+    F = ec.fed_rows(N * min(k, E), E)
+    choice = lambda stack, scatter: ec.kernel_choice(
+        N, F, stack, fast, scatter, x.dtype.itemsize)
+    kw = choice(lp.we1, False)
+    if kw is None or choice(lp.we2, True) is None:
+        return _experts_chunk_xla(cfg, x, local, weights, m, lp)
+    # the sort of _sorted_pairs alone: the kernel reads a pair's weight where
+    # the router left it, so nothing is gathered into sorted copies (two
+    # gathers of N k values cost 34 us a layer on a v5e, a sort 6)
+    order = jnp.argsort(local, stable=True)
+    rows = order // k
+    runs, fed = _runs(cfg, local, ec.TILE_ROWS)
+
+    def grouped(a, stack, *pair_weights, rows_out):
+        note_q40_path("grouped")
+        return ec.expert_chunk(a, stack, m, runs, rows, *pair_weights,
+                               rows_out=rows_out, **kw)
+
+    a = (_hidden_act(cfg, grouped(x, lp.we1, rows_out=F))
+         * grouped(x, lp.we3, rows_out=F))
+    return grouped(a.astype(x.dtype), lp.we2, (order, weights),
+                   rows_out=N), fed
 
 
 def routed_ffn(cfg: ModelConfig, h: jax.Array, lp, m,
@@ -195,8 +268,11 @@ def routed_ffn(cfg: ModelConfig, h: jax.Array, lp, m,
     at = lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False)
     weights, idx = route(cfg, x, at(lp.moe_gate))
     local, stats = routed_pairs(cfg, idx, live)
-    form = _experts_step if B * T <= FUSED_MAX_M else _experts_chunk
-    y = form(cfg, x, local, weights, m, lp)
+    if B * T <= FUSED_MAX_M:
+        y = _experts_step(cfg, x, local, weights, m, lp)
+    else:
+        y, fed = _experts_chunk(cfg, x, local, weights, m, lp)
+        stats = stats.at[2].set(fed.astype(jnp.int32))
     if lp.ws1 is not None:
         y = y + swiglu(cfg, h, _plane(lp.ws1, m), _plane(lp.ws2, m),
                         _plane(lp.ws3, m)).reshape(B * T, D)

@@ -97,6 +97,29 @@ BN_CANDIDATES = (512, 256, 128)
 BK_CANDIDATES = (512, 256, 128)
 
 
+def dequant_blocks(codes_ref, s32_ref, wd_ref, groups: int) -> None:
+    """``wd_ref [K, n] = codes_ref [K, n] * s32_ref [K/32, n]``, one Q40
+    block of 32 rows at a time: the dequant of :func:`_decode_kernel` (its
+    docstring has the reasons), shared with the kernels that land a plane
+    themselves (``ops.expert_chunk``). The product is taken in float32
+    over scales already rounded to ``wd_ref``'s dtype, and rounds once."""
+    wd_dt = wd_ref.dtype
+
+    def dequant(c, carry):
+        # `groups` Q40 blocks a trip, unrolled: independent loads, converts
+        # and stores for the scheduler to overlap
+        for j in range(groups):
+            g = c * groups + j
+            k0 = pl.multiple_of(g * Q40_BLOCK_SIZE, Q40_BLOCK_SIZE)
+            rows = pl.ds(k0, Q40_BLOCK_SIZE)
+            wd_ref[rows, :] = (codes_ref[rows, :].astype(jnp.float32)
+                               * s32_ref[pl.ds(g, 1), :]).astype(wd_dt)
+        return carry
+
+    n_blocks = codes_ref.shape[0] // Q40_BLOCK_SIZE
+    jax.lax.fori_loop(0, n_blocks // groups, dequant, 0)
+
+
 def _decode_kernel(x_ref, codes_ref, scales_ref, out_ref, wd_ref, s32_ref,
                    *, groups: int, fast: bool):
     """One n-column stripe of the DECODE-shaped fused dequant-GEMV.
@@ -144,20 +167,7 @@ def _decode_kernel(x_ref, codes_ref, scales_ref, out_ref, wd_ref, s32_ref,
     # the stack entry cannot afford a cast of all L layers per call); f32
     # rows are what a dynamic sublane index can address
     s32_ref[...] = scales_ref[...].astype(wd_dt).astype(jnp.float32)
-
-    def dequant(c, carry):
-        # `groups` Q40 blocks a trip, unrolled: independent loads, converts
-        # and stores for the scheduler to overlap
-        for j in range(groups):
-            g = c * groups + j
-            k0 = pl.multiple_of(g * Q40_BLOCK_SIZE, Q40_BLOCK_SIZE)
-            rows = pl.ds(k0, Q40_BLOCK_SIZE)
-            wd_ref[rows, :] = (codes_ref[rows, :].astype(jnp.float32)
-                               * s32_ref[pl.ds(g, 1), :]).astype(wd_dt)
-        return carry
-
-    n_blocks = codes_ref.shape[0] // Q40_BLOCK_SIZE
-    jax.lax.fori_loop(0, n_blocks // groups, dequant, 0)
+    dequant_blocks(codes_ref, s32_ref, wd_ref, groups)
     out_ref[...] = jax.lax.dot_general(
         x_ref[...], wd_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),

@@ -423,12 +423,15 @@ def _event_listener(name: str, duration_s: float, **_kw) -> None:
         win["n_trace"] += 1
 
 
-# "chunk" first: the step's "N fused / 0 tiled / 0 xla" reads as it always did
-Q40_PATHS = ("chunk", "fused", "tiled", "xla")
+# "chunk" first and "grouped" (the routed chunk kernel, ops.expert_chunk:
+# one count a projection) last: the step's "N fused / 0 tiled / 0 xla" and
+# a dense chunk's "N chunk / 0 fused / 0 tiled / 0 xla" read as they always did
+Q40_PATHS = ("chunk", "fused", "tiled", "xla", "grouped")
 
 
 def note_q40_path(path: str) -> None:
-    """``ops.linear`` calls this while a Q40 matmul is traced, with the path
+    """``ops.linear`` (and ``models.share`` for the routed chunk kernel)
+    calls this while a Q40 matmul is traced, with the path
     it gave it (one of :data:`Q40_PATHS`). The count goes to the program
     whose :class:`ObservedJit` is tracing on this thread; outside one it is
     dropped. A layer scan's body is traced once, so its matmuls count once

@@ -1550,7 +1550,7 @@ class PagedGenerator(_GeneratorCore):
             from ..models.share import zero_totals
 
             self.moe_stats = zero_totals(self.cfg)
-            self._moe_seen = np.zeros((2, 2 + self.cfg.n_experts), np.int64)
+            self._moe_seen = np.zeros(self.moe_stats.shape, np.int64)
         if self.window:
             self.wpool = BlockPool(n_wblocks, block_size)
             wshape = (self.cfg.n_window_layers, n_wblocks,
@@ -1788,9 +1788,11 @@ class PagedGenerator(_GeneratorCore):
             max(0, n_wblocks - 1))
         self._m_moe_pairs = self._tm.counter(telemetry.MOE_PAIRS)
         self._m_moe_tokens = self._tm.counter(telemetry.MOE_EXPERT_TOKENS)
+        self._m_moe_fed = self._tm.counter(telemetry.MOE_CHUNK_ROWS_FED)
         if self.moe_stats is not None:
             for where in ("held", "absent"):
                 self._m_moe_pairs.inc(0, where=where)
+            self._m_moe_fed.inc(0)
         self._update_block_gauges()
         engine._stamp_startup("generator", t_phase)
 
@@ -2703,15 +2705,19 @@ class PagedGenerator(_GeneratorCore):
 
     def _note_moe(self, totals: np.ndarray, wait) -> None:  # dlint: owner=loop-thread
         """The device's running routing counters (row 0 the steps', row 1
-        the committed chunks') into the registry: what was added since the
-        last step's fetch (int32 on the device: the difference is taken
-        modulo 2**32). The step's ``step_wait`` span gets the held pairs
+        the committed chunks'; held pairs, absent pairs, rows the chunk form
+        fed its planes, tokens a held expert) into the registry: what was
+        added since the last step's fetch (int32 on the device: the
+        difference is taken modulo 2**32). The step's ``step_wait`` span
+        gets the held pairs
         THIS step computed (``moe_pairs``: the routed kernel's roofline
         share reads them) and, while a profiler listens, the counters'
         running totals, so that a reader of a traced slice takes what the
         slice added and not what the process has counted since it started
         (``moe_held`` / ``moe_absent``, ``moe_tokens`` a held expert joined
-        by ``/``, ``wblocks_allocated`` / ``wblocks_returned``)."""
+        by ``/``, ``moe_chunk_held`` / ``moe_chunk_fed`` the chunks' own
+        pairs and the rows they fed, ``wblocks_allocated`` /
+        ``wblocks_returned``)."""
         delta = (totals.astype(np.int64) - self._moe_seen) % (1 << 32)
         self._moe_seen = totals.astype(np.int64)
         both = delta.sum(axis=0)
@@ -2719,8 +2725,10 @@ class PagedGenerator(_GeneratorCore):
             self._m_moe_pairs.inc(int(both[0]), where="held")
         if both[1]:
             self._m_moe_pairs.inc(int(both[1]), where="absent")
-        for e in np.nonzero(both[2:])[0]:
-            self._m_moe_tokens.inc(int(both[2 + e]), expert=str(int(e)))
+        if both[2]:
+            self._m_moe_fed.inc(int(both[2]))
+        for e in np.nonzero(both[3:])[0]:
+            self._m_moe_tokens.inc(int(both[3 + e]), expert=str(int(e)))
         wait.set(moe_pairs=int(delta[0, 0]))
         if wait.traced:
             pairs, tokens = self._m_moe_pairs, self._m_moe_tokens
@@ -2729,6 +2737,8 @@ class PagedGenerator(_GeneratorCore):
                      moe_tokens="/".join(
                          str(int(tokens.total(expert=str(e))))
                          for e in range(self.cfg.n_experts)),
+                     moe_chunk_held=int(self._moe_seen[1, 0]),
+                     moe_chunk_fed=int(self._moe_seen[1, 2]),
                      wblocks_allocated=int(self._m_wblocks_alloc.total()),
                      wblocks_returned=int(self._m_wblocks_returned.total()))
 

@@ -202,6 +202,7 @@ KV_WINDOW_BLOCKS_TOTAL = "dllama_kv_window_blocks_total"
 KV_WINDOW_BLOCKS_ALLOCATED = "dllama_kv_window_blocks_allocated_total"
 KV_WINDOW_BLOCKS_RETURNED = "dllama_kv_window_blocks_returned_total"
 MOE_PAIRS = "dllama_moe_pairs_total"
+MOE_CHUNK_ROWS_FED = "dllama_moe_chunk_rows_fed_total"
 MOE_EXPERT_TOKENS = "dllama_moe_expert_tokens_total"
 MOE_EXPERTS_HELD = "dllama_moe_experts_held"
 MOE_EXPERTS_TOTAL = "dllama_moe_experts_total"
@@ -516,6 +517,12 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "where the expert lives: held (computed on this chip) or absent "
           "(another chip's share: nothing is computed for it here). "
           "Accumulated on the device, fetched with each step's tokens"),
+    _spec(MOE_CHUNK_ROWS_FED, "counter",
+          "Rows the prefill chunks fed to held experts' planes: with the "
+          "grouped kernel the chunks' held pairs rounded up to whole tiles "
+          "a run of pairs that share an expert (over the chunks' held "
+          "pairs: what the tiling pads), with the every-row form the "
+          "chunk's rows once a chosen expert"),
     _spec(MOE_EXPERT_TOKENS, "counter",
           "Tokens each HELD expert computed, summed over the routed "
           "layers, by the expert's index among those held"),

@@ -275,8 +275,30 @@ def test_whole_forward_logits(bench, engine, T):
     want = _reference_logits(bench, engine.params, tokens)
     assert float(np.abs(np.asarray(logits[0]) - want).max()) < LOGIT_TOL
     stats = np.asarray(col.stats)
-    assert stats[0] + stats[1] == T * 4 * 3 and stats[2:].sum() == stats[0]          # every pair counted once
+    assert stats[0] + stats[1] == T * 4 * 3 and stats[3:].sum() == stats[0]          # every pair counted once
     assert not np.asarray(col.c[..., 40:]).any() and np.asarray(col.c[:, 0, 0, :T, :40]).all(axis=-1).all()
+
+
+def test_whole_forward_logits_through_the_grouped_kernel(bench, engine, monkeypatch):
+    """The same chunk with the kernels forced (interpret mode off a TPU): the
+    routed layers' three projections each ONE ``expert_chunk`` call a traced
+    body, the column's counters the pairs and the rows fed (whole tiles a
+    run), the logits the reference's."""
+    from dllama_tpu.models import axk1, llama
+    from dllama_tpu.ops import expert_chunk as ec
+
+    cfg, T, calls = engine.cfg, 40, []
+    grouped = ec.expert_chunk
+    monkeypatch.setattr(ec, "expert_chunk", lambda *a, **kw: calls.append(kw["rows_out"]) or grouped(*a, **kw))
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "fused")
+    tokens = _tokens(T)
+    logits, col = jax.jit(lambda params, ids, col: llama.forward(params, cfg, ids, jnp.int32(0), col))(
+        engine.params, jnp.asarray([tokens], jnp.int32), axk1.LatentColumn.zeros(cfg, jnp.float32))
+    fed_bound = ec.fed_rows(T * min(cfg.n_active_experts, cfg.n_experts), cfg.n_experts)
+    assert calls == [fed_bound, fed_bound, T]                  # gate and up into the fed layout, down back to the rows
+    assert float(np.abs(np.asarray(logits[0]) - _reference_logits(bench, engine.params, tokens)).max()) < LOGIT_TOL
+    stats = np.asarray(col.stats)
+    assert stats[0] <= stats[2] <= stats[0] + 3 * cfg.n_experts * (ec.TILE_ROWS - 1) and stats[2] % ec.TILE_ROWS == 0
 
 
 @pytest.mark.parametrize("variant", ["nogroups", "bf16router", "nomscale", "norope", "nocnorm", "noshared", "latent8"])
@@ -497,7 +519,7 @@ def test_the_chunk_form_is_its_pairs_summed(engine, n_live):
     def form(x, live, m):
         weights, idx = share.route(cfg, x, lp.moe_gate[m])
         local, _stats = share.routed_pairs(cfg, idx, live)
-        return share._experts_chunk(cfg, x, local, weights, m, lp), local, weights
+        return share._experts_chunk(cfg, x, local, weights, m, lp)[0], local, weights
 
     got, local, weights = jax.jit(form)(x, live, m)
     want = _pairs_by_hand(cfg, lp, x, local, weights, m)
@@ -516,7 +538,7 @@ def test_a_chunk_of_the_windowed_decoder_takes_the_same_form(monkeypatch):
     from dllama_tpu.ops.quant_matmul import FUSED_MAX_M
 
     seen = []
-    monkeypatch.setattr(share, "_experts_chunk", lambda cfg, x, *a: seen.append(("chunk", x.shape[0])) or jnp.zeros(x.shape, jnp.float32))
+    monkeypatch.setattr(share, "_experts_chunk", lambda cfg, x, *a: seen.append(("chunk", x.shape[0])) or (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
     monkeypatch.setattr(share, "_experts_step", lambda cfg, x, *a: seen.append(("step", x.shape[0])) or jnp.zeros(x.shape, jnp.float32))
     cfg = types.SimpleNamespace(n_experts=4, moe_first_expert=0, n_active_experts=2, moe_n_group=0, moe_score="softmax",
                                 moe_norm_topk=True, moe_routed_scale=1.0)

@@ -459,6 +459,40 @@ def test_expert_gemv_stripes_a_wide_plane_for_v5e(one_chip, k, n, tn):
     assert any("expert_gemv" in name for name in kernels), kernels
 
 
+@pytest.mark.parametrize("layers,held,k,kk,n,rows,scatter", [
+    (23, 32, 10, 3072, 1024, 256, False), (23, 32, 10, 1024, 3072, 256, True),     # laguna-s-2.1: a plane lands whole
+    (9, 12, 8, 7168, 2048, 256, False), (9, 12, 8, 2048, 7168, 256, True),         # A.X-K1: in stripes
+    (23, 32, 10, 3072, 1024, 32, False), (23, 32, 10, 1024, 3072, 32, True),       # the narrowest bucket: 40 pairs over 32 experts
+    (4, 64, 8, 3072, 1024, 256, False), (4, 64, 8, 1024, 3072, 256, True)])        # a whole layer held: 32 rows an expert
+def test_expert_chunk_compiles_for_v5e(one_chip, layers, held, k, kk, n, rows, scatter):
+    """The routed chunk kernel at the two clients' expert planes, both ends,
+    at the static bound of the fed layout (2,432 rows for A.X-K1's 2,048
+    pairs over 12 held, 3,584 for laguna's 2,560 over 32, in tiles of 32): the chunk's rows
+    and the result resident in VMEM beside two landing halves of a stripe
+    and its dequantized copy, the runs and the sorted pairs' rows (and the
+    router's weights, float32) as scalar-prefetch operands."""
+    from dllama_tpu.ops import expert_chunk as ec
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    pairs = rows * min(k, held)
+    fed = ec.fed_rows(pairs, held)
+    tn = ec.stripe(rows, fed, kk, n, True, scatter)
+    assert tn is not None and (tn == n) == (kk * n < 4 * 1024 * 1024)
+    stack = QuantizedWeight(scales=_shape(one_chip, (layers, held, kk // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (layers, held, kk, n), jnp.int8))
+    i32 = lambda *shape: _shape(one_chip, shape, jnp.int32)
+    runs = (i32(),) + tuple(i32(held) for _ in range(4))
+    if scatter:
+        kernels = _compiled_kernels(
+            lambda x, st, layer, runs, r, at, w: ec.expert_chunk(x, st, layer, runs, r, (at, w), rows_out=rows, fast=True),
+            _shape(one_chip, (fed, kk), jnp.bfloat16), stack, i32(), runs, i32(pairs), i32(pairs), _shape(one_chip, (rows, k), jnp.float32))
+    else:
+        kernels = _compiled_kernels(
+            lambda x, st, layer, runs, r: ec.expert_chunk(x, st, layer, runs, r, rows_out=fed, fast=True),
+            _shape(one_chip, (rows, kk), jnp.bfloat16), stack, i32(), runs, i32(pairs))
+    assert any("expert_chunk" in name for name in kernels), kernels
+
+
 def test_mla_paged_step_compiles_for_v5e(one_chip):
     """A.X-K1's latent walk: 16 rows x 64 heads over rows of 576 values in 640
     lanes, 10 layers of 17,409 blocks of 16 (3.57 GB in bfloat16), a table of

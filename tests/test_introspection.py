@@ -109,8 +109,8 @@ def test_q40_path_counts_per_program_line_and_gauge(model_files, capsys):
     assert introspection.q40_paths_line(scope) == ""   # nothing traced yet
     e.generate("hello world", 3, stop_on_eos=False)
     paths = introspection.ledger().q40_paths(scope)
-    assert paths["greedy_step"] == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 8}
-    assert paths["forward"] == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 8}
+    assert paths["greedy_step"] == {"chunk": 0, "fused": 0, "tiled": 0, "grouped": 0, "xla": 8}
+    assert paths["forward"] == {"chunk": 0, "fused": 0, "tiled": 0, "grouped": 0, "xla": 8}
     line = introspection.q40_paths_line(scope)
     assert line.startswith("🧮 q40 matmuls: ")
     assert "greedy_step 0 chunk / 0 fused / 0 tiled / 8 xla" in line

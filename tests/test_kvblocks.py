@@ -780,8 +780,8 @@ def test_paged_step_tokens_equal_the_xla_modes(monkeypatch, mode, path):
     want, pkv_x, paths_x = run("xla")
     got, pkv_k, paths_k = run(mode)
     n = 7 * 1 + 1      # the scanned body's seven planes, and the Q40 head
-    assert paths_x == {"chunk": 0, "fused": 0, "tiled": 0, "xla": n}
-    assert paths_k == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 0, path: n}
+    assert paths_x == {"chunk": 0, "fused": 0, "tiled": 0, "grouped": 0, "xla": n}
+    assert paths_k == {"chunk": 0, "fused": 0, "tiled": 0, "grouped": 0, "xla": 0, path: n}
     # the inactive row is nobody's to read: the paged kernel writes zeros for
     # a row whose table starts with the null block (PR 31), the oracle attends
     # over whatever the null block holds; both stay finite

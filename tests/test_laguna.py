@@ -174,8 +174,31 @@ def test_whole_forward_logits(bench, engine, T):
     want = _reference_logits(bench, engine.params, tokens)
     assert float(np.abs(np.asarray(logits[0]) - want).max()) < LOGIT_TOL
     stats = np.asarray(col.stats)          # one chunk: a column carries its own counters, one row
-    assert stats[0] + stats[1] == T * 4 * 7 and stats[2:].sum() == stats[0]      # every pair counted once
+    assert stats[0] + stats[1] == T * 4 * 7 and stats[3:].sum() == stats[0]      # every pair counted once
     assert 0.35 < stats[0] / (T * 4 * 7) < 0.65                                   # half the experts are held
+
+
+def test_whole_forward_logits_through_the_grouped_kernel(bench, engine, monkeypatch):
+    """The same chunk with the kernels forced (interpret mode off a TPU): each
+    routed body's three projections ONE ``expert_chunk`` call (two bodies are
+    traced: a period's full layer and its sliding ones), the column's
+    counters the pairs and the rows fed (whole tiles a run), the logits the
+    reference's."""
+    from dllama_tpu.models import laguna, llama
+    from dllama_tpu.ops import expert_chunk as ec
+
+    cfg, T, calls = engine.cfg, 40, []
+    grouped = ec.expert_chunk
+    monkeypatch.setattr(ec, "expert_chunk", lambda *a, **kw: calls.append(kw["rows_out"]) or grouped(*a, **kw))
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "fused")
+    tokens = _tokens(T)
+    logits, col = jax.jit(lambda params, ids, col: llama.forward(params, cfg, ids, jnp.int32(0), col))(
+        engine.params, jnp.asarray([tokens], jnp.int32), laguna.LagunaColumn.zeros(cfg, jnp.float32))
+    fed_bound = ec.fed_rows(T * min(cfg.n_active_experts, cfg.n_experts), cfg.n_experts)
+    assert calls == [fed_bound, fed_bound, T] * 2              # gate and up into the fed layout, down back to the rows
+    assert float(np.abs(np.asarray(logits[0]) - _reference_logits(bench, engine.params, tokens)).max()) < LOGIT_TOL
+    stats = np.asarray(col.stats)
+    assert stats[0] <= stats[2] <= stats[0] + 7 * cfg.n_experts * (ec.TILE_ROWS - 1) and stats[2] % ec.TILE_ROWS == 0
 
 
 def _decode(gen, slots, n_steps):

@@ -566,3 +566,143 @@ def test_expert_gemv_gate_routes_through_the_one_gate(monkeypatch):
     assert eg.kernel_choice(8, stack, False) == {"interpret": True, "fast": False}
     monkeypatch.delenv("DLLAMA_TPU_QUANT_KERNEL")
     assert eg.kernel_choice(8, stack, False) is None      # auto, off a TPU
+
+
+# -- the chunk form's kernel: expert_chunk against its pairs and the every-row form --
+
+
+def _plane(stack, layer, e):
+    return np.asarray(stack.codes[layer, e], np.float32) * np.repeat(np.asarray(stack.scales[layer, e], np.float32), 32, axis=0)
+
+
+def _share_case(case, rng):
+    """``(held, k, N, local [N k])`` of one shape of routing: ``local`` reads
+    ``held`` where a pair's expert is absent or its row dead or padding. ``N``
+    is two tiles and a quarter."""
+    from dllama_tpu.ops.expert_chunk import TILE_ROWS as tm
+
+    held, k, N = 4, 2, 2 * tm + 8
+    if case == "no held pair":
+        local = np.full(N * k, held)
+    elif case == "every pair on one expert":                # a run of every row: three tiles, the last of 8 rows
+        local = np.where(np.arange(N * k) % k == 0, 2, held)
+    elif case == "at the static bound":                     # every pair held, the whole layer (held = width)
+        local = np.stack([rng.permutation(held)[:k] for _ in range(N)]).reshape(-1)
+    elif case == "dead and padding rows":                   # rows 3, 17 dead, the last 11 padding; a third of the rest absent
+        local = np.stack([rng.permutation(held + 2)[:k] for _ in range(N)])
+        local[[3, 17]] = held
+        local[N - 11:] = held
+        local = np.minimum(local, held).reshape(-1)
+    elif case == "a run ends on a tile edge, the next one past it":   # a tile of pairs on expert 0, one more on expert 1, 1 on 3
+        local = np.full((N, k), held)
+        local[:tm, 0], local[:tm + 1, 1], local[N - 1, 0] = 0, 1, 3
+        local = local.reshape(-1)
+    else:                                                   # "the whole layer, eight a token"
+        held, k, N = 8, 8, 24
+        local = np.stack([rng.permutation(held) for _ in range(N)]).reshape(-1)
+    return held, k, N, local.astype(np.int32)
+
+
+_SHARE_CASES = ["no held pair", "every pair on one expert", "at the static bound", "dead and padding rows",
+                "a run ends on a tile edge, the next one past it", "the whole layer, eight a token"]
+
+
+@pytest.mark.parametrize("striped", [False, True], ids=["whole plane", "striped"])
+@pytest.mark.parametrize("case", _SHARE_CASES)
+def test_expert_chunk_is_its_pairs_one_at_a_time(case, striped):
+    """``expert_chunk`` (interpret mode off a TPU), both ends, against each held
+    pair computed alone from the planes themselves: the gather end writes pair
+    ``p`` of run ``j`` to row ``tile0[j] tm + (p - pair0[j])`` of the fed
+    layout, the scatter end adds each pair's weighted row to its token's. A
+    plane whole, and in two stripes of its output columns (reduced shapes with
+    the real ones' divisibility: K a multiple of 32, N of lane tiles)."""
+    import types
+
+    from dllama_tpu.models import share
+    from dllama_tpu.ops import expert_chunk as ec
+
+    rng = np.random.default_rng(len(case))
+    held, k, N, local = _share_case(case, rng)
+    D, H, tm = 64, 256, ec.TILE_ROWS
+    cfg = types.SimpleNamespace(n_experts=held)
+    we1, we2 = _expert_stack(rng, 2, held, D, H), _expert_stack(rng, 2, held, H, D)
+    x = jnp.asarray(rng.standard_normal((N, D)), jnp.float32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=(N, k)), jnp.float32)
+    rows, experts, w, n_held = share._sorted_pairs(cfg, jnp.asarray(local), weights)
+    runs, fed = share._runs(cfg, jnp.asarray(local), tm)
+    counts = np.bincount(local, minlength=held + 1)[:held]
+    assert int(fed) == int((-(-counts // tm)).sum()) * tm and int(runs[0]) == int((counts > 0).sum())
+    F = ec.fed_rows(N * min(k, held), held)
+    assert int(fed) <= F and int(n_held) <= N * min(k, held)
+    if case == "at the static bound":
+        assert int(n_held) == N * min(k, held)
+    m, rows, w = jnp.int32(1), np.asarray(rows), np.asarray(w)
+    kw = {"interpret": True, "tn": 128 if striped else None}
+    h = np.asarray(ec.expert_chunk(x, we1, m, runs, jnp.asarray(rows), rows_out=F, **kw))
+    a = rng.standard_normal((F, H)).astype(np.float32)       # the scatter end's input: anything, in the fed layout
+    # the scatter end reads a pair's weight where the router left it: the pair's place in the flat [N k] weights
+    at = jnp.argsort(jnp.asarray(local), stable=True)
+    y = np.asarray(ec.expert_chunk(jnp.asarray(a), we2, m, runs, jnp.asarray(rows), (at, weights), rows_out=N, **{**kw, "tn": 32 if striped else None}))
+    want, p = np.zeros((N, D), np.float32), 0
+    tile0 = np.cumsum(-(-counts // tm)) - -(-counts // tm)
+    for e in range(held):
+        for r in range(counts[e]):
+            q = tile0[e] * tm + r
+            np.testing.assert_allclose(h[q], np.asarray(x[rows[p]]) @ _plane(we1, 1, e), rtol=2e-4, atol=2e-4)
+            want[rows[p]] += w[p] * (a[q] @ _plane(we2, 1, e))
+            p += 1
+    assert p == int(n_held)
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-4)
+    untouched = np.setdiff1d(np.arange(N), rows[:p])
+    assert not y[untouched].any()                            # a row no held pair names stays zero
+
+
+@pytest.mark.parametrize("case", _SHARE_CASES)
+def test_the_grouped_chunk_form_is_the_every_row_form(case, monkeypatch):
+    """``share._experts_chunk`` with the kernel forced (interpret mode) against
+    ``_experts_chunk_xla``, the every-row form through ``linear`` that stays
+    the form off a TPU: the same pairs, the same arithmetic a pair, the same
+    order of a token's experts, so float32 graphs agree to rounding; and the
+    rows each says it fed its planes."""
+    import types
+
+    from dllama_tpu.formats.mfile import HiddenAct
+    from dllama_tpu.models import share
+    from dllama_tpu.ops import expert_chunk as ec
+
+    rng = np.random.default_rng(len(case))
+    held, k, N, local = _share_case(case, rng)
+    D, H = 64, 128
+    cfg = types.SimpleNamespace(n_experts=held, hidden_act=HiddenAct.SILU)
+    lp = types.SimpleNamespace(we1=_expert_stack(rng, 2, held, D, H), we2=_expert_stack(rng, 2, held, H, D),
+                               we3=_expert_stack(rng, 2, held, D, H))
+    x = jnp.asarray(rng.standard_normal((N, D)), jnp.float32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=(N, k)), jnp.float32)
+    args = (cfg, x, jnp.asarray(local), weights, jnp.int32(1), lp)
+    want, every = share._experts_chunk_xla(*args)
+    assert share._experts_chunk(*args)[1] == every            # auto, off a TPU: the every-row form
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "fused")
+    got, fed = jax.jit(lambda *a: share._experts_chunk(cfg, *a, lp))(*args[1:5])
+    counts = np.bincount(local, minlength=held + 1)[:held]
+    assert int(fed) == int((-(-counts // ec.TILE_ROWS)).sum()) * ec.TILE_ROWS
+    assert int(every) == N * int((counts > 0).sum())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    if counts.sum():
+        assert float(jnp.abs(want).max()) > 0.05
+
+
+def test_expert_chunk_gate_routes_through_the_one_gate(monkeypatch):
+    from dllama_tpu.ops import expert_chunk as ec
+
+    stack = _expert_stack(np.random.default_rng(0), 1, 2, 64, 128)
+    F = ec.fed_rows(80, 2)
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "xla")
+    assert ec.kernel_choice(40, F, stack, False, False) is None
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "fused")
+    assert ec.kernel_choice(40, F, stack, False, True) == {"interpret": True, "fast": False}
+    monkeypatch.delenv("DLLAMA_TPU_QUANT_KERNEL")
+    assert ec.kernel_choice(40, F, stack, False, False) is None      # auto, off a TPU
+    # the VMEM predicate: a stripe for a plane too wide to land whole twice, none for a width off the lane grid
+    assert ec.stripe(256, ec.fed_rows(2048, 12), 7168, 2048, True, False) in (512, 1024)
+    assert ec.stripe(256, ec.fed_rows(2560, 32), 3072, 1024, True, False) == 1024
+    assert ec.stripe(256, F, 64, 100, True, False) is None

@@ -546,7 +546,7 @@ def test_linear_layer_slice_matches_the_plain_slice(monkeypatch, mode, path):
         np.testing.assert_array_equal(
             np.asarray(f(x, stack, jnp.int32(l))), np.asarray(linear(x, w)))
     counts = introspection.ledger().q40_paths(scope)["p"]
-    assert counts == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 0, path: 1}
+    assert counts == {"chunk": 0, "fused": 0, "tiled": 0, "grouped": 0, "xla": 0, path: 1}
 
 
 def test_layer_slice_under_a_plan_takes_the_slice(monkeypatch):
@@ -643,8 +643,8 @@ def test_dense_forward_scans_the_layer_index_for_decode_shapes(monkeypatch, t,
 
     want, kv_x, paths_x = run("xla")
     got, kv_k, paths_k = run("fused")
-    assert paths_x == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 8}
-    assert paths_k == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 0, path: 8}
+    assert paths_x == {"chunk": 0, "fused": 0, "tiled": 0, "grouped": 0, "xla": 8}
+    assert paths_k == {"chunk": 0, "fused": 0, "tiled": 0, "grouped": 0, "xla": 0, path: 8}
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(kv_k.k), np.asarray(kv_x.k),
@@ -766,7 +766,7 @@ def test_linear_layer_slice_at_chunk_width(monkeypatch, mode, path):
             np.asarray(f(x, stack, jnp.int32(l)), np.float32),
             np.asarray(want, np.float32))
     counts = introspection.ledger().q40_paths(scope)["p"]
-    assert counts == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 0, path: 1}
+    assert counts == {"chunk": 0, "fused": 0, "tiled": 0, "grouped": 0, "xla": 0, path: 1}
 
 
 @pytest.mark.parametrize("t", [64, 256])
@@ -802,8 +802,8 @@ def test_dense_forward_takes_the_chunk_kernel_at_bucket_widths(monkeypatch, t):
 
     want, kv_x, paths_x = run("xla")
     got, kv_k, paths_k = run("fused")
-    assert paths_x == {"chunk": 0, "fused": 0, "tiled": 0, "xla": 8}
-    assert paths_k == {"chunk": 8, "fused": 0, "tiled": 0, "xla": 0}
+    assert paths_x == {"chunk": 0, "fused": 0, "tiled": 0, "grouped": 0, "xla": 8}
+    assert paths_k == {"chunk": 8, "fused": 0, "tiled": 0, "grouped": 0, "xla": 0}
     scale = float(np.abs(np.asarray(want)).max())
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-2 * scale)
