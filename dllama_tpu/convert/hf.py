@@ -36,6 +36,7 @@ ARCH_BY_MODEL_TYPE = {
     "laguna": ArchType.LAGUNA,
     "falcon_h1": ArchType.FALCON_H1,
     "axk1": ArchType.AXK1,
+    "lfm2_moe": ArchType.LFM2,
 }
 
 HIDDEN_ACT_BY_NAME = {"gelu": HiddenAct.GELU, "silu": HiddenAct.SILU}
@@ -111,10 +112,11 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
     params: dict = {
         "version": 0,
         "arch_type": int(ARCH_BY_MODEL_TYPE[model_type]),
-        # the laguna config names no activation: its experts are SwiGLU
-        "hidden_act": int(HIDDEN_ACT_BY_NAME[cfg.get("hidden_act", "silu")
-                                             if model_type == "laguna"
-                                             else cfg["hidden_act"]]),
+        # the laguna and lfm2_moe configs name no activation: their
+        # feed-forwards are SwiGLU
+        "hidden_act": int(HIDDEN_ACT_BY_NAME[
+            cfg.get("hidden_act", "silu")
+            if model_type in ("laguna", "lfm2_moe") else cfg["hidden_act"]]),
         "dim": cfg["hidden_size"],
         "hidden_dim": cfg["intermediate_size"],
         "n_layers": cfg["num_hidden_layers"],
@@ -160,6 +162,8 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
 
     if model_type == "axk1":
         return {**params, **_axk1_header(cfg)}
+    if model_type == "lfm2_moe":
+        return {**params, **_lfm2_header(cfg)}
 
     if model_type == "falcon_h1":
         params.update(_falcon_h1_header(cfg))
@@ -332,6 +336,60 @@ def _axk1_header(cfg: dict) -> dict:
     }
 
 
+def _lfm2_header(cfg: dict) -> dict:
+    """``model_type: lfm2_moe``'s config keys as the header's extension keys
+    (formats/mfile.py, HeaderKey 70-71, 22, the share's 33-38, 21 and 67).
+    ``layer_types`` must be ``num_dense_layers`` leading ``conv`` layers and
+    then periods of one ``full_attention`` layer and ``conv`` ones (the last
+    may be cut short). A whole checkpoint holds every expert: the router's
+    width is ``num_experts`` and the first held expert 0. What the config
+    does not say (pre-norm, SwiGLU, the half-split rotary pairing, the order
+    ``B C X`` of the in-projection's rows, the ``1e-6`` in the weights'
+    renormalisation, the expert bias entering the selection only, a sigmoid
+    router, tied embeddings written twice) the arch implies
+    (models/lfm2.py)."""
+    kinds = list(cfg["layer_types"])
+    lead = int(cfg.get("num_dense_layers") or 0)
+    behind = kinds[lead:]
+    period = (behind.index("full_attention", 1)
+              if "full_attention" in behind[1:] else len(behind))
+    want = ["conv"] * lead + [
+        "full_attention" if i % period == 0 else "conv"
+        for i in range(len(behind))]
+    if kinds != want or len(kinds) != cfg["num_hidden_layers"] or not lead \
+            or not behind:
+        raise ValueError(
+            "lfm2_moe: layer_types is not num_dense_layers leading conv "
+            "layers and then periods of a full_attention layer and conv ones")
+    rope = cfg.get("rope_parameters") or {}
+    if cfg.get("conv_bias") or rope.get("rope_type", "default") != "default" \
+            or cfg.get("norm_eps") not in (1e-5, 1e-6):
+        raise ValueError(
+            "lfm2_moe: a convolution bias, a scaled rotary table or a norm "
+            "epsilon other than 1e-5 / 1e-6 are not carried")
+    return {
+        "hidden_dim": int(cfg["moe_intermediate_size"]),
+        "n_experts": int(cfg["num_experts"]),
+        "n_active_experts": int(cfg["num_experts_per_tok"]),
+        "moe_norm_topk": int(bool(cfg.get("norm_topk_prob", False))),
+        "head_dim": cfg["hidden_size"] // cfg["num_attention_heads"],
+        "norm_epsilon": 5 if cfg["norm_eps"] == 1e-5 else 6,
+        "rope_theta": int(rope.get("rope_theta", cfg.get("rope_theta", 1e6))),
+        "rope_type": int(RopeType.FALCON),
+        "layer_period": period,
+        "short_conv_kernel": int(cfg["conv_L_cache"]),
+        "moe_select_bias": int(bool(cfg.get("use_expert_bias"))),
+        "moe_score_func": 1,
+        "n_dense_layers": lead,
+        "dense_hidden_dim": int(cfg["intermediate_size"]),
+        "shared_expert_dim": 0,
+        "moe_routed_scale_milli": int(round(
+            float(cfg.get("routed_scaling_factor", 1.0)) * 1000)),
+        "moe_router_width": int(cfg["num_experts"]),
+        "moe_first_expert": 0,
+    }
+
+
 def _laguna_header(cfg: dict) -> dict:
     """``model_type: laguna``'s config keys as the header's extension keys
     (formats/mfile.py, HeaderKey 22, 29-38, and the rope-scaling keys 14-17
@@ -448,6 +506,15 @@ def hf_tensor_plan(params: dict) -> list[PlanItem]:
             "pair half-split: a published checkpoint's interleaved pairs are "
             "permuted in W_uq's and W_dkv's rope rows, as permute_rope_rows "
             "does for the Llama files)")
+    if arch == ArchType.LFM2:
+        raise NotImplementedError(
+            "lfm2_moe: the header is mapped (load_hf_config), the "
+            "checkpoint's tensor names are not: they could not be read where "
+            "this was written, and a guessed map is worse than none. The "
+            "target layout is formats/mfile.py's _walk_lfm2_layer (the "
+            "in-projection's rows in the order B, C, X; the taps [K, dim] "
+            "with tap K - 1 on the current position; q and k rows paired "
+            "half-split)")
     if arch == ArchType.FALCON_H1:
         raise NotImplementedError(
             "falcon_h1: the header is mapped (load_hf_config), the "

@@ -159,6 +159,16 @@ class HeaderKey(enum.IntEnum):
     MOE_SCORE_FUNC = 67          # 0 softmax, 1 sigmoid
     YARN_MSCALE = 68
     YARN_MSCALE_ALL_DIM = 69
+    # OUR format extension, read by ArchType.LFM2 only (models/lfm2.py): the
+    # gated short convolution's taps (``conv_L_cache``) and whether the
+    # router's selection carries a learned bias a routed layer
+    # (``use_expert_bias``: a float32 row of MOE_ROUTER_WIDTH behind the
+    # router's rows). The arch shares LAYER_PERIOD (22: an attention layer,
+    # then LAYER_PERIOD - 1 conv layers, after the N_DENSE_LAYERS leading conv
+    # layers), N_DENSE_LAYERS .. MOE_FIRST_EXPERT (33-38, SHARED_EXPERT_DIM 0),
+    # MOE_NORM_TOPK and MOE_SCORE_FUNC with the other routed archs.
+    SHORT_CONV_KERNEL = 70
+    MOE_SELECT_BIAS = 71
 
 
 class ArchType(enum.IntEnum):
@@ -183,6 +193,13 @@ class ArchType(enum.IntEnum):
     # sigmoid group-limited router over experts of which a share may be
     # held, and a shared one (models/axk1.py)
     AXK1 = 0xABCD05
+    # ours: gated short-convolution layers (a double gate around a causal
+    # depthwise convolution of a few taps: the whole state a sequence carries
+    # there is the convolution's tail) beside grouped-query attention layers
+    # with a per-head q/k norm, in a periodic pattern behind leading conv
+    # layers with a dense feed-forward; every other layer routes over experts
+    # through a sigmoid router with a selection-only bias (models/lfm2.py)
+    LFM2 = 0xABCD06
 
 
 class RopeType(enum.IntEnum):
@@ -283,6 +300,19 @@ class ModelHeader:
     moe_score_func: int = 0
     yarn_mscale: float = 1.0
     yarn_mscale_all_dim: float = 1.0
+    # LFM2 (HeaderKey 70-71); 0 for every other arch
+    short_conv_kernel: int = 0
+    moe_select_bias: int = 0
+
+    @property
+    def n_attn_layers(self) -> int:
+        """LFM2's attention layers: the first of each period behind the
+        leading conv layers (the last period may be cut short)."""
+        return -(-(self.n_layers - self.n_dense_layers) // self.layer_period)
+
+    def lfm2_is_attn(self, l: int) -> bool:
+        l -= self.n_dense_layers
+        return l >= 0 and l % self.layer_period == 0
 
     @property
     def ssm_inner_dim(self) -> int:
@@ -355,7 +385,8 @@ _HYBRID_KEYS = {k: k.name.lower() for k in (
     HeaderKey.Q_LORA_RANK, HeaderKey.KV_LORA_RANK,
     HeaderKey.QK_NOPE_HEAD_DIM, HeaderKey.QK_ROPE_HEAD_DIM,
     HeaderKey.V_HEAD_DIM, HeaderKey.MOE_N_GROUP, HeaderKey.MOE_TOPK_GROUP,
-    HeaderKey.MOE_SCORE_FUNC)}
+    HeaderKey.MOE_SCORE_FUNC, HeaderKey.SHORT_CONV_KERNEL,
+    HeaderKey.MOE_SELECT_BIAS)}
 # FALCON_H1's float keys: the value is a float32's bit pattern
 _F32_BITS_KEYS = {k: k.name.lower() for k in HeaderKey
                   if HeaderKey.EMBEDDING_MULT <= k <= HeaderKey.SSM_MULT_DT}
@@ -470,6 +501,32 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
                 f"must divide the heads")
         if h.n_experts:
             raise ValueError("falcon_h1 model: routed experts are unsupported")
+    if h.arch_type == ArchType.LFM2:
+        h.rope_type = RopeType.FALCON
+        h.moe_router_width = h.moe_router_width or h.n_experts
+        if (h.short_conv_kernel < 2 or h.layer_period < 2
+                or not 0 < h.n_dense_layers < h.n_layers
+                or not h.dense_hidden_dim):
+            raise ValueError(
+                f"lfm2 model: {h.short_conv_kernel} taps, a period of "
+                f"{h.layer_period} (one attention layer, then conv layers) "
+                f"behind {h.n_dense_layers} leading conv layers of "
+                f"{h.n_layers} with a dense feed-forward {h.dense_hidden_dim} "
+                f"wide: every size must be set, at least one layer leading "
+                f"and one behind")
+        if not (0 < h.n_active_experts <= h.moe_router_width
+                and 0 < h.n_experts
+                and h.moe_first_expert + h.n_experts <= h.moe_router_width):
+            raise ValueError(
+                f"lfm2 model: experts [{h.moe_first_expert}, "
+                f"{h.moe_first_expert + h.n_experts}) held of a router over "
+                f"{h.moe_router_width}, {h.n_active_experts} a token")
+        if h.moe_score_func not in (0, 1) or h.moe_select_bias not in (0, 1) \
+                or h.shared_expert_dim:
+            raise ValueError(
+                f"lfm2 model: score function code {h.moe_score_func} (0 "
+                f"softmax, 1 sigmoid), selection bias {h.moe_select_bias} "
+                f"(0 / 1), shared expert {h.shared_expert_dim} (none)")
     if h.arch_type == ArchType.AXK1:
         h.rope_type = RopeType.YARN
         h.moe_router_width = h.moe_router_width or h.n_experts
@@ -660,6 +717,9 @@ class ModelFile:
             if h.arch_type == ArchType.AXK1:
                 off = self._walk_axk1_layer(l, off)
                 continue
+            if h.arch_type == ArchType.LFM2:
+                off = self._walk_lfm2_layer(l, off)
+                continue
             off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
             off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
             off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
@@ -792,6 +852,34 @@ class ModelFile:
         off += self._add("block_norm_1", l, (h.dim,), F32, off)
         return off
 
+    def _walk_lfm2_layer(self, l: int, off: int) -> int:
+        """One layer of an LFM2 file (OUR layout; the reference has none).
+        An attention layer (:meth:`ModelHeader.lfm2_is_attn`): q k v wo and
+        the per-head q and k norms (F32, ``head_dim`` each). A conv layer:
+        ``W_in`` (``3 dim`` rows in the order B, C, X), the convolution's
+        taps (F32, ``[K, dim]``, tap ``K - 1`` on the current position),
+        ``W_out``. Then a leading dense layer's w1 w2 w3 or the router, its
+        selection bias (F32, the router's width, where the header says it has
+        one) and the HELD experts (:meth:`_walk_share_ffn`); the two block
+        norms (the operator's, the feed-forward's)."""
+        h, wt = self.header, self.header.weight_type
+        if h.lfm2_is_attn(l):
+            off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
+            off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
+            off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
+            off += self._add("block_matmul_wo", l, (h.dim, h.q_dim), wt, off)
+            off += self._add("block_norm_q", l, (h.head_dim,), F32, off)
+            off += self._add("block_norm_k", l, (h.head_dim,), F32, off)
+        else:
+            off += self._add("block_conv_in", l, (3 * h.dim, h.dim), wt, off)
+            off += self._add("block_conv_taps", l,
+                             (h.short_conv_kernel, h.dim), F32, off)
+            off += self._add("block_conv_out", l, (h.dim, h.dim), wt, off)
+        off = self._walk_share_ffn(l, off)
+        off += self._add("block_norm_0", l, (h.dim,), F32, off)
+        off += self._add("block_norm_1", l, (h.dim,), F32, off)
+        return off
+
     def _walk_share_ffn(self, l: int, off: int) -> int:
         """The feed-forward of a layer whose routed experts may be a SHARE:
         a leading dense layer's w1 w2 w3 at ``dense_hidden_dim``, or the
@@ -807,6 +895,9 @@ class ModelFile:
             return off
         off += self._add("block_moe_gate", l,
                          (h.moe_router_width, h.dim), F32, off)
+        if h.moe_select_bias:
+            off += self._add("block_moe_bias", l, (h.moe_router_width,),
+                             F32, off)
         for e in range(h.n_experts):
             off += self._add("block_expert_w3", l, (h.hidden_dim, h.dim),
                              wt, off, expert=e)

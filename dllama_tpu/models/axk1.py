@@ -112,7 +112,7 @@ class LatentColumn(NamedTuple):
     layer as a dense column, and the chunks' routing counters."""
 
     c: jax.Array       # [L, 1, 1, S, latent_row]
-    stats: jax.Array   # [3 + held] int32
+    stats: jax.Array   # [share.N_COUNTS + held] int32
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, dtype) -> "LatentColumn":

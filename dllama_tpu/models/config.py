@@ -141,6 +141,22 @@ class ModelConfig:
     moe_topk_group: int = 0
     yarn_mscale: float = 1.0
     yarn_mscale_all_dim: float = 1.0
+    # Gated short-convolution layers beside grouped-query attention layers,
+    # routed experts behind leading dense layers (ArchType.LFM2,
+    # models/lfm2.py). ``conv_kernel`` taps a channel (``conv_L_cache``): a
+    # conv layer's whole state is the convolution's tail, ``conv_kernel - 1``
+    # rows of ``dim``. The first ``n_dense_layers`` layers are conv layers
+    # with a dense feed-forward; behind them ``layer_period`` P = one
+    # attention layer, then P - 1 conv layers, the last period cut short
+    # where the depth says so. The share's fields are LAGUNA's (every expert
+    # held: ``n_experts`` = ``moe_router_width``); ``moe_select_bias``: a
+    # learned bias a routed layer enters the router's SELECTION only;
+    # ``moe_norm_eps`` is added to the chosen scores' sum before it divides
+    # them. The arch implies: pre-norm, a per-head RMS norm on q and k, the
+    # half-split rotary over the whole head, no bias, no shared expert.
+    conv_kernel: int = 0
+    moe_select_bias: bool = False
+    moe_norm_eps: float = 0.0
 
     # TPU execution choices (no reference equivalent):
     compute_dtype: str = "float32"  # "float32" for parity, "bfloat16" for speed
@@ -246,15 +262,24 @@ class ModelConfig:
     @property
     def cache_width(self) -> int:
         """Lanes of a cached row a head: a K (and V) head, or the latent
-        row."""
-        return self.latent_row if self.has_latent_cache else self.head_dim
+        row. A head of the short-conv arch's attention layers (64 lanes in
+        the published model) is padded to whole lane tiles of 128, as the
+        latent row is: the compiled paged kernel's DMA cannot slice a minor
+        dimension that is not whole tiles, and XLA's tiled layout pads a
+        64-lane row to 128 in HBM anyway, so the pool holds the same bytes
+        either way and the zero lanes add nothing to a score."""
+        if self.has_latent_cache:
+            return self.latent_row
+        if self.has_short_conv:
+            return -(-self.head_dim // 128) * 128
+        return self.head_dim
 
     @property
     def cache_row_elems(self) -> int:
         """Elements a cached token takes in one layer over all the pool's
         planes: K and V of every K/V head, or the one latent row."""
         return (self.latent_row if self.has_latent_cache
-                else 2 * self.kv_dim)
+                else 2 * self.n_kv_heads * self.cache_width)
 
     @property
     def attn_scale(self) -> float:
@@ -300,21 +325,44 @@ class ModelConfig:
         return self.ssm_heads > 0
 
     @property
+    def has_short_conv(self) -> bool:
+        """Gated short-convolution layers beside attention layers
+        (models/lfm2.py): their state is the convolution's tail alone."""
+        return self.conv_kernel > 0
+
+    @property
+    def n_attn_layers(self) -> int:
+        """The short-conv arch's attention layers: the first of each
+        period behind the leading conv layers."""
+        return (-(-(self.n_layers - self.n_dense_layers) // self.layer_period)
+                if self.has_short_conv else 0)
+
+    @property
+    def n_conv_layers(self) -> int:
+        return self.n_layers - self.n_attn_layers if self.has_short_conv else 0
+
+    @property
     def has_state(self) -> bool:
         """Layers with a recurrent state: a slot's context is K/V blocks
         AND a row of the state pool (runtime/kvblocks.StatePool), whichever
         architecture owns the state's shape."""
-        return self.is_hybrid or self.has_ssm
+        return self.is_hybrid or self.has_ssm or self.has_short_conv
 
     @property
     def n_state_layers(self) -> int:
         """Layers that own a row of the state pool: a hybrid's linear ones,
-        or every layer where the mixer sits beside attention."""
+        every layer where the mixer sits beside attention, or the conv
+        layers."""
+        if self.has_short_conv:
+            return self.n_conv_layers
         return self.n_layers if self.has_ssm else self.n_linear_layers
 
-    def state_shape(self, rows: int) -> tuple[int, ...]:
+    def state_shape(self, rows: int) -> tuple[int, ...] | None:
         """The float32 recurrent state of ``rows`` sequences, the
-        architecture's: ``[layers, rows, heads, ...]``."""
+        architecture's: ``[layers, rows, heads, ...]``; None where the
+        convolution's tail is the whole state (a short-conv layer)."""
+        if self.has_short_conv:
+            return None
         if self.has_ssm:
             return (self.n_layers, rows, self.ssm_heads, self.ssm_head_dim,
                     self.ssm_state_dim)
@@ -324,6 +372,8 @@ class ModelConfig:
     def conv_shape(self, rows: int) -> tuple[int, ...]:
         """The causal convolution's last ``K - 1`` inputs of ``rows``
         sequences: ``[layers, rows, K - 1, channels]``."""
+        if self.has_short_conv:
+            return (self.n_conv_layers, rows, self.conv_kernel - 1, self.dim)
         if self.has_ssm:
             return (self.n_layers, rows, self.ssm_conv_kernel - 1,
                     self.ssm_conv_dim)
@@ -358,7 +408,10 @@ class ModelConfig:
     def n_kv_layers(self) -> int:
         """Layers whose K/V lives in THE block pool: every one, a hybrid's
         full ones, or the full ones beside window layers (those have a pool
-        of their own, ``n_window_layers`` deep)."""
+        of their own, ``n_window_layers`` deep), or the attention layers
+        beside short-conv ones."""
+        if self.has_short_conv:
+            return self.n_attn_layers
         return self.n_periods if self.layer_period else self.n_layers
 
     @property
@@ -413,6 +466,18 @@ class ModelConfig:
                 n_dense_layers=h.n_dense_layers,
                 dense_hidden_dim=h.dense_hidden_dim,
                 shared_expert_dim=h.shared_expert_dim,
+                moe_routed_scale=h.moe_routed_scale_milli / 1000.0,
+                moe_router_width=h.moe_router_width,
+                moe_first_expert=h.moe_first_expert)
+        if h.arch_type == ArchType.LFM2:
+            hybrid = dict(
+                layer_period=h.layer_period,
+                conv_kernel=h.short_conv_kernel,
+                moe_select_bias=bool(h.moe_select_bias),
+                moe_norm_eps=1e-6,
+                moe_score=("softmax", "sigmoid")[h.moe_score_func],
+                n_dense_layers=h.n_dense_layers,
+                dense_hidden_dim=h.dense_hidden_dim,
                 moe_routed_scale=h.moe_routed_scale_milli / 1000.0,
                 moe_router_width=h.moe_router_width,
                 moe_first_expert=h.moe_first_expert)

@@ -60,7 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import ssd
-from ..ops.gated_delta import causal_conv
+from ..ops.causal_conv import causal_conv
 from ..ops.linear import Weight, linear
 from ..ops.norms import rms_norm
 from ..parallel.api import current_plan

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import gated_delta as gd
+from ..ops.causal_conv import causal_conv
 from ..ops.linear import Weight, linear
 from ..ops.norms import rms_norm
 from ..parallel.api import current_plan
@@ -125,7 +126,7 @@ def _mixer_inputs(cfg: ModelConfig, u: jax.Array, lp: LinearLayerParams,
     qkv, z = proj[..., :cfg.lin_conv_dim], proj[..., cfg.lin_conv_dim:]
     ab = jnp.einsum("btd,hd->bth", u.astype(jnp.float32), lp.w_ab,
                     precision=jax.lax.Precision.HIGHEST)
-    y, tail = gd.causal_conv(qkv, tail, lp.conv_w, n_valid)
+    y, tail = causal_conv(qkv, tail, lp.conv_w, n_valid)
     q = gd.l2norm(y[..., :H * dk].reshape(B, T, H, dk)) * dk ** -0.5
     k = gd.l2norm(y[..., H * dk:2 * H * dk].reshape(B, T, H, dk))
     v = y[..., 2 * H * dk:].reshape(B, T, H, dv)

@@ -117,7 +117,7 @@ class LagunaColumn(NamedTuple):
 
     k: jax.Array       # [L, 1, n_kv, S, hd]
     v: jax.Array
-    stats: jax.Array   # [3 + held] int32
+    stats: jax.Array   # [share.N_COUNTS + held] int32
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, dtype) -> "LagunaColumn":

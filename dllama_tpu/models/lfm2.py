@@ -1,0 +1,316 @@
+"""A decoder of gated short-convolution layers beside grouped-query
+attention layers, routed experts behind leading dense layers
+(``ArchType.LFM2``; LFM2-24B-A2B is two leading conv layers, then one
+attention layer to three conv ones, 64 routed experts of which a token takes
+4, no shared one).
+
+**The equations.** Every layer is pre-norm: ``h = x + Op_l(rmsnorm(x; w_o))``,
+``out = h + Ffn_l(rmsnorm(h; w_f))``; a final RMS norm, then the head.
+
+* a CONV layer, input ``u``: ``[B | C | X] = W_in u`` (``dim -> 3 dim``, split
+  in that order), ``v_t = B_t * X_t``, ``c_t = sum_j w_j v_{t-(K-1)+j}`` (a
+  causal depthwise convolution of ``K = conv_kernel`` taps a channel, no
+  bias, NO activation: :func:`~dllama_tpu.ops.causal_conv.causal_conv` with
+  ``activation=None``), ``y_t = C_t * c_t``, ``Op = W_out y``. What a sequence
+  carries is the convolution's TAIL, ``v_{t-K+1} .. v_{t-1}``: ``K - 1`` rows
+  of ``dim`` a layer in the compute dtype, and nothing else.
+* an ATTENTION layer: ``q, k, v = W_q u, W_k u, W_v u`` (``n_heads`` query and
+  ``n_kv_heads`` K/V heads of ``head_dim`` lanes), an RMS norm over each
+  head's lanes of ``q`` and of ``k``, rotary positions (half-split pairing
+  over the whole head), causal softmax at ``head_dim ** -0.5``, ``W_o``.
+* the first ``n_dense_layers`` layers (conv layers) carry a SwiGLU
+  feed-forward ``dense_hidden_dim`` wide; every other layer the routed one
+  of ``models/share.py``: a sigmoid router in float32 whose SELECTION adds a
+  learned bias a layer (``moe_bias``) and whose weights are the chosen
+  scores alone over ``(their sum + 1e-6)``.
+
+**The stack** is the leading conv layers (a ``fori_loop`` over one traced
+body with the dense feed-forward), then ONE scan over PERIODS: an attention
+layer, then ``P - 1`` conv layers (a ``fori_loop`` over one traced body); a
+last period that the depth cuts short is traced once behind the scan. Two
+mixer stacks (:class:`ConvParams` over the conv layers, :class:`AttnParams`
+over the attention layers), the feed-forwards' stacks as ``models/share.py``
+names them. Every Q40 plane stays whole and reaches ``linear`` as stack +
+index, every expert stack reaches the routed kernels as stack + layer.
+
+**A slot's context** is three things, all in the scan's carry and written
+in place: K/V rows of the attention layers only (a column ``[n_attn, 1, n_kv,
+S, W]`` during prefill, blocks of the paged pool ``[n_attn, n_blocks, n_kv,
+bs, W]`` afterwards), the conv layers' tails
+(:class:`~dllama_tpu.runtime.kvblocks.StateColumn`'s ``conv`` during
+prefill, a row of :class:`~dllama_tpu.runtime.kvblocks.StatePool`
+afterwards: ``s`` is None, the tail is the whole state) and the routing
+counters of ``models/share.py``. ``W = cfg.cache_width``: a head's lanes
+padded with zeros to whole lane tiles of 128, so that the compiled paged
+kernel and the flash kernel take the pool and the column as they take the
+128-lane models'; the zero lanes add nothing to a score (its scale is
+``head_dim``'s) and the padded lanes of the result are dropped.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.causal_conv import causal_conv
+from ..ops.linear import Weight, linear
+from ..ops.norms import rms_norm, rms_norm_per_head
+from ..parallel.api import current_plan
+from ..runtime.introspection import note_short_conv_path
+from ..runtime.kvblocks import StateColumn
+from .config import ModelConfig
+from .llama import Params, _attend_dense, _attend_paged, _stack_at
+from .rope import apply_rope_partial, build_partial_rope_cache
+from .share import _plane, ffn_half, swiglu, zero_stats
+
+
+class ConvParams(NamedTuple):
+    """The conv layers' mixers, stacked over the ``n_conv_layers`` in the
+    model's order."""
+
+    w_in: Weight          # [NC, 3 dim, dim]: the B, C and X rows, in that order
+    conv_w: jax.Array     # [NC, K, dim] float32: tap K - 1 on the current position
+    w_out: Weight         # [NC, dim, dim]
+    norm_att: jax.Array   # [NC, dim]: the operator's norm
+
+
+class AttnParams(NamedTuple):
+    """The attention layers' mixers, stacked over the ``n_attn_layers``."""
+
+    wq: Weight            # [NA, q_dim, dim]
+    wk: Weight            # [NA, kv_dim, dim]
+    wv: Weight
+    wo: Weight            # [NA, dim, q_dim]
+    norm_q: jax.Array     # [NA, head_dim]
+    norm_k: jax.Array
+    norm_att: jax.Array   # [NA, dim]
+
+
+_CONV_MATMULS = ("w_in", "w_out")
+_ATTN_MATMULS = ("wq", "wk", "wv", "wo")
+
+
+class Lfm2Layers(NamedTuple):
+    """``Params.layers``: the two mixer stacks, the leading layers' dense
+    feed-forward, the routed layers' (stacked over the ``n_moe_layers`` that
+    have one: layer ``l`` is entry ``l - n_dense_layers``), named as
+    ``models/share.py`` reads them."""
+
+    conv: ConvParams
+    attn: AttnParams
+    norm_ffn: jax.Array          # [L, dim]
+    w1: Weight                   # [n_dense, dense_hidden, dim]
+    w2: Weight
+    w3: Weight
+    moe_gate: jax.Array          # [NM, router_width, dim] float32
+    moe_bias: jax.Array | None   # [NM, router_width] float32: the selection's
+    we1: Weight                  # [NM, held, dim, hidden]
+    we2: Weight                  # [NM, held, hidden, dim]
+    we3: Weight
+    ws1: None = None             # no shared expert (share.routed_ffn asks)
+    ws2: None = None
+    ws3: None = None
+
+
+def _check(cfg: ModelConfig) -> None:
+    if current_plan() is not None:
+        raise ValueError("a decoder with short-convolution layers and routed "
+                         "experts has no mesh plan (tp/sp/pp/dp > 1) yet")
+    if cfg.sync_q80 or cfg.offload:
+        raise ValueError("a decoder with short-convolution layers supports "
+                         "neither Q80 sync emulation nor offloaded weights")
+
+
+def _at(a: jax.Array, l):
+    return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+
+
+def _conv_mixer(cfg: ModelConfig, u: jax.Array, cp: ConvParams,
+                tail: jax.Array, n_valid):
+    """The gated short convolution over ``u [B, T, dim]`` (normed) behind
+    ``tail [B, K - 1, dim]``: ``W_out (C * conv(B * X))`` and the new tail.
+    The gated input ``v = B * X`` is rounded once to the activation dtype,
+    which is what the tail holds, so a chunk and a step see the same
+    values."""
+    d = cfg.dim
+    proj = linear(u, cp.w_in).astype(jnp.float32)
+    v = (proj[..., :d] * proj[..., 2 * d:]).astype(u.dtype)
+    c, tail = causal_conv(v, tail, cp.conv_w, n_valid, activation=None)
+    return linear((proj[..., d:2 * d] * c).astype(u.dtype), cp.w_out), tail
+
+
+def _pad_lanes(a: jax.Array, width: int) -> jax.Array:
+    pad = width - a.shape[-1]
+    return a if not pad else jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, pad),))
+
+
+def _attn_mixer(cfg: ModelConfig, u: jax.Array, ap: AttnParams, table,
+                positions: jax.Array, attend):
+    """Grouped-query attention over ``u [B, T, dim]`` (normed);
+    ``attend(q, k, v) -> att`` owns the cache and takes and gives heads of
+    ``cfg.cache_width`` lanes."""
+    B, T, _ = u.shape
+    hd, W = cfg.head_dim, cfg.cache_width
+    q = linear(u, ap.wq).reshape(B, T, cfg.n_heads, hd)
+    k = linear(u, ap.wk).reshape(B, T, cfg.n_kv_heads, hd)
+    v = linear(u, ap.wv).reshape(B, T, cfg.n_kv_heads, hd)
+    q = rms_norm_per_head(q, ap.norm_q, cfg.norm_epsilon)
+    k = rms_norm_per_head(k, ap.norm_k, cfg.norm_epsilon)
+    q = apply_rope_partial(q, *table, positions)
+    k = apply_rope_partial(k, *table, positions)
+    att = attend(_pad_lanes(q, W), _pad_lanes(k, W), _pad_lanes(v, W))
+    return linear(att[..., :hd].reshape(B, T, cfg.q_dim), ap.wo)
+
+
+def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    x = rms_norm(x, params.final_norm, cfg.norm_epsilon)
+    return linear(x, params.logits, out_axis="vocab").astype(jnp.float32)
+
+
+def _scan_layers(params: Params, cfg: ModelConfig, x, caches, stats, live,
+                 positions, conv_mixer, attend):
+    """The walk both programs share. ``caches = (k, v, conv)`` (a column's
+    arrays, or the two pools') and ``stats`` ride every loop's carry whole.
+    ``conv_mixer(h, cp, c, conv) -> (y, conv')`` is conv layer ``c``'s mixer
+    in the program's form, ``attend(q, k, v, k_c, v_c, a) -> (att, k_c,
+    v_c)`` attention layer ``a``'s cache."""
+    lp: Lfm2Layers = params.layers
+    P, lead, L = cfg.layer_period, cfg.n_dense_layers, cfg.n_layers
+    eps = cfg.norm_epsilon
+    table = build_partial_rope_cache(cfg.seq_len, cfg.head_dim,
+                                     float(cfg.rope_theta))
+
+    def conv_half(x, conv, c):
+        cp = _stack_at(lp.conv, c, _CONV_MATMULS)
+        y, conv = conv_mixer(rms_norm(x, cp.norm_att, eps), cp, c, conv)
+        return x + y, conv
+
+    def leading(l, carry):
+        x, (k_c, v_c, conv) = carry
+        x, conv = conv_half(x, conv, l)
+        h = rms_norm(x, _at(lp.norm_ffn, l), eps)
+        x = x + swiglu(cfg, h, _plane(lp.w1, l), _plane(lp.w2, l),
+                       _plane(lp.w3, l))
+        return x, (k_c, v_c, conv)
+
+    def conv_layer(x, caches, stats, c, l):
+        k_c, v_c, conv = caches
+        x, conv = conv_half(x, conv, c)
+        x, s = ffn_half(cfg, x, lp, l, live, may_be_dense=False)
+        return x, (k_c, v_c, conv), stats + s
+
+    def attn_layer(x, caches, stats, a, l):
+        k_c, v_c, conv = caches
+        ap = _stack_at(lp.attn, a, _ATTN_MATMULS)
+        box = {}
+
+        def att(q, k, v):
+            out, box["k"], box["v"] = attend(q, k, v, k_c, v_c, a)
+            return out
+
+        x = x + _attn_mixer(cfg, rms_norm(x, ap.norm_att, eps), ap, table,
+                            positions, att)
+        x, s = ffn_half(cfg, x, lp, l, live, may_be_dense=False)
+        return x, (box["k"], box["v"], conv), stats + s
+
+    def period(p, carry, n_conv):
+        """Period ``p``: its attention layer, then ``n_conv`` conv layers."""
+        l0 = lead + p * P
+        x, caches, stats = attn_layer(*carry, p, l0)
+
+        def conv_j(j, carry):
+            return conv_layer(*carry, lead + p * (P - 1) + j, l0 + 1 + j)
+
+        return jax.lax.fori_loop(0, n_conv, conv_j, (x, caches, stats))
+
+    x, caches = jax.lax.fori_loop(0, lead, leading, (x, caches))
+    whole, rest = divmod(L - lead, P)
+    (x, caches, stats), _ = jax.lax.scan(
+        lambda carry, p: (period(p, carry, P - 1), None), (x, caches, stats),
+        jnp.arange(whole, dtype=jnp.int32))
+    if rest:
+        x, caches, stats = period(jnp.int32(whole), (x, caches, stats),
+                                  rest - 1)
+    return _head(params, cfg, x), caches, stats
+
+
+def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+            start_pos: jax.Array, col: StateColumn,
+            n_valid: jax.Array | None = None):
+    """A chunk ``tokens [B, T]`` at scalar ``start_pos`` over a gathered
+    column: float32 logits ``[B, T, vocab]`` and the column, advanced by
+    the chunk's first ``n_valid`` positions (absent: all ``T``). Positions at
+    or past ``n_valid`` are padding: their K/V rows are overwritten later,
+    they are not routed, and they never enter a tail."""
+    _check(cfg)
+    start_pos = jnp.asarray(start_pos, dtype=jnp.int32)
+    if start_pos.ndim:
+        raise ValueError("the chunk form takes one start position (the "
+                         "dense slot pool's ragged rows are not carried to "
+                         "a convolution's tail)")
+    B, T = tokens.shape
+    n_valid = jnp.asarray(T if n_valid is None else n_valid, jnp.int32)
+    live = jnp.tile(jnp.arange(T) < n_valid, B)
+    x = params.embedding[tokens].astype(cfg.compute_dtype)
+    positions = jnp.broadcast_to(
+        start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
+
+    def put(a, a_l, l):
+        return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
+
+    def conv_mixer(h, cp, c, conv):
+        note_short_conv_path("chunk", "xla")
+        y, tail = _conv_mixer(cfg, h, cp, _at(conv, c), n_valid)
+        return y, put(conv, tail, c)
+
+    def attend(q, k, v, k_c, v_c, a):
+        att, k_a, v_a = _attend_dense(cfg, q, k, v, _at(k_c, a), _at(v_c, a),
+                                      start_pos, positions)
+        return att, put(k_c, k_a, a), put(v_c, v_a, a)
+
+    logits, (k, v, conv), stats = _scan_layers(
+        params, cfg, x, (col.k, col.v, col.conv), col.stats, live, positions,
+        conv_mixer, attend)
+    return logits, StateColumn(k=k, v=v, s=None, conv=conv, stats=stats)
+
+
+def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                  pos_vec: jax.Array, cache, tables: jax.Array,
+                  write_lens: jax.Array | None = None):
+    """The decode step over the K/V pool, the tail pool and the routing
+    counters: ``tokens [B, 1]`` at per-row ``pos_vec``, ``cache =
+    (PagedKVCache, StatePool, totals)``, all given back (the pools written in
+    place, the step's counters added to row 0 of ``totals``). Row ``b`` is
+    slot ``b``: its tails are row ``b + 1`` of the pool, or the null row 0
+    while its block table is all null (such a row is not routed)."""
+    from ..runtime.kvblocks import PagedKVCache, StatePool
+
+    _check(cfg)
+    B, T = tokens.shape
+    if T != 1 or write_lens is not None:
+        raise ValueError("the step form takes one token a row: a "
+                         "speculative verify's rejected drafts cannot be "
+                         "rolled back out of a convolution's tail")
+    pkv, pool, totals = cache
+    positions = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]
+    live = tables[:, 0] != 0
+    rows = jnp.where(live, jnp.arange(1, B + 1, dtype=jnp.int32),
+                     StatePool.NULL)
+    x = params.embedding[tokens].astype(cfg.compute_dtype)
+
+    def conv_mixer(h, cp, c, conv):
+        note_short_conv_path("step", "xla")
+        y, tail = _conv_mixer(cfg, h, cp, _at(conv, c)[rows], None)
+        return y, conv.at[c, rows].set(tail)
+
+    def attend(q, k, v, k_pool, v_pool, a):
+        return _attend_paged(cfg, q, k, v, k_pool, v_pool, a, positions,
+                             tables)
+
+    logits, (k, v, conv), stats = _scan_layers(
+        params, cfg, x, (pkv.k, pkv.v, pool.conv), zero_stats(cfg), live,
+        positions, conv_mixer, attend)
+    return logits, (PagedKVCache(k=k, v=v), StatePool(s=None, conv=conv),
+                    totals.at[0].add(stats))

@@ -800,7 +800,7 @@ def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
         def view(pool):
             gathered = pool[l, tables]           # [B, M, n_kv, bs, hd]
             return jnp.moveaxis(gathered, 2, 1).reshape(
-                B, cfg.n_kv_heads, n_blocks_seq * bs, cfg.head_dim)
+                B, cfg.n_kv_heads, n_blocks_seq * bs, pool.shape[-1])
 
         att = attention(q, view(k_pool), view(v_pool), positions,
                         cfg.head_dim, window=window)
@@ -997,6 +997,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         from . import axk1
 
         return axk1.forward(params, cfg, tokens, start_pos, kv, n_valid)
+    if cfg.has_short_conv:
+        from . import lfm2
+
+        return lfm2.forward(params, cfg, tokens, start_pos, kv, n_valid)
     start_pos = jnp.asarray(start_pos, dtype=jnp.int32)
     ragged = start_pos.ndim > 0
     # numerics observatory taps (runtime/numerics): a TRACE-TIME flag, so
@@ -1328,6 +1332,11 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         from . import axk1
 
         return axk1.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
+                                  write_lens)
+    if cfg.has_short_conv:
+        from . import lfm2
+
+        return lfm2.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
                                   write_lens)
     if _numerics.taps_active():
         raise ValueError("numerics taps are unsupported on the paged KV "

@@ -31,7 +31,10 @@ topk_group`` largest) from the configuration.
 **Counters**: ``stats`` = (pairs computed here, pairs that fell on absent
 experts, rows the chunk form fed its planes (with the grouped kernel the
 pairs rounded up to whole tiles a run: over the pairs, what the tiling
-pads), tokens each held expert saw), summed over the layers, accumulated on
+pads), PLANES: the distinct held experts a layer's rows chose, which is what
+a kernel that fetches a plane once a run of pairs reads (the pairs over
+them say how many times the decode kernel, a plane a pair, reads each),
+tokens each held expert saw), summed over the layers, accumulated on
 the device and given back with the pools.
 
 A layer stack that uses these functions names its leaves ``norm_ffn``, ``w1
@@ -49,11 +52,19 @@ from ..ops import expert_gemv as eg
 from ..ops.linear import (LayerSlice, QuantizedWeight, Weight, _fast_mode,
                           linear)
 from ..ops.norms import rms_norm
+from ..ops.quant_matmul import FUSED_MAX_M
 from ..runtime.introspection import note_q40_path
 from .config import ModelConfig
 from .llama import _hidden_act
 
 _HIGHEST = jax.lax.Precision.HIGHEST
+# rows up to which a dispatch takes the decode form (a plane a PAIR); wider
+# ones take the chunk form (a plane a RUN of pairs): the dense kernels' own
+# boundary between their decode and chunk regimes
+STEP_FORM_MAX_ROWS = FUSED_MAX_M
+# entries of ``stats`` in front of the tokens a held expert: held pairs,
+# absent pairs, rows fed, planes
+N_COUNTS = 4
 
 
 def _plane(w: Weight, l) -> Weight:
@@ -67,15 +78,16 @@ def _plane(w: Weight, l) -> Weight:
 
 def zero_stats(cfg: ModelConfig) -> jax.Array:
     """One dispatch's routing counters: held pairs, absent pairs, rows the
-    chunk form fed the planes, tokens a held expert."""
-    return jnp.zeros((3 + cfg.n_experts,), jnp.int32)
+    chunk form fed the planes, distinct held experts chosen a layer, tokens
+    a held expert."""
+    return jnp.zeros((N_COUNTS + cfg.n_experts,), jnp.int32)
 
 
 def zero_totals(cfg: ModelConfig) -> jax.Array:
     """The generator's running totals beside its pools: row 0 what the
     decode steps added, row 1 what the prefill chunks did (kept apart so
     that a step's own pairs can be read off after it)."""
-    return jnp.zeros((2, 3 + cfg.n_experts), jnp.int32)
+    return jnp.zeros((2, N_COUNTS + cfg.n_experts), jnp.int32)
 
 
 def swiglu(cfg: ModelConfig, h: jax.Array, w1, w2, w3) -> jax.Array:
@@ -83,20 +95,27 @@ def swiglu(cfg: ModelConfig, h: jax.Array, w1, w2, w3) -> jax.Array:
     return linear(gate * linear(h, w3), w2)
 
 
-def route(cfg: ModelConfig, h: jax.Array, gate: jax.Array):
+def route(cfg: ModelConfig, h: jax.Array, gate: jax.Array,
+          bias: jax.Array | None = None):
     """The router over its whole width, float32: ``(weights [N, k], experts
     [N, k])`` for ``h [N, dim]``. Scores are ``cfg.moe_score`` of the logits;
     with ``cfg.moe_n_group`` groups the choice is limited to the
     ``cfg.moe_topk_group`` groups whose ``k / topk_group`` largest scores sum
     highest (ties go to the lower index, as ``lax.top_k`` breaks them);
     weights are the chosen scores, renormalised over the chosen where
-    ``moe_norm_topk`` and scaled by ``moe_routed_scale``."""
+    ``moe_norm_topk`` (``cfg.moe_norm_eps`` added to their sum) and scaled
+    by ``moe_routed_scale``. ``bias [width]`` (a layer's learned selection
+    bias, ``cfg.moe_select_bias``) enters the CHOICE only: the experts are
+    the ``top_k`` of ``scores + bias``, their weights the scores alone."""
     logits = jnp.einsum("nd,ed->ne", h.astype(jnp.float32),
                         gate.astype(jnp.float32), precision=_HIGHEST)
     scores = (jax.nn.sigmoid(logits) if cfg.moe_score == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
     k, G = cfg.n_active_experts, cfg.moe_n_group
     if G > 1:
+        if bias is not None:
+            raise ValueError("a selection bias under a group limit is not "
+                             "carried")
         N, W = scores.shape
         per_group = jax.lax.top_k(scores.reshape(N, G, W // G),
                                   k // cfg.moe_topk_group)[0].sum(axis=-1)
@@ -106,10 +125,14 @@ def route(cfg: ModelConfig, h: jax.Array, gate: jax.Array):
         limited = jnp.where(jnp.repeat(allowed, W // G, axis=1), scores,
                             -jnp.inf)
         top, idx = jax.lax.top_k(limited, k)
+    elif bias is not None:
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
     else:
         top, idx = jax.lax.top_k(scores, k)
     if cfg.moe_norm_topk:
-        top = top / jnp.sum(top, axis=-1, keepdims=True)
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        top = top / (total + cfg.moe_norm_eps if cfg.moe_norm_eps else total)
     return top * cfg.moe_routed_scale, idx
 
 
@@ -118,7 +141,8 @@ def routed_pairs(cfg: ModelConfig, idx: jax.Array, live: jax.Array):
     ``local [N k]`` the expert's index among those held (``n_experts`` where
     it is absent or the row is not ``live [N]``), and ``stats`` (held
     pairs, absent pairs of live rows, 0 for the rows fed, which the chunk
-    form fills in, tokens a held expert)."""
+    form fills in, the distinct held experts chosen, tokens a held
+    expert)."""
     E = cfg.n_experts
     local = idx - cfg.moe_first_expert
     here = (local >= 0) & (local < E)
@@ -126,8 +150,9 @@ def routed_pairs(cfg: ModelConfig, idx: jax.Array, live: jax.Array):
     local = jnp.where(held, local.reshape(-1), E)
     absent = jnp.sum(~here & live[:, None])
     tokens = jnp.bincount(local, length=E + 1)[:E]
-    stats = jnp.concatenate([jnp.stack([jnp.sum(held), absent, 0]),
-                             tokens]).astype(jnp.int32)
+    stats = jnp.concatenate([
+        jnp.stack([jnp.sum(held), absent, 0, jnp.sum(tokens > 0)]),
+        tokens]).astype(jnp.int32)
     return local, stats
 
 
@@ -261,14 +286,15 @@ def routed_ffn(cfg: ModelConfig, h: jax.Array, lp, m,
     """``scale sum_{held} w_e E_e(h) + S(h)`` for ``h [B, T, dim]`` in routed
     layer ``m``, and the layer's ``stats``; ``live [B * T]`` marks the rows
     that are real (a dead slot's, a chunk's padding, are not routed)."""
-    from ..ops.quant_matmul import FUSED_MAX_M
-
     B, T, D = h.shape
     x = h.reshape(B * T, D)
     at = lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False)
-    weights, idx = route(cfg, x, at(lp.moe_gate))
+    bias = getattr(lp, "moe_bias", None)
+    # a stack without a bias calls the router as its clients' tests patch it
+    weights, idx = (route(cfg, x, at(lp.moe_gate)) if bias is None
+                    else route(cfg, x, at(lp.moe_gate), at(bias)))
     local, stats = routed_pairs(cfg, idx, live)
-    if B * T <= FUSED_MAX_M:
+    if B * T <= STEP_FORM_MAX_ROWS:
         y = _experts_step(cfg, x, local, weights, m, lp)
     else:
         y, fed = _experts_chunk(cfg, x, local, weights, m, lp)

@@ -22,6 +22,8 @@ def attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
               key_start: jax.Array | int = 0) -> jax.Array:
     """Attend ``q: [B, T, n_heads, head_dim]`` over cached
     ``k/v: [B, n_kv_heads, S, head_dim]`` (head-major, see runtime.kvcache).
+    The lanes may be wider than ``head_dim`` (heads padded with zeros to
+    whole lane tiles, models/lfm2.py): ``head_dim`` is the score's scale.
 
     ``positions: [B, T]`` is the absolute position of each query row; cache
     entries at ``s <= position`` are visible (the reference's ``t <= pos`` loop
@@ -36,7 +38,7 @@ def attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     S = k_cache.shape[2]
     kv_mul = n_heads // n_kv
 
-    qg = q.reshape(B, T, n_kv, kv_mul, head_dim)
+    qg = q.reshape(B, T, n_kv, kv_mul, q.shape[-1])
     scores = jnp.einsum("btkmh,bksh->btkms", qg.astype(jnp.float32),
                         k_cache.astype(jnp.float32))
     scores = scores / jnp.sqrt(jnp.float32(head_dim))
@@ -51,4 +53,4 @@ def attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     probs = jax.nn.softmax(scores, axis=-1)
 
     out = jnp.einsum("btkms,bksh->btkmh", probs, v_cache.astype(jnp.float32))
-    return out.reshape(B, T, n_heads, head_dim).astype(q.dtype)
+    return out.reshape(B, T, n_heads, -1).astype(q.dtype)

@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .causal_conv import causal_conv  # noqa: F401  (its first home)
+
 SUB_CHUNK = 64
 L2_EPS = 1e-6
 # the mixer's small matmuls feed a float32 state that is carried over
@@ -70,29 +72,6 @@ def gates(a: jax.Array, b: jax.Array, a_log: jax.Array, dt_bias: jax.Array,
         a + dt_bias.astype(jnp.float32))
     beta = jax.nn.sigmoid(b) * (2.0 if neg_eigval else 1.0)
     return g, beta
-
-
-def causal_conv(x: jax.Array, tail: jax.Array, w: jax.Array,
-                n_valid: jax.Array | None = None,
-                bias: jax.Array | None = None):
-    """Causal depthwise convolution over time, then SiLU: ``y_t = silu(sum_j
-    w[j] x_{t-(K-1)+j} + bias)``. ``x [B, T, C]``; ``tail [B, K-1, C]`` are
-    the K-1 inputs before the chunk (zeros at a sequence's start); ``w [K,
-    C]``; ``bias [C]`` where the mixer has one (ops/ssd.py's does, the
-    gated delta rule's does not). Returns float32 ``y [B, T, C]`` and the
-    new tail: the last K-1 inputs at or before position ``n_valid`` (a
-    scalar; absent, ``T``), so padding behind a chunk's valid length never
-    enters it."""
-    K, T = w.shape[0], x.shape[1]
-    seq = jnp.concatenate([tail.astype(jnp.float32), x.astype(jnp.float32)],
-                          axis=1)
-    wf = w.astype(jnp.float32)
-    y = sum(wf[j] * seq[:, j:j + T] for j in range(K))
-    if bias is not None:
-        y = y + bias.astype(jnp.float32)
-    start = T if n_valid is None else n_valid
-    new_tail = jax.lax.dynamic_slice_in_dim(seq, start, K - 1, axis=1)
-    return jax.nn.silu(y), new_tail.astype(tail.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -237,6 +237,11 @@ class InferenceEngine:
                     "has no mesh plan yet, the share's exchange between "
                     "chips is not built)"
                     if latent else
+                    "a decoder with gated short-convolution layers and "
+                    "routed experts (a convolution's tail a conv layer in "
+                    "the state pool, routing counters beside it; the period "
+                    "scan has no mesh plan yet)"
+                    if self.cfg.has_short_conv else
                     "a decoder with an SSD mixer beside attention in every "
                     "layer (a recurrent state a layer; the layer scan "
                     "carries the state pool and has no mesh plan yet)"
@@ -655,6 +660,7 @@ class InferenceEngine:
         kinds.set(self.cfg.n_layers if self.cfg.has_latent_cache else 0,
                   kind="latent")
         kinds.set(self.cfg.n_window_layers, kind="sliding")
+        kinds.set(self.cfg.n_conv_layers, kind="conv")
         # the expert share (models/share.py): held here, of those routed
         telemetry.registry().gauge(telemetry.MOE_EXPERTS_HELD).set(
             self.cfg.n_experts)

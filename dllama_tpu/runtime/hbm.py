@@ -74,6 +74,17 @@ def matmul_weight_count(cfg) -> int:
                  + cfg.q_dim * cfg.dim + cfg.dim * cfg.ssm_in_dim
                  + cfg.ssm_inner_dim * cfg.dim + 3 * cfg.dim * cfg.hidden_dim)
         return cfg.n_layers * layer + cfg.dim * cfg.vocab_size
+    if cfg.has_short_conv:
+        # two kinds of mixer (the conv layers' in- and out-projection, or
+        # q k v wo), the leading dense feed-forward, the held experts of a
+        # routed layer with its router over its whole width
+        conv = 4 * cfg.dim * cfg.dim
+        attn = 2 * cfg.dim * (cfg.q_dim + cfg.kv_dim)
+        routed = (cfg.dim * cfg.moe_router_width
+                  + 3 * cfg.dim * cfg.hidden_dim * cfg.n_experts)
+        return (cfg.n_conv_layers * conv + cfg.n_attn_layers * attn
+                + cfg.n_dense_layers * 3 * cfg.dim * cfg.dense_hidden_dim
+                + cfg.n_moe_layers * routed + cfg.dim * cfg.vocab_size)
     if cfg.has_latent_cache:
         # what is HELD: latent attention's planes a layer (W_ukv per head in
         # the compute dtype: two Q40 weights' bytes a weight), the

@@ -445,9 +445,11 @@ def note_q40_path(path: str) -> None:
 # the recurrent mixers by the key their counts are filed under: the gauge
 # each publishes to and the name the start-up report gives it
 _MIXER_GAUGES = {"gdn": telemetry.GATED_DELTA_PATHS, "ssd": telemetry.SSD_PATHS,
-                 "mla": telemetry.MLA_PATHS}
+                 "mla": telemetry.MLA_PATHS,
+                 "conv": telemetry.SHORT_CONV_PATHS}
 _MIXER_TITLES = {"gdn": "gated delta rule", "ssd": "ssd mixer",
-                 "mla": "latent attention"}
+                 "mla": "latent attention",
+                 "conv": "gated short convolution"}
 
 
 def _note_mixer_path(kind: str, form: str, path: str) -> None:
@@ -474,6 +476,14 @@ def note_mla_path(form: str, path: str) -> None:
     and ``chunk`` each took ``pallas`` (the ``mla_paged_step`` / ``mla_chunk``
     kernel) or ``xla``."""
     _note_mixer_path("mla", form, path)
+
+
+def note_short_conv_path(form: str, path: str) -> None:
+    """:func:`note_gdn_path` for the gated short convolution
+    (``models.lfm2``): ``chunk`` and ``step`` both run ``xla`` (three taps a
+    channel over a tail of two rows: elementwise work XLA fuses into the
+    projections' neighbours; there is no kernel to choose)."""
+    _note_mixer_path("conv", form, path)
 
 
 def _monitoring_on() -> bool:
@@ -602,6 +612,25 @@ def startup_line(engine) -> str:
                  f"from {cfg.moe_first_expert}, {cfg.n_active_experts} a "
                  f"token of {cfg.moe_topk_group or 1} of "
                  f"{cfg.moe_n_group or 1} groups")
+    if cfg.has_short_conv:
+        from ..ops import paged_attention as _pa
+
+        # the step's attention at this engine's geometry: what the paged
+        # kernel's gate would say of the padded heads on a chip
+        step_q = (1, 1, cfg.n_heads, cfg.cache_width)
+        compiled = _pa.supports(
+            step_q, cfg.n_kv_heads,
+            -(-cfg.seq_len // max(1, engine.kv_block_size)),
+            max(1, engine.kv_block_size), compiled=True)
+        kinds = (f"; layers: {cfg.n_conv_layers} conv ({cfg.conv_kernel} "
+                 f"taps, a tail of {cfg.conv_kernel - 1} x {cfg.dim} a "
+                 f"sequence), {cfg.n_attn_layers} full (heads of "
+                 f"{cfg.head_dim} lanes cached in {cfg.cache_width}: the "
+                 f"paged kernel {'compiles' if compiled else 'does NOT compile'}"
+                 f" for them); experts: {cfg.n_experts} of "
+                 f"{cfg.moe_router_width} held from {cfg.moe_first_expert}, "
+                 f"{cfg.n_active_experts} a token"
+                 f"{', selection bias' if cfg.moe_select_bias else ''}")
     if cfg.has_window_layers:
         kinds = (f"; layers: {cfg.n_kv_layers} full, {cfg.n_window_layers} "
                  f"sliding (window {cfg.sliding_window}); experts: "

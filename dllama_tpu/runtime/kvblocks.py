@@ -240,7 +240,10 @@ class StatePool(NamedTuple):
     The shapes are the ARCHITECTURE's (``cfg.state_shape`` /
     ``cfg.conv_shape``): a gated delta-rule state ``[H, dk, dv]`` in a
     hybrid's linear layers (models/hybrid.py), an SSD state ``[H, P, N]`` in
-    every layer where the mixer sits beside attention (models/falcon_h1.py).
+    every layer where the mixer sits beside attention (models/falcon_h1.py),
+    or NONE where the convolution's tail is the whole state (a gated
+    short-convolution layer, models/lfm2.py: ``cfg.state_shape`` is None, ``s``
+    is None, and no float32 row is allocated, committed or counted).
     Row 0 is the NULL row, as block 0 is the null block: inactive slots
     riding along a decode step read and write it, and its contents are
     value-invisible. Slot ``i`` owns row ``i + 1``.
@@ -254,7 +257,7 @@ class StatePool(NamedTuple):
     decode step in place, and is simply left behind at retirement: the next
     admission's commit overwrites the row."""
 
-    s: "jax.Array"
+    s: "jax.Array | None"
     conv: "jax.Array"
 
     NULL = 0
@@ -263,12 +266,27 @@ class StatePool(NamedTuple):
     def create(cls, cfg, n_slots: int, conv_dtype) -> "StatePool":
         import jax.numpy as jnp
 
-        return cls(s=jnp.zeros(cfg.state_shape(n_slots + 1), jnp.float32),
+        return cls(s=_zero_state(cfg, n_slots + 1),
                    conv=jnp.zeros(cfg.conv_shape(n_slots + 1), conv_dtype))
 
     @property
     def n_bytes(self) -> int:
-        return self.s.nbytes + self.conv.nbytes
+        return state_bytes(self)
+
+
+def _zero_state(cfg, rows: int):
+    """The float32 state of ``rows`` sequences at a sequence's start, or
+    None where the architecture has none beside the tail."""
+    import jax.numpy as jnp
+
+    shape = cfg.state_shape(rows)
+    return None if shape is None else jnp.zeros(shape, jnp.float32)
+
+
+def state_bytes(state) -> int:
+    """Bytes of the state a :class:`StatePool` or :class:`StateColumn`
+    holds: the tail, and the float32 state where there is one."""
+    return state.conv.nbytes + (0 if state.s is None else state.s.nbytes)
 
 
 class StateColumn(NamedTuple):
@@ -276,13 +294,16 @@ class StateColumn(NamedTuple):
     than K/V (the ``KVCache`` of a decoder with a recurrent state): K/V of
     the layers that cache it, the state layers' state and the convolution's
     tail. What an admission carries from chunk to chunk and its commit
-    writes: K/V through the block table, ``s`` and ``conv`` to the slot's
-    row of :class:`StatePool`."""
+    writes: K/V through the block table, ``s`` (None where the tail is the
+    whole state) and ``conv`` to the slot's row of :class:`StatePool`, and,
+    where the decoder also routes over experts (models/lfm2.py), the chunks'
+    routing counters ``stats`` into the generator's running totals."""
 
-    k: "jax.Array"      # [n_kv_layers, B, n_kv, S, hd]
+    k: "jax.Array"      # [n_kv_layers, B, n_kv, S, cache_width]
     v: "jax.Array"
-    s: "jax.Array"      # cfg.state_shape(B), float32
+    s: "jax.Array | None"   # cfg.state_shape(B), float32
     conv: "jax.Array"   # cfg.conv_shape(B)
+    stats: "jax.Array | None" = None   # models/share.zero_stats
 
     @classmethod
     def zeros(cls, cfg, k: "jax.Array", v: "jax.Array",
@@ -291,8 +312,13 @@ class StateColumn(NamedTuple):
         import jax.numpy as jnp
 
         B = k.shape[1]
-        return cls(k=k, v=v, s=jnp.zeros(cfg.state_shape(B), jnp.float32),
-                   conv=jnp.zeros(cfg.conv_shape(B), conv_dtype))
+        stats = None
+        if cfg.has_expert_share:
+            from ..models.share import zero_stats
+
+            stats = zero_stats(cfg)
+        return cls(k=k, v=v, s=_zero_state(cfg, B),
+                   conv=jnp.zeros(cfg.conv_shape(B), conv_dtype), stats=stats)
 
 
 def state_pool_bytes(cfg, n_slots: int, conv_dtype_bytes: int) -> int:
@@ -302,7 +328,8 @@ def state_pool_bytes(cfg, n_slots: int, conv_dtype_bytes: int) -> int:
         return 0
     import math
 
-    return (math.prod(cfg.state_shape(n_slots + 1)) * 4
+    shape = cfg.state_shape(n_slots + 1)
+    return ((0 if shape is None else math.prod(shape) * 4)
             + math.prod(cfg.conv_shape(n_slots + 1)) * conv_dtype_bytes)
 
 

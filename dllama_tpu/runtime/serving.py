@@ -54,8 +54,10 @@ from ..parallel.multihost import (
     CTRL_SRV_VERIFY,
 )
 from ..tokenizer.sampler import xorshift_random_f32
+from ..models.share import N_COUNTS
 from .kvblocks import (SPILL_BATCH, BlockPoolExhausted, PageInError,
-                       StateColumn, window_blocks_cap, window_first_block)
+                       StateColumn, state_bytes, window_blocks_cap,
+                       window_first_block)
 from .kvcache import KVCache
 
 if TYPE_CHECKING:
@@ -1551,6 +1553,15 @@ class PagedGenerator(_GeneratorCore):
 
             self.moe_stats = zero_totals(self.cfg)
             self._moe_seen = np.zeros(self.moe_stats.shape, np.int64)
+        # what the step's cache is made of, in the order every step program
+        # takes it and gives it back (models/*.paged_forward): ONE
+        # description of what the architecture carries, so that a decoder
+        # with a state pool AND routing counters is no case of its own
+        self._cache_parts = tuple(
+            name for name, has in (
+                ("pkv", True), ("wkv", bool(self.window)),
+                ("spool", self.spool is not None),
+                ("moe_stats", self.moe_stats is not None)) if has)
         if self.window:
             self.wpool = BlockPool(n_wblocks, block_size)
             wshape = (self.cfg.n_window_layers, n_wblocks,
@@ -1663,8 +1674,14 @@ class PagedGenerator(_GeneratorCore):
 
         def _state_put_fn(spool, s, conv, row):
             put = jax.lax.dynamic_update_index_in_dim
-            return StatePool(s=put(spool.s, s[:, 0], row, 1),
+            # where the tail is the whole state there is no ``s`` to write
+            return StatePool(s=(None if s is None
+                                else put(spool.s, s[:, 0], row, 1)),
                              conv=put(spool.conv, conv[:, 0], row, 1))
+
+        def _add_chunk_stats_fn(totals, stats):
+            # an admission's routing counters into the totals' chunk row
+            return totals.at[1].add(stats)
 
         def _put_fn(pkv, col, table):
             return PagedKVCache(k=back(pkv.k, col.k, table),
@@ -1696,6 +1713,8 @@ class PagedGenerator(_GeneratorCore):
         # a recurrent state's commit writes the admission's state to the
         # slot's row of the state pool, in place
         self._state_put = jax.jit(_state_put_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
+        self._add_chunk_stats = jax.jit(_add_chunk_stats_fn,  # dlint: disable=jit-entry
+                                        donate_argnums=(0,))
         self._put = jax.jit(_put_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
         self._copy_block = jax.jit(_copy_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
         # KV migration wire (runtime/kvwire): export gathers one block at
@@ -2361,7 +2380,7 @@ class PagedGenerator(_GeneratorCore):
             if span.traced:
                 span.set(rid=adm.req.rid)
             if self.spool is not None and not adm.req.score:
-                span.set(state_bytes=adm.col.s.nbytes + adm.col.conv.nbytes)
+                span.set(state_bytes=state_bytes(adm.col))
             if self.wpool is not None:
                 span.set(window_blocks=len(self._wbids[adm.slot]))
             if self.latent and adm.col is not None:
@@ -2454,6 +2473,9 @@ class PagedGenerator(_GeneratorCore):
             # slot's row (the previous occupant's state goes with it)
             self.spool = self._state_put(self.spool, adm.col.s, adm.col.conv,
                                          jnp.int32(slot + 1))
+            if adm.col.stats is not None:
+                self.moe_stats = self._add_chunk_stats(self.moe_stats,
+                                                       adm.col.stats)
         self.pool.register_prompt(bids, rest)
         # the table goes live only NOW, with the committed pos riding in
         # _arm_decode — no dispatch ever sees this slot's real table
@@ -2644,33 +2666,26 @@ class PagedGenerator(_GeneratorCore):
         t0 = time.perf_counter()
         with self._step_io("batch_step") as io:
             wait = io.span
-            # with a recurrent state the step takes the state pool beside the
-            # blocks, both donated, and gives both back
-            cache = (self.pkv if self.spool is None
-                     else (self.pkv, self.spool))
-            tables = self.tables
-            if self.wpool is not None:
-                # two pools and the running counters in, all three back
-                cache = (self.pkv, self.wkv, self.moe_stats)
-                tables = self._both_tables
-            elif self.latent:
-                cache = (self.pkv, self.moe_stats)
-                # blocks this step's latent walk reads, over the live rows
-                # (ops/mla.py walks ceil((pos + 1) / block_size) entries)
-                walk_blocks = int(sum(
-                    -(-(int(self.pos[i]) + 1) // self.block_size)
-                    for i in active))
+            # the step takes what the architecture carries (_cache_parts:
+            # the blocks, then a window pool, a state pool, the routing
+            # counters), all donated, and gives all of it back
+            parts = self._cache_parts
+            cache = tuple(getattr(self, name) for name in parts)
+            tables = (self.tables if self.wpool is None
+                      else self._both_tables)
+            # blocks this step's walk over the cache reads, over the live
+            # rows (ops/paged_attention.py and ops/mla.py both walk
+            # ceil((pos + 1) / block_size) entries a row)
+            walk_blocks = int(sum(
+                -(-(int(self.pos[i]) + 1) // self.block_size)
+                for i in active))
             (nxt, nf), cache = io.call(
-                self._step, cache, self.next_token.astype(np.int32)[:, None],
+                self._step, cache if len(parts) > 1 else cache[0],
+                self.next_token.astype(np.int32)[:, None],
                 self.pos.astype(np.int32), tables, temps, topps, coins)
-            if self.wpool is not None:
-                self.pkv, self.wkv, self.moe_stats = cache
-            elif self.latent:
-                self.pkv, self.moe_stats = cache
-            elif self.spool is None:
-                self.pkv = cache
-            else:
-                self.pkv, self.spool = cache
+            for name, part in zip(parts, cache if len(parts) > 1
+                                  else (cache,)):
+                setattr(self, name, part)
             if self.moe_stats is None:
                 nxt, nf = io.fetch(tokens=nxt, nonfinite=nf)
             else:
@@ -2681,6 +2696,8 @@ class PagedGenerator(_GeneratorCore):
                 self._note_moe(totals, wait)
             if self.latent:
                 wait.set(mla_walk_blocks=walk_blocks)
+            else:
+                wait.set(kv_walk_blocks=walk_blocks)
             if wait.traced:
                 # running totals, as the routing counters': a reader of a
                 # traced slice takes last less first
@@ -2715,7 +2732,9 @@ class PagedGenerator(_GeneratorCore):
         running totals, so that a reader of a traced slice takes what the
         slice added and not what the process has counted since it started
         (``moe_held`` / ``moe_absent``, ``moe_tokens`` a held expert joined
-        by ``/``, ``moe_chunk_held`` / ``moe_chunk_fed`` the chunks' own
+        by ``/``, ``moe_step_held`` / ``moe_planes`` the steps' own pairs
+        and the distinct held experts their layers chose: pairs over planes
+        is how often the decode kernel, a plane a pair, reads a plane, ``moe_chunk_held`` / ``moe_chunk_fed`` the chunks' own
         pairs and the rows they fed, ``wblocks_allocated`` /
         ``wblocks_returned``)."""
         delta = (totals.astype(np.int64) - self._moe_seen) % (1 << 32)
@@ -2727,8 +2746,9 @@ class PagedGenerator(_GeneratorCore):
             self._m_moe_pairs.inc(int(both[1]), where="absent")
         if both[2]:
             self._m_moe_fed.inc(int(both[2]))
-        for e in np.nonzero(both[3:])[0]:
-            self._m_moe_tokens.inc(int(both[3 + e]), expert=str(int(e)))
+        for e in np.nonzero(both[N_COUNTS:])[0]:
+            self._m_moe_tokens.inc(int(both[N_COUNTS + e]),
+                                   expert=str(int(e)))
         wait.set(moe_pairs=int(delta[0, 0]))
         if wait.traced:
             pairs, tokens = self._m_moe_pairs, self._m_moe_tokens
@@ -2737,6 +2757,8 @@ class PagedGenerator(_GeneratorCore):
                      moe_tokens="/".join(
                          str(int(tokens.total(expert=str(e))))
                          for e in range(self.cfg.n_experts)),
+                     moe_step_held=int(self._moe_seen[0, 0]),
+                     moe_planes=int(self._moe_seen[0, 3]),
                      moe_chunk_held=int(self._moe_seen[1, 0]),
                      moe_chunk_fed=int(self._moe_seen[1, 2]),
                      wblocks_allocated=int(self._m_wblocks_alloc.total()),

@@ -192,6 +192,7 @@ Q40_MATMUL_PATHS = "dllama_q40_matmul_paths"
 GATED_DELTA_PATHS = "dllama_gated_delta_paths"
 SSD_PATHS = "dllama_ssd_paths"
 MLA_PATHS = "dllama_mla_paths"
+SHORT_CONV_PATHS = "dllama_short_conv_paths"
 LAYER_KINDS = "dllama_layer_kinds"
 STATE_SLOTS_USED = "dllama_state_slots_used"
 STATE_SLOTS_TOTAL = "dllama_state_slots_total"
@@ -477,13 +478,19 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "prefill chunk over a latent column) and the path they took: "
           "pallas (the mla_paged_step and mla_chunk kernels) or xla; both "
           "forms are absorbed"),
+    _spec(SHORT_CONV_PATHS, "gauge",
+          "Gated short-convolution mixers of a program's newest trace by "
+          "form (chunk: a prefill chunk over a column's tails; step: the "
+          "decode step over the tail pool in place) and the path they took: "
+          "xla (there is no kernel: three taps a channel)"),
     _spec(LAYER_KINDS, "gauge",
           "Layers of the loaded model by kind (linear: gated delta-rule "
           "layers with a recurrent state; full: softmax attention with a "
           "K/V cache; ssm_beside_full: an SSD mixer with a recurrent state "
           "and softmax attention side by side in one layer; latent: "
-          "latent attention over one compressed cache row a token); a "
-          "dense decoder is all full"),
+          "latent attention over one compressed cache row a token; conv: "
+          "gated short-convolution layers whose state is the convolution's "
+          "tail); a dense decoder is all full"),
     _spec(STATE_SLOTS_USED, "gauge",
           "Rows of the recurrent state pool held by live sequences "
           "(committed and not yet retired); 0 without recurrent layers"),

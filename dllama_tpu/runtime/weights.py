@@ -639,6 +639,66 @@ def _load_axk1_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
                          if dense_logits_wanted(ld.fast_numerics) else None)))
 
 
+def _load_lfm2_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
+    """A decoder of gated short-convolution and attention layers with routed
+    experts (models/lfm2.py) from the tensors ``mfile._walk_lfm2_layer``
+    names: two mixer stacks by layer kind, the leading layers' dense
+    feed-forward, the routed layers' router, selection bias and HELD
+    experts, each stacked over its own layers of the model."""
+    from ..models.lfm2 import AttnParams, ConvParams, Lfm2Layers
+    from ..models.llama import Params
+
+    h = ld.h
+    every = list(range(h.n_layers))
+    attn_ids = [l for l in every if h.lfm2_is_attn(l)]
+    conv_ids = [l for l in every if not h.lfm2_is_attn(l)]
+    dense_ids, moe_ids = every[:h.n_dense_layers], every[h.n_dense_layers:]
+    mm = lambda ids, name, o, i: ld.matmul(
+        name, o, i, stacked=True, out_axis=None, in_axis=None, layers=ids)
+    wide = h.dense_hidden_dim
+    experts = lambda name, o, i: ld.expert_stack(name, o, i, None, None,
+                                                 layers=moe_ids)
+    layers = Lfm2Layers(
+        conv=ConvParams(
+            w_in=mm(conv_ids, "block_conv_in", 3 * h.dim, h.dim),
+            conv_w=ld.stacked_f32("block_conv_taps", h.short_conv_kernel,
+                                  h.dim, layers=conv_ids),
+            w_out=mm(conv_ids, "block_conv_out", h.dim, h.dim),
+            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=conv_ids)),
+        attn=AttnParams(
+            wq=mm(attn_ids, "block_matmul_q", h.q_dim, h.dim),
+            wk=mm(attn_ids, "block_matmul_k", h.kv_dim, h.dim),
+            wv=mm(attn_ids, "block_matmul_v", h.kv_dim, h.dim),
+            wo=mm(attn_ids, "block_matmul_wo", h.dim, h.q_dim),
+            norm_q=ld.stacked_f32("block_norm_q", h.head_dim,
+                                  layers=attn_ids),
+            norm_k=ld.stacked_f32("block_norm_k", h.head_dim,
+                                  layers=attn_ids),
+            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=attn_ids)),
+        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
+        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim),
+        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide),
+        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim),
+        moe_gate=ld.stacked_f32("block_moe_gate", h.moe_router_width, h.dim,
+                                layers=moe_ids),
+        moe_bias=(ld.stacked_f32("block_moe_bias", h.moe_router_width,
+                                 layers=moe_ids)
+                  if h.moe_select_bias else None),
+        we1=experts("block_expert_w1", h.hidden_dim, h.dim),
+        we2=experts("block_expert_w2", h.dim, h.hidden_dim),
+        we3=experts("block_expert_w3", h.hidden_dim, h.dim))
+    return Params(
+        embedding=ld.f32("embedding", h.vocab_size, h.dim,
+                         dtype=jnp.dtype(cfg.compute_dtype)),
+        layers=layers,
+        final_norm=ld.f32("final_norm", h.dim),
+        logits=ld.matmul(
+            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
+            out_axis="vocab", in_axis=None,
+            force_dense=(jnp.bfloat16
+                         if dense_logits_wanted(ld.fast_numerics) else None)))
+
+
 def _load_laguna_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
     """A decoder of window and full attention layers with an expert share
     (models/laguna.py) from the tensors ``mfile._walk_laguna_layer`` names:
@@ -719,14 +779,15 @@ def load_params(mf: ModelFile, cfg: "ModelConfig", weight_mode: str = "auto",
         return _load_hybrid_params(ld, cfg)
     if h.arch_type == ArchType.FALCON_H1:
         return _load_falcon_h1_params(ld, cfg)
-    if h.arch_type in (ArchType.LAGUNA, ArchType.AXK1):
+    if h.arch_type in (ArchType.LAGUNA, ArchType.AXK1, ArchType.LFM2):
         if not ld.quantized:
             raise ValueError(
                 f"a {h.arch_type.name} file's matmul planes must be Q40 or "
                 f"Q80: the routed decode kernel (ops/expert_gemv.py) and its "
                 f"XLA form read quantized expert stacks")
-        return (_load_laguna_params if h.arch_type == ArchType.LAGUNA
-                else _load_axk1_params)(ld, cfg)
+        return {ArchType.LAGUNA: _load_laguna_params,
+                ArchType.AXK1: _load_axk1_params,
+                ArchType.LFM2: _load_lfm2_params}[h.arch_type](ld, cfg)
 
     # Under offload only the per-layer stacks go host-side: they are the
     # O(model) bytes and stream through the scan; embedding / final norm /

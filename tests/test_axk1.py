@@ -275,7 +275,7 @@ def test_whole_forward_logits(bench, engine, T):
     want = _reference_logits(bench, engine.params, tokens)
     assert float(np.abs(np.asarray(logits[0]) - want).max()) < LOGIT_TOL
     stats = np.asarray(col.stats)
-    assert stats[0] + stats[1] == T * 4 * 3 and stats[3:].sum() == stats[0]          # every pair counted once
+    assert stats[0] + stats[1] == T * 4 * 3 and stats[4:].sum() == stats[0]          # every pair counted once
     assert not np.asarray(col.c[..., 40:]).any() and np.asarray(col.c[:, 0, 0, :T, :40]).all(axis=-1).all()
 
 
@@ -541,7 +541,7 @@ def test_a_chunk_of_the_windowed_decoder_takes_the_same_form(monkeypatch):
     monkeypatch.setattr(share, "_experts_chunk", lambda cfg, x, *a: seen.append(("chunk", x.shape[0])) or (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
     monkeypatch.setattr(share, "_experts_step", lambda cfg, x, *a: seen.append(("step", x.shape[0])) or jnp.zeros(x.shape, jnp.float32))
     cfg = types.SimpleNamespace(n_experts=4, moe_first_expert=0, n_active_experts=2, moe_n_group=0, moe_score="softmax",
-                                moe_norm_topk=True, moe_routed_scale=1.0)
+                                moe_norm_topk=True, moe_routed_scale=1.0, moe_norm_eps=0.0)
     lp = types.SimpleNamespace(moe_gate=jnp.ones((1, 8, 16), jnp.float32), ws1=None)
     for rows in (FUSED_MAX_M, FUSED_MAX_M + 1, 256):
         share.routed_ffn(cfg, jnp.ones((1, rows, 16), jnp.float32), lp, jnp.int32(0), jnp.ones((rows,), bool))

@@ -35,7 +35,12 @@ NEW_CELLS = ("olmo-hybrid-7b.long-prompt", "mistral-7b-v0.3.single-stream")
 PR34_CELLS = ("laguna-s-2.1.mixed-queue", "mistral-7b-v0.3.mixed-queue")     # the same three cases, for the same reason
 PR36_CELL = "falcon-h1-34b.chat"     # one cell, so two of the three cases
 PR42_CELL = "a.x-k1.agent-sessions"  # one cell again
+PR44_CELL = "lfm2-24b-a2b.batch-generate"  # and again
 KNOWN_RED = {
+    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR44_CELL}]":
+        "selftest/counts_frozen.json has no rows for the cell PR 44 added",
+    f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{PR44_CELL}]":
+        "lfm2-24b-a2b names its modules: the test asserts that no configuration of BENCHMARK.json does",
     f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR42_CELL}]":
         "selftest/counts_frozen.json has no rows for the cell PR 42 added",
     f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{PR42_CELL}]":
@@ -249,3 +254,39 @@ def test_benchmark_json_keeps_the_form_the_driver_refuses_a_file_for(section):
         assert re.fullmatch(r"[A-Za-z0-9_/%.\-]{1,16}", e.get("unit", "ms")), e
         assert e.get("better", "lower") in ("lower", "higher") and e.get("chips", 1) in (1, 4) and len(e.get("reduced", ())) <= 16
         assert re.fullmatch(r"benchmark/[A-Za-z0-9_.\-/]+", e.get("file", "benchmark/x")), e
+
+
+# -- and PR 44's cell, from a file of PR 44's own -----------------------------------
+
+with open(os.path.join(BENCH, "lfm2", "selftest", "counts_frozen.json"), encoding="utf-8") as _f:
+    PR44_FROZEN = json.load(_f)
+
+
+def test_pr44s_cell_counts_through_the_seam_are_what_pr44_froze():
+    _cell, conf, _traffic, mods = _seam._resolve(PR44_CELL)
+    rows = [r for r in PR44_FROZEN["rows"] if r["cell"] == PR44_CELL]
+    assert len(rows) == 24
+    for r in rows:
+        assert getattr(mods["counts"], r["fn"])(conf["model"], **r["args"]) == r["value"], r
+
+
+def test_the_short_conv_cell_gets_the_modules_it_names_and_its_traffic_is_the_issues():
+    cell, conf, traffic_path, mods = _seam._resolve(PR44_CELL)
+    assert {k: os.path.relpath(m.__file__, BENCH) for k, m in mods.items()} == conf["modules"] == {
+        "reference": "lfm2/reference.py", "weights": "lfm2/weights.py", "counts": "lfm2/counts.py"}
+    assert {"nobias", "biasweight", "convsilu", "notail", "noqknorm", "bf16router", "shift", "droplayer", "dropblock"} \
+        <= set(mods["reference"].CONTROLS)
+    counts = mods["counts"]
+    assert counts.kernel_counts(conf["model"], "paged_ragged_attention", rows=32)["calls_per_program"] == 4
+    assert counts.kernel_counts(conf["model"], "expert_chunk", rows=32)["layers"] == 16
+    assert conf["reduced"] == ["num_hidden_layers", "layer_types", "max_position_embeddings"]
+    assert conf["model"]["norm_epsilon"] == conf["norm_eps"] == 1e-05
+    assert (cell["chips"], cell["traffic"], len(cell["why"]) <= 200) == (1, "batch-generate-lfm2", True)
+    import traffic
+    with open(traffic_path, encoding="utf-8") as f:
+        mix = json.load(f)
+    plans = [traffic.plan(mix, seed=seed, seconds=45.0, vocab_size=conf["model"]["vocab_size"]) for seed in (3, 2 ** 31 + 5)]
+    sizes = [[(len(r.new_tokens), r.max_tokens) for r in p.requests] for p in plans]
+    assert sizes[0] == sizes[1] and plans[0].max_context <= 896 <= conf["engine"]["max_seq_len"]      # the seed moves no size
+    assert all(64 <= n <= 256 and 256 <= m <= 640 for n, m in sizes[0]) and plans[0].clients == 32
+    assert max(t for r in plans[1].requests for t in r.new_tokens) > 60000               # ids from the whole vocabulary

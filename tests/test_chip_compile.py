@@ -528,6 +528,89 @@ def test_mla_chunk_compiles_for_v5e(one_chip, T):
     assert any("mla_chunk" in name for name in kernels), kernels
 
 
+# LiquidAI/LFM2-24B-A2B (PR 44): 2048 wide, 32:8 heads of 64 lanes cached in 128, experts 2048 x 1536, 64 held
+LFM2_STEP = [(2048, 6144), (2048, 2048), (2048, 11776), (11776, 2048), (2048, 512)]
+
+
+def test_paged_attention_compiles_at_64_lane_heads_padded_for_v5e(one_chip):
+    """lfm2-24b-a2b's step: 32 slots of 1024, 32:8 heads whose 64 lanes the
+    pool holds in 128 (``cfg.cache_width``): the kernel that the 128-lane
+    cells compile, every K/V head a grid step, the score's scale the head's own
+    64. At 64 lanes the compiled gate still says no: the padding is what
+    takes the path off the gather + oracle."""
+    from dllama_tpu.ops.paged_attention import supports
+
+    assert not supports((32, 1, 32, 64), 8, 64, 16, compiled=True)
+    heads, group = _compile_paged_attention(one_chip, 32, 1, 32, 8, 128, 64, 16, jnp.bfloat16)
+    assert (heads, group * 16) == (8, 128)
+
+
+@pytest.mark.parametrize("rows", [32, 64, 256])
+@pytest.mark.parametrize("k,n", LFM2_STEP)
+def test_chunk_kernel_compiles_at_lfm2s_planes_for_v5e(one_chip, k, n, rows):
+    """lfm2-24b-a2b's Q40 planes (the conv mixer's 6144-wide in-projection,
+    its square out-projection, the dense feed-forward at 11776, a K/V
+    projection) in the chunk regime, which is ALSO its 32-row step's: stack +
+    index, one Mosaic kernel."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+    from dllama_tpu.ops.quant_matmul import fused_path, quant_matmul
+
+    stack = QuantizedWeight(scales=_shape(one_chip, (4, k // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (4, k, n), jnp.int8))
+    one = QuantizedWeight(*(jax.ShapeDtypeStruct(p.shape[1:], p.dtype) for p in stack))
+    x = _shape(one_chip, (1, rows, k), jnp.bfloat16)
+    assert fused_path(x.shape, one, True) == "chunk"
+    kernels = _compiled_kernels(
+        lambda x, w, l: quant_matmul(x, w, interpret=False, fast=True, fused=True, layer=l),
+        x, stack, _shape(one_chip, (), jnp.int32))
+    assert kernels.get("quant_matmul") == 1, kernels
+
+
+@pytest.mark.parametrize("kk,n,rows,scatter", [(2048, 1536, 32, False), (1536, 2048, 32, True),
+                                               (2048, 1536, 256, False), (1536, 2048, 256, True)])
+def test_expert_chunk_compiles_at_lfm2s_experts_for_v5e(one_chip, kk, n, rows, scatter):
+    """The grouped routed kernel at lfm2-24b-a2b's expert (2048 x 1536, the
+    size of laguna's and not its shape), 16 layers of 64 held of 64, 4 a
+    token: the 32-row STEP's form (128 pairs over up to 64 runs) and a chunk's."""
+    from dllama_tpu.ops import expert_chunk as ec
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    held, k = 64, 4
+    pairs = rows * k
+    fed = ec.fed_rows(pairs, held)
+    assert ec.stripe(rows, fed, kk, n, True, scatter) is not None
+    stack = QuantizedWeight(scales=_shape(one_chip, (16, held, kk // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (16, held, kk, n), jnp.int8))
+    i32 = lambda *shape: _shape(one_chip, shape, jnp.int32)
+    runs = (i32(),) + tuple(i32(held) for _ in range(4))
+    if scatter:
+        kernels = _compiled_kernels(
+            lambda x, st, layer, runs, r, at, w: ec.expert_chunk(x, st, layer, runs, r, (at, w), rows_out=rows, fast=True),
+            _shape(one_chip, (fed, kk), jnp.bfloat16), stack, i32(), runs, i32(pairs), i32(pairs), _shape(one_chip, (rows, k), jnp.float32))
+    else:
+        kernels = _compiled_kernels(
+            lambda x, st, layer, runs, r: ec.expert_chunk(x, st, layer, runs, r, rows_out=fed, fast=True),
+            _shape(one_chip, (rows, kk), jnp.bfloat16), stack, i32(), runs, i32(pairs))
+    assert any("expert_chunk" in name for name in kernels), kernels
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)])
+def test_expert_gemv_compiles_at_lfm2s_experts_for_v5e(one_chip, k, n):
+    """The decode form at the same planes, 64 pairs (16 rows x 4): what a
+    generator of up to 16 slots would run (``share.STEP_FORM_MAX_ROWS``)."""
+    from dllama_tpu.ops import expert_gemv as eg
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    assert eg.supports(64, k, n, True)
+    stack = QuantizedWeight(scales=_shape(one_chip, (16, 64, k // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (16, 64, k, n), jnp.int8))
+    kernels = _compiled_kernels(
+        lambda x, st, layer, experts, n_pairs: eg.expert_gemv(x, st, layer, experts, n_pairs, fast=True),
+        _shape(one_chip, (64, k), jnp.bfloat16), stack, _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (64,), jnp.int32), _shape(one_chip, (), jnp.int32))
+    assert any("expert_gemv" in name for name in kernels), kernels
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_chip_smoke_rehearsal_on_cpu(chips):
     """The script end to end at a toy size with JAX_PLATFORMS=cpu children

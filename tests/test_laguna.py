@@ -174,7 +174,7 @@ def test_whole_forward_logits(bench, engine, T):
     want = _reference_logits(bench, engine.params, tokens)
     assert float(np.abs(np.asarray(logits[0]) - want).max()) < LOGIT_TOL
     stats = np.asarray(col.stats)          # one chunk: a column carries its own counters, one row
-    assert stats[0] + stats[1] == T * 4 * 7 and stats[3:].sum() == stats[0]      # every pair counted once
+    assert stats[0] + stats[1] == T * 4 * 7 and stats[4:].sum() == stats[0]      # every pair counted once
     assert 0.35 < stats[0] / (T * 4 * 7) < 0.65                                   # half the experts are held
 
 
