@@ -156,7 +156,11 @@ def plan_scoped_jit(fun, *, program: str | None = None,
     event — program name (default: the function's ``__name__``), ``scope``
     (the owning engine's namespace; retrace steadiness is per scope) — at
     two thread-local writes per call (compiles are detected via
-    jax.monitoring events; the pjit cache size is NOT a compile signal)."""
+    jax.monitoring events; the pjit cache size is NOT a compile signal).
+    It is also where the program store (runtime/program_store) stands: with
+    the persistent cache enabled and no mesh plan, the proxy loads each
+    specialization's executable from the store instead of tracing it, so
+    it is told the function and the jit options the store's key holds."""
     import functools
 
     from ..runtime.introspection import observe
@@ -167,7 +171,8 @@ def plan_scoped_jit(fun, *, program: str | None = None,
 
     return observe(jax.jit(_plan_scoped, **jit_kwargs),
                    scope=scope or "default",
-                   program=program or getattr(fun, "__name__", "jit"))
+                   program=program or getattr(fun, "__name__", "jit"),
+                   fun=fun, options=jit_kwargs)
 
 
 def constrain(x: jax.Array, *logical_axes: str | None) -> jax.Array:

@@ -20,7 +20,10 @@ or why — each was diagnosed by hand. This module is that record:
   (``dllama_program_hbm_bytes{program,kind}``) and ``cost_analysis()``
   FLOPs (``dllama_program_flops``) — a second backend compile of identical
   HLO, absorbed by the persistent compile cache, so it is on by default
-  only in api serving mode.
+  only in api serving mode. With the persistent cache enabled the proxy is
+  also the program store's client (runtime/program_store): a warm start
+  LOADS each specialization's executable instead of tracing it, and the
+  ledger's events say which (``source``: ``store`` | ``trace``).
 * **Retrace sentinel** — once an engine scope is marked steady (the batch
   scheduler does this after two compile-quiet ticks; single-sequence mode
   after one compile-quiet completion), any further compile in that scope is
@@ -39,13 +42,15 @@ telemetry lint tooling can import it without a backend.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import re
 import threading
 import time
 from collections import deque
 
-from . import telemetry
+from .. import compile_cache
+from . import program_store, telemetry
 
 # a broken analysis pass must never break the dispatch it rode in on; cap
 # the WARN spam one misbehaving program can emit
@@ -309,11 +314,14 @@ class CompileLedger:
 
     def record(self, entry: dict, compile_s: float, signature: dict,
                plan: str, analysis: dict | None, *,
-               backend_s: float = 0.0) -> None:
-        """File one trace+compile event. ``compile_s`` is the observed call
-        wall time (trace + compile + first execution); ``backend_s`` the XLA
-        backend portion (0 when the persistent compile cache served the
-        executable — the retrace still cost the trace)."""
+               backend_s: float = 0.0, source: str = "trace") -> None:
+        """File one event of a program coming into being. ``source`` says
+        how: ``trace`` (traced, lowered and compiled; ``compile_s`` is the
+        observed wall time, the first execution included when the jitted
+        call itself did it; ``backend_s`` the XLA backend portion, the
+        retrieval alone when the persistent compile cache served the
+        executable: the retrace still cost the trace) or ``store`` (deserialized from the program
+        store, runtime/program_store: ``compile_s`` is the load alone)."""
         scope, program = entry["scope"], entry["program"]
         reg = telemetry.registry()
         with self._lock:
@@ -337,7 +345,7 @@ class CompileLedger:
             self._events.append({
                 "seq": self._seq, "time": time.time(), "scope": scope,
                 "program": program, "compile_s": round(compile_s, 6),
-                "backend_s": round(backend_s, 6),
+                "backend_s": round(backend_s, 6), "source": source,
                 "plan": plan, "n_leaves": len(signature),
                 "unexpected": unexpected, "diff": diff,
                 "analysis": analysis,
@@ -348,11 +356,18 @@ class CompileLedger:
         reg.counter(telemetry.COMPILE_TOTAL).inc(scope=scope,
                                                  program=program)
         reg.histogram(telemetry.COMPILE_SECONDS).record(compile_s)
+        loaded = source == "store"
+        reg.counter(telemetry.PROGRAMS_LOADED if loaded
+                    else telemetry.PROGRAMS_TRACED).inc()
+        reg.counter(telemetry.PROGRAM_LOAD_SECONDS if loaded
+                    else telemetry.PROGRAM_TRACE_SECONDS).inc(compile_s)
         if unexpected:
             reg.counter(telemetry.RETRACE_UNEXPECTED).inc(program=program)
         if warn:
             lines = "\n".join(f"      {d}" for d in (diff or []))
-            print(f"⚠️ unexpected recompile after steady state: "
+            print(f"⚠️ unexpected "
+                  f"{'program load' if loaded else 'recompile'} after "
+                  f"steady state: "
                   f"{scope}/{program} took {compile_s * 1e3:.0f} ms "
                   f"(plan {plan})\n{lines}", flush=True)
 
@@ -409,6 +424,9 @@ def ledger() -> CompileLedger:
 _tls = threading.local()
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+# the XLA persistent cache served a compile request (a plain event, no
+# duration): what the program store asks before it serializes on the CPU
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _monitoring_state: list = []  # [] = untried, [True] = on, [False] = absent
 
 
@@ -421,6 +439,12 @@ def _event_listener(name: str, duration_s: float, **_kw) -> None:
         win["n_backend"] += 1
     elif name == _TRACE_EVENT:
         win["n_trace"] += 1
+
+
+def _plain_event_listener(name: str, **_kw) -> None:
+    win = getattr(_tls, "window", None)
+    if win is not None and name == _CACHE_HIT_EVENT:
+        win["n_cache_hit"] += 1
 
 
 # "chunk" first and "grouped" (the routed chunk kernel, ops.expert_chunk:
@@ -492,6 +516,7 @@ def _monitoring_on() -> bool:
             from jax import monitoring
 
             monitoring.register_event_duration_secs_listener(_event_listener)
+            monitoring.register_event_listener(_plain_event_listener)
             _monitoring_state.append(True)
         except Exception:  # noqa: BLE001 — degrade to pass-through, no ledger
             _monitoring_state.append(False)
@@ -501,7 +526,24 @@ def _monitoring_on() -> bool:
 def _new_window() -> dict:
     """What one traced call gathers on its thread: the monitoring events'
     counts, and (``q40``, once a matmul is noted) the Q40 path counts."""
-    return {"backend_s": 0.0, "n_backend": 0, "n_trace": 0}
+    return {"backend_s": 0.0, "n_backend": 0, "n_trace": 0, "n_cache_hit": 0}
+
+
+@contextlib.contextmanager
+def _thread_window():
+    """A fresh window for what this thread traces or compiles inside the
+    block, the enclosing one (a dispatch's own) put back after it. The
+    dispatch path itself does this inline: it is the hot one."""
+    prev = getattr(_tls, "window", None)
+    win = _tls.window = _new_window()
+    try:
+        yield win
+    finally:
+        _tls.window = prev
+
+
+# a specialization the program store does not serve: the jit path takes it
+_ASIDE = object()
 
 
 class ObservedJit:
@@ -509,16 +551,45 @@ class ObservedJit:
     compile ledger. Hit path: two thread-local writes. Compile path (a
     retrace/compile just happened — already 100 ms+): build the leaf
     signature, optionally AOT-relower for memory/cost analysis, record.
-    AOT attributes (``lower``, ``eval_shape``, ...) delegate."""
+    AOT attributes (``lower``, ``eval_shape``, ...) delegate.
 
-    def __init__(self, jitted, scope: str, program: str):
+    With the persistent cache enabled (``compile_cache.programs_dir()``) and
+    the jit's function and options known, it is also the program store's
+    client (runtime/program_store): the first call with a signature loads
+    that specialization's executable from the store, or lowers and compiles
+    it once and files it there, and every later call with the signature goes
+    to that executable. Hit path then: one dictionary lookup on the static
+    arguments and the top-level arrays' shapes (never a walk of a tree) and
+    the call into the ``jax.stages.Compiled``. The store stands aside under
+    a mesh plan or several processes, for keyword arguments and for anything
+    its key cannot hold; an executable that refuses its arguments drops out
+    and the call falls back to the jit, as a signature never stored does."""
+
+    def __init__(self, jitted, scope: str, program: str, *, fun=None,
+                 options: dict | None = None):
         self._jitted = jitted
         self.scope = scope
         self.program = program
         self._observed = _monitoring_on()
         self._entry = _ledger.register(scope, program)
+        self._fun = fun
+        self._options = dict(options or {})
+        # the store's key holds these two options and no other
+        self._storable = (fun is not None and options is not None and
+                          set(options) <= {"static_argnums", "donate_argnums"})
+        static = self._options.get("static_argnums", ())
+        self._static = frozenset((static,) if isinstance(static, int)
+                                 else static)
+        self._programs: dict[tuple, object] = {}
+        self._programs_lock = threading.Lock()
 
     def __call__(self, *args, **kwargs):
+        if self._storable and not kwargs:
+            directory = compile_cache.programs_dir()
+            if directory is not None:
+                out = self._call_stored(directory, args)
+                if out is not _ASIDE:
+                    return out
         if not self._observed:
             return self._jitted(*args, **kwargs)
         prev = getattr(_tls, "window", None)
@@ -547,32 +618,128 @@ class ObservedJit:
         except Exception as e:  # noqa: BLE001 — never break the dispatch
             analysis = {"error": f"{type(e).__name__}: {e}"}
             sig = {}
-        _ledger.note_q40_paths(self._entry, win.get("q40"))
-        _ledger.note_mixer_paths(self._entry, win)
+        self._note_paths(win)
         _ledger.record(self._entry, compile_s, sig, _plan_desc(), analysis,
                        backend_s=win["backend_s"])
         return out
 
+    def _call_stored(self, directory: str, args: tuple):
+        """The call through the signature's stored executable, or
+        :data:`_ASIDE` where the jit has to take it."""
+        static = self._static
+        sig = tuple(a if i in static else getattr(a, "shape", None)
+                    for i, a in enumerate(args))
+        exe = self._programs.get(sig)
+        if exe is None:
+            exe = self._admit(directory, sig, args)
+        if exe is _ASIDE:
+            return _ASIDE
+        try:
+            out = exe(*[a for i, a in enumerate(args) if i not in static])
+        except (TypeError, ValueError) as e:
+            # the compiled call's own check, made before anything runs or
+            # is donated: not this executable's arguments (another tree
+            # under the same top-level shapes)
+            self._programs[sig] = _ASIDE
+            program_store.say(
+                f"{self.program} refused its arguments "
+                f"({type(e).__name__}: {str(e)[:200]}); this signature "
+                f"goes through the jit from here on")
+            return _ASIDE
+        self._entry["hits"] += 1
+        return out
+
+    def _note_paths(self, notes: dict) -> None:
+        _ledger.note_q40_paths(self._entry, notes.get("q40"))
+        _ledger.note_mixer_paths(self._entry, notes)
+
+    def _admit(self, directory: str, sig: tuple, args: tuple):
+        """The executable for a signature met for the first time: loaded
+        from the store, or lowered and compiled here and filed there; or
+        :data:`_ASIDE`. One thread builds, the others wait for it."""
+        with self._programs_lock:
+            exe = self._programs.get(sig)
+            if exe is None:
+                exe = self._programs[sig] = self._build(directory, args)
+            return exe
+
+    def _build(self, directory: str, args: tuple):
+        import jax
+
+        plan = _plan_desc()
+        if plan != "none" or jax.process_count() > 1:
+            # no cell runs there: unmeasured code has no claim. An engine
+            # keeps its plan, so the wrapper stops asking
+            self._storable = False
+            return _ASIDE
+        try:
+            key, devices = program_store.program_key(
+                program=self.program, fun=self._fun, options=self._options,
+                args=args, static=self._static, plan=plan)
+        except program_store.Unkeyable as e:
+            program_store.say(f"{self.program} has no key ({e}); traced at "
+                              f"every start")
+            return _ASIDE
+        t0 = time.perf_counter()
+        got = program_store.load(directory, self.program, key, devices)
+        backend_s = 0.0
+        if got is not None:
+            compiled, notes = got
+            source = "store"
+        else:
+            # lower and compile ONCE (it reads and feeds the XLA cache
+            # exactly as the jitted call would), under a window of this
+            # thread's so that the trace's path notes are gathered
+            with _thread_window() as notes:
+                compiled = self._jitted.lower(*args).compile()
+            backend_s = notes["backend_s"]
+            fresh = self._observed and not notes["n_cache_hit"]
+            notes = {k: v for k, v in notes.items()
+                     if k == "q40" or k in _MIXER_GAUGES}
+            if fresh or program_store.serializes_again():
+                program_store.save(directory, self.program, key, compiled,
+                                   notes)
+            else:
+                program_store.say(
+                    "XLA:CPU cannot serialize again what its persistent "
+                    "cache served; such programs are served from their "
+                    "compiles and traced at the next start", once="cpu")
+            source = "trace"
+        seconds = time.perf_counter() - t0
+        analysis = None
+        try:
+            signature = _signature(args, {})
+            if _ledger.analyze:
+                analysis = analyze_compiled(self.program, compiled,
+                                            scope=self.scope)
+        except Exception as e:  # noqa: BLE001 — never break the dispatch
+            analysis = {"error": f"{type(e).__name__}: {e}"}
+            signature = {}
+        self._note_paths(notes)
+        _ledger.record(self._entry, seconds, signature, plan, analysis,
+                       backend_s=backend_s, source=source)
+        return compiled
+
     def lower(self, *args, **kwargs):
         # an AOT lowering traces too (the start-up report's programs): give
         # note_q40_path a window, and keep it out of a dispatch's own
-        prev = getattr(_tls, "window", None)
-        win = _tls.window = _new_window()
-        try:
-            return self._jitted.lower(*args, **kwargs)
-        finally:
-            _tls.window = prev
-            _ledger.note_q40_paths(self._entry, win.get("q40"))
-            _ledger.note_mixer_paths(self._entry, win)
+        with _thread_window() as win:
+            try:
+                return self._jitted.lower(*args, **kwargs)
+            finally:
+                self._note_paths(win)
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)
 
 
-def observe(jitted, *, scope: str, program: str) -> ObservedJit:
+def observe(jitted, *, scope: str, program: str, fun=None,
+            options: dict | None = None) -> ObservedJit:
     """Wrap a jitted callable for the compile ledger (plan_scoped_jit's
-    hook point)."""
-    return ObservedJit(jitted, scope, program)
+    hook point). ``fun`` (what was jitted) and ``options`` (the jit's) are
+    what the program store's key needs; without them the wrapper only
+    observes."""
+    return ObservedJit(jitted, scope, program, fun=fun, options=options)
 
 
 # -- HBM startup report --------------------------------------------------------
@@ -681,14 +848,23 @@ def compile_report(scope: str, emit=print) -> None:
     where a miss was analyzed (``ledger().analyze``), its measured HBM bytes
     and compiled Pallas kernels."""
     events = [e for e in _ledger.snapshot()["events"] if e["scope"] == scope]
+    loaded = [e for e in events if e["source"] == "store"]
     emit(f"🧮 compiles: {len(events)} in {scope}, "
          f"{sum(e['compile_s'] for e in events):.2f} s wall, "
-         f"{sum(e['backend_s'] for e in events):.2f} s in the XLA backend")
+         f"{sum(e['backend_s'] for e in events):.2f} s in the XLA backend"
+         + (f"; {len(loaded)} loaded from the program store in "
+            f"{sum(e['compile_s'] for e in loaded):.2f} s, "
+            f"{len(events) - len(loaded)} traced" if loaded else ""))
     for e in events:
         a = e["analysis"] or {}
         kern = a.get("kernels")
-        emit(f"🧮   compiled {e['program']}: {e['compile_s']:.2f} s wall, "
-             f"{e['backend_s']:.2f} s backend"
+        if e["source"] == "store":
+            head = (f"🧮   loaded {e['program']}: {e['compile_s']:.2f} s "
+                    f"from the program store")
+        else:
+            head = (f"🧮   compiled {e['program']}: {e['compile_s']:.2f} s "
+                    f"wall, {e['backend_s']:.2f} s backend")
+        emit(head
              + (f", HBM {_gb(a['hbm_total_bytes'])}"
                 if a.get("hbm_total_bytes") else "")
              + ("" if kern is None else ", Pallas kernels: "

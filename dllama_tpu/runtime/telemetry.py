@@ -186,6 +186,11 @@ ACHIEVED_TFLOPS = "dllama_achieved_tflops"
 # XLA compile introspection (runtime/introspection.py)
 COMPILE_TOTAL = "dllama_compile_total"
 COMPILE_SECONDS = "dllama_compile_seconds"
+# the program store (runtime/program_store.py): how each program came to be
+PROGRAMS_LOADED = "dllama_programs_loaded_total"
+PROGRAMS_TRACED = "dllama_programs_traced_total"
+PROGRAM_LOAD_SECONDS = "dllama_program_load_seconds_total"
+PROGRAM_TRACE_SECONDS = "dllama_program_trace_seconds_total"
 PROGRAM_HBM_BYTES = "dllama_program_hbm_bytes"
 PROGRAM_FLOPS = "dllama_program_flops"
 Q40_MATMUL_PATHS = "dllama_q40_matmul_paths"
@@ -455,6 +460,18 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "Wall time of one trace+compile event, seconds (includes the "
           "triggering dispatch's first execution)",
           buckets=_COMPILE_BUCKETS_S),
+    _spec(PROGRAMS_LOADED, "counter",
+          "Programs deserialized from the program store instead of traced "
+          "(compile-ledger events with source=store)"),
+    _spec(PROGRAMS_TRACED, "counter",
+          "Programs traced, lowered and compiled in this process: a miss "
+          "of the program store, a fallback, or the store off "
+          "(compile-ledger events with source=trace)"),
+    _spec(PROGRAM_LOAD_SECONDS, "counter",
+          "Seconds spent loading programs from the program store"),
+    _spec(PROGRAM_TRACE_SECONDS, "counter",
+          "Seconds spent tracing, lowering and compiling programs (the "
+          "wall time of every source=trace event)"),
     _spec(PROGRAM_HBM_BYTES, "gauge",
           "Per-program device bytes by kind (temp/output/argument/code/"
           "alias) from compiled.memory_analysis()"),

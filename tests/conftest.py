@@ -93,3 +93,16 @@ def _dllama_env_leak_sentinel():
         + str({k: (before.get(k), after.get(k))
                for k in set(before) | set(after)
                if before.get(k) != after.get(k)}))
+
+
+@_pt.fixture(autouse=True)
+def _program_store_off_after():
+    """``compile_cache.enable()`` (``benchmark/run.py``'s ``main`` and the
+    CLI's, which tests call in-process) turns the program store on for the
+    process: the next test must not inherit it."""
+    yield
+    import sys
+
+    cc = sys.modules.get("dllama_tpu.compile_cache")
+    if cc is not None:
+        cc._programs_dir = None
