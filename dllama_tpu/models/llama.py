@@ -113,9 +113,10 @@ def _scan_by_index(cfg: ModelConfig, rows: int) -> bool:
     """Whether a layer scan walks the layer INDEX with the weight stack
     closed over (:func:`_layer_at`) instead of scanning the stack: a
     dispatch the fused kernel has a regime for (``rows`` = B x T
-    flattened: a decode step's 1..16, a prefill chunk's up to 256) on one
-    device. Wider dispatches, mesh plans and offloaded weights scan the
-    stack itself, as ever. Platform and kernel mode do not enter: where
+    flattened: a decode step's 1..16, a prefill chunk's up to 256, a
+    chunk with a tick's decode rows joined to it) on one device. Wider
+    dispatches, mesh plans and offloaded weights scan the stack itself, as
+    ever. Platform and kernel mode do not enter: where
     no kernel takes the stack, linear() slices it, which is what the scan
     did."""
     from ..ops.quant_matmul import CHUNK_MAX_M
@@ -1397,6 +1398,95 @@ def paged_sampled_step_guarded(params: Params, cfg: ModelConfig,
     last = _poison_logits(logits[:, -1, :], poison)
     return (sampled_token(last, temps, topps, coins),
             _nonfinite_rows(last)), pkv
+
+
+@_exact_f32_dots
+def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                     pos_vec: jax.Array, cache, tables: jax.Array,
+                     chunk: jax.Array, chunk_pos: jax.Array,
+                     poison: jax.Array):
+    """A tick that carries a prefill chunk, as ONE program: :func:`forward`
+    over ``chunk [1, T]`` at ``chunk_pos`` into an admission's column AND
+    :func:`paged_sampled_step_guarded`'s layers and head over the tick's
+    decode rows (``tokens [R, 1]`` at ``pos_vec`` through ``tables``), so
+    that every layer's seven planes are read once for both. ``cache`` is
+    ``(column, pool)``, both given back (and both donated where the server
+    jits this).
+
+    One layer scan carries the joined activations ``[1, T + R, D]`` and the
+    whole pool; the column's layers ride as the scan's xs/ys, as
+    :func:`forward` has them. Everything a layer does a row at a time (the
+    norms, rope, the seven matmuls, the residuals) runs once over the
+    ``T + R`` rows, which to ``ops.quant_matmul`` is a chunk: one fetch and
+    one dequant of each stripe. Only attention tells the rows apart: the
+    chunk's attend over its column (:func:`_attend_dense`), the decode rows
+    write their K/V into the pool in place and attend through their tables
+    (:func:`_attend_paged`); a row with an all-null table is dead, as an
+    inactive slot of a step is, and every row may be.
+
+    After the scan the head runs for the decode ROWS alone (the serving
+    prefill throws a chunk's logits away), then the poison and the
+    non-finite count. Returns ``((token, nonfinite, logits), (column,
+    pool))``: ``token`` is each row's ARGMAX, which is what the step's
+    sampler gives a batch in which no row samples; where one does, the
+    caller hands ``logits [R, V]`` (float32, poisoned as the step's are) to
+    ``ops.sampling.sampled_token`` with the rows' knobs. The sampler is not
+    in here because it is 7.3 of the step's 12.8 MB of executable (its two
+    vocabulary-wide sorts), this program exists once a prefill bucket, and a
+    start pays for every byte it loads (PERF.md section 6, PR 47). The
+    dense decoders' program, with no mesh plan: the five other
+    architectures keep a ``forward`` / ``paged_forward`` pair of their
+    own."""
+    from ..runtime.kvblocks import PagedKVCache
+
+    if cfg.paged_only or _current_plan() is not None:
+        raise ValueError("forward_and_step is the dense decoders' program "
+                         "on one device")
+    col, pkv = cache
+    chunk_pos = jnp.asarray(chunk_pos, dtype=jnp.int32)
+    pos_vec = jnp.asarray(pos_vec, dtype=jnp.int32)
+    T, R = chunk.shape[1], tokens.shape[0]
+    joined = jnp.concatenate([chunk[0], tokens[:, 0]])
+    x = params.embedding[joined].astype(cfg.compute_dtype)[None]
+
+    cos, sin = build_rope_cache(cfg)
+    cpos = (chunk_pos + jnp.arange(T, dtype=jnp.int32))[None, :]   # [1, T]
+    rpos = pos_vec[:, None]                                        # [R, 1]
+    positions = jnp.concatenate([cpos, rpos.T], axis=1)            # [1, T+R]
+    fq = fake_quant_q80 if cfg.sync_q80 else (lambda a: a)
+    by_index = _scan_by_index(cfg, T + R)
+
+    def body(carry, xs):
+        x, k_pool, v_pool = carry
+        l, lp, k_l, v_l = xs
+        if by_index:
+            lp = _layer_at(params.layers, l)
+        elif cfg.offload:
+            lp = jax.device_put(lp, jax.memory.Space.Device)
+        q, k, v = _attn_qkv(cfg, x, lp, cos, sin, positions, fq)
+        att_c, k_l, v_l = _attend_dense(cfg, q[:, :T], k[:, :T], v[:, :T],
+                                        k_l, v_l, chunk_pos, cpos)
+        rows = lambda a: jnp.swapaxes(a[:, T:], 0, 1)   # [R, 1, heads, hd]
+        att_r, k_pool, v_pool = _attend_paged(cfg, rows(q), rows(k), rows(v),
+                                              k_pool, v_pool, l, rpos, tables)
+        att = jnp.concatenate([att_c, jnp.swapaxes(att_r, 0, 1)], axis=1)
+        x, _ = _attn_out_and_ffn(cfg, x, att, lp, fq, taps=False)
+        return (x, k_pool, v_pool), (k_l, v_l)
+
+    xs = (_layer_indices(cfg), None if by_index else params.layers,
+          col.k, col.v)
+    (x, pool_k, pool_v), (col_k, col_v) = jax.lax.scan(
+        body, (x, pkv.k, pkv.v), xs)
+
+    h = rms_norm(jnp.swapaxes(x[:, T:], 0, 1), params.final_norm,
+                 cfg.norm_epsilon)
+    if cfg.sync_q80:
+        h = fake_quant_q80(h)
+    logits = linear(h, params.logits, out_axis="vocab").astype(jnp.float32)
+    last = _poison_logits(logits[:, -1, :], poison)
+    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    return ((greedy, _nonfinite_rows(last), last),
+            (KVCache(k=col_k, v=col_v), PagedKVCache(k=pool_k, v=pool_v)))
 
 
 def paged_verify_step(params: Params, cfg: ModelConfig, tokens: jax.Array,

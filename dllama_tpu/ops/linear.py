@@ -163,7 +163,7 @@ def _pallas_wanted(x: jax.Array, w: QuantizedWeight, fast: bool) -> dict | None:
     What ``auto`` comes to on a TPU: exact mode takes the tiled kernel
     (HIGHEST-precision dots that match the host oracle); fast mode takes
     the fused full-K kernel over a 2-D plane pair for 1..16 flattened rows
-    (a decode step: the dequant-GEMV) and for 17..256 (a prefill chunk:
+    (a decode step: the dequant-GEMV) and for 17..320 (a prefill chunk:
     the same dequant into VMEM in front of one MXU pass), and the XLA
     dequant + dot for everything else: wider, stacked expert planes, a
     width off the lane grid (the tiled kernel streams codes at ~130 GB/s
@@ -213,7 +213,7 @@ def _fused_path(kw: dict | None, x: jax.Array, w: QuantizedWeight,
                 fast: bool) -> str | None:
     """The path quant_matmul, given these gate kwargs, runs the full-K
     fused kernel under on this dispatch (``fused`` at 1..16 rows, ``chunk``
-    at 17..256), or None where it runs the tiled one."""
+    at 17..``CHUNK_MAX_M``), or None where it runs the tiled one."""
     from .quant_matmul import fused_path, wants_fused
 
     return fused_path(tuple(x.shape), w, fast) if wants_fused(kw) else None
@@ -254,7 +254,7 @@ def linear(x: jax.Array, w: Weight, *, out_axis: str | None = None,
     is quant_matmul.pallas_mode_gate. On a TPU ``auto`` means: exact (f32)
     graphs take the tiled kernel; fast (bf16) graphs take the fused full-K
     kernel over a 2-D plane pair with no plan, for a decode-shaped
-    dispatch (1..16 flattened rows) and for a prefill chunk (17..256: the
+    dispatch (1..16 flattened rows) and for a prefill chunk (17..320: the
     plane is dequantized in VMEM, not in passes through HBM), and the XLA
     dequant + dot for everything else. A :class:`LayerSlice` hands that
     kernel the layer stack and an index; unsupported shapes fall back to

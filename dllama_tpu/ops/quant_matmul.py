@@ -132,7 +132,7 @@ def _decode_kernel(x_ref, codes_ref, scales_ref, out_ref, wd_ref, s32_ref,
     over the weight planes, which is exactly the decode regime's byte
     budget (weights dominate; the T<=16 activation rides along in VMEM).
 
-    The same body is the prefill CHUNK's kernel in fast mode (17..256 rows,
+    The same body is the prefill CHUNK's kernel in fast mode (17..320 rows,
     PR 35): there the stripe's dequant is what XLA otherwise does in passes
     of its own through HBM (slice the codes, convert, spread the scales,
     multiply, write a bf16 plane, read it back: 48% of a cell's device
@@ -189,9 +189,14 @@ def _decode_kernel_at(layer_ref, *refs, groups: int, fast: bool):
 # (T=K+1, small) — the same rule as models.llama._OVERLAP_MAX_WIDTH.
 FUSED_MAX_M = 16
 # Widest dispatch the same kernel takes in its CHUNK regime (fast mode
-# only): the widest prefill bucket (runtime.engine.PREFILL_BUCKETS). Past
-# it the dequant amortizes over enough rows for XLA's dequant + dot.
-CHUNK_MAX_M = 256
+# only): the widest prefill bucket (runtime.engine.PREFILL_BUCKETS, 256)
+# with a tick's decode rows joined to it (models.llama.forward_and_step:
+# the chunk's rows and up to 64 slots' through ONE matmul, so a stripe is
+# fetched and dequantized once for both). At K = 14336 the resident set of
+# 320 rows is 52 MB of the budget's 64 at bn = 512, as _decode_blocks
+# reckons it. Past it the dequant amortizes over enough rows for XLA's
+# dequant + dot.
+CHUNK_MAX_M = 256 + 64
 
 # VMEM the kernel asks Mosaic for (the default scoped limit is 16 MB of a
 # v5e's 128), and what its resident set may take of that: the wd scratch,
@@ -357,8 +362,8 @@ def quant_matmul(x: jax.Array, w: QuantizedWeight, *, interpret: bool = False,
     ``fused=True`` prefers the full-K kernel (:func:`_decode_kernel` —
     bit-parity with the XLA fused-dequant reference) in either of its
     regimes (:func:`fused_path`: up to 16 rows, or a fast-mode chunk of up
-    to 256), falling back to the (n, k)-tiled kernel otherwise, so a
-    ``fused``-mode dispatch never fails on a shape past both. ``layer`` (an
+    to ``CHUNK_MAX_M``), falling back to the (n, k)-tiled kernel otherwise,
+    so a ``fused``-mode dispatch never fails on a shape past both. ``layer`` (an
     int32 scalar) says that ``w`` is the layer STACK, leading axis = layer,
     and picks one: that kernel's stack-and-index entry
     (:func:`_decode_call`), for callers that checked :func:`fused_path` on
@@ -514,8 +519,8 @@ def pallas_mode_gate(fast: bool, x_shape: tuple[int, ...] | None = None,
     also the kill switch for every kernel this gate guards), ``pallas``
     (force the tiled kernel; interpret mode off-TPU, the test path),
     ``fused`` (force the full-K fused kernel where it fits — up to 16
-    rows, or a fast-mode chunk of up to 256 — and the tiled kernel where
-    it does not), or ``auto``, resolved from what
+    rows, or a fast-mode chunk of up to ``CHUNK_MAX_M`` — and the tiled
+    kernel where it does not), or ``auto``, resolved from what
     the dispatch shows:
 
     * off a TPU: no kernel.
@@ -524,9 +529,10 @@ def pallas_mode_gate(fast: bool, x_shape: tuple[int, ...] | None = None,
     * fast mode (bf16 graphs, serving): the fused full-K kernel where
       :func:`fused_path` finds a regime for the dispatch — ``x_shape``
       flattens to 1..``FUSED_MAX_M`` rows (a decode step: the dequant-GEMV)
-      or to ``CHUNK_MAX_M`` at most (a prefill chunk: the same body at
-      chunk width, so the dequantized plane stays in VMEM and never
-      crosses HBM), ``w`` is ONE 2-D Q40 plane pair whose stripe fits VMEM
+      or to ``CHUNK_MAX_M`` at most (a prefill chunk, alone or with a
+      tick's decode rows joined to it: the same body at chunk width, so
+      the dequantized plane stays in VMEM and never crosses HBM), ``w``
+      is ONE 2-D Q40 plane pair whose stripe fits VMEM
       and no mesh plan is active — and NO kernel for anything else (wider,
       a plan, stacked expert planes, a width off the lane grid): those keep
       the XLA dequant + dot and never land on the tiled kernel (130 GB/s

@@ -428,6 +428,13 @@ class _GeneratorCore:
         # step_wait walls of chunk-free steps (_settle_prefill)
         self._chunks_pending: list[_PendingChunk] = []
         self._step_waits: deque = deque(maxlen=STEP_WAIT_SAMPLES)
+        # running totals: plain prefill chunks dispatched, and those of them
+        # whose program also stepped at least one live decode row
+        # (PagedGenerator._run_rows)
+        self._n_chunks = self._n_chunks_rows = 0
+        self._m_chunks = self._tm.counter(telemetry.PREFILL_CHUNKS)
+        for rows in ("live", "none"):
+            self._m_chunks.inc(0, rows=rows)
         # tenant observatory (runtime/tenancy): every accounting site
         # below notes the SAME value it publishes globally, so per-tenant
         # sums reconcile with the global counters bit-exactly
@@ -453,6 +460,15 @@ class _GeneratorCore:
         and those of them that came from matched blocks. The dense pool
         shares nothing."""
         return 0, 0
+
+    def take_rows_rode(self) -> bool:  # dlint: owner=loop-thread
+        """Whether a prefill chunk's program has stepped the decode rows
+        since the last step (asked once a tick: it forgets). Then the tick
+        dispatches no step of its own: a tick that carries a chunk is one
+        program, and a slot that chunk's commit armed takes its first step
+        in the next tick. Only the paged generator of a dense decoder has
+        such a program (:meth:`PagedGenerator._step_with_chunk`)."""
+        return False
 
     def abort_admit(self, adm: "_Admission") -> None:  # dlint: owner=loop-thread
         """Roll back an admission that will never commit (client cancel
@@ -685,16 +701,33 @@ class _GeneratorCore:
         sync is added for telemetry's sake), so its cost is attributed
         later, by :meth:`_settle_prefill`, when the next step's fetch has
         waited for it."""
+        self._enqueue_chunk(adm, padded, n_valid, adm.pos)
+
+    def _enqueue_chunk(self, adm: "_Admission", padded, n_valid: int,
+                       pos: int) -> None:
+        """The chunk at ``pos`` alone, no decode row beside it."""
         t0 = telemetry.now_ns()
-        adm.col = self._exec_prefill(adm.col, padded, adm.pos, n_valid)
+        adm.col = self._exec_prefill(adm.col, padded, pos, n_valid)
+        self._note_chunk(adm, len(padded), n_valid, t0, rows="none")
+
+    def _note_chunk(self, adm: "_Admission", width: int, n_valid: int,
+                    t_enqueue_ns: int, *, rows: str) -> None:
+        """The books of one plain chunk just enqueued: pending until a
+        wait settles it, the tenant's tokens, the tick record, and the
+        chunk totals (``rows`` is ``live`` where the chunk's program also
+        stepped the tick's decode rows, ``none`` otherwise)."""
         self._chunks_pending.append(
-            _PendingChunk(adm.req, adm.slot, len(padded), n_valid, t0))
+            _PendingChunk(adm.req, adm.slot, width, n_valid, t_enqueue_ns))
         self._tenancy.note_prefill_tokens(adm.req.tenant, n_valid)
         # the tick that dispatched the chunk spent the tokens; the wall
         # lands in the tick whose step waited for it (usually this one)
         self.flight.note_prefill(adm.req.rid, 0.0, n_valid)
+        self._n_chunks += 1
+        self._n_chunks_rows += rows == "live"
+        self._m_chunks.inc(rows=rows)
 
-    def _settle_prefill(self, t_wait0_ns: int, t_wait1_ns: int) -> None:  # dlint: owner=loop-thread
+    def _settle_prefill(self, t_wait0_ns: int, t_wait1_ns: int, *,
+                        rode: bool = False) -> None:  # dlint: owner=loop-thread
         """Attribute the prefill chunks enqueued since the last step,
         now that a step's ``step_wait`` (``t_wait0_ns``..``t_wait1_ns``)
         has waited for them. The device runs programs in dispatch order,
@@ -708,7 +741,14 @@ class _GeneratorCore:
         ``prefill_chunk`` span. With no chunk pending the wait is a
         baseline sample. Until a chunk-free step has been seen (a cold
         server's first request) the baseline is 0 and the first step's
-        own time is charged with the chunks, once."""
+        own time is charged with the chunks, once.
+
+        ``rode``: the wait was for a chunk's program that stepped the rows
+        itself (:meth:`PagedGenerator._run_rows`), so no step's own wait
+        lies in the wall behind the chunks. They are charged the whole
+        wall, shares that sum to it; the bystanders, whose token came out
+        of the same program, are stalled by what the wall holds beyond a
+        step of their own, as before."""
         pending = self._chunks_pending
         if not pending:
             self._step_waits.append((t_wait1_ns - t_wait0_ns) / 1e6)
@@ -716,14 +756,16 @@ class _GeneratorCore:
         self._chunks_pending = []
         base = statistics.median(self._step_waits) if self._step_waits else 0.0
         t0 = pending[0].t_enqueue_ns
-        total = max(0.0, (t_wait1_ns - t0) / 1e6 - base)
+        wall = (t_wait1_ns - t0) / 1e6
+        stall = max(0.0, wall - base)
+        total = wall if rode else stall
         width = sum(c.width for c in pending)
         for c in pending:
             ms = total * c.width / width
             c.req.ms_prefill += ms
             for s in self.slots:
                 if s is not None and s is not c.req:
-                    s.ms_preempt += ms
+                    s.ms_preempt += stall * c.width / width
             self._m_prefill_ms.record(ms)
             self.flight.note_prefill(c.req.rid, ms, 0)
             t1 = t0 + int(ms * 1e6)
@@ -1615,6 +1657,44 @@ class PagedGenerator(_GeneratorCore):
         # prefill rides the ENGINE's jitted forward over the gathered
         # column (same program its solo path compiles — shared cache)
         self._prefill_fwd = engine._step
+        # ... except where a chunk and the tick's decode rows can be ONE
+        # program (models.llama.forward_and_step: the layers' planes read
+        # once for both): a decoder that takes llama.py's own forward /
+        # paged_forward pair, one device, a plain step a tick, and a chunk
+        # regime of the Q40 kernel wide enough for the widest bucket with
+        # every slot's row joined to it. Then EVERY plain chunk goes through
+        # it, its rows dead (null tables, as an inactive slot rides a step)
+        # where nobody decodes: one executable a bucket, no variant that
+        # only some ticks reach. Everything else keeps its two programs.
+        self._tick = None
+        self._riding = None       # a chunk waiting for the tick's rows
+        self._rows_rode = False   # ... which rode one since the last step()
+        from ..ops.quant_matmul import CHUNK_MAX_M
+
+        if (not self.cfg.paged_only and engine.plan is None
+                and not self.spec
+                and getattr(engine, "decode_chunk", 1) == 1
+                and max(engine.prefill_buckets) + n_slots <= CHUNK_MAX_M):
+            from ..models.llama import forward_and_step
+            from ..ops.sampling import sampled_token
+
+            self._tick = steppack.jit_packed_step(
+                forward_and_step, scope=_sc, name="forward_and_step")
+            self._dead_rows = (
+                np.zeros((n_slots, 1), np.int32), np.zeros(n_slots, np.int32),
+                np.zeros_like(self.tables))
+            # the tick program ends in an argmax (what the step's sampler
+            # gives a batch in which no row samples) and hands back the
+            # rows' logits; where a row does sample, the sampler runs over
+            # them as a program of its own, one for every bucket. Met here
+            # once, so that the first sampled row beside a chunk finds it
+            # loaded
+            self._sample_rows = plan_scoped_jit(sampled_token, scope=_sc,
+                                                program="sample_rows")
+            none = np.zeros(n_slots, np.float32)
+            self._sample_rows(
+                jnp.zeros((n_slots, self.cfg.vocab_size), jnp.float32),
+                none, none, none)
         M, bs = self.table_width, block_size
 
         heads, width = self.cfg.cache_heads, self.cfg.cache_width
@@ -2356,12 +2436,37 @@ class PagedGenerator(_GeneratorCore):
         valid = (jnp.int32(n_valid),) if self.cfg.paged_only else ()
         with self.eng.watchdog.guard("batch_prefill"):
             failpoints.fire("step_hang")
+            if self._tick is not None:
+                # the tick's program with every row dead: nobody decodes,
+                # or the rows rode an earlier chunk of this tick. Enqueued
+                # and not waited for, as the plain forward is; no row's
+                # logits to poison, so the failpoint is not asked
+                fields = (*self._dead_rows, *self._chunk_fields(padded, pos),
+                          np.float32(0.0))
+                _, (col, self.pkv) = self._tick(
+                    self.eng.params, self.cfg,
+                    jnp.asarray(steppack.pack(fields)), (col, self.pkv),
+                    steppack.layout_of(fields))
+                return self._pin_home(col)
             with self._plan_ctx():
                 _, col = self._prefill_fwd(
                     self.eng.params, self.cfg,
                     jnp.asarray(np.asarray(padded).reshape(1, -1), jnp.int32),
                     jnp.int32(pos), col, *valid)
             return self._pin_home(col)
+
+    @staticmethod
+    def _chunk_fields(padded, pos: int) -> tuple:
+        """A chunk as the tick program's last two host fields."""
+        return np.asarray(padded, np.int32).reshape(1, -1), np.int32(pos)
+
+    def _prefill_chunk(self, adm: "_Admission", padded, n_valid: int) -> None:
+        if self._tick is None or self._rows_rode or not self.n_active:
+            return super()._prefill_chunk(adm, padded, n_valid)
+        # live rows, and no chunk has carried them since the last step():
+        # this one waits for continue_admit to dispatch it WITH them, under
+        # the step's own phases (adm.pos moves on before that)
+        self._riding = (adm, padded, n_valid, adm.pos)
 
     def continue_admit(self, adm: "_Admission") -> bool:  # dlint: owner=loop-thread
         """One admission step: drain a page-in batch (KV tier, resumed
@@ -2373,8 +2478,11 @@ class PagedGenerator(_GeneratorCore):
         block is never a write target) and registers the prompt's blocks
         for future sharing."""
         with self.flight.tick_phase("prefill_dispatch") as span:
-            if not self._advance_traced(adm, span):
-                return False
+            done = self._advance_traced(adm, span)
+        if self._riding is not None:
+            self._step_with_chunk()
+        if not done:
+            return False
         with self.flight.tick_phase("admit_commit") as span:
             self._commit_admit(adm)
             if span.traced:
@@ -2547,6 +2655,7 @@ class PagedGenerator(_GeneratorCore):
         self.slots = [None] * self.n_slots
         self._proposers = [None] * self.n_slots
         self._chunks_pending = []
+        self._riding, self._rows_rode = None, False
         self._seq_bids = [[] for _ in range(self.n_slots)]
         self._n_shared = [0] * self.n_slots
         self._reserve = [0] * self.n_slots
@@ -2642,6 +2751,26 @@ class PagedGenerator(_GeneratorCore):
                     bid = int(self.tables[i, p // self.block_size])
                     assert self.pool.refcount(bid) == 1, (i, p, bid)
 
+    def take_rows_rode(self) -> bool:  # dlint: owner=loop-thread
+        rode, self._rows_rode = self._rows_rode, False
+        return rode
+
+    def _prepare_rows(self):  # dlint: owner=loop-thread
+        """The ``step_prepare`` of a plain step, alone or behind a chunk:
+        the live rows once the cancelled are swept, each with a block for
+        the position it writes, and their sampling knobs (None under
+        ``--spec-lookup``, whose verify prepares its own, and where no row
+        is left)."""
+        active = self._sweep_cancelled()
+        if not active or self.spec:
+            return active, None
+        zeros = [0] * self.n_slots
+        self._grow_or_fail(active, zeros)
+        if not active:
+            return active, None
+        self._assert_writable(active, zeros)
+        return active, self._sampling_rows(active)
+
     def step(self) -> int:  # dlint: owner=loop-thread
         """One paged ragged decode step for every active slot. Inactive
         slots ride along with all-null tables (their writes land in the
@@ -2649,43 +2778,75 @@ class PagedGenerator(_GeneratorCore):
         occupancy or block-table contents. Under ``--spec-lookup`` the
         dispatch is the ragged paged VERIFY step instead
         (:meth:`_spec_step`)."""
-        rows = None
+        self._rows_rode = False
         with self.flight.tick_phase("step_prepare"):
-            active = self._sweep_cancelled()
-            if active and not self.spec:
-                zeros = [0] * self.n_slots
-                self._grow_or_fail(active, zeros)
-                if active:
-                    self._assert_writable(active, zeros)
-                    rows = self._sampling_rows(active)
+            active, rows = self._prepare_rows()
         if not active:
             return 0
         if self.spec:
             return self._spec_step(active)
+        return self._run_rows(active, rows)
+
+    def _step_with_chunk(self) -> None:  # dlint: owner=loop-thread
+        """The chunk :meth:`_prefill_chunk` left waiting and the tick's
+        decode rows as ONE dispatch: the step's preparation first, then
+        ``forward_and_step`` through the helper every step path uses, the
+        emit after it. The scheduler's tick then dispatches no step
+        (:meth:`take_rows_rode`)."""
+        (adm, padded, n_valid, pos), self._riding = self._riding, None
+        with self.flight.tick_phase("step_prepare") as span:
+            active, rows = self._prepare_rows()
+            if not active:
+                # every row left in the sweep: the chunk alone, unwaited
+                span.next_phase("prefill_dispatch")
+                self._enqueue_chunk(adm, padded, n_valid, pos)
+                return
+        self._rows_rode = True
+        self._run_rows(active, rows, chunk=(adm, padded, n_valid, pos))
+
+    def _run_rows(self, active: list[int], rows, chunk=None) -> int:  # dlint: owner=loop-thread
+        """Dispatch, wait for and emit one token a live row: the step
+        program, or with ``chunk`` (an admission, its padded tokens, their
+        valid count and position) the tick program, which runs that chunk
+        into the admission's column in the same pass over the weights."""
         temps, topps, coins = rows
         t0 = time.perf_counter()
         with self._step_io("batch_step") as io:
             wait = io.span
-            # the step takes what the architecture carries (_cache_parts:
-            # the blocks, then a window pool, a state pool, the routing
-            # counters), all donated, and gives all of it back
-            parts = self._cache_parts
-            cache = tuple(getattr(self, name) for name in parts)
             tables = (self.tables if self.wpool is None
                       else self._both_tables)
+            host = (self.next_token.astype(np.int32)[:, None],
+                    self.pos.astype(np.int32), tables)
             # blocks this step's walk over the cache reads, over the live
             # rows (ops/paged_attention.py and ops/mla.py both walk
             # ceil((pos + 1) / block_size) entries a row)
             walk_blocks = int(sum(
                 -(-(int(self.pos[i]) + 1) // self.block_size)
                 for i in active))
-            (nxt, nf), cache = io.call(
-                self._step, cache if len(parts) > 1 else cache[0],
-                self.next_token.astype(np.int32)[:, None],
-                self.pos.astype(np.int32), tables, temps, topps, coins)
-            for name, part in zip(parts, cache if len(parts) > 1
-                                  else (cache,)):
-                setattr(self, name, part)
+            if chunk is None:
+                # the step takes what the architecture carries
+                # (_cache_parts: the blocks, then a window pool, a state
+                # pool, the routing counters), all donated, and gives all
+                # of it back
+                parts = self._cache_parts
+                cache = tuple(getattr(self, name) for name in parts)
+                (nxt, nf), cache = io.call(
+                    self._step, cache if len(parts) > 1 else cache[0], *host,
+                    temps, topps, coins)
+                for name, part in zip(parts, cache if len(parts) > 1
+                                      else (cache,)):
+                    setattr(self, name, part)
+            else:
+                adm, padded, n_valid, pos = chunk
+                t_enqueue = telemetry.now_ns()
+                (nxt, nf, logits), (col, self.pkv) = io.call(
+                    self._tick, (adm.col, self.pkv), *host,
+                    *self._chunk_fields(padded, pos))
+                if (temps > 0.0).any():
+                    nxt = self._sample_rows(logits, temps, topps, coins)
+                adm.col = self._pin_home(col)
+                self._note_chunk(adm, len(padded), n_valid, t_enqueue,
+                                 rows="live")
             if self.moe_stats is None:
                 nxt, nf = io.fetch(tokens=nxt, nonfinite=nf)
             else:
@@ -2702,10 +2863,13 @@ class PagedGenerator(_GeneratorCore):
                 # running totals, as the routing counters': a reader of a
                 # traced slice takes last less first
                 matched, prompt = self.prefix_totals()
-                wait.set(prefix_tokens=matched, prompt_tokens=prompt)
+                wait.set(prefix_tokens=matched, prompt_tokens=prompt,
+                         chunks=self._n_chunks,
+                         chunks_with_rows=self._n_chunks_rows)
         ms = (time.perf_counter() - t0) * 1000.0
         with self.flight.tick_phase("emit"):
-            self._settle_prefill(wait.t0_ns, wait.t1_ns)
+            self._settle_prefill(wait.t0_ns, wait.t1_ns,
+                                 rode=chunk is not None)
             if not self._tier_rewarmed:
                 self._tier_rewarm()
             self._attrib_decode(active, ms)
@@ -3547,6 +3711,9 @@ class BatchScheduler:
             if rids and span.traced:
                 span.set(rids="/".join(map(str, rids)))
         self._advance_admissions()
+        # a chunk's program stepped this tick's rows (asked here, before
+        # any return: the answer is this tick's alone)
+        rows_rode = self.gen.take_rows_rode()
         # golden canary drift sentinel (runtime/numerics): time-gated
         # fixed-seed replay on this thread — the same thread that owns
         # every device dispatch, so it can never race a batch step. Its
@@ -3572,7 +3739,9 @@ class BatchScheduler:
         # per-token — the same latency/throughput trade as the engine's
         # chunked decode)
         chunk = getattr(self.gen.eng, "decode_chunk", 1)
-        if chunk > 1:
+        if rows_rode:
+            pass    # one program a tick: no step behind a carried chunk
+        elif chunk > 1:
             self.gen.step_chunk(chunk)
         else:
             self.gen.step()

@@ -40,6 +40,7 @@ from dataclasses import dataclass
 
 # engine (runtime/engine.py)
 PREFILL_CHUNK_MS = "dllama_prefill_chunk_ms"
+PREFILL_CHUNKS = "dllama_prefill_chunks_total"
 PREFILL_TOKENS = "dllama_prefill_tokens_total"
 DECODE_STEP_MS = "dllama_decode_step_ms"
 DECODE_TOKENS = "dllama_decode_tokens_total"
@@ -243,6 +244,12 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "Wall time of one prefill chunk (single-sequence: the fetched "
           "dispatch; batched serving: the chunk's device-inclusive cost, "
           "settled when the next step's fetch has waited for it)"),
+    _spec(PREFILL_CHUNKS, "counter",
+          "Plain prefill chunks batched serving dispatched (label rows): "
+          "live = the chunk's program also stepped at least one live "
+          "decode row of its tick (the paged generator's forward_and_step "
+          "for a dense decoder, so the weights are read once for both); "
+          "none = no row rode it"),
     _spec(PREFILL_TOKENS, "counter", "Prompt tokens prefilled"),
     _spec(DECODE_STEP_MS, "histogram",
           "Wall time of one decode dispatch (single, fused-chunk, or "

@@ -194,6 +194,35 @@ def test_chunk_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
     assert kernels.get("quant_matmul") == 1, kernels
 
 
+@pytest.mark.parametrize("rows", [272, 320])
+@pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B)
+def test_chunk_kernel_compiles_at_the_joined_widths_for_v5e(one_chip, k, n, rows):
+    """The same kernel as the tick program calls it (PR 47,
+    ``models.llama.forward_and_step``): the widest bucket's 256 rows with 16
+    slots' decode rows joined to them, and the regime's upper edge, over the
+    layer stack and a traced index, for the two dense decoders that program
+    serves. K = 14336 with 320 rows resident stays inside the chunk regime's
+    VMEM limit."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+    from dllama_tpu.ops.quant_matmul import (CHUNK_MAX_M, fused_path,
+                                             quant_matmul)
+
+    assert rows <= CHUNK_MAX_M
+    L = 4
+    stack = QuantizedWeight(
+        scales=_shape(one_chip, (L, k // 32, n), jnp.bfloat16),
+        codes=_shape(one_chip, (L, k, n), jnp.int8))
+    one = QuantizedWeight(*(jax.ShapeDtypeStruct(p.shape[1:], p.dtype)
+                            for p in stack))
+    x = _shape(one_chip, (1, rows, k), jnp.bfloat16)
+    assert fused_path(x.shape, one, True) == "chunk"
+    kernels = _compiled_kernels(
+        lambda x, w, l: quant_matmul(x, w, interpret=False, fast=True,
+                                     fused=True, layer=l),
+        x, stack, _shape(one_chip, (), jnp.int32))
+    assert kernels.get("quant_matmul") == 1, kernels
+
+
 @pytest.mark.parametrize("rows", [17, 40, 128])
 def test_chunk_kernel_compiles_off_the_buckets_for_v5e(one_chip, rows):
     """Row counts no bucket has (a speculative verify over several slots, a

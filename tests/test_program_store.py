@@ -496,6 +496,60 @@ def test_a_donated_cache_is_deleted_after_a_loaded_call(cache_on, tmp_path):
         engine.close()
 
 
+def test_the_tick_program_comes_from_the_store_with_both_caches_donated(
+        cache_on, tmp_path):
+    """``forward_and_step`` (PR 47: a dense decoder's every prefill chunk, the
+    tick's decode rows riding it) is keyable, packed layout and all: filed a
+    bucket on the first start, loaded on the second (``source`` ``store``),
+    as many events as the first start had and every one loaded; the loaded
+    executable takes BOTH donated arguments, the admission's column and the
+    pool; tokens as the traced start's."""
+    from dllama_tpu.runtime.engine import InferenceEngine
+    from dllama_tpu.runtime.serving import PagedGenerator, Request
+
+    first, scope1 = _serve("dense", tmp_path)
+    cold = [(e["program"], e["source"]) for e in _events(scope1)]
+    assert ("forward_and_step", "trace") in cold
+    assert not any(program == "forward" for program, _source in cold)
+    files = sorted(os.listdir(cache_on))
+    assert sum(f.startswith("forward_and_step-") for f in files) \
+        == sum(program == "forward_and_step" for program, _source in cold) >= 2
+
+    t0 = _totals()
+    second, scope2 = _serve("dense", tmp_path)
+    warm = _moved(t0)
+    assert sorted(program for program, _ in cold) \
+        == sorted(e["program"] for e in _events(scope2))
+    assert {e["source"] for e in _events(scope2)} == {"store"}
+    # the benchmark's programs_loaded_share and program_trace_s over this start
+    assert 100.0 * warm["PROGRAMS_LOADED"] / (warm["PROGRAMS_LOADED"]
+                                               + warm["PROGRAMS_TRACED"]) == 100.0
+    assert warm["PROGRAMS_LOADED"] == len(cold) and warm["PROGRAM_TRACE_SECONDS"] == 0
+    assert sorted(os.listdir(cache_on)) == files and second == first
+
+    t1 = _totals()
+    engine = InferenceEngine(_model_file("dense", tmp_path), None,
+                             max_seq_len=256, compute_dtype="float32",
+                             kv_block_size=16, tp=1)
+    try:
+        gen = PagedGenerator(engine, n_slots=2)
+        rng = np.random.default_rng(5)
+        gen.admit(Request(rid=1, prompt_ids=rng.integers(0, 128, size=5).tolist(),
+                          max_tokens=8, stop_on_eos=False), 0)
+        gen.step()
+        adm = gen.begin_admit(Request(rid=2, prompt_ids=rng.integers(0, 128, size=40).tolist(),
+                                      max_tokens=8, stop_on_eos=False), 1)
+        col_before, pool_before = adm.col.k, gen.pkv.k
+        assert not gen.continue_admit(adm)          # the first of two chunks, row 0 riding it
+        assert gen.take_rows_rode()
+        assert col_before.is_deleted() and pool_before.is_deleted()
+        assert not adm.col.k.is_deleted() and not gen.pkv.k.is_deleted()
+        moved = _moved(t1)
+        assert moved["PROGRAMS_TRACED"] == 0 and moved["PROGRAMS_LOADED"] >= 2
+    finally:
+        engine.close()
+
+
 def test_a_mesh_plan_stands_the_store_aside(cache_on, tmp_path):
     t0 = _totals()
     tokens, scope = _serve("dense", tmp_path, run=RUN[:2], tp=2)

@@ -404,7 +404,7 @@ def _gate_oracle(mode, fast, tpu, m, ndim, plan):
         return None
     if not fast:
         return ("tiled", False)
-    if 1 <= m <= 256 and ndim == 2 and not plan:
+    if 1 <= m <= 320 and ndim == 2 and not plan:
         return ("fused", False)
     return None
 
@@ -416,11 +416,11 @@ def _path_oracle(fast, m, ndim):
         return None
     if 1 <= m <= 16:
         return "fused"
-    return "chunk" if fast and m <= 256 else None
+    return "chunk" if fast and m <= 320 else None
 
 
 @pytest.mark.parametrize("m", [1, 2, 4, 8, 16, 17, 32, 64, 128, 256, 257,
-                               512])
+                               272, 320, 321, 512])
 @pytest.mark.parametrize("plan", [False, True], ids=["noplan", "plan"])
 @pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "tpu"])
 @pytest.mark.parametrize("fast", [False, True], ids=["exact", "fast"])
@@ -429,8 +429,9 @@ def test_gate_truth_table(monkeypatch, mode, fast, tpu, plan, m):
     """mode x fast x platform x M x 2-D/3-D weight x plan/no plan. The rows
     that matter: fast ``auto`` on a TPU is the fused kernel at EVERY M from
     1 to 16 (no lower bound: 2 and 4 engage like 16), counted ``fused``,
-    and at every M from 17 to 256 (a prefill chunk), counted ``chunk``;
-    nothing at 257 or 512, under a plan or over a stack of experts — and
+    and at every M from 17 to 320 (a prefill chunk of up to 256 rows,
+    alone or with a tick's decode rows joined to it), counted ``chunk``;
+    nothing at 321 or 512, under a plan or over a stack of experts — and
     never the tiled kernel by this rule. Exact mode has no chunk regime."""
     from contextlib import nullcontext
 
@@ -651,7 +652,8 @@ def test_dense_forward_scans_the_layer_index_for_decode_shapes(monkeypatch, t,
                                rtol=1e-5, atol=1e-6)
 
 
-# --- the chunk regime of the fused kernel (PR 35): 17..256 rows, fast mode ---
+# --- the chunk regime of the fused kernel (PR 35): 17..256 rows, fast mode;
+# --- to 320 since PR 47 (the widest bucket and a tick's decode rows) ---------
 
 # reduced copies of the benchmark configurations' plane shapes [K, N]: the
 # widths cut by 8 (or so) and kept on the 128-lane grid, the oddities kept:
@@ -680,12 +682,14 @@ def _planes(k, n, seed, lead=()):
 
 
 @pytest.mark.parametrize("stacked", [False, True], ids=["plane", "stack"])
-@pytest.mark.parametrize("m", [17, 32, 64, 128, 256])
+@pytest.mark.parametrize("m", [17, 32, 64, 128, 256, 272, 320])
 @pytest.mark.parametrize("shape", sorted(CHUNK_SHAPES))
 def test_chunk_kernel_matches_dequant_then_dot(shape, m, stacked):
     """The chunk regime against ``dequantize_weight`` + ``dot_general``
-    (what linear() falls back to) at every bucket width and one off the
-    buckets, handed a plane pair or the layer stack and a traced index."""
+    (what linear() falls back to) at every bucket width, one off the
+    buckets and the joined widths (the widest bucket with 16 slots' decode
+    rows, and the regime's upper edge), handed a plane pair or the layer
+    stack and a traced index."""
     from dllama_tpu.ops import quant_matmul as qm
 
     k, n = CHUNK_SHAPES[shape]
@@ -726,13 +730,13 @@ def test_chunk_kernel_dequantizes_the_tile_bit_for_bit(scales):
                                   np.asarray(want, np.float32))
 
 
-def test_chunk_regime_is_fast_modes_alone_and_ends_at_256_rows():
+def test_chunk_regime_is_fast_modes_alone_and_ends_at_320_rows():
     """quant_matmul(fused=True) past either bound runs the tiled kernel
     (exact mode keeps what the goldens were taken with), and the stack
     entry refuses such a dispatch instead of reading layer 0."""
     w = _mk(256, 512, seed=31)
     _, stack = _stack(2, 256, 512, seed=95)
-    for m, fast in ((32, False), (257, True)):
+    for m, fast in ((32, False), (321, True)):
         x = jnp.asarray(np.random.default_rng(m).standard_normal((m, 512)),
                         jnp.float32)
         np.testing.assert_array_equal(

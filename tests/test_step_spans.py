@@ -174,10 +174,18 @@ def test_a_capture_holds_the_fetches_and_no_phase_outside_the_vocabulary(path_en
     assert len(uploads) == len(waits)
     n_fields = {"paged_step": 7, "paged_verify": 9, "dense_step": 6, "dense_step_chunk": 6, "dense_verify": 6}[name]
     assert {int(u[4]["arrays"]) for u in uploads} == {1}
+    # the paged generator of a dense decoder sends every prefill chunk through the tick program
+    # (forward_and_step: the step's fields less its three sampling rows, then the chunk's tokens and position), a layout a bucket,
+    # and those with no live row go up outside any step_upload
+    ticks = {p for p in packed if p[0] == n_fields - 1} if name == "paged_step" else set()
+    assert (name == "paged_step") == bool(ticks)
+    steps = set(packed) - ticks
     # one layout a program (the chunk path steps singly too, K times fewer coins)
-    assert len(set(packed)) == (2 if name == "dense_step_chunk" else 1)
-    assert all(fields == n_fields and words == held > 4 * n_fields for fields, held, words in packed)
-    assert {int(u[4]["bytes"]) for u in uploads} == {words for _f, _h, words in packed}
+    assert len(steps) == (2 if name == "dense_step_chunk" else 1)
+    assert all(fields == n_fields and words == held > 4 * n_fields for fields, held, words in steps)
+    assert all(words == held for _f, held, words in ticks)
+    sent = {int(u[4]["bytes"]) for u in uploads}
+    assert {w for _f, _h, w in steps} <= sent <= {w for _f, _h, w in packed}
     # the call alone carries no transfer count
     assert all("arrays" not in e[4] for e in phases if e[1] == prefix + "step_dispatch")
 
