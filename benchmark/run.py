@@ -515,6 +515,10 @@ def main(argv=None) -> int:
     finally:
         sched.close()
         engine.close()
+    # what `correct` compared, each number beside its limit: stderr's last line (the line's "gap" key has the same)
+    gap = result["gap"]
+    _note(f"correct {result['correct']}: gap_max {gap['max']} limit {gap['tolerance']} over {gap['positions']} positions; "
+          + " ".join(f"{name} {held}" for name, held in gap["invariants"].items()))
     print(json.dumps(result), flush=True)
     return 0
 

@@ -8,7 +8,16 @@ under each control, is ``selftest.py``'s part.
 What must not move: the model the dense modules see for the two configurations
 the benchmark has, and every count of the three cells to the byte
 (``counts_frozen.json``: what ``peaks.py``'s functions gave before they moved
-to ``counts.py``)."""
+to ``counts.py``). A cell that came later froze its counts in a file of its
+configuration's own (``<configuration>/selftest/counts_frozen.json``, held by
+``tests/test_benchmark_selftest.py``), so the two tests that once ran over
+every cell run over the cells this file's ``FROZEN`` holds and over the cells
+whose configuration names no module (PR 49).
+
+Also here, because tier-1 collects this file's tests and names no other:
+what ``BENCHMARK.json`` must keep (one judged tail a cell, lists that name
+cells, a file an end-to-end metric and the reverse) and ``ladder.py``'s rule
+on ladders made from a seed."""
 
 import glob
 import importlib
@@ -25,6 +34,7 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 
 sys.path.insert(0, BENCH)
+import ladder  # noqa: E402
 import run  # noqa: E402
 
 with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
@@ -33,7 +43,17 @@ with open(os.path.join(HERE, "counts_frozen.json"), encoding="utf-8") as f:
     FROZEN = json.load(f)
 SELFTEST_MANIFEST = os.path.join(HERE, "manifest.json")
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
+FROZEN_CELLS = sorted({r["cell"] for r in FROZEN["rows"]})
 MOE = "tiny-qwen3-moe.closed"
+
+
+def _names_modules(entry: dict) -> bool:
+    with open(os.path.join(ROOT, entry["file"]), encoding="utf-8") as f:
+        return "modules" in json.load(f)
+
+
+DENSE_CONFIGS = {c["name"] for c in MANIFEST["configs"] if not _names_modules(c)}
+DENSE_CELLS = [w["name"] for w in MANIFEST["workloads"] if w["config"] in DENSE_CONFIGS]
 
 
 def _resolve(workload: str, manifest_path: str = os.path.join(ROOT, "BENCHMARK.json")):
@@ -51,7 +71,7 @@ def test_the_dense_modules_see_the_nine_keys_they_saw(config):
     assert {k: conf["model"][k] for k in conf["program"]} == conf["program"]
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", FROZEN_CELLS)
 def test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte(cell):
     _cell, conf, _traffic, mods = _resolve(cell)
     rows = [r for r in FROZEN["rows"] if r["cell"] == cell]
@@ -60,7 +80,7 @@ def test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte(cell):
         assert getattr(mods["counts"], r["fn"])(conf["model"], **r["args"]) == r["value"], r
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", DENSE_CELLS)
 def test_a_configuration_that_names_no_module_gets_the_dense_decoders(cell):
     _cell, conf, _traffic, mods = _resolve(cell)
     assert "modules" not in conf
@@ -224,23 +244,132 @@ def test_every_listed_metric_has_its_file_and_reader_and_moves_a_metric_its_cell
     for m in MANIFEST["per_layer"]:
         moved = e2e[m["moves"]]
         assert set(m.get("workloads", CELLS)) <= set(moved.get("workloads", CELLS)), m["name"]
-    stale = {os.path.splitext(f)[0] for f in os.listdir(os.path.join(BENCH, "end_to_end"))} - set(e2e)
-    assert not stale, f"end_to_end/ holds files of metrics BENCHMARK.json does not list: {stale}"
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_has_a_judged_tail_of_the_gap(cell):
     """A mean alone lets a change through that shortens most gaps and lengthens
-    the longest (two chunks packed into a tick): every cell is also held to a
-    percentile of its gaps at the 90th or above (PERF.md section 2: which, and why)."""
-    tails = []
+    the longest (two chunks packed into a tick): every cell is also held to ONE
+    percentile of its gaps, one of those a file in ``end_to_end/`` makes a
+    metric (``ladder.candidates``), whichever ``ladder.py`` gives the cell
+    (PERF.md section 2: which, and why)."""
+    tails = [m["name"] for m in MANIFEST["end_to_end"] if re.fullmatch(r"itl_p\d+_ms", m["name"]) and cell in m["workloads"]]
+    assert len(tails) == 1 and tails[0] in {ladder.tail_name(q) for q in ladder.candidates()}, tails
+
+
+def _tails_say_what_their_names_say():
+    """An ``itl_p<q>_ms`` entry, and no other, reads percentile q of the gaps; it lists its cells, and the lists deal every cell once."""
+    on = []
     for m in MANIFEST["end_to_end"]:
+        named = re.fullmatch(r"itl_p(\d+)_ms", m["name"])
         with open(os.path.join(BENCH, "end_to_end", m["name"] + ".json"), encoding="utf-8") as f:
             spec = json.load(f)
-        if (spec["reader"] == "loadgen_percentile" and spec["args"]["what"] == "itl_ms" and spec["args"]["q"] >= 90
-                and cell in m.get("workloads", CELLS)):
-            tails.append(m["name"])
-    assert len(tails) == 1, tails
+        reads = spec["reader"] == "loadgen_percentile" and spec["args"]["what"] == "itl_ms"
+        assert bool(named) == reads, m["name"]
+        if named:
+            assert spec["args"]["q"] == int(named.group(1)) and m["workloads"], m["name"]
+            on += m["workloads"]
+    assert sorted(on) == sorted(CELLS)
+
+
+def _lists_name_cells():
+    for section in ("end_to_end", "per_layer"):
+        for m in MANIFEST[section]:
+            listed = m.get("workloads", [])
+            assert set(listed) <= set(CELLS) and len(set(listed)) == len(listed), (m["name"], set(listed) - set(CELLS))
+
+
+def _a_file_an_entry_and_the_reverse():
+    for section, folder in (("end_to_end", "end_to_end"), ("per_layer", "layer_metrics")):
+        files = sorted(os.path.splitext(f)[0] for f in os.listdir(os.path.join(BENCH, folder)))
+        assert files == sorted(m["name"] for m in MANIFEST[section]), section
+
+
+@pytest.mark.parametrize("holds", [_tails_say_what_their_names_say, _lists_name_cells, _a_file_an_entry_and_the_reverse],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_benchmark_json_keeps_what_a_moved_tail_could_break(holds):
+    """Moving a cell from one tail's list to another is an edit of two lists by
+    hand: a cell left on both or on neither, a name mistyped, a metric's file
+    without its entry would each pass the driver's check of the form."""
+    holds()
+
+
+# -- ladder.py: where a judged percentile may stand ---------------------------------
+
+def _ladder_set(kind: str, runs: int = 6, seed: int = 49) -> list:
+    """Gaps made from a seed. ``cliff``: a class of steps at 17 ms and a plateau
+    of carried chunks at 38 ms that holds 11.6% of the gaps, half a point more
+    or less from run to run (mixed-queue since PR 47); ``ramp``: 400 gaps whose
+    upper 8% are strewn between 35 and 42 ms (chat above its 92nd, PR 29);
+    ``few``: the cliff's shape in 180 gaps, nine of them beyond the 95th."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    n = {"cliff": 2177, "ramp": 400, "few": 180}[kind]
+    out = []
+    for _ in range(runs):
+        if kind == "ramp":
+            upper = rng.uniform(35.0, 42.0, size=round(0.08 * n))
+        else:
+            upper = rng.normal(38.0, 0.3, size=round(n * (0.116 + rng.uniform(-0.005, 0.005))))
+        out.append(np.concatenate([rng.normal(17.0, 0.4, size=n - len(upper)), upper]).tolist())
+    return out
+
+
+@pytest.mark.parametrize("kind, q, verdict", [("cliff", 90, "cliff below"), ("cliff", 95, "steady"), ("cliff", 85, "steady"),
+                                              ("ramp", 95, "spreads"), ("few", 95, "too few beyond")])
+def test_the_ladder_rule_on_gaps_made_from_a_seed(kind, q, verdict):
+    runs = [{"itl_ms": g, "metrics": {"itl_mean_ms": sum(g) / len(g), "setup_s": 20.0 + 0.1 * i}}
+            for i, g in enumerate(_ladder_set(kind))]
+    manifest = {"end_to_end": [{"name": "itl_p95_ms", "bound": 0.0275, "workloads": ["b"]},
+                               {"name": "itl_p90_ms", "bound": 0.0225, "workloads": ["a"]},
+                               {"name": "itl_mean_ms", "bound": 0.06}, {"name": "setup_s", "bound": 0.1}]}
+    report = ladder.read(runs, manifest, workload="a", qs=(95, 90, 85))
+    found = report["candidates"][q]
+    assert found["verdict"] == verdict, found
+    assert found["bound"] == {95: 0.0275, 90: 0.0225, 85: ladder.TAIL_BOUND}[q]          # no metric reads the 85th here
+    assert len(report["runs"]) == 6 and report["runs"][0]["n"] == len(runs[0]["itl_ms"]) and set(ladder.RUNGS) < set(report["runs"][0])
+    assert set(report["line"]) == {"itl_mean_ms", "setup_s"} and report["line"]["setup_s"]["within"]
+    # a cell on the 90th's list goes to the highest candidate that reads steady: the 95th off the cliff, the 85th under a ramp or where the 95th has too few
+    assert (report["judged"], report["chosen"]) == (90, {"cliff": 95, "ramp": 85, "few": 85}[kind])
+    if kind == "cliff":
+        # the sweep finds the two classes and nothing on the cliff between them
+        assert {84, 85, 94, 95} <= set(report["steady_at"]) and not {87, 88, 89, 90} & set(report["steady_at"])
+        assert ladder.choose(report["candidates"], 85) == 85 and ladder.choose(report["candidates"], None) == 95
+        text = ladder.render(report, [f"run{i}" for i in range(6)])
+        assert "itl_p90_ms: median 3" in text and text.endswith("judged by itl_p90_ms; the rule gives itl_p95_ms")
+        ladder_table, judged_table = ladder.markdown(report, "a", "six seeds").split("\n\n")
+        assert [len(row.split(" | ")) for row in ladder_table.splitlines()] == [3 + len(ladder.TABLE_RUNGS) + 3] * 3
+        assert "| `a` | six seeds | 6 x 2,177-2,177 | 1" in ladder_table and ladder_table.endswith("| itl_p90_ms -> itl_p95_ms |")
+        assert "| `itl_p90_ms` (half 1.12): 37." in judged_table and "| `itl_mean_ms` (half 3.00): 19." in judged_table
+        assert "**over**" not in judged_table and "setup_s" not in judged_table       # on the plateau here: the cliff is its lower flank's
+    with pytest.raises(ValueError):
+        ladder.read(runs[:2], manifest)
+
+
+STEADY, SPREADS, CLIFF, FEW = "steady", "spreads", "cliff above, spreads", "too few beyond"
+
+
+@pytest.mark.parametrize("said, judged, chosen", [
+    ({95: (STEADY, 0.2), 90: (STEADY, 0.9), 88: (STEADY, 0.1)}, 90, 90),          # a steady tail stays, whatever else is steady
+    ({95: (CLIFF, 0.2), 90: (SPREADS, 2.0), 88: (STEADY, 0.5)}, 90, 88),          # one that spreads goes to one that is steady,
+    ({95: (STEADY, 0.5), 90: (CLIFF, 2.0), 88: (STEADY, 0.1)}, 90, 95),           # the highest of them
+    ({95: (CLIFF, 1.5), 90: (SPREADS, 2.2), 88: (SPREADS, 1.9)}, 90, 90),         # every rung spreads: no move cures that (chat, laguna)
+    ({95: (SPREADS, 2.1), 90: (CLIFF, 5.2), 88: (CLIFF, 3.7)}, 90, 95),           # off a cliff to the one candidate that has none (mixed-queue)
+    ({95: (SPREADS, 1.4), 90: (CLIFF, 2.0), 88: (CLIFF, 0.7), 85: (SPREADS, 1.2)}, 90, 85),   # never ONTO a cliff for its smaller spread
+    ({95: (CLIFF, 1.4), 90: (CLIFF, 2.0), 88: (FEW, 0.7)}, 90, 90),               # a fault everywhere: it stays, and needs a file of its own
+    ({95: (CLIFF, 1.4), 90: (CLIFF, 2.0)}, None, None),                           # a new cell, the same
+    ({95: (SPREADS, 1.4), 90: (SPREADS, 1.1)}, None, 90),                         # a new cell, nothing steady: the soundest
+])
+def test_the_rule_moves_a_tail_off_a_fault_and_never_onto_one(said, judged, chosen):
+    found = {q: {"verdict": verdict, "drivers": spread / 100} for q, (verdict, spread) in said.items()}
+    assert ladder.choose(found, judged) == chosen
+
+
+def test_the_candidates_are_the_percentiles_a_file_makes_a_metric():
+    """A cell whose class no candidate meets is one data file (and its entry) away from one that does."""
+    listed = sorted((int(m["name"][5:-3]) for m in MANIFEST["end_to_end"] if re.fullmatch(r"itl_p\d+_ms", m["name"])), reverse=True)
+    assert ladder.candidates() == tuple(listed) and len(listed) >= 2
 
 
 def test_the_mean_gap_is_taken_over_every_gap_of_the_window():
