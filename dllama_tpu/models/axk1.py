@@ -58,9 +58,11 @@ from ..ops.norms import rms_norm
 from ..parallel.api import current_plan
 from ..runtime.introspection import note_mla_path
 from .config import ModelConfig
+from .family import Family, Refusal, layer_kinds
 from .llama import Params, _stack_at, _write_kv_rows
 from .rope import apply_rope_partial, build_partial_rope_cache, yarn_mscale
-from .share import ffn_half, zero_stats, zero_totals  # noqa: F401
+from .share import (ffn_half, require_quantized, zero_stats,  # noqa: F401
+                    zero_totals)
 
 
 class AxK1Layers(NamedTuple):
@@ -283,3 +285,100 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                        zero_stats(cfg), live, positions,
                                        attend)
     return logits, (PagedKVCache(k=pool, v=None), totals.at[0].add(stats))
+
+
+def _load_params(ld, cfg: ModelConfig) -> Params:
+    """From the tensors ``mfile._walk_axk1_layer`` names. ``W_dkv``'s plane
+    is padded with zero columns to ``cfg.latent_row`` (whole lane tiles for
+    the fused kernels); ``W_ukv`` is contracted per head on its plane's
+    output side in the absorbed form, so it is held per head in the compute
+    dtype (``wuk``, ``wuv [L, H, ., kv_lora]``), dequantized once here."""
+    require_quantized(ld)
+    h = ld.h
+    every = list(range(h.n_layers))
+    dense_ids, moe_ids = every[:h.n_dense_layers], every[h.n_dense_layers:]
+    mm = lambda ids, name, o, i, **kw: ld.matmul(
+        name, o, i, stacked=True, out_axis=None, in_axis=None, layers=ids,
+        **kw)
+    H, r, nope = h.n_heads, h.kv_lora_rank, h.qk_nope_head_dim
+    wdkv = mm(every, "block_mla_dkv", r + h.qk_rope_head_dim, h.dim)
+    pad = cfg.latent_row - cfg.latent_dim
+    wdkv = jax.tree.map(
+        lambda a: jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, pad),)), wdkv)
+    wukv = mm(every, "block_mla_ukv", H * (nope + h.v_head_dim), r,
+              force_dense=jnp.dtype(cfg.compute_dtype)).reshape(
+        h.n_layers, H, nope + h.v_head_dim, r)
+    wide, sh = h.dense_hidden_dim, h.shared_expert_dim
+    experts = lambda name, o, i: ld.expert_stack(name, o, i, None, None,
+                                                 layers=moe_ids)
+    return ld.params(AxK1Layers(
+        wdq=mm(every, "block_mla_dq", h.q_lora_rank, h.dim),
+        norm_qa=ld.stacked_f32("block_mla_norm_q", h.q_lora_rank),
+        wuq=mm(every, "block_mla_uq", H * h.head_dim, h.q_lora_rank),
+        wdkv=wdkv,
+        norm_kva=ld.stacked_f32("block_mla_norm_kv", r),
+        wuk=wukv[:, :, :nope], wuv=wukv[:, :, nope:],
+        wo=mm(every, "block_matmul_wo", h.dim, H * h.v_head_dim),
+        norm_att=ld.stacked_f32("block_norm_0", h.dim),
+        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
+        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim),
+        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide),
+        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim),
+        moe_gate=ld.stacked_f32("block_moe_gate", h.moe_router_width, h.dim,
+                                layers=moe_ids),
+        we1=experts("block_expert_w1", h.hidden_dim, h.dim),
+        we2=experts("block_expert_w2", h.dim, h.hidden_dim),
+        we3=experts("block_expert_w3", h.hidden_dim, h.dim),
+        ws1=mm(moe_ids, "block_shared_w1", sh, h.dim) if sh else None,
+        ws2=mm(moe_ids, "block_shared_w2", h.dim, sh) if sh else None,
+        ws3=mm(moe_ids, "block_shared_w3", sh, h.dim) if sh else None))
+
+
+def _matmul_weight_count(cfg: ModelConfig) -> int:
+    # what is HELD: latent attention's planes a layer (W_ukv per head in
+    # the compute dtype: two Q40 weights' bytes a weight), the
+    # leading dense feed-forward, the held experts of a routed layer with its
+    # router (over its whole width) and shared expert, the vocabulary's
+    # rows
+    H = cfg.n_heads
+    attn = (cfg.dim * (cfg.q_lora_rank + cfg.latent_row)
+            + cfg.q_lora_rank * H * cfg.head_dim
+            + 2 * cfg.kv_lora_rank * H * (cfg.qk_nope_dim + cfg.v_head_dim)
+            + H * cfg.v_head_dim * cfg.dim)
+    routed = (cfg.dim * cfg.moe_router_width
+              + 3 * cfg.dim * (cfg.hidden_dim * cfg.n_experts
+                               + cfg.shared_expert_dim))
+    return (cfg.n_layers * attn
+            + cfg.n_dense_layers * 3 * cfg.dim * cfg.dense_hidden_dim
+            + cfg.n_moe_layers * routed + cfg.dim * cfg.vocab_size)
+
+
+FAMILY = Family(
+    forward=forward,
+    paged_forward=paged_forward,
+    tick=None,
+    # the slot's latent rows through its table, matched prefix blocks
+    # included: the chunks attend over them as they lie
+    column=lambda cfg, k, v: LatentColumn(c=k, stats=zero_stats(cfg)),
+    load_params=_load_params,
+    matmul_weight_count=_matmul_weight_count,
+    layer_kinds=lambda cfg: layer_kinds(latent=cfg.n_layers),
+    describe=lambda cfg, engine: (
+        f"; layers: {cfg.n_layers} of latent attention (a row of "
+        f"{cfg.latent_dim} in {cfg.latent_row} lanes a token); "
+        f"experts: {cfg.n_experts} of {cfg.moe_router_width} held "
+        f"from {cfg.moe_first_expert}, {cfg.n_active_experts} a "
+        f"token of {cfg.moe_topk_group or 1} of "
+        f"{cfg.moe_n_group or 1} groups"),
+    refusal=Refusal(
+        what=("a decoder with latent attention and an expert share (one "
+              "pool of compressed rows a sequence; the layer scan has no "
+              "mesh plan yet, the share's exchange between chips is not "
+              "built)"),
+        carries="the latent pool",
+        spec_lookup=("the latent walk takes one token a row; a verify's "
+                     "lanes would each need a bound of their own"),
+        kv_host_blocks=("the host tier's transfer programs, and with them "
+                        "kvwire export/ingest and mid-stream resume, frame "
+                        "a block as K and V planes; a latent pool's block "
+                        "is one plane of compressed rows")))

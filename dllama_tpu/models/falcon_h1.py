@@ -65,7 +65,9 @@ from ..ops.linear import Weight, linear
 from ..ops.norms import rms_norm
 from ..parallel.api import current_plan
 from ..runtime.introspection import note_ssd_path
+from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
+from .family import Family, layer_kinds, state_refusal
 from .llama import (Params, _attend_dense, _attend_paged, _hidden_act,
                     _stack_at)
 from .rope import apply_rope, build_rope_cache
@@ -232,8 +234,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     :class:`~dllama_tpu.runtime.kvblocks.StateColumn`: float32 logits ``[B,
     T, vocab]`` and the column, advanced by the chunk's first ``n_valid``
     positions (absent: all ``T``)."""
-    from ..runtime.kvblocks import StateColumn
-
     _check(cfg)
     start_pos = jnp.asarray(start_pos, dtype=jnp.int32)
     if start_pos.ndim:
@@ -298,3 +298,59 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                          pool.s, pool.conv, pkv.k, pkv.v,
                                          mixer, attend)
     return logits, (PagedKVCache(k=k, v=v), StatePool(s=s, conv=conv))
+
+
+def _load_params(ld, cfg: ModelConfig) -> Params:
+    """The one homogeneous stack from the tensors
+    ``mfile._walk_falcon_h1_layer`` names."""
+    h = ld.h
+    mm = lambda name, o, i: ld.matmul(name, o, i, stacked=True,
+                                      out_axis=None, in_axis=None)
+    f32 = ld.stacked_f32
+    return ld.params(FalconH1Layers(
+        wq=mm("block_matmul_q", h.q_dim, h.dim),
+        wk=mm("block_matmul_k", h.kv_dim, h.dim),
+        wv=mm("block_matmul_v", h.kv_dim, h.dim),
+        wo=mm("block_matmul_wo", h.dim, h.q_dim),
+        w_in=mm("block_ssm_in", h.ssm_in_dim, h.dim),
+        w_dt=f32("block_ssm_dt", h.ssm_n_heads, h.dim),
+        conv_w=f32("block_ssm_conv", h.ssm_conv_kernel, h.ssm_conv_dim),
+        conv_b=f32("block_ssm_conv_bias", h.ssm_conv_dim),
+        a_log=f32("block_ssm_a_log", h.ssm_n_heads),
+        d_skip=f32("block_ssm_d", h.ssm_n_heads),
+        dt_bias=f32("block_ssm_dt_bias", h.ssm_n_heads),
+        norm_ssm=f32("block_ssm_norm", h.ssm_inner_dim),
+        w_out=mm("block_ssm_out", h.dim, h.ssm_inner_dim),
+        w1=mm("block_matmul_w1", h.hidden_dim, h.dim),
+        w2=mm("block_matmul_w2", h.dim, h.hidden_dim),
+        w3=mm("block_matmul_w3", h.hidden_dim, h.dim),
+        norm_att=f32("block_norm_0", h.dim),
+        norm_ffn=f32("block_norm_1", h.dim)))
+
+
+def _matmul_weight_count(cfg: ModelConfig) -> int:
+    # one kind of layer: q k v wo, the mixer's packed input projection
+    # (its dt rows are a small float32 plane, not counted here) and
+    # output projection, a dense feed-forward
+    layer = (cfg.dim * cfg.q_dim + 2 * cfg.dim * cfg.kv_dim
+             + cfg.q_dim * cfg.dim + cfg.dim * cfg.ssm_in_dim
+             + cfg.ssm_inner_dim * cfg.dim + 3 * cfg.dim * cfg.hidden_dim)
+    return cfg.n_layers * layer + cfg.dim * cfg.vocab_size
+
+
+FAMILY = Family(
+    forward=forward,
+    paged_forward=paged_forward,
+    tick=None,
+    column=StateColumn.zeros,
+    load_params=_load_params,
+    matmul_weight_count=_matmul_weight_count,
+    # a layer with an SSD mixer beside its attention is neither linear nor
+    # full
+    layer_kinds=lambda cfg: layer_kinds(ssm_beside_full=cfg.n_layers),
+    describe=lambda cfg, engine: (f"; layers: {cfg.n_layers} with an SSD "
+                                  f"mixer beside attention"),
+    refusal=state_refusal(
+        "a decoder with an SSD mixer beside attention in every layer (a "
+        "recurrent state a layer; the layer scan carries the state pool and "
+        "has no mesh plan yet)"))

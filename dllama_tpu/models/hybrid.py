@@ -65,6 +65,7 @@ from ..parallel.api import current_plan
 from ..runtime.introspection import note_gdn_path
 from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
+from .family import Family, layer_kinds, state_refusal
 from .llama import (LayerParams, Params, _attend_dense, _attend_paged,
                     _hidden_act, _layer_at, _stack_at)
 
@@ -329,3 +330,78 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                           pkv.k, pkv.v, mixer,
                                           lambda _a, new, _l: new, attend)
     return logits, (PagedKVCache(k=k, v=v), StatePool(s=s, conv=conv))
+
+
+def _load_params(ld, cfg: ModelConfig) -> Params:
+    """The two stacks from the tensors ``mfile._walk_hybrid_layer`` names:
+    the linear layers' and the full layers', each stacked over its own
+    layers of the model."""
+    h = ld.h
+    P = h.layer_period
+    lin_ids = [l for l in range(h.n_layers) if (l + 1) % P]
+    full_ids = [l for l in range(h.n_layers) if (l + 1) % P == 0]
+    vdim = h.linear_n_value_heads * h.linear_value_head_dim
+
+    def stack(ids):
+        mm = lambda name, o, i, **kw: ld.matmul(
+            name, o, i, stacked=True, out_axis=None, in_axis=None,
+            layers=ids, **kw)
+        f32 = lambda name, *tail: ld.stacked_f32(name, *tail, layers=ids)
+        return mm, f32
+
+    mm, f32 = stack(lin_ids)
+    lin = LinearLayerParams(
+        w_in=mm("block_gdn_in", h.linear_in_dim, h.dim),
+        w_ab=f32("block_gdn_ab", 2 * h.linear_n_value_heads, h.dim),
+        conv_w=f32("block_gdn_conv", h.linear_conv_kernel, h.linear_conv_dim),
+        a_log=f32("block_gdn_a_log", h.linear_n_value_heads),
+        dt_bias=f32("block_gdn_dt_bias", h.linear_n_value_heads),
+        norm_o=f32("block_gdn_norm", h.linear_value_head_dim),
+        w_out=mm("block_gdn_out", h.dim, vdim),
+        w1=mm("block_matmul_w1", h.hidden_dim, h.dim),
+        w2=mm("block_matmul_w2", h.dim, h.hidden_dim),
+        w3=mm("block_matmul_w3", h.hidden_dim, h.dim),
+        norm_att=f32("block_norm_0", h.dim),
+        norm_ffn=f32("block_norm_1", h.dim))
+    mm, f32 = stack(full_ids)
+    full = LayerParams(
+        wq=mm("block_matmul_q", h.q_dim, h.dim),
+        wk=mm("block_matmul_k", h.kv_dim, h.dim),
+        wv=mm("block_matmul_v", h.kv_dim, h.dim),
+        wo=mm("block_matmul_wo", h.dim, h.q_dim),
+        w1=mm("block_matmul_w1", h.hidden_dim, h.dim),
+        w2=mm("block_matmul_w2", h.dim, h.hidden_dim),
+        w3=mm("block_matmul_w3", h.hidden_dim, h.dim),
+        norm_att=f32("block_norm_0", h.dim),
+        norm_ffn=f32("block_norm_1", h.dim),
+        norm_q=f32("block_norm_q", h.q_dim),
+        norm_k=f32("block_norm_k", h.kv_dim))
+    return ld.params(HybridLayers(lin=lin, full=full))
+
+
+def _matmul_weight_count(cfg: ModelConfig) -> int:
+    # two kinds of layer: the mixer's packed input projection and its
+    # output projection, or q k v wo; a dense feed-forward in both
+    ffn = 3 * cfg.dim * cfg.hidden_dim
+    lin = (cfg.dim * cfg.lin_in_dim
+           + cfg.lin_heads * cfg.lin_value_dim * cfg.dim + ffn)
+    full = (cfg.dim * cfg.q_dim + 2 * cfg.dim * cfg.kv_dim
+            + cfg.q_dim * cfg.dim + ffn)
+    return (cfg.n_linear_layers * lin + cfg.n_kv_layers * full
+            + cfg.dim * cfg.vocab_size)
+
+
+FAMILY = Family(
+    forward=forward,
+    paged_forward=paged_forward,
+    tick=None,
+    column=StateColumn.zeros,
+    load_params=_load_params,
+    matmul_weight_count=_matmul_weight_count,
+    layer_kinds=lambda cfg: layer_kinds(linear=cfg.n_linear_layers,
+                                        full=cfg.n_kv_layers),
+    describe=lambda cfg, engine: (f"; layers: {cfg.n_linear_layers} linear, "
+                                  f"{cfg.n_kv_layers} full"),
+    refusal=state_refusal(
+        "a hybrid decoder (linear-attention layers with a recurrent state; "
+        "the period scan has no mesh plan yet)"))

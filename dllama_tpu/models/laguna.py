@@ -67,10 +67,11 @@ from ..ops.norms import rms_norm
 from ..parallel.api import current_plan
 from ..runtime.kvcache import update_layer
 from .config import ModelConfig
+from .family import Family, Refusal, layer_kinds
 from .llama import Params, _attend_dense, _attend_paged, _stack_at
 from .rope import apply_rope_partial, build_partial_rope_cache
-from .share import (ffn_half, route, routed_ffn, routed_pairs,  # noqa: F401
-                    zero_stats, zero_totals)
+from .share import (ffn_half, require_quantized, route,  # noqa: F401
+                    routed_ffn, routed_pairs, zero_stats, zero_totals)
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -330,3 +331,92 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         positions, attend_full, attend_slide)
     return logits, (PagedKVCache(k=fk, v=fv), PagedKVCache(k=wk, v=wv),
                     totals.at[0].add(stats))
+
+
+def _load_params(ld, cfg: ModelConfig) -> Params:
+    """From the tensors ``mfile._walk_laguna_layer`` names: two attention
+    stacks by layer kind, the leading dense layers' feed-forward, and the
+    routed layers' router, HELD experts and shared expert, each stacked over
+    its own layers of the model."""
+    require_quantized(ld)
+    h = ld.h
+    P, hd = h.layer_period, h.head_dim
+    every = list(range(h.n_layers))
+    full_ids = [l for l in every if l % P == 0]
+    slide_ids = [l for l in every if l % P]
+    dense_ids, moe_ids = every[:h.n_dense_layers], every[h.n_dense_layers:]
+    mm = lambda ids, name, o, i: ld.matmul(
+        name, o, i, stacked=True, out_axis=None, in_axis=None, layers=ids)
+
+    def attn(ids, heads):
+        return AttnParams(
+            wq=mm(ids, "block_matmul_q", heads * hd, h.dim),
+            wk=mm(ids, "block_matmul_k", h.kv_dim, h.dim),
+            wv=mm(ids, "block_matmul_v", h.kv_dim, h.dim),
+            wo=mm(ids, "block_matmul_wo", h.dim, heads * hd),
+            wg=ld.stacked_f32("block_attn_gate", heads, h.dim, layers=ids),
+            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=ids))
+
+    wide, sh = h.dense_hidden_dim, h.shared_expert_dim
+    experts = lambda name, o, i: ld.expert_stack(name, o, i, None, None,
+                                                 layers=moe_ids)
+    return ld.params(LagunaLayers(
+        full=attn(full_ids, h.n_heads),
+        slide=attn(slide_ids, h.n_heads_sliding),
+        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
+        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim),
+        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide),
+        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim),
+        moe_gate=ld.stacked_f32("block_moe_gate", h.moe_router_width, h.dim,
+                                layers=moe_ids),
+        we1=experts("block_expert_w1", h.hidden_dim, h.dim),
+        we2=experts("block_expert_w2", h.dim, h.hidden_dim),
+        we3=experts("block_expert_w3", h.hidden_dim, h.dim),
+        ws1=mm(moe_ids, "block_shared_w1", sh, h.dim) if sh else None,
+        ws2=mm(moe_ids, "block_shared_w2", h.dim, sh) if sh else None,
+        ws3=mm(moe_ids, "block_shared_w3", sh, h.dim) if sh else None))
+
+
+def _matmul_weight_count(cfg: ModelConfig) -> int:
+    # what is HELD: two kinds of attention layer at their own head
+    # counts, the leading dense feed-forward, the held experts of a
+    # routed layer with its router (over its whole width) and shared
+    # expert, the vocabulary's rows
+    attn = lambda heads: 2 * cfg.dim * (heads * cfg.head_dim + cfg.kv_dim)
+    routed = (cfg.dim * cfg.moe_router_width
+              + 3 * cfg.dim * (cfg.hidden_dim * cfg.n_experts
+                               + cfg.shared_expert_dim))
+    return (cfg.n_kv_layers * attn(cfg.n_heads)
+            + cfg.n_window_layers * attn(cfg.n_heads_sliding)
+            + cfg.n_dense_layers * 3 * cfg.dim * cfg.dense_hidden_dim
+            + cfg.n_moe_layers * routed + cfg.dim * cfg.vocab_size)
+
+
+FAMILY = Family(
+    forward=forward,
+    paged_forward=paged_forward,
+    tick=None,
+    # prefix blocks are never shared here, so an admission's column starts
+    # empty (the slot's gathered view is not read); every layer's rows are
+    # built in it, the two pools see them at commit
+    column=lambda cfg, k, v: LagunaColumn.zeros(cfg, k.dtype),
+    load_params=_load_params,
+    matmul_weight_count=_matmul_weight_count,
+    layer_kinds=lambda cfg: layer_kinds(full=cfg.n_kv_layers,
+                                        sliding=cfg.n_window_layers),
+    describe=lambda cfg, engine: (
+        f"; layers: {cfg.n_kv_layers} full, {cfg.n_window_layers} "
+        f"sliding (window {cfg.sliding_window}); experts: "
+        f"{cfg.n_experts} of {cfg.moe_router_width} held from "
+        f"{cfg.moe_first_expert}, {cfg.n_active_experts} a token"),
+    refusal=Refusal(
+        what=("a decoder with window layers and an expert share (two block "
+              "pools a sequence; the period scan has no mesh plan yet, the "
+              "share's exchange between chips is not built)"),
+        carries="the two block pools",
+        spec_lookup=("a sliding window's walk takes one token a row; a "
+                     "verify's lanes would each need a window of their own"),
+        kv_host_blocks=("the host tier keeps one list of blocks by token "
+                        "range; the window pool's blocks behind the window "
+                        "are gone, and with them kvwire export/ingest and "
+                        "mid-stream resume")))

@@ -61,9 +61,10 @@ from ..parallel.api import current_plan
 from ..runtime.introspection import note_short_conv_path
 from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
+from .family import Family, layer_kinds, state_refusal
 from .llama import Params, _attend_dense, _attend_paged, _stack_at
 from .rope import apply_rope_partial, build_partial_rope_cache
-from .share import _plane, ffn_half, swiglu, zero_stats
+from .share import _plane, ffn_half, require_quantized, swiglu, zero_stats
 
 
 class ConvParams(NamedTuple):
@@ -314,3 +315,100 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         positions, conv_mixer, attend)
     return logits, (PagedKVCache(k=k, v=v), StatePool(s=None, conv=conv),
                     totals.at[0].add(stats))
+
+
+def _load_params(ld, cfg: ModelConfig) -> Params:
+    """From the tensors ``mfile._walk_lfm2_layer`` names: two mixer stacks
+    by layer kind, the leading layers' dense feed-forward, the routed
+    layers' router, selection bias and HELD experts, each stacked over its
+    own layers of the model."""
+    require_quantized(ld)
+    h = ld.h
+    every = list(range(h.n_layers))
+    attn_ids = [l for l in every if h.lfm2_is_attn(l)]
+    conv_ids = [l for l in every if not h.lfm2_is_attn(l)]
+    dense_ids, moe_ids = every[:h.n_dense_layers], every[h.n_dense_layers:]
+    mm = lambda ids, name, o, i: ld.matmul(
+        name, o, i, stacked=True, out_axis=None, in_axis=None, layers=ids)
+    wide = h.dense_hidden_dim
+    experts = lambda name, o, i: ld.expert_stack(name, o, i, None, None,
+                                                 layers=moe_ids)
+    return ld.params(Lfm2Layers(
+        conv=ConvParams(
+            w_in=mm(conv_ids, "block_conv_in", 3 * h.dim, h.dim),
+            conv_w=ld.stacked_f32("block_conv_taps", h.short_conv_kernel,
+                                  h.dim, layers=conv_ids),
+            w_out=mm(conv_ids, "block_conv_out", h.dim, h.dim),
+            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=conv_ids)),
+        attn=AttnParams(
+            wq=mm(attn_ids, "block_matmul_q", h.q_dim, h.dim),
+            wk=mm(attn_ids, "block_matmul_k", h.kv_dim, h.dim),
+            wv=mm(attn_ids, "block_matmul_v", h.kv_dim, h.dim),
+            wo=mm(attn_ids, "block_matmul_wo", h.dim, h.q_dim),
+            norm_q=ld.stacked_f32("block_norm_q", h.head_dim,
+                                  layers=attn_ids),
+            norm_k=ld.stacked_f32("block_norm_k", h.head_dim,
+                                  layers=attn_ids),
+            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=attn_ids)),
+        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
+        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim),
+        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide),
+        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim),
+        moe_gate=ld.stacked_f32("block_moe_gate", h.moe_router_width, h.dim,
+                                layers=moe_ids),
+        moe_bias=(ld.stacked_f32("block_moe_bias", h.moe_router_width,
+                                 layers=moe_ids)
+                  if h.moe_select_bias else None),
+        we1=experts("block_expert_w1", h.hidden_dim, h.dim),
+        we2=experts("block_expert_w2", h.dim, h.hidden_dim),
+        we3=experts("block_expert_w3", h.hidden_dim, h.dim)))
+
+
+def _matmul_weight_count(cfg: ModelConfig) -> int:
+    # two kinds of mixer (the conv layers' in- and out-projection, or
+    # q k v wo), the leading dense feed-forward, the held experts of a
+    # routed layer with its router over its whole width
+    conv = 4 * cfg.dim * cfg.dim
+    attn = 2 * cfg.dim * (cfg.q_dim + cfg.kv_dim)
+    routed = (cfg.dim * cfg.moe_router_width
+              + 3 * cfg.dim * cfg.hidden_dim * cfg.n_experts)
+    return (cfg.n_conv_layers * conv + cfg.n_attn_layers * attn
+            + cfg.n_dense_layers * 3 * cfg.dim * cfg.dense_hidden_dim
+            + cfg.n_moe_layers * routed + cfg.dim * cfg.vocab_size)
+
+
+def _describe(cfg: ModelConfig, engine) -> str:
+    from ..ops import paged_attention as _pa
+
+    # the step's attention at this engine's geometry: what the paged
+    # kernel's gate would say of the padded heads on a chip
+    step_q = (1, 1, cfg.n_heads, cfg.cache_width)
+    compiled = _pa.supports(
+        step_q, cfg.n_kv_heads,
+        -(-cfg.seq_len // max(1, engine.kv_block_size)),
+        max(1, engine.kv_block_size), compiled=True)
+    return (f"; layers: {cfg.n_conv_layers} conv ({cfg.conv_kernel} "
+            f"taps, a tail of {cfg.conv_kernel - 1} x {cfg.dim} a "
+            f"sequence), {cfg.n_attn_layers} full (heads of "
+            f"{cfg.head_dim} lanes cached in {cfg.cache_width}: the "
+            f"paged kernel {'compiles' if compiled else 'does NOT compile'}"
+            f" for them); experts: {cfg.n_experts} of "
+            f"{cfg.moe_router_width} held from {cfg.moe_first_expert}, "
+            f"{cfg.n_active_experts} a token"
+            f"{', selection bias' if cfg.moe_select_bias else ''}")
+
+
+FAMILY = Family(
+    forward=forward,
+    paged_forward=paged_forward,
+    tick=None,
+    column=StateColumn.zeros,
+    load_params=_load_params,
+    matmul_weight_count=_matmul_weight_count,
+    layer_kinds=lambda cfg: layer_kinds(full=cfg.n_kv_layers,
+                                        conv=cfg.n_conv_layers),
+    describe=_describe,
+    refusal=state_refusal(
+        "a decoder with gated short-convolution layers and routed experts "
+        "(a convolution's tail a conv layer in the state pool, routing "
+        "counters beside it; the period scan has no mesh plan yet)"))

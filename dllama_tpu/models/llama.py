@@ -49,6 +49,7 @@ from ..parallel.api import current_plan as _current_plan
 from ..runtime import numerics as _numerics
 from ..runtime.kvcache import KVCache, update_layer
 from .config import ModelConfig
+from .family import Family, family_of, layer_kinds
 from .rope import apply_rope, build_rope_cache
 
 
@@ -982,26 +983,14 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     (runtime/serving.py), where each slot of the batch is its own sequence
     at its own depth. One compilation per ``T`` either way.
     """
-    if cfg.is_hybrid:
-        from . import hybrid
+    return family_of(cfg).forward(params, cfg, tokens, start_pos, kv, n_valid)
 
-        return hybrid.forward(params, cfg, tokens, start_pos, kv, n_valid)
-    if cfg.has_ssm:
-        from . import falcon_h1
 
-        return falcon_h1.forward(params, cfg, tokens, start_pos, kv, n_valid)
-    if cfg.has_window_layers:
-        from . import laguna
-
-        return laguna.forward(params, cfg, tokens, start_pos, kv, n_valid)
-    if cfg.has_latent_cache:
-        from . import axk1
-
-        return axk1.forward(params, cfg, tokens, start_pos, kv, n_valid)
-    if cfg.has_short_conv:
-        from . import lfm2
-
-        return lfm2.forward(params, cfg, tokens, start_pos, kv, n_valid)
+def _dense_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                   start_pos: jax.Array, kv: KVCache, n_valid=None):
+    """:func:`forward` of the dense decoders (the Llama and the Qwen3
+    equations): one scan over the stacked layers, the cache's layers as
+    xs/ys. ``n_valid`` is not theirs."""
     start_pos = jnp.asarray(start_pos, dtype=jnp.int32)
     ragged = start_pos.ndim > 0
     # numerics observatory taps (runtime/numerics): a TRACE-TIME flag, so
@@ -1312,33 +1301,17 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     (speculative verify: per-row valid input width minus one, i.e. the
     row's draft length) masks KV writes for lanes past it to the null
     block — see :func:`_paged_layer_step`."""
+    return family_of(cfg).paged_forward(params, cfg, tokens, pos_vec, pkv,
+                                        tables, write_lens)
+
+
+def _dense_paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                         pos_vec: jax.Array, pkv, tables: jax.Array,
+                         write_lens: jax.Array | None = None):
+    """:func:`paged_forward` of the dense decoders: one scan over the layer
+    index, the pool's two planes whole in its carry."""
     from ..runtime.kvblocks import PagedKVCache
 
-    if cfg.is_hybrid:
-        from . import hybrid
-
-        return hybrid.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
-                                    write_lens)
-    if cfg.has_ssm:
-        from . import falcon_h1
-
-        return falcon_h1.paged_forward(params, cfg, tokens, pos_vec, pkv,
-                                       tables, write_lens)
-    if cfg.has_window_layers:
-        from . import laguna
-
-        return laguna.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
-                                    write_lens)
-    if cfg.has_latent_cache:
-        from . import axk1
-
-        return axk1.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
-                                  write_lens)
-    if cfg.has_short_conv:
-        from . import lfm2
-
-        return lfm2.paged_forward(params, cfg, tokens, pos_vec, pkv, tables,
-                                  write_lens)
     if _numerics.taps_active():
         raise ValueError("numerics taps are unsupported on the paged KV "
                          "path (use the dense slot pool for tap sessions)")
@@ -1660,3 +1633,72 @@ def init_random_params(cfg: ModelConfig, seed: int = 0, scale: float = 0.02,
         logits=(quantize_weight_q40(logits) if quantized
                 else jnp.asarray(logits, dtype=dtype)),
     )
+
+
+# ---------------------------------------------------------------------------
+# The dense decoders as a family (models/family.py)
+# ---------------------------------------------------------------------------
+
+
+def _load_params(ld, cfg: ModelConfig) -> Params:
+    """The dense decoders' one stack from the tensors ``mfile``'s walk
+    names, through the streaming loader (runtime/weights.py)."""
+    h = ld.h
+    moe = h.n_experts > 0
+    qk_norm = cfg.uses_qk_norm
+    # Under offload only the per-layer stacks go host-side: they are the
+    # O(model) bytes and stream through the scan; embedding / final norm /
+    # logits are used outside it and stay resident in device memory.
+    ld.host_scope = True
+    layers = LayerParams(
+        wq=ld.matmul("block_matmul_q", h.q_dim, h.dim, stacked=True,
+                     out_axis="heads", in_axis=None),
+        wk=ld.matmul("block_matmul_k", h.kv_dim, h.dim, stacked=True,
+                     out_axis="kv_heads", in_axis=None),
+        wv=ld.matmul("block_matmul_v", h.kv_dim, h.dim, stacked=True,
+                     out_axis="kv_heads", in_axis=None),
+        wo=ld.matmul("block_matmul_wo", h.dim, h.q_dim, stacked=True,
+                     out_axis=None, in_axis="heads"),
+        w1=None if moe else ld.matmul("block_matmul_w1", h.hidden_dim, h.dim,
+                                      stacked=True, out_axis="hidden", in_axis=None),
+        w2=None if moe else ld.matmul("block_matmul_w2", h.dim, h.hidden_dim,
+                                      stacked=True, out_axis=None, in_axis="hidden"),
+        w3=None if moe else ld.matmul("block_matmul_w3", h.hidden_dim, h.dim,
+                                      stacked=True, out_axis="hidden", in_axis=None),
+        norm_att=ld.stacked_f32("block_norm_0", h.dim),
+        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
+        norm_q=ld.stacked_f32("block_norm_q", h.head_dim) if qk_norm else None,
+        norm_k=ld.stacked_f32("block_norm_k", h.head_dim) if qk_norm else None,
+        moe_gate=ld.stacked_f32("block_moe_gate", h.n_experts, h.dim) if moe else None,
+        we1=(ld.expert_stack("block_expert_w1", h.hidden_dim, h.dim,
+                             "hidden", None) if moe else None),
+        we2=(ld.expert_stack("block_expert_w2", h.dim, h.hidden_dim,
+                             None, "hidden") if moe else None),
+        we3=(ld.expert_stack("block_expert_w3", h.hidden_dim, h.dim,
+                             "hidden", None) if moe else None),
+    )
+    ld.host_scope = False
+    return ld.params(layers)
+
+
+def _matmul_weight_count(cfg: ModelConfig) -> int:
+    per_layer = (cfg.dim * cfg.q_dim + 2 * cfg.dim * cfg.kv_dim
+                 + cfg.q_dim * cfg.dim)
+    if cfg.is_moe:
+        per_layer += (3 * cfg.dim * cfg.hidden_dim * cfg.n_experts
+                      + cfg.dim * cfg.n_experts)
+    else:
+        per_layer += 3 * cfg.dim * cfg.hidden_dim
+    return cfg.n_layers * per_layer + cfg.dim * cfg.vocab_size  # + lm head
+
+
+FAMILY = Family(
+    forward=_dense_forward,
+    paged_forward=_dense_paged_forward,
+    tick=forward_and_step,
+    column=lambda cfg, k, v: KVCache(k=k, v=v),
+    load_params=_load_params,
+    matmul_weight_count=_matmul_weight_count,
+    layer_kinds=lambda cfg: layer_kinds(full=cfg.n_layers),
+    describe=lambda cfg, engine: "",
+    refusal=None)

@@ -90,6 +90,16 @@ def zero_totals(cfg: ModelConfig) -> jax.Array:
     return jnp.zeros((2, N_COUNTS + cfg.n_experts), jnp.int32)
 
 
+def require_quantized(ld) -> None:
+    """A routed family's loader (runtime/weights.StreamingLoader ``ld``)
+    refuses a file whose expert stacks would load dense."""
+    if not ld.quantized:
+        raise ValueError(
+            f"a {ld.h.arch_type.name} file's matmul planes must be Q40 or "
+            f"Q80: the routed decode kernel (ops/expert_gemv.py) and its "
+            f"XLA form read quantized expert stacks")
+
+
 def swiglu(cfg: ModelConfig, h: jax.Array, w1, w2, w3) -> jax.Array:
     gate = _hidden_act(cfg, linear(h, w1))
     return linear(gate * linear(h, w3), w2)

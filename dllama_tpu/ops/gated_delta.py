@@ -43,8 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .causal_conv import causal_conv  # noqa: F401  (its first home)
-
 SUB_CHUNK = 64
 L2_EPS = 1e-6
 # the mixer's small matmuls feed a float32 state that is carried over

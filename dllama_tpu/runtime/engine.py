@@ -31,6 +31,7 @@ import numpy as np
 from ..formats.mfile import ModelFile
 from ..formats.quants import F32, Q80
 from ..models.config import ModelConfig
+from ..models.family import family_of
 from ..models.llama import (
     Params,
     forward,
@@ -221,63 +222,22 @@ class InferenceEngine:
                 f"spec_lookup {self.spec_lookup} exceeds the control packet's "
                 f"{self.packet_slots} token slots (raise --nbatches)")
 
-        if self.cfg.paged_only:
-            # a recurrent state beside K/V (models/hybrid.py: in the linear
-            # layers of a pattern; models/falcon_h1.py: in every layer,
-            # beside attention), or window
-            # layers with a block pool of their own and an expert share
-            # (models/laguna.py): what this engine does not carry to them
-            # is refused HERE, by flag and reason; nothing is silently
-            # ignored and no code stands in
+        refusal = family_of(self.cfg).refusal
+        if refusal is not None:
+            # a slot's context that is more than one list of K/V blocks (a
+            # recurrent state, a second pool, a latent pool: the family
+            # says which, models/family.py): what this engine does not
+            # carry to it is refused HERE, by flag and reason; nothing is
+            # silently ignored and no code stands in
             tp = 1 if tp is None else tp
-            state = self.cfg.has_state
-            latent = self.cfg.has_latent_cache
-            what = ("a decoder with latent attention and an expert share "
-                    "(one pool of compressed rows a sequence; the layer scan "
-                    "has no mesh plan yet, the share's exchange between "
-                    "chips is not built)"
-                    if latent else
-                    "a decoder with gated short-convolution layers and "
-                    "routed experts (a convolution's tail a conv layer in "
-                    "the state pool, routing counters beside it; the period "
-                    "scan has no mesh plan yet)"
-                    if self.cfg.has_short_conv else
-                    "a decoder with an SSD mixer beside attention in every "
-                    "layer (a recurrent state a layer; the layer scan "
-                    "carries the state pool and has no mesh plan yet)"
-                    if self.cfg.has_ssm else
-                    "a hybrid decoder (linear-attention layers with a "
-                    "recurrent state; the period scan has no mesh plan yet)"
-                    if state else
-                    "a decoder with window layers and an expert share (two "
-                    "block pools a sequence; the period scan has no mesh "
-                    "plan yet, the share's exchange between chips is not "
-                    "built)")
             unsupported = [
                 ("no --kv-block-size (the dense slot pool, and the "
                  "single-sequence inference/chat/perplexity path: only the "
-                 "paged generator carries "
-                 + ("the state pool)" if state else "the latent pool)"
-                    if latent else "the two block pools)"),
+                 f"paged generator carries {refusal.carries})",
                  not int(kv_block_size or 0)),
-                ("--spec-lookup (a rejected draft cannot be rolled back "
-                 "out of a recurrent state)" if state else
-                 "--spec-lookup (the latent walk takes one token a row; a "
-                 "verify's lanes would each need a bound of their own)"
-                 if latent else
-                 "--spec-lookup (a sliding window's walk takes one token a "
-                 "row; a verify's lanes would each need a window of their "
-                 "own)", self.spec_lookup > 0),
-                ("--kv-host-blocks (the host tier spills and pages in K/V "
-                 "blocks; a state has no host copy)" if state else
-                 "--kv-host-blocks (the host tier's transfer programs, and "
-                 "with them kvwire export/ingest and mid-stream resume, "
-                 "frame a block as K and V planes; a latent pool's block is "
-                 "one plane of compressed rows)" if latent else
-                 "--kv-host-blocks (the host tier keeps one list of blocks "
-                 "by token range; the window pool's blocks behind the "
-                 "window are gone, and with them kvwire export/ingest and "
-                 "mid-stream resume)",
+                (f"--spec-lookup ({refusal.spec_lookup})",
+                 self.spec_lookup > 0),
+                (f"--kv-host-blocks ({refusal.kv_host_blocks})",
                  int(kv_host_blocks or 0) > 0),
                 ("--tp > 1", tp > 1), ("--sp > 1", sp > 1),
                 ("--pp > 1", pp > 1), ("--dp > 1", dp > 1),
@@ -291,7 +251,7 @@ class InferenceEngine:
             bad = [name for name, hit in unsupported if hit]
             if bad:
                 raise ValueError(
-                    f"{what} does not support: {'; '.join(bad)}")
+                    f"{refusal.what} does not support: {'; '.join(bad)}")
         # paged KV serving (--kv-block-size, runtime/kvblocks.py): validate
         # the block geometry AND the feature combos up front — the paged
         # program family covers plain + tp ragged decode only, and a combo
@@ -651,16 +611,8 @@ class InferenceEngine:
         self.kv: KVCache = None if self.cfg.paged_only else self._fresh_kv()
         self.pos = 0
         kinds = telemetry.registry().gauge(telemetry.LAYER_KINDS)
-        kinds.set(self.cfg.n_linear_layers, kind="linear")
-        # a layer with an SSD mixer beside its attention is neither
-        kinds.set(self.cfg.n_layers if self.cfg.has_ssm else 0,
-                  kind="ssm_beside_full")
-        kinds.set(0 if self.cfg.has_ssm or self.cfg.has_latent_cache
-                  else self.cfg.n_kv_layers, kind="full")
-        kinds.set(self.cfg.n_layers if self.cfg.has_latent_cache else 0,
-                  kind="latent")
-        kinds.set(self.cfg.n_window_layers, kind="sliding")
-        kinds.set(self.cfg.n_conv_layers, kind="conv")
+        for kind, n in family_of(self.cfg).layer_kinds(self.cfg).items():
+            kinds.set(n, kind=kind)
         # the expert share (models/share.py): held here, of those routed
         telemetry.registry().gauge(telemetry.MOE_EXPERTS_HELD).set(
             self.cfg.n_experts)

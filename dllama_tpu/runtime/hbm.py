@@ -55,74 +55,11 @@ def device_memory_bytes() -> int | None:
 
 
 def matmul_weight_count(cfg) -> int:
-    """Total matmul-plane weights (the quantized payload)."""
-    if cfg.is_hybrid:
-        # two kinds of layer: the mixer's packed input projection and its
-        # output projection, or q k v wo; a dense feed-forward in both
-        ffn = 3 * cfg.dim * cfg.hidden_dim
-        linear = (cfg.dim * cfg.lin_in_dim
-                  + cfg.lin_heads * cfg.lin_value_dim * cfg.dim + ffn)
-        full = (cfg.dim * cfg.q_dim + 2 * cfg.dim * cfg.kv_dim
-                + cfg.q_dim * cfg.dim + ffn)
-        return (cfg.n_linear_layers * linear + cfg.n_kv_layers * full
-                + cfg.dim * cfg.vocab_size)
-    if cfg.has_ssm:
-        # one kind of layer: q k v wo, the mixer's packed input projection
-        # (its dt rows are a small float32 plane, not counted here) and
-        # output projection, a dense feed-forward
-        layer = (cfg.dim * cfg.q_dim + 2 * cfg.dim * cfg.kv_dim
-                 + cfg.q_dim * cfg.dim + cfg.dim * cfg.ssm_in_dim
-                 + cfg.ssm_inner_dim * cfg.dim + 3 * cfg.dim * cfg.hidden_dim)
-        return cfg.n_layers * layer + cfg.dim * cfg.vocab_size
-    if cfg.has_short_conv:
-        # two kinds of mixer (the conv layers' in- and out-projection, or
-        # q k v wo), the leading dense feed-forward, the held experts of a
-        # routed layer with its router over its whole width
-        conv = 4 * cfg.dim * cfg.dim
-        attn = 2 * cfg.dim * (cfg.q_dim + cfg.kv_dim)
-        routed = (cfg.dim * cfg.moe_router_width
-                  + 3 * cfg.dim * cfg.hidden_dim * cfg.n_experts)
-        return (cfg.n_conv_layers * conv + cfg.n_attn_layers * attn
-                + cfg.n_dense_layers * 3 * cfg.dim * cfg.dense_hidden_dim
-                + cfg.n_moe_layers * routed + cfg.dim * cfg.vocab_size)
-    if cfg.has_latent_cache:
-        # what is HELD: latent attention's planes a layer (W_ukv per head in
-        # the compute dtype: two Q40 weights' bytes a weight), the
-        # leading dense feed-forward, the held experts of a routed layer with its
-        # router (over its whole width) and shared expert, the vocabulary's
-        # rows
-        H = cfg.n_heads
-        attn = (cfg.dim * (cfg.q_lora_rank + cfg.latent_row)
-                + cfg.q_lora_rank * H * cfg.head_dim
-                + 2 * cfg.kv_lora_rank * H * (cfg.qk_nope_dim + cfg.v_head_dim)
-                + H * cfg.v_head_dim * cfg.dim)
-        routed = (cfg.dim * cfg.moe_router_width
-                  + 3 * cfg.dim * (cfg.hidden_dim * cfg.n_experts
-                                   + cfg.shared_expert_dim))
-        return (cfg.n_layers * attn
-                + cfg.n_dense_layers * 3 * cfg.dim * cfg.dense_hidden_dim
-                + cfg.n_moe_layers * routed + cfg.dim * cfg.vocab_size)
-    if cfg.has_window_layers:
-        # what is HELD: two kinds of attention layer at their own head
-        # counts, the leading dense feed-forward, the held experts of a
-        # routed layer with its router (over its whole width) and shared
-        # expert, the vocabulary's rows
-        attn = lambda heads: 2 * cfg.dim * (heads * cfg.head_dim + cfg.kv_dim)
-        routed = (cfg.dim * cfg.moe_router_width
-                  + 3 * cfg.dim * (cfg.hidden_dim * cfg.n_experts
-                                   + cfg.shared_expert_dim))
-        return (cfg.n_kv_layers * attn(cfg.n_heads)
-                + cfg.n_window_layers * attn(cfg.n_heads_sliding)
-                + cfg.n_dense_layers * 3 * cfg.dim * cfg.dense_hidden_dim
-                + cfg.n_moe_layers * routed + cfg.dim * cfg.vocab_size)
-    per_layer = (cfg.dim * cfg.q_dim + 2 * cfg.dim * cfg.kv_dim
-                 + cfg.q_dim * cfg.dim)
-    if cfg.is_moe:
-        per_layer += (3 * cfg.dim * cfg.hidden_dim * cfg.n_experts
-                      + cfg.dim * cfg.n_experts)
-    else:
-        per_layer += 3 * cfg.dim * cfg.hidden_dim
-    return cfg.n_layers * per_layer + cfg.dim * cfg.vocab_size  # + lm head
+    """Total matmul-plane weights (the quantized payload): the decoder
+    family's own arithmetic over what it HOLDS (models/family.py)."""
+    from ..models.family import family_of
+
+    return family_of(cfg).matmul_weight_count(cfg)
 
 
 def estimate_device_bytes(cfg, *, weight_repr: str, kv_dtype_bytes: int,

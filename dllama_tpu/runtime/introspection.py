@@ -765,47 +765,14 @@ def hbm_budget_line(engine) -> str:
 def startup_line(engine) -> str:
     """The engine's start-up stamps (``engine.startup_s``: seconds per
     build phase, the serving generator's included once it is built), one
-    line beside the HBM budget."""
-    parts = getattr(engine, "startup_s", None) or {}
-    cfg = engine.cfg
-    kinds = (f"; layers: {cfg.n_linear_layers} linear, {cfg.n_kv_layers} full"
-             if cfg.is_hybrid else
-             f"; layers: {cfg.n_layers} with an SSD mixer beside attention"
-             if cfg.has_ssm else "")
-    if cfg.has_latent_cache:
-        kinds = (f"; layers: {cfg.n_layers} of latent attention (a row of "
-                 f"{cfg.latent_dim} in {cfg.latent_row} lanes a token); "
-                 f"experts: {cfg.n_experts} of {cfg.moe_router_width} held "
-                 f"from {cfg.moe_first_expert}, {cfg.n_active_experts} a "
-                 f"token of {cfg.moe_topk_group or 1} of "
-                 f"{cfg.moe_n_group or 1} groups")
-    if cfg.has_short_conv:
-        from ..ops import paged_attention as _pa
+    line beside the HBM budget, and the decoder family's words on its
+    layers (models/family.py)."""
+    from ..models.family import family_of
 
-        # the step's attention at this engine's geometry: what the paged
-        # kernel's gate would say of the padded heads on a chip
-        step_q = (1, 1, cfg.n_heads, cfg.cache_width)
-        compiled = _pa.supports(
-            step_q, cfg.n_kv_heads,
-            -(-cfg.seq_len // max(1, engine.kv_block_size)),
-            max(1, engine.kv_block_size), compiled=True)
-        kinds = (f"; layers: {cfg.n_conv_layers} conv ({cfg.conv_kernel} "
-                 f"taps, a tail of {cfg.conv_kernel - 1} x {cfg.dim} a "
-                 f"sequence), {cfg.n_attn_layers} full (heads of "
-                 f"{cfg.head_dim} lanes cached in {cfg.cache_width}: the "
-                 f"paged kernel {'compiles' if compiled else 'does NOT compile'}"
-                 f" for them); experts: {cfg.n_experts} of "
-                 f"{cfg.moe_router_width} held from {cfg.moe_first_expert}, "
-                 f"{cfg.n_active_experts} a token"
-                 f"{', selection bias' if cfg.moe_select_bias else ''}")
-    if cfg.has_window_layers:
-        kinds = (f"; layers: {cfg.n_kv_layers} full, {cfg.n_window_layers} "
-                 f"sliding (window {cfg.sliding_window}); experts: "
-                 f"{cfg.n_experts} of {cfg.moe_router_width} held from "
-                 f"{cfg.moe_first_expert}, {cfg.n_active_experts} a token")
+    parts = getattr(engine, "startup_s", None) or {}
     return (f"🧮 start-up: {sum(parts.values()):.2f} s ("
             + ", ".join(f"{k} {v:.2f}" for k, v in parts.items()) + ")"
-            + kinds)
+            + family_of(engine.cfg).describe(engine.cfg, engine))
 
 
 def q40_paths_line(scope: str) -> str:

@@ -307,11 +307,14 @@ class StateColumn(NamedTuple):
 
     @classmethod
     def zeros(cls, cfg, k: "jax.Array", v: "jax.Array",
-              conv_dtype) -> "StateColumn":
-        """A sequence's start: the given K/V column, zero state and tail."""
+              conv_dtype=None) -> "StateColumn":
+        """A sequence's start: the given K/V column, zero state and tail
+        (in the compute dtype, as :class:`StatePool`'s, unless given)."""
         import jax.numpy as jnp
 
         B = k.shape[1]
+        if conv_dtype is None:
+            conv_dtype = jnp.dtype(cfg.compute_dtype)
         stats = None
         if cfg.has_expert_share:
             from ..models.share import zero_stats

@@ -42,6 +42,7 @@ import numpy as np
 from . import (failpoints, flightrec, introspection, numerics, steppack,
                telemetry, tenancy)
 
+from ..models.family import family_of
 from ..models.llama import forward, sampled_step_guarded
 from ..parallel.api import plan_scoped_jit, use_plan
 from ..parallel.multihost import (
@@ -56,8 +57,7 @@ from ..parallel.multihost import (
 from ..tokenizer.sampler import xorshift_random_f32
 from ..models.share import N_COUNTS
 from .kvblocks import (SPILL_BATCH, BlockPoolExhausted, PageInError,
-                       StateColumn, state_bytes, window_blocks_cap,
-                       window_first_block)
+                       state_bytes, window_blocks_cap, window_first_block)
 from .kvcache import KVCache
 
 if TYPE_CHECKING:
@@ -1659,8 +1659,8 @@ class PagedGenerator(_GeneratorCore):
         self._prefill_fwd = engine._step
         # ... except where a chunk and the tick's decode rows can be ONE
         # program (models.llama.forward_and_step: the layers' planes read
-        # once for both): a decoder that takes llama.py's own forward /
-        # paged_forward pair, one device, a plain step a tick, and a chunk
+        # once for both): a decoder family that has such a program
+        # (models/family.py: ``tick``), one device, a plain step a tick, and a chunk
         # regime of the Q40 kernel wide enough for the widest bucket with
         # every slot's row joined to it. Then EVERY plain chunk goes through
         # it, its rows dead (null tables, as an inactive slot rides a step)
@@ -1671,15 +1671,15 @@ class PagedGenerator(_GeneratorCore):
         self._rows_rode = False   # ... which rode one since the last step()
         from ..ops.quant_matmul import CHUNK_MAX_M
 
-        if (not self.cfg.paged_only and engine.plan is None
+        family = family_of(self.cfg)
+        if (family.tick is not None and engine.plan is None
                 and not self.spec
                 and getattr(engine, "decode_chunk", 1) == 1
                 and max(engine.prefill_buckets) + n_slots <= CHUNK_MAX_M):
-            from ..models.llama import forward_and_step
             from ..ops.sampling import sampled_token
 
             self._tick = steppack.jit_packed_step(
-                forward_and_step, scope=_sc, name="forward_and_step")
+                family.tick, scope=_sc, name="forward_and_step")
             self._dead_rows = (
                 np.zeros((n_slots, 1), np.int32), np.zeros(n_slots, np.int32),
                 np.zeros_like(self.tables))
@@ -1705,31 +1705,13 @@ class PagedGenerator(_GeneratorCore):
             return g.reshape(g.shape[0], 1, heads, M * bs, width)
 
         def _take_fn(pkv, table):
-            return KVCache(k=view(pkv.k, table), v=view(pkv.v, table))
-
-        def _take_latent_fn(pkv, table):
-            # the slot's latent rows through its table, matched prefix
-            # blocks included: the chunks attend over them as they lie
-            from ..models.axk1 import LatentColumn
-            from ..models.share import zero_stats
-
-            return LatentColumn(c=view(pkv.k, table),
-                                stats=zero_stats(self.cfg))
-
-        def _take_state_fn(pkv, table):
-            # an admission starts from a zero state: prefix blocks are
-            # never shared here, so a column is always a sequence's first
-            kv = _take_fn(pkv, table)
-            return StateColumn.zeros(self.cfg, kv.k, kv.v,
-                                     self.spool.conv.dtype)
-
-        def _take_window_fn(pkv, table):
-            # prefix blocks are never shared here, so an admission's column
-            # starts empty; every layer's rows are built in it, the two
-            # pools see them at commit
-            from ..models.laguna import LagunaColumn
-
-            return LagunaColumn.zeros(self.cfg, engine.kv_dtype)
+            # an admission's column from the slot's gathered view, as the
+            # decoder family makes it (models/family.py): the view itself,
+            # matched prefix blocks included, or a sequence's start where
+            # prefix blocks are never shared; a latent pool has no V plane
+            return family.column(
+                self.cfg, view(pkv.k, table),
+                None if pkv.v is None else view(pkv.v, table))
 
         full_ids = np.arange(0, self.cfg.n_layers,
                              max(1, self.cfg.layer_period))
@@ -1785,9 +1767,7 @@ class PagedGenerator(_GeneratorCore):
         # raw jit is deliberate for the three block-movement programs:
         # plan-independent gather/scatter/copy (no constrain()), safe to
         # share across engines — same argument as the dense pool's pair
-        self._take = jax.jit(_take_state_fn if self.cfg.has_state  # dlint: disable=jit-entry
-                             else _take_window_fn if self.window
-                             else _take_latent_fn if self.latent else _take_fn)
+        self._take = jax.jit(_take_fn)  # dlint: disable=jit-entry
         self._put_latent = jax.jit(_put_latent_fn, donate_argnums=(0, 1))  # dlint: disable=jit-entry
         self._put_window = jax.jit(_put_window_fn, donate_argnums=(0, 1, 2))  # dlint: disable=jit-entry
         # a recurrent state's commit writes the admission's state to the

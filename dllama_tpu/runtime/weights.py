@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..formats.mfile import ArchType, ModelFile
+from ..formats.mfile import ModelFile
 from ..formats.quants import Q40, Q80, QUANT_BLOCK_SIZE
 from ..ops.linear import QuantizedWeight
 from ..parallel.api import MeshPlan, make_tp_mesh
@@ -245,7 +245,14 @@ def _make(shape: tuple[int, ...], dtype, sharding, cb: Callable) -> jax.Array:
         shape, sharding, lambda idx: np.asarray(cb(idx), dtype=dtype))
 
 
-class _StreamingLoader:
+class StreamingLoader:
+    """What a family's ``load_params`` is handed: the file's header ``h``,
+    ``quantized`` (the matmul planes stay Q40 / Q80 on the device), and one
+    reader a kind of tensor (``matmul``, ``stacked_f32``, ``f32``,
+    ``expert_stack``), each of which places what it reads; ``params`` closes
+    the tree. ``host_scope`` set: the stacks read meanwhile land in pinned
+    host memory under ``--weight-mode offload``."""
+
     def __init__(self, mf: ModelFile, cfg: "ModelConfig", plan: MeshPlan | None,
                  weight_mode: str):
         self.mf = mf
@@ -267,7 +274,7 @@ class _StreamingLoader:
                           and weight_mode in ("auto", "offload"))
         self.dense_dtype = jnp.bfloat16 if weight_mode == "bf16" else jnp.float32
         self.weight_mode = weight_mode
-        self._host_scope = False
+        self.host_scope = False
         # fast-mode numerics already round dequant to bf16, so storing the
         # scales in bf16 halves their HBM footprint AND removes a per-step
         # f32->bf16 conversion pass over every scale plane (the round-4
@@ -284,7 +291,7 @@ class _StreamingLoader:
         """Build the target sharding; inside a host-placed scope (the layer
         stacks under offload) the arrays land in pinned host memory."""
         sh = self.plan.sharding_for(shape, *axes)
-        if self.offload and self._host_scope:
+        if self.offload and self.host_scope:
             sh = sh.with_memory_kind("pinned_host")
         return sh
 
@@ -394,6 +401,30 @@ class _StreamingLoader:
         return _make(tuple(shape), dtype, sh,
                      lambda idx: self.rd.tensor_f32(name)[idx])
 
+    def params(self, layers) -> "Params":
+        """The model's tree around a family's ``layers``: the embedding,
+        the final norm and the logits head, which every family reads the
+        same way."""
+        from ..models.llama import Params
+
+        h = self.h
+        return Params(
+            # the embedding is only ever read as
+            # ``embedding[tokens].astype(compute_dtype)`` (models.llama.forward),
+            # so storing it AT compute dtype is bit-identical (same rounding of
+            # the same values) and, for bf16 configs, halves its HBM footprint
+            # (~1 GB on the 8B shape)
+            embedding=self.f32("embedding", h.vocab_size, h.dim,
+                               dtype=jnp.dtype(self.cfg.compute_dtype)),
+            layers=layers,
+            final_norm=self.f32("final_norm", h.dim),
+            logits=self.matmul(
+                "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
+                out_axis="vocab", in_axis=None,
+                force_dense=(jnp.bfloat16
+                             if dense_logits_wanted(self.fast_numerics)
+                             else None)))
+
     def expert_stack(self, name: str, out_dim: int, in_dim: int,
                      out_axis: str | None, in_axis: str | None,
                      layers: list[int] | None = None):
@@ -477,363 +508,22 @@ class _StreamingLoader:
         return _make(shape, target, sh, read)
 
 
-def _load_hybrid_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
-    """A hybrid decoder's two stacks (models/hybrid.py) from the tensors
-    ``mfile._walk_hybrid_layer`` names: the linear layers' and the full
-    layers', each stacked over its own layers of the model."""
-    from ..models.hybrid import HybridLayers, LinearLayerParams
-    from ..models.llama import LayerParams, Params
-
-    h = ld.h
-    P = h.layer_period
-    lin_ids = [l for l in range(h.n_layers) if (l + 1) % P]
-    full_ids = [l for l in range(h.n_layers) if (l + 1) % P == 0]
-    vdim = h.linear_n_value_heads * h.linear_value_head_dim
-
-    def stack(ids):
-        mm = lambda name, o, i, **kw: ld.matmul(
-            name, o, i, stacked=True, out_axis=None, in_axis=None,
-            layers=ids, **kw)
-        f32 = lambda name, *tail: ld.stacked_f32(name, *tail, layers=ids)
-        return mm, f32
-
-    mm, f32 = stack(lin_ids)
-    lin = LinearLayerParams(
-        w_in=mm("block_gdn_in", h.linear_in_dim, h.dim),
-        w_ab=f32("block_gdn_ab", 2 * h.linear_n_value_heads, h.dim),
-        conv_w=f32("block_gdn_conv", h.linear_conv_kernel, h.linear_conv_dim),
-        a_log=f32("block_gdn_a_log", h.linear_n_value_heads),
-        dt_bias=f32("block_gdn_dt_bias", h.linear_n_value_heads),
-        norm_o=f32("block_gdn_norm", h.linear_value_head_dim),
-        w_out=mm("block_gdn_out", h.dim, vdim),
-        w1=mm("block_matmul_w1", h.hidden_dim, h.dim),
-        w2=mm("block_matmul_w2", h.dim, h.hidden_dim),
-        w3=mm("block_matmul_w3", h.hidden_dim, h.dim),
-        norm_att=f32("block_norm_0", h.dim),
-        norm_ffn=f32("block_norm_1", h.dim))
-    mm, f32 = stack(full_ids)
-    full = LayerParams(
-        wq=mm("block_matmul_q", h.q_dim, h.dim),
-        wk=mm("block_matmul_k", h.kv_dim, h.dim),
-        wv=mm("block_matmul_v", h.kv_dim, h.dim),
-        wo=mm("block_matmul_wo", h.dim, h.q_dim),
-        w1=mm("block_matmul_w1", h.hidden_dim, h.dim),
-        w2=mm("block_matmul_w2", h.dim, h.hidden_dim),
-        w3=mm("block_matmul_w3", h.hidden_dim, h.dim),
-        norm_att=f32("block_norm_0", h.dim),
-        norm_ffn=f32("block_norm_1", h.dim),
-        norm_q=f32("block_norm_q", h.q_dim),
-        norm_k=f32("block_norm_k", h.kv_dim))
-    return Params(
-        embedding=ld.f32("embedding", h.vocab_size, h.dim,
-                         dtype=jnp.dtype(cfg.compute_dtype)),
-        layers=HybridLayers(lin=lin, full=full),
-        final_norm=ld.f32("final_norm", h.dim),
-        logits=ld.matmul(
-            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
-            out_axis="vocab", in_axis=None,
-            force_dense=(jnp.bfloat16
-                         if dense_logits_wanted(ld.fast_numerics) else None)))
-
-
-def _load_falcon_h1_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
-    """One homogeneous stack of layers with an SSD mixer beside attention
-    (models/falcon_h1.py) from the tensors ``mfile._walk_falcon_h1_layer``
-    names."""
-    from ..models.falcon_h1 import FalconH1Layers
-    from ..models.llama import Params
-
-    h = ld.h
-    mm = lambda name, o, i: ld.matmul(name, o, i, stacked=True,
-                                      out_axis=None, in_axis=None)
-    f32 = ld.stacked_f32
-    layers = FalconH1Layers(
-        wq=mm("block_matmul_q", h.q_dim, h.dim),
-        wk=mm("block_matmul_k", h.kv_dim, h.dim),
-        wv=mm("block_matmul_v", h.kv_dim, h.dim),
-        wo=mm("block_matmul_wo", h.dim, h.q_dim),
-        w_in=mm("block_ssm_in", h.ssm_in_dim, h.dim),
-        w_dt=f32("block_ssm_dt", h.ssm_n_heads, h.dim),
-        conv_w=f32("block_ssm_conv", h.ssm_conv_kernel, h.ssm_conv_dim),
-        conv_b=f32("block_ssm_conv_bias", h.ssm_conv_dim),
-        a_log=f32("block_ssm_a_log", h.ssm_n_heads),
-        d_skip=f32("block_ssm_d", h.ssm_n_heads),
-        dt_bias=f32("block_ssm_dt_bias", h.ssm_n_heads),
-        norm_ssm=f32("block_ssm_norm", h.ssm_inner_dim),
-        w_out=mm("block_ssm_out", h.dim, h.ssm_inner_dim),
-        w1=mm("block_matmul_w1", h.hidden_dim, h.dim),
-        w2=mm("block_matmul_w2", h.dim, h.hidden_dim),
-        w3=mm("block_matmul_w3", h.hidden_dim, h.dim),
-        norm_att=f32("block_norm_0", h.dim),
-        norm_ffn=f32("block_norm_1", h.dim))
-    return Params(
-        embedding=ld.f32("embedding", h.vocab_size, h.dim,
-                         dtype=jnp.dtype(cfg.compute_dtype)),
-        layers=layers,
-        final_norm=ld.f32("final_norm", h.dim),
-        logits=ld.matmul(
-            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
-            out_axis="vocab", in_axis=None,
-            force_dense=(jnp.bfloat16
-                         if dense_logits_wanted(ld.fast_numerics) else None)))
-
-
-def _load_axk1_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
-    """A decoder of latent attention layers with an expert share
-    (models/axk1.py) from the tensors ``mfile._walk_axk1_layer`` names.
-    ``W_dkv``'s plane is padded with zero columns to ``cfg.latent_row``
-    (whole lane tiles for the fused kernels); ``W_ukv`` is contracted per
-    head on its plane's output side in the absorbed form, so it is held
-    per head in the compute dtype (``wuk``, ``wuv [L, H, ., kv_lora]``),
-    dequantized once here."""
-    from ..models.axk1 import AxK1Layers
-    from ..models.llama import Params
-
-    h = ld.h
-    every = list(range(h.n_layers))
-    dense_ids, moe_ids = every[:h.n_dense_layers], every[h.n_dense_layers:]
-    mm = lambda ids, name, o, i, **kw: ld.matmul(
-        name, o, i, stacked=True, out_axis=None, in_axis=None, layers=ids,
-        **kw)
-    H, r, nope = h.n_heads, h.kv_lora_rank, h.qk_nope_head_dim
-    wdkv = mm(every, "block_mla_dkv", r + h.qk_rope_head_dim, h.dim)
-    pad = cfg.latent_row - cfg.latent_dim
-    wdkv = jax.tree.map(
-        lambda a: jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, pad),)), wdkv)
-    wukv = mm(every, "block_mla_ukv", H * (nope + h.v_head_dim), r,
-              force_dense=jnp.dtype(cfg.compute_dtype)).reshape(
-        h.n_layers, H, nope + h.v_head_dim, r)
-    wide, sh = h.dense_hidden_dim, h.shared_expert_dim
-    experts = lambda name, o, i: ld.expert_stack(name, o, i, None, None,
-                                                 layers=moe_ids)
-    layers = AxK1Layers(
-        wdq=mm(every, "block_mla_dq", h.q_lora_rank, h.dim),
-        norm_qa=ld.stacked_f32("block_mla_norm_q", h.q_lora_rank),
-        wuq=mm(every, "block_mla_uq", H * h.head_dim, h.q_lora_rank),
-        wdkv=wdkv,
-        norm_kva=ld.stacked_f32("block_mla_norm_kv", r),
-        wuk=wukv[:, :, :nope], wuv=wukv[:, :, nope:],
-        wo=mm(every, "block_matmul_wo", h.dim, H * h.v_head_dim),
-        norm_att=ld.stacked_f32("block_norm_0", h.dim),
-        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
-        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim),
-        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide),
-        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim),
-        moe_gate=ld.stacked_f32("block_moe_gate", h.moe_router_width, h.dim,
-                                layers=moe_ids),
-        we1=experts("block_expert_w1", h.hidden_dim, h.dim),
-        we2=experts("block_expert_w2", h.dim, h.hidden_dim),
-        we3=experts("block_expert_w3", h.hidden_dim, h.dim),
-        ws1=mm(moe_ids, "block_shared_w1", sh, h.dim) if sh else None,
-        ws2=mm(moe_ids, "block_shared_w2", h.dim, sh) if sh else None,
-        ws3=mm(moe_ids, "block_shared_w3", sh, h.dim) if sh else None)
-    return Params(
-        embedding=ld.f32("embedding", h.vocab_size, h.dim,
-                         dtype=jnp.dtype(cfg.compute_dtype)),
-        layers=layers,
-        final_norm=ld.f32("final_norm", h.dim),
-        logits=ld.matmul(
-            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
-            out_axis="vocab", in_axis=None,
-            force_dense=(jnp.bfloat16
-                         if dense_logits_wanted(ld.fast_numerics) else None)))
-
-
-def _load_lfm2_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
-    """A decoder of gated short-convolution and attention layers with routed
-    experts (models/lfm2.py) from the tensors ``mfile._walk_lfm2_layer``
-    names: two mixer stacks by layer kind, the leading layers' dense
-    feed-forward, the routed layers' router, selection bias and HELD
-    experts, each stacked over its own layers of the model."""
-    from ..models.lfm2 import AttnParams, ConvParams, Lfm2Layers
-    from ..models.llama import Params
-
-    h = ld.h
-    every = list(range(h.n_layers))
-    attn_ids = [l for l in every if h.lfm2_is_attn(l)]
-    conv_ids = [l for l in every if not h.lfm2_is_attn(l)]
-    dense_ids, moe_ids = every[:h.n_dense_layers], every[h.n_dense_layers:]
-    mm = lambda ids, name, o, i: ld.matmul(
-        name, o, i, stacked=True, out_axis=None, in_axis=None, layers=ids)
-    wide = h.dense_hidden_dim
-    experts = lambda name, o, i: ld.expert_stack(name, o, i, None, None,
-                                                 layers=moe_ids)
-    layers = Lfm2Layers(
-        conv=ConvParams(
-            w_in=mm(conv_ids, "block_conv_in", 3 * h.dim, h.dim),
-            conv_w=ld.stacked_f32("block_conv_taps", h.short_conv_kernel,
-                                  h.dim, layers=conv_ids),
-            w_out=mm(conv_ids, "block_conv_out", h.dim, h.dim),
-            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=conv_ids)),
-        attn=AttnParams(
-            wq=mm(attn_ids, "block_matmul_q", h.q_dim, h.dim),
-            wk=mm(attn_ids, "block_matmul_k", h.kv_dim, h.dim),
-            wv=mm(attn_ids, "block_matmul_v", h.kv_dim, h.dim),
-            wo=mm(attn_ids, "block_matmul_wo", h.dim, h.q_dim),
-            norm_q=ld.stacked_f32("block_norm_q", h.head_dim,
-                                  layers=attn_ids),
-            norm_k=ld.stacked_f32("block_norm_k", h.head_dim,
-                                  layers=attn_ids),
-            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=attn_ids)),
-        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
-        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim),
-        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide),
-        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim),
-        moe_gate=ld.stacked_f32("block_moe_gate", h.moe_router_width, h.dim,
-                                layers=moe_ids),
-        moe_bias=(ld.stacked_f32("block_moe_bias", h.moe_router_width,
-                                 layers=moe_ids)
-                  if h.moe_select_bias else None),
-        we1=experts("block_expert_w1", h.hidden_dim, h.dim),
-        we2=experts("block_expert_w2", h.dim, h.hidden_dim),
-        we3=experts("block_expert_w3", h.hidden_dim, h.dim))
-    return Params(
-        embedding=ld.f32("embedding", h.vocab_size, h.dim,
-                         dtype=jnp.dtype(cfg.compute_dtype)),
-        layers=layers,
-        final_norm=ld.f32("final_norm", h.dim),
-        logits=ld.matmul(
-            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
-            out_axis="vocab", in_axis=None,
-            force_dense=(jnp.bfloat16
-                         if dense_logits_wanted(ld.fast_numerics) else None)))
-
-
-def _load_laguna_params(ld: _StreamingLoader, cfg: "ModelConfig") -> "Params":
-    """A decoder of window and full attention layers with an expert share
-    (models/laguna.py) from the tensors ``mfile._walk_laguna_layer`` names:
-    two attention stacks by layer kind, the leading dense layers'
-    feed-forward, and the routed layers' router, HELD experts and shared
-    expert, each stacked over its own layers of the model."""
-    from ..models.laguna import AttnParams, LagunaLayers
-    from ..models.llama import Params
-
-    h = ld.h
-    P, hd = h.layer_period, h.head_dim
-    every = list(range(h.n_layers))
-    full_ids = [l for l in every if l % P == 0]
-    slide_ids = [l for l in every if l % P]
-    dense_ids, moe_ids = every[:h.n_dense_layers], every[h.n_dense_layers:]
-    mm = lambda ids, name, o, i: ld.matmul(
-        name, o, i, stacked=True, out_axis=None, in_axis=None, layers=ids)
-
-    def attn(ids, heads):
-        return AttnParams(
-            wq=mm(ids, "block_matmul_q", heads * hd, h.dim),
-            wk=mm(ids, "block_matmul_k", h.kv_dim, h.dim),
-            wv=mm(ids, "block_matmul_v", h.kv_dim, h.dim),
-            wo=mm(ids, "block_matmul_wo", h.dim, heads * hd),
-            wg=ld.stacked_f32("block_attn_gate", heads, h.dim, layers=ids),
-            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=ids))
-
-    wide, sh = h.dense_hidden_dim, h.shared_expert_dim
-    experts = lambda name, o, i: ld.expert_stack(name, o, i, None, None,
-                                                 layers=moe_ids)
-    layers = LagunaLayers(
-        full=attn(full_ids, h.n_heads),
-        slide=attn(slide_ids, h.n_heads_sliding),
-        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
-        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim),
-        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide),
-        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim),
-        moe_gate=ld.stacked_f32("block_moe_gate", h.moe_router_width, h.dim,
-                                layers=moe_ids),
-        we1=experts("block_expert_w1", h.hidden_dim, h.dim),
-        we2=experts("block_expert_w2", h.dim, h.hidden_dim),
-        we3=experts("block_expert_w3", h.hidden_dim, h.dim),
-        ws1=mm(moe_ids, "block_shared_w1", sh, h.dim) if sh else None,
-        ws2=mm(moe_ids, "block_shared_w2", h.dim, sh) if sh else None,
-        ws3=mm(moe_ids, "block_shared_w3", sh, h.dim) if sh else None)
-    return Params(
-        embedding=ld.f32("embedding", h.vocab_size, h.dim,
-                         dtype=jnp.dtype(cfg.compute_dtype)),
-        layers=layers,
-        final_norm=ld.f32("final_norm", h.dim),
-        logits=ld.matmul(
-            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
-            out_axis="vocab", in_axis=None,
-            force_dense=(jnp.bfloat16
-                         if dense_logits_wanted(ld.fast_numerics) else None)))
-
-
 def load_params(mf: ModelFile, cfg: "ModelConfig", weight_mode: str = "auto",
                 plan: MeshPlan | None = None) -> "Params":
     """Build fully-placed (and, under a plan, fully-sharded) device params.
 
     Drop-in successor of the round-1 stacking loader: same Params tree, but
     host peak memory is bounded by one tensor shard and no second
-    ``device_put``/reshard pass is needed.
+    ``device_put``/reshard pass is needed. Which tensors make which tree is
+    the decoder family's to say (``models/family.py``: its ``load_params``
+    takes the loader).
     """
-    from ..models.llama import LayerParams, Params
+    from ..models.family import family_of
 
-    h = mf.header
-    moe = h.n_experts > 0
-    if moe and not mf.has_moe_router:
+    if mf.header.n_experts > 0 and not mf.has_moe_router:
         raise ValueError(
             "MoE model file has no router tensors (written by the reference "
             "converter, which never emits block_moe_gate) — reconvert with "
             "python -m dllama_tpu.convert")
-    ld = _StreamingLoader(mf, cfg, plan, weight_mode)
-    qwen3 = h.arch_type == ArchType.QWEN3
-    if h.arch_type == ArchType.OLMO_HYBRID:
-        return _load_hybrid_params(ld, cfg)
-    if h.arch_type == ArchType.FALCON_H1:
-        return _load_falcon_h1_params(ld, cfg)
-    if h.arch_type in (ArchType.LAGUNA, ArchType.AXK1, ArchType.LFM2):
-        if not ld.quantized:
-            raise ValueError(
-                f"a {h.arch_type.name} file's matmul planes must be Q40 or "
-                f"Q80: the routed decode kernel (ops/expert_gemv.py) and its "
-                f"XLA form read quantized expert stacks")
-        return {ArchType.LAGUNA: _load_laguna_params,
-                ArchType.AXK1: _load_axk1_params,
-                ArchType.LFM2: _load_lfm2_params}[h.arch_type](ld, cfg)
-
-    # Under offload only the per-layer stacks go host-side: they are the
-    # O(model) bytes and stream through the scan; embedding / final norm /
-    # logits are used outside it and stay resident in device memory.
-    ld._host_scope = True
-    layers = LayerParams(
-        wq=ld.matmul("block_matmul_q", h.q_dim, h.dim, stacked=True,
-                     out_axis="heads", in_axis=None),
-        wk=ld.matmul("block_matmul_k", h.kv_dim, h.dim, stacked=True,
-                     out_axis="kv_heads", in_axis=None),
-        wv=ld.matmul("block_matmul_v", h.kv_dim, h.dim, stacked=True,
-                     out_axis="kv_heads", in_axis=None),
-        wo=ld.matmul("block_matmul_wo", h.dim, h.q_dim, stacked=True,
-                     out_axis=None, in_axis="heads"),
-        w1=None if moe else ld.matmul("block_matmul_w1", h.hidden_dim, h.dim,
-                                      stacked=True, out_axis="hidden", in_axis=None),
-        w2=None if moe else ld.matmul("block_matmul_w2", h.dim, h.hidden_dim,
-                                      stacked=True, out_axis=None, in_axis="hidden"),
-        w3=None if moe else ld.matmul("block_matmul_w3", h.hidden_dim, h.dim,
-                                      stacked=True, out_axis="hidden", in_axis=None),
-        norm_att=ld.stacked_f32("block_norm_0", h.dim),
-        norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
-        norm_q=ld.stacked_f32("block_norm_q", h.head_dim) if qwen3 else None,
-        norm_k=ld.stacked_f32("block_norm_k", h.head_dim) if qwen3 else None,
-        moe_gate=ld.stacked_f32("block_moe_gate", h.n_experts, h.dim) if moe else None,
-        we1=(ld.expert_stack("block_expert_w1", h.hidden_dim, h.dim,
-                             "hidden", None) if moe else None),
-        we2=(ld.expert_stack("block_expert_w2", h.dim, h.hidden_dim,
-                             None, "hidden") if moe else None),
-        we3=(ld.expert_stack("block_expert_w3", h.hidden_dim, h.dim,
-                             "hidden", None) if moe else None),
-    )
-    ld._host_scope = False
-    return Params(
-        # the embedding is only ever read as
-        # ``embedding[tokens].astype(compute_dtype)`` (models.llama.forward),
-        # so storing it AT compute dtype is bit-identical (same rounding of
-        # the same values) and, for bf16 configs, halves its HBM footprint
-        # (~1 GB on the 8B shape)
-        embedding=ld.f32("embedding", h.vocab_size, h.dim,
-                         dtype=jnp.dtype(cfg.compute_dtype)),
-        layers=layers,
-        final_norm=ld.f32("final_norm", h.dim),
-        logits=ld.matmul(
-            "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
-            out_axis="vocab", in_axis=None,
-            force_dense=(jnp.bfloat16
-                         if dense_logits_wanted(ld.fast_numerics) else None)),
-    )
+    return family_of(cfg).load_params(
+        StreamingLoader(mf, cfg, plan, weight_mode), cfg)

@@ -8,18 +8,11 @@ benchmark/selftest/selftest.py`` still runs them with the rest of the
 self-test; before PR 30 nothing under ``tests/`` did, so tier-1 could not see
 the seam regress.
 
-**Three cases a PR's two cells are red, and are marked so** (``KNOWN_RED``,
-strict: one that turns green fails the run until its row is taken out; PR 30's
-three and, for the same reason, PR 34's three and PR 36's two). Two of
-``test_modules.py``'s tests run over EVERY cell of ``BENCHMARK.json`` and
-assert what held of PR 29's three cells: 24 rows a cell in
-``selftest/counts_frozen.json``, and no configuration naming modules. PR 30's
-two cells break both by construction (one brings its modules, neither has
-frozen rows), and a ``model_config`` PR may edit no file that is under
-``benchmark/`` already; ``selftest.py`` reports the same three. PERF.md
-section 7 has the edit a ``benchmark`` PR owes. What those tests would have
-held the new cells to is held here instead, from a file of PR 30's own
-(``benchmark/olmo_hybrid/selftest/counts_frozen.json``): the tests at the end.
+Two of ``test_modules.py``'s tests run over the cells of ``BENCHMARK.json``
+that name no modules and have rows in ``selftest/counts_frozen.json`` (PR 29's
+three). What they would hold a cell that brings its own modules to is held
+here instead, from a file of that cell's own PR (PR 30's is
+``benchmark/olmo_hybrid/selftest/counts_frozen.json``): the tests at the end.
 """
 
 import importlib
@@ -32,36 +25,10 @@ import pytest
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
 SELFTEST = os.path.join(BENCH, "selftest")
 NEW_CELLS = ("olmo-hybrid-7b.long-prompt", "mistral-7b-v0.3.single-stream")
-PR34_CELLS = ("laguna-s-2.1.mixed-queue", "mistral-7b-v0.3.mixed-queue")     # the same three cases, for the same reason
-PR36_CELL = "falcon-h1-34b.chat"     # one cell, so two of the three cases
-PR42_CELL = "a.x-k1.agent-sessions"  # one cell again
-PR44_CELL = "lfm2-24b-a2b.batch-generate"  # and again
-KNOWN_RED = {
-    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR44_CELL}]":
-        "selftest/counts_frozen.json has no rows for the cell PR 44 added",
-    f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{PR44_CELL}]":
-        "lfm2-24b-a2b names its modules: the test asserts that no configuration of BENCHMARK.json does",
-    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR42_CELL}]":
-        "selftest/counts_frozen.json has no rows for the cell PR 42 added",
-    f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{PR42_CELL}]":
-        "a.x-k1 names its modules: the test asserts that no configuration of BENCHMARK.json does",
-    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR36_CELL}]":
-        "selftest/counts_frozen.json has no rows for the cell PR 36 added",
-    f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{PR36_CELL}]":
-        "falcon-h1-34b names its modules: the test asserts that no configuration of BENCHMARK.json does",
-    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR34_CELLS[0]}]":
-        "selftest/counts_frozen.json has no rows for a cell PR 34 added",
-    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{PR34_CELLS[1]}]":
-        "selftest/counts_frozen.json has no rows for a cell PR 34 added",
-    f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{PR34_CELLS[0]}]":
-        "laguna-s-2.1 names its modules: the test asserts that no configuration of BENCHMARK.json does",
-    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{NEW_CELLS[0]}]":
-        "selftest/counts_frozen.json has no rows for a cell PR 30 added",
-    f"test_counts_through_the_seam_are_what_peaks_py_gave_to_the_byte[{NEW_CELLS[1]}]":
-        "selftest/counts_frozen.json has no rows for a cell PR 30 added",
-    f"test_a_configuration_that_names_no_module_gets_the_dense_decoders[{NEW_CELLS[0]}]":
-        "olmo-hybrid-7b names its modules: the test asserts that no configuration of BENCHMARK.json does",
-}
+PR34_CELLS = ("laguna-s-2.1.mixed-queue", "mistral-7b-v0.3.mixed-queue")
+PR36_CELL = "falcon-h1-34b.chat"
+PR42_CELL = "a.x-k1.agent-sessions"
+PR44_CELL = "lfm2-24b-a2b.batch-generate"
 
 sys.path.insert(0, SELFTEST)
 try:
@@ -72,14 +39,6 @@ try:
     import test_modules as _seam
 finally:
     sys.path.remove(SELFTEST)
-
-
-@pytest.fixture(autouse=True)
-def _known_red(request):
-    """The three cases PR 30's cells turn red, as strict expected failures."""
-    reason = KNOWN_RED.get(request.node.name)
-    if reason:
-        request.applymarker(pytest.mark.xfail(strict=True, reason=reason + " (owed by a benchmark PR: PERF.md section 7)"))
 
 
 @pytest.fixture(autouse=True)
@@ -97,7 +56,7 @@ def _engine_loader_put_back():
     engine_mod.load_params_from_mfile = load_params_from_mfile
 
 
-# -- what the red cases would have held PR 30's cells to ---------------------------
+# -- what test_modules.py's two tests would hold PR 30's cells to -------------------
 
 with open(os.path.join(BENCH, "olmo_hybrid", "selftest", "counts_frozen.json"), encoding="utf-8") as _f:
     NEW_FROZEN = json.load(_f)

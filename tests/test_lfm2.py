@@ -147,11 +147,9 @@ def test_the_pattern_the_pools_and_the_state_are_the_architectures(engine):
 
 def test_causal_conv_takes_its_activation_and_lives_in_a_module_of_its_own():
     """``activation=None`` is the plain convolution; the default is the SiLU
-    the two standing clients always had, under the name they import."""
-    from dllama_tpu.ops import gated_delta
+    the two standing clients always had."""
     from dllama_tpu.ops.causal_conv import causal_conv
 
-    assert gated_delta.causal_conv is causal_conv
     rng = np.random.default_rng(0)
     x, tail, w = (jnp.asarray(rng.normal(size=s).astype(np.float32)) for s in ((2, 5, 6), (2, 2, 6), (3, 6)))
     plain, new_tail = causal_conv(x, tail, w, jnp.int32(3), activation=None)

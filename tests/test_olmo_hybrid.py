@@ -159,6 +159,7 @@ def test_step_form_iterated_is_the_per_token_recurrence():
 
 def test_masked_positions_leave_the_state_untouched():
     from dllama_tpu.ops import gated_delta as gd
+    from dllama_tpu.ops.causal_conv import causal_conv
 
     q, k, v, g, beta, s0 = _mixer_inputs(64)
     real = (jnp.arange(64) < 41)[None, :, None]
@@ -167,7 +168,7 @@ def test_masked_positions_leave_the_state_untouched():
     assert float(jnp.abs(s - s_41).max()) < FORM_TOL
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 6))
     tail = jnp.ones((1, 3, 6))
-    _y, new_tail = gd.causal_conv(x, tail, jnp.ones((4, 6)), jnp.int32(2))
+    _y, new_tail = causal_conv(x, tail, jnp.ones((4, 6)), jnp.int32(2))
     np.testing.assert_allclose(new_tail[0], jnp.concatenate([tail[0, 2:], x[0, :2]]))
 
 
