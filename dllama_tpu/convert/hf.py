@@ -37,9 +37,11 @@ ARCH_BY_MODEL_TYPE = {
     "falcon_h1": ArchType.FALCON_H1,
     "axk1": ArchType.AXK1,
     "lfm2_moe": ArchType.LFM2,
+    "nemotron_h": ArchType.NEMOTRON_H,
 }
 
-HIDDEN_ACT_BY_NAME = {"gelu": HiddenAct.GELU, "silu": HiddenAct.SILU}
+HIDDEN_ACT_BY_NAME = {"gelu": HiddenAct.GELU, "silu": HiddenAct.SILU,
+                      "relu2": HiddenAct.RELU2}
 
 
 def _keyed_checksums(path: str | Path, crcs: list[int]) -> dict[str, int]:
@@ -114,9 +116,12 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
         "arch_type": int(ARCH_BY_MODEL_TYPE[model_type]),
         # the laguna and lfm2_moe configs name no activation: their
         # feed-forwards are SwiGLU
+        # nemotron_h names its feed-forwards' activation ``mlp_hidden_act``
         "hidden_act": int(HIDDEN_ACT_BY_NAME[
             cfg.get("hidden_act", "silu")
-            if model_type in ("laguna", "lfm2_moe") else cfg["hidden_act"]]),
+            if model_type in ("laguna", "lfm2_moe")
+            else cfg["mlp_hidden_act"] if model_type == "nemotron_h"
+            else cfg["hidden_act"]]),
         "dim": cfg["hidden_size"],
         "hidden_dim": cfg["intermediate_size"],
         "n_layers": cfg["num_hidden_layers"],
@@ -164,6 +169,8 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
         return {**params, **_axk1_header(cfg)}
     if model_type == "lfm2_moe":
         return {**params, **_lfm2_header(cfg)}
+    if model_type == "nemotron_h":
+        return {**params, **_nemotron_h_header(cfg)}
 
     if model_type == "falcon_h1":
         params.update(_falcon_h1_header(cfg))
@@ -334,6 +341,113 @@ def _axk1_header(cfg: dict) -> dict:
         "moe_router_width": int(cfg["n_routed_experts"]),
         "moe_first_expert": 0,
     }
+
+
+def _nemotron_h_header(cfg: dict) -> dict:
+    """``model_type: nemotron_h``'s config keys as the header's extension
+    keys (formats/mfile.py, HeaderKey 72-73, the mixer's 39-44, the share's
+    35-38, 21, 67 and 71). ``hybrid_override_pattern`` is a string over ``M *
+    E`` of ``num_hidden_layers`` characters. A whole checkpoint holds every
+    expert: the router's width is ``n_routed_experts`` and the first held
+    expert 0. What the config does not say (no positions in attention, the
+    ``z x B C dt`` order of the in-projection's rows, the gate before the
+    grouped norm, the router and the shared expert on the layer's input, the
+    ``1e-20`` in the weights' renormalisation) the arch implies
+    (models/nemotron_h.py). The multi-token-prediction head
+    (``num_nextn_predict_layers``) is not written: it never enters the
+    next-token logits, and nothing here serves it."""
+    pattern = cfg["hybrid_override_pattern"]
+    heads = int(cfg["mamba_num_heads"])
+    eps = cfg.get("layer_norm_epsilon", cfg.get("norm_eps"))
+    if (len(pattern) != cfg["num_hidden_layers"] or set(pattern) - set("M*E")
+            or heads * cfg["mamba_head_dim"]
+            != cfg["expand"] * cfg["hidden_size"]
+            or eps not in (1e-5, 1e-6)):
+        raise ValueError(
+            "nemotron_h: hybrid_override_pattern is not num_hidden_layers "
+            "characters over M * E, the mixer's heads are not expand x "
+            "hidden_size wide, or the norm epsilon is neither 1e-5 nor 1e-6")
+    if (cfg.get("mlp_hidden_act") != "relu2" or not cfg.get("use_conv_bias")
+            or cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1
+            or cfg.get("n_shared_experts", 1) != 1
+            or any(cfg.get(k) for k in ("attention_bias", "mlp_bias",
+                                        "mamba_proj_bias", "use_bias"))):
+        raise ValueError(
+            "nemotron_h: another activation than relu2, a projection bias, a "
+            "convolution without its bias, a group limit or more than one "
+            "shared expert are not carried")
+    return {
+        "hidden_dim": int(cfg["moe_intermediate_size"]),
+        "n_experts": int(cfg["n_routed_experts"]),
+        "n_active_experts": int(cfg["num_experts_per_tok"]),
+        "moe_norm_topk": int(bool(cfg.get("norm_topk_prob", True))),
+        "head_dim": int(cfg["head_dim"]),
+        "norm_epsilon": 5 if eps == 1e-5 else 6,
+        "rope_theta": int(cfg.get("rope_theta", 10000)),
+        "shared_expert_dim": int(cfg["moe_shared_expert_intermediate_size"]),
+        "moe_routed_scale_milli": int(round(
+            float(cfg.get("routed_scaling_factor", 1.0)) * 1000)),
+        "moe_router_width": int(cfg["n_routed_experts"]),
+        "moe_first_expert": 0,
+        "ssm_n_heads": heads,
+        "ssm_head_dim": int(cfg["mamba_head_dim"]),
+        "ssm_n_groups": int(cfg["n_groups"]),
+        "ssm_state_dim": int(cfg["ssm_state_size"]),
+        "ssm_conv_kernel": int(cfg["conv_kernel"]),
+        "ssm_chunk_size": int(cfg["chunk_size"]),
+        "moe_score_func": 1,
+        "moe_select_bias": 1,
+        "moe_latent_dim": int(cfg.get("moe_latent_size") or 0),
+        "layer_pattern": pattern,
+    }
+
+
+def _nemotron_h_plan(params: dict) -> list["PlanItem"]:
+    """``model_type: nemotron_h``'s tensors in the order
+    ``mfile._walk_nemotron_h_layer`` reads them. A layer's block is
+    ``<prefix>.layers.N.mixer`` whatever its kind, its norm
+    ``<prefix>.layers.N.norm``; ``<prefix>`` is ``backbone`` (``model`` is
+    taken too). The mixer's ``in_proj`` is split: its last ``mamba_num_heads``
+    rows are the float32 ``dt`` plane; ``conv1d.weight`` ``[C, 1, K]`` becomes
+    taps ``[K, C]`` (tap ``K - 1`` on the current position, as the causal
+    convolution has it)."""
+    wt = params["weight_float_type"]
+    n_dt = params["ssm_n_heads"]
+    both = lambda tail: (f"backbone.{tail}", f"model.{tail}")
+    plan = [PlanItem(both("embeddings.weight") + ("model.embed_tokens.weight",),
+                     F32)]
+    for l, kind in enumerate(params["layer_pattern"]):
+        mx = lambda name, l=l: both(f"layers.{l}.mixer.{name}")
+        if kind == "M":
+            plan += [
+                PlanItem(mx("in_proj.weight"), wt, lambda w: w[:-n_dt]),
+                PlanItem(mx("in_proj.weight"), F32, lambda w: w[-n_dt:]),
+                PlanItem(mx("conv1d.weight"), F32,
+                         lambda w: np.ascontiguousarray(w[:, 0, :].T)),
+                PlanItem(mx("conv1d.bias"), F32),
+                PlanItem(mx("A_log"), F32),
+                PlanItem(mx("D"), F32),
+                PlanItem(mx("dt_bias"), F32),
+                PlanItem(mx("norm.weight"), F32),
+                PlanItem(mx("out_proj.weight"), wt)]
+        elif kind == "*":
+            plan += [PlanItem(mx(f"{p}_proj.weight"), wt) for p in "qkvo"]
+        else:
+            plan += [PlanItem(mx("gate.weight"), F32),
+                     PlanItem(mx("gate.e_score_correction_bias"), F32)]
+            if params["moe_latent_dim"]:
+                plan.append(PlanItem(mx("fc1_latent_proj.weight"), wt))
+            for e in range(params["n_experts"]):
+                plan += [PlanItem(mx(f"experts.{e}.up_proj.weight"), wt),
+                         PlanItem(mx(f"experts.{e}.down_proj.weight"), wt)]
+            if params["moe_latent_dim"]:
+                plan.append(PlanItem(mx("fc2_latent_proj.weight"), wt))
+            plan += [PlanItem(mx("shared_experts.up_proj.weight"), wt),
+                     PlanItem(mx("shared_experts.down_proj.weight"), wt)]
+        plan.append(PlanItem(both(f"layers.{l}.norm.weight"), F32))
+    plan.append(PlanItem(both("norm_f.weight") + ("model.norm.weight",), F32))
+    plan.append(PlanItem(("lm_head.weight",), wt))
+    return plan
 
 
 def _lfm2_header(cfg: dict) -> dict:
@@ -515,6 +629,8 @@ def hf_tensor_plan(params: dict) -> list[PlanItem]:
             "in-projection's rows in the order B, C, X; the taps [K, dim] "
             "with tap K - 1 on the current position; q and k rows paired "
             "half-split)")
+    if arch == ArchType.NEMOTRON_H:
+        return _nemotron_h_plan(params)
     if arch == ArchType.FALCON_H1:
         raise NotImplementedError(
             "falcon_h1: the header is mapped (load_hf_config), the "
@@ -631,6 +747,10 @@ def convert_hf(source_dir: str | Path, weight_float_type: int | str,
     src = SafetensorsDirectory(files)
 
     plan = hf_tensor_plan(params)
+    skipped = [k for k in src.key_to_file if k.startswith("mtp.")]
+    if skipped and progress:
+        print(f"⏭️ skipping {len(skipped)} mtp.* tensors: the multi-token-"
+              f"prediction head is not served")
     crcs: list[int] = []
     try:
         with open(output_path, "wb") as out:

@@ -169,6 +169,19 @@ class HeaderKey(enum.IntEnum):
     # MOE_NORM_TOPK and MOE_SCORE_FUNC with the other routed archs.
     SHORT_CONV_KERNEL = 70
     MOE_SELECT_BIAS = 71
+    # OUR format extension, read by ArchType.NEMOTRON_H only
+    # (models/nemotron_h.py): every layer is ONE block, and which one is the
+    # header's: LAYER_PATTERN may stand more than once, each value the next
+    # PATTERN_KINDS_A_WORD layers' kinds at two bits a layer, the first in
+    # the lowest bits (0 ``M`` an SSD mixer, 1 ``*`` attention, 2 ``E`` a
+    # routed feed-forward); N_LAYERS says how many there are. The experts
+    # live in a latent MOE_LATENT_DIM wide (0: the model's width), one
+    # projection down in front of the dispatch and one up behind the sum.
+    # The arch shares the mixer's sizes (39-44), SHARED_EXPERT_DIM ..
+    # MOE_FIRST_EXPERT (35-38), MOE_NORM_TOPK, MOE_SCORE_FUNC and
+    # MOE_SELECT_BIAS; HIDDEN_ACT is an expert's (HiddenAct.RELU2: ungated).
+    LAYER_PATTERN = 72
+    MOE_LATENT_DIM = 73
 
 
 class ArchType(enum.IntEnum):
@@ -200,6 +213,30 @@ class ArchType(enum.IntEnum):
     # layers with a dense feed-forward; every other layer routes over experts
     # through a sigmoid router with a selection-only bias (models/lfm2.py)
     LFM2 = 0xABCD06
+    # ours: every layer is ONE pre-norm block, an SSD (Mamba-2) mixer, a
+    # grouped-query attention layer without positions, or a routed
+    # feed-forward of ungated squared-ReLU experts in a latent space beside a
+    # shared one, in the order the header's pattern gives
+    # (models/nemotron_h.py)
+    NEMOTRON_H = 0xABCD07
+
+
+PATTERN_KINDS = "M*E"          # LAYER_PATTERN's two-bit codes, in this order
+PATTERN_KINDS_A_WORD = 15      # 30 bits: a header value is a signed int32
+
+
+def pattern_words(pattern: str) -> list[int]:
+    """``pattern`` (a string over ``M * E``) as LAYER_PATTERN's values."""
+    n = PATTERN_KINDS_A_WORD
+    return [sum(PATTERN_KINDS.index(c) << (2 * i)
+                for i, c in enumerate(pattern[at:at + n]))
+            for at in range(0, len(pattern), n)]
+
+
+def pattern_from_words(words: list[int], n_layers: int) -> str:
+    kinds = [PATTERN_KINDS[(w >> (2 * i)) & 3]
+             for w in words for i in range(PATTERN_KINDS_A_WORD)]
+    return "".join(kinds[:n_layers])
 
 
 class RopeType(enum.IntEnum):
@@ -217,6 +254,8 @@ class RopeType(enum.IntEnum):
 class HiddenAct(enum.IntEnum):
     GELU = 0
     SILU = 1
+    # ours: relu(x) ** 2, the activation of an UNGATED feed-forward
+    RELU2 = 2
 
 
 @dataclass
@@ -303,6 +342,13 @@ class ModelHeader:
     # LFM2 (HeaderKey 70-71); 0 for every other arch
     short_conv_kernel: int = 0
     moe_select_bias: int = 0
+    # NEMOTRON_H (HeaderKey 72-73); empty / 0 for every other arch
+    layer_pattern: str = ""
+    moe_latent_dim: int = 0
+
+    def pattern_layers(self, kind: str) -> list[int]:
+        """The model's layers of ``kind`` (one of ``M * E``), in order."""
+        return [l for l, c in enumerate(self.layer_pattern) if c == kind]
 
     @property
     def n_attn_layers(self) -> int:
@@ -386,7 +432,7 @@ _HYBRID_KEYS = {k: k.name.lower() for k in (
     HeaderKey.QK_NOPE_HEAD_DIM, HeaderKey.QK_ROPE_HEAD_DIM,
     HeaderKey.V_HEAD_DIM, HeaderKey.MOE_N_GROUP, HeaderKey.MOE_TOPK_GROUP,
     HeaderKey.MOE_SCORE_FUNC, HeaderKey.SHORT_CONV_KERNEL,
-    HeaderKey.MOE_SELECT_BIAS)}
+    HeaderKey.MOE_SELECT_BIAS, HeaderKey.MOE_LATENT_DIM)}
 # FALCON_H1's float keys: the value is a float32's bit pattern
 _F32_BITS_KEYS = {k: k.name.lower() for k in HeaderKey
                   if HeaderKey.EMBEDDING_MULT <= k <= HeaderKey.SSM_MULT_DT}
@@ -414,6 +460,7 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
         raise ValueError(f"unsupported magic number {magic:#x}")
     n_kv = (header_size - 8) // 8
     h = ModelHeader()
+    words: list[int] = []
     for i in range(n_kv):
         key, value = struct.unpack_from("<ii", raw, 8 + i * 8)
         if key == HeaderKey.VERSION:
@@ -460,6 +507,8 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
             h.head_dim = value
         elif key == HeaderKey.NORM_EPSILON:
             h.norm_epsilon = _norm_epsilon_from_int(value)
+        elif key == HeaderKey.LAYER_PATTERN:
+            words.append(value)
         elif key in _HYBRID_KEYS:
             setattr(h, _HYBRID_KEYS[key], value)
         elif key in _F32_BITS_KEYS:
@@ -478,6 +527,7 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
     h.sync_type = sync_type
     h.header_size = header_size
     h.file_size = path_size
+    h.layer_pattern = pattern_from_words(words, h.n_layers)
     if h.arch_type == ArchType.QWEN3:
         h.rope_type = RopeType.FALCON
     if h.arch_type == ArchType.OLMO_HYBRID:
@@ -501,6 +551,35 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
                 f"must divide the heads")
         if h.n_experts:
             raise ValueError("falcon_h1 model: routed experts are unsupported")
+    if h.arch_type == ArchType.NEMOTRON_H:
+        h.moe_router_width = h.moe_router_width or h.n_experts
+        per_group = h.ssm_n_heads // max(1, h.ssm_n_groups)
+        if len(h.layer_pattern) != h.n_layers or not h.n_layers:
+            raise ValueError(
+                f"nemotron_h model: a pattern of {len(h.layer_pattern)} "
+                f"layers for {h.n_layers}")
+        if "M" in h.layer_pattern and not (
+                h.ssm_n_heads and h.ssm_head_dim and h.ssm_state_dim
+                and h.ssm_conv_kernel > 1 and h.ssm_chunk_size
+                and per_group * h.ssm_n_groups == h.ssm_n_heads):
+            raise ValueError(
+                f"nemotron_h model: {h.ssm_n_heads} mixer heads of "
+                f"{h.ssm_head_dim} in {h.ssm_n_groups} groups, state "
+                f"{h.ssm_state_dim}, {h.ssm_conv_kernel} taps, chunks of "
+                f"{h.ssm_chunk_size}: every size must be set and the groups "
+                f"must divide the heads")
+        if "E" in h.layer_pattern and not (
+                0 < h.n_active_experts <= h.moe_router_width
+                and 0 < h.n_experts
+                and h.moe_first_expert + h.n_experts <= h.moe_router_width
+                and h.moe_score_func in (0, 1)
+                and h.moe_select_bias in (0, 1)):
+            raise ValueError(
+                f"nemotron_h model: experts [{h.moe_first_expert}, "
+                f"{h.moe_first_expert + h.n_experts}) held of a router over "
+                f"{h.moe_router_width}, {h.n_active_experts} a token, score "
+                f"function code {h.moe_score_func}, selection bias "
+                f"{h.moe_select_bias}")
     if h.arch_type == ArchType.LFM2:
         h.rope_type = RopeType.FALCON
         h.moe_router_width = h.moe_router_width or h.n_experts
@@ -720,6 +799,9 @@ class ModelFile:
             if h.arch_type == ArchType.LFM2:
                 off = self._walk_lfm2_layer(l, off)
                 continue
+            if h.arch_type == ArchType.NEMOTRON_H:
+                off = self._walk_nemotron_h_layer(l, off)
+                continue
             off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
             off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
             off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
@@ -878,6 +960,62 @@ class ModelFile:
         off = self._walk_share_ffn(l, off)
         off += self._add("block_norm_0", l, (h.dim,), F32, off)
         off += self._add("block_norm_1", l, (h.dim,), F32, off)
+        return off
+
+    def _walk_nemotron_h_layer(self, l: int, off: int) -> int:
+        """One layer of a NEMOTRON_H file (OUR layout; the reference has
+        none), ONE block of the kind ``layer_pattern[l]`` and its norm
+        (``block_norm_0``). ``M``: the SSD mixer's tensors as
+        :meth:`_walk_falcon_h1_layer` orders them (the packed ``z x B C``
+        rows, the ``dt`` rows in F32, taps and bias, ``A_log``, ``D``,
+        ``dt_bias``, the gated norm's weight, the output projection). ``*``:
+        q k v wo. ``E``: the router's rows over ``moe_router_width`` (F32),
+        its selection bias (F32, where the header says it has one), the
+        projection into the latent, the HELD experts (w1 up, w2 down: two
+        planes each, in the latent's width), the projection out of it, the
+        shared expert's w1 w2 over the model's width."""
+        h, wt = self.header, self.header.weight_type
+        kind = h.layer_pattern[l]
+        if kind == "M":
+            nh = h.ssm_n_heads
+            off += self._add("block_ssm_in", l, (h.ssm_in_dim, h.dim), wt, off)
+            off += self._add("block_ssm_dt", l, (nh, h.dim), F32, off)
+            off += self._add("block_ssm_conv", l,
+                             (h.ssm_conv_kernel, h.ssm_conv_dim), F32, off)
+            off += self._add("block_ssm_conv_bias", l, (h.ssm_conv_dim,), F32,
+                             off)
+            off += self._add("block_ssm_a_log", l, (nh,), F32, off)
+            off += self._add("block_ssm_d", l, (nh,), F32, off)
+            off += self._add("block_ssm_dt_bias", l, (nh,), F32, off)
+            off += self._add("block_ssm_norm", l, (h.ssm_inner_dim,), F32, off)
+            off += self._add("block_ssm_out", l, (h.dim, h.ssm_inner_dim), wt,
+                             off)
+        elif kind == "*":
+            off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
+            off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
+            off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
+            off += self._add("block_matmul_wo", l, (h.dim, h.q_dim), wt, off)
+        else:
+            lat = h.moe_latent_dim or h.dim
+            off += self._add("block_moe_gate", l,
+                             (h.moe_router_width, h.dim), F32, off)
+            if h.moe_select_bias:
+                off += self._add("block_moe_bias", l, (h.moe_router_width,),
+                                 F32, off)
+            if h.moe_latent_dim:
+                off += self._add("block_latent_in", l, (lat, h.dim), wt, off)
+            for e in range(h.n_experts):
+                off += self._add("block_expert_w1", l, (h.hidden_dim, lat),
+                                 wt, off, expert=e)
+                off += self._add("block_expert_w2", l, (lat, h.hidden_dim),
+                                 wt, off, expert=e)
+            if h.moe_latent_dim:
+                off += self._add("block_latent_out", l, (h.dim, lat), wt, off)
+            if h.shared_expert_dim:
+                wide = h.shared_expert_dim
+                off += self._add("block_shared_w1", l, (wide, h.dim), wt, off)
+                off += self._add("block_shared_w2", l, (h.dim, wide), wt, off)
+        off += self._add("block_norm_0", l, (h.dim,), F32, off)
         return off
 
     def _walk_share_ffn(self, l: int, off: int) -> int:
@@ -1121,6 +1259,11 @@ def write_header(f, params: dict) -> None:
     data = b""
     for key, value in params.items():
         k = HeaderKey[key.upper()]
+        # LAYER_PATTERN takes the string and stands once a word of it
+        if k == HeaderKey.LAYER_PATTERN:
+            data += b"".join(struct.pack("<ii", int(k), w)
+                             for w in pattern_words(value))
+            continue
         # FALCON_H1's float keys take the float and store its float32 bits
         data += struct.pack("<ii", int(k), f32_bits(value)
                             if k in _F32_BITS_KEYS else int(value))

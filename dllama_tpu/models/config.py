@@ -157,6 +157,22 @@ class ModelConfig:
     conv_kernel: int = 0
     moe_select_bias: bool = False
     moe_norm_eps: float = 0.0
+    # Every layer ONE pre-norm block (ArchType.NEMOTRON_H,
+    # models/nemotron_h.py): ``layer_pattern`` names each layer's kind, ``M``
+    # an SSD mixer (the ``ssm_*`` sizes, every multiplier 1), ``*``
+    # grouped-query attention WITHOUT positions (no rotary table is built),
+    # ``E`` a routed feed-forward. Three stacks, each over its own layers of
+    # the pattern: ``n_state_layers`` / ``n_kv_layers`` / ``n_moe_layers``
+    # count them, and the pools' layer axes are those counts. The experts
+    # are UNGATED (two planes: the stack has no ``we3``; ``hidden_act``
+    # relu2) and live in a latent ``moe_latent_dim`` wide (0: the model's
+    # width): one projection down in front of the dispatch, one up behind
+    # the weighted sum; the router and the shared expert
+    # (``shared_expert_dim``, ungated too) read the model's width. The
+    # share's fields are LAGUNA's, ``moe_select_bias`` and ``moe_norm_eps``
+    # LFM2's.
+    layer_pattern: tuple[str, ...] = ()
+    moe_latent_dim: int = 0
 
     # TPU execution choices (no reference equivalent):
     compute_dtype: str = "float32"  # "float32" for parity, "bfloat16" for speed
@@ -304,12 +320,30 @@ class ModelConfig:
         return "window_layers" if self.has_window_layers else None
 
     @property
+    def expert_width_held(self) -> int:
+        """An expert's hidden width as its planes HOLD it. The routed
+        kernels fetch a plane's scales ``[width / 32, out]`` by one DMA, and
+        Mosaic pads an HBM operand's second-minor dimension to whole tiles
+        of 8 rows and refuses a slice that is not: 2688 lanes are 84 scale
+        rows of 88. So where ``layer_pattern`` is set (models/nemotron_h.py)
+        a width past 256 is held rounded up to whole tiles of 8 blocks (256
+        lanes), the lanes behind it zero in both planes: ``act(0) = 0`` for
+        an ungated squared ReLU and zero rows of the down-projection add
+        nothing, so the function is the published width's."""
+        wide = self.hidden_dim
+        if not self.layer_pattern or wide <= 256:
+            return wide
+        return -(-wide // 256) * 256
+
+    @property
     def n_window_layers(self) -> int:
         return (self.n_layers - self.n_layers // self.layer_period
                 if self.has_window_layers else 0)
 
     @property
     def n_moe_layers(self) -> int:
+        if self.layer_pattern:
+            return self.layer_pattern.count("E")
         return self.n_layers - self.n_dense_layers if self.is_moe else 0
 
     @property
@@ -320,8 +354,9 @@ class ModelConfig:
 
     @property
     def has_ssm(self) -> bool:
-        """An SSD mixer beside attention in every layer
-        (models/falcon_h1.py)."""
+        """Layers with an SSD mixer: beside attention in every layer
+        (models/falcon_h1.py), or the pattern's ``M`` layers
+        (models/nemotron_h.py)."""
         return self.ssm_heads > 0
 
     @property
@@ -351,10 +386,12 @@ class ModelConfig:
     @property
     def n_state_layers(self) -> int:
         """Layers that own a row of the state pool: a hybrid's linear ones,
-        every layer where the mixer sits beside attention, or the conv
-        layers."""
+        every layer where the mixer sits beside attention, the pattern's
+        mixer layers, or the conv layers."""
         if self.has_short_conv:
             return self.n_conv_layers
+        if self.layer_pattern:
+            return self.layer_pattern.count("M")
         return self.n_layers if self.has_ssm else self.n_linear_layers
 
     def state_shape(self, rows: int) -> tuple[int, ...] | None:
@@ -364,9 +401,9 @@ class ModelConfig:
         if self.has_short_conv:
             return None
         if self.has_ssm:
-            return (self.n_layers, rows, self.ssm_heads, self.ssm_head_dim,
-                    self.ssm_state_dim)
-        return (self.n_linear_layers, rows, self.lin_heads, self.lin_key_dim,
+            return (self.n_state_layers, rows, self.ssm_heads,
+                    self.ssm_head_dim, self.ssm_state_dim)
+        return (self.n_state_layers, rows, self.lin_heads, self.lin_key_dim,
                 self.lin_value_dim)
 
     def conv_shape(self, rows: int) -> tuple[int, ...]:
@@ -375,9 +412,9 @@ class ModelConfig:
         if self.has_short_conv:
             return (self.n_conv_layers, rows, self.conv_kernel - 1, self.dim)
         if self.has_ssm:
-            return (self.n_layers, rows, self.ssm_conv_kernel - 1,
+            return (self.n_state_layers, rows, self.ssm_conv_kernel - 1,
                     self.ssm_conv_dim)
-        return (self.n_linear_layers, rows, self.lin_conv_kernel - 1,
+        return (self.n_state_layers, rows, self.lin_conv_kernel - 1,
                 self.lin_conv_dim)
 
     @property
@@ -408,10 +445,12 @@ class ModelConfig:
     def n_kv_layers(self) -> int:
         """Layers whose K/V lives in THE block pool: every one, a hybrid's
         full ones, or the full ones beside window layers (those have a pool
-        of their own, ``n_window_layers`` deep), or the attention layers
-        beside short-conv ones."""
+        of their own, ``n_window_layers`` deep), the attention layers
+        beside short-conv ones, or the pattern's attention layers."""
         if self.has_short_conv:
             return self.n_attn_layers
+        if self.layer_pattern:
+            return self.layer_pattern.count("*")
         return self.n_periods if self.layer_period else self.n_layers
 
     @property
@@ -478,6 +517,21 @@ class ModelConfig:
                 moe_score=("softmax", "sigmoid")[h.moe_score_func],
                 n_dense_layers=h.n_dense_layers,
                 dense_hidden_dim=h.dense_hidden_dim,
+                moe_routed_scale=h.moe_routed_scale_milli / 1000.0,
+                moe_router_width=h.moe_router_width,
+                moe_first_expert=h.moe_first_expert)
+        if h.arch_type == ArchType.NEMOTRON_H:
+            hybrid = dict(
+                layer_pattern=tuple(h.layer_pattern),
+                ssm_heads=h.ssm_n_heads, ssm_head_dim=h.ssm_head_dim,
+                ssm_groups=h.ssm_n_groups, ssm_state_dim=h.ssm_state_dim,
+                ssm_conv_kernel=h.ssm_conv_kernel, ssm_chunk=h.ssm_chunk_size,
+                mult=Multipliers(),
+                moe_latent_dim=h.moe_latent_dim,
+                moe_select_bias=bool(h.moe_select_bias),
+                moe_norm_eps=1e-20,
+                moe_score=("softmax", "sigmoid")[h.moe_score_func],
+                shared_expert_dim=h.shared_expert_dim,
                 moe_routed_scale=h.moe_routed_scale_milli / 1000.0,
                 moe_router_width=h.moe_router_width,
                 moe_first_expert=h.moe_first_expert)

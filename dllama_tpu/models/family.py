@@ -2,7 +2,8 @@
 its loader, its sizes, its refusals and its start-up words.
 
 A family's module (``models/llama.py`` for the dense Llama / Qwen3 equations,
-``hybrid.py``, ``falcon_h1.py``, ``laguna.py``, ``axk1.py``, ``lfm2.py``) ends
+``hybrid.py``, ``falcon_h1.py``, ``laguna.py``, ``axk1.py``, ``lfm2.py``,
+``nemotron_h.py``) ends
 in ``FAMILY = Family(...)``; :func:`family_of` picks it by ``cfg.arch``, and
 ``llama.forward`` / ``llama.paged_forward`` (the one entry of every family),
 the engine, the HBM guard, the loader, the paged generator and the start-up
@@ -25,7 +26,7 @@ from ..formats.mfile import ArchType
 
 # the labels of ``dllama_layer_kinds`` (runtime/telemetry.LAYER_KINDS)
 LAYER_KINDS = ("linear", "ssm_beside_full", "full", "latent", "sliding",
-               "conv")
+               "conv", "mamba", "attention", "moe")
 
 
 def layer_kinds(**counts: int) -> dict[str, int]:
@@ -91,6 +92,7 @@ _MODULES = {
     ArchType.FALCON_H1: "falcon_h1",
     ArchType.AXK1: "axk1",
     ArchType.LFM2: "lfm2",
+    ArchType.NEMOTRON_H: "nemotron_h",
 }
 
 

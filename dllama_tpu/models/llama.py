@@ -220,8 +220,12 @@ def _sharded_flash(cfg: ModelConfig, plan, q, k_cache, v_cache, start_pos):
 def _hidden_act(cfg: ModelConfig, x: jax.Array) -> jax.Array:
     if cfg.hidden_act == HiddenAct.SILU:
         return jax.nn.silu(x)
-    # tanh-approx gelu (reference: gelu_F32, nn-cpu-ops.cpp:1133-1142)
-    return jax.nn.gelu(x, approximate=True)
+    if cfg.hidden_act == HiddenAct.RELU2:
+        return jnp.square(jax.nn.relu(x))
+    if cfg.hidden_act == HiddenAct.GELU:
+        # tanh-approx gelu (reference: gelu_F32, nn-cpu-ops.cpp:1133-1142)
+        return jax.nn.gelu(x, approximate=True)
+    raise ValueError(f"unknown hidden activation {cfg.hidden_act!r}")
 
 
 def _moe_router(cfg: ModelConfig, h: jax.Array, gate: jax.Array):

@@ -37,9 +37,20 @@ them say how many times the decode kernel, a plane a pair, reads each),
 tokens each held expert saw), summed over the layers, accumulated on
 the device and given back with the pools.
 
+**An expert's form is data too**: gated (``W2 (act(W1 x) * W3 x)``, three
+planes) or not (``W2 act(W1 x)``, two: the stack's ``we3`` / ``ws3`` are
+None), its activation ``cfg.hidden_act``. With a LATENT
+(``cfg.moe_latent_dim``: the stack's ``w_lat_in w_lat_out``) the experts live
+in that width: ``w_lat_in`` projects the layer's input down in front of the
+dispatch, ``w_lat_out`` the weighted sum of the held experts back up behind
+it (linear, so a share's partial sum is projected as the whole would be: the
+chips of a layer would exchange rows of the latent's width); the router and
+the shared expert read the layer's input at the model's width.
+
 A layer stack that uses these functions names its leaves ``norm_ffn``, ``w1
 w2 w3`` (the leading dense layers'), ``moe_gate``, ``we1 we2 we3``, ``ws1 ws2
-ws3``, the routed ones stacked over the ``n_moe_layers`` that have one.
+ws3``, the routed ones stacked over the ``n_moe_layers`` that have one, and
+``w_lat_in w_lat_out`` where it has a latent.
 """
 
 from __future__ import annotations
@@ -101,8 +112,18 @@ def require_quantized(ld) -> None:
 
 
 def swiglu(cfg: ModelConfig, h: jax.Array, w1, w2, w3) -> jax.Array:
+    """A feed-forward over planes read whole: ``W2 (act(W1 h) * W3 h)``, or
+    the ungated ``W2 act(W1 h)`` where ``w3`` is None."""
     gate = _hidden_act(cfg, linear(h, w1))
-    return linear(gate * linear(h, w3), w2)
+    return linear(gate if w3 is None else gate * linear(h, w3), w2)
+
+
+def _gate_up(cfg: ModelConfig, project, x: jax.Array, lp) -> jax.Array:
+    """``act(W1 x) * W3 x``, or ``act(W1 x)`` for ungated experts (a stack
+    without ``we3``), through ``project(x, stack)`` (either form's kernel
+    over one projection)."""
+    a = _hidden_act(cfg, project(x, lp.we1))
+    return a if lp.we3 is None else a * project(x, lp.we3)
 
 
 def route(cfg: ModelConfig, h: jax.Array, gate: jax.Array,
@@ -194,8 +215,7 @@ def _experts_step(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
     else:
         gemv = lambda a, stack: eg.expert_gemv_xla(a, stack, m, experts,
                                                    n_held, fast=fast)
-    xp = x[rows]
-    a = _hidden_act(cfg, gemv(xp, lp.we1)) * gemv(xp, lp.we3)
+    a = _gate_up(cfg, gemv, x[rows], lp)
     y = gemv(a.astype(x.dtype), lp.we2) * w[:, None]
     return jnp.zeros(x.shape, jnp.float32).at[rows].add(y)
 
@@ -211,7 +231,8 @@ def _experts_chunk_xla(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
     N, k = weights.shape
     flat = lambda we: QuantizedWeight(*(a.reshape((-1,) + a.shape[2:])
                                         for a in we))
-    f1, f2, f3 = flat(lp.we1), flat(lp.we2), flat(lp.we3)
+    f1, f2 = flat(lp.we1), flat(lp.we2)
+    f3 = None if lp.we3 is None else flat(lp.we3)
     # [N, held]: row n's router weight for held expert e, 0 where it did
     # not choose it (or is not live: ``local`` reads ``held`` there)
     w = jnp.zeros((N, E + 1), jnp.float32).at[
@@ -222,7 +243,7 @@ def _experts_chunk_xla(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
         def some(y):
             i = m * E + e
             out = swiglu(cfg, x, LayerSlice(f1, i), LayerSlice(f2, i),
-                         LayerSlice(f3, i))
+                         None if f3 is None else LayerSlice(f3, i))
             return y + out.astype(jnp.float32) * jax.lax.dynamic_slice_in_dim(
                 w, e, 1, axis=1)
 
@@ -249,6 +270,29 @@ def _runs(cfg: ModelConfig, local: jax.Array, tm: int):
     return runs, tile_end[-1] * tm
 
 
+# dlint: static-fn
+def _chunk_pieces(cfg: ModelConfig, x: jax.Array, N: int, k: int, lp):
+    """``(rows a piece, kernel kwargs)`` for a chunk of ``N`` rows: the
+    whole chunk where the grouped kernel's gate takes it, else the largest
+    halving of it (down to a tile of rows) that the gate does take: the
+    fed layout's static bound ``fed_rows(rows min(k, held), held)`` grows
+    with ``k`` and the held experts, and at 22 of 128 held a chunk of 128
+    rows is past the kernel's VMEM budget where one of 64 is not. None
+    where no piece passes (off a TPU, under a plan)."""
+    E = cfg.n_experts
+    fast = _fast_mode(x) or lp.we1.scales.dtype == jnp.bfloat16
+    rows = N
+    while True:
+        F = ec.fed_rows(rows * min(k, E), E)
+        kw = ec.kernel_choice(rows, F, lp.we1, fast, False, x.dtype.itemsize)
+        if kw is not None and ec.kernel_choice(
+                rows, F, lp.we2, fast, True, x.dtype.itemsize) is not None:
+            return rows, kw
+        if rows % 2 or rows // 2 < ec.TILE_ROWS:
+            return None
+        rows //= 2
+
+
 def _experts_chunk(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
     """The chunk form, and the rows it fed the planes: the held pairs sorted
     by expert, each of the three projections ONE grouped kernel over the
@@ -262,17 +306,36 @@ def _experts_chunk(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
     the FED layout (a run owns whole tiles), at its static bound
     ``fed_rows(N min(k, held), held)``: the gate-times-up product there and
     the copies of those arrays in and out of the kernels' VMEM are the work
-    that the bound, not the pairs, sizes. Off a TPU, under a plan, or where
-    the kernel's VMEM predicate refuses: :func:`_experts_chunk_xla`."""
+    that the bound, not the pairs, sizes. A chunk whose bound the kernel's
+    VMEM predicate refuses goes through it in PIECES of rows that it takes
+    (:func:`_chunk_pieces`: a scan over one traced piece, a piece fetching
+    the planes its own rows chose). Off a TPU, under a plan, or where no
+    piece passes: :func:`_experts_chunk_xla`."""
+    N, k = weights.shape
+    pieces = _chunk_pieces(cfg, x, N, k, lp)
+    if pieces is None:
+        return _experts_chunk_xla(cfg, x, local, weights, m, lp)
+    rows_a_piece, kw = pieces
+    if rows_a_piece == N:
+        return _experts_grouped(cfg, x, local, weights, m, lp, kw)
+
+    def piece(fed, xs):
+        y, fed_p = _experts_grouped(cfg, *xs, m, lp, kw)
+        return fed + fed_p, y
+
+    n = N // rows_a_piece
+    fed, y = jax.lax.scan(piece, jnp.int32(0), (
+        x.reshape(n, rows_a_piece, -1), local.reshape(n, rows_a_piece * k),
+        weights.reshape(n, rows_a_piece, k)))
+    return y.reshape(N, -1), fed
+
+
+def _experts_grouped(cfg: ModelConfig, x: jax.Array, local, weights, m, lp,
+                     kw: dict):
+    """:func:`_experts_chunk` for rows the grouped kernel takes whole."""
     N, k = weights.shape
     E = cfg.n_experts
-    fast = _fast_mode(x) or lp.we1.scales.dtype == jnp.bfloat16
     F = ec.fed_rows(N * min(k, E), E)
-    choice = lambda stack, scatter: ec.kernel_choice(
-        N, F, stack, fast, scatter, x.dtype.itemsize)
-    kw = choice(lp.we1, False)
-    if kw is None or choice(lp.we2, True) is None:
-        return _experts_chunk_xla(cfg, x, local, weights, m, lp)
     # the sort of _sorted_pairs alone: the kernel reads a pair's weight where
     # the router left it, so nothing is gathered into sorted copies (two
     # gathers of N k values cost 34 us a layer on a v5e, a sort 6)
@@ -285,8 +348,7 @@ def _experts_chunk(cfg: ModelConfig, x: jax.Array, local, weights, m, lp):
         return ec.expert_chunk(a, stack, m, runs, rows, *pair_weights,
                                rows_out=rows_out, **kw)
 
-    a = (_hidden_act(cfg, grouped(x, lp.we1, rows_out=F))
-         * grouped(x, lp.we3, rows_out=F))
+    a = _gate_up(cfg, lambda x, stack: grouped(x, stack, rows_out=F), x, lp)
     return grouped(a.astype(x.dtype), lp.we2, (order, weights),
                    rows_out=N), fed
 
@@ -295,7 +357,9 @@ def routed_ffn(cfg: ModelConfig, h: jax.Array, lp, m,
                live: jax.Array):
     """``scale sum_{held} w_e E_e(h) + S(h)`` for ``h [B, T, dim]`` in routed
     layer ``m``, and the layer's ``stats``; ``live [B * T]`` marks the rows
-    that are real (a dead slot's, a chunk's padding, are not routed)."""
+    that are real (a dead slot's, a chunk's padding, are not routed). With a
+    latent: ``W_out (scale sum_{held} w_e E_e(W_in h)) + S(h)``, the router
+    on ``h``."""
     B, T, D = h.shape
     x = h.reshape(B * T, D)
     at = lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False)
@@ -304,14 +368,21 @@ def routed_ffn(cfg: ModelConfig, h: jax.Array, lp, m,
     weights, idx = (route(cfg, x, at(lp.moe_gate)) if bias is None
                     else route(cfg, x, at(lp.moe_gate), at(bias)))
     local, stats = routed_pairs(cfg, idx, live)
+    # a latent (cfg.moe_latent_dim) is the stack's two projection leaves
+    lat_in = getattr(lp, "w_lat_in", None)
+    z = x if lat_in is None else linear(x, _plane(lat_in, m))
     if B * T <= STEP_FORM_MAX_ROWS:
-        y = _experts_step(cfg, x, local, weights, m, lp)
+        y = _experts_step(cfg, z, local, weights, m, lp)
     else:
-        y, fed = _experts_chunk(cfg, x, local, weights, m, lp)
+        y, fed = _experts_chunk(cfg, z, local, weights, m, lp)
         stats = stats.at[2].set(fed.astype(jnp.int32))
+    if lat_in is not None:
+        y = linear(y.astype(h.dtype),
+                   _plane(lp.w_lat_out, m)).astype(jnp.float32)
     if lp.ws1 is not None:
         y = y + swiglu(cfg, h, _plane(lp.ws1, m), _plane(lp.ws2, m),
-                        _plane(lp.ws3, m)).reshape(B * T, D)
+                        None if lp.ws3 is None else _plane(lp.ws3, m)
+                        ).reshape(B * T, D)
     return y.reshape(B, T, D).astype(h.dtype), stats
 
 

@@ -111,20 +111,22 @@ def step_kernel_choice() -> dict | None:  # dlint: static-fn
 
 def _step_kernel(layer_ref, rows_ref, xa_ref, bc_ref, s_ref, y_ref, s_out_ref,
                  *, heads: int):
-    """One (row, group of ``heads`` heads) of the step form. ``xa_ref [1,
-    heads, P, 2]`` holds ``dt x`` and the decay (repeated down the column)
-    as columns; ``bc_ref [1, 1, 8, N]`` the heads' group's B and C as rows 0
-    and 1; ``s_ref [heads, P, N]`` is the state, read once, and
-    ``s_out_ref`` the same cells of the same pool, written once."""
+    """One (row, group of ``heads`` heads) of the step form. ``xa_ref [1, 1,
+    2, P, heads]`` holds ``dt x`` (plane 0) and the decay (plane 1, repeated
+    down the column) with a head a LANE: head ``h``'s column is ``[:, h:h +
+    1]``; ``bc_ref [1, 1, 8, N]`` the heads' group's B and C as rows 0 and
+    1; ``s_ref [heads, P, N]`` is the state, read once, and ``s_out_ref`` the
+    same cells of the same pool, written once; ``y_ref [1, 1, P, heads]``
+    takes head ``h``'s readout as its column ``h``."""
     del layer_ref, rows_ref  # spent in the index maps
     b = bc_ref[0, 0, 0:1, :]                      # [1, N]
     c = bc_ref[0, 0, 1:2, :]
     for h in range(heads):
-        dx = xa_ref[0, h, :, 0:1]                 # [P, 1]
-        decay = xa_ref[0, h, :, 1:2]
+        dx = xa_ref[0, 0, 0, :, h:h + 1]          # [P, 1]
+        decay = xa_ref[0, 0, 1, :, h:h + 1]
         S = s_ref[h] * decay + dx * b
         s_out_ref[h] = S
-        y_ref[0, h] = jnp.sum(S * c, axis=1, keepdims=True)
+        y_ref[0, 0, :, h:h + 1] = jnp.sum(S * c, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -133,42 +135,55 @@ def ssd_step(pool, layer, rows, x, dt, decay, Bm, Cm, *,
     """:func:`ssd_step_xla` as ONE Pallas kernel over the pool in place:
     layer and rows ride in as scalar-prefetch operands, the index maps pick
     ``(layer, rows[b], head group)``, and the pool's output is aliased onto
-    its input, so cells no row names are never touched."""
+    its input, so cells no row names are never touched.
+
+    The kernel wants ``dt x`` and the decay as COLUMNS down a head's ``P``
+    sublanes. They reach it ``[B, H / hb, 2, P, hb]``, a grid step's ``hb``
+    heads side by side on the lanes, and ``y`` comes back ``[B, H / hb, P,
+    hb]``: the tiled layout pads a minor dimension to 128 lanes, so a head a
+    lane costs ``128 / hb`` times the values where a minor dimension of 2 (or
+    1) cost 64 (or 128) times (at 32 rows x 128 heads x 64 that was 134 MB
+    an operand a layer, written and read around a kernel that moves 270 MB
+    of state: PERF.md section 6, PR 51)."""
     _L, _R, H, P, N = pool.shape
     B, G = x.shape[0], Bm.shape[1]
     per_group = H // G
     hb = next(c for c in _HEADS_PER_STEP if per_group % c == 0)
     f32 = jnp.float32
-    xa = jnp.stack([(dt[..., None] * x).astype(f32),
-                    jnp.broadcast_to(decay.astype(f32)[..., None], (B, H, P))],
-                   axis=-1)                                         # [B, H, P, 2]
+    by_lane = lambda a: jnp.swapaxes(a.reshape(B, H // hb, hb, P), 2, 3)
+    xa = jnp.stack([by_lane((dt[..., None] * x).astype(f32)),
+                    by_lane(jnp.broadcast_to(decay.astype(f32)[..., None],
+                                             (B, H, P)))],
+                   axis=2)                                # [B, H / hb, 2, P, hb]
     bc = jnp.stack([Bm.astype(f32), Cm.astype(f32)]
                    + [jnp.zeros((B, G, N), f32)] * 6, axis=2)       # [B, G, 8, N]
     vmem = pltpu.VMEM
     state = pl.BlockSpec((None, None, hb, P, N),
                          lambda b, h, l, r: (l[0], r[b], h, 0, 0),
                          memory_space=vmem)
-    per_row = lambda last: pl.BlockSpec(
-        (1, hb, P, last), lambda b, h, l, r: (b, h, 0, 0), memory_space=vmem)
+    columns = pl.BlockSpec((1, 1, 2, P, hb), lambda b, h, l, r: (b, h, 0, 0, 0),
+                           memory_space=vmem)
+    readout = pl.BlockSpec((1, 1, P, hb), lambda b, h, l, r: (b, h, 0, 0),
+                           memory_space=vmem)
     group = pl.BlockSpec((1, 1, 8, N),
                          lambda b, h, l, r: (b, (h * hb) // per_group, 0, 0),
                          memory_space=vmem)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # layer, rows
         grid=(B, H // hb),
-        in_specs=[per_row(2), group, state],
-        out_specs=[per_row(1), state],
+        in_specs=[columns, group, state],
+        out_specs=[readout, state],
     )
     y, pool = pl.pallas_call(
         functools.partial(_step_kernel, heads=hb),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, H, P, 1), f32),
+        out_shape=[jax.ShapeDtypeStruct((B, H // hb, P, hb), f32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         input_output_aliases={4: 1},  # the pool, after layer rows xa bc
         name="ssd_step", interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
       xa, bc, pool)
-    return y[..., 0], pool
+    return jnp.swapaxes(y, 2, 3).reshape(B, H, P), pool
 
 
 def _mm(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:

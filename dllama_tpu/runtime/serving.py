@@ -1595,6 +1595,9 @@ class PagedGenerator(_GeneratorCore):
 
             self.moe_stats = zero_totals(self.cfg)
             self._moe_seen = np.zeros(self.moe_stats.shape, np.int64)
+            # held planes a step's routed layers COULD fetch, added a step:
+            # what ``moe_planes`` is a share of
+            self._moe_plane_slots = 0
         # what the step's cache is made of, in the order every step program
         # takes it and gives it back (models/*.paged_forward): ONE
         # description of what the architecture carries, so that a decoder
@@ -2878,7 +2881,10 @@ class PagedGenerator(_GeneratorCore):
         (``moe_held`` / ``moe_absent``, ``moe_tokens`` a held expert joined
         by ``/``, ``moe_step_held`` / ``moe_planes`` the steps' own pairs
         and the distinct held experts their layers chose: pairs over planes
-        is how often the decode kernel, a plane a pair, reads a plane, ``moe_chunk_held`` / ``moe_chunk_fed`` the chunks' own
+        is how often the decode kernel, a plane a pair, reads a plane,
+        ``moe_plane_slots`` the routed layers times the held experts added a
+        step: planes over them is the share of its held planes a step
+        fetched, ``moe_chunk_held`` / ``moe_chunk_fed`` the chunks' own
         pairs and the rows they fed, ``wblocks_allocated`` /
         ``wblocks_returned``)."""
         delta = (totals.astype(np.int64) - self._moe_seen) % (1 << 32)
@@ -2894,6 +2900,7 @@ class PagedGenerator(_GeneratorCore):
             self._m_moe_tokens.inc(int(both[N_COUNTS + e]),
                                    expert=str(int(e)))
         wait.set(moe_pairs=int(delta[0, 0]))
+        self._moe_plane_slots += self.cfg.n_moe_layers * self.cfg.n_experts
         if wait.traced:
             pairs, tokens = self._m_moe_pairs, self._m_moe_tokens
             wait.set(moe_held=int(pairs.total(where="held")),
@@ -2903,6 +2910,7 @@ class PagedGenerator(_GeneratorCore):
                          for e in range(self.cfg.n_experts)),
                      moe_step_held=int(self._moe_seen[0, 0]),
                      moe_planes=int(self._moe_seen[0, 3]),
+                     moe_plane_slots=self._moe_plane_slots,
                      moe_chunk_held=int(self._moe_seen[1, 0]),
                      moe_chunk_fed=int(self._moe_seen[1, 2]),
                      wblocks_allocated=int(self._m_wblocks_alloc.total()),

@@ -514,7 +514,9 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "and softmax attention side by side in one layer; latent: "
           "latent attention over one compressed cache row a token; conv: "
           "gated short-convolution layers whose state is the convolution's "
-          "tail); a dense decoder is all full"),
+          "tail; mamba / attention / moe: the blocks of a decoder whose "
+          "every layer is ONE of an SSD mixer, attention without positions "
+          "or a routed feed-forward); a dense decoder is all full"),
     _spec(STATE_SLOTS_USED, "gauge",
           "Rows of the recurrent state pool held by live sequences "
           "(committed and not yet retired); 0 without recurrent layers"),

@@ -29,6 +29,7 @@ PR34_CELLS = ("laguna-s-2.1.mixed-queue", "mistral-7b-v0.3.mixed-queue")
 PR36_CELL = "falcon-h1-34b.chat"
 PR42_CELL = "a.x-k1.agent-sessions"
 PR44_CELL = "lfm2-24b-a2b.batch-generate"
+PR51_CELL = "nemotron-3-super-120b-a12b.reasoning"
 
 sys.path.insert(0, SELFTEST)
 try:
@@ -249,3 +250,47 @@ def test_the_short_conv_cell_gets_the_modules_it_names_and_its_traffic_is_the_is
     assert sizes[0] == sizes[1] and plans[0].max_context <= 896 <= conf["engine"]["max_seq_len"]      # the seed moves no size
     assert all(64 <= n <= 256 and 256 <= m <= 640 for n, m in sizes[0]) and plans[0].clients == 32
     assert max(t for r in plans[1].requests for t in r.new_tokens) > 60000               # ids from the whole vocabulary
+
+
+# -- and PR 51's cell, from a file of PR 51's own -----------------------------------
+
+with open(os.path.join(BENCH, "nemotron_h", "selftest", "counts_frozen.json"), encoding="utf-8") as _f:
+    PR51_FROZEN = json.load(_f)
+
+
+def test_pr51s_cell_counts_through_the_seam_are_what_pr51_froze():
+    _cell, conf, _traffic, mods = _seam._resolve(PR51_CELL)
+    rows = [r for r in PR51_FROZEN["rows"] if r["cell"] == PR51_CELL]
+    assert len(rows) == 24
+    for r in rows:
+        assert getattr(mods["counts"], r["fn"])(conf["model"], **r["args"]) == r["value"], r
+
+
+def test_the_pattern_cell_gets_the_modules_it_names_and_the_lists_it_was_appended_to():
+    cell, conf, traffic_path, mods = _seam._resolve(PR51_CELL)
+    assert {k: os.path.relpath(m.__file__, BENCH) for k, m in mods.items()} == conf["modules"] == {
+        "reference": "nemotron_h/reference.py", "weights": "nemotron_h/weights.py", "counts": "nemotron_h/counts.py"}
+    assert {"none", "shift", "droplayer", "dropblock", "dropstate", "nodecay", "bf16state", "misroute", "noshared",
+            "bf16router", "nolatent", "gated", "nobias", "rope"} == set(mods["reference"].CONTROLS)
+    counts = mods["counts"]
+    assert counts.kernel_counts(conf["model"], "ssd_step", rows=32)["calls_per_program"] == 10
+    assert counts.kernel_counts(conf["model"], "expert_chunk", rows=32)["layers"] == 10
+    assert counts.kernel_counts(conf["model"], "paged_ragged_attention", rows=32)["calls_per_program"] == 2
+    assert (cell["chips"], cell["traffic"], len(cell["why"]) <= 200) == (1, "reasoning-nemotron-3-super", True)
+    assert os.path.basename(traffic_path) == "reasoning-nemotron-3-super.json"
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json"), encoding="utf-8") as f:
+        manifest = json.load(f)
+    assert len(manifest["workloads"]) == 11 and len(manifest["configs"]) == 8 and manifest["workloads"][-1]["name"] == PR51_CELL
+    mine = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [PR51_CELL]]
+    assert mine == ["moe_planes_fetched_share"] and manifest["per_layer"][-1]["name"] == mine[0]
+    with open(os.path.join(BENCH, "layer_metrics", mine[0] + ".json"), encoding="utf-8") as f:
+        spec = json.load(f)
+    assert spec == {"reader": "slice_counters", "args": {"what": "ratio", "over": ["moe_planes"],
+                                                          "under": ["moe_plane_slots"], "scale": 100.0}}
+    # appended to every list lfm2's cell stands in (its judged tail apart: the ladder's) and to falcon's two
+    lists = {m["name"]: m["workloads"] for s in ("end_to_end", "per_layer") for m in manifest[s] if "workloads" in m}
+    beside_lfm2 = {n for n, w in lists.items() if PR44_CELL in w} - {"itl_p90_ms"}
+    assert all(lists[n][-1] == PR51_CELL for n in beside_lfm2 | {"ssd_step_share", "ssd_step_hbm_share"})
+    assert sum(PR51_CELL in lists[n] for n in ("itl_p88_ms", "itl_p90_ms", "itl_p95_ms")) == 1      # ONE judged tail
+    assert not {"gated_delta_step_share", "mla_step_share", "expert_gemv_share", "window_blocks_returned_share"} & {
+        n for n, w in lists.items() if PR51_CELL in w}

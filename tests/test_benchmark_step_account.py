@@ -245,8 +245,9 @@ def test_manifest_entries_are_appended_with_the_accepted_layers():
         "fetch_copy_ms_p50", "step_inner_gap_ms_p50"]
     assert [w["name"] for w in manifest["workloads"]][:len(CELLS)] == CELLS
     # and ``lfm2-24b-a2b.batch-generate`` (PR 44) to all six: its slice is chunk-free step ticks four times in five
+    # and ``nemotron-3-super-120b-a12b.reasoning`` (PR 51) behind it: the same kind of slice
     later = {name: (["a.x-k1.agent-sessions"] if name in ("step_upload_ms_p50", "step_call_ms_p50") else [])
-             + ["lfm2-24b-a2b.batch-generate"] for name in EXPECTED}
+             + ["lfm2-24b-a2b.batch-generate", "nemotron-3-super-120b-a12b.reasoning"] for name in EXPECTED}
     for name in EXPECTED:
         m = by_name[name]
         assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".json"))

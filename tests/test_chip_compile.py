@@ -640,6 +640,118 @@ def test_expert_gemv_compiles_at_lfm2s_experts_for_v5e(one_chip, k, n):
     assert any("expert_gemv" in name for name in kernels), kernels
 
 
+# -- nemotron-3-super-120b-a12b at its published widths (PR 51) ------------------------
+# the fused / chunk GEMV's planes: the mixer's 18,432-wide in-projection and its
+# out-projection, the shared expert at 5376, the two latent projections at 1024,
+# q (4096) and k / v (256)
+NEMOTRON_STEP = [(4096, 18432), (8192, 4096), (4096, 5376), (5376, 4096), (4096, 1024), (1024, 4096),
+                 (4096, 4096), (4096, 256)]
+
+
+def test_ssd_step_compiles_at_nemotrons_state_for_v5e(one_chip):
+    """The SSD step form's kernel at ``[10, 33, 128, 64, 128]``: ten mixer
+    layers held, 128 heads of 64 in 8 groups, a state of 128, 32 slots and
+    the null row, the pool in place."""
+    from dllama_tpu.ops import ssd
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+
+    slots, H, P, G, N = 32, 128, 64, 8, 128
+    f32 = jnp.float32
+    pool = _shape(one_chip, (10, slots + 1, H, P, N), f32)
+    compiled = jax.jit(functools.partial(ssd.ssd_step, interpret=False),
+                       donate_argnums=(0,)).lower(
+        pool, _shape(one_chip, (), jnp.int32), _shape(one_chip, (slots,), jnp.int32),
+        _shape(one_chip, (slots, H, P), f32), _shape(one_chip, (slots, H), f32),
+        _shape(one_chip, (slots, H), f32), _shape(one_chip, (slots, G, N), f32),
+        _shape(one_chip, (slots, G, N), f32)).compile()
+    assert mosaic_kernels(compiled.as_text()).get("ssd_step") == 1
+    # in place: the program holds no second pool (10 x 33 x 4.19 MB = 1.38 GB),
+    # and ``dt x``, the decay and ``y`` reach the kernel a head a LANE
+    # (``[32, 16, 2, 64, 8]``: 34 MB as tiled), not with a minor dimension of
+    # 2 and 1 that the tiled layout pads to 128 lanes (134 MB each: PR 51)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("k,n", [(1024, 2816), (2816, 1024)])
+def test_expert_gemv_compiles_at_nemotrons_experts_for_v5e(one_chip, k, n):
+    """The decode form at an expert's two planes in the latent's width, 352
+    pairs (16 rows x 22 a token) over 128 held of 10 routed layers. The
+    planes are HELD 2816 wide (``cfg.expert_width_held``): at the published
+    2688 a plane's scales are 84 rows, Mosaic pads the HBM operand to 88
+    (whole tiles of 8) and refuses the DMA's slice of 84."""
+    from dllama_tpu.ops import expert_gemv as eg
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    assert eg.supports(352, k, n, True)
+    stack = QuantizedWeight(scales=_shape(one_chip, (10, 128, k // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (10, 128, k, n), jnp.int8))
+    kernels = _compiled_kernels(
+        lambda x, st, layer, experts, n_pairs: eg.expert_gemv(x, st, layer, experts, n_pairs, fast=True),
+        _shape(one_chip, (352, k), jnp.bfloat16), stack, _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (352,), jnp.int32), _shape(one_chip, (), jnp.int32))
+    assert any("expert_gemv" in name for name in kernels), kernels
+
+
+@pytest.mark.parametrize("kk,n,rows,scatter", [(1024, 2816, 32, False), (2816, 1024, 32, True)])
+def test_expert_chunk_compiles_at_nemotrons_experts_for_v5e(one_chip, kk, n, rows, scatter):
+    """The grouped routed kernel at the same planes, 22 a token over 128 held
+    of 512, the 32-row STEP's form: 704 pairs of which a quarter fall here,
+    the fed layout at its static bound of 4,800 rows. A prefill chunk of 128
+    rows or more is past the kernel's VMEM predicate (the bound of the fed
+    layout, 6,912 rows and up, times 2816 float32 lanes: 78 MB of a budget
+    of 72) and takes the every-row form through ``linear``: the gate says
+    so."""
+    from dllama_tpu.ops import expert_chunk as ec
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    held, k = 128, 22
+    pairs = rows * k
+    fed = ec.fed_rows(pairs, held)
+    assert ec.stripe(rows, fed, kk, n, True, scatter) is not None
+    assert scatter or (ec.stripe(64, ec.fed_rows(64 * k, held), kk, n, True, False) == 1408
+                       and ec.stripe(128, ec.fed_rows(128 * k, held), kk, n, True, False) is None)
+    stack = QuantizedWeight(scales=_shape(one_chip, (10, held, kk // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (10, held, kk, n), jnp.int8))
+    i32 = lambda *shape: _shape(one_chip, shape, jnp.int32)
+    runs = (i32(),) + tuple(i32(held) for _ in range(4))
+    if scatter:
+        kernels = _compiled_kernels(
+            lambda x, st, layer, runs, r, at, w: ec.expert_chunk(x, st, layer, runs, r, (at, w), rows_out=rows, fast=True),
+            _shape(one_chip, (fed, kk), jnp.bfloat16), stack, i32(), runs, i32(pairs), i32(pairs), _shape(one_chip, (rows, k), jnp.float32))
+    else:
+        kernels = _compiled_kernels(
+            lambda x, st, layer, runs, r: ec.expert_chunk(x, st, layer, runs, r, rows_out=fed, fast=True),
+            _shape(one_chip, (rows, kk), jnp.bfloat16), stack, i32(), runs, i32(pairs))
+    assert any("expert_chunk" in name for name in kernels), kernels
+
+
+def test_paged_attention_compiles_at_a_group_of_16_over_2_kv_heads_for_v5e(one_chip):
+    """The step's walk: 32 slots of 2048 in blocks of 16, 32:2 heads of 128
+    (a group of 16 query heads a K/V head), no rotary table anywhere."""
+    heads, group = _compile_paged_attention(one_chip, 32, 1, 32, 2, 128, 128, 16, jnp.bfloat16)
+    assert heads == 2 and group >= 1
+
+
+@pytest.mark.parametrize("rows", [16, 32, 256])
+@pytest.mark.parametrize("k,n", NEMOTRON_STEP)
+def test_quant_matmul_compiles_at_nemotrons_planes_for_v5e(one_chip, k, n, rows):
+    """The dense Q40 planes of the three blocks, stack + index: the fused
+    GEMV up to 16 rows, the chunk regime beyond (which is also the 32-row
+    step's): one Mosaic kernel each."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+    from dllama_tpu.ops.quant_matmul import fused_path, quant_matmul
+
+    stack = QuantizedWeight(scales=_shape(one_chip, (10, k // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (10, k, n), jnp.int8))
+    one = QuantizedWeight(*(jax.ShapeDtypeStruct(p.shape[1:], p.dtype) for p in stack))
+    x = _shape(one_chip, (1, rows, k), jnp.bfloat16)
+    assert fused_path(x.shape, one, True) == ("fused" if rows <= 16 else "chunk")
+    kernels = _compiled_kernels(
+        lambda x, w, l: quant_matmul(x, w, interpret=False, fast=True, fused=True, layer=l),
+        x, stack, _shape(one_chip, (), jnp.int32))
+    assert kernels.get("quant_matmul") == 1, kernels
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_chip_smoke_rehearsal_on_cpu(chips):
     """The script end to end at a toy size with JAX_PLATFORMS=cpu children

@@ -170,7 +170,7 @@ def test_packed_plane_and_float32_dt_rows_are_the_unpacked_in_projection(engine)
     float32 ``dt`` rows) against the ONE in-projection of the published
     layout, joined back and multiplied by ``mup_vector``: the same ``z``,
     the same ``dt`` and, through taps of 1 on the last lane, the same xBC."""
-    from dllama_tpu.models import falcon_h1
+    from dllama_tpu.models import ssd_mixer
     from dllama_tpu.models.llama import _stack_at
     from dllama_tpu.ops.linear import dequantize_weight
 
@@ -181,7 +181,7 @@ def test_packed_plane_and_float32_dt_rows_are_the_unpacked_in_projection(engine)
     lp = lp._replace(conv_w=jnp.zeros_like(lp.conv_w).at[-1].set(1.0), conv_b=jnp.zeros_like(lp.conv_b))
     tail = jnp.zeros((1, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim))
     with jax.default_matmul_precision("highest"):
-        x, dt, Bm, Cm, z, _tail = falcon_h1._mixer_inputs(cfg, u, lp, tail, None)
+        x, dt, Bm, Cm, z, _tail = ssd_mixer.mixer_inputs(cfg, u, lp, tail, None)
         w = jnp.concatenate([dequantize_weight(lp.w_in).T, lp.w_dt], axis=0)       # [192 + 4, dim]: as published
         m, d, gn = cfg.mult, cfg.ssm_inner_dim, cfg.ssm_groups * cfg.ssm_state_dim
         mup = jnp.concatenate([jnp.full((d,), m.ssm_z), jnp.full((d,), m.ssm_x), jnp.full((gn,), m.ssm_b),

@@ -1,5 +1,5 @@
 """One description a decoder family (``dllama_tpu/models/family.py``): what
-``family_of`` hands back for each of the seven ``ArchType`` values, that
+``family_of`` hands back for each of the eight ``ArchType`` values, that
 ``runtime/`` and ``serve/`` ask IT and name no family themselves, and that the
 three small answers the ladders used to give (the HBM guard's weight count, the
 layer-kind gauges, the start-up line's words) are, for each family's tiny
@@ -8,7 +8,7 @@ were written from a run of that parent (PR 49's tree: its engines built on the
 same tiny files); the whole-engine tests of each family cover the rest.
 
 The tiny configurations are the family tests' own: the benchmark's selftest
-files through the benchmark's weight-makers for the five families that bring a
+files through the benchmark's weight-makers for the six families that bring a
 module, ``helpers.tiny_header_params`` for the dense two.
 """
 
@@ -41,6 +41,7 @@ TINY = {
     ArchType.FALCON_H1: ("falcon_h1", "tiny-falcon-h1.json"),
     ArchType.AXK1: ("a_x_k1", "tiny-a.x-k1.json"),
     ArchType.LFM2: ("lfm2", "tiny-lfm2.json"),
+    ArchType.NEMOTRON_H: ("nemotron_h", "tiny-nemotron-h.json"),
 }
 ARCHS = list(ArchType)
 
@@ -59,6 +60,11 @@ PARENT = {
                     "; layers: 7 conv (3 taps, a tail of 2 x 64 a sequence), 2 full (heads of 16 lanes cached in "
                     "128: the paged kernel compiles for them); experts: 8 of 8 held from 0, 2 a token, selection "
                     "bias"),
+    # no parent: PR 51 brought the family; what its own tiny configuration gives
+    ArchType.NEMOTRON_H: (1257472, {"mamba": 4, "attention": 2, "moe": 4},
+                          "; layers: EMEM*EMEM* = 2 x [(EM)x2 *]: 4 SSD mixers (4 heads of 32 in 2 groups, state 16), 2 "
+                          "attention without positions (4:2 heads of 16), 4 routed; experts: 8 of 16 held from 4, 4 a "
+                          "token, 288 wide (held in 512) in a latent of 32, shared 64, selection bias"),
 }
 DENSE_MOE_WEIGHTS = 180736   # tiny_header_params(QWEN3, n_experts=4, n_active_experts=2), the parent's count
 
@@ -129,10 +135,10 @@ def test_the_dense_equations_share_one_family_and_the_entry_is_llamas(cfgs):
     # the one entry of every family keeps its name (the engine jits it as program ``forward``)
     assert llama.forward.__name__ == "forward" and llama.paged_forward.__name__ == "paged_forward"
     others = {family_of(cfgs[a]) for a in ARCHS if TINY[a] is not None}
-    assert len(others) == 5 and llama.FAMILY not in others
+    assert len(others) == 6 and llama.FAMILY not in others
 
 
-FAMILY_MODULES = {"hybrid", "falcon_h1", "laguna", "axk1", "lfm2"}
+FAMILY_MODULES = {"hybrid", "falcon_h1", "laguna", "axk1", "lfm2", "nemotron_h"}
 FAMILY_NAMING = {"is_hybrid", "has_ssm", "has_short_conv"}
 FAMILY_ARCHS = {a.name for a in ARCHS} - {"LLAMA", "QWEN3"}
 
@@ -146,7 +152,7 @@ def _python_files(*folders):
 @pytest.mark.parametrize("path", sorted(_python_files("runtime", "serve")), ids=lambda p: os.path.relpath(p, ROOT))
 def test_runtime_and_serve_name_no_family(path):
     """No import of a family's module, no read of a family-naming predicate,
-    no comparison against one of the five families' ``ArchType`` values."""
+    no comparison against one of the six families' ``ArchType`` values."""
     with open(path, encoding="utf-8") as f:
         tree = ast.parse(f.read())
     found = []
