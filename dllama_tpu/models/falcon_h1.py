@@ -51,6 +51,12 @@ rule's gate rows are.
   STEP form over the state pool in place (the Pallas kernel ``ssd_step`` on
   a TPU, its XLA twin elsewhere); rows whose block table is all null
   (inactive slots riding along) use the pool's null row.
+* :func:`forward_and_step` (``FAMILY.tick``, PR 52): a chunk AND the tick's
+  decode rows through one pass over the nine planes of every layer, the
+  paged server's program for every plain chunk; no chunk logits.
+
+The three are three pairs of closures over ONE layer body
+(:func:`_scan_layers`).
 """
 
 from __future__ import annotations
@@ -66,10 +72,10 @@ from ..parallel.api import current_plan
 from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
 from .family import Family, layer_kinds, state_refusal
-from .llama import (Params, _attend_dense, _attend_paged, _hidden_act,
-                    _stack_at)
+from .llama import (Params, _attend_dense, _attend_paged, _exact_f32_dots,
+                    _hidden_act, _nonfinite_rows, _poison_logits, _stack_at)
 from .rope import apply_rope, build_rope_cache
-from .ssd_mixer import mixer_chunk, mixer_step
+from .ssd_mixer import mixer_chunk, mixer_chunk_and_step, mixer_step
 
 
 class FalconH1Layers(NamedTuple):
@@ -121,15 +127,27 @@ def _qkv(cfg: ModelConfig, u: jax.Array, lp: FalconH1Layers, cos, sin,
             v)
 
 
+def _at(a: jax.Array, l: jax.Array) -> jax.Array:
+    """Layer ``l`` of a column's leaf ``[L, ...]``."""
+    return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+
+
+def _put(a: jax.Array, a_l: jax.Array, l: jax.Array) -> jax.Array:
+    return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
+
+
 def _scan_layers(params: Params, cfg: ModelConfig, tokens: jax.Array,
                  positions: jax.Array, s, conv, k, v, mixer, attend):
-    """The layer scan both programs share. Everything a slot's context is
-    made of rides the CARRY whole, a column's or the pools: ``s, conv``
-    (every layer's state and tail) and ``k, v`` (every layer's cache);
-    nothing is sliced into the scan or stacked out of it. ``mixer(u, lp, l,
-    s, conv) -> (y, s, conv)`` is the form of the SSD mixer and
-    ``attend(q, k, v, k_c, v_c, l) -> (att, k_c, v_c)`` owns the cache; both
-    give the whole arrays back."""
+    """The layer scan the three programs share: the hidden rows ``[B, T,
+    dim]`` behind the last layer, in front of the final norm (:func:`_head`).
+    Everything a slot's context is made of rides the CARRY whole, a column's,
+    the pools' or (the tick program) a pair of both: ``s, conv`` (every
+    layer's state and tail) and ``k, v`` (every layer's cache); nothing is
+    sliced into the scan or stacked out of it. ``mixer(u, lp, l, s, conv) ->
+    (y, s, conv)`` is the form of the SSD mixer and ``attend(q, k, v, k_c,
+    v_c, l) -> (att, k_c, v_c)`` owns the cache; both give the whole arrays
+    back. Everything else a layer does it does a row at a time, so the rows
+    along ``T`` need not be one sequence's: only the two closures know."""
     m = cfg.mult
     B, T = tokens.shape
     cos, sin = build_rope_cache(cfg)
@@ -154,9 +172,15 @@ def _scan_layers(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
     (x, s, conv, k, v), _ = jax.lax.scan(layer, (x, s, conv, k, v), layers)
+    return x, s, conv, k, v
+
+
+def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """Float32 logits of the hidden rows ``x``: the final norm, the head,
+    its multiplier."""
     x = rms_norm(x, params.final_norm, cfg.norm_epsilon)
     logits = linear(x, params.logits, out_axis="vocab").astype(jnp.float32)
-    return logits * m.lm_head, s, conv, k, v
+    return logits * cfg.mult.lm_head
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -176,26 +200,20 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.broadcast_to(
         start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
 
-    def at(a, l):
-        return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-
-    def put(a, a_l, l):
-        return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
-
     def mixer(u, lp, l, s, conv):
-        y, s_l, conv_l = mixer_chunk(cfg, u, lp, at(s, l), at(conv, l),
-                                      n_valid)
-        return y, put(s, s_l, l), put(conv, conv_l, l)
+        y, s_l, conv_l = mixer_chunk(cfg, u, lp, _at(s, l), _at(conv, l),
+                                     n_valid)
+        return y, _put(s, s_l, l), _put(conv, conv_l, l)
 
     def attend(q, k, v, k_c, v_c, l):
-        att, k_l, v_l = _attend_dense(cfg, q, k, v, at(k_c, l), at(v_c, l),
+        att, k_l, v_l = _attend_dense(cfg, q, k, v, _at(k_c, l), _at(v_c, l),
                                       start_pos, positions)
-        return att, put(k_c, k_l, l), put(v_c, v_l, l)
+        return att, _put(k_c, k_l, l), _put(v_c, v_l, l)
 
-    logits, s, conv, k, v = _scan_layers(params, cfg, tokens, positions,
-                                         col.s, col.conv, col.k, col.v,
-                                         mixer, attend)
-    return logits, StateColumn(k=k, v=v, s=s, conv=conv)
+    x, s, conv, k, v = _scan_layers(params, cfg, tokens, positions,
+                                    col.s, col.conv, col.k, col.v,
+                                    mixer, attend)
+    return _head(params, cfg, x), StateColumn(k=k, v=v, s=s, conv=conv)
 
 
 def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -225,10 +243,88 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return _attend_paged(cfg, q, k, v, k_pool, v_pool, l, positions,
                              tables)
 
-    logits, s, conv, k, v = _scan_layers(params, cfg, tokens, positions,
-                                         pool.s, pool.conv, pkv.k, pkv.v,
-                                         mixer, attend)
-    return logits, (PagedKVCache(k=k, v=v), StatePool(s=s, conv=conv))
+    x, s, conv, k, v = _scan_layers(params, cfg, tokens, positions,
+                                    pool.s, pool.conv, pkv.k, pkv.v,
+                                    mixer, attend)
+    return (_head(params, cfg, x),
+            (PagedKVCache(k=k, v=v), StatePool(s=s, conv=conv)))
+
+
+@_exact_f32_dots
+def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                     pos_vec: jax.Array, cache, tables: jax.Array,
+                     chunk: jax.Array, chunk_pos: jax.Array,
+                     n_valid: jax.Array, poison: jax.Array):
+    """A tick that carries a prefill chunk, as ONE program
+    (``Family.tick``; the dense decoders' is ``llama.forward_and_step``,
+    whose signature this is plus the chunk's valid length, which a recurrent
+    state needs): :func:`forward` over ``chunk [1, T]`` at ``chunk_pos`` into
+    an admission's column AND :func:`paged_forward`'s layers over the tick's
+    decode rows (``tokens [R, 1]`` at ``pos_vec`` through ``tables``), so
+    that every layer's nine planes are read once for both. ``cache`` is
+    ``(column, (PagedKVCache, StatePool))``, all given back (and donated
+    where the server jits this).
+
+    ONE call of :func:`_scan_layers` over the joined rows ``[1, T + R]``,
+    its carry the column AND the pools whole, each as its own program
+    carries it. Only what owns a context tells the rows apart: ``attend``
+    (the chunk's rows over the column's layer, the decode rows into the
+    block pool in place through their tables) and ``mixer``
+    (:func:`~dllama_tpu.models.ssd_mixer.mixer_chunk_and_step`: the
+    convolution and the recurrence a part at a time, the chunk form against
+    the column, the step form against the pools). A row with an all-null
+    table is dead, as an inactive slot of a step is (the null block, the
+    pool's null row), and every row may be.
+
+    The head runs for the decode ROWS alone: no chunk logits exist (the
+    serving prefill never read one). Returns ``((token, nonfinite, logits),
+    (column, (pkv, pool)))`` as the dense tick does: ``token`` each row's
+    ARGMAX, ``logits [R, V]`` float32 and poisoned as the step's are, for
+    ``ops.sampling.sampled_token`` where a row samples."""
+    from ..runtime.kvblocks import PagedKVCache, StatePool
+
+    _check(cfg)
+    col, (pkv, pool) = cache
+    chunk_pos = jnp.asarray(chunk_pos, dtype=jnp.int32)
+    n_valid = jnp.asarray(n_valid, dtype=jnp.int32)
+    T, R = chunk.shape[1], tokens.shape[0]
+    joined = jnp.concatenate([chunk[0], tokens[:, 0]])[None]        # [1, T+R]
+    cpos = (chunk_pos + jnp.arange(T, dtype=jnp.int32))[None, :]    # [1, T]
+    rpos = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]           # [R, 1]
+    positions = jnp.concatenate([cpos, rpos.T], axis=1)
+    rows = jnp.where(tables[:, 0] != 0, jnp.arange(1, R + 1, dtype=jnp.int32),
+                     StatePool.NULL)
+
+    def mixer(u, lp, l, s, conv):
+        (s_col, s_pool), (conv_col, conv_pool) = s, conv
+        y, (s_l, conv_l), (s_pool, conv_pool) = mixer_chunk_and_step(
+            cfg, u, lp, l, T, _at(s_col, l), _at(conv_col, l), n_valid, rows,
+            s_pool, conv_pool)
+        return (y, (_put(s_col, s_l, l), s_pool),
+                (_put(conv_col, conv_l, l), conv_pool))
+
+    def attend(q, k, v, k_c, v_c, l):
+        (k_col, k_pool), (v_col, v_pool) = k_c, v_c
+        att_c, k_l, v_l = _attend_dense(cfg, q[:, :T], k[:, :T], v[:, :T],
+                                        _at(k_col, l), _at(v_col, l),
+                                        chunk_pos, cpos)
+        by_row = lambda a: jnp.swapaxes(a[:, T:], 0, 1)  # [R, 1, heads, hd]
+        att_r, k_pool, v_pool = _attend_paged(
+            cfg, by_row(q), by_row(k), by_row(v), k_pool, v_pool, l, rpos,
+            tables)
+        att = jnp.concatenate([att_c, jnp.swapaxes(att_r, 0, 1)], axis=1)
+        return (att, (_put(k_col, k_l, l), k_pool),
+                (_put(v_col, v_l, l), v_pool))
+
+    x, s, conv, k, v = _scan_layers(
+        params, cfg, joined, positions, (col.s, pool.s),
+        (col.conv, pool.conv), (col.k, pkv.k), (col.v, pkv.v), mixer, attend)
+    logits = _head(params, cfg, jnp.swapaxes(x[:, T:], 0, 1))      # [R, 1, V]
+    last = _poison_logits(logits[:, -1, :], poison)
+    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    return ((greedy, _nonfinite_rows(last), last),
+            (StateColumn(k=k[0], v=v[0], s=s[0], conv=conv[0]),
+             (PagedKVCache(k=k[1], v=v[1]), StatePool(s=s[1], conv=conv[1]))))
 
 
 def _load_params(ld, cfg: ModelConfig) -> Params:
@@ -272,7 +368,7 @@ def _matmul_weight_count(cfg: ModelConfig) -> int:
 FAMILY = Family(
     forward=forward,
     paged_forward=paged_forward,
-    tick=None,
+    tick=forward_and_step,
     column=StateColumn.zeros,
     load_params=_load_params,
     matmul_weight_count=_matmul_weight_count,

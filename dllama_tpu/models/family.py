@@ -67,7 +67,9 @@ class Family:
     #   -> logits, cache
     paged_forward: Callable
     # a prefill chunk and the tick's decode rows as ONE program
-    # (llama.forward_and_step), or None: a chunk and a step are two
+    # (llama.forward_and_step's arguments, plus the chunk's valid length in
+    # front of ``poison`` where ``cfg.paged_only``), or None: a chunk and a
+    # step are two
     tick: Callable | None
     # (cfg, k, v) -> an admission's column from the slot's gathered view
     # (``v`` None where the pool has no V plane)

@@ -1411,9 +1411,9 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     in here because it is 7.3 of the step's 12.8 MB of executable (its two
     vocabulary-wide sorts), this program exists once a prefill bucket, and a
     start pays for every byte it loads (PERF.md section 6, PR 47). The
-    dense decoders' program, with no mesh plan: the five other
-    architectures keep a ``forward`` / ``paged_forward`` pair of their
-    own."""
+    dense decoders' program, with no mesh plan: another family brings a
+    tick program of its own (``models/falcon_h1.py``) or keeps its
+    ``forward`` / ``paged_forward`` pair (``Family.tick`` None)."""
     from ..runtime.kvblocks import PagedKVCache
 
     if cfg.paged_only or _current_plan() is not None:

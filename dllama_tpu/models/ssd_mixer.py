@@ -14,6 +14,14 @@ every layer (``models/falcon_h1.py``) and as a layer of its own
 architecture has none). ``lp`` is one layer of any stack that names its
 leaves ``w_in`` (the ``z x B C`` rows, one Q40 plane), ``w_dt`` (the ``dt``
 rows, float32), ``conv_w conv_b a_log d_skip dt_bias norm_ssm`` and ``w_out``.
+
+The mixer is split where its rows stop being independent: :func:`mixer_project`
+and :func:`mixer_output` work a row at a time (the two Q40 planes, the ``dt``
+rows, the gate, the grouped norm), :func:`chunk_part` and :func:`step_part`
+own a context (the convolution against a tail, the recurrence against a
+state). :func:`mixer_chunk` and :func:`mixer_step` put ONE part between the
+two; :func:`mixer_chunk_and_step` puts both, over rows joined along ``T``
+(``falcon_h1.forward_and_step``: a tick's chunk and its decode rows).
 """
 
 from __future__ import annotations
@@ -28,20 +36,32 @@ from ..runtime.introspection import note_ssd_path
 from .config import ModelConfig
 
 
-def mixer_inputs(cfg: ModelConfig, u: jax.Array, lp,
-                  tail: jax.Array, n_valid):
-    """Everything of the SSD mixer in front of the recurrence, for ``u [B,
-    T, dim]`` and the convolution's ``tail [B, K - 1, C]``: float32 ``x [B,
-    T, H, P]``, ``dt [B, T, H]`` (after its softplus), the groups' ``Bm, Cm
-    [B, T, G, N]``, the gate ``z [B, T, d_ssm]`` and the new tail."""
-    B, T, _ = u.shape
+def mixer_project(cfg: ModelConfig, u: jax.Array, lp):
+    """What the mixer does a ROW at a time in front of its convolution, for
+    ``u [B, T, dim]``: the packed in-projection ``proj [B, T, ssm_in_dim]``
+    (the ``z x B C`` lanes, ONE read of ``w_in`` for however many rows), the
+    float32 ``dt [B, T, H]`` before its bias and softplus, and the gate ``z
+    [B, T, d_ssm]``. Rows of different sequences may be joined along ``T``:
+    nothing here looks across them."""
     m = cfg.mult
-    H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state_dim
-    d_ssm, gn = cfg.ssm_inner_dim, G * N
     proj = linear(u, lp.w_in)
     dt = jnp.einsum("btd,hd->bth", u.astype(jnp.float32), lp.w_dt,
                     precision=jax.lax.Precision.HIGHEST) * m.ssm_dt
-    z = proj[..., :d_ssm].astype(jnp.float32) * m.ssm_z
+    z = proj[..., :cfg.ssm_inner_dim].astype(jnp.float32) * m.ssm_z
+    return proj, dt, z
+
+
+def mixer_conv(cfg: ModelConfig, proj: jax.Array, dt: jax.Array, lp,
+               tail: jax.Array, n_valid):
+    """What owns a context in front of the recurrence, for ONE sequence a
+    batch row: :func:`mixer_project`'s ``proj`` and ``dt`` ``[B, T, ...]``
+    through the convolution against ``tail [B, K - 1, C]``. Returns float32
+    ``x [B, T, H, P]``, ``dt [B, T, H]`` (after its softplus), the groups'
+    ``Bm, Cm [B, T, G, N]`` and the new tail."""
+    B, T, _ = proj.shape
+    m = cfg.mult
+    H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state_dim
+    d_ssm, gn = cfg.ssm_inner_dim, G * N
     # the x, B and C lanes of the in-projection, each under its multiplier
     lanes = jnp.concatenate([jnp.full((d_ssm,), m.ssm_x, jnp.float32),
                              jnp.full((gn,), m.ssm_b, jnp.float32),
@@ -53,7 +73,7 @@ def mixer_inputs(cfg: ModelConfig, u: jax.Array, lp,
     x = xbc[..., :d_ssm].reshape(B, T, H, P)
     Bm = xbc[..., d_ssm:d_ssm + gn].reshape(B, T, G, N)
     Cm = xbc[..., d_ssm + gn:].reshape(B, T, G, N)
-    return x, jax.nn.softplus(dt + lp.dt_bias), Bm, Cm, z, tail
+    return x, jax.nn.softplus(dt + lp.dt_bias), Bm, Cm, tail
 
 
 def mixer_output(cfg: ModelConfig, y: jax.Array, x: jax.Array, z: jax.Array,
@@ -69,22 +89,33 @@ def mixer_output(cfg: ModelConfig, y: jax.Array, x: jax.Array, z: jax.Array,
                   lp.w_out)
 
 
-def mixer_chunk(cfg, u, lp, s_l, conv_l, n_valid):
-    """The mixer over a chunk: ``s_l [B, H, P, N]`` in and out."""
-    T = u.shape[1]
-    x, dt, Bm, Cm, z, conv_l = mixer_inputs(cfg, u, lp, conv_l, n_valid)
+def chunk_part(cfg, proj, dt, lp, s_l, conv_l, n_valid):
+    """Convolution and recurrence over ONE sequence's chunk, from
+    :func:`mixer_project`'s rows of it: against the tail ``conv_l [B, K - 1,
+    C]`` and the state ``s_l [B, H, P, N]``, ``dt`` zeroed at and past
+    ``n_valid``. Returns float32 ``y, x [B, T, H, P]``, the state and the
+    tail."""
+    T = proj.shape[1]
+    x, dt, Bm, Cm, conv_l = mixer_conv(cfg, proj, dt, lp, conv_l, n_valid)
     real = (jnp.arange(T) < n_valid)[None, :, None]
     note_ssd_path("chunk", "xla")
     y, s_l = ssd.ssd_chunk(x, jnp.where(real, dt, 0.0), -jnp.exp(lp.a_log),
                            Bm, Cm, s_l, cfg.ssm_chunk)
-    return mixer_output(cfg, y, x, z, lp, u.dtype), s_l, conv_l
+    return y, x, s_l, conv_l
 
 
-def mixer_step(cfg, u, lp, l, rows, s_pool, conv_pool):
-    """The mixer over one token a row, the pools in and out: row ``b``'s
-    state and tail are ``[l, rows[b]]`` of them."""
-    tail = jax.lax.dynamic_index_in_dim(conv_pool, l, 0, keepdims=False)[rows]
-    x, dt, Bm, Cm, z, tail = mixer_inputs(cfg, u, lp, tail, None)
+def pool_tails(conv_pool, l, rows):
+    """The rows' tails of layer ``l``: ``[B, K - 1, C]``."""
+    return jax.lax.dynamic_index_in_dim(conv_pool, l, 0, keepdims=False)[rows]
+
+
+def step_part(cfg, proj, dt, lp, l, rows, tail, s_pool, conv_pool):
+    """Convolution and recurrence over one token a row, from
+    :func:`mixer_project`'s rows ``[B, 1, ...]`` and the rows' ``tail``
+    (:func:`pool_tails`): row ``b``'s state and tail are ``[l, rows[b]]`` of
+    the pools, written in place. Returns float32 ``y, x [B, 1, H, P]`` and
+    the pools."""
+    x, dt, Bm, Cm, tail = mixer_conv(cfg, proj, dt, lp, tail, None)
     conv_pool = conv_pool.at[l, rows].set(tail)
     kernel = ssd.step_kernel_choice()
     note_ssd_path("step", "xla" if kernel is None else "pallas")
@@ -93,4 +124,43 @@ def mixer_step(cfg, u, lp, l, rows, s_pool, conv_pool):
     dt1 = dt[:, 0]
     y, s_pool = step(s_pool, l, rows, x[:, 0], dt1,
                      jnp.exp(-dt1 * jnp.exp(lp.a_log)), Bm[:, 0], Cm[:, 0])
-    return mixer_output(cfg, y[:, None], x, z, lp, u.dtype), s_pool, conv_pool
+    return y[:, None], x, s_pool, conv_pool
+
+
+def mixer_chunk(cfg, u, lp, s_l, conv_l, n_valid):
+    """The mixer over a chunk: ``s_l [B, H, P, N]`` in and out."""
+    proj, dt, z = mixer_project(cfg, u, lp)
+    y, x, s_l, conv_l = chunk_part(cfg, proj, dt, lp, s_l, conv_l, n_valid)
+    return mixer_output(cfg, y, x, z, lp, u.dtype), s_l, conv_l
+
+
+def mixer_step(cfg, u, lp, l, rows, s_pool, conv_pool):
+    """The mixer over one token a row, the pools in and out: row ``b``'s
+    state and tail are ``[l, rows[b]]`` of them."""
+    tail = pool_tails(conv_pool, l, rows)
+    proj, dt, z = mixer_project(cfg, u, lp)
+    y, x, s_pool, conv_pool = step_part(cfg, proj, dt, lp, l, rows, tail,
+                                        s_pool, conv_pool)
+    return mixer_output(cfg, y, x, z, lp, u.dtype), s_pool, conv_pool
+
+
+def mixer_chunk_and_step(cfg, u, lp, l, T, s_l, conv_l, n_valid, rows,
+                         s_pool, conv_pool):
+    """A chunk and the tick's decode rows through ONE pass over the mixer's
+    planes: ``u [1, T + R, dim]`` is the chunk's ``T`` rows and then one row
+    a slot. The in-projection, the ``dt`` rows, the gate, the grouped norm
+    and ``w_out`` run once over the joined rows; the convolution and the
+    recurrence run a part at a time, the chunk's as :func:`mixer_chunk` has
+    them (column layer ``s_l, conv_l``), the rows' as :func:`mixer_step`
+    (the pools at ``[l, rows[b]]``). Returns ``y [1, T + R, dim]``, the
+    column's layer and the pools."""
+    tail = pool_tails(conv_pool, l, rows)
+    proj, dt, z = mixer_project(cfg, u, lp)
+    y_c, x_c, s_l, conv_l = chunk_part(cfg, proj[:, :T], dt[:, :T], lp, s_l,
+                                       conv_l, n_valid)
+    by_row = lambda a: jnp.swapaxes(a[:, T:], 0, 1)          # [R, 1, ...]
+    y_r, x_r, s_pool, conv_pool = step_part(
+        cfg, by_row(proj), by_row(dt), lp, l, rows, tail, s_pool, conv_pool)
+    join = lambda c, r: jnp.concatenate([c, jnp.swapaxes(r, 0, 1)], axis=1)
+    return (mixer_output(cfg, join(y_c, y_r), join(x_c, x_r), z, lp, u.dtype),
+            (s_l, conv_l), (s_pool, conv_pool))

@@ -1580,9 +1580,14 @@ class PagedGenerator(_GeneratorCore):
         # a recurrent state, slot-indexed, beside the blocks, in the shape
         # the architecture gives it (kvblocks.StatePool has its rules:
         # never shared, written once at commit, in place through every step)
-        self.spool = (StatePool.create(self.cfg, n_slots,
-                                       jnp.dtype(self.cfg.compute_dtype))
-                      if self.cfg.has_state else None)
+        self.spool = None
+        if self.cfg.has_state:
+            self.spool = StatePool.create(self.cfg, n_slots,
+                                          jnp.dtype(self.cfg.compute_dtype))
+            if engine.plan is None:
+                # pinned as the blocks are: a tick program takes it before
+                # any step or commit has left it there
+                self.spool = self._pin_home(self.spool)
         # window layers: the second pool, its allocator and its tables,
         # and the routing counters the step and the chunks accumulate on
         # the device (models/laguna.py)
@@ -1661,9 +1666,10 @@ class PagedGenerator(_GeneratorCore):
         # column (same program its solo path compiles — shared cache)
         self._prefill_fwd = engine._step
         # ... except where a chunk and the tick's decode rows can be ONE
-        # program (models.llama.forward_and_step: the layers' planes read
-        # once for both): a decoder family that has such a program
-        # (models/family.py: ``tick``), one device, a plain step a tick, and a chunk
+        # program (the layers' planes read once for both): a decoder family
+        # that brings such a program (models/family.py: ``tick``; the dense
+        # decoders' is models.llama.forward_and_step), one device, a plain
+        # step a tick, and a chunk
         # regime of the Q40 kernel wide enough for the widest bucket with
         # every slot's row joined to it. Then EVERY plain chunk goes through
         # it, its rows dead (null tables, as an inactive slot rides a step)
@@ -2424,12 +2430,14 @@ class PagedGenerator(_GeneratorCore):
                 # or the rows rode an earlier chunk of this tick. Enqueued
                 # and not waited for, as the plain forward is; no row's
                 # logits to poison, so the failpoint is not asked
-                fields = (*self._dead_rows, *self._chunk_fields(padded, pos),
+                fields = (*self._dead_rows,
+                          *self._chunk_fields(padded, pos, n_valid),
                           np.float32(0.0))
-                _, (col, self.pkv) = self._tick(
+                _, (col, cache) = self._tick(
                     self.eng.params, self.cfg,
-                    jnp.asarray(steppack.pack(fields)), (col, self.pkv),
-                    steppack.layout_of(fields))
+                    jnp.asarray(steppack.pack(fields)),
+                    (col, self._step_cache()), steppack.layout_of(fields))
+                self._keep_step_cache(cache)
                 return self._pin_home(col)
             with self._plan_ctx():
                 _, col = self._prefill_fwd(
@@ -2438,10 +2446,26 @@ class PagedGenerator(_GeneratorCore):
                     jnp.int32(pos), col, *valid)
             return self._pin_home(col)
 
-    @staticmethod
-    def _chunk_fields(padded, pos: int) -> tuple:
-        """A chunk as the tick program's last two host fields."""
-        return np.asarray(padded, np.int32).reshape(1, -1), np.int32(pos)
+    def _chunk_fields(self, padded, pos: int, n_valid: int) -> tuple:
+        """A chunk as the tick program's last host fields: its tokens, its
+        position and, where a recurrent state would keep what padding wrote
+        (as :meth:`_exec_prefill` passes ``forward``), its valid length."""
+        valid = (np.int32(n_valid),) if self.cfg.paged_only else ()
+        return (np.asarray(padded, np.int32).reshape(1, -1), np.int32(pos),
+                *valid)
+
+    def _step_cache(self):
+        """What the step's cache is made of (``_cache_parts``: the blocks,
+        then a window pool, a state pool, the routing counters) as a step
+        or tick program takes it: the one part, or the tuple of them."""
+        cache = tuple(getattr(self, name) for name in self._cache_parts)
+        return cache if len(cache) > 1 else cache[0]
+
+    def _keep_step_cache(self, cache) -> None:
+        """... and as the program gave it back (all of it was donated)."""
+        parts = self._cache_parts
+        for name, part in zip(parts, cache if len(parts) > 1 else (cache,)):
+            setattr(self, name, part)
 
     def _prefill_chunk(self, adm: "_Admission", padded, n_valid: int) -> None:
         if self._tick is None or self._rows_rode or not self.n_active:
@@ -2806,25 +2830,20 @@ class PagedGenerator(_GeneratorCore):
             walk_blocks = int(sum(
                 -(-(int(self.pos[i]) + 1) // self.block_size)
                 for i in active))
+            # either program takes what the architecture carries
+            # (_step_cache), all donated, and gives all of it back
             if chunk is None:
-                # the step takes what the architecture carries
-                # (_cache_parts: the blocks, then a window pool, a state
-                # pool, the routing counters), all donated, and gives all
-                # of it back
-                parts = self._cache_parts
-                cache = tuple(getattr(self, name) for name in parts)
                 (nxt, nf), cache = io.call(
-                    self._step, cache if len(parts) > 1 else cache[0], *host,
+                    self._step, self._step_cache(), *host,
                     temps, topps, coins)
-                for name, part in zip(parts, cache if len(parts) > 1
-                                      else (cache,)):
-                    setattr(self, name, part)
+                self._keep_step_cache(cache)
             else:
                 adm, padded, n_valid, pos = chunk
                 t_enqueue = telemetry.now_ns()
-                (nxt, nf, logits), (col, self.pkv) = io.call(
-                    self._tick, (adm.col, self.pkv), *host,
-                    *self._chunk_fields(padded, pos))
+                (nxt, nf, logits), (col, cache) = io.call(
+                    self._tick, (adm.col, self._step_cache()), *host,
+                    *self._chunk_fields(padded, pos, n_valid))
+                self._keep_step_cache(cache)
                 if (temps > 0.0).any():
                     nxt = self._sample_rows(logits, temps, topps, coins)
                 adm.col = self._pin_home(col)

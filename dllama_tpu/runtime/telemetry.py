@@ -248,8 +248,8 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "Plain prefill chunks batched serving dispatched (label rows): "
           "live = the chunk's program also stepped at least one live "
           "decode row of its tick (the paged generator's forward_and_step "
-          "for a dense decoder, so the weights are read once for both); "
-          "none = no row rode it"),
+          "where the decoder family brings one, so the weights are read "
+          "once for both); none = no row rode it"),
     _spec(PREFILL_TOKENS, "counter", "Prompt tokens prefilled"),
     _spec(DECODE_STEP_MS, "histogram",
           "Wall time of one decode dispatch (single, fused-chunk, or "

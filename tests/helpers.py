@@ -205,6 +205,40 @@ def compile_paged_step(cfg, params, n_slots: int, n_blocks: int,
     return compiled, pkv.k.size * pkv.k.dtype.itemsize
 
 
+def lowered_program_digests(cfg, params, column) -> dict:
+    """sha256 of the lowered text of a stateful decoder family's two
+    programs at one small geometry, from shapes alone: ``forward`` over a
+    chunk of 32 into ``column`` (with its valid length) and
+    ``paged_sampled_step_guarded`` over 4 rows, 33 blocks of 16, tables 8
+    wide, float32 pools. What a refactoring of code the family shares with
+    another must leave as it was: take the digests on the parent commit with
+    this same function and hold the change to them."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from dllama_tpu.models import llama
+    from dllama_tpu.runtime.kvblocks import PagedKVCache, StatePool
+
+    S = jax.ShapeDtypeStruct
+    shapes = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+    i32, f32 = jnp.int32, jnp.float32
+    digest = lambda lowered: hashlib.sha256(lowered.as_text().encode()).hexdigest()
+    B = 4
+    cache = (shapes(PagedKVCache.create(cfg, 33, 16, dtype=f32)), shapes(StatePool.create(cfg, B, f32)))
+    if cfg.has_expert_share:
+        from dllama_tpu.models.share import zero_totals
+
+        cache += (shapes(zero_totals(cfg)),)
+    return {
+        "forward": digest(jax.jit(lambda p, *a: llama.forward(p, cfg, *a)).lower(
+            shapes(params), S((1, 32), i32), S((), i32), shapes(column), S((), i32))),
+        "step": digest(jax.jit(lambda p, *a: llama.paged_sampled_step_guarded(p, cfg, *a)).lower(
+            shapes(params), S((B, 1), i32), S((B,), i32), cache, S((B, 8), i32),
+            S((B,), f32), S((B,), f32), S((B,), f32), S((), f32)))}
+
+
 def param_shapes(cfg, scales_dtype):
     """``Params`` of a dense decoder, a hybrid one or one with an SSD mixer
     beside attention as shapes: Q40 planes for

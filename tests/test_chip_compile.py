@@ -194,20 +194,27 @@ def test_chunk_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
     assert kernels.get("quant_matmul") == 1, kernels
 
 
-@pytest.mark.parametrize("rows", [272, 320])
-@pytest.mark.parametrize("k,n", MISTRAL_7B + QWEN3_4B)
+@pytest.mark.parametrize("k,n,rows", [
+    (k, n, rows) for rows in (272, 320) for k, n in MISTRAL_7B + QWEN3_4B]
+    + [(k, n, 272) for k, n in FALCON_H1_34B])
 def test_chunk_kernel_compiles_at_the_joined_widths_for_v5e(one_chip, k, n, rows):
-    """The same kernel as the tick program calls it (PR 47,
-    ``models.llama.forward_and_step``): the widest bucket's 256 rows with 16
-    slots' decode rows joined to them, and the regime's upper edge, over the
-    layer stack and a traced index, for the two dense decoders that program
-    serves. K = 14336 with 320 rows resident stays inside the chunk regime's
-    VMEM limit."""
+    """The same kernel as a tick program calls it (PR 47,
+    ``models.llama.forward_and_step``; PR 52,
+    ``models.falcon_h1.forward_and_step``): the widest bucket's 256 rows with
+    16 slots' decode rows joined to them, over the layer stack and a traced
+    index, and for the two dense decoders the regime's upper edge. K = 14336
+    with 320 rows resident stays inside the chunk regime's VMEM limit; the
+    joined rows take the stripe width the 256-row ``forward`` had (falcon's K
+    = 21504 takes 256-wide stripes, every other plane of its seven 512), so a
+    tick program brings no kernel parameter of its own."""
     from dllama_tpu.ops.linear import QuantizedWeight
-    from dllama_tpu.ops.quant_matmul import (CHUNK_MAX_M, fused_path,
-                                             quant_matmul)
+    from dllama_tpu.ops.quant_matmul import (CHUNK_MAX_M, _decode_blocks,
+                                             fused_path, quant_matmul)
 
     assert rows <= CHUNK_MAX_M
+    assert _decode_blocks(rows, k, n, True) == _decode_blocks(256, k, n, True)
+    if (k, n) in FALCON_H1_34B:
+        assert _decode_blocks(rows, k, n, True)[0] == (256 if k == 21504 else 512)
     L = 4
     stack = QuantizedWeight(
         scales=_shape(one_chip, (L, k // 32, n), jnp.bfloat16),

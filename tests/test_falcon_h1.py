@@ -181,7 +181,8 @@ def test_packed_plane_and_float32_dt_rows_are_the_unpacked_in_projection(engine)
     lp = lp._replace(conv_w=jnp.zeros_like(lp.conv_w).at[-1].set(1.0), conv_b=jnp.zeros_like(lp.conv_b))
     tail = jnp.zeros((1, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim))
     with jax.default_matmul_precision("highest"):
-        x, dt, Bm, Cm, z, _tail = ssd_mixer.mixer_inputs(cfg, u, lp, tail, None)
+        packed, dt, z = ssd_mixer.mixer_project(cfg, u, lp)
+        x, dt, Bm, Cm, _tail = ssd_mixer.mixer_conv(cfg, packed, dt, lp, tail, None)
         w = jnp.concatenate([dequantize_weight(lp.w_in).T, lp.w_dt], axis=0)       # [192 + 4, dim]: as published
         m, d, gn = cfg.mult, cfg.ssm_inner_dim, cfg.ssm_groups * cfg.ssm_state_dim
         mup = jnp.concatenate([jnp.full((d,), m.ssm_z), jnp.full((d,), m.ssm_x), jnp.full((gn,), m.ssm_b),
@@ -274,7 +275,9 @@ def test_padded_chunked_prefill_then_decode_logits(bench, engine, n_prompt, kern
     prompt = _tokens(n_prompt, seed=n_prompt)
     gen.admit(Request(rid=1, prompt_ids=prompt, max_tokens=8, stop_on_eos=False), 1)
     got, emitted = _decode_logits(gen, 1, 8)
-    assert sorted(calls) == (["attn", "ssd"] if kernel else [])      # traced once: the one layer body
+    # the one layer body, traced once a program: the step here, and in the admission the tick program of each of the
+    # prompt's two buckets (64 and 32, 256 and 32), whose dead rows go through both kernels too
+    assert sorted(calls) == (["attn"] * 3 + ["ssd"] * 3 if kernel else [])
     want = _reference_logits(bench, engine.params, prompt + emitted)[n_prompt - 1:n_prompt + 7]
     assert float(np.abs(got - want).max()) < LOGIT_TOL
 
@@ -371,10 +374,11 @@ def test_state_pool_rules_hold_for_both_architectures(arch, engine, olmo_engine)
     assert not np.asarray(adm.col.s).any() and not np.asarray(adm.col.conv).any()     # zero at admission
     assert adm.col.k.shape[0] == cfg.n_kv_layers
     while not gen.continue_admit(adm):
-        np.testing.assert_array_equal(np.asarray(gen.spool.s), before)                # not before the commit
+        # not before the commit; the null row takes the writes of a tick program's dead rows, as it takes a step's
+        np.testing.assert_array_equal(np.asarray(gen.spool.s)[:, 1:], before[:, 1:])
     after = np.asarray(gen.spool.s)
     assert after[:, 2].any()                                                          # slot 1 owns row 2
-    np.testing.assert_array_equal(np.delete(after, 2, axis=1), np.delete(before, 2, axis=1))
+    np.testing.assert_array_equal(np.delete(after, [0, 2], axis=1), np.delete(before, [0, 2], axis=1))
     gen.step()
     stepped = np.asarray(gen.spool.s)
     assert (stepped[:, 2] != after[:, 2]).any()
@@ -428,7 +432,9 @@ def test_scheduler_interleaved_slots_reuse_and_same_prompt_twice(bench, engine):
         assert (reg.gauge(telemetry.STATE_SLOTS_USED).value(), reg.gauge(telemetry.STATE_SLOTS_TOTAL).value()) == (0, 2)
         rendered = reg.render()
         assert 'dllama_ssd_paths{form="step",path="xla",program="paged_sampled_step"' in rendered
-        assert 'dllama_ssd_paths{form="chunk",path="xla",program="forward"' in rendered
+        # every chunk of this family goes through its tick program (PR 52), which runs both forms
+        assert 'dllama_ssd_paths{form="chunk",path="xla",program="forward_and_step"' in rendered
+        assert 'dllama_ssd_paths{form="step",path="xla",program="forward_and_step"' in rendered
     finally:
         sched.close()
 

@@ -119,12 +119,48 @@ def test_every_arch_has_a_family_with_every_field_set(cfgs, arch):
             continue
         assert callable(value), field.name
     dense = TINY[arch] is None
-    # the dense equations alone have a tick program and are carried by every path
-    assert (fam.tick is not None) == dense and (fam.refusal is None) == dense
+    # the dense equations alone are carried by every path
+    assert (fam.refusal is None) == dense
     assert dense == (not cfg.paged_only)
     if not dense:
         assert isinstance(fam.refusal, Refusal) and all(fam.refusal)
         assert fam.refusal.what.startswith("a ") and not fam.refusal.carries.endswith(")")
+
+
+# which families bring a program for a tick that carries a chunk (``Family.tick``: the chunk and the tick's decode rows
+# in ONE pass over the weights), as module and function, and which keep a chunk and a step apart (ROADMAP Speed 2 has
+# their order)
+TICK = {
+    ArchType.LLAMA: ("llama", "forward_and_step"),
+    ArchType.QWEN3: ("llama", "forward_and_step"),
+    ArchType.OLMO_HYBRID: None,
+    ArchType.LAGUNA: None,
+    ArchType.FALCON_H1: ("falcon_h1", "forward_and_step"),
+    ArchType.AXK1: None,
+    ArchType.LFM2: None,
+    ArchType.NEMOTRON_H: None,
+}
+
+
+@pytest.mark.parametrize("arch", ARCHS, ids=lambda a: a.name)
+def test_which_families_bring_a_tick_program(cfgs, arch):
+    """The table above, a case a family; one that brings a tick brings its
+    OWN module's, under the name the benchmark times as a chunk
+    (``jit_forward...``), taking the dense program's arguments and, where a
+    recurrent state would keep what padding wrote, the chunk's valid length."""
+    import importlib
+    import inspect
+
+    fam = family_of(cfgs[arch])
+    if TICK[arch] is None:
+        assert fam.tick is None
+        return
+    module, name = TICK[arch]
+    assert fam.tick is getattr(importlib.import_module("dllama_tpu.models." + module), name)
+    assert fam.tick.__name__ == "forward_and_step"
+    args = list(inspect.signature(fam.tick).parameters)
+    valid = ["n_valid"] if cfgs[arch].paged_only else []
+    assert args == ["params", "cfg", "tokens", "pos_vec", "cache", "tables", "chunk", "chunk_pos", *valid, "poison"]
 
 
 def test_the_dense_equations_share_one_family_and_the_entry_is_llamas(cfgs):

@@ -351,6 +351,44 @@ def test_everything_else_keeps_its_two_programs(files, why):
         eng.close()
 
 
+@pytest.mark.parametrize("family", ["OLMO_HYBRID", "LAGUNA", "FALCON_H1", "AXK1", "LFM2", "NEMOTRON_H"])
+def test_every_other_family_keeps_its_two_programs_and_falcon_h1_brings_its_own(family, tmp_path):
+    """A generator takes the tick program its FAMILY brings and asks no name:
+    the five that bring none dispatch ``forward`` for a chunk beside a live
+    row and a step behind it, count no chunk as carried and never load
+    ``forward_and_step``; the one that brings its own loads no ``forward``."""
+    import dllama_tpu.runtime.engine as engine_mod
+    from test_falcon_h1 import BENCH, _bench, _engine
+    from test_family import TICK, TINY         # the six families' selftest files, and which of the eight bring a tick
+
+    arch = mfile.ArchType[family]
+    (folder, tiny), has_tick = TINY[arch], TICK[arch] is not None
+    folder = os.path.join(BENCH, folder)
+    try:
+        eng = _engine(_bench(folder, os.path.join(folder, "selftest", "configs", tiny), f"tick_table_{family}"), tmp_path)
+    finally:
+        engine_mod.load_params_from_mfile = llama.load_params_from_mfile       # the weights module's seam
+    try:
+        gen = PagedGenerator(eng, n_slots=2)
+        assert (gen._tick is not None) == has_tick
+        small = lambda n, seed: [t % 100 + 1 for t in _prompt(n, seed)]   # inside every tiny vocabulary
+        a = Request(rid=1, prompt_ids=small(40, 1), max_tokens=4, stop_on_eos=False)
+        b = Request(rid=2, prompt_ids=small(70, 2), max_tokens=4, stop_on_eos=False)
+        gen.admit(a, 0)
+        gen.step()
+        gen.admit(b, 1)                  # with a live row beside it
+        assert gen.take_rows_rode() == has_tick and len(a.tokens) == 1 + has_tick
+        while gen.n_active:
+            gen.step()
+        assert a.error is None and b.error is None and len(a.tokens) == len(b.tokens) == 4
+        programs = {e["program"] for e in introspection.ledger().snapshot()["events"]
+                    if e["scope"] == eng.introspection_scope}
+        assert ("forward_and_step" in programs) == has_tick and ("forward" in programs) == (not has_tick)
+        assert (gen._n_chunks, gen._n_chunks_rows) == (4, int(has_tick))  # 39 and 69 tokens: two chunks each
+    finally:
+        eng.close()
+
+
 def test_a_direct_caller_sees_rows_ride_the_first_chunk_after_a_step(engine):
     """Without a scheduler: ``admit`` beside a live row steps that row with
     its first chunk (one token, settled), later chunks of the same call carry
