@@ -45,6 +45,25 @@ padded with zeros to whole lane tiles of 128, so that the compiled paged
 kernel and the flash kernel take the pool and the column as they take the
 128-lane models'; the zero lanes add nothing to a score (its scale is
 ``head_dim``'s) and the padded lanes of the result are dropped.
+
+**Three programs, three pairs of closures over ONE walk**
+(:func:`_scan_layers`): :func:`forward` (a prefill chunk over a slot's
+gathered column), :func:`paged_forward` (the decode step, one token a row,
+over the pools in place) and :func:`forward_and_step` (``FAMILY.tick``, PR
+53: a chunk AND the tick's decode rows through one pass over every plane, the
+paged server's program for every plain chunk; no chunk logits). The walk does
+everything a layer does a row at a time; ``conv_mixer`` and ``attend`` are the
+two closures that know whose rows they are. The conv mixer is split where its
+rows stop being independent (:func:`_conv_gate_in`, the convolution,
+:func:`_conv_gate_out`), so the tick joins its rows in front of ``W_in`` and
+behind the convolution.
+
+**Where the counters go.** A step adds its dispatches' counters to row 0 of
+the running totals (``share.zero_totals``), a chunk's ride ``col.stats`` to
+the admission's commit, which adds them to row 1. The tick program's one
+joined dispatch is a chunk-form one: it adds ALL of its counters (the chunk's
+pairs and the decode rows') to row 1 itself and touches neither row 0 nor
+``col.stats`` (see :func:`forward_and_step`).
 """
 
 from __future__ import annotations
@@ -62,7 +81,8 @@ from ..runtime.introspection import note_short_conv_path
 from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
 from .family import Family, layer_kinds, state_refusal
-from .llama import Params, _attend_dense, _attend_paged, _stack_at
+from .llama import (Params, _attend_dense, _attend_paged, _exact_f32_dots,
+                    _nonfinite_rows, _poison_logits, _stack_at)
 from .rope import apply_rope_partial, build_partial_rope_cache
 from .share import _plane, ffn_half, require_quantized, swiglu, zero_stats
 
@@ -128,18 +148,36 @@ def _at(a: jax.Array, l):
     return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
 
 
+def _put(a: jax.Array, a_l: jax.Array, l) -> jax.Array:
+    return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
+
+
+def _conv_gate_in(cfg: ModelConfig, u: jax.Array, cp: ConvParams):
+    """What a conv mixer does a row at a time IN FRONT of its convolution:
+    ``proj = W_in u`` in float32 and the gated input ``v = B * X``, rounded
+    once to the activation dtype, which is what the tail holds, so a chunk
+    and a step see the same values."""
+    d = cfg.dim
+    proj = linear(u, cp.w_in).astype(jnp.float32)
+    return proj, (proj[..., :d] * proj[..., 2 * d:]).astype(u.dtype)
+
+
+def _conv_gate_out(cfg: ModelConfig, proj: jax.Array, c: jax.Array,
+                   cp: ConvParams, dtype) -> jax.Array:
+    """... and BEHIND it: ``W_out (C * c)`` for the convolution's float32
+    output ``c``."""
+    d = cfg.dim
+    return linear((proj[..., d:2 * d] * c).astype(dtype), cp.w_out)
+
+
 def _conv_mixer(cfg: ModelConfig, u: jax.Array, cp: ConvParams,
                 tail: jax.Array, n_valid):
     """The gated short convolution over ``u [B, T, dim]`` (normed) behind
-    ``tail [B, K - 1, dim]``: ``W_out (C * conv(B * X))`` and the new tail.
-    The gated input ``v = B * X`` is rounded once to the activation dtype,
-    which is what the tail holds, so a chunk and a step see the same
-    values."""
-    d = cfg.dim
-    proj = linear(u, cp.w_in).astype(jnp.float32)
-    v = (proj[..., :d] * proj[..., 2 * d:]).astype(u.dtype)
+    ``tail [B, K - 1, dim]``: ``W_out (C * conv(B * X))`` and the new tail,
+    the rows of ONE program's kind (a chunk's, or one token a row)."""
+    proj, v = _conv_gate_in(cfg, u, cp)
     c, tail = causal_conv(v, tail, cp.conv_w, n_valid, activation=None)
-    return linear((proj[..., d:2 * d] * c).astype(u.dtype), cp.w_out), tail
+    return _conv_gate_out(cfg, proj, c, cp, u.dtype), tail
 
 
 def _pad_lanes(a: jax.Array, width: int) -> jax.Array:
@@ -172,11 +210,16 @@ def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 def _scan_layers(params: Params, cfg: ModelConfig, x, caches, stats, live,
                  positions, conv_mixer, attend):
-    """The walk both programs share. ``caches = (k, v, conv)`` (a column's
-    arrays, or the two pools') and ``stats`` ride every loop's carry whole.
-    ``conv_mixer(h, cp, c, conv) -> (y, conv')`` is conv layer ``c``'s mixer
-    in the program's form, ``attend(q, k, v, k_c, v_c, a) -> (att, k_c,
-    v_c)`` attention layer ``a``'s cache."""
+    """The walk the three programs share: the hidden rows ``[B, T, dim]``
+    behind the last layer, in front of the final norm (:func:`_head`).
+    ``caches = (k, v, conv)`` (a column's arrays, the two pools', or (the
+    tick program) a pair of both each) and ``stats`` ride every loop's carry
+    whole. ``conv_mixer(h, cp, c, conv) -> (y, conv')`` is conv layer ``c``'s
+    mixer in the program's form, ``attend(q, k, v, k_c, v_c, a) -> (att,
+    k_c, v_c)`` attention layer ``a``'s cache. Everything else a layer does
+    (the norms, the dense planes, the routed feed-forward over the rows that
+    are ``live``) it does a row at a time, so the rows along ``T`` need not
+    be one sequence's: only the two closures know."""
     lp: Lfm2Layers = params.layers
     P, lead, L = cfg.layer_period, cfg.n_dense_layers, cfg.n_layers
     eps = cfg.norm_epsilon
@@ -234,7 +277,7 @@ def _scan_layers(params: Params, cfg: ModelConfig, x, caches, stats, live,
     if rest:
         x, caches, stats = period(jnp.int32(whole), (x, caches, stats),
                                   rest - 1)
-    return _head(params, cfg, x), caches, stats
+    return x, caches, stats
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -258,23 +301,21 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.broadcast_to(
         start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
 
-    def put(a, a_l, l):
-        return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
-
     def conv_mixer(h, cp, c, conv):
         note_short_conv_path("chunk", "xla")
         y, tail = _conv_mixer(cfg, h, cp, _at(conv, c), n_valid)
-        return y, put(conv, tail, c)
+        return y, _put(conv, tail, c)
 
     def attend(q, k, v, k_c, v_c, a):
         att, k_a, v_a = _attend_dense(cfg, q, k, v, _at(k_c, a), _at(v_c, a),
                                       start_pos, positions)
-        return att, put(k_c, k_a, a), put(v_c, v_a, a)
+        return att, _put(k_c, k_a, a), _put(v_c, v_a, a)
 
-    logits, (k, v, conv), stats = _scan_layers(
+    x, (k, v, conv), stats = _scan_layers(
         params, cfg, x, (col.k, col.v, col.conv), col.stats, live, positions,
         conv_mixer, attend)
-    return logits, StateColumn(k=k, v=v, s=None, conv=conv, stats=stats)
+    return (_head(params, cfg, x),
+            StateColumn(k=k, v=v, s=None, conv=conv, stats=stats))
 
 
 def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -310,11 +351,110 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return _attend_paged(cfg, q, k, v, k_pool, v_pool, a, positions,
                              tables)
 
-    logits, (k, v, conv), stats = _scan_layers(
+    x, (k, v, conv), stats = _scan_layers(
         params, cfg, x, (pkv.k, pkv.v, pool.conv), zero_stats(cfg), live,
         positions, conv_mixer, attend)
-    return logits, (PagedKVCache(k=k, v=v), StatePool(s=None, conv=conv),
-                    totals.at[0].add(stats))
+    return (_head(params, cfg, x),
+            (PagedKVCache(k=k, v=v), StatePool(s=None, conv=conv),
+             totals.at[0].add(stats)))
+
+
+@_exact_f32_dots
+def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                     pos_vec: jax.Array, cache, tables: jax.Array,
+                     chunk: jax.Array, chunk_pos: jax.Array,
+                     n_valid: jax.Array, poison: jax.Array):
+    """A tick that carries a prefill chunk, as ONE program
+    (``Family.tick``; ``falcon_h1.forward_and_step``'s signature):
+    :func:`forward` over ``chunk [1, T]`` at ``chunk_pos`` into an
+    admission's column AND :func:`paged_forward`'s layers over the tick's
+    decode rows (``tokens [R, 1]`` at ``pos_vec`` through ``tables``), so
+    that every plane of every layer, a routed expert's among them, is read
+    once for both. ``cache`` is ``(column, (PagedKVCache, StatePool,
+    totals))``, all given back (and donated where the server jits this).
+
+    ONE call of :func:`_scan_layers` over the joined rows ``[1, T + R]``,
+    its carry the column AND the pools whole, each as its own program
+    carries it. Only what owns a context tells the rows apart: ``attend``
+    (the chunk's rows over the column's layer, the decode rows into the
+    block pool in place through their tables) and ``conv_mixer`` (``W_in``,
+    both gates and ``W_out`` over the joined rows; the convolution a part at
+    a time, the chunk's behind the column's tail with ``n_valid``, the rows'
+    one token each behind their rows of the tail pool). A row with an
+    all-null table is dead, as an inactive slot of a step is (the null
+    block, the pool's null row, not routed), and every row may be; the
+    chunk's padding is not routed either.
+
+    **The routed half is ONE dispatch of the chunk form** over the joined
+    rows (``T + R`` is past ``share.STEP_FORM_MAX_ROWS``): a plane fetched
+    once a RUN over the union of what the chunk and the rows chose. Its
+    counters are therefore a chunk-form dispatch's, ALL of them (the
+    chunk's pairs and the decode rows', rows fed, planes, tokens an
+    expert), and the program adds them itself to the totals' CHUNK row
+    (row 1), not through ``col.stats`` and the commit: each pair is counted
+    once, and the decode rows' are not lost with an admission that is
+    cancelled before it commits. ``col.stats`` goes back as it came. Row 0
+    is "what the step PROGRAM's dispatches did" (its planes are divided by
+    that program's kernel time) and is left as it was.
+
+    The head runs for the decode ROWS alone: no chunk logits exist (the
+    serving prefill never read one). Returns ``((token, nonfinite, logits),
+    (column, (pkv, pool, totals)))`` as the dense tick does: ``token`` each
+    row's ARGMAX, ``logits [R, V]`` float32 and poisoned as the step's are,
+    for ``ops.sampling.sampled_token`` where a row samples."""
+    from ..runtime.kvblocks import PagedKVCache, StatePool
+
+    _check(cfg)
+    col, (pkv, pool, totals) = cache
+    chunk_pos = jnp.asarray(chunk_pos, dtype=jnp.int32)
+    n_valid = jnp.asarray(n_valid, dtype=jnp.int32)
+    T, R = chunk.shape[1], tokens.shape[0]
+    joined = jnp.concatenate([chunk[0], tokens[:, 0]])[None]        # [1, T+R]
+    cpos = (chunk_pos + jnp.arange(T, dtype=jnp.int32))[None, :]    # [1, T]
+    rpos = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]           # [R, 1]
+    positions = jnp.concatenate([cpos, rpos.T], axis=1)
+    alive = tables[:, 0] != 0
+    live = jnp.concatenate([jnp.arange(T) < n_valid, alive])
+    rows = jnp.where(alive, jnp.arange(1, R + 1, dtype=jnp.int32),
+                     StatePool.NULL)
+    x = params.embedding[joined].astype(cfg.compute_dtype)
+    by_row = lambda a: jnp.swapaxes(a[:, T:], 0, 1)              # [R, 1, ...]
+    join = lambda c, r: jnp.concatenate([c, jnp.swapaxes(r, 0, 1)], axis=1)
+
+    def conv_mixer(h, cp, c, conv):
+        conv_col, conv_pool = conv
+        proj, v = _conv_gate_in(cfg, h, cp)
+        note_short_conv_path("chunk", "xla")
+        y_c, tail_c = causal_conv(v[:, :T], _at(conv_col, c), cp.conv_w,
+                                  n_valid, activation=None)
+        note_short_conv_path("step", "xla")
+        y_r, tail_r = causal_conv(by_row(v), _at(conv_pool, c)[rows],
+                                  cp.conv_w, None, activation=None)
+        return (_conv_gate_out(cfg, proj, join(y_c, y_r), cp, h.dtype),
+                (_put(conv_col, tail_c, c), conv_pool.at[c, rows].set(tail_r)))
+
+    def attend(q, k, v, k_c, v_c, a):
+        (k_col, k_pool), (v_col, v_pool) = k_c, v_c
+        att_c, k_a, v_a = _attend_dense(cfg, q[:, :T], k[:, :T], v[:, :T],
+                                        _at(k_col, a), _at(v_col, a),
+                                        chunk_pos, cpos)
+        att_r, k_pool, v_pool = _attend_paged(
+            cfg, by_row(q), by_row(k), by_row(v), k_pool, v_pool, a, rpos,
+            tables)
+        return (join(att_c, att_r), (_put(k_col, k_a, a), k_pool),
+                (_put(v_col, v_a, a), v_pool))
+
+    x, (k, v, conv), stats = _scan_layers(
+        params, cfg, x, ((col.k, pkv.k), (col.v, pkv.v),
+                         (col.conv, pool.conv)),
+        zero_stats(cfg), live, positions, conv_mixer, attend)
+    logits = _head(params, cfg, jnp.swapaxes(x[:, T:], 0, 1))      # [R, 1, V]
+    last = _poison_logits(logits[:, -1, :], poison)
+    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    return ((greedy, _nonfinite_rows(last), last),
+            (col._replace(k=k[0], v=v[0], conv=conv[0]),
+             (PagedKVCache(k=k[1], v=v[1]),
+              StatePool(s=None, conv=conv[1]), totals.at[1].add(stats))))
 
 
 def _load_params(ld, cfg: ModelConfig) -> Params:
@@ -401,7 +541,7 @@ def _describe(cfg: ModelConfig, engine) -> str:
 FAMILY = Family(
     forward=forward,
     paged_forward=paged_forward,
-    tick=None,
+    tick=forward_and_step,
     column=StateColumn.zeros,
     load_params=_load_params,
     matmul_weight_count=_matmul_weight_count,

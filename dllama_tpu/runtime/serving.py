@@ -1599,6 +1599,12 @@ class PagedGenerator(_GeneratorCore):
             from ..models.share import zero_totals
 
             self.moe_stats = zero_totals(self.cfg)
+            if engine.plan is None:
+                # pinned as the pools are (a tick program takes the totals
+                # before any step or commit has), in the spelling every
+                # program hands them back in: they are a NEW array each
+                # time, replicated with its axes named to its rank
+                self.moe_stats = self._pin_home(self.moe_stats, by_rank=True)
             self._moe_seen = np.zeros(self.moe_stats.shape, np.int64)
             # held planes a step's routed layers COULD fetch, added a step:
             # what ``moe_planes`` is a share of
@@ -2389,7 +2395,7 @@ class PagedGenerator(_GeneratorCore):
         table[:len(bids)] = bids
         return self._pin_home(self._take(self.pkv, jnp.asarray(table)))
 
-    def _pin_home(self, col):
+    def _pin_home(self, col, by_rank: bool = False):
         """Pin ONE canonical sharding on an admission's column (and on the
         pool it is gathered from, at its creation): the prefill
         executable is keyed on its input's sharding (and its trace on the
@@ -2413,10 +2419,15 @@ class PagedGenerator(_GeneratorCore):
         # them, a chunk's column included; a bare device otherwise
         s = jax.tree.leaves(self.eng.params)[0].sharding
         if isinstance(s, jax.sharding.NamedSharding):
-            s = jax.sharding.NamedSharding(s.mesh, jax.sharding.PartitionSpec())
+            # ``by_rank``: the same placement spelled ``(None,) * ndim``,
+            # which is a different cache key
+            at = lambda a: jax.sharding.NamedSharding(
+                s.mesh, jax.sharding.PartitionSpec(
+                    *(None,) * (a.ndim if by_rank else 0)))
         else:
-            s = jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
-        return jax.device_put(col, jax.tree.map(lambda _: s, col))
+            one = jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
+            at = lambda _: one
+        return jax.device_put(col, jax.tree.map(at, col))
 
     def _exec_prefill(self, col, padded, pos: int, n_valid: int):
         # a recurrent state would keep what padding wrote into it: the
@@ -2824,12 +2835,6 @@ class PagedGenerator(_GeneratorCore):
                       else self._both_tables)
             host = (self.next_token.astype(np.int32)[:, None],
                     self.pos.astype(np.int32), tables)
-            # blocks this step's walk over the cache reads, over the live
-            # rows (ops/paged_attention.py and ops/mla.py both walk
-            # ceil((pos + 1) / block_size) entries a row)
-            walk_blocks = int(sum(
-                -(-(int(self.pos[i]) + 1) // self.block_size)
-                for i in active))
             # either program takes what the architecture carries
             # (_step_cache), all donated, and gives all of it back
             if chunk is None:
@@ -2857,10 +2862,18 @@ class PagedGenerator(_GeneratorCore):
                 nxt, nf, totals = io.fetch(tokens=nxt, nonfinite=nf,
                                            moe_stats=self.moe_stats)
                 self._note_moe(totals, wait)
-            if self.latent:
-                wait.set(mla_walk_blocks=walk_blocks)
-            else:
-                wait.set(kv_walk_blocks=walk_blocks)
+            if chunk is None:
+                # blocks this step's walk over the cache reads, over the
+                # live rows (ops/paged_attention.py and ops/mla.py both walk
+                # ceil((pos + 1) / block_size) entries a row). The STEP
+                # program's walk alone, as the routing counters above: the
+                # walk's roofline share divides it by that program's kernel
+                # time, and a carried tick's rows walk inside the tick program
+                walk_blocks = int(sum(
+                    -(-(int(self.pos[i]) + 1) // self.block_size)
+                    for i in active))
+                wait.set(**{"mla_walk_blocks" if self.latent
+                            else "kv_walk_blocks": walk_blocks})
             if wait.traced:
                 # running totals, as the routing counters': a reader of a
                 # traced slice takes last less first
@@ -2919,7 +2932,12 @@ class PagedGenerator(_GeneratorCore):
             self._m_moe_tokens.inc(int(both[N_COUNTS + e]),
                                    expert=str(int(e)))
         wait.set(moe_pairs=int(delta[0, 0]))
-        self._moe_plane_slots += self.cfg.n_moe_layers * self.cfg.n_experts
+        if delta[0, :2].any():
+            # the STEP program ran: a tick program's one joined dispatch is a
+            # chunk-form one and counts on the chunk row alone
+            # (models/lfm2.py), so planes over slots stays a step's share
+            self._moe_plane_slots += (self.cfg.n_moe_layers
+                                      * self.cfg.n_experts)
         if wait.traced:
             pairs, tokens = self._m_moe_pairs, self._m_moe_tokens
             wait.set(moe_held=int(pairs.total(where="held")),

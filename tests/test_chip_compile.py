@@ -581,13 +581,14 @@ def test_paged_attention_compiles_at_64_lane_heads_padded_for_v5e(one_chip):
     assert (heads, group * 16) == (8, 128)
 
 
-@pytest.mark.parametrize("rows", [32, 64, 256])
+@pytest.mark.parametrize("rows", [32, 64, 256, 288])
 @pytest.mark.parametrize("k,n", LFM2_STEP)
 def test_chunk_kernel_compiles_at_lfm2s_planes_for_v5e(one_chip, k, n, rows):
     """lfm2-24b-a2b's Q40 planes (the conv mixer's 6144-wide in-projection,
     its square out-projection, the dense feed-forward at 11776, a K/V
     projection) in the chunk regime, which is ALSO its 32-row step's: stack +
-    index, one Mosaic kernel."""
+    index, one Mosaic kernel. 288 rows are its widest TICK (PR 53: a 256-row
+    chunk and 32 decode rows joined, ``lfm2.forward_and_step``)."""
     from dllama_tpu.ops.linear import QuantizedWeight
     from dllama_tpu.ops.quant_matmul import fused_path, quant_matmul
 
@@ -603,11 +604,17 @@ def test_chunk_kernel_compiles_at_lfm2s_planes_for_v5e(one_chip, k, n, rows):
 
 
 @pytest.mark.parametrize("kk,n,rows,scatter", [(2048, 1536, 32, False), (1536, 2048, 32, True),
-                                               (2048, 1536, 256, False), (1536, 2048, 256, True)])
+                                               (2048, 1536, 256, False), (1536, 2048, 256, True)]
+                         + [case for rows in (64, 96, 160, 288)
+                            for case in ((2048, 1536, rows, False), (1536, 2048, rows, True))])
 def test_expert_chunk_compiles_at_lfm2s_experts_for_v5e(one_chip, kk, n, rows, scatter):
     """The grouped routed kernel at lfm2-24b-a2b's expert (2048 x 1536, the
     size of laguna's and not its shape), 16 layers of 64 held of 64, 4 a
-    token: the 32-row STEP's form (128 pairs over up to 64 runs) and a chunk's."""
+    token: the 32-row STEP's form (128 pairs over up to 64 runs), a chunk's,
+    and (PR 53) the TICK's joined dispatch at every bucket, 32 / 64 / 128 /
+    256 chunk rows and 32 decode rows: ``stripe(...) is not None`` says the
+    kernel takes the joined rows WHOLE (where ``share._chunk_pieces`` halved
+    them a plane would be fetched once a piece, twice again)."""
     from dllama_tpu.ops import expert_chunk as ec
     from dllama_tpu.ops.linear import QuantizedWeight
 

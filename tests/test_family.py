@@ -137,7 +137,7 @@ TICK = {
     ArchType.LAGUNA: None,
     ArchType.FALCON_H1: ("falcon_h1", "forward_and_step"),
     ArchType.AXK1: None,
-    ArchType.LFM2: None,
+    ArchType.LFM2: ("lfm2", "forward_and_step"),
     ArchType.NEMOTRON_H: None,
 }
 

@@ -353,10 +353,12 @@ def test_everything_else_keeps_its_two_programs(files, why):
 
 @pytest.mark.parametrize("family", ["OLMO_HYBRID", "LAGUNA", "FALCON_H1", "AXK1", "LFM2", "NEMOTRON_H"])
 def test_every_other_family_keeps_its_two_programs_and_falcon_h1_brings_its_own(family, tmp_path):
-    """A generator takes the tick program its FAMILY brings and asks no name:
-    the five that bring none dispatch ``forward`` for a chunk beside a live
-    row and a step behind it, count no chunk as carried and never load
-    ``forward_and_step``; the one that brings its own loads no ``forward``."""
+    """A generator takes the tick program its FAMILY brings and asks no name
+    (``test_family.TICK`` is the table): those that bring none dispatch
+    ``forward`` for a chunk beside a live row and a step behind it, count no
+    chunk as carried and never load ``forward_and_step``; one that brings its
+    own (falcon_h1 since PR 52, lfm2 since PR 53) loads no ``forward`` and
+    counts one chunk as carried."""
     import dllama_tpu.runtime.engine as engine_mod
     from test_falcon_h1 import BENCH, _bench, _engine
     from test_family import TICK, TINY         # the six families' selftest files, and which of the eight bring a tick

@@ -1,0 +1,418 @@
+"""A tick that carries a prefill chunk as ONE program in the family of gated
+short convolutions beside attention with routed experts (PR 53):
+``models.lfm2.forward_and_step`` against ``forward`` followed by
+``paged_sampled_step_guarded`` on the same inputs (same tokens, column, block
+pool and tail pool; padding behind ``n_valid`` leaves the tails alone and is not
+routed), then the paged generator that dispatches it: every plain chunk goes
+through it (one executable a bucket), the tick's live rows ride the tick's first
+chunk, and every request's tokens are those of a generator that keeps its two
+programs. And the routing counters: the joined dispatch is a chunk-form one and
+counts on the totals' chunk row alone, each pair once. CPU, the cell's selftest
+configuration (hidden 64, 9 layers, 8 experts of which a token takes 2,
+float32); nothing here is a timing claim. The tolerances are
+tests/test_forward_and_step.py's: the joined rows' matmuls sum in the order the
+parts' do, a row at a time (off a TPU the routed chunk form is every chosen
+expert over every row, the step form a GEMV a pair: the same products in
+another order)."""
+
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dllama_tpu.models import lfm2, llama
+from dllama_tpu.models.share import N_COUNTS, zero_totals
+from dllama_tpu.ops import sampling
+from dllama_tpu.runtime import flightrec, introspection
+from dllama_tpu.runtime import telemetry as tm
+from dllama_tpu.runtime.kvblocks import PagedKVCache, StateColumn, StatePool
+from dllama_tpu.runtime.serving import BatchScheduler, PagedGenerator
+
+from test_forward_and_step import _drive
+from test_lfm2 import REAL, _reference_logits
+from test_lfm2 import bench, engine  # noqa: F401  (module-scoped fixtures: this file gets an engine of its own)
+
+R, BS, M = 4, 16, 8          # slots, block size, table width (positions under 128)
+K, ROUTED = 2, 7             # experts a token, routed layers (the selftest configuration's)
+
+
+@pytest.fixture(autouse=True)
+def _fresh_recorder_and_loader():
+    import dllama_tpu.runtime.engine as engine_mod
+
+    flightrec.recorder().reset()
+    yield
+    flightrec.recorder().reset()
+    engine_mod.load_params_from_mfile = llama.load_params_from_mfile       # the weights module's seam
+
+
+# -- the program ---------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def programs(engine):
+    cfg = engine.cfg
+    # functions of this file's own: a jit of llama.forward itself would share its trace cache with the worker's
+    return (jax.jit(lambda p, *a: llama.forward(p, cfg, *a)),
+            jax.jit(lambda p, *a: llama.paged_sampled_step_guarded(p, cfg, *a)),
+            jax.jit(lambda p, *a: lfm2.forward_and_step(p, cfg, *a)),
+            jax.jit(sampling.sampled_token))
+
+
+def _inputs(cfg, T, live, sampled=False, seed=0):
+    """A column, a block pool and a tail pool of noise and running totals that
+    are not zero (what is not written must come back as it went in), ``live``
+    rows with tables of their own at positions inside them, the others dead
+    (null tables, a stale position)."""
+    rng = np.random.default_rng([seed, T, len(live)])
+    noise = lambda shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    pkv = PagedKVCache(*(noise((cfg.n_kv_layers, R * M + 1, cfg.n_kv_heads, BS, cfg.cache_width)) for _ in "kv"))
+    spool = StatePool(s=None, conv=noise(cfg.conv_shape(R + 1)))
+    totals = jnp.asarray(rng.integers(1, 1000, size=zero_totals(cfg).shape), jnp.int32)
+    col = StateColumn(*(noise((cfg.n_kv_layers, 1, cfg.n_kv_heads, 128, cfg.cache_width)) for _ in "kv"),
+                      s=None, conv=noise(cfg.conv_shape(1)),
+                      stats=jnp.asarray(rng.integers(1, 1000, size=totals.shape[1:]), jnp.int32))
+    tables = np.zeros((R, M), np.int32)
+    pos = rng.integers(0, 100, size=R).astype(np.int32)
+    for i in live:
+        n = int(pos[i]) // BS + 1
+        tables[i, :n] = 1 + i * M + np.arange(n)
+    temps, topps, coins = np.zeros(R, np.float32), np.zeros(R, np.float32), np.zeros(R, np.float32)
+    if sampled:
+        for i in live[::2] or [0]:
+            temps[i], topps[i], coins[i] = 0.8, 0.9, rng.random()
+    tokens = rng.integers(0, cfg.vocab_size, size=(R, 1)).astype(np.int32)
+    chunk = rng.integers(0, cfg.vocab_size, size=(1, T)).astype(np.int32)
+    return col, (pkv, spool, totals), tables, pos, tokens, chunk, (temps, topps, coins)
+
+
+def _both(engine, programs, col, pools, tables, pos, tokens, chunk, knobs, chunk_pos, n_valid, poison=0.0):
+    fwd, step, tick, sample = programs
+    params, poison = engine.params, np.float32(poison)
+    _logits, col_a = fwd(params, chunk, jnp.int32(chunk_pos), col, jnp.int32(n_valid))
+    (tok_a, nf_a), pools_a = step(params, tokens, pos, pools, tables, *knobs, poison)
+    (tok_b, nf_b, logits), (col_b, pools_b) = tick(params, tokens, pos, (col, pools), tables, chunk,
+                                                   jnp.int32(chunk_pos), jnp.int32(n_valid), poison)
+    np.testing.assert_array_equal(np.asarray(tok_b), np.argmax(np.asarray(logits), axis=-1))
+    if (knobs[0] > 0).any():         # a row samples: the generator runs the sampler over the rows' logits
+        tok_b = sample(logits, *knobs)
+    return (tok_a, nf_a, col_a, pools_a), (tok_b, nf_b, col_b, pools_b)
+
+
+def _same(a, b):
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5, atol=1e-5)
+
+
+ADDITIVE = np.r_[0, 1, N_COUNTS:N_COUNTS + 8]      # held pairs, absent pairs, tokens a held expert: sums over dispatches
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("live", [[], [2], [0, 1, 2, 3]], ids=["no-row", "one-row", "every-row"])
+@pytest.mark.parametrize("T,n_valid", [(32, 32), (32, 5), (64, 41)])
+def test_the_tick_program_is_forward_then_the_step(engine, programs, T, n_valid, live, sampled):
+    """Tokens and non-finite counts of the LIVE rows, the column's K/V and
+    tails and both pools equal what the two programs give on the same inputs:
+    greedy from the program's own argmax, and with the same coins from the
+    sampler over the logits it hands back; a dead row writes the null block and
+    the null row alone. The counters: row 0 of the totals is what it was,
+    ``col.stats`` goes back as it came, and the chunk row grew by what
+    ``forward`` put on ``col.stats`` PLUS what the step put on row 0 (pairs
+    and tokens an expert; the planes are the union's, at most the sum)."""
+    cfg = engine.cfg
+    inputs = _inputs(cfg, T, live, sampled)
+    (tok_a, nf_a, col_a, pools_a), (tok_b, nf_b, col_b, pools_b) = _both(engine, programs, *inputs, chunk_pos=16,
+                                                                         n_valid=n_valid)
+    np.testing.assert_array_equal(np.asarray(tok_a)[live], np.asarray(tok_b)[live])
+    np.testing.assert_array_equal(np.asarray(nf_a), np.asarray(nf_b))
+    assert not np.asarray(nf_b).any()
+    _same(col_a._replace(stats=None), col_b._replace(stats=None))
+    _same(pools_a[:2], pools_b[:2])
+    col0, (pkv0, spool0, totals0) = inputs[0], inputs[1]
+    totals0, stats0 = np.asarray(totals0), np.asarray(col0.stats)
+    chunk_added, step_added = np.asarray(col_a.stats) - stats0, np.asarray(pools_a[2])[0] - totals0[0]
+    tick_added = np.asarray(pools_b[2]) - totals0
+    np.testing.assert_array_equal(np.asarray(col_b.stats), stats0)
+    assert not tick_added[0].any()
+    np.testing.assert_array_equal(tick_added[1][ADDITIVE], (chunk_added + step_added)[ADDITIVE])
+    assert tick_added[1][0] + tick_added[1][1] == K * ROUTED * (n_valid + len(live))
+    assert max(chunk_added[3], step_added[3]) <= tick_added[1][3] <= chunk_added[3] + step_added[3]
+    assert tick_added[1][2] >= (T + R) * tick_added[1][3] // ROUTED       # the every-row form feeds every row a plane
+    # ... and what neither wrote is what went in: the rest of the column, the last row's last block, and the tails
+    # of the dead slots (row b + 1 is slot b's)
+    np.testing.assert_array_equal(np.asarray(col_b.k)[:, :, :, 16 + T:], np.asarray(col0.k)[:, :, :, 16 + T:])
+    assert np.any(np.asarray(col_b.k)[:, :, :, 16:16 + T] != np.asarray(col0.k)[:, :, :, 16:16 + T])
+    np.testing.assert_array_equal(np.asarray(pools_b[0].k)[:, R * M], np.asarray(pkv0.k)[:, R * M])
+    dead = [1 + i for i in range(R) if i not in live]
+    got, was = np.asarray(pools_b[1].conv), np.asarray(spool0.conv)
+    np.testing.assert_array_equal(got[:, dead], was[:, dead])
+    assert all(np.any(got[:, 1 + i] != was[:, 1 + i]) for i in live)
+
+
+@pytest.mark.parametrize("T,n_valid", [(32, 5), (32, 29), (64, 33)])
+def test_padding_behind_n_valid_leaves_tails_alone_and_is_not_routed(engine, programs, T, n_valid):
+    """The padded positions never enter a convolution's tail and are not
+    routed: with other tokens behind ``n_valid`` the column's tails, the
+    counters and every decode row's token come out bit for bit the same."""
+    col, pools, tables, pos, tokens, chunk, knobs = _inputs(engine.cfg, T, [0, 3], seed=3)
+    other = np.array(chunk)
+    other[0, n_valid:] = (other[0, n_valid:] + 1 + np.arange(T - n_valid)) % engine.cfg.vocab_size
+    tick = programs[2]
+    run = lambda c: tick(engine.params, tokens, pos, (col, pools), tables, c, jnp.int32(16), jnp.int32(n_valid),
+                         np.float32(0))
+    (tok_a, _nf, logits_a), (col_a, pools_a) = run(chunk)
+    (tok_b, _nf, logits_b), (col_b, pools_b) = run(other)
+    for a, b in ((col_a.conv, col_b.conv), (tok_a, tok_b), (logits_a, logits_b), (pools_a[1].conv, pools_b[1].conv),
+                 (pools_a[0].k, pools_b[0].k), (pools_a[2], pools_b[2])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    added = np.asarray(pools_a[2])[1] - np.asarray(pools[2])[1]
+    assert added[0] + added[1] == K * ROUTED * (n_valid + 2)
+    # the valid positions' K/V rows are the same; the padded ones' differ (and are overwritten by the next chunk)
+    np.testing.assert_array_equal(np.asarray(col_a.k)[:, :, :, :16 + n_valid], np.asarray(col_b.k)[:, :, :, :16 + n_valid])
+    assert np.any(np.asarray(col_a.k)[:, :, :, 16 + n_valid:16 + T] != np.asarray(col_b.k)[:, :, :, 16 + n_valid:16 + T])
+    assert np.any(np.asarray(col_a.conv) != np.asarray(col.conv))
+
+
+def test_a_poisoned_row_fails_alone(engine, programs):
+    """A non-finite value in ONE row's tails reaches that row's logits and no
+    other's, nor the chunk's column; the failpoint's selector poisons every
+    row's logits, as the step's does."""
+    cfg = engine.cfg
+    col, (pkv, spool, totals), tables, pos, tokens, chunk, knobs = _inputs(cfg, 32, [0, 1, 2, 3])
+    spool = spool._replace(conv=spool.conv.at[:, 1 + 1].set(jnp.nan))               # slot 1's row is 2
+    (tok_a, nf_a, col_a, _), (tok_b, nf_b, col_b, _) = _both(engine, programs, col, (pkv, spool, totals), tables, pos,
+                                                             tokens, chunk, knobs, 0, 32)
+    nf_b = np.asarray(nf_b)
+    assert nf_b[1] > 0 and not nf_b[[0, 2, 3]].any()
+    np.testing.assert_array_equal(np.asarray(nf_a), nf_b)
+    np.testing.assert_array_equal(np.asarray(tok_a)[[0, 2, 3]], np.asarray(tok_b)[[0, 2, 3]])
+    assert all(np.isfinite(np.asarray(leaf)).all() for leaf in jax.tree.leaves(col_b))
+    _same(col_a._replace(stats=None), col_b._replace(stats=None))
+    col, pools, *rest = _inputs(cfg, 32, [0, 1, 2, 3])
+    _, (_tok, nf, _col, _pools) = _both(engine, programs, col, pools, *rest, 0, 32, poison=1.0)
+    assert (np.asarray(nf) == cfg.vocab_size).all()
+
+
+def test_the_module_is_named_for_the_chunk_and_is_the_familys_tick():
+    """The benchmark tells a chunk's program from a step's by the XLA module's
+    name (``prefill_chunk_device_ms`` matches ``jit_forward``): this one is
+    ``jit_forward_and_step``, as the dense decoders' and falcon's are."""
+    from dllama_tpu.runtime import steppack
+
+    assert lfm2.FAMILY.tick is lfm2.forward_and_step
+    assert "jit_" + steppack.packed_program(lfm2.FAMILY.tick).__name__ == "jit_forward_and_step"
+
+
+def test_one_read_of_every_plane_a_layer_and_one_grouped_dispatch(engine, monkeypatch):
+    """What the program is for: each traced layer body asks ``linear`` ONCE a
+    dense plane over the joined ``T + R`` rows (two a conv mixer, four an
+    attention mixer, three a leading feed-forward), ``routed_ffn`` meets the
+    joined rows as ONE dispatch of the chunk form a routed body (the step form
+    is not traced at all), and the head runs over the R rows alone."""
+    from dllama_tpu.models import share
+
+    cfg = engine.cfg
+    col, pools, tables, pos, tokens, chunk, _knobs = _inputs(cfg, 32, [1])
+    seen, forms = [], []
+    real = lfm2.linear
+    monkeypatch.setattr(lfm2, "linear", lambda x, w, **kw: seen.append(x.shape) or real(x, w, **kw))
+    chunk_form, step_form = share._experts_chunk, share._experts_step
+    monkeypatch.setattr(share, "_experts_chunk", lambda cfg, x, *a: forms.append(("chunk", x.shape[0]))
+                        or chunk_form(cfg, x, *a))
+    monkeypatch.setattr(share, "_experts_step", lambda cfg, x, *a: forms.append(("step", x.shape[0]))
+                        or step_form(cfg, x, *a))
+    jax.eval_shape(lambda p, *a: lfm2.forward_and_step(p, cfg, *a), engine.params, tokens, pos, (col, pools),
+                   tables, chunk, jnp.int32(16), jnp.int32(32), np.float32(0))
+    # traced bodies: the leading conv layer; the scan's attention layer and conv layer; the cut period's two
+    assert len(seen) == 2 + (4 + 2) + (4 + 2) + 1
+    assert all(shape[:2] == (1, 32 + R) for shape in seen[:-1]) and seen[-1][:2] == (R, 1)
+    assert forms == [("chunk", 32 + R)] * 4
+
+
+# -- through the generator and the scheduler ------------------------------------
+
+
+def _prompt(n, seed):
+    return np.random.default_rng(seed).integers(1, 250, size=n).tolist()
+
+
+def _staggered(engine, two_programs, temps=(0.0,) * 6):
+    prompts = [_prompt(n, seed=n) for n in (70, 33, 130, 97, 40, 161)]
+    sched = BatchScheduler(engine, n_slots=3, _start_thread=False)
+    gen = sched.gen
+    assert isinstance(gen, PagedGenerator) and gen._tick is not None
+    if two_programs:
+        gen._tick = None                 # what spec_lookup, a plan or a family without a tick leave it at
+    gen.dispatched = {"step": 0, "tick": 0}             # which program stepped the rows, counted at the one door
+    run_rows = gen._run_rows
+
+    def counted(active, rows, chunk=None):
+        gen.dispatched["step" if chunk is None else "tick"] += 1
+        return run_rows(active, rows, chunk=chunk)
+
+    gen._run_rows = counted
+    try:
+        kw = lambda i: dict(stop_on_eos=False, temperature=temps[i], topp=0.9, seed=90 + i)
+        reqs = [sched.submit(prompts[0], 12, **kw(0))]
+        for i, p in enumerate(prompts[1:], 1):
+            for _ in range(3):
+                sched._tick()
+            reqs.append(sched.submit(p, 12, **kw(i)))
+        _drive(sched, reqs)
+        totals = np.asarray(gen.moe_stats)
+    finally:
+        sched.close()
+    assert all(r.error is None and len(r.tokens) == 12 for r in reqs)
+    return [r.tokens for r in reqs], gen, totals, sum(len(p) - 1 for p in prompts)
+
+
+@pytest.mark.parametrize("temps", [(0.0,) * 6, (0.8, 0.0, 1.1, 0.0, 0.7, 0.0)], ids=["greedy", "some-sample"])
+def test_staggered_arrivals_emit_the_two_program_generators_tokens(engine, temps):
+    """Requests admitted while others decode, prompts of one to three chunks,
+    padded last chunks among them: every request's tokens are those of the
+    generator that dispatches ``forward`` and the step apart (a sampling row's
+    with the same coins); the chunks with live rows were counted, and no plain
+    ``forward`` was dispatched at all. **The counters over the run**: held +
+    absent pairs of both rows sum to ``k`` x (live decode rows + valid chunk
+    tokens) x routed layers in either generator, each pair counted once; with
+    the tick the carried rows' pairs moved from row 0 to the chunk row, and
+    the plane slots grew a STEP program's dispatch alone."""
+    chunks = tm.registry().counter(tm.PREFILL_CHUNKS)
+    live0, none0 = chunks.total(rows="live"), chunks.total(rows="none")
+    seen0 = {e["program"] for e in introspection.ledger().snapshot()["events"]
+             if e["scope"] == engine.introspection_scope}
+    carried, gen, totals, prefilled = _staggered(engine, False, temps)
+    live, none = chunks.total(rows="live") - live0, chunks.total(rows="none") - none0
+    assert live > 0 and none > 0            # the first prompt's chunks had nobody beside them
+    assert (gen._n_chunks, gen._n_chunks_rows) == (live + none, live)
+    programs = {e["program"] for e in introspection.ledger().snapshot()["events"]
+                if e["scope"] == engine.introspection_scope}
+    assert "forward_and_step" in programs and ("forward" in seen0 or "forward" not in programs)
+    plain, gen2, totals2, _ = _staggered(engine, True, temps)
+    assert carried == plain
+    assert gen2._n_chunks == live + none and gen2._n_chunks_rows == 0
+    # every request decodes 12 tokens: its first comes from the commit's step, so 12 decode rows a request
+    every = K * ROUTED * (6 * 12 + prefilled)
+    assert totals[:, :2].sum() == totals2[:, :2].sum() == every
+    assert totals2[1, :2].sum() == K * ROUTED * prefilled
+    rode = (totals[1, :2].sum() - totals2[1, :2].sum()) // (K * ROUTED)      # decode rows a carried tick stepped
+    assert rode >= live and totals[0, :2].sum() == totals2[0, :2].sum() - rode * K * ROUTED
+    slots_a_step = ROUTED * gen.cfg.n_experts
+    assert gen.dispatched["tick"] == live and gen2.dispatched["tick"] == 0
+    assert gen._moe_plane_slots == gen.dispatched["step"] * slots_a_step       # a carried tick ran no step program
+    assert gen2._moe_plane_slots == gen2.dispatched["step"] * slots_a_step
+
+
+def test_a_carried_ticks_span_carries_nothing_of_the_step_programs(engine, tmp_path):
+    """While a profiler listens: the ``step_wait`` span of a tick whose rows
+    the STEP program stepped carries ``kv_walk_blocks`` and its own
+    ``moe_pairs``; a carried tick's carries no walk and ``moe_pairs`` 0, and
+    the running totals the step readers take (``moe_step_held``,
+    ``moe_planes``, ``moe_plane_slots``) stand still across it while
+    ``moe_chunk_held`` grows: what those readers divide by is the step
+    program's kernel time, which holds none of a carried tick."""
+    import program_spans        # benchmark/program_spans.py (test_lfm2 put benchmark/ on sys.path)
+
+    sched = BatchScheduler(engine, n_slots=3, _start_thread=False)
+    try:
+        first = sched.submit(_prompt(40, 7), 40, stop_on_eos=False)
+        for _ in range(4):
+            sched._tick()
+        trace_dir = str(tmp_path / "trace")
+        with jax.profiler.trace(trace_dir):
+            for _ in range(2):
+                sched._tick()
+            second = sched.submit(_prompt(130, 8), 6, stop_on_eos=False)
+            _drive(sched, [first, second])
+        assert sched.gen._n_chunks_rows >= 1
+    finally:
+        sched.close()
+    spans = program_spans.load(program_spans.newest_trace(trace_dir))
+    waits = [st for t in spans["ticks"] for name, _s, _e, st in t["children"] if name == "step_wait"]
+    carried = [i for i, st in enumerate(waits) if "kv_walk_blocks" not in st]
+    assert carried and len(carried) < len(waits) and carried[0] > 0
+    for i in carried:
+        before, at = waits[i - 1], waits[i]
+        assert int(at["moe_pairs"]) == 0 and int(at["moe_chunk_held"]) > int(before["moe_chunk_held"])
+        assert all(int(at[key]) == int(before[key]) for key in ("moe_step_held", "moe_planes", "moe_plane_slots"))
+    stepped = [st for st in waits if "kv_walk_blocks" in st]
+    assert all(int(st["moe_pairs"]) > 0 for st in stepped)
+    assert int(stepped[-1]["moe_plane_slots"]) - int(stepped[0]["moe_plane_slots"]) == (len(stepped) - 1) * ROUTED * 8
+
+
+def test_the_first_token_is_the_references_argmax(bench, engine):
+    """Held against the plain reference, not only against the other
+    generator: a request prefilled by carried chunks and decoded beside
+    others emits the reference's greedy continuation."""
+    sched = BatchScheduler(engine, n_slots=2, _start_thread=False)
+    try:
+        a, b = _prompt(150, 1), _prompt(37, 2)
+        reqs = [sched.submit(a, 20, stop_on_eos=False)]
+        for _ in range(4):
+            sched._tick()
+        reqs.append(sched.submit(b, 10, stop_on_eos=False))
+        _drive(sched, reqs)
+        assert sched.gen._n_chunks_rows > 0
+    finally:
+        sched.close()
+    for prompt, req in zip((a, b), reqs):
+        want = _reference_logits(bench, engine.params, prompt + list(req.tokens))
+        assert [int(r.argmax()) for r in want[len(prompt) - 1:-1]] == list(req.tokens)
+
+
+def test_one_tick_executable_a_bucket_and_none_from_churn(engine):
+    """Admit / retire churn over every bucket compiles the tick program once a
+    bucket and then nothing: live rows or none, first chunk or later, padded
+    or full, the executable is the bucket's."""
+    ledger = introspection.ledger()
+    scope = engine.introspection_scope
+    of_scope = lambda: [e for e in ledger.snapshot()["events"] if e["scope"] == scope]
+    sched = BatchScheduler(engine, n_slots=3, _start_thread=False)
+    n0 = len(of_scope())                 # a generator's programs are its own: an earlier test's are not these
+    seen0 = set(engine.seen_buckets)
+    engine.seen_buckets.clear()
+    lengths = (33, 65, 129, 257, 97, 40)
+
+    def wave(seed):
+        reqs = []
+        for i, n in enumerate(lengths):
+            reqs.append(sched.submit(_prompt(n, seed=seed + i), 6, stop_on_eos=False))
+            sched._tick()
+        _drive(sched, reqs)
+        assert all(r.error is None for r in reqs)
+
+    try:
+        wave(100)
+        wave(200)
+        before = ledger.compile_count(scope)
+        events = of_scope()[n0:]
+        wave(300)
+        wave(400)
+        assert ledger.compile_count(scope) == before
+        buckets = set(engine.seen_buckets)
+    finally:
+        sched.close()
+        engine.seen_buckets |= seen0
+    ticks = [e for e in events if e["program"] == "forward_and_step"]
+    assert len(ticks) == len(buckets) and buckets == {32, 64, 128, 256}
+    assert sum(e["program"] == "paged_sampled_step" for e in events) <= 1
+    assert not any(e["program"] == "forward" for e in events)
+
+
+def test_the_cells_engine_options_take_the_tick():
+    """The conditions under which a generator takes ``family.tick``, read off
+    the cell's own file: widest bucket 256 and 32 slots are 288 rows, inside
+    the kernel's chunk regime; no speculative verify, no plan; and the
+    narrowest tick (bucket 32 + 32 slots) is past the routed step form, so
+    every tick's routed half is one grouped dispatch."""
+    from dllama_tpu.models.share import STEP_FORM_MAX_ROWS
+    from dllama_tpu.ops.quant_matmul import CHUNK_MAX_M
+
+    with open(REAL, encoding="utf-8") as f:
+        eng = json.load(f)["engine"]
+    assert eng["slots"] == 32 and 256 + eng["slots"] <= CHUNK_MAX_M
+    assert 32 + eng["slots"] > STEP_FORM_MAX_ROWS
+    assert not eng.get("spec_lookup") and eng.get("tp", 1) == 1
