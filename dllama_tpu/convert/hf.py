@@ -38,7 +38,13 @@ ARCH_BY_MODEL_TYPE = {
     "axk1": ArchType.AXK1,
     "lfm2_moe": ArchType.LFM2,
     "nemotron_h": ArchType.NEMOTRON_H,
+    "granitemoehybrid": ArchType.GRANITE_HYBRID,
 }
+
+# behind a refusal of ``tie_word_embeddings``: which families do carry a tie
+_TIE_CARRIED_BY = ("(a tie is carried by granitemoehybrid, whose head and "
+                   "embedding are ONE array, and by the Llama and Qwen3 "
+                   "files, which hold it twice)")
 
 HIDDEN_ACT_BY_NAME = {"gelu": HiddenAct.GELU, "silu": HiddenAct.SILU,
                       "relu2": HiddenAct.RELU2}
@@ -171,6 +177,8 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
         return {**params, **_lfm2_header(cfg)}
     if model_type == "nemotron_h":
         return {**params, **_nemotron_h_header(cfg)}
+    if model_type == "granitemoehybrid":
+        return {**params, **_granite_hybrid_header(cfg)}
 
     if model_type == "falcon_h1":
         params.update(_falcon_h1_header(cfg))
@@ -255,7 +263,7 @@ def _falcon_h1_header(cfg: dict) -> dict:
             "falcon_h1: projection biases, a convolution without its bias, "
             "a mixer without its gated norm or with the norm before the "
             "gate, attention in some layers only, rope scaling and tied "
-            "embeddings are not carried")
+            "embeddings are not carried " + _TIE_CARRIED_BY)
     heads, hd = int(cfg["mamba_n_heads"]), int(cfg["mamba_d_head"])
     if heads * hd != int(cfg["mamba_d_ssm"]):
         raise ValueError(
@@ -307,7 +315,7 @@ def _axk1_header(cfg: dict) -> dict:
             "an activation other than silu, tied embeddings, a scoring "
             "function other than sigmoid or softmax, a top-k method with a "
             "score-correction bias, a rope scaling other than yarn or a norm "
-            "epsilon other than 1e-6 are not carried")
+            "epsilon other than 1e-6 are not carried " + _TIE_CARRIED_BY)
     n_shared = int(cfg.get("n_shared_experts") or 0)
     return {
         "hidden_dim": int(cfg["moe_intermediate_size"]),
@@ -402,6 +410,24 @@ def _nemotron_h_header(cfg: dict) -> dict:
     }
 
 
+def _ssd_mixer_items(mx, wt: int, n_dt: int) -> list["PlanItem"]:
+    """One SSD mixer's tensors in the walk's order, ``mx(name)`` the
+    candidate keys of the checkpoint's tensor ``name``: ``in_proj`` split (its
+    last ``n_dt`` rows are the float32 ``dt`` plane), ``conv1d.weight`` ``[C, 1,
+    K]`` as taps ``[K, C]`` (tap ``K - 1`` on the current position)."""
+    return [
+        PlanItem(mx("in_proj.weight"), wt, lambda w: w[:-n_dt]),
+        PlanItem(mx("in_proj.weight"), F32, lambda w: w[-n_dt:]),
+        PlanItem(mx("conv1d.weight"), F32,
+                 lambda w: np.ascontiguousarray(w[:, 0, :].T)),
+        PlanItem(mx("conv1d.bias"), F32),
+        PlanItem(mx("A_log"), F32),
+        PlanItem(mx("D"), F32),
+        PlanItem(mx("dt_bias"), F32),
+        PlanItem(mx("norm.weight"), F32),
+        PlanItem(mx("out_proj.weight"), wt)]
+
+
 def _nemotron_h_plan(params: dict) -> list["PlanItem"]:
     """``model_type: nemotron_h``'s tensors in the order
     ``mfile._walk_nemotron_h_layer`` reads them. A layer's block is
@@ -419,17 +445,7 @@ def _nemotron_h_plan(params: dict) -> list["PlanItem"]:
     for l, kind in enumerate(params["layer_pattern"]):
         mx = lambda name, l=l: both(f"layers.{l}.mixer.{name}")
         if kind == "M":
-            plan += [
-                PlanItem(mx("in_proj.weight"), wt, lambda w: w[:-n_dt]),
-                PlanItem(mx("in_proj.weight"), F32, lambda w: w[-n_dt:]),
-                PlanItem(mx("conv1d.weight"), F32,
-                         lambda w: np.ascontiguousarray(w[:, 0, :].T)),
-                PlanItem(mx("conv1d.bias"), F32),
-                PlanItem(mx("A_log"), F32),
-                PlanItem(mx("D"), F32),
-                PlanItem(mx("dt_bias"), F32),
-                PlanItem(mx("norm.weight"), F32),
-                PlanItem(mx("out_proj.weight"), wt)]
+            plan += _ssd_mixer_items(mx, wt, n_dt)
         elif kind == "*":
             plan += [PlanItem(mx(f"{p}_proj.weight"), wt) for p in "qkvo"]
         else:
@@ -447,6 +463,116 @@ def _nemotron_h_plan(params: dict) -> list["PlanItem"]:
         plan.append(PlanItem(both(f"layers.{l}.norm.weight"), F32))
     plan.append(PlanItem(both("norm_f.weight") + ("model.norm.weight",), F32))
     plan.append(PlanItem(("lm_head.weight",), wt))
+    return plan
+
+
+def _granite_hybrid_header(cfg: dict) -> dict:
+    """``model_type: granitemoehybrid``'s config keys as the header's
+    extension keys (formats/mfile.py, HeaderKey 74-76, 46-47, the pattern 72,
+    the mixer's 39-44, the share's 35-38, 21 and 67). ``layer_types`` is
+    ``num_hidden_layers`` entries of ``mamba`` / ``attention``; the header's
+    pattern is of BLOCKS, two a layer (``ME`` / ``*E``), and ``n_layers`` counts
+    them. ``intermediate_size`` is read as an expert's width (the config has
+    no key of its own for it), the head's width is ``hidden_size /
+    num_attention_heads``, ``rope_theta`` is not read (``position_embedding_
+    type: nope``). What the config does not say (the ``z x B C dt`` order of
+    the in-projection's rows, the gate before the grouped norm, ``dt``
+    unclamped, the fused ``input_linear``'s first half under ``silu``) the
+    arch implies (models/granite_hybrid.py)."""
+    from ..models.granite_hybrid import BLOCKS, layer_pattern
+
+    kinds = list(cfg["layer_types"])
+    heads = int(cfg["mamba_n_heads"])
+    eps = cfg.get("rms_norm_eps")
+    if (len(kinds) != cfg["num_hidden_layers"] or set(kinds) - set(BLOCKS)
+            or heads * cfg["mamba_d_head"]
+            != cfg["mamba_expand"] * cfg["hidden_size"]
+            or eps not in (1e-5, 1e-6)):
+        raise ValueError(
+            "granitemoehybrid: layer_types is not num_hidden_layers entries "
+            "of mamba / attention, the mixer's heads are not mamba_expand x "
+            "hidden_size wide, or the norm epsilon is neither 1e-5 nor 1e-6")
+    if (cfg.get("hidden_act", "silu") != "silu"
+            or not cfg.get("mamba_conv_bias", True)
+            or cfg.get("position_embedding_type", "nope") != "nope"
+            or cfg.get("normalization_function", "rmsnorm") != "rmsnorm"
+            or not cfg.get("num_local_experts")
+            or not cfg.get("shared_intermediate_size")
+            or any(cfg.get(k) for k in ("attention_bias", "mamba_proj_bias"))):
+        raise ValueError(
+            "granitemoehybrid: another activation than silu, a projection "
+            "bias, a convolution without its bias, positions in attention "
+            "(rope), another norm than rmsnorm, or a layer without routed "
+            "experts and a shared one are not carried")
+    return {
+        "n_layers": 2 * len(kinds),
+        "hidden_dim": int(cfg["intermediate_size"]),
+        "n_experts": int(cfg["num_local_experts"]),
+        "n_active_experts": int(cfg["num_experts_per_tok"]),
+        "moe_norm_topk": 1,
+        "head_dim": cfg["hidden_size"] // cfg["num_attention_heads"],
+        "norm_epsilon": 5 if eps == 1e-5 else 6,
+        "rope_theta": int(cfg.get("rope_theta") or 10000),
+        "shared_expert_dim": int(cfg["shared_intermediate_size"]),
+        "moe_routed_scale_milli": 1000,
+        "moe_router_width": int(cfg["num_local_experts"]),
+        "moe_first_expert": 0,
+        "ssm_n_heads": heads,
+        "ssm_head_dim": int(cfg["mamba_d_head"]),
+        "ssm_n_groups": int(cfg["mamba_n_groups"]),
+        "ssm_state_dim": int(cfg["mamba_d_state"]),
+        "ssm_conv_kernel": int(cfg["mamba_d_conv"]),
+        "ssm_chunk_size": int(cfg["mamba_chunk_size"]),
+        "moe_score_func": 0,
+        "embedding_mult": float(cfg.get("embedding_multiplier", 1.0)),
+        "lm_head_mult": 1.0 / float(cfg.get("logits_scaling", 1.0)),
+        "residual_mult": float(cfg.get("residual_multiplier", 1.0)),
+        "attn_scale": float(cfg["attention_multiplier"]),
+        "tied_embeddings": int(bool(cfg.get("tie_word_embeddings"))),
+        "layer_pattern": layer_pattern(kinds),
+    }
+
+
+def _granite_hybrid_plan(params: dict) -> list["PlanItem"]:
+    """``model_type: granitemoehybrid``'s tensors in the order
+    ``mfile._walk_nemotron_h_layer`` reads a GRANITE_HYBRID file's blocks.
+    Published layer ``N`` is blocks ``2N`` (``model.layers.N.mamba`` or
+    ``.self_attn`` behind ``input_layernorm``) and ``2N + 1`` (``block_sparse_moe``
+    and ``shared_mlp`` behind ``post_attention_layernorm``). The mixer's
+    ``in_proj`` and ``conv1d`` as :func:`_nemotron_h_plan` splits them. The
+    fused ``input_linear`` ``[E, 2 W, dim]`` (``[2 W, dim]`` for the shared
+    expert) is split into the halves the kernels read as planes: the FIRST
+    ``W`` rows are the one under ``silu`` (``w1``), the second the other
+    (``w3``). A tied head is written from the embedding (the reference
+    format's walk ends in the head; the loader keeps one array)."""
+    wt = params["weight_float_type"]
+    n_dt, hid, wide = (params["ssm_n_heads"], params["hidden_dim"],
+                       params["shared_expert_dim"])
+    plan = [PlanItem(("model.embed_tokens.weight",), F32)]
+    blocks = params["layer_pattern"]
+    for n in range(len(blocks) // 2):
+        at = lambda name, n=n: (f"model.layers.{n}.{name}",)
+        if blocks[2 * n] == "M":
+            plan += _ssd_mixer_items(lambda name: at("mamba." + name), wt,
+                                     n_dt)
+        else:
+            plan += [PlanItem(at(f"self_attn.{p}_proj.weight"), wt)
+                     for p in "qkvo"]
+        plan.append(PlanItem(at("input_layernorm.weight"), F32))
+        fused, out = (at("block_sparse_moe.input_linear.weight"),
+                      at("block_sparse_moe.output_linear.weight"))
+        plan.append(PlanItem(at("block_sparse_moe.router.layer.weight"), F32))
+        for e in range(params["n_experts"]):
+            plan += [PlanItem(fused, wt, lambda w, e=e: w[e, hid:]),
+                     PlanItem(fused, wt, lambda w, e=e: w[e, :hid]),
+                     PlanItem(out, wt, lambda w, e=e: w[e])]
+        shared = at("shared_mlp.input_linear.weight")
+        plan += [PlanItem(shared, wt, lambda w: w[:wide]),
+                 PlanItem(at("shared_mlp.output_linear.weight"), wt),
+                 PlanItem(shared, wt, lambda w: w[wide:]),
+                 PlanItem(at("post_attention_layernorm.weight"), F32)]
+    plan.append(PlanItem(("model.norm.weight",), F32))
+    plan.append(PlanItem(("lm_head.weight", "model.embed_tokens.weight"), wt))
     return plan
 
 
@@ -631,6 +757,8 @@ def hf_tensor_plan(params: dict) -> list[PlanItem]:
             "half-split)")
     if arch == ArchType.NEMOTRON_H:
         return _nemotron_h_plan(params)
+    if arch == ArchType.GRANITE_HYBRID:
+        return _granite_hybrid_plan(params)
     if arch == ArchType.FALCON_H1:
         raise NotImplementedError(
             "falcon_h1: the header is mapped (load_hf_config), the "

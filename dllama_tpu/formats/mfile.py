@@ -182,6 +182,19 @@ class HeaderKey(enum.IntEnum):
     # MOE_SELECT_BIAS; HIDDEN_ACT is an expert's (HiddenAct.RELU2: ungated).
     LAYER_PATTERN = 72
     MOE_LATENT_DIM = 73
+    # OUR format extension, read by ArchType.GRANITE_HYBRID only
+    # (models/granite_hybrid.py): NEMOTRON_H's pattern and keys with GATED
+    # experts and a gated shared one (three planes each, as the other routed
+    # files order them), and what its equation adds, the floats as float32
+    # bits: RESIDUAL_MULT scales every block's output where it joins the
+    # stream, ATTN_SCALE multiplies an attention score in place of ``head_dim
+    # ** -0.5``; EMBEDDING_MULT and LM_HEAD_MULT (46-47) mean what they mean
+    # for FALCON_H1. TIED_EMBEDDINGS 1: the head IS the embedding; the file
+    # still carries ``final_matmul_logits`` (the reference format's walk ends
+    # in it) and the loader does not read it.
+    RESIDUAL_MULT = 74
+    ATTN_SCALE = 75
+    TIED_EMBEDDINGS = 76
 
 
 class ArchType(enum.IntEnum):
@@ -219,6 +232,17 @@ class ArchType(enum.IntEnum):
     # shared one, in the order the header's pattern gives
     # (models/nemotron_h.py)
     NEMOTRON_H = 0xABCD07
+    # ours: every published layer is an SSD mixer or attention without
+    # positions, THEN gated routed experts beside a gated shared one, each
+    # behind its own norm: NEMOTRON_H's blocks two a layer (``ME`` / ``*E``),
+    # every block's output under one residual multiplier, the score's scale,
+    # the embedding's and the logits' stated, the head tied to the embedding
+    # (models/granite_hybrid.py)
+    GRANITE_HYBRID = 0xABCD08
+
+
+# the archs whose layers are blocks of ``layer_pattern``
+PATTERN_ARCHS = (ArchType.NEMOTRON_H, ArchType.GRANITE_HYBRID)
 
 
 PATTERN_KINDS = "M*E"          # LAYER_PATTERN's two-bit codes, in this order
@@ -345,6 +369,11 @@ class ModelHeader:
     # NEMOTRON_H (HeaderKey 72-73); empty / 0 for every other arch
     layer_pattern: str = ""
     moe_latent_dim: int = 0
+    # GRANITE_HYBRID (HeaderKey 74-76); 1.0 / 0 for every other arch
+    # (``attn_scale`` 0: ``head_dim ** -0.5``)
+    residual_mult: float = 1.0
+    attn_scale: float = 0.0
+    tied_embeddings: int = 0
 
     def pattern_layers(self, kind: str) -> list[int]:
         """The model's layers of ``kind`` (one of ``M * E``), in order."""
@@ -432,13 +461,16 @@ _HYBRID_KEYS = {k: k.name.lower() for k in (
     HeaderKey.QK_NOPE_HEAD_DIM, HeaderKey.QK_ROPE_HEAD_DIM,
     HeaderKey.V_HEAD_DIM, HeaderKey.MOE_N_GROUP, HeaderKey.MOE_TOPK_GROUP,
     HeaderKey.MOE_SCORE_FUNC, HeaderKey.SHORT_CONV_KERNEL,
-    HeaderKey.MOE_SELECT_BIAS, HeaderKey.MOE_LATENT_DIM)}
+    HeaderKey.MOE_SELECT_BIAS, HeaderKey.MOE_LATENT_DIM,
+    HeaderKey.TIED_EMBEDDINGS)}
 # FALCON_H1's float keys: the value is a float32's bit pattern
 _F32_BITS_KEYS = {k: k.name.lower() for k in HeaderKey
                   if HeaderKey.EMBEDDING_MULT <= k <= HeaderKey.SSM_MULT_DT}
 _F32_BITS_KEYS[HeaderKey.ROPE_THETA_F32] = "rope_theta"
 _F32_BITS_KEYS[HeaderKey.YARN_MSCALE] = "yarn_mscale"
 _F32_BITS_KEYS[HeaderKey.YARN_MSCALE_ALL_DIM] = "yarn_mscale_all_dim"
+_F32_BITS_KEYS[HeaderKey.RESIDUAL_MULT] = "residual_mult"
+_F32_BITS_KEYS[HeaderKey.ATTN_SCALE] = "attn_scale"
 
 
 def f32_bits(x: float) -> int:
@@ -551,19 +583,20 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
                 f"must divide the heads")
         if h.n_experts:
             raise ValueError("falcon_h1 model: routed experts are unsupported")
-    if h.arch_type == ArchType.NEMOTRON_H:
+    if h.arch_type in PATTERN_ARCHS:
+        arch = h.arch_type.name.lower()
         h.moe_router_width = h.moe_router_width or h.n_experts
         per_group = h.ssm_n_heads // max(1, h.ssm_n_groups)
         if len(h.layer_pattern) != h.n_layers or not h.n_layers:
             raise ValueError(
-                f"nemotron_h model: a pattern of {len(h.layer_pattern)} "
+                f"{arch} model: a pattern of {len(h.layer_pattern)} "
                 f"layers for {h.n_layers}")
         if "M" in h.layer_pattern and not (
                 h.ssm_n_heads and h.ssm_head_dim and h.ssm_state_dim
                 and h.ssm_conv_kernel > 1 and h.ssm_chunk_size
                 and per_group * h.ssm_n_groups == h.ssm_n_heads):
             raise ValueError(
-                f"nemotron_h model: {h.ssm_n_heads} mixer heads of "
+                f"{arch} model: {h.ssm_n_heads} mixer heads of "
                 f"{h.ssm_head_dim} in {h.ssm_n_groups} groups, state "
                 f"{h.ssm_state_dim}, {h.ssm_conv_kernel} taps, chunks of "
                 f"{h.ssm_chunk_size}: every size must be set and the groups "
@@ -575,11 +608,20 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
                 and h.moe_score_func in (0, 1)
                 and h.moe_select_bias in (0, 1)):
             raise ValueError(
-                f"nemotron_h model: experts [{h.moe_first_expert}, "
+                f"{arch} model: experts [{h.moe_first_expert}, "
                 f"{h.moe_first_expert + h.n_experts}) held of a router over "
                 f"{h.moe_router_width}, {h.n_active_experts} a token, score "
                 f"function code {h.moe_score_func}, selection bias "
                 f"{h.moe_select_bias}")
+    if h.arch_type == ArchType.GRANITE_HYBRID and (
+            h.moe_latent_dim or h.n_dense_layers or h.attn_scale < 0
+            or h.residual_mult <= 0):
+        raise ValueError(
+            f"granite_hybrid model: a latent of {h.moe_latent_dim}, "
+            f"{h.n_dense_layers} leading dense layers, a score scale of "
+            f"{h.attn_scale}, a residual multiplier of {h.residual_mult}: "
+            f"the experts live in the model's width behind every mixer and "
+            f"both scalars are positive")
     if h.arch_type == ArchType.LFM2:
         h.rope_type = RopeType.FALCON
         h.moe_router_width = h.moe_router_width or h.n_experts
@@ -799,7 +841,7 @@ class ModelFile:
             if h.arch_type == ArchType.LFM2:
                 off = self._walk_lfm2_layer(l, off)
                 continue
-            if h.arch_type == ArchType.NEMOTRON_H:
+            if h.arch_type in PATTERN_ARCHS:
                 off = self._walk_nemotron_h_layer(l, off)
                 continue
             off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
@@ -973,7 +1015,9 @@ class ModelFile:
         its selection bias (F32, where the header says it has one), the
         projection into the latent, the HELD experts (w1 up, w2 down: two
         planes each, in the latent's width), the projection out of it, the
-        shared expert's w1 w2 over the model's width."""
+        shared expert's w1 w2 over the model's width. A GRANITE_HYBRID
+        file's ``E`` block is :meth:`_walk_share_ffn`'s: gated experts (w3 w1
+        w2 each) and a gated shared one (w1 w2 w3), no latent."""
         h, wt = self.header, self.header.weight_type
         kind = h.layer_pattern[l]
         if kind == "M":
@@ -995,6 +1039,8 @@ class ModelFile:
             off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
             off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
             off += self._add("block_matmul_wo", l, (h.dim, h.q_dim), wt, off)
+        elif h.arch_type == ArchType.GRANITE_HYBRID:
+            off = self._walk_share_ffn(l, off)
         else:
             lat = h.moe_latent_dim or h.dim
             off += self._add("block_moe_gate", l,

@@ -10,7 +10,8 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import NamedTuple
 
-from ..formats.mfile import ArchType, HiddenAct, ModelHeader, RopeType
+from ..formats.mfile import (PATTERN_ARCHS, ArchType, HiddenAct, ModelHeader,
+                             RopeType)
 
 
 class Multipliers(NamedTuple):
@@ -19,7 +20,11 @@ class Multipliers(NamedTuple):
     ``embedding_multiplier``, ``lm_head_multiplier``, ``attention_in/out_
     multiplier``, ``key_multiplier``, ``ssm_in/out_multiplier``,
     ``mlp_multipliers`` (gate, down) and ``ssm_multipliers`` (over the z, x,
-    B, C and dt lanes of the mixer's in-projection)."""
+    B, C and dt lanes of the mixer's in-projection); and
+    ``ArchType.GRANITE_HYBRID``'s ``residual_multiplier`` on every block's
+    output where it joins the stream (models/nemotron_h.py's walk; its
+    ``embedding_multiplier`` is ``embedding``, one over its
+    ``logits_scaling`` is ``lm_head``)."""
 
     embedding: float = 1.0
     lm_head: float = 1.0
@@ -35,6 +40,7 @@ class Multipliers(NamedTuple):
     ssm_b: float = 1.0
     ssm_c: float = 1.0
     ssm_dt: float = 1.0
+    residual: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -173,6 +179,16 @@ class ModelConfig:
     # LFM2's.
     layer_pattern: tuple[str, ...] = ()
     moe_latent_dim: int = 0
+    # NEMOTRON_H's blocks two a published layer, ``ME`` or ``*E``
+    # (ArchType.GRANITE_HYBRID, models/granite_hybrid.py): gated experts and
+    # a gated shared one (the stacks have ``we3`` / ``ws3``), a softmax
+    # router, no latent; ``mult.residual`` on every block's output,
+    # ``mult.embedding`` and ``mult.lm_head``; ``attn_score_scale``
+    # multiplies an attention score (0: ``head_dim ** -0.5``);
+    # ``tied_embeddings``: ``Params.logits`` IS ``Params.embedding``, one
+    # array at the compute dtype.
+    attn_score_scale: float = 0.0
+    tied_embeddings: bool = False
 
     # TPU execution choices (no reference equivalent):
     compute_dtype: str = "float32"  # "float32" for parity, "bfloat16" for speed
@@ -302,6 +318,8 @@ class ModelConfig:
         """What multiplies an attention score: ``head_dim ** -0.5``, and
         with latent attention under YaRN the square of ``0.1 mscale_all_dim
         ln(factor) + 1`` on top (the whole score's, nope part too)."""
+        if self.attn_score_scale:
+            return self.attn_score_scale
         scale = self.head_dim ** -0.5
         if self.has_latent_cache and self.rope_scaling_factor > 1.0:
             from .rope import yarn_mscale
@@ -309,6 +327,15 @@ class ModelConfig:
             scale *= yarn_mscale(self.rope_scaling_factor,
                                  self.yarn_mscale_all_dim) ** 2
         return scale
+
+    @property
+    def score_dim(self) -> float:
+        """What the attention kernels take for the score's scale, ``score_dim
+        ** -0.5`` (ops/attention.py: "``head_dim`` is the score's scale"):
+        the head's width, or where the header states the scale the number
+        whose root divides a score by it (1 / 128 a score: 16384)."""
+        return (self.attn_score_scale ** -2 if self.attn_score_scale
+                else self.head_dim)
 
     @property
     def prefix_reuse_skipped(self) -> str | None:
@@ -520,16 +547,22 @@ class ModelConfig:
                 moe_routed_scale=h.moe_routed_scale_milli / 1000.0,
                 moe_router_width=h.moe_router_width,
                 moe_first_expert=h.moe_first_expert)
-        if h.arch_type == ArchType.NEMOTRON_H:
+        if h.arch_type in PATTERN_ARCHS:
+            granite = h.arch_type == ArchType.GRANITE_HYBRID
             hybrid = dict(
                 layer_pattern=tuple(h.layer_pattern),
                 ssm_heads=h.ssm_n_heads, ssm_head_dim=h.ssm_head_dim,
                 ssm_groups=h.ssm_n_groups, ssm_state_dim=h.ssm_state_dim,
                 ssm_conv_kernel=h.ssm_conv_kernel, ssm_chunk=h.ssm_chunk_size,
-                mult=Multipliers(),
+                # every header field at its default: Multipliers()
+                mult=Multipliers(embedding=h.embedding_mult,
+                                 lm_head=h.lm_head_mult,
+                                 residual=h.residual_mult),
+                attn_score_scale=h.attn_scale,
+                tied_embeddings=bool(h.tied_embeddings),
                 moe_latent_dim=h.moe_latent_dim,
                 moe_select_bias=bool(h.moe_select_bias),
-                moe_norm_eps=1e-20,
+                moe_norm_eps=0.0 if granite else 1e-20,
                 moe_score=("softmax", "sigmoid")[h.moe_score_func],
                 shared_expert_dim=h.shared_expert_dim,
                 moe_routed_scale=h.moe_routed_scale_milli / 1000.0,

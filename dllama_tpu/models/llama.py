@@ -665,7 +665,8 @@ def _attend_dense(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
     sharded or plain flash kernel, or the XLA oracle, as plan and shapes
     resolve. Returns ``(att, k_cache, v_cache)``. Shared by
     :func:`_layer_step` and the hybrid decoder's full layers
-    (models/hybrid.py)."""
+    (models/hybrid.py). The score's scale is ``cfg.score_dim ** -0.5``: the
+    head's width unless the header states the scale."""
     sp_res = None
     plan = _current_plan()
     if plan is not None and plan.axis_size("sp") > 1 \
@@ -691,11 +692,11 @@ def _attend_dense(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
                 # forced 'flash' off-TPU runs the kernel in interpret mode
                 # (the test path, same rule _sharded_flash applies)
                 att = flash_attention(
-                    q, k_cache, v_cache, start_pos, cfg.head_dim,
+                    q, k_cache, v_cache, start_pos, cfg.score_dim,
                     interpret=(cfg.attn_impl == "flash"
                                and not on_tpu()))
             else:
-                att = attention(q, k_cache, v_cache, positions, cfg.head_dim)
+                att = attention(q, k_cache, v_cache, positions, cfg.score_dim)
     att = constrain(att, "batch", None, "heads", None)
     return att, k_cache, v_cache
 
@@ -800,7 +801,7 @@ def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
         # dense logical cache never materializes in HBM, and a dead row
         # (an all-null table, whatever its stale position) costs nothing
         att = _pa.paged_ragged_attention(q, k_pool, v_pool, l, tables,
-                                         positions, cfg.head_dim,
+                                         positions, cfg.score_dim,
                                          window=window, **kernel)
     else:
         def view(pool):
@@ -809,7 +810,7 @@ def _attend_paged(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
                 B, cfg.n_kv_heads, n_blocks_seq * bs, pool.shape[-1])
 
         att = attention(q, view(k_pool), view(v_pool), positions,
-                        cfg.head_dim, window=window)
+                        cfg.score_dim, window=window)
     att = constrain(att, "batch", None, "heads", None)
     return att, k_pool, v_pool
 

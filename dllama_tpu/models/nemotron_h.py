@@ -39,6 +39,15 @@ S, hd]`` in prefill, the paged pool afterwards), the mixer layers' float32
 state and convolution tail (a :class:`~dllama_tpu.runtime.kvblocks.StateColumn`'s
 or the :class:`~dllama_tpu.runtime.kvblocks.StatePool`), and the routing
 counters.
+
+**What another family's equation may add** (``models/granite_hybrid.py``
+walks these same blocks two a published layer): ``cfg.mult.residual`` on
+every block's output where it joins the stream, ``cfg.mult.embedding`` on the
+embedded rows and ``cfg.mult.lm_head`` on the logits, the score's scale
+(``cfg.score_dim``, read by ``llama._attend_*``), gated experts and a gated
+shared one (the stack's ``we3`` / ``ws3``), a head that IS the embedding. A
+multiplier of 1 is not traced as a multiply: this family's programs lower to
+the text they lowered to before the other came.
 """
 
 from __future__ import annotations
@@ -93,7 +102,8 @@ _ATTN_MATMULS = ("wq", "wk", "wv", "wo")
 class NemotronHLayers(NamedTuple):
     """``Params.layers``: the two mixer stacks and the ``E`` layers' leaves,
     named as ``models/share.py`` reads them (two planes an expert: no
-    ``we3``, no ``ws3``)."""
+    ``we3``, no ``ws3``; three where the experts are gated,
+    models/granite_hybrid.py)."""
 
     mixer: MixerParams
     attn: AttnParams
@@ -108,8 +118,8 @@ class NemotronHLayers(NamedTuple):
                                  # behind ``hidden_dim``
     ws1: Weight | None           # [NE, shared, dim]
     ws2: Weight | None           # [NE, dim, shared]
-    we3: None = None
-    ws3: None = None
+    we3: Weight | None = None    # [NE, held, latent, wide]: gated experts'
+    ws3: Weight | None = None    # [NE, shared, dim]: a gated shared one's
 
 
 def stack_indices(pattern: tuple[str, ...] | str) -> np.ndarray:  # dlint: static-fn
@@ -222,12 +232,16 @@ def _run_layers(params: Params, cfg: ModelConfig, x, caches, stats, live,
     v_c)`` attention layer ``i``'s cache."""
     lp: NemotronHLayers = params.layers
     eps = cfg.norm_epsilon
+    r = cfg.mult.residual
+    # a block's output joins the stream under the residual multiplier; 1 is
+    # not traced
+    scaled = (lambda y: y) if r == 1.0 else (lambda y: y * r)
 
     def mixer_block(carry, i):
         x, (k_c, v_c, s, conv), stats = carry
         mp = _stack_at(lp.mixer, i, _MIXER_MATMULS)
         y, s, conv = mixer(rms_norm(x, mp.norm, eps), mp, i, s, conv)
-        return x + y.astype(x.dtype), (k_c, v_c, s, conv), stats
+        return x + scaled(y).astype(x.dtype), (k_c, v_c, s, conv), stats
 
     def attn_block(carry, i):
         x, (k_c, v_c, s, conv), stats = carry
@@ -238,21 +252,33 @@ def _run_layers(params: Params, cfg: ModelConfig, x, caches, stats, live,
             out, box["k"], box["v"] = attend(q, k, v, k_c, v_c, i)
             return out
 
-        x = x + _attn_block(cfg, rms_norm(x, ap.norm, eps), ap, att)
+        x = x + scaled(_attn_block(cfg, rms_norm(x, ap.norm, eps), ap, att))
         return x, (box["k"], box["v"], s, conv), stats
 
     def moe_block(carry, i):
         x, caches, stats = carry
         y, st = routed_ffn(cfg, rms_norm(x, _at(lp.norm_moe, i), eps), lp, i,
                            live)
-        return x + y, caches, stats + st
+        return x + scaled(y), caches, stats + st
 
     x, caches, stats = _walk(cfg, (x, caches, stats),
                              {"M": mixer_block, "*": attn_block,
                               "E": moe_block})
     x = rms_norm(x, params.final_norm, eps)
     logits = linear(x, params.logits, out_axis="vocab").astype(jnp.float32)
+    if cfg.mult.lm_head != 1.0:
+        logits = logits * cfg.mult.lm_head
     return logits, caches, stats
+
+
+def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
+    """The embedded rows at the compute dtype, under the embedding's
+    multiplier where the equation has one (float32 in between, as
+    models/falcon_h1.py has it)."""
+    x = params.embedding[tokens]
+    if cfg.mult.embedding != 1.0:
+        x = x.astype(jnp.float32) * cfg.mult.embedding
+    return x.astype(cfg.compute_dtype)
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -273,7 +299,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     B, T = tokens.shape
     n_valid = jnp.asarray(T if n_valid is None else n_valid, jnp.int32)
     live = jnp.tile(jnp.arange(T) < n_valid, B)
-    x = params.embedding[tokens].astype(cfg.compute_dtype)
+    x = _embed(params, cfg, tokens)
     positions = jnp.broadcast_to(
         start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
 
@@ -318,7 +344,7 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     live = tables[:, 0] != 0
     rows = jnp.where(live, jnp.arange(1, B + 1, dtype=jnp.int32),
                      StatePool.NULL)
-    x = params.embedding[tokens].astype(cfg.compute_dtype)
+    x = _embed(params, cfg, tokens)
 
     def mixer(u, mp, i, s, conv):
         return mixer_step(cfg, u, mp, i, rows, s, conv)
@@ -334,9 +360,11 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                     totals.at[0].add(stats))
 
 
-def _load_params(ld, cfg: ModelConfig) -> Params:
+def _load_params(ld, cfg: ModelConfig, gated: bool = False) -> Params:
     """From the tensors ``mfile._walk_nemotron_h_layer`` names: three stacks
-    by layer kind, each over its own layers of the pattern."""
+    by layer kind, each over its own layers of the pattern. ``gated``: the
+    experts and the shared one have a third plane (``we3`` / ``ws3``,
+    models/granite_hybrid.py)."""
     require_quantized(ld)
     h = ld.h
     m_ids, a_ids, e_ids = (h.pattern_layers(kind) for kind in "M*E")
@@ -394,7 +422,11 @@ def _load_params(ld, cfg: ModelConfig) -> Params:
         we1=experts("block_expert_w1", h.hidden_dim, lat, -1),
         we2=experts("block_expert_w2", lat, h.hidden_dim, -2),
         ws1=mm(e_ids, "block_shared_w1", wide, h.dim) if wide else None,
-        ws2=mm(e_ids, "block_shared_w2", h.dim, wide) if wide else None))
+        ws2=mm(e_ids, "block_shared_w2", h.dim, wide) if wide else None,
+        we3=experts("block_expert_w3", h.hidden_dim, lat, -1) if gated
+        else None,
+        ws3=(mm(e_ids, "block_shared_w3", wide, h.dim) if gated and wide
+             else None)))
 
 
 def _matmul_weight_count(cfg: ModelConfig) -> int:
@@ -413,10 +445,16 @@ def _matmul_weight_count(cfg: ModelConfig) -> int:
             + cfg.n_moe_layers * routed + cfg.dim * cfg.vocab_size)
 
 
-def _describe(cfg: ModelConfig, engine) -> str:
+def pattern_words(cfg: ModelConfig) -> str:  # dlint: static-fn
+    """The pattern and how the walk cuts it, for a start-up line:
+    ``EMEM*EMEM* = 2 x [(EM)x2 *]``."""
     period, repeats = fold_runs(pattern_runs(cfg.layer_pattern))
     runs = " ".join(unit if n == 1 else f"({unit})x{n}" for unit, n in period)
-    return (f"; layers: {''.join(cfg.layer_pattern)} = {repeats} x [{runs}]"
+    return f"{''.join(cfg.layer_pattern)} = {repeats} x [{runs}]"
+
+
+def _describe(cfg: ModelConfig, engine) -> str:
+    return (f"; layers: {pattern_words(cfg)}"
             f": {cfg.n_state_layers} SSD mixers ({cfg.ssm_heads} heads of "
             f"{cfg.ssm_head_dim} in {cfg.ssm_groups} groups, state "
             f"{cfg.ssm_state_dim}), {cfg.n_kv_layers} attention without "

@@ -6,8 +6,9 @@ halves.
 and takes ``n_active_experts``; the planes hold ``n_experts`` of them, from
 ``moe_first_expert``. A (row, expert) pair whose expert is not held, or whose
 row is dead or padding, is not computed: both forms sort the pairs by held
-expert, the absent ones last. The decode form (:func:`_sorted_pairs`)
-compacts the held ones to the front
+expert, the absent ones last. Which form a dispatch takes is
+:func:`step_form`'s: static, from the configuration and the rows. The decode
+form (:func:`_sorted_pairs`) compacts the held ones to the front
 and :func:`~dllama_tpu.ops.expert_gemv.expert_gemv` loops over those alone,
 a plane a PAIR; the chunk form (:func:`_experts_chunk`) runs each of the
 three projections as one grouped kernel,
@@ -69,13 +70,30 @@ from .config import ModelConfig
 from .llama import _hidden_act
 
 _HIGHEST = jax.lax.Precision.HIGHEST
-# rows up to which a dispatch takes the decode form (a plane a PAIR); wider
-# ones take the chunk form (a plane a RUN of pairs): the dense kernels' own
-# boundary between their decode and chunk regimes
+# rows up to which a dispatch MAY take the decode form (a plane a PAIR);
+# wider ones take the chunk form (a plane a RUN of pairs): the dense kernels'
+# own boundary between their decode and chunk regimes
 STEP_FORM_MAX_ROWS = FUSED_MAX_M
 # entries of ``stats`` in front of the tokens a held expert: held pairs,
 # absent pairs, rows fed, planes
 N_COUNTS = 4
+
+
+def step_form(cfg: ModelConfig, rows: int) -> bool:  # dlint: static-fn
+    """Whether a dispatch of ``rows`` takes the decode form (a plane a PAIR,
+    ``expert_gemv``) and not the chunk form (a plane a RUN of pairs,
+    ``expert_chunk``). Static, from the configuration and the program's rows:
+    up to :data:`STEP_FORM_MAX_ROWS`, and only while the pairs the rows choose
+    do not outnumber the experts they choose among (``rows n_active <=
+    moe_router_width``; with a share held both sides scale by it). Past that
+    a plane is chosen more than once on average and the pair form reads it
+    once a PAIR: 13 rows at ten of 72 are 130 pairs over some 62 planes, 13 GB
+    a step over ten layers where the run form reads 6.2 (PERF.md, PR 54, has
+    both measured). Sixteen rows at ten of 256 (laguna), eight of 192
+    (A.X-K1), four of 64 (lfm2) and 22 of 512 (nemotron_h) stay on the pair
+    form."""
+    return (rows <= STEP_FORM_MAX_ROWS
+            and rows * cfg.n_active_experts <= cfg.moe_router_width)
 
 
 def _plane(w: Weight, l) -> Weight:
@@ -371,7 +389,7 @@ def routed_ffn(cfg: ModelConfig, h: jax.Array, lp, m,
     # a latent (cfg.moe_latent_dim) is the stack's two projection leaves
     lat_in = getattr(lp, "w_lat_in", None)
     z = x if lat_in is None else linear(x, _plane(lat_in, m))
-    if B * T <= STEP_FORM_MAX_ROWS:
+    if step_form(cfg, B * T):
         y = _experts_step(cfg, z, local, weights, m, lp)
     else:
         y, fed = _experts_chunk(cfg, z, local, weights, m, lp)

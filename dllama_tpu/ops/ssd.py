@@ -201,7 +201,14 @@ def ssd_chunk(x, dt, A, Bm, Cm, S0, chunk: int):
 
     Every exponent is a difference that is <= 0. The heads of a group share
     ``C B^T``; nothing is repeated per head. No loop over tokens: one scan
-    over the ``T / C`` sub-chunks carries the state."""
+    over the ``T / C`` sub-chunks carries the state. The whole form is XLA
+    under the named scope ``ssd_chunk`` (as ``ops/mla.py`` names its XLA
+    form), so a device trace can tell its fusions from a program's others."""
+    with jax.named_scope("ssd_chunk"):
+        return _ssd_chunk(x, dt, A, Bm, Cm, S0, chunk)
+
+
+def _ssd_chunk(x, dt, A, Bm, Cm, S0, chunk: int):
     B, T, H, P = x.shape
     G, N = Bm.shape[2:]
     K = H // G

@@ -76,10 +76,12 @@ def estimate_device_bytes(cfg, *, weight_repr: str, kv_dtype_bytes: int,
     emb_elem = np.dtype(getattr(cfg, "compute_dtype", "float32") or
                         "float32").itemsize
     emb_bytes = cfg.vocab_size * cfg.dim * emb_elem
-    if wbytes < 2.0:
+    if wbytes < 2.0 and not getattr(cfg, "tied_embeddings", False):
         # fast configs load the logits head as resident dense bf16
         # (runtime.weights.dense_logits_wanted); charge the delta so the
-        # budget check sees the real footprint
+        # budget check sees the real footprint. A tied head is the
+        # embedding's own buffer, counted once above (its family's
+        # ``matmul_weight_count`` leaves the head out)
         from .weights import dense_logits_resolved
 
         if dense_logits_resolved(getattr(cfg, "compute_dtype", "")):

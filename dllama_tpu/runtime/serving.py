@@ -2916,8 +2916,10 @@ class PagedGenerator(_GeneratorCore):
         is how often the decode kernel, a plane a pair, reads a plane,
         ``moe_plane_slots`` the routed layers times the held experts added a
         step: planes over them is the share of its held planes a step
-        fetched, ``moe_chunk_held`` / ``moe_chunk_fed`` the chunks' own
-        pairs and the rows they fed, ``wblocks_allocated`` /
+        fetched, ``moe_chunk_held`` / ``moe_chunk_fed`` /
+        ``moe_chunk_planes`` the chunks' own pairs, the rows they fed and
+        the distinct held experts their layers chose (what a prefill
+        chunk's grouped kernel fetched), ``wblocks_allocated`` /
         ``wblocks_returned``)."""
         delta = (totals.astype(np.int64) - self._moe_seen) % (1 << 32)
         self._moe_seen = totals.astype(np.int64)
@@ -2950,6 +2952,7 @@ class PagedGenerator(_GeneratorCore):
                      moe_plane_slots=self._moe_plane_slots,
                      moe_chunk_held=int(self._moe_seen[1, 0]),
                      moe_chunk_fed=int(self._moe_seen[1, 2]),
+                     moe_chunk_planes=int(self._moe_seen[1, 3]),
                      wblocks_allocated=int(self._m_wblocks_alloc.total()),
                      wblocks_returned=int(self._m_wblocks_returned.total()))
 

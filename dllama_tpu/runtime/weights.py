@@ -408,17 +408,22 @@ class StreamingLoader:
         from ..models.llama import Params
 
         h = self.h
+        # the embedding is only ever read as
+        # ``embedding[tokens].astype(compute_dtype)`` (models.llama.forward),
+        # so storing it AT compute dtype is bit-identical (same rounding of
+        # the same values) and, for bf16 configs, halves its HBM footprint
+        # (~1 GB on the 8B shape)
+        embedding = self.f32("embedding", h.vocab_size, h.dim,
+                             dtype=jnp.dtype(self.cfg.compute_dtype))
         return Params(
-            # the embedding is only ever read as
-            # ``embedding[tokens].astype(compute_dtype)`` (models.llama.forward),
-            # so storing it AT compute dtype is bit-identical (same rounding of
-            # the same values) and, for bf16 configs, halves its HBM footprint
-            # (~1 GB on the 8B shape)
-            embedding=self.f32("embedding", h.vocab_size, h.dim,
-                               dtype=jnp.dtype(self.cfg.compute_dtype)),
+            embedding=embedding,
             layers=layers,
             final_norm=self.f32("final_norm", h.dim),
-            logits=self.matmul(
+            # a tied head IS the embedding, ``[vocab, dim]`` as a dense
+            # weight lies: ONE device buffer, and the file's
+            # ``final_matmul_logits`` (the reference format carries it) is
+            # not read
+            logits=embedding if h.tied_embeddings else self.matmul(
                 "final_matmul_logits", h.vocab_size, h.dim, stacked=False,
                 out_axis="vocab", in_axis=None,
                 force_dense=(jnp.bfloat16

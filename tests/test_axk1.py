@@ -541,7 +541,8 @@ def test_a_chunk_of_the_windowed_decoder_takes_the_same_form(monkeypatch):
     monkeypatch.setattr(share, "_experts_chunk", lambda cfg, x, *a: seen.append(("chunk", x.shape[0])) or (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
     monkeypatch.setattr(share, "_experts_step", lambda cfg, x, *a: seen.append(("step", x.shape[0])) or jnp.zeros(x.shape, jnp.float32))
     cfg = types.SimpleNamespace(n_experts=4, moe_first_expert=0, n_active_experts=2, moe_n_group=0, moe_score="softmax",
-                                moe_norm_topk=True, moe_routed_scale=1.0, moe_norm_eps=0.0)
+                                moe_norm_topk=True, moe_routed_scale=1.0, moe_norm_eps=0.0,
+                                moe_router_width=192)     # ``share.step_form`` (PR 54): 16 rows x 2 do not outnumber 192
     lp = types.SimpleNamespace(moe_gate=jnp.ones((1, 8, 16), jnp.float32), ws1=None)
     for rows in (FUSED_MAX_M, FUSED_MAX_M + 1, 256):
         share.routed_ffn(cfg, jnp.ones((1, rows, 16), jnp.float32), lp, jnp.int32(0), jnp.ones((rows,), bool))

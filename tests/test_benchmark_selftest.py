@@ -16,6 +16,7 @@ here instead, from a file of that cell's own PR (PR 30's is
 """
 
 import importlib
+import importlib.util
 import json
 import os
 import sys
@@ -30,6 +31,7 @@ PR36_CELL = "falcon-h1-34b.chat"
 PR42_CELL = "a.x-k1.agent-sessions"
 PR44_CELL = "lfm2-24b-a2b.batch-generate"
 PR51_CELL = "nemotron-3-super-120b-a12b.reasoning"
+PR54_CELL = "granite-4.0-h-small.doc-qa"
 
 sys.path.insert(0, SELFTEST)
 try:
@@ -280,9 +282,9 @@ def test_the_pattern_cell_gets_the_modules_it_names_and_the_lists_it_was_appende
     assert os.path.basename(traffic_path) == "reasoning-nemotron-3-super.json"
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json"), encoding="utf-8") as f:
         manifest = json.load(f)
-    assert len(manifest["workloads"]) == 11 and len(manifest["configs"]) == 8 and manifest["workloads"][-1]["name"] == PR51_CELL
+    assert len(manifest["workloads"]) >= 11 and len(manifest["configs"]) >= 8 and manifest["workloads"][10]["name"] == PR51_CELL
     mine = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [PR51_CELL]]
-    assert mine == ["moe_planes_fetched_share"] and manifest["per_layer"][-1]["name"] == mine[0]
+    assert mine == ["moe_planes_fetched_share"]
     with open(os.path.join(BENCH, "layer_metrics", mine[0] + ".json"), encoding="utf-8") as f:
         spec = json.load(f)
     assert spec == {"reader": "slice_counters", "args": {"what": "ratio", "over": ["moe_planes"],
@@ -290,7 +292,77 @@ def test_the_pattern_cell_gets_the_modules_it_names_and_the_lists_it_was_appende
     # appended to every list lfm2's cell stands in (its judged tail apart: the ladder's) and to falcon's two
     lists = {m["name"]: m["workloads"] for s in ("end_to_end", "per_layer") for m in manifest[s] if "workloads" in m}
     beside_lfm2 = {n for n, w in lists.items() if PR44_CELL in w} - {"itl_p90_ms"}
-    assert all(lists[n][-1] == PR51_CELL for n in beside_lfm2 | {"ssd_step_share", "ssd_step_hbm_share"})
+    assert all(lists[n][lists[n].index(PR44_CELL) + 1 if PR44_CELL in lists[n] else 1] == PR51_CELL
+               for n in beside_lfm2 | {"ssd_step_share", "ssd_step_hbm_share"})
     assert sum(PR51_CELL in lists[n] for n in ("itl_p88_ms", "itl_p90_ms", "itl_p95_ms")) == 1      # ONE judged tail
     assert not {"gated_delta_step_share", "mla_step_share", "expert_gemv_share", "window_blocks_returned_share"} & {
         n for n, w in lists.items() if PR51_CELL in w}
+
+
+# -- and PR 54's cell, from a file of PR 54's own -----------------------------------
+
+with open(os.path.join(BENCH, "granite_hybrid", "selftest", "counts_frozen.json"), encoding="utf-8") as _f:
+    PR54_FROZEN = json.load(_f)
+
+
+def test_pr54s_cell_counts_through_the_seam_are_what_pr54_froze():
+    _cell, conf, _traffic, mods = _seam._resolve(PR54_CELL)
+    rows = [r for r in PR54_FROZEN["rows"] if r["cell"] == PR54_CELL]
+    assert len(rows) == 18
+    for r in rows:
+        assert getattr(mods["counts"], r["fn"])(conf["model"], **r["args"]) == r["value"], r
+
+
+def test_the_granite_cell_gets_the_modules_it_names_and_the_lists_it_was_appended_to():
+    cell, conf, traffic_path, mods = _seam._resolve(PR54_CELL)
+    assert {k: os.path.relpath(m.__file__, BENCH) for k, m in mods.items()} == conf["modules"] == {
+        "reference": "granite_hybrid/reference.py", "weights": "granite_hybrid/weights.py",
+        "counts": "granite_hybrid/counts.py"}
+    assert {"none", "shift", "droplayer", "dropblock", "noresmult", "noembmult", "sqrtscale", "nologitscale", "rope",
+            "bf16state", "bf16router", "softmaxall", "misroute", "noshared", "dropstate", "secondhalf"} == set(
+        mods["reference"].CONTROLS)
+    counts = mods["counts"]
+    assert counts.kernel_counts(conf["model"], "ssd_step", rows=16)["calls_per_program"] == 9
+    assert counts.kernel_counts(conf["model"], "expert_chunk", rows=16)["layers"] == 10
+    assert counts.kernel_counts(conf["model"], "paged_ragged_attention", rows=16)["calls_per_program"] == 1
+    assert (cell["chips"], cell["traffic"], len(cell["why"]) <= 200) == (1, "doc-qa-granite-4.0-h-small", True)
+    assert os.path.basename(traffic_path) == "doc-qa-granite-4.0-h-small.json"
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json"), encoding="utf-8") as f:
+        manifest = json.load(f)
+    assert len(manifest["workloads"]) >= 12 and len(manifest["configs"]) >= 9 and manifest["workloads"][11]["name"] == PR54_CELL
+    assert manifest["configs"][8]["reduced"] == ["num_hidden_layers", "layer_types", "max_position_embeddings"]
+    mine = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [PR54_CELL]]
+    assert mine == ["prefill_xla_share", "expert_chunk_prefill_share", "expert_chunk_prefill_hbm_share"]
+    assert all(m["moves"] == "itl_mean_ms" and m["unit"] == "%" for m in manifest["per_layer"] if m["name"] in mine)
+    specs = {}
+    for name in mine:
+        with open(os.path.join(BENCH, "layer_metrics", name + ".json"), encoding="utf-8") as f:
+            specs[name] = json.load(f)
+    assert specs == {
+        "prefill_xla_share": {"reader": "program_xla_share", "args": {"program": "forward"}},
+        "expert_chunk_prefill_share": {"reader": "kernel_roofline",
+                                       "args": {"kernel": "expert_chunk", "program": "forward", "share": "time"}},
+        "expert_chunk_prefill_hbm_share": {"reader": "expert_planes_span_roofline",
+                                           "args": {"kernel": "expert_chunk", "program": "forward",
+                                                    "field": "moe_chunk_planes"}}}
+    # appended behind PR 51's cell in every list that cell stands in but the one metric that is its own
+    lists = {m["name"]: m["workloads"] for s in ("end_to_end", "per_layer") for m in manifest[s] if "workloads" in m}
+    beside_pr51 = {n for n, w in lists.items() if PR51_CELL in w} - {"moe_planes_fetched_share", "itl_p88_ms",
+                                                                      "itl_p90_ms", "itl_p95_ms"}
+    assert beside_pr51 and all(lists[n][lists[n].index(PR51_CELL) + 1] == PR54_CELL for n in beside_pr51)
+    assert sum(PR54_CELL in lists[n] for n in ("itl_p88_ms", "itl_p90_ms", "itl_p95_ms")) == 1      # ONE judged tail
+    assert not {"gated_delta_step_share", "mla_step_share", "expert_gemv_share", "window_blocks_returned_share",
+                "moe_planes_fetched_share"} & {n for n, w in lists.items() if PR54_CELL in w}
+
+
+def test_the_xla_share_reader_counts_what_is_not_a_custom_call():
+    spec = importlib.util.spec_from_file_location("program_xla_share", os.path.join(BENCH, "readers", "program_xla_share.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    trace = {"device_ops": [["forward/fusion.12 fusion", 3.0], ["forward/expert_chunk.9 custom-call", 5.0],
+                            ["forward/copy.3 copy", 1.0], ["paged_sampled_step_guarded/fusion.12 fusion", 40.0],
+                            ["forward_and_step/fusion.1 fusion", 7.0]],
+             "modules": {"jit_forward(123)": [4.0, 6.0], "jit_paged_sampled_step_guarded(9)": [50.0]}}
+    assert reader.read({"trace": trace}, program="forward") == 100.0 * 4.0 / 10.0
+    assert reader.read({"trace": None}, program="forward") is None
+    assert reader.read({"trace": {"device_ops": [], "modules": {}}}, program="forward") is None

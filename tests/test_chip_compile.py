@@ -766,6 +766,177 @@ def test_quant_matmul_compiles_at_nemotrons_planes_for_v5e(one_chip, k, n, rows)
     assert kernels.get("quant_matmul") == 1, kernels
 
 
+# -- granite-4.0-h-small at its published widths (PR 54) ----------------------------------
+# the fused / chunk GEMV's planes: the mixer's 16,640-wide in-projection and its
+# out-projection, the gated shared expert at 1536, q / o (4096) and k / v (1024)
+GRANITE_STEP = [(4096, 16640), (8192, 4096), (4096, 1536), (1536, 4096), (4096, 4096), (4096, 1024)]
+
+
+def test_ssd_step_compiles_at_one_group_for_v5e(one_chip):
+    """The SSD step form's kernel at ``[9, 17, 128, 64, 128]``: nine mixer
+    layers held, 128 heads of 64 on ONE group of B and C (the grid is slots x
+    head groups of 8: all sixteen read the same group), 16 slots and the null
+    row, the pool in place."""
+    from dllama_tpu.ops import ssd
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+
+    slots, H, P, G, N = 16, 128, 64, 1, 128
+    f32 = jnp.float32
+    pool = _shape(one_chip, (9, slots + 1, H, P, N), f32)
+    compiled = jax.jit(functools.partial(ssd.ssd_step, interpret=False),
+                       donate_argnums=(0,)).lower(
+        pool, _shape(one_chip, (), jnp.int32), _shape(one_chip, (slots,), jnp.int32),
+        _shape(one_chip, (slots, H, P), f32), _shape(one_chip, (slots, H), f32),
+        _shape(one_chip, (slots, H), f32), _shape(one_chip, (slots, G, N), f32),
+        _shape(one_chip, (slots, G, N), f32)).compile()
+    assert mosaic_kernels(compiled.as_text()).get("ssd_step") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("k,n", [(4096, 768), (768, 4096)])
+def test_expert_gemv_compiles_at_granites_experts_for_v5e(one_chip, k, n):
+    """The pair form at an expert's planes in the model's width, 160 pairs (16
+    rows x 10 a token) over 72 held of 10 routed blocks: what ``share.step_form``
+    leaves to 7 rows and fewer, and what the builder's wrapper forced to 16 for
+    the comparison PERF.md has."""
+    from dllama_tpu.ops import expert_gemv as eg
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    assert eg.supports(160, k, n, True)
+    stack = QuantizedWeight(scales=_shape(one_chip, (10, 72, k // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (10, 72, k, n), jnp.int8))
+    kernels = _compiled_kernels(
+        lambda x, st, layer, experts, n_pairs: eg.expert_gemv(x, st, layer, experts, n_pairs, fast=True),
+        _shape(one_chip, (160, k), jnp.bfloat16), stack, _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (160,), jnp.int32), _shape(one_chip, (), jnp.int32))
+    assert any("expert_gemv" in name for name in kernels), kernels
+
+
+@pytest.mark.parametrize("kk,n,rows,scatter", [(4096, 768, 16, False), (768, 4096, 16, True),
+                                               (4096, 768, 256, False), (768, 4096, 256, True)])
+def test_expert_chunk_compiles_at_granites_experts_for_v5e(one_chip, kk, n, rows, scatter):
+    """The run form at the same planes, ten a token over 72 held of 72: the
+    16-row STEP's (160 pairs over up to 72 runs: ``share.step_form``) and a
+    256-row prefill chunk's, which the kernel takes WHOLE (``stripe(...) is not
+    None``: no pieces, a plane fetched once a chunk)."""
+    from dllama_tpu.ops import expert_chunk as ec
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    held, k = 72, 10
+    pairs = rows * k
+    fed = ec.fed_rows(pairs, held)
+    assert ec.stripe(rows, fed, kk, n, True, scatter) is not None
+    stack = QuantizedWeight(scales=_shape(one_chip, (10, held, kk // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (10, held, kk, n), jnp.int8))
+    i32 = lambda *shape: _shape(one_chip, shape, jnp.int32)
+    runs = (i32(),) + tuple(i32(held) for _ in range(4))
+    if scatter:
+        kernels = _compiled_kernels(
+            lambda x, st, layer, runs, r, at, w: ec.expert_chunk(x, st, layer, runs, r, (at, w), rows_out=rows, fast=True),
+            _shape(one_chip, (fed, kk), jnp.bfloat16), stack, i32(), runs, i32(pairs), i32(pairs), _shape(one_chip, (rows, k), jnp.float32))
+    else:
+        kernels = _compiled_kernels(
+            lambda x, st, layer, runs, r: ec.expert_chunk(x, st, layer, runs, r, rows_out=fed, fast=True),
+            _shape(one_chip, (rows, kk), jnp.bfloat16), stack, i32(), runs, i32(pairs))
+    assert any("expert_chunk" in name for name in kernels), kernels
+
+
+def test_paged_attention_compiles_at_8704_tokens_a_slot_for_v5e(one_chip):
+    """The step's walk: 16 slots of 8,704 in blocks of 16 (544 table entries a
+    row), 32:8 heads of 128, ONE attention layer, no rotary table anywhere."""
+    heads, group = _compile_paged_attention(one_chip, 16, 1, 32, 8, 128, 8704 // 16, 16, jnp.bfloat16)
+    assert heads == 8 and group >= 1
+
+
+@pytest.mark.parametrize("rows", [16, 256])
+@pytest.mark.parametrize("k,n", GRANITE_STEP)
+def test_quant_matmul_compiles_at_granites_planes_for_v5e(one_chip, k, n, rows):
+    """The dense Q40 planes of the mixer, the attention layer and the shared
+    expert, stack + index: the fused GEMV at the step's 16 rows, the chunk
+    regime at a prefill chunk's 256: one Mosaic kernel each."""
+    from dllama_tpu.ops.linear import QuantizedWeight
+    from dllama_tpu.ops.quant_matmul import fused_path, quant_matmul
+
+    stack = QuantizedWeight(scales=_shape(one_chip, (10, k // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (10, k, n), jnp.int8))
+    one = QuantizedWeight(*(jax.ShapeDtypeStruct(p.shape[1:], p.dtype) for p in stack))
+    x = _shape(one_chip, (1, rows, k), jnp.bfloat16)
+    assert fused_path(x.shape, one, True) == ("fused" if rows <= 16 else "chunk")
+    kernels = _compiled_kernels(
+        lambda x, w, l: quant_matmul(x, w, interpret=False, fast=True, fused=True, layer=l),
+        x, stack, _shape(one_chip, (), jnp.int32))
+    assert kernels.get("quant_matmul") == 1, kernels
+
+
+@pytest.mark.parametrize("program", ["step", "forward"])
+def test_granites_two_programs_compile_at_the_cells_size_for_v5e(one_chip, program, monkeypatch):
+    """``paged_sampled_step_guarded`` over 16 rows (pools of 16 x 8,704 tokens,
+    donated) and ``forward`` over a 256-token chunk into an 8,704-token column,
+    from the cell's own configuration and the benchmark's shapes: every Q40
+    plane a kernel, the step's routed blocks the RUN form (``share.step_form``:
+    160 pairs over 72 planes), the mixers' step form ``ssd_step``, the walk
+    ``paged_ragged_attention``; the chunk's mixers XLA under the ``ssd_chunk``
+    scope, which is in the instructions' metadata and NOT in their names (why
+    the cell reads ``prefill_xla_share`` and not a kernel's share). The head is
+    the embedding: the programs are handed ONE array for both."""
+    import importlib.util
+    import struct
+
+    from dllama_tpu.formats import mfile
+    from dllama_tpu.models import llama
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.models.share import zero_totals
+    from dllama_tpu.ops import quant_matmul
+    from dllama_tpu.parallel import api
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+    from dllama_tpu.runtime.kvblocks import PagedKVCache, StateColumn, StatePool
+
+    bench = os.path.join(REPO, "benchmark")
+    sys.path.insert(0, bench)
+    import run as bench_run
+
+    spec = importlib.util.spec_from_file_location("granite_hybrid_weights_for_compile",
+                                                  os.path.join(bench, "granite_hybrid", "weights.py"))
+    weights = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(weights)
+    with open(os.path.join(bench, "configs", "granite-4.0-h-small.json"), encoding="utf-8") as f:
+        conf = json.load(f)
+    data = b"".join(struct.pack("<ii", k, int(v)) for k, v in weights.header_fields(bench_run.model_view(conf)))
+    header = mfile.parse_header(struct.pack("<ii", mfile.MODEL_MAGIC, 8 + len(data)) + data, 0,
+                                max_seq_len=conf["engine"]["max_seq_len"])
+    cfg = ModelConfig.from_header(header, "bfloat16")
+    for module in (quant_matmul, llama, api):          # the gates ask jax.default_backend(), which is the CPU here
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    on_chip = lambda tree: jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(weights.params_builder(cfg, None)[0], jax.random.PRNGKey(0)))
+    params = params._replace(logits=params.embedding)
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    slots, seq, block = conf["engine"]["slots"], cfg.seq_len, conf["engine"]["kv_block_size"]
+    if program == "step":
+        cache = (on_chip(jax.eval_shape(lambda: PagedKVCache.create(cfg, slots * seq // block + 1, block, dtype=bf16))),
+                 on_chip(jax.eval_shape(lambda: StatePool.create(cfg, slots, bf16))),
+                 on_chip(jax.eval_shape(lambda: zero_totals(cfg))))
+        rows = lambda dtype, *tail: _shape(one_chip, (slots, *tail), dtype)
+        compiled = jax.jit(llama.paged_sampled_step_guarded, static_argnums=1, donate_argnums=(4,)).lower(
+            params, cfg, rows(i32, 1), rows(i32), cache, rows(i32, seq // block), rows(f32), rows(f32), rows(f32),
+            _shape(one_chip, (), f32)).compile()
+        assert mosaic_kernels(compiled.as_text()) == {"quant_matmul": 17, "expert_chunk": 9, "ssd_step": 2,
+                                                      "paged_ragged_attention": 1}
+        # the pools are written in place: no second state pool (0.65 GB) or K/V pool (0.57 GB) among the temporaries
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+        return
+    kv = lambda: jnp.zeros((cfg.n_kv_layers, 1, cfg.n_kv_heads, seq, cfg.cache_width), bf16)
+    column = on_chip(jax.eval_shape(lambda: StateColumn.zeros(cfg, kv(), kv(), bf16)))
+    compiled = jax.jit(llama.forward, static_argnums=1, donate_argnums=(4,)).lower(
+        params, cfg, _shape(one_chip, (1, 256), i32), _shape(one_chip, (), i32), column,
+        _shape(one_chip, (), i32)).compile()
+    text = compiled.as_text()
+    kernels = mosaic_kernels(text)
+    assert kernels.get("quant_matmul") == 17 and kernels.get("expert_chunk") == 9 and "ssd_step" not in kernels
+    scoped = [line for line in text.splitlines() if "ssd_chunk/" in line]
+    assert len(scoped) > 50 and not any(line.split(" = ")[0].count("ssd_chunk") for line in scoped)
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_chip_smoke_rehearsal_on_cpu(chips):
     """The script end to end at a toy size with JAX_PLATFORMS=cpu children

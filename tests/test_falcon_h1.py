@@ -554,7 +554,7 @@ def test_header_round_trip_and_walk(bench, tmp_path):
     assert cfg.n_kv_layers == cfg.n_state_layers == cfg.n_layers == 4 and cfg.kv_mul == 5
     assert (cfg.ssm_inner_dim, cfg.ssm_conv_dim, cfg.ssm_in_dim) == (64, 128, 192)
     assert cfg.state_shape(3) == (4, 3, 4, 16, 16) and cfg.conv_shape(3) == (4, 3, 3, 128)
-    assert len(cfg.mult) == 14 and cfg.mult.embedding == f32(5.656854249492381) and hash(cfg) is not None
+    assert len(cfg.mult) == 15 and cfg.mult.residual == 1.0 and cfg.mult.embedding == f32(5.656854249492381) and hash(cfg) is not None
     # the writer takes the floats themselves and stores their bits
     path2 = str(tmp_path / "h2.m")
     with open(path2, "wb") as f:

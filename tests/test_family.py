@@ -1,5 +1,5 @@
 """One description a decoder family (``dllama_tpu/models/family.py``): what
-``family_of`` hands back for each of the eight ``ArchType`` values, that
+``family_of`` hands back for each of the nine ``ArchType`` values, that
 ``runtime/`` and ``serve/`` ask IT and name no family themselves, and that the
 three small answers the ladders used to give (the HBM guard's weight count, the
 layer-kind gauges, the start-up line's words) are, for each family's tiny
@@ -42,6 +42,7 @@ TINY = {
     ArchType.AXK1: ("a_x_k1", "tiny-a.x-k1.json"),
     ArchType.LFM2: ("lfm2", "tiny-lfm2.json"),
     ArchType.NEMOTRON_H: ("nemotron_h", "tiny-nemotron-h.json"),
+    ArchType.GRANITE_HYBRID: ("granite_hybrid", "tiny-granite-hybrid.json"),
 }
 ARCHS = list(ArchType)
 
@@ -65,6 +66,13 @@ PARENT = {
                           "; layers: EMEM*EMEM* = 2 x [(EM)x2 *]: 4 SSD mixers (4 heads of 32 in 2 groups, state 16), 2 "
                           "attention without positions (4:2 heads of 16), 4 routed; experts: 8 of 16 held from 4, 4 a "
                           "token, 288 wide (held in 512) in a latent of 32, shared 64, selection bias"),
+    # no parent: PR 54 brought the family; its head is the embedding, so no head among the planes
+    ArchType.GRANITE_HYBRID: (502784, {"mamba": 4, "attention": 2, "moe": 6},
+                              "; blocks: MEME*EMEME*E = 1 x [(ME)x2 * (EM)x2 E * E]: 6 layers of a mixer then experts, 4 "
+                              "SSD mixers (4 heads of 32 in 1 groups, state 16), 2 attention without positions (4:2 "
+                              "heads of 16, scores x 0.0625); experts: 8 of 8 held from 0, 3 a token, gated, 32 wide, "
+                              "shared 64; multipliers: embedding 12, residual 0.22, logits 0.0625; head tied to the "
+                              "embedding (one array)"),
 }
 DENSE_MOE_WEIGHTS = 180736   # tiny_header_params(QWEN3, n_experts=4, n_active_experts=2), the parent's count
 
@@ -139,6 +147,7 @@ TICK = {
     ArchType.AXK1: None,
     ArchType.LFM2: ("lfm2", "forward_and_step"),
     ArchType.NEMOTRON_H: None,
+    ArchType.GRANITE_HYBRID: None,
 }
 
 
@@ -171,10 +180,10 @@ def test_the_dense_equations_share_one_family_and_the_entry_is_llamas(cfgs):
     # the one entry of every family keeps its name (the engine jits it as program ``forward``)
     assert llama.forward.__name__ == "forward" and llama.paged_forward.__name__ == "paged_forward"
     others = {family_of(cfgs[a]) for a in ARCHS if TINY[a] is not None}
-    assert len(others) == 6 and llama.FAMILY not in others
+    assert len(others) == 7 and llama.FAMILY not in others
 
 
-FAMILY_MODULES = {"hybrid", "falcon_h1", "laguna", "axk1", "lfm2", "nemotron_h"}
+FAMILY_MODULES = {"hybrid", "falcon_h1", "laguna", "axk1", "lfm2", "nemotron_h", "granite_hybrid"}
 FAMILY_NAMING = {"is_hybrid", "has_ssm", "has_short_conv"}
 FAMILY_ARCHS = {a.name for a in ARCHS} - {"LLAMA", "QWEN3"}
 
