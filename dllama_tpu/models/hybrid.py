@@ -29,20 +29,32 @@ of :class:`~dllama_tpu.runtime.kvblocks.StatePool` afterwards).
   its STEP form over the state pool in place (the Pallas kernel
   ``gated_delta_step`` on a TPU, its XLA twin elsewhere); rows whose block
   table is all null (inactive slots riding along) use the pool's null row.
+* :func:`forward_and_step` (``FAMILY.tick``, PR 55): a chunk AND the tick's
+  decode rows through one pass over the planes of both stacks, the paged
+  server's program for every plain chunk; no chunk logits.
 
-In both, everything a slot's context is made of rides the period scan's
-CARRY whole: the state and the tail, and the full layers' K/V (a column's
-or the pool's), which period ``p`` writes in place and attends through the
-whole array and ``p`` (:func:`~dllama_tpu.models.llama._attend_paged`). As
-the scan's ``xs``/``ys`` the K/V pool was sliced, stacked and copied back
-every step (PERF.md section 6, PR 33).
+The three are three sets of closures (``mixer``, ``store``, ``attend``) over
+ONE period scan (:func:`_scan_periods`). In all of them everything a slot's
+context is made of rides the scan's CARRY whole: the state and the tail,
+and the full layers' K/V (a column's, the pool's or, in the tick program,
+both), which period ``p`` writes in place and attends through the whole
+array and ``p`` (:func:`~dllama_tpu.models.llama._attend_paged`). As the
+scan's ``xs``/``ys`` the K/V pool was sliced, stacked and copied back every
+step (PERF.md section 6, PR 33).
 
 A linear layer's mixer, for its input ``u``: one packed projection ``[q~ k~
 v~ z] = W_in u``, gates ``[a b] = W_ab u``; a causal depthwise convolution
 of ``K`` taps and SiLU over ``q~ k~ v~``; per head ``q = l2norm(q') /
 sqrt(dk)``, ``k = l2norm(k')``, ``beta = sigmoid(b)`` (doubled where
 ``lin_neg_eigval``), ``alpha = exp(-exp(A_log) softplus(a + dt_bias))``; the
-gated delta rule; ``y = W_out (rmsnorm_dv(o) * silu(z))``.
+gated delta rule; ``y = W_out (rmsnorm_dv(o) * silu(z))``. The mixer is cut
+where its rows stop being independent, as models/ssd_mixer.py is:
+:func:`_mixer_project`, :func:`_mixer_heads` and :func:`_mixer_output` work a
+row at a time (both planes, the gate rows, the norms and gates), the
+convolution (against a tail) and the rule (:func:`_rule_chunk` /
+:func:`_rule_step`, against a state) own a context. :func:`_mixer_chunk` and
+:func:`_mixer_step` put ONE of each between them,
+:func:`_mixer_chunk_and_step` both, over rows joined along ``T``.
 
 The arch implies three conventions (the Olmo 2/3 family's; none is in the
 published config): block norms sit on a sublayer's OUTPUT (``x + norm(f(x))``),
@@ -67,7 +79,8 @@ from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
 from .family import Family, layer_kinds, state_refusal
 from .llama import (LayerParams, Params, _attend_dense, _attend_paged,
-                    _hidden_act, _layer_at, _stack_at)
+                    _exact_f32_dots, _hidden_act, _layer_at, _nonfinite_rows,
+                    _poison_logits, _stack_at)
 
 
 class LinearLayerParams(NamedTuple):
@@ -104,6 +117,15 @@ class HybridLayers(NamedTuple):
 HybridColumn = StateColumn
 
 
+def _at(a: jax.Array, l: jax.Array) -> jax.Array:
+    """Layer ``l`` of a column's leaf ``[L, ...]``."""
+    return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+
+
+def _put(a: jax.Array, a_l: jax.Array, l: jax.Array) -> jax.Array:
+    return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
+
+
 def _sublayer(cfg: ModelConfig, x: jax.Array, norm_w: jax.Array, f):
     """``x + norm(f(x))``: the norm sits on the sublayer's output."""
     return x + rms_norm(f(x), norm_w, cfg.norm_epsilon)
@@ -115,60 +137,125 @@ def _ffn(cfg: ModelConfig, h: jax.Array, lp) -> jax.Array:
                   in_axis="hidden")
 
 
-def _mixer_inputs(cfg: ModelConfig, u: jax.Array, lp: LinearLayerParams,
-                  tail: jax.Array, n_valid):
-    """Everything of the mixer in front of the rule, for ``u [B, T, dim]``
-    and the convolution's ``tail [B, K - 1, C]``: float32 ``q, k [B, T, H,
-    dk]``, ``v [B, T, H, dv]``, ``g`` (log decay) and ``beta [B, T, H]``, the
-    output gate ``z [B, T, H, dv]`` and the new tail."""
-    B, T, _ = u.shape
-    H, dk, dv = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
+def _mixer_project(cfg: ModelConfig, u: jax.Array, lp: LinearLayerParams):
+    """What the mixer does a ROW at a time in front of its convolution, for
+    ``u [B, T, dim]``: ONE read of the packed ``w_in`` for however many rows
+    (``qkv [B, T, lin_conv_dim]`` the convolution's input, ``z [B, T, H
+    dv]`` the output gate) and the float32 gate rows ``ab [B, T, 2 H]``.
+    Rows of different sequences may be joined along ``T``: nothing here
+    looks across them."""
     proj = linear(u, lp.w_in)
     qkv, z = proj[..., :cfg.lin_conv_dim], proj[..., cfg.lin_conv_dim:]
     ab = jnp.einsum("btd,hd->bth", u.astype(jnp.float32), lp.w_ab,
                     precision=jax.lax.Precision.HIGHEST)
-    y, tail = causal_conv(qkv, tail, lp.conv_w, n_valid)
+    return qkv, z, ab
+
+
+def _mixer_heads(cfg: ModelConfig, y: jax.Array, z: jax.Array, ab: jax.Array,
+                 lp: LinearLayerParams):
+    """What the mixer does a row at a time BEHIND its convolution, in front
+    of the rule, from the convolved ``y [B, T, lin_conv_dim]`` and
+    :func:`_mixer_project`'s ``z`` and ``ab``: float32 ``q, k [B, T, H,
+    dk]``, ``v [B, T, H, dv]``, ``g`` (log decay) and ``beta [B, T, H]``, and
+    the output gate ``z [B, T, H, dv]``."""
+    B, T, _ = y.shape
+    H, dk, dv = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
     q = gd.l2norm(y[..., :H * dk].reshape(B, T, H, dk)) * dk ** -0.5
     k = gd.l2norm(y[..., H * dk:2 * H * dk].reshape(B, T, H, dk))
     v = y[..., 2 * H * dk:].reshape(B, T, H, dv)
     g, beta = gd.gates(ab[..., :H], ab[..., H:], lp.a_log, lp.dt_bias,
                        cfg.lin_neg_eigval)
-    return q, k, v, g, beta, z.reshape(B, T, H, dv), tail
+    return q, k, v, g, beta, z.reshape(B, T, H, dv)
 
 
 def _mixer_output(cfg: ModelConfig, o: jax.Array, z: jax.Array,
                   lp: LinearLayerParams, dtype) -> jax.Array:
-    """``W_out (rmsnorm_dv(o) * silu(z))`` from float32 ``o [B, T, H, dv]``."""
+    """``W_out (rmsnorm_dv(o) * silu(z))`` from float32 ``o [B, T, H, dv]``,
+    a row at a time."""
     B, T = o.shape[:2]
     gated = (rms_norm(o, lp.norm_o, cfg.norm_epsilon)
              * jax.nn.silu(z.astype(jnp.float32)))
     return linear(gated.reshape(B, T, -1).astype(dtype), lp.w_out)
 
 
-def _mixer_chunk(cfg, u, lp, s_l, conv_l, n_valid):
-    """The mixer over a chunk: ``s_l [B, H, dk, dv]`` in and out."""
-    T = u.shape[1]
-    q, k, v, g, beta, z, conv_l = _mixer_inputs(cfg, u, lp, conv_l, n_valid)
-    real = (jnp.arange(T) < n_valid)[None, :, None]
+def _rule_chunk(q, k, v, g, beta, s_l, n_valid):
+    """The rule over ONE sequence's chunk against its state ``s_l [B, H, dk,
+    dv]``: positions at or past ``n_valid`` get ``beta = 0, alpha = 1`` and
+    leave it alone. Returns ``o [B, T, H, dv]`` and the state."""
+    real = (jnp.arange(q.shape[1]) < n_valid)[None, :, None]
     note_gdn_path("chunk", "xla")
-    o, s_l = gd.gated_delta_chunk(q, k, v, jnp.where(real, g, 0.0),
-                                  jnp.where(real, beta, 0.0), s_l)
-    return _mixer_output(cfg, o, z, lp, u.dtype), s_l, conv_l
+    return gd.gated_delta_chunk(q, k, v, jnp.where(real, g, 0.0),
+                                jnp.where(real, beta, 0.0), s_l)
 
 
-def _mixer_step(cfg, u, lp, l, rows, s_pool, conv_pool):
-    """The mixer over one token a row, the pools in and out: row ``b``'s
-    state and tail are ``[l, rows[b]]`` of them."""
-    tail = jax.lax.dynamic_index_in_dim(conv_pool, l, 0, keepdims=False)[rows]
-    q, k, v, g, beta, z, tail = _mixer_inputs(cfg, u, lp, tail, None)
-    conv_pool = conv_pool.at[l, rows].set(tail)
+def _rule_step(l, rows, q, k, v, g, beta, s_pool):
+    """The rule over one token a row, ``[B, 1, ...]``, the state pool in
+    place: row ``b``'s state is ``[l, rows[b]]`` of it. Returns ``o [B, 1,
+    H, dv]`` and the pool."""
     kernel = gd.step_kernel_choice()
     note_gdn_path("step", "xla" if kernel is None else "pallas")
     step = (gd.gated_delta_step_xla if kernel is None
             else lambda *a: gd.gated_delta_step(*a, **kernel))
     o, s_pool = step(s_pool, l, rows, q[:, 0], k[:, 0], v[:, 0],
                      jnp.exp(g[:, 0]), beta[:, 0])
-    return _mixer_output(cfg, o[:, None], z, lp, u.dtype), s_pool, conv_pool
+    return o[:, None], s_pool
+
+
+def _mixer_chunk(cfg, u, lp, s_l, conv_l, n_valid):
+    """The mixer over a chunk: ``s_l [B, H, dk, dv]`` and the tail ``conv_l
+    [B, K - 1, C]`` in and out."""
+    qkv, z, ab = _mixer_project(cfg, u, lp)
+    y, conv_l = causal_conv(qkv, conv_l, lp.conv_w, n_valid)
+    q, k, v, g, beta, z = _mixer_heads(cfg, y, z, ab, lp)
+    o, s_l = _rule_chunk(q, k, v, g, beta, s_l, n_valid)
+    return _mixer_output(cfg, o, z, lp, u.dtype), s_l, conv_l
+
+
+def _mixer_step(cfg, u, lp, l, rows, s_pool, conv_pool):
+    """The mixer over one token a row, the pools in and out: row ``b``'s
+    state and tail are ``[l, rows[b]]`` of them."""
+    tail = _at(conv_pool, l)[rows]            # [B, K - 1, C]
+    qkv, z, ab = _mixer_project(cfg, u, lp)
+    y, tail = causal_conv(qkv, tail, lp.conv_w, None)
+    q, k, v, g, beta, z = _mixer_heads(cfg, y, z, ab, lp)
+    conv_pool = conv_pool.at[l, rows].set(tail)
+    o, s_pool = _rule_step(l, rows, q, k, v, g, beta, s_pool)
+    return _mixer_output(cfg, o, z, lp, u.dtype), s_pool, conv_pool
+
+
+def _by_row(a: jax.Array, T: int) -> jax.Array:
+    """The decode rows behind a chunk's ``T``, one a batch row: ``[1, T + R,
+    ...] -> [R, 1, ...]``."""
+    return jnp.swapaxes(a[:, T:], 0, 1)
+
+
+def _join(c: jax.Array, r: jax.Array) -> jax.Array:
+    """:func:`_by_row` undone: ``[1, T, ...]`` and ``[R, 1, ...]`` as ``[1,
+    T + R, ...]``."""
+    return jnp.concatenate([c, jnp.swapaxes(r, 0, 1)], axis=1)
+
+
+def _mixer_chunk_and_step(cfg, u, lp, l, T, n_valid, rows, s, conv):
+    """A chunk and the tick's decode rows through ONE pass over the mixer's
+    planes: ``u [1, T + R, dim]`` is the chunk's ``T`` rows and then one row
+    a slot. What works a row at a time runs once over the joined rows
+    (:func:`_mixer_project`, :func:`_mixer_heads`, :func:`_mixer_output`);
+    the convolution and the rule run a part at a time, the chunk's as
+    :func:`_mixer_chunk` has them, the rows' as :func:`_mixer_step`. ``s``
+    and ``conv`` are each a pair, the column's layer (``s_l``, ``conv_l``)
+    and the pool whole (rows at ``[l, rows[b]]``), and come back so beside
+    ``y [1, T + R, dim]``."""
+    (s_l, s_pool), (conv_l, conv_pool) = s, conv
+    tail = _at(conv_pool, l)[rows]            # [B, K - 1, C]
+    qkv, z, ab = _mixer_project(cfg, u, lp)
+    y_c, conv_l = causal_conv(qkv[:, :T], conv_l, lp.conv_w, n_valid)
+    y_r, tail = causal_conv(_by_row(qkv, T), tail, lp.conv_w, None)
+    conv_pool = conv_pool.at[l, rows].set(tail)
+    *heads, z = _mixer_heads(cfg, _join(y_c, y_r), z, ab, lp)
+    o_c, s_l = _rule_chunk(*(a[:, :T] for a in heads), s_l, n_valid)
+    o_r, s_pool = _rule_step(l, rows, *(_by_row(a, T) for a in heads), s_pool)
+    return (_mixer_output(cfg, _join(o_c, o_r), z, lp, u.dtype),
+            (s_l, s_pool), (conv_l, conv_pool))
 
 
 def _full_qkv(cfg: ModelConfig, h: jax.Array, lp: LayerParams):
@@ -214,16 +301,20 @@ def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 def _scan_periods(params: Params, cfg: ModelConfig, x: jax.Array, s, conv,
                   k, v, mixer, store, attend):
-    """The period scan both programs share: a period's linear layers (a
-    ``fori_loop`` over one traced body), then its full layer. Everything a
-    slot's context is made of rides the CARRY whole, a column's or the
-    pool: ``s, conv`` (every linear layer's state and tail) and ``k, v``
+    """The period scan the three programs share: a period's linear layers (a
+    ``fori_loop`` over one traced body), then its full layer; the hidden
+    rows ``[B, T, dim]`` behind the last period come back, in front of the
+    final norm (:func:`_head`). Everything a slot's context is made of rides
+    the CARRY whole, a column's, the pool or (the tick program) a pair of
+    both: ``s, conv`` (every linear layer's state and tail) and ``k, v``
     (the full layers' cache, indexed by the period ``p``); nothing is
     sliced into the scan or stacked out of it, so the pools are written in
     place. ``mixer(h, lp, l, s, conv) -> (y, s', conv')`` is the form of the
     mixer and ``store(a, a', l)`` puts what it gave back into the carry (a
     column's layer ``l``; the pool comes back whole);
-    ``attend(q, k, v, k_c, v_c, p) -> (att, k_c, v_c)`` owns the cache."""
+    ``attend(q, k, v, k_c, v_c, p) -> (att, k_c, v_c)`` owns the cache.
+    Everything else a layer does it does a row at a time, so the rows along
+    ``T`` need not be one sequence's: only the closures know."""
     per_period = cfg.layer_period - 1
     lin, full = params.layers
 
@@ -257,7 +348,7 @@ def _scan_periods(params: Params, cfg: ModelConfig, x: jax.Array, s, conv,
 
     periods = jnp.arange(cfg.n_periods, dtype=jnp.int32)
     (x, s, conv, k, v), _ = jax.lax.scan(period, (x, s, conv, k, v), periods)
-    return _head(params, cfg, x), s, conv, k, v
+    return x, s, conv, k, v
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -278,24 +369,17 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.broadcast_to(
         start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
 
-    def at(a, l):
-        return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-
     def mixer(h, lp, l, s, conv):
-        return _mixer_chunk(cfg, h, lp, at(s, l), at(conv, l), n_valid)
-
-    def store(a, a_l, l):
-        return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
+        return _mixer_chunk(cfg, h, lp, _at(s, l), _at(conv, l), n_valid)
 
     def attend(q, k, v, k_c, v_c, p):
-        att, k_p, v_p = _attend_dense(cfg, q, k, v, at(k_c, p), at(v_c, p),
+        att, k_p, v_p = _attend_dense(cfg, q, k, v, _at(k_c, p), _at(v_c, p),
                                       start_pos, positions)
-        return att, store(k_c, k_p, p), store(v_c, v_p, p)
+        return att, _put(k_c, k_p, p), _put(v_c, v_p, p)
 
-    logits, s, conv, k, v = _scan_periods(params, cfg, x, col.s, col.conv,
-                                          col.k, col.v, mixer, store,
-                                          attend)
-    return logits, HybridColumn(k=k, v=v, s=s, conv=conv)
+    x, s, conv, k, v = _scan_periods(params, cfg, x, col.s, col.conv,
+                                     col.k, col.v, mixer, _put, attend)
+    return _head(params, cfg, x), HybridColumn(k=k, v=v, s=s, conv=conv)
 
 
 def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -326,10 +410,84 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return _attend_paged(cfg, q, k, v, k_pool, v_pool, p, positions,
                              tables)
 
-    logits, s, conv, k, v = _scan_periods(params, cfg, x, pool.s, pool.conv,
-                                          pkv.k, pkv.v, mixer,
-                                          lambda _a, new, _l: new, attend)
-    return logits, (PagedKVCache(k=k, v=v), StatePool(s=s, conv=conv))
+    x, s, conv, k, v = _scan_periods(params, cfg, x, pool.s, pool.conv,
+                                     pkv.k, pkv.v, mixer,
+                                     lambda _a, new, _l: new, attend)
+    return (_head(params, cfg, x),
+            (PagedKVCache(k=k, v=v), StatePool(s=s, conv=conv)))
+
+
+@_exact_f32_dots
+def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                     pos_vec: jax.Array, cache, tables: jax.Array,
+                     chunk: jax.Array, chunk_pos: jax.Array,
+                     n_valid: jax.Array, poison: jax.Array):
+    """A tick that carries a prefill chunk, as ONE program
+    (``Family.tick``; ``falcon_h1.forward_and_step``'s signature to the
+    letter): :func:`forward` over ``chunk [1, T]`` at ``chunk_pos`` into an
+    admission's column AND :func:`paged_forward`'s layers over the tick's
+    decode rows (``tokens [R, 1]`` at ``pos_vec`` through ``tables``), so
+    that every plane of both stacks is read once for both. ``cache`` is
+    ``(column, (PagedKVCache, StatePool))``, all given back (and donated
+    where the server jits this).
+
+    ONE call of :func:`_scan_periods` over the joined rows ``[1, T + R]``,
+    its carry the column AND the pools whole, each as its own program
+    carries it (``store`` puts the column's layer and passes the pool
+    through). Only what owns a context tells the rows apart: ``attend`` (the
+    chunk's rows over the column's period, the decode rows into the block
+    pool in place through their tables) and ``mixer``
+    (:func:`_mixer_chunk_and_step`: the convolution and the rule a part at a
+    time, the chunk form against the column, the step form against the
+    pools). A row with an all-null table is dead, as an inactive slot of a
+    step is (the null block, the pool's null row), and every row may be.
+
+    The head runs for the decode ROWS alone: no chunk logits exist (the
+    serving prefill never read one). Returns ``((token, nonfinite, logits),
+    (column, (pkv, pool)))`` as the dense tick does: ``token`` each row's
+    ARGMAX, ``logits [R, V]`` float32 and poisoned as the step's are, for
+    ``ops.sampling.sampled_token`` where a row samples."""
+    from ..runtime.kvblocks import PagedKVCache, StatePool
+
+    _check(cfg)
+    col, (pkv, pool) = cache
+    chunk_pos = jnp.asarray(chunk_pos, dtype=jnp.int32)
+    n_valid = jnp.asarray(n_valid, dtype=jnp.int32)
+    T, R = chunk.shape[1], tokens.shape[0]
+    joined = jnp.concatenate([chunk[0], tokens[:, 0]])[None]        # [1, T+R]
+    x = params.embedding[joined].astype(cfg.compute_dtype)
+    cpos = (chunk_pos + jnp.arange(T, dtype=jnp.int32))[None, :]    # [1, T]
+    rpos = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]           # [R, 1]
+    rows = jnp.where(tables[:, 0] != 0, jnp.arange(1, R + 1, dtype=jnp.int32),
+                     StatePool.NULL)
+
+    def mixer(h, lp, l, s, conv):
+        return _mixer_chunk_and_step(cfg, h, lp, l, T, n_valid, rows,
+                                     (_at(s[0], l), s[1]),
+                                     (_at(conv[0], l), conv[1]))
+
+    def store(a, new, l):
+        return _put(a[0], new[0], l), new[1]
+
+    def attend(q, k, v, k_c, v_c, p):
+        (k_col, k_pool), (v_col, v_pool) = k_c, v_c
+        att_c, k_p, v_p = _attend_dense(cfg, q[:, :T], k[:, :T], v[:, :T],
+                                        _at(k_col, p), _at(v_col, p),
+                                        chunk_pos, cpos)
+        att_r, k_pool, v_pool = _attend_paged(
+            cfg, _by_row(q, T), _by_row(k, T), _by_row(v, T), k_pool, v_pool,
+            p, rpos, tables)
+        return (_join(att_c, att_r), (_put(k_col, k_p, p), k_pool),
+                (_put(v_col, v_p, p), v_pool))
+
+    x, s, conv, k, v = _scan_periods(
+        params, cfg, x, (col.s, pool.s), (col.conv, pool.conv),
+        (col.k, pkv.k), (col.v, pkv.v), mixer, store, attend)
+    last = _poison_logits(_head(params, cfg, _by_row(x, T))[:, -1, :], poison)
+    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    return ((greedy, _nonfinite_rows(last), last),
+            (HybridColumn(k=k[0], v=v[0], s=s[0], conv=conv[0]),
+             (PagedKVCache(k=k[1], v=v[1]), StatePool(s=s[1], conv=conv[1]))))
 
 
 def _load_params(ld, cfg: ModelConfig) -> Params:
@@ -394,7 +552,7 @@ def _matmul_weight_count(cfg: ModelConfig) -> int:
 FAMILY = Family(
     forward=forward,
     paged_forward=paged_forward,
-    tick=None,
+    tick=forward_and_step,
     column=StateColumn.zeros,
     load_params=_load_params,
     matmul_weight_count=_matmul_weight_count,

@@ -196,17 +196,20 @@ def test_chunk_kernel_stack_entry_compiles_for_v5e(one_chip, k, n, rows):
 
 @pytest.mark.parametrize("k,n,rows", [
     (k, n, rows) for rows in (272, 320) for k, n in MISTRAL_7B + QWEN3_4B]
-    + [(k, n, 272) for k, n in FALCON_H1_34B])
+    + [(k, n, 272) for k, n in FALCON_H1_34B] + [(k, n, 260) for k, n in OLMO_HYBRID_7B])
 def test_chunk_kernel_compiles_at_the_joined_widths_for_v5e(one_chip, k, n, rows):
     """The same kernel as a tick program calls it (PR 47,
     ``models.llama.forward_and_step``; PR 52,
-    ``models.falcon_h1.forward_and_step``): the widest bucket's 256 rows with
-    16 slots' decode rows joined to them, over the layer stack and a traced
-    index, and for the two dense decoders the regime's upper edge. K = 14336
-    with 320 rows resident stays inside the chunk regime's VMEM limit; the
-    joined rows take the stripe width the 256-row ``forward`` had (falcon's K
-    = 21504 takes 256-wide stripes, every other plane of its seven 512), so a
-    tick program brings no kernel parameter of its own."""
+    ``models.falcon_h1.forward_and_step``; PR 55,
+    ``models.hybrid.forward_and_step``): the widest bucket's 256 rows with
+    16 slots' decode rows joined to them (the hybrid's 4), over the layer
+    stack and a traced index, and for the two dense decoders the regime's
+    upper edge. K = 14336 with 320 rows resident stays inside the chunk
+    regime's VMEM limit; the joined rows take the stripe width the 256-row
+    ``forward`` had (falcon's K = 21504 takes 256-wide stripes, every other
+    plane of its seven 512; the hybrid's 17280-wide packed plane 128-wide ones,
+    its four others 256), so a tick program brings no kernel parameter of its
+    own."""
     from dllama_tpu.ops.linear import QuantizedWeight
     from dllama_tpu.ops.quant_matmul import (CHUNK_MAX_M, _decode_blocks,
                                              fused_path, quant_matmul)

@@ -141,7 +141,7 @@ def test_every_arch_has_a_family_with_every_field_set(cfgs, arch):
 TICK = {
     ArchType.LLAMA: ("llama", "forward_and_step"),
     ArchType.QWEN3: ("llama", "forward_and_step"),
-    ArchType.OLMO_HYBRID: None,
+    ArchType.OLMO_HYBRID: ("hybrid", "forward_and_step"),
     ArchType.LAGUNA: None,
     ArchType.FALCON_H1: ("falcon_h1", "forward_and_step"),
     ArchType.AXK1: None,

@@ -357,7 +357,7 @@ def test_every_other_family_keeps_its_two_programs_and_falcon_h1_brings_its_own(
     (``test_family.TICK`` is the table): those that bring none dispatch
     ``forward`` for a chunk beside a live row and a step behind it, count no
     chunk as carried and never load ``forward_and_step``; one that brings its
-    own (falcon_h1 since PR 52, lfm2 since PR 53) loads no ``forward`` and
+    own (falcon_h1 since PR 52, lfm2 since PR 53, the hybrid since PR 55) loads no ``forward`` and
     counts one chunk as carried."""
     import dllama_tpu.runtime.engine as engine_mod
     from test_falcon_h1 import BENCH, _bench, _engine
