@@ -41,6 +41,20 @@ batch) needs. This module is that record:
   Inside ``step_wait`` each blocking fetch is a nested
   ``dllama.step.fetch`` annotation (:func:`fetch_span`), the profiler's
   alone.
+* **The loop's whole life** — from one tick's end to the next one's
+  start is an interval of its own (``telemetry.BETWEEN_TICKS``: the
+  annotation ``dllama.loop.between_ticks``, the next tick record's
+  ``gap_before_ms``, a series of the phase counter). Under a profiler
+  both edges of every tick read the loop thread's CPU clock (``cpu_ms``
+  on the record, ``cpu_us`` on the annotations); always, one edge every
+  :data:`CPU_SAMPLE_NS` reads it and the process's, so that a stall can
+  be attributed.
+* **Stall ring** — any ONE interval (a phase span, a gap between two
+  phases, a gap between two ticks) of :data:`STALL_MIN_MS` or more
+  leaves a record that outlives the tick ring (:data:`RING_STALLS`),
+  attributed by :func:`stall_cause` from the CPU clocks, the collector's
+  time (one ``gc.callbacks`` hook) and the compile ledger's counts, and
+  one ``loop stall`` line on stderr.
 
 Dependency-free (stdlib + runtime.telemetry only — importable without
 jax: the serving layer injects the annotation factory through
@@ -53,8 +67,10 @@ an audit log.
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -64,6 +80,26 @@ from . import telemetry
 
 RING_TICKS = 256
 RING_EVENTS = 4096
+RING_STALLS = 64
+# one interval of the loop's life this long is a stall: the longest
+# honest one in any benchmark cell is a 130 ms prefill chunk (idle_wait
+# sleeps at most 50 ms), the shortest stall met 0.43 s
+STALL_MIN_MS = 250.0
+# the loop thread reads its CPU clocks (its own and the process's) at a
+# tick's end when the last reading is this old, and wherever a stall is
+# found; while a profiler listens its own clock alone at both edges of
+# every tick. Each read is a system call (no vDSO serves a CPU clock):
+# 0.3 us on a bare host, 6 us on the sealed machine that holds the chip,
+# where the clocks also tick at 10 ms, so that a reading a tick would say
+# little and cost four calls; a reading a second costs nothing
+CPU_SAMPLE_NS = 1_000_000_000
+# at most one "loop stall" line a second on stderr (every stall is still
+# recorded and counted)
+STALL_LOG_MIN_INTERVAL_S = 1.0
+# phases that call into the runtime: a stall there with neither CPU clock
+# moving is blocked on the device side, not a process that stood still
+_RUNTIME_PHASES = frozenset(
+    ("prefill_dispatch", "step_upload", "step_dispatch", "step_wait"))
 # one postmortem per reason per window: exhaustion under sustained
 # pressure must not spray a file per tick
 DUMP_MIN_INTERVAL_S = 30.0
@@ -97,6 +133,65 @@ def fetch_span(what: str):
     if _annotate is None:
         return _NO_SPAN
     return _annotate(telemetry.STEP_FETCH_SPAN, what=what)
+
+
+def _cpu_ns() -> tuple[int, int]:
+    """``(loop thread's, process's)`` CPU time in ns: what tells a loop
+    that worked from one that was kept off its CPU. Two system calls (no
+    vDSO serves a CPU clock), the second one a walk over every thread."""
+    return time.thread_time_ns(), time.process_time_ns()
+
+
+class _CollectorWatch:
+    """The garbage collector's running time by generation, from ONE
+    ``gc.callbacks`` hook for the process (installed by the first tick any
+    recorder opens). A collection stops every Python thread wherever it was
+    triggered, so the total is the process's; a recorder reads ``ns_by_gen``
+    at a tick's edges and takes differences."""
+
+    def __init__(self):
+        self.clock = telemetry.now_ns
+        self.ns_by_gen = [0, 0, 0]
+        self.installed = False
+        self._t0 = 0
+
+    def install(self) -> None:
+        if not self.installed:
+            self.installed = True
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = self.clock()
+        else:
+            self.ns_by_gen[info["generation"]] += self.clock() - self._t0
+
+
+_collector = _CollectorWatch()
+
+
+def stall_cause(where: str, ms: float, cpu_ms: float | None,
+                proc_cpu_ms: float | None, gc_ms: float = 0.0,
+                compiles: int = 0, loads: int = 0) -> str:
+    """Why an interval of ``ms`` lasted that long, one name of
+    ``telemetry.STALL_CAUSES``: the first row that applies (TELEMETRY.md
+    prints the table). "Small" is under a tenth of the interval; CPU
+    times of ``None`` (never read on this thread) decide nothing."""
+    small = 0.1 * ms
+    if compiles or loads:
+        return "compile"
+    if gc_ms > 0.5 * ms:
+        return "collector"
+    if cpu_ms is None or proc_cpu_ms is None:
+        return "unknown"
+    if cpu_ms > 0.5 * ms:
+        return "own_code"
+    if cpu_ms < small and proc_cpu_ms > 0.5 * ms:
+        return "other_thread"
+    if cpu_ms < small and proc_cpu_ms < small:
+        return ("device_wait" if where in _RUNTIME_PHASES
+                else "process_stood_still")
+    return "unknown"
 
 
 def _phase_sums(phase_spans) -> dict:
@@ -162,15 +257,38 @@ class FlightRecorder:
     All state is under one lock; every operation is O(1) appends.
 
     ``clock`` is injectable (monotonic ns) so the golden-fixture
-    generator can record deterministic timelines."""
+    generator can record deterministic timelines, and ``cpu_clock``
+    (``() -> (thread's, process's) CPU ns``) and ``thread_clock`` (the
+    first of the two alone) beside it."""
 
-    def __init__(self, clock=None):
+    def __init__(self, clock=None, cpu_clock=None, thread_clock=None):
         self._clock = clock or telemetry.now_ns
+        self._cpu_clock = cpu_clock or _cpu_ns
+        self._thread_clock = thread_clock or time.thread_time_ns
         self._lock = threading.Lock()
         self._ticks: deque = deque(maxlen=RING_TICKS)
         self._events: deque = deque(maxlen=RING_EVENTS)
+        self._stalls: deque = deque(maxlen=RING_STALLS)
         self._cur: dict | None = None
         self._root = None  # the open tick's dllama.tick annotation
+        # the open tick's opening edge: (the collector's ns_by_gen,
+        # n_active going in)
+        self._opened = ([0, 0, 0], 0)
+        # the last tick's closing edge: (thread ident, t_end_ns,
+        # ns_by_gen); None until a loop has closed a tick
+        self._edge: tuple | None = None
+        # the loop thread's last reading of its CPU clocks: (thread ident,
+        # the edge's monotonic ns, thread's CPU ns, process's CPU ns), and
+        # the two clocks' usual rates (CPU ns a wall ns) over the last
+        # sampling period that held no stall
+        self._cpu_mark: tuple | None = None
+        self._cpu_usual = (0.0, 0.0)
+        # under a profiler: the loop thread's CPU clock at the open tick's
+        # start and at the last tick's end
+        self._thread_open: int | None = None
+        self._thread_edge: int | None = None
+        self._gap_ann = None  # the open between-ticks annotation
+        self._stall_logged_ns: int | None = None
         self._tick_seq = 0
         self._dump_seq = 0
         self._last_dump: dict[str, float] = {}
@@ -178,14 +296,20 @@ class FlightRecorder:
         reg = telemetry.registry()
         self._m_ticks = reg.counter(telemetry.FLIGHT_TICKS)
         self._m_phase_ms = reg.counter(telemetry.TICK_PHASE_MS)
+        self._m_stalls = reg.counter(telemetry.LOOP_STALLS)
+        self._m_stall_ms = reg.counter(telemetry.LOOP_STALL_MS)
         self._m_dumps = reg.counter(telemetry.FLIGHT_DUMPS)
 
     def reset(self) -> None:
         """Forget everything, including the dump rate limiter (tests)."""
+        self.loop_edge()
         with self._lock:
             self._ticks.clear()
             self._events.clear()
+            self._stalls.clear()
             self._cur = None
+            self._cpu_mark = None
+            self._stall_logged_ns = None
             self._tick_seq = 0
             self._dump_seq = 0
             self._last_dump.clear()
@@ -193,26 +317,97 @@ class FlightRecorder:
 
     # -- tick lifecycle (scheduler loop thread) -----------------------------
 
+    def loop_edge(self) -> None:
+        """A scheduler's loop starts or has ended: what precedes its next
+        tick is no gap between two ticks of one loop."""
+        gap, self._gap_ann, self._edge = self._gap_ann, None, None
+        if gap is not None:
+            gap.__exit__(None, None, None)
+
+    def _cpu_sample(self, ident: int, t_ns: int, inside_ms: float | None):
+        """Read the loop thread's CPU clocks at the edge ``t_ns`` and make
+        the reading the mark. ``inside_ms`` None: a sampling period ended
+        without a stall, and what it spent a wall ns becomes the usual
+        rate. Else the last ``inside_ms`` before ``t_ns`` are a tick or a
+        gap that holds a stall: returns what THAT spent, as what the whole
+        window since the mark spent less the usual rate over the rest of
+        it: ``{"cpu_ms", "proc_cpu_ms", "cpu_window_ms"}`` (``{}`` where
+        this thread had read nothing to subtract from)."""
+        thread_ns, proc_ns = self._cpu_clock()
+        mark, self._cpu_mark = self._cpu_mark, (ident, t_ns, thread_ns,
+                                                proc_ns)
+        if mark is None or mark[0] != ident or t_ns <= mark[1]:
+            self._cpu_usual = (0.0, 0.0)
+            return {}
+        window_ns = t_ns - mark[1]
+        spent = (thread_ns - mark[2], proc_ns - mark[3])
+        if inside_ms is None:
+            self._cpu_usual = (spent[0] / window_ns, spent[1] / window_ns)
+            return {}
+        rest_ns = max(0.0, window_ns - inside_ms * 1e6)
+        cpu, proc = (max(0.0, ns - usual * rest_ns) / 1e6
+                     for ns, usual in zip(spent, self._cpu_usual))
+        return {"cpu_ms": cpu, "proc_cpu_ms": proc,
+                "cpu_window_ms": window_ns / 1e6}
+
     def begin_tick(self, queue_depth: int = 0, n_admissions: int = 0,
                    n_active: int = 0) -> None:
         """Open a tick record and, under a profiler, the root
         ``dllama.tick`` annotation (``tick`` = this record's number,
-        ``n_active`` = live slots going in) the tick's phases nest in."""
+        ``n_active`` = live slots going in) the tick's phases nest in.
+        What lay between the last tick's end and here (same thread) closes
+        as the between-ticks interval: its annotation, this record's
+        ``gap_before_ms``, the phase counter's ``between_ticks`` series,
+        and a stall record where it lasted :data:`STALL_MIN_MS`."""
+        _collector.install()
+        ident = threading.get_ident()
+        gc_ns = _collector.ns_by_gen[:]
+        gap, self._gap_ann = self._gap_ann, None
+        edge, self._edge = self._edge, None
+        if edge is not None and edge[0] != ident:
+            edge = None   # another loop's tick: CPU clocks of two threads
+        thread_ns = None
+        if gap is not None:
+            if gap.is_enabled() and edge is not None \
+                    and self._thread_edge is not None:
+                thread_ns = self._thread_clock()
+                gap.set_metadata(
+                    cpu_us=(thread_ns - self._thread_edge) // 1000)
+            gap.__exit__(None, None, None)
         with self._lock:
             self._tick_seq += 1
             seq = self._tick_seq
+            t_ns = self._clock()
+            gap_ms = (t_ns - edge[1]) / 1e6 if edge is not None else 0.0
             self._cur = {"tick": seq,
-                         "t_start_ns": self._clock(),
+                         "t_start_ns": t_ns,
+                         "gap_before_ms": gap_ms,
                          "queue_depth": queue_depth,
                          "n_admissions": n_admissions,
                          "decisions": [], "dispatch_ms": 0.0,
                          "prefill_ms": 0.0, "prefill_tokens": 0,
                          "decode_tokens": 0, "n_active": 0,
                          "phase_spans": []}
+        self._opened = (gc_ns, n_active)
+        self._thread_open = None
         if _annotate is not None:
             self._root = _annotate(telemetry.TICK_SPAN, tick=seq,
                                    n_active=n_active)
             self._root.__enter__()
+            if self._root.is_enabled():
+                self._thread_open = (thread_ns if thread_ns is not None
+                                     else self._thread_clock())
+        mark = self._cpu_mark
+        if mark is None or mark[0] != ident:
+            self._cpu_sample(ident, t_ns, None)    # this thread's first
+        if edge is not None:
+            self._m_phase_ms.inc(gap_ms, phase=telemetry.BETWEEN_TICKS)
+            if gap_ms >= STALL_MIN_MS:
+                self.note_stall(
+                    telemetry.BETWEEN_TICKS, edge[1], gap_ms, tick=seq,
+                    n_active=n_active, queue_depth=queue_depth,
+                    **self._cpu_sample(ident, t_ns, gap_ms),
+                    gc_ns=[b - a for a, b in zip(edge[2], gc_ns)])
 
     def tick_phase(self, name: str) -> _TickPhase:
         """``with recorder.tick_phase("emit"):`` — one phase of the
@@ -273,6 +468,17 @@ class FlightRecorder:
             self._cur["prefill_ms"] += ms
             self._cur["prefill_tokens"] += n_tokens
 
+    def note_queued(self, ms: float) -> None:
+        """The open tick's ``step_wait`` waited behind device work that was
+        queued before the step (a prompt's prefill chunks, enqueued in a
+        burst), ``ms`` of it by the generator's own reckoning from recent
+        such waits: the wait is held against :data:`STALL_MIN_MS` less
+        that, so a long prompt is no stall and a wait that outlasts its
+        queue by a quarter second still is."""
+        with self._lock:
+            if self._cur is not None:
+                self._cur["queued_ms"] = self._cur.get("queued_ms", 0.0) + ms
+
     def note_spec(self, drafted: int, accepted: int) -> None:
         """One speculative verify dispatch's draft/accept counts inside
         the current tick — the tick record's view of what the verify
@@ -291,30 +497,142 @@ class FlightRecorder:
     def end_tick(self, blocks: dict | None = None, **extra) -> None:
         """Close the tick (and its root annotation). Idle ticks (no
         decisions, no dispatch, no prefill) are dropped — the ring stays
-        signal-dense and tick numbering gaps mark idle stretches."""
+        signal-dense and tick numbering gaps mark idle stretches — but
+        not before their intervals were held against
+        :data:`STALL_MIN_MS`: a tick that only overslept in ``idle_wait``
+        still leaves its stall record. ``extra`` lands in the record
+        (``compiles`` / ``loads``: what the compile ledger counted over
+        the tick, which a stall record of this tick is attributed by).
+        While a profiler listens the record carries ``cpu_ms``, the loop
+        thread's CPU time over the tick, and the root annotation
+        ``cpu_us``."""
+        ident = threading.get_ident()
+        gc0, n_active = self._opened
+        cpu_us = None
         root, self._root = self._root, None
+        self._thread_edge = None
         if root is not None:
+            if root.is_enabled():
+                self._thread_edge = self._thread_clock()
+                if self._thread_open is not None:
+                    cpu_us = (self._thread_edge - self._thread_open) // 1000
+                    root.set_metadata(cpu_us=cpu_us)
             root.__exit__(None, None, None)
         with self._lock:
             cur, self._cur = self._cur, None
             if cur is None:
                 return
-            cur["t_end_ns"] = self._clock()
+            t_end = cur["t_end_ns"] = self._clock()
+            wall_ms = (t_end - cur["t_start_ns"]) / 1e6
             cur["phases"] = _phase_sums(cur["phase_spans"])
+            unphased_ms = cur["unphased_ms"] = max(
+                0.0, wall_ms - sum(cur["phases"].values()))
+            if cpu_us is not None:
+                cur["cpu_ms"] = cpu_us / 1e3
             if blocks is not None:
                 cur["blocks"] = dict(blocks)
             cur.update(extra)
-            if not (cur["decisions"] or cur["dispatch_ms"]
-                    or cur["prefill_ms"] or cur["prefill_tokens"]):
-                return
-            self._ticks.append(cur)
-        self._m_ticks.inc()
+            work = bool(cur["decisions"] or cur["dispatch_ms"]
+                        or cur["prefill_ms"] or cur["prefill_tokens"])
+            if work:
+                self._ticks.append(cur)
+        gc_ns = _collector.ns_by_gen[:]
+        if wall_ms >= STALL_MIN_MS:
+            self._tick_stalls(cur, wall_ms, max(n_active, cur["n_active"]),
+                              self._cpu_sample(ident, t_end, wall_ms),
+                              [b - a for a, b in zip(gc0, gc_ns)])
+        elif t_end - self._cpu_mark[1] >= CPU_SAMPLE_NS:
+            self._cpu_sample(ident, t_end, None)
+        self._edge = (ident, t_end, gc_ns)
+        if _annotate is not None:
+            self._gap_ann = _annotate(telemetry.LOOP_GAP_SPAN)
+            self._gap_ann.__enter__()
+        self._m_phase_ms.inc(unphased_ms, phase=telemetry.BETWEEN_PHASES)
+        if work:
+            self._m_ticks.inc()
+
+    # -- stalls ---------------------------------------------------------------
+
+    def _tick_stalls(self, cur: dict, wall_ms: float, n_active: int,
+                     spent: dict, gc_ns: list[int]) -> None:
+        """One stall record for every interval of the closed tick ``cur``
+        (a phase span, or a gap between two phases or a phase and the
+        tick's edge) of :data:`STALL_MIN_MS` or more."""
+        about = dict(tick=cur["tick"], n_active=n_active,
+                     queue_depth=cur["queue_depth"], **spent, gc_ns=gc_ns,
+                     compiles=cur.get("compiles", 0),
+                     loads=cur.get("loads", 0))
+        t0, at, prev = cur["t_start_ns"], 0.0, "tick_start"
+        queued = {"step_wait": cur.get("queued_ms", 0.0)}
+        for name, off, ms in [*cur["phase_spans"], ["tick_end", wall_ms, 0.0]]:
+            if off - at >= STALL_MIN_MS:
+                self.note_stall(telemetry.BETWEEN_PHASES, t0 + int(at * 1e6),
+                                off - at, between=[prev, name], **about)
+            if ms - queued.get(name, 0.0) >= STALL_MIN_MS:
+                self.note_stall(name, t0 + int(off * 1e6), ms, **about)
+            at, prev = off + ms, name
+
+    def note_stall(self, where: str, t_start_ns: int, ms: float, *, tick: int,
+                   n_active: int = 0, queue_depth: int = 0,
+                   cpu_ms: float | None = None,
+                   proc_cpu_ms: float | None = None,
+                   cpu_window_ms: float | None = None,
+                   gc_ns=(0, 0, 0), compiles: int = 0, loads: int = 0,
+                   between: list[str] | None = None) -> dict:
+        """Record ONE interval of the loop's life that lasted
+        :data:`STALL_MIN_MS` or more: into the stall ring, the two
+        counters, and (at most once a second) a line on stderr, so that an
+        untraced run says what its stall was. ``where`` is a name of
+        ``telemetry.TICK_PHASES``, ``between_ticks`` or ``between_phases``
+        (then ``between`` names the phases on either side). ``cpu_ms`` /
+        ``proc_cpu_ms`` are the loop thread's and the process's CPU time
+        over the enclosing tick or gap, reckoned (:meth:`_cpu_sample`)
+        from the ``cpu_window_ms`` of wall since the last reading, at most
+        :data:`CPU_SAMPLE_NS` before it (``None``: this thread had read
+        nothing to subtract from);
+        ``compiles`` / ``loads`` are the enclosing tick's, ``gc_ns`` the
+        collector's time in the tick or gap by generation. Times are the recorder's monotonic ns (the clock of
+        ``Request.t_submit``). Returns the record."""
+        gens = [g for g, ns in enumerate(gc_ns) if ns > 0]
+        gc_ms = sum(gc_ns) / 1e6
+        rec = {"t_start_ns": int(t_start_ns), "ms": ms, "where": where,
+               "tick": tick, "n_active": n_active,
+               "queue_depth": queue_depth, "cpu_ms": cpu_ms,
+               "proc_cpu_ms": proc_cpu_ms,
+               "cpu_window_ms": cpu_window_ms, "gc_ms": gc_ms,
+               "gc_gen": gens[-1] if gens else None,
+               "compiles": compiles, "loads": loads,
+               "cause": stall_cause(where, ms, cpu_ms, proc_cpu_ms, gc_ms,
+                                    compiles, loads)}
+        if between is not None:
+            rec["between"] = list(between)
+        t_end_ns = rec["t_start_ns"] + int(ms * 1e6)
+        with self._lock:
+            self._stalls.append(rec)
+            last = self._stall_logged_ns
+            log = last is None or \
+                t_end_ns - last >= STALL_LOG_MIN_INTERVAL_S * 1e9
+            if log:
+                self._stall_logged_ns = t_end_ns
+        self._m_stalls.inc(where=where, cause=rec["cause"])
+        self._m_stall_ms.inc(ms, where=where)
+        if log:
+            at = where if between is None else \
+                f"{where} ({between[0]} | {between[1]})"
+            cpu = "cpu not read" if cpu_ms is None else (
+                f"cpu {cpu_ms:.1f} ms, process cpu {proc_cpu_ms:.1f} ms")
+            print(f"⚠ loop stall {ms:.0f} ms in {at}, tick {tick}, "
+                  f"{n_active} rows: {cpu}, gc {gc_ms:.1f} ms, compiles "
+                  f"{compiles}, loads {loads} -> {rec['cause']}",
+                  file=sys.stderr, flush=True)
+        return rec
 
     # -- views ---------------------------------------------------------------
 
     def snapshot(self, n_ticks: int = RING_TICKS,
                  n_events: int = RING_EVENTS) -> dict:
-        """The live rings (``GET /debug/flight``), newest last. An OPEN
+        """The live rings (``GET /debug/flight``), newest last; ``stalls``
+        is the whole stall ring, which outlives the ticks it names. An OPEN
         tick is included as a partial record marked ``"open": true`` — a
         mid-tick postmortem (exhaustion dump, watchdog stall while the
         loop thread is wedged inside a dispatch) must show the dying
@@ -331,6 +649,7 @@ class FlightRecorder:
             return {"tick_seq": self._tick_seq,
                     "ticks": ticks,
                     "events": list(self._events)[-n_events:],
+                    "stalls": list(self._stalls),
                     "dumps": list(self._dumps)}
 
     def payload(self, reason: str, victims=(), info: dict | None = None, *,
@@ -461,15 +780,23 @@ def to_chrome_trace(data: dict) -> dict:
     chrome://tracing.
 
     Track layout: pid 1 = the scheduler (tid 0: one ``X`` slice per tick
-    with its decisions in ``args``, plus queue-depth / active-slot /
-    kv-block counter tracks); pid 2 = requests (one thread per slot,
-    ``X`` slices per request phase from the span ring, plus one flow —
-    ``s``/``t``/``f`` events, id = request id — chaining each request's
+    with its decisions in ``args``, one instant per stall record, plus
+    queue-depth / active-slot / kv-block counter tracks); pid 2 =
+    requests (one thread per slot, ``X`` slices per request phase from
+    the span ring, plus one flow — ``s``/``t``/``f`` events, id = request id — chaining each request's
     phases across slots). Timestamps are the recorder's monotonic ns
     rendered as µs; spans and ticks share one clock."""
     ticks = data.get("ticks") or []
     spans = data.get("spans") or []
     out: list[dict] = []
+    # a stall is an instant on the scheduler track where it began (its
+    # tick may have left the tick ring long since)
+    for st in data.get("stalls") or ():
+        out.append({"ph": "i", "pid": 1, "tid": 0, "s": "t",
+                    "ts": st["t_start_ns"] / 1e3,
+                    "name": f"stall {st['where']}", "cat": "stall",
+                    "args": {k: v for k, v in st.items()
+                             if k != "t_start_ns"}})
 
     def meta(pid, tid, what, name):
         e = {"ph": "M", "pid": pid, "name": what, "args": {"name": name}}
@@ -495,7 +822,8 @@ def to_chrome_trace(data: dict) -> dict:
             dur = max((off + ms for _n, off, ms in phase_spans),
                       default=0.0) * 1e3
         args = {k: t[k] for k in ("queue_depth", "n_admissions", "decisions",
-                                  "dispatch_ms", "prefill_ms",
+                                  "gap_before_ms", "unphased_ms", "cpu_ms",
+                                  "proc_cpu_ms", "dispatch_ms", "prefill_ms",
                                   "prefill_tokens", "decode_tokens",
                                   "spec_draft_tokens", "spec_accept_tokens",
                                   "n_active", "slots", "blocks",

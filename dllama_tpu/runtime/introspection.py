@@ -211,6 +211,7 @@ class CompileLedger:
         self._programs: dict[tuple[str, str], dict] = {}
         self._steady: dict[str, bool] = {}
         self._compiles_by_scope: dict[str, int] = {}
+        self._loads_by_scope: dict[str, int] = {}
         self._seq = 0
         # per-miss AOT memory/cost analysis (a second compile of identical
         # HLO): on for api serving, opt-in elsewhere. Env overrides both
@@ -238,8 +239,14 @@ class CompileLedger:
     # -- steady-state -------------------------------------------------------
 
     def compile_count(self, scope: str) -> int:
+        return self.build_counts(scope)[0]
+
+    def build_counts(self, scope: str) -> tuple[int, int]:
+        """``(programs built, of those loaded from the program store)`` in
+        ``scope``; their difference was traced and compiled here."""
         with self._lock:
-            return self._compiles_by_scope.get(scope, 0)
+            return (self._compiles_by_scope.get(scope, 0),
+                    self._loads_by_scope.get(scope, 0))
 
     def steady(self, scope: str) -> bool:
         with self._lock:
@@ -341,6 +348,9 @@ class CompileLedger:
                 entry["unexpected"] += 1
             self._compiles_by_scope[scope] = \
                 self._compiles_by_scope.get(scope, 0) + 1
+            if source == "store":
+                self._loads_by_scope[scope] = \
+                    self._loads_by_scope.get(scope, 0) + 1
             self._seq += 1
             self._events.append({
                 "seq": self._seq, "time": time.time(), "scope": scope,
@@ -398,6 +408,7 @@ class CompileLedger:
             self._programs.clear()
             self._steady.clear()
             self._compiles_by_scope.clear()
+            self._loads_by_scope.clear()
 
 
 _ledger = CompileLedger()

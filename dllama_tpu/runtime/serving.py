@@ -428,6 +428,9 @@ class _GeneratorCore:
         # step_wait walls of chunk-free steps (_settle_prefill)
         self._chunks_pending: list[_PendingChunk] = []
         self._step_waits: deque = deque(maxlen=STEP_WAIT_SAMPLES)
+        # what recent waits behind queued chunks cost a dispatched token:
+        # the next such wait's usual length, which is no stall
+        self._chunk_ms_per_token: deque = deque(maxlen=STEP_WAIT_SAMPLES)
         # running totals: plain prefill chunks dispatched, and those of them
         # whose program also stepped at least one live decode row
         # (PagedGenerator._run_rows)
@@ -760,6 +763,13 @@ class _GeneratorCore:
         stall = max(0.0, wall - base)
         total = wall if rode else stall
         width = sum(c.width for c in pending)
+        # a prompt's chunks are enqueued in a burst and the step waits for
+        # all of them: the wait is as long as they are many, and only what
+        # it lasted beyond their usual cost is held against the stall limit
+        if self._chunk_ms_per_token:
+            self.flight.note_queued(
+                width * statistics.median(self._chunk_ms_per_token))
+        self._chunk_ms_per_token.append(total / width)
         for c in pending:
             ms = total * c.width / width
             c.req.ms_prefill += ms
@@ -1606,6 +1616,10 @@ class PagedGenerator(_GeneratorCore):
                 # time, replicated with its axes named to its rank
                 self.moe_stats = self._pin_home(self.moe_stats, by_rank=True)
             self._moe_seen = np.zeros(self.moe_stats.shape, np.int64)
+            # tokens a held expert since this generator was built: the
+            # running total a traced step's span carries (the registry's
+            # series would cost one lookup an expert a step to join)
+            self._moe_tokens_total = np.zeros(self.cfg.n_experts, np.int64)
             # held planes a step's routed layers COULD fetch, added a step:
             # what ``moe_planes`` is a share of
             self._moe_plane_slots = 0
@@ -2933,6 +2947,7 @@ class PagedGenerator(_GeneratorCore):
         for e in np.nonzero(both[N_COUNTS:])[0]:
             self._m_moe_tokens.inc(int(both[N_COUNTS + e]),
                                    expert=str(int(e)))
+        self._moe_tokens_total += both[N_COUNTS:]
         wait.set(moe_pairs=int(delta[0, 0]))
         if delta[0, :2].any():
             # the STEP program ran: a tick program's one joined dispatch is a
@@ -2941,12 +2956,11 @@ class PagedGenerator(_GeneratorCore):
             self._moe_plane_slots += (self.cfg.n_moe_layers
                                       * self.cfg.n_experts)
         if wait.traced:
-            pairs, tokens = self._m_moe_pairs, self._m_moe_tokens
+            pairs = self._m_moe_pairs
             wait.set(moe_held=int(pairs.total(where="held")),
                      moe_absent=int(pairs.total(where="absent")),
                      moe_tokens="/".join(
-                         str(int(tokens.total(expert=str(e))))
-                         for e in range(self.cfg.n_experts)),
+                         map(str, self._moe_tokens_total.tolist())),
                      moe_step_held=int(self._moe_seen[0, 0]),
                      moe_planes=int(self._moe_seen[0, 3]),
                      moe_plane_slots=self._moe_plane_slots,
@@ -3124,11 +3138,14 @@ class BatchScheduler:
         # flight recorder (runtime/flightrec): the scheduler owns the tick
         # framing; every decision in _tick lands in the open tick record
         self.flight = self.gen.flight
-        # a scrape shows every tick phase from start-up, not only those
-        # a tick has reached yet
-        for ph in telemetry.TICK_PHASES:
+        # a scrape shows every tick phase (and what lies between two phases
+        # and between two ticks) from start-up, not only those a tick has
+        # reached yet
+        for ph in (*telemetry.TICK_PHASES, *telemetry.LOOP_GAPS):
             telemetry.registry().counter(telemetry.TICK_PHASE_MS).inc(
                 0.0, phase=ph)
+        # this loop's first tick follows no tick of its own
+        self.flight.loop_edge()
         self.max_queue = max_queue
         self.max_restarts = max_restarts
         # tenant observatory (runtime/tenancy): the process-wide
@@ -3169,6 +3186,8 @@ class BatchScheduler:
         # an unexpected retrace (WARNed + dllama_retrace_unexpected_total)
         self._introspect_scope = getattr(engine, "introspection_scope", None)
         self._quiet_ticks = 0
+        # the ledger's (built, loaded) counts at the open tick's start
+        self._built_before = self._build_counts()  # dlint: owner=loop-thread
         # step watchdog (runtime.watchdog): a wedged dispatch blocks the
         # loop thread inside step(), so supervision can't run there — the
         # watchdog's monitor thread calls _on_stall instead
@@ -3662,11 +3681,14 @@ class BatchScheduler:
     # -- the loop ------------------------------------------------------------
 
     def _loop(self) -> None:  # dlint: owner=loop-thread
-        while not self._stop:
-            try:
-                self._tick()
-            except Exception as exc:  # noqa: BLE001 — supervised: fail-all + bounded restart
-                self._on_crash(exc)
+        try:
+            while not self._stop:
+                try:
+                    self._tick()
+                except Exception as exc:  # noqa: BLE001 — supervised: fail-all + bounded restart
+                    self._on_crash(exc)
+        finally:
+            self.flight.loop_edge()    # nothing follows the last tick
 
     STEADY_TICKS = 2  # compile-quiet work ticks before steady is declared
 
@@ -3704,8 +3726,18 @@ class BatchScheduler:
                 blocks = self.gen.flight_blocks()
                 slots = [s.rid if s is not None else None
                          for s in self.gen.slots]
+                # programs built inside the tick: a stall record of it is
+                # attributed by them (a load from the program store on a
+                # warm start is no jax compile event)
+                built, loaded = (b - a for a, b in zip(
+                    self._built_before, self._build_counts()))
             self.flight.end_tick(blocks=blocks, slots=slots,
-                                 prefill_budget=self.prefill_budget)
+                                 prefill_budget=self.prefill_budget,
+                                 compiles=built - loaded, loads=loaded)
+
+    def _build_counts(self) -> tuple[int, int]:  # dlint: owner=loop-thread
+        scope = self._introspect_scope
+        return introspection.ledger().build_counts(scope) if scope else (0, 0)
 
     def _tick_body(self) -> None:  # dlint: owner=loop-thread
         """One tick, divided into ``telemetry.TICK_PHASES`` spans
@@ -3713,9 +3745,8 @@ class BatchScheduler:
         the device's idle gaps overlap in a profile names what the host
         was doing."""
         with self.flight.tick_phase("deadlines"):
-            compiles_before = (
-                introspection.ledger().compile_count(self._introspect_scope)
-                if self._introspect_scope else 0)
+            self._built_before = self._build_counts()
+            compiles_before = self._built_before[0]
             self._check_deadlines()
             # KV migration service points (runtime/kvwire): peer export
             # gathers run here (the loop thread owns the pool), and

@@ -123,6 +123,8 @@ ITL_ATTRIB_MS = "dllama_itl_attrib_ms"
 FLIGHT_TICKS = "dllama_flight_ticks_total"
 FLIGHT_DUMPS = "dllama_flight_dumps_total"
 TICK_PHASE_MS = "dllama_tick_phase_ms_total"
+LOOP_STALLS = "dllama_loop_stalls_total"
+LOOP_STALL_MS = "dllama_loop_stall_ms_total"
 
 # fleet router (serve/router.py — the scheduler-over-engines tier)
 ROUTER_REPLICA_UP = "dllama_router_replica_up"
@@ -591,10 +593,26 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "mark idle stretches)"),
     _spec(TICK_PHASE_MS, "counter",
           "Wall milliseconds the scheduler loop spent in each phase of "
-          "its tick (label phase, one of telemetry.TICK_PHASES; every "
-          "phase renders from start-up). Host share of the loop between "
-          "two scrapes = the increase of every phase except step_wait "
-          "and idle_wait over the increase of all phases"),
+          "its tick (label phase, one of telemetry.TICK_PHASES), "
+          "between two phases of a tick (between_phases) and between "
+          "two ticks (between_ticks; telemetry.LOOP_GAPS); every "
+          "series renders from start-up, and together they are the "
+          "loop thread's wall since the scheduler started. Host "
+          "share of the loop between two "
+          "scrapes = the increase of every series except step_wait "
+          "and idle_wait over the increase of all of them"),
+    _spec(LOOP_STALLS, "counter",
+          "Intervals of the scheduler loop's life that lasted "
+          "flightrec.STALL_MIN_MS (250 ms) or more: one phase span, "
+          "one gap between two phases, or one gap between two ticks "
+          "(label where: a telemetry.TICK_PHASES name, between_ticks "
+          "or between_phases; label cause: one of "
+          "telemetry.STALL_CAUSES). Each also leaves a record in the "
+          "flight recorder's stall ring (/debug/flight, stalls) and "
+          "a 'loop stall' line on stderr"),
+    _spec(LOOP_STALL_MS, "counter",
+          "Wall milliseconds of the intervals counted by "
+          "dllama_loop_stalls_total, by where"),
     _spec(FLIGHT_DUMPS, "counter",
           "Flight-recorder postmortem dumps written, by reason "
           "(watchdog_stall / scheduler_crash / kv_block_exhaustion; "
@@ -1034,6 +1052,44 @@ TICK_PHASES = ("deadlines", "admit_begin", "prefill_dispatch",
                "step_dispatch", "step_wait", "emit", "bookkeeping", "canary",
                "idle_wait")
 TICK_SPAN = "dllama.tick"
+# What lies between one tick's end and the next one's start (the
+# ``while`` of ``BatchScheduler._loop``, the recorder's own closing and
+# opening) is an interval of the loop's life and no part of a tick: the
+# flight recorder gives it the three records of a phase under names of
+# its own: ``between_ticks`` as the series of
+# ``dllama_tick_phase_ms_total`` and the stall record's ``where``, the
+# next tick record's ``gap_before_ms``, and the profiler annotation
+# :data:`LOOP_GAP_SPAN`, which is deliberately NOT under ``dllama.tick``
+# (every span with that prefix is read as a tick or a tick's child).
+# ``between_phases`` is what lies between two phases inside a tick (the
+# seams of ``_tick_body``, the recorder's own stamps): the tick record's
+# ``unphased_ms``, a series of the counter (so that its series sum to the
+# loop thread's wall), and the ``where`` of a stall record of ONE such
+# gap, which then names the phases on either side.
+BETWEEN_TICKS = "between_ticks"
+BETWEEN_PHASES = "between_phases"
+LOOP_GAPS = (BETWEEN_TICKS, BETWEEN_PHASES)
+LOOP_GAP_SPAN = "dllama.loop.between_ticks"
+# Why one interval of the loop's life lasted a quarter second or more
+# (runtime/flightrec ``stall_cause``: the first row that applies, in
+# this order; closed-world like TICK_PHASES, tools/dlint span-phases):
+#
+# * ``compile`` — a program was traced and compiled, or loaded from the
+#   program store, inside the tick.
+# * ``collector`` — the garbage collector ran for more than half of the
+#   interval.
+# * ``own_code`` — the loop thread was on a CPU for more than half of it.
+# * ``other_thread`` — the loop thread hardly ran, the process did for
+#   more than half of it: another thread ran or held the GIL.
+# * ``device_wait`` — neither ran, inside a phase that calls into the
+#   runtime (``step_upload`` / ``step_dispatch`` / ``step_wait`` /
+#   ``prefill_dispatch``): blocked there.
+# * ``process_stood_still`` — neither ran, anywhere else: descheduled,
+#   paged out or frozen with every thread.
+# * ``unknown`` — none of the above (a CPU clock read between a tenth
+#   and a half of the interval, or the thread had read none yet).
+STALL_CAUSES = ("compile", "collector", "own_code", "other_thread",
+                "device_wait", "process_stood_still", "unknown")
 # One blocking fetch of a step output inside ``step_wait``
 # (``what=<tokens|nonfinite|...>``; outputs fetched in one call are
 # joined by ``/``). Deliberately NOT under ``dllama.tick.``: every span

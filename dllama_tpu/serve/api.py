@@ -71,7 +71,7 @@ _DEBUG_INDEX = {
     "/debug/numerics": "GET: numerics observatory — tripwire totals, tapped "
                        "activation stats, canary status",
     "/debug/flight": "GET: flight-recorder rings — per-tick scheduler "
-                     "decisions + request lifecycle events",
+                     "decisions + request lifecycle events + loop stalls",
     "/debug/timeline": "GET: Perfetto-loadable Chrome trace of the flight "
                        "rings + span ring",
     "/debug/roofline": "GET: roofline observatory — per-program achieved "

@@ -524,6 +524,55 @@ def test_span_phases_tick_phase_must_be_a_literal(tmp_path):
                and f.lineno == 2 for f in findings)
 
 
+_STALL_FIXTURE = {
+    "dllama_tpu/runtime/rec.py": """\
+        from . import telemetry
+
+
+        def stall_cause(where, cpu):
+            if cpu > 1:
+                return "own_code"
+            if cpu < 0:
+                return where
+            return "bogus_cause" if where else "unknown"
+
+
+        def gap(counter, ms):
+            counter.inc(ms, phase=telemetry.BETWEEN_TICKS)
+        """,
+    "dllama_tpu/runtime/telemetry.py": """\
+        # * ``between_ticks`` and ``between_phases`` - documented here
+        # * ``own_code`` / ``unknown`` / ``never_returned`` - documented here
+        BETWEEN_TICKS = "between_ticks"
+        BETWEEN_PHASES = "between_phases"
+        BETWEEN_NOTHING = "undocumented_gap"
+        """,
+    "dllama_tpu/runtime/TELEMETRY.md": "between_ticks between_phases own_code unknown never_returned\n",
+}
+
+
+def test_span_phases_loop_gaps_and_stall_causes_are_closed(tmp_path):
+    """LOOP_GAPS: emitted where another module reads the constant off
+    telemetry. STALL_CAUSES: emitted where ``stall_cause`` returns the
+    literal, every arm of a conditional return included."""
+    from tools.dlint import span_phases
+
+    findings, summary = span_phases.check(
+        _tree(tmp_path, _STALL_FIXTURE),
+        phases=((), (), (), ("between_ticks", "between_phases", "undocumented_gap"),
+                ("own_code", "unknown", "never_returned")))
+    msgs = "\n".join(f.message for f in findings)
+    assert "telemetry.LOOP_GAPS documents 'between_phases' but no call site" in msgs
+    assert "documents 'between_ticks' but no call site" not in msgs
+    assert "'undocumented_gap' is not documented in TELEMETRY.md" in msgs
+    assert "'bogus_cause' which is not in telemetry.STALL_CAUSES" in msgs
+    assert "telemetry.STALL_CAUSES documents 'never_returned' but no call site" in msgs
+    assert "documents 'unknown' but no call site" not in msgs
+    assert any("stall_cause returns something that is not a string constant" in f.message
+               and f.lineno == 8 for f in findings)
+    assert "3 loop gaps + 3 stall causes" in summary
+
+
 def test_span_phases_live_tick_vocabulary_is_closed():
     """The live tree: every TICK_PHASES name has a call site in
     runtime/serving.py and the two-tuple form older fixtures pass still
@@ -532,7 +581,7 @@ def test_span_phases_live_tick_vocabulary_is_closed():
 
     findings, summary = span_phases.check(Project(REPO))
     assert findings == []
-    assert "12 tick phases" in summary
+    assert "12 tick phases + 2 loop gaps + 7 stall causes" in summary
     findings, _ = span_phases.check(Project(REPO), phases=((), ()))
     assert all("TICK_PHASES documents" not in f.message for f in findings)
 

@@ -162,7 +162,8 @@ def test_a_capture_holds_the_fetches_and_no_phase_outside_the_vocabulary(path_en
     prefix = tm.TICK_SPAN + "."
     phases = [e for e in events if e[1].startswith(prefix)]
     assert phases and {e[1][len(prefix):] for e in phases} <= set(tm.TICK_PHASES)
-    assert {e[1] for e in events} <= {tm.TICK_SPAN, tm.STEP_FETCH_SPAN} | {prefix + p for p in tm.TICK_PHASES}
+    assert {e[1] for e in events} <= ({tm.TICK_SPAN, tm.STEP_FETCH_SPAN, tm.LOOP_GAP_SPAN}
+                                      | {prefix + p for p in tm.TICK_PHASES})
     waits = [e for e in phases if e[1] == prefix + "step_wait"]
     fetches = [e for e in events if e[1] == tm.STEP_FETCH_SPAN]
     assert waits and len(fetches) == len(PATHS[name][1]) * len(waits)
@@ -282,7 +283,7 @@ def test_without_a_profiler_the_fetch_spans_leave_no_record(model_files):
     assert "fetch" not in str(snap)
     text = tm.registry().render()
     assert sorted(p for p in tm.TICK_PHASES if f'{tm.TICK_PHASE_MS}{{phase="{p}"}}' in text) == sorted(tm.TICK_PHASES)
-    assert text.count(tm.TICK_PHASE_MS + "{") == len(tm.TICK_PHASES)
+    assert text.count(tm.TICK_PHASE_MS + "{") == len(tm.TICK_PHASES) + len(tm.LOOP_GAPS)
     assert not any("fetch" in name for name in before)
 
 

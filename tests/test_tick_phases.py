@@ -108,34 +108,48 @@ def test_recorded_ticks_are_tiled_by_the_closed_vocabulary(paged_engine):
             "bookkeeping"} <= seen
 
 
-def test_counter_is_monotone_and_sums_to_the_loops_wall(paged_engine):
-    """``dllama_tick_phase_ms_total``: every phase only grows, and over a
+@pytest.mark.parametrize("wall_of", ["ticks", "loop"])
+def test_counter_is_monotone_and_sums_to_the_loops_wall(paged_engine, wall_of):
+    """``dllama_tick_phase_ms_total``: every series only grows, and over a
     hand-driven run (this thread is the loop) the phases add up to the wall
-    spent inside ``_tick``: idle waits included, nothing counted twice."""
+    spent inside ``_tick``: idle waits included, nothing counted twice
+    (``ticks``). With what lies between two phases and between two ticks
+    (``telemetry.LOOP_GAPS``) the series reach the loop's WHOLE wall, from
+    the first ``_tick``'s start to the last one's end, the ``while``'s own
+    condition included, within 1% + 0.5 ms (``loop``)."""
     c = tm.registry().counter(tm.TICK_PHASE_MS)
+    names = tm.TICK_PHASES if wall_of == "ticks" else (*tm.TICK_PHASES, *tm.LOOP_GAPS)
     sched = BatchScheduler(paged_engine, n_slots=2, _start_thread=False)
     try:
         reqs = [sched.submit(paged_engine.tokenizer.encode(p, is_start=True), 6,
                              stop_on_eos=False) for p in PROMPTS[:4]]
-        before = {p: c.total(phase=p) for p in tm.TICK_PHASES}
+        before = {p: c.total(phase=p) for p in names}
         prev, wall_ns = dict(before), 0
+        t_first = time.monotonic_ns()
         while not all(r.done.is_set() for r in reqs):
             t0 = time.monotonic_ns()
             sched._tick()
-            wall_ns += time.monotonic_ns() - t0
-            now = {p: c.total(phase=p) for p in tm.TICK_PHASES}
-            assert all(now[p] >= prev[p] for p in tm.TICK_PHASES)
+            t_last = time.monotonic_ns()
+            wall_ns += t_last - t0
+            now = {p: c.total(phase=p) for p in names}
+            assert all(now[p] >= prev[p] for p in names)
             prev = now
+        if wall_of == "loop":
+            wall_ns = t_last - t_first
         sched._tick()                                # one idle tick: idle_wait counts
         wall_ns += 50_000_000
     finally:
         sched.close()
-    grown = {p: prev[p] - before[p] for p in tm.TICK_PHASES}
+    grown = {p: prev[p] - before[p] for p in names}
     assert c.total(phase="idle_wait") > before["idle_wait"]
     total, wall = sum(grown.values()), (wall_ns - 50_000_000) / 1e6
     assert total <= wall + 1e-6
-    assert wall - total <= 0.05 * wall + 0.5, (total, wall, grown)
-    assert c.total() == pytest.approx(sum(c.total(phase=p) for p in tm.TICK_PHASES))
+    if wall_of == "ticks":
+        assert wall - total <= 0.05 * wall + 0.5, (total, wall, grown)
+    else:
+        assert grown[tm.BETWEEN_TICKS] > 0 and grown[tm.BETWEEN_PHASES] > 0
+        assert wall - total <= 0.01 * wall + 0.5, (total, wall, grown)
+    assert c.total() == pytest.approx(sum(c.total(phase=p) for p in (*tm.TICK_PHASES, *tm.LOOP_GAPS)))
 
 
 def test_metrics_render_every_phase_from_startup(paged_engine):
@@ -145,18 +159,21 @@ def test_metrics_render_every_phase_from_startup(paged_engine):
         text = tm.registry().render()
     finally:
         sched.close()
-    for p in tm.TICK_PHASES:
+    for p in (*tm.TICK_PHASES, *tm.LOOP_GAPS):
         assert f'{tm.TICK_PHASE_MS}{{phase="{p}"}}' in text, p
 
 
-def test_profiler_capture_holds_the_tick_with_its_children(paged_engine, tmp_path):
-    """A ``jax.profiler`` capture around a few hand-driven ticks: the
-    ``dllama.tick`` spans carry the flight recorder's tick number, every
-    ``dllama.tick.<phase>`` span lies inside one of them on the same line,
-    and an admitting ``admit_begin`` says how many it admitted."""
+@pytest.fixture(scope="module")
+def capture(paged_engine, tmp_path_factory):
+    """A ``jax.profiler`` capture around a few hand-driven ticks, read back:
+    the roots, their children and the between-ticks spans as ``(plane, line,
+    start_ns, end_ns, ...)``, the number of ticks driven and the recorder's
+    tick number going in."""
     import jax
     from jax.profiler import ProfileData
 
+    tmp_path = tmp_path_factory.mktemp("capture")
+    flightrec.recorder().reset()
     sched = BatchScheduler(paged_engine, n_slots=2, _start_thread=False)
     try:
         _drive(sched, [sched.submit(paged_engine.tokenizer.encode(PROMPTS[0], is_start=True), 4,
@@ -175,16 +192,26 @@ def test_profiler_capture_holds_the_tick_with_its_children(paged_engine, tmp_pat
         sched.close()
     path = max(glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True),
                key=os.path.getmtime)
-    roots, kids = [], []
+    roots, kids, gaps = [], [], []
     for plane in ProfileData.from_file(path).planes:
         for li, line in enumerate(plane.lines):
             for ev in line.events:
+                at = (plane.name, li, ev.start_ns, ev.start_ns + ev.duration_ns)
                 if ev.name == tm.TICK_SPAN:
-                    roots.append((plane.name, li, ev.start_ns, ev.start_ns + ev.duration_ns,
-                                  dict(ev.stats)))
+                    roots.append((*at, dict(ev.stats)))
                 elif ev.name.startswith(tm.TICK_SPAN + "."):
-                    kids.append((plane.name, li, ev.start_ns, ev.start_ns + ev.duration_ns,
-                                 ev.name[len(tm.TICK_SPAN) + 1:], dict(ev.stats)))
+                    kids.append((*at, ev.name[len(tm.TICK_SPAN) + 1:], dict(ev.stats)))
+                elif ev.name == tm.LOOP_GAP_SPAN:
+                    gaps.append((*at, dict(ev.stats)))
+    return {"roots": roots, "kids": kids, "gaps": gaps, "n": n, "seq0": seq0}
+
+
+def test_profiler_capture_holds_the_tick_with_its_children(capture):
+    """A ``jax.profiler`` capture around a few hand-driven ticks: the
+    ``dllama.tick`` spans carry the flight recorder's tick number, every
+    ``dllama.tick.<phase>`` span lies inside one of them on the same line,
+    and an admitting ``admit_begin`` says how many it admitted."""
+    roots, kids, n, seq0 = (capture[k] for k in ("roots", "kids", "n", "seq0"))
     assert len(roots) == n
     assert sorted(int(r[4]["tick"]) for r in roots) == list(range(seq0 + 1, seq0 + n + 1))
     assert all("n_active" in r[4] for r in roots)
@@ -194,6 +221,25 @@ def test_profiler_capture_holds_the_tick_with_its_children(paged_engine, tmp_pat
                    for p, l, rs, re, _st in roots)
     admitted = [int(k[5]["admitted"]) for k in kids if k[4] == "admit_begin"]
     assert sum(admitted) == 2 and len(admitted) == n
+
+
+def test_profiler_capture_tiles_the_loops_life_and_carries_cpu_time(capture):
+    """Between every two roots on the loop thread's line lies ONE
+    ``dllama.loop.between_ticks`` span, overlapping neither, and every root
+    carries ``cpu_us`` (the loop thread's CPU time over the tick, at most its
+    wall but for the clocks' grain); a gap carries its own."""
+    roots = sorted(capture["roots"], key=lambda r: r[2])
+    gaps = sorted(capture["gaps"], key=lambda g: g[2])
+    assert len({(r[0], r[1]) for r in roots}) == 1 and {(g[0], g[1]) for g in gaps} == {roots[0][:2]}
+    assert len(gaps) >= len(roots) - 1
+    for before, after in zip(roots, roots[1:]):
+        inside = [g for g in gaps if before[3] <= g[2] and g[3] <= after[2]]
+        assert len(inside) == 1, (before[4], after[4], inside)
+        assert "cpu_us" in inside[0][4]
+    for _plane, _li, s, e, _stats in gaps:
+        assert not any(rs < e and s < re for _p, _l, rs, re, _st in roots)
+    for _plane, _li, s, e, stats in roots:
+        assert 0 <= int(stats["cpu_us"]) <= (e - s) / 1e3 + 500.0
 
 
 def test_zero_post_steady_compiles_with_the_spans_in(paged_engine):
