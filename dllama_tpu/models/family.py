@@ -70,10 +70,10 @@ class Family:
     # (llama.forward_and_step's arguments, plus the chunk's valid length in
     # front of ``poison`` where ``cfg.paged_only``), or None: a chunk and a
     # step are two. Brought by the dense decoders (PR 47), falcon_h1 (PR 52),
-    # lfm2 (PR 53) and the hybrid (PR 55): a third set of closures over the
-    # family's one layer walk. ``cache`` is ``(column, <the step's cache>)``;
-    # where that holds routing counters the program adds ITS dispatches' to
-    # the totals' chunk row (models/lfm2.py says why)
+    # lfm2 (PR 53), the hybrid (PR 55) and laguna (PR 57): a third set of
+    # closures over the family's one layer walk. ``cache`` is ``(column,
+    # <the step's cache>)``; where that holds routing counters the program
+    # adds ITS dispatches' to the totals' chunk row (models/lfm2.py says why)
     tick: Callable | None
     # (cfg, k, v) -> an admission's column from the slot's gathered view
     # (``v`` None where the pool has no V plane)

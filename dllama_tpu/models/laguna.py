@@ -49,9 +49,23 @@ to the free list). Both ride the period scan's carry whole and are written
 in place (PERF.md section 6, PR 33). During chunked prefill a slot's context
 is ONE dense column over all layers (:class:`LagunaColumn`).
 
+**Three programs, three pairs of closures over ONE walk**
+(:func:`_scan_periods`): :func:`forward` (a prefill chunk over an admission's
+column, :func:`_column_attends`), :func:`paged_forward` (the decode step, one
+token a row, over the two pools in place, :func:`_pool_attends`) and
+:func:`forward_and_step` (``FAMILY.tick``, PR 57: a chunk AND the tick's
+decode rows through one pass over every plane, the other two programs'
+closures side by side; the paged server's program for every plain chunk; no
+chunk logits). The walk does everything a layer does a row at a time; the two
+closures are all that knows whose rows they are.
+
 **Counters** ride the same carry: ``stats`` = (pairs computed here, pairs
 that fell on absent experts, tokens each held expert saw), summed over the
-layers, accumulated on the device and given back with the pools.
+layers, accumulated on the device and given back with the pools. A step adds
+its dispatches' to row 0 of the running totals (``share.zero_totals``), a
+chunk's ride ``col.stats`` to the admission's commit, which adds them to row
+1; the tick program's one joined dispatch is a chunk-form one and adds ALL of
+its counters to row 1 itself (``models/lfm2.py`` says why).
 """
 
 from __future__ import annotations
@@ -68,7 +82,8 @@ from ..parallel.api import current_plan
 from ..runtime.kvcache import update_layer
 from .config import ModelConfig
 from .family import Family, Refusal, layer_kinds
-from .llama import Params, _attend_dense, _attend_paged, _stack_at
+from .llama import (Params, _attend_dense, _attend_paged, _exact_f32_dots,
+                    _nonfinite_rows, _poison_logits, _stack_at)
 from .rope import apply_rope_partial, build_partial_rope_cache
 from .share import (ffn_half, require_quantized, route,  # noqa: F401
                     routed_ffn, routed_pairs, zero_stats, zero_totals)
@@ -189,7 +204,7 @@ def _attend_window_dense(cfg: ModelConfig, q, k, v, k_l, v_l, start_pos,
     return att, k_l, v_l
 
 
-# -- the two programs --------------------------------------------------------
+# -- the three programs ------------------------------------------------------
 
 
 def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
@@ -199,11 +214,17 @@ def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 def _scan_periods(params: Params, cfg: ModelConfig, x, caches, stats, live,
                   positions, attend_full, attend_slide):
-    """The period scan both programs share. ``caches`` (a column's arrays, or
-    the two pools') and ``stats`` ride the carry whole.
+    """The period scan the three programs share: the hidden rows ``[B, T,
+    dim]`` behind the last layer, in front of the final norm (:func:`_head`).
+    ``caches`` (a column's arrays, the two pools', or (the tick program) a
+    pair of both) and ``stats`` ride the carry whole.
     ``attend_full(q, k, v, caches, p) -> (att, caches)`` is period ``p``'s
     full layer, ``attend_slide(q, k, v, caches, p, j)`` its ``j``-th sliding
-    one."""
+    one. Everything else a layer does (the norms, the per-head gate, the
+    partial rotary by ``positions``, the dense planes, the routed
+    feed-forward over the rows that are ``live``) it does a row at a time, so
+    the rows along ``T`` need not be one sequence's: only the two closures
+    know."""
     P = cfg.layer_period
     lp: LagunaLayers = params.layers
     t_full, t_slide = rope_tables(cfg)
@@ -240,7 +261,48 @@ def _scan_periods(params: Params, cfg: ModelConfig, x, caches, stats, live,
     (x, caches, stats), _ = jax.lax.scan(
         period, (x, caches, stats),
         jnp.arange(cfg.n_periods, dtype=jnp.int32))
-    return _head(params, cfg, x), caches, stats
+    return x, caches, stats
+
+
+def _column_attends(cfg: ModelConfig, start_pos, positions):
+    """A chunk's two closures: its rows at ``start_pos`` over ``(k, v)`` of
+    a dense column ``[L, 1, n_kv, S, hd]``, a full layer over all of its
+    layer's keys, a sliding one over the span its windows reach."""
+    P = cfg.layer_period
+
+    def over(attend, q, k, v, kv, l):
+        at = lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+        put = lambda a, a_l: jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
+        att, k_l, v_l = attend(cfg, q, k, v, at(kv[0]), at(kv[1]), start_pos,
+                               positions)
+        return att, (put(kv[0], k_l), put(kv[1], v_l))
+
+    return (lambda q, k, v, kv, p: over(_attend_dense, q, k, v, kv, p * P),
+            lambda q, k, v, kv, p, j: over(_attend_window_dense, q, k, v, kv,
+                                           p * P + 1 + j))
+
+
+def _pool_attends(cfg: ModelConfig, positions, t_full, t_win):
+    """The decode rows' two closures: one token a row at ``positions [B,
+    1]``, written in place into ``(full k, full v, window k, window v)``
+    through the rows' full-pool and window-pool tables ``[B, M]``, a sliding
+    layer's walk behind its window."""
+    P = cfg.layer_period
+
+    def attend_full(q, k, v, pools, p):
+        fk, fv, wk, wv = pools
+        att, fk, fv = _attend_paged(cfg, q, k, v, fk, fv, p, positions,
+                                    t_full)
+        return att, (fk, fv, wk, wv)
+
+    def attend_slide(q, k, v, pools, p, j):
+        fk, fv, wk, wv = pools
+        att, wk, wv = _attend_paged(cfg, q, k, v, wk, wv, p * (P - 1) + j,
+                                    positions, t_win,
+                                    window=cfg.sliding_window)
+        return att, (fk, fv, wk, wv)
+
+    return attend_full, attend_slide
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -262,31 +324,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     x = params.embedding[tokens].astype(cfg.compute_dtype)
     positions = jnp.broadcast_to(
         start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
-    P = cfg.layer_period
-
-    def at(a, l):
-        return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-
-    def put(a, a_l, l):
-        return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
-
-    def attend_full(q, k, v, kv, p):
-        k_c, v_c = kv
-        att, k_l, v_l = _attend_dense(cfg, q, k, v, at(k_c, p * P),
-                                      at(v_c, p * P), start_pos, positions)
-        return att, (put(k_c, k_l, p * P), put(v_c, v_l, p * P))
-
-    def attend_slide(q, k, v, kv, p, j):
-        k_c, v_c = kv
-        l = p * P + 1 + j
-        att, k_l, v_l = _attend_window_dense(cfg, q, k, v, at(k_c, l),
-                                             at(v_c, l), start_pos, positions)
-        return att, (put(k_c, k_l, l), put(v_c, v_l, l))
-
-    logits, (k, v), stats = _scan_periods(
+    x, (k, v), stats = _scan_periods(
         params, cfg, x, (col.k, col.v), col.stats, live, positions,
-        attend_full, attend_slide)
-    return logits, LagunaColumn(k=k, v=v, stats=stats)
+        *_column_attends(cfg, start_pos, positions))
+    return _head(params, cfg, x), LagunaColumn(k=k, v=v, stats=stats)
 
 
 def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -311,26 +352,92 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]
     live = t_full[:, 0] != 0
     x = params.embedding[tokens].astype(cfg.compute_dtype)
-    P = cfg.layer_period
-
-    def attend_full(q, k, v, pools, p):
-        fk, fv, wk, wv = pools
-        att, fk, fv = _attend_paged(cfg, q, k, v, fk, fv, p, positions,
-                                    t_full)
-        return att, (fk, fv, wk, wv)
-
-    def attend_slide(q, k, v, pools, p, j):
-        fk, fv, wk, wv = pools
-        att, wk, wv = _attend_paged(cfg, q, k, v, wk, wv, p * (P - 1) + j,
-                                    positions, t_win,
-                                    window=cfg.sliding_window)
-        return att, (fk, fv, wk, wv)
-
-    logits, (fk, fv, wk, wv), stats = _scan_periods(
+    x, (fk, fv, wk, wv), stats = _scan_periods(
         params, cfg, x, (pkv.k, pkv.v, wkv.k, wkv.v), zero_stats(cfg), live,
-        positions, attend_full, attend_slide)
-    return logits, (PagedKVCache(k=fk, v=fv), PagedKVCache(k=wk, v=wv),
-                    totals.at[0].add(stats))
+        positions, *_pool_attends(cfg, positions, t_full, t_win))
+    return _head(params, cfg, x), (PagedKVCache(k=fk, v=fv),
+                                   PagedKVCache(k=wk, v=wv),
+                                   totals.at[0].add(stats))
+
+
+@_exact_f32_dots
+def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                     pos_vec: jax.Array, cache, tables: jax.Array,
+                     chunk: jax.Array, chunk_pos: jax.Array,
+                     n_valid: jax.Array, poison: jax.Array):
+    """A tick that carries a prefill chunk, as ONE program
+    (``Family.tick``; ``lfm2.forward_and_step``'s signature):
+    :func:`forward` over ``chunk [1, T]`` at ``chunk_pos`` into an
+    admission's column AND :func:`paged_forward`'s layers over the tick's
+    decode rows (``tokens [R, 1]`` at ``pos_vec`` through ``tables [2, R,
+    M]``), so that every plane of every layer, a routed expert's among them,
+    is read once for both. ``cache`` is ``(column, (full PagedKVCache, window
+    PagedKVCache, totals))``, all given back (and donated where the server
+    jits this).
+
+    ONE call of :func:`_scan_periods` over the joined rows ``[1, T + R]``,
+    its carry the column's ``k, v`` AND the four pool arrays whole. Its two
+    closures are the other two programs' own, side by side: the chunk's
+    ``T`` rows through :func:`_column_attends`, the ``R`` decode rows through
+    :func:`_pool_attends` (a full layer into the full pool through
+    ``tables[0]``, a sliding one into the window pool through ``tables[1]``
+    behind its window). A row whose full table is all null is dead, as an
+    inactive slot of a step is (null blocks in BOTH tables, not routed), and
+    every row may be; the chunk's padding is not routed either.
+
+    **The routed half is ONE dispatch of the chunk form** over the joined
+    rows (``T + R`` is past ``share.step_form``): a plane fetched once a RUN
+    over the union of what the chunk and the rows chose. Its counters are
+    therefore a chunk-form dispatch's, ALL of them (the chunk's pairs and the
+    decode rows', rows fed, planes, tokens an expert), and the program adds
+    them itself to the totals' CHUNK row (row 1), not through ``col.stats``
+    and the commit: each pair is counted once (``models/lfm2.py`` says why).
+    ``col.stats`` goes back as it came; row 0 is "what the step PROGRAM's
+    dispatches did" and is left as it was.
+
+    The head runs for the decode ROWS alone: no chunk logits exist (the
+    serving prefill never read one). Returns ``((token, nonfinite, logits),
+    (column, (pkv, wkv, totals)))`` as the dense tick does: ``token`` each
+    row's ARGMAX, ``logits [R, V]`` float32 and poisoned as the step's are,
+    for ``ops.sampling.sampled_token`` where a row samples."""
+    from ..runtime.kvblocks import PagedKVCache
+
+    _check(cfg)
+    col, (pkv, wkv, totals) = cache
+    chunk_pos = jnp.asarray(chunk_pos, dtype=jnp.int32)
+    n_valid = jnp.asarray(n_valid, dtype=jnp.int32)
+    T = chunk.shape[1]
+    joined = jnp.concatenate([chunk[0], tokens[:, 0]])[None]        # [1, T+R]
+    cpos = (chunk_pos + jnp.arange(T, dtype=jnp.int32))[None, :]    # [1, T]
+    rpos = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]           # [R, 1]
+    positions = jnp.concatenate([cpos, rpos.T], axis=1)
+    t_full, t_win = tables[0], tables[1]
+    live = jnp.concatenate([jnp.arange(T) < n_valid, t_full[:, 0] != 0])
+    x = params.embedding[joined].astype(cfg.compute_dtype)
+    by_row = lambda a: jnp.swapaxes(a[:, T:], 0, 1)              # [R, 1, ...]
+
+    def side_by_side(of_chunk, of_rows):
+        def attend(q, k, v, caches, *where):
+            att_c, kv = of_chunk(q[:, :T], k[:, :T], v[:, :T], caches[0],
+                                 *where)
+            att_r, pools = of_rows(by_row(q), by_row(k), by_row(v),
+                                   caches[1], *where)
+            return (jnp.concatenate([att_c, jnp.swapaxes(att_r, 0, 1)],
+                                    axis=1), (kv, pools))
+        return attend
+
+    x, ((k, v), (fk, fv, wk, wv)), stats = _scan_periods(
+        params, cfg, x, ((col.k, col.v), (pkv.k, pkv.v, wkv.k, wkv.v)),
+        zero_stats(cfg), live, positions,
+        *map(side_by_side, _column_attends(cfg, chunk_pos, cpos),
+             _pool_attends(cfg, rpos, t_full, t_win)))
+    logits = _head(params, cfg, jnp.swapaxes(x[:, T:], 0, 1))      # [R, 1, V]
+    last = _poison_logits(logits[:, -1, :], poison)
+    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    return ((greedy, _nonfinite_rows(last), last),
+            (col._replace(k=k, v=v),
+             (PagedKVCache(k=fk, v=fv), PagedKVCache(k=wk, v=wv),
+              totals.at[1].add(stats))))
 
 
 def _load_params(ld, cfg: ModelConfig) -> Params:
@@ -395,7 +502,7 @@ def _matmul_weight_count(cfg: ModelConfig) -> int:
 FAMILY = Family(
     forward=forward,
     paged_forward=paged_forward,
-    tick=None,
+    tick=forward_and_step,
     # prefix blocks are never shared here, so an admission's column starts
     # empty (the slot's gathered view is not read); every layer's rows are
     # built in it, the two pools see them at commit

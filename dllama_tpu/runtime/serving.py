@@ -1636,8 +1636,11 @@ class PagedGenerator(_GeneratorCore):
             self.wpool = BlockPool(n_wblocks, block_size)
             wshape = (self.cfg.n_window_layers, n_wblocks,
                       self.cfg.n_kv_heads, block_size, self.cfg.head_dim)
-            self.wkv = PagedKVCache(k=jnp.zeros(wshape, engine.kv_dtype),
-                                    v=jnp.zeros(wshape, engine.kv_dtype))
+            # pinned as the blocks are: a tick program takes the window
+            # pool before any step or commit has left it there
+            self.wkv = self._pin_home(
+                PagedKVCache(k=jnp.zeros(wshape, engine.kv_dtype),
+                             v=jnp.zeros(wshape, engine.kv_dtype)))
         # window blocks a slot owns, by table index: host truth from
         # begin_admit on (the table row is published at commit)
         self._wbids: list[dict[int, int]] = [{} for _ in range(n_slots)]
@@ -1709,9 +1712,12 @@ class PagedGenerator(_GeneratorCore):
 
             self._tick = steppack.jit_packed_step(
                 family.tick, scope=_sc, name="forward_and_step")
+            # the tables in the shape a step takes them (_run_rows): with a
+            # window pool, a row's two side by side
             self._dead_rows = (
                 np.zeros((n_slots, 1), np.int32), np.zeros(n_slots, np.int32),
-                np.zeros_like(self.tables))
+                np.zeros_like(self._both_tables if self.window
+                              else self.tables))
             # the tick program ends in an argmax (what the step's sampler
             # gives a batch in which no row samples) and hands back the
             # rows' logits; where a row does sample, the sampler runs over
@@ -2596,10 +2602,14 @@ class PagedGenerator(_GeneratorCore):
             n_sh = self._n_shared[slot]
             put_table[n_sh:len(bids)] = bids[n_sh:]
             if self.wpool is not None:
-                self.pkv, self.wkv, self.moe_stats = self._put_window(
+                self.pkv, self.wkv, totals = self._put_window(
                     self.pkv, self.wkv, self.moe_stats, adm.col,
                     jnp.asarray(put_table),
                     jnp.asarray(self._wtable_row(slot)))
+                # the commit hands the totals back spelled ``()``, the step
+                # and the tick program by rank: one spelling, or the chunk
+                # behind a commit keys a second executable a bucket
+                self.moe_stats = self._pin_home(totals, by_rank=True)
             elif self.latent:
                 self.pkv, self.moe_stats = self._put_latent(
                     self.pkv, self.moe_stats, adm.col,

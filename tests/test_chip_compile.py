@@ -502,7 +502,10 @@ def test_expert_gemv_stripes_a_wide_plane_for_v5e(one_chip, k, n, tn):
     (23, 32, 10, 3072, 1024, 256, False), (23, 32, 10, 1024, 3072, 256, True),     # laguna-s-2.1: a plane lands whole
     (9, 12, 8, 7168, 2048, 256, False), (9, 12, 8, 2048, 7168, 256, True),         # A.X-K1: in stripes
     (23, 32, 10, 3072, 1024, 32, False), (23, 32, 10, 1024, 3072, 32, True),       # the narrowest bucket: 40 pairs over 32 experts
-    (4, 64, 8, 3072, 1024, 256, False), (4, 64, 8, 1024, 3072, 256, True)])        # a whole layer held: 32 rows an expert
+    (4, 64, 8, 3072, 1024, 256, False), (4, 64, 8, 1024, 3072, 256, True)]         # a whole layer held: 32 rows an expert
+    # laguna's TICK (PR 57): a bucket's rows and the 16 decode rows as one dispatch, whole (no piece: a plane once a run)
+    + [case for rows in (48, 80, 144, 272) for case in ((23, 32, 10, 3072, 1024, rows, False),
+                                                        (23, 32, 10, 1024, 3072, rows, True))])
 def test_expert_chunk_compiles_for_v5e(one_chip, layers, held, k, kk, n, rows, scatter):
     """The routed chunk kernel at the two clients' expert planes, both ends,
     at the static bound of the fed layout (2,432 rows for A.X-K1's 2,048

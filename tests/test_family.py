@@ -142,7 +142,7 @@ TICK = {
     ArchType.LLAMA: ("llama", "forward_and_step"),
     ArchType.QWEN3: ("llama", "forward_and_step"),
     ArchType.OLMO_HYBRID: ("hybrid", "forward_and_step"),
-    ArchType.LAGUNA: None,
+    ArchType.LAGUNA: ("laguna", "forward_and_step"),
     ArchType.FALCON_H1: ("falcon_h1", "forward_and_step"),
     ArchType.AXK1: None,
     ArchType.LFM2: ("lfm2", "forward_and_step"),

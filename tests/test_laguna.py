@@ -249,8 +249,10 @@ def test_padded_chunked_prefill_then_decode_logits(bench, engine, n_prompt, kern
     gen.admit(Request(rid=1, prompt_ids=prompt, max_tokens=n_steps, stop_on_eos=False), 1)
     got = _decode(gen, [1], n_steps)[1]
     emitted = got.argmax(axis=1).tolist()
-    # traced once each: one full layer's body, one sliding layer's; two routed bodies of three GEMVs
-    assert (sorted(calls["attention"]), calls["experts"]) == (([0, 32], 6) if kernel else ([], 0))
+    # traced once a program (the step, and the tick program of each of the two buckets either prompt pads to, whose
+    # decode rows walk the pools too): one full layer's body, one sliding layer's; two routed bodies of three GEMVs
+    # in the step ALONE (a tick program's routed half is the chunk form)
+    assert (sorted(calls["attention"]), calls["experts"]) == (([0] * 3 + [32] * 3, 6) if kernel else ([], 0))
     want = _reference_logits(bench, engine.params, prompt + emitted)[n_prompt - 1:n_prompt - 1 + n_steps]
     assert float(np.abs(got - want).max()) < LOGIT_TOL
     # the window pool holds the window and no more; the table's entries behind it are null
@@ -446,12 +448,18 @@ def test_a_returned_block_is_reused_while_the_first_row_still_decodes(bench, eng
     assert returned and gen._m_wblocks_returned.total() >= len(returned)
     gen.admit(Request(rid=2, prompt_ids=b, max_tokens=64, stop_on_eos=False), 1)
     assert returned & set(gen._wbids[1].values())                 # B holds blocks A gave back
+    # A's row rode B's first chunk (the tick program, PR 57): one token of A's whose logits nobody kept, written at
+    # position 80 into a block the window pool handed out after B had taken its own
+    assert gen.take_rows_rode() and gen.pos[0] == 81
+    rode = int(gen.next_token[0])
     both = _decode(gen, [0, 1], 20)
-    got_a = np.concatenate([got_a1, both[0]])
-    for prompt, got in ((a, got_a), (b, both[1])):
-        emitted = got.argmax(axis=1).tolist()
-        want = _reference_logits(bench, engine.params, prompt + emitted)[len(prompt) - 1:len(prompt) - 1 + len(emitted)]
-        assert float(np.abs(got - want).max()) < LOGIT_TOL
+    emitted_a = got_a1.argmax(axis=1).tolist() + [rode] + both[0].argmax(axis=1).tolist()
+    want = _reference_logits(bench, engine.params, a + emitted_a)[len(a) - 1:len(a) - 1 + 42]
+    assert float(np.abs(got_a1 - want[:21]).max()) < LOGIT_TOL and int(want[21].argmax()) == rode
+    assert float(np.abs(both[0] - want[22:]).max()) < LOGIT_TOL
+    emitted = both[1].argmax(axis=1).tolist()
+    want = _reference_logits(bench, engine.params, b + emitted)[len(b) - 1:len(b) - 1 + len(emitted)]
+    assert float(np.abs(both[1] - want).max()) < LOGIT_TOL
     gen._retire(0)
     gen._retire(1)
     assert (gen.pool.free_blocks(), gen.wpool.free_blocks()) == (free0, wfree0)
