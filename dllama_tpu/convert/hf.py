@@ -39,6 +39,7 @@ ARCH_BY_MODEL_TYPE = {
     "lfm2_moe": ArchType.LFM2,
     "nemotron_h": ArchType.NEMOTRON_H,
     "granitemoehybrid": ArchType.GRANITE_HYBRID,
+    "solar_open2": ArchType.SOLAR_OPEN2,
 }
 
 # behind a refusal of ``tie_word_embeddings``: which families do carry a tie
@@ -120,12 +121,12 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
     params: dict = {
         "version": 0,
         "arch_type": int(ARCH_BY_MODEL_TYPE[model_type]),
-        # the laguna and lfm2_moe configs name no activation: their
-        # feed-forwards are SwiGLU
+        # the laguna, lfm2_moe and solar_open2 configs name no activation:
+        # their feed-forwards are SwiGLU
         # nemotron_h names its feed-forwards' activation ``mlp_hidden_act``
         "hidden_act": int(HIDDEN_ACT_BY_NAME[
             cfg.get("hidden_act", "silu")
-            if model_type in ("laguna", "lfm2_moe")
+            if model_type in ("laguna", "lfm2_moe", "solar_open2")
             else cfg["mlp_hidden_act"] if model_type == "nemotron_h"
             else cfg["hidden_act"]]),
         "dim": cfg["hidden_size"],
@@ -179,6 +180,8 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
         return {**params, **_nemotron_h_header(cfg)}
     if model_type == "granitemoehybrid":
         return {**params, **_granite_hybrid_header(cfg)}
+    if model_type == "solar_open2":
+        return {**params, **_solar_open2_header(cfg)}
 
     if model_type == "falcon_h1":
         params.update(_falcon_h1_header(cfg))
@@ -576,6 +579,115 @@ def _granite_hybrid_plan(params: dict) -> list["PlanItem"]:
     return plan
 
 
+def _solar_open2_header(cfg: dict) -> dict:
+    """``model_type: solar_open2``'s config keys as the header's extension keys
+    (formats/mfile.py, HeaderKey 77-79, the hybrid's 22-28, the share's 35-38,
+    21, 67 and 71). ``gqa_layers`` must be the FIRST layer of every period of
+    ``gqa_interval + 1``. A whole checkpoint holds every expert: the router's
+    width is ``n_routed_experts`` and the first held expert 0. What the config
+    does not say (pre-norm; the full layers' gate a plane of the normed input,
+    one sigmoid a lane in front of ``o_proj``; the low-rank gates' inner width
+    = ``linear_attn_config.head_dim``, which is the Kimi Delta Attention
+    paper's; a decay a key channel from ``A_log`` a head and ``dt_bias`` a
+    channel; a sigmoid router whose bias enters the selection only; an
+    ungated shared expert ``moe_intermediate_size`` wide; ``intermediate_size``
+    unread, there being no dense layer) the arch implies
+    (models/solar_open2.py)."""
+    lin = cfg["linear_attn_config"]
+    P, L = int(cfg["gqa_interval"]) + 1, int(cfg["num_hidden_layers"])
+    eps = cfg.get("rms_norm_eps")
+    if L % P or list(cfg["gqa_layers"]) != list(range(0, L, P)) \
+            or eps not in (1e-5, 1e-6) \
+            or lin.get("num_kv_heads") not in (None, lin["num_heads"]):
+        raise ValueError(
+            "solar_open2: gqa_layers is not the first of every gqa_interval "
+            "+ 1 layers in whole periods, the norm epsilon is neither 1e-5 "
+            "nor 1e-6, or the mixer's K/V heads are not its heads")
+    if (cfg.get("use_rope") or not cfg.get("use_gqa_gate")
+            or cfg.get("kda_use_full_proj") or cfg.get("first_k_dense_replace")
+            or cfg.get("n_shared_experts") != 1
+            or cfg.get("tie_word_embeddings")):
+        raise ValueError(
+            "solar_open2: a rotary embedding, an ungated full layer, "
+            "full-rank gate projections, leading dense layers, another "
+            "count of shared experts than one and tied embeddings are not "
+            "carried " + _TIE_CARRIED_BY)
+    return {
+        "hidden_dim": int(cfg["moe_intermediate_size"]),
+        "n_experts": int(cfg["n_routed_experts"]),
+        "n_active_experts": int(cfg["num_experts_per_tok"]),
+        "moe_norm_topk": int(bool(cfg.get("norm_topk_prob", True))),
+        "head_dim": int(cfg["head_dim"]),
+        "norm_epsilon": 5 if eps == 1e-5 else 6,
+        "layer_period": P,
+        "full_layer_at": 0,
+        "linear_n_key_heads": int(lin["num_heads"]),
+        "linear_n_value_heads": int(lin["num_heads"]),
+        "linear_key_head_dim": int(lin["head_dim"]),
+        "linear_value_head_dim": int(lin["head_dim"]),
+        "linear_conv_kernel": int(lin["short_conv_kernel_size"]),
+        "linear_neg_eigval": int(bool(cfg.get("kda_allow_neg_eigval"))),
+        "linear_decay_dim": int(lin["head_dim"]),
+        "linear_gate_rank": int(lin["head_dim"]),
+        "shared_expert_dim": int(cfg["moe_intermediate_size"]),
+        "moe_routed_scale_milli": round(
+            1000 * float(cfg.get("routed_scaling_factor", 1.0))),
+        "moe_router_width": int(cfg["n_routed_experts"]),
+        "moe_first_expert": 0,
+        "moe_score_func": 1,
+        "moe_select_bias": 1,
+    }
+
+
+def _solar_open2_plan(params: dict) -> list["PlanItem"]:
+    """``model_type: solar_open2``'s tensors in the order
+    ``mfile._walk_solar_open2_layer`` reads them. NO CHECKPOINT WAS AT HAND:
+    the delta-rule layers' names are the Kimi Delta Attention reference
+    implementation's (``self_attn.q_proj k_proj v_proj``, ``q_conv1d k_conv1d
+    v_conv1d`` with weights ``[C, 1, K]``, ``A_log``, ``f_a_proj f_b_proj``,
+    ``dt_bias``, ``b_proj``, ``g_a_proj g_b_proj``, ``o_norm``, ``o_proj``), whose
+    ``linear_attn_config`` keys this config repeats letter for letter; the
+    routed half's are the DeepSeek-V3 family's (``mlp.gate`` with its
+    ``e_score_correction_bias``, ``mlp.experts.N.*``, ``mlp.shared_experts.*``);
+    the full layers' gate is ASSUMED to be ``self_attn.g_proj``. A name that
+    differs fails with the tensor it did not find."""
+    wt = params["weight_float_type"]
+    P, at0 = params["layer_period"], params["full_layer_at"]
+    taps = lambda w: np.ascontiguousarray(w[:, 0, :].T)   # [C, 1, K] -> [K, C]
+    flat = lambda w: np.ascontiguousarray(w.reshape(-1))
+    plan = [PlanItem(("model.embed_tokens.weight",), F32)]
+    for l in range(params["n_layers"]):
+        at = lambda name, l=l: (f"model.layers.{l}.{name}",)
+        sa = lambda name: at("self_attn." + name)
+        if l % P == at0:
+            plan += [PlanItem(sa(f"{p}_proj.weight"), wt) for p in "qkvog"]
+        else:
+            plan += [PlanItem(sa(f"{p}_proj.weight"), wt) for p in "qkv"]
+            plan += [PlanItem(sa(f"{p}_conv1d.weight"), F32, taps)
+                     for p in "qkv"]
+            plan += [PlanItem(sa("A_log"), F32, flat),
+                     PlanItem(sa("f_a_proj.weight"), F32),
+                     PlanItem(sa("f_b_proj.weight"), F32),
+                     PlanItem(sa("dt_bias"), F32, flat),
+                     PlanItem(sa("b_proj.weight"), F32),
+                     PlanItem(sa("g_a_proj.weight"), F32),
+                     PlanItem(sa("g_b_proj.weight"), F32),
+                     PlanItem(sa("o_norm.weight"), F32),
+                     PlanItem(sa("o_proj.weight"), wt)]
+        plan += [PlanItem(at("mlp.gate.weight"), F32),
+                 PlanItem(at("mlp.gate.e_score_correction_bias"), F32)]
+        for e in range(params["n_experts"]):
+            plan += [PlanItem(at(f"mlp.experts.{e}.{p}_proj.weight"), wt)
+                     for p in ("up", "gate", "down")]
+        plan += [PlanItem(at(f"mlp.shared_experts.{p}_proj.weight"), wt)
+                 for p in ("gate", "down", "up")]
+        plan += [PlanItem(at("input_layernorm.weight"), F32),
+                 PlanItem(at("post_attention_layernorm.weight"), F32)]
+    plan.append(PlanItem(("model.norm.weight",), F32))
+    plan.append(PlanItem(("lm_head.weight",), wt))
+    return plan
+
+
 def _lfm2_header(cfg: dict) -> dict:
     """``model_type: lfm2_moe``'s config keys as the header's extension keys
     (formats/mfile.py, HeaderKey 70-71, 22, the share's 33-38, 21 and 67).
@@ -759,6 +871,8 @@ def hf_tensor_plan(params: dict) -> list[PlanItem]:
         return _nemotron_h_plan(params)
     if arch == ArchType.GRANITE_HYBRID:
         return _granite_hybrid_plan(params)
+    if arch == ArchType.SOLAR_OPEN2:
+        return _solar_open2_plan(params)
     if arch == ArchType.FALCON_H1:
         raise NotImplementedError(
             "falcon_h1: the header is mapped (load_hf_config), the "

@@ -195,6 +195,22 @@ class HeaderKey(enum.IntEnum):
     RESIDUAL_MULT = 74
     ATTN_SCALE = 75
     TIED_EMBEDDINGS = 76
+    # OUR format extension, read by ArchType.SOLAR_OPEN2 only
+    # (models/solar_open2.py): a delta rule whose decay is a VECTOR a head
+    # (Kimi Delta Attention) beside gated full attention, routed experts
+    # behind both. The arch shares LAYER_PERIOD and the ``linear_*`` sizes
+    # (22-28) with OLMO_HYBRID, SHARED_EXPERT_DIM .. MOE_FIRST_EXPERT
+    # (35-38), MOE_NORM_TOPK, MOE_SCORE_FUNC and MOE_SELECT_BIAS with the
+    # routed archs. Two of the three STATE what the arch implies and are
+    # refused at any other value: LINEAR_DECAY_DIM, decays a head
+    # (LINEAR_KEY_HEAD_DIM: one a key channel; one number a head is
+    # OLMO_HYBRID's rule and lives there), and FULL_LAYER_AT, where the
+    # full layer stands in its period (0, first; OLMO_HYBRID's is
+    # LAYER_PERIOD - 1 and not written). LINEAR_GATE_RANK: the inner width
+    # of the decay's and the output gate's low-rank projections.
+    LINEAR_DECAY_DIM = 77
+    LINEAR_GATE_RANK = 78
+    FULL_LAYER_AT = 79
 
 
 class ArchType(enum.IntEnum):
@@ -239,6 +255,14 @@ class ArchType(enum.IntEnum):
     # the embedding's and the logits' stated, the head tied to the embedding
     # (models/granite_hybrid.py)
     GRANITE_HYBRID = 0xABCD08
+    # ours: three delta-rule layers whose decay is a vector a head (Kimi
+    # Delta Attention: three projections, a short convolution on each, a
+    # low-rank decay and a low-rank sigmoid output gate) behind one full
+    # grouped-query layer without positions whose output is gated a lane, in
+    # every period; pre-norm; behind EVERY mixer a sigmoid router with a
+    # selection-only bias over experts of which a share may be held, and a
+    # shared one (models/solar_open2.py)
+    SOLAR_OPEN2 = 0xABCD09
 
 
 # the archs whose layers are blocks of ``layer_pattern``
@@ -374,6 +398,10 @@ class ModelHeader:
     residual_mult: float = 1.0
     attn_scale: float = 0.0
     tied_embeddings: int = 0
+    # SOLAR_OPEN2 (HeaderKey 77-79); 0 for every other arch
+    linear_decay_dim: int = 0
+    linear_gate_rank: int = 0
+    full_layer_at: int = 0
 
     def pattern_layers(self, kind: str) -> list[int]:
         """The model's layers of ``kind`` (one of ``M * E``), in order."""
@@ -462,7 +490,8 @@ _HYBRID_KEYS = {k: k.name.lower() for k in (
     HeaderKey.V_HEAD_DIM, HeaderKey.MOE_N_GROUP, HeaderKey.MOE_TOPK_GROUP,
     HeaderKey.MOE_SCORE_FUNC, HeaderKey.SHORT_CONV_KERNEL,
     HeaderKey.MOE_SELECT_BIAS, HeaderKey.MOE_LATENT_DIM,
-    HeaderKey.TIED_EMBEDDINGS)}
+    HeaderKey.TIED_EMBEDDINGS, HeaderKey.LINEAR_DECAY_DIM,
+    HeaderKey.LINEAR_GATE_RANK, HeaderKey.FULL_LAYER_AT)}
 # FALCON_H1's float keys: the value is a float32's bit pattern
 _F32_BITS_KEYS = {k: k.name.lower() for k in HeaderKey
                   if HeaderKey.EMBEDDING_MULT <= k <= HeaderKey.SSM_MULT_DT}
@@ -622,6 +651,41 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
             f"{h.attn_scale}, a residual multiplier of {h.residual_mult}: "
             f"the experts live in the model's width behind every mixer and "
             f"both scalars are positive")
+    if h.arch_type == ArchType.SOLAR_OPEN2:
+        h.moe_router_width = h.moe_router_width or h.n_experts
+        if h.layer_period < 2 or h.n_layers % h.layer_period \
+                or h.full_layer_at:
+            raise ValueError(
+                f"solar_open2 model: layer period {h.layer_period} with the "
+                f"full layer at {h.full_layer_at} does not divide "
+                f"{h.n_layers} layers into whole periods, each led by its "
+                f"full layer")
+        if not (h.linear_n_value_heads and h.linear_key_head_dim
+                and h.linear_value_head_dim and h.linear_conv_kernel > 1
+                and h.linear_gate_rank
+                and h.linear_n_key_heads == h.linear_n_value_heads
+                and h.linear_decay_dim == h.linear_key_head_dim):
+            raise ValueError(
+                f"solar_open2 model: {h.linear_n_key_heads} key and "
+                f"{h.linear_n_value_heads} value heads of "
+                f"{h.linear_key_head_dim} / {h.linear_value_head_dim}, "
+                f"{h.linear_conv_kernel} taps, gates through "
+                f"{h.linear_gate_rank}, {h.linear_decay_dim} decays a head: "
+                f"every size must be set, the heads pair one to one, and a "
+                f"head decays by one number a key channel")
+        if not (0 < h.n_active_experts <= h.moe_router_width
+                and 0 < h.n_experts
+                and h.moe_first_expert + h.n_experts <= h.moe_router_width
+                and h.moe_score_func in (0, 1)
+                and h.moe_select_bias in (0, 1)
+                and not h.n_dense_layers):
+            raise ValueError(
+                f"solar_open2 model: experts [{h.moe_first_expert}, "
+                f"{h.moe_first_expert + h.n_experts}) held of a router over "
+                f"{h.moe_router_width}, {h.n_active_experts} a token, score "
+                f"function code {h.moe_score_func}, selection bias "
+                f"{h.moe_select_bias}, {h.n_dense_layers} leading dense "
+                f"layers (every layer routes)")
     if h.arch_type == ArchType.LFM2:
         h.rope_type = RopeType.FALCON
         h.moe_router_width = h.moe_router_width or h.n_experts
@@ -844,6 +908,9 @@ class ModelFile:
             if h.arch_type in PATTERN_ARCHS:
                 off = self._walk_nemotron_h_layer(l, off)
                 continue
+            if h.arch_type == ArchType.SOLAR_OPEN2:
+                off = self._walk_solar_open2_layer(l, off)
+                continue
             off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
             off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
             off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
@@ -912,6 +979,49 @@ class ModelFile:
         off += self._add("block_matmul_w1", l, (h.hidden_dim, h.dim), wt, off)
         off += self._add("block_matmul_w2", l, (h.dim, h.hidden_dim), wt, off)
         off += self._add("block_matmul_w3", l, (h.hidden_dim, h.dim), wt, off)
+        off += self._add("block_norm_0", l, (h.dim,), F32, off)
+        off += self._add("block_norm_1", l, (h.dim,), F32, off)
+        return off
+
+    def _walk_solar_open2_layer(self, l: int, off: int) -> int:
+        """One layer of a SOLAR_OPEN2 file (OUR layout; the reference has
+        none). The full layer of a period (at ``full_layer_at``): q k v wo
+        and the output gate's plane (one gate a lane of the heads'
+        output). A delta-rule layer: the three projections q k v, the
+        convolution taps ``[kernel, channels]`` of each (q~, k~, v~: three
+        records; the loader joins them side by side), ``A_log`` a head, the decay's low-rank pair (down ``[rank, dim]``, up
+        ``[H decays, rank]``) and ``dt_bias`` (a decay each), the ``beta``
+        rows, the output gate's low-rank pair, the output norm over a value
+        head (all F32), the output projection. Then, in both, the router's
+        rows, its selection bias, the HELD experts and the shared one
+        (:meth:`_walk_share_ffn`) and the two block norms."""
+        h, wt = self.header, self.header.weight_type
+        if l % h.layer_period == h.full_layer_at:
+            off += self._add("block_matmul_q", l, (h.q_dim, h.dim), wt, off)
+            off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
+            off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
+            off += self._add("block_matmul_wo", l, (h.dim, h.q_dim), wt, off)
+            off += self._add("block_matmul_wg", l, (h.q_dim, h.dim), wt, off)
+        else:
+            nh, rank = h.linear_n_value_heads, h.linear_gate_rank
+            kdim, vdim = nh * h.linear_key_head_dim, nh * h.linear_value_head_dim
+            decays = nh * h.linear_decay_dim
+            off += self._add("block_kda_q", l, (kdim, h.dim), wt, off)
+            off += self._add("block_kda_k", l, (kdim, h.dim), wt, off)
+            off += self._add("block_kda_v", l, (vdim, h.dim), wt, off)
+            for name, wide in (("q", kdim), ("k", kdim), ("v", vdim)):
+                off += self._add("block_kda_conv_" + name, l,
+                                 (h.linear_conv_kernel, wide), F32, off)
+            off += self._add("block_kda_a_log", l, (nh,), F32, off)
+            off += self._add("block_kda_f_down", l, (rank, h.dim), F32, off)
+            off += self._add("block_kda_f_up", l, (decays, rank), F32, off)
+            off += self._add("block_kda_dt_bias", l, (decays,), F32, off)
+            off += self._add("block_kda_b", l, (nh, h.dim), F32, off)
+            off += self._add("block_kda_g_down", l, (rank, h.dim), F32, off)
+            off += self._add("block_kda_g_up", l, (vdim, rank), F32, off)
+            off += self._add("block_kda_norm", l, (h.linear_value_head_dim,), F32, off)
+            off += self._add("block_kda_out", l, (h.dim, vdim), wt, off)
+        off = self._walk_share_ffn(l, off)
         off += self._add("block_norm_0", l, (h.dim,), F32, off)
         off += self._add("block_norm_1", l, (h.dim,), F32, off)
         return off

@@ -189,6 +189,23 @@ class ModelConfig:
     # array at the compute dtype.
     attn_score_scale: float = 0.0
     tied_embeddings: bool = False
+    # A delta rule whose decay is a VECTOR a head beside gated full
+    # attention, routed experts behind both (ArchType.SOLAR_OPEN2,
+    # models/solar_open2.py): OLMO_HYBRID's ``layer_period`` and ``lin_*``
+    # sizes, with ``lin_decay_dim`` decays a head (0 in every other arch: one
+    # number, the gated delta rule; here always ``lin_key_dim``: one a key
+    # channel, Kimi Delta Attention),
+    # ``lin_gate_rank`` the inner width of the decay's and the output gate's
+    # low-rank projections, and ``full_layer_at`` the full layer's place in
+    # its period (OLMO_HYBRID's is the last, ``layer_period - 1``). The
+    # share's fields are LAGUNA's (no leading dense layer), the router's
+    # ``moe_score`` and ``moe_select_bias`` LFM2's. The arch implies:
+    # pre-norm, three separate projections with a short convolution each, no
+    # q/k norm and no rotary embedding in the full layers, a sigmoid gate a
+    # lane on their output, an ungated shared expert.
+    lin_decay_dim: int = 0
+    lin_gate_rank: int = 0
+    full_layer_at: int = 0
 
     # TPU execution choices (no reference equivalent):
     compute_dtype: str = "float32"  # "float32" for parity, "bfloat16" for speed
@@ -507,6 +524,22 @@ class ModelConfig:
                 lin_value_dim=h.linear_value_head_dim,
                 lin_conv_kernel=h.linear_conv_kernel,
                 lin_neg_eigval=bool(h.linear_neg_eigval))
+        if h.arch_type == ArchType.SOLAR_OPEN2:
+            hybrid = dict(
+                layer_period=h.layer_period, full_layer_at=h.full_layer_at,
+                lin_heads=h.linear_n_value_heads,
+                lin_key_dim=h.linear_key_head_dim,
+                lin_value_dim=h.linear_value_head_dim,
+                lin_conv_kernel=h.linear_conv_kernel,
+                lin_neg_eigval=bool(h.linear_neg_eigval),
+                lin_decay_dim=h.linear_decay_dim,
+                lin_gate_rank=h.linear_gate_rank,
+                moe_select_bias=bool(h.moe_select_bias),
+                moe_score=("softmax", "sigmoid")[h.moe_score_func],
+                shared_expert_dim=h.shared_expert_dim,
+                moe_routed_scale=h.moe_routed_scale_milli / 1000.0,
+                moe_router_width=h.moe_router_width,
+                moe_first_expert=h.moe_first_expert)
         if h.arch_type == ArchType.FALCON_H1:
             hybrid = dict(
                 ssm_heads=h.ssm_n_heads, ssm_head_dim=h.ssm_head_dim,

@@ -42,6 +42,16 @@ array and ``p`` (:func:`~dllama_tpu.models.llama._attend_paged`). As the
 scan's ``xs``/``ys`` the K/V pool was sliced, stacked and copied back every
 step (PERF.md section 6, PR 33).
 
+**The scan and the two programs around it are not this family's alone**
+(PR 58): what a family brings to them is a :class:`Walk` (its two stacks,
+where the full layer stands in its period, a sublayer's form, the full
+layer's attention, the feed-forward and the mixer's two forms, as closures),
+and :func:`chunk_program` / :func:`step_program` are ``forward`` /
+``paged_forward`` over any walk. This module's walk (:func:`_olmo_walk`) is
+what the next paragraphs describe; ``models/solar_open2.py``'s puts the full
+layer FIRST, norms on a sublayer's input and a routed feed-forward whose
+counters ride the carry behind both stacks.
+
 A linear layer's mixer, for its input ``u``: one packed projection ``[q~ k~
 v~ z] = W_in u``, gates ``[a b] = W_ab u``; a causal depthwise convolution
 of ``K`` taps and SiLU over ``q~ k~ v~``; per head ``q = l2norm(q') /
@@ -64,7 +74,8 @@ split, and the full layers carry no rotary embedding.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import functools
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -78,9 +89,9 @@ from ..runtime.introspection import note_gdn_path
 from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
 from .family import Family, layer_kinds, state_refusal
-from .llama import (LayerParams, Params, _attend_dense, _attend_paged,
-                    _exact_f32_dots, _hidden_act, _layer_at, _nonfinite_rows,
-                    _poison_logits, _stack_at)
+from .llama import (_LAYER_MATMULS, LayerParams, Params, _attend_dense,
+                    _attend_paged, _exact_f32_dots, _hidden_act,
+                    _nonfinite_rows, _poison_logits, _stack_at)
 
 
 class LinearLayerParams(NamedTuple):
@@ -180,12 +191,14 @@ def _mixer_output(cfg: ModelConfig, o: jax.Array, z: jax.Array,
 
 def _rule_chunk(q, k, v, g, beta, s_l, n_valid):
     """The rule over ONE sequence's chunk against its state ``s_l [B, H, dk,
-    dv]``: positions at or past ``n_valid`` get ``beta = 0, alpha = 1`` and
+    dv]``: positions at or past ``n_valid`` get ``beta = 0, alpha = 1`` (in
+    every channel, where ``g [B, T, H, dk]`` is a decay a key channel) and
     leave it alone. Returns ``o [B, T, H, dv]`` and the state."""
     real = (jnp.arange(q.shape[1]) < n_valid)[None, :, None]
     note_gdn_path("chunk", "xla")
-    return gd.gated_delta_chunk(q, k, v, jnp.where(real, g, 0.0),
-                                jnp.where(real, beta, 0.0), s_l)
+    return gd.gated_delta_chunk(
+        q, k, v, jnp.where(real if g.ndim == 3 else real[..., None], g, 0.0),
+        jnp.where(real, beta, 0.0), s_l)
 
 
 def _rule_step(l, rows, q, k, v, g, beta, s_pool):
@@ -272,17 +285,56 @@ def _full_qkv(cfg: ModelConfig, h: jax.Array, lp: LayerParams):
             v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim))
 
 
-def _full_layer(cfg: ModelConfig, x: jax.Array, lp: LayerParams, attend):
-    """One full layer; ``attend(q, k, v) -> att`` owns the cache."""
-    B, T, _ = x.shape
+def _attention(cfg: ModelConfig, h: jax.Array, lp: LayerParams, attend):
+    """A full layer's attention over its sublayer's input ``h``;
+    ``attend(q, k, v) -> att`` owns the cache."""
+    B, T, _ = h.shape
+    q, k, v = _full_qkv(cfg, h, lp)
+    return linear(attend(q, k, v).reshape(B, T, cfg.q_dim), lp.wo,
+                  in_axis="heads")
 
-    def attention(h):
-        q, k, v = _full_qkv(cfg, h, lp)
-        return linear(attend(q, k, v).reshape(B, T, cfg.q_dim), lp.wo,
-                      in_axis="heads")
 
-    x = _sublayer(cfg, x, lp.norm_att, attention)
-    return _sublayer(cfg, x, lp.norm_ffn, lambda h: _ffn(cfg, h, lp))
+class Walk(NamedTuple):
+    """What a family brings to the period scan (:func:`_scan_periods`) and
+    to the two programs over it (:func:`chunk_program`,
+    :func:`step_program`): its two stacks, where the full layer stands in
+    its period, and what a layer is made of, as closures. This module's
+    (:func:`_olmo_walk`): the full layer LAST, norms on a sublayer's output,
+    a dense feed-forward. ``models/solar_open2.py``'s: the full layer FIRST,
+    norms on a sublayer's input, a routed feed-forward whose counters ride
+    the carry (``acc``)."""
+
+    lin: Any                      # the linear layers' stack, [n_linear, ...]
+    lin_matmuls: tuple[str, ...]  # its 2-D matmul planes (llama._stack_at)
+    full: Any                     # the full layers' stack, [n_periods, ...]
+    full_matmuls: tuple[str, ...]
+    full_at: int                  # the full layer's place in its period
+    # (x, norm_w, f) -> x: a sublayer, residual and norm placed
+    sublayer: Callable
+    # (h, lp, attend) -> y: the full layer's attention inside its sublayer
+    attention: Callable
+    # (x, lp, l, acc, live) -> (x, acc): the feed-forward sublayer of layer
+    # ``l`` of the MODEL; ``lp`` is the layer's entry of its own stack
+    ffn: Callable
+    # the mixer's two forms: _mixer_chunk's and _mixer_step's signatures
+    mixer_chunk: Callable
+    mixer_step: Callable
+    # () -> what ``acc`` starts a step from (None: nothing is accumulated)
+    acc0: Callable = lambda: None
+
+
+def _olmo_walk(params: Params, cfg: ModelConfig) -> Walk:
+    def ffn(x, lp, _l, acc, _live):
+        return _sublayer(cfg, x, lp.norm_ffn, lambda h: _ffn(cfg, h, lp)), acc
+
+    return Walk(
+        lin=params.layers.lin, lin_matmuls=_LINEAR_MATMULS,
+        full=params.layers.full, full_matmuls=_LAYER_MATMULS,
+        full_at=cfg.layer_period - 1,
+        sublayer=functools.partial(_sublayer, cfg),
+        attention=functools.partial(_attention, cfg), ffn=ffn,
+        mixer_chunk=functools.partial(_mixer_chunk, cfg),
+        mixer_step=functools.partial(_mixer_step, cfg))
 
 
 def _check(cfg: ModelConfig) -> None:
@@ -299,64 +351,79 @@ def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     return linear(x, params.logits, out_axis="vocab").astype(jnp.float32)
 
 
-def _scan_periods(params: Params, cfg: ModelConfig, x: jax.Array, s, conv,
-                  k, v, mixer, store, attend):
-    """The period scan the three programs share: a period's linear layers (a
-    ``fori_loop`` over one traced body), then its full layer; the hidden
-    rows ``[B, T, dim]`` behind the last period come back, in front of the
-    final norm (:func:`_head`). Everything a slot's context is made of rides
-    the CARRY whole, a column's, the pool or (the tick program) a pair of
-    both: ``s, conv`` (every linear layer's state and tail) and ``k, v``
-    (the full layers' cache, indexed by the period ``p``); nothing is
-    sliced into the scan or stacked out of it, so the pools are written in
-    place. ``mixer(h, lp, l, s, conv) -> (y, s', conv')`` is the form of the
-    mixer and ``store(a, a', l)`` puts what it gave back into the carry (a
-    column's layer ``l``; the pool comes back whole);
-    ``attend(q, k, v, k_c, v_c, p) -> (att, k_c, v_c)`` owns the cache.
-    Everything else a layer does it does a row at a time, so the rows along
-    ``T`` need not be one sequence's: only the closures know."""
-    per_period = cfg.layer_period - 1
-    lin, full = params.layers
+def _scan_periods(walk: Walk, cfg: ModelConfig, x: jax.Array, s, conv, k, v,
+                  acc, live, mixer, store, attend):
+    """The period scan every program of every family shares: a period's
+    linear layers (a ``fori_loop`` over one traced body) around its full
+    layer, which stands at ``walk.full_at`` (last: one loop in front of it;
+    first: one behind it); the hidden rows ``[B, T, dim]`` behind the last
+    period come back, in front of the final norm (:func:`_head`).
+    Everything a slot's context is made of rides the CARRY whole, a
+    column's, the pool or (the tick program) a pair of both: ``s, conv``
+    (every linear layer's state and tail), ``k, v`` (the full layers' cache,
+    indexed by the period ``p``) and ``acc`` (what ``walk.ffn`` accumulates:
+    a routed feed-forward's counters, or None); nothing is sliced into the
+    scan or stacked out of it, so the pools are written in place.
+    ``mixer(h, lp, l, s, conv) -> (y, s', conv')`` is the form of the mixer
+    and ``store(a, a', l)`` puts what it gave back into the carry (a
+    column's layer ``l``; the pool comes back whole); ``attend(q, k, v, k_c,
+    v_c, p) -> (att, k_c, v_c)`` owns the cache; ``live`` (the rows that are
+    real) is ``walk.ffn``'s. Everything else a layer does it does a row at a
+    time, so the rows along ``T`` need not be one sequence's: only the
+    closures know."""
+    P, at = cfg.layer_period, walk.full_at
+    # the loops of linear layers in front of a period's full layer and behind
+    # it; one without layers is not traced
+    front, behind = ([(lo, hi)] if hi > lo else [] for lo, hi in ((0, at), (at, P - 1)))
 
     def period(carry, p):
-        x, s, conv, k_c, v_c = carry
+        x, s, conv, k_c, v_c, acc = carry
 
         def linear_layer(j, carry):
-            x, s, conv = carry
-            l = p * per_period + j
-            lp = _stack_at(lin, l, _LINEAR_MATMULS)
+            x, s, conv, acc = carry
+            l = p * (P - 1) + j
+            lp = _stack_at(walk.lin, l, walk.lin_matmuls)
             new = {}
 
             def mix(h):
                 y, new["s"], new["conv"] = mixer(h, lp, l, s, conv)
                 return y
 
-            x = _sublayer(cfg, x, lp.norm_att, mix)
-            x = _sublayer(cfg, x, lp.norm_ffn, lambda h: _ffn(cfg, h, lp))
-            return x, store(s, new["s"], l), store(conv, new["conv"], l)
+            x = walk.sublayer(x, lp.norm_att, mix)
+            x, acc = walk.ffn(x, lp, p * P + j + (j >= at), acc, live)
+            return x, store(s, new["s"], l), store(conv, new["conv"], l), acc
 
-        x, s, conv = jax.lax.fori_loop(0, per_period, linear_layer,
-                                       (x, s, conv))
+        for lo, hi in front:
+            x, s, conv, acc = jax.lax.fori_loop(lo, hi, linear_layer,
+                                                (x, s, conv, acc))
+        lp = _stack_at(walk.full, p, walk.full_matmuls)
         cache = {}
 
         def attend_p(q, k, v):
             att, cache["k"], cache["v"] = attend(q, k, v, k_c, v_c, p)
             return att
 
-        x = _full_layer(cfg, x, _layer_at(full, p), attend_p)
-        return (x, s, conv, cache["k"], cache["v"]), None
+        x = walk.sublayer(x, lp.norm_att,
+                          lambda h: walk.attention(h, lp, attend_p))
+        x, acc = walk.ffn(x, lp, p * P + at, acc, live)
+        for lo, hi in behind:
+            x, s, conv, acc = jax.lax.fori_loop(lo, hi, linear_layer,
+                                                (x, s, conv, acc))
+        return (x, s, conv, cache["k"], cache["v"], acc), None
 
     periods = jnp.arange(cfg.n_periods, dtype=jnp.int32)
-    (x, s, conv, k, v), _ = jax.lax.scan(period, (x, s, conv, k, v), periods)
-    return x, s, conv, k, v
+    carry, _ = jax.lax.scan(period, (x, s, conv, k, v, acc), periods)
+    return carry
 
 
-def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
-            start_pos: jax.Array, col: HybridColumn,
-            n_valid: jax.Array | None = None):
+def chunk_program(walk: Walk, params: Params, cfg: ModelConfig,
+                  tokens: jax.Array, start_pos: jax.Array, col: HybridColumn,
+                  n_valid: jax.Array | None = None):
     """A chunk ``tokens [B, T]`` at scalar ``start_pos`` over a gathered
     column: float32 logits ``[B, T, vocab]`` and the column, advanced by
-    the chunk's first ``n_valid`` positions (absent: all ``T``)."""
+    the chunk's first ``n_valid`` positions (absent: all ``T``; positions
+    behind them are not ``live`` for ``walk.ffn``). A column that carries
+    routing counters (``col.stats``) gets the chunk's added."""
     _check(cfg)
     start_pos = jnp.asarray(start_pos, dtype=jnp.int32)
     if start_pos.ndim:
@@ -370,25 +437,30 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
 
     def mixer(h, lp, l, s, conv):
-        return _mixer_chunk(cfg, h, lp, _at(s, l), _at(conv, l), n_valid)
+        return walk.mixer_chunk(h, lp, _at(s, l), _at(conv, l), n_valid)
 
     def attend(q, k, v, k_c, v_c, p):
         att, k_p, v_p = _attend_dense(cfg, q, k, v, _at(k_c, p), _at(v_c, p),
                                       start_pos, positions)
         return att, _put(k_c, k_p, p), _put(v_c, v_p, p)
 
-    x, s, conv, k, v = _scan_periods(params, cfg, x, col.s, col.conv,
-                                     col.k, col.v, mixer, _put, attend)
-    return _head(params, cfg, x), HybridColumn(k=k, v=v, s=s, conv=conv)
+    x, s, conv, k, v, stats = _scan_periods(
+        walk, cfg, x, col.s, col.conv, col.k, col.v, col.stats,
+        jnp.tile(jnp.arange(T) < n_valid, B), mixer, _put, attend)
+    return _head(params, cfg, x), HybridColumn(k=k, v=v, s=s, conv=conv,
+                                               stats=stats)
 
 
-def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
-                  pos_vec: jax.Array, cache, tables: jax.Array,
-                  write_lens: jax.Array | None = None):
+def step_program(walk: Walk, params: Params, cfg: ModelConfig,
+                 tokens: jax.Array, pos_vec: jax.Array, cache,
+                 tables: jax.Array, write_lens: jax.Array | None = None):
     """The decode step over the paged pool and the state pool: ``tokens [B,
-    1]`` at per-row ``pos_vec``, ``cache = (PagedKVCache, StatePool)``, both
-    given back. Row ``b`` is slot ``b``: its state is row ``b + 1`` of the
-    pool, or the null row 0 while its block table is all null."""
+    1]`` at per-row ``pos_vec``, ``cache = (PagedKVCache, StatePool)`` and,
+    where ``walk.ffn`` accumulates (``walk.acc0``), the generator's routing
+    totals behind them (the step's counters added to row 0); all given
+    back. Row ``b`` is slot ``b``: its state is row ``b + 1`` of the pool,
+    or the null row 0 while its block table is all null (such a row is not
+    ``live``)."""
     from ..runtime.kvblocks import PagedKVCache, StatePool
 
     _check(cfg)
@@ -397,24 +469,42 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         raise ValueError("a hybrid decoder's step form takes one token a "
                          "row: a speculative verify's rejected drafts "
                          "cannot be rolled back out of a recurrent state")
-    pkv, pool = cache
+    pkv, pool, *totals = cache
     positions = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]
-    rows = jnp.where(tables[:, 0] != 0, jnp.arange(1, B + 1, dtype=jnp.int32),
+    live = tables[:, 0] != 0
+    rows = jnp.where(live, jnp.arange(1, B + 1, dtype=jnp.int32),
                      StatePool.NULL)
     x = params.embedding[tokens].astype(cfg.compute_dtype)
 
     def mixer(h, lp, l, s, conv):
-        return _mixer_step(cfg, h, lp, l, rows, s, conv)
+        return walk.mixer_step(h, lp, l, rows, s, conv)
 
     def attend(q, k, v, k_pool, v_pool, p):
         return _attend_paged(cfg, q, k, v, k_pool, v_pool, p, positions,
                              tables)
 
-    x, s, conv, k, v = _scan_periods(params, cfg, x, pool.s, pool.conv,
-                                     pkv.k, pkv.v, mixer,
-                                     lambda _a, new, _l: new, attend)
+    x, s, conv, k, v, stats = _scan_periods(
+        walk, cfg, x, pool.s, pool.conv, pkv.k, pkv.v, walk.acc0(), live,
+        mixer, lambda _a, new, _l: new, attend)
     return (_head(params, cfg, x),
-            (PagedKVCache(k=k, v=v), StatePool(s=s, conv=conv)))
+            (PagedKVCache(k=k, v=v), StatePool(s=s, conv=conv))
+            + tuple(t.at[0].add(stats) for t in totals))
+
+
+def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+            start_pos: jax.Array, col: HybridColumn,
+            n_valid: jax.Array | None = None):
+    """:func:`chunk_program` over this module's walk."""
+    return chunk_program(_olmo_walk(params, cfg), params, cfg, tokens,
+                         start_pos, col, n_valid)
+
+
+def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
+                  pos_vec: jax.Array, cache, tables: jax.Array,
+                  write_lens: jax.Array | None = None):
+    """:func:`step_program` over this module's walk."""
+    return step_program(_olmo_walk(params, cfg), params, cfg, tokens,
+                        pos_vec, cache, tables, write_lens)
 
 
 @_exact_f32_dots
@@ -480,9 +570,10 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return (_join(att_c, att_r), (_put(k_col, k_p, p), k_pool),
                 (_put(v_col, v_p, p), v_pool))
 
-    x, s, conv, k, v = _scan_periods(
-        params, cfg, x, (col.s, pool.s), (col.conv, pool.conv),
-        (col.k, pkv.k), (col.v, pkv.v), mixer, store, attend)
+    x, s, conv, k, v, _ = _scan_periods(
+        _olmo_walk(params, cfg), cfg, x, (col.s, pool.s),
+        (col.conv, pool.conv), (col.k, pkv.k), (col.v, pkv.v), None, None,
+        mixer, store, attend)
     last = _poison_logits(_head(params, cfg, _by_row(x, T))[:, -1, :], poison)
     greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
     return ((greedy, _nonfinite_rows(last), last),

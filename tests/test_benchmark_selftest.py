@@ -32,6 +32,7 @@ PR42_CELL = "a.x-k1.agent-sessions"
 PR44_CELL = "lfm2-24b-a2b.batch-generate"
 PR51_CELL = "nemotron-3-super-120b-a12b.reasoning"
 PR54_CELL = "granite-4.0-h-small.doc-qa"
+PR58_CELL = "solar-open2-250b.long-doc"
 
 sys.path.insert(0, SELFTEST)
 try:
@@ -283,7 +284,8 @@ def test_the_pattern_cell_gets_the_modules_it_names_and_the_lists_it_was_appende
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json"), encoding="utf-8") as f:
         manifest = json.load(f)
     assert len(manifest["workloads"]) >= 11 and len(manifest["configs"]) >= 8 and manifest["workloads"][10]["name"] == PR51_CELL
-    mine = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [PR51_CELL]]
+    # the metrics the cell brought: its name leads their lists (later cells are appended behind it)
+    mine = [m["name"] for m in manifest["per_layer"] if (m.get("workloads") or [None])[0] == PR51_CELL]
     assert mine == ["moe_planes_fetched_share"]
     with open(os.path.join(BENCH, "layer_metrics", mine[0] + ".json"), encoding="utf-8") as f:
         spec = json.load(f)
@@ -331,7 +333,7 @@ def test_the_granite_cell_gets_the_modules_it_names_and_the_lists_it_was_appende
         manifest = json.load(f)
     assert len(manifest["workloads"]) >= 12 and len(manifest["configs"]) >= 9 and manifest["workloads"][11]["name"] == PR54_CELL
     assert manifest["configs"][8]["reduced"] == ["num_hidden_layers", "layer_types", "max_position_embeddings"]
-    mine = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [PR54_CELL]]
+    mine = [m["name"] for m in manifest["per_layer"] if (m.get("workloads") or [None])[0] == PR54_CELL]
     assert mine == ["prefill_xla_share", "expert_chunk_prefill_share", "expert_chunk_prefill_hbm_share"]
     assert all(m["moves"] == "itl_mean_ms" and m["unit"] == "%" for m in manifest["per_layer"] if m["name"] in mine)
     specs = {}
@@ -366,3 +368,53 @@ def test_the_xla_share_reader_counts_what_is_not_a_custom_call():
     assert reader.read({"trace": trace}, program="forward") == 100.0 * 4.0 / 10.0
     assert reader.read({"trace": None}, program="forward") is None
     assert reader.read({"trace": {"device_ops": [], "modules": {}}}, program="forward") is None
+
+
+# -- and PR 58's cell, from a file of PR 58's own -----------------------------------
+
+with open(os.path.join(BENCH, "solar_open2", "selftest", "counts_frozen.json"), encoding="utf-8") as _f:
+    PR58_FROZEN = json.load(_f)
+
+
+def test_pr58s_cell_counts_through_the_seam_are_what_pr58_froze():
+    _cell, conf, _traffic, mods = _seam._resolve(PR58_CELL)
+    rows = [r for r in PR58_FROZEN["rows"] if r["cell"] == PR58_CELL]
+    assert len(rows) == 18
+    for r in rows:
+        assert getattr(mods["counts"], r["fn"])(conf["model"], **r["args"]) == r["value"], r
+
+
+def test_the_solar_cell_gets_the_modules_it_names_and_the_lists_it_was_appended_to():
+    cell, conf, traffic_path, mods = _seam._resolve(PR58_CELL)
+    assert {k: os.path.relpath(m.__file__, BENCH) for k, m in mods.items()} == conf["modules"] == {
+        "reference": "solar_open2/reference.py", "weights": "solar_open2/weights.py", "counts": "solar_open2/counts.py"}
+    assert {"none", "shift", "droplayer", "dropblock", "scalardecay", "nonegeig", "nogate", "misroute", "noshared",
+            "state16", "bf16router"} == set(mods["reference"].CONTROLS)
+    counts = mods["counts"]
+    assert counts.kernel_counts(conf["model"], "gated_delta_step", rows=16)["calls_per_program"] == 6
+    assert counts.kernel_counts(conf["model"], "expert_gemv", rows=16)["layers"] == 8
+    assert counts.kernel_counts(conf["model"], "paged_ragged_attention", rows=16)["calls_per_program"] == 2
+    assert (cell["chips"], cell["traffic"], len(cell["why"]) <= 200) == (1, "long-doc-solar-open2", True)
+    assert os.path.basename(traffic_path) == "long-doc-solar-open2.json"
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json"), encoding="utf-8") as f:
+        manifest = json.load(f)
+    assert len(manifest["workloads"]) >= 13 and len(manifest["configs"]) >= 10 and manifest["workloads"][12]["name"] == PR58_CELL
+    assert manifest["configs"][9]["reduced"] == ["n_routed_experts", "vocab_size", "num_hidden_layers", "gqa_layers",
+                                                 "max_position_embeddings"]
+    # it brought no metric of its own: every list it stands in had a cell before it, and it stands LAST in each
+    lists = {m["name"]: m["workloads"] for s in ("end_to_end", "per_layer") for m in manifest[s] if "workloads" in m}
+    mine = {n for n, w in lists.items() if PR58_CELL in w}
+    assert mine and all(lists[n][-1] == PR58_CELL and len(lists[n]) > 1 for n in mine)
+    # behind granite's cell wherever that stands, but for the kernels this step does not run (an SSD mixer; the run
+    # form at 16 rows: 128 pairs <= 320 keep the pair form); and in the lists of the kernels it does run
+    beside_pr54 = {n for n, w in lists.items() if PR54_CELL in w} - {
+        "ssd_step_share", "ssd_step_hbm_share", "expert_chunk_step_share", "expert_chunk_step_hbm_share",
+        "itl_p88_ms", "itl_p90_ms", "itl_p95_ms"}
+    assert beside_pr54 <= mine
+    assert {"gated_delta_step_share", "gated_delta_step_hbm_share", "expert_gemv_share", "expert_gemv_hbm_share",
+            "paged_attn_step_share", "paged_attn_step_hbm_share", "prefill_xla_share", "expert_chunk_prefill_share",
+            "expert_chunk_prefill_hbm_share", "moe_held_pair_share", "moe_expert_load_max_over_mean",
+            "moe_pairs_per_plane", "moe_planes_fetched_share"} <= mine
+    assert sum(PR58_CELL in lists[n] for n in ("itl_p88_ms", "itl_p90_ms", "itl_p95_ms")) == 1      # ONE judged tail
+    assert not {"ssd_step_share", "mla_step_share", "expert_chunk_step_share", "window_blocks_returned_share",
+                "chunk_with_rows_share"} & mine

@@ -247,9 +247,11 @@ def test_manifest_entries_are_appended_with_the_accepted_layers():
     # and ``lfm2-24b-a2b.batch-generate`` (PR 44) to all six: its slice is chunk-free step ticks four times in five
     # and ``nemotron-3-super-120b-a12b.reasoning`` (PR 51) behind it: the same kind of slice; and
     # ``granite-4.0-h-small.doc-qa`` (PR 54): most of its ticks carry a chunk, a chunk and a step are two programs
-    # there, and the ticks between two admissions are enough for every median to read (its traced line has all six)
+    # there, and the ticks between two admissions are enough for every median to read (its traced line has all six);
+    # and ``solar-open2-250b.long-doc`` (PR 58): three ticks in five are chunk-free steps (its traced line has all six)
     later = {name: (["a.x-k1.agent-sessions"] if name in ("step_upload_ms_p50", "step_call_ms_p50") else [])
-             + ["lfm2-24b-a2b.batch-generate", "nemotron-3-super-120b-a12b.reasoning", "granite-4.0-h-small.doc-qa"]
+             + ["lfm2-24b-a2b.batch-generate", "nemotron-3-super-120b-a12b.reasoning", "granite-4.0-h-small.doc-qa",
+                "solar-open2-250b.long-doc"]
              for name in EXPECTED}
     for name in EXPECTED:
         m = by_name[name]

@@ -943,6 +943,99 @@ def test_granites_two_programs_compile_at_the_cells_size_for_v5e(one_chip, progr
     assert len(scoped) > 50 and not any(line.split(" = ")[0].count("ssd_chunk") for line in scoped)
 
 
+# -- solar-open2-250b at its published widths (PR 58) ----------------------------------
+
+
+def test_gated_delta_step_compiles_with_a_decay_a_channel_for_v5e(one_chip):
+    """The step form's ONE kernel at ``[6, 17, 64, 128, 128]`` with ``alpha [16,
+    64, 128]``: six delta-rule layers held, 64 heads of 128 x 128, 16 slots and
+    the null row, the pool in place; q, k and the decays ride as ROWS of one
+    ``[16, 64, 8, 128]`` operand (whole lane tiles: as columns ``[.., 128, 3]``
+    the tiled layout padded them to 128 lanes, 67 MB a layer beside 134 MB of
+    state) and the kernel turns them into columns."""
+    from dllama_tpu.ops.gated_delta import gated_delta_step
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+
+    slots, H, d = 16, 64, 128
+    f32 = jnp.float32
+    vec = lambda *tail: _shape(one_chip, (slots, H, *tail), f32)
+    compiled = jax.jit(functools.partial(gated_delta_step, interpret=False), donate_argnums=(0,)).lower(
+        _shape(one_chip, (6, slots + 1, H, d, d), f32), _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (slots,), jnp.int32), vec(d), vec(d), vec(d), vec(d), vec()).compile()
+    assert mosaic_kernels(compiled.as_text()).get("gated_delta_step") == 1
+    # in place: no second pool (0.43 GB), and no lane-padded copy of the vectors (67 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024 * 1024
+
+
+@pytest.mark.parametrize("program", ["step", "forward"])
+def test_solars_two_programs_compile_at_the_cells_size_for_v5e(one_chip, program, monkeypatch):
+    """``paged_sampled_step_guarded`` over 16 rows (pools of 16 x 9,472 tokens,
+    donated) and ``forward`` over a 256-token chunk into a 9,472-token column,
+    from the cell's own configuration and the benchmark's shapes: every Q40
+    plane a kernel, the step's routed halves the PAIR form (``share.step_form``:
+    128 pairs over 320), the rule's step form ONE ``gated_delta_step`` a traced
+    layer body, the walk ``paged_ragged_attention``; the chunk's rule XLA (no
+    kernel of its own), its routed halves ``expert_chunk``."""
+    import importlib.util
+
+    from dllama_tpu.formats import mfile
+    from dllama_tpu.models import llama
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.models.share import zero_totals
+    from dllama_tpu.ops import quant_matmul
+    from dllama_tpu.parallel import api
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+    from dllama_tpu.runtime.kvblocks import PagedKVCache, StateColumn, StatePool
+
+    bench = os.path.join(REPO, "benchmark")
+    sys.path.insert(0, bench)
+    import run as bench_run
+    import weights as dense_weights
+
+    spec = importlib.util.spec_from_file_location("solar_open2_weights_for_compile",
+                                                  os.path.join(bench, "solar_open2", "weights.py"))
+    weights = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(weights)
+    with open(os.path.join(bench, "configs", "solar-open2-250b.json"), encoding="utf-8") as f:
+        conf = json.load(f)
+    import struct
+    data = b"".join(struct.pack("<ii", k if isinstance(k, int) else dense_weights.HEADER_KEYS[k], int(v))
+                    for k, v in weights.header_fields(bench_run.model_view(conf)).items())
+    header = mfile.parse_header(struct.pack("<ii", mfile.MODEL_MAGIC, 8 + len(data)) + data, 0,
+                                max_seq_len=conf["engine"]["max_seq_len"])
+    cfg = ModelConfig.from_header(header, "bfloat16")
+    for module in (quant_matmul, llama, api):          # the gates ask jax.default_backend(), which is the CPU here
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    on_chip = lambda tree: jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(weights.params_builder(cfg, None)[0], jax.random.PRNGKey(0)))
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    slots, seq, block = conf["engine"]["slots"], cfg.seq_len, conf["engine"]["kv_block_size"]
+    if program == "step":
+        cache = (on_chip(jax.eval_shape(lambda: PagedKVCache.create(cfg, slots * seq // block + 1, block, dtype=bf16))),
+                 on_chip(jax.eval_shape(lambda: StatePool.create(cfg, slots, bf16))),
+                 on_chip(jax.eval_shape(lambda: zero_totals(cfg))))
+        rows = lambda dtype, *tail: _shape(one_chip, (slots, *tail), dtype)
+        compiled = jax.jit(llama.paged_sampled_step_guarded, static_argnums=1, donate_argnums=(4,)).lower(
+            params, cfg, rows(i32, 1), rows(i32), cache, rows(i32, seq // block), rows(f32), rows(f32), rows(f32),
+            _shape(one_chip, (), f32)).compile()
+        kernels = mosaic_kernels(compiled.as_text())
+        assert kernels.get("gated_delta_step") == 1 and kernels.get("paged_ragged_attention") == 1, kernels
+        assert kernels.get("expert_gemv") == 6 and "expert_chunk" not in kernels, kernels     # two traced routed bodies
+        assert kernels.get("quant_matmul") == 15, kernels      # 4 + 3 a delta-rule body, 5 + 3 the full one
+        # the pools are written in place: no second state pool (0.43 GB) or K/V pool (1.24 GB) among the temporaries
+        assert compiled.memory_analysis().temp_size_in_bytes < 96 * 1024 * 1024
+        return
+    kv = lambda: jnp.zeros((cfg.n_kv_layers, 1, cfg.n_kv_heads, seq, cfg.cache_width), bf16)
+    column = on_chip(jax.eval_shape(lambda: StateColumn.zeros(cfg, kv(), kv(), bf16)))
+    compiled = jax.jit(llama.forward, static_argnums=1, donate_argnums=(4,)).lower(
+        params, cfg, _shape(one_chip, (1, 256), i32), _shape(one_chip, (), i32), column,
+        _shape(one_chip, (), i32)).compile()
+    kernels = mosaic_kernels(compiled.as_text())
+    assert kernels.get("expert_chunk") == 6 and "gated_delta_step" not in kernels, kernels
+    assert kernels.get("quant_matmul") == 15, kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < 1536 * 1024 * 1024
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_chip_smoke_rehearsal_on_cpu(chips):
     """The script end to end at a toy size with JAX_PLATFORMS=cpu children

@@ -43,6 +43,7 @@ TINY = {
     ArchType.LFM2: ("lfm2", "tiny-lfm2.json"),
     ArchType.NEMOTRON_H: ("nemotron_h", "tiny-nemotron-h.json"),
     ArchType.GRANITE_HYBRID: ("granite_hybrid", "tiny-granite-hybrid.json"),
+    ArchType.SOLAR_OPEN2: ("solar_open2", "tiny-solar-open2.json"),
 }
 ARCHS = list(ArchType)
 
@@ -73,6 +74,11 @@ PARENT = {
                               "heads of 16, scores x 0.0625); experts: 8 of 8 held from 0, 3 a token, gated, 32 wide, "
                               "shared 64; multipliers: embedding 12, residual 0.22, logits 0.0625; head tied to the "
                               "embedding (one array)"),
+    # no parent: PR 58 brought the family; a short module over the hybrid's period scan
+    ArchType.SOLAR_OPEN2: (393216, {"linear": 6, "full": 2, "moe": 8},
+                           "; layers: 2 full (gated, no positions, 4:2 heads of 16; the first of every 4), 6 delta-rule "
+                           "(4 heads of 16 x 16, a decay a key channel (16 a head), gates through 16); experts behind "
+                           "every mixer: 4 of 8 held from 2, 3 a token, 32 wide, shared 32, selection bias"),
 }
 DENSE_MOE_WEIGHTS = 180736   # tiny_header_params(QWEN3, n_experts=4, n_active_experts=2), the parent's count
 
@@ -148,6 +154,7 @@ TICK = {
     ArchType.LFM2: ("lfm2", "forward_and_step"),
     ArchType.NEMOTRON_H: None,
     ArchType.GRANITE_HYBRID: None,
+    ArchType.SOLAR_OPEN2: None,
 }
 
 
@@ -180,10 +187,10 @@ def test_the_dense_equations_share_one_family_and_the_entry_is_llamas(cfgs):
     # the one entry of every family keeps its name (the engine jits it as program ``forward``)
     assert llama.forward.__name__ == "forward" and llama.paged_forward.__name__ == "paged_forward"
     others = {family_of(cfgs[a]) for a in ARCHS if TINY[a] is not None}
-    assert len(others) == 7 and llama.FAMILY not in others
+    assert len(others) == 8 and llama.FAMILY not in others
 
 
-FAMILY_MODULES = {"hybrid", "falcon_h1", "laguna", "axk1", "lfm2", "nemotron_h", "granite_hybrid"}
+FAMILY_MODULES = {"hybrid", "falcon_h1", "laguna", "axk1", "lfm2", "nemotron_h", "granite_hybrid", "solar_open2"}
 FAMILY_NAMING = {"is_hybrid", "has_ssm", "has_short_conv"}
 FAMILY_ARCHS = {a.name for a in ARCHS} - {"LLAMA", "QWEN3"}
 
