@@ -47,10 +47,30 @@ from jax.experimental.pallas import tpu as pltpu
 # the small matmuls feed a float32 state that is carried over thousands of
 # tokens: full float32 precision on every backend
 _PREC = jax.lax.Precision.HIGHEST
-# heads one grid step of the step kernel handles (a head's state is 128 KB at
-# P 128, N 256): amortizes the ~0.35 us a grid step costs; a step's heads
-# share one group's B and C, so the count divides the heads of a group
-_HEADS_PER_STEP = (8, 4, 2, 1)
+# the state one grid step of the step kernel moves each way, at most. On a v5e
+# (tools/state_step_sweep.py; PERF.md section 5, PR 59) a grid step costs
+# 0.3-0.45 us beside its bytes while the block is small (32 KB a head at P 64,
+# N 128: 8 heads, 256 KB, ran at 56.6% of the HBM roof, 32 heads, 1 MB, at
+# 67.8%) and nothing is left to win past 1 MB (2 MB: 68.0% there; 128 KB a
+# head at P 128, N 256 reads 80.0% at 1 MB and at 2 MB, where a plain copy
+# through the same blocks stands). The block in and the block out are each
+# double-buffered: four blocks, 4 MB of the 16 MB a kernel's scope may hold
+# there without a raised ``vmem_limit_bytes`` (the lane-padded vectors beside
+# them are under 0.3 MB), so the call raises nothing
+_STATE_BLOCK_BYTES = 1 << 20
+
+
+def heads_per_step(H: int, G: int, P: int, N: int) -> int:  # dlint: static-fn
+    """Heads one grid step of the step kernel moves: the largest count that
+    divides ``H``, holds part of ONE group or WHOLE groups (a divisor or a
+    multiple of ``H / G``: never parts of two groups, whose B and C a block
+    could not name), and keeps the float32 state block ``hb x P x N`` at or
+    under :data:`_STATE_BLOCK_BYTES`; 1 where one head's state is over it."""
+    per_group = H // G
+    most = max(1, _STATE_BLOCK_BYTES // (4 * P * N))
+    return max(c for c in range(1, H + 1)
+               if H % c == 0 and c <= most
+               and (per_group % c == 0 or c % per_group == 0))
 
 
 def _per_head(m: jax.Array, heads: int) -> jax.Array:
@@ -110,18 +130,21 @@ def step_kernel_choice() -> dict | None:  # dlint: static-fn
 
 
 def _step_kernel(layer_ref, rows_ref, xa_ref, bc_ref, s_ref, y_ref, s_out_ref,
-                 *, heads: int):
-    """One (row, group of ``heads`` heads) of the step form. ``xa_ref [1, 1,
+                 *, heads: int, groups: int):
+    """One (row, block of ``heads`` heads) of the step form. ``xa_ref [1, 1,
     2, P, heads]`` holds ``dt x`` (plane 0) and the decay (plane 1, repeated
     down the column) with a head a LANE: head ``h``'s column is ``[:, h:h +
-    1]``; ``bc_ref [1, 1, 8, N]`` the heads' group's B and C as rows 0 and
-    1; ``s_ref [heads, P, N]`` is the state, read once, and ``s_out_ref`` the
-    same cells of the same pool, written once; ``y_ref [1, 1, P, heads]``
-    takes head ``h``'s readout as its column ``h``."""
+    1]``; ``bc_ref [1, groups, 8, N]`` the B and C of the block's ``groups``
+    groups as rows 0 and 1 (one group where the block is part of it, head
+    ``h``'s is ``h // (heads / groups)``); ``s_ref [heads, P, N]`` is the
+    state, read once, and ``s_out_ref`` the same cells of the same pool,
+    written once; ``y_ref [1, 1, P, heads]`` takes head ``h``'s readout as its
+    column ``h``."""
     del layer_ref, rows_ref  # spent in the index maps
-    b = bc_ref[0, 0, 0:1, :]                      # [1, N]
-    c = bc_ref[0, 0, 1:2, :]
+    bc = [(bc_ref[0, g, 0:1, :], bc_ref[0, g, 1:2, :])    # [1, N] each
+          for g in range(groups)]
     for h in range(heads):
+        b, c = bc[h * groups // heads]
         dx = xa_ref[0, 0, 0, :, h:h + 1]          # [P, 1]
         decay = xa_ref[0, 0, 1, :, h:h + 1]
         S = s_ref[h] * decay + dx * b
@@ -134,8 +157,10 @@ def ssd_step(pool, layer, rows, x, dt, decay, Bm, Cm, *,
              interpret: bool = False):
     """:func:`ssd_step_xla` as ONE Pallas kernel over the pool in place:
     layer and rows ride in as scalar-prefetch operands, the index maps pick
-    ``(layer, rows[b], head group)``, and the pool's output is aliased onto
-    its input, so cells no row names are never touched.
+    ``(layer, rows[b], block of heads)``, and the pool's output is aliased
+    onto its input, so cells no row names are never touched. A block is
+    :func:`heads_per_step` heads, chosen from the shapes seen here: part of
+    one group, or ``gb`` whole groups whose B and C ride in together.
 
     The kernel wants ``dt x`` and the decay as COLUMNS down a head's ``P``
     sublanes. They reach it ``[B, H / hb, 2, P, hb]``, a grid step's ``hb``
@@ -148,7 +173,8 @@ def ssd_step(pool, layer, rows, x, dt, decay, Bm, Cm, *,
     _L, _R, H, P, N = pool.shape
     B, G = x.shape[0], Bm.shape[1]
     per_group = H // G
-    hb = next(c for c in _HEADS_PER_STEP if per_group % c == 0)
+    hb = heads_per_step(H, G, P, N)
+    gb = max(1, hb // per_group)
     f32 = jnp.float32
     by_lane = lambda a: jnp.swapaxes(a.reshape(B, H // hb, hb, P), 2, 3)
     xa = jnp.stack([by_lane((dt[..., None] * x).astype(f32)),
@@ -165,8 +191,8 @@ def ssd_step(pool, layer, rows, x, dt, decay, Bm, Cm, *,
                            memory_space=vmem)
     readout = pl.BlockSpec((1, 1, P, hb), lambda b, h, l, r: (b, h, 0, 0),
                            memory_space=vmem)
-    group = pl.BlockSpec((1, 1, 8, N),
-                         lambda b, h, l, r: (b, (h * hb) // per_group, 0, 0),
+    group = pl.BlockSpec((1, gb, 8, N),
+                         lambda b, h, l, r: (b, (h * hb) // (per_group * gb), 0, 0),
                          memory_space=vmem)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # layer, rows
@@ -175,7 +201,7 @@ def ssd_step(pool, layer, rows, x, dt, decay, Bm, Cm, *,
         out_specs=[readout, state],
     )
     y, pool = pl.pallas_call(
-        functools.partial(_step_kernel, heads=hb),
+        functools.partial(_step_kernel, heads=hb, groups=gb),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, H // hb, P, hb), f32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],

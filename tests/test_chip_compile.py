@@ -70,6 +70,17 @@ def _compiled_kernels(fn, *args) -> dict:
     return mosaic_kernels(jax.jit(fn).lower(*args).compile().as_text())
 
 
+def _ssd_step_operands(text: str) -> list[str]:
+    """The shapes the ONE ``ssd_step`` Mosaic call of a compiled program takes
+    (``operand_layout_constraints``: layer, rows, ``dt x`` and the decay a
+    head a lane, the groups' B and C, the pool) and the readout's, last."""
+    import re
+
+    (call,) = [ln for ln in text.splitlines() if "custom-call(" in ln and ln.lstrip().startswith("%ssd_step")]
+    taken = re.search(r"operand_layout_constraints=\{(.*?)\}\}", call).group(1)
+    return re.findall(r"\w+\[[\d,]*\]", taken) + re.findall(r"\w+\[[\d,]*\]", call.split("=", 1)[1])[:1]
+
+
 def test_described_device_is_a_v5e_with_a_roofline_row(topo):
     from dllama_tpu.runtime import roofline
 
@@ -426,6 +437,11 @@ def test_ssd_step_compiles_for_v5e(one_chip):
         _shape(one_chip, (slots, H), f32), _shape(one_chip, (slots, G, N), f32),
         _shape(one_chip, (slots, G, N), f32)).compile()
     assert mosaic_kernels(compiled.as_text()).get("ssd_step") == 1
+    # 128 KB a head: 8 heads are the 1 MB a grid step moves (``ssd.heads_per_step``), half a
+    # group, as the fixed list gave: ``dt x`` and the decay ``[16, 4, 2, 128, 8]``, one group's
+    # B and C a block, ``y`` ``[16, 4, 128, 8]``
+    assert _ssd_step_operands(compiled.as_text()) == [
+        "s32[1]", "s32[16]", "f32[16,4,2,128,8]", "f32[16,2,8,256]", "f32[12,17,32,128,256]", "f32[16,4,128,8]"]
     # in place: the program holds no second pool (12 x 17 x 4.19 MB = 856 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024 * 1024
 
@@ -685,11 +701,15 @@ def test_ssd_step_compiles_at_nemotrons_state_for_v5e(one_chip):
         _shape(one_chip, (slots, H), f32), _shape(one_chip, (slots, G, N), f32),
         _shape(one_chip, (slots, G, N), f32)).compile()
     assert mosaic_kernels(compiled.as_text()).get("ssd_step") == 1
-    # in place: the program holds no second pool (10 x 33 x 4.19 MB = 1.38 GB),
-    # and ``dt x``, the decay and ``y`` reach the kernel a head a LANE
-    # (``[32, 16, 2, 64, 8]``: 34 MB as tiled), not with a minor dimension of
-    # 2 and 1 that the tiled layout pads to 128 lanes (134 MB each: PR 51)
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+    # 32 KB a head: 32 heads, TWO groups of 16, are the 1 MB a grid step moves (PR 59; the
+    # fixed list's 8 moved 256 KB). ``dt x``, the decay and ``y`` reach the kernel a head a
+    # LANE, ``[32, 4, 2, 64, 32]`` and ``[32, 4, 64, 32]``: 8.4 MB and 4.2 MB as tiled (the
+    # 32 lanes padded to 128), where 8 lanes were 34 MB and 17 MB and a minor dimension of
+    # 2 and 1 134 MB each (PR 51)
+    assert _ssd_step_operands(compiled.as_text()) == [
+        "s32[1]", "s32[32]", "f32[32,4,2,64,32]", "f32[32,8,8,128]", "f32[10,33,128,64,128]", "f32[32,4,64,32]"]
+    # in place: the program holds no second pool (10 x 33 x 4.19 MB = 1.38 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024 * 1024
 
 
 @pytest.mark.parametrize("k,n", [(1024, 2816), (2816, 1024)])
@@ -781,8 +801,8 @@ GRANITE_STEP = [(4096, 16640), (8192, 4096), (4096, 1536), (1536, 4096), (4096, 
 def test_ssd_step_compiles_at_one_group_for_v5e(one_chip):
     """The SSD step form's kernel at ``[9, 17, 128, 64, 128]``: nine mixer
     layers held, 128 heads of 64 on ONE group of B and C (the grid is slots x
-    head groups of 8: all sixteen read the same group), 16 slots and the null
-    row, the pool in place."""
+    blocks of 32 heads, 1 MB of state: all four read the same group), 16 slots
+    and the null row, the pool in place."""
     from dllama_tpu.ops import ssd
     from dllama_tpu.runtime.introspection import mosaic_kernels
 
@@ -796,7 +816,9 @@ def test_ssd_step_compiles_at_one_group_for_v5e(one_chip):
         _shape(one_chip, (slots, H), f32), _shape(one_chip, (slots, G, N), f32),
         _shape(one_chip, (slots, G, N), f32)).compile()
     assert mosaic_kernels(compiled.as_text()).get("ssd_step") == 1
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+    assert _ssd_step_operands(compiled.as_text()) == [
+        "s32[1]", "s32[16]", "f32[16,4,2,64,32]", "f32[16,1,8,128]", "f32[9,17,128,64,128]", "f32[16,4,64,32]"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024 * 1024
 
 
 @pytest.mark.parametrize("k,n", [(4096, 768), (768, 4096)])
