@@ -40,6 +40,7 @@ ARCH_BY_MODEL_TYPE = {
     "nemotron_h": ArchType.NEMOTRON_H,
     "granitemoehybrid": ArchType.GRANITE_HYBRID,
     "solar_open2": ArchType.SOLAR_OPEN2,
+    "mellum": ArchType.MELLUM,
 }
 
 # behind a refusal of ``tie_word_embeddings``: which families do carry a tie
@@ -171,6 +172,8 @@ def load_hf_config(folder: str | Path, weight_float_type: int) -> dict:
         params.update(_olmo_hybrid_header(cfg))
     if model_type == "laguna":
         params.update(_laguna_header(cfg))
+    if model_type == "mellum":
+        params.update(_mellum_header(cfg))
 
     if model_type == "axk1":
         return {**params, **_axk1_header(cfg)}
@@ -815,6 +818,62 @@ def _laguna_header(cfg: dict) -> dict:
     }
 
 
+def _mellum_header(cfg: dict) -> dict:
+    """``model_type: mellum``'s config keys as LAGUNA's extension keys and
+    FULL_LAYER_AT (formats/mfile.py). ``layer_types`` must be whole periods
+    of sliding layers CLOSED by a full one, ``mlp_layer_types`` all sparse;
+    one head count, the whole head rotating in both kinds, no shared expert,
+    a whole checkpoint's every expert held. What the published config does
+    not say (pre-norm, the per-head q/k norm of the Qwen3-MoE layout whose
+    keys it uses, a window that counts the current token) the arch implies
+    (models/mellum.py)."""
+    kinds = list(cfg["layer_types"])
+    period = (kinds.index("full_attention") + 1
+              if "full_attention" in kinds else 0)
+    want = (["sliding_attention"] * (period - 1) + ["full_attention"]) \
+        * (len(kinds) // max(period, 1))
+    if period < 2 or kinds != want or len(kinds) != cfg["num_hidden_layers"]:
+        raise ValueError(
+            "mellum: layer_types is not whole periods of sliding layers "
+            "closed by a full one")
+    if set(cfg.get("mlp_layer_types") or ["sparse"]) != {"sparse"} \
+            or cfg.get("attention_bias") or not cfg.get("use_sliding_window", True) \
+            or cfg.get("max_window_layers") \
+            or cfg.get("shared_expert_intermediate_size"):
+        raise ValueError(
+            "mellum: a dense layer, attention bias, a shared expert, "
+            "max_window_layers or use_sliding_window false are not carried")
+    rope = cfg["rope_parameters"]
+    rf, rs = rope["full_attention"], rope["sliding_attention"]
+    if rf.get("rope_type") != "yarn" or rs.get("rope_type") != "default" \
+            or rf.get("partial_rotary_factor", 1) != 1 \
+            or rs.get("partial_rotary_factor", 1) != 1:
+        raise ValueError("mellum: rope_parameters must be yarn on the full "
+                         "layers and default on the sliding ones, both over "
+                         "the whole head")
+    return {
+        "hidden_dim": int(cfg["moe_intermediate_size"]),
+        "head_dim": int(cfg["head_dim"]),
+        "moe_norm_topk": int(bool(cfg.get("norm_topk_prob", False))),
+        "rope_theta": int(rf["rope_theta"]),
+        "rope_type": int(RopeType.YARN),
+        "rope_scaling_factor": int(rf["factor"]),
+        "rope_scaling_low_freq_factor": int(rf["beta_slow"]),
+        "rope_scaling_high_freq_factory": int(rf["beta_fast"]),
+        "rope_scaling_orig_max_seq_len": int(
+            rf["original_max_position_embeddings"]),
+        "layer_period": period,
+        "full_layer_at": period - 1,
+        "sliding_window": int(cfg["sliding_window"]),
+        "n_heads_sliding": int(cfg["num_attention_heads"]),
+        "rope_theta_sliding": int(rs["rope_theta"]),
+        "rope_dim": int(cfg["head_dim"]),
+        "moe_routed_scale_milli": 1000,
+        "moe_router_width": int(cfg["num_experts"]),
+        "moe_first_expert": 0,
+    }
+
+
 # ---------------------------------------------------------------------------
 # tensor plan
 # ---------------------------------------------------------------------------
@@ -849,6 +908,15 @@ def hf_tensor_plan(params: dict) -> list[PlanItem]:
             "tensor names are not: they could not be read where this was "
             "written, and a guessed map is worse than none. The target "
             "layout is formats/mfile.py's _walk_laguna_layer")
+    if arch == ArchType.MELLUM:
+        raise NotImplementedError(
+            "mellum: the header is mapped (load_hf_config), the checkpoint's "
+            "tensor names are not: they could not be read where this was "
+            "written, and a guessed map is worse than none. The target "
+            "layout is formats/mfile.py's _walk_laguna_layer (q k v wo, the "
+            "q and k norms' weights in the gate's place, the router's rows, "
+            "w3 w1 w2 an expert, the two block norms; q and k rows paired "
+            "half-split)")
     if arch == ArchType.AXK1:
         raise NotImplementedError(
             "axk1: the header is mapped (load_hf_config), the checkpoint's "

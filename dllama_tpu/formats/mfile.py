@@ -263,6 +263,13 @@ class ArchType(enum.IntEnum):
     # selection-only bias over experts of which a share may be held, and a
     # shared one (models/solar_open2.py)
     SOLAR_OPEN2 = 0xABCD09
+    # ours: LAGUNA's window and full layers with the full layer LAST in its
+    # period, one head count for both kinds, a per-head RMS norm on q and k
+    # before the rotary embedding, no gate, no dense layer, no shared
+    # expert, every routed expert held or a share (models/mellum.py over
+    # models/laguna.py's walk). LAGUNA's header keys, and FULL_LAYER_AT
+    # stating ``LAYER_PERIOD - 1``
+    MELLUM = 0xABCD0A
 
 
 # the archs whose layers are blocks of ``layer_pattern``
@@ -748,7 +755,21 @@ def parse_header(raw: bytes, path_size: int, max_seq_len: int = 0,
             raise ValueError(
                 f"axk1 model: {h.n_dense_layers} leading dense layers "
                 f"(this walk carries at most one, with its width)")
-    if h.arch_type == ArchType.LAGUNA:
+    if h.arch_type == ArchType.MELLUM:
+        h.n_heads_sliding = h.n_heads_sliding or h.n_heads
+        if (h.full_layer_at != h.layer_period - 1
+                or h.n_heads_sliding != h.n_heads or h.n_dense_layers
+                or h.shared_expert_dim
+                or (h.rope_dim and h.rope_dim != h.head_dim)):
+            raise ValueError(
+                f"mellum model: the full layer at {h.full_layer_at} of a "
+                f"period of {h.layer_period}, {h.n_heads_sliding} sliding "
+                f"heads beside {h.n_heads}, {h.n_dense_layers} dense layers, "
+                f"a shared expert of {h.shared_expert_dim}, {h.rope_dim} "
+                f"rotating lanes: the arch closes its period with the full "
+                f"layer, has one head count, no dense layer, no shared "
+                f"expert, and rotates the whole head")
+    if h.arch_type in (ArchType.LAGUNA, ArchType.MELLUM):
         h.rope_type = RopeType.YARN
         h.moe_router_width = h.moe_router_width or h.n_experts
         if h.layer_period < 2 or h.n_layers % h.layer_period:
@@ -893,7 +914,7 @@ class ModelFile:
             if h.arch_type == ArchType.OLMO_HYBRID:
                 off = self._walk_hybrid_layer(l, off)
                 continue
-            if h.arch_type == ArchType.LAGUNA:
+            if h.arch_type in (ArchType.LAGUNA, ArchType.MELLUM):
                 off = self._walk_laguna_layer(l, off)
                 continue
             if h.arch_type == ArchType.FALCON_H1:
@@ -1213,15 +1234,22 @@ class ModelFile:
         layer's w1 w2 w3 at ``dense_hidden_dim``, or the router's rows over
         ``moe_router_width`` (F32), the HELD experts (w3 w1 w2 each, as the
         other MoE files order them) and the shared expert's w1 w2 w3; the
-        two block norms."""
+        two block norms. A MELLUM file's layer is the same walk with the
+        full layer where ``full_layer_at`` says and, in the gate's place,
+        the q and k norms' ``head_dim`` weights (F32)."""
         h, wt = self.header, self.header.weight_type
-        heads = h.n_heads if l % h.layer_period == 0 else h.n_heads_sliding
+        heads = (h.n_heads if l % h.layer_period == h.full_layer_at
+                 else h.n_heads_sliding)
         q_dim = heads * h.head_dim
         off += self._add("block_matmul_q", l, (q_dim, h.dim), wt, off)
         off += self._add("block_matmul_k", l, (h.kv_dim, h.dim), wt, off)
         off += self._add("block_matmul_v", l, (h.kv_dim, h.dim), wt, off)
         off += self._add("block_matmul_wo", l, (h.dim, q_dim), wt, off)
-        off += self._add("block_attn_gate", l, (heads, h.dim), F32, off)
+        if h.arch_type == ArchType.MELLUM:
+            off += self._add("block_norm_q", l, (h.head_dim,), F32, off)
+            off += self._add("block_norm_k", l, (h.head_dim,), F32, off)
+        else:
+            off += self._add("block_attn_gate", l, (heads, h.dim), F32, off)
         off = self._walk_share_ffn(l, off)
         off += self._add("block_norm_0", l, (h.dim,), F32, off)
         off += self._add("block_norm_1", l, (h.dim,), F32, off)

@@ -206,6 +206,13 @@ class ModelConfig:
     lin_decay_dim: int = 0
     lin_gate_rank: int = 0
     full_layer_at: int = 0
+    # LAGUNA's walk with the full layer LAST in its period
+    # (ArchType.MELLUM, models/mellum.py: ``full_layer_at`` = ``layer_period
+    # - 1``), ``n_heads_sliding`` = ``n_heads``, the whole head rotating in
+    # both kinds, no dense layer and no shared expert. The arch implies:
+    # pre-norm, a per-head RMS norm on q and k before the rotary embedding
+    # (``uses_qk_norm``), a softmax router, NO gate on the attention output
+    # (``has_attention_gate``).
 
     # TPU execution choices (no reference equivalent):
     compute_dtype: str = "float32"  # "float32" for parity, "bfloat16" for speed
@@ -237,6 +244,13 @@ class ModelConfig:
     # Resolved by the engine from --comm-overlap {off,auto,N}; static trace
     # config, so it is part of the multihost cluster fingerprint.
     comm_overlap: int = 0
+    # Rows of the SLIDING part of an admission's column (models/laguna.py's
+    # ``LagunaColumn``): a sliding layer never reads more than ``window - 1``
+    # rows behind a chunk, so its K/V rides the chunks in a buffer of the
+    # window and the widest chunk, not at the slot's length. Set by the
+    # engine from the block size and its prefill buckets
+    # (runtime/kvblocks.window_column_rows); 0: the slot's padded length.
+    window_column_rows: int = 0
 
     @property
     def q_dim(self) -> int:
@@ -254,7 +268,13 @@ class ModelConfig:
     @property
     def uses_qk_norm(self) -> bool:
         """Qwen3 applies per-head RMS norm to q/k before rope (llm.cpp:285-309)."""
-        return self.arch == ArchType.QWEN3
+        return self.arch in (ArchType.QWEN3, ArchType.MELLUM)
+
+    @property
+    def has_attention_gate(self) -> bool:
+        """A sigmoid gate a query head on the attention output before
+        ``wo`` (models/laguna.py; models/mellum.py's walk has none)."""
+        return self.arch == ArchType.LAGUNA
 
     @property
     def is_moe(self) -> bool:
@@ -356,12 +376,13 @@ class ModelConfig:
 
     @property
     def prefix_reuse_skipped(self) -> str | None:
-        """Why a matched prefix block is passed over (the label of
-        ``dllama_prefix_reuse_skipped_total``), or None where blocks are
-        shared."""
-        if self.has_state:
-            return "recurrent_state"
-        return "window_layers" if self.has_window_layers else None
+        """Why a matched prefix block is passed over whatever it holds (a
+        label of ``dllama_prefix_reuse_skipped_total``), or None where blocks
+        are shared: with window layers too, where a match is used as far
+        back as the window pool still holds its window
+        (runtime/kvblocks.match_windowed; a request whose window is gone is
+        counted ``window_miss``)."""
+        return "recurrent_state" if self.has_state else None
 
     @property
     def expert_width_held(self) -> int:
@@ -373,9 +394,12 @@ class ModelConfig:
         a width past 256 is held rounded up to whole tiles of 8 blocks (256
         lanes), the lanes behind it zero in both planes: ``act(0) = 0`` for
         an ungated squared ReLU and zero rows of the down-projection add
-        nothing, so the function is the published width's."""
+        nothing, so the function is the published width's. ArchType.MELLUM
+        alike (896 lanes are 28 scale rows, held as 1024): ``silu(0) * 0 =
+        0`` for its gated experts."""
         wide = self.hidden_dim
-        if not self.layer_pattern or wide <= 256:
+        if wide <= 256 or not (self.layer_pattern
+                               or self.arch == ArchType.MELLUM):
             return wide
         return -(-wide // 256) * 256
 
@@ -601,9 +625,10 @@ class ModelConfig:
                 moe_routed_scale=h.moe_routed_scale_milli / 1000.0,
                 moe_router_width=h.moe_router_width,
                 moe_first_expert=h.moe_first_expert)
-        if h.arch_type == ArchType.LAGUNA:
+        if h.arch_type in (ArchType.LAGUNA, ArchType.MELLUM):
             hybrid = dict(
                 layer_period=h.layer_period,
+                full_layer_at=h.full_layer_at,
                 sliding_window=h.sliding_window,
                 n_heads_sliding=h.n_heads_sliding,
                 rope_theta_sliding=float(h.rope_theta_sliding),

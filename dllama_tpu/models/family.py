@@ -3,7 +3,8 @@ its loader, its sizes, its refusals and its start-up words.
 
 A family's module (``models/llama.py`` for the dense Llama / Qwen3 equations,
 ``hybrid.py``, ``falcon_h1.py``, ``laguna.py``, ``axk1.py``, ``lfm2.py``,
-``nemotron_h.py``, ``granite_hybrid.py``, ``solar_open2.py``) ends
+``nemotron_h.py``, ``granite_hybrid.py``, ``solar_open2.py``, ``mellum.py``)
+ends
 in ``FAMILY = Family(...)``; :func:`family_of` picks it by ``cfg.arch``, and
 ``llama.forward`` / ``llama.paged_forward`` (the one entry of every family),
 the engine, the HBM guard, the loader, the paged generator and the start-up
@@ -101,6 +102,7 @@ _MODULES = {
     ArchType.NEMOTRON_H: "nemotron_h",
     ArchType.GRANITE_HYBRID: "granite_hybrid",
     ArchType.SOLAR_OPEN2: "solar_open2",
+    ArchType.MELLUM: "mellum",
 }
 
 

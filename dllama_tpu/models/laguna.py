@@ -33,7 +33,11 @@ heads) and the vocabulary share (fewer rows) are only smaller numbers in
 the header.
 
 **The stack** is scanned once over PERIODS: a full layer, then ``P - 1``
-sliding ones (a ``fori_loop`` over one traced body). Two attention stacks
+sliding ones (a ``fori_loop`` over one traced body); where the header puts
+the full layer elsewhere in its period (``cfg.full_layer_at``:
+models/mellum.py closes its period with it) the sliding layers in front of
+it are a second ``fori_loop`` over the same body. A gate, a q/k norm, a
+dense layer and a shared expert each exist where their stack is not None. Two attention stacks
 (:class:`AttnParams` over the full and over the sliding layers), the routed
 feed-forward's stacks over the layers that have one, the leading dense
 layer's planes: every Q40 plane stays whole and reaches ``linear`` as stack +
@@ -47,7 +51,12 @@ and the sliding layers' ``[n_sliding, n_window_blocks, ...]`` through a
 second table whose entries behind the window are null (their blocks went back
 to the free list). Both ride the period scan's carry whole and are written
 in place (PERF.md section 6, PR 33). During chunked prefill a slot's context
-is ONE dense column over all layers (:class:`LagunaColumn`).
+is one column (:class:`LagunaColumn`): the full layers dense at the slot's
+length, the sliding layers a buffer of the window and the widest chunk that
+slides with the chunks. A matched prefix brings its window with it
+(runtime/kvblocks.py, "Window layers"): the generator gathers the match's
+last window into the buffer, and a commit writes the buffer's newest rows to
+the slot's own window blocks.
 
 **Three programs, three pairs of closures over ONE walk**
 (:func:`_scan_periods`): :func:`forward` (a prefill chunk over an admission's
@@ -77,7 +86,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import attention
 from ..ops.linear import Weight, linear
-from ..ops.norms import rms_norm
+from ..ops.norms import rms_norm, rms_norm_per_head
 from ..parallel.api import current_plan
 from ..runtime.kvcache import update_layer
 from .config import ModelConfig
@@ -86,7 +95,8 @@ from .llama import (Params, _attend_dense, _attend_paged, _exact_f32_dots,
                     _nonfinite_rows, _poison_logits, _stack_at)
 from .rope import apply_rope_partial, build_partial_rope_cache
 from .share import (ffn_half, require_quantized, route,  # noqa: F401
-                    routed_ffn, routed_pairs, zero_stats, zero_totals)
+                    routed_ffn, routed_pairs, widen_experts, zero_stats,
+                    zero_totals)
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -99,8 +109,14 @@ class AttnParams(NamedTuple):
     wk: Weight            # [N, kv_dim, dim]
     wv: Weight
     wo: Weight            # [N, dim, heads * hd]
-    wg: jax.Array         # [N, heads, dim] float32: the per-head gate's rows
+    # [N, heads, dim] float32: the per-head gate's rows (None: no gate,
+    # models/mellum.py)
+    wg: jax.Array | None
     norm_att: jax.Array   # [N, dim]
+    # [N, hd]: the per-head RMS norm on q and on k in front of the rotary
+    # embedding (``cfg.uses_qk_norm``, models/mellum.py; None: none)
+    norm_q: jax.Array | None = None
+    norm_k: jax.Array | None = None
 
 
 _ATTN_MATMULS = ("wq", "wk", "wv", "wo")
@@ -115,9 +131,9 @@ class LagunaLayers(NamedTuple):
     full: AttnParams
     slide: AttnParams
     norm_ffn: jax.Array    # [L, dim]
-    w1: Weight             # [n_dense, dense_hidden, dim]
-    w2: Weight
-    w3: Weight
+    w1: Weight | None      # [n_dense, dense_hidden, dim]; None: no dense layer
+    w2: Weight | None
+    w3: Weight | None
     moe_gate: jax.Array    # [NM, router_width, dim] float32
     we1: Weight            # [NM, held, dim, hidden]   (in-major, as LayerParams')
     we2: Weight            # [NM, held, hidden, dim]
@@ -128,21 +144,55 @@ class LagunaLayers(NamedTuple):
 
 
 class LagunaColumn(NamedTuple):
-    """One slot's context during chunked prefill: a dense K/V column over
-    ALL layers in the model's order, and the chunks' routing counters."""
+    """One slot's context during chunked prefill, and the chunks' routing
+    counters. The FULL layers' K/V is dense at the slot's length, by
+    position (the slot's gathered view, matched prefix blocks included). A
+    SLIDING layer never reads more than ``window - 1`` rows behind a chunk,
+    so its K/V is a buffer of ``cfg.window_column_rows`` rows (the window and
+    the widest chunk) that holds positions ``[base, base + rows)`` and slides
+    with the chunks (:func:`_slide_column`). Behind a matched prefix the
+    generator gathers the match's last window into it and sets ``base``
+    (runtime/serving.py); a commit writes its newest rows to the window
+    pool's blocks."""
 
-    k: jax.Array       # [L, 1, n_kv, S, hd]
+    k: jax.Array       # [n_full, 1, n_kv, S, hd]
     v: jax.Array
+    wk: jax.Array      # [n_sliding, 1, n_kv, rows, hd]
+    wv: jax.Array
+    base: jax.Array    # int32 scalar: the position of the buffer's row 0
     stats: jax.Array   # [share.N_COUNTS + held] int32
+
+    @classmethod
+    def behind(cls, cfg: ModelConfig, k: jax.Array,
+               v: jax.Array) -> "LagunaColumn":
+        """A sequence's start over the full layers' gathered view: an empty
+        buffer at position 0."""
+        rows = min(k.shape[3], cfg.window_column_rows or k.shape[3])
+        shape = (cfg.n_window_layers, 1, cfg.n_kv_heads, rows, cfg.head_dim)
+        return cls(k=k, v=v, wk=jnp.zeros(shape, k.dtype),
+                   wv=jnp.zeros(shape, k.dtype), base=jnp.int32(0),
+                   stats=zero_stats(cfg))
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, dtype) -> "LagunaColumn":
         from ..runtime.kvcache import padded_cache_len
 
-        shape = (cfg.n_layers, 1, cfg.n_kv_heads,
+        shape = (cfg.n_kv_layers, 1, cfg.n_kv_heads,
                  padded_cache_len(cfg.seq_len), cfg.head_dim)
-        return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                   stats=zero_stats(cfg))
+        return cls.behind(cfg, jnp.zeros(shape, dtype),
+                          jnp.zeros(shape, dtype))
+
+
+def _slide_column(col: LagunaColumn, start_pos, T: int) -> LagunaColumn:
+    """The sliding layers' buffer moved up so that a chunk of ``T`` rows at
+    ``start_pos`` ends inside it: rows roll towards 0 by what ``base`` grows
+    (the rows rolled in behind the chunk are garbage at positions no query
+    of the chunk can see yet). ``rows >= window - 1 + T``, so every key the
+    chunk's windows reach stays."""
+    rows = col.wk.shape[3]
+    base = jnp.maximum(col.base, start_pos + T - rows)
+    roll = lambda a: jnp.roll(a, col.base - base, axis=3)
+    return col._replace(wk=roll(col.wk), wv=roll(col.wv), base=base)
 
 
 def rope_tables(cfg: ModelConfig):
@@ -178,29 +228,32 @@ def _attention_half(cfg: ModelConfig, x: jax.Array, ap: AttnParams,
     q = linear(h, ap.wq).reshape(B, T, heads, cfg.head_dim)
     k = linear(h, ap.wk).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
     v = linear(h, ap.wv).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    gate = jax.nn.sigmoid(jnp.einsum(
-        "btd,hd->bth", h.astype(jnp.float32), ap.wg.astype(jnp.float32),
-        precision=_HIGHEST))
+    gate = None
+    if ap.wg is not None:
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "btd,hd->bth", h.astype(jnp.float32), ap.wg.astype(jnp.float32),
+            precision=_HIGHEST))
+    if ap.norm_q is not None:
+        q = rms_norm_per_head(q, ap.norm_q, cfg.norm_epsilon)
+        k = rms_norm_per_head(k, ap.norm_k, cfg.norm_epsilon)
     q = apply_rope_partial(q, *table, positions)
     k = apply_rope_partial(k, *table, positions)
-    att = attend(q, k, v).astype(jnp.float32) * gate[..., None]
+    att = attend(q, k, v)
+    if gate is not None:
+        att = att.astype(jnp.float32) * gate[..., None]
     return x + linear(att.astype(x.dtype).reshape(B, T, heads * cfg.head_dim),
                       ap.wo)
 
 
-def _attend_window_dense(cfg: ModelConfig, q, k, v, k_l, v_l, start_pos,
-                         positions):
-    """A sliding layer over a dense column ``k_l, v_l [B, n_kv, S, hd]``:
-    the chunk's rows written at ``start_pos``, then attention over the span
-    the chunk's windows reach, cut out of the column (``window - 1 + T``
-    keys, rounded up), not over all ``S``."""
-    T, S = q.shape[1], k_l.shape[2]
-    k_l, v_l = update_layer(k_l, v_l, k, v, start_pos)
-    span = min(S, -(-(cfg.sliding_window + T) // 128) * 128)
-    first = jnp.clip(start_pos + T - span, 0, S - span)
-    cut = lambda a: jax.lax.dynamic_slice_in_dim(a, first, span, axis=2)
-    att = attention(q, cut(k_l), cut(v_l), positions, cfg.head_dim,
-                    window=cfg.sliding_window, key_start=first)
+def _attend_window_buffer(cfg: ModelConfig, q, k, v, k_l, v_l, start_pos,
+                          positions, base):
+    """A sliding layer over its buffer ``k_l, v_l [B, n_kv, rows, hd]`` of
+    positions ``[base, base + rows)``: the chunk's rows written where
+    ``start_pos`` falls in it, then attention over the buffer (the window and
+    the widest chunk), not over the slot's length."""
+    k_l, v_l = update_layer(k_l, v_l, k, v, start_pos - base)
+    att = attention(q, k_l, v_l, positions, cfg.head_dim,
+                    window=cfg.sliding_window, key_start=base)
     return att, k_l, v_l
 
 
@@ -225,7 +278,7 @@ def _scan_periods(params: Params, cfg: ModelConfig, x, caches, stats, live,
     feed-forward over the rows that are ``live``) it does a row at a time, so
     the rows along ``T`` need not be one sequence's: only the two closures
     know."""
-    P = cfg.layer_period
+    P, A = cfg.layer_period, cfg.full_layer_at
     lp: LagunaLayers = params.layers
     t_full, t_slide = rope_tables(cfg)
 
@@ -240,13 +293,19 @@ def _scan_periods(params: Params, cfg: ModelConfig, x, caches, stats, live,
         x, s = ffn_half(cfg, x, lp, l, live, may_be_dense=first)
         return x, box["caches"], stats + s
 
-    def period(carry, p):
-        x, caches, stats = carry
-        x, caches, stats = layer(
-            x, caches, stats, _stack_at(lp.full, p, _ATTN_MATMULS),
-            cfg.n_heads, t_full,
-            lambda q, k, v, c: attend_full(q, k, v, c, p), p * P, True)
+    # the model's layer of a period's full layer and of its ``j``-th sliding
+    # one, in front of the full layer or behind it (Python's choice, made
+    # once: the traced sums are the ones a period that OPENS with its full
+    # layer always had)
+    if A:
+        full_layer = lambda p: p * P + A
+    else:
+        full_layer = lambda p: p * P
+    in_front = lambda p, j: p * P + j
+    behind = lambda p, j: p * P + 1 + j
 
+    def slides(carry, p, lo, hi, layer_of):
+        # the period's sliding layers ``lo .. hi - 1`` (of its ``P - 1``)
         def sliding(j, carry):
             x, caches, stats = carry
             return layer(
@@ -254,9 +313,24 @@ def _scan_periods(params: Params, cfg: ModelConfig, x, caches, stats, live,
                 _stack_at(lp.slide, p * (P - 1) + j, _ATTN_MATMULS),
                 cfg.n_heads_sliding, t_slide,
                 lambda q, k, v, c: attend_slide(q, k, v, c, p, j),
-                p * P + 1 + j, False)
+                layer_of(p, j), False)
 
-        return jax.lax.fori_loop(0, P - 1, sliding, (x, caches, stats)), None
+        return jax.lax.fori_loop(lo, hi, sliding, carry)
+
+    any_in_front, any_behind = A > 0, A < P - 1
+
+    def period(carry, p):
+        if any_in_front:
+            carry = slides(carry, p, 0, A, in_front)
+        x, caches, stats = carry
+        carry = layer(
+            x, caches, stats, _stack_at(lp.full, p, _ATTN_MATMULS),
+            cfg.n_heads, t_full,
+            lambda q, k, v, c: attend_full(q, k, v, c, p),
+            full_layer(p), A == 0)
+        if any_behind:
+            carry = slides(carry, p, A, P - 1, behind)
+        return carry, None
 
     (x, caches, stats), _ = jax.lax.scan(
         period, (x, caches, stats),
@@ -264,22 +338,30 @@ def _scan_periods(params: Params, cfg: ModelConfig, x, caches, stats, live,
     return x, caches, stats
 
 
-def _column_attends(cfg: ModelConfig, start_pos, positions):
-    """A chunk's two closures: its rows at ``start_pos`` over ``(k, v)`` of
-    a dense column ``[L, 1, n_kv, S, hd]``, a full layer over all of its
-    layer's keys, a sliding one over the span its windows reach."""
+def _column_attends(cfg: ModelConfig, start_pos, positions, base):
+    """A chunk's two closures: its rows at ``start_pos`` over a column's
+    ``(k, v, wk, wv)``, a full layer over all of its layer's keys ``[n_full,
+    1, n_kv, S, hd]``, a sliding one over its buffer ``[n_sliding, 1, n_kv,
+    rows, hd]`` of positions from ``base`` (:func:`_slide_column` has moved
+    it under the chunk)."""
     P = cfg.layer_period
+    at = lambda a, l: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
+    put = lambda a, a_l, l: jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
 
-    def over(attend, q, k, v, kv, l):
-        at = lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-        put = lambda a, a_l: jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
-        att, k_l, v_l = attend(cfg, q, k, v, at(kv[0]), at(kv[1]), start_pos,
-                               positions)
-        return att, (put(kv[0], k_l), put(kv[1], v_l))
+    def attend_full(q, k, v, kv, p):
+        ck, cv, wk, wv = kv
+        att, k_l, v_l = _attend_dense(cfg, q, k, v, at(ck, p), at(cv, p),
+                                      start_pos, positions)
+        return att, (put(ck, k_l, p), put(cv, v_l, p), wk, wv)
 
-    return (lambda q, k, v, kv, p: over(_attend_dense, q, k, v, kv, p * P),
-            lambda q, k, v, kv, p, j: over(_attend_window_dense, q, k, v, kv,
-                                           p * P + 1 + j))
+    def attend_slide(q, k, v, kv, p, j):
+        ck, cv, wk, wv = kv
+        l = p * (P - 1) + j
+        att, k_l, v_l = _attend_window_buffer(
+            cfg, q, k, v, at(wk, l), at(wv, l), start_pos, positions, base)
+        return att, (ck, cv, put(wk, k_l, l), put(wv, v_l, l))
+
+    return attend_full, attend_slide
 
 
 def _pool_attends(cfg: ModelConfig, positions, t_full, t_win):
@@ -324,10 +406,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     x = params.embedding[tokens].astype(cfg.compute_dtype)
     positions = jnp.broadcast_to(
         start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
-    x, (k, v), stats = _scan_periods(
-        params, cfg, x, (col.k, col.v), col.stats, live, positions,
-        *_column_attends(cfg, start_pos, positions))
-    return _head(params, cfg, x), LagunaColumn(k=k, v=v, stats=stats)
+    col = _slide_column(col, start_pos, T)
+    x, (k, v, wk, wv), stats = _scan_periods(
+        params, cfg, x, (col.k, col.v, col.wk, col.wv), col.stats, live,
+        positions, *_column_attends(cfg, start_pos, positions, col.base))
+    return _head(params, cfg, x), col._replace(k=k, v=v, wk=wk, wv=wv,
+                                               stats=stats)
 
 
 def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -426,16 +510,18 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                     axis=1), (kv, pools))
         return attend
 
-    x, ((k, v), (fk, fv, wk, wv)), stats = _scan_periods(
-        params, cfg, x, ((col.k, col.v), (pkv.k, pkv.v, wkv.k, wkv.v)),
+    col = _slide_column(col, chunk_pos, T)
+    x, ((k, v, ck, cv), (fk, fv, wk, wv)), stats = _scan_periods(
+        params, cfg, x, ((col.k, col.v, col.wk, col.wv),
+                         (pkv.k, pkv.v, wkv.k, wkv.v)),
         zero_stats(cfg), live, positions,
-        *map(side_by_side, _column_attends(cfg, chunk_pos, cpos),
+        *map(side_by_side, _column_attends(cfg, chunk_pos, cpos, col.base),
              _pool_attends(cfg, rpos, t_full, t_win)))
     logits = _head(params, cfg, jnp.swapaxes(x[:, T:], 0, 1))      # [R, 1, V]
     last = _poison_logits(logits[:, -1, :], poison)
     greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
     return ((greedy, _nonfinite_rows(last), last),
-            (col._replace(k=k, v=v),
+            (col._replace(k=k, v=v, wk=ck, wv=cv),
              (PagedKVCache(k=fk, v=fv), PagedKVCache(k=wk, v=wv),
               totals.at[1].add(stats))))
 
@@ -449,8 +535,8 @@ def _load_params(ld, cfg: ModelConfig) -> Params:
     h = ld.h
     P, hd = h.layer_period, h.head_dim
     every = list(range(h.n_layers))
-    full_ids = [l for l in every if l % P == 0]
-    slide_ids = [l for l in every if l % P]
+    full_ids = [l for l in every if l % P == h.full_layer_at]
+    slide_ids = [l for l in every if l % P != h.full_layer_at]
     dense_ids, moe_ids = every[:h.n_dense_layers], every[h.n_dense_layers:]
     mm = lambda ids, name, o, i: ld.matmul(
         name, o, i, stacked=True, out_axis=None, in_axis=None, layers=ids)
@@ -461,24 +547,30 @@ def _load_params(ld, cfg: ModelConfig) -> Params:
             wk=mm(ids, "block_matmul_k", h.kv_dim, h.dim),
             wv=mm(ids, "block_matmul_v", h.kv_dim, h.dim),
             wo=mm(ids, "block_matmul_wo", h.dim, heads * hd),
-            wg=ld.stacked_f32("block_attn_gate", heads, h.dim, layers=ids),
-            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=ids))
+            wg=(ld.stacked_f32("block_attn_gate", heads, h.dim, layers=ids)
+                if cfg.has_attention_gate else None),
+            norm_att=ld.stacked_f32("block_norm_0", h.dim, layers=ids),
+            **({name: ld.stacked_f32("block_" + name, hd, layers=ids)
+                for name in ("norm_q", "norm_k")}
+               if cfg.uses_qk_norm else {}))
 
     wide, sh = h.dense_hidden_dim, h.shared_expert_dim
-    experts = lambda name, o, i: ld.expert_stack(name, o, i, None, None,
-                                                 layers=moe_ids)
+    # (an expert's planes are HELD ``cfg.expert_width_held`` wide)
+    experts = lambda name, o, i, axis: widen_experts(
+        ld.expert_stack(name, o, i, None, None, layers=moe_ids), axis,
+        h.hidden_dim, cfg.expert_width_held)
     return ld.params(LagunaLayers(
         full=attn(full_ids, h.n_heads),
         slide=attn(slide_ids, h.n_heads_sliding),
         norm_ffn=ld.stacked_f32("block_norm_1", h.dim),
-        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim),
-        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide),
-        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim),
+        w1=mm(dense_ids, "block_matmul_w1", wide, h.dim) if dense_ids else None,
+        w2=mm(dense_ids, "block_matmul_w2", h.dim, wide) if dense_ids else None,
+        w3=mm(dense_ids, "block_matmul_w3", wide, h.dim) if dense_ids else None,
         moe_gate=ld.stacked_f32("block_moe_gate", h.moe_router_width, h.dim,
                                 layers=moe_ids),
-        we1=experts("block_expert_w1", h.hidden_dim, h.dim),
-        we2=experts("block_expert_w2", h.dim, h.hidden_dim),
-        we3=experts("block_expert_w3", h.hidden_dim, h.dim),
+        we1=experts("block_expert_w1", h.hidden_dim, h.dim, -1),
+        we2=experts("block_expert_w2", h.dim, h.hidden_dim, -2),
+        we3=experts("block_expert_w3", h.hidden_dim, h.dim, -1),
         ws1=mm(moe_ids, "block_shared_w1", sh, h.dim) if sh else None,
         ws2=mm(moe_ids, "block_shared_w2", h.dim, sh) if sh else None,
         ws3=mm(moe_ids, "block_shared_w3", sh, h.dim) if sh else None))
@@ -503,10 +595,10 @@ FAMILY = Family(
     forward=forward,
     paged_forward=paged_forward,
     tick=forward_and_step,
-    # prefix blocks are never shared here, so an admission's column starts
-    # empty (the slot's gathered view is not read); every layer's rows are
-    # built in it, the two pools see them at commit
-    column=lambda cfg, k, v: LagunaColumn.zeros(cfg, k.dtype),
+    # the full layers' gathered view, matched prefix blocks included, and
+    # an empty buffer for the sliding layers: the generator gathers a
+    # match's last window into it
+    column=LagunaColumn.behind,
     load_params=_load_params,
     matmul_weight_count=_matmul_weight_count,
     layer_kinds=lambda cfg: layer_kinds(full=cfg.n_kv_layers,

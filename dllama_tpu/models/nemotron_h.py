@@ -65,7 +65,8 @@ from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
 from .family import Family, layer_kinds, state_refusal
 from .llama import Params, _attend_dense, _attend_paged, _stack_at
-from .share import require_quantized, routed_ffn, zero_stats
+from .share import (require_quantized, routed_ffn, widen_experts,
+                    zero_stats)
 from .ssd_mixer import mixer_chunk, mixer_step
 
 
@@ -379,18 +380,9 @@ def _load_params(ld, cfg: ModelConfig, gated: bool = False) -> Params:
         """The held experts' planes of one projection, their hidden axis
         (``axis`` of a plane ``[in, out]``) padded with zero codes and scales
         to ``cfg.expert_width_held``."""
-        stack = ld.expert_stack(name, o, i, None, None, layers=e_ids)
-
-        def widen(a, to):
-            pad = [(0, 0)] * a.ndim
-            pad[axis] = (0, to - a.shape[axis])
-            return jnp.pad(a, pad)
-
-        if held == h.hidden_dim:
-            return stack
-        return type(stack)(
-            scales=widen(stack.scales, held if axis == -1 else held // 32),
-            codes=widen(stack.codes, held))
+        return widen_experts(
+            ld.expert_stack(name, o, i, None, None, layers=e_ids), axis,
+            h.hidden_dim, held)
 
     return ld.params(NemotronHLayers(
         mixer=MixerParams(

@@ -96,6 +96,24 @@ def step_form(cfg: ModelConfig, rows: int) -> bool:  # dlint: static-fn
             and rows * cfg.n_active_experts <= cfg.moe_router_width)
 
 
+def widen_experts(stack: Weight, axis: int, hidden: int, held: int) -> Weight:
+    """A held expert stack's planes with their hidden axis (``axis`` of a
+    plane ``[in, out]``: -1 where it is the output, -2 the input) padded from
+    ``hidden`` lanes with zero codes and scales to ``held``
+    (``cfg.expert_width_held``)."""
+    if held == hidden:
+        return stack
+
+    def widen(a, to):
+        pad = [(0, 0)] * a.ndim
+        pad[axis] = (0, to - a.shape[axis])
+        return jnp.pad(a, pad)
+
+    return type(stack)(scales=widen(stack.scales,
+                                    held if axis == -1 else held // 32),
+                       codes=widen(stack.codes, held))
+
+
 def _plane(w: Weight, l) -> Weight:
     """Entry ``l`` of a stacked 2-D matmul weight: stack + index for a Q40
     plane (the fused kernel reads it where it lies, llama._layer_at), the

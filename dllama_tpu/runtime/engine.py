@@ -258,6 +258,16 @@ class InferenceEngine:
         # it can't serve must fail at startup with the reason, not as a
         # per-request trace-time error
         self.kv_block_size = max(0, int(kv_block_size or 0))
+        if self.kv_block_size and self.cfg.has_window_layers:
+            # the sliding part of an admission's column: the window, the
+            # widest chunk, and what a commit still writes to blocks
+            from dataclasses import replace as _replace
+
+            from .kvblocks import window_column_rows
+
+            self.cfg = _replace(self.cfg, window_column_rows=window_column_rows(
+                self.cfg.sliding_window, self.kv_block_size,
+                self.prefill_buckets, self.cfg.seq_len))
         if self.kv_block_size:
             from .kvblocks import validate_block_size
 

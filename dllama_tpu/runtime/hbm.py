@@ -122,6 +122,28 @@ def fit_batch_slots(cfg, n_slots: int, *, weight_repr: str,
     return 0, est
 
 
+def admission_column_bytes(cfg, kv_dtype) -> int:
+    """Device bytes of ONE admission's column as its decoder family makes it
+    from a slot's gathered view (``Family.column``, shapes only): the view
+    itself, or with window layers the full layers' view and the sliding
+    layers' buffer of the window and the widest chunk, a recurrent state
+    where there is one."""
+    import math
+
+    import jax
+
+    from ..models.family import family_of
+
+    view = jax.ShapeDtypeStruct(
+        (cfg.n_kv_layers, 1, cfg.cache_heads, padded_cache_len(cfg.seq_len),
+         cfg.cache_width), kv_dtype)
+    col = jax.eval_shape(
+        lambda k, v: family_of(cfg).column(cfg, k, v), view,
+        None if cfg.has_latent_cache else view)
+    return sum(math.prod(a.shape) * a.dtype.itemsize
+               for a in jax.tree.leaves(col))
+
+
 def estimate_block_pool_bytes(cfg, n_blocks: int, block_size: int,
                               kv_dtype_bytes: int) -> int:
     """Device bytes of a paged KV block pool
@@ -134,7 +156,8 @@ def estimate_block_pool_bytes(cfg, n_blocks: int, block_size: int,
 def fit_block_pool(cfg, n_blocks: int, *, block_size: int, min_blocks: int,
                    weight_repr: str, kv_dtype_bytes: int, n_shards: int = 1,
                    offload: bool = False,
-                   state_bytes: int = 0) -> tuple[int, dict]:
+                   state_bytes: int = 0,
+                   column_bytes: int = 0) -> tuple[int, dict]:
     """Largest paged block-pool size ``<= n_blocks`` whose estimate fits
     the device limit — the paged twin of :func:`fit_batch_slots`: blocks
     are the admission currency, so the pool shrinks block-granularly
@@ -148,7 +171,12 @@ def fit_block_pool(cfg, n_blocks: int, *, block_size: int, min_blocks: int,
     the device size stops bounding how many idle sessions keep their
     KV. ``state_bytes`` is the recurrent state pool of a decoder that has one
     (kvblocks.state_pool_bytes): it does not shrink with the blocks, so it
-    is charged whole, beside them."""
+    is charged whole, beside them. So are ``column_bytes``: the admission
+    columns of every slot at the family's column shape
+    (:func:`admission_column_bytes`; every slot can be mid-prefill at once,
+    as a start-up burst is), so that a configuration whose columns do not
+    fit is degraded or refused HERE and not by an allocation failing under
+    load."""
     limit = (None if os.environ.get("DLLAMA_SKIP_HBM_CHECK")
              else device_memory_bytes())
     base = estimate_device_bytes(
@@ -160,9 +188,11 @@ def fit_block_pool(cfg, n_blocks: int, *, block_size: int, min_blocks: int,
         est = dict(base)
         est["kv_pool_bytes"] = pool
         est["state_pool_bytes"] = state_bytes
+        est["admission_columns_bytes"] = column_bytes
         est["need_per_device"] = (
             base["need_per_device"]
-            + int((pool / max(1, n_shards) + state_bytes) * _MARGIN))
+            + int((pool / max(1, n_shards) + state_bytes
+                   + column_bytes / max(1, n_shards)) * _MARGIN))
         return est
 
     n = max(min_blocks, n_blocks)

@@ -36,15 +36,30 @@ in XLA:
 * **Window layers** — a block id addresses ``pool[l, bid]`` for every
   layer of a pool at once, so a sliding-window layer cannot give a block
   back while a full-attention layer keeps it. A model with both
-  (models/laguna.py) has TWO pools and two tables a sequence: the full
-  layers' here, and the window layers' in a second :class:`PagedKVCache`
-  over a second :class:`BlockPool` (no prefix index is ever built in it),
-  whose blocks return to the free list as soon as every position in them
-  is more than ``window - 1`` behind the row's next position
+  (models/laguna.py, models/mellum.py) has TWO pools and two tables a
+  sequence: the full layers' here, and the window layers' in a second
+  :class:`PagedKVCache` over a second :class:`BlockPool`, whose blocks a
+  sequence gives back as soon as every position in them is more than
+  ``window - 1`` behind the row's next position
   (:func:`window_first_block`); their table entries become the null
   block, which paged attention never reads (its walk starts at the first
-  live block). Prefix sharing would hand a request blocks the window pool
-  has already taken back, so it is off for such a model, and counted.
+  live block). **A matched prefix brings its window with it.** A match of
+  ``m`` tokens in the full pool is usable only if the window pool still
+  holds the sliding layers' K/V of positions ``m - window + 1 .. m - 1``
+  computed behind the same tokens (they cannot be rebuilt from the last
+  window's tokens: a sliding layer's K/V at a position is a function of
+  every lower layer's output there, whose reach grows by a window a
+  sliding layer). So the window pool has registrations of its own, under
+  the FULL pool's chain ids (:meth:`BlockPool.register_keyed`: a chain id
+  names a whole prefix, and K/V at a block's positions is a function of
+  that prefix alone): a commit registers the prompt's last window, an
+  admission that passes a boundary the full pool matched and the window
+  pool missed leaves that boundary's window behind
+  (``PagedGenerator._save_window``), a registered block a sequence gives
+  back parks refcount-0 in the LRU (still shareable) and is what an
+  allocation takes back once the free list is dry. :func:`match_windowed`
+  returns the longest block boundary BOTH pools hold. No copy-on-write
+  tail: a boundary is a whole block.
 
 * **Host tier** — with ``n_host_blocks > 0`` (``--kv-host-blocks``), the
   LRU cached machinery becomes a *spill point* instead of a drop point:
@@ -191,6 +206,46 @@ def window_blocks_cap(window: int, block_size: int) -> int:
     most: the window's, one more where it starts mid-block, and the block
     the next position opens before the oldest is returned."""
     return window // block_size + 2
+
+
+def window_column_rows(window: int, block_size: int, buckets,
+                       seq_len: int) -> int:
+    """Rows of the sliding layers' buffer in an admission's column
+    (models/laguna.LagunaColumn): the keys the widest chunk's windows reach
+    (``window - 1`` behind it and itself), and what a commit or a saved
+    boundary still writes to blocks behind a chunk that was padded to the
+    narrowest bucket (the window's first block may start ``block_size - 1``
+    before the window, a boundary ``block_size - 1`` before the chunk's
+    end). Whole tiles of 128; never more than the slot's padded length."""
+    need = window + max(max(buckets) - 1,
+                        2 * block_size + min(buckets) - 4)
+    return min(padded_cache_len(seq_len), -(-need // 128) * 128)
+
+
+def match_windowed(pool: "BlockPool", wpool: "BlockPool", tokens,
+                   window: int) -> tuple[list[int], dict[int, int], list[int]]:
+    """Longest block boundary of ``tokens`` that BOTH pools hold, for a model
+    with window layers: ``(shared, window_bids, chain)``.
+
+    ``chain`` are the chain ids of every full block the FULL pool matched
+    (``len(chain) * block_size`` tokens: what it alone would share).
+    ``shared`` are the first ``b`` of its blocks, ``b`` the largest count
+    whose boundary's window is whole in the window pool: every table index
+    from :func:`window_first_block` of position ``b * block_size`` up to ``b
+    - 1`` registered there under the SAME chain id (``window_bids``: index
+    -> window block). ``b`` is 0 where no boundary's window is whole; no
+    refcount is taken here."""
+    bs = pool.block_size
+    bids, chain = pool.match_chain(tokens)
+    held = [wpool.keyed(cid) for cid in chain]
+    run = best = 0          # consecutive held blocks ending at j; best boundary
+    for j, wbid in enumerate(held):
+        run = run + 1 if wbid is not None else 0
+        b = j + 1
+        if run >= b - window_first_block(b * bs, window, bs):
+            best = b
+    first = window_first_block(best * bs, window, bs)
+    return (bids[:best], {j: held[j] for j in range(first, best)}, chain)
 
 
 class PagedKVCache(NamedTuple):
@@ -563,6 +618,9 @@ class BlockPool:
         self._by_parent: dict[int, list[int]] = {}      # pcid -> candidate tails
         self._meta: dict[int, tuple] = {}               # bid -> (kind, pcid, tokens)
         self._next_cid = 1  # 0 is _ROOT (the empty prefix)
+        # a window pool's registrations: the FULL pool's chain id -> block
+        # (``_meta[bid] = ("keyed", key, None)``); its own trie stays empty
+        self._keyed: dict[int, int] = {}
 
     # -- accounting ----------------------------------------------------------
 
@@ -676,7 +734,13 @@ class BlockPool:
         self._nodes.clear()
         self._by_parent.clear()
         self._meta.clear()
+        self._keyed.clear()
         self._next_cid = 1
+
+    def cached_blocks(self) -> int:
+        """Registered blocks parked at refcount 0 (shareable until an
+        allocation takes them back)."""
+        return len(self._cached)
 
     # -- tiering: spill (device→host) and page-in (host→device) -------------
 
@@ -836,8 +900,28 @@ class BlockPool:
                 self._meta[bid] = ("partial", cid,
                                    tuple(tokens[n_full * bs:]))
 
+    def register_keyed(self, bid: int, key: int) -> bool:  # dlint: owner=loop-thread
+        """Register live block ``bid`` under ``key`` (a window pool's block
+        under the full pool's chain id of the same prefix). False, and
+        nothing done, where the key or the block is registered already (the
+        same content committed twice: the first stays)."""
+        if key in self._keyed or bid in self._meta:
+            return False
+        if self._ref[bid] <= 0:
+            raise ValueError(f"block {bid} is not live")
+        self._keyed[key] = bid
+        self._meta[bid] = ("keyed", key, None)
+        return True
+
+    def keyed(self, key: int) -> int | None:
+        """The block registered under ``key``, live or parked, or None."""
+        return self._keyed.get(key)
+
     def _unregister(self, bid: int) -> None:  # dlint: owner=loop-thread
         kind, pcid, blk = self._meta.pop(bid)
+        if kind == "keyed":
+            del self._keyed[pcid]
+            return
         if kind == "full":
             node = self._nodes.get((pcid, blk))
             if node is not None and node[1] == bid:
@@ -854,6 +938,25 @@ class BlockPool:
             if not sibs:
                 del self._by_parent[pcid]
 
+    def match_chain(self, tokens) -> tuple[list[int], list[int]]:  # dlint: owner=loop-thread
+        """The full blocks of ``tokens`` the index holds, as ``(bids,
+        chain ids)``: block ``j``'s chain id names the prefix up to and with
+        it, and is what a window pool registers its block ``j`` under."""
+        bs = self.block_size
+        cid = _ROOT
+        bids: list[int] = []
+        chain: list[int] = []
+        i = 0
+        while i + bs <= len(tokens):
+            node = self._nodes.get((cid, tuple(tokens[i:i + bs])))
+            if node is None:
+                break
+            cid, bid = node
+            bids.append(bid)
+            chain.append(cid)
+            i += bs
+        return bids, chain
+
     def match_prefix(self, tokens) -> tuple[list[int], int, int | None, int]:  # dlint: owner=loop-thread
         """Longest block-level match of ``tokens`` against the index:
         ``(shared_bids, n_shared_tokens, cow_src_bid, cow_tokens)``.
@@ -864,17 +967,9 @@ class BlockPool:
         registered block whose first ``cow_tokens`` ids extend the match —
         the caller allocates a fresh block, device-copies the source into
         it, and resumes prefill at ``n_shared_tokens + cow_tokens``."""
-        bs = self.block_size
-        cid = _ROOT
-        shared: list[int] = []
-        i = 0
-        while i + bs <= len(tokens):
-            node = self._nodes.get((cid, tuple(tokens[i:i + bs])))
-            if node is None:
-                break
-            cid, bid = node
-            shared.append(bid)
-            i += bs
+        shared, chain = self.match_chain(tokens)
+        cid = chain[-1] if chain else _ROOT
+        i = len(shared) * self.block_size
         tail = tuple(tokens[i:])
         best_bid, best_r = None, 0
         if tail:

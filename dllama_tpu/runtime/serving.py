@@ -57,7 +57,8 @@ from ..parallel.multihost import (
 from ..tokenizer.sampler import xorshift_random_f32
 from ..models.share import N_COUNTS
 from .kvblocks import (SPILL_BATCH, BlockPoolExhausted, PageInError,
-                       state_bytes, window_blocks_cap, window_first_block)
+                       match_windowed, state_bytes, window_blocks_cap,
+                       window_first_block)
 from .kvcache import KVCache
 
 if TYPE_CHECKING:
@@ -300,6 +301,11 @@ class _Admission:
     cow: tuple | None = None
     cow_release: int = 0
     need_take: bool = False
+    # window layers: a boundary (in blocks) the full pool matched and the
+    # window pool missed, with the match's chain ids: a chunk is cut to end
+    # on it and its window is left behind (PagedGenerator._save_window)
+    wsave: int = 0
+    wchain: list | None = None
 
 
 class _PendingChunk(NamedTuple):
@@ -463,6 +469,12 @@ class _GeneratorCore:
         and those of them that came from matched blocks. The dense pool
         shares nothing."""
         return 0, 0
+
+    # with window layers: (prompt tokens the full pool matched, admissions
+    # whose whole match was used, bytes of the last admission's column);
+    # None where a slot's context has no window pool
+    def window_totals(self) -> tuple[int, int, int] | None:
+        return None
 
     def take_rows_rode(self) -> bool:  # dlint: owner=loop-thread
         """Whether a prefill chunk's program has stepped the decode rows
@@ -1512,7 +1524,8 @@ class PagedGenerator(_GeneratorCore):
     def __init__(self, engine: "InferenceEngine", n_slots: int = 4):
         from ..runtime.kvblocks import (BlockPool, PagedKVCache, StatePool,
                                         blocks_per_seq, state_pool_bytes)
-        from .hbm import check_budget, fit_block_pool
+        from .hbm import (admission_column_bytes, check_budget,
+                          fit_block_pool)
 
         block_size = int(getattr(engine, "kv_block_size", 0) or 0)
         if block_size <= 0:
@@ -1538,7 +1551,10 @@ class PagedGenerator(_GeneratorCore):
                        if self.cfg.has_window_layers else 0)
         self._wcap = (window_blocks_cap(self.window, block_size)
                       if self.window else 0)
-        n_wblocks = n_slots * self._wcap + 1 if self.window else 0
+        # ... and as many again for the windows that registered boundaries
+        # leave parked (a session's last prompt, a shared prompt's end):
+        # what a matched prefix brings with it (runtime/kvblocks.py)
+        n_wblocks = 2 * n_slots * self._wcap + 1 if self.window else 0
         wpool_bytes = (2 * self.cfg.n_window_layers * n_wblocks
                        * self.cfg.kv_dim * block_size
                        * engine.kv_dtype.itemsize)
@@ -1550,15 +1566,24 @@ class PagedGenerator(_GeneratorCore):
             n_shards=engine.tp * engine.pp,
             offload=(engine.weight_mode == "offload"),
             state_bytes=wpool_bytes + state_pool_bytes(
-                self.cfg, n_slots, jnp.dtype(self.cfg.compute_dtype).itemsize))
+                self.cfg, n_slots, jnp.dtype(self.cfg.compute_dtype).itemsize),
+            # every slot can be mid-prefill at once (a start-up burst is).
+            # The dense decoders' columns are NOT charged yet: an accepted
+            # configuration of theirs (16 slots of 6144) would lose a third
+            # of its pool to them (PERF.md section 7)
+            column_bytes=(n_slots * admission_column_bytes(
+                self.cfg, engine.kv_dtype) if self.cfg.paged_only else 0))
         if n_blocks == 0:
             check_budget(est["need_per_device"],
                          f"paged serving ({want} blocks of {block_size})")
         if n_blocks < want:
             print(f"⚠️ HBM admission guard: {want} KV blocks do not fit the "
                   f"device budget — degrading to {n_blocks} blocks "
-                  f"({(n_blocks - 1) * block_size} cache rows) instead of "
-                  f"risking an OOM (runtime/hbm.py)", flush=True)
+                  f"({(n_blocks - 1) * block_size} cache rows) beside "
+                  f"{n_slots} admission columns of "
+                  f"{est['admission_columns_bytes'] / n_slots / 2**20:.0f} "
+                  f"MiB instead of risking an OOM (runtime/hbm.py)",
+                  flush=True)
         self.hbm_need = est["need_per_device"]
         # tiered KV memory (--kv-host-blocks, runtime/kvblocks.py): a
         # host-DRAM mirror pool sized through the host budget — cold
@@ -1667,6 +1692,11 @@ class PagedGenerator(_GeneratorCore):
         # prompt tokens admitted and those of them that came from matched
         # blocks (or a copy-on-write block's reused rows), since start-up
         self._n_prefix_tokens = self._n_prompt_tokens = 0
+        # with window layers: prompt tokens the FULL pool matched (those of
+        # them whose window was found are the prefix tokens above),
+        # admissions whose whole match was used, and the bytes of the last
+        # admission's column as allocated
+        self._n_full_matched = self._n_window_hits = self._column_bytes = 0
 
         _sc = getattr(engine, "introspection_scope", None) or "default"
         from ..models.llama import paged_sampled_step_guarded
@@ -1748,25 +1778,57 @@ class PagedGenerator(_GeneratorCore):
                 self.cfg, view(pkv.k, table),
                 None if pkv.v is None else view(pkv.v, table))
 
-        full_ids = np.arange(0, self.cfg.n_layers,
-                             max(1, self.cfg.layer_period))
-        slide_ids = np.setdiff1d(np.arange(self.cfg.n_layers), full_ids)
-
         def back(pool, c, table):
             L = c.shape[0]
             c = c[:, 0].reshape(L, heads, M, bs, width)
             c = jnp.moveaxis(c, 2, 1)                 # [L, M, n_kv, bs, hd]
             return pool.at[:, table].set(c.astype(pool.dtype))
 
-        def _put_window_fn(pkv, wkv, stats, col, table, wtable):
-            # the column's full layers through the slot's table, its
-            # sliding layers through the window table (entries behind the
-            # window are null: those rows land in the null block), and the
-            # chunks' routing counters into the running totals' chunk row
-            return (PagedKVCache(k=back(pkv.k, col.k[full_ids], table),
-                                 v=back(pkv.v, col.v[full_ids], table)),
-                    PagedKVCache(k=back(wkv.k, col.k[slide_ids], wtable),
-                                 v=back(wkv.v, col.v[slide_ids], wtable)),
+        # window blocks one program moves between the window pool and a
+        # column's sliding buffer: a window's, and the two a cap counts
+        lanes = self._wcap
+        in_block = jnp.arange(bs, dtype=jnp.int32)[None, :]
+
+        def _window_rows(col, widx):
+            # [lanes * bs]: where table index widx's rows lie in the buffer
+            return (widx[:, None] * bs + in_block - col.base).reshape(-1)
+
+        def _save_window_fn(wkv, col, widx, wdst):
+            # the buffer's rows of table indices ``widx`` into window
+            # blocks ``wdst`` (a lane not in use: the null block)
+            rows = jnp.clip(_window_rows(col, widx), 0, col.wk.shape[3] - 1)
+
+            def put(pool, buf):
+                g = buf[:, 0][:, :, rows]          # [NS, n_kv, lanes*bs, hd]
+                g = g.reshape(g.shape[0], heads, lanes, bs, width)
+                return pool.at[:, wdst].set(
+                    jnp.moveaxis(g, 2, 1).astype(pool.dtype))
+
+            return PagedKVCache(k=put(wkv.k, col.wk), v=put(wkv.v, col.wv))
+
+        def _take_window_fn(wkv, col, wsrc, widx, base):
+            # a matched boundary's window blocks ``wsrc`` (table indices
+            # ``widx``; a lane not in use names an index past the buffer)
+            # into the column's sliding buffer, which starts at ``base``
+            col = col._replace(base=base)
+            rows = _window_rows(col, widx)
+
+            def fill(buf, pool):
+                g = jnp.moveaxis(pool[:, wsrc], 2, 1)  # [NS, n_kv, lanes, bs, hd]
+                g = g.reshape(g.shape[0], heads, lanes * bs, width)
+                return buf.at[:, 0, :, rows].set(
+                    jnp.moveaxis(g, 2, 0).astype(buf.dtype), mode="drop")
+
+            return col._replace(wk=fill(col.wk, wkv.k), wv=fill(col.wv, wkv.v))
+
+        def _put_window_fn(pkv, wkv, stats, col, table, widx, wdst):
+            # the column's full layers through the slot's table (matched
+            # entries null there), its sliding layers' newest rows into the
+            # slot's own window blocks, and the chunks' routing counters
+            # into the running totals' chunk row
+            return (PagedKVCache(k=back(pkv.k, col.k, table),
+                                 v=back(pkv.v, col.v, table)),
+                    _save_window_fn(wkv, col, widx, wdst),
                     stats.at[1].add(col.stats))
 
         def _state_put_fn(spool, s, conv, row):
@@ -1805,6 +1867,8 @@ class PagedGenerator(_GeneratorCore):
         self._take = jax.jit(_take_fn)  # dlint: disable=jit-entry
         self._put_latent = jax.jit(_put_latent_fn, donate_argnums=(0, 1))  # dlint: disable=jit-entry
         self._put_window = jax.jit(_put_window_fn, donate_argnums=(0, 1, 2))  # dlint: disable=jit-entry
+        self._take_window = jax.jit(_take_window_fn, donate_argnums=(1,))  # dlint: disable=jit-entry
+        self._save_window_blocks = jax.jit(_save_window_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
         # a recurrent state's commit writes the admission's state to the
         # slot's row of the state pool, in place
         self._state_put = jax.jit(_state_put_fn, donate_argnums=(0,))  # dlint: disable=jit-entry
@@ -1831,6 +1895,14 @@ class PagedGenerator(_GeneratorCore):
         # shapes on the first post-decode admission (the donated-output
         # recompile the canary docs measured)
         self.pkv = self._copy_block(self.pkv, jnp.int32(0), jnp.int32(0))
+        if self.window:
+            # ... and the two programs that move a boundary's window between
+            # the window pool and a column's sliding buffer, on lanes that
+            # name no block: the first admission behind a match, and the
+            # first boundary left behind, must not be a compile either
+            nobody = self._window_lanes({})
+            col = self._exec_take_window(self._exec_take([]), {}, 0)
+            self.wkv = self._save_window_blocks(self.wkv, col, *nobody)
         # host KV tier: the mirror owns the host buffers + transfer
         # programs; its warmup compiles the gather/scatter pair and
         # exercises both device_put hops on the null block NOW, so the
@@ -1898,6 +1970,8 @@ class PagedGenerator(_GeneratorCore):
             telemetry.KV_WINDOW_BLOCKS_ALLOCATED)
         self._m_wblocks_returned = self._tm.counter(
             telemetry.KV_WINDOW_BLOCKS_RETURNED)
+        self._m_wblocks_parked = self._tm.gauge(
+            telemetry.KV_WINDOW_BLOCKS_PARKED)
         self._tm.gauge(telemetry.KV_WINDOW_BLOCKS_TOTAL).set(
             max(0, n_wblocks - 1))
         self._m_moe_pairs = self._tm.counter(telemetry.MOE_PAIRS)
@@ -1915,10 +1989,17 @@ class PagedGenerator(_GeneratorCore):
     def prefix_totals(self) -> tuple[int, int]:
         return int(self._n_prefix_tokens), int(self._n_prompt_tokens)
 
+    def window_totals(self) -> tuple[int, int, int] | None:
+        if self.wpool is None:
+            return None
+        return (int(self._n_full_matched), int(self._n_window_hits),
+                self._column_bytes)
+
     def _update_block_gauges(self) -> None:
         self._m_blocks_used.set(self.pool.used_blocks())
         if self.wpool is not None:
             self._m_wblocks_used.set(self.wpool.used_blocks())
+            self._m_wblocks_parked.set(self.wpool.cached_blocks())
         if self.spool is not None:
             self._m_state_used.set(self.n_active)
         self._m_blocks_shared.set(self.pool.shared_blocks())
@@ -2238,12 +2319,21 @@ class PagedGenerator(_GeneratorCore):
                 f"(seq_len {self.cfg.seq_len})")
         t_begin = telemetry.now_ns()  # the "admit" span: block bookkeeping
         rest = ids[:-1]
+        wshared: dict[int, int] = {}   # table index -> matched window block
+        chain: list[int] = []          # chain ids of the full pool's match
         if req.score:
             # teacher-forced eval (runtime/evalharness): every position
             # must be scored, so block-level prefix reuse is disabled —
             # a matched prefix would skip its NLL terms and the run would
             # no longer be bit-comparable to the single-sequence oracle
             shared, n_tok, cow_src, cow_r = [], 0, None, 0
+        elif self.wpool is not None:
+            # the longest block boundary BOTH pools hold: a match is used
+            # as far back as its window is whole in the window pool (no
+            # copy-on-write tail: a boundary is a whole block)
+            shared, wshared, chain = match_windowed(self.pool, self.wpool,
+                                                    rest, self.window)
+            n_tok, cow_src, cow_r = len(shared) * self.block_size, None, 0
         else:
             shared, n_tok, cow_src, cow_r = self.pool.match_prefix(rest)
         if req.score and self.latent:
@@ -2251,21 +2341,25 @@ class PagedGenerator(_GeneratorCore):
                 "teacher-forced scoring is not carried to a latent column "
                 "(its chunks carry routing counters, not scores)")
         skip = self.cfg.prefix_reuse_skipped
+        if req.score and (skip is not None or self.wpool is not None):
+            raise ValueError(
+                "teacher-forced scoring is not carried to a "
+                "recurrent state (its chunks run unmasked), "
+                "nor to window layers (their column carries routing "
+                "counters, not scores)")
         if skip is not None:
-            if req.score:
-                raise ValueError(
-                    "teacher-forced scoring is not carried to a "
-                    "recurrent state (its chunks run unmasked), "
-                    "nor to window layers (their column carries routing "
-                    "counters, not scores)")
             # a matched block holds K/V this request did not compute and
-            # NO state of the linear layers (or, with window layers, names
-            # positions whose window blocks went back to their pool long
-            # ago): nothing is reused, and the counter says a match was
-            # passed over
+            # NO state of the linear layers: nothing is reused, and the
+            # counter says a match was passed over
             if n_tok or (cow_src is not None and cow_r > 0):
                 self._m_skipped.inc(reason=skip)
             shared, n_tok, cow_src, cow_r = [], 0, None, 0
+        if len(chain) > len(shared):
+            # the full pool matched a boundary whose window the window pool
+            # no longer holds: prefilled from the longest boundary both
+            # hold (or 0), and this admission leaves the missed boundary's
+            # window behind as its chunks pass it (_save_window)
+            self._m_skipped.inc(reason="window_miss")
         # KV tier: matched blocks may be HOST-resident (a resumed /
         # prefix-matched session whose cold blocks spilled under
         # pressure). Stage their page-in NOW — device blocks allocated
@@ -2287,14 +2381,23 @@ class PagedGenerator(_GeneratorCore):
         wbids: dict[int, int] = {}
         try:
             if self.wpool is not None and rest:
-                # the window pool's share of the prompt: the blocks the
-                # first decode step's window still reaches (the earlier
-                # positions live in the admission's column only, and are
-                # never written to a block)
+                # the matched boundary's window FIRST, pinned across the
+                # allocations below (refcount >= 1: no allocation takes a
+                # parked block back from under this admission)
+                for idx, b in wshared.items():
+                    self.wpool.share(b)
+                    wbids[idx] = b
+                # the window pool's share of the prompt: the window of the
+                # prompt's LAST block boundary, which the commit registers
+                # for the session's next turn, and so the blocks the first
+                # decode step's window still reaches (the earlier positions
+                # live in the admission's column only, and are never
+                # written to a block)
+                bs = self.block_size
                 for idx in range(
-                        window_first_block(len(rest), self.window,
-                                           self.block_size),
-                        (len(rest) - 1) // self.block_size + 1):
+                        max(len(shared), window_first_block(
+                            len(rest) // bs * bs, self.window, bs)),
+                        (len(rest) - 1) // bs + 1):
                     wbids[idx] = self.wpool.alloc()
             # pin every DEVICE-resident matched block FIRST: the page-in
             # (and CoW/growth) allocations below resolve pressure against
@@ -2354,6 +2457,8 @@ class PagedGenerator(_GeneratorCore):
             need_take = reused < len(rest) or self.spool is not None
             col = (self._exec_take(bids)
                    if need_take and not pairs else None)
+            if col is not None and wshared:
+                col = self._exec_take_window(col, wshared, n_tok)
         except Exception as e:  # noqa: BLE001 — atomic rollback, re-raised
             # ANY failure before the slot owns the blocks (exhaustion, a
             # device error in the CoW copy or the column gather) releases
@@ -2378,8 +2483,16 @@ class PagedGenerator(_GeneratorCore):
                     telemetry.KV_BLOCK_EXHAUSTION).inc()
             raise
         self._seq_bids[slot] = bids
+        if wshared:
+            # a matched block behind the prompt's last window was pinned for
+            # the gather alone (enqueued above, in front of whatever writes
+            # the block next): it parks again, registered as it was
+            first = window_first_block(len(rest), self.window,
+                                       self.block_size)
+            for idx in [i for i in wshared if i < first]:
+                self.wpool.release(wbids.pop(idx))
         self._wbids[slot] = wbids
-        self._m_wblocks_alloc.inc(len(wbids))
+        self._m_wblocks_alloc.inc(len(wbids) - sum(i in wbids for i in wshared))
         self._n_shared[slot] = len(shared)
         self._reserve[slot] = max(
             0, self._worst_case_blocks(len(ids), req.max_tokens) - len(bids))
@@ -2397,6 +2510,10 @@ class PagedGenerator(_GeneratorCore):
         adm.cow_release = cow_release
         adm.need_take = col is None and need_take
         adm.pos = reused  # prefill resumes after the reused prefix
+        if len(chain) > len(shared):
+            adm.wsave, adm.wchain = len(chain), chain
+        if col is not None and self.wpool is not None:
+            self._column_bytes = sum(a.nbytes for a in jax.tree.leaves(col))
         # paged-lifecycle span: the admission's block match/share/alloc +
         # column gather work (n_tokens = prefix positions reused)
         telemetry.tracer().emit(req.rid, "admit", t_begin,
@@ -2407,6 +2524,8 @@ class PagedGenerator(_GeneratorCore):
         # ``step_wait`` span carries both, ``admit_begin`` its own admissions'
         self._n_prefix_tokens += reused
         self._n_prompt_tokens += len(rest)
+        self._n_full_matched += len(chain) * self.block_size
+        self._n_window_hits += bool(chain) and len(chain) == len(shared)
         self._update_block_gauges()
         return adm
 
@@ -2414,6 +2533,52 @@ class PagedGenerator(_GeneratorCore):
         table = np.full(self.table_width, self.pool.NULL, dtype=np.int32)
         table[:len(bids)] = bids
         return self._pin_home(self._take(self.pkv, jnp.asarray(table)))
+
+    def _window_lanes(self, blocks: dict[int, int]):
+        """``(table indices, window blocks)`` as the window programs take
+        them: ``_wcap`` lanes, one not in use an index past every buffer and
+        the null block."""
+        widx = np.full(self._wcap, 1 << 24, np.int32)
+        wbid = np.zeros(self._wcap, np.int32)
+        widx[:len(blocks)] = list(blocks)
+        wbid[:len(blocks)] = list(blocks.values())
+        return jnp.asarray(widx), jnp.asarray(wbid)
+
+    def _exec_take_window(self, col, wshared: dict[int, int], n_tok: int):
+        """The matched boundary's window gathered into the column's sliding
+        buffer, which then ends at the boundary."""
+        widx, wsrc = self._window_lanes(wshared)
+        base = max(0, n_tok - col.wk.shape[3])
+        return self._pin_home(self._take_window(self.wkv, col, wsrc, widx,
+                                                jnp.int32(base)))
+
+    def _save_window(self, adm: "_Admission") -> None:  # dlint: owner=loop-thread
+        """Leave a boundary's window behind: the chunks have just reached
+        the boundary the full pool matched and the window pool missed
+        (``adm.wsave`` blocks), so the column's sliding buffer ends with its
+        window. Those rows go into fresh window blocks registered under the
+        boundary's chain ids and parked at once: the next request that
+        matches this far finds them. Where the window pool has no block to
+        spare nothing is kept."""
+        n, chain, bs = adm.wsave, adm.wchain, self.block_size
+        adm.wsave, adm.wchain = 0, None
+        got: dict[int, int] = {}
+        try:
+            for idx in range(window_first_block(n * bs, self.window, bs), n):
+                if self.wpool.keyed(chain[idx]) is None:
+                    got[idx] = self.wpool.alloc()
+        except BlockPoolExhausted:
+            for b in got.values():
+                self.wpool.release(b)
+            return
+        if not got:
+            return
+        self.wkv = self._save_window_blocks(self.wkv, adm.col,
+                                            *self._window_lanes(got))
+        for idx, b in got.items():
+            self.wpool.register_keyed(b, chain[idx])
+            self.wpool.release(b)
+        self._m_wblocks_alloc.inc(len(got))
 
     def _pin_home(self, col, by_rank: bool = False):
         """Pin ONE canonical sharding on an admission's column (and on the
@@ -2519,6 +2684,8 @@ class PagedGenerator(_GeneratorCore):
             done = self._advance_traced(adm, span)
         if self._riding is not None:
             self._step_with_chunk()
+        if adm.wsave and adm.pos >= adm.wsave * self.block_size:
+            self._save_window(adm)
         if not done:
             return False
         with self.flight.tick_phase("admit_commit") as span:
@@ -2560,8 +2727,12 @@ class PagedGenerator(_GeneratorCore):
             adm.need_take = False
         rest = adm.req.prompt_ids[:-1]
         if adm.pos < len(rest):
-            n_b = self.eng._prefill_chunk_size(len(rest) - adm.pos)
-            chunk = rest[adm.pos:adm.pos + n_b]
+            # a chunk ends on a boundary whose window is to be left behind
+            end = len(rest)
+            if adm.pos < adm.wsave * self.block_size:
+                end = adm.wsave * self.block_size
+            n_b = self.eng._prefill_chunk_size(end - adm.pos)
+            chunk = rest[adm.pos:min(adm.pos + n_b, end)]
             pad_to = min(n_b, self.cfg.seq_len - adm.pos)
             padded = chunk + [0] * (pad_to - len(chunk))
             if adm.req.score:
@@ -2602,10 +2773,13 @@ class PagedGenerator(_GeneratorCore):
             n_sh = self._n_shared[slot]
             put_table[n_sh:len(bids)] = bids[n_sh:]
             if self.wpool is not None:
+                # the slot's OWN window blocks: a matched one is shared
                 self.pkv, self.wkv, totals = self._put_window(
                     self.pkv, self.wkv, self.moe_stats, adm.col,
                     jnp.asarray(put_table),
-                    jnp.asarray(self._wtable_row(slot)))
+                    *self._window_lanes({i: b for i, b
+                                         in self._wbids[slot].items()
+                                         if i >= n_sh}))
                 # the commit hands the totals back spelled ``()``, the step
                 # and the tick program by rank: one spelling, or the chunk
                 # behind a commit keys a second executable a bucket
@@ -2627,6 +2801,16 @@ class PagedGenerator(_GeneratorCore):
                 self.moe_stats = self._add_chunk_stats(self.moe_stats,
                                                        adm.col.stats)
         self.pool.register_prompt(bids, rest)
+        if self.wpool is not None:
+            # the prompt's last boundary keeps its window: the slot's full
+            # window blocks under the chain ids the full pool gave their
+            # prefixes (one already registered, shared or a duplicate, stays
+            # as it is). They are never written again: positions only
+            # advance, and decode writes past the last full block
+            _, chain = self.pool.match_chain(rest)
+            for idx, b in self._wbids[slot].items():
+                if idx < len(chain):
+                    self.wpool.register_keyed(b, chain[idx])
         # the table goes live only NOW, with the committed pos riding in
         # _arm_decode — no dispatch ever sees this slot's real table
         # paired with a stale position
@@ -2905,6 +3089,13 @@ class PagedGenerator(_GeneratorCore):
                 wait.set(prefix_tokens=matched, prompt_tokens=prompt,
                          chunks=self._n_chunks,
                          chunks_with_rows=self._n_chunks_rows)
+                if self.wpool is not None:
+                    # what the full pool matched (the prefix tokens above
+                    # are those of them whose window was found), and the
+                    # parked windows beside the window pool's blocks
+                    wait.set(full_matched_tokens=self._n_full_matched,
+                             wblocks_parked=self.wpool.cached_blocks(),
+                             wblocks_total=self.wpool.n_blocks - 1)
         ms = (time.perf_counter() - t0) * 1000.0
         with self.flight.tick_phase("emit"):
             self._settle_prefill(wait.t0_ns, wait.t1_ns,
@@ -3769,6 +3960,7 @@ class BatchScheduler:
                 self._service_migrations()
         with self.flight.tick_phase("admit_begin") as span:
             before = self.gen.prefix_totals()
+            wbefore = self.gen.window_totals()
             rids = self._begin_admissions()
             span.set(admitted=len(rids))
             if rids:
@@ -3777,6 +3969,15 @@ class BatchScheduler:
                 matched, prompt = (a - b for a, b in zip(
                     self.gen.prefix_totals(), before))
                 span.set(prefix_tokens=matched, prompt_tokens=prompt)
+                if wbefore is not None:
+                    # with window layers: the matched length in each pool
+                    # (the window pool's is what was used), the admissions
+                    # whose whole match was, and a column as allocated
+                    after = self.gen.window_totals()
+                    span.set(matched_full=after[0] - wbefore[0],
+                             matched_window=matched,
+                             window_hit=after[1] - wbefore[1],
+                             column_bytes=after[2])
             if rids and span.traced:
                 span.set(rids="/".join(map(str, rids)))
         self._advance_admissions()

@@ -210,6 +210,7 @@ KV_WINDOW_BLOCKS_USED = "dllama_kv_window_blocks_used"
 KV_WINDOW_BLOCKS_TOTAL = "dllama_kv_window_blocks_total"
 KV_WINDOW_BLOCKS_ALLOCATED = "dllama_kv_window_blocks_allocated_total"
 KV_WINDOW_BLOCKS_RETURNED = "dllama_kv_window_blocks_returned_total"
+KV_WINDOW_BLOCKS_PARKED = "dllama_kv_window_blocks_parked"
 MOE_PAIRS = "dllama_moe_pairs_total"
 MOE_CHUNK_ROWS_FED = "dllama_moe_chunk_rows_fed_total"
 MOE_EXPERT_TOKENS = "dllama_moe_expert_tokens_total"
@@ -530,9 +531,11 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "the convolution tails of every row, the null row included"),
     _spec(PREFIX_REUSE_SKIPPED, "counter",
           "Admissions whose prompt matched cached prefix blocks that were "
-          "NOT reused, by reason (recurrent_state: the blocks carry K/V "
-          "but no state of the layers that have one; window_layers: the "
-          "window pool has already taken back the blocks the match names)"),
+          "NOT reused, or not as far as the full pool matched, by reason "
+          "(recurrent_state: the blocks carry K/V but no state of the "
+          "layers that have one; window_miss: the window pool no longer "
+          "holds the window of the boundary the full pool matched, so the "
+          "longest boundary both pools hold was used, or none)"),
     _spec(KV_WINDOW_BLOCKS_USED, "gauge",
           "Blocks of the sliding-window layers' pool held by live "
           "sequences; 0 without window layers"),
@@ -547,6 +550,10 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
           "Blocks given back to the sliding-window layers' pool by a LIVE "
           "sequence because every position in them fell behind its window "
           "(retirement's releases are not counted)"),
+    _spec(KV_WINDOW_BLOCKS_PARKED, "gauge",
+          "Registered blocks of the sliding-window layers' pool parked at "
+          "refcount 0: the windows matched prefixes bring with them, until "
+          "an allocation takes them back; 0 without window layers"),
     _spec(MOE_PAIRS, "counter",
           "(token, expert) pairs the router chose in routed layers, by "
           "where the expert lives: held (computed on this chip) or absent "
@@ -1016,7 +1023,10 @@ PHASES = ("queue", "admit", "prefill", "prefill_chunk", "decode", "verify",
 #   generator's ``begin_admit`` (prefix match, block allocation,
 #   copy-on-write, column gather dispatch) and the cancelled-admission
 #   sweep; the annotation carries ``admitted=<n>`` and, under a
-#   profiler when it admitted something, ``rids`` (joined by ``/``).
+#   profiler when it admitted something, ``rids`` (joined by ``/``);
+#   with window layers also ``matched_full`` / ``matched_window`` (prompt
+#   tokens the full pool matched, and those of them used because the
+#   window pool held their window), ``window_hit`` and ``column_bytes``.
 # * ``prefill_dispatch`` — ``continue_admit`` up to the chunk's enqueue
 #   (page-in batch, deferred copy/gather, the prefill program's
 #   dispatch). Nothing waits for the device here. Under a profiler it

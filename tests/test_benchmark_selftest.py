@@ -33,6 +33,7 @@ PR44_CELL = "lfm2-24b-a2b.batch-generate"
 PR51_CELL = "nemotron-3-super-120b-a12b.reasoning"
 PR54_CELL = "granite-4.0-h-small.doc-qa"
 PR58_CELL = "solar-open2-250b.long-doc"
+PR60_CELL = "mellum2-12b-a2.5b.ide-agent"
 
 sys.path.insert(0, SELFTEST)
 try:
@@ -174,7 +175,8 @@ def test_the_latent_cell_gets_the_modules_it_names_and_its_traffic_is_the_issues
     # every new per-layer metric of the cell names a reader that is there
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json"), encoding="utf-8") as f:
         manifest = json.load(f)
-    mine = [m["name"] for m in manifest["per_layer"] if m.get("workloads") == [PR42_CELL]]
+    # (PR 60's cell, the second with a prefix to hit, was appended behind it in that list)
+    mine = [m["name"] for m in manifest["per_layer"] if m.get("workloads") in ([PR42_CELL], [PR42_CELL, PR60_CELL])]
     assert mine == ["mla_step_share", "mla_step_hbm_share", "mla_step_mxu_share", "mla_chunk_share", "mla_chunk_mxu_share",
                     "prefix_hit_share"]
     for name in mine:
@@ -403,6 +405,8 @@ def test_the_solar_cell_gets_the_modules_it_names_and_the_lists_it_was_appended_
                                                  "max_position_embeddings"]
     # it brought no metric of its own: every list it stands in had a cell before it, and it stands LAST in each
     lists = {m["name"]: m["workloads"] for s in ("end_to_end", "per_layer") for m in manifest[s] if "workloads" in m}
+    # (as PR 58 left them: PR 60's cell was appended behind it since)
+    lists = {n: [c for c in w if c != PR60_CELL] for n, w in lists.items()}
     mine = {n for n, w in lists.items() if PR58_CELL in w}
     assert mine and all(lists[n][-1] == PR58_CELL and len(lists[n]) > 1 for n in mine)
     # behind granite's cell wherever that stands, but for the kernels this step does not run (an SSD mixer; the run

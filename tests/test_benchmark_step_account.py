@@ -249,9 +249,12 @@ def test_manifest_entries_are_appended_with_the_accepted_layers():
     # ``granite-4.0-h-small.doc-qa`` (PR 54): most of its ticks carry a chunk, a chunk and a step are two programs
     # there, and the ticks between two admissions are enough for every median to read (its traced line has all six);
     # and ``solar-open2-250b.long-doc`` (PR 58): three ticks in five are chunk-free steps (its traced line has all six)
+    # and ``mellum2-12b-a2.5b.ide-agent`` (PR 60) to the two that a.x-k1's cell reads too: its traced lines were read
+    # with those two and the other four were not tried there
     later = {name: (["a.x-k1.agent-sessions"] if name in ("step_upload_ms_p50", "step_call_ms_p50") else [])
              + ["lfm2-24b-a2b.batch-generate", "nemotron-3-super-120b-a12b.reasoning", "granite-4.0-h-small.doc-qa",
                 "solar-open2-250b.long-doc"]
+             + (["mellum2-12b-a2.5b.ide-agent"] if name in ("step_upload_ms_p50", "step_call_ms_p50") else [])
              for name in EXPECTED}
     for name in EXPECTED:
         m = by_name[name]

@@ -1083,3 +1083,46 @@ def test_chip_smoke_refuses_to_run_without_a_chip():
                        capture_output=True, text=True, cwd=REPO, timeout=300)
     assert p.returncode != 0
     assert '"ok"' not in p.stdout
+
+
+# -- mellum2-12b-a2.5b at its published widths (PR 60) --------------------------------------
+# 8 of 64 experts a token, every one held, 896 wide HELD in 1024 (``cfg.expert_width_held``: at 896 a plane's
+# scales are 28 rows, the HBM operand is tiled to 32 and Mosaic refuses the DMA's slice of 28: the first chip call
+# of PR 60 died there). The step at 16 rows is past ``share.step_form`` (128 pairs over 64 experts) and takes the
+# run form, as every chunk and every tick program does.
+
+
+@pytest.mark.parametrize("rows", [16, 32 + 16, 256 + 16])
+@pytest.mark.parametrize("kk,n,scatter", [(2304, 1024, False), (1024, 2304, True)])
+def test_expert_chunk_compiles_at_mellums_experts_for_v5e(one_chip, kk, n, rows, scatter):
+    """The grouped routed kernel at an expert's planes over 16 layers of 64
+    held: the step's 16 rows, and a tick program's narrowest and widest
+    bucket with every slot's row joined to it."""
+    from dllama_tpu.ops import expert_chunk as ec
+    from dllama_tpu.ops.linear import QuantizedWeight
+
+    held, k = 64, 8
+    pairs = rows * k
+    fed = ec.fed_rows(pairs, held)
+    assert ec.stripe(rows, fed, kk, n, True, scatter) is not None
+    stack = QuantizedWeight(scales=_shape(one_chip, (16, held, kk // 32, n), jnp.bfloat16),
+                            codes=_shape(one_chip, (16, held, kk, n), jnp.int8))
+    i32 = lambda *shape: _shape(one_chip, shape, jnp.int32)
+    runs = (i32(),) + tuple(i32(held) for _ in range(4))
+    if scatter:
+        kernels = _compiled_kernels(
+            lambda x, st, layer, runs, r, at, w: ec.expert_chunk(x, st, layer, runs, r, (at, w), rows_out=rows, fast=True),
+            _shape(one_chip, (fed, kk), jnp.bfloat16), stack, i32(), runs, i32(pairs), i32(pairs),
+            _shape(one_chip, (rows, k), jnp.float32))
+    else:
+        kernels = _compiled_kernels(
+            lambda x, st, layer, runs, r: ec.expert_chunk(x, st, layer, runs, r, rows_out=fed, fast=True),
+            _shape(one_chip, (rows, kk), jnp.bfloat16), stack, i32(), runs, i32(pairs))
+    assert any("expert_chunk" in name for name in kernels), kernels
+
+
+def test_paged_attention_compiles_at_mellums_tables_for_v5e(one_chip):
+    """The step's walk: 16 slots of 11,776 in blocks of 16 (tables 736 wide,
+    in both pools), 32:4 heads of 128 (a group of 8 query heads a K/V head)."""
+    heads, group = _compile_paged_attention(one_chip, 16, 1, 32, 4, 128, 736, 16, jnp.bfloat16)
+    assert 4 % heads == 0 and group >= 1

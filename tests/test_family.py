@@ -44,6 +44,7 @@ TINY = {
     ArchType.NEMOTRON_H: ("nemotron_h", "tiny-nemotron-h.json"),
     ArchType.GRANITE_HYBRID: ("granite_hybrid", "tiny-granite-hybrid.json"),
     ArchType.SOLAR_OPEN2: ("solar_open2", "tiny-solar-open2.json"),
+    ArchType.MELLUM: ("mellum", "tiny-mellum.json"),
 }
 ARCHS = list(ArchType)
 
@@ -79,6 +80,10 @@ PARENT = {
                            "; layers: 2 full (gated, no positions, 4:2 heads of 16; the first of every 4), 6 delta-rule "
                            "(4 heads of 16 x 16, a decay a key channel (16 a head), gates through 16); experts behind "
                            "every mixer: 4 of 8 held from 2, 3 a token, 32 wide, shared 32, selection bias"),
+    # no parent: PR 60 brought the family; data over laguna's period scan
+    ArchType.MELLUM: (1138688, {"full": 2, "sliding": 6},
+                      "; layers: 3 sliding (window 32) and a full one a period, 2 periods, q/k normed; experts: 16 of 16 "
+                      "held from 0, 4 a token, 32 wide (held in 32)"),
 }
 DENSE_MOE_WEIGHTS = 180736   # tiny_header_params(QWEN3, n_experts=4, n_active_experts=2), the parent's count
 
@@ -155,6 +160,7 @@ TICK = {
     ArchType.NEMOTRON_H: None,
     ArchType.GRANITE_HYBRID: None,
     ArchType.SOLAR_OPEN2: None,
+    ArchType.MELLUM: ("laguna", "forward_and_step"),       # laguna's three programs, the header's data
 }
 
 
@@ -187,10 +193,11 @@ def test_the_dense_equations_share_one_family_and_the_entry_is_llamas(cfgs):
     # the one entry of every family keeps its name (the engine jits it as program ``forward``)
     assert llama.forward.__name__ == "forward" and llama.paged_forward.__name__ == "paged_forward"
     others = {family_of(cfgs[a]) for a in ARCHS if TINY[a] is not None}
-    assert len(others) == 8 and llama.FAMILY not in others
+    assert len(others) == 9 and llama.FAMILY not in others
 
 
-FAMILY_MODULES = {"hybrid", "falcon_h1", "laguna", "axk1", "lfm2", "nemotron_h", "granite_hybrid", "solar_open2"}
+FAMILY_MODULES = {"hybrid", "falcon_h1", "laguna", "axk1", "lfm2", "nemotron_h", "granite_hybrid", "solar_open2",
+                  "mellum"}
 FAMILY_NAMING = {"is_hybrid", "has_ssm", "has_short_conv"}
 FAMILY_ARCHS = {a.name for a in ARCHS} - {"LLAMA", "QWEN3"}
 
@@ -266,9 +273,9 @@ def test_a_dense_routed_files_weight_count_is_the_parents(tmp_path):
 def test_an_admissions_column_is_the_familys(cfgs, arch):
     """What ``PagedGenerator._take`` gets from the slot's gathered view:
     the view itself where prefix blocks are shared (the dense decoders' K/V,
-    the latent rows), a sequence's start where they never are (a zero state
-    and tail in the compute dtype beside the view; an empty column over every
-    layer in the pool's dtype)."""
+    the latent rows, the full layers' K/V beside window layers' empty buffer),
+    a sequence's start where they never are (a zero state and tail in the
+    compute dtype beside the view)."""
     import jax.numpy as jnp
 
     from dllama_tpu.runtime.kvblocks import StateColumn
@@ -283,9 +290,13 @@ def test_an_admissions_column_is_the_familys(cfgs, arch):
         assert isinstance(col, KVCache) and col.k is k and col.v is v
     elif arch == ArchType.AXK1:
         assert type(col).__name__ == "LatentColumn" and col.c is k and not col.stats.any()
-    elif arch == ArchType.LAGUNA:
-        assert type(col).__name__ == "LagunaColumn" and col.k.dtype == k.dtype and not col.k.any()
-        assert col.k.shape == (cfg.n_layers, 1, cfg.n_kv_heads, padded_cache_len(cfg.seq_len), cfg.head_dim)
+    elif arch in (ArchType.LAGUNA, ArchType.MELLUM):
+        # the full layers' view itself (matched prefix blocks are shared), and an empty buffer for the sliding
+        # layers at position 0: the generator gathers a match's last window into it
+        assert type(col).__name__ == "LagunaColumn" and col.k is k and col.v is v
+        assert col.wk.shape == (cfg.n_window_layers, 1, cfg.n_kv_heads, S, cfg.head_dim)
+        assert col.wk.dtype == k.dtype and not col.wk.any() and not col.wv.any() and int(col.base) == 0
+        assert padded_cache_len(cfg.seq_len) > S
     else:
         assert isinstance(col, StateColumn) and col.k is k and col.v is v
         assert col.conv.shape == cfg.conv_shape(1) and col.conv.dtype == jnp.dtype(cfg.compute_dtype)

@@ -153,3 +153,59 @@ def test_estimate_prefill_temp_bytes_scales_with_tokens():
     small = estimate_prefill_temp_bytes(c, 32)
     big = estimate_prefill_temp_bytes(c, 256)
     assert big == small * 8 and small > 0
+
+
+# -- the admission columns (PR 60) -------------------------------------------------
+
+
+def _mellum_cfg(rows=1280, seq_len=11776):
+    """Mellum2-12B-A2.5B's sixteen held layers as the engine reads them."""
+    return ModelConfig(
+        arch=mfile.ArchType.MELLUM, dim=2304, hidden_dim=896, n_layers=16, n_heads=32, n_kv_heads=4, head_dim=128,
+        vocab_size=98304, seq_len=seq_len, norm_epsilon=1e-6, rope_theta=500000.0, rope_type=mfile.RopeType.YARN,
+        n_experts=64, n_active_experts=8, moe_router_width=64, layer_period=4, full_layer_at=3, sliding_window=1024,
+        n_heads_sliding=32, rope_theta_sliding=500000.0, rope_dim=128, compute_dtype="bfloat16",
+        window_column_rows=rows)
+
+
+def test_an_admission_column_is_priced_at_the_familys_shape():
+    """The full layers dense at the slot's length and the sliding layers' buffer
+    of the window and the widest chunk: 127 MB where a column dense over all
+    sixteen layers would be 386 MB; a dense decoder's column is its view."""
+    import jax.numpy as jnp
+
+    from dllama_tpu.runtime.hbm import admission_column_bytes
+
+    row = 2 * 4 * 128 * 2                                 # K and V of 4 heads of 128 in bfloat16
+    stats = admission_column_bytes(_mellum_cfg(), jnp.bfloat16) - row * (4 * 11776 + 12 * 1280)
+    assert 0 < stats < 1024                               # the routing counters and the buffer's position
+    assert admission_column_bytes(_mellum_cfg(rows=0), jnp.bfloat16) - stats == row * 16 * 11776
+    dense = _cfg(seq_len=1024)
+    assert admission_column_bytes(dense, jnp.bfloat16) == 32 * 2 * 8 * 128 * 1024 * 2
+
+
+@pytest.mark.parametrize("limit_gib, fits", [(15.75, "whole"), (14.5, "degraded"), (12.5, "refused")])
+def test_columns_that_do_not_fit_degrade_or_refuse_the_pool_at_construction(monkeypatch, limit_gib, fits):
+    """``slots`` columns are charged whole beside the blocks: the cell's
+    sixteen (2.0 GB) fit a 16 GB chip beside 9 GB of weights and both pools;
+    on a smaller budget the pool shrinks by what they take, and where even one
+    sequence's blocks do not fit beside them the construction is refused."""
+    import jax.numpy as jnp
+
+    from dllama_tpu.runtime.hbm import admission_column_bytes, fit_block_pool
+
+    monkeypatch.setenv("DLLAMA_HBM_BYTES", str(int(limit_gib * 2 ** 30)))
+    cfg, slots = _mellum_cfg(), 16
+    want = slots * 736 + 1
+    window_pool = 2 * 12 * (2 * slots * 66 + 1) * 4 * 128 * 16 * 2
+    kw = dict(block_size=16, min_blocks=737, weight_repr="q40", kv_dtype_bytes=2, state_bytes=window_pool)
+    columns = slots * admission_column_bytes(cfg, jnp.bfloat16)
+    bare, _ = fit_block_pool(cfg, want, **kw)
+    n, est = fit_block_pool(cfg, want, column_bytes=columns, **kw)
+    assert est["admission_columns_bytes"] == columns and 2.0e9 < columns < 2.1e9
+    if fits == "whole":
+        assert n == bare == want
+    elif fits == "degraded":
+        assert 737 <= n < bare <= want
+    else:
+        assert n == 0 and bare > 0

@@ -1375,3 +1375,185 @@ def test_window_pool_churn_never_passes_its_cap_and_comes_back_whole(seed):
         for bid in held[i].values():
             pool.release(bid)
     assert pool.free_blocks() == free0 and pool.used_blocks() == 0
+
+
+# -- window layers: a matched prefix brings its window ------------------------------
+
+
+def _commit(pool, wpool, tokens, window, bs):
+    """What an admission and its commit do to the two allocators, host
+    bookkeeping alone: the full pool's blocks registered, the window blocks of
+    the prompt's last boundary registered under the same chain ids. Returns the
+    blocks the sequence holds in each pool."""
+    from dllama_tpu.runtime.kvblocks import window_first_block
+
+    bids = [pool.alloc() for _ in range(-(-len(tokens) // bs))]
+    pool.register_prompt(bids, tokens)
+    _, chain = pool.match_chain(tokens)
+    n_full = len(tokens) // bs
+    wbids = {idx: wpool.alloc() for idx in range(window_first_block(n_full * bs, window, bs), -(-len(tokens) // bs))}
+    for idx, b in wbids.items():
+        if idx < len(chain):
+            wpool.register_keyed(b, chain[idx])
+    return bids, wbids
+
+
+def test_a_match_is_as_long_as_both_pools_hold_it():
+    from dllama_tpu.runtime.kvblocks import BlockPool, match_windowed, window_first_block
+
+    window, bs = 12, 4                       # a window of 12 reaches back three or four blocks
+    pool, wpool = BlockPool(64, bs), BlockPool(32, bs)
+    tokens = list(range(100, 140))           # ten blocks
+    bids, wbids = _commit(pool, wpool, tokens, window, bs)
+    assert sorted(wbids) == [7, 8, 9] and window_first_block(40, window, bs) == 7
+    shared, wshared, chain = match_windowed(pool, wpool, tokens + [1, 2, 3], window)
+    assert shared == bids and wshared == wbids and len(chain) == 10
+    # a prompt that leaves the chain at block 6 matches six blocks in the full pool; their window (blocks 3-5) was
+    # never kept, so nothing is usable: the window pool missed
+    shared, wshared, chain = match_windowed(pool, wpool, tokens[:24] + [9] * 8, window)
+    assert (shared, wshared, len(chain)) == ([], {}, 6)
+    # the boundary's window left behind under the chain's ids (what the admission that missed does): now it is whole
+    saved = {idx: wpool.alloc() for idx in range(window_first_block(24, window, bs), 6)}
+    for idx, b in saved.items():
+        assert wpool.register_keyed(b, chain[idx])
+        wpool.release(b)                                    # parked at once
+    shared, wshared, chain = match_windowed(pool, wpool, tokens[:24] + [9] * 8, window)
+    assert shared == bids[:6] and wshared == saved
+    # one parked block of that window evicted: the walk goes back to a boundary whose window is whole, here none
+    wpool._unregister(saved[4])
+    wpool._cached.pop(saved[4])
+    wpool._free.append(saved[4])
+    assert match_windowed(pool, wpool, tokens[:24] + [9] * 8, window)[:2] == ([], {})
+    # a second registration under a key already held, or of a block already registered, changes nothing
+    other = wpool.alloc()
+    assert not wpool.register_keyed(other, chain[3]) and not wpool.register_keyed(saved[3], 12345)
+    # under a window shorter than a block every boundary is usable from its own last block
+    assert match_windowed(pool, wpool, tokens, 1)[0] == bids
+
+
+@pytest.mark.parametrize("seed", range(5))
+def test_two_pools_under_sessions_never_match_what_is_not_registered_behind_the_same_chain(seed):
+    """Sessions of growing prompts over two allocators, host bookkeeping alone:
+    every turn matches, shares, allocates, commits, decodes (sliding its
+    window) and retires at random, other sessions evicting what it parked. At
+    every step: a returned match's window blocks are all registered under the
+    chain ids of the SAME prefix and cover the boundary's whole window; a
+    parked block is never in a live sequence's hands, so never a write target;
+    a block a sequence writes has refcount 1; both pools' counts balance (free
+    + parked + live = all) and come back whole once every sequence retires."""
+    from dllama_tpu.runtime.kvblocks import (BlockPool, BlockPoolExhausted, match_windowed, window_blocks_cap,
+                                             window_first_block)
+
+    rng = np.random.default_rng(seed)
+    window, bs, slots = 24, 4, 3
+    cap = window_blocks_cap(window, bs)
+    pool, wpool = BlockPool(slots * 40 + 1, bs), BlockPool(2 * slots * cap + 1, bs)
+    all_full, all_win = pool.free_blocks(), wpool.free_blocks()
+    system = [int(t) for t in rng.integers(0, 50, size=20)]
+    sessions = [list(system) for _ in range(5)]
+    live: dict[int, tuple] = {}                  # slot -> (full bids, window bids by index, position)
+    hits = {"system": 0, "turn": 0}
+
+    def balanced():
+        for p, total in ((pool, all_full), (wpool, all_win)):
+            held = sum(1 for b in range(1, p.n_blocks) if p.refcount(b) > 0)
+            assert len(p._free) + len(p._cached) + held == total
+            assert not set(p._free) & set(p._cached) and all(p.refcount(b) == 0 for b in p._cached)
+        mine = [b for _f, w, _p in live.values() for b in w.values()]
+        assert not set(mine) & set(wpool._cached) and not set(mine) & set(wpool._free)
+
+    for step in range(300):
+        slot = int(rng.integers(slots))
+        if slot in live:
+            bids, wbids, pos = live[slot]
+            if rng.random() < 0.3:                                   # retire
+                for b in bids:
+                    pool.release(b)
+                for b in wbids.values():
+                    wpool.release(b)
+                del live[slot]
+            else:                                                    # a few decode steps: the window slides
+                for _ in range(int(rng.integers(1, 9))):
+                    first = window_first_block(pos, window, bs)
+                    for idx in [j for j in wbids if j < first]:
+                        wpool.release(wbids.pop(idx))
+                    if pos // bs not in wbids:
+                        wbids[pos // bs] = wpool.alloc()
+                    if pos // bs >= len(bids):
+                        bids.append(pool.alloc())
+                    assert wpool.refcount(wbids[pos // bs]) == 1 and pool.refcount(bids[pos // bs]) == 1
+                    pos += 1
+                live[slot] = (bids, wbids, pos)
+            balanced()
+            continue
+        s = int(rng.integers(len(sessions)))
+        sessions[s] = sessions[s] + [int(t) for t in rng.integers(0, 50, size=int(rng.integers(3, 30)))]
+        if len(sessions[s]) > 120:
+            sessions[s] = list(system) + [int(t) for t in rng.integers(0, 50, size=5)]       # a new session
+        tokens = sessions[s]
+        shared, wshared, chain = match_windowed(pool, wpool, tokens, window)
+        n = len(shared)
+        # the match's window is whole and registered behind the same chain
+        assert sorted(wshared) == list(range(window_first_block(n * bs, window, bs), n))
+        assert all(wpool.keyed(chain[idx]) == b for idx, b in wshared.items()) and pool.match_chain(tokens)[1] == chain
+        hits["system" if n * bs == len(system) else "turn" if n else "none"] = \
+            hits.get("system" if n * bs == len(system) else "turn" if n else "none", 0) + 1
+        bids, wbids = list(shared), dict(wshared)
+        try:
+            for b in shared:
+                pool.share(b)
+            for b in wshared.values():
+                wpool.share(b)
+            n_full = len(tokens) // bs
+            while len(bids) < -(-len(tokens) // bs):
+                bids.append(pool.alloc())
+            for idx in range(max(n, window_first_block(n_full * bs, window, bs)), -(-len(tokens) // bs)):
+                wbids[idx] = wpool.alloc()
+        except BlockPoolExhausted:
+            for b in bids:
+                pool.release(b)
+            for b in wbids.values():
+                wpool.release(b)
+            balanced()
+            continue
+        # a block this sequence will WRITE (at or past the match) is its own
+        assert all(pool.refcount(b) == 1 for b in bids[n:]) and all(wpool.refcount(b) == 1
+                                                                    for i, b in wbids.items() if i >= n)
+        if len(chain) > n:                                           # the window pool missed: leave it behind
+            try:
+                got = {idx: wpool.alloc() for idx in range(window_first_block(len(chain) * bs, window, bs), len(chain))
+                       if wpool.keyed(chain[idx]) is None}
+            except BlockPoolExhausted:
+                got = {}
+            for idx, b in got.items():
+                wpool.register_keyed(b, chain[idx])
+                wpool.release(b)
+        pool.register_prompt(bids, tokens)
+        _, chain = pool.match_chain(tokens)
+        for idx, b in wbids.items():
+            if idx < len(chain):
+                wpool.register_keyed(b, chain[idx])
+        first = window_first_block(len(tokens), window, bs)
+        for idx in [j for j in wbids if j < first]:
+            wpool.release(wbids.pop(idx))
+        live[slot] = (bids, wbids, len(tokens))
+        balanced()
+    assert hits["turn"] > 5 and hits["system"] > 0          # both kinds of boundary matched
+    for bids, wbids, _pos in live.values():
+        for b in bids:
+            pool.release(b)
+        for b in wbids.values():
+            wpool.release(b)
+    assert (pool.free_blocks(), wpool.free_blocks()) == (all_full, all_win)
+    assert pool.used_blocks() == 0 and wpool.used_blocks() == 0
+
+
+def test_window_column_rows_hold_the_window_the_widest_chunk_and_a_commits_blocks():
+    from dllama_tpu.runtime.kvblocks import window_column_rows
+
+    buckets = (256, 128, 64, 32)
+    assert window_column_rows(1024, 16, buckets, 11776) == 1280        # mellum2: the window and the widest chunk
+    assert window_column_rows(512, 16, buckets, 4096) == 768           # laguna
+    assert window_column_rows(32, 16, buckets, 512) == 384
+    assert window_column_rows(1024, 128, buckets, 11776) == 1408       # blocks of 128: two of them and the padding
+    assert window_column_rows(1024, 16, buckets, 1024) == 1024         # never more than the slot's padded length

@@ -168,7 +168,7 @@ def test_whole_forward_logits(bench, engine, T):
     cfg = engine.cfg
     tokens = _tokens(T)
     col = laguna.LagunaColumn.zeros(cfg, jnp.float32)
-    assert col.k.shape[0] == 8 and (cfg.n_kv_layers, cfg.n_window_layers, cfg.n_moe_layers) == (2, 6, 7)
+    assert (col.k.shape[0], col.wk.shape[0]) == (2, 6) and (cfg.n_kv_layers, cfg.n_window_layers, cfg.n_moe_layers) == (2, 6, 7)
     logits, col = jax.jit(lambda params, ids, col: llama.forward(params, cfg, ids, jnp.int32(0), col))(
         engine.params, jnp.asarray([tokens], jnp.int32), col)
     want = _reference_logits(bench, engine.params, tokens)
@@ -431,8 +431,8 @@ def test_the_held_share_is_the_whole_layer_less_the_absent_experts(bench, engine
 
 def test_a_returned_block_is_reused_while_the_first_row_still_decodes(bench, engine):
     """Row A decodes past its window and gives blocks back; row B is admitted
-    and takes them (the free list is LIFO) while A still decodes; both rows'
-    logits stay the reference's. After both retire, both pools' free counts
+    and takes them (parked, and the free list dry) while A still decodes; both
+    rows' logits stay the reference's. After both retire, both pools' free counts
     are what they started at."""
     from dllama_tpu.runtime.serving import PagedGenerator, Request
 
@@ -446,8 +446,14 @@ def test_a_returned_block_is_reused_while_the_first_row_still_decodes(bench, eng
     got_a1 = _decode(gen, [0], 21)[0]
     returned = held_at_start - set(gen._wbids[0].values())
     assert returned and gen._m_wblocks_returned.total() >= len(returned)
+    # they are the prompt's last window, which the commit registered: given back, they PARK (a session's next turn
+    # would match them) and are what an allocation takes once the free list is dry
+    assert returned <= set(gen.wpool._cached) and gen.wpool.cached_blocks() == len(returned)
+    spare = [gen.wpool.alloc() for _ in range(len(gen.wpool._free) - 2)]     # B needs three, A one more at 80
     gen.admit(Request(rid=2, prompt_ids=b, max_tokens=64, stop_on_eos=False), 1)
     assert returned & set(gen._wbids[1].values())                 # B holds blocks A gave back
+    for bid in spare:
+        gen.wpool.release(bid)
     # A's row rode B's first chunk (the tick program, PR 57): one token of A's whose logits nobody kept, written at
     # position 80 into a block the window pool handed out after B had taken its own
     assert gen.take_rows_rode() and gen.pos[0] == 81
@@ -498,15 +504,17 @@ def test_admission_asks_both_pools(engine):
 
 def test_scheduler_serves_through_two_pools_and_counts(bench, engine, tmp_path):
     """Through ``BatchScheduler``: interleaved requests finish, the same prompt
-    twice gives the same tokens with the prefix NOT reused (and counted), the
-    routing counters reach the registry with the steps' tokens."""
+    twice gives the same tokens with its prefix REUSED the second time (80 of
+    its 89 positions: the full pool's five blocks, whose last window the
+    window pool had parked), the routing counters reach the registry with the
+    steps' tokens."""
     from dllama_tpu.runtime import telemetry
     from dllama_tpu.runtime.serving import BatchScheduler
 
     reg = telemetry.registry()
     pairs, skipped = reg.counter(telemetry.MOE_PAIRS), reg.counter(telemetry.PREFIX_REUSE_SKIPPED)
     held0, absent0 = pairs.total(where="held"), pairs.total(where="absent")
-    skip0 = skipped.total(reason="window_layers")
+    skip0 = skipped.total()
     sched = BatchScheduler(engine, n_slots=3)
     try:
         prompts = [_tokens(n, seed=n) for n in (90, 33, 150)]
@@ -515,12 +523,12 @@ def test_scheduler_serves_through_two_pools_and_counts(bench, engine, tmp_path):
             assert r.done.wait(300) and not r.error
         again = sched.submit(prompts[0], 12, stop_on_eos=False)
         assert again.done.wait(300) and list(again.tokens) == list(reqs[0].tokens)
-        assert skipped.total(reason="window_layers") == skip0 + 1
+        assert skipped.total() == skip0 and sched.gen.prefix_totals()[0] == 80 and sched.gen.window_totals()[:2] == (80, 1)
         held, absent = pairs.total(where="held") - held0, pairs.total(where="absent") - absent0
-        tokens = sum(len(p) - 1 + 12 for p in prompts + [prompts[0]])         # prefilled + decoded positions
+        tokens = sum(len(p) - 1 + 12 for p in prompts + [prompts[0]]) - 80    # prefilled + decoded positions
         assert held + absent == tokens * 4 * 7
         assert reg.gauge(telemetry.MOE_EXPERTS_HELD).value() == 8 and reg.gauge(telemetry.MOE_EXPERTS_TOTAL).value() == 16
-        assert reg.gauge(telemetry.KV_WINDOW_BLOCKS_TOTAL).value() == 3 * (32 // 16 + 2)
+        assert reg.gauge(telemetry.KV_WINDOW_BLOCKS_TOTAL).value() == 2 * 3 * (32 // 16 + 2)   # live and parked
         want = _reference_logits(bench, engine.params, prompts[1] + list(reqs[1].tokens))
         assert [int(r.argmax()) for r in want[len(prompts[1]) - 1:-1]] == list(reqs[1].tokens)
         # while a profiler listens the steps' spans carry the counters' running totals, and the benchmark's reader
@@ -604,7 +612,7 @@ def test_header_round_trip_and_walk(bench, tmp_path):
         cfg = ModelConfig.from_header(h, "float32")
     assert cfg.has_window_layers and cfg.paged_only and not cfg.is_hybrid and cfg.is_moe
     assert (cfg.n_periods, cfg.n_kv_layers, cfg.n_window_layers, cfg.n_moe_layers) == (2, 2, 6, 7)
-    assert cfg.moe_routed_scale == 2.5 and cfg.prefix_reuse_skipped == "window_layers"
+    assert cfg.moe_routed_scale == 2.5 and cfg.prefix_reuse_skipped is None
     bad = dict(bench["model"], first_expert=12)             # 12 + 8 held runs past the router's 16
     bench["weights"].write_sparse_model(path, bad)
     with pytest.raises(ValueError, match="held of a router over 16"):

@@ -85,7 +85,14 @@ def _inputs(cfg, T, live, sampled=False, seed=0):
     pkv = PagedKVCache(*(noise((cfg.n_kv_layers, R * M + 1, cfg.n_kv_heads, BS, cfg.head_dim)) for _ in "kv"))
     wkv = PagedKVCache(*(noise((cfg.n_window_layers, R * WB + 1, cfg.n_kv_heads, BS, cfg.head_dim)) for _ in "kv"))
     totals = jnp.asarray(rng.integers(1, 1000, size=zero_totals(cfg).shape), jnp.int32)
-    col = laguna.LagunaColumn(*(noise((cfg.n_layers, 1, cfg.n_kv_heads, S, cfg.head_dim)) for _ in "kv"),
+    # the full layers dense at the slot's length, the sliding layers' buffer (the window and the widest chunk:
+    # 384 rows here) at position 0
+    # (drawn over all eight layers at the slot's length, as when the column was dense, and cut from that)
+    k, v = (noise((cfg.n_layers, 1, cfg.n_kv_heads, S, cfg.head_dim)) for _ in "kv")
+    full = np.arange(cfg.full_layer_at, cfg.n_layers, cfg.layer_period)
+    slide = np.setdiff1d(np.arange(cfg.n_layers), full)
+    col = laguna.LagunaColumn(k[full], v[full], k[slide][:, :, :, :cfg.window_column_rows],
+                              v[slide][:, :, :, :cfg.window_column_rows], base=jnp.int32(0),
                               stats=jnp.asarray(rng.integers(1, 1000, size=totals.shape[1:]), jnp.int32))
     tables = np.zeros((2, R, M), np.int32)
     pos = rng.integers(0, 100, size=R).astype(np.int32)
@@ -194,8 +201,8 @@ def test_padding_behind_n_valid_is_not_routed_and_its_rows_are_overwritten(engin
     assert np.any(np.asarray(col_a.k)[:, :, :, 16 + n_valid:16 + T] != np.asarray(col_b.k)[:, :, :, 16 + n_valid:16 + T])
     (_t, _n, _l), (next_a, _p) = run(chunk, col_a, 16 + n_valid, T)
     (_t, _n, _l), (next_b, _p) = run(chunk, col_b, 16 + n_valid, T)
-    np.testing.assert_array_equal(np.asarray(next_a.k), np.asarray(next_b.k))
-    np.testing.assert_array_equal(np.asarray(next_a.v), np.asarray(next_b.v))
+    for a, b in ((next_a.k, next_b.k), (next_a.v, next_b.v), (next_a.wk, next_b.wk), (next_a.wv, next_b.wv)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_a_poisoned_row_fails_alone(engine, programs):
@@ -271,12 +278,12 @@ def test_one_read_of_every_plane_a_layer_and_one_grouped_dispatch(engine, monkey
                         or chunk_form(cfg, x, *a))
     monkeypatch.setattr(share, "_experts_step", lambda cfg, x, *a: forms.append(("step", x.shape[0]))
                         or step_form(cfg, x, *a))
-    paged, dense, window = laguna._attend_paged, laguna._attend_dense, laguna._attend_window_dense
+    paged, dense, window = laguna._attend_paged, laguna._attend_dense, laguna._attend_window_buffer
     monkeypatch.setattr(laguna, "_attend_paged", lambda cfg, q, k, v, kp, *a, **kw: walks.append(
         ("paged", q.shape[:2], kp.shape[0], kw.get("window", 0))) or paged(cfg, q, k, v, kp, *a, **kw))
     monkeypatch.setattr(laguna, "_attend_dense", lambda cfg, q, *a: walks.append(("dense", q.shape[:2]))
                         or dense(cfg, q, *a))
-    monkeypatch.setattr(laguna, "_attend_window_dense", lambda cfg, q, *a: walks.append(("window", q.shape[:2]))
+    monkeypatch.setattr(laguna, "_attend_window_buffer", lambda cfg, q, *a: walks.append(("window", q.shape[:2]))
                         or window(cfg, q, *a))
     jax.eval_shape(lambda p, *a: laguna.forward_and_step(p, cfg, *a), engine.params, tokens, pos, (col, pools),
                    tables, chunk, jnp.int32(16), jnp.int32(32), np.float32(0))
