@@ -20,11 +20,11 @@ state ``[n_linear, H, dk, dv]`` and the convolution's last ``K - 1`` inputs
 of :class:`~dllama_tpu.runtime.kvblocks.StatePool` afterwards).
 
 * :func:`forward`: a prefill chunk over a slot's gathered column. The
-  mixer runs its CHUNK form (ops/gated_delta.gated_delta_chunk), state in
-  and state out. ``n_valid`` masks padding: K/V rows written for padded
-  positions are overwritten later, a state would keep them, so positions at
-  or past ``n_valid`` get ``beta = 0, alpha = 1`` and never enter the
-  convolution's tail.
+  mixer runs its CHUNK form, state in and state out (the Pallas kernel
+  ``gated_delta_chunk`` on a TPU, its XLA twin elsewhere). ``n_valid`` masks
+  padding: K/V rows written for padded positions are overwritten later, a
+  state would keep them, so positions at or past ``n_valid`` get ``beta =
+  0, alpha = 1`` and never enter the convolution's tail.
 * :func:`paged_forward`: the decode step, one token a row. The mixer runs
   its STEP form over the state pool in place (the Pallas kernel
   ``gated_delta_step`` on a TPU, its XLA twin elsewhere); rows whose block
@@ -195,8 +195,11 @@ def _rule_chunk(q, k, v, g, beta, s_l, n_valid):
     every channel, where ``g [B, T, H, dk]`` is a decay a key channel) and
     leave it alone. Returns ``o [B, T, H, dv]`` and the state."""
     real = (jnp.arange(q.shape[1]) < n_valid)[None, :, None]
-    note_gdn_path("chunk", "xla")
-    return gd.gated_delta_chunk(
+    kernel = gd.chunk_kernel_choice(q.shape[1])
+    note_gdn_path("chunk", "xla" if kernel is None else "pallas")
+    chunk = (gd.gated_delta_chunk_xla if kernel is None
+             else lambda *a: gd.gated_delta_chunk(*a, **kernel))
+    return chunk(
         q, k, v, jnp.where(real if g.ndim == 3 else real[..., None], g, 0.0),
         jnp.where(real, beta, 0.0), s_l)
 

@@ -25,19 +25,26 @@ a ``[.., H]`` decay is never broadcast to ``dk`` in memory.
   decay, delta update and readout, and writes it once, aliased onto its
   input; :func:`gated_delta_step_xla` is its twin for the CPU and its oracle.
 * **chunk form** (:func:`gated_delta_chunk`): a prefill chunk, chunkwise
-  parallel. Inside sub-chunks of ``SUB_CHUNK`` tokens everything is a
-  matmul; only the pass over sub-chunks is sequential, with the incoming
-  state in and the outgoing state out. Plain ``jax.numpy``/``lax`` (XLA).
-  :func:`gated_delta_recurrent`, the per-token scan, is its oracle.
+  parallel over sub-chunks of ``SUB_CHUNK`` tokens; only the pass over
+  sub-chunks is sequential, with the incoming state in and the outgoing
+  state out. ONE Pallas kernel (PR 61), named ``gated_delta_chunk`` after
+  its jitted entry: a grid step holds one sub-chunk of a group of heads in
+  VMEM (:func:`_chunk_head` has the body), the state stays there across a
+  head's sub-chunks, and nothing goes back to HBM but ``o`` and, once, the
+  state. :func:`gated_delta_chunk_xla` is its twin for the CPU and for a
+  mesh plan (:func:`chunk_kernel_choice`), plain ``jax.numpy``/``lax``;
+  :func:`gated_delta_recurrent`, the per-token scan, is the oracle of both.
 
 The chunk form's algebra. Inside a sub-chunk write ``G_t = prod_{s<=t}
 alpha_s`` and ``u_t = beta_t (v_t - alpha_t S_{t-1}^T k_t)``, so that ``S_t =
 G_t S_0 + sum_{j<=t} (G_t / G_j) k_j u_j^T``. Substituting ``S_{t-1}`` into
 ``u_t`` gives ``(I + L) U = beta V - (beta K G) S_0`` with ``L`` strictly
 lower triangular, ``L_tj = beta_t (G_t / G_j) (k_t . k_j)``. ``I + L`` is
-inverted by blocked forward substitution (:func:`_unit_lower_inverse`). Then ``O = (Q G) S_0 + tril(Q K^T G_t / G_j) U`` and
-``S_C = G_C S_0 + (K G_C / G)^T U``. A token with ``beta = 0`` and ``alpha =
-1`` leaves the state as it was: that is how a padded position is masked.
+inverted by blocked forward substitution (:func:`_unit_lower_inverse`; the
+kernel solves for ``U`` instead, a column of ``L`` at a time). Then ``O = (Q
+G) S_0 + tril(Q K^T G_t / G_j) U`` and ``S_C = G_C S_0 + (K G_C / G)^T U``. A
+token with ``beta = 0`` and ``alpha = 1`` leaves the state as it was: that is
+how a padded position is masked.
 
 With a VECTOR decay ``G_t`` is a vector too and ``G_t / G_j`` no longer leaves
 the contraction over the key channels: ``L_tj = beta_t sum_c k_tc (G_tc /
@@ -322,8 +329,8 @@ def _decayed_pairs(a: jax.Array, k: jax.Array, gc: jax.Array) -> jax.Array:
             + placed.reshape(placed.shape[:-4] + (C, C)))
 
 
-def gated_delta_chunk(q, k, v, g, beta, S0):
-    """The chunk form: same arguments and results as
+def gated_delta_chunk_xla(q, k, v, g, beta, S0):
+    """The chunk form in XLA: same arguments and results as
     :func:`gated_delta_recurrent` (``g [B, T, H]`` or ``[B, T, H, dk]``),
     chunkwise parallel over sub-chunks of ``gcd(T, SUB_CHUNK)`` tokens (the
     module docstring has the algebra). No loop over tokens: one scan over
@@ -378,3 +385,207 @@ def gated_delta_chunk(q, k, v, g, beta, S0):
     S, o = jax.lax.scan(body, S0, (V, W, QK, qg, k_end, g_end))
     # [N, B, H, C, dv] -> [B, T, H, dv]
     return jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(B, T, H, -1), S
+
+
+# ---------------------------------------------------------------------------
+# the chunk form's Pallas kernel
+# ---------------------------------------------------------------------------
+
+# float32 bytes of q, k, v, o (and a vector decay) that one grid step of the
+# chunk kernel moves: what the head group is chosen from
+# (:func:`chunk_heads_per_step`), as ``ssd._STATE_BLOCK_BYTES`` chooses the
+# step kernels'
+_CHUNK_BLOCK_BYTES = 1 << 20
+
+
+def chunk_heads_per_step(H: int, C: int, dk: int, dv: int,
+                         per_channel: bool) -> int:  # dlint: static-fn
+    """Heads one grid step of the chunk kernel handles: the largest divisor
+    of ``H`` whose sub-chunk of q, k (and a decay a key channel), v and o
+    stays at or under :data:`_CHUNK_BLOCK_BYTES`; 1 where one head is over."""
+    per_head = 4 * C * ((3 if per_channel else 2) * dk + 2 * dv)
+    most = max(1, _CHUNK_BLOCK_BYTES // per_head)
+    return max(c for c in range(1, H + 1) if H % c == 0 and c <= most)
+
+
+def _dot(a: jax.Array, b: jax.Array, contract=((1,), (0,))) -> jax.Array:
+    """A float32 matmul inside the kernel at :data:`_PREC` (Mosaic's
+    ``contract_precision<fp32>``: the six passes ``_mm`` gets from XLA)."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=_PREC,
+                               preferred_element_type=jnp.float32)
+
+
+# rows a block of the kernel's solve: its columns in front of a block go
+# through the matrix unit, its own through the vector unit, and at 32 the two
+# take about as long (tools/delta_chunk_sweep.py; the XLA twin's
+# ``_SOLVE_BLOCK`` is its own)
+_KERNEL_BLOCK = 32
+# heads the kernel walks in lockstep: one head's matmuls wait six passes
+# each, the other's columns fill the wait
+_LOCKSTEP = 2
+
+
+def _chunk_head(q, k, v, g, beta, S, *, per_channel: bool):
+    """One head's sub-chunk against its state, everything a value in VMEM:
+    ``q, k [C, dk]``, ``v [C, dv]``, ``S [dk, dv]``, ``beta`` a ROW ``[1, C]``
+    and the log decay ``g`` ``[C, dk]`` (a key channel) or, one a head, a row
+    ``[1, C]`` too. A generator: it yields between its stages (so that
+    :func:`_chunk_kernel` can trace several heads' stages in turn) and
+    returns ``o [C, dv]`` and the state behind the sub-chunk.
+
+    The module docstring's algebra by row blocks of ``_KERNEL_BLOCK`` tokens,
+    EVERY exponent <= 0 for either decay (``_decayed_pairs``' two cases; a
+    decay a head is the same lines with ``[.., 1]`` where a key channel's has
+    ``[.., dk]``): a block's columns in front of it take the split at the
+    block's edge, ``(G_t / G_e) (G_e / G_j)``, ONE matmul for ``K K^T`` and ``Q
+    K^T`` together and one against the rows of ``U`` that are done; its own
+    columns take the pairwise exponents exactly, a column at a time on the
+    vector unit (over the rows from the column's own sublane tile down: the
+    rows above it are zeros), and each column of ``L`` is spent at once on
+    the forward substitution (``R <- R - L[:, j] R[j]``: row ``j`` of the
+    right-hand side is final when column ``j`` comes). The matrix unit's six
+    passes a float32 product are what this kernel waits for
+    (tools/delta_chunk_sweep.py): a ``[b, b]`` triangle through it costs more
+    than these ``b`` columns."""
+    C, dk = k.shape
+    b = min(C, _KERNEL_BLOCK)
+    f32 = jnp.float32
+    iota = lambda shape, axis: jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    tril = iota((C, C), 0) >= iota((C, C), 1)
+    eye = iota((C, C), 0) == iota((C, C), 1)
+    beta = jnp.sum(jnp.where(eye, beta, 0.0), axis=1, keepdims=True)  # the row as a column [C, 1]
+    if per_channel:
+        gc = _dot(tril.astype(f32), g)                          # log G_t [C, dk]
+    else:
+        gc = jnp.sum(jnp.where(tril, g, 0.0), axis=1, keepdims=True)  # [C, 1]
+    kb, vb = k * beta, v * beta
+    eg = jnp.exp(gc)
+    # (beta K G) S_0 and (Q G) S_0 read the state once
+    both = _dot(jnp.concatenate([kb * eg, q * eg], axis=0), S)  # [2C, dv]
+    rhs, o_state = vb - both[:C], both[C:]
+    sub = iota((b, 1), 0)
+    below = lambda x, lo, new: jnp.concatenate([x[:lo], new], axis=0) if lo else new
+    U, o = [], []
+    yield
+    for r0 in range(0, C, b):
+        g_i, k_i, kb_i, q_i = (x[r0:r0 + b] for x in (gc, k, kb, q))
+        R, o_i = rhs[r0:r0 + b], o_state[r0:r0 + b]
+        if r0:
+            edge = gc[r0 - 1:r0]
+            left = jnp.concatenate([kb_i, q_i], axis=0) * jnp.exp(
+                jnp.concatenate([g_i, g_i], axis=0) - edge)
+            right = k[:r0] * jnp.exp(edge - gc[:r0])
+            cross = _dot(_dot(left, right, ((1,), (1,))),       # [2b, r0]
+                         jnp.concatenate(U, axis=0))            # [2b, dv]
+            R, o_i = R - cross[:b], o_i + cross[b:]
+        yield
+        qk = []
+        for j in range(b):
+            lo = j // 8 * 8                                     # column j is zeros above row j
+            pair = jnp.exp(jnp.where(sub[lo:] >= j, g_i[lo:] - g_i[j:j + 1], -jnp.inf))
+            k_j = k_i[j:j + 1] * pair                           # [b - lo, dk]
+            l_j = jnp.sum(kb_i[lo:] * k_j, axis=1, keepdims=True)   # L[lo:, j]
+            qk.append(jnp.sum(q_i[lo:] * k_j, axis=1, keepdims=True))
+            R = below(R, lo, R[lo:] - jnp.where(sub[lo:] > j, l_j, 0.0) * R[j:j + 1])
+            if j % 4 == 3:
+                yield
+        for j in range(b):
+            lo = j // 8 * 8
+            o_i = below(o_i, lo, o_i[lo:] + qk[j] * R[j:j + 1])
+        U.append(R)
+        o.append(o_i)
+    U = jnp.concatenate(U, axis=0)
+    g_end = gc[C - 1:C]                                         # log G_C
+    if per_channel:   # a channel a state row: the row [1, dk] as a column
+        decay = jnp.sum(jnp.where(iota((dk, dk), 0) == iota((dk, dk), 1), jnp.exp(g_end), 0.0),
+                        axis=1, keepdims=True)
+    else:
+        decay = jnp.exp(g_end)
+    S = decay * S + _dot(k * jnp.exp(g_end - gc), U, ((0,), (0,)))
+    return jnp.concatenate(o, axis=0), S
+
+
+def _chunk_kernel(q_ref, k_ref, v_ref, *rest, heads: int, per_channel: bool):
+    """One (sequence, group of ``heads`` heads, sub-chunk) of the chunk
+    form; the sub-chunks are the innermost, sequential grid axis. ``s_ref
+    [heads, dk, dv]`` is the state's OUTPUT block, which every sub-chunk of
+    a head group names: it stays in VMEM across them, is filled from
+    ``s0_ref`` at the first and goes back to HBM once, behind the last.
+    ``q_ref, k_ref [heads, C, dk]``, ``v_ref, o_ref [heads, C, dv]``;
+    ``rows_ref [heads, 2, C]`` holds the log decay a head and, last, beta as
+    ROWS (whole lane tiles in HBM, where a ``[C, 1]`` operand's minor axis
+    is padded to 128 lanes); a decay a key channel comes as ``g_ref [heads,
+    C, dk]`` in front of a ``rows_ref [heads, 1, C]`` that holds beta alone.
+    The heads go ``_LOCKSTEP`` at a time, a stage of each in turn
+    (:func:`_chunk_head` yields between stages): the program's order is what
+    the scheduler fills one head's waits from."""
+    g_ref, rows_ref, s0_ref, o_ref, s_ref = rest if per_channel else (None, *rest)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    for h0 in range(0, heads, _LOCKSTEP):
+        live = {h: _chunk_head(q_ref[h], k_ref[h], v_ref[h],
+                               g_ref[h] if per_channel else rows_ref[h, 0:1],
+                               rows_ref[h, -1:], s_ref[h], per_channel=per_channel)
+                for h in range(h0, min(heads, h0 + _LOCKSTEP))}
+        while live:
+            for h, stages in list(live.items()):
+                try:
+                    next(stages)
+                except StopIteration as done:
+                    o_ref[h], s_ref[h] = done.value
+                    del live[h]
+
+
+def chunk_kernel_choice(T: int) -> dict | None:  # dlint: static-fn
+    """The chunk kernel's gate: :func:`step_kernel_choice`'s (the ONE mode
+    gate, no mesh plan), and a sub-chunk ``gcd(T, SUB_CHUNK)`` that fills
+    whole sublane tiles. Returns :func:`gated_delta_chunk` kwargs, or None
+    for the XLA twin."""
+    return step_kernel_choice() if math.gcd(T, SUB_CHUNK) % 8 == 0 else None
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gated_delta_chunk(q, k, v, g, beta, S0, *, interpret: bool = False):
+    """:func:`gated_delta_chunk_xla` as ONE Pallas kernel: a grid over
+    (sequence, head group, sub-chunk) whose grid step holds a sub-chunk of
+    :func:`chunk_heads_per_step` heads in VMEM and writes nothing back but
+    ``o`` and, behind a head group's last sub-chunk, the state (aliased onto
+    ``S0``). q, k, v (and a decay a key channel) go in heads first, ``[B, H,
+    T, d]``: one transpose each in front and one of ``o`` behind."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    C = math.gcd(T, SUB_CHUNK)
+    per_channel = g.ndim == 4
+    hb = chunk_heads_per_step(H, C, dk, dv, per_channel)
+    f32 = jnp.float32
+    heads_first = lambda x: jnp.swapaxes(x.astype(f32), 1, 2)   # [B, H, T, d]
+    vmem = pltpu.VMEM
+    tokens = lambda d: pl.BlockSpec((None, hb, C, d), lambda b, h, n: (b, h, n, 0),
+                                    memory_space=vmem)
+    state = pl.BlockSpec((None, hb, dk, dv), lambda b, h, n: (b, h, 0, 0),
+                         memory_space=vmem)
+    operands = [(heads_first(x), tokens(x.shape[-1])) for x in (q, k, v)]
+    if per_channel:
+        operands.append((heads_first(g), tokens(dk)))
+    # what is one number a token and head, as rows: [B, H, N, 1 or 2, C]
+    rows = jnp.stack(([] if per_channel else [g]) + [beta], axis=-1).astype(f32)
+    rows = jnp.transpose(rows.reshape(B, T // C, C, H, -1), (0, 3, 1, 4, 2))
+    operands.append((rows, pl.BlockSpec((None, hb, None, rows.shape[3], C),
+                                        lambda b, h, n: (b, h, n, 0, 0), memory_space=vmem)))
+    operands.append((S0.astype(f32), state))
+    o, S = pl.pallas_call(
+        functools.partial(_chunk_kernel, heads=hb, per_channel=per_channel),
+        grid=(B, H // hb, T // C),
+        in_specs=[spec for _x, spec in operands],
+        out_specs=[tokens(dv), state],
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, dv), f32),
+                   jax.ShapeDtypeStruct((B, H, dk, dv), f32)],
+        input_output_aliases={len(operands) - 1: 1},  # S0, the last operand
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="gated_delta_chunk", interpret=interpret,
+    )(*(x for x, _spec in operands))
+    return jnp.swapaxes(o, 1, 2), S

@@ -494,7 +494,8 @@ SPECS: dict[str, MetricSpec] = {s.name: s for s in (
     _spec(GATED_DELTA_PATHS, "gauge",
           "Gated delta-rule mixers of a program's newest trace by form "
           "(chunk: a prefill chunk; step: the decode step) and the path "
-          "they took: pallas (the gated_delta_step kernel) or xla"),
+          "they took: pallas (the gated_delta_chunk / gated_delta_step "
+          "kernel) or xla"),
     _spec(SSD_PATHS, "gauge",
           "SSD (Mamba-2) mixers of a program's newest trace by form "
           "(chunk: a prefill chunk; step: the decode step) and the path "

@@ -297,3 +297,15 @@ def param_shapes(cfg, scales_dtype):
     dense = jnp.dtype(cfg.compute_dtype)
     return Params(embedding=S((cfg.vocab_size, dim), dense), layers=layers,
                   final_norm=f32(dim), logits=S((cfg.vocab_size, dim), dense))
+
+
+def delta_chunk_form(form: str):
+    """The gated delta rule's chunk form by name: ``"xla"``, the jitted XLA
+    twin, or ``"kernel"``, the Pallas kernel in interpret mode."""
+    import jax
+
+    from dllama_tpu.ops import gated_delta as gd
+
+    if form == "xla":
+        return jax.jit(gd.gated_delta_chunk_xla)
+    return lambda *a: gd.gated_delta_chunk(*a, interpret=True)

@@ -405,8 +405,10 @@ def test_the_solar_cell_gets_the_modules_it_names_and_the_lists_it_was_appended_
                                                  "max_position_embeddings"]
     # it brought no metric of its own: every list it stands in had a cell before it, and it stands LAST in each
     lists = {m["name"]: m["workloads"] for s in ("end_to_end", "per_layer") for m in manifest[s] if "workloads" in m}
-    # (as PR 58 left them: PR 60's cell was appended behind it since)
-    lists = {n: [c for c in w if c != PR60_CELL] for n, w in lists.items()}
+    # (as PR 58 left them: PR 60's cell was appended behind it since, and PR 61 brought the chunk kernel's share of
+    # ``forward``, a metric of this cell alone)
+    lists = {n: [c for c in w if c != PR60_CELL] for n, w in lists.items() if n != "gated_delta_chunk_prefill_share"}
+    assert manifest["per_layer"][-1]["name"] == "gated_delta_chunk_prefill_share" and manifest["per_layer"][-1]["workloads"] == [PR58_CELL]
     mine = {n for n, w in lists.items() if PR58_CELL in w}
     assert mine and all(lists[n][-1] == PR58_CELL and len(lists[n]) > 1 for n in mine)
     # behind granite's cell wherever that stands, but for the kernels this step does not run (an SSD mixer; the run
@@ -422,3 +424,33 @@ def test_the_solar_cell_gets_the_modules_it_names_and_the_lists_it_was_appended_
     assert sum(PR58_CELL in lists[n] for n in ("itl_p88_ms", "itl_p90_ms", "itl_p95_ms")) == 1      # ONE judged tail
     assert not {"ssd_step_share", "mla_step_share", "expert_chunk_step_share", "window_blocks_returned_share",
                 "chunk_with_rows_share"} & mine
+
+
+# -- PR 61's two metrics: data files on the reader that is there ----------------------
+
+
+@pytest.mark.parametrize("name,program,cell", [
+    ("gated_delta_chunk_tick_share", "forward_and_step", "olmo-hybrid-7b.long-prompt"),
+    ("gated_delta_chunk_prefill_share", "forward", "solar-open2-250b.long-doc")])
+def test_the_chunk_kernels_share_reads_its_program_and_nothing_from_a_parent(name, program, cell):
+    """``kernel_roofline`` with ``share: "time"`` over the metric's own file:
+    the ``gated_delta_chunk`` ops' time under the program over the program's
+    runs, in percent; ``None`` where the trace holds no such op (the parent
+    commit: its chunk form was XLA fusions) and where there is no trace; and
+    the metric stands at the end of ``per_layer`` with its one cell."""
+    spec = importlib.util.spec_from_file_location("kernel_roofline", os.path.join(BENCH, "readers", "kernel_roofline.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    with open(os.path.join(BENCH, "layer_metrics", name + ".json"), encoding="utf-8") as f:
+        metric = json.load(f)
+    assert metric == {"reader": "kernel_roofline", "args": {"kernel": "gated_delta_chunk", "program": program, "share": "time"}}
+    ops = [[f"{program}/gated_delta_chunk.9 custom-call", 3.0], [f"{program}/quant_matmul.1 custom-call", 20.0],
+           [f"{program}/fusion.7 fusion", 2.0], ["paged_sampled_step_guarded/gated_delta_step.9 custom-call", 1.0]]
+    modules = {f"jit_{program}(7)": [10.0, 15.0], "jit_paged_sampled_step_guarded(3)": [30.0]}
+    assert reader.read({"trace": {"device_ops": ops, "modules": modules}}, **metric["args"]) == 100.0 * 3.0 / 25.0
+    assert reader.read({"trace": {"device_ops": ops[1:], "modules": modules}}, **metric["args"]) is None
+    assert reader.read({"trace": None}, **metric["args"]) is None
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json"), encoding="utf-8") as f:
+        entry = next(m for m in json.load(f)["per_layer"][-2:] if m["name"] == name)
+    assert (entry["workloads"], entry["better"], entry["unit"], entry["moves"], entry["source"]) == (
+        [cell], "lower", "%", "itl_mean_ms", "device_trace")

@@ -420,6 +420,26 @@ def test_gated_delta_step_compiles_for_v5e(one_chip, slots):
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024 * 1024
 
 
+@pytest.mark.parametrize("T", [32, 256])
+@pytest.mark.parametrize("H,dk,dv,per_channel", [(30, 96, 192, False), (64, 128, 128, True)])
+def test_gated_delta_chunk_compiles_for_v5e(one_chip, H, dk, dv, per_channel, T):
+    """The chunk form's kernel at Olmo-Hybrid-7B's head shape (one decay a
+    head; dk 96 is not a lane tile) and Solar-Open2's (a decay a key channel),
+    one sequence's narrowest and widest bucket: one Mosaic kernel, and nothing
+    of the rule's ``[.., 64, 64]`` pair matrices in the program around it."""
+    from dllama_tpu.ops.gated_delta import gated_delta_chunk
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+
+    f32 = jnp.float32
+    tok = lambda *tail: _shape(one_chip, (1, T, H) + tail, f32)
+    compiled = jax.jit(functools.partial(gated_delta_chunk, interpret=False)).lower(
+        tok(dk), tok(dk), tok(dv), tok(dk) if per_channel else tok(), tok(),
+        _shape(one_chip, (1, H, dk, dv), f32)).compile()
+    text = compiled.as_text()
+    assert mosaic_kernels(text).get("gated_delta_chunk") == 1
+    assert "64,64]" not in text
+
+
 def test_ssd_step_compiles_for_v5e(one_chip):
     """The SSD step form's kernel at Falcon-H1-34B's sizes (12 layers held,
     32 heads of 128 x 256 in 2 groups, 16 slots and the null row), over the
@@ -989,6 +1009,45 @@ def test_gated_delta_step_compiles_with_a_decay_a_channel_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024 * 1024
 
 
+def _cell_at_its_size(one_chip, monkeypatch, config: str, weights_dir: str):
+    """``(conf, cfg, params, on_chip)`` of a benchmark configuration whose
+    family brings its own ``weights`` module: the cell's file, the
+    ``ModelConfig`` its header gives, the parameters' shapes on the described
+    chip, and the function that puts a tree of shapes there. The gates are
+    told they are on a TPU (they ask ``jax.default_backend()``, which is the
+    CPU here)."""
+    import importlib.util
+    import struct
+
+    from dllama_tpu.formats import mfile
+    from dllama_tpu.models import llama
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.ops import quant_matmul
+    from dllama_tpu.parallel import api
+
+    bench = os.path.join(REPO, "benchmark")
+    sys.path.insert(0, bench)
+    import run as bench_run
+    import weights as dense_weights
+
+    spec = importlib.util.spec_from_file_location(weights_dir + "_weights_for_compile",
+                                                  os.path.join(bench, weights_dir, "weights.py"))
+    weights = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(weights)
+    with open(os.path.join(bench, "configs", config + ".json"), encoding="utf-8") as f:
+        conf = json.load(f)
+    data = b"".join(struct.pack("<ii", k if isinstance(k, int) else dense_weights.HEADER_KEYS[k], int(v))
+                    for k, v in weights.header_fields(bench_run.model_view(conf)).items())
+    header = mfile.parse_header(struct.pack("<ii", mfile.MODEL_MAGIC, 8 + len(data)) + data, 0,
+                                max_seq_len=conf["engine"]["max_seq_len"])
+    cfg = ModelConfig.from_header(header, "bfloat16")
+    for module in (quant_matmul, llama, api):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
+    on_chip = lambda tree: jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(weights.params_builder(cfg, None)[0], jax.random.PRNGKey(0)))
+    return conf, cfg, params, on_chip
+
+
 @pytest.mark.parametrize("program", ["step", "forward"])
 def test_solars_two_programs_compile_at_the_cells_size_for_v5e(one_chip, program, monkeypatch):
     """``paged_sampled_step_guarded`` over 16 rows (pools of 16 x 9,472 tokens,
@@ -996,40 +1055,16 @@ def test_solars_two_programs_compile_at_the_cells_size_for_v5e(one_chip, program
     from the cell's own configuration and the benchmark's shapes: every Q40
     plane a kernel, the step's routed halves the PAIR form (``share.step_form``:
     128 pairs over 320), the rule's step form ONE ``gated_delta_step`` a traced
-    layer body, the walk ``paged_ragged_attention``; the chunk's rule XLA (no
-    kernel of its own), its routed halves ``expert_chunk``."""
-    import importlib.util
-
-    from dllama_tpu.formats import mfile
+    layer body, the walk ``paged_ragged_attention``; the chunk's rule ONE
+    ``gated_delta_chunk`` a traced layer body since PR 61 (and none of the XLA
+    form's ``[.., H, 64, 64]`` pair matrices or ``[.., 16, 16, 128]`` exponents
+    left in the program), its routed halves ``expert_chunk``."""
     from dllama_tpu.models import llama
-    from dllama_tpu.models.config import ModelConfig
     from dllama_tpu.models.share import zero_totals
-    from dllama_tpu.ops import quant_matmul
-    from dllama_tpu.parallel import api
     from dllama_tpu.runtime.introspection import mosaic_kernels
     from dllama_tpu.runtime.kvblocks import PagedKVCache, StateColumn, StatePool
 
-    bench = os.path.join(REPO, "benchmark")
-    sys.path.insert(0, bench)
-    import run as bench_run
-    import weights as dense_weights
-
-    spec = importlib.util.spec_from_file_location("solar_open2_weights_for_compile",
-                                                  os.path.join(bench, "solar_open2", "weights.py"))
-    weights = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(weights)
-    with open(os.path.join(bench, "configs", "solar-open2-250b.json"), encoding="utf-8") as f:
-        conf = json.load(f)
-    import struct
-    data = b"".join(struct.pack("<ii", k if isinstance(k, int) else dense_weights.HEADER_KEYS[k], int(v))
-                    for k, v in weights.header_fields(bench_run.model_view(conf)).items())
-    header = mfile.parse_header(struct.pack("<ii", mfile.MODEL_MAGIC, 8 + len(data)) + data, 0,
-                                max_seq_len=conf["engine"]["max_seq_len"])
-    cfg = ModelConfig.from_header(header, "bfloat16")
-    for module in (quant_matmul, llama, api):          # the gates ask jax.default_backend(), which is the CPU here
-        monkeypatch.setattr(module, "on_tpu", lambda: True)
-    on_chip = lambda tree: jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype), tree)
-    params = on_chip(jax.eval_shape(weights.params_builder(cfg, None)[0], jax.random.PRNGKey(0)))
+    conf, cfg, params, on_chip = _cell_at_its_size(one_chip, monkeypatch, "solar-open2-250b", "solar_open2")
     i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
     slots, seq, block = conf["engine"]["slots"], cfg.seq_len, conf["engine"]["kv_block_size"]
     if program == "step":
@@ -1052,10 +1087,42 @@ def test_solars_two_programs_compile_at_the_cells_size_for_v5e(one_chip, program
     compiled = jax.jit(llama.forward, static_argnums=1, donate_argnums=(4,)).lower(
         params, cfg, _shape(one_chip, (1, 256), i32), _shape(one_chip, (), i32), column,
         _shape(one_chip, (), i32)).compile()
-    kernels = mosaic_kernels(compiled.as_text())
+    text = compiled.as_text()
+    kernels = mosaic_kernels(text)
     assert kernels.get("expert_chunk") == 6 and "gated_delta_step" not in kernels, kernels
-    assert kernels.get("quant_matmul") == 15, kernels
+    assert kernels.get("quant_matmul") == 15 and kernels.get("gated_delta_chunk") == 1, kernels
+    assert "64,64,64]" not in text and "16,16,128]" not in text        # [N, B, H, C, C] pairs; a block's exponents
     assert compiled.memory_analysis().temp_size_in_bytes < 1536 * 1024 * 1024
+
+
+def test_the_hybrids_tick_program_compiles_with_the_chunk_kernel_at_the_cells_size_for_v5e(one_chip, monkeypatch):
+    """``hybrid.forward_and_step`` from olmo-hybrid-7b's own configuration at the
+    widest bucket (a 256-token chunk into a 4096-token column, the 4 slots'
+    decode rows beside it, pools donated): the rule's chunk form is ONE
+    ``gated_delta_chunk`` and its step form ONE ``gated_delta_step``, the traced
+    linear-layer body's, and none of the XLA form's ``[.., H, 64, 64]`` pair
+    matrices is left in the program (PR 61)."""
+    from dllama_tpu.models import hybrid
+    from dllama_tpu.runtime.introspection import mosaic_kernels
+    from dllama_tpu.runtime.kvblocks import PagedKVCache, StateColumn, StatePool
+
+    conf, cfg, params, on_chip = _cell_at_its_size(one_chip, monkeypatch, "olmo-hybrid-7b", "olmo_hybrid")
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    slots, seq, block = conf["engine"]["slots"], cfg.seq_len, conf["engine"]["kv_block_size"]
+    pools = (on_chip(jax.eval_shape(lambda: PagedKVCache.create(cfg, slots * seq // block + 1, block, dtype=bf16))),
+             on_chip(jax.eval_shape(lambda: StatePool.create(cfg, slots, bf16))))
+    kv = lambda: jnp.zeros((cfg.n_kv_layers, 1, cfg.n_kv_heads, seq, cfg.cache_width), bf16)
+    column = on_chip(jax.eval_shape(lambda: StateColumn.zeros(cfg, kv(), kv(), bf16)))
+    rows = lambda dtype, *tail: _shape(one_chip, (slots, *tail), dtype)
+    scalar = lambda dtype: _shape(one_chip, (), dtype)
+    compiled = jax.jit(hybrid.forward_and_step, static_argnums=1, donate_argnums=(4,)).lower(
+        params, cfg, rows(i32, 1), rows(i32), (column, pools), rows(i32, seq // block),
+        _shape(one_chip, (1, 256), i32), scalar(i32), scalar(i32), scalar(f32)).compile()
+    text = compiled.as_text()
+    kernels = mosaic_kernels(text)
+    assert kernels.get("gated_delta_chunk") == 1 and kernels.get("gated_delta_step") == 1, kernels
+    assert kernels.get("quant_matmul") == 12, kernels           # five a linear layer's body, seven the full one's
+    assert "30,64,64]" not in text                              # [N, B, H, C, C] pairs
 
 
 @pytest.mark.parametrize("chips", [1, 4])

@@ -396,3 +396,65 @@ def test_the_cells_engine_options_take_the_tick():
         eng = json.load(f)["engine"]
     assert eng["slots"] == 4 and 256 + eng["slots"] <= CHUNK_MAX_M
     assert not eng.get("spec_lookup") and eng.get("tp", 1) == 1
+
+
+# -- the chunk rule's kernel under the gate -------------------------------------
+
+
+def _rule_notes(trace, plan=None):
+    """What a trace of ``trace()`` notes of the delta rule's paths (``{"chunk:pallas": n, ..}``), under ``plan``."""
+    from dllama_tpu.parallel import use_plan
+
+    with introspection._thread_window() as notes, use_plan(plan):
+        trace()
+    return notes["gdn"]
+
+
+def _two_programs(engine, T=32):
+    cfg = engine.cfg
+    params, *args = _tick_shapes(engine, T)
+    _tokens, _pos, (col, _pools), _tables, chunk, chunk_pos, n_valid, _poison = args
+    return {"forward": lambda: jax.eval_shape(lambda p, *a: hybrid.forward(p, cfg, *a), params, chunk, chunk_pos, col, n_valid),
+            "forward_and_step": lambda: jax.eval_shape(lambda p, *a: hybrid.forward_and_step(p, cfg, *a), params, *args)}
+
+
+@pytest.mark.parametrize("program", ["forward", "forward_and_step"])
+def test_the_chunk_rule_takes_its_kernel_under_the_gate(engine, monkeypatch, program):
+    """Traced with the kernels forced (interpret mode off a TPU: what ``auto``
+    resolves to on one), ``forward`` and the tick program note ``chunk:pallas``
+    and ask for ONE ``gated_delta_chunk``, the linear layer's body's (a
+    period's three linear layers are one scan), over the chunk's rows alone;
+    the tick's decode rows go through the step kernel beside it."""
+    from dllama_tpu.ops import gated_delta as gd
+
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "pallas")
+    calls = []
+    entry = gd.gated_delta_chunk
+    monkeypatch.setattr(gd, "gated_delta_chunk", lambda *a, **kw: calls.append((a[0].shape, kw)) or entry(*a, **kw))
+    notes = _rule_notes(_two_programs(engine)[program])
+    cfg = engine.cfg
+    assert calls == [((1, 32, cfg.lin_heads, cfg.lin_key_dim), {"interpret": True})]
+    assert notes == ({"chunk:pallas": 1} if program == "forward" else {"chunk:pallas": 1, "step:pallas": 1})
+
+
+@pytest.mark.parametrize("program", ["forward", "forward_and_step", "the rule under a plan"])
+def test_the_chunk_rule_keeps_its_xla_form_off_a_tpu_and_under_a_plan(engine, monkeypatch, program):
+    """On the plain CPU path (``auto`` off a TPU: no kernel) both programs note
+    ``chunk:xla`` and never ask for the kernel; nor does the rule under a mesh
+    plan with the kernels forced (the auto-sharder cannot partition a
+    ``pallas_call``; the period scan itself refuses a plan, so the rule is
+    traced alone there)."""
+    from dllama_tpu.ops import gated_delta as gd
+    from dllama_tpu.parallel.api import make_tp_mesh
+
+    monkeypatch.setattr(gd, "gated_delta_chunk", lambda *a, **kw: pytest.fail("the kernel was asked for"))
+    if program in ("forward", "forward_and_step"):
+        notes = _rule_notes(_two_programs(engine)[program])
+        assert notes == ({"chunk:xla": 1} if program == "forward" else {"chunk:xla": 1, "step:xla": 1})
+        return
+    monkeypatch.setenv("DLLAMA_TPU_QUANT_KERNEL", "pallas")
+    f = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    rule = lambda: jax.eval_shape(hybrid._rule_chunk, f(1, 32, 2, 8), f(1, 32, 2, 8), f(1, 32, 2, 16), f(1, 32, 2), f(1, 32, 2),
+                                  f(1, 2, 8, 16), jnp.int32(20))
+    assert gd.chunk_kernel_choice(32) == {"interpret": True} and gd.chunk_kernel_choice(20) is None    # 20: sub-chunks of 4
+    assert _rule_notes(rule, make_tp_mesh(1)) == {"chunk:xla": 1}

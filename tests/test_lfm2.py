@@ -425,7 +425,7 @@ def test_scheduler_serves_state_and_counters_in_one_step(bench, engine, tmp_path
 
     reg = telemetry.registry()
     pairs, skipped = reg.counter(telemetry.MOE_PAIRS), reg.counter(telemetry.PREFIX_REUSE_SKIPPED)
-    held0, skip0 = pairs.total(where="held"), skipped.total(reason="recurrent_state")
+    held0, absent0, skip0 = pairs.total(where="held"), pairs.total(where="absent"), skipped.total(reason="recurrent_state")
     sched = BatchScheduler(engine, n_slots=3)
     try:
         prompts = [_tokens(n, seed=n) for n in (90, 33, 150)]
@@ -436,7 +436,7 @@ def test_scheduler_serves_state_and_counters_in_one_step(bench, engine, tmp_path
         assert again.done.wait(300) and list(again.tokens) == list(reqs[0].tokens)
         assert skipped.total(reason="recurrent_state") == skip0 + 1
         tokens = sum(len(p) - 1 + 12 for p in prompts + [prompts[0]])         # prefilled + decoded positions
-        assert pairs.total(where="held") - held0 == tokens * 2 * 7 and pairs.total(where="absent") == 0
+        assert pairs.total(where="held") - held0 == tokens * 2 * 7 and pairs.total(where="absent") == absent0
         assert reg.gauge(telemetry.LAYER_KINDS).value(kind="conv") == 7
         assert reg.gauge(telemetry.STATE_POOL_BYTES).value() == 7 * 4 * 2 * 64 * 4
         want = _reference_logits(bench, engine.params, prompts[1] + list(reqs[1].tokens))

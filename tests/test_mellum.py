@@ -371,7 +371,7 @@ def test_scheduler_serves_sessions_and_counts(bench, engine):
 
     reg = telemetry.registry()
     pairs = reg.counter(telemetry.MOE_PAIRS)
-    held0 = pairs.total(where="held")
+    held0, absent0 = pairs.total(where="held"), pairs.total(where="absent")      # the worker's registry: other files' models count there
     sched = BatchScheduler(engine, n_slots=3)
     try:
         first = _tokens(100, seed=61)
@@ -383,7 +383,7 @@ def test_scheduler_serves_sessions_and_counts(bench, engine):
         assert sched.gen.prefix_totals() == (96, 99 + 139) and sched.gen.window_totals()[:2] == (96, 1)
         want = _reference_logits(bench, engine.params, second + list(r2.tokens))
         assert [int(r.argmax()) for r in want[len(second) - 1:-1]] == list(r2.tokens)
-        assert pairs.total(where="held") - held0 == (99 + 10 + 139 - 96 + 10) * 4 * 8 and pairs.total(where="absent") == 0
+        assert pairs.total(where="held") - held0 == (99 + 10 + 139 - 96 + 10) * 4 * 8 and pairs.total(where="absent") == absent0
         assert reg.gauge(telemetry.KV_WINDOW_BLOCKS_TOTAL).value() == 2 * 3 * (WINDOW // BS + 2)
         assert reg.gauge(telemetry.KV_WINDOW_BLOCKS_PARKED).value() == sched.gen.wpool.cached_blocks() > 0
     finally:

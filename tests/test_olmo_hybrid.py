@@ -31,6 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers import delta_chunk_form
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 HYBRID = os.path.join(BENCH, "olmo_hybrid")
@@ -130,15 +132,23 @@ def _mixer_inputs(T, seed=0, B=2, H=3, dk=8, dv=16):
     return q, k, v, g, beta, jax.random.normal(ks[5], (B, H, dk, dv))
 
 
-@pytest.mark.parametrize("T", [192, 32])
-def test_chunk_form_is_the_per_token_recurrence(T):
+# the kernel at every bucket's sub-chunk count (T 32: one sub-chunk of 32; 256: four of 64), at 48 (sub-chunks of 16: ONE
+# solve block) and at olmo's head shape cut in H only (dk 96 is not a lane tile, dv 192 is a tile and a half)
+@pytest.mark.parametrize("T,form,shape", [
+    (192, "xla", {}), (32, "xla", {}),
+    (32, "kernel", {}), (48, "kernel", {}), (64, "kernel", {}), (128, "kernel", {}), (192, "kernel", {}), (256, "kernel", {}),
+    (128, "kernel", dict(B=1, H=2, dk=96, dv=192))])
+def test_chunk_form_is_the_per_token_recurrence(T, form, shape):
     from dllama_tpu.ops import gated_delta as gd
 
-    args = _mixer_inputs(T)
+    args = _mixer_inputs(T, **shape)       # the state coming in is noise, not zeros
     o_rec, s_rec = gd.gated_delta_recurrent(*args)
-    o, s = jax.jit(gd.gated_delta_chunk)(*args)
+    o, s = delta_chunk_form(form)(*args)
     assert float(jnp.abs(o - o_rec).max()) < FORM_TOL
     assert float(jnp.abs(s - s_rec).max()) < FORM_TOL
+    if form == "kernel":                   # and its twin
+        o_x, s_x = delta_chunk_form("xla")(*args)
+        assert float(jnp.abs(o - o_x).max()) < FORM_TOL and float(jnp.abs(s - s_x).max()) < FORM_TOL
 
 
 def test_step_form_iterated_is_the_per_token_recurrence():
@@ -157,13 +167,14 @@ def test_step_form_iterated_is_the_per_token_recurrence():
     assert float(jnp.abs(pool[0]).max()) == 0.0 and float(jnp.abs(pool[1, 0]).max()) == 0.0
 
 
-def test_masked_positions_leave_the_state_untouched():
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_masked_positions_leave_the_state_untouched(form):
     from dllama_tpu.ops import gated_delta as gd
     from dllama_tpu.ops.causal_conv import causal_conv
 
     q, k, v, g, beta, s0 = _mixer_inputs(64)
     real = (jnp.arange(64) < 41)[None, :, None]
-    _o, s = gd.gated_delta_chunk(q, k, v, jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0), s0)
+    _o, s = delta_chunk_form(form)(q, k, v, jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0), s0)
     _o, s_41 = gd.gated_delta_recurrent(q[:, :41], k[:, :41], v[:, :41], g[:, :41], beta[:, :41], s0)
     assert float(jnp.abs(s - s_41).max()) < FORM_TOL
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 6))

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers import delta_chunk_form
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 SO = os.path.join(BENCH, "solar_open2")
@@ -125,26 +127,36 @@ def _rule_inputs(rng, B, T, H, dk, dv, *, strong=False):
     return q, k, v, g, beta, f(B, H, dk, dv)
 
 
-@pytest.mark.parametrize("T,strong", [(16, False), (64, False), (192, False), (64, True), (192, True)])
-def test_the_chunk_form_with_a_vector_decay_is_the_recurrence(T, strong):
-    """``gated_delta_chunk`` with ``g [B, T, H, dk]`` against the per-token scan,
-    state in and state out; with channels that decay by e^-20 a token (e^-1280
-    over a sub-chunk, where ``(k * G) . (k / G)`` would overflow float32 by the
-    fifth token) everything stays finite and equal."""
+# the kernel at every bucket's sub-chunk count, with the overflow case over one sub-chunk and over three, and at solar's
+# head shape cut in H only (dk = dv = 128: whole lane tiles)
+@pytest.mark.parametrize("T,strong,form,heads", [
+    (16, False, "xla", (3, 16, 8)), (64, False, "xla", (3, 16, 8)), (192, False, "xla", (3, 16, 8)),
+    (64, True, "xla", (3, 16, 8)), (192, True, "xla", (3, 16, 8)),
+    (32, False, "kernel", (3, 16, 8)), (64, False, "kernel", (3, 16, 8)), (128, False, "kernel", (3, 16, 8)),
+    (256, False, "kernel", (3, 16, 8)), (64, True, "kernel", (3, 16, 8)), (192, True, "kernel", (3, 16, 8)),
+    (128, False, "kernel", (2, 128, 128)), (64, True, "kernel", (2, 128, 128))])
+def test_the_chunk_form_with_a_vector_decay_is_the_recurrence(T, strong, form, heads):
+    """``gated_delta_chunk`` (the XLA twin and the kernel) with ``g [B, T, H,
+    dk]`` against the per-token scan, a state of noise in and the state out;
+    with channels that decay by e^-20 a token (e^-1280 over a sub-chunk, where
+    ``(k * G) . (k / G)`` would overflow float32 by the fifth token) everything
+    stays finite and equal."""
     from dllama_tpu.ops import gated_delta as gd
 
-    q, k, v, g, beta, S0 = _rule_inputs(np.random.default_rng(T), 2, T, 3, 16, 8, strong=strong)
+    q, k, v, g, beta, S0 = _rule_inputs(np.random.default_rng(T), 2, T, *heads, strong=strong)
     o_ref, S_ref = gd.gated_delta_recurrent(q, k, v, g, beta, S0)
-    o, S = jax.jit(gd.gated_delta_chunk)(q, k, v, g, beta, S0)
+    o, S = delta_chunk_form(form)(q, k, v, g, beta, S0)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(S).all())
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=5e-6)
     np.testing.assert_allclose(np.asarray(S), np.asarray(S_ref), atol=2e-5)
 
 
-def test_no_exponent_of_the_vector_chunk_form_is_positive():
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_no_exponent_of_the_vector_chunk_form_is_positive(form):
     """Every ``exp`` the vector case traces takes an argument <= 0, whatever
-    the decay: read off the jaxpr's ``exp`` operands on a worst case (e^-60 a
-    token in every channel: e^-3840 over a sub-chunk)."""
+    the decay: read off the ``exp`` operands on a worst case (e^-60 a token in
+    every channel: e^-3840 over a sub-chunk), in the XLA twin and in the
+    kernel's body (``_chunk_head``, one head's sub-chunk as plain values)."""
     from dllama_tpu.ops import gated_delta as gd
 
     q, k, v, g, beta, S0 = _rule_inputs(np.random.default_rng(0), 1, 64, 2, 16, 8)
@@ -154,11 +166,24 @@ def test_no_exponent_of_the_vector_chunk_form_is_positive():
     try:
         gd.jnp.exp = lambda a: seen.append(float(jnp.max(a))) or real(a)
         with jax.disable_jit():              # the scan over sub-chunks as a Python loop: its exps are read too
-            o, S = gd.gated_delta_chunk(q, k, v, g, beta, S0)
+            if form == "xla":
+                o, S = gd.gated_delta_chunk_xla(q, k, v, g, beta, S0)
+            else:
+                stages = gd._chunk_head(q[0, :, 0], k[0, :, 0], v[0, :, 0], g[0, :, 0], beta[0, None, :, 0], S0[0, 0],
+                                        per_channel=True)
+                try:
+                    while True:
+                        next(stages)
+                except StopIteration as done:
+                    o, S = done.value
     finally:
         gd.jnp.exp = real
     assert len(seen) >= 5 and max(seen) <= 0.0, seen
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(S).all())
+    if form == "kernel":
+        o_ref, S_ref = gd.gated_delta_recurrent(q, k, v, g, beta, S0)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref[0, :, 0]), atol=5e-6)
+        np.testing.assert_allclose(np.asarray(S), np.asarray(S_ref[0, 0]), atol=2e-5)
 
 
 @pytest.mark.parametrize("strong", [False, True])
@@ -194,7 +219,7 @@ def test_a_scalar_decay_is_the_vector_whose_channels_agree_and_keeps_its_operand
     q, k, v, g, beta, S0 = _rule_inputs(np.random.default_rng(9), B, T, H, dk, dv)
     gs = g[..., 0]
     wide = jnp.broadcast_to(gs[..., None], g.shape)
-    for rule in (gd.gated_delta_recurrent, gd.gated_delta_chunk):
+    for rule in (gd.gated_delta_recurrent, gd.gated_delta_chunk_xla, delta_chunk_form("kernel")):
         (o1, S1), (o2, S2) = rule(q, k, v, gs, beta, S0), rule(q, k, v, wide, beta, S0)
         np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-6)
         np.testing.assert_allclose(np.asarray(S1), np.asarray(S2), atol=5e-6)
