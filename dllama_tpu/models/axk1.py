@@ -59,7 +59,7 @@ from ..parallel.api import current_plan
 from ..runtime.introspection import note_mla_path
 from .config import ModelConfig
 from .family import Family, Refusal, layer_kinds
-from .llama import Params, _stack_at, _write_kv_rows
+from .llama import Params, _live_rows, _stack_at, _write_kv_rows
 from .rope import apply_rope_partial, build_partial_rope_cache, yarn_mscale
 from .share import (ffn_half, require_quantized, zero_stats,  # noqa: F401
                     zero_totals)
@@ -262,7 +262,7 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     pkv, totals = cache
     pos0 = jnp.asarray(pos_vec, dtype=jnp.int32)
     positions = pos0[:, None]
-    live = tables[:, 0] != 0
+    live = _live_rows(tables)
     x = params.embedding[tokens].astype(cfg.compute_dtype)
     bs, M = pkv.k.shape[3], tables.shape[1]
     blk = tables[jnp.arange(B, dtype=jnp.int32)[:, None], positions // bs]

@@ -72,8 +72,10 @@ from ..parallel.api import current_plan
 from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
 from .family import Family, layer_kinds, state_refusal
-from .llama import (Params, _attend_dense, _attend_paged, _exact_f32_dots,
-                    _hidden_act, _nonfinite_rows, _poison_logits, _stack_at)
+from .llama import (Params, _at, _attend_dense, _attend_paged, _attend_split,
+                    _exact_f32_dots, _hidden_act, _join_positions,
+                    _join_tokens, _live_rows, _pick_rows, _put, _stack_at,
+                    _state_rows)
 from .rope import apply_rope, build_rope_cache
 from .ssd_mixer import mixer_chunk, mixer_chunk_and_step, mixer_step
 
@@ -125,15 +127,6 @@ def _qkv(cfg: ModelConfig, u: jax.Array, lp: FalconH1Layers, cos, sin,
     return (apply_rope(q, cos, sin, positions, cfg.rope_type),
             apply_rope(k.astype(u.dtype), cos, sin, positions, cfg.rope_type),
             v)
-
-
-def _at(a: jax.Array, l: jax.Array) -> jax.Array:
-    """Layer ``l`` of a column's leaf ``[L, ...]``."""
-    return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-
-
-def _put(a: jax.Array, a_l: jax.Array, l: jax.Array) -> jax.Array:
-    return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
 
 
 def _scan_layers(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -233,8 +226,7 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                          "cannot be rolled back out of it")
     pkv, pool = cache
     positions = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]
-    rows = jnp.where(tables[:, 0] != 0, jnp.arange(1, B + 1, dtype=jnp.int32),
-                     StatePool.NULL)
+    rows = _state_rows(_live_rows(tables))
 
     def mixer(u, lp, l, s, conv):
         return mixer_step(cfg, u, lp, l, rows, s, conv)
@@ -276,24 +268,20 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     table is dead, as an inactive slot of a step is (the null block, the
     pool's null row), and every row may be.
 
-    The head runs for the decode ROWS alone: no chunk logits exist (the
-    serving prefill never read one). Returns ``((token, nonfinite, logits),
-    (column, (pkv, pool)))`` as the dense tick does: ``token`` each row's
-    ARGMAX, ``logits [R, V]`` float32 and poisoned as the step's are, for
-    ``ops.sampling.sampled_token`` where a row samples."""
+    Behind the scan the decode ROWS alone get a head, the poison, the argmax
+    and the non-finite count (:func:`~dllama_tpu.models.llama._pick_rows`).
+    Returns ``((token, nonfinite, logits),
+    (column, (pkv, pool)))``, as the dense tick does."""
     from ..runtime.kvblocks import PagedKVCache, StatePool
 
     _check(cfg)
     col, (pkv, pool) = cache
     chunk_pos = jnp.asarray(chunk_pos, dtype=jnp.int32)
     n_valid = jnp.asarray(n_valid, dtype=jnp.int32)
-    T, R = chunk.shape[1], tokens.shape[0]
-    joined = jnp.concatenate([chunk[0], tokens[:, 0]])[None]        # [1, T+R]
-    cpos = (chunk_pos + jnp.arange(T, dtype=jnp.int32))[None, :]    # [1, T]
-    rpos = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]           # [R, 1]
-    positions = jnp.concatenate([cpos, rpos.T], axis=1)
-    rows = jnp.where(tables[:, 0] != 0, jnp.arange(1, R + 1, dtype=jnp.int32),
-                     StatePool.NULL)
+    T = chunk.shape[1]
+    joined = _join_tokens(chunk, tokens)[None]                      # [1, T+R]
+    cpos, rpos, positions = _join_positions(chunk_pos, pos_vec, T)
+    rows = _state_rows(_live_rows(tables))
 
     def mixer(u, lp, l, s, conv):
         (s_col, s_pool), (conv_col, conv_pool) = s, conv
@@ -305,24 +293,16 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     def attend(q, k, v, k_c, v_c, l):
         (k_col, k_pool), (v_col, v_pool) = k_c, v_c
-        att_c, k_l, v_l = _attend_dense(cfg, q[:, :T], k[:, :T], v[:, :T],
-                                        _at(k_col, l), _at(v_col, l),
-                                        chunk_pos, cpos)
-        by_row = lambda a: jnp.swapaxes(a[:, T:], 0, 1)  # [R, 1, heads, hd]
-        att_r, k_pool, v_pool = _attend_paged(
-            cfg, by_row(q), by_row(k), by_row(v), k_pool, v_pool, l, rpos,
-            tables)
-        att = jnp.concatenate([att_c, jnp.swapaxes(att_r, 0, 1)], axis=1)
+        att, k_l, v_l, k_pool, v_pool = _attend_split(
+            cfg, q, k, v, T, lambda: (_at(k_col, l), _at(v_col, l)), k_pool,
+            v_pool, l, chunk_pos, cpos, rpos, tables)
         return (att, (_put(k_col, k_l, l), k_pool),
                 (_put(v_col, v_l, l), v_pool))
 
     x, s, conv, k, v = _scan_layers(
         params, cfg, joined, positions, (col.s, pool.s),
         (col.conv, pool.conv), (col.k, pkv.k), (col.v, pkv.v), mixer, attend)
-    logits = _head(params, cfg, jnp.swapaxes(x[:, T:], 0, 1))      # [R, 1, V]
-    last = _poison_logits(logits[:, -1, :], poison)
-    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
-    return ((greedy, _nonfinite_rows(last), last),
+    return (_pick_rows(_head, params, cfg, x, T, poison),
             (StateColumn(k=k[0], v=v[0], s=s[0], conv=conv[0]),
              (PagedKVCache(k=k[1], v=v[1]), StatePool(s=s[1], conv=conv[1]))))
 

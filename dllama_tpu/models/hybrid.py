@@ -89,9 +89,10 @@ from ..runtime.introspection import note_gdn_path
 from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
 from .family import Family, layer_kinds, state_refusal
-from .llama import (_LAYER_MATMULS, LayerParams, Params, _attend_dense,
-                    _attend_paged, _exact_f32_dots, _hidden_act,
-                    _nonfinite_rows, _poison_logits, _stack_at)
+from .llama import (_LAYER_MATMULS, LayerParams, Params, _at, _attend_dense,
+                    _attend_paged, _attend_split, _by_row, _exact_f32_dots,
+                    _hidden_act, _join, _join_positions, _join_tokens,
+                    _live_rows, _pick_rows, _put, _stack_at, _state_rows)
 
 
 class LinearLayerParams(NamedTuple):
@@ -126,15 +127,6 @@ class HybridLayers(NamedTuple):
 # one slot's context gathered for chunked prefill: the state's own column
 # type (runtime/kvblocks.py), under the name this module gave it first
 HybridColumn = StateColumn
-
-
-def _at(a: jax.Array, l: jax.Array) -> jax.Array:
-    """Layer ``l`` of a column's leaf ``[L, ...]``."""
-    return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-
-
-def _put(a: jax.Array, a_l: jax.Array, l: jax.Array) -> jax.Array:
-    return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
 
 
 def _sublayer(cfg: ModelConfig, x: jax.Array, norm_w: jax.Array, f):
@@ -237,18 +229,6 @@ def _mixer_step(cfg, u, lp, l, rows, s_pool, conv_pool):
     conv_pool = conv_pool.at[l, rows].set(tail)
     o, s_pool = _rule_step(l, rows, q, k, v, g, beta, s_pool)
     return _mixer_output(cfg, o, z, lp, u.dtype), s_pool, conv_pool
-
-
-def _by_row(a: jax.Array, T: int) -> jax.Array:
-    """The decode rows behind a chunk's ``T``, one a batch row: ``[1, T + R,
-    ...] -> [R, 1, ...]``."""
-    return jnp.swapaxes(a[:, T:], 0, 1)
-
-
-def _join(c: jax.Array, r: jax.Array) -> jax.Array:
-    """:func:`_by_row` undone: ``[1, T, ...]`` and ``[R, 1, ...]`` as ``[1,
-    T + R, ...]``."""
-    return jnp.concatenate([c, jnp.swapaxes(r, 0, 1)], axis=1)
 
 
 def _mixer_chunk_and_step(cfg, u, lp, l, T, n_valid, rows, s, conv):
@@ -474,9 +454,8 @@ def step_program(walk: Walk, params: Params, cfg: ModelConfig,
                          "cannot be rolled back out of a recurrent state")
     pkv, pool, *totals = cache
     positions = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]
-    live = tables[:, 0] != 0
-    rows = jnp.where(live, jnp.arange(1, B + 1, dtype=jnp.int32),
-                     StatePool.NULL)
+    live = _live_rows(tables)
+    rows = _state_rows(live)
     x = params.embedding[tokens].astype(cfg.compute_dtype)
 
     def mixer(h, lp, l, s, conv):
@@ -535,24 +514,21 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     pools). A row with an all-null table is dead, as an inactive slot of a
     step is (the null block, the pool's null row), and every row may be.
 
-    The head runs for the decode ROWS alone: no chunk logits exist (the
-    serving prefill never read one). Returns ``((token, nonfinite, logits),
-    (column, (pkv, pool)))`` as the dense tick does: ``token`` each row's
-    ARGMAX, ``logits [R, V]`` float32 and poisoned as the step's are, for
-    ``ops.sampling.sampled_token`` where a row samples."""
+    Behind the scan the decode ROWS alone get a head, the poison, the argmax
+    and the non-finite count (:func:`~dllama_tpu.models.llama._pick_rows`).
+    Returns ``((token, nonfinite, logits),
+    (column, (pkv, pool)))``, as the dense tick does."""
     from ..runtime.kvblocks import PagedKVCache, StatePool
 
     _check(cfg)
     col, (pkv, pool) = cache
     chunk_pos = jnp.asarray(chunk_pos, dtype=jnp.int32)
     n_valid = jnp.asarray(n_valid, dtype=jnp.int32)
-    T, R = chunk.shape[1], tokens.shape[0]
-    joined = jnp.concatenate([chunk[0], tokens[:, 0]])[None]        # [1, T+R]
+    T = chunk.shape[1]
+    joined = _join_tokens(chunk, tokens)[None]                      # [1, T+R]
     x = params.embedding[joined].astype(cfg.compute_dtype)
-    cpos = (chunk_pos + jnp.arange(T, dtype=jnp.int32))[None, :]    # [1, T]
-    rpos = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]           # [R, 1]
-    rows = jnp.where(tables[:, 0] != 0, jnp.arange(1, R + 1, dtype=jnp.int32),
-                     StatePool.NULL)
+    cpos, rpos, _ = _join_positions(chunk_pos, pos_vec, T)   # no rotary here
+    rows = _state_rows(_live_rows(tables))
 
     def mixer(h, lp, l, s, conv):
         return _mixer_chunk_and_step(cfg, h, lp, l, T, n_valid, rows,
@@ -564,22 +540,17 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     def attend(q, k, v, k_c, v_c, p):
         (k_col, k_pool), (v_col, v_pool) = k_c, v_c
-        att_c, k_p, v_p = _attend_dense(cfg, q[:, :T], k[:, :T], v[:, :T],
-                                        _at(k_col, p), _at(v_col, p),
-                                        chunk_pos, cpos)
-        att_r, k_pool, v_pool = _attend_paged(
-            cfg, _by_row(q, T), _by_row(k, T), _by_row(v, T), k_pool, v_pool,
-            p, rpos, tables)
-        return (_join(att_c, att_r), (_put(k_col, k_p, p), k_pool),
+        att, k_p, v_p, k_pool, v_pool = _attend_split(
+            cfg, q, k, v, T, lambda: (_at(k_col, p), _at(v_col, p)), k_pool,
+            v_pool, p, chunk_pos, cpos, rpos, tables)
+        return (att, (_put(k_col, k_p, p), k_pool),
                 (_put(v_col, v_p, p), v_pool))
 
     x, s, conv, k, v, _ = _scan_periods(
         _olmo_walk(params, cfg), cfg, x, (col.s, pool.s),
         (col.conv, pool.conv), (col.k, pkv.k), (col.v, pkv.v), None, None,
         mixer, store, attend)
-    last = _poison_logits(_head(params, cfg, _by_row(x, T))[:, -1, :], poison)
-    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
-    return ((greedy, _nonfinite_rows(last), last),
+    return (_pick_rows(_head, params, cfg, x, T, poison),
             (HybridColumn(k=k[0], v=v[0], s=s[0], conv=conv[0]),
              (PagedKVCache(k=k[1], v=v[1]), StatePool(s=s[1], conv=conv[1]))))
 

@@ -91,8 +91,9 @@ from ..parallel.api import current_plan
 from ..runtime.kvcache import update_layer
 from .config import ModelConfig
 from .family import Family, Refusal, layer_kinds
-from .llama import (Params, _attend_dense, _attend_paged, _exact_f32_dots,
-                    _nonfinite_rows, _poison_logits, _stack_at)
+from .llama import (Params, _at, _attend_dense, _attend_paged, _by_row,
+                    _exact_f32_dots, _join, _join_positions, _join_tokens,
+                    _live_rows, _pick_rows, _put, _stack_at)
 from .rope import apply_rope_partial, build_partial_rope_cache
 from .share import (ffn_half, require_quantized, route,  # noqa: F401
                     routed_ffn, routed_pairs, widen_experts, zero_stats,
@@ -345,21 +346,19 @@ def _column_attends(cfg: ModelConfig, start_pos, positions, base):
     rows, hd]`` of positions from ``base`` (:func:`_slide_column` has moved
     it under the chunk)."""
     P = cfg.layer_period
-    at = lambda a, l: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-    put = lambda a, a_l, l: jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
 
     def attend_full(q, k, v, kv, p):
         ck, cv, wk, wv = kv
-        att, k_l, v_l = _attend_dense(cfg, q, k, v, at(ck, p), at(cv, p),
+        att, k_l, v_l = _attend_dense(cfg, q, k, v, _at(ck, p), _at(cv, p),
                                       start_pos, positions)
-        return att, (put(ck, k_l, p), put(cv, v_l, p), wk, wv)
+        return att, (_put(ck, k_l, p), _put(cv, v_l, p), wk, wv)
 
     def attend_slide(q, k, v, kv, p, j):
         ck, cv, wk, wv = kv
         l = p * (P - 1) + j
         att, k_l, v_l = _attend_window_buffer(
-            cfg, q, k, v, at(wk, l), at(wv, l), start_pos, positions, base)
-        return att, (ck, cv, put(wk, k_l, l), put(wv, v_l, l))
+            cfg, q, k, v, _at(wk, l), _at(wv, l), start_pos, positions, base)
+        return att, (ck, cv, _put(wk, k_l, l), _put(wv, v_l, l))
 
     return attend_full, attend_slide
 
@@ -434,7 +433,7 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     pkv, wkv, totals = cache
     t_full, t_win = tables[0], tables[1]
     positions = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]
-    live = t_full[:, 0] != 0
+    live = _live_rows(t_full)
     x = params.embedding[tokens].astype(cfg.compute_dtype)
     x, (fk, fv, wk, wv), stats = _scan_periods(
         params, cfg, x, (pkv.k, pkv.v, wkv.k, wkv.v), zero_stats(cfg), live,
@@ -479,11 +478,10 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     ``col.stats`` goes back as it came; row 0 is "what the step PROGRAM's
     dispatches did" and is left as it was.
 
-    The head runs for the decode ROWS alone: no chunk logits exist (the
-    serving prefill never read one). Returns ``((token, nonfinite, logits),
-    (column, (pkv, wkv, totals)))`` as the dense tick does: ``token`` each
-    row's ARGMAX, ``logits [R, V]`` float32 and poisoned as the step's are,
-    for ``ops.sampling.sampled_token`` where a row samples."""
+    Behind the scan the decode ROWS alone get a head, the poison, the argmax
+    and the non-finite count (:func:`~dllama_tpu.models.llama._pick_rows`).
+    Returns ``((token, nonfinite, logits),
+    (column, (pkv, wkv, totals)))``, as the dense tick does."""
     from ..runtime.kvblocks import PagedKVCache
 
     _check(cfg)
@@ -491,23 +489,19 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     chunk_pos = jnp.asarray(chunk_pos, dtype=jnp.int32)
     n_valid = jnp.asarray(n_valid, dtype=jnp.int32)
     T = chunk.shape[1]
-    joined = jnp.concatenate([chunk[0], tokens[:, 0]])[None]        # [1, T+R]
-    cpos = (chunk_pos + jnp.arange(T, dtype=jnp.int32))[None, :]    # [1, T]
-    rpos = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]           # [R, 1]
-    positions = jnp.concatenate([cpos, rpos.T], axis=1)
+    joined = _join_tokens(chunk, tokens)[None]                      # [1, T+R]
+    cpos, rpos, positions = _join_positions(chunk_pos, pos_vec, T)
     t_full, t_win = tables[0], tables[1]
-    live = jnp.concatenate([jnp.arange(T) < n_valid, t_full[:, 0] != 0])
+    live = jnp.concatenate([jnp.arange(T) < n_valid, _live_rows(t_full)])
     x = params.embedding[joined].astype(cfg.compute_dtype)
-    by_row = lambda a: jnp.swapaxes(a[:, T:], 0, 1)              # [R, 1, ...]
 
     def side_by_side(of_chunk, of_rows):
         def attend(q, k, v, caches, *where):
             att_c, kv = of_chunk(q[:, :T], k[:, :T], v[:, :T], caches[0],
                                  *where)
-            att_r, pools = of_rows(by_row(q), by_row(k), by_row(v),
-                                   caches[1], *where)
-            return (jnp.concatenate([att_c, jnp.swapaxes(att_r, 0, 1)],
-                                    axis=1), (kv, pools))
+            att_r, pools = of_rows(_by_row(q, T), _by_row(k, T),
+                                   _by_row(v, T), caches[1], *where)
+            return _join(att_c, att_r), (kv, pools)
         return attend
 
     col = _slide_column(col, chunk_pos, T)
@@ -517,10 +511,7 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
         zero_stats(cfg), live, positions,
         *map(side_by_side, _column_attends(cfg, chunk_pos, cpos, col.base),
              _pool_attends(cfg, rpos, t_full, t_win)))
-    logits = _head(params, cfg, jnp.swapaxes(x[:, T:], 0, 1))      # [R, 1, V]
-    last = _poison_logits(logits[:, -1, :], poison)
-    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
-    return ((greedy, _nonfinite_rows(last), last),
+    return (_pick_rows(_head, params, cfg, x, T, poison),
             (col._replace(k=k, v=v, wk=ck, wv=cv),
              (PagedKVCache(k=fk, v=fv), PagedKVCache(k=wk, v=wv),
               totals.at[1].add(stats))))

@@ -81,8 +81,10 @@ from ..runtime.introspection import note_short_conv_path
 from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
 from .family import Family, layer_kinds, state_refusal
-from .llama import (Params, _attend_dense, _attend_paged, _exact_f32_dots,
-                    _nonfinite_rows, _poison_logits, _stack_at)
+from .llama import (Params, _at, _attend_dense, _attend_paged, _attend_split,
+                    _by_row, _exact_f32_dots, _join, _join_positions,
+                    _join_tokens, _live_rows, _pick_rows, _put, _stack_at,
+                    _state_rows)
 from .rope import apply_rope_partial, build_partial_rope_cache
 from .share import _plane, ffn_half, require_quantized, swiglu, zero_stats
 
@@ -142,14 +144,6 @@ def _check(cfg: ModelConfig) -> None:
     if cfg.sync_q80 or cfg.offload:
         raise ValueError("a decoder with short-convolution layers supports "
                          "neither Q80 sync emulation nor offloaded weights")
-
-
-def _at(a: jax.Array, l):
-    return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
-
-
-def _put(a: jax.Array, a_l: jax.Array, l) -> jax.Array:
-    return jax.lax.dynamic_update_index_in_dim(a, a_l, l, 0)
 
 
 def _conv_gate_in(cfg: ModelConfig, u: jax.Array, cp: ConvParams):
@@ -337,9 +331,8 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                          "rolled back out of a convolution's tail")
     pkv, pool, totals = cache
     positions = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]
-    live = tables[:, 0] != 0
-    rows = jnp.where(live, jnp.arange(1, B + 1, dtype=jnp.int32),
-                     StatePool.NULL)
+    live = _live_rows(tables)
+    rows = _state_rows(live)
     x = params.embedding[tokens].astype(cfg.compute_dtype)
 
     def conv_mixer(h, cp, c, conv):
@@ -397,29 +390,23 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     is "what the step PROGRAM's dispatches did" (its planes are divided by
     that program's kernel time) and is left as it was.
 
-    The head runs for the decode ROWS alone: no chunk logits exist (the
-    serving prefill never read one). Returns ``((token, nonfinite, logits),
-    (column, (pkv, pool, totals)))`` as the dense tick does: ``token`` each
-    row's ARGMAX, ``logits [R, V]`` float32 and poisoned as the step's are,
-    for ``ops.sampling.sampled_token`` where a row samples."""
+    Behind the scan the decode ROWS alone get a head, the poison, the argmax
+    and the non-finite count (:func:`~dllama_tpu.models.llama._pick_rows`).
+    Returns ``((token, nonfinite, logits),
+    (column, (pkv, pool, totals)))``, as the dense tick does."""
     from ..runtime.kvblocks import PagedKVCache, StatePool
 
     _check(cfg)
     col, (pkv, pool, totals) = cache
     chunk_pos = jnp.asarray(chunk_pos, dtype=jnp.int32)
     n_valid = jnp.asarray(n_valid, dtype=jnp.int32)
-    T, R = chunk.shape[1], tokens.shape[0]
-    joined = jnp.concatenate([chunk[0], tokens[:, 0]])[None]        # [1, T+R]
-    cpos = (chunk_pos + jnp.arange(T, dtype=jnp.int32))[None, :]    # [1, T]
-    rpos = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]           # [R, 1]
-    positions = jnp.concatenate([cpos, rpos.T], axis=1)
-    alive = tables[:, 0] != 0
+    T = chunk.shape[1]
+    joined = _join_tokens(chunk, tokens)[None]                      # [1, T+R]
+    cpos, rpos, positions = _join_positions(chunk_pos, pos_vec, T)
+    alive = _live_rows(tables)
     live = jnp.concatenate([jnp.arange(T) < n_valid, alive])
-    rows = jnp.where(alive, jnp.arange(1, R + 1, dtype=jnp.int32),
-                     StatePool.NULL)
+    rows = _state_rows(alive)
     x = params.embedding[joined].astype(cfg.compute_dtype)
-    by_row = lambda a: jnp.swapaxes(a[:, T:], 0, 1)              # [R, 1, ...]
-    join = lambda c, r: jnp.concatenate([c, jnp.swapaxes(r, 0, 1)], axis=1)
 
     def conv_mixer(h, cp, c, conv):
         conv_col, conv_pool = conv
@@ -428,30 +415,24 @@ def forward_and_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
         y_c, tail_c = causal_conv(v[:, :T], _at(conv_col, c), cp.conv_w,
                                   n_valid, activation=None)
         note_short_conv_path("step", "xla")
-        y_r, tail_r = causal_conv(by_row(v), _at(conv_pool, c)[rows],
+        y_r, tail_r = causal_conv(_by_row(v, T), _at(conv_pool, c)[rows],
                                   cp.conv_w, None, activation=None)
-        return (_conv_gate_out(cfg, proj, join(y_c, y_r), cp, h.dtype),
+        return (_conv_gate_out(cfg, proj, _join(y_c, y_r), cp, h.dtype),
                 (_put(conv_col, tail_c, c), conv_pool.at[c, rows].set(tail_r)))
 
     def attend(q, k, v, k_c, v_c, a):
         (k_col, k_pool), (v_col, v_pool) = k_c, v_c
-        att_c, k_a, v_a = _attend_dense(cfg, q[:, :T], k[:, :T], v[:, :T],
-                                        _at(k_col, a), _at(v_col, a),
-                                        chunk_pos, cpos)
-        att_r, k_pool, v_pool = _attend_paged(
-            cfg, by_row(q), by_row(k), by_row(v), k_pool, v_pool, a, rpos,
-            tables)
-        return (join(att_c, att_r), (_put(k_col, k_a, a), k_pool),
+        att, k_a, v_a, k_pool, v_pool = _attend_split(
+            cfg, q, k, v, T, lambda: (_at(k_col, a), _at(v_col, a)), k_pool,
+            v_pool, a, chunk_pos, cpos, rpos, tables)
+        return (att, (_put(k_col, k_a, a), k_pool),
                 (_put(v_col, v_a, a), v_pool))
 
     x, (k, v, conv), stats = _scan_layers(
         params, cfg, x, ((col.k, pkv.k), (col.v, pkv.v),
                          (col.conv, pool.conv)),
         zero_stats(cfg), live, positions, conv_mixer, attend)
-    logits = _head(params, cfg, jnp.swapaxes(x[:, T:], 0, 1))      # [R, 1, V]
-    last = _poison_logits(logits[:, -1, :], poison)
-    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
-    return ((greedy, _nonfinite_rows(last), last),
+    return (_pick_rows(_head, params, cfg, x, T, poison),
             (col._replace(k=k[0], v=v[0], conv=conv[0]),
              (PagedKVCache(k=k[1], v=v[1]),
               StatePool(s=None, conv=conv[1]), totals.at[1].add(stats))))

@@ -64,7 +64,8 @@ from ..parallel.api import current_plan
 from ..runtime.kvblocks import StateColumn
 from .config import ModelConfig
 from .family import Family, layer_kinds, state_refusal
-from .llama import Params, _attend_dense, _attend_paged, _stack_at
+from .llama import (Params, _at, _attend_dense, _attend_paged, _live_rows,
+                    _put, _stack_at, _state_rows)
 from .share import (require_quantized, routed_ffn, widen_experts,
                     zero_stats)
 from .ssd_mixer import mixer_chunk, mixer_step
@@ -174,10 +175,6 @@ def _check(cfg: ModelConfig) -> None:
         raise ValueError("a decoder of one block a layer in a pattern "
                          "supports neither Q80 sync emulation nor offloaded "
                          "weights")
-
-
-def _at(a: jax.Array, i):
-    return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
 
 
 def _walk(cfg: ModelConfig, carry, blocks: dict):
@@ -304,18 +301,15 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.broadcast_to(
         start_pos + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
 
-    def put(a, a_i, i):
-        return jax.lax.dynamic_update_index_in_dim(a, a_i, i, 0)
-
     def mixer(u, mp, i, s, conv):
         y, s_i, conv_i = mixer_chunk(cfg, u, mp, _at(s, i), _at(conv, i),
                                      n_valid)
-        return y, put(s, s_i, i), put(conv, conv_i, i)
+        return y, _put(s, s_i, i), _put(conv, conv_i, i)
 
     def attend(q, k, v, k_c, v_c, i):
         att, k_i, v_i = _attend_dense(cfg, q, k, v, _at(k_c, i), _at(v_c, i),
                                       start_pos, positions)
-        return att, put(k_c, k_i, i), put(v_c, v_i, i)
+        return att, _put(k_c, k_i, i), _put(v_c, v_i, i)
 
     logits, (k, v, s, conv), stats = _run_layers(
         params, cfg, x, (col.k, col.v, col.s, col.conv), col.stats, live,
@@ -342,9 +336,8 @@ def paged_forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                          "cannot be rolled back out of it")
     pkv, pool, totals = cache
     positions = jnp.asarray(pos_vec, dtype=jnp.int32)[:, None]
-    live = tables[:, 0] != 0
-    rows = jnp.where(live, jnp.arange(1, B + 1, dtype=jnp.int32),
-                     StatePool.NULL)
+    live = _live_rows(tables)
+    rows = _state_rows(live)
     x = _embed(params, cfg, tokens)
 
     def mixer(u, mp, i, s, conv):

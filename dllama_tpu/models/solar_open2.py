@@ -53,7 +53,7 @@ from ..runtime.kvblocks import StateColumn
 from . import hybrid
 from .config import ModelConfig
 from .family import Family, layer_kinds, state_refusal
-from .llama import Params
+from .llama import Params, _at
 from .share import ffn_half, require_quantized, zero_stats
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -170,7 +170,7 @@ def _mixer_chunk(cfg, u, lp, s_l, conv_l, n_valid):
 def _mixer_step(cfg, u, lp, l, rows, s_pool, conv_pool):
     """The mixer over one token a row, the pools in and out
     (``hybrid._mixer_step``'s signature)."""
-    tail = hybrid._at(conv_pool, l)[rows]            # [B, K - 1, C]
+    tail = _at(conv_pool, l)[rows]                   # [B, K - 1, C]
     qkv, z, fb = _mixer_project(cfg, u, lp)
     y, tail = causal_conv(qkv, tail, lp.conv_w, None)
     q, k, v, g, beta, z = _mixer_heads(cfg, y, z, fb, lp)

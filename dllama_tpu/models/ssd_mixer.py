@@ -34,6 +34,7 @@ from ..ops.causal_conv import causal_conv
 from ..ops.linear import linear
 from ..runtime.introspection import note_ssd_path
 from .config import ModelConfig
+from .llama import _by_row, _join
 
 
 def mixer_project(cfg: ModelConfig, u: jax.Array, lp):
@@ -158,9 +159,9 @@ def mixer_chunk_and_step(cfg, u, lp, l, T, s_l, conv_l, n_valid, rows,
     proj, dt, z = mixer_project(cfg, u, lp)
     y_c, x_c, s_l, conv_l = chunk_part(cfg, proj[:, :T], dt[:, :T], lp, s_l,
                                        conv_l, n_valid)
-    by_row = lambda a: jnp.swapaxes(a[:, T:], 0, 1)          # [R, 1, ...]
     y_r, x_r, s_pool, conv_pool = step_part(
-        cfg, by_row(proj), by_row(dt), lp, l, rows, tail, s_pool, conv_pool)
-    join = lambda c, r: jnp.concatenate([c, jnp.swapaxes(r, 0, 1)], axis=1)
-    return (mixer_output(cfg, join(y_c, y_r), join(x_c, x_r), z, lp, u.dtype),
+        cfg, _by_row(proj, T), _by_row(dt, T), lp, l, rows, tail, s_pool,
+        conv_pool)
+    return (mixer_output(cfg, _join(y_c, y_r), _join(x_c, x_r), z, lp,
+                         u.dtype),
             (s_l, conv_l), (s_pool, conv_pool))

@@ -354,133 +354,32 @@ def replicated_forward(params, cfg, tokens, start_pos, kv):
     return constrain(logits, None, None, None), kv
 
 
-def replicated_greedy(params, cfg, tokens, start_pos, kv):
-    import jax.numpy as jnp
+def replicated(program):
+    """``program``, one of ``models.llama``'s decode programs (a step, a
+    chunk of steps or a verify: ``((picked..., nonfinite), kv)``), as every
+    process of a multihost run must run it: its logits replicated BEFORE
+    the pick (:func:`replicated_forward`), so that every host computes the
+    pick from the same full row, and every output but the cache replicated,
+    so that every host can read it (``np.asarray`` of a non-addressable
+    global array throws). This is the one place that says what "replicated"
+    means for a decode program; the engine's solo programs and the slot
+    pool's ragged ones are all calls of it.
+
+    ``poison`` is always 0 under multihost (the failpoint injection is
+    single-host only: a root-only NaN would desync the replicated pick),
+    but the scalar stays in the program so root and worker compile
+    identical executables."""
+    import jax
 
     from .api import constrain
 
-    logits, kv = replicated_forward(params, cfg, tokens, start_pos, kv)
-    tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-    return constrain(tok, None), kv
+    def wrapped(params, cfg, *args):
+        outs, kv = program(params, cfg, *args, fwd=replicated_forward)
+        return jax.tree.map(lambda a: constrain(a, *[None] * a.ndim),
+                            outs), kv
 
-
-def replicated_verify(params, cfg, tokens, start_pos, kv):
-    """Speculative verify with replicated (host-addressable) results."""
-    import jax.numpy as jnp
-
-    from .api import constrain
-
-    logits, kv = replicated_forward(params, cfg, tokens, start_pos, kv)
-    preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    ok = (tokens[:, 1:] == preds[:, :-1]).astype(jnp.int32)
-    n_acc = jnp.sum(jnp.cumprod(ok, axis=-1), axis=-1)
-    return constrain(n_acc, None), constrain(preds, None, None), kv
-
-
-def replicated_sampled(params, cfg, tokens, start_pos, kv,
-                       temperature, topp, coin):
-    """Fused sampled decode with a replicated token result (every host reads
-    the same pick; the coin arrived identically via the control packet)."""
-    from ..ops.sampling import sampled_token
-    from .api import constrain
-
-    logits, kv = replicated_forward(params, cfg, tokens, start_pos, kv)
-    tok = sampled_token(logits[:, -1, :], temperature, topp, coin)
-    return constrain(tok, None), kv
-
-
-def replicated_greedy_steps(params, cfg, token, start_pos, kv, n_steps):
-    """Chunked decode with replicated output: the shared scan
-    (models.llama.scan_decode) over the replicated single step."""
-    from ..models.llama import scan_decode
-    from .api import constrain
-
-    toks, kv = scan_decode(
-        lambda t, p, kv: replicated_greedy(params, cfg, t, p, kv),
-        token, start_pos, kv, n_steps)
-    return constrain(toks, None, None), kv
-
-
-def replicated_sampled_steps(params, cfg, token, start_pos, kv, temperature,
-                             topp, coins, n_steps):
-    from ..models.llama import scan_decode
-    from .api import constrain
-
-    toks, kv = scan_decode(
-        lambda t, p, kv, c: replicated_sampled(params, cfg, t, p, kv,
-                                               temperature, topp, c),
-        token, start_pos, kv, n_steps, coins=coins)
-    return constrain(toks, None, None), kv
-
-
-def replicated_greedy_guarded(params, cfg, tokens, start_pos, kv, poison):
-    """Guarded (non-finite tripwire) twin of :func:`replicated_greedy`:
-    ``((token, nonfinite), kv)``, both replicated so every host reads the
-    same values. ``poison`` is always 0 under multihost (the failpoint
-    injection is single-host only — a root-only NaN would desync the
-    replicated pick), but the scalar stays in the program so root and
-    worker compile identical executables."""
-    import jax.numpy as jnp
-
-    from ..models.llama import _nonfinite_rows, _poison_logits
-    from .api import constrain
-
-    logits, kv = replicated_forward(params, cfg, tokens, start_pos, kv)
-    last = _poison_logits(logits[:, -1, :], poison)
-    tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
-    return (constrain(tok, None), constrain(_nonfinite_rows(last), None)), kv
-
-
-def replicated_sampled_guarded(params, cfg, tokens, start_pos, kv,
-                               temperature, topp, coin, poison):
-    from ..models.llama import _nonfinite_rows, _poison_logits
-    from ..ops.sampling import sampled_token
-    from .api import constrain
-
-    logits, kv = replicated_forward(params, cfg, tokens, start_pos, kv)
-    last = _poison_logits(logits[:, -1, :], poison)
-    tok = sampled_token(last, temperature, topp, coin)
-    return (constrain(tok, None), constrain(_nonfinite_rows(last), None)), kv
-
-
-def replicated_verify_guarded(params, cfg, tokens, start_pos, kv, poison):
-    import jax.numpy as jnp
-
-    from ..models.llama import _nonfinite_rows, _poison_logits
-    from .api import constrain
-
-    logits, kv = replicated_forward(params, cfg, tokens, start_pos, kv)
-    logits = _poison_logits(logits, poison)
-    preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    ok = (tokens[:, 1:] == preds[:, :-1]).astype(jnp.int32)
-    n_acc = jnp.sum(jnp.cumprod(ok, axis=-1), axis=-1)
-    return (constrain(n_acc, None), constrain(preds, None, None),
-            constrain(_nonfinite_rows(logits), None)), kv
-
-
-def replicated_greedy_steps_guarded(params, cfg, token, start_pos, kv,
-                                    n_steps, poison):
-    from ..models.llama import _scan_decode_guarded
-    from .api import constrain
-
-    (toks, nf), kv = _scan_decode_guarded(
-        lambda t, p, kv: replicated_greedy_guarded(params, cfg, t, p, kv,
-                                                   poison),
-        token, start_pos, kv, n_steps)
-    return (constrain(toks, None, None), constrain(nf, None)), kv
-
-
-def replicated_sampled_steps_guarded(params, cfg, token, start_pos, kv,
-                                     temperature, topp, coins, n_steps,
-                                     poison):
-    from ..models.llama import _scan_decode_guarded
-    from .api import constrain
-
-    (toks, nf), kv = _scan_decode_guarded(
-        lambda t, p, kv, c: replicated_sampled_guarded(
-            params, cfg, t, p, kv, temperature, topp, c, poison),
-        token, start_pos, kv, n_steps, coins=coins)
-    return (constrain(toks, None, None), constrain(nf, None)), kv
+    wrapped.__name__ = wrapped.__qualname__ = "replicated_" + program.__name__
+    return wrapped
 
 
 def worker_serve(engine: "InferenceEngine", *,
@@ -514,8 +413,12 @@ def worker_serve(engine: "InferenceEngine", *,
             elif kind == CTRL_SRV_TAKE:
                 adm_cols[int(payload[0])] = gen._exec_take(aux)
             elif kind == CTRL_SRV_PREFILL:
+                # the dense slot pool pads freely: every row of the
+                # chunk counts as valid (the argument is the paged
+                # generator's, for a recurrent state)
                 adm_cols[aux] = gen._exec_prefill(
-                    adm_cols[aux], payload[1:], int(payload[0]))
+                    adm_cols[aux], payload[1:], int(payload[0]),
+                    len(payload) - 1)
             elif kind == CTRL_SRV_COMMIT:
                 gen._exec_commit(aux, adm_cols.pop(aux))
             elif kind == CTRL_SRV_STEP:

@@ -634,112 +634,73 @@ class InferenceEngine:
         self.split = None          # decode program's EvalSyncSplit | None
         self.split_prefill = None  # prefill program's split (measure_split)
         self.traffic = None        # runtime.profiling.TrafficStats | None
-        # donate the KV cache (arg 4) so decode updates it in place
+        # plan_scoped_jit: the traced programs bake in THIS engine's mesh
+        # plan (constrain reads it at trace time), so the trace cache must
+        # key on this engine, not the shared module-level function: a
+        # second engine with a different plan would otherwise dispatch the
+        # first engine's sharding constraints. scope= files every program
+        # under this engine in the compile ledger (runtime.introspection).
+        _sc = self.introspection_scope
         if multihost:
-            from ..parallel.multihost import (
-                replicated_forward,
-                replicated_greedy_guarded,
-                replicated_greedy_steps_guarded,
-                replicated_sampled_guarded,
-                replicated_sampled_steps_guarded,
-                replicated_verify_guarded,
-            )
-
-            # plan_scoped_jit: the traced programs bake in THIS engine's
-            # mesh plan (constrain reads it at trace time), so the trace
-            # cache must key on this engine, not the shared module-level
-            # function — a second engine with a different plan would
-            # otherwise dispatch the first engine's sharding constraints.
-            # scope= files every program under this engine in the compile
-            # ledger (runtime.introspection). The decode-path programs are
-            # the *_guarded twins (non-finite tripwire fused in) but keep
-            # their historical program names — the ledger's view of "what
-            # does this engine compile" is unchanged.
-            _sc = self.introspection_scope
-            self._step = plan_scoped_jit(replicated_forward, scope=_sc,
-                                         static_argnums=1,
-                                         donate_argnums=(4,))
-            self._greedy_step = plan_scoped_jit(
-                replicated_greedy_guarded, scope=_sc,
-                program="replicated_greedy", static_argnums=1,
-                donate_argnums=(4,))
-            self._sampled_step = plan_scoped_jit(
-                replicated_sampled_guarded, scope=_sc,
-                program="replicated_sampled", static_argnums=1,
-                donate_argnums=(4,))
-            self._greedy_steps = plan_scoped_jit(
-                replicated_greedy_steps_guarded, scope=_sc,
-                program="replicated_greedy_steps", static_argnums=(1, 5),
-                donate_argnums=(4,))
-            self._sampled_steps = plan_scoped_jit(
-                replicated_sampled_steps_guarded, scope=_sc,
-                program="replicated_sampled_steps", static_argnums=(1, 8),
-                donate_argnums=(4,))
-            self._verify_step = plan_scoped_jit(
-                replicated_verify_guarded, scope=_sc,
-                program="replicated_verify", static_argnums=1,
-                donate_argnums=(4,))
-            # quality observatory: no replicated prefill_nll twin yet —
-            # score_nll refuses loudly instead of silently diverging the
-            # worker mirrors with an un-broadcast program
-            self._nll_step = None
+            from ..parallel.multihost import replicated, replicated_forward
         else:
-            _sc = self.introspection_scope
-            self._step = plan_scoped_jit(forward, scope=_sc, static_argnums=1,
-                                         donate_argnums=(4,))
-            # greedy fast path: argmax fused into the step — ONE dispatch per
-            # token and a 4-byte host transfer instead of a full logits row;
-            # used by next_token() when temperature == 0. The sampled twin
-            # fuses temperature/top-p on device the same way (temp/topp/coin
-            # are traced scalars, so knob changes never recompile). All
-            # decode programs are the *_guarded twins — the non-finite
-            # tripwire rides every dispatch, the poison scalar is traced so
-            # chaos arming never recompiles — under the historical program
-            # names (compile-ledger view unchanged).
-            self._greedy_step = plan_scoped_jit(greedy_step_guarded,
-                                                scope=_sc,
-                                                program="greedy_step",
-                                                static_argnums=1,
-                                                donate_argnums=(4,))
-            self._sampled_step = plan_scoped_jit(
-                sampled_step_guarded, scope=_sc, program="sampled_step",
-                static_argnums=1, donate_argnums=(4,))
-            self._greedy_steps = plan_scoped_jit(greedy_steps_guarded,
-                                                 scope=_sc,
-                                                 program="greedy_steps",
-                                                 static_argnums=(1, 5),
-                                                 donate_argnums=(4,))
-            self._sampled_steps = plan_scoped_jit(sampled_steps_guarded,
-                                                  scope=_sc,
-                                                  program="sampled_steps",
-                                                  static_argnums=(1, 8),
-                                                  donate_argnums=(4,))
-            self._verify_step = plan_scoped_jit(verify_step_guarded,
-                                                scope=_sc,
-                                                program="verify_step",
-                                                static_argnums=1,
-                                                donate_argnums=(4,))
-            # the sampled pair again behind packed arguments
-            # (runtime/steppack), as the slot-pool generator dispatches them
-            # at its pool's batch width: owned here so that every generator
-            # serving this engine shares one executable a program. Lazy like
-            # the rest: nothing compiles until a generator dispatches, and
-            # the ledger's entries are the two names above.
-            self._packed_sampled_step = steppack.jit_packed_step(
-                sampled_step_guarded, scope=_sc, name="sampled_step")
-            self._packed_sampled_steps = steppack.jit_packed_step(
-                sampled_steps_guarded, scope=_sc, name="sampled_steps",
-                n_static=1)
-            # quality observatory (runtime/evalharness): teacher-forced
-            # prefill twin whose epilogue is the fused log-softmax-gather
-            # NLL reduction — eval chunks never download full-vocab
-            # logits. Registration is trace-lazy: nothing compiles until
-            # an eval run dispatches it, so a serving-only engine's
-            # compile ledger is byte-identical to before.
-            self._nll_step = plan_scoped_jit(prefill_nll, scope=_sc,
-                                             program="prefill_nll",
-                                             static_argnums=1,
-                                             donate_argnums=(5,))
+            replicated = lambda program: program  # noqa: E731
+
+        def jit_decode(program, solo, mirrored, static=(1,)):
+            # a decode program as this engine dispatches it: the tripwire
+            # rides every dispatch (its poison scalar traced, so arming
+            # chaos never recompiles); under multihost its replicated form,
+            # which root and worker compile alike. Filed under the name the
+            # compile ledger always had for it; the KV cache (arg 4) is
+            # donated, so decode updates it in place
+            return plan_scoped_jit(
+                replicated(program), scope=_sc,
+                program=mirrored if multihost else solo,
+                static_argnums=static, donate_argnums=(4,))
+
+        self._step = plan_scoped_jit(
+            replicated_forward if multihost else forward, scope=_sc,
+            static_argnums=1, donate_argnums=(4,))
+        # greedy fast path: argmax fused into the step, ONE dispatch per
+        # token and a 4-byte host transfer instead of a full logits row;
+        # used by next_token() when temperature == 0. The sampled twin
+        # fuses temperature/top-p on device the same way (temp/topp/coin
+        # are traced scalars, so knob changes never recompile).
+        self._greedy_step = jit_decode(
+            greedy_step_guarded, "greedy_step", "replicated_greedy")
+        self._sampled_step = jit_decode(
+            sampled_step_guarded, "sampled_step", "replicated_sampled")
+        self._greedy_steps = jit_decode(
+            greedy_steps_guarded, "greedy_steps", "replicated_greedy_steps",
+            (1, 5))
+        self._sampled_steps = jit_decode(
+            sampled_steps_guarded, "sampled_steps",
+            "replicated_sampled_steps", (1, 8))
+        self._verify_step = jit_decode(
+            verify_step_guarded, "verify_step", "replicated_verify")
+        # the sampled pair again behind packed arguments (runtime/steppack),
+        # as the slot-pool generator dispatches them at its pool's batch
+        # width (everything broadcasts over rows, so they ARE the ragged
+        # step and the ragged chunk): owned here so that every generator
+        # serving this engine shares one executable a program. Lazy like
+        # the rest: nothing compiles until a generator dispatches.
+        self._packed_sampled_step = steppack.jit_packed_step(
+            replicated(sampled_step_guarded), scope=_sc,
+            name="_replicated_ragged_step" if multihost else "sampled_step")
+        self._packed_sampled_steps = steppack.jit_packed_step(
+            replicated(sampled_steps_guarded), scope=_sc,
+            name="_replicated_ragged_steps" if multihost else "sampled_steps",
+            n_static=1)
+        # quality observatory (runtime/evalharness): teacher-forced prefill
+        # twin whose epilogue is the fused log-softmax-gather NLL reduction,
+        # so eval chunks never download full-vocab logits. Registration is
+        # trace-lazy: nothing compiles until an eval run dispatches it.
+        # Under multihost there is no replicated twin yet: score_nll refuses
+        # loudly instead of silently diverging the worker mirrors with an
+        # un-broadcast program
+        self._nll_step = None if multihost else plan_scoped_jit(
+            prefill_nll, scope=_sc, program="prefill_nll", static_argnums=1,
+            donate_argnums=(5,))
         # activation taps (numerics observatory): the tapped forward is
         # only jitted when the engine opted in — a taps-off engine never
         # registers the program, keeping the default compile ledger

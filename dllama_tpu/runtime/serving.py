@@ -43,7 +43,7 @@ from . import (failpoints, flightrec, introspection, numerics, steppack,
                telemetry, tenancy)
 
 from ..models.family import family_of
-from ..models.llama import forward, sampled_step_guarded
+from ..models.llama import forward, ragged_verify_step_guarded
 from ..parallel.api import plan_scoped_jit, use_plan
 from ..parallel.multihost import (
     CTRL_SRV_COMMIT,
@@ -53,6 +53,7 @@ from ..parallel.multihost import (
     CTRL_SRV_STEP_CHUNK,
     CTRL_SRV_TAKE,
     CTRL_SRV_VERIFY,
+    replicated,
 )
 from ..tokenizer.sampler import xorshift_random_f32
 from ..models.share import N_COUNTS
@@ -134,40 +135,6 @@ def check_hbm_admission(engine, n_prompt: int, need_bytes: int) -> None:
     if not ok:
         telemetry.registry().counter(telemetry.HBM_ADMISSION_REJECTS).inc()
         raise HbmAdmissionError(reason)
-
-
-def _replicated_ragged_step(params, cfg, tokens, pos, kv, temps, topps,
-                            coins, poison):
-    """Ragged sampled step with replicated picked tokens (multihost: every
-    process reads the same [B] vector on host). Guarded: the non-finite
-    tripwire's per-row count rides along, replicated too."""
-    from ..parallel.api import constrain
-
-    (tok, nf), kv = sampled_step_guarded(params, cfg, tokens, pos, kv,
-                                         temps, topps, coins, poison)
-    return (constrain(tok, None), constrain(nf, None)), kv
-
-
-def _replicated_ragged_steps(params, cfg, token, pos, kv, temps, topps,
-                             coins, n_steps, poison):
-    from ..models.llama import sampled_steps_guarded
-    from ..parallel.api import constrain
-
-    (toks, nf), kv = sampled_steps_guarded(params, cfg, token, pos, kv,
-                                           temps, topps, coins, n_steps,
-                                           poison)
-    return (constrain(toks, None, None), constrain(nf, None)), kv
-
-
-def _replicated_ragged_verify(params, cfg, tokens, pos, kv, temps, topps,
-                              coins, poison):
-    from ..models.llama import ragged_verify_step_guarded
-    from ..parallel.api import constrain
-
-    (n_acc, preds, nf), kv = ragged_verify_step_guarded(
-        params, cfg, tokens, pos, kv, temps, topps, coins, poison)
-    return (constrain(n_acc, None), constrain(preds, None, None),
-            constrain(nf, None)), kv
 
 
 @dataclass
@@ -1031,36 +998,22 @@ class BatchedGenerator(_GeneratorCore):
         self._ctx: list[list[int] | None] = [None] * n_slots
 
         # one fused ragged step: forward + per-row sample (greedy rows mixed
-        # in via temperature 0); same jitted function family as the engine's.
-        # Under multihost the host-read outputs (picked tokens, verify
-        # accept counts) must be REPLICATED or np.asarray on a
-        # non-addressable global array throws — the ragged twin of
-        # parallel.multihost's replicated_* wrappers.
-        # plan_scoped_jit everywhere a shared module-level model function
-        # is jitted: the traced program bakes in THIS engine's mesh plan
-        # (constrain is trace-time), so the trace cache must be scoped to
-        # the ENGINE, not shared via the bare function's identity. The
-        # step programs are the model's step functions behind their packed
-        # arguments (steppack.jit_packed_step). The engine owns the two
-        # that every slot-pool generator serving it dispatches, so a second
-        # generator on this engine (a supervised restart builds one) shares
-        # the executables the first compiled: a fresh wrapper here would
-        # recompile a full-model program (minutes on real models).
+        # in via temperature 0), and the chunked ragged decode (engine
+        # --decode-chunk composed with --batch-slots: K fused steps over the
+        # whole pool per dispatch, K× fewer dispatches, host-loop ticks and,
+        # under multihost, control packets, when every active slot has K rows
+        # of headroom): the model's step functions behind their packed
+        # arguments (steppack.jit_packed_step). The ENGINE owns both, so a
+        # second generator on this engine (a supervised restart builds one)
+        # shares the executables the first compiled: a fresh wrapper here
+        # would recompile a full-model program (minutes on real models).
+        # Under multihost the host-read outputs (picked tokens, verify accept
+        # counts) must be REPLICATED or np.asarray on a non-addressable
+        # global array throws: the programs are
+        # parallel.multihost.replicated's, as the engine's solo ones are.
         _sc = getattr(engine, "introspection_scope", None) or "default"
-        self._step = (steppack.jit_packed_step(
-            _replicated_ragged_step, scope=_sc,
-            name="_replicated_ragged_step")
-            if engine.multihost else engine._packed_sampled_step)
-        # chunked ragged decode (engine --decode-chunk composed with
-        # --batch-slots): K fused steps over the whole pool per dispatch —
-        # K× fewer dispatches and host-loop ticks (and control packets,
-        # under multihost) when every active slot has K rows of headroom.
-        # sampled_steps broadcasts over rows (vector temps/topps, [K, B]
-        # coins), so the engine's chunk program IS the ragged chunk program.
-        self._steps = (steppack.jit_packed_step(
-            _replicated_ragged_steps, scope=_sc,
-            name="_replicated_ragged_steps", n_static=1)
-            if engine.multihost else engine._packed_sampled_steps)
+        self._step = engine._packed_sampled_step
+        self._steps = engine._packed_sampled_steps
         # speculative serving (engine --spec-lookup): per-slot prompt-lookup
         # drafts verified in the ragged program. Greedy rows accept runs;
         # sampled rows keep their exact one-token/one-coin behavior, so every
@@ -1068,10 +1021,8 @@ class BatchedGenerator(_GeneratorCore):
         self.spec = max(0, getattr(engine, "spec_lookup", 0))
         self._proposers: list = [None] * n_slots
         if self.spec:
-            from ..models.llama import ragged_verify_step_guarded
-
             self._verify = steppack.jit_packed_step(
-                _replicated_ragged_verify if engine.multihost
+                replicated(ragged_verify_step_guarded) if engine.multihost
                 else ragged_verify_step_guarded, scope=_sc,
                 name=("_replicated_ragged_verify" if engine.multihost
                       else "ragged_verify_step"))
@@ -1344,7 +1295,7 @@ class BatchedGenerator(_GeneratorCore):
         return emitted
 
     def step_chunk(self, k: int) -> int:  # dlint: owner=loop-thread
-        """K fused ragged decode steps in one dispatch (models.sampled_steps, ragged form).
+        """K fused ragged decode steps in one dispatch (models.sampled_steps_guarded, ragged form).
 
         Falls back to :meth:`step` when chunking can't apply this tick:
         k<=1, speculative mode (spec already multiplies tokens/dispatch), or
@@ -1417,7 +1368,7 @@ class BatchedGenerator(_GeneratorCore):
         return live / (self.n_slots * self.cfg.seq_len)
 
     def _spec_step(self, active: list[int], temps, topps, coins) -> int:  # dlint: owner=loop-thread
-        """One ragged speculative verify dispatch (models.ragged_verify_step):
+        """One ragged speculative verify dispatch (models.ragged_verify_step_guarded):
         greedy rows emit their accepted run, sampled rows exactly one token."""
         with self.flight.tick_phase("step_prepare"):
             toks = np.zeros((self.n_slots, self.spec + 1), dtype=np.int32)

@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import os
+
 import numpy as np
 
 from dllama_tpu.formats import mfile, quants, tfile
@@ -205,38 +207,93 @@ def compile_paged_step(cfg, params, n_slots: int, n_blocks: int,
     return compiled, pkv.k.size * pkv.k.dtype.itemsize
 
 
-def lowered_program_digests(cfg, params, column) -> dict:
-    """sha256 of the lowered text of a stateful decoder family's two
+def tiny_family_engine(folder: str, tmp_path, seed: int = 7):
+    """An engine over ``benchmark/<folder>``'s selftest model (float32, a
+    context of 512, blocks of 16), its planes drawn by the folder's own
+    weights module from ``seed``: what every family's test file builds. The
+    weights module's seam replaces ``runtime.engine.load_params_from_mfile``
+    for the process; the caller puts it back."""
+    import glob
+    import importlib.util
+    import json
+    import sys
+
+    from dllama_tpu.runtime.engine import InferenceEngine
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)        # as run.py puts it: a weights module imports its neighbours by name
+    import run as bench_run
+
+    (tiny,) = glob.glob(os.path.join(bench, folder, "selftest", "configs", "tiny-*.json"))
+    with open(tiny, encoding="utf-8") as f:
+        model = bench_run.model_view(json.load(f))
+    spec = importlib.util.spec_from_file_location(f"{folder}_digest_weights", os.path.join(bench, folder, "weights.py"))
+    weights = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(weights)
+    path = str(tmp_path / f"tiny-{folder}.m")
+    weights.write_sparse_model(path, model)
+    weights.install_seam(seed)
+    return InferenceEngine(path, None, max_seq_len=512, compute_dtype="float32", kv_block_size=16)
+
+
+LOWERED_PROGRAMS = ("forward", "step", "tick")
+
+
+def lowered_program_digest(cfg, params, program: str) -> str | None:
+    """sha256 of the lowered text of one of a decoder family's three
     programs at one small geometry, from shapes alone: ``forward`` over a
-    chunk of 32 into ``column`` (with its valid length) and
-    ``paged_sampled_step_guarded`` over 4 rows, 33 blocks of 16, tables 8
-    wide, float32 pools. What a refactoring of code the family shares with
-    another must leave as it was: take the digests on the parent commit with
-    this same function and hold the change to them."""
+    chunk of 32 into an admission's column of 512 positions (with its valid
+    length where the family is paged-only), ``step``
+    (``paged_sampled_step_guarded``) over 4 rows, 33 blocks of 16 (a window
+    pool of 13), tables 8 wide, float32 pools, and ``tick`` (``family_of(cfg)
+    .tick``, None where the family brings none) over both. What a refactoring
+    of code that families share must leave as it was: take the digests on
+    the parent commit with this same function and hold the change to them
+    (``tests/goldens/program_hlo_sha256.json``). ``as_text()`` carries no
+    source locations, so moving and renaming Python functions leaves it
+    alone; the order of traced operations does not."""
     import hashlib
 
     import jax
     import jax.numpy as jnp
 
     from dllama_tpu.models import llama
+    from dllama_tpu.models.family import family_of
     from dllama_tpu.runtime.kvblocks import PagedKVCache, StatePool
 
     S = jax.ShapeDtypeStruct
     shapes = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
     i32, f32 = jnp.int32, jnp.float32
-    digest = lambda lowered: hashlib.sha256(lowered.as_text().encode()).hexdigest()
-    B = 4
-    cache = (shapes(PagedKVCache.create(cfg, 33, 16, dtype=f32)), shapes(StatePool.create(cfg, B, f32)))
+    B, T, BS, M = 4, 32, 16, 8
+    family = family_of(cfg)
+    k = S((cfg.n_kv_layers, 1, cfg.cache_heads, 512, cfg.cache_width), f32)
+    column = jax.eval_shape(lambda k: family.column(cfg, k, None if cfg.has_latent_cache else k), k)
+    # what the step's cache is made of, in the order ``PagedGenerator._cache_parts`` has it
+    cache = [shapes(PagedKVCache.create(cfg, 33, BS, dtype=f32))]
+    if cfg.has_window_layers:
+        cache.append(PagedKVCache(*(S((cfg.n_window_layers, 13, cfg.n_kv_heads, BS, cfg.head_dim), f32) for _ in "kv")))
+    if cfg.has_state:
+        cache.append(shapes(StatePool.create(cfg, B, f32)))
     if cfg.has_expert_share:
         from dllama_tpu.models.share import zero_totals
 
-        cache += (shapes(zero_totals(cfg)),)
-    return {
-        "forward": digest(jax.jit(lambda p, *a: llama.forward(p, cfg, *a)).lower(
-            shapes(params), S((1, 32), i32), S((), i32), shapes(column), S((), i32))),
-        "step": digest(jax.jit(lambda p, *a: llama.paged_sampled_step_guarded(p, cfg, *a)).lower(
-            shapes(params), S((B, 1), i32), S((B,), i32), cache, S((B, 8), i32),
-            S((B,), f32), S((B,), f32), S((B,), f32), S((), f32)))}
+        cache.append(shapes(zero_totals(cfg)))
+    cache = cache[0] if len(cache) == 1 else tuple(cache)
+    tables = S((2, B, M) if cfg.has_window_layers else (B, M), i32)
+    valid = (S((), i32),) if cfg.paged_only else ()
+    rows = (S((B, 1), i32), S((B,), i32))
+    if program == "forward":
+        fn, args = llama.forward, (S((1, T), i32), S((), i32), column, *valid)
+    elif program == "step":
+        fn = llama.paged_sampled_step_guarded
+        args = (*rows, cache, tables, S((B,), f32), S((B,), f32), S((B,), f32), S((), f32))
+    elif family.tick is None:
+        return None
+    else:
+        fn, args = family.tick, (*rows, (column, cache), tables, S((1, T), i32), S((), i32), *valid, S((), f32))
+    lowered = jax.jit(lambda p, *a: fn(p, cfg, *a)).lower(shapes(params), *args)
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
 
 
 def param_shapes(cfg, scales_dtype):

@@ -180,24 +180,52 @@ def test_shard_map_shim_fires_on_code_not_prose(tmp_path):
 
 
 def test_guarded_twin_completeness(tmp_path):
+    """A decode program WITHOUT the tripwire is the finding: one that does
+    not end in ``_guarded``, one that ends so and takes no ``poison``, and a
+    replicated one alike. The survivors (a ``_guarded`` program that takes
+    ``poison``), ``forward``-named programs and private helpers are not."""
     project = _tree(tmp_path, {
         "dllama_tpu/models/llama.py": """\
             def fancy_sampled_step(params, cfg, tokens, pos, kv):
                 return tokens
 
 
-            def sampled_step(params, cfg, tokens, pos, kv):
+            def sampled_step_guarded(params, cfg, tokens, pos, kv, poison):
                 return tokens
 
 
-            def sampled_step_guarded(params, cfg, tokens, pos, kv, poison):
+            def greedy_steps_guarded(params, cfg, token, pos, kv, n_steps):
+                return token
+
+
+            def forward_and_step(params, cfg, tokens, pos, kv):
+                return tokens
+
+
+            def _scan_decode_guarded(step1, token, pos, kv, n_steps):
+                return token
+            """,
+        "dllama_tpu/parallel/multihost.py": """\
+            def replicated_forward(params, cfg, tokens, pos, kv):
+                return tokens
+
+
+            def replicated(program):
+                return program
+
+
+            def replicated_greedy(params, cfg, tokens, pos, kv):
                 return tokens
             """,
     })
     res = _run("guarded-twin", project)
-    assert [(f.rule, f.lineno) for f in res.findings] == [
-        ("guarded-twin", 1)]
+    assert [(f.rule, f.path, f.lineno) for f in res.findings] == [
+        ("guarded-twin", "dllama_tpu/models/llama.py", 1),
+        ("guarded-twin", "dllama_tpu/models/llama.py", 9),
+        ("guarded-twin", "dllama_tpu/parallel/multihost.py", 9)]
     assert "fancy_sampled_step" in res.findings[0].message
+    assert "greedy_steps_guarded" in res.findings[1].message
+    assert "replicated_greedy" in res.findings[2].message
 
 
 # -- thread ownership ---------------------------------------------------------

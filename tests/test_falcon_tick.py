@@ -186,22 +186,6 @@ def test_the_module_is_named_for_the_chunk_and_is_the_familys_tick():
     assert "jit_" + steppack.packed_program(falcon_h1.FAMILY.tick).__name__ == "jit_forward_and_step"
 
 
-@pytest.mark.parametrize("program,parent", [
-    ("forward", "2a4314b82693a0cc6a41518ffa1f6493da0049764271dc36dbe9874a399527fc"),
-    ("step", "ee25f2cea24c699c45c1847368a83d0568a8b467bd9bdd30cbeccb450f5d54d2")])
-def test_the_two_programs_are_as_lowered_before_the_third(engine, program, parent):
-    """The tick is a third pair of closures over ``_scan_layers``, with the
-    head taken out of it (``_head``) and the mixer split at its in-projection:
-    ``forward`` and the step, which every chunk-free tick still runs, lower to
-    the text they lowered to on commit 5133172 (PR 51), the change's parent
-    (``helpers.lowered_program_digests`` there)."""
-    from helpers import lowered_program_digests
-
-    cfg = engine.cfg
-    k = jnp.zeros((cfg.n_layers, 1, cfg.n_kv_heads, 512, cfg.head_dim), jnp.float32)
-    assert lowered_program_digests(cfg, engine.params, StateColumn.zeros(cfg, k, k, jnp.float32))[program] == parent
-
-
 def test_one_read_of_every_plane_a_layer(engine, monkeypatch):
     """What the program is for: the traced layer body asks ``linear`` ONCE for
     each of the nine Q40 planes, over the joined ``T + R`` rows, and once for

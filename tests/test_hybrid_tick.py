@@ -193,23 +193,6 @@ def test_the_module_is_named_for_the_chunk_and_is_the_familys_tick():
     assert "jit_" + steppack.packed_program(hybrid.FAMILY.tick).__name__ == "jit_forward_and_step"
 
 
-@pytest.mark.parametrize("program,parent", [
-    ("forward", "4c7eb60dcc1ba50ceb1b75ad7c4643cb39c4aa9ed7813ade509e3a4b01396f9f"),
-    ("step", "62d8ae91098b48a8b855c4b082803e533d3b0834155b6385587af921045f7250")])
-def test_the_two_programs_are_as_lowered_before_the_third(engine, program, parent):
-    """The tick is a third set of closures over ``_scan_periods``, with the
-    head taken out of it (``_head``) and the mixer cut in front of its
-    convolution and behind it: ``forward`` and the step, which every
-    chunk-free tick still runs, lower to the text they lowered to on commit
-    99b391b (PR 54), the change's parent (``helpers.lowered_program_digests``
-    there)."""
-    from helpers import lowered_program_digests
-
-    cfg = engine.cfg
-    k = jnp.zeros((cfg.n_kv_layers, 1, cfg.n_kv_heads, 512, cfg.head_dim), jnp.float32)
-    assert lowered_program_digests(cfg, engine.params, StateColumn.zeros(cfg, k, k, jnp.float32))[program] == parent
-
-
 def _tick_shapes(engine, T):
     col, pools, tables, pos, tokens, chunk, _knobs = _inputs(engine.cfg, T, [1])
     return (engine.params, tokens, pos, (col, pools), tables, chunk, jnp.int32(16), jnp.int32(T), np.float32(0))

@@ -237,30 +237,6 @@ def test_the_module_is_named_for_the_chunk_and_is_the_familys_tick(engine):
     assert "jit_" + steppack.packed_program(laguna.FAMILY.tick).__name__ == "jit_forward_and_step"
 
 
-def test_the_step_program_lowers_to_the_parents_text(engine):
-    """The walk now hands back the hidden rows and its closures are made by
-    two functions the tick program shares: the STEP program, which every
-    step reader of the cell divides by, lowers to the text it lowered to on
-    the parent commit (sha256 taken on b0e7988 with these same lines: 4 rows,
-    a full pool of 33 and a window pool of 13 blocks of 16, tables 8 wide).
-    ``forward`` does not: the layer index ``p * P`` is now traced once where
-    it was traced four times, the same program behind XLA's CSE, and no
-    serving path dispatches it any more."""
-    import hashlib
-
-    cfg = engine.cfg
-    S, i32, f32 = jax.ShapeDtypeStruct, jnp.int32, jnp.float32
-    shapes = lambda tree: jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
-    pool = lambda layers, blocks: PagedKVCache(*(S((layers, blocks, cfg.n_kv_heads, BS, cfg.head_dim), f32)
-                                                 for _ in "kv"))
-    cache = (pool(cfg.n_kv_layers, 33), pool(cfg.n_window_layers, 13), shapes(zero_totals(cfg)))
-    lowered = jax.jit(lambda p, *a: llama.paged_sampled_step_guarded(p, cfg, *a)).lower(
-        shapes(engine.params), S((R, 1), i32), S((R,), i32), cache, S((2, R, M), i32),
-        S((R,), f32), S((R,), f32), S((R,), f32), S((), f32))
-    assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == \
-        "0d970d62ea925632e9d118eb69365072988b12ee12d4e4b0dffc25c0e27c92dc"
-
-
 def test_one_read_of_every_plane_a_layer_and_one_grouped_dispatch(engine, monkeypatch):
     """What the program is for: each traced layer body asks ``linear`` ONCE a
     dense plane over the joined ``T + R`` rows (four an attention half),

@@ -270,22 +270,6 @@ def test_the_references_variants_are_another_function(bench, engine, variant, le
     assert float(np.abs(_reference_logits(bench, engine.params, tokens, variant=variant) - honest).max()) > least
 
 
-@pytest.mark.parametrize("program,parent", [
-    ("forward", "92d1d6ec4e2e39137de689cccd7c5ca70c897bb23eb8951ac1e41eedaa6dbadd"),
-    ("step", "e429dc1525bfd680e18a4bc8162e5ea35f24b32d4b4173fed1333bace8cba226")])
-def test_the_two_programs_are_as_lowered_before_the_third(engine, program, parent):
-    """PR 53's tick program is a third pair of closures over ``_scan_layers``,
-    with the head taken out of the walk (``_head``) and the conv mixer split
-    where its rows stop being independent (``_conv_gate_in``, the convolution,
-    ``_conv_gate_out``): ``forward`` and the step, which every chunk-free tick
-    still runs, call the same pieces in the same order of trace and lower to
-    the text they lowered to on commit 1bb7f27 (PR 52), the change's parent
-    (``helpers.lowered_program_digests`` there)."""
-    from helpers import lowered_program_digests
-
-    assert lowered_program_digests(engine.cfg, engine.params, _column(engine.cfg))[program] == parent
-
-
 def _decode(gen, slots, n_steps):
     """Greedy decode of ``slots`` by hand over the generator's own pools, one
     step program a token, keeping the logits: what ``PagedGenerator.step``

@@ -349,23 +349,6 @@ def test_the_chunk_form_is_the_step_form_across_a_chunk_boundary_with_padding(be
     assert np.asarray(col_a.stats)[0] == np.asarray(col_b.stats)[0]       # padding is not routed
 
 
-@pytest.mark.parametrize("program,parent", [
-    ("forward", "9d097d920776878308fab016a8dedab2c7839a43d6aae633f0da811533175a5e"),
-    ("step", "c313e199cd2fc10cf9151c67c3f4ac844415021bd73f9813c312a4627591291c")])
-def test_the_shared_mixers_split_leaves_both_programs_as_lowered(engine, program, parent):
-    """PR 52 split ``models/ssd_mixer.py`` at the in-projection (what runs a
-    row at a time, then what owns a context) so that falcon_h1's tick program
-    can join a chunk's rows and the decode rows in front of it;
-    ``mixer_chunk`` and ``mixer_step`` are the one-part callers of the same
-    pieces, traced in the order they were. The lowered text of this family's
-    chunk and step is what it was: ``parent`` is
-    ``helpers.lowered_program_digests`` on commit 5133172 (PR 51), the
-    change's parent."""
-    from helpers import lowered_program_digests
-
-    assert lowered_program_digests(engine.cfg, engine.params, _column(engine.cfg))[program] == parent
-
-
 def test_a_bfloat16_program_stays_within_its_stated_tolerance(bench, tmp_path):
     from dllama_tpu.models import llama
 

@@ -2,7 +2,7 @@
 
 Speculative greedy must be BIT-IDENTICAL to plain greedy on every input —
 the verify step accepts exactly the prefix the model itself would have
-produced (models.llama.verify_step) — while a self-repeating prompt must
+produced (models.llama.verify_step_guarded) — while a self-repeating prompt must
 show real multi-token acceptance (fewer dispatches than tokens). The
 reference has no speculative path (one token per step, dllama.cpp:88-99);
 this is a TPU-economics feature: decode is HBM-bound, so tokens per weight
@@ -18,7 +18,7 @@ import pytest
 
 from dllama_tpu.formats import quants, tfile
 from dllama_tpu.models import ModelConfig, init_random_params
-from dllama_tpu.models.llama import greedy_step, verify_step
+from dllama_tpu.models.llama import greedy_step_guarded, verify_step_guarded
 from dllama_tpu.runtime import KVCache
 from dllama_tpu.runtime.engine import InferenceEngine
 from dllama_tpu.runtime.speculative import NgramProposer
@@ -70,7 +70,9 @@ def test_proposer_bigram_fallback_when_trigram_unseen():
     assert p.draft() == [7, 7]
 
 
-# -- verify_step vs sequential greedy ---------------------------------------
+# -- verify_step_guarded vs sequential greedy -------------------------------
+
+CLEAN = np.float32(0.0)          # the tripwire's poison selector outside a chaos run
 
 
 def _cfg():
@@ -93,20 +95,22 @@ def test_verify_matches_sequential_greedy(trial):
 
     # sequential oracle
     kv = KVCache.create(cfg)
-    step = jax.jit(greedy_step, static_argnums=1)
+    step = jax.jit(greedy_step_guarded, static_argnums=1)
     seq = []
     t = token
     for i in range(len(drafts) + 1):
-        nxt, kv = step(params, cfg, jnp.asarray([[t]]), jnp.int32(pos + i), kv)
+        (nxt, nf), kv = step(params, cfg, jnp.asarray([[t]]), jnp.int32(pos + i), kv, CLEAN)
+        assert int(nf[0]) == 0
         seq.append(int(nxt[0]))
         t = seq[-1]
 
     # one verify dispatch
     kv2 = KVCache.create(cfg)
-    ver = jax.jit(verify_step, static_argnums=1)
-    n_acc, preds, _ = ver(params, cfg,
-                          jnp.asarray([[token, *drafts]], jnp.int32),
-                          jnp.int32(pos), kv2)
+    ver = jax.jit(verify_step_guarded, static_argnums=1)
+    (n_acc, preds, nf), _ = ver(params, cfg,
+                                jnp.asarray([[token, *drafts]], jnp.int32),
+                                jnp.int32(pos), kv2, CLEAN)
+    assert int(nf[0]) == 0
     n_acc = int(n_acc[0])
     preds = np.asarray(preds)[0]
 
@@ -188,10 +192,11 @@ def test_spec_ignored_at_temperature(model_files):
 
 
 def test_ragged_verify_matches_per_row_oracles():
-    """ragged_verify_step row-by-row: greedy rows equal a solo verify_step
-    at that row's position; sampled rows equal sampled_token on the
-    position-0 logits with n_acc forced to 0."""
-    from dllama_tpu.models.llama import ragged_verify_step
+    """ragged_verify_step_guarded row-by-row: greedy rows equal a solo
+    verify_step_guarded at that row's position; sampled rows equal
+    sampled_token on the position-0 logits with n_acc forced to 0; no row
+    counts a non-finite logit."""
+    from dllama_tpu.models.llama import ragged_verify_step_guarded
     from dllama_tpu.ops.sampling import sampled_token
 
     cfg = _cfg()
@@ -205,15 +210,16 @@ def test_ragged_verify_matches_per_row_oracles():
     coins = jnp.asarray([0.0, 0.37, 0.0], jnp.float32)
 
     kv = KVCache.create(cfg, batch_size=B)
-    n_acc, preds, _ = jax.jit(ragged_verify_step, static_argnums=1)(
-        params, cfg, toks, pos, kv, temps, topps, coins)
+    (n_acc, preds, nf), _ = jax.jit(ragged_verify_step_guarded, static_argnums=1)(
+        params, cfg, toks, pos, kv, temps, topps, coins, CLEAN)
     n_acc, preds = np.asarray(n_acc), np.asarray(preds)
+    assert (np.asarray(nf) == 0).all()
 
     for b in (0, 2):  # greedy rows: equal a solo single-row verify
         kv1 = KVCache.create(cfg)
-        na1, p1, _ = jax.jit(verify_step, static_argnums=1)(
-            params, cfg, toks[b:b + 1], pos[b], kv1)
-        assert int(na1[0]) == n_acc[b]
+        (na1, p1, nf1), _ = jax.jit(verify_step_guarded, static_argnums=1)(
+            params, cfg, toks[b:b + 1], pos[b], kv1, CLEAN)
+        assert int(na1[0]) == n_acc[b] and int(nf1[0]) == 0
         np.testing.assert_array_equal(np.asarray(p1)[0], preds[b])
 
     # sampled row: n_acc 0 and first token from the row's own coin
@@ -229,7 +235,7 @@ def test_ragged_verify_matches_per_row_oracles():
 
 
 def test_speculative_on_moe_model(tmp_path):
-    """verify_step is forward-based, so speculation rides MoE models too:
+    """verify_step_guarded is forward-based, so speculation rides MoE models too:
     identical to plain greedy."""
     m, t = tmp_path / "m.m", tmp_path / "t.t"
     write_tiny_model(m, tiny_header_params(vocab_size=268, seq_len=96,

@@ -109,7 +109,7 @@ def test_fused_greedy_decode_on_hw(tpu_backend):
 
     from dllama_tpu.formats.mfile import ArchType, RopeType
     from dllama_tpu.models import ModelConfig, init_random_params
-    from dllama_tpu.models.llama import greedy_step
+    from dllama_tpu.models.llama import greedy_step_guarded
     from dllama_tpu.runtime import KVCache
 
     cfg = ModelConfig(
@@ -119,12 +119,14 @@ def test_fused_greedy_decode_on_hw(tpu_backend):
         compute_dtype="bfloat16")
     params = init_random_params(cfg, seed=3, quantized=True)
     kv = KVCache.create(cfg, dtype=jnp.bfloat16)
-    greedy = jax.jit(greedy_step, static_argnums=1, donate_argnums=(4,))
+    greedy = jax.jit(greedy_step_guarded, static_argnums=1, donate_argnums=(4,))
+    clean = jnp.float32(0.0)            # the tripwire's poison selector outside a chaos run
 
     token = jnp.zeros((1, 1), jnp.int32)
     toks = []
     for pos in range(4):
-        nxt, kv = greedy(params, cfg, token, jnp.int32(pos), kv)
+        (nxt, nf), kv = greedy(params, cfg, token, jnp.int32(pos), kv, clean)
+        assert int(nf[0]) == 0
         token = nxt[:, None]
         toks.append(int(nxt[0]))
     assert all(0 <= t < cfg.vocab_size for t in toks)
@@ -133,7 +135,7 @@ def test_fused_greedy_decode_on_hw(tpu_backend):
     token = jnp.zeros((1, 1), jnp.int32)
     toks2 = []
     for pos in range(4):
-        nxt, kv2 = greedy(params, cfg, token, jnp.int32(pos), kv2)
+        (nxt, _), kv2 = greedy(params, cfg, token, jnp.int32(pos), kv2, clean)
         token = nxt[:, None]
         toks2.append(int(nxt[0]))
     assert toks == toks2
@@ -192,7 +194,7 @@ def test_ragged_serving_programs_on_hw(tpu_backend):
 
     from dllama_tpu.formats.mfile import ArchType, RopeType
     from dllama_tpu.models import ModelConfig, init_random_params
-    from dllama_tpu.models.llama import ragged_verify_step, sampled_step
+    from dllama_tpu.models.llama import ragged_verify_step_guarded, sampled_step_guarded
     from dllama_tpu.runtime import KVCache
 
     cfg = ModelConfig(
@@ -203,20 +205,22 @@ def test_ragged_serving_programs_on_hw(tpu_backend):
     params = init_random_params(cfg, seed=9, quantized=True)
     n_slots = 4
     kv = KVCache.create(cfg, batch_size=n_slots, dtype=jnp.bfloat16)
-    step = jax.jit(sampled_step, static_argnums=1, donate_argnums=(4,))
-    verify = jax.jit(ragged_verify_step, static_argnums=1, donate_argnums=(4,))
+    step = jax.jit(sampled_step_guarded, static_argnums=1, donate_argnums=(4,))
+    verify = jax.jit(ragged_verify_step_guarded, static_argnums=1, donate_argnums=(4,))
+    clean = jnp.float32(0.0)            # the tripwire's poison selector outside a chaos run
 
     pos = jnp.asarray([3, 0, 9, 5], jnp.int32)
     temps = jnp.asarray([0.0, 0.8, 0.0, 1.2], jnp.float32)
     topps = jnp.full((n_slots,), 0.9, jnp.float32)
     coins = jnp.full((n_slots,), 0.4, jnp.float32)
     toks = jnp.ones((n_slots, 1), jnp.int32)
-    nxt, kv = step(params, cfg, toks, pos, kv, temps, topps, coins)
-    assert nxt.shape == (n_slots,)
+    (nxt, nf), kv = step(params, cfg, toks, pos, kv, temps, topps, coins, clean)
+    assert nxt.shape == (n_slots,) and (np.asarray(nf) == 0).all()
     draft = jnp.tile(nxt[:, None], (1, 5))
-    n_acc, preds, kv = verify(params, cfg, draft, pos + 1, kv,
-                              temps, topps, coins)
+    (n_acc, preds, nf), kv = verify(params, cfg, draft, pos + 1, kv,
+                                    temps, topps, coins, clean)
     n_acc, preds = np.asarray(n_acc), np.asarray(preds)
+    assert (np.asarray(nf) == 0).all()
     assert preds.shape == (n_slots, 5)
     sampled_rows = np.asarray(temps) > 0
     assert (n_acc[sampled_rows] == 0).all()  # sampled rows accept nothing
@@ -338,7 +342,7 @@ def test_decode_rate_physically_sane_on_hw(tpu_backend):
 
     from dllama_tpu.formats.mfile import ArchType, RopeType
     from dllama_tpu.models import ModelConfig, init_random_params
-    from dllama_tpu.models.llama import greedy_step
+    from dllama_tpu.models.llama import greedy_step_guarded
     from dllama_tpu.ops.linear import QuantizedWeight
     from dllama_tpu.runtime import KVCache
 
@@ -349,15 +353,17 @@ def test_decode_rate_physically_sane_on_hw(tpu_backend):
         rope_type=RopeType.LLAMA, compute_dtype="bfloat16")
     params = init_random_params(cfg, seed=5, quantized=True)
     kv = KVCache.create(cfg, dtype=jnp.bfloat16)
-    greedy = jax.jit(greedy_step, static_argnums=1, donate_argnums=(4,))
+    greedy = jax.jit(greedy_step_guarded, static_argnums=1, donate_argnums=(4,))
+    clean = jnp.float32(0.0)            # the tripwire's poison selector outside a chaos run
 
     def fetch(x):
         jax.device_get(jnp.ravel(x)[0])
 
     token = jnp.zeros((1,), jnp.int32)
-    token, kv = greedy(params, cfg, token[:, None], jnp.int32(0), kv)
+    (token, nf), kv = greedy(params, cfg, token[:, None], jnp.int32(0), kv, clean)
+    assert int(nf[0]) == 0
     fetch(token)
-    token, kv = greedy(params, cfg, token[:, None], jnp.int32(1), kv)
+    (token, _), kv = greedy(params, cfg, token[:, None], jnp.int32(1), kv, clean)
     fetch(token)  # throwaway: first post-compile dispatch absorbs backlog
     probe = jax.jit(lambda x: x + 1)(jnp.zeros((8,), jnp.int32))
     fetch(probe)
@@ -368,7 +374,7 @@ def test_decode_rate_physically_sane_on_hw(tpu_backend):
     steps = 24
     t0 = time.perf_counter()
     for i in range(steps):
-        token, kv = greedy(params, cfg, token[:, None], jnp.int32(2 + i), kv)
+        (token, _), kv = greedy(params, cfg, token[:, None], jnp.int32(2 + i), kv, clean)
     fetch(token)
     ms = 1e3 * max(1e-9, time.perf_counter() - t0 - rtt) / steps
 

@@ -37,11 +37,12 @@ Three rule families over ``dllama_tpu/`` (see LINTS.md for the catalog):
   tracers are static and stay allowed.
 
 * ``guarded-twin`` — **tripwire completeness** (the PR5 contract): every
-  decode-program in the ``*_step``/``*_steps`` family
-  (``models/llama.py``) and the replicated multihost family
-  (``parallel/multihost.py``) must have its ``*_guarded`` twin, or the
-  non-finite tripwire has a blind spot exactly where an engine could
-  dispatch.
+  decode program in the ``*_step``/``*_steps`` family (``models/llama.py``)
+  and every ``replicated_*`` program (``parallel/multihost.py``) takes
+  ``poison`` and ends in ``_guarded``. There is ONE program a kind and the
+  tripwire is in it (a caller that wants no injection passes poison 0.0): a
+  decode program without it is a dispatch the non-finite tripwire cannot
+  ride, exactly where an engine could dispatch.
 """
 
 from __future__ import annotations
@@ -516,47 +517,50 @@ _LLAMA = f"{PKG}/models/llama.py"
 _MULTIHOST = f"{PKG}/parallel/multihost.py"
 
 
-def _module_defs(sf: SourceFile) -> dict[str, int]:
-    out: dict[str, int] = {}
+def _module_defs(sf: SourceFile) -> dict[str, ast.FunctionDef]:
+    out: dict[str, ast.FunctionDef] = {}
     if sf.tree is None:
         return out
     for node in sf.tree.body:  # module level only
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            out[node.name] = node.lineno
+            out[node.name] = node
     return out
 
 
 @rule("guarded-twin",
-      "every decode program in the *_step family has its _guarded "
-      "tripwire twin (PR5 contract)")
+      "every decode program in the *_step family carries the tripwire: it "
+      "takes `poison` and ends in _guarded (PR5 contract)")
 def check_guarded_twins(project: Project):
     findings: list[Finding] = []
     checked = 0
 
     def family(sf: SourceFile, member) -> None:
         nonlocal checked
-        defs = _module_defs(sf)
-        for name, lineno in sorted(defs.items()):
-            if name.startswith("_") or name.endswith("_guarded"):
+        for name, fn in sorted(_module_defs(sf).items()):
+            if name.startswith("_") or "forward" in name:
                 continue
-            if "forward" in name or not member(name):
+            if not member(name.removesuffix("_guarded")):
                 continue
             checked += 1
-            if f"{name}_guarded" not in defs:
+            args = fn.args
+            takes = {a.arg for a in (*args.posonlyargs, *args.args,
+                                     *args.kwonlyargs)}
+            if not name.endswith("_guarded") or "poison" not in takes:
                 findings.append(Finding(
-                    "guarded-twin", sf.rel, lineno,
-                    f"decode program {name!r} has no {name}_guarded twin "
-                    f"— the non-finite tripwire (PR5) cannot ride its "
-                    f"dispatches; add the twin next to it"))
+                    "guarded-twin", sf.rel, fn.lineno,
+                    f"decode program {name!r} carries no tripwire (it must "
+                    f"take `poison` and end in _guarded): the non-finite "
+                    f"tripwire (PR5) cannot ride its dispatches. There is "
+                    f"one program a kind; put the tripwire in it, and pass "
+                    f"poison 0.0 where no injection is wanted"))
 
     llama = project.file(_LLAMA)
     if llama is not None:
-        family(llama, lambda n: n.endswith(("_step", "_steps"))
-               or n in ("greedy_step", "sampled_step"))
+        family(llama, lambda n: n.endswith(("_step", "_steps")))
     elif project.file(PKG) is not None:  # pragma: no cover
         findings.append(Finding("guarded-twin", _LLAMA, 0, "file missing"))
     mh = project.file(_MULTIHOST)
     if mh is not None:
         family(mh, lambda n: n.startswith("replicated_"))
-    return findings, (f"{checked} decode-family programs all have their "
-                      f"_guarded tripwire twins")
+    return findings, (f"{checked} decode-family programs all carry the "
+                      f"tripwire")
